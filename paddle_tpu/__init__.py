@@ -19,7 +19,6 @@ Typical use mirrors fluid:
 
 __version__ = "0.1.0"
 
-from . import jax_compat as _jax_compat  # older-jax aliases first  # noqa: F401
 from . import ops as _ops  # registers all op lowerings  # noqa: F401
 
 from .core.framework import (  # noqa: F401

@@ -56,7 +56,10 @@ class HloInstr:
     name: str
     opcode: str
     shapes: List[Shape]               # result shapes (tuple flattened)
-    operands: List[Tuple[Shape, str]]  # shaped operand refs, in order
+    # (shape, name) per operand defined in the same computation; the
+    # compiler prints operands as bare %refs, so the shape is the
+    # producer's (None where the producer is a tuple)
+    operands: List[Tuple[Optional[Shape], str]]
     operand_names: List[str]          # every %ref on the line, in order
     is_root: bool = False
     line: str = ""
@@ -90,8 +93,6 @@ def _result_shapes(text: str) -> List[Shape]:
             for m in _SHAPE_RE.finditer(text)]
 
 
-_OPERAND_RE = re.compile(
-    r"([a-z][a-z0-9]*\[[\d,]*\](?:\{[^}]*\})?)\s+%([\w.\-]+)")
 _REF_RE = re.compile(r"%([\w.\-]+)")
 
 
@@ -158,12 +159,16 @@ def entry_instructions(hlo_text: str) -> List[HloInstr]:
             name=m.group(2),
             opcode=om.group(1),
             shapes=_result_shapes(shape_txt),
-            operands=[(parse_shape(s.group(1)), s.group(2))
-                      for s in _OPERAND_RE.finditer(rest)],
+            operands=[],
             operand_names=_REF_RE.findall(rest),
             is_root=bool(m.group(1)),
             line=line.strip(),
         ))
+    by_name = {i.name: i for i in out}
+    for instr in out:
+        instr.operands = [
+            (by_name[n].shapes[0] if len(by_name[n].shapes) == 1 else None, n)
+            for n in instr.operand_names if n in by_name]
     return out
 
 

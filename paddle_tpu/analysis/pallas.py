@@ -167,8 +167,9 @@ def kernel_cost(eqn) -> KernelCost:
     invars (scalar-prefetch SMEM operands, scratch shapes)."""
     gm = eqn.params["grid_mapping"]
     kernel_jaxpr = eqn.params["jaxpr"]
-    name = str(eqn.params.get("name_and_src_info", "pallas_call"))
-    name = name.split(" at ")[0] or "pallas_call"
+    # the name Mosaic gives the kernel: pallas_call's `name=` when one was
+    # passed, else the kernel function's own
+    name = eqn.params["name"] or kernel_jaxpr.debug_info.func_name
     grid = tuple(int(g) for g in gm.grid if isinstance(g, int))
     grid_size = 1
     for g in grid:

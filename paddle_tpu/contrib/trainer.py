@@ -72,13 +72,13 @@ class CheckpointConfig:
 
 
 def check_and_get_place(place):
-    """reference: trainer.py:143 — default to TPU when available."""
+    """reference: trainer.py:143 — default to the TPU where the backend
+    has one, else the CPU."""
     if place is not None:
         return place
-    try:
-        return TPUPlace()
-    except Exception:
-        return CPUPlace()
+    import jax
+
+    return CPUPlace() if jax.default_backend() == "cpu" else TPUPlace()
 
 
 class Trainer:

@@ -10,7 +10,8 @@ composition (pass-created ops store their intermediates exactly like the
 unfused chain — no recompute), or the "pallas" kernel pair
 (kernels/conv_epilogue.py), which accumulates BN statistics inside the
 conv pass and backs it with the analytic vjp — the HBM-roofline attack
-(92.5 GB/step measured on ResNet-50, BENCH_builder_r05).
+(92.5 GB/step on ResNet-50 in an earlier round's v5e run, not
+re-measured).
 
 The pass runs at COMPILE time on the op list a CompiledBlock is about to
 lower (core/compiler.py); the ProgramDesc itself is never mutated, so

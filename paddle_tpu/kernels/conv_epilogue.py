@@ -2,8 +2,8 @@
 
 Reference counterpart: conv2d_fusion — cuDNN's fused
 conv+bias+activation op (/root/reference/paddle/fluid/operators/
-conv_fusion_op.cu.cc:1).  This is the TPU-native answer to the round-4
-minimal-traffic analysis (CHANGES_r04): with XLA owning convs, BN's
+conv_fusion_op.cu.cc:1).  This is the TPU-native answer to a
+minimal-traffic analysis: with XLA owning convs, BN's
 batch statistics force extra full passes over every conv output, which
 bounds XLA-conv ResNet-50 near MFU ~0.20 on v5e.  Fusing the stats
 accumulation INTO the conv pass and the normalize/residual/relu into

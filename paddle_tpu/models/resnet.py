@@ -19,12 +19,11 @@ def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu",
     """conv -> BN(+act).  fuse_bn=True emits the recompute-tagged
     fused_bn_add_act op: same numbers, but backward rebuilds the normalize/
     act chain instead of storing it — the HBM-traffic fix for the profile's
-    72% elementwise share (CHANGES_r03).  The DEFAULT is False — the
-    defaults-follow-measurements rule (VERDICT r4 weak #1): the only
-    chip-measured ResNet trajectory (r3, 2225 img/s) ran the unfused
-    chain, and the r4 instruction-count watch-item flags ~3x transposes
-    on the fused path; the default flips to True the day the chip A/B
-    (chip_session fuse_bn_ab) measures the fused op faster.  fuse_bn=False
+    72% elementwise share (an earlier round's v5e run, not re-measured).
+    The DEFAULT is False — defaults follow measurements: that run used
+    the unfused chain, and an instruction count flags ~3x transposes on
+    the fused path; the default flips to True the day a chip A/B
+    measures the fused op faster.  fuse_bn=False
     also keeps the separate reference-shaped batch_norm op (transpilers
     that pattern-match conv+BN, e.g. the inference fold, want that
     shape)."""

@@ -48,7 +48,7 @@ __all__ = [
 ]
 
 # step-time-shaped default buckets (seconds): sub-ms host dispatch up to
-# multi-second relay compiles, +Inf implicit
+# multi-second compiles, +Inf implicit
 DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 120.0,

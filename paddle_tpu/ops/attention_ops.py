@@ -21,23 +21,52 @@ def _fused_attn_infer(op, block):
     set_output(block, op, "Out", list(q.shape), q.dtype)
 
 
+def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
+    """Wrap `attend(q, k, v[, k_lengths])` in a shard_map over `mesh`:
+    batch over dp, heads over tp where tp divides them, sequence whole.
+    XLA cannot partition a Mosaic kernel by itself ("Mosaic kernels cannot
+    be automatically partitioned") — without this the SPMD step of a
+    flash-attention model does not compile for more than one chip.  Each
+    device runs the kernel on its own [B/dp, H/tp, S, D] block; attention
+    never mixes batch rows or heads, so no collective is needed."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import AXIS_DP, AXIS_TP
+
+    dp = AXIS_DP if mesh.has_axis(AXIS_DP) else None
+    tp = AXIS_TP if (mesh.has_axis(AXIS_TP)
+                     and n_head % mesh.axis_size(AXIS_TP) == 0) else None
+    qkv = P(dp, tp, None, None)
+    in_specs = (qkv, qkv, qkv) + ((P(dp),) if has_lengths else ())
+    # check_vma off: pallas_call has no replication rule
+    return jax.shard_map(attend, mesh=mesh.mesh, in_specs=in_specs,
+                         out_specs=qkv, check_vma=False)
+
+
 @register_op("fused_attention", infer_shape=_fused_attn_infer,
              diff_inputs=["Q", "K", "V"])
 def _fused_attention(ctx, ins, attrs):
     from ..kernels import flash_attention
+    from ..kernels.flash_attention import _use_pallas
 
     q = data(ins["Q"][0])  # [B, H, Sq, D]
     k = data(ins["K"][0])
     v = data(ins["V"][0])
     klen_in = ins.get("KLengths", [None])[0]
     klen = data(klen_in).reshape(-1) if klen_in is not None else None
-    return {
-        "Out": [
-            flash_attention(
-                q, k, v,
-                causal=bool(attrs.get("causal", False)),
-                scale=attrs.get("scale") or None,
-                k_lengths=klen,
-            )
-        ]
-    }
+
+    def attend(q, k, v, klen=None):
+        return flash_attention(
+            q, k, v,
+            causal=bool(attrs.get("causal", False)),
+            scale=attrs.get("scale") or None,
+            k_lengths=klen,
+        )
+
+    if (ctx.mesh is not None and ctx.mesh.num_devices > 1
+            and _use_pallas("auto")):
+        attend = _shard_over_mesh(attend, ctx.mesh, q.shape[1],
+                                  klen is not None)
+    args = (q, k, v) + ((klen,) if klen is not None else ())
+    return {"Out": [attend(*args)]}

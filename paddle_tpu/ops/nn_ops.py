@@ -453,7 +453,8 @@ def _fused_bn_add_act(ctx, ins, attrs):
     jax.checkpoint drops the op-INTERNAL buffers (x_hat, the pre-relu
     sum) and backward recomputes them from X/Z — which BN's backward
     must read anyway.  On an HBM-bound model (ResNet-50: 72% of device
-    time in these chains, CHANGES_r03) that removes one-to-two
+    time in these chains in an earlier round's v5e run, not
+    re-measured) that removes one-to-two
     activation-sized HBM round-trips per BN."""
     outs = _bn_core(ctx, ins, attrs)
     y = outs["Y"][0]
@@ -506,7 +507,7 @@ def _conv_bn_add_act(ctx, ins, attrs):
     kernels/conv_epilogue.py — BN statistics accumulate INSIDE the conv
     pass and normalize/residual/act run as one epilogue pass, cutting
     per-conv activation HBM traffic from ~4-5 passes to 3 (the
-    MFU-ceiling attack, CHANGES_r04).  Train mode only for pallas; test
+    MFU-ceiling attack).  Train mode only for pallas; test
     mode always takes the reference path (moving-stats normalize, no
     batch statistics)."""
     from .. import flags as _flags

@@ -160,8 +160,8 @@ def _kv_spec(axis: str = AXIS_TP) -> P:
 # boundary (entry params AND outputs — the donated pool aliases, so
 # they must agree) erases every relayout copy: the banked
 # sharded_decode zoo entry pins relayout-copy-pair at 0 and the
-# bytes/step win.  Verified against DeviceLocalLayout.AUTO: XLA picks
-# this same layout when left free.
+# bytes/step win.  Verified against Layout.AUTO: XLA picks this same
+# layout when left free.
 KV_POOL_MAJOR_TO_MINOR = (0, 2, 3, 1, 4)
 
 
@@ -169,11 +169,9 @@ def kv_pool_layout(sharding: NamedSharding):
     """The XLA-preferred pool-shard layout wrapped over `sharding` — the
     in/out sharding entry the kv pool args carry on TPU compiles (the
     AOT zoo capture and the real TPU program use the same one)."""
-    from jax.experimental.layout import DeviceLocalLayout, Layout
+    from jax.experimental.layout import Format, Layout
 
-    return Layout(
-        DeviceLocalLayout(major_to_minor=KV_POOL_MAJOR_TO_MINOR),
-        sharding)
+    return Format(Layout(major_to_minor=KV_POOL_MAJOR_TO_MINOR), sharding)
 
 
 # ---------------------------------------------------------------------------

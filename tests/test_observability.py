@@ -630,9 +630,8 @@ def _bench_obs_env(monkeypatch, tmp_path, model, bs):
     monkeypatch.setenv("BENCH_STEPS", "2")
     monkeypatch.setenv("BENCH_TUNE", "0")
     monkeypatch.setenv("BENCH_AMP", "0")
-    monkeypatch.setenv("BENCH_SMOKE", "1")
     monkeypatch.setenv("BENCH_DEADLINE_S", "0")
-    monkeypatch.setenv("BENCH_PREPROBE", "0")
+    monkeypatch.setenv("BENCH_COMPILE_CACHE", "0")
     monkeypatch.setenv("BENCH_CKPT_DIR", "")
     monkeypatch.setenv("BENCH_OBS_DIR", str(tmp_path / "obs"))
     monkeypatch.setenv("BENCH_BASELINE", str(tmp_path / "base.json"))
@@ -678,6 +677,9 @@ def _run_bench_obs(monkeypatch, capsys, tmp_path, model, bs, metric):
         obs.reset()  # artifacts are on disk; keep later tests clean
     rec = json.loads(line)
     assert rec["metric"] == metric, rec
+    # a declared CPU run: the row says so, and claims no MFU
+    assert rec["platform"] == "cpu" and rec["device_count"] >= 1
+    assert rec["mfu"] is None
     _assert_bench_obs_artifacts(rec, tmp_path, metric)
 
 

@@ -611,7 +611,6 @@ def _run_bench(extra_env, timeout=560):
     env.update({
         "JAX_PLATFORMS": "cpu",
         "BENCH_TUNE": "0",
-        "BENCH_PREPROBE": "0",
         "BENCH_DEADLINE_S": "0",
         "BENCH_COMPILE_CACHE": "0",
         "PYTHONPATH": REPO,

@@ -2,7 +2,7 @@
 BENCH-format artifact: first model becomes the primary record, the rest go
 to extra_metrics — the same shape bench.py emits for a multi-model run.
 
-Usage: python tools/bank_merge.py /tmp/bank/*.json > BENCH_builder_rNN.json
+Usage: python tools/bank_merge.py /tmp/bank/*.json > merged.json
 """
 
 from __future__ import annotations

@@ -3,9 +3,11 @@
 pinned on BN's extra passes over conv outputs; reference counterpart
 conv_fusion_op.cu.cc).
 
-Round 3's lesson: never learn relay viability from a 50-minute
-full-model compile.  Three stages, cheapest first, each a clean
-subprocess with its own deadline:
+Never learn whether a kernel compiles from a full-model compile.  Three
+stages, cheapest first, each a clean subprocess with its own deadline.
+The parent never imports jax and runs the stages one after the other, so
+on a chip host each stage has the chip to itself — keep it so: a parent
+that touched jax would hold the chip and every stage would fail or hang.
 
   1. tiny block     N=2 16x16x32 -> 32, K=3  (compile + run + parity)
   2. resnet shape   N=8 56x56x64 -> 64, K=3  (the stage-2 block shape)
@@ -17,8 +19,7 @@ On a CPU backend the kernels run in interpret mode — the pipeline is
 validated but stage 3's timings are meaningless off-chip and are
 labeled backend=cpu.  Prints one JSON line per stage
 {"stage": n, "ok": bool, ...}; exit 0 iff every attempted stage passed.
-Stops at the first failed stage (a wedged relay fails stage 1 in one
-deadline, not three).
+Stops at the first failed stage.
 """
 
 from __future__ import annotations
@@ -35,15 +36,8 @@ STAGE_SRC = r"""
 import json, os, sys, time
 sys.path.insert(0, os.environ["PROBE_REPO"])
 import jax
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-# bank the risky pallas compiles: this stage uses raw jax.jit/pallas_call
-# (never CompiledBlock), so the FLAGS_compile_cache_dir env var that
-# chip_session exports must be applied to jax directly — otherwise a
-# healthy window's multi-minute compiles are thrown away (round-3 lesson)
-if os.environ.get("FLAGS_compile_cache_dir"):
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["FLAGS_compile_cache_dir"])
+# raw jax.jit/pallas_call, never a CompiledBlock: to keep these compiles
+# across runs, set JAX_COMPILATION_CACHE_DIR — jax honours it by itself
 import jax.numpy as jnp
 import numpy as np
 

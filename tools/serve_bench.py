@@ -1640,16 +1640,10 @@ def main(argv=None) -> int:
             "serve_bench: --procs/--fleet-retries must be >= 0\n")
         return 2
     if args.mesh > 1:
-        # the sharded decode program needs a mesh: force virtual CPU
-        # devices while that is still possible (the flag only works
-        # before the jax backend initializes)
-        if "jax" not in sys.modules:
-            fl = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in fl:
-                os.environ["XLA_FLAGS"] = (
-                    fl + " --xla_force_host_platform_device_count="
-                    f"{args.mesh}")
-            os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # the sharded decode program needs a mesh of the devices jax
+        # finds: real chips, or the virtual CPU devices the CALLER set
+        # up (JAX_PLATFORMS=cpu XLA_FLAGS=
+        # --xla_force_host_platform_device_count=N) — never chosen here
         import jax
 
         if len(jax.devices()) < args.mesh:
@@ -1745,4 +1739,9 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # run as a program (not main() called by a test): share the entry
+    # points' persistent compile cache
+    from paddle_tpu.core.compiler import default_compile_cache
+
+    sys.stderr.write(f"# compile cache: {default_compile_cache()}\n")
     sys.exit(main())

@@ -80,10 +80,17 @@ def main() -> None:
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import bench
 
-    logdir = os.environ.get("PROFILE_LOGDIR", "/tmp/paddle_tpu_profile")
+    # default under chiprun_out/: the one directory a chip call brings back
+    logdir = os.environ.get("PROFILE_LOGDIR", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "chiprun_out", "profile"))
     os.makedirs(logdir, exist_ok=True)
 
-    peak = float(os.environ.get("BENCH_PEAK_TFLOPS", "197")) * 1e12
+    from paddle_tpu.core.compiler import default_compile_cache
+
+    sys.stderr.write(f"# compile cache: {default_compile_cache()}\n")
+    # this process holds the chip and runs the traced steps itself
+    peak = bench._peak_flops(bench._bench_place().jax_device())
     amp = os.environ.get("BENCH_AMP", "keep")
     layout = os.environ.get("BENCH_LAYOUT", "NHWC")
 
