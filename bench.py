@@ -149,16 +149,6 @@ def _fuse_bn_mode():
         os.environ.get("BENCH_FUSE_BN", "0"), False)
 
 
-def _maybe_trace(logdir):
-    if logdir:
-        import jax
-
-        return jax.profiler.trace(logdir)
-    import contextlib
-
-    return contextlib.nullcontext()
-
-
 def _apply_config(amp: str, layout: str) -> None:
     import paddle_tpu as fluid
 
@@ -182,11 +172,11 @@ def _apply_config(amp: str, layout: str) -> None:
 
 
 def run_model(model: str, steps: int, peak_flops: float,
-              amp: str = "1", layout: str = "NCHW",
-              profile_logdir: str | None = None) -> dict:
-    """profile_logdir: wrap ONLY the timed steady-state loop in
-    jax.profiler.trace (startup/compile/warmup excluded), so per-op device
-    totals divide cleanly by `steps` (tools/tpu_profile.py)."""
+              amp: str = "1", layout: str = "NCHW") -> dict:
+    """One model's timed steady-state loop.  For a device profile of a
+    cell use `python3 benchmark/run.py --workload <cell> --trace 1` and
+    open bench_out/trace/<cell> in TensorBoard or Perfetto: the
+    executor.* spans sit above the device rows."""
     import jax
     import paddle_tpu as fluid
     from paddle_tpu import models
@@ -559,20 +549,19 @@ def run_model(model: str, steps: int, peak_flops: float,
         (warm,) = exe.run_steps(feed_list=feed_list, fetch_list=[fetch_var],
                                 steps=unroll, return_numpy=False, mode=umode)
         jax.block_until_ready(warm)
-        with _maybe_trace(profile_logdir):
-            t0 = time.perf_counter()
-            loss_v = None
-            for k in range(steps // unroll):
-                (loss_v,) = exe.run_steps(
-                    feed_list=feed_list, fetch_list=[fetch_var],
-                    steps=unroll, return_numpy=False, mode=umode)
-                # cadence at dispatch granularity: every ~ckpt_every steps
-                if ckpt_mgr and ckpt_every and (
-                        (k + 1) % max(1, ckpt_every // unroll) == 0):
-                    _ckpt_save(ckpt_base + (k + 1) * unroll,
-                               asynchronous=True)
-            jax.block_until_ready(loss_v)
-            dt = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        loss_v = None
+        for k in range(steps // unroll):
+            (loss_v,) = exe.run_steps(
+                feed_list=feed_list, fetch_list=[fetch_var],
+                steps=unroll, return_numpy=False, mode=umode)
+            # cadence at dispatch granularity: every ~ckpt_every steps
+            if ckpt_mgr and ckpt_every and (
+                    (k + 1) % max(1, ckpt_every // unroll) == 0):
+                _ckpt_save(ckpt_base + (k + 1) * unroll,
+                           asynchronous=True)
+        jax.block_until_ready(loss_v)
+        dt = time.perf_counter() - t0
     else:
         warm = None
         for i in range(len(batches) + 1):
@@ -580,17 +569,16 @@ def run_model(model: str, steps: int, peak_flops: float,
                               fetch_list=[fetch_var], return_numpy=False)
         jax.block_until_ready(warm)
 
-        with _maybe_trace(profile_logdir):
-            t0 = time.perf_counter()
-            loss_v = None
-            for i in range(steps):
-                (loss_v,) = exe.run(program=run_program, feed=step_feed(i),
-                                    fetch_list=[fetch_var], return_numpy=False)
-                if ckpt_mgr and ckpt_every and (i + 1) % ckpt_every == 0:
-                    # async: snapshot now, write in the background
-                    _ckpt_save(ckpt_base + i + 1, asynchronous=True)
-            jax.block_until_ready(loss_v)
-            dt = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        loss_v = None
+        for i in range(steps):
+            (loss_v,) = exe.run(program=run_program, feed=step_feed(i),
+                                fetch_list=[fetch_var], return_numpy=False)
+            if ckpt_mgr and ckpt_every and (i + 1) % ckpt_every == 0:
+                # async: snapshot now, write in the background
+                _ckpt_save(ckpt_base + i + 1, asynchronous=True)
+        jax.block_until_ready(loss_v)
+        dt = time.perf_counter() - t0
     if ckpt_mgr:
         # final synchronous checkpoint outside the timed region: the run
         # is resumable from its end state (joins the in-flight async

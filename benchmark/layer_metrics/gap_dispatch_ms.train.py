@@ -1,0 +1,9 @@
+"""Idle time of the first device inside the traced window, per traced step,
+that lies under an `executor.dispatch` span (the jit call until the executable is enqueued), from the trace
+(kind train)."""
+
+from benchmark.harness import step_spans
+
+
+def read(obs):
+    return step_spans.gap_ms(obs, "dispatch")

@@ -419,7 +419,7 @@ def _maybe_enable_compile_cache() -> None:
 
 def default_compile_cache() -> str:
     """The one place the entry points (chip_smoke.py, bench.py,
-    tools/serve_bench.py, tools/tpu_profile.py) turn the persistent cache
+    tools/serve_bench.py, benchmark/run.py) turn the persistent cache
     on.  Returns the directory in use: JAX_COMPILATION_CACHE_DIR where it
     is set, else xla_cache/ at the root of the checkout — a fixed path,
     because the path is part of the cache key."""

@@ -13,7 +13,7 @@ param-update semantics of the reference's optimizer ops without mutation.
 
 from __future__ import annotations
 
-import time
+import operator
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -33,11 +33,6 @@ from .scope import Scope, global_scope
 __all__ = ["Executor", "RNG_STATE_VAR"]
 
 RNG_STATE_VAR = "@rng_key@"
-
-# shared no-op context for the observability-off compile path
-import contextlib as _contextlib
-
-_NULL_CTX = _contextlib.nullcontext()
 
 
 def _as_feed_value(value, var_desc=None):
@@ -188,6 +183,125 @@ def _check_nan_inf(plan, fetches, new_states) -> None:
                 f"FLAGS_check_nan_inf: variable '{name}' contains nan/inf "
                 "after this step"
             )
+
+
+def cached_entry(cache, key, fp, build, use_cache: bool = True):
+    """The ONE copy of the fingerprint-validated lookup both executors'
+    run and run_steps make: (entry, hit), the entry being
+    (fp,) + build() on a miss, built under the `compile` span.  An
+    in-place desc mutation (another fp under the same key) rebuilds and
+    replaces the stale entry.  (The reference keys on the Program object,
+    executor.py _get_program_cache — unsound here because descs mutate in
+    place.)"""
+    entry = cache.get(key) if use_cache else None
+    hit = entry is not None and entry[0] == fp
+    if not hit:
+        with _obs.span("compile", program=fp.hex()[:12]) as sp:
+            entry = (fp,) + tuple(build())
+        if use_cache:
+            cache[key] = entry
+    if flags.flag("FLAGS_observability"):
+        _obs.record_compile_cache(hit=hit)
+        if not hit and sp.seconds is not None:  # on since before the build
+            _obs.record_compile(
+                sp.seconds, fused_regions=getattr(
+                    entry[1], "fused_conv_epilogue", 0))
+    return entry, hit
+
+
+_ARRAY_TYPES = set()  # concrete types already seen to be a jax.Array
+
+
+def not_arrays(vals) -> int:
+    """`moved` where jax.device_put takes a bare Device: this jax rewraps
+    every array it is handed there, so only a value that is not a
+    jax.Array can be told as placed, without asking each its sharding.
+    (isinstance against jax.Array's abstract class costs 0.2 us a value;
+    a step has a thousand, so the concrete types are remembered.)"""
+    n = 0
+    for v in vals:
+        if type(v) not in _ARRAY_TYPES:
+            if isinstance(v, jax.Array):
+                _ARRAY_TYPES.add(type(v))
+            else:
+                n += 1
+    return n
+
+
+def replaced(given, staged) -> int:
+    """`moved` where jax.device_put takes a Sharding: it hands back the
+    very object it was given for a committed array already placed so."""
+    return sum(map(operator.is_not, given, staged))
+
+
+def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
+             return_numpy, donated=False, steps=None, sentinel=None,
+             cost=None):
+    """The ONE copy of a step's sequence, for Executor and ParallelExecutor,
+    run and run_steps: plan, stage, dispatch, commit, fetch, each a span
+    under `executor.step` (observability/tracing.py: on the profiler's
+    clock always, in the ring under FLAGS_observability).  The callers give
+    what differs between them:
+
+    lookup() -> ((fp, call, plan), hit); a miss nests the `compile` span
+    feeds(plan, block0) -> the feed values as the plan phase leaves them
+    stage(plan, block0, feed_vals, state_vals, rng) ->
+        (feed_vals, state_vals, rng, moved): every jax.device_put before
+        the call
+    placed: the context the call is made in (a default device, a mesh)
+    sentinel(plan, fetches, new_states) -> whether to skip the write-back
+    cost(entry, feed_vals, state_vals, rng): once-a-program attribution,
+        made after the step so that it is in no step's time
+    """
+    from ..resilience import faultinject
+
+    skipped = False
+    with _obs.span("executor.step", kind=kind) as step:
+        with _obs.span("executor.plan") as sp:
+            entry, hit = lookup()
+            _, call, plan = entry
+            block0 = program.desc.block(0)
+            feed_vals = feeds(plan, block0)
+            state_vals = plan.state_values(scope, block0)
+            rng = plan.rng_value(scope, program)
+            sp.set(cache="hit" if hit else "miss")
+        n_given = len(plan.feed_names) + len(state_vals) + 1
+        step.set(n_state=len(state_vals), n_feed=len(plan.feed_names))
+        if steps is not None:
+            step.set(steps=steps)
+        with _obs.span("executor.stage") as sp:
+            feed_vals, state_vals, rng, moved = stage(
+                plan, block0, feed_vals, state_vals, rng)
+            sp.set(n=n_given, moved=moved)
+        with _obs.span("executor.dispatch"), placed:
+            fetches, new_states, new_rng = call(feed_vals, state_vals, rng)
+        with _obs.span("executor.commit") as sp:
+            fetches = faultinject.nan_fetches(plan.fetch_names, fetches)
+            if sentinel is not None and sentinel(plan, fetches, new_states):
+                # skip the bad step AMP-loss-scaler style: nothing is
+                # written back, the previous params stay live (donation
+                # is off under FLAGS_check_numerics)
+                skipped = True
+                sp.set(skipped=1)
+            else:
+                plan.write_back(scope, new_states, new_rng)
+                _check_nan_inf(plan, fetches, new_states)
+        with _obs.span("executor.fetch") as sp:
+            out = plan.convert_fetches(fetches, block0, return_numpy)
+            sp.set(n=len(out))
+    if step.seconds is not None:  # FLAGS_observability
+        if steps is None:
+            _obs.record_executor_step(step.seconds, donated=donated,
+                                      skipped=skipped)
+        else:
+            _obs.default_registry().histogram(
+                "paddle_tpu_executor_run_steps_seconds",
+                "run_steps wall time per K-step dispatch",
+            ).observe(step.seconds, steps=str(steps))
+        _obs.record_device_memory(device)
+        if cost is not None and not skipped:
+            cost(entry, feed_vals, state_vals, rng)
+    return out
 
 
 def scan_multi_fn(body, n_batches, steps, flat: bool = False):
@@ -354,12 +468,6 @@ class Executor:
             pe = program._executor_for_scope(scope or global_scope())
             return pe.run(fetch_list=fetch_list, feed=feed, return_numpy=return_numpy)
 
-        # FLAGS_observability per-step telemetry: ONE flag check on the
-        # disabled path — no clock read, no allocation, no call into the
-        # observability package (tier-1 asserts this via tracemalloc)
-        obs_on = flags.flag("FLAGS_observability")
-        t0 = time.perf_counter() if obs_on else 0.0
-
         program = program or default_main_program()
         if feed is None and getattr(program, "_py_readers", None):
             # feed-less run: pull the next ready batch from the program's
@@ -374,88 +482,68 @@ class Executor:
 
         feed_names = sorted(feed)
         fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
-
-        fp, compiled, plan = self._cache_entry(
-            program, feed_names, fetch_names, use_program_cache)
-
-        block0 = program.desc.block(0)
-        feed_vals = plan.feed_values(feed, block0)
-        state_vals = plan.state_values(scope, block0)
-        rng = plan.rng_value(scope, program)
-
-        # explicit async host->device transfer: device_put enqueues the copy
-        # and returns immediately, so step N's compute overlaps batch N+1's
-        # transfer (the reference gets this from double-buffer reader ops,
-        # operators/reader/create_double_buffer_reader_op.cc; here JAX's
-        # async dispatch provides the overlap once the transfer is nonblocking)
         device = self.place.jax_device()
-        feed_vals = jax.device_put(feed_vals, device)
-        # commit states too: a host-numpy state (fresh from the startup
-        # program) would compile one jit variant, and the committed device
-        # arrays it returns would compile a SECOND — device_put is a no-op
-        # for values already on `device`
-        state_vals = jax.device_put(state_vals, device)
-        # commit the PRNG key too: a fresh host key (first call) and the
-        # committed key a previous call wrote back lower to DIFFERENT
-        # executables (committed-ness is part of jax's lowering cache
-        # key), so without this every program compiled twice — trace
-        # cache hit, full XLA recompile (observed: 2x ~8 s flat-unroll
-        # compiles on CPU)
-        rng = jax.device_put(rng, device)
 
-        with jax.default_device(device):
-            fetches, new_states, new_rng = compiled(feed_vals, state_vals, rng)
+        def stage(plan, block0, feed_vals, state_vals, rng):
+            moved = not_arrays(feed_vals + state_vals + (rng,))
+            # explicit async host->device transfer: device_put enqueues the
+            # copy and returns immediately, so step N's compute overlaps
+            # batch N+1's transfer (the reference gets this from
+            # double-buffer reader ops,
+            # operators/reader/create_double_buffer_reader_op.cc; here
+            # JAX's async dispatch provides the overlap once the transfer
+            # is nonblocking)
+            feed_vals = jax.device_put(feed_vals, device)
+            # commit states too: a host-numpy state (fresh from the startup
+            # program) would compile one jit variant, and the committed
+            # device arrays it returns would compile a SECOND — device_put
+            # is a no-op for values already on `device`
+            state_vals = jax.device_put(state_vals, device)
+            # commit the PRNG key too: a fresh host key (first call) and
+            # the committed key a previous call wrote back lower to
+            # DIFFERENT executables (committed-ness is part of jax's
+            # lowering cache key), so without this every program compiled
+            # twice — trace cache hit, full XLA recompile (observed: 2x
+            # ~8 s flat-unroll compiles on CPU)
+            rng = jax.device_put(rng, device)
+            return feed_vals, state_vals, rng, moved
 
-        from ..resilience import faultinject
+        return run_step(
+            "serial", program, scope,
+            lambda: self._cache_entry(program, feed_names, fetch_names,
+                                      use_program_cache),
+            lambda plan, block0: plan.feed_values(feed, block0),
+            stage, jax.default_device(device), device, return_numpy,
+            donated=self._donate_states_now(),
+            sentinel=self._numerics_tripped, cost=self._maybe_record_cost)
 
-        fetches = faultinject.nan_fetches(plan.fetch_names, fetches)
-        if flags.flag("check_numerics"):
-            from ..resilience.sentinel import NaNSentinel
+    def _numerics_tripped(self, plan, fetches, new_states) -> bool:
+        """FLAGS_check_numerics: whether this step's fetches or new state
+        hold a non-finite value; record_trip raises NonFiniteStepError
+        after N consecutive trips."""
+        if not flags.flag("check_numerics"):
+            return False
+        from ..resilience.sentinel import NaNSentinel
 
-            if self._sentinel is None:
-                self._sentinel = NaNSentinel()
-            bad = self._sentinel.first_nonfinite(
-                tuple(plan.fetch_names) + tuple(plan.state_names),
-                tuple(fetches) + tuple(new_states),
-            )
-            if bad is not None:
-                # skip the bad step AMP-loss-scaler style: nothing is
-                # written back, the previous params stay live (donation
-                # is off under this flag); record_trip raises
-                # NonFiniteStepError after N consecutive trips
-                self._sentinel.record_trip(bad)
-                if obs_on:
-                    self._obs_step(t0, donated=False, skipped=True)
-                return plan.convert_fetches(fetches, block0, return_numpy)
+        if self._sentinel is None:
+            self._sentinel = NaNSentinel()
+        bad = self._sentinel.first_nonfinite(
+            tuple(plan.fetch_names) + tuple(plan.state_names),
+            tuple(fetches) + tuple(new_states),
+        )
+        if bad is None:
             self._sentinel.record_clean()
-        plan.write_back(scope, new_states, new_rng)
-        _check_nan_inf(plan, fetches, new_states)
-        if obs_on:
-            # step time FIRST: the one-shot cost attribution below can
-            # take minutes (tpu AOT mode) and must not poison this
-            # step's histogram/StepStats sample
-            self._obs_step(t0, donated=self._donate_states_now())
-            _obs.record_device_memory(device)
-            self._maybe_record_cost(fp, compiled, feed_vals, state_vals, rng)
-        return plan.convert_fetches(fetches, block0, return_numpy)
+            return False
+        self._sentinel.record_trip(bad)
+        return True
 
     @staticmethod
-    def _obs_step(t0: float, donated: bool, skipped: bool = False) -> None:
-        t1 = time.perf_counter()
-        _obs.record_executor_step(t1 - t0, donated=donated, skipped=skipped)
-        # span via record(): no context-manager plumbing through the run
-        # body; same-thread time containment nests it under callers and
-        # over the compile span in the merged trace
-        _obs.default_tracer().record(
-            "executor.step", t0, t1,
-            **({"skipped": True} if skipped else {}))
-
-    def _maybe_record_cost(self, fp, compiled, feed_vals, state_vals,
-                           rng) -> None:
+    def _maybe_record_cost(entry, feed_vals, state_vals, rng) -> None:
         """FLAGS_observability_cost: once per fresh compiled entry,
         record the XLA cost model's bytes/flops per step labeled by
         program fingerprint + fused-region count — flag-flip A/Bs (e.g.
         the conv-epilogue pass) land on separate series with no chip."""
+        fp, compiled, _ = entry
         mode = flags.flag("observability_cost")
         if mode == "off" or getattr(compiled, "_obs_cost_done", False):
             return
@@ -475,45 +563,22 @@ class Executor:
 
     def _cache_entry(self, program, feed_names, fetch_names,
                      use_program_cache: bool = True):
-        """The ONE copy of the compiled-program cache logic shared by
-        _run_scoped and cost_analysis: (desc fingerprint, compiled, plan)
-        keyed on (program id, feeds, fetches, amp policy, trace flags),
-        fingerprint-revalidated so in-place desc mutations recompile and
-        replace the stale entry.  (The reference keys on the Program
-        object, executor.py _get_program_cache — unsound here because
-        descs mutate in place.)"""
-        fp = program.desc.fingerprint()
+        """The ONE copy of the compiled-program cache key shared by
+        _run_scoped and cost_analysis: ((desc fingerprint, compiled, plan),
+        hit) keyed on (program id, feeds, fetches, amp policy, trace
+        flags), fingerprint-revalidated (cached_entry)."""
         key = (id(program), tuple(feed_names), tuple(fetch_names),
                amp.state_key(), flags.trace_key())
-        entry = self._cache.get(key) if use_program_cache else None
-        if entry is not None and entry[0] != fp:
-            entry = None
-        obs_on = flags.flag("FLAGS_observability")
-        if entry is None:
-            if obs_on:
-                _obs.record_compile_cache(hit=False)
+
+        def build():
             plan = _RunPlan(program, feed_names, fetch_names)
-            with _obs.span("compile", program=fp.hex()[:12]) if obs_on \
-                    else _NULL_CTX:
-                tc0 = time.perf_counter() if obs_on else 0.0
-                compiled = CompiledBlock(
-                    program,
-                    0,
-                    plan.feed_names,
-                    plan.fetch_names,
-                    plan.state_names,
-                    donate_states=self._donate_states_now(),
-                )
-                if obs_on:
-                    _obs.record_compile(
-                        time.perf_counter() - tc0,
-                        fused_regions=compiled.fused_conv_epilogue)
-            entry = (fp, compiled, plan)
-            if use_program_cache:
-                self._cache[key] = entry
-        elif obs_on:
-            _obs.record_compile_cache(hit=True)
-        return entry
+            return CompiledBlock(
+                program, 0, plan.feed_names, plan.fetch_names,
+                plan.state_names, donate_states=self._donate_states_now(),
+            ), plan
+
+        return cached_entry(self._cache, key, program.desc.fingerprint(),
+                            build, use_program_cache)
 
     def cost_analysis(
         self,
@@ -591,7 +656,7 @@ class Executor:
             v.name if isinstance(v, Variable) else str(v)
             for v in fetch_list
         ]
-        _, compiled, plan = self._cache_entry(
+        (_, compiled, plan), _ = self._cache_entry(
             program, feed_names, fetch_names)
         block0 = program.desc.block(0)
         feed_vals = plan.feed_values(feed, block0)
@@ -639,7 +704,7 @@ class Executor:
                 v.name if isinstance(v, Variable) else str(v)
                 for v in fetch_list
             ]
-            _, compiled, plan = self._cache_entry(
+            (_, compiled, plan), _ = self._cache_entry(
                 program, feed_names, fetch_names)
             block0 = program.desc.block(0)
             feed_vals = plan.feed_values(feed, block0)
@@ -722,20 +787,23 @@ class Executor:
         if mode not in ("scan", "flat"):
             raise ValueError(f"run_steps mode must be 'scan' or 'flat', "
                              f"got {mode!r}")
-        fp = program.desc.fingerprint()
         key = ("run_steps", id(program), steps, len(feed_list),
                tuple(feed_names), tuple(fetch_names), amp.state_key(),
                flags.trace_key(), mode)
-        entry = self._cache.get(key)
-        if entry is not None and entry[0] != fp:
-            entry = None
-        if entry is None:
+        fp = None  # the plan phase takes the fingerprint, stage reads it
+
+        def lookup():
+            nonlocal fp
+            fp = program.desc.fingerprint()
+            return cached_entry(self._cache, key, fp, build)
+
+        def build():
             plan = _RunPlan(program, feed_names, fetch_names)
             compiled = CompiledBlock(
                 program, 0, plan.feed_names, plan.fetch_names,
                 plan.state_names, donate_states=False,
             )
-            fn = jax.jit(
+            return jax.jit(
                 scan_multi_fn(compiled.raw_fn, len(feed_list), steps,
                               flat=(mode == "flat")),
                 # plain self.donate_states: the skip-step sentinel never
@@ -743,39 +811,27 @@ class Executor:
                 # always writes back — keeping pre-step buffers alive
                 # here would double state HBM for zero benefit
                 donate_argnums=(1,) if self.donate_states else (),
-            )
-            entry = (fp, (compiled, fn), plan)
-            self._cache[key] = entry
-        _, (compiled, fn), plan = entry
+            ), plan
 
         device = self.place.jax_device()
-        feeds_stack = stacked_feeds(
-            self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-            lambda t: jax.device_put(t, device),
-        )
-        state_vals = plan.state_values(scope, block0)
-        rng = plan.rng_value(scope, program)
 
-        state_vals = jax.device_put(state_vals, device)
-        rng = jax.device_put(rng, device)  # see run(): avoids a second
-        # full XLA compile when the committed written-back key returns
-        obs_on = flags.flag("FLAGS_observability")
-        t0 = time.perf_counter() if obs_on else 0.0
-        with jax.default_device(device):
-            fetches, new_states, new_rng = fn(feeds_stack, state_vals, rng)
+        def stage(plan, block0, feed_list, state_vals, rng):
+            moved = not_arrays(state_vals + (rng,)) + not_arrays(
+                feed[n] for feed in feed_list for n in plan.feed_names)
+            feeds_stack = stacked_feeds(
+                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
+                lambda t: jax.device_put(t, device),
+            )
+            state_vals = jax.device_put(state_vals, device)
+            rng = jax.device_put(rng, device)  # see run(): avoids a second
+            # full XLA compile when the committed written-back key returns
+            return feeds_stack, state_vals, rng, moved
 
-        plan.write_back(scope, new_states, new_rng)
-        _check_nan_inf(plan, fetches, new_states)
-        if obs_on:
-            t1 = time.perf_counter()
-            _obs.default_registry().histogram(
-                "paddle_tpu_executor_run_steps_seconds",
-                "Executor.run_steps wall time per K-step dispatch",
-            ).observe(t1 - t0, steps=str(steps))
-            _obs.default_tracer().record(
-                "executor.run_steps", t0, t1, steps=steps)
-            _obs.record_device_memory(device)
-        return plan.convert_fetches(fetches, block0, return_numpy)
+        return run_step(
+            "serial", program, scope, lookup,
+            lambda plan, block0: feed_list,
+            stage, jax.default_device(device), device, return_numpy,
+            steps=steps)
 
     @staticmethod
     def _restore_declared_dtype(arr: np.ndarray, var_desc) -> np.ndarray:

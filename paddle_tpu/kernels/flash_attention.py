@@ -8,7 +8,8 @@ FlashAttention), so HBM traffic stays O(S*D) and the MXU sees back-to-back
 block matmuls.
 
 The backward is the FlashAttention-2 recipe in two Pallas kernels — a
-round-3 change driven by a chip profile (tools/tpu_profile.py) showing the
+round-3 change driven by a chip profile (today: `python3 benchmark/run.py
+--workload transformer-train --trace 1`) showing the
 previous recompute-with-dense-jax backward's softmax-gradient elementwise
 chains dominating transformer step time:
 - forward additionally emits the per-row logsumexp L;

@@ -10,12 +10,15 @@ was a chrome-trace stub with no hot-path consumers.  Now:
   a process-wide registry; JSON snapshots, Prometheus text exposition,
   atomic per-process dumps with cross-process merge (`aggregate_dir`).
 - **Spans** (`tracing.py`): `span("compile")` / `span("step", step=n)` /
-  `span("ckpt.save")` nest per-thread, attach to an active jax.profiler
-  device trace, and export one merged Chrome/Perfetto trace per run with
-  named threads and stable tids (timeline.py is rebased onto this
-  writer).
-- **Step stats** (`stepstats.py`): ring buffer of Executor.run wall
-  times with rolling p50/p99, plus the BENCH_BASELINE regression gate
+  `span("ckpt.save")` nest per-thread, land in any running jax.profiler
+  session (flag or no flag), and export one merged Chrome/Perfetto trace
+  per run with named threads and stable tids (timeline.py is rebased onto
+  this writer).  Both executors wrap a step's phases in them
+  (`executor.step` over `.plan`, `.stage`, `.dispatch`, `.commit`,
+  `.fetch`; core/executor.py::run_step).
+- **Step stats** (`stepstats.py`): ring buffer of the `executor.step`
+  spans' durations (to the fetched values on the host) with rolling
+  p50/p99, plus the BENCH_BASELINE regression gate
   bench.py uses to emit pass/fail deltas.
 - **Request traces** (`requesttrace.py`): per-request trace ids minted
   at Engine.submit(), cross-thread span trees (submit thread ->
@@ -29,11 +32,13 @@ was a chrome-trace stub with no hot-path consumers.  Now:
   circuit breaker trips or engine health enters BROKEN — the black box
   every chaos failure leaves behind.
 
-Everything is gated on **FLAGS_observability** (env `FLAGS_observability=1`
-or `fluid.set_flags({"FLAGS_observability": True})`).  Disabled, every
-instrument returns after one dict lookup — no locks, no allocation, no
-clock reads (tier-1 asserts the executor's disabled path allocates
-nothing from this package).  `FLAGS_observability_cost=native|tpu`
+Everything but a span's place in a profiler session is gated on
+**FLAGS_observability** (env `FLAGS_observability=1` or
+`fluid.set_flags({"FLAGS_observability": True})`).  Disabled, every
+instrument returns after one dict lookup and a span is an inert
+jax.profiler.TraceAnnotation — no locks, no clock reads, no registry call,
+nothing appended, nothing that outlives the with-block (tier-1 asserts this
+of the executor's disabled path).  `FLAGS_observability_cost=native|tpu`
 additionally records each compiled program's bytes/step from XLA's cost
 model (the `tpu` mode prices the CHIP program via the chip-less AOT
 tier, core/aot_tpu.py — the conv-epilogue layout-tax measurement loop
@@ -153,24 +158,25 @@ def reset() -> None:
 
 
 # -- executor instruments ---------------------------------------------------
-# Called from Executor hot paths ONLY when FLAGS_observability is on (the
-# executor performs the flag check so its disabled path never enters this
-# module); each emits into the default registry.
+# Called from the executors' hot paths ONLY when FLAGS_observability is on
+# (core/executor.py performs the flag check so the disabled path never
+# enters these); each emits into the default registry.
 
 def record_executor_step(seconds: float, donated: bool,
                          skipped: bool = False) -> None:
-    """One Executor.run dispatch: host-side wall time (async dispatch —
-    device time shows up via block_until_ready at the caller's sync
-    points), donation status, and whether the sentinel skipped the
+    """One step of either executor: the `executor.step` span's duration
+    (plan to the fetched values on the host; with return_numpy=False the
+    fetch does not wait and the device's time shows up at the caller's
+    sync points), donation status, and whether the sentinel skipped the
     write-back."""
     reg = default_registry()
     reg.histogram(
         "paddle_tpu_executor_step_seconds",
-        "Executor.run wall time per step (host-side dispatch)",
+        "wall time of a step (the executor.step span)",
     ).observe(seconds)
     reg.counter(
         "paddle_tpu_executor_steps",
-        "Executor.run calls by state-donation status",
+        "executor steps by state-donation status",
     ).inc(donated="1" if donated else "0")
     if skipped:
         reg.counter(

@@ -1,7 +1,8 @@
 """StepStats ring buffer + the perf-regression gate.
 
-`StepStats` keeps the last K step wall times (the executor records every
-`Executor.run` dispatch when FLAGS_observability is on) and answers
+`StepStats` keeps the last K step wall times (either executor records
+every `executor.step` span's duration when FLAGS_observability is on) and
+answers
 rolling p50/p90/p99 — the numbers obsdump renders and bench.py reports.
 
 The regression gate compares a current measurement against a banked

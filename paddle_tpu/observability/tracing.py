@@ -4,9 +4,14 @@
 (name, start, end, thread, parent, attrs) into a process-wide Tracer.
 Spans nest correctly across threads — each thread carries its own span
 stack (thread-local), so a checkpoint writer thread's spans never adopt
-the training thread's open "step" as parent.  When a jax.profiler device
-trace is active, each span also enters jax.profiler.TraceAnnotation, so
-the SAME names line up in the TensorBoard/XLA device timeline.
+the training thread's open "step" as parent.
+
+One mechanism, two sinks.  Sink A, the profiler's clock: every span enters
+a jax.profiler.TraceAnnotation, which is inert outside a profiler session
+and, inside one (however it was started: jax.profiler.start_trace, Fluid's
+start_profiler, TensorBoard's capture), lands on the host plane of the same
+xplane.pb as the device's operations, counts and all.  Sink B, the ring
+below (and through it the chrome trace): only under FLAGS_observability.
 
 The chrome-trace writer here is the single exporter for the repo:
 `timeline.export_chrome_trace` (the old 50-line stub) is rebased onto it
@@ -16,8 +21,8 @@ stable per-thread tids (main thread is always tid 0; other threads are
 ordered by their first span's start time — insertion-order ints with no
 names left Perfetto rows unlabeled).
 
-Disabled-path cost: `span()` returns a shared no-op context after one
-dict lookup; nothing is allocated and no clock is read.
+Flag off: a span is its TraceAnnotation and nothing else: no clock is
+read, nothing is appended, nothing outlives the with-block.
 """
 
 from __future__ import annotations
@@ -26,6 +31,8 @@ import json
 import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from .. import flags as _flags
 
@@ -66,66 +73,44 @@ class Span:
                 "cat": self.cat}
 
 
-class _NullCtx:
-    """Reentrant no-op context for the disabled path (one shared
-    instance; __enter__/__exit__ carry no state)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullCtx()
-
-
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annot")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annot", "seconds")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
-        self._annot = None
+        self._annot = TraceAnnotation(name, **args)
+        self._t0 = None
+        self.seconds = None  # the span's duration, under the flag only
 
     def __enter__(self):
-        stack = self._tracer._stack()
-        stack.append(self._name)
-        # attach to the device trace when one is running: the same span
-        # names appear in the XLA/TensorBoard timeline (profiler keeps
-        # its own on/off state; TraceAnnotation outside a trace is cheap
-        # but not free, so gate on it)
-        try:
-            from .. import profiler as _profiler
-
-            if _profiler._state["on"]:
-                import jax
-
-                self._annot = jax.profiler.TraceAnnotation(self._name)
-                self._annot.__enter__()
-        except Exception:
-            self._annot = None
-        self._t0 = time.perf_counter()
+        self._annot.__enter__()
+        if _on():
+            self._tracer._stack().append(self._name)
+            self._t0 = time.perf_counter()
         return self
 
+    def set(self, **counts) -> None:
+        """Counts known only once the work is done (`moved`, `cache`):
+        onto the open span, in both sinks."""
+        self._annot.set_metadata(**counts)
+        if self._t0 is not None:
+            self._args.update(counts)
+
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        if self._annot is not None:
-            try:
-                self._annot.__exit__(*exc)
-            except Exception:
-                pass
-        stack = self._tracer._stack()
-        if stack and stack[-1] == self._name:
-            stack.pop()
-        parent = stack[-1] if stack else None
-        th = threading.current_thread()
-        self._tracer._append(Span(
-            self._name, self._t0, t1, threading.get_ident(), th.name,
-            parent=parent, args=self._args))
+        self._annot.__exit__(*exc)
+        if self._t0 is not None:
+            t1 = time.perf_counter()
+            self.seconds = t1 - self._t0
+            stack = self._tracer._stack()
+            if stack and stack[-1] == self._name:
+                stack.pop()
+            parent = stack[-1] if stack else None
+            th = threading.current_thread()
+            self._tracer._append(Span(
+                self._name, self._t0, t1, threading.get_ident(), th.name,
+                parent=parent, args=self._args))
         return False
 
 
@@ -197,10 +182,8 @@ def default_tracer() -> Tracer:
 
 
 def span(name: str, **args):
-    """`with span("step", step=n):` — records into the default tracer
-    when FLAGS_observability is on; a shared no-op context otherwise."""
-    if not _on():
-        return _NULL
+    """`with span("step", step=n):` — a TraceAnnotation always, and a
+    record in the default tracer when FLAGS_observability is on."""
     return _default.span(name, **args)
 
 

@@ -24,7 +24,15 @@ import dataclasses
 
 from .. import flags
 from ..core.compiler import CompiledBlock
-from ..core.executor import _RunPlan
+from ..core.executor import (
+    _RunPlan,
+    cached_entry,
+    not_arrays,
+    replaced,
+    run_step,
+    scan_multi_fn,
+    stacked_feeds,
+)
 from ..core.framework import Program, Variable, default_main_program
 from ..core.scope import Scope, global_scope
 from .mesh import DeviceMesh, default_mesh
@@ -150,8 +158,7 @@ class ParallelExecutor:
     def _mesh_is_tpu(self) -> bool:
         from ..core.place import device_is_tpu
 
-        devs = np.asarray(self.mesh.mesh.devices).ravel()
-        return bool(len(devs)) and device_is_tpu(devs[0])
+        return device_is_tpu(self._first_device())
 
     def _run_scoped(
         self,
@@ -184,54 +191,52 @@ class ParallelExecutor:
         feed_names = sorted(feed)
         fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
 
-        # fingerprint-validated cache: an in-place desc mutation recompiles
-        # and replaces the stale entry (see core/executor.py for rationale)
         from ..core import amp
-
-        fp = self.program.desc.fingerprint()
-        key = (tuple(feed_names), tuple(fetch_names), amp.state_key(),
-               flags.trace_key())
-        entry = self._cache.get(key)
-        if entry is not None and entry[0] != fp:
-            entry = None
-        if entry is None:
-            plan = _RunPlan(self.program, feed_names, fetch_names)
-            entry = (fp, self._compile(plan), plan)
-            self._cache[key] = entry
-        _, compiled, plan = entry
-
         from .multihost import global_feed_value, is_multiprocess
 
-        block0 = self.program.desc.block(0)
-        feed_vals = plan.feed_values(feed, block0)
-        if not is_multiprocess(self.mesh):
-            # multihost feeds are per-process shards assembled into the
-            # global array below — their local dim 0 is a fraction of the
-            # dp axis, so the single-process divisibility contract does
-            # not apply
-            self._check_batch_divisible(plan.feed_names, feed_vals, block0)
-        state_vals = plan.state_values(self.scope, block0)
-        rng = plan.rng_value(self.scope, self.program)
+        key = (tuple(feed_names), tuple(fetch_names), amp.state_key(),
+               flags.trace_key())
+        multiprocess = is_multiprocess(self.mesh)
 
-        if is_multiprocess(self.mesh):
-            # each process feeds ITS batch shard; jax assembles the global
-            # array (reference: per-trainer reader shards under nccl2)
-            feed_vals = tuple(
-                global_feed_value(self._feed_sharding(n, block0), v)
-                for n, v in zip(plan.feed_names, feed_vals)
-            )
+        def build():
+            plan = _RunPlan(self.program, feed_names, fetch_names)
+            return self._compile(plan), plan
 
-        if not is_multiprocess(self.mesh):
-            state_vals, rng = self._reshard_serial_state(
-                state_vals, rng, plan, block0)
-        with self.mesh.mesh:
-            fetches, new_states, new_rng = compiled(feed_vals, state_vals, rng)
+        def feeds(plan, block0):
+            feed_vals = plan.feed_values(feed, block0)
+            if not multiprocess:
+                # multihost feeds are per-process shards assembled into
+                # the global array in stage() — their local dim 0 is a
+                # fraction of the dp axis, so the single-process
+                # divisibility contract does not apply
+                self._check_batch_divisible(
+                    plan.feed_names, feed_vals, block0)
+            return feed_vals
 
-        plan.write_back(self.scope, new_states, new_rng)
-        from ..core.executor import _check_nan_inf
+        def stage(plan, block0, feed_vals, state_vals, rng):
+            # a feed goes to the call as it is: pjit's in_shardings place it
+            moved = not_arrays(feed_vals)
+            if multiprocess:
+                # each process feeds ITS batch shard; jax assembles the
+                # global array (reference: per-trainer reader shards under
+                # nccl2)
+                feed_vals = tuple(
+                    global_feed_value(self._feed_sharding(n, block0), v)
+                    for n, v in zip(plan.feed_names, feed_vals)
+                )
+                moved += not_arrays(state_vals + (rng,))
+            else:
+                state_vals, rng, resharded = self._reshard_serial_state(
+                    state_vals, rng, plan, block0)
+                moved += resharded
+            return feed_vals, state_vals, rng, moved
 
-        _check_nan_inf(plan, fetches, new_states)
-        return plan.convert_fetches(fetches, block0, return_numpy)
+        return run_step(
+            "spmd", self.program, self.scope,
+            lambda: cached_entry(self._cache, key,
+                                 self.program.desc.fingerprint(), build),
+            feeds, stage, self.mesh.mesh, self._first_device(),
+            return_numpy, donated=True)
 
     def run_steps(
         self,
@@ -264,11 +269,6 @@ class ParallelExecutor:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..core import amp
-        from ..core.executor import (
-            _check_nan_inf,
-            scan_multi_fn,
-            stacked_feeds,
-        )
         from .multihost import is_multiprocess
 
         if is_multiprocess(self.mesh):
@@ -297,16 +297,20 @@ class ParallelExecutor:
         ]
         block0 = self.program.desc.block(0)
 
-        fp = self.program.desc.fingerprint()
         if mode not in ("scan", "flat"):
             raise ValueError(f"run_steps mode must be 'scan' or 'flat', "
                              f"got {mode!r}")
         key = ("pe_run_steps", steps, len(feed_list), tuple(feed_names),
                tuple(fetch_names), amp.state_key(), flags.trace_key(), mode)
-        entry = self._cache.get(key)
-        if entry is not None and entry[0] != fp:
-            entry = None
-        if entry is None:
+
+        fp = None  # the plan phase takes the fingerprint, stage reads it
+
+        def lookup():
+            nonlocal fp
+            fp = self.program.desc.fingerprint()
+            return cached_entry(self._cache, key, fp, build)
+
+        def build():
             plan = _RunPlan(self.program, feed_names, fetch_names)
             compiled = CompiledBlock(
                 self.program, 0, plan.feed_names, plan.fetch_names,
@@ -326,7 +330,7 @@ class ParallelExecutor:
                 )
                 for n in plan.feed_names
             )
-            fn = jax.jit(
+            return jax.jit(
                 multi,
                 in_shardings=(stack_sh, state_sh, self.mesh.replicated()),
                 out_shardings=(
@@ -335,29 +339,30 @@ class ParallelExecutor:
                     self.mesh.replicated(),
                 ),
                 donate_argnums=(1,),
+            ), plan
+
+        def stage(plan, block0, feed_list, state_vals, rng):
+            moved = not_arrays(
+                feed[n] for feed in feed_list for n in plan.feed_names)
+            feeds_stack = stacked_feeds(
+                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
+                lambda t: t,  # pjit's in_shardings own device placement
             )
-            entry = (fp, fn, plan)
-            self._cache[key] = entry
-        _, fn, plan = entry
+            self._check_batch_divisible(
+                plan.feed_names, tuple(f[0] for f in feeds_stack), block0
+            )
+            state_vals, rng, resharded = self._reshard_serial_state(
+                state_vals, rng, plan, block0)
+            return feeds_stack, state_vals, rng, moved + resharded
 
-        feeds_stack = stacked_feeds(
-            self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-            lambda t: t,  # pjit's in_shardings own device placement
-        )
-        self._check_batch_divisible(
-            plan.feed_names, tuple(f[0] for f in feeds_stack), block0
-        )
-        state_vals = plan.state_values(self.scope, block0)
-        rng = plan.rng_value(self.scope, self.program)
+        return run_step(
+            "spmd", self.program, self.scope, lookup,
+            lambda plan, block0: feed_list,
+            stage, self.mesh.mesh, self._first_device(), return_numpy,
+            donated=True, steps=steps)
 
-        state_vals, rng = self._reshard_serial_state(
-            state_vals, rng, plan, block0)
-        with self.mesh.mesh:
-            fetches, new_states, new_rng = fn(feeds_stack, state_vals, rng)
-
-        plan.write_back(self.scope, new_states, new_rng)
-        _check_nan_inf(plan, fetches, new_states)
-        return plan.convert_fetches(fetches, block0, return_numpy)
+    def _first_device(self):
+        return np.asarray(self.mesh.mesh.devices).ravel()[0]
 
     def _check_batch_divisible(self, feed_names, feed_vals, block0) -> None:
         """A dim-0-sharded feed whose batch isn't divisible by its mesh
@@ -399,14 +404,16 @@ class ParallelExecutor:
         commits state/rng to ITS device (lowering-cache stability), and
         pjit raises on committed single-device args that mismatch
         in_shardings — explicitly reshard them to this mesh's shardings.
-        One-time copy: arrays come back FROM pjit already in place."""
-        state_vals = tuple(
+        One-time copy: arrays come back FROM pjit already in place.
+        Returns (state_vals, rng, how many of them had to be placed)."""
+        staged = tuple(
             jax.device_put(v, self._state_sharding(n, block0))
             if isinstance(v, jax.Array) else v
             for n, v in zip(plan.state_names, state_vals)
-        )
-        rng = jax.device_put(rng, self.mesh.replicated())
-        return state_vals, rng
+        ) + (jax.device_put(rng, self.mesh.replicated()),)
+        # a host array stays as it is for pjit to place: that is a move too
+        moved = replaced(state_vals + (rng,), staged) + not_arrays(state_vals)
+        return staged[:-1], staged[-1], moved
 
     def drop_local_exe_scopes(self):  # reference API; scopes are XLA-owned
         pass

@@ -115,8 +115,7 @@ def test_bench_survives_single_model_failure(monkeypatch, capsys):
 
     import bench
 
-    def fake_run_model(model, steps, peak_flops, amp="1", layout="NCHW",
-                       profile_logdir=None):
+    def fake_run_model(model, steps, peak_flops, amp="1", layout="NCHW"):
         if model == "transformer":
             raise ValueError("pallas lowering rejected block shape")
         return {"metric": f"{model}_train_examples_per_sec_per_chip",
@@ -142,8 +141,7 @@ def test_bench_survives_single_model_failure(monkeypatch, capsys):
 def test_bench_all_models_failing_exits_2(monkeypatch, capsys):
     import bench
 
-    def fake_run_model(model, steps, peak_flops, amp="1", layout="NCHW",
-                       profile_logdir=None):
+    def fake_run_model(model, steps, peak_flops, amp="1", layout="NCHW"):
         raise ValueError("boom")
 
     monkeypatch.setattr(bench, "run_model", fake_run_model)

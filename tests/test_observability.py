@@ -446,10 +446,14 @@ def test_histogram_rejects_conflicting_buckets(obs_on):
 
 
 def test_disabled_path_zero_observability_overhead(monkeypatch):
-    """Acceptance: with the flag off the per-step path is one flag check
-    — no observability calls, and NO allocations attributed to the
-    observability package (tracemalloc filename filter)."""
+    """The contract with the flag off and no profiler session: a span is
+    its inert TraceAnnotation and nothing else.  No instrument of the
+    registry is reached, nothing is appended to the ring, the package reads
+    no clock, and nothing it allocated outlives the step."""
+    import gc
     import tracemalloc
+
+    from paddle_tpu.observability import tracing
 
     assert not obs.enabled()
     exe, loss = _build_step(name="obs_cold_w")
@@ -457,30 +461,214 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
         exe.run(feed=_feed(i), fetch_list=[loss])
 
     calls = []
-    monkeypatch.setattr(obs, "record_executor_step",
-                        lambda *a, **k: calls.append(1))
-    monkeypatch.setattr(obs, "record_compile_cache",
-                        lambda *a, **k: calls.append(1))
+    for name in ("record_executor_step", "record_compile_cache",
+                 "record_device_memory", "default_registry"):
+        monkeypatch.setattr(obs, name,
+                            lambda *a, _n=name, **k: calls.append(_n))
+    monkeypatch.setattr(tracing.Tracer, "_append",
+                        lambda self, s: calls.append("ring"))
+
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with the flag off")
+
+    monkeypatch.setattr(tracing, "time", NoClock())
     obs_pkg_dir = os.path.dirname(os.path.abspath(obs.__file__))
     tracemalloc.start()
     try:
         for i in range(3):
             exe.run(feed=_feed(i), fetch_list=[loss])
+        # an annotation's memory goes back at the next collection, not at
+        # the with-block's end: live is what a collection leaves
+        gc.collect()
         snap = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
-    assert calls == []  # no instrument reached
+    assert calls == []  # no instrument reached, nothing appended
+    assert obs.default_tracer().spans() == []
     hits = snap.filter_traces(
         [tracemalloc.Filter(True, os.path.join(obs_pkg_dir, "*"))]
     ).statistics("filename")
     assert hits == [], f"observability allocated while disabled: {hits}"
+    monkeypatch.undo()
     # control: the SAME steps with the flag on do reach the instruments
+    calls = []
+    monkeypatch.setattr(obs, "record_executor_step",
+                        lambda *a, **k: calls.append(1))
     fluid.set_flags({"FLAGS_observability": True})
     try:
         exe.run(feed=_feed(0), fetch_list=[loss])
     finally:
         fluid.set_flags({"FLAGS_observability": False})
+        obs.reset()
     assert calls
+
+
+# -----------------------------------------------------------------------
+# the step's phases as spans: both executors, both sinks
+# -----------------------------------------------------------------------
+PHASES = ["executor.plan", "executor.stage", "executor.dispatch",
+          "executor.commit", "executor.fetch"]
+STEPPERS = [("serial", "run"), ("serial", "run_steps"),
+            ("spmd", "run"), ("spmd", "run_steps")]
+
+
+def _stepper(kind, how):
+    """A callable that makes one call into the executor of `kind` through
+    `how`; the feed is staged on the device(s) once, as a training loop
+    stages it."""
+    import jax
+
+    _, loss = _build_step(name=f"obs_{kind}_{how}_w")
+    host = {"x": np.ones((4, 4), "float32")}
+    if kind == "serial":
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = jax.device_put(host, exe.place.jax_device())
+        if how == "run":
+            return lambda: exe.run(feed=feed, fetch_list=[loss])
+        return lambda: exe.run_steps(feed_list=[feed, feed],
+                                     fetch_list=[loss])
+    from paddle_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
+    feed = jax.device_put(host, mesh.batch_sharding())
+    if how == "run":
+        return lambda: pe.run(feed=feed, fetch_list=[loss])
+    return lambda: pe.run_steps(feed_list=[feed, feed], fetch_list=[loss])
+
+
+def _host_events(logdir):
+    """[(name, start_ns, end_ns, counts)] of the program's spans on the
+    host planes of the trace under `logdir`, by start."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("executor.", "compile")):
+                    evs.append((e.name.split("#")[0], e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(evs, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.mark.parametrize("kind,how", STEPPERS)
+def test_step_phases_land_in_a_plain_profiler_session(kind, how, tmp_path):
+    """Sink A: a session started with jax.profiler.start_trace itself, the
+    flag off, holds executor.step over its five phases in order, with
+    their counts, for both executors and both entry points."""
+    import jax
+
+    assert not obs.enabled()
+    step = _stepper(kind, how)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # as benchmark/harness/trace.py starts it
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        step()  # compiles; the state comes from the startup program's run
+        step()
+    finally:
+        jax.profiler.stop_trace()
+    assert obs.default_tracer().spans() == []  # sink B stayed shut
+    evs = _host_events(str(tmp_path))
+    steps = [e for e in evs if e[0] == "executor.step"]
+    assert len(steps) == 2
+    for name, s0, e0, counts in steps:
+        inner = [e for e in evs if e[0] != "executor.step"
+                 and e[0] != "compile" and s0 <= e[1] and e[2] <= e0]
+        assert [e[0] for e in inner] == PHASES
+        # in that order, one after the other, none outside its step
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+        assert counts["kind"] == kind and counts["n_feed"] == 1
+        assert counts["n_state"] >= 2  # the weight, the bias, ...
+        assert ("steps" in counts) == (how == "run_steps")
+    first, second = ([e for e in evs if e[0] == name]
+                     for name in ("executor.plan", "executor.stage"))
+    assert [e[3]["cache"] for e in first] == ["miss", "hit"]
+    # the miss built under `compile`, nested in the plan span
+    (comp,) = [e for e in evs if e[0] == "compile"]
+    assert first[0][1] <= comp[1] and comp[2] <= first[0][2]
+    # steady state: everything the second step stages is in place already
+    assert second[1][3]["moved"] == 0
+    assert second[1][3]["n"] == steps[1][3]["n_state"] + 1 + 1
+    (fetch, _) = [e for e in evs if e[0] == "executor.fetch"]
+    assert fetch[3]["n"] == 1
+
+
+@pytest.mark.parametrize("kind,how", STEPPERS)
+def test_step_phases_land_in_the_ring_with_parents(kind, how, obs_on):
+    """Sink B: the flag on and no profiler session, the ring holds the same
+    tree, each phase with executor.step as its parent, and the step is
+    counted as an Executor's is, whichever executor made it."""
+    step = _stepper(kind, how)
+    obs.reset()
+    step()
+    step()
+    spans = obs.default_tracer().spans()
+    steps = [s for s in spans if s.name == "executor.step"]
+    assert len(steps) == 2 and all(s.parent is None for s in steps)
+    for st in steps:
+        inner = sorted((s for s in spans
+                        if s.name in PHASES and st.t0 <= s.t0
+                        and s.t1 <= st.t1), key=lambda s: s.t0)
+        assert [s.name for s in inner] == PHASES
+        assert all(s.parent == "executor.step" for s in inner)
+        assert st.args["kind"] == kind
+    stage = [s for s in spans if s.name == "executor.stage"]
+    assert stage[1].args["moved"] == 0
+    (comp,) = [s for s in spans if s.name == "compile"]
+    assert comp.parent == "executor.plan"
+    reg = obs.default_registry()
+    cc = reg.counter("paddle_tpu_compile_cache", "")
+    assert (cc.value(result="miss"), cc.value(result="hit")) == (1, 1)
+    if how == "run":
+        assert obs.step_stats().count == 2
+        assert reg.counter("paddle_tpu_executor_steps", "").value(
+            donated="1") == 2
+        assert reg.histogram("paddle_tpu_executor_step_seconds",
+                             "").series_summary()["count"] == 2
+        # the step's time is the span's: to the fetched value on the host
+        assert obs.step_stats().summary()["max_s"] == pytest.approx(
+            max(s.duration for s in steps))
+    else:
+        assert reg.histogram("paddle_tpu_executor_run_steps_seconds",
+                             "").series_summary(steps="2")["count"] == 2
+
+
+def test_values_already_placed_are_not_counted_as_moved(tmp_path):
+    """`moved` on the stage span: host values count, arrays in place do
+    not; on a mesh a single-device array counts (it is resharded)."""
+    import jax
+
+    _, loss = _build_step(name="obs_moved_w")
+    from paddle_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
+    host = {"x": np.ones((4, 4), "float32")}
+    fluid.set_flags({"FLAGS_observability": True})
+    obs.reset()
+    try:
+        pe.run(feed=host, fetch_list=[loss])   # state fresh from startup
+        pe.run(feed=host, fetch_list=[loss])   # state in place, feed not
+        pe.run(feed=jax.device_put(host, mesh.batch_sharding()),
+               fetch_list=[loss])
+        moved = [s.args["moved"] for s in obs.default_tracer().spans()
+                 if s.name == "executor.stage"]
+        n = [s.args["n"] for s in obs.default_tracer().spans()
+             if s.name == "executor.stage"]
+    finally:
+        fluid.set_flags({"FLAGS_observability": False})
+        obs.reset()
+    # the first step places every state array, the key and the feed
+    assert moved == [n[0], 1, 0]
 
 
 # -----------------------------------------------------------------------
