@@ -13,7 +13,7 @@ param-update semantics of the reference's optimizer ops without mutation.
 
 from __future__ import annotations
 
-import operator
+import itertools
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -105,6 +105,10 @@ class _RunPlan:
         self.fetch_names = list(fetch_names)
         self.state_names = _block_state_names(program, extra=fetch_names)
         self.rbw = _read_before_write(program, self.state_names, self.feed_names)
+        # ParallelExecutor: the shardings its call wants on its mesh, (the
+        # feeds', the state values' and the key's), built once with the
+        # entry and read every step
+        self.shardings = None
 
     def feed_values(self, feed, block0):
         return tuple(
@@ -209,29 +213,50 @@ def cached_entry(cache, key, fp, build, use_cache: bool = True):
     return entry, hit
 
 
-_ARRAY_TYPES = set()  # concrete types already seen to be a jax.Array
+def in_place(v, want) -> bool:
+    """The staging rule's predicate: `v` is already where the call wants it,
+    a jax.Array COMMITTED to the sharding `want`.  Stateless: it reads the
+    value, not a memory of what the last step returned.  Not in place: a
+    host value (fresh from the startup program, io.load_persistables,
+    scope.set_var), an uncommitted array (jnp.zeros, a fresh PRNGKey), an
+    array committed elsewhere (a scope shared by executors on two places;
+    a single-device array under a mesh).  Under half a microsecond a value
+    (PERF.md 3)."""
+    return getattr(v, "committed", False) and v.sharding == want
 
 
-def not_arrays(vals) -> int:
-    """`moved` where jax.device_put takes a bare Device: this jax rewraps
-    every array it is handed there, so only a value that is not a
-    jax.Array can be told as placed, without asking each its sharding.
-    (isinstance against jax.Array's abstract class costs 0.2 us a value;
-    a step has a thousand, so the concrete types are remembered.)"""
-    n = 0
-    for v in vals:
-        if type(v) not in _ARRAY_TYPES:
-            if isinstance(v, jax.Array):
-                _ARRAY_TYPES.add(type(v))
-            else:
-                n += 1
-    return n
+def stage_values(vals, wants):
+    """The ONE staging rule of Executor and ParallelExecutor, run and
+    run_steps: (values, wanted placement) -> (staged values, how many
+    were placed).  `wants` is one Sharding for every value or one for each.
+    A value in place goes on as the very object it came as; only the rest
+    go to jax.device_put, in one call.  That call is what keeps a step to
+    ONE executable: committed-ness is part of jax's lowering key, so a
+    host-numpy state (first step) and the committed arrays the step
+    returns (every later step) must reach the jit call alike, committed.
+    It is not free on values in place (this jax hands a bare Device a new
+    Array for every input, 16 us a value on the chip), hence the
+    predicate."""
+    if isinstance(wants, jax.sharding.Sharding):
+        wants = itertools.repeat(wants)
+    todo = [(i, w) for i, (v, w) in enumerate(zip(vals, wants))
+            if not in_place(v, w)]
+    if not todo:
+        return vals, 0
+    placed = jax.device_put([vals[i] for i, _ in todo],
+                            [w for _, w in todo])
+    staged = list(vals)
+    for (i, _), v in zip(todo, placed):
+        staged[i] = v
+    return tuple(staged), len(todo)
 
 
-def replaced(given, staged) -> int:
-    """`moved` where jax.device_put takes a Sharding: it hands back the
-    very object it was given for a committed array already placed so."""
-    return sum(map(operator.is_not, given, staged))
+def staged_args(feed_vals, state_vals, rng, wants):
+    """A call's three arguments through stage_values, as run_step's
+    `stage` returns them: (feed_vals, state_vals, rng, moved)."""
+    vals, moved = stage_values(feed_vals + state_vals + (rng,), wants)
+    n = len(feed_vals)
+    return vals[:n], vals[n:-1], vals[-1], moved
 
 
 def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
@@ -246,8 +271,9 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     lookup() -> ((fp, call, plan), hit); a miss nests the `compile` span
     feeds(plan, block0) -> the feed values as the plan phase leaves them
     stage(plan, block0, feed_vals, state_vals, rng) ->
-        (feed_vals, state_vals, rng, moved): every jax.device_put before
-        the call
+        (feed_vals, state_vals, rng, moved): the placement the caller
+        wants, asked of stage_values; `moved` is how many values went to
+        jax.device_put, the other n - moved were in place
     placed: the context the call is made in (a default device, a mesh)
     sentinel(plan, fetches, new_states) -> whether to skip the write-back
     cost(entry, feed_vals, state_vals, rng): once-a-program attribution,
@@ -359,8 +385,9 @@ def scan_multi_fn(body, n_batches, steps, flat: bool = False):
     return multi
 
 
-def stacked_feeds(cache, stack_key, fp, plan, feed_list, block0, put):
-    """Stack per-step feeds into [K, ...] device arrays, with an
+def stacked_feeds(cache, stack_key, fp, plan, feed_list, block0, wants):
+    """Stack per-step feeds into [K, ...] device arrays placed as `wants`
+    (stage_values) -> (the stack, how many arrays were placed), with an
     identity-keyed cache: repeated calls with the SAME feed objects (a
     training loop cycling one staged list) reuse the stacked copy instead
     of paying conversion + stack + transfer per call.  Only immutable
@@ -386,7 +413,7 @@ def stacked_feeds(cache, stack_key, fp, plan, feed_list, block0, put):
             for a, b in zip(row_a, row_b)
         )
     ):
-        return cached[1]
+        return cached[1], 0
     batches = []
     for feed in feed_list:
         vals = plan.feed_values(feed, block0)
@@ -397,13 +424,13 @@ def stacked_feeds(cache, stack_key, fp, plan, feed_list, block0, put):
                     "for ragged batches"
                 )
         batches.append(vals)
-    feeds_stack = put(tuple(
+    feeds_stack, moved = stage_values(tuple(
         jax.numpy.stack([b[i] for b in batches])
         for i in range(len(plan.feed_names))
-    ))
+    ), wants)
     if cacheable:
         cache[stack_key] = (fp, feeds_stack, feed_arrays)
-    return feeds_stack
+    return feeds_stack, moved
 
 
 class Executor:
@@ -484,29 +511,16 @@ class Executor:
         fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
         device = self.place.jax_device()
 
+        # feeds, state and key alike through the one staging rule
+        # (stage_values): what the last step returned is in place and goes
+        # on as it is; a host batch goes to device_put, which enqueues the
+        # copy and returns, so step N's compute overlaps batch N+1's
+        # transfer (the reference gets this from double-buffer reader ops,
+        # operators/reader/create_double_buffer_reader_op.cc)
+        want = jax.sharding.SingleDeviceSharding(device)
+
         def stage(plan, block0, feed_vals, state_vals, rng):
-            moved = not_arrays(feed_vals + state_vals + (rng,))
-            # explicit async host->device transfer: device_put enqueues the
-            # copy and returns immediately, so step N's compute overlaps
-            # batch N+1's transfer (the reference gets this from
-            # double-buffer reader ops,
-            # operators/reader/create_double_buffer_reader_op.cc; here
-            # JAX's async dispatch provides the overlap once the transfer
-            # is nonblocking)
-            feed_vals = jax.device_put(feed_vals, device)
-            # commit states too: a host-numpy state (fresh from the startup
-            # program) would compile one jit variant, and the committed
-            # device arrays it returns would compile a SECOND — device_put
-            # is a no-op for values already on `device`
-            state_vals = jax.device_put(state_vals, device)
-            # commit the PRNG key too: a fresh host key (first call) and
-            # the committed key a previous call wrote back lower to
-            # DIFFERENT executables (committed-ness is part of jax's
-            # lowering cache key), so without this every program compiled
-            # twice — trace cache hit, full XLA recompile (observed: 2x
-            # ~8 s flat-unroll compiles on CPU)
-            rng = jax.device_put(rng, device)
-            return feed_vals, state_vals, rng, moved
+            return staged_args(feed_vals, state_vals, rng, want)
 
         return run_step(
             "serial", program, scope,
@@ -624,10 +638,9 @@ class Executor:
             # same device commit as run(): the analyzed executable must
             # BE the one run() dispatches (an uncommitted key would
             # lower a second, never-reused variant)
-            device = self.place.jax_device()
-            feed_vals = jax.device_put(feed_vals, device)
-            state_vals = jax.device_put(state_vals, device)
-            rng = jax.device_put(rng, device)
+            feed_vals, state_vals, rng, _ = staged_args(
+                feed_vals, state_vals, rng,
+                jax.sharding.SingleDeviceSharding(self.place.jax_device()))
             return compiled.cost_analysis(feed_vals, state_vals, rng)
 
     def _resolve_entry(
@@ -814,18 +827,15 @@ class Executor:
             ), plan
 
         device = self.place.jax_device()
+        want = jax.sharding.SingleDeviceSharding(device)
 
         def stage(plan, block0, feed_list, state_vals, rng):
-            moved = not_arrays(state_vals + (rng,)) + not_arrays(
-                feed[n] for feed in feed_list for n in plan.feed_names)
-            feeds_stack = stacked_feeds(
+            feeds_stack, stacked = stacked_feeds(
                 self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-                lambda t: jax.device_put(t, device),
-            )
-            state_vals = jax.device_put(state_vals, device)
-            rng = jax.device_put(rng, device)  # see run(): avoids a second
-            # full XLA compile when the committed written-back key returns
-            return feeds_stack, state_vals, rng, moved
+                want)
+            _, state_vals, rng, moved = staged_args(
+                (), state_vals, rng, want)
+            return feeds_stack, state_vals, rng, stacked + moved
 
         return run_step(
             "serial", program, scope, lookup,

@@ -27,11 +27,11 @@ from ..core.compiler import CompiledBlock
 from ..core.executor import (
     _RunPlan,
     cached_entry,
-    not_arrays,
-    replaced,
+    in_place,
     run_step,
     scan_multi_fn,
     stacked_feeds,
+    staged_args,
 )
 from ..core.framework import Program, Variable, default_main_program
 from ..core.scope import Scope, global_scope
@@ -118,11 +118,11 @@ class ParallelExecutor:
         )
         block0 = self.program.desc.block(0)
         state_shardings = tuple(self._state_sharding(n, block0) for n in state_names)
-        in_shardings = (
-            tuple(self._feed_sharding(n, block0) for n in feed_names),
-            state_shardings,
-            self.mesh.replicated(),
-        )
+        feed_shardings = tuple(
+            self._feed_sharding(n, block0) for n in feed_names)
+        replicated = self.mesh.replicated()
+        plan.shardings = (feed_shardings, state_shardings + (replicated,))
+        in_shardings = (feed_shardings, state_shardings, replicated)
         # pin state outputs to their input shardings so persistable state
         # round-trips across steps without resharding; fetches gather to
         # replicated (they head to host anyway)
@@ -214,21 +214,26 @@ class ParallelExecutor:
             return feed_vals
 
         def stage(plan, block0, feed_vals, state_vals, rng):
-            # a feed goes to the call as it is: pjit's in_shardings place it
-            moved = not_arrays(feed_vals)
-            if multiprocess:
-                # each process feeds ITS batch shard; jax assembles the
-                # global array (reference: per-trainer reader shards under
-                # nccl2)
-                feed_vals = tuple(
-                    global_feed_value(self._feed_sharding(n, block0), v)
-                    for n, v in zip(plan.feed_names, feed_vals)
-                )
-                moved += not_arrays(state_vals + (rng,))
-            else:
-                state_vals, rng, resharded = self._reshard_serial_state(
-                    state_vals, rng, plan, block0)
-                moved += resharded
+            feed_sh, state_sh = plan.shardings  # the key's with the state's
+            if not multiprocess:
+                # the one staging rule (stage_values).  What pjit returned
+                # is in place.  The serial->SPMD handoff is not: the serial
+                # Executor commits state and key to ITS device, and pjit
+                # raises on committed single-device arguments that
+                # mismatch in_shardings, so those are resharded to this
+                # mesh, once
+                return staged_args(feed_vals, state_vals, rng,
+                                   feed_sh + state_sh)
+            # each process feeds ITS batch shard; jax assembles the global
+            # array (reference: per-trainer reader shards under nccl2).
+            # State that is not in place is pjit's to place here
+            # (device_put to a sharding over processes checks the value
+            # equal on each, a collective): the same predicate, counting
+            moved = len(feed_vals) + sum(
+                not in_place(v, w)
+                for v, w in zip(state_vals + (rng,), state_sh))
+            feed_vals = tuple(
+                global_feed_value(w, v) for w, v in zip(feed_sh, feed_vals))
             return feed_vals, state_vals, rng, moved
 
         return run_step(
@@ -330,6 +335,8 @@ class ParallelExecutor:
                 )
                 for n in plan.feed_names
             )
+            plan.shardings = (
+                stack_sh, state_sh + (self.mesh.replicated(),))
             return jax.jit(
                 multi,
                 in_shardings=(stack_sh, state_sh, self.mesh.replicated()),
@@ -342,18 +349,18 @@ class ParallelExecutor:
             ), plan
 
         def stage(plan, block0, feed_list, state_vals, rng):
-            moved = not_arrays(
-                feed[n] for feed in feed_list for n in plan.feed_names)
-            feeds_stack = stacked_feeds(
-                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-                lambda t: t,  # pjit's in_shardings own device placement
-            )
+            stack_sh, state_sh = plan.shardings
+            # before the stack is placed: jax's own error for a batch that
+            # does not divide is not the framework's
             self._check_batch_divisible(
-                plan.feed_names, tuple(f[0] for f in feeds_stack), block0
-            )
-            state_vals, rng, resharded = self._reshard_serial_state(
-                state_vals, rng, plan, block0)
-            return feeds_stack, state_vals, rng, moved + resharded
+                plan.feed_names, plan.feed_values(feed_list[0], block0),
+                block0)
+            feeds_stack, stacked = stacked_feeds(
+                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
+                stack_sh)
+            _, state_vals, rng, moved = staged_args(
+                (), state_vals, rng, state_sh)
+            return feeds_stack, state_vals, rng, stacked + moved
 
         return run_step(
             "spmd", self.program, self.scope, lookup,
@@ -398,22 +405,6 @@ class ParallelExecutor:
                     f"the tail batch (e.g. paddle_tpu.reader decorators "
                     f"batch(..., drop_last=True))"
                 )
-
-    def _reshard_serial_state(self, state_vals, rng, plan, block0):
-        """The ONE copy of the serial->SPMD handoff: the serial Executor
-        commits state/rng to ITS device (lowering-cache stability), and
-        pjit raises on committed single-device args that mismatch
-        in_shardings — explicitly reshard them to this mesh's shardings.
-        One-time copy: arrays come back FROM pjit already in place.
-        Returns (state_vals, rng, how many of them had to be placed)."""
-        staged = tuple(
-            jax.device_put(v, self._state_sharding(n, block0))
-            if isinstance(v, jax.Array) else v
-            for n, v in zip(plan.state_names, state_vals)
-        ) + (jax.device_put(rng, self.mesh.replicated()),)
-        # a host array stays as it is for pjit to place: that is a move too
-        moved = replaced(state_vals + (rng,), staged) + not_arrays(state_vals)
-        return staged[:-1], staged[-1], moved
 
     def drop_local_exe_scopes(self):  # reference API; scopes are XLA-owned
         pass

@@ -562,3 +562,110 @@ print("STEPS_REPEAT_COMPILES", compiles["n"] - base2)
     ns = int(out.stdout.split("STEPS_REPEAT_COMPILES")[1].split()[0])
     assert ns == 0, (
         f"repeated identical run_steps must not recompile, got {ns}")
+
+
+_ONE_COMPILE_PRELUDE = r"""
+import os, sys
+sys.path.insert(0, os.environ["REPO"])
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+jax.config.update("jax_platforms", "cpu")
+compiles = {"n": 0}
+from jax._src import monitoring
+monitoring.register_event_duration_secs_listener(
+    lambda event, dur, **kw: compiles.__setitem__("n", compiles["n"] + 1)
+    if "backend_compile" in event else None)
+import numpy as np
+import paddle_tpu as fluid
+from paddle_tpu import observability as obs
+x = fluid.layers.data("x", [8], dtype="float32")
+h = fluid.layers.fc(x, size=8, act="tanh",
+                    param_attr=fluid.ParamAttr(name="w"))
+loss = fluid.layers.mean(h)
+fluid.optimizer.SGD(0.1).minimize(loss)
+exe = fluid.Executor(fluid.CPUPlace())
+exe.run(fluid.default_startup_program())
+scope = fluid.global_scope()
+fluid.set_flags({"FLAGS_observability": True})
+def stage_spans():
+    out = [(s.args["n"], s.args["moved"])
+           for s in obs.default_tracer().spans() if s.name == "executor.stage"]
+    obs.reset()
+    return out
+obs.reset()
+"""
+
+_ONE_COMPILE_CASES = {
+    # what the old blanket device_put guarded: a first step from host-numpy
+    # state, steady steps, then a checkpoint load in the middle
+    "host_state_then_checkpoint_load": r"""
+for n in scope.local_var_names():
+    scope.set_var(n, np.asarray(scope.find_var(n)))
+feed = {"x": jax.device_put(np.ones((4, 8), "float32"),
+                            fluid.CPUPlace().jax_device())}
+base = compiles["n"]
+exe.run(feed=feed, fetch_list=[loss])
+print("FIRST_COMPILES", compiles["n"] - base)
+base = compiles["n"]
+for _ in range(2):
+    exe.run(feed=feed, fetch_list=[loss])
+scope.set_var("w", np.asarray(scope.find_var("w")))
+for _ in range(2):
+    exe.run(feed=feed, fetch_list=[loss])
+print("LATER_COMPILES", compiles["n"] - base)
+print("STAGE", stage_spans())
+""",
+    # startup by Executor, steps by ParallelExecutor; the first from a host
+    # batch, the rest from one in place (two executables before the rule
+    # staged feeds too: pjit keyed on the host batch)
+    "serial_to_spmd_handoff": r"""
+from paddle_tpu.parallel import make_mesh
+mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
+feed = {"x": jax.device_put(np.ones((4, 8), "float32"),
+                            mesh.batch_sharding())}
+base = compiles["n"]
+pe.run(feed={"x": np.ones((4, 8), "float32")}, fetch_list=[loss])
+print("FIRST_COMPILES", compiles["n"] - base)
+base = compiles["n"]
+for _ in range(3):
+    pe.run(feed=feed, fetch_list=[loss])
+print("LATER_COMPILES", compiles["n"] - base)
+print("STAGE", stage_spans())
+""",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ONE_COMPILE_CASES))
+def test_one_compile_whatever_the_state_arrives_as(case):
+    """The staging rule places only what is not in place, and the step
+    still has ONE executable: a first step from host state (or from the
+    serial executor's single-device state, under a mesh), steady steps and
+    a checkpoint load in the middle all hit it.  `moved` on the stage span
+    is exact: everything on the first step, the loaded value alone on the
+    step after the load, nothing on a steady step."""
+    import ast
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", _ONE_COMPILE_PRELUDE + _ONE_COMPILE_CASES[case]],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, REPO=repo))
+    assert out.returncode == 0, out.stderr[-1500:]
+    first = int(out.stdout.split("FIRST_COMPILES")[1].split()[0])
+    assert first >= 1, "the backend_compile listener never fired"
+    later = int(out.stdout.split("LATER_COMPILES")[1].split()[0])
+    assert later == 0, (
+        f"every later step must hit the first step's executable, "
+        f"{later} compiled")
+    stage = ast.literal_eval(out.stdout.split("STAGE")[1].strip())
+    n = stage[0][0]
+    assert all(s[0] == n for s in stage)
+    if case == "host_state_then_checkpoint_load":
+        # the feed is in place; of the first step all the rest is host
+        assert [s[1] for s in stage] == [n - 1, 0, 0, 1, 0]
+    else:
+        assert [s[1] for s in stage] == [n, 0, 0, 0]

@@ -643,20 +643,29 @@ def test_step_phases_land_in_the_ring_with_parents(kind, how, obs_on):
 
 
 def test_values_already_placed_are_not_counted_as_moved(tmp_path):
-    """`moved` on the stage span: host values count, arrays in place do
-    not; on a mesh a single-device array counts (it is resharded)."""
+    """`moved` on the stage span is exact in both executors: host values
+    count, and so does a device array that is somewhere else (on another
+    device; single-device under a mesh, where it is resharded); arrays in
+    place do not."""
     import jax
 
-    _, loss = _build_step(name="obs_moved_w")
+    exe, loss = _build_step(name="obs_moved_w")
     from paddle_tpu.parallel import make_mesh
 
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
     host = {"x": np.ones((4, 4), "float32")}
+    scope = fluid.global_scope()
+    here = fluid.CPUPlace().jax_device()
     fluid.set_flags({"FLAGS_observability": True})
     obs.reset()
     try:
-        pe.run(feed=host, fetch_list=[loss])   # state fresh from startup
+        exe.run(feed=host, fetch_list=[loss])  # state as startup left it
+        scope.set_var("obs_moved_w", jax.device_put(
+            np.asarray(scope.find_var("obs_moved_w")), jax.devices()[1]))
+        exe.run(feed=host, fetch_list=[loss])  # the feed and the stray one
+        exe.run(feed=jax.device_put(host, here), fetch_list=[loss])
+        pe.run(feed=host, fetch_list=[loss])   # state on the serial device
         pe.run(feed=host, fetch_list=[loss])   # state in place, feed not
         pe.run(feed=jax.device_put(host, mesh.batch_sharding()),
                fetch_list=[loss])
@@ -667,8 +676,10 @@ def test_values_already_placed_are_not_counted_as_moved(tmp_path):
     finally:
         fluid.set_flags({"FLAGS_observability": False})
         obs.reset()
-    # the first step places every state array, the key and the feed
-    assert moved == [n[0], 1, 0]
+    # serial: the host feed; with it the array on another device; nothing.
+    # On the mesh the first step places every state array, the key and the
+    # feed
+    assert moved == [1, 2, 0, n[3], 1, 0]
 
 
 # -----------------------------------------------------------------------
