@@ -250,6 +250,24 @@ def _all_concrete(ins: Dict[str, List[Any]]) -> bool:
     return True
 
 
+# the most elements an op's output may have and still be folded at trace
+# time: counters, conditions, lengths and small tables are far below it
+_FOLD_MAX_ELEMENTS = 1 << 16
+
+
+def _outputs_are_small(ctx: LoweringContext, op: OpDesc) -> bool:
+    """False where the descs say an output of `op` has a static shape of
+    more than _FOLD_MAX_ELEMENTS elements; an unknown shape counts as
+    small (it folds as it always did)."""
+    for name in op.output_arg_names():
+        var = ctx.block._find_var_recursive(name) if name else None
+        shape = list(getattr(var, "shape", None) or [])
+        if shape and all(d >= 0 for d in shape) \
+                and int(np.prod(shape)) > _FOLD_MAX_ELEMENTS:
+            return False
+    return True
+
+
 def _lower_forward_op(ctx: LoweringContext, op: OpDesc, need_vjp: bool) -> None:
     info = OpRegistry.get(op.type)
     ins = _gather_inputs(ctx, op)
@@ -263,7 +281,13 @@ def _lower_forward_op(ctx: LoweringContext, op: OpDesc, need_vjp: bool) -> None:
         # with static trip counts (the reference pins these to CPU with
         # force_cpu fill_constants; here they fold out of the program
         # entirely).
-        if not info.random and not info.stateful and _all_concrete(ins):
+        # Only what is small is folded: a fill_constant of a table's Adam
+        # moments is no loop counter, and folded it is computed eagerly,
+        # embedded in the program as a constant and kept on the device
+        # for as long as the executable lives (3.26 GB of zeros in the
+        # start-up program of a 407 M-parameter model, PERF.md PR 27).
+        if (not info.random and not info.stateful and _all_concrete(ins)
+                and _outputs_are_small(ctx, op)):
             with jax.ensure_compile_time_eval():
                 outs = info.lower(ctx, ins, attrs)
         else:
@@ -287,10 +311,12 @@ def _lower_forward_op(ctx: LoweringContext, op: OpDesc, need_vjp: bool) -> None:
             out_spec_holder.append(out_spec)
         return tuple(out_leaves)
 
-    if attrs.get("@recompute@"):
+    if attrs.get("@recompute@") and not info.meta.get("own_recompute"):
         # rematerialization (framework.recompute_scope): backward re-runs
         # this op's lowering from its inputs instead of keeping internal
-        # activations resident — jax.checkpoint drops the residuals
+        # activations resident — jax.checkpoint drops the residuals.  An
+        # op registered own_recompute (recurrence) places the checkpoint
+        # itself, around a unit smaller than the whole op
         fwd = jax.checkpoint(fwd)
     primal_outs, vjp_fn = jax.vjp(fwd, *leaves)
     out_spec = out_spec_holder[0]

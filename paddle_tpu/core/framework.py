@@ -657,20 +657,30 @@ _RECOMPUTE_DEPTH = [0]
 
 @contextlib.contextmanager
 def recompute_scope():
-    """Ops appended inside this scope carry the @recompute@ attr: the
-    compiler wraps each one's forward lowering in jax.checkpoint, so
-    backward re-runs the op from its inputs instead of keeping its
-    residuals.
+    """Ops appended inside this scope carry the @recompute@ attr: backward
+    re-runs their forward lowering (jax.checkpoint) instead of keeping its
+    residuals.  Two units, by the kind of op:
 
-    The remat boundary is PER OP.  That drops op-INTERNAL state — which
-    is where the memory is for composite lowerings: fused_attention's
-    [B, H, S, S] probability matrix, lstm/gru scan per-step gates, a
-    while sub-block's carried intermediates.  Activations at op
-    boundaries (one op's output feeding the next) remain resident either
-    way, so tagging a chain of primitive ops (mul, softmax, add as
-    separate ops) costs recompute FLOPs without saving memory.  No 2018
-    reference analogue; later Paddle's RecomputeOptimizer trades the
-    same way at segment granularity."""
+    * Any ordinary op: the remat boundary is PER OP — the compiler wraps
+      that op's forward lowering.  That drops op-INTERNAL state, which is
+      where the memory is for composite lowerings: fused_attention's
+      [B, H, S, S] probability matrix, lstm/gru scan per-step gates, a
+      `while` sub-block's carried intermediates (the whole loop is
+      computed again).  Activations at op boundaries (one op's output
+      feeding the next) remain resident either way, so tagging a chain of
+      primitive ops (mul, softmax, add as separate ops) costs recompute
+      FLOPs without saving memory.
+    * A `recurrence` op (layers.Recurrence built inside the scope): the
+      unit is the TRIP.  The op places the checkpoint itself, around the
+      body of its lax.scan: each trip's incoming carry is kept, and the
+      trip's activations — however many ops its body has — are computed
+      again when the backward pass reaches that trip.  This is the unit a
+      weight-tied, looped model needs (memory O(one trip) instead of
+      O(trips x body)), and the one per-op tagging cannot express; the
+      tags on the ops inside the body are not read.
+
+    No 2018 reference analogue; later Paddle's RecomputeOptimizer trades
+    the same way at segment granularity."""
     _RECOMPUTE_DEPTH[0] += 1
     try:
         yield

@@ -22,7 +22,7 @@ __all__ = [
     "equal", "not_equal", "less_than", "less_equal",
     "greater_than", "greater_equal",
     "logical_and", "logical_or", "logical_xor", "logical_not",
-    "While", "StaticRNN", "DynamicRNN", "IfElse", "Switch",
+    "While", "StaticRNN", "Recurrence", "DynamicRNN", "IfElse", "Switch",
     "increment", "array_write", "array_read", "array_length", "create_array",
     "lod_rank_table", "max_sequence_len", "lod_tensor_to_array",
     "array_to_lod_tensor", "shrink_memory", "split_lod_tensor",
@@ -556,6 +556,125 @@ class StaticRNN:
         if len(outs) == 1:
             return outs[0]
         return outs
+
+
+# ---------------------------------------------------------------------------
+# Recurrence
+# ---------------------------------------------------------------------------
+class Recurrence:
+    """One body block run a static number of trips over carried values
+    (TPU-native; no reference analogue): a weight-tied recurrence over
+    depth, where StaticRNN is one over time.  Parameters made inside the
+    body are made once and read on every trip.
+
+    rec = Recurrence(trips=4)
+    with rec.block():
+        h = rec.carry(h0)                 # h0 on the first trip, then
+        out = stack_of_layers(h)          # what update() handed on
+        rec.update(h, out)
+        rec.output(out)                   # kept from every trip
+    states = rec()                        # [trips, ...]
+    last = rec.final(h)                   # the carry after the last trip
+
+    The `recurrence` op lowers its body once (lax.scan), whatever the
+    number of trips; a parameter read in the body has one gradient, summed
+    over the trips; built inside fluid.recompute_scope() the trip is the
+    unit of recomputation (ops/control_flow_ops.py::_recurrence)."""
+
+    def __init__(self, trips: int, name: Optional[str] = None):
+        if int(trips) < 1:
+            raise ValueError(f"Recurrence needs at least one trip, got {trips}")
+        self.helper = LayerHelper("recurrence", name=name)
+        self.trips = int(trips)
+        self._parent_block = None
+        self._sub_block = None
+        self._carries: List[list] = []   # [init, in-block var, next, final]
+        self._outputs: List[tuple] = []  # (in-block step var, stacked var)
+        self._in_block = False
+
+    @contextlib.contextmanager
+    def block(self):
+        program = self.helper.main_program
+        self._parent_block = program.current_block()
+        self._sub_block = program._create_block()
+        self._in_block = True
+        try:
+            yield
+        finally:
+            self._in_block = False
+            program._rollback()
+        self._complete()
+
+    def _assert_in_block(self):
+        if not self._in_block:
+            raise RuntimeError("Recurrence method used outside rec.block()")
+
+    def carry(self, init):
+        """The carried value as the body sees it; `init` on the first trip."""
+        self._assert_in_block()
+        if init.block is self._sub_block:
+            raise ValueError(
+                f"Recurrence.carry: the first value {init.name} is made "
+                "inside the body; make it before rec.block()")
+        mem = self._sub_block.create_var(
+            name=unique_name("recurrence_carry"), shape=list(init.shape),
+            dtype=init.dtype)
+        final = self._parent_block.create_var(
+            name=unique_name("recurrence_final"), shape=list(init.shape),
+            dtype=init.dtype)
+        self._carries.append([init, mem, None, final])
+        return mem
+
+    def _entry(self, mem, method: str) -> list:
+        for c in self._carries:
+            if c[1] is mem:
+                return c
+        raise ValueError(f"{method}() target was not created by carry()")
+
+    def update(self, mem, var):
+        """The next trip's value of a carry()."""
+        self._assert_in_block()
+        self._entry(mem, "update")[2] = var
+
+    def output(self, *outputs):
+        """Values of the body kept from every trip, stacked on axis 0."""
+        self._assert_in_block()
+        for o in outputs:
+            stacked = self._parent_block.create_var(
+                name=unique_name("recurrence_out"),
+                shape=[self.trips] + list(o.shape), dtype=o.dtype)
+            self._outputs.append((o, stacked))
+
+    def _complete(self):
+        missing = [c[1].name for c in self._carries if c[2] is None]
+        if not self._carries or missing:
+            raise RuntimeError(
+                "Recurrence needs a carry(), and an update() for each "
+                f"(none for {missing})")
+        x_names, _ = _analyze_block_io(
+            self._sub_block, include_read_outputs=False)
+        self._parent_block.append_op(
+            type="recurrence",
+            inputs={"X": x_names, "Init": [c[0] for c in self._carries]},
+            outputs={"Out": [s for _, s in self._outputs],
+                     "Final": [c[3] for c in self._carries]},
+            attrs={
+                "sub_block": self._sub_block.idx,
+                "trips": self.trips,
+                "__x_names__": x_names,
+                "__carry_names__": [c[1].name for c in self._carries],
+                "__next_names__": [c[2].name for c in self._carries],
+                "__step_out_names__": [o.name for o, _ in self._outputs],
+            },
+        )
+
+    def final(self, mem):
+        """The value of a carry() after the last trip."""
+        return self._entry(mem, "final")[3]
+
+    def __call__(self):
+        outs = [s for _, s in self._outputs]
+        return outs[0] if len(outs) == 1 else outs
 
 
 def _parent_fill_constant(block, shape, dtype, value):

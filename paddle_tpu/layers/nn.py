@@ -24,6 +24,7 @@ __all__ = [
     "beam_search",
     "beam_search_decode",
     "fused_attention",
+    "rotary_embedding",
     "edit_distance",
     "conv2d",
     "conv3d",
@@ -34,6 +35,7 @@ __all__ = [
     "fused_bn_add_act",
     "conv_bn_add_act",
     "layer_norm",
+    "rms_norm",
     "group_norm",
     "dropout",
     "softmax",
@@ -572,6 +574,29 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-5,
         attrs={"begin_norm_axis": begin_norm_axis, "epsilon": epsilon},
     )
     return helper.append_activation(out)
+
+
+def rms_norm(input, begin_norm_axis=-1, epsilon=1e-6, param_attr=None,
+             name=None):
+    """input / sqrt(mean(input^2) + epsilon) over dims >= begin_norm_axis,
+    times a learned scale that starts at 1 (param_attr=False: no scale).
+    TPU-native addition (ops/nn_ops.py rms_norm)."""
+    helper = LayerHelper("rms_norm", input=input, param_attr=param_attr,
+                         name=name)
+    dtype = input.dtype
+    begin = begin_norm_axis % len(input.shape)
+    inputs = {"X": [input]}
+    if param_attr is not False:
+        inputs["Scale"] = [helper.create_parameter(
+            helper.param_attr,
+            shape=[int(np.prod([abs(d) for d in input.shape[begin:]]))],
+            dtype=dtype, default_initializer=ConstantInitializer(1.0))]
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(
+        type="rms_norm", inputs=inputs, outputs={"Y": [out]},
+        attrs={"begin_norm_axis": begin, "epsilon": epsilon},
+    )
+    return out
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
@@ -1270,6 +1295,20 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
     helper.append_op(
         type="fused_attention", inputs=inputs, outputs={"Out": [out]},
         attrs={"causal": causal, "scale": float(scale) if scale else 0.0},
+    )
+    return out
+
+
+def rotary_embedding(x, base=10000.0, offset=0, name=None):
+    """Rotary position embedding of x [..., S, D] (heads first, then
+    positions, then the head's features), half-split pairs, position
+    offset + index along axis -2, angles in fp32 (TPU-native; see
+    ops/attention_ops.py rotary_embedding)."""
+    helper = LayerHelper("rotary_embedding", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rotary_embedding", inputs={"X": [x]}, outputs={"Out": [out]},
+        attrs={"base": float(base), "offset": int(offset)},
     )
     return out
 

@@ -9,9 +9,11 @@ additive [Sq, Sk] bias tensor, so nothing score-shaped ever hits HBM.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
+from ..core import amp
 from ..core.registry import register_op
-from .common import data, in_desc, set_output
+from .common import data, in_desc, same_shape, set_output
 
 
 def _fused_attn_infer(op, block):
@@ -70,3 +72,25 @@ def _fused_attention(ctx, ins, attrs):
                                   klen is not None)
     args = (q, k, v) + ((klen,) if klen is not None else ())
     return {"Out": [attend(*args)]}
+
+
+@register_op("rotary_embedding", infer_shape=same_shape("X", "Out"),
+             diff_inputs=["X"])
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary position embedding (Su et al. 2021) of X [..., S, D] along
+    its last two axes, position p = offset + index on axis -2, in the
+    half-split layout: pair i is (x[i], x[i + D/2]), turned by the angle
+    p * base^(-2i/D).  The angles are fp32 whatever X is (at base 1e6 and
+    p in the thousands bf16 has no digit left of them); Out has X's dtype."""
+    x = data(ins["X"][0])
+    seq, dim = x.shape[-2], x.shape[-1]
+    half = dim // 2
+    inv_freq = float(attrs.get("base", 10000.0)) ** (
+        -np.arange(half, dtype=np.float64) * 2.0 / dim)
+    pos = np.arange(seq, dtype=np.float64) + int(attrs.get("offset", 0))
+    angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xs = x.astype(amp.stats_dtype(x))
+    x1, x2 = xs[..., :half], xs[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return {"Out": [out.astype(x.dtype)]}

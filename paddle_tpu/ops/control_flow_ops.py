@@ -36,6 +36,7 @@ from ..core.lod import LoDValue
 from ..core.proto import DataType
 from ..core.registry import register_op
 from ..core.tensor_array import StackedTensorArray, TensorArrayValue
+from ..observability import default_registry, span
 from .common import data, in_desc, lengths, same_shape, set_output
 
 
@@ -523,10 +524,12 @@ def _while(ctx, ins, attrs):
                     ctx, sub_block, env, out_names, cond_name, T, arr_lens,
                     arr_writes, base_key,
                 )
-            except Exception:
+            except Exception as e:
                 # any pattern outside the scan contract (body-local arrays,
                 # LoDValue steps, traced-index list writes, ...) falls back
-                # to the unroll path, which is the reference semantics
+                # to the unroll path, which is the reference semantics; the
+                # T bodies it compiles are counted and the reason kept
+                _note_unrolled("while", T, f"{type(e).__name__}: {e}")
                 env = dict(zip(x_names, ins["X"]))  # body untouched; retry
         it = 0
         while _concrete_bool(cond):
@@ -576,6 +579,79 @@ def _while(ctx, ins, attrs):
     final = jax.lax.while_loop(cond_fn, body_fn, tuple(env[n] for n in carry_names))
     env_f = dict(zip(carry_names, final))
     return {"Out": [env_f.get(n) for n in out_names]}
+
+
+def _note_unrolled(op_type: str, trips: int, why: str) -> None:
+    """A loop that could have been one scan body is lowered `trips` times:
+    counted (`recurrence_unrolled_total`) and the reason left on a span,
+    so that a program that quietly compiles T bodies is seen."""
+    default_registry().counter(
+        "recurrence_unrolled",
+        "static-trip loops lowered by unrolling where one lax.scan body "
+        "was tried",
+    ).inc(op=op_type)
+    with span("recurrence.unrolled", op=op_type, trips=trips, why=why[:200]):
+        pass
+
+
+# ---------------------------------------------------------------------------
+# recurrence
+# ---------------------------------------------------------------------------
+def _recurrence_infer(op, block):
+    pass  # layers.Recurrence shapes the outputs as it creates them
+
+
+@register_op("recurrence", infer_shape=_recurrence_infer, random=True,
+             diff_inputs=["X", "Init"], own_recompute=True)
+def _recurrence(ctx, ins, attrs):
+    """One body block run a static number of trips (layers.Recurrence): the
+    carried values go from trip to trip, every trip's step outputs are
+    stacked on a new leading axis, and what the body reads from outside
+    (its parameters) is the same every trip.
+
+    One lowering: the body is lowered once, as the step of a lax.scan, for
+    any number of trips, so compile time is O(body).  Differentiated as a
+    whole by the compiler's jax.vjp, so a value read from outside has ONE
+    gradient, summed over the trips inside the scan's transpose.  Under
+    framework.recompute_scope (the op's @recompute@ attr) the TRIP is the
+    unit of rematerialization: jax.checkpoint goes around the scan body,
+    each trip's incoming carry is kept and its activations are computed
+    again in the backward pass."""
+    from ..core.compiler import LoweringContext, lower_op
+
+    sub_block = ctx.program.block(attrs["sub_block"])
+    ops = list(sub_block.desc.ops)
+    trips = int(attrs["trips"])
+    carry_names: List[str] = attrs["__carry_names__"]
+    next_names: List[str] = attrs["__next_names__"]
+    step_names: List[str] = attrs["__step_out_names__"]
+    recompute = bool(attrs.get("@recompute@"))
+    closure = dict(zip(attrs["__x_names__"], ins["X"]))
+    init = tuple(jnp.asarray(data(v)) for v in ins["Init"])
+
+    lowered = [0]  # times the body went through lower_op: 1 under scan
+
+    def body(carry, key):
+        lowered[0] += 1
+        env = dict(closure)
+        env.update(zip(carry_names, carry))
+        inner = LoweringContext(ctx.program, sub_block, env, key,
+                                mesh=ctx.mesh, is_test=ctx.is_test)
+        for op in ops:
+            lower_op(inner, op, frozenset())
+        # a carry keeps the dtype it came in with (under amp's keep tier
+        # the body hands a bf16 state on where the first came in fp32)
+        new = tuple(jnp.asarray(data(env[n])).astype(c.dtype)
+                    for n, c in zip(next_names, carry))
+        return new, tuple(data(env[n]) for n in step_names)
+
+    with span("recurrence.lower", trips=trips,
+              recompute=int(recompute)) as sp:
+        step = jax.checkpoint(body, prevent_cse=False) if recompute else body
+        final, stacked = jax.lax.scan(
+            step, init, jax.random.split(ctx.rng(), trips))
+        sp.set(bodies_lowered=lowered[0])
+    return {"Out": list(stacked), "Final": list(final)}
 
 
 # ---------------------------------------------------------------------------

@@ -674,6 +674,34 @@ def _layer_norm(ctx, ins, attrs):
     }
 
 
+def _rms_norm_infer(op, block):
+    x = in_desc(op, block, "X")
+    if x is None:
+        return
+    set_output(block, op, "Y", x.shape, x.dtype)
+
+
+@register_op("rms_norm", infer_shape=_rms_norm_infer,
+             diff_inputs=["X", "Scale"])
+def _rms_norm(ctx, ins, attrs):
+    """x / sqrt(mean(x^2) + eps) [* scale] over dims >= begin_norm_axis
+    (Zhang & Sennrich 2019): layer_norm without the mean and the shift.
+    TPU-native addition; the 2018 reference has no such op."""
+    x = data(ins["X"][0])
+    begin = attrs.get("begin_norm_axis", x.ndim - 1)
+    eps = attrs.get("epsilon", 1e-6)
+    axes = tuple(range(begin, x.ndim))
+    # the mean of squares and the scaling in fp32 even for bf16
+    # activations (amp keep_output mode); Y is written in x's dtype
+    xs = x.astype(amp.stats_dtype(x))
+    y = xs * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xs), axis=axes, keepdims=True) + eps)
+    scale = ins.get("Scale", [None])[0]
+    if scale is not None:
+        y = y * jnp.reshape(data(scale), (1,) * begin + x.shape[begin:])
+    return {"Y": [y.astype(x.dtype)]}
+
+
 def _group_norm_infer(op, block):
     x = in_desc(op, block, "X")
     if x is None:

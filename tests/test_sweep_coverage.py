@@ -193,7 +193,20 @@ def _via_print():
     return y, {"x": np.ones((2, 3), "float32")}
 
 
+def _via_recurrence():
+    # numerics and gradients: tests/test_looped_decoder.py
+    x = layers.data("x", [3], dtype="float32")
+    rec = layers.Recurrence(trips=3)
+    with rec.block():
+        h = rec.carry(x)
+        nxt = layers.scale(h, scale=0.5, bias=1.0)
+        rec.update(h, nxt)
+        rec.output(nxt)
+    return [rec(), rec.final(h)], {"x": np.ones((2, 3), "float32")}
+
+
 EXERCISED_VIA = {
+    "recurrence": _via_recurrence,
     "gru": _via_dynamic_gru,
     "fusion_gru": _via_fusion_gru,
     "fused_attention": _via_fused_attention,
