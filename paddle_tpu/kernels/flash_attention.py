@@ -7,6 +7,27 @@ through VMEM with the online-softmax recurrence (Dao et al.,
 FlashAttention), so HBM traffic stays O(S*D) and the MXU sees back-to-back
 block matmuls.
 
+The forward's grid is planned from the shape (PR 28).  A grid step costs
+about 0.35 us before it computes anything, so at 128 x 128 blocks (all this
+kernel had while its only shape was S 256, head 64) a 32 x 2048 x 128 call
+was 8192 steps of overhead.  Two faces of one mechanism:
+- _plan_blocks: the blocks that take the fewest grid steps whose working
+  set (fwd_working_set_bytes: the declared buffers plus the fp32 score and
+  probability blocks) fits 3/4 of the v5e's scoped VMEM.  S 2048 causal
+  runs 1024 x 1024, S 256 one 256 x 256 block a head.  It reads the shape
+  and `causal`, nothing else: no flag, no argument a model sets.
+- under `causal`, a k-block wholly above the diagonal (bottom-right
+  aligned) is neither fetched nor computed: its body is under pl.when, and
+  the K/V index maps repeat the block already held, which the pipeline
+  answers with no DMA.  The blocks that run take the iota/compare/select
+  mask only where the diagonal, the end of the keys or klen[b] cuts them.
+Rounding is where it was: operands in the input dtype, scores, softmax
+statistics and the accumulator fp32, the scale on the fp32 scores,
+probabilities cast to V's dtype for the second matmul; only the order of
+the online-softmax sums moves with the block size.  `flash.plan` (an
+observability span, at lowering) says what a call was given.
+tools/flash_fwd_probe.py times the forward alone on the chip.
+
 The backward is the FlashAttention-2 recipe in two Pallas kernels — a
 round-3 change driven by a chip profile (today: `python3 benchmark/run.py
 --workload transformer-train --trace 1`) showing the
@@ -20,6 +41,8 @@ chains dominating transformer step time:
 - D = rowsum(dO * O) is a cheap fused elementwise pass outside the kernels.
 Zero-padded dO rows make padded q rows contribute exactly zero to dK/dV,
 and the same key-padding/causal masks as forward zero padded k columns.
+Their q-block is the forward's (the packed lse plane is laid out by it);
+their k-block is still 128, and they skip nothing yet.
 
 Backward selection (FLAGS_flash_bwd): "jax" (default) differentiates the
 reference formulation under jax.vjp — a recompute backward XLA fuses well;
@@ -27,8 +50,8 @@ reference formulation under jax.vjp — a recompute backward XLA fuses well;
 yet been compared on a chip (ROADMAP D7); the kernels are
 correctness-tested in interpret mode and compile for v5e
 (tests/test_aot_cost.py).  pallas_call instances are memoized by
-static config so the 3 distinct attention shapes of an 18-block
-transformer serialize to 3 kernel payloads, not 54.
+static config, blocks included, so every attention site of one shape
+(the 18 of a Transformer-base step are 3 shapes) shares one kernel payload.
 """
 
 from __future__ import annotations
@@ -40,25 +63,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["flash_attention", "fwd_vmem_bytes"]
+from ..analysis.pallas import V5E_VMEM_BYTES, tile_padded_bytes
+from ..observability import span
+
+__all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes"]
 
 NEG_INF = -1e30
+
+# What the forward's planned working set (fwd_working_set_bytes) may take:
+# 3/4 of the v5e's 16 MiB of scoped VMEM, the share conv_epilogue plans its
+# tiles against.  Settled on the chip (tools/flash_fwd_probe.py --sweep,
+# PERF.md PR 28): at 32 x 2048 x 128 causal the time falls with the steps
+# all the way to 1024 x 1024 (11.5 MB by this count, 0.43 ms against 4.58 at
+# 128 x 128); the count is cautious, Mosaic still compiles 20.5 MB of it and
+# refuses 22.
+_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
 
 
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
                    head_dim: int = 128, num_q_blocks: int = 1,
                    dtype="float32", emit_lse: bool = True) -> int:
-    """Analytic VMEM working set of ONE forward pallas invocation — the
-    kernel's own statement of the linter's pricing model
+    """Analytic VMEM footprint of the buffers ONE forward pallas invocation
+    declares — the kernel's own statement of the linter's pricing model
     (paddle_tpu.analysis.pallas.kernel_vmem_bytes; tests hold the two
     equal on the traced call): the double-buffered padded q/k/v/o
     blocks (+ the packed lse plane when emitted) plus the fp32
-    online-softmax scratch.  The SMEM klen vector is outside VMEM.
-    Default blocks at d=128 sit near 0.5 MB — an order of magnitude
-    under the v5e budget, which is why this kernel never needed a tile
-    planner (conv_epilogue._plan is the shape that does)."""
-    from ..analysis.pallas import tile_padded_bytes
-
+    online-softmax scratch.  The SMEM klen vector is outside VMEM, and so
+    are the score blocks the body computes: fwd_working_set_bytes adds
+    those, and it is what _plan_blocks holds under its budget."""
     blocks = [
         ((1, block_q, head_dim), dtype),   # q
         ((1, block_k, head_dim), dtype),   # k
@@ -71,6 +103,87 @@ def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
                ((block_q, head_dim), "float32")]
     return (2 * sum(tile_padded_bytes(s, d) for s, d in blocks)
             + sum(tile_padded_bytes(s, d) for s, d in scratch))
+
+
+def fwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
+                          dtype="float32", emit_lse=True) -> int:
+    """fwd_vmem_bytes plus what a grid step computes between its two
+    matmuls: the fp32 score block and the fp32 probability block, each
+    [block_q, block_k].  At 128 x 128 they are 128 KB and were never
+    counted; at 512 x 512 they are 2 MB, more than every declared buffer
+    together, and they are what bounds the block plan."""
+    return (fwd_vmem_bytes(block_q, block_k, head_dim, num_q_blocks, dtype,
+                           emit_lse)
+            + 2 * tile_padded_bytes((block_q, block_k), "float32"))
+
+
+def _block_lengths(seq: int):
+    """The block lengths a sequence of `seq` rows may be cut into: the
+    sequence itself where it is no longer than one 128-row tile, else every
+    multiple of 128 that divides the sequence rounded up to 128 — so a plan
+    never pads by more than the rounding the 128 blocks always paid."""
+    if seq <= 128:
+        return [seq]
+    tiles = -(-seq // 128)
+    return [128 * n for n in range(1, tiles + 1) if tiles % n == 0]
+
+
+def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
+    """(block_q, block_k) of the forward's grid, from the shape alone.
+
+    A grid step costs about the same whatever it computes (the pipeline's
+    bookkeeping, two DMAs, a read-modify-write of the fp32 accumulator and
+    a dozen VPU passes whose fixed part dominates a small block), so the
+    plan takes the fewest steps: of the block pairs whose working set
+    (fwd_working_set_bytes) fits _PLAN_VMEM_BUDGET the one with the fewest,
+    and the wider key block where two tie (the accumulator is rescaled once
+    a k-step: the probe reads 256 x 1024 at 0.59 ms, 1024 x 256 at 1.52).
+    Under `causal` the steps counted are those that run: _skipped_k_steps
+    are free."""
+    def steps_then_wide(plan):
+        bq, bk = plan
+        nqb, nkb = -(-sq // bq), -(-sk // bk)
+        skipped = (_skipped_k_steps(nqb, nkb, bq, bk, sk - sq)
+                   if causal else 0)
+        return nqb * nkb - skipped, -bk
+
+    plans = [(bq, bk) for bq in _block_lengths(sq)
+             for bk in _block_lengths(sk)]
+    fits = [(bq, bk) for bq, bk in plans
+            if fwd_working_set_bytes(bq, bk, head_dim, -(-sq // bq), dtype,
+                                     emit_lse) <= _PLAN_VMEM_BUDGET]
+    # a head so wide that not even the smallest blocks fit the share still
+    # gets them: the share is headroom, not the compiler's limit
+    return min(fits or plans[:1], key=steps_then_wide)
+
+
+def _block_runs(qi, ki, block_q, block_k, causal_offset):
+    """Whether score block (qi, ki) has any key at or under the causal
+    diagonal (bottom-right aligned, as _block_mask has it): its first key
+    is one the q-block's last row sees.  Python ints or traced scalars."""
+    return ki * block_k <= (qi + 1) * block_q - 1 + causal_offset
+
+
+def _kv_block_index(qi, ki, block_q, block_k, causal_offset):
+    """The K/V block a causal grid step (qi, ki) holds: its own while it
+    runs; past the last one q-block qi can see (_flash_kernel skips those
+    steps) the index stays where it was, so the pipeline sees the block it
+    already holds and issues no DMA."""
+    last = jnp.maximum((qi + 1) * block_q - 1 + causal_offset, 0) // block_k
+    return jnp.minimum(ki, last)
+
+
+def _skipped_k_steps(nqb, nkb, block_q, block_k, causal_offset):
+    """How many of one (batch, head)'s nqb x nkb grid steps lie wholly
+    above the causal diagonal, and cost neither a fetch nor a matmul: for
+    each q-block, the k-blocks past the last one that _block_runs (a row
+    at a time, so a 128k sequence plans in no time)."""
+    def running(i):
+        last = ((i + 1) * block_q - 1 + causal_offset) // block_k
+        return min(max(last + 1, 0), nkb)
+
+    return sum(nkb - running(i) for i in range(nqb))
+
 
 # The per-row logsumexp/D residuals are PACKED: [B*H, num_q_blocks,
 # block_q] fp32, row qi of the packed plane holding q-block qi's
@@ -135,7 +248,16 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     online-softmax state lives in VMEM scratch across K steps.  klen_ref
     (SMEM) holds every batch row's valid key count (key-padding mask),
     indexed by program_id(0).  Emits O and the per-row logsumexp L
-    (backward residual)."""
+    (backward residual).
+
+    A step does only what its block needs.  Under `causal` a k-block that
+    starts past the last key the q-block's last row sees is wholly above
+    the diagonal: its body does not run (and _fwd_call's K/V index maps
+    hand the pipeline the previous block again, so nothing is fetched).
+    Of the blocks that run, only one that can be cut takes the
+    iota/compare/select mask: it crosses the diagonal, or it reaches past
+    the keys this batch row has (the padded end of the sequence, or
+    klen[b], which is data: a scalar read from SMEM)."""
     import jax.experimental.pallas as pl
 
     bi = pl.program_id(0)
@@ -155,25 +277,42 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0]  # [block_q, D]
-    k = k_ref[0]  # [block_k, D]
-    v = v_ref[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q, block_k,
-                       seq_k, causal, causal_offset)
-    s = jnp.where(mask, s, NEG_INF)
+    def _update(cut):
+        q = q_ref[0]  # [block_q, D]
+        k = k_ref[0]  # [block_k, D]
+        v = v_ref[0]
+        # q @ k.T, contracting the head dim of both: no transposed copy
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if cut:
+            mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q,
+                               block_k, seq_k, causal, causal_offset)
+            s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[:]  # [block_q, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    correction = jnp.exp(m_prev - m_new)
-    l_new = correction * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * correction + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    m_scr[:] = m_new
-    l_scr[:] = l_new
+        m_prev = m_scr[:]  # [block_q, 1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = correction * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * correction + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_scr[:] = m_new
+        l_scr[:] = l_new
+
+    k_end = jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
+    cut = (ki + 1) * block_k > k_end
+    runs = True
+    if causal:
+        runs = _block_runs(qi, ki, block_q, block_k, causal_offset)
+        # the block's last key is past what its first row sees
+        cut = jnp.logical_or(
+            cut, (ki + 1) * block_k - 1 > qi * block_q + causal_offset)
+    pl.when(jnp.logical_and(runs, cut))(lambda: _update(True))
+    pl.when(jnp.logical_and(runs, jnp.logical_not(cut)))(
+        lambda: _update(False))
 
     @pl.when(ki == num_kb - 1)
     def _finalize():
@@ -305,6 +444,12 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
     kernel = _flash_kernel if emit_lse else _flash_kernel_fwd_only
     nqb = sqp // bq
+
+    def kv_block(b, i, j):
+        if causal:
+            j = _kv_block_index(i, j, bq, bk, causal_offset)
+        return (b, j, 0)
+
     out_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, sqp, d), jnp.dtype(dtype))]
     if emit_lse:
@@ -326,8 +471,8 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             pl.BlockSpec((bh,), lambda b, i, j: (0,),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), kv_block),
+            pl.BlockSpec((1, bk, d), kv_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -340,18 +485,20 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
     )
 
 
-def _pallas_flash(q, k, v, klen, causal, scale, block_q=128, block_k=128,
+def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
                   interpret=False, need_lse=True):
     """Returns (out [B,H,Sq,D], lse [B*H, num_q_blocks, block_q] fp32
     per-row logsumexp in the PACKED residual layout — see the module
-    comment; _pallas_flash_bwd consumes it as-is).  need_lse=False
-    (inference / the recompute-jax backward) skips the lse output
-    entirely — its HBM write is pure waste when nothing consumes it —
-    and returns (out, None)."""
+    comment; _pallas_flash_bwd reads block_q off its shape).
+    need_lse=False (inference / the recompute-jax backward) skips the lse
+    output entirely — its HBM write is pure waste when nothing consumes
+    it — and returns (out, None).  The blocks come from _plan_blocks;
+    block_q / block_k pin them for a test or the probe, never a model."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
+    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse)
+    bq = plan_q if block_q is None else min(block_q, Sq)
+    bk = plan_k if block_k is None else min(block_k, Sk)
     # pad sequence dims to block multiples (masked in-kernel)
     q = _pad_seq(q, bq)
     k = _pad_seq(k, bk)
@@ -361,10 +508,16 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=128, block_k=128,
     vf = v.reshape(B * H, v.shape[2], D)
     klen_bh = jnp.repeat(klen, H)  # [B*H] valid key counts
 
-    call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
-                     scale, Sk, Sk - Sq, str(q.dtype), interpret,
-                     emit_lse=need_lse)
-    res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
+    nqb, nkb = qf.shape[1] // bq, kf.shape[1] // bk
+    skipped = _skipped_k_steps(nqb, nkb, bq, bk, Sk - Sq) if causal else 0
+    # at lowering, as recurrence.lower: static counts over one (b, h)
+    with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=bq,
+              block_k=bk, k_steps=nqb * nkb, k_steps_skipped=skipped,
+              causal=int(causal)):
+        call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
+                         scale, Sk, Sk - Sq, str(q.dtype), interpret,
+                         emit_lse=need_lse)
+        res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
     out = res[0].reshape(B, H, res[0].shape[1], D)
     if out.shape[2] != Sq:
         out = out[:, :, :Sq]
@@ -436,10 +589,12 @@ def _bwd_calls(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
 
 def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
-                      block_q=128, block_k=128, interpret=False):
+                      block_k=128, interpret=False):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, Sq)
+    # the packed lse plane [B*H, nqb, block_q] is laid out by the forward's
+    # plan: the q-block is read off it, so the two cannot disagree
+    bq = lse.shape[2]
     bk = min(block_k, Sk)
     qp = _pad_seq(q, bq)
     op = _pad_seq(out, bq)

@@ -735,26 +735,35 @@ def test_flash_fwd_vmem_estimate_matches_linter_price():
     klen vector excluded from both)."""
     from paddle_tpu.analysis import pallas as AP
     from paddle_tpu.kernels.flash_attention import (
-        flash_attention, fwd_vmem_bytes)
+        _plan_blocks, flash_attention, fwd_vmem_bytes,
+        fwd_working_set_bytes)
 
-    B, H_, S, D = 2, 2, 256, 128
-    qkv = jax.ShapeDtypeStruct((B, H_, S, D), jnp.float32)
+    B, H_, S, D = 2, 2, 2048, 128
+    qkv = jax.ShapeDtypeStruct((B, H_, S, D), jnp.bfloat16)
     eqns = _traced_pallas_eqns(
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         force="interpret"), qkv, qkv, qkv)
     assert len(eqns) == 1
     priced = AP.kernel_vmem_bytes(eqns[0])
+    # the blocks are the plan's, not a literal
+    bq, bk = _plan_blocks(S, S, D, jnp.bfloat16, True, False)
+    assert (bq, bk) != (128, 128)
     # the primal (inference) path drops the lse output entirely —
     # fwd_vmem_bytes(emit_lse=False) is its exact working set
     assert priced == fwd_vmem_bytes(
-        block_q=128, block_k=128, head_dim=D, num_q_blocks=S // 128,
-        emit_lse=False)
+        block_q=bq, block_k=bk, head_dim=D, num_q_blocks=S // bq,
+        dtype="bfloat16", emit_lse=False)
     # the training forward adds (only) the packed per-row lse plane
+    bq, bk = _plan_blocks(S, S, D, jnp.bfloat16, True, True)
     with_lse = fwd_vmem_bytes(
-        block_q=128, block_k=128, head_dim=D, num_q_blocks=S // 128,
-        emit_lse=True)
+        block_q=bq, block_k=bk, head_dim=D, num_q_blocks=S // bq,
+        dtype="bfloat16", emit_lse=True)
     assert with_lse > priced
-    assert with_lse < AP.default_vmem_budget()
+    # and what the plan holds under the budget counts the score blocks,
+    # which no declared buffer shows the linter
+    planned = fwd_working_set_bytes(bq, bk, D, S // bq, "bfloat16", True)
+    assert planned >= with_lse + 2 * bq * bk * 4
+    assert planned < AP.default_vmem_budget()
 
 
 def test_corpus_vmem_overflow_exactly_its_detector_with_fields():

@@ -254,6 +254,7 @@ def _paged(dtype, page_size, two_level=False):
 _MAIN_PATH_KERNELS = {
     "flash_fwd_transformer_base": lambda: _flash_fwd((32, 8, 256, 64)),
     "flash_fwd_long_context": lambda: _flash_fwd((2, 8, 2048, 64)),
+    "flash_fwd_ouro": lambda: _flash_fwd((2, 16, 2048, 128)),
     "flash_bwd_dq_dkv_transformer_base": lambda: _flash_bwd((32, 8, 256, 64)),
     "paged_decode_bf16_ps16": lambda: _paged(jnp.bfloat16, 16),
     "paged_decode_int8_ps32": lambda: _paged(jnp.int8, 32),

@@ -32,10 +32,12 @@ def _tpu_lowers(fn, *args):
 
 class TestFlashLowering:
     # (B, H, Sq, Sk, D): the transformer bench (256-seq), the longctx
-    # bench (2048-seq), a cached-decode shape (Sq < Sk), and a ragged
-    # shape exercising the padding path
+    # bench (2048-seq), a cached-decode shape (Sq < Sk), a ragged
+    # shape exercising the padding path, and ouro-train-loop4's (head 128,
+    # where the plan's blocks are largest)
     SHAPES = [(16, 16, 256, 256, 64), (4, 16, 2048, 2048, 64),
-              (8, 8, 128, 384, 64), (2, 4, 200, 200, 64)]
+              (8, 8, 128, 384, 64), (2, 4, 200, 200, 64),
+              (2, 16, 2048, 2048, 128)]
 
     @pytest.mark.parametrize("shape", SHAPES)
     def test_forward_with_lse(self, shape):
@@ -94,8 +96,11 @@ class TestFlashLowering:
         exp = jax.export.export(jax.jit(f), platforms=["tpu"])(q)
         txt = exp.mlir_module()
         assert f"tensor<{B * H}x{Sq}x128xf32>" not in txt
-        # the packed residual layout is what flows instead
-        assert f"tensor<{B * H}x{Sq // 128}x128xf32>" in txt
+        # the packed residual layout is what flows instead, one row a
+        # q-block of the forward's plan
+        bq, _ = fa._plan_blocks(Sq, Sk, D, jnp.bfloat16, True, True)
+        assert bq > 128 and Sq % bq == 0
+        assert f"tensor<{B * H}x{Sq // bq}x{bq}xf32>" in txt
 
 
 class TestConvEpilogueLowering:

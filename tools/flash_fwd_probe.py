@@ -1,0 +1,184 @@
+"""The flash forward alone, timed on the chip at the cells' shapes.
+
+  ouro         32 x 2048 x 128 (B 2, H 16), causal           ouro-train-loop4
+  nmt-decoder  768 x 256 x 64  (B 96, H 8), causal           transformer-train
+  nmt-encoder  768 x 256 x 64, not causal, ragged k_lengths  transformer-train
+
+For each shape: this repo's kernel with its blocks pinned to 128 x 128 (what
+every shape ran before the plan), the kernel at the blocks _plan_blocks gives
+it, and jax.experimental.pallas.ops.tpu.flash_attention as a yardstick, once
+at its shipped default blocks (all 128) and once at the plan's.  `--parent
+FILE` times another commit's kernel beside them (`git show
+<commit>:paddle_tpu/kernels/flash_attention.py > chip_scratch/...`).  `--sweep`
+also pins every block pair a shape admits, which is how the plan's VMEM share
+was settled.  Prints ms a call and the TFLOP/s of the causal count (the
+(q, k) pairs a causal mask leaves) and of the uncausal one (4 * B * H * Sq *
+Sk * D), and writes the rows to chiprun_out/flash_fwd_probe.json.
+
+A tool, run by no benchmark cell:
+    chiprun --chips 1 -- python3 tools/flash_fwd_probe.py --seed 7 [--sweep]
+    JAX_PLATFORMS=cpu python3 tools/flash_fwd_probe.py --rehearse
+`--rehearse` runs tiny shapes through the Pallas interpreter, skips the
+yardstick (a TPU-only kernel) and exits 3: its times are not the chip's.
+One process holds the chip; it starts no child.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib
+import importlib.util
+import json
+import math
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+SHAPES = {
+    # name: (B, H, S, D, causal, ragged)
+    "ouro": (2, 16, 2048, 128, True, False),
+    "nmt-decoder": (96, 8, 256, 64, True, False),
+    "nmt-encoder": (96, 8, 256, 64, False, True),
+}
+REHEARSAL_SHAPES = {
+    "ouro": (1, 2, 512, 128, True, False),
+    "nmt-decoder": (2, 2, 256, 64, True, False),
+    "nmt-encoder": (2, 2, 256, 64, False, True),
+}
+PEAK_TFLOPS = 197.0  # one v5e, bf16 (Google Cloud documentation, "TPU v5e")
+
+
+def _time_ms(fn, args, calls):
+    """ms a call: `calls` calls enqueued back to back, one wait; the best
+    of three such rounds, after one call that compiles."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = math.inf
+    for _ in range(3):
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best * 1e3
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--calls", type=int, default=50)
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--parent", metavar="FILE", help="kernels/"
+                    "flash_attention.py of another commit (git show), timed "
+                    "as it stands beside this tree's")
+    a = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    parent = None
+    if a.parent:
+        spec = importlib.util.spec_from_file_location(
+            "paddle_tpu.kernels._parent_flash_attention", a.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+    dev = jax.devices()[0]
+    if not a.rehearse and dev.platform != "tpu":
+        print("flash_fwd_probe: no TPU here (use --rehearse on the CPU)",
+              file=sys.stderr)
+        return 2
+    shapes = REHEARSAL_SHAPES if a.rehearse else SHAPES
+    rows = []
+    for name in a.shapes.split(","):
+        B, H, S, D, causal, ragged = shapes[name]
+        rng = np.random.RandomState(a.seed % (2 ** 32))
+        q, k, v = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
+                   for _ in range(3))
+        lengths = (rng.randint(S // 2, S + 1, size=B) if ragged
+                   else np.full(B, S))
+        klen = jnp.asarray(lengths, jnp.float32)
+        scale = 1.0 / math.sqrt(D)
+        uncausal = 4.0 * B * H * S * S * D
+        visible = S * (S + 1) / 2 if causal else float(S * np.mean(lengths))
+        counted = 4.0 * B * H * visible * D
+        plan = fa._plan_blocks(S, S, D, q.dtype, causal, False)
+
+        def ours(bq, bk, need_lse=False):
+            return jax.jit(lambda q, k, v, klen: fa._pallas_flash(
+                q, k, v, klen, causal, scale, block_q=bq, block_k=bk,
+                interpret=a.rehearse, need_lse=need_lse)[0])
+
+        def shipped(bq, bk):
+            from jax.experimental.pallas.ops.tpu import flash_attention as jx
+
+            bs = jx.BlockSizes(block_q=bq, block_k_major=bk, block_k=bk,
+                               block_b=1)
+            kvseg = jnp.where(jnp.arange(S)[None, :] < klen[:, None], 1, 2)
+            seg = jx.SegmentIds(q=jnp.ones((B, S), jnp.int32),
+                                kv=kvseg.astype(jnp.int32))
+            return jax.jit(lambda q, k, v, klen: jx.flash_attention(
+                q, k, v, segment_ids=seg if ragged else None, causal=causal,
+                sm_scale=scale, block_sizes=bs))
+
+        variants = []
+        if parent is not None:
+            variants.append(("parent-128", jax.jit(
+                lambda q, k, v, klen: parent._pallas_flash(
+                    q, k, v, klen, causal, scale, interpret=a.rehearse,
+                    need_lse=False)[0]), (128, 128)))
+        variants += [("pinned-128", ours(128, 128), (128, 128)),
+                    ("plan", ours(*plan), plan),
+                    ("plan+lse", ours(*plan, need_lse=True), plan)]
+        if a.sweep:
+            lens = fa._block_lengths(S)
+            variants += [(f"pinned-{bq}x{bk}", ours(bq, bk), (bq, bk))
+                         for bq in lens for bk in lens
+                         if (bq, bk) not in ((128, 128), plan)]
+        if not a.rehearse:
+            variants += [("jax-shipped-128", shipped(128, 128), (128, 128)),
+                         ("jax-shipped-at-plan", shipped(*plan), plan)]
+        want = np.asarray(fa._reference_attention(
+            q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)
+        ).astype(jnp.float32))
+        for label, fn, (bq, bk) in variants:
+            row = {"shape": name, "bh": B * H, "s": S, "d": D,
+                   "causal": causal, "variant": label, "block_q": bq,
+                   "block_k": bk, "seed": a.seed,
+                   "working_set_mb": round(fa.fwd_working_set_bytes(
+                       bq, bk, D, -(-S // bq), "bfloat16",
+                       label.endswith("lse")) / 2 ** 20, 3)}
+            try:
+                got = np.asarray(fn(q, k, v, klen).astype(jnp.float32))
+                row["max_abs_err"] = float(np.max(np.abs(got - want)))
+                if not a.rehearse:  # an interpreter's time is no one's
+                    ms = _time_ms(fn, (q, k, v, klen), a.calls)
+                    row.update(
+                        ms_a_call=round(ms, 4),
+                        tflops_causal_count=round(counted / ms / 1e9, 2),
+                        tflops_uncausal_count=round(uncausal / ms / 1e9, 2),
+                        share_of_peak=round(
+                            counted / ms / 1e9 / PEAK_TFLOPS, 4))
+            except Exception as e:  # a block pair Mosaic refuses is a row
+                row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+           "rehearsal": bool(a.rehearse), "date": time.strftime(
+               "%Y-%m-%d %H:%M UTC", time.gmtime()), "rows": rows}
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/flash_fwd_probe.json", "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps({k: out[k] for k in ("device", "rehearsal", "date")}))
+    return 3 if a.rehearse else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
