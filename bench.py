@@ -16,13 +16,12 @@ overlap from its double-buffer reader ops
 
 Env knobs: BENCH_BS (resnet bs, default 256), BENCH_TRANSFORMER_BS (default
 16), BENCH_STEPS (default 20), BENCH_MODELS (comma list, default
-"resnet50,transformer"), BENCH_AMP (default "1": bf16 matmul/conv compute;
-"keep" = bf16 activations between matmuls; "0" = fp32), BENCH_FLASH
-(default "1"), BENCH_LAYOUT ("NCHW"/"NHWC" conv internal layout, default
-NCHW; the chip's peak for MFU comes from DEVICE_PEAKS by device_kind),
-BENCH_TUNE (default 1: probe amp-tier x conv-layout combos on a few steps
-per model and pick the fastest for the timed run, recording every probe in
-"tuned"; 0 pins the BENCH_AMP/BENCH_LAYOUT config),
+"resnet50,transformer"), BENCH_AMP ("1": bf16 matmul/conv compute; "keep" =
+bf16 activations between matmuls; "0" = fp32; unset: the policy stays
+unset, which a TPU program resolves to "keep"), BENCH_FLASH (default "1"),
+BENCH_LAYOUT ("NCHW"/"NHWC" conv internal layout; unset: "auto", NHWC for a
+TPU program; the chip's peak for MFU comes from DEVICE_PEAKS by
+device_kind),
 BENCH_DATA=pyreader (feed through the py_reader worker-thread pipeline
 instead of pre-staged device arrays — proves the data stack keeps up),
 BENCH_UNROLL (default 0; K>=2 = run K training steps per device dispatch
@@ -35,9 +34,6 @@ run).  BENCH_COST_ONLY=1: per-model bytes/step table from the TPU
 compiler's own cost model via a chip-less AOT topology compile
 (BENCH_COST_PLATFORM=native for the host executable instead).  Both run
 on a CPU host and mark their rows "chipless".
-BENCH_FUSE_CONV_EPILOGUE=1 turns on the compile-time conv-epilogue fusion
-pass (FLAGS_fuse_conv_epilogue); BENCH_CONV_EPILOGUE=reference|pallas pins
-the fused op's implementation.
 
 The device: TPUPlace(), which raises where jax finds no TPU — there is no
 fallback.  Only JAX_PLATFORMS=cpu makes it CPUPlace(): a declared CPU run
@@ -138,41 +134,25 @@ def _peak_flops(device):
     return DEVICE_PEAKS[device.device_kind]["bf16_flops"]
 
 
-CONV_MODELS = {"resnet50", "lenet", "alexnet", "googlenet", "vgg19",
-               "vgg19_infer", "vgg19_infer_int8", "se_resnext"}
-
-
-def _fuse_bn_mode():
-    """Resolved BENCH_FUSE_BN: False (unfused, default), True
-    (fused_bn_add_act), or "conv" (one-op conv_bn_add_act tier)."""
-    return {"1": True, "conv": "conv"}.get(
-        os.environ.get("BENCH_FUSE_BN", "0"), False)
-
-
-def _apply_config(amp: str, layout: str) -> None:
+def _apply_config(amp, layout) -> None:
+    """BENCH_AMP / BENCH_LAYOUT where given; otherwise the AMP policy stays
+    unset and the layout "auto", which flags.tpu_trace_scope resolves to
+    what the chip measured best (keep-tier bf16, NHWC), as the benchmark's
+    cells rely on."""
     import paddle_tpu as fluid
+    from paddle_tpu.core import amp as amp_policy
 
-    if amp == "0":
+    if amp is None:
+        amp_policy.reset_amp()
+    elif amp == "0":
         fluid.disable_amp()
     else:
         fluid.enable_amp("bfloat16", keep_output=(amp == "keep"))
-    # always (re)set BOTH epilogue flags: probes toggle them via env
-    # overrides and set_flags state persists across run_model calls, so
-    # an unset env must mean "back to this process's bootstrap value",
-    # not "whatever the previous probe left behind"
-    fluid.set_flags({
-        "FLAGS_conv_layout": layout,
-        "FLAGS_fuse_conv_epilogue":
-            os.environ.get("BENCH_FUSE_CONV_EPILOGUE")
-            or os.environ.get("FLAGS_fuse_conv_epilogue", "0"),
-        "FLAGS_conv_epilogue":
-            os.environ.get("BENCH_CONV_EPILOGUE")
-            or os.environ.get("FLAGS_conv_epilogue", "reference"),
-    })
+    fluid.set_flags({"FLAGS_conv_layout": layout or "auto"})
 
 
 def run_model(model: str, steps: int, peak_flops: float,
-              amp: str = "1", layout: str = "NCHW") -> dict:
+              amp=None, layout=None) -> dict:
     """One model's timed steady-state loop.  For a device profile of a
     cell use `python3 benchmark/run.py --workload <cell> --trace 1` and
     open bench_out/trace/<cell> in TensorBoard or Perfetto: the
@@ -187,11 +167,7 @@ def run_model(model: str, steps: int, peak_flops: float,
     if model == "resnet50":
         # r2 on-chip sweep: bs=256 gave 1715.6 img/s vs 1674.7 at bs=128
         bs = int(os.environ.get("BENCH_BS", "256"))
-        # BENCH_FUSE_BN=0 re-measures with the unfused reference-shaped
-        # bn/add/relu chain (A/B for the recompute-tagged fused op)
-        spec = models.resnet_imagenet(
-            depth=50, class_num=1000,
-            fuse_bn=_fuse_bn_mode())
+        spec = models.resnet_imagenet(depth=50, class_num=1000)
         unit = "images/sec"
         items_per_step = bs
         metric = "resnet50_train_images_per_sec_per_chip"
@@ -631,24 +607,8 @@ def run_model(model: str, steps: int, peak_flops: float,
         except Exception as e:  # never lose the timed number to accounting
             result["cost_analysis_error"] = str(e)[:200]
     # feature provenance, so a number is attributable to the config that
-    # produced it (fused BN / fused smoothed CE / flash backward impl)
+    # produced it (fused smoothed CE / flash backward impl)
     feats = {}
-    if model == "resnet50":
-        # record the RESOLVED mode, not the raw env string: an
-        # unrecognized value builds unfused and must be attributed so
-        feats["fuse_bn"] = _fuse_bn_mode()
-        if feats["fuse_bn"] == "conv":
-            feats["conv_epilogue"] = fluid.get_flags(
-                "conv_epilogue")["FLAGS_conv_epilogue"]
-    if model in CONV_MODELS:
-        fce = fluid.get_flags(
-            "fuse_conv_epilogue")["FLAGS_fuse_conv_epilogue"]
-        if fce:
-            # the compile-time fusion pass rewrote conv->bn chains; the
-            # impl that actually ran is FLAGS_conv_epilogue's choice
-            feats["fuse_conv_epilogue"] = True
-            feats["conv_epilogue"] = fluid.get_flags(
-                "conv_epilogue")["FLAGS_conv_epilogue"]
     if model in ("transformer", "transformer_longctx"):
         feats["fuse_smooth_ce"] = cfg.fuse_smooth_ce
         feats["flash_bwd"] = fluid.get_flags("flash_bwd")["FLAGS_flash_bwd"]
@@ -657,133 +617,6 @@ def run_model(model: str, steps: int, peak_flops: float,
         feats["unroll_mode"] = os.environ.get("BENCH_UNROLL_MODE", "scan")
     if feats:
         result["features"] = feats
-    return result
-
-
-def _tune_and_run(model: str, steps: int, peak_flops: float,
-                  state: dict) -> dict:
-    """Measure FIRST, tune second: the full timed run happens immediately
-    on the primary config (keep-tier AMP; NHWC for conv models) and is
-    recorded into `state["results"]` before any probe runs, so a probe
-    that fails or hangs cannot lose the model's number.  Probes for the
-    other amp-tier x conv-layout combos then run within the budget; if one
-    beats the banked number by >3% the timed run re-runs with it and the
-    recorded result is replaced in place.  Every probe is recorded in the
-    artifact's "tuned" field (VERDICT r2 task 1)."""
-    import contextlib
-
-    @contextlib.contextmanager
-    def _env(overrides):
-        saved = {k: os.environ.get(k) for k in overrides}
-        os.environ.update(overrides)
-        try:
-            yield
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-
-    def _probe_name(amp, layout, env_over):
-        extra = "".join(f",{k}={v}" for k, v in sorted(env_over.items()))
-        return f"amp={amp},layout={layout}{extra}"
-
-    # keep-tier AMP + NHWC won every conv-model probe of an earlier
-    # round's v5e run (not re-measured), so the first timed number uses
-    # that shape — including BENCH_FUSE_BN=0 for resnet50 (the fused-BN
-    # op is numerics-identical but chip-unmeasured; it rides as a tuner
-    # candidate below and wins the timed slot only by measuring faster)
-    primary = ("keep", "NHWC") if model in CONV_MODELS else ("keep", "NCHW")
-    prim_env = {}
-    if model == "resnet50" and "BENCH_FUSE_BN" not in os.environ:
-        prim_env = {"BENCH_FUSE_BN": "0"}
-    probe_steps = int(os.environ.get("BENCH_TUNE_STEPS", "5"))
-    with _env(prim_env):
-        result = run_model(model, steps, peak_flops, amp=primary[0],
-                           layout=primary[1])
-    probes = {_probe_name(primary[0], primary[1], prim_env) + " (timed)":
-              result["value"]}
-    result["tuned"] = {
-        "probes": dict(probes),
-        "picked": _probe_name(primary[0], primary[1], prim_env),
-        "probe_steps": probe_steps,
-    }
-
-    def bank(r):
-        # the watchdog json.dumps's state["results"] concurrently: bank
-        # an isolated deep copy and only ever REPLACE the slot (atomic
-        # item assignment), never mutate a banked dict in place
-        return json.loads(json.dumps(r))
-
-    state["results"].append(bank(result))
-    slot = len(state["results"]) - 1
-
-    if model == "resnet50" and "BENCH_FUSE_BN" not in os.environ:
-        # the fused-BN candidate probes FIRST: it is the round's headline
-        # hypothesis and must be measured before lower-priority combos
-        # every resnet50 combo pins BENCH_FUSE_BN explicitly (ADVICE r4:
-        # an empty env here would silently default to fused while the
-        # probe name omitted it, misattributing which config produced
-        # the number); non-fused combos match the primary's unfused shape
-        combos = [("keep", "NHWC", {"BENCH_FUSE_BN": "1"}),
-                  # the one-op conv_bn_add_act tier (reference impl —
-                  # plain XLA; the pallas impl is the next combo)
-                  ("keep", "NHWC", {"BENCH_FUSE_BN": "conv"}),
-                  # the compile-time fusion pass + pallas conv-epilogue
-                  # kernels (FLAGS_fuse_conv_epilogue): the unfused
-                  # reference-shaped program, fused at lowering time
-                  ("keep", "NHWC", {"BENCH_FUSE_BN": "0",
-                                    "BENCH_FUSE_CONV_EPILOGUE": "1",
-                                    "BENCH_CONV_EPILOGUE": "pallas"}),
-                  ("keep", "NCHW", {"BENCH_FUSE_BN": "0"}),
-                  ("1", "NHWC", {"BENCH_FUSE_BN": "0"}),
-                  ("1", "NCHW", {"BENCH_FUSE_BN": "0"})]
-    elif model in CONV_MODELS:
-        combos = [("keep", "NCHW", {}), ("1", "NHWC", {}), ("1", "NCHW", {})]
-    else:
-        combos = [("1", "NCHW", {})]
-    budget = float(os.environ.get("BENCH_TUNE_BUDGET_S", "600"))
-    t0 = time.perf_counter()
-    # probe the primary too (executor cache makes this nearly free) so the
-    # rerun decision compares probe-to-probe, not a 5-step probe against
-    # the full-length run's throughput.  BENCH_CKPT_DIR="" keeps the
-    # resumable-run cadence out of every short probe: only full timed
-    # runs bank/restore checkpoints, so probe configs never
-    # cross-pollinate params through the checkpoint dir
-    with _env({**prim_env, "BENCH_CKPT_DIR": ""}):
-        r0 = run_model(model, probe_steps, peak_flops, amp=primary[0],
-                       layout=primary[1])
-    probes[_probe_name(primary[0], primary[1], prim_env)] = r0["value"]
-    best, best_v = (primary[0], primary[1], prim_env), r0["value"]
-    for amp, layout, env_over in combos:
-        if time.perf_counter() - t0 > budget:
-            probes["(budget_exhausted)"] = round(
-                time.perf_counter() - t0, 1)
-            break
-        with _env({**env_over, "BENCH_CKPT_DIR": ""}):
-            r = run_model(model, probe_steps, peak_flops, amp=amp,
-                          layout=layout)
-        probes[_probe_name(amp, layout, env_over)] = r["value"]
-        if r["value"] > best_v:
-            best, best_v = (amp, layout, env_over), r["value"]
-    result["tuned"]["probes"] = dict(probes)
-    state["results"][slot] = bank(result)
-    if best != (primary[0], primary[1], prim_env) and best_v > r0["value"] * 1.03:
-        with _env(best[2]):
-            rerun = run_model(model, steps, peak_flops, amp=best[0],
-                              layout=best[1])
-        if rerun["value"] > result["value"]:
-            rerun["tuned"] = dict(
-                result["tuned"],
-                picked=_probe_name(best[0], best[1], best[2]),
-            )
-            result = rerun
-        else:
-            probes[_probe_name(best[0], best[1], best[2])
-                   + " (timed, slower)"] = rerun["value"]
-            result["tuned"]["probes"] = dict(probes)
-        state["results"][slot] = bank(result)
     return result
 
 
@@ -891,8 +724,8 @@ def _arm_deadline(state: dict) -> None:
 
 def main() -> None:
     if os.environ.get("BENCH_COMPILE_CACHE", "1") != "0":
-        # persistent executable cache: tune probes, the final timed run and
-        # repeated invocations share compiles across processes
+        # persistent executable cache: repeated invocations share
+        # compiles across processes
         from paddle_tpu.core.compiler import default_compile_cache
 
         sys.stderr.write(f"# compile cache: {default_compile_cache()}\n")
@@ -908,15 +741,8 @@ def main() -> None:
     if not names:
         raise SystemExit("BENCH_MODELS is empty")
 
-    amp = os.environ.get("BENCH_AMP", "1")
-    layout = os.environ.get("BENCH_LAYOUT", "NCHW")
-    # default ON: the r2 verdict's open question (does the keep-tier AMP /
-    # NHWC layout win on-chip?) answers itself in every bench run, with
-    # all probes recorded in the artifact.  An explicit BENCH_AMP /
-    # BENCH_LAYOUT pins that config instead (no silent override);
-    # BENCH_TUNE=1/0 always wins when set.
-    pinned = "BENCH_AMP" in os.environ or "BENCH_LAYOUT" in os.environ
-    tune = os.environ.get("BENCH_TUNE", "0" if pinned else "1") == "1"
+    amp = os.environ.get("BENCH_AMP")
+    layout = os.environ.get("BENCH_LAYOUT")
     import threading
 
     state = {"results": [], "model_errors": [], "printed": False,
@@ -936,30 +762,20 @@ def main() -> None:
         # the device first: TPUPlace raises here where there is no chip
         peak_flops = _peak_flops(_bench_place().jax_device())
         for m in names:
-            n_before = len(state["results"])
             try:
                 with _span("bench.model", model=m):
-                    if tune:
-                        _tune_and_run(m, steps, peak_flops, state)
-                    else:
-                        state["results"].append(
-                            run_model(m, steps, peak_flops, amp=amp,
-                                      layout=layout))
+                    state["results"].append(
+                        run_model(m, steps, peak_flops, amp=amp,
+                                  layout=layout))
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:  # noqa: BLE001 — one model's failure
                 # (e.g. a kernel lowering error) does not abort the other
                 # models' measurements; it does fail the run (exit code)
-                rec = {
+                model_errors.append({
                     "model": m, "error": type(e).__name__,
                     "detail": str(e)[:800],
-                }
-                if len(state["results"]) > n_before:
-                    # tune mode banks the timed number BEFORE later
-                    # probes: the measurement stands, the error is
-                    # post-measurement bookkeeping, not a failed model
-                    rec["post_measurement"] = True
-                model_errors.append(rec)
+                })
         results = state["results"]
         if not results:
             raise RuntimeError(

@@ -46,28 +46,37 @@ def _broadcast_lse_operand() -> ProgramArtifacts:
 
 
 def _conv_relayout_sandwich() -> ProgramArtifacts:
-    """The ROADMAP 'layout tax': an unfused conv feeding the pallas
-    conv-epilogue custom call and another conv consuming it.  XLA prefers
-    {3,0,2,1} for conv activations while the custom call pins row-major,
-    so the compiled module brackets the call with relayout copies."""
-    from ..kernels.conv_epilogue import conv_bn_act
+    """The ROADMAP 'layout tax': a conv feeding a pallas custom call on
+    the [N, H, H, C] activation and another conv consuming it.  XLA
+    prefers {3,0,2,1} for conv activations while the custom call pins
+    row-major, so the compiled module brackets the call with relayout
+    copies."""
+    import jax.experimental.pallas as pl
 
     N, H, C = 2, 56, 64
 
-    def fn(x, w0, w, g, b, w2):
+    def _scale_kernel(x_ref, g_ref, o_ref):
+        o_ref[...] = x_ref[...] * g_ref[...]
+
+    def fn(x, w0, g, w2):
         h = jax.lax.conv_general_dilated(
             x, w0, (1, 1), [(1, 1), (1, 1)],
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        h, _, _ = conv_bn_act(h, w, g, b)
+        h = pl.pallas_call(
+            _scale_kernel, grid=(N,),
+            in_specs=[pl.BlockSpec((1, H, H, C), lambda n: (n, 0, 0, 0)),
+                      pl.BlockSpec((1, 1, 1, C), lambda n: (0, 0, 0, 0))],
+            out_specs=pl.BlockSpec((1, H, H, C), lambda n: (n, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
+        )(h, g.reshape(1, 1, 1, C))
         return jax.lax.conv_general_dilated(
             h, w2, (1, 1), [(1, 1), (1, 1)],
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
     wsd = jax.ShapeDtypeStruct((3, 3, C, C), jnp.float32)
-    gsd = jax.ShapeDtypeStruct((C,), jnp.float32)
     return capture_fn(
         fn, jax.ShapeDtypeStruct((N, H, H, C), jnp.float32),
-        wsd, wsd, gsd, gsd, wsd,
+        wsd, jax.ShapeDtypeStruct((C,), jnp.float32), wsd,
         name="corpus_relayout_sandwich")
 
 

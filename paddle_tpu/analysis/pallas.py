@@ -23,8 +23,8 @@ the way the chip allocates them:
 
 ``detect_vmem_overflow`` flags any program whose kernel invocation
 exceeds the configurable v5e budget (``FLAGS_analysis_vmem_budget``,
-default the full 16 MiB/core — kernels/conv_epilogue.py plans its own
-tiles against the stricter 3/4 share to leave the compiler headroom).
+default the full 16 MiB/core — kernels/flash_attention.py plans its own
+blocks against the stricter 3/4 share to leave the compiler headroom).
 """
 
 from __future__ import annotations

@@ -6,8 +6,8 @@ the banked full-scale artifacts measured, in ~2 min total on a CPU
 host):
 
   resnet50_train     full ResNet-50 train step (Momentum), bs=2, 64x64
-                     — the conv/BN pillar (AOT_COST_AB.json's program at
-                     bench scale)
+                     — the conv/BN pillar (the conv -> batch_norm -> relu
+                     chain at bench scale)
   transformer_train  2-layer flash-attention transformer train step
                      (Adam, fused qkv), bs=4, S=32 — the attention
                      pillar, pallas custom calls included
@@ -80,7 +80,7 @@ host):
                      in scope for collective-placement
 
 Baselines live in AOT_COST_ZOO.json: per-program finding counts by
-detector plus AOT bytes/step + flops/step (extending AOT_COST_AB /
+detector plus AOT bytes/step + flops/step (extending
 AOT_COST_PAGED into one gated table).  ``gate()`` fails on any new
 finding (count above baseline, or a program with no banked entry) and on
 a bytes/step regression past tolerance — the per-PR perf-regression CI
@@ -725,7 +725,7 @@ def default_baseline_path() -> str:
 def bank(results: List[ZooResult], path: str,
          tolerance: float = DEFAULT_TOLERANCE) -> dict:
     """Write the zoo baseline artifact (the banked counterpart of
-    AOT_COST_AB/AOT_COST_PAGED, now one gated table).  Refuses results
+    AOT_COST_PAGED, now one gated table).  Refuses results
     whose AOT compile failed: banking bytes_per_step=0 would make every
     later healthy run look like a regression (and the broken one pass)."""
     broken = [r.name for r in results if r.artifacts.compile_error]

@@ -493,33 +493,6 @@ class CompiledBlock:
         _maybe_enable_compile_cache()
         block = self.block
         ops = list(block.desc.ops)
-        # FLAGS_fuse_conv_epilogue lowering pass: rewrite private
-        # conv2d -> batch_norm [-> add] [-> relu] chains (and their grad
-        # windows) onto the one-op conv_bn_add_act tier.  Compile-time
-        # only — the ProgramDesc is untouched; the executor cache keys on
-        # flags.trace_key(), so flipping the flag recompiles.  No match
-        # leaves `ops` as the identical list (byte-identical lowering).
-        self.fused_conv_epilogue = 0
-        from .. import flags as _flags
-
-        if _flags.flag("fuse_conv_epilogue") and block_idx == 0:
-            from .fusion import fuse_conv_epilogue_ops
-
-            # fetches must survive, and so must anything a control-flow
-            # sub-block reads from the outer scope by name (closure
-            # semantics: those reads don't appear in block-0 op inputs)
-            protected = set(self.fetch_names)
-            for sub in program.desc.blocks[1:]:
-                for sop in sub.ops:
-                    protected.update(sop.input_arg_names())
-            with _obs_span("compile.fuse_conv_epilogue"):
-                fused = fuse_conv_epilogue_ops(
-                    ops, block.desc.vars, protected=protected)
-            if fused is not ops:
-                self.fused_conv_epilogue = sum(
-                    1 for op in fused if op.type == "conv_bn_add_act"
-                    and op.attrs.get("__fused_from__"))
-                ops = fused
         need_vjps = collect_needed_vjps(ops)
 
         def fn(feed_vals, state_vals, key):

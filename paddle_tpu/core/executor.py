@@ -207,9 +207,7 @@ def cached_entry(cache, key, fp, build, use_cache: bool = True):
     if flags.flag("FLAGS_observability"):
         _obs.record_compile_cache(hit=hit)
         if not hit and sp.seconds is not None:  # on since before the build
-            _obs.record_compile(
-                sp.seconds, fused_regions=getattr(
-                    entry[1], "fused_conv_epilogue", 0))
+            _obs.record_compile(sp.seconds)
     return entry, hit
 
 
@@ -555,8 +553,8 @@ class Executor:
     def _maybe_record_cost(entry, feed_vals, state_vals, rng) -> None:
         """FLAGS_observability_cost: once per fresh compiled entry,
         record the XLA cost model's bytes/flops per step labeled by
-        program fingerprint + fused-region count — flag-flip A/Bs (e.g.
-        the conv-epilogue pass) land on separate series with no chip."""
+        program fingerprint, so a flag flip that recompiles lands on a
+        separate series with no chip."""
         fp, compiled, _ = entry
         mode = flags.flag("observability_cost")
         if mode == "off" or getattr(compiled, "_obs_cost_done", False):
@@ -566,9 +564,7 @@ class Executor:
             ca = compiled.cost_analysis(
                 feed_vals, state_vals, rng,
                 platform="tpu" if mode == "tpu" else None)
-            _obs.record_cost(
-                ca, program=fp.hex()[:12],
-                fused_regions=compiled.fused_conv_epilogue, platform=mode)
+            _obs.record_cost(ca, program=fp.hex()[:12], platform=mode)
         except Exception as e:  # costing must never fail the step
             import logging
 

@@ -55,8 +55,8 @@ _DEFS: Dict[str, Any] = {
     # compiled entry when observability is on: "native" prices the
     # executable the host actually runs (cheap — the re-lower hits jax's
     # compile cache), "tpu" prices the CHIP program via the chip-less
-    # AOT topology tier (core/aot_tpu.py — minutes for big models, the
-    # chip-less conv-epilogue measurement loop), "off" skips costing
+    # AOT topology tier (core/aot_tpu.py — minutes for big models),
+    # "off" skips costing
     "FLAGS_observability_cost": "off",
     # request-scoped tracing (observability/requesttrace.py): hard
     # per-run cap on how many requests keep FULL span detail in the
@@ -98,23 +98,6 @@ _DEFS: Dict[str, Any] = {
     # compares).  Default jax: the three are not yet compared on a chip
     # (ROADMAP D7)
     "FLAGS_flash_bwd": "jax",
-    # conv_bn_add_act implementation: "reference" (XLA conv + BN chain —
-    # one op, XLA fuses the epilogue; the parity-safe default) or
-    # "pallas" (kernels/conv_epilogue.py: BN stats accumulate inside the
-    # conv pass, normalize/residual/act in one epilogue pass — ~4-5
-    # activation passes down to 3).  Pallas stays opt-in until the
-    # staged probe (tools/conv_epilogue_probe.py) banks a winning
-    # on-chip A/B: defaults follow measurements
-    "FLAGS_conv_epilogue": "reference",
-    # compile-time fusion pass (core/fusion.py): pattern-match
-    # conv2d -> batch_norm [-> elementwise_add] -> relu chains in block 0
-    # and route them through the one-op conv_bn_add_act tier at lowering
-    # time — the program desc itself is untouched.  The op that runs is
-    # then picked by FLAGS_conv_epilogue (reference composition vs the
-    # pallas conv-epilogue kernel pair).  Default off until a chip A/B
-    # banks a win (defaults follow measurements); the bytes/step win is
-    # CPU-verifiable via Executor.cost_analysis (tests/test_conv_fusion_pass.py)
-    "FLAGS_fuse_conv_epilogue": False,
     # serving (paddle_tpu/serving/): the dynamic batcher's batch-size
     # bucket ladder.  Queued requests coalesce into micro-batches padded
     # UP to the smallest bucket that fits, so a polymorphic-batch AOT
@@ -231,7 +214,6 @@ def get_flags(names=None) -> Dict[str, Any]:
 _CHOICES: Dict[str, tuple] = {
     "FLAGS_conv_layout": ("auto", "NCHW", "NHWC"),
     "FLAGS_flash_bwd": ("jax", "pallas", "jaxlib"),
-    "FLAGS_conv_epilogue": ("reference", "pallas"),
     "FLAGS_observability_cost": ("off", "native", "tpu"),
     "FLAGS_serving_paged_impl": ("auto", "reference", "pallas", "interpret"),
 }
@@ -303,8 +285,6 @@ def trace_key() -> tuple:
     cache keys so a flag flip between runs recompiles instead of reusing
     a stale executable."""
     return (conv_layout(), _VALUES["FLAGS_flash_bwd"],
-            _VALUES["FLAGS_conv_epilogue"],
-            _VALUES["FLAGS_fuse_conv_epilogue"],
             # not trace-affecting, but executable-affecting: the sentinel
             # turns state-buffer donation off, so a flag flip must land on
             # a different compiled entry instead of reusing one whose

@@ -71,8 +71,8 @@ __all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes"]
 NEG_INF = -1e30
 
 # What the forward's planned working set (fwd_working_set_bytes) may take:
-# 3/4 of the v5e's 16 MiB of scoped VMEM, the share conv_epilogue plans its
-# tiles against.  Settled on the chip (tools/flash_fwd_probe.py --sweep,
+# 3/4 of the v5e's 16 MiB of scoped VMEM, which leaves the compiler its
+# headroom.  Settled on the chip (tools/flash_fwd_probe.py --sweep,
 # PERF.md PR 28): at 32 x 2048 x 128 causal the time falls with the steps
 # all the way to 1024 x 1024 (11.5 MB by this count, 0.43 ms against 4.58 at
 # 128 x 128); the count is cautious, Mosaic still compiles 20.5 MB of it and

@@ -52,7 +52,7 @@ one VPU multiply per streamed block and HBM still only ever sees the
 1-byte elements — KV bytes halve again vs bf16.  The reference gather
 dequantizes the same way (``gather_kv_pages(..., scales=)``).
 
-Selection (the kernels/conv_epilogue.py precedent — measured Mosaic
+Selection (a measured Mosaic
 envelope, explicit fallback, flag-driven): ``FLAGS_serving_paged_impl``
 (auto|reference|pallas|interpret) supplies the default; ``auto`` picks
 pallas on TPU when ``pallas_paged_viable`` accepts the pool geometry
@@ -61,7 +61,7 @@ envelope falls back to reference with a one-time log, never a Mosaic
 compile bomb.  The envelope: head_dim a lane multiple (128) and
 page_size a sublane multiple (8 fp32 / 16 bf16 / 32 int8), so every
 K/V page block is natively (sublane, lane)-tiled — the constraint
-class that produced the flash residual-layout and conv-epilogue
+class that produced the flash residual-layout and unaligned-window
 'non-native tiling' chip failures.
 
 Pool layout is KERNEL-NATIVE by default: [H_kv, P, page_size, D] per
@@ -328,7 +328,7 @@ def resolve_paged_impl(impl, page_size: int, head_dim: int,
     the one that will actually run: 'auto' takes pallas on TPU inside
     the envelope and reference otherwise; an explicit 'pallas' outside
     the envelope falls back to 'reference' with a one-time log (the
-    conv-epilogue fallback contract — never a Mosaic compile failure)."""
+    fallback contract: never a Mosaic compile failure)."""
     global _fallback_noted
     if impl is None:
         from .. import flags

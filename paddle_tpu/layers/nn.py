@@ -33,7 +33,6 @@ __all__ = [
     "pool3d",
     "batch_norm",
     "fused_bn_add_act",
-    "conv_bn_add_act",
     "layer_norm",
     "rms_norm",
     "group_norm",
@@ -373,17 +372,20 @@ def pool3d(input, pool_size=-1, pool_type="max", pool_stride=1, pool_padding=0,
     return out
 
 
-def _bn_state(helper, c, dtype, param_attr, bias_attr, moving_mean_name,
+def _bn_build(helper, input, data_layout, moving_mean_name,
               moving_variance_name):
     """The ONE copy of BN parameter/state creation (scale, bias, moving
     mean/variance with initializers, saved stats, output var) shared by
-    batch_norm, fused_bn_add_act, and conv_bn_add_act."""
+    batch_norm and its fused twin: returns (inputs dict, outputs dict,
+    out var)."""
+    dtype = input.dtype
+    c = input.shape[1] if data_layout == "NCHW" else input.shape[-1]
     scale = helper.create_parameter(
-        param_attr or ParamAttr(),
+        helper.param_attr or ParamAttr(),
         shape=[c], dtype=dtype, default_initializer=ConstantInitializer(1.0),
     )
     bias = helper.create_parameter(
-        bias_attr or ParamAttr(),
+        helper.bias_attr or ParamAttr(),
         shape=[c], dtype=dtype, is_bias=True,
     )
     from ..core.framework import unique_name
@@ -404,18 +406,6 @@ def _bn_state(helper, c, dtype, param_attr, bias_attr, moving_mean_name,
     saved_var = helper.create_variable_for_type_inference(
         dtype, stop_gradient=True)
     out = helper.create_variable_for_type_inference(dtype)
-    return scale, bias, mean, variance, saved_mean, saved_var, out
-
-
-def _bn_build(helper, input, data_layout, moving_mean_name,
-              moving_variance_name):
-    """Shared scale/bias/moving-stat setup for batch_norm and its fused
-    twin: returns (inputs dict, outputs dict, out var)."""
-    dtype = input.dtype
-    c = input.shape[1] if data_layout == "NCHW" else input.shape[-1]
-    scale, bias, mean, variance, saved_mean, saved_var, out = _bn_state(
-        helper, c, dtype, helper.param_attr, helper.bias_attr,
-        moving_mean_name, moving_variance_name)
     inputs = {
         "X": [input], "Scale": [scale], "Bias": [bias],
         "Mean": [mean], "Variance": [variance],
@@ -479,70 +469,6 @@ def fused_bn_add_act(x, y=None, act="relu", is_test=False, momentum=0.9,
             "momentum": momentum, "epsilon": epsilon, "is_test": is_test,
             "data_layout": data_layout, "use_global_stats": use_global_stats,
             "act": act, "@recompute@": True,
-        },
-    )
-    return out
-
-
-def conv_bn_add_act(input, num_filters, filter_size, residual=None,
-                    stride=1, padding=0, groups=1, act="relu",
-                    is_test=False, momentum=0.9, epsilon=1e-5,
-                    param_attr=None, bn_param_attr=None,
-                    bn_bias_attr=None, moving_mean_name=None,
-                    moving_variance_name=None, name=None):
-    """conv2d (no bias) + batch_norm + residual + activation as ONE op —
-    the whole ResNet block tail including the conv (reference
-    counterpart: operators/conv_fusion_op.cu.cc).  Where
-    fused_bn_add_act fuses everything AFTER the conv, this op also owns
-    the conv so the pallas implementation (FLAGS_conv_epilogue=pallas)
-    can accumulate BN statistics inside the conv pass — the extra
-    full-tensor stats read over the conv output disappears.  The default
-    implementation ("reference") composes the same XLA conv + BN math in
-    one lowering: numerics match the conv2d -> batch_norm -> add -> act
-    chain exactly (parity-tested).  NCHW contract, square
-    stride/padding."""
-    helper = LayerHelper("conv_bn_add_act", input=input,
-                         param_attr=param_attr, act=None, name=name)
-    dtype = input.dtype
-    num_channels = input.shape[1]
-    fsize = _pair(filter_size)
-    if fsize[0] != fsize[1]:
-        raise ValueError("conv_bn_add_act needs a square filter")
-    if _pair(stride)[0] != _pair(stride)[1] or \
-            _pair(padding)[0] != _pair(padding)[1]:
-        # fail at model-definition time, not first exe.run (the lowering
-        # would raise the same constraint much later)
-        raise NotImplementedError(
-            "conv_bn_add_act needs square stride/padding "
-            f"(got stride={stride}, padding={padding})")
-    filter_shape = [num_filters, num_channels // groups] + fsize
-    fan_in = (num_channels // groups) * fsize[0] * fsize[1]
-    w = helper.create_parameter(
-        helper.param_attr, shape=filter_shape, dtype=dtype,
-        default_initializer=NormalInitializer(0.0, (2.0 / fan_in) ** 0.5),
-    )
-    scale, bias, mean, variance, saved_mean, saved_var, out = _bn_state(
-        helper, num_filters, dtype, bn_param_attr, bn_bias_attr,
-        moving_mean_name, moving_variance_name)
-
-    inputs = {"X": [input], "Filter": [w], "Scale": [scale], "Bias": [bias],
-              "Mean": [mean], "Variance": [variance]}
-    if residual is not None:
-        inputs["Z"] = [residual]
-    helper.append_op(
-        type="conv_bn_add_act",
-        inputs=inputs,
-        outputs={"Y": [out], "MeanOut": [mean], "VarianceOut": [variance],
-                 "SavedMean": [saved_mean], "SavedVariance": [saved_var]},
-        attrs={
-            "strides": _pair(stride), "paddings": _pair(padding),
-            "groups": groups,
-            "momentum": momentum, "epsilon": epsilon, "is_test": is_test,
-            "act": act,
-            # NO @recompute@ tag: the pallas impl's custom_vjp already
-            # recomputes in backward, and the reference impl checkpoints
-            # INSIDE the lowering — a compiler-level wrap here would
-            # re-run the forward kernels a second time (review r5)
         },
     )
     return out

@@ -14,83 +14,54 @@ from .. import layers
 from .common import ModelSpec, class_batch
 
 
-def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu",
-                  fuse_bn=False):
-    """conv -> BN(+act).  fuse_bn=True emits the recompute-tagged
-    fused_bn_add_act op: same numbers, but backward rebuilds the normalize/
-    act chain instead of storing it — the HBM-traffic fix for the profile's
-    72% elementwise share (an earlier round's v5e run, not re-measured).
-    The DEFAULT is False — defaults follow measurements: that run used
-    the unfused chain, and an instruction count flags ~3x transposes on
-    the fused path; the default flips to True the day a chip A/B
-    measures the fused op faster.  fuse_bn=False
-    also keeps the separate reference-shaped batch_norm op (transpilers
-    that pattern-match conv+BN, e.g. the inference fold, want that
-    shape)."""
-    if fuse_bn == "conv":
-        # whole-block one-op tier: the conv itself joins the fusion so
-        # FLAGS_conv_epilogue=pallas can accumulate BN stats inside the
-        # conv pass (kernels/conv_epilogue.py)
-        return layers.conv_bn_add_act(
-            input, ch_out, filter_size, stride=stride, padding=padding,
-            act=act)
+def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu"):
+    """conv2d -> batch_norm(+act): the one formulation of the block.  The
+    fused one-op forms lost their chip A/B to this chain by 17-49%
+    (PERF.md 6, PR 29); the separate reference-shaped batch_norm op is
+    also what the inference transpiler's conv+BN fold pattern-matches."""
     conv = layers.conv2d(
         input=input, num_filters=ch_out, filter_size=filter_size,
         stride=stride, padding=padding, act=None, bias_attr=False,
     )
-    if fuse_bn:
-        return layers.fused_bn_add_act(conv, act=act)
     return layers.batch_norm(input=conv, act=act)
 
 
-def _shortcut(input, ch_out, stride, fuse_bn=False):
+def _shortcut(input, ch_out, stride):
     ch_in = input.shape[1]
     if ch_in != ch_out or stride != 1:
-        return conv_bn_layer(input, ch_out, 1, stride, 0, act=None,
-                             fuse_bn=fuse_bn)
+        return conv_bn_layer(input, ch_out, 1, stride, 0, act=None)
     return input
 
 
-def basicblock(input, ch_out, stride, fuse_bn=False):
-    s = _shortcut(input, ch_out, stride, fuse_bn=fuse_bn)
-    conv1 = conv_bn_layer(input, ch_out, 3, stride, 1, fuse_bn=fuse_bn)
-    if fuse_bn == "conv":
-        return layers.conv_bn_add_act(conv1, ch_out, 3, residual=s,
-                                      stride=1, padding=1, act="relu")
+def basicblock(input, ch_out, stride):
+    s = _shortcut(input, ch_out, stride)
+    conv1 = conv_bn_layer(input, ch_out, 3, stride, 1)
     conv2 = layers.conv2d(conv1, num_filters=ch_out, filter_size=3,
                           stride=1, padding=1, act=None, bias_attr=False)
-    if fuse_bn:
-        # BN + residual + relu in ONE recompute-tagged op
-        return layers.fused_bn_add_act(conv2, s, act="relu")
     bn2 = layers.batch_norm(input=conv2, act=None)
     return layers.elementwise_add(s, bn2, act="relu")
 
 
-def bottleneck(input, ch_out, stride, fuse_bn=False):
-    s = _shortcut(input, ch_out * 4, stride, fuse_bn=fuse_bn)
-    conv1 = conv_bn_layer(input, ch_out, 1, 1, 0, fuse_bn=fuse_bn)
-    conv2 = conv_bn_layer(conv1, ch_out, 3, stride, 1, fuse_bn=fuse_bn)
-    if fuse_bn == "conv":
-        return layers.conv_bn_add_act(conv2, ch_out * 4, 1, residual=s,
-                                      stride=1, padding=0, act="relu")
+def bottleneck(input, ch_out, stride):
+    s = _shortcut(input, ch_out * 4, stride)
+    conv1 = conv_bn_layer(input, ch_out, 1, 1, 0)
+    conv2 = conv_bn_layer(conv1, ch_out, 3, stride, 1)
     conv3 = layers.conv2d(conv2, num_filters=ch_out * 4, filter_size=1,
                           stride=1, padding=0, act=None, bias_attr=False)
-    if fuse_bn:
-        return layers.fused_bn_add_act(conv3, s, act="relu")
     bn3 = layers.batch_norm(input=conv3, act=None)
     return layers.elementwise_add(s, bn3, act="relu")
 
 
-def _layer_warp(block_func, input, ch_out, count, stride, fuse_bn=False):
-    res = block_func(input, ch_out, stride, fuse_bn=fuse_bn)
+def _layer_warp(block_func, input, ch_out, count, stride):
+    res = block_func(input, ch_out, stride)
     for _ in range(1, count):
-        res = block_func(res, ch_out, 1, fuse_bn=fuse_bn)
+        res = block_func(res, ch_out, 1)
     return res
 
 
 def resnet_imagenet(
     img=None, label=None, depth: int = 50, class_num: int = 1000,
-    img_shape=(3, 224, 224), fuse_bn: bool = False,
+    img_shape=(3, 224, 224),
 ) -> ModelSpec:
     """ImageNet-scale ResNet: 7x7/2 stem + maxpool + 4 bottleneck stages +
     global average pool + FC."""
@@ -108,15 +79,14 @@ def resnet_imagenet(
     }
     stages, block_func = cfg[depth]
 
-    conv1 = conv_bn_layer(img, ch_out=64, filter_size=7, stride=2, padding=3,
-                          fuse_bn=fuse_bn)
+    conv1 = conv_bn_layer(img, ch_out=64, filter_size=7, stride=2, padding=3)
     pool1 = layers.pool2d(
         input=conv1, pool_type="max", pool_size=3, pool_stride=2, pool_padding=1
     )
-    res1 = _layer_warp(block_func, pool1, 64, stages[0], 1, fuse_bn=fuse_bn)
-    res2 = _layer_warp(block_func, res1, 128, stages[1], 2, fuse_bn=fuse_bn)
-    res3 = _layer_warp(block_func, res2, 256, stages[2], 2, fuse_bn=fuse_bn)
-    res4 = _layer_warp(block_func, res3, 512, stages[3], 2, fuse_bn=fuse_bn)
+    res1 = _layer_warp(block_func, pool1, 64, stages[0], 1)
+    res2 = _layer_warp(block_func, res1, 128, stages[1], 2)
+    res3 = _layer_warp(block_func, res2, 256, stages[2], 2)
+    res4 = _layer_warp(block_func, res3, 512, stages[3], 2)
     pool2 = layers.pool2d(
         input=res4, pool_size=7, pool_type="avg", pool_stride=1, global_pooling=True
     )
@@ -142,7 +112,6 @@ def resnet_imagenet(
 
 def resnet_cifar10(
     img=None, label=None, depth: int = 32, class_num: int = 10,
-    fuse_bn: bool = False,
 ) -> ModelSpec:
     """CIFAR-scale ResNet (6n+2 basicblock layout)."""
     if img is None:
@@ -152,11 +121,10 @@ def resnet_cifar10(
     assert (depth - 2) % 6 == 0, "depth must be 6n+2"
     n = (depth - 2) // 6
 
-    conv1 = conv_bn_layer(img, ch_out=16, filter_size=3, stride=1, padding=1,
-                          fuse_bn=fuse_bn)
-    res1 = _layer_warp(basicblock, conv1, 16, n, 1, fuse_bn=fuse_bn)
-    res2 = _layer_warp(basicblock, res1, 32, n, 2, fuse_bn=fuse_bn)
-    res3 = _layer_warp(basicblock, res2, 64, n, 2, fuse_bn=fuse_bn)
+    conv1 = conv_bn_layer(img, ch_out=16, filter_size=3, stride=1, padding=1)
+    res1 = _layer_warp(basicblock, conv1, 16, n, 1)
+    res2 = _layer_warp(basicblock, res1, 32, n, 2)
+    res3 = _layer_warp(basicblock, res2, 64, n, 2)
     pool = layers.pool2d(
         input=res3, pool_size=8, pool_type="avg", pool_stride=1, global_pooling=True
     )

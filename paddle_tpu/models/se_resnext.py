@@ -11,15 +11,7 @@ from .common import ModelSpec, class_batch
 __all__ = ["se_resnext"]
 
 
-def _conv_bn(input, num_filters, filter_size, stride=1, groups=1, act=None,
-             fuse_bn=False):
-    if fuse_bn == "conv":
-        # whole-block one-op tier (models/resnet.py conv_bn_layer); the
-        # grouped cardinality convs take the reference composition
-        # inside the op until a grouped pallas tier exists
-        return layers.conv_bn_add_act(
-            input, num_filters, filter_size, stride=stride,
-            padding=(filter_size - 1) // 2, groups=groups, act=act)
+def _conv_bn(input, num_filters, filter_size, stride=1, groups=1, act=None):
     conv = layers.conv2d(
         input=input,
         num_filters=num_filters,
@@ -29,10 +21,6 @@ def _conv_bn(input, num_filters, filter_size, stride=1, groups=1, act=None,
         groups=groups,
         bias_attr=False,
     )
-    if fuse_bn:
-        # recompute-tagged fused BN(+act): numerics identical to
-        # batch_norm, backward rebuilds the chain (models/resnet.py)
-        return layers.fused_bn_add_act(conv, act=act)
     return layers.batch_norm(input=conv, act=act)
 
 
@@ -49,23 +37,21 @@ def _squeeze_excitation(input, num_channels, reduction_ratio):
     return layers.elementwise_mul(input, exc)
 
 
-def _shortcut(input, ch_out, stride, fuse_bn=False):
+def _shortcut(input, ch_out, stride):
     ch_in = input.shape[1]
     if ch_in != ch_out or stride != 1:
-        return _conv_bn(input, ch_out, 1, stride, fuse_bn=fuse_bn)
+        return _conv_bn(input, ch_out, 1, stride)
     return input
 
 
-def _bottleneck(input, num_filters, stride, cardinality, reduction_ratio,
-                fuse_bn=False):
-    conv0 = _conv_bn(input, num_filters, 1, act="relu", fuse_bn=fuse_bn)
+def _bottleneck(input, num_filters, stride, cardinality, reduction_ratio):
+    conv0 = _conv_bn(input, num_filters, 1, act="relu")
     conv1 = _conv_bn(
-        conv0, num_filters, 3, stride=stride, groups=cardinality, act="relu",
-        fuse_bn=fuse_bn
+        conv0, num_filters, 3, stride=stride, groups=cardinality, act="relu"
     )
-    conv2 = _conv_bn(conv1, num_filters * 2, 1, fuse_bn=fuse_bn)
+    conv2 = _conv_bn(conv1, num_filters * 2, 1)
     scaled = _squeeze_excitation(conv2, num_filters * 2, reduction_ratio)
-    short = _shortcut(input, num_filters * 2, stride, fuse_bn=fuse_bn)
+    short = _shortcut(input, num_filters * 2, stride)
     return layers.relu(layers.elementwise_add(short, scaled))
 
 
@@ -75,12 +61,11 @@ def se_resnext(
     cardinality: int = 32,
     reduction_ratio: int = 16,
     img_shape=(3, 224, 224),
-    fuse_bn: bool = False,
 ) -> ModelSpec:
     img = layers.data("image", list(img_shape), dtype="float32")
     label = layers.data("label", [1], dtype="int64")
 
-    conv = _conv_bn(img, 64, 7, stride=2, act="relu", fuse_bn=fuse_bn)
+    conv = _conv_bn(img, 64, 7, stride=2, act="relu")
     conv = layers.pool2d(
         input=conv, pool_size=3, pool_stride=2, pool_padding=1,
         pool_type="max",
@@ -94,7 +79,6 @@ def se_resnext(
                 stride=2 if i == 0 and block != 0 else 1,
                 cardinality=cardinality,
                 reduction_ratio=reduction_ratio,
-                fuse_bn=fuse_bn,
             )
     pool = layers.pool2d(input=conv, pool_type="avg", global_pooling=True)
     drop = layers.dropout(pool, dropout_prob=0.2)

@@ -41,8 +41,7 @@ nothing appended, nothing that outlives the with-block (tier-1 asserts this
 of the executor's disabled path).  `FLAGS_observability_cost=native|tpu`
 additionally records each compiled program's bytes/step from XLA's cost
 model (the `tpu` mode prices the CHIP program via the chip-less AOT
-tier, core/aot_tpu.py — the conv-epilogue layout-tax measurement loop
-with no chip).
+tier, core/aot_tpu.py — a bytes/step measurement loop with no chip).
 
 Artifacts: `export_run(dirname)` writes `metrics.prom`, `metrics.json`,
 `trace.json` (Perfetto-loadable) and `report.json` (step-time summary +
@@ -194,7 +193,7 @@ def record_compile_cache(hit: bool) -> None:
     ).inc(result="hit" if hit else "miss")
 
 
-def record_compile(seconds: float, fused_regions: int = 0) -> None:
+def record_compile(seconds: float) -> None:
     """One CompiledBlock build (trace-time lowering setup; the XLA
     compile itself lands in the first step's wall time)."""
     reg = default_registry()
@@ -202,23 +201,15 @@ def record_compile(seconds: float, fused_regions: int = 0) -> None:
         "paddle_tpu_compile_seconds",
         "CompiledBlock construction (lowering setup) wall time",
     ).observe(seconds)
-    if fused_regions:
-        reg.gauge(
-            "paddle_tpu_fused_conv_epilogue_regions",
-            "conv->bn[->add][->act] chains fused by the lowering pass "
-            "in the most recent compile",
-        ).set(fused_regions)
 
 
-def record_cost(cost: dict, program: str, fused_regions: int = 0,
+def record_cost(cost: dict, program: str,
                 platform: str = "native") -> None:
     """XLA cost-model attribution for one compiled program: bytes/step
-    and flops/step, labeled by program fingerprint + fused-region count
-    so flag flips (e.g. FLAGS_fuse_conv_epilogue) land on separate series
-    — the chip-free A/B loop for the conv-epilogue layout tax."""
+    and flops/step, labeled by program fingerprint so a flag flip that
+    recompiles lands on a separate series: a chip-free A/B loop."""
     reg = default_registry()
-    labels = {"program": program, "fused_regions": str(fused_regions),
-              "platform": platform}
+    labels = {"program": program, "platform": platform}
     b = cost.get("bytes accessed")
     if b is not None:
         reg.gauge(

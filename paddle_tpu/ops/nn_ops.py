@@ -470,168 +470,6 @@ def _fused_bn_add_act(ctx, ins, attrs):
     return outs
 
 
-def _conv_bn_add_act_infer(op, block):
-    x = in_desc(op, block, "X")
-    f = in_desc(op, block, "Filter")
-    if x is None or f is None:
-        return
-    strides = op.attr("strides", [1, 1])
-    paddings = op.attr("paddings", [0, 0])
-    n, _, h, w = x.shape
-    oc = f.shape[0]
-    kh, kw = f.shape[2], f.shape[3]
-    ho = _conv_out_dim(h, kh, paddings[0], strides[0], 1)
-    wo = _conv_out_dim(w, kw, paddings[1], strides[1], 1)
-    z = in_desc(op, block, "Z")
-    if z is not None and list(z.shape) != [n, oc, ho, wo]:
-        raise ValueError(
-            f"conv_bn_add_act: residual Z shape {list(z.shape)} must equal "
-            f"the conv output shape {[n, oc, ho, wo]}")
-    set_output(block, op, "Y", [n, oc, ho, wo], x.dtype)
-    for slot in ("MeanOut", "VarianceOut", "SavedMean", "SavedVariance"):
-        set_output(block, op, slot, [oc], x.dtype)
-
-
-@register_op(
-    "conv_bn_add_act",
-    infer_shape=_conv_bn_add_act_infer,
-    diff_inputs=["X", "Filter", "Scale", "Bias", "Z"],
-)
-def _conv_bn_add_act(ctx, ins, attrs):
-    """conv2d + batch_norm(batch stats) + residual + activation as ONE op
-    (reference counterpart: operators/conv_fusion_op.cu.cc — cuDNN fused
-    conv+bias+act; this op fuses BN instead of bias, the pattern ResNet
-    actually runs).  FLAGS_conv_epilogue picks the implementation:
-    "reference" composes the XLA conv with the BN math in one lowering
-    (numerics = the unfused chain); "pallas" routes through
-    kernels/conv_epilogue.py — BN statistics accumulate INSIDE the conv
-    pass and normalize/residual/act run as one epilogue pass, cutting
-    per-conv activation HBM traffic from ~4-5 passes to 3 (the
-    MFU-ceiling attack).  Train mode only for pallas; test
-    mode always takes the reference path (moving-stats normalize, no
-    batch statistics)."""
-    from .. import flags as _flags
-    from ..kernels.conv_epilogue import (
-        conv_bn_act_reference,
-        make_conv_bn_act,
-    )
-
-    x = data(ins["X"][0])
-    f = data(ins["Filter"][0])
-    scale = data(ins["Scale"][0])
-    bias = data(ins["Bias"][0])
-    mean = data(ins["Mean"][0])
-    var = data(ins["Variance"][0])
-    z = (data(ins["Z"][0])
-         if ins.get("Z") and ins["Z"][0] is not None else None)
-    eps = attrs.get("epsilon", 1e-5)
-    momentum = attrs.get("momentum", 0.9)
-    strides = attrs.get("strides", [1, 1])
-    paddings = attrs.get("paddings", [0, 0])
-    act = attrs.get("act") or ""
-    is_test = attrs.get("is_test", False) or ctx.is_test
-    if strides[0] != strides[1] or paddings[0] != paddings[1]:
-        raise NotImplementedError(
-            "conv_bn_add_act needs square stride/padding "
-            f"(got strides={strides}, paddings={paddings})")
-    stride, padding = int(strides[0]), int(paddings[0])
-    groups = int(attrs.get("groups", 1) or 1)
-
-    if is_test or attrs.get("use_global_stats", False):
-        # moving-stats normalize: compose the standard conv lowering with
-        # the affine epilogue — XLA fuses; the inference-deploy story is
-        # the transpiler fold, not this op
-        out = data(_conv2d_lower(
-            ctx, {"Input": ins["X"], "Filter": ins["Filter"]},
-            {"strides": strides, "paddings": paddings,
-             "dilations": [1, 1], "groups": groups})["Output"][0])
-        inv = jax.lax.rsqrt(var + eps)
-        bshape = [1, -1, 1, 1]
-        y = ((out.astype(inv.dtype) - mean.reshape(bshape))
-             * inv.reshape(bshape) * scale.reshape(bshape)
-             + bias.reshape(bshape))
-        y = y.astype(out.dtype)
-        if z is not None:
-            y = y + z.astype(y.dtype)
-        if act == "relu":
-            y = jax.nn.relu(y)
-        elif act:
-            raise ValueError(f"conv_bn_add_act: unsupported act {act!r}")
-        return {
-            "Y": [y],
-            "MeanOut": [mean], "VarianceOut": [var],
-            "SavedMean": [mean.astype(x.dtype)],
-            "SavedVariance": [jax.lax.rsqrt(var + eps).astype(x.dtype)],
-        }
-
-    xc, fc = amp.mxu_operands(x, f)
-    # NCHW program contract -> NHWC kernel layout behind boundary
-    # transposes (XLA cancels them between chained blocks, same trade as
-    # the conv2d lowering's NHWC mode)
-    xn = jnp.transpose(xc, (0, 2, 3, 1))
-    wn = jnp.transpose(fc, (2, 3, 1, 0))
-    zn = (jnp.transpose(z.astype(xn.dtype), (0, 2, 3, 1))
-          if z is not None else None)
-    impl = _flags.flag("conv_epilogue")
-    if impl == "pallas":
-        from ..kernels.conv_epilogue import pallas_viable
-
-        # explicit fallback instead of a compile-time bail: grouped convs
-        # (single-group per-tap matmuls only, ResNeXt cardinality) and
-        # shapes whose row tiles cannot fit VMEM take the reference
-        # composition
-        Np, Hp_, Wp_, Cp = xn.shape
-        if not pallas_viable(Np, Hp_, Wp_, Cp, wn.shape[-1], wn.shape[0],
-                             stride=stride, padding=padding,
-                             dtype=xn.dtype, groups=groups):
-            impl = "reference"
-    if impl == "pallas":
-        # interpret iff the TRACE TARGET is a CPU host: under the TPU
-        # trace scope (chip runs, AOT cost analysis, the lowering gate)
-        # the real Mosaic kernels must lower even when the process
-        # default backend is cpu — keying off default_backend alone
-        # silently compiled interpret-mode pallas into AOT-for-TPU
-        # modules (caught by the chip-less full-compile tier)
-        fn = make_conv_bn_act(
-            has_residual=z is not None, stride=stride, padding=padding,
-            eps=eps, act=act,
-            interpret=(jax.default_backend() == "cpu"
-                       and not _flags.tpu_trace_active()))
-        args = (xn, wn, scale, bias) + ((zn,) if z is not None else ())
-        yn, bmean, bvar = fn(*args)
-    else:
-        ref = lambda a, b, c, d, e: conv_bn_act_reference(  # noqa: E731
-            a, b, c, d, e, stride=stride, padding=padding,
-            eps=eps, act=act, groups=groups)
-        if not attrs.get("__fused_from__"):
-            # checkpoint INSIDE the lowering: backward recomputes the
-            # conv/BN chain instead of storing its intermediates — the
-            # same storage trade as fused_bn_add_act's @recompute@ tag,
-            # but owned here so the pallas branch (whose custom_vjp
-            # already recomputes) is never double-wrapped.  Ops the
-            # FUSION PASS created skip it: the chip-less v5e cost model
-            # prices the recompute at ~1.5x the unfused chain's bytes
-            # (the round-5 one-op A/B loss), and the pass's contract is
-            # "never worse than the chain it replaced" — its reference
-            # fallback stores intermediates exactly like the unfused
-            # lowering would
-            ref = jax.checkpoint(ref)
-        yn, bmean, bvar = ref(xn, wn, scale, bias, zn)
-    y = jnp.transpose(yn, (0, 3, 1, 2))
-    y = amp.mxu_output(y, x, f)
-
-    sd = amp.stats_dtype(x)
-    bmean, bvar = bmean.astype(sd), bvar.astype(sd)
-    new_mean = momentum * mean + (1.0 - momentum) * bmean
-    new_var = momentum * var + (1.0 - momentum) * bvar
-    return {
-        "Y": [y],
-        "MeanOut": [new_mean], "VarianceOut": [new_var],
-        "SavedMean": [bmean.astype(x.dtype)],
-        "SavedVariance": [jax.lax.rsqrt(bvar + eps).astype(x.dtype)],
-    }
-
-
 def _layer_norm_infer(op, block):
     x = in_desc(op, block, "X")
     if x is None:

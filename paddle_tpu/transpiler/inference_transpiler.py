@@ -45,10 +45,10 @@ _IS_TEST_OPS = ("batch_norm", "fused_bn_add_act", "dropout", "lrn",
 
 
 def _is_foldable_bn(op):
-    """batch_norm, or the fused twin WITHOUT a residual input (the Z-free
-    fused_bn_add_act the conv builders emit for plain conv->BN(+act)
-    stacks is the same conv+BN shape the fold handles; its activation is
-    re-emitted as a standalone relu after the folded add)."""
+    """batch_norm, or the fused twin WITHOUT a residual input (a Z-free
+    fused_bn_add_act behind a conv is the same conv+BN shape the fold
+    handles; its activation is re-emitted as a standalone relu after the
+    folded add)."""
     if op.type == "batch_norm":
         return True
     return (op.type == "fused_bn_add_act"

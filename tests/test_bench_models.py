@@ -158,3 +158,62 @@ def test_bench_all_models_failing_exits_2(monkeypatch, capsys):
     rec = __import__("json").loads(
         capsys.readouterr().out.strip().splitlines()[-1])
     assert rec["metric"] == "error"
+
+
+@pytest.mark.parametrize("amp,layout,want", [
+    (None, None, ((None, False), "auto")),
+    ("keep", None, (("bfloat16", True), "auto")),
+    ("1", "NHWC", (("bfloat16", False), "NHWC")),
+    ("0", "NCHW", ((None, False), "NCHW")),
+])
+def test_bench_apply_config_sets_only_what_is_given(amp, layout, want):
+    """bench.py sets the AMP policy and FLAGS_conv_layout only where
+    BENCH_AMP / BENCH_LAYOUT are given; with neither the policy stays
+    unset and the layout "auto", which a TPU program resolves to the
+    chip's measured winners, as the benchmark's cells rely on."""
+    import bench
+    from paddle_tpu import flags
+    from paddle_tpu.core import amp as amp_policy
+
+    fluid.enable_amp("bfloat16", keep_output=True)   # what a run before left
+    fluid.set_flags({"FLAGS_conv_layout": "NHWC"})
+    try:
+        bench._apply_config(amp, layout)
+        dtype, keep = amp_policy.amp_dtype(), amp_policy.keep_output()
+        assert ((None if dtype is None else str(dtype), keep),
+                flags.flag("conv_layout")) == want
+        assert amp_policy._POLICY["explicit"] is (amp is not None)
+        if amp is None and layout is None:
+            with flags.tpu_trace_scope(True):
+                assert str(amp_policy.amp_dtype()) == "bfloat16"
+                assert amp_policy.keep_output() is True
+                assert flags.conv_layout() == "NHWC"
+    finally:
+        amp_policy.reset_amp()
+        fluid.set_flags({"FLAGS_conv_layout": "auto"})
+
+
+def test_bench_main_passes_unset_amp_and_layout_through(monkeypatch, capsys):
+    """Nothing exported: run_model gets amp=None, layout=None (no tuner,
+    no "1"/NCHW default); BENCH_AMP / BENCH_LAYOUT go through as given."""
+    import bench
+
+    seen = []
+
+    def fake_run_model(model, steps, peak_flops, amp="1", layout="NCHW"):
+        seen.append((amp, layout))
+        return {"metric": model, "value": 1.0, "unit": "x",
+                "vs_baseline": None}
+
+    monkeypatch.setattr(bench, "run_model", fake_run_model)
+    monkeypatch.setenv("BENCH_MODELS", "lenet")
+    monkeypatch.setenv("BENCH_DEADLINE_S", "0")
+    monkeypatch.setenv("BENCH_COMPILE_CACHE", "0")
+    for var in ("BENCH_AMP", "BENCH_LAYOUT", "BENCH_TUNE"):
+        monkeypatch.delenv(var, raising=False)
+    bench.main()
+    monkeypatch.setenv("BENCH_AMP", "keep")
+    monkeypatch.setenv("BENCH_LAYOUT", "NHWC")
+    bench.main()
+    capsys.readouterr()
+    assert seen == [(None, None), ("keep", "NHWC")]

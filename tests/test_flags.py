@@ -302,3 +302,65 @@ def test_compile_cache_coldstart_cross_process(tmp_path):
     assert verdict["coldstart_ok"] is True
     assert verdict["cold_cache_hits"] > 0
     assert verdict["cold_cache_misses"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the flags tier after the conv-epilogue arms went (PR 29)
+
+
+@pytest.mark.parametrize("name", ["FLAGS_conv_epilogue",
+                                  "FLAGS_fuse_conv_epilogue"])
+def test_removed_flag_is_unknown_and_ignored_in_environment(name,
+                                                            monkeypatch):
+    """A removed flag raises KeyError where the program sets or reads it
+    and, like any variable the tier does not define, is ignored where the
+    environment still exports it."""
+    from paddle_tpu import flags as flagmod
+
+    with pytest.raises(KeyError):
+        fluid.set_flags({name: "pallas"})
+    with pytest.raises(KeyError):
+        fluid.get_flags(name)
+    monkeypatch.setenv(name, "pallas")
+    before = fluid.get_flags()
+    flagmod._bootstrap()
+    assert fluid.get_flags() == before and name not in before
+
+
+def test_trace_key_has_exactly_three_entries():
+    """layout (resolved), FLAGS_flash_bwd, FLAGS_check_numerics: what
+    changes the traced program or its executable, and nothing else."""
+    from paddle_tpu import flags as flagmod
+
+    assert flagmod.trace_key() == ("NCHW", "jax", False)
+    with flagmod.tpu_trace_scope(True):
+        assert flagmod.trace_key() == ("NHWC", "jax", False)
+
+
+@pytest.mark.parametrize("name,value,default", [
+    ("FLAGS_conv_layout", "NHWC", "auto"),
+    ("FLAGS_flash_bwd", "pallas", "jax"),
+    ("FLAGS_check_numerics", True, False),
+])
+def test_trace_key_flag_flip_lands_on_another_cached_entry(name, value,
+                                                           default):
+    """Executor.run keys its compiled entries on trace_key(): a flip
+    between two runs of one program compiles again, and flipping back
+    finds the first entry."""
+    fluid.reset_default_env()
+    x = fluid.layers.data(name="x", shape=[2, 4, 4], dtype="float32")
+    out = fluid.layers.reduce_mean(fluid.layers.conv2d(x, 2, 3))
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.ones((1, 2, 4, 4), "float32")}
+    (first,) = exe.run(feed=feed, fetch_list=[out])
+    entries = len(exe._cache)
+    fluid.set_flags({name: value})
+    try:
+        (flipped,) = exe.run(feed=feed, fetch_list=[out])
+        assert len(exe._cache) == entries + 1
+    finally:
+        fluid.set_flags({name: default})
+    exe.run(feed=feed, fetch_list=[out])
+    assert len(exe._cache) == entries + 1
+    np.testing.assert_allclose(flipped, first, rtol=1e-5)

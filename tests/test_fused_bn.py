@@ -106,39 +106,6 @@ def test_fused_test_mode_uses_moving_stats():
         np.asarray(fluid.global_scope().find_var("bn_mean")), mean_before)
 
 
-def test_resnet_fused_matches_unfused():
-    """resnet_cifar10-scale end to end: fuse_bn=True and False give the
-    same loss trajectory (the flagship model's default path is safe)."""
-    from paddle_tpu import models
-
-    def run(fuse_bn):
-        fluid.reset_default_env()
-        fluid.default_main_program().random_seed = 3
-        fluid.default_startup_program().random_seed = 3
-        img = layers.data("image", [3, 16, 16], dtype="float32")
-        label = layers.data("label", [1], dtype="int64")
-        s = _shortcut_block(img, fuse_bn)
-        pool = layers.pool2d(s, pool_size=8, pool_type="avg")
-        pred = layers.fc(pool, size=4, act="softmax")
-        loss = layers.mean(layers.cross_entropy(pred, label))
-        fluid.optimizer.SGDOptimizer(learning_rate=0.05).minimize(loss)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        rng = np.random.RandomState(1)
-        xv = rng.randn(8, 3, 16, 16).astype("float32")
-        yv = rng.randint(0, 4, size=(8, 1)).astype("int64")
-        return [
-            float(np.ravel(np.asarray(exe.run(
-                feed={"image": xv, "label": yv}, fetch_list=[loss])[0]))[0])
-            for _ in range(4)
-        ]
-
-    def _shortcut_block(img, fuse_bn):
-        return models.resnet.bottleneck(img, 8, 2, fuse_bn=fuse_bn)
-
-    np.testing.assert_allclose(run(True), run(False), rtol=1e-5, atol=1e-6)
-
-
 def test_flash_bwd_jaxlib_flag_accepted_cpu_fallback():
     """FLAGS_flash_bwd=jaxlib routes to the jax-shipped TPU kernel pair on
     TPU only; on CPU the flag is accepted and attention falls back to the

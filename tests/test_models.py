@@ -36,6 +36,41 @@ def test_resnet_imagenet_builds_and_trains_small():
     _train(spec, bs=2)
 
 
+_CONV_BN_BUILDERS = {
+    "resnet18": lambda **kw: models.resnet_imagenet(depth=18, **kw),
+    "resnet34": lambda **kw: models.resnet_imagenet(depth=34, **kw),
+    "resnet50": lambda **kw: models.resnet_imagenet(depth=50, **kw),
+    "resnet101": lambda **kw: models.resnet_imagenet(depth=101, **kw),
+    "resnet152": lambda **kw: models.resnet_imagenet(depth=152, **kw),
+    "resnet_cifar10": lambda **kw: models.resnet_cifar10(depth=32, **kw),
+    "se_resnext": lambda **kw: models.se_resnext(**kw),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CONV_BN_BUILDERS))
+def test_conv_builders_emit_one_formulation(name):
+    """A ResNet / SE-ResNeXt block is written once: conv2d -> batch_norm
+    [-> elementwise_add] -> relu.  No fused one-op form in the program,
+    and a batch_norm behind every convolution."""
+    fluid.reset_default_env()
+    _CONV_BN_BUILDERS[name]()
+    types = [op.type for op in
+             fluid.default_main_program().global_block().ops]
+    assert "fused_bn_add_act" not in types
+    assert "conv_bn_add_act" not in types
+    assert types.count("batch_norm") == types.count("conv2d") > 0
+    for i, t in enumerate(types):
+        if t == "conv2d":
+            assert types[i + 1] == "batch_norm", (i, types[i:i + 3])
+
+
+def test_conv_builders_take_no_fuse_option():
+    for name, build in sorted(_CONV_BN_BUILDERS.items()):
+        fluid.reset_default_env()
+        with pytest.raises(TypeError, match="fuse_bn"):
+            build(fuse_bn=True)
+
+
 def test_vgg16_trains():
     _train(models.vgg16(), bs=2)
 

@@ -201,73 +201,55 @@ class TestReduceMeanOp(OpTest):
         self.check_grad(["X"], "Out")
 
 
-class TestConvBnAddActOp(OpTest):
-    """conv_bn_add_act: numpy reference for outputs + finite-difference
-    gradient check through the fused conv+BN+residual+relu backward
-    (the reference's OpTest pattern for conv_fusion-class ops)."""
+class TestBatchNormOp(OpTest):
+    """batch_norm, the op every ResNet step runs once a convolution: numpy
+    reference for all five outputs and a finite-difference gradient, with
+    batch statistics (train) and moving statistics (is_test), channels
+    second (NCHW) and last (NHWC)."""
 
-    op_type = "conv_bn_add_act"
+    op_type = "batch_norm"
 
-    def setup(self, act="relu"):
-        rng = np.random.RandomState(7)
-        N, C, H, F, K = 2, 4, 6, 5, 3
-        x = rng.uniform(-1, 1, (N, C, H, H)).astype("float32")
-        w = (rng.uniform(-1, 1, (F, C, K, K)) * 0.4).astype("float32")
-        scale = rng.uniform(0.6, 1.4, (F,)).astype("float32")
-        bias = (rng.uniform(-0.2, 0.2, (F,))).astype("float32")
+    def setup(self, is_test, layout):
+        rng = np.random.RandomState(9)
+        N, C, H = 3, 4, 5
+        shape = (N, C, H, H) if layout == "NCHW" else (N, H, H, C)
+        caxis = 1 if layout == "NCHW" else 3
+        axes = tuple(i for i in range(4) if i != caxis)
+        bshape = [1, 1, 1, 1]
+        bshape[caxis] = C
+        x = rng.uniform(-1, 1, shape).astype("float32")
+        scale = rng.uniform(0.6, 1.4, (C,)).astype("float32")
+        bias = rng.uniform(-0.2, 0.2, (C,)).astype("float32")
         # nonzero moving stats: an all-zero mean would let a wrong
         # momentum blend of the old mean pass undetected
-        mean = rng.uniform(-0.5, 0.5, (F,)).astype("float32")
-        var = rng.uniform(0.5, 1.5, (F,)).astype("float32")
-        z = rng.uniform(-1, 1, (N, F, H, H)).astype("float32")
+        mean = rng.uniform(-0.5, 0.5, (C,)).astype("float32")
+        var = rng.uniform(0.5, 1.5, (C,)).astype("float32")
         eps, momentum = 1e-5, 0.9
+        if is_test:
+            use_mean, use_var, new_mean, new_var = mean, var, mean, var
+        else:
+            use_mean, use_var = x.mean(axis=axes), x.var(axis=axes)
+            new_mean = momentum * mean + (1 - momentum) * use_mean
+            new_var = momentum * var + (1 - momentum) * use_var
+        inv = 1.0 / np.sqrt(use_var + eps)
+        y = ((x - use_mean.reshape(bshape)) * inv.reshape(bshape)
+             * scale.reshape(bshape) + bias.reshape(bshape))
+        self.inputs = {"X": x, "Scale": scale, "Bias": bias,
+                       "Mean": mean, "Variance": var}
+        self.attrs = {"epsilon": eps, "momentum": momentum,
+                      "is_test": is_test, "data_layout": layout}
+        self.outputs = {"Y": y, "MeanOut": new_mean, "VarianceOut": new_var,
+                        "SavedMean": use_mean, "SavedVariance": inv}
 
-        # numpy reference: NCHW conv (stride 1, pad 1) + batch stats BN
-        # + residual + relu
-        xp = np.pad(x, ((0, 0), (0, 0), (1, 1), (1, 1)))
-        out = np.zeros((N, F, H, H), "float32")
-        for kh in range(K):
-            for kw in range(K):
-                patch = xp[:, :, kh:kh + H, kw:kw + H]
-                out += np.einsum("nchw,fc->nfhw", patch, w[:, :, kh, kw])
-        bm = out.mean(axis=(0, 2, 3))
-        bv = out.var(axis=(0, 2, 3))
-        inv = 1.0 / np.sqrt(bv + eps)
-        y = ((out - bm[None, :, None, None]) * inv[None, :, None, None]
-             * scale[None, :, None, None] + bias[None, :, None, None])
-        y = y + z
-        if act == "relu":
-            y = np.maximum(y, 0.0)
-
-        self.inputs = {"X": x, "Filter": w, "Scale": scale, "Bias": bias,
-                       "Mean": mean, "Variance": var, "Z": z}
-        self.attrs = {"strides": [1, 1], "paddings": [1, 1],
-                      "epsilon": eps, "momentum": momentum, "act": act}
-        self.outputs = {
-            "Y": y,
-            "MeanOut": momentum * mean + (1 - momentum) * bm,
-            "VarianceOut": momentum * var + (1 - momentum) * bv,
-            "SavedMean": bm,
-            "SavedVariance": inv,
-        }
-
-    def test_output(self):
-        self.setup()
+    @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
+    @pytest.mark.parametrize("is_test", [False, True])
+    def test_output(self, is_test, layout):
+        self.setup(is_test, layout)
         self.check_output(atol=1e-4, rtol=1e-4)
 
-    @pytest.mark.parametrize("impl", ["reference", "pallas"])
-    def test_grad(self, impl):
-        # the smooth path (no relu kink): finite differences across the
-        # activation's corner dominate the error otherwise.  impl=pallas
-        # numerically validates the hand-written custom_vjp backward of
-        # kernels/conv_epilogue.py (interpret mode on CPU), not just the
-        # autodiff'd reference composition
-        import paddle_tpu as fluid
-
-        fluid.set_flags({"FLAGS_conv_epilogue": impl})
-        try:
-            self.setup(act="")
-            self.check_grad(["X", "Filter", "Scale", "Bias", "Z"], "Y",
-                            max_relative_error=0.02)
-        finally:
-            fluid.set_flags({"FLAGS_conv_epilogue": "reference"})
+    @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
+    @pytest.mark.parametrize("is_test", [False, True])
+    def test_grad(self, is_test, layout):
+        self.setup(is_test, layout)
+        self.check_grad(["X", "Scale", "Bias"], "Y",
+                        max_relative_error=0.02)

@@ -3,26 +3,30 @@
 Round-5 chip lesson: pallas interpret-mode tests validate numerics but
 NEVER see the real TPU's Mosaic constraints — the first healthy chip
 window in five rounds was half-lost to a (1, block_q) lse block that
-violates the (8, 128) tile rule, and the staged conv-epilogue probe
-would have burned a second window on a strided-slice lowering failure.
-Both fail CLIENT-SIDE at lowering time, which means `jax.export` with
-platforms=["tpu"] reproduces them on a CPU host with no TPU attached.
+violates the (8, 128) tile rule.  That fails CLIENT-SIDE at lowering
+time, which means `jax.export` with platforms=["tpu"] reproduces it on
+a CPU host with no TPU attached.
 
 Every pallas kernel in the repo must TPU-lower here, at realistic
-shapes (the flagship bench configs), including the shapes that caught
-the two bugs above.
+shapes (the flagship bench configs), including the shape that caught
+that bug; and so must the conv -> batch_norm -> relu chain every ResNet
+block is made of.
 """
+
+import importlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-import importlib
+import paddle_tpu as fluid
+from paddle_tpu import flags, layers
+from paddle_tpu.core import amp
 
 # the kernels package re-exports the flash_attention FUNCTION under the
 # same name as its module; go through importlib for the module itself
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
-from paddle_tpu.kernels.conv_epilogue import conv_bn_act
 
 
 def _tpu_lowers(fn, *args):
@@ -103,30 +107,97 @@ class TestFlashLowering:
         assert f"tensor<{B * H}x{Sq // bq}x{bq}xf32>" in txt
 
 
-class TestConvEpilogueLowering:
-    # ResNet-50 block shapes (NHWC), incl. the stride-2 stage
-    # transitions that Mosaic's strided-slice limitation used to kill
+class TestConvBnChain:
+    """The one formulation of a ResNet block (models/resnet.py): conv2d ->
+    batch_norm [-> elementwise_add] -> relu built through `layers`, at
+    ResNet-50's block shapes."""
+
+    # (N, H, W, C, F, K, stride, residual): the stage transitions at
+    # stride 2 among them, the 7x7 stride-2 stem, a 1x1 stride-2
+    # projection shortcut and a bottleneck's widening 1x1 tail
     CASES = [
         (8, 56, 56, 64, 64, 1, 1, False),
         (8, 56, 56, 64, 64, 3, 1, True),
         (8, 56, 56, 128, 128, 3, 2, False),
         (8, 28, 28, 256, 256, 3, 2, False),
         (8, 7, 7, 512, 512, 3, 1, True),
+        (8, 224, 224, 3, 64, 7, 2, False),
+        (8, 56, 56, 256, 512, 1, 2, False),
+        (8, 56, 56, 64, 256, 1, 1, True),
+        (8, 14, 14, 512, 512, 3, 2, False),
     ]
 
-    @pytest.mark.parametrize("case", CASES)
-    def test_conv_bn_act(self, case):
-        N, H, W, C, F, K, s, res = case
-        x = jax.ShapeDtypeStruct((N, H, W, C), jnp.bfloat16)
-        w = jax.ShapeDtypeStruct((K, K, C, F), jnp.bfloat16)
-        g = jax.ShapeDtypeStruct((F,), jnp.float32)
+    @staticmethod
+    def _build(case, batch):
+        """(loss, feed, [(parameter, gradient)]) of the chain, its loss the
+        mean of the output under a fixed random weighting."""
+        _, H, W, C, F, K, s, res = case
         Ho = -(-H // s)
-        args = (x, w, g, g)
+        fluid.reset_default_env()
+        fluid.default_startup_program().random_seed = 17
+        x = layers.data("x", [C, H, W], dtype="float32")
+        m = layers.data("m", [F, Ho, Ho], dtype="float32")
+        conv = layers.conv2d(x, num_filters=F, filter_size=K, stride=s,
+                             padding=(K - 1) // 2, bias_attr=False)
+        out = layers.batch_norm(conv, act=None if res else "relu")
+        rng = np.random.RandomState(K * 100 + C)
+        feed = {"x": rng.randn(batch, C, H, W).astype("float32"),
+                "m": rng.rand(batch, F, Ho, Ho).astype("float32")}
         if res:
-            args += (jax.ShapeDtypeStruct((N, Ho, Ho, F), jnp.bfloat16),)
+            z = layers.data("z", [F, Ho, Ho], dtype="float32")
+            out = layers.elementwise_add(z, out, act="relu")
+            feed["z"] = rng.randn(batch, F, Ho, Ho).astype("float32")
+        loss = layers.mean(layers.elementwise_mul(out, m))
+        return loss, feed, fluid.append_backward(loss)
 
-        def f(x, w, gamma, beta, z=None):
-            return conv_bn_act(x, w, gamma, beta, z, stride=s,
-                               padding="SAME")
+    @pytest.mark.parametrize("case", CASES)
+    def test_lowers_for_tpu_in_nhwc(self, case):
+        """The chip program of the chain (NHWC and keep-bf16 resolved by
+        the TPU trace scope, nothing set) lowers with no TPU attached, its
+        convolutions channels-last on bf16 operands."""
+        loss, feed, grads = self._build(case, batch=case[0])
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        with flags.tpu_trace_scope(True):
+            compiled, feed_vals, state_vals, rng = exe.capture_program(
+                feed=feed, fetch_list=[loss] + [g for _, g in grads])
+            txt = jax.export.export(compiled.fn, platforms=["tpu"])(
+                tuple(feed_vals), tuple(state_vals), rng).mlir_module()
+        convs = [ln for ln in txt.splitlines()
+                 if "stablehlo.convolution" in ln]
+        assert len(convs) == 2      # forward and the filter's gradient
+        N, H, W, C = case[:4]
+        assert "[b, 0, 1, f]x[0, 1, i, o]->[b, 0, 1, f]" in convs[0]
+        assert f"(tensor<{N}x{H}x{W}x{C}xbf16>" in convs[0]
+        for ln in convs:
+            assert "xf32>" not in ln.split(" : (")[1], ln
 
-        _tpu_lowers(f, *args)
+    @pytest.mark.parametrize("case", CASES)
+    def test_nhwc_bf16_matches_nchw_fp32(self, case):
+        """Loss and every parameter gradient under the chip's tier (NHWC,
+        bf16 kept between ops) against the reference tier (NCHW, fp32),
+        batch 2, within what bf16's 8 mantissa bits allow."""
+        def run(chip_tier):
+            if chip_tier:
+                fluid.enable_amp("bfloat16", keep_output=True)
+                fluid.set_flags({"FLAGS_conv_layout": "NHWC"})
+            try:
+                loss, feed, grads = self._build(case, batch=2)
+                exe = fluid.Executor(fluid.CPUPlace())
+                exe.run(fluid.default_startup_program())
+                got = exe.run(feed=feed,
+                              fetch_list=[loss] + [g for _, g in grads])
+                return [np.asarray(v, dtype=np.float32) for v in got]
+            finally:
+                amp.reset_amp()
+                fluid.set_flags({"FLAGS_conv_layout": "auto"})
+
+        ref, got = run(False), run(True)
+        assert len(ref) == 4        # loss; filter, scale and bias gradients
+        np.testing.assert_allclose(got[0], ref[0], rtol=2e-2, atol=2e-3)
+        for r, g in zip(ref[1:], got[1:]):
+            assert np.all(np.isfinite(g))
+            cos = float(np.sum(r * g) / (np.linalg.norm(r)
+                                         * np.linalg.norm(g) + 1e-30))
+            assert cos > 0.98, cos
+            assert abs(np.linalg.norm(g) / np.linalg.norm(r) - 1) < 0.05

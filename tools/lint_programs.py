@@ -10,7 +10,7 @@ dtype promotions, scan/while carry widenings, host-sync points, SPMD
 collective placement, and (the kernel-interior tier, analysis/pallas.py)
 pallas_call VMEM working sets priced against the v5e budget.
 Per-program AOT bytes/step and finding counts are banked in
-AOT_COST_ZOO.json (the successor table to AOT_COST_AB.json /
+AOT_COST_ZOO.json (the successor table to
 AOT_COST_PAGED.json) and gated per PR.  Findings are ordered
 severity-then-bytes (and vmem-overflow findings carry per-finding
 vmem_bytes/budget in --json) so gate diffs are stable.
