@@ -607,11 +607,10 @@ def run_model(model: str, steps: int, peak_flops: float,
         except Exception as e:  # never lose the timed number to accounting
             result["cost_analysis_error"] = str(e)[:200]
     # feature provenance, so a number is attributable to the config that
-    # produced it (fused smoothed CE / flash backward impl)
+    # produced it (fused smoothed CE, recompute)
     feats = {}
     if model in ("transformer", "transformer_longctx"):
         feats["fuse_smooth_ce"] = cfg.fuse_smooth_ce
-        feats["flash_bwd"] = fluid.get_flags("flash_bwd")["FLAGS_flash_bwd"]
         feats["recompute"] = cfg.use_recompute
     if use_unroll:
         feats["unroll_mode"] = os.environ.get("BENCH_UNROLL_MODE", "scan")

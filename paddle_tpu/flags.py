@@ -90,14 +90,6 @@ _DEFS: Dict[str, Any] = {
     # won every round-3 tuner probe, so TPUPlace gets it with no env vars
     # (VERDICT r3 item 5) while CPU keeps bit-parity with the reference
     "FLAGS_conv_layout": "auto",
-    # flash-attention backward implementation: "jax" (recompute the
-    # reference formulation under jax.vjp — XLA fuses it well),
-    # "pallas" (this repo's FlashAttention-2 dq/dkv kernels; O(S*D) HBM
-    # in backward), or "jaxlib" (the jax-shipped TPU kernel pair, fwd AND
-    # bwd — independent compile behavior, tools/flash_bwd_probe.py
-    # compares).  Default jax: the three are not yet compared on a chip
-    # (ROADMAP D7)
-    "FLAGS_flash_bwd": "jax",
     # serving (paddle_tpu/serving/): the dynamic batcher's batch-size
     # bucket ladder.  Queued requests coalesce into micro-batches padded
     # UP to the smallest bucket that fits, so a polymorphic-batch AOT
@@ -213,7 +205,6 @@ def get_flags(names=None) -> Dict[str, Any]:
 # silently select the default branch at the use site)
 _CHOICES: Dict[str, tuple] = {
     "FLAGS_conv_layout": ("auto", "NCHW", "NHWC"),
-    "FLAGS_flash_bwd": ("jax", "pallas", "jaxlib"),
     "FLAGS_observability_cost": ("off", "native", "tpu"),
     "FLAGS_serving_paged_impl": ("auto", "reference", "pallas", "interpret"),
 }
@@ -284,7 +275,7 @@ def trace_key() -> tuple:
     executors include this (plus amp.state_key()) in compiled-program
     cache keys so a flag flip between runs recompiles instead of reusing
     a stale executable."""
-    return (conv_layout(), _VALUES["FLAGS_flash_bwd"],
+    return (conv_layout(),
             # not trace-affecting, but executable-affecting: the sentinel
             # turns state-buffer donation off, so a flag flip must land on
             # a different compiled entry instead of reusing one whose

@@ -28,29 +28,48 @@ the online-softmax sums moves with the block size.  `flash.plan` (an
 observability span, at lowering) says what a call was given.
 tools/flash_fwd_probe.py times the forward alone on the chip.
 
-The backward is the FlashAttention-2 recipe in two Pallas kernels — a
-round-3 change driven by a chip profile (today: `python3 benchmark/run.py
---workload transformer-train --trace 1`) showing the
-previous recompute-with-dense-jax backward's softmax-gradient elementwise
-chains dominating transformer step time:
-- forward additionally emits the per-row logsumexp L;
-- dQ kernel: grid (BH, q-blocks, k-blocks), rebuilds P = exp(S - L) per
-  block and accumulates dQ = sum_k (P*(dP - D))*scale @ K in VMEM scratch;
-- dK/dV kernel: grid (BH, k-blocks, q-blocks), accumulates
-  dK = sum_q dS^T Q and dV = sum_q P^T dO;
-- D = rowsum(dO * O) is a cheap fused elementwise pass outside the kernels.
+The backward is the FlashAttention-2 recipe in ONE Pallas kernel (PR 30),
+on the forward's two principles:
+- forward additionally emits the per-row logsumexp L (packed, below);
+- D = rowsum(dO * O) is a cheap fused elementwise pass outside the kernel;
+- _flash_bwd_kernel: grid (BH, k-blocks, q-blocks), q innermost.  A step
+  rebuilds P^T = exp(S^T - L) for its block, transposed so that P^T and
+  dS^T = P^T * (dP^T - D) are the left operands of plain matmuls:
+  dV += P^T dO and dK += dS^T Q in VMEM scratch across the q-blocks, and
+  dQ += dS K (contracting the key dimension of dS^T and K) into an fp32
+  [Sqp, D] scratch that stays a whole batch-head row, because a TPU grid
+  runs in order.  Five block matmuls and one exp a step; a dq kernel and a
+  dkv kernel, which this replaced, take seven and two (on the chip 1.29 ms
+  a call against 0.89 at 32 x 2048 x 128 causal; the XLA recompute
+  backward 3.70: tools/flash_bwd_probe.py, PERF.md PR 30).
+- its grid is planned from the shape (_plan_bwd_blocks: the fewest steps
+  that run whose working set, bwd_working_set_bytes with FOUR fp32 score
+  planes and the row's dQ, fits the same share of VMEM); it need not be
+  the forward's: the packed lse plane is a view of [B*H, Sqp] and is
+  re-cut for free (_repack).  S 2048 causal runs 512 x 512.
+- under `causal` the q-blocks that end before a k-block's first key are
+  neither fetched nor computed (pl.when; the q/dO index maps wait at the
+  first q-block that runs, _q_block_index), and a block that runs takes
+  the mask only where the diagonal, the padded end or klen[b] cuts it.
 Zero-padded dO rows make padded q rows contribute exactly zero to dK/dV,
 and the same key-padding/causal masks as forward zero padded k columns.
-Their q-block is the forward's (the packed lse plane is laid out by it);
-their k-block is still 128, and they skip nothing yet.
+Rounding is the forward's: operands in the input dtype, scores, exp, D and
+every accumulator fp32, the scale on the fp32 scores (dS's on the fp32
+accumulators of dQ and dK), P and dS cast to the operand dtype only for
+the MXU.  `flash.bwd_plan` (a span, at lowering) says what a site was
+given; the backward's operations sit under the name scope `flash.bwd`.
 
-Backward selection (FLAGS_flash_bwd): "jax" (default) differentiates the
-reference formulation under jax.vjp — a recompute backward XLA fuses well;
-"pallas" uses the dq/dkv kernels.  The default stays jax: the two have not
-yet been compared on a chip (ROADMAP D7); the kernels are
-correctness-tested in interpret mode and compile for v5e
-(tests/test_aot_cost.py).  pallas_call instances are memoized by
-static config, blocks included, so every attention site of one shape
+Backward selection is read from the shape in one place (_bwd_plan): the
+Pallas kernel where the plan's score block has at least 384 x 384 scores
+to spread a grid step's fixed cost over and the row's dQ fits VMEM
+(S >= 384 up to ~4k at head 128), else jax.vjp of the reference
+formulation, a recompute backward that XLA fuses, whose forward emits no
+lse.  At S 256 a head is one grid step: the kernel alone ties with XLA,
+and the lse its forward must then emit makes the pair 9% slower
+(the probe's table), so XLA keeps it.  No flag, no model name:
+force="interpret" keeps the Pallas backward at every shape (the CPU
+tests' door), force="jax" keeps none.  pallas_call instances are memoized
+by static config, blocks included, so every attention site of one shape
 (the 18 of a Transformer-base step are 3 shapes) shares one kernel payload.
 """
 
@@ -66,7 +85,8 @@ import numpy as np
 from ..analysis.pallas import V5E_VMEM_BYTES, tile_padded_bytes
 from ..observability import span
 
-__all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes"]
+__all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes",
+           "bwd_working_set_bytes"]
 
 NEG_INF = -1e30
 
@@ -128,18 +148,11 @@ def _block_lengths(seq: int):
     return [128 * n for n in range(1, tiles + 1) if tiles % n == 0]
 
 
-def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
-    """(block_q, block_k) of the forward's grid, from the shape alone.
-
-    A grid step costs about the same whatever it computes (the pipeline's
-    bookkeeping, two DMAs, a read-modify-write of the fp32 accumulator and
-    a dozen VPU passes whose fixed part dominates a small block), so the
-    plan takes the fewest steps: of the block pairs whose working set
-    (fwd_working_set_bytes) fits _PLAN_VMEM_BUDGET the one with the fewest,
-    and the wider key block where two tie (the accumulator is rescaled once
-    a k-step: the probe reads 256 x 1024 at 0.59 ms, 1024 x 256 at 1.52).
-    Under `causal` the steps counted are those that run: _skipped_k_steps
-    are free."""
+def _fewest_steps(sq, sk, causal, working_set):
+    """The (block_q, block_k) whose grid takes the fewest steps that run,
+    of the pairs whose `working_set(block_q, block_k)` bytes fit
+    _PLAN_VMEM_BUDGET; the wider key block where two tie.  Under `causal`
+    the steps counted are those that run: _skipped_k_steps are free."""
     def steps_then_wide(plan):
         bq, bk = plan
         nqb, nkb = -(-sq // bq), -(-sk // bk)
@@ -149,12 +162,58 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
 
     plans = [(bq, bk) for bq in _block_lengths(sq)
              for bk in _block_lengths(sk)]
-    fits = [(bq, bk) for bq, bk in plans
-            if fwd_working_set_bytes(bq, bk, head_dim, -(-sq // bq), dtype,
-                                     emit_lse) <= _PLAN_VMEM_BUDGET]
+    fits = [plan for plan in plans if working_set(*plan) <= _PLAN_VMEM_BUDGET]
     # a head so wide that not even the smallest blocks fit the share still
     # gets them: the share is headroom, not the compiler's limit
     return min(fits or plans[:1], key=steps_then_wide)
+
+
+def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
+    """(block_q, block_k) of the forward's grid, from the shape alone.
+
+    A grid step costs about the same whatever it computes (the pipeline's
+    bookkeeping, two DMAs, a read-modify-write of the fp32 accumulator and
+    a dozen VPU passes whose fixed part dominates a small block), so the
+    plan takes the fewest steps (_fewest_steps) whose working set
+    (fwd_working_set_bytes) fits, and the wider key block where two tie
+    (the accumulator is rescaled once a k-step: the probe reads 256 x 1024
+    at 0.59 ms, 1024 x 256 at 1.52)."""
+    return _fewest_steps(
+        sq, sk, causal, lambda bq, bk: fwd_working_set_bytes(
+            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse))
+
+
+def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
+                          dtype="float32") -> int:
+    """What one grid step of the backward kernel holds: the double-buffered
+    q, dO, k, v blocks, the packed lse and D planes and the dK, dV and
+    (whole-row) dQ blocks it writes, the fp32 accumulators of dK and dV and
+    of the whole row's dQ, and FOUR fp32 [block_k, block_q] planes between
+    its matmuls (scores and probabilities, dP, dS and the operand cast for
+    the MXU) where the forward holds two.  The dQ row is what bounds the
+    sequence: 2 MB of it at 2048 x 128 bf16, 8 MB of the 12 at 8192."""
+    def tile(shape, dt=dtype):
+        return tile_padded_bytes(shape, dt)
+
+    rows = num_q_blocks * block_q
+    blocks = (2 * tile((1, block_q, head_dim))          # q, dO
+              + 4 * tile((1, block_k, head_dim))        # k, v, dK, dV
+              + tile((1, rows, head_dim))               # dQ
+              + 2 * tile((1, num_q_blocks, block_q), "float32"))
+    scratch = (tile((rows, head_dim), "float32")
+               + 2 * tile((block_k, head_dim), "float32"))
+    return (2 * blocks + scratch
+            + 4 * tile((block_k, block_q), "float32"))
+
+
+def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal):
+    """(block_q, block_k) of the backward's grid, on the forward's
+    principle: the fewest grid steps that run (_fewest_steps) whose working
+    set (bwd_working_set_bytes) fits.  It need not be the forward's pair:
+    the packed lse plane is re-cut for free (_repack)."""
+    return _fewest_steps(
+        sq, sk, causal, lambda bq, bk: bwd_working_set_bytes(
+            bq, bk, head_dim, -(-sq // bq), dtype))
 
 
 def _block_runs(qi, ki, block_q, block_k, causal_offset):
@@ -194,17 +253,11 @@ def _skipped_k_steps(nqb, nkb, block_q, block_k, causal_offset):
 # full 128-lane register instead ([B*H, Sqp, 128] fp32, ~67 MB/tensor at
 # the longcontext shape, 128x the payload, and XLA does NOT fuse that
 # broadcast away: it materializes as custom-call operands).  The packed
-# layout is exact-size ((8,128)-tiled with no replication); each kernel
-# step reads its (block_q,) row and transposes it to the [block_q, 1]
-# column the softmax math wants — one register-level lane->sublane
-# transpose per grid step buys a 128x smaller HBM residual.
-
-
-def _packed_col(ref, qi):
-    """[block_q, 1] column for q-block qi from a packed residual ref
-    (block shape [1, num_q_blocks, block_q])."""
-    row = ref[0, qi, :].reshape(1, -1)
-    return jnp.transpose(row, (1, 0))
+# layout is exact-size ((8,128)-tiled with no replication): the forward
+# transposes its [block_q, 1] column to the (block_q,) row once a q-block
+# (one register-level sublane->lane transpose buys a 128x smaller HBM
+# residual), and the backward, whose scores are [block_k, block_q], reads
+# the row as it lies.
 
 
 def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None):
@@ -228,17 +281,45 @@ def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None):
 
 
 def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
-                causal, causal_offset):
+                causal, causal_offset, transposed=False):
     """Key-padding (+ causal) mask for score block (qi, ki) of batch row
-    bi — identical in forward and backward."""
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bi — identical in forward and backward.  `transposed`: the block is
+    [block_k, block_q] (the backward kernel's scores)."""
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
     mask = k_pos < jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
     if causal:
         # bottom-right alignment (matches jnp.tril(k=Sk-Sq)): with cached
         # keys (Sk > Sq) a query at row i sees keys up to i + Sk - Sq
         mask &= k_pos <= q_pos + causal_offset
     return mask
+
+
+def _step_cases(klen_ref, bi, qi, ki, *, causal, block_q, block_k, seq_k,
+                causal_offset):
+    """(runs, cut) of score block (qi, ki), forward and backward: whether
+    the block has a key at or under the causal diagonal, and whether the
+    diagonal (its last key is past what its first row sees), the padded end
+    of the keys or klen[b] (data: a scalar read from SMEM) cuts it.  Only a
+    block that is cut pays for the iota/compare/select mask."""
+    k_end = jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
+    cut = (ki + 1) * block_k > k_end
+    runs = True
+    if causal:
+        runs = _block_runs(qi, ki, block_q, block_k, causal_offset)
+        cut = jnp.logical_or(
+            cut, (ki + 1) * block_k - 1 > qi * block_q + causal_offset)
+    return runs, cut
+
+
+def _when_runs(runs, cut, update):
+    """Run update(cut) under pl.when, once for each static value of `cut`."""
+    import jax.experimental.pallas as pl
+
+    pl.when(jnp.logical_and(runs, cut))(lambda: update(True))
+    pl.when(jnp.logical_and(runs, jnp.logical_not(cut)))(
+        lambda: update(False))
 
 
 def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -302,17 +383,9 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    k_end = jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
-    cut = (ki + 1) * block_k > k_end
-    runs = True
-    if causal:
-        runs = _block_runs(qi, ki, block_q, block_k, causal_offset)
-        # the block's last key is past what its first row sees
-        cut = jnp.logical_or(
-            cut, (ki + 1) * block_k - 1 > qi * block_q + causal_offset)
-    pl.when(jnp.logical_and(runs, cut))(lambda: _update(True))
-    pl.when(jnp.logical_and(runs, jnp.logical_not(cut)))(
-        lambda: _update(False))
+    _when_runs(*_step_cases(
+        klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+        seq_k=seq_k, causal_offset=causal_offset), _update)
 
     @pl.when(ki == num_kb - 1)
     def _finalize():
@@ -332,87 +405,77 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             lse_ref[0, qi, :] = jnp.transpose(lse, (1, 0))[0]
 
 
-def _flash_bwd_dq_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         dvec_ref, dq_ref, acc_scr,
-                         *, causal, scale, block_q, block_k, seq_k,
-                         causal_offset):
-    """dQ: grid (BH, num_q_blocks, num_k_blocks), K innermost; the dQ
-    accumulator for one q block stays in VMEM across all K blocks."""
-    import jax.experimental.pallas as pl
+def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      dvec_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
+                      dv_scr, *, causal, scale, block_q, block_k, seq_k,
+                      causal_offset):
+    """dQ, dK and dV in one kernel: grid (BH, num_k_blocks, num_q_blocks),
+    Q innermost.  The dK/dV accumulators of one k-block stay in VMEM across
+    its q-blocks; dQ accumulates across the k-blocks into an fp32
+    [Sqp, D] scratch that stays a whole batch-head row (the TPU grid runs
+    in order), and leaves in the input dtype during the last k-block: five
+    block matmuls and one exp a step, where a dq and a dkv kernel take
+    seven and two.
 
-    bi = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_kb = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = _packed_col(lse_ref, qi)    # [block_q, 1] (packed residual)
-    dvec = _packed_col(dvec_ref, qi)  # [block_q, 1]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q, block_k,
-                       seq_k, causal, causal_offset)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - dvec) * scale
-    acc_scr[:] = acc_scr[:] + jnp.dot(
-        ds.astype(k.dtype), k, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(ki == num_kb - 1)
-    def _finalize():
-        dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                          dvec_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                          *, causal, scale, block_q, block_k, seq_k,
-                          causal_offset):
-    """dK/dV: grid (BH, num_k_blocks, num_q_blocks), Q innermost; the
-    dK/dV accumulators for one k block stay in VMEM across all Q blocks."""
+    The scores are built transposed, [block_k, block_q]: P^T and dS^T are
+    then the left operands of plain matmuls for dV and dK, the packed lse/D
+    rows broadcast down the sublanes as they lie, and dQ contracts dS^T
+    with K over the key dimension of both (no transposed copy).  Under
+    `causal` the q-blocks that end before the k-block's first key do not
+    run, and _bwd_call holds the q/dO index at the first that does.  The
+    scale of dS is applied once, to the fp32 accumulators."""
     import jax.experimental.pallas as pl
 
     bi = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    num_qb = pl.num_programs(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = _packed_col(lse_ref, qi)
-    dvec = _packed_col(dvec_ref, qi)
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[1]), jnp.float32)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q, block_k,
-                       seq_k, causal, causal_offset)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - dvec) * scale
-    dk_scr[:] = dk_scr[:] + jnp.dot(
-        ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32
-    )
-    dv_scr[:] = dv_scr[:] + jnp.dot(
-        p.T.astype(do.dtype), do, preferred_element_type=jnp.float32
-    )
+    def _update(cut):
+        q = q_ref[0]
+        k = k_ref[0]
+        do = do_ref[0]
+        nt = (((1,), (1,)), ((), ()))  # a @ b.T on the contracting dims
+        st = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0, qi, :].reshape(1, -1))
+        if cut:
+            pt = jnp.where(
+                _block_mask(klen_ref, bi, qi, ki, st.shape, block_q, block_k,
+                            seq_k, causal, causal_offset, transposed=True),
+                pt, 0.0)
+        dpt = jax.lax.dot_general(v_ref[0], do, nt,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - dvec_ref[0, qi, :].reshape(1, -1))).astype(q.dtype)
+        dv_scr[:] = dv_scr[:] + jnp.dot(
+            pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dk_scr[:] = dk_scr[:] + jnp.dot(
+            dst, q, preferred_element_type=jnp.float32)
+        dq_scr[rows, :] = dq_scr[rows, :] + jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(qi == num_qb - 1)
+    _when_runs(*_step_cases(
+        klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+        seq_k=seq_k, causal_offset=causal_offset), _update)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _finalize_dq():
+        dq_ref[0, rows, :] = (dq_scr[rows, :] * scale).astype(dq_ref.dtype)
 
 
 def _pad_seq(x, to):
@@ -489,7 +552,7 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
                   interpret=False, need_lse=True):
     """Returns (out [B,H,Sq,D], lse [B*H, num_q_blocks, block_q] fp32
     per-row logsumexp in the PACKED residual layout — see the module
-    comment; _pallas_flash_bwd reads block_q off its shape).
+    comment; _pallas_flash_bwd re-cuts it to its own q-block).
     need_lse=False (inference / the recompute-jax backward) skips the lse
     output entirely — its HBM write is pure waste when nothing consumes
     it — and returns (out, None).  The blocks come from _plan_blocks;
@@ -523,79 +586,94 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
         out = out[:, :, :Sq]
     if not need_lse:
         return out, None
-    return out, res[1]  # packed [B*H, nqb, bq]; the bwd reads it as-is
+    return out, res[1]  # packed [B*H, nqb, bq]; the bwd re-cuts it (_repack)
+
+
+def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb):
+    """The q/dO block a causal dK/dV grid step (ki, qi) holds: its own from
+    the first q-block whose last row sees k-block ki's first key; before
+    that one (the kernel skips those steps) the index waits there, so the
+    first block that runs is the only one fetched."""
+    first = jnp.maximum(ki * block_k - causal_offset, 0) // block_q
+    return jnp.maximum(qi, jnp.minimum(first, nqb - 1))
 
 
 @functools.lru_cache(maxsize=128)
-def _bwd_calls(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
-               causal_offset, q_dtype, k_dtype, v_dtype, interpret):
-    """Memoized (dq_call, dkv_call) pair — see _fwd_call."""
+def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
+              causal_offset, q_dtype, k_dtype, v_dtype, interpret):
+    """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    common = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
-                  seq_k=seq_k, causal_offset=causal_offset)
-    smem = pl.BlockSpec((bh,), lambda *_: (0,), memory_space=pltpu.SMEM)
     nqb = sqp // bq
     # packed lse/dvec residuals: the whole (tiny) [nqb, bq] plane for
-    # batch-head row b rides in VMEM; kernels read their q-block's row
-    packed = pl.BlockSpec((1, nqb, bq), lambda b, *_: (b, 0, 0))
+    # batch-head row b rides in VMEM; the kernel reads its q-block's row
+    packed = pl.BlockSpec((1, nqb, bq), lambda b, j, i: (b, 0, 0))
+    kv_block = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
 
-    dq_call = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(bh, sqp // bq, skp // bk),
-        in_specs=[
-            smem,
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            packed,
-            packed,
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sqp, d), jnp.dtype(q_dtype)),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )
+    def q_of_kv(b, j, i):
+        if causal:
+            i = _q_block_index(i, j, bq, bk, causal_offset, nqb)
+        return (b, i, 0)
 
-    dkv_call = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(bh, skp // bk, sqp // bq),
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, seq_k=seq_k,
+                          causal_offset=causal_offset),
+        grid=(bh, skp // bk, nqb),
         in_specs=[
-            smem,
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((bh,), lambda b, j, i: (0,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bq, d), q_of_kv),
+            kv_block,
+            kv_block,
+            pl.BlockSpec((1, bq, d), q_of_kv),
             packed,
             packed,
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+            # dQ: one block a batch-head row, written back when b advances
+            pl.BlockSpec((1, sqp, d), lambda b, j, i: (b, 0, 0)),
+            kv_block,
+            kv_block,
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, sqp, d), jnp.dtype(q_dtype)),
             jax.ShapeDtypeStruct((bh, skp, d), jnp.dtype(k_dtype)),
             jax.ShapeDtypeStruct((bh, skp, d), jnp.dtype(v_dtype)),
         ],
         scratch_shapes=[
+            pltpu.VMEM((sqp, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
     )
-    return dq_call, dkv_call
+
+
+def _repack(plane, sq, block_q, fill):
+    """A packed per-row plane [B*H, n, b] re-cut to q-blocks of `block_q`:
+    it is a view of [B*H, n * b], so a block length that divides the same
+    padded length is a free reshape; otherwise the rows past `sq` are cut
+    and padded anew with `fill`."""
+    flat = plane.reshape(plane.shape[0], -1)
+    sqp = -(-sq // block_q) * block_q
+    if flat.shape[1] != sqp:
+        flat = jnp.pad(flat[:, :sq], ((0, 0), (0, sqp - sq)),
+                       constant_values=fill)
+    return flat.reshape(plane.shape[0], sqp // block_q, block_q)
 
 
 def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
-                      block_k=128, interpret=False):
+                      block_q=None, block_k=None, interpret=False):
+    """(dq, dk, dv) by _flash_bwd_kernel at the backward's own plan
+    (_plan_bwd_blocks; block_q / block_k pin it for a test or the probe).
+    `lse` is the forward's packed plane, whatever q-block laid it out."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    # the packed lse plane [B*H, nqb, block_q] is laid out by the forward's
-    # plan: the q-block is read off it, so the two cannot disagree
-    bq = lse.shape[2]
-    bk = min(block_k, Sk)
+    plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k),
+                engine="pallas")
+    bq, bk = plan["block_q"], plan["block_k"]
     qp = _pad_seq(q, bq)
     op = _pad_seq(out, bq)
     gp = _pad_seq(g, bq)  # zero-padded dO rows contribute nothing to dK/dV
@@ -608,21 +686,21 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     kf = kp.reshape(B * H, Skp, D)
     vf = vp.reshape(B * H, Skp, D)
     klen_bh = jnp.repeat(klen, H)
+    # a padded row's lse is the fully-masked row's: exp(s - lse) is 0
+    lse = _repack(lse, Sq, bq, -NEG_INF)
     # D_i = rowsum(dO * O): one fused elementwise+reduce pass, fp32,
     # reshaped (a free, layout-preserving view) straight into the packed
-    # [B*H, nqb, bq] residual layout the kernels index — no lane
+    # [B*H, nqb, bq] residual layout the kernel indexes — no lane
     # broadcast ever materializes (the old [B*H, Sqp, 128] operands were
     # 128x the payload and did NOT fuse away: custom-call operands are
     # materialized in HBM)
     dvec = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     dvec = dvec.reshape(B * H, Sqp // bq, bq)
 
-    dq_call, dkv_call = _bwd_calls(
-        B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk, Sk - Sq,
-        str(q.dtype), str(k.dtype), str(v.dtype), interpret,
-    )
-    dq = dq_call(klen_bh, qf, kf, vf, gf, lse, dvec)
-    dk, dv = dkv_call(klen_bh, qf, kf, vf, gf, lse, dvec)
+    call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk, Sk - Sq,
+                     str(q.dtype), str(k.dtype), str(v.dtype), interpret)
+    with span("flash.bwd_plan", **plan):  # at lowering, as flash.plan
+        dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
 
     dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
     dk = dk.reshape(B, H, Skp, D)[:, :, :Sk]
@@ -645,97 +723,94 @@ def _use_pallas(force: str) -> bool:
     return force == "pallas" or (force == "auto" and _on_tpu())
 
 
-def _pallas_bwd_enabled(force: str) -> bool:
-    """The dq/dkv kernels run in backward only when asked: force
-    'interpret' (CPU correctness tests) or FLAGS_flash_bwd=pallas.  The
-    default is the recompute-jax backward (module docstring)."""
+# The backward's engine is read from the shape: the Pallas kernel where
+# the plan's score block [block_k, block_q] has at least this many scores
+# over which to spread what a grid step costs before it computes anything
+# (and the row's dQ fits VMEM at all), the XLA recompute backward elsewhere.
+# Settled on the chip by tools/flash_bwd_probe.py (PERF.md, PR 30).
+_BWD_PALLAS_MIN_BLOCK_SCORES = 384 * 384
+
+
+def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None):
+    """What the backward of one attention call of this shape is given, the
+    `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
+    the probe, else _plan_bwd_blocks'), steps and steps_skipped (static,
+    over one batch-head row) and engine, "pallas" or "xla": the one place
+    that says which."""
+    bq, bk = _plan_bwd_blocks(sq, sk, head_dim, dtype, causal)
+    fits = bwd_working_set_bytes(
+        bq, bk, head_dim, -(-sq // bq), dtype) <= _PLAN_VMEM_BUDGET
+    engine = ("pallas" if fits and bq * bk >= _BWD_PALLAS_MIN_BLOCK_SCORES
+              else "xla")
+    bq = bq if block_q is None else min(block_q, sq)
+    bk = bk if block_k is None else min(block_k, sk)
+    nqb, nkb = -(-sq // bq), -(-sk // bk)
+    skipped = _skipped_k_steps(nqb, nkb, bq, bk, sk - sq) if causal else 0
+    return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=bq, block_k=bk,
+                steps=nqb * nkb, steps_skipped=skipped, engine=engine)
+
+
+def _pallas_backward(q, k, causal, force) -> bool:
+    """Whether this call's backward runs the Pallas kernel (and so its
+    forward emits lse): by the shape under "auto"/"pallas", always under
+    "interpret" (the CPU tests' door), never under "jax"."""
     if force == "interpret":
         return True
-    if force == "jax":
-        return False
-    from .. import flags
+    return _use_pallas(force) and _bwd_plan(
+        q.shape[2], k.shape[2], q.shape[3], q.dtype, causal
+    )["engine"] == "pallas"
 
-    return flags.flag("flash_bwd") == "pallas"
+
+def _forward(q, k, v, klen, causal, scale, force, need_lse):
+    """(out, packed lse or None) by the engine `force` names."""
+    if _use_pallas(force) or force == "interpret":
+        return _pallas_flash(q, k, v, klen, causal, scale,
+                             interpret=(force == "interpret"),
+                             need_lse=need_lse)
+    return _reference_attention(
+        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)), None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash(q, k, v, klen, causal, scale, force):
     # klen rides as float32 so custom_vjp treats it uniformly (zero grad)
-    if _use_pallas(force):
-        return _pallas_flash(q, k, v, klen, causal, scale,
-                             need_lse=False)[0]
-    if force == "interpret":
-        return _pallas_flash(q, k, v, klen, causal, scale, interpret=True,
-                             need_lse=False)[0]
-    return _reference_attention(
-        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)
-    )
+    return _forward(q, k, v, klen, causal, scale, force, need_lse=False)[0]
 
 
 def _flash_fwd(q, k, v, klen, causal, scale, force):
-    if _use_pallas(force) or force == "interpret":
-        interp = force == "interpret"
-        need = _pallas_bwd_enabled(force)
-        out, lse = _pallas_flash(q, k, v, klen, causal, scale,
-                                 interpret=interp, need_lse=need)
-        if need:
-            return out, (q, k, v, klen, out, lse)
-        # recompute-jax backward: don't hold O/L as residuals (and the
-        # forward call above skipped the lse HBM write entirely)
-        return out, (q, k, v, klen, None, None)
-    out = _reference_attention(
-        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)
-    )
-    return out, (q, k, v, klen, None, None)
+    # the XLA recompute backward holds neither O nor L as residuals, and
+    # its forward skips the lse HBM write entirely
+    out, lse = _forward(q, k, v, klen, causal, scale, force,
+                        need_lse=_pallas_backward(q, k, causal, force))
+    return out, (q, k, v, klen, None if lse is None else out, lse)
 
 
 def _flash_bwd(causal, scale, force, res, g):
     q, k, v, klen, out, lse = res
-    if lse is not None:
-        dq, dk, dv = _pallas_flash_bwd(
-            q, k, v, klen, out, lse, g, causal, scale,
-            interpret=(force == "interpret"),
+    with jax.named_scope("flash.bwd"):
+        if lse is not None:
+            dq, dk, dv = _pallas_flash_bwd(
+                q, k, v, klen, out, lse, g, causal, scale,
+                interpret=(force == "interpret"),
+            )
+            return dq, dk, dv, jnp.zeros_like(klen)
+        if _use_pallas(force):
+            # at lowering, beside flash.plan: the site keeps the XLA engine
+            with span("flash.bwd_plan", **_bwd_plan(
+                    q.shape[2], k.shape[2], q.shape[3], q.dtype, causal)):
+                pass
+        # recompute-backward: differentiate the reference formulation
+        _, vjp = jax.vjp(
+            lambda q_, k_, v_: _reference_attention(
+                q_, k_, v_, causal, scale, k_lengths=klen.astype(jnp.int32)
+            ),
+            q, k, v,
         )
+        dq, dk, dv = vjp(g)
         return dq, dk, dv, jnp.zeros_like(klen)
-    # recompute-backward: differentiate the reference formulation
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _reference_attention(
-            q_, k_, v_, causal, scale, k_lengths=klen.astype(jnp.int32)
-        ),
-        q, k, v,
-    )
-    dq, dk, dv = vjp(g)
-    return dq, dk, dv, jnp.zeros_like(klen)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-def _jaxlib_flash(q, k, v, k_lengths, causal, scale):
-    """Route through the jax-shipped TPU pallas flash attention
-    (jax.experimental.pallas.ops.tpu.flash_attention) — a maintained
-    fwd+bwd kernel pair with its own custom_vjp.  Selected by
-    FLAGS_flash_bwd=jaxlib on TPU: an alternative to this module's
-    hand-written backward (tools/flash_bwd_probe.py stage 4 compares
-    them)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes, SegmentIds, flash_attention as jx_flash)
-
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    seg = None
-    if k_lengths is not None:
-        kl = jnp.asarray(k_lengths, jnp.int32).reshape(-1)
-        # key-padding semantics: q rows all live (segment 1), padded key
-        # positions get segment 2 -> mismatch masks them, matching this
-        # module's klen contract
-        kvseg = jnp.where(
-            jnp.arange(Sk)[None, :] < kl[:, None], 1, 2
-        ).astype(jnp.int32)
-        seg = SegmentIds(q=jnp.ones((B, Sq), jnp.int32), kv=kvseg)
-    bs = BlockSizes.get_default(B, H, Sq, Sk, D)
-    return jx_flash(q, k, v, segment_ids=seg, causal=causal,
-                    sm_scale=float(scale), block_sizes=bs)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
@@ -744,16 +819,10 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
     (key-padding mask).
 
     force: "auto" (pallas on TPU, jax elsewhere), "pallas", "interpret"
-    (pallas interpreter — CPU testing), or "jax".  Under force="auto" on
-    TPU, FLAGS_flash_bwd=jaxlib swaps in the jax-shipped kernel pair
-    (fwd AND bwd) instead of this module's kernels."""
+    (pallas interpreter — CPU testing), or "jax".  The backward's engine
+    is read from the shape (_bwd_plan): never from a flag."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if force == "auto" and _on_tpu():
-        from .. import flags
-
-        if flags.flag("flash_bwd") == "jaxlib":
-            return _jaxlib_flash(q, k, v, k_lengths, causal, scale)
     if k_lengths is None:
         klen = jnp.full((q.shape[0],), k.shape[2], dtype=jnp.float32)
     else:

@@ -305,11 +305,13 @@ def test_compile_cache_coldstart_cross_process(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the flags tier after the conv-epilogue arms went (PR 29)
+# the flags tier after the conv-epilogue arms (PR 29) and the flash
+# backward's flag (PR 30: the engine is read from the shape) went
 
 
 @pytest.mark.parametrize("name", ["FLAGS_conv_epilogue",
-                                  "FLAGS_fuse_conv_epilogue"])
+                                  "FLAGS_fuse_conv_epilogue",
+                                  "FLAGS_flash_bwd"])
 def test_removed_flag_is_unknown_and_ignored_in_environment(name,
                                                             monkeypatch):
     """A removed flag raises KeyError where the program sets or reads it
@@ -327,19 +329,19 @@ def test_removed_flag_is_unknown_and_ignored_in_environment(name,
     assert fluid.get_flags() == before and name not in before
 
 
-def test_trace_key_has_exactly_three_entries():
-    """layout (resolved), FLAGS_flash_bwd, FLAGS_check_numerics: what
-    changes the traced program or its executable, and nothing else."""
+def test_trace_key_has_exactly_two_entries():
+    """layout (resolved) and FLAGS_check_numerics: what changes the traced
+    program or its executable, and nothing else."""
     from paddle_tpu import flags as flagmod
 
-    assert flagmod.trace_key() == ("NCHW", "jax", False)
+    assert flagmod.trace_key() == ("NCHW", False)
     with flagmod.tpu_trace_scope(True):
-        assert flagmod.trace_key() == ("NHWC", "jax", False)
+        assert flagmod.trace_key() == ("NHWC", False)
+    assert len(flagmod._DEFS) == 24
 
 
 @pytest.mark.parametrize("name,value,default", [
     ("FLAGS_conv_layout", "NHWC", "auto"),
-    ("FLAGS_flash_bwd", "pallas", "jax"),
     ("FLAGS_check_numerics", True, False),
 ])
 def test_trace_key_flag_flip_lands_on_another_cached_entry(name, value,

@@ -27,19 +27,32 @@ def _qkv(seed, B, H, Sq, Sk, D, dtype):
             jnp.asarray(rng.randn(B, H, Sk, D), dtype))
 
 
-def _plan_spans(fn, *args):
-    """The flash.plan spans that lowering `fn` leaves (abstractly: nothing
-    compiles or runs)."""
+def _spans(name, fn, *args):
+    """The args of the spans called `name` that lowering `fn` leaves
+    (abstractly: nothing compiles or runs)."""
     observability.reset()
     was = fluid.flags._VALUES["FLAGS_observability"]
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
         jax.eval_shape(fn, *args)
         return [s.args for s in observability.default_tracer().spans()
-                if s.name == "flash.plan"]
+                if s.name == name]
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = was
         observability.reset()
+
+
+def _plan_spans(fn, *args):
+    return _spans("flash.plan", fn, *args)
+
+
+def _bwd_spans(fn, *args):
+    return _spans("flash.bwd_plan", fn, *args)
+
+
+def _tol(dtype, fp32):
+    """bf16 operands against an fp32 reference, or `fp32`'s tolerances."""
+    return dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16 else fp32
 
 
 # (a) planned blocks against the reference and against the same kernel
@@ -248,52 +261,308 @@ def test_plan_reads_the_shape_and_nothing_else():
         declared + 2 * 512 * 512 * 4
 
 
-# (c) the backward follows the forward's plan --------------------------------
+# (c) the backward: a plan of its own, both directions skipped, the engine
+# read from the shape (PR 30) ----------------------------------------------
 
-@pytest.mark.parametrize("backward", ["jax", "pallas"])
-def test_gradient_matches_reference_at_a_planned_block_q(backward,
-                                                         monkeypatch):
-    """The packed lse plane [B*H, nqb, block_q] is laid out by the forward's
-    plan; the dq/dkv kernels read block_q off it."""
+def _reference_grads(q, k, v, g, klen, causal, scale):
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    _, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)), *f32)
+    return vjp(g.astype(jnp.float32))
+
+
+# name: (B, H, Sq, Sk, D, dtype, causal, k_lengths, forward's pinned
+# (block_q, block_k) or None for its plan, backward's pinned pair or None)
+BACKWARD_CASES = {
+    # blocks that straddle the diagonal, wide and tall
+    "causal_diagonal_wide_blocks": (1, 2, 256, 256, 64, jnp.float32, True,
+                                    None, None, (64, 128)),
+    "causal_diagonal_tall_blocks": (1, 2, 256, 256, 64, jnp.float32, True,
+                                    None, None, (128, 64)),
+    # a cached prefix: the diagonal is bottom-right aligned (offset 256)
+    "causal_sk_longer_offset": (2, 1, 128, 384, 64, jnp.float32, True,
+                                [384, 300], None, (64, 128)),
+    # more queries than keys: the first q-blocks see no key at all
+    "causal_sq_longer_rows_without_keys": (2, 1, 384, 128, 64, jnp.float32,
+                                           True, [128, 100], None, (128, 64)),
+    # klen inside a k-block, before a whole k-block, and at the end
+    "noncausal_klen_cuts_blocks": (3, 1, 256, 512, 64, jnp.float32, False,
+                                   [300, 100, 512], None, (128, 128)),
+    "causal_padded_last_block": (2, 1, 200, 200, 64, jnp.float32, True,
+                                 [200, 150], None, (128, 128)),
+    "row_without_keys": (2, 1, 200, 200, 64, jnp.float32, False, [200, 0],
+                         None, None),
+    "planned_blocks_s1024_bf16": (1, 1, 1024, 1024, 128, jnp.bfloat16, True,
+                                  None, None, None),
+    "pinned_blocks_bf16": (1, 2, 512, 512, 128, jnp.bfloat16, True, None,
+                           None, (128, 256)),
+    # the backward's q-block is not the forward's: the packed lse plane is
+    # re-cut, for free where both divide one padded length ...
+    "bwd_q_block_shorter_than_fwd": (1, 2, 256, 256, 64, jnp.float32, True,
+                                     None, (256, 256), (64, 128)),
+    "bwd_q_block_longer_than_fwd": (1, 2, 256, 256, 64, jnp.float32, True,
+                                    None, (64, 64), (256, 128)),
+    # ... and by cutting and padding anew where they pad to other lengths
+    "bwd_pads_to_another_length": (1, 2, 200, 200, 64, jnp.float32, True,
+                                   None, (128, 128), (40, 40)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+def test_backward_kernels_match_the_reference_vjp(case):
+    B, H, Sq, Sk, D, dtype, causal, lengths, fwd_pin, bwd_pin = \
+        BACKWARD_CASES[case]
+    q, k, v = _qkv(17, B, H, Sq, Sk, D, dtype)
+    g = jnp.asarray(np.random.RandomState(18).randn(B, H, Sq, D), dtype)
+    klen = jnp.asarray(lengths if lengths is not None else [Sk] * B,
+                       jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+    fq, fk = fwd_pin or (None, None)
+    bq, bk = bwd_pin or (None, None)
+    out, lse = fa._pallas_flash(q, k, v, klen, causal, scale, block_q=fq,
+                                block_k=fk, interpret=True)
+    got = fa._pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
+                               block_q=bq, block_k=bk, interpret=True)
+    want = _reference_grads(q, k, v, g, klen, causal, scale)
+    tol = _tol(dtype, dict(rtol=2e-4, atol=2e-5))
+    for name, x, w in zip("qkv", got, want):
+        assert x.dtype == dtype and x.shape == w.shape
+        np.testing.assert_allclose(np.asarray(x.astype(jnp.float32)),
+                                   np.asarray(w), err_msg="d" + name, **tol)
+    if lengths is not None and 0 in lengths:
+        row = lengths.index(0)
+        assert not any(np.any(np.asarray(x)[row]) for x in got)
+    if bwd_pin is None and max(Sq, Sk) > 512:    # a plan of its own
+        assert fa._plan_bwd_blocks(Sq, Sk, D, dtype, causal) != \
+            fa._plan_blocks(Sq, Sk, D, dtype, causal, True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gradient_through_the_custom_vjp_matches_reference(dtype):
+    """force="interpret" keeps the Pallas backward at every shape, here one
+    the rule would give to XLA; force="jax" keeps none."""
     B, H, S, D = 1, 2, 256, 64
-    assert fa._plan_blocks(S, S, D, jnp.float32, True, True)[0] == 256
-    if backward == "jax":   # force="interpret" alone always picks pallas
-        monkeypatch.setattr(fa, "_pallas_bwd_enabled", lambda force: False)
-    q, k, v = _qkv(11, B, H, S, S, D, jnp.float32)
+    assert fa._bwd_plan(S, S, D, dtype, True)["engine"] == "xla"
+    q, k, v = _qkv(11, B, H, S, S, D, dtype)
     klen = jnp.asarray([200.0])
     w = jnp.asarray(np.random.RandomState(12).randn(B, H, S, D), jnp.float32)
 
-    def loss(attend):
-        return lambda q, k, v: jnp.sum(attend(q, k, v) * w)
+    def grads(attend):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * w), argnums=(0, 1, 2))
 
-    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=True, k_lengths=klen, force="interpret")),
-        argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(lambda q, k, v: fa._reference_attention(
-        q, k, v, True, 1.0 / np.sqrt(D), k_lengths=klen.astype(jnp.int32))),
-        argnums=(0, 1, 2))(q, k, v)
-    for g, w_ in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w_), rtol=2e-4,
-                                   atol=2e-5)
+    want = grads(lambda q, k, v: fa._reference_attention(
+        q, k, v, True, 1.0 / np.sqrt(D), k_lengths=klen.astype(jnp.int32)))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    tol = _tol(dtype, dict(rtol=2e-4, atol=2e-5))
+    for force, sites in (("interpret", 1), ("jax", 0)):
+        attend = lambda q, k, v: fa.flash_attention(     # noqa: E731
+            q, k, v, causal=True, k_lengths=klen, force=force)
+        spans = _bwd_spans(grads(attend), q, k, v)
+        assert [s["engine"] for s in spans] == ["pallas"] * sites
+        for g, w_ in zip(grads(attend)(q, k, v), want):
+            np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)),
+                                       np.asarray(w_), **tol)
 
 
-def test_pallas_backward_reads_block_q_off_the_packed_lse():
-    q, k, v = _qkv(13, 1, 1, 256, 256, 64, jnp.float32)
-    klen = jnp.full((1,), 256, jnp.float32)
-    out, lse = fa._pallas_flash(q, k, v, klen, True, 0.125, interpret=True)
-    assert lse.shape == (1, 1, 256)         # one q-block of the planned 256
-    out128, lse128 = fa._pallas_flash(q, k, v, klen, True, 0.125,
-                                      block_q=128, block_k=128,
-                                      interpret=True)
-    assert lse128.shape == (1, 2, 128)
-    np.testing.assert_allclose(np.asarray(lse).reshape(-1),
-                               np.asarray(lse128).reshape(-1), rtol=1e-5)
-    g = jnp.ones_like(out)
-    for o, l in ((out, lse), (out128, lse128)):
-        grads = fa._pallas_flash_bwd(q, k, v, klen, o, l, g, True, 0.125,
-                                     interpret=True)
-        _, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
-            q, k, v, True, 0.125), q, k, v)
-        for got, want in zip(grads, vjp(g)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       rtol=2e-4, atol=2e-5)
+def test_first_q_block_of_a_k_block_is_the_dense_masks():
+    """q innermost: before the first q-block that sees k-block j the q/dO
+    index waits at that block; from it on, it is the step's own."""
+    for sq, sk, bq, bk in SMALL:
+        visible = _dense_blocks_visible(sq, sk, bq, bk)
+        nqb, nkb = visible.shape
+        for j in range(nkb):
+            ran = [i for i in range(nqb) if visible[i, j]]
+            for i in range(nqb):
+                at = int(fa._q_block_index(jnp.int32(i), jnp.int32(j), bq, bk,
+                                           sk - sq, nqb))
+                want = i if (ran and i >= min(ran)) else (
+                    min(ran) if ran else nqb - 1)
+                assert at == want, (sq, sk, bq, bk, i, j)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", SKIP_CASES)
+def test_backward_computes_exactly_the_blocks_with_a_visible_element(
+        sq, sk, bq, bk):
+    """Behaviour, in both directions.  Poison V's k-block j: q-block i's dQ
+    is NaN if and only if step (i, j) runs.  Poison dO's q-block i: k-block
+    j's dK and dV are NaN if and only if step (i, j) runs."""
+    q, k, v = _qkv(5, 1, 1, sq, sk, 8, jnp.float32)
+    g = jnp.asarray(np.random.RandomState(6).randn(1, 1, sq, 8), jnp.float32)
+    klen = jnp.full((1,), sk, jnp.float32)
+    visible = _dense_blocks_visible(sq, sk, bq, bk)
+    out, lse = fa._pallas_flash(q, k, v, klen, True, 0.35, block_q=bq,
+                                block_k=bk, interpret=True)
+
+    def bwd(v, g):
+        return fa._pallas_flash_bwd(q, k, v, klen, out, lse, g, True, 0.35,
+                                    block_q=bq, block_k=bk, interpret=True)
+
+    def blocks_hit(x, block):
+        hit = np.isnan(np.asarray(x)[0, 0]).reshape(-1, block, 8)
+        assert (hit.all(axis=(1, 2)) == hit.any(axis=(1, 2))).all()
+        return hit.any(axis=(1, 2))
+
+    for j in range(sk // bk):
+        dq, _, _ = bwd(v.at[:, :, j * bk:(j + 1) * bk].set(jnp.nan), g)
+        np.testing.assert_array_equal(blocks_hit(dq, bq), visible[:, j])
+    for i in range(sq // bq):
+        _, dk, dv = bwd(v, g.at[:, :, i * bq:(i + 1) * bq].set(jnp.nan))
+        np.testing.assert_array_equal(blocks_hit(dk, bk), visible[i])
+        np.testing.assert_array_equal(blocks_hit(dv, bk), visible[i])
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", SKIP_CASES)
+def test_bwd_plan_span_counts_equal_the_dense_count(sq, sk, bq, bk):
+    x = jax.ShapeDtypeStruct((1, 1, sq, 8), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 1, sk, 8), jnp.float32)
+    klen = jax.ShapeDtypeStruct((1,), jnp.float32)
+
+    def bwd(q, k, v, klen):
+        out, lse = fa._pallas_flash(q, k, v, klen, True, 0.35, interpret=True)
+        return fa._pallas_flash_bwd(q, k, v, klen, out, lse, q, True, 0.35,
+                                    block_q=bq, block_k=bk, interpret=True)
+
+    visible = _dense_blocks_visible(sq, sk, bq, bk)
+    # static counts over one batch-head row
+    assert _bwd_spans(bwd, x, kv, kv, klen) == [dict(
+        sq=sq, sk=sk, head_dim=8, block_q=bq, block_k=bk,
+        steps=visible.size, steps_skipped=int((~visible).sum()),
+        engine="pallas")]
+
+
+def test_repack_is_a_view_where_the_padded_lengths_agree():
+    plane = jnp.arange(2 * 4 * 128, dtype=jnp.float32).reshape(2, 4, 128)
+    for bq in (128, 256, 512):
+        np.testing.assert_array_equal(
+            np.asarray(fa._repack(plane, 500, bq, 9.0)).reshape(2, -1),
+            np.asarray(plane).reshape(2, -1))
+    cut = np.asarray(fa._repack(plane, 300, 100, 9.0))
+    assert cut.shape == (2, 3, 100)
+    np.testing.assert_array_equal(cut.reshape(2, -1),
+                                  np.asarray(plane).reshape(2, -1)[:, :300])
+    grown = np.asarray(fa._repack(plane[:, :1], 100, 40, 9.0))
+    assert grown.shape == (2, 3, 40)
+    assert (grown.reshape(2, -1)[:, 100:] == 9.0).all()
+    np.testing.assert_array_equal(grown.reshape(2, -1)[:, :100],
+                                  np.asarray(plane)[:, 0, :100])
+
+
+# the backward's plan and the rule that reads the engine off it -------------
+
+@pytest.mark.parametrize("sq,sk,d,dtype", PLAN_SHAPES)
+@pytest.mark.parametrize("causal", [False, True])
+def test_bwd_plan_is_tiled_inside_its_share(sq, sk, d, dtype, causal):
+    bq, bk = fa._plan_bwd_blocks(sq, sk, d, dtype, causal)
+    for s, b in ((sq, bq), (sk, bk)):
+        if s <= 128:
+            assert b == s
+        else:
+            assert b % 128 == 0
+            assert -(-s // b) * b == -(-s // 128) * 128
+    ws = fa.bwd_working_set_bytes(bq, bk, d, -(-sq // bq), dtype)
+    assert ws <= fa._PLAN_VMEM_BUDGET or (bq, bk) == (128, 128)
+    # four fp32 score planes where the forward counts two
+    assert ws - 4 * bq * bk * 4 > 0
+    fwd = fa._plan_blocks(sq, sk, d, dtype, causal, True)
+    assert bq * bk <= fwd[0] * fwd[1]
+
+
+# (B*H is not the rule's to read) name: (Sq, Sk, D, dtype, causal, engine)
+ENGINE_BY_SHAPE = {
+    "ouro_2.6b_self": (2048, 2048, 128, "bfloat16", True, "pallas"),
+    "transformer_base_decoder_self": (256, 256, 64, "bfloat16", True, "xla"),
+    "transformer_base_encoder_self": (256, 256, 64, "bfloat16", False,
+                                      "xla"),
+    "transformer_base_cross": (256, 256, 64, "bfloat16", False, "xla"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_BY_SHAPE))
+def test_engine_is_read_from_the_shape(case):
+    sq, sk, d, dtype, causal, engine = ENGINE_BY_SHAPE[case]
+    plan = fa._bwd_plan(sq, sk, d, dtype, causal)
+    assert plan["engine"] == engine
+    assert fa._bwd_plan(sq, sk, d, dtype, causal) == plan   # a pure function
+    assert (plan["block_q"], plan["block_k"]) == fa._plan_bwd_blocks(
+        sq, sk, d, dtype, causal)
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_BY_SHAPE))
+def test_lowered_tpu_text_carries_the_backward_kernels_by_shape(case):
+    """What a TPU program gets (flags.tpu_trace_scope, force="auto"): the
+    forward kernel at every shape; the backward kernel's custom call at the
+    ouro-2.6b shape and at none of transformer-base's three."""
+    sq, sk, d, dtype, causal, engine = ENGINE_BY_SHAPE[case]
+    ragged = "encoder" in case or "cross" in case
+    q = jax.ShapeDtypeStruct((2, 2, sq, d), jnp.dtype(dtype))
+    kv = jax.ShapeDtypeStruct((2, 2, sk, d), jnp.dtype(dtype))
+    klen = jax.ShapeDtypeStruct((2,), jnp.float32)
+
+    def loss(q, k, v, klen):
+        o = fa.flash_attention(q, k, v, causal=causal,
+                               k_lengths=klen if ragged else None)
+        return jnp.sum(o.astype(jnp.float32))
+
+    with fluid.flags.tpu_trace_scope(True):
+        # the loss is returned too: a step keeps its forward
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).trace(
+            q, kv, kv, klen).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == (2 if engine == "pallas" else 1)
+    assert ("_flash_bwd_kernel" in text) == (engine == "pallas")
+
+
+def _step_bwd_spans(spec, feed):
+    """flash.bwd_plan spans of one training step lowered abstractly for the
+    TPU (nothing compiles or runs)."""
+    def lower():
+        with fluid.flags.tpu_trace_scope(True):
+            compiled, feed_vals, state_vals, rng = fluid.Executor(
+                fluid.CPUPlace()).capture_program(
+                    fluid.default_main_program(), feed=feed)
+            return compiled.raw_fn(feed_vals, state_vals, rng)
+
+    return _bwd_spans(lower)
+
+
+def test_ouro_body_has_four_pallas_backward_sites():
+    """ouro-2.6b's attention shape (S 2048, head 128, causal) at a width cut
+    to two heads: the body's four layers are four sites, lowered once for
+    any trip count, and every one takes the Pallas backward."""
+    from paddle_tpu import models
+
+    fluid.reset_default_env()
+    spec = models.looped_decoder(models.LoopedDecoderConfig(
+        vocab_size=64, max_length=2048, n_layer=4, n_head=2, head_dim=128,
+        d_model=256, d_inner=64, loop_steps=4, exit_gate=True,
+        use_recompute=True))
+    fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(spec.loss)
+    fluid.Executor(fluid.CPUPlace()).run(fluid.default_startup_program())
+    ids = np.zeros((2, 2049), np.int64)
+    tokens, labels = spec.feed_names
+    spans = _step_bwd_spans(spec, {tokens: ids[:, :-1], labels: ids[:, 1:]})
+    assert len(spans) == 4
+    want = fa._bwd_plan(2048, 2048, 128, jnp.bfloat16, True)
+    assert want["engine"] == "pallas" and want["steps_skipped"] > 0
+    assert all(s == want for s in spans)
+
+
+def test_transformer_base_has_eighteen_xla_backward_sites():
+    """transformer-base's three attention shapes (S 256, head 64) at a width
+    cut to two heads: 6 encoder self, 6 decoder self, 6 cross, every one
+    left to the XLA recompute backward."""
+    from paddle_tpu import models
+
+    fluid.reset_default_env()
+    spec = models.transformer(models.TransformerConfig(
+        src_vocab_size=64, trg_vocab_size=64, max_length=256, n_layer=6,
+        n_head=2, d_model=128, d_inner=64, dropout=0.1, label_smooth_eps=0.1,
+        use_flash_attention=True, fuse_qkv=True))
+    fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(spec.loss)
+    fluid.Executor(fluid.CPUPlace()).run(fluid.default_startup_program())
+    words = np.ones((2, 256), np.int64)
+    spans = _step_bwd_spans(spec, {n: words for n in spec.feed_names})
+    assert len(spans) == 18
+    assert {s["engine"] for s in spans} == {"xla"}
+    assert {(s["sq"], s["sk"], s["head_dim"]) for s in spans} == \
+        {(256, 256, 64)}

@@ -106,26 +106,6 @@ def test_fused_test_mode_uses_moving_stats():
         np.asarray(fluid.global_scope().find_var("bn_mean")), mean_before)
 
 
-def test_flash_bwd_jaxlib_flag_accepted_cpu_fallback():
-    """FLAGS_flash_bwd=jaxlib routes to the jax-shipped TPU kernel pair on
-    TPU only; on CPU the flag is accepted and attention falls back to the
-    recompute-jax path with unchanged numerics."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.kernels.flash_attention import flash_attention
-
-    q = jnp.asarray(np.random.RandomState(0).randn(1, 2, 16, 8),
-                    jnp.float32)
-    base = flash_attention(q, q, q, causal=True)
-    fluid.set_flags({"FLAGS_flash_bwd": "jaxlib"})
-    try:
-        out = flash_attention(q, q, q, causal=True)
-    finally:
-        fluid.set_flags({"FLAGS_flash_bwd": "jax"})
-    np.testing.assert_allclose(np.asarray(out), np.asarray(base),
-                               rtol=1e-6, atol=1e-7)
-
-
 def test_fused_bn_fuzz_parity_vs_composed_ops():
     """Seeded fuzz: random shapes / eps / momentum / residual presence /
     act, fwd + one SGD step, fused op vs the composed batch_norm +

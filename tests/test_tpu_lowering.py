@@ -68,8 +68,10 @@ class TestFlashLowering:
         _tpu_lowers(f, q)
 
     @pytest.mark.parametrize("shape", [(16, 16, 256, 256, 64),
-                                       (4, 16, 2048, 2048, 64)])
-    def test_backward_pair(self, shape):
+                                       (4, 16, 2048, 2048, 64),
+                                       (2, 16, 2048, 2048, 128),
+                                       (2, 4, 200, 200, 64)])
+    def test_backward_kernel(self, shape):
         B, H, Sq, Sk, D = shape
         q = jax.ShapeDtypeStruct((B, H, Sq, D), jnp.bfloat16)
 
@@ -101,10 +103,12 @@ class TestFlashLowering:
         txt = exp.mlir_module()
         assert f"tensor<{B * H}x{Sq}x128xf32>" not in txt
         # the packed residual layout is what flows instead, one row a
-        # q-block of the forward's plan
-        bq, _ = fa._plan_blocks(Sq, Sk, D, jnp.bfloat16, True, True)
-        assert bq > 128 and Sq % bq == 0
-        assert f"tensor<{B * H}x{Sq // bq}x{bq}xf32>" in txt
+        # q-block: of the forward's plan where it leaves the forward, of
+        # the backward's own where it enters the backward
+        for bq in (fa._plan_blocks(Sq, Sk, D, jnp.bfloat16, True, True)[0],
+                   fa._plan_bwd_blocks(Sq, Sk, D, jnp.bfloat16, True)[0]):
+            assert bq > 128 and Sq % bq == 0
+            assert f"tensor<{B * H}x{Sq // bq}x{bq}xf32>" in txt
 
 
 class TestConvBnChain:

@@ -1,140 +1,209 @@
-"""Incremental on-chip proof for the pallas flash-attention backward:
-staged, each stage with its own hard deadline, so a kernel that cannot
-compile is diagnosed by the CHEAP stage instead of a full-model compile.
+"""The flash backward alone, timed on the chip: both engines at the cells'
+shapes and along a ladder of sequence lengths, which is where the rule that
+picks the engine from the shape (kernels/flash_attention.py::_bwd_plan) was
+settled.
 
-  stage 1  standalone backward, one block   dq+dkv pallas_calls, S=128
-  stage 2  multi-block backward             S=512, 4x4 grid per kernel
-  stage 3  flash fwd+bwd under jax.grad     the real custom-vjp path, jit
-  stage 4  jax-shipped kernel pair          FLAGS_flash_bwd=jaxlib route
-           (independent implementation: if stages 1-3 fail but 4 passes,
-           bench with jaxlib instead of the in-repo pallas backward)
+  ouro         32 x 2048 x 128 (B 2, H 16), causal           ouro-train-loop4
+  nmt-decoder  768 x 256 x 64  (B 96, H 8), causal           transformer-train
+  nmt-encoder  768 x 256 x 64, not causal, ragged k_lengths  transformer-train
+  s<S>-d<D>    the ladder: S 256 / 384 / 512 / 1024 / 2048 at head 64 and
+               128, causal, B * S held at 24576 (d 64) or 4096 (d 128) tokens
 
-Run:  python tools/flash_bwd_probe.py [stage] [timeout_s]
-Each stage runs in a clean subprocess, one after the other.  The parent
-never imports jax, so each stage has the chip to itself — keep it so: a
-parent that touched jax would hold the chip and every stage would fail or
-hang.  Output is one JSON line per stage:
-{"stage": N, "ok": bool, "wall_s": ..., "detail": ...}.  Stop at the
-first failure — that IS the finding.  Only after all three pass is
-FLAGS_flash_bwd=pallas worth trying on a full bench model.
+For each shape, one JSON line a row:
+  xla          jax.vjp of the reference formulation given (q, k, v, dO): the
+               recompute backward, its score planes through HBM
+  pallas       _flash_bwd_kernel given (q, k, v, O, lse, dO) at the blocks
+               _plan_bwd_blocks gives the shape
+  step-xla / step-pallas
+               forward AND backward through flash_attention's custom_vjp
+               under jax.value_and_grad (what a training step runs: the
+               forward emits lse only for the Pallas backward) with the
+               engine held to one side, and
+  step-rule    the same with the engine the rule reads off the shape
+`--sweep` also pins every block pair the shape admits whose working set is
+under 1.5 x the plan's share; `--parent FILE` times another commit's
+`_pallas_flash_bwd` as it stands (`git show <commit>:paddle_tpu/kernels/
+flash_attention.py > chip_scratch/...`).  Prints ms a call and the TFLOP/s of
+the causal count at 2.5 x the forward's FLOPs (the five block matmuls the
+kernel runs: the recompute of the scores among them), and writes the rows
+to `--out` (chiprun_out/flash_bwd_probe.json).
+
+A tool, run by no benchmark cell:
+    chiprun --chips 1 -- python3 tools/flash_bwd_probe.py --seed 7 [--sweep]
+    JAX_PLATFORMS=cpu python3 tools/flash_bwd_probe.py --rehearse
+`--rehearse` runs tiny shapes through the Pallas interpreter and exits 3:
+its times are not the chip's.  One process holds the chip; it starts no
+child.
 """
 
 from __future__ import annotations
 
+import argparse
+import importlib
+import importlib.util
 import json
+import math
 import os
-import subprocess
 import sys
 import time
 
-STAGE_SRC = {
-    1: r"""
-import time, jax, jax.numpy as jnp, numpy as np
-import importlib
-fa = importlib.import_module('paddle_tpu.kernels.flash_attention')
-B, H, S, D = 1, 1, 128, 64
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
-klen = jnp.full((B,), S, jnp.int32)
-out, lse = fa._pallas_flash(q, q, q, klen, causal=True, scale=0.125)
-g = jnp.ones_like(out)
-t0 = time.perf_counter()
-dq, dk, dv = fa._pallas_flash_bwd(q, q, q, klen, out, lse, g,
-                                  causal=True, scale=0.125)
-jax.block_until_ready((dq, dk, dv))
-print(f"STAGE_OK compile+run {time.perf_counter()-t0:.1f}s", flush=True)
-""",
-    2: r"""
-import time, jax, jax.numpy as jnp, numpy as np
-import importlib
-fa = importlib.import_module('paddle_tpu.kernels.flash_attention')
-B, H, S, D = 2, 4, 512, 64
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
-klen = jnp.full((B,), S, jnp.int32)
-out, lse = fa._pallas_flash(q, q, q, klen, causal=True, scale=0.125)
-g = jnp.ones_like(out)
-t0 = time.perf_counter()
-dq, dk, dv = fa._pallas_flash_bwd(q, q, q, klen, out, lse, g,
-                                  causal=True, scale=0.125)
-jax.block_until_ready((dq, dk, dv))
-print(f"STAGE_OK compile+run {time.perf_counter()-t0:.1f}s", flush=True)
-""",
-    3: r"""
-import time, jax, jax.numpy as jnp, numpy as np
-import paddle_tpu as fluid
-from paddle_tpu.kernels.flash_attention import flash_attention
-fluid.set_flags({"FLAGS_flash_bwd": "pallas"})
-B, H, S, D = 2, 8, 512, 64
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-def loss(q):
-    return flash_attention(q, q, q, causal=True).sum()
+from flash_fwd_probe import PEAK_TFLOPS, _time_ms  # noqa: E402
 
-t0 = time.perf_counter()
-g = jax.jit(jax.grad(loss))(q)
-jax.block_until_ready(g)
-print(f"STAGE_OK compile+run {time.perf_counter()-t0:.1f}s", flush=True)
-""",
+SHAPES = {
+    # name: (B, H, S, D, causal, ragged)
+    "ouro": (2, 16, 2048, 128, True, False),
+    "nmt-decoder": (96, 8, 256, 64, True, False),
+    "nmt-encoder": (96, 8, 256, 64, False, True),
+    "s256-d128": (16, 16, 256, 128, True, False),
+    "s384-d64": (64, 8, 384, 64, True, False),
+    "s384-d128": (11, 16, 384, 128, True, False),
+    "s512-d64": (48, 8, 512, 64, True, False),
+    "s512-d128": (8, 16, 512, 128, True, False),
+    "s1024-d64": (24, 8, 1024, 64, True, False),
+    "s1024-d128": (4, 16, 1024, 128, True, False),
+    "s2048-d64": (12, 8, 2048, 64, True, False),
+}
+REHEARSAL_SHAPES = {
+    "ouro": (1, 2, 512, 128, True, False),
+    "nmt-decoder": (2, 2, 256, 64, True, False),
+    "nmt-encoder": (2, 2, 256, 64, False, True),
 }
 
 
-STAGE_SRC[4] = r"""
-import time, jax, jax.numpy as jnp, numpy as np
-import paddle_tpu as fluid
-from paddle_tpu.kernels.flash_attention import flash_attention
-fluid.set_flags({"FLAGS_flash_bwd": "jaxlib"})
-B, H, S, D = 2, 8, 512, 64
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--calls", type=int, default=30)
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--shapes", default=None)
+    ap.add_argument("--out", default="chiprun_out/flash_bwd_probe.json")
+    ap.add_argument("--parent", metavar="FILE", help="kernels/"
+                    "flash_attention.py of another commit (git show), its "
+                    "_pallas_flash_bwd timed as it stands")
+    a = ap.parse_args()
 
-def loss(q):
-    return flash_attention(q, q, q, causal=True).sum()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
 
-t0 = time.perf_counter()
-g = jax.jit(jax.grad(loss))(q)
-jax.block_until_ready(g)
-print(f"STAGE_OK compile+run {time.perf_counter()-t0:.1f}s", flush=True)
-"""
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    parent = None
+    if a.parent:
+        spec = importlib.util.spec_from_file_location(
+            "paddle_tpu.kernels._parent_flash_attention", a.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+    dev = jax.devices()[0]
+    if not a.rehearse and dev.platform != "tpu":
+        print("flash_bwd_probe: no TPU here (use --rehearse on the CPU)",
+              file=sys.stderr)
+        return 2
+    shapes = REHEARSAL_SHAPES if a.rehearse else SHAPES
+    rule_threshold = fa._BWD_PALLAS_MIN_BLOCK_SCORES
+    rows = []
+    for name in (a.shapes.split(",") if a.shapes else shapes):
+        B, H, S, D, causal, ragged = shapes[name]
+        rng = np.random.RandomState(a.seed % (2 ** 32))
+        q, k, v, g = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
+                      for _ in range(4))
+        lengths = (rng.randint(S // 2, S + 1, size=B) if ragged
+                   else np.full(B, S))
+        klen = jnp.asarray(lengths, jnp.float32)
+        scale = 1.0 / math.sqrt(D)
+        visible = S * (S + 1) / 2 if causal else float(S * np.mean(lengths))
+        counted = 2.5 * 4.0 * B * H * visible * D
+        plan = fa._bwd_plan(S, S, D, q.dtype, causal)
+        force = "interpret" if a.rehearse else "pallas"
 
+        def reference(q, k, v):
+            return fa._reference_attention(
+                q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32))
 
-def run_stage(stage: int, timeout_s: float) -> dict:
-    t0 = time.perf_counter()
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", STAGE_SRC[stage]],
-            capture_output=True, text=True, timeout=timeout_s,
-            env=dict(os.environ),
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        ok = out.returncode == 0 and "STAGE_OK" in out.stdout
-        tail = (out.stdout + out.stderr).strip().splitlines()
-        detail = tail[-1][:300] if tail else ""
-    except subprocess.TimeoutExpired:
-        ok, detail = False, f"timeout after {timeout_s:.0f}s"
-    return {"stage": stage, "ok": ok,
-            "wall_s": round(time.perf_counter() - t0, 1), "detail": detail}
+        out, lse = jax.jit(lambda q, k, v: fa._pallas_flash(
+            q, k, v, klen, causal, scale, interpret=a.rehearse))(q, k, v)
 
+        def kernels(module, **pins):
+            return jax.jit(lambda q, k, v, g: module._pallas_flash_bwd(
+                q, k, v, klen, out, lse, g, causal, scale,
+                interpret=a.rehearse, **pins))
 
-def main() -> None:
-    stages = ([int(sys.argv[1])] if len(sys.argv) > 1 else [1, 2, 3, 4])
-    timeout_s = float(sys.argv[2]) if len(sys.argv) > 2 else 900.0
-    ok_all = True
-    for s in stages:
-        r = run_stage(s, timeout_s)
-        print(json.dumps(r), flush=True)
-        if not r["ok"]:
-            ok_all = False
-            if s != 4:
-                # stages 1-3 build on each other; stage 4 is independent
-                # and still worth probing after a 1-3 failure
-                if 4 in stages:
-                    r4 = run_stage(4, timeout_s)
-                    print(json.dumps(r4), flush=True)
-                break
-    sys.exit(0 if ok_all else 1)
+        def step(threshold):
+            """fwd + bwd with the rule's threshold held at `threshold` while
+            the call is traced; the loss is returned too, or the forward
+            of the XLA engine (nothing of it is a residual) is dead code."""
+            def loss(q, k, v, g):
+                o = fa.flash_attention(q, k, v, causal=causal,
+                                       k_lengths=klen, force=force)
+                return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
+
+            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+            def run(*args):
+                fa._BWD_PALLAS_MIN_BLOCK_SCORES = threshold
+                try:
+                    return fn(*args)[1]
+                finally:
+                    fa._BWD_PALLAS_MIN_BLOCK_SCORES = rule_threshold
+            return run
+
+        xla = jax.jit(lambda q, k, v, g: jax.vjp(reference, q, k, v)[1](g))
+        pair = (plan["block_q"], plan["block_k"])
+        variants = [("xla", xla, (None, None)),
+                    ("pallas", kernels(fa), pair)]
+        if parent is not None:
+            variants.append(("parent-pallas", kernels(parent),
+                             (lse.shape[2], 128)))
+        if not a.rehearse:   # force="interpret" keeps the Pallas backward
+            variants.append(("step-xla", step(2 ** 62), (None, None)))
+        variants += [("step-pallas", step(0), pair),
+                     ("step-rule:" + plan["engine"], step(rule_threshold),
+                      pair)]
+        if a.sweep:
+            lens = fa._block_lengths(S)
+            variants += [
+                (f"pallas-{bq}x{bk}", kernels(fa, block_q=bq, block_k=bk),
+                 (bq, bk)) for bq in lens for bk in lens
+                if (bq, bk) != pair and fa.bwd_working_set_bytes(
+                    bq, bk, D, -(-S // bq), "bfloat16")
+                <= 1.5 * fa._PLAN_VMEM_BUDGET]
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+        want = [np.asarray(x) for x in xla(*f32)]
+        for label, fn, (bq, bk) in variants:
+            row = {"shape": name, "bh": B * H, "s": S, "d": D,
+                   "causal": causal, "variant": label, "block_q": bq,
+                   "block_k": bk, "seed": a.seed}
+            if bq is not None:
+                row["working_set_mb"] = round(fa.bwd_working_set_bytes(
+                    bq, bk, D, -(-S // bq), "bfloat16") / 2 ** 20, 3)
+            try:
+                got = [np.asarray(x.astype(jnp.float32))
+                       for x in fn(q, k, v, g)]
+                row["max_abs_err"] = max(float(np.max(np.abs(x - w)))
+                                         for x, w in zip(got, want))
+                if not a.rehearse:  # an interpreter's time is no one's
+                    ms = _time_ms(fn, (q, k, v, g), a.calls)
+                    row.update(
+                        ms_a_call=round(ms, 4),
+                        tflops_causal_count=round(counted / ms / 1e9, 2),
+                        share_of_peak=round(
+                            counted / ms / 1e9 / PEAK_TFLOPS, 4))
+            except Exception as e:  # a block pair Mosaic refuses is a row
+                row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+           "rehearsal": bool(a.rehearse), "date": time.strftime(
+               "%Y-%m-%d %H:%M UTC", time.gmtime()), "rows": rows}
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    with open(a.out, "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps({k: out[k] for k in ("device", "rehearsal", "date")}))
+    return 3 if a.rehearse else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
