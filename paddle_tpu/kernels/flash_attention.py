@@ -102,7 +102,8 @@ _PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
 
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
                    head_dim: int = 128, num_q_blocks: int = 1,
-                   dtype="float32", emit_lse: bool = True) -> int:
+                   dtype="float32", emit_lse: bool = True,
+                   v_dim: int | None = None) -> int:
     """Analytic VMEM footprint of the buffers ONE forward pallas invocation
     declares — the kernel's own statement of the linter's pricing model
     (paddle_tpu.analysis.pallas.kernel_vmem_bytes; tests hold the two
@@ -110,30 +111,32 @@ def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
     blocks (+ the packed lse plane when emitted) plus the fp32
     online-softmax scratch.  The SMEM klen vector is outside VMEM, and so
     are the score blocks the body computes: fwd_working_set_bytes adds
-    those, and it is what _plan_blocks holds under its budget."""
+    those, and it is what _plan_blocks holds under its budget.  `v_dim`
+    is the width of V and O where it is not the head_dim of Q and K."""
+    v_dim = head_dim if v_dim is None else v_dim
     blocks = [
         ((1, block_q, head_dim), dtype),   # q
         ((1, block_k, head_dim), dtype),   # k
-        ((1, block_k, head_dim), dtype),   # v
-        ((1, block_q, head_dim), dtype),   # o
+        ((1, block_k, v_dim), dtype),      # v
+        ((1, block_q, v_dim), dtype),      # o
     ]
     if emit_lse:
         blocks.append(((1, num_q_blocks, block_q), "float32"))
     scratch = [((block_q, 1), "float32"), ((block_q, 1), "float32"),
-               ((block_q, head_dim), "float32")]
+               ((block_q, v_dim), "float32")]
     return (2 * sum(tile_padded_bytes(s, d) for s, d in blocks)
             + sum(tile_padded_bytes(s, d) for s, d in scratch))
 
 
 def fwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
-                          dtype="float32", emit_lse=True) -> int:
+                          dtype="float32", emit_lse=True, v_dim=None) -> int:
     """fwd_vmem_bytes plus what a grid step computes between its two
     matmuls: the fp32 score block and the fp32 probability block, each
     [block_q, block_k].  At 128 x 128 they are 128 KB and were never
     counted; at 512 x 512 they are 2 MB, more than every declared buffer
     together, and they are what bounds the block plan."""
     return (fwd_vmem_bytes(block_q, block_k, head_dim, num_q_blocks, dtype,
-                           emit_lse)
+                           emit_lse, v_dim)
             + 2 * tile_padded_bytes((block_q, block_k), "float32"))
 
 
@@ -168,7 +171,7 @@ def _fewest_steps(sq, sk, causal, working_set):
     return min(fits or plans[:1], key=steps_then_wide)
 
 
-def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
+def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None):
     """(block_q, block_k) of the forward's grid, from the shape alone.
 
     A grid step costs about the same whatever it computes (the pipeline's
@@ -180,40 +183,45 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse):
     at 0.59 ms, 1024 x 256 at 1.52)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: fwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse))
+            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse, v_dim))
 
 
 def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
-                          dtype="float32") -> int:
+                          dtype="float32", v_dim=None) -> int:
     """What one grid step of the backward kernel holds: the double-buffered
     q, dO, k, v blocks, the packed lse and D planes and the dK, dV and
     (whole-row) dQ blocks it writes, the fp32 accumulators of dK and dV and
     of the whole row's dQ, and FOUR fp32 [block_k, block_q] planes between
     its matmuls (scores and probabilities, dP, dS and the operand cast for
     the MXU) where the forward holds two.  The dQ row is what bounds the
-    sequence: 2 MB of it at 2048 x 128 bf16, 8 MB of the 12 at 8192."""
+    sequence: 2 MB of it at 2048 x 128 bf16, 8 MB of the 12 at 8192.
+    `v_dim` is the width of V, dO and dV where it is not Q's and K's."""
     def tile(shape, dt=dtype):
         return tile_padded_bytes(shape, dt)
 
+    v_dim = head_dim if v_dim is None else v_dim
     rows = num_q_blocks * block_q
-    blocks = (2 * tile((1, block_q, head_dim))          # q, dO
-              + 4 * tile((1, block_k, head_dim))        # k, v, dK, dV
+    blocks = (tile((1, block_q, head_dim))              # q
+              + tile((1, block_q, v_dim))               # dO
+              + 2 * tile((1, block_k, head_dim))        # k, dK
+              + 2 * tile((1, block_k, v_dim))           # v, dV
               + tile((1, rows, head_dim))               # dQ
               + 2 * tile((1, num_q_blocks, block_q), "float32"))
     scratch = (tile((rows, head_dim), "float32")
-               + 2 * tile((block_k, head_dim), "float32"))
+               + tile((block_k, head_dim), "float32")
+               + tile((block_k, v_dim), "float32"))
     return (2 * blocks + scratch
             + 4 * tile((block_k, block_q), "float32"))
 
 
-def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal):
+def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None):
     """(block_q, block_k) of the backward's grid, on the forward's
     principle: the fewest grid steps that run (_fewest_steps) whose working
     set (bwd_working_set_bytes) fits.  It need not be the forward's pair:
     the packed lse plane is re-cut for free (_repack)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: bwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype))
+            bq, bk, head_dim, -(-sq // bq), dtype, v_dim))
 
 
 def _block_runs(qi, ki, block_q, block_k, causal_offset):
@@ -496,25 +504,27 @@ def _flash_kernel_fwd_only(klen_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.lru_cache(maxsize=128)
 def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
-              causal_offset, dtype, interpret, emit_lse=True):
+              causal_offset, dtype, interpret, emit_lse=True, dv=None):
     """Memoized pallas_call: every attention site with the same static
     config reuses ONE traced callable, so XLA sees identical kernel
     payloads (compile-cache friendly) instead of per-site clones.
     emit_lse=False drops the lse output entirely (see
-    _flash_kernel_fwd_only)."""
+    _flash_kernel_fwd_only).  `dv` is the width of V and O where it is
+    not Q's and K's `d`."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     kernel = _flash_kernel if emit_lse else _flash_kernel_fwd_only
     nqb = sqp // bq
+    dv = d if dv is None else dv
 
     def kv_block(b, i, j):
         if causal:
             j = _kv_block_index(i, j, bq, bk, causal_offset)
         return (b, j, 0)
 
-    out_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bh, sqp, d), jnp.dtype(dtype))]
+    out_specs = [pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bh, sqp, dv), jnp.dtype(dtype))]
     if emit_lse:
         # packed lse: one [nqb, bq] plane per batch-head row, revisited
         # across q/k steps and flushed when b advances
@@ -535,14 +545,14 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d), kv_block),
-            pl.BlockSpec((1, bk, d), kv_block),
+            pl.BlockSpec((1, bk, dv), kv_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
     )
@@ -550,7 +560,7 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
 def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
                   interpret=False, need_lse=True):
-    """Returns (out [B,H,Sq,D], lse [B*H, num_q_blocks, block_q] fp32
+    """Returns (out [B,H,Sq,Dv], lse [B*H, num_q_blocks, block_q] fp32
     per-row logsumexp in the PACKED residual layout — see the module
     comment; _pallas_flash_bwd re-cuts it to its own q-block).
     need_lse=False (inference / the recompute-jax backward) skips the lse
@@ -558,8 +568,8 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     it — and returns (out, None).  The blocks come from _plan_blocks;
     block_q / block_k pin them for a test or the probe, never a model."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse)
+    Sk, Dv = k.shape[2], v.shape[3]
+    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv)
     bq = plan_q if block_q is None else min(block_q, Sq)
     bk = plan_k if block_k is None else min(block_k, Sk)
     # pad sequence dims to block multiples (masked in-kernel)
@@ -568,7 +578,7 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     v = _pad_seq(v, bk)
     qf = q.reshape(B * H, q.shape[2], D)
     kf = k.reshape(B * H, k.shape[2], D)
-    vf = v.reshape(B * H, v.shape[2], D)
+    vf = v.reshape(B * H, v.shape[2], Dv)
     klen_bh = jnp.repeat(klen, H)  # [B*H] valid key counts
 
     nqb, nkb = qf.shape[1] // bq, kf.shape[1] // bk
@@ -579,9 +589,9 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
               causal=int(causal)):
         call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
                          scale, Sk, Sk - Sq, str(q.dtype), interpret,
-                         emit_lse=need_lse)
+                         emit_lse=need_lse, dv=Dv)
         res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
-    out = res[0].reshape(B, H, res[0].shape[1], D)
+    out = res[0].reshape(B, H, res[0].shape[1], Dv)
     if out.shape[2] != Sq:
         out = out[:, :, :Sq]
     if not need_lse:
@@ -600,16 +610,18 @@ def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb):
 
 @functools.lru_cache(maxsize=128)
 def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
-              causal_offset, q_dtype, k_dtype, v_dtype, interpret):
+              causal_offset, q_dtype, k_dtype, v_dtype, interpret, dv=None):
     """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nqb = sqp // bq
+    dv = d if dv is None else dv
     # packed lse/dvec residuals: the whole (tiny) [nqb, bq] plane for
     # batch-head row b rides in VMEM; the kernel reads its q-block's row
     packed = pl.BlockSpec((1, nqb, bq), lambda b, j, i: (b, 0, 0))
-    kv_block = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+    k_block = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+    v_block = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
 
     def q_of_kv(b, j, i):
         if causal:
@@ -625,27 +637,27 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             pl.BlockSpec((bh,), lambda b, j, i: (0,),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), q_of_kv),
-            kv_block,
-            kv_block,
-            pl.BlockSpec((1, bq, d), q_of_kv),
+            k_block,
+            v_block,
+            pl.BlockSpec((1, bq, dv), q_of_kv),
             packed,
             packed,
         ],
         out_specs=[
             # dQ: one block a batch-head row, written back when b advances
             pl.BlockSpec((1, sqp, d), lambda b, j, i: (b, 0, 0)),
-            kv_block,
-            kv_block,
+            k_block,
+            v_block,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sqp, d), jnp.dtype(q_dtype)),
             jax.ShapeDtypeStruct((bh, skp, d), jnp.dtype(k_dtype)),
-            jax.ShapeDtypeStruct((bh, skp, d), jnp.dtype(v_dtype)),
+            jax.ShapeDtypeStruct((bh, skp, dv), jnp.dtype(v_dtype)),
         ],
         scratch_shapes=[
             pltpu.VMEM((sqp, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
     )
@@ -670,8 +682,8 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     (_plan_bwd_blocks; block_q / block_k pin it for a test or the probe).
     `lse` is the forward's packed plane, whatever q-block laid it out."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k),
+    Sk, Dv = k.shape[2], v.shape[3]
+    plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv),
                 engine="pallas")
     bq, bk = plan["block_q"], plan["block_k"]
     qp = _pad_seq(q, bq)
@@ -681,10 +693,10 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     vp = _pad_seq(v, bk)
     Sqp, Skp = qp.shape[2], kp.shape[2]
     qf = qp.reshape(B * H, Sqp, D)
-    of = op.reshape(B * H, Sqp, D)
-    gf = gp.reshape(B * H, Sqp, D).astype(qf.dtype)
+    of = op.reshape(B * H, Sqp, Dv)
+    gf = gp.reshape(B * H, Sqp, Dv).astype(qf.dtype)
     kf = kp.reshape(B * H, Skp, D)
-    vf = vp.reshape(B * H, Skp, D)
+    vf = vp.reshape(B * H, Skp, Dv)
     klen_bh = jnp.repeat(klen, H)
     # a padded row's lse is the fully-masked row's: exp(s - lse) is 0
     lse = _repack(lse, Sq, bq, -NEG_INF)
@@ -698,13 +710,14 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     dvec = dvec.reshape(B * H, Sqp // bq, bq)
 
     call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk, Sk - Sq,
-                     str(q.dtype), str(k.dtype), str(v.dtype), interpret)
+                     str(q.dtype), str(k.dtype), str(v.dtype), interpret,
+                     dv=Dv)
     with span("flash.bwd_plan", **plan):  # at lowering, as flash.plan
         dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
 
     dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
     dk = dk.reshape(B, H, Skp, D)[:, :, :Sk]
-    dv = dv.reshape(B, H, Skp, D)[:, :, :Sk]
+    dv = dv.reshape(B, H, Skp, Dv)[:, :, :Sk]
     return dq, dk, dv
 
 
@@ -731,15 +744,16 @@ def _use_pallas(force: str) -> bool:
 _BWD_PALLAS_MIN_BLOCK_SCORES = 384 * 384
 
 
-def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None):
+def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
+              v_dim=None):
     """What the backward of one attention call of this shape is given, the
     `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
     the probe, else _plan_bwd_blocks'), steps and steps_skipped (static,
     over one batch-head row) and engine, "pallas" or "xla": the one place
     that says which."""
-    bq, bk = _plan_bwd_blocks(sq, sk, head_dim, dtype, causal)
+    bq, bk = _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim)
     fits = bwd_working_set_bytes(
-        bq, bk, head_dim, -(-sq // bq), dtype) <= _PLAN_VMEM_BUDGET
+        bq, bk, head_dim, -(-sq // bq), dtype, v_dim) <= _PLAN_VMEM_BUDGET
     engine = ("pallas" if fits and bq * bk >= _BWD_PALLAS_MIN_BLOCK_SCORES
               else "xla")
     bq = bq if block_q is None else min(block_q, sq)
@@ -750,15 +764,15 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None):
                 steps=nqb * nkb, steps_skipped=skipped, engine=engine)
 
 
-def _pallas_backward(q, k, causal, force) -> bool:
+def _pallas_backward(q, k, v, causal, force) -> bool:
     """Whether this call's backward runs the Pallas kernel (and so its
     forward emits lse): by the shape under "auto"/"pallas", always under
     "interpret" (the CPU tests' door), never under "jax"."""
     if force == "interpret":
         return True
     return _use_pallas(force) and _bwd_plan(
-        q.shape[2], k.shape[2], q.shape[3], q.dtype, causal
-    )["engine"] == "pallas"
+        q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
+        v_dim=v.shape[3])["engine"] == "pallas"
 
 
 def _forward(q, k, v, klen, causal, scale, force, need_lse):
@@ -781,7 +795,7 @@ def _flash_fwd(q, k, v, klen, causal, scale, force):
     # the XLA recompute backward holds neither O nor L as residuals, and
     # its forward skips the lse HBM write entirely
     out, lse = _forward(q, k, v, klen, causal, scale, force,
-                        need_lse=_pallas_backward(q, k, causal, force))
+                        need_lse=_pallas_backward(q, k, v, causal, force))
     return out, (q, k, v, klen, None if lse is None else out, lse)
 
 
@@ -797,7 +811,8 @@ def _flash_bwd(causal, scale, force, res, g):
         if _use_pallas(force):
             # at lowering, beside flash.plan: the site keeps the XLA engine
             with span("flash.bwd_plan", **_bwd_plan(
-                    q.shape[2], k.shape[2], q.shape[3], q.dtype, causal)):
+                    q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
+                    v_dim=v.shape[3])):
                 pass
         # recompute-backward: differentiate the reference formulation
         _, vjp = jax.vjp(

@@ -25,6 +25,10 @@ __all__ = [
     "beam_search_decode",
     "fused_attention",
     "rotary_embedding",
+    "latent_attention",
+    "moe_router",
+    "moe_experts",
+    "moe_bias_update",
     "edit_distance",
     "conv2d",
     "conv3d",
@@ -1237,6 +1241,83 @@ def rotary_embedding(x, base=10000.0, offset=0, name=None):
         attrs={"base": float(base), "offset": int(offset)},
     )
     return out
+
+
+def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
+                     qk_rope_head_dim, v_head_dim, rope_base=10000.0,
+                     name=None):
+    """Causal multi-head latent attention (MLA) from its projections: q
+    [B, S, H * (nope + rope)], the normalised latent [B, S, rank], the one
+    rotary key part a token k_rope [B, S, rope], and the parameter
+    kv_up_w [rank, H * (nope + v)]; returns the heads' contexts
+    [B, S, H * v] (TPU-native; ops/attention_ops.py latent_attention)."""
+    helper = LayerHelper("latent_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        type="latent_attention",
+        inputs={"Q": [q], "Latent": [latent], "KRope": [k_rope],
+                "KvUpW": [kv_up_w]},
+        outputs={"Out": [out]},
+        attrs={"n_head": int(n_head),
+               "qk_nope_head_dim": int(qk_nope_head_dim),
+               "qk_rope_head_dim": int(qk_rope_head_dim),
+               "v_head_dim": int(v_head_dim), "rope_base": float(rope_base)},
+    )
+    return out
+
+
+def moe_router(x, weight, bias, top_k, scaling=1.0, norm_topk_prob=True,
+               name=None):
+    """Sigmoid router over all the experts weight [d, E] has: (the top_k
+    experts of score + bias a token [..., k] int32, their weights [..., k]
+    fp32: the scores without the bias, normalised over the chosen and
+    times `scaling`, the tokens that chose each expert [E]).  `bias` is
+    state without a gradient (TPU-native; ops/moe_ops.py)."""
+    helper = LayerHelper("moe_router", input=x, name=name)
+    idx = helper.create_variable_for_type_inference("int32",
+                                                    stop_gradient=True)
+    top_w = helper.create_variable_for_type_inference("float32")
+    load = helper.create_variable_for_type_inference("float32",
+                                                     stop_gradient=True)
+    helper.append_op(
+        type="moe_router",
+        inputs={"X": [x], "Weight": [weight], "Bias": [bias]},
+        outputs={"TopIdx": [idx], "TopWeight": [top_w], "Load": [load]},
+        attrs={"top_k": int(top_k), "scaling": float(scaling),
+               "norm_topk_prob": bool(norm_topk_prob)},
+    )
+    return idx, top_w, load
+
+
+def moe_experts(x, top_idx, top_weight, gate_w, up_w, down_w, experts_total,
+                expert_offset=0, name=None):
+    """The held experts' part of sum_i g_i E_i(x): gate_w / up_w [held, d,
+    f] and down_w [held, f, d] are experts expert_offset .. expert_offset +
+    held of `experts_total`; every token routed to one of them is computed,
+    none is dropped (TPU-native; ops/moe_ops.py)."""
+    helper = LayerHelper("moe_experts", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [x], "TopIdx": [top_idx], "TopWeight": [top_weight],
+                "GateW": [gate_w], "UpW": [up_w], "DownW": [down_w]},
+        outputs={"Out": [out]},
+        attrs={"experts_total": int(experts_total),
+               "expert_offset": int(expert_offset)},
+    )
+    return out
+
+
+def moe_bias_update(bias, load, gamma, name=None):
+    """bias_i += gamma * sign(mean load - load_i), in place: the
+    auxiliary-loss-free balancing of a router's selection bias (load [E]
+    or [n, E], summed over n)."""
+    helper = LayerHelper("moe_bias_update", input=bias, name=name)
+    helper.append_op(
+        type="moe_bias_update", inputs={"Bias": [bias], "Load": [load]},
+        outputs={"BiasOut": [bias]}, attrs={"gamma": float(gamma)},
+    )
+    return bias
 
 
 def edit_distance(input, label, normalized=True, ignored_tokens=None,

@@ -97,12 +97,13 @@ class _Builder:
                              shape=[0, 0, h * dh])
         return self.linear(ctx, h * dh, cfg.d_model, f"{name}_o")
 
-    def mlp(self, x, name):
+    def mlp(self, x, name, d_inner=None):
         cfg = self.cfg
-        gate = layers.swish(self.linear(x, cfg.d_model, cfg.d_inner,
+        d_inner = d_inner or cfg.d_inner
+        gate = layers.swish(self.linear(x, cfg.d_model, d_inner,
                                         f"{name}_gate"))
-        up = self.linear(x, cfg.d_model, cfg.d_inner, f"{name}_up")
-        return self.linear(layers.elementwise_mul(gate, up), cfg.d_inner,
+        up = self.linear(x, cfg.d_model, d_inner, f"{name}_up")
+        return self.linear(layers.elementwise_mul(gate, up), d_inner,
                            cfg.d_model, f"{name}_down")
 
     def layer(self, h, name):

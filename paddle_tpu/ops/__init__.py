@@ -19,6 +19,7 @@ from . import (  # noqa: F401
     math_ops,
     metric_ops,
     misc_ops,
+    moe_ops,
     nn_ops,
     optimizer_ops,
     proposal_ops,

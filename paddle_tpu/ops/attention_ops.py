@@ -13,6 +13,7 @@ import numpy as np
 
 from ..core import amp
 from ..core.registry import register_op
+from ..observability import span
 from .common import data, in_desc, same_shape, set_output
 
 
@@ -46,51 +47,110 @@ def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
                          out_specs=qkv, check_vma=False)
 
 
-@register_op("fused_attention", infer_shape=_fused_attn_infer,
-             diff_inputs=["Q", "K", "V"])
-def _fused_attention(ctx, ins, attrs):
+def _attend(ctx, q, k, v, klen, causal, scale):
+    """flash_attention of q/k [B, H, S, D] and v [B, H, S, Dv], under a
+    shard_map where the program runs on a mesh of several devices."""
     from ..kernels import flash_attention
     from ..kernels.flash_attention import _use_pallas
 
-    q = data(ins["Q"][0])  # [B, H, Sq, D]
-    k = data(ins["K"][0])
-    v = data(ins["V"][0])
-    klen_in = ins.get("KLengths", [None])[0]
-    klen = data(klen_in).reshape(-1) if klen_in is not None else None
-
     def attend(q, k, v, klen=None):
-        return flash_attention(
-            q, k, v,
-            causal=bool(attrs.get("causal", False)),
-            scale=attrs.get("scale") or None,
-            k_lengths=klen,
-        )
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               k_lengths=klen)
 
     if (ctx.mesh is not None and ctx.mesh.num_devices > 1
             and _use_pallas("auto")):
         attend = _shard_over_mesh(attend, ctx.mesh, q.shape[1],
                                   klen is not None)
-    args = (q, k, v) + ((klen,) if klen is not None else ())
-    return {"Out": [attend(*args)]}
+    return attend(*((q, k, v) + ((klen,) if klen is not None else ())))
 
 
-@register_op("rotary_embedding", infer_shape=same_shape("X", "Out"),
-             diff_inputs=["X"])
-def _rotary_embedding(ctx, ins, attrs):
-    """Rotary position embedding (Su et al. 2021) of X [..., S, D] along
+@register_op("fused_attention", infer_shape=_fused_attn_infer,
+             diff_inputs=["Q", "K", "V"])
+def _fused_attention(ctx, ins, attrs):
+    q = data(ins["Q"][0])  # [B, H, Sq, D]
+    k = data(ins["K"][0])
+    v = data(ins["V"][0])
+    klen_in = ins.get("KLengths", [None])[0]
+    klen = data(klen_in).reshape(-1) if klen_in is not None else None
+    return {"Out": [_attend(ctx, q, k, v, klen,
+                            bool(attrs.get("causal", False)),
+                            attrs.get("scale") or None)]}
+
+
+def _rotate(x, base: float, offset: int = 0):
+    """Rotary position embedding (Su et al. 2021) of x [..., S, D] along
     its last two axes, position p = offset + index on axis -2, in the
     half-split layout: pair i is (x[i], x[i + D/2]), turned by the angle
-    p * base^(-2i/D).  The angles are fp32 whatever X is (at base 1e6 and
-    p in the thousands bf16 has no digit left of them); Out has X's dtype."""
-    x = data(ins["X"][0])
+    p * base^(-2i/D).  The angles are fp32 whatever x is (at base 1e6 and
+    p in the thousands bf16 has no digit left of them); x's dtype out."""
     seq, dim = x.shape[-2], x.shape[-1]
     half = dim // 2
-    inv_freq = float(attrs.get("base", 10000.0)) ** (
-        -np.arange(half, dtype=np.float64) * 2.0 / dim)
-    pos = np.arange(seq, dtype=np.float64) + int(attrs.get("offset", 0))
+    inv_freq = float(base) ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
+    pos = np.arange(seq, dtype=np.float64) + int(offset)
     angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     xs = x.astype(amp.stats_dtype(x))
     x1, x2 = xs[..., :half], xs[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return {"Out": [out.astype(x.dtype)]}
+    return out.astype(x.dtype)
+
+
+@register_op("rotary_embedding", infer_shape=same_shape("X", "Out"),
+             diff_inputs=["X"])
+def _rotary_embedding(ctx, ins, attrs):
+    return {"Out": [_rotate(data(ins["X"][0]), attrs.get("base", 10000.0),
+                            attrs.get("offset", 0))]}
+
+
+def _latent_attn_infer(op, block):
+    q = in_desc(op, block, "Q")
+    if q is None:
+        return
+    heads = int(op.attr("n_head", 1))
+    set_output(block, op, "Out",
+               list(q.shape[:-1]) + [heads * int(op.attr("v_head_dim", 0))],
+               q.dtype)
+
+
+@register_op("latent_attention", infer_shape=_latent_attn_infer,
+             diff_inputs=["Q", "Latent", "KRope", "KvUpW"])
+def _latent_attention(ctx, ins, attrs):
+    """Causal multi-head latent attention (MLA, DeepSeek-V2/V3) from its
+    projections to the heads' contexts.  Q [B, S, H * (dn + dr)]: a head's
+    query is `nope` dn | `rope` dr.  Latent [B, S, r] (normalised): KvUpW
+    [r, H * (dn + dv)] takes it to a head's key `nope` dn | value dv.
+    KRope [B, S, dr]: ONE rotary key part a token, shared by all heads.
+    Rotary on the two rope parts; k = [k_nope | k_rope], scores q.k /
+    sqrt(dn + dr); Out [B, S, H * dv].
+
+    The flash kernels take q and k at dn + dr and v at dv as they are: the
+    kernels carry a value width of their own (kernels/flash_attention.py),
+    which tools/moonlight_kernel_probe.py held against v zero-padded to the
+    key width on the chip (PERF.md, PR 31).  `mla.lower` (a span, at
+    lowering) says what a site was given."""
+    q = data(ins["Q"][0])
+    latent = data(ins["Latent"][0])
+    k_rope = data(ins["KRope"][0])
+    kv_w = data(ins["KvUpW"][0])
+    H = int(attrs["n_head"])
+    dn, dr, dv = (int(attrs[a]) for a in
+                  ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+    base = float(attrs.get("rope_base", 10000.0))
+    B, S = q.shape[0], q.shape[1]
+
+    def heads(t):                                    # [B, H, S, width]
+        return jnp.swapaxes(t.reshape(B, S, H, -1), 1, 2)
+
+    with span("mla.lower", heads=H, qk_dim=dn + dr, v_dim=dv,
+              kv_rank=int(latent.shape[-1]), padded_v=0):
+        lc, wc = amp.mxu_operands(latent, kv_w)
+        kv = heads(amp.mxu_output(jnp.matmul(lc, wc), latent, kv_w))
+        q = heads(q)
+        q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], base)], -1)
+        shared = jnp.broadcast_to(_rotate(k_rope[:, None], base).astype(
+            kv.dtype), (B, H, S, dr))
+        k = jnp.concatenate([kv[..., :dn], shared], -1)
+        q, k = amp.match_kept(q, k)
+        out = _attend(ctx, q, k, kv[..., dn:].astype(k.dtype), None, True,
+                      (dn + dr) ** -0.5)
+    return {"Out": [jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)]}

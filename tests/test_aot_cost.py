@@ -179,7 +179,42 @@ def _paged(dtype, page_size, two_level=False):
     return fn, (q, kp, kp, ln) + tables + scales
 
 
+def _flash_mla():
+    """moonlight-16b-a3b's attention: q and k 192 wide, v 128 (the kernels'
+    own value width), forward and Pallas backward at S 2048."""
+    q = _sds((4, 16, 2048, 192), jnp.bfloat16)
+    v = _sds((4, 16, 2048, 128), jnp.bfloat16)
+    from paddle_tpu.kernels import flash_attention
+
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, force="pallas").astype(jnp.float32)),
+        argnums=(0, 1, 2)), (q, q, v)
+
+
+def _held_experts():
+    """moonlight-16b-a3b's expert layer at the cell's size: 8192 tokens, 8
+    held of 64, top 6, forward and backward, the three row buffers under
+    the one conditional, the Pallas grouped matmuls at _gmm_tiling's tiles."""
+    from paddle_tpu.ops import moe_ops
+
+    T, d, f, held, k = 8192, 2048, 1408, 8, 6
+    x = _sds((T, d), jnp.bfloat16)
+    idx, weight = _sds((T, k), jnp.int32), _sds((T, k), jnp.float32)
+    wide = _sds((held, d, f), jnp.bfloat16)
+    down = _sds((held, f, d), jnp.bfloat16)
+
+    def layer(x, weight, gate_w, up_w, down_w, idx):
+        return jnp.sum(moe_ops.held_experts_part(
+            x, idx, weight, gate_w, up_w, down_w, 0, 64, engine="megablox"))
+
+    return (jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4)),
+            (x, weight, wide, wide, down, idx))
+
+
 _MAIN_PATH_KERNELS = {
+    "flash_bwd_pallas_moonlight_192_128": _flash_mla,
+    "held_experts_moonlight": _held_experts,
     "flash_fwd_transformer_base": lambda: _flash_fwd((32, 8, 256, 64)),
     "flash_fwd_long_context": lambda: _flash_fwd((2, 8, 2048, 64)),
     "flash_fwd_ouro": lambda: _flash_fwd((2, 16, 2048, 128)),

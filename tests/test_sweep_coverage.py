@@ -205,7 +205,23 @@ def _via_recurrence():
     return [rec(), rec.final(h)], {"x": np.ones((2, 3), "float32")}
 
 
+def _via_expert_decoder():
+    # numerics and gradients: tests/test_expert_decoder.py
+    from paddle_tpu import models
+
+    spec = models.expert_decoder(models.ExpertDecoderConfig(
+        vocab_size=32, max_length=8, n_layer=2, d_model=16, d_inner=32,
+        n_head=2, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        kv_lora_rank=12, n_routed_experts=8, experts_held=2, top_k=2,
+        d_expert=12))
+    return spec.loss, spec.synthetic_batch(2, 0)
+
+
 EXERCISED_VIA = {
+    "latent_attention": _via_expert_decoder,
+    "moe_router": _via_expert_decoder,
+    "moe_experts": _via_expert_decoder,
+    "moe_bias_update": _via_expert_decoder,
     "recurrence": _via_recurrence,
     "gru": _via_dynamic_gru,
     "fusion_gru": _via_fusion_gru,
