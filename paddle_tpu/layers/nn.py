@@ -606,7 +606,15 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
         loss = (1-eps) * CE(label) + eps * mean_V(-log p)
     identical to one_hot -> label_smooth -> soft-label CE but WITHOUT
     materializing any [*, V] label tensor (at vocab 32k and bench batch
-    that chain moves ~1 GB/step of HBM)."""
+    that chain moves ~1 GB/step of HBM).
+
+    What the backward reads (hard labels, ops/loss_ops.py::_hard_ce): the
+    forward makes one pass over the logits and saves e = exp(x - max) in
+    the LOGITS' dtype (bf16 under AMP, the dtype of the Softmax output;
+    fp32 for fp32 logits) behind an optimization barrier, with its fp32
+    row sum s; dLogits = (g / s) * e - g * target evaluates no exponential
+    and is rounded to the logits' dtype.  The statistics (max, sum, lse,
+    the loss) stay fp32.  soft_label=True keeps jax's own gradient."""
     if smooth_eps and soft_label:
         # validate BEFORE creating any program vars: a rejected call must
         # not leave orphan Softmax/Loss descs behind

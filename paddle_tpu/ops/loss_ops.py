@@ -3,13 +3,16 @@ softmax_with_cross_entropy_op, sigmoid_cross_entropy_with_logits_op...)."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core import amp
 from ..core.proto import DataType
-from ..core.registry import register_op
+from ..core.registry import GRAD_SUFFIX, register_op
+from ..observability import span
 from .common import data, in_desc, same_shape, set_output, wrap_lod
 
 
@@ -65,36 +68,122 @@ def _swce_infer(op, block):
     set_output(block, op, "Loss", list(x.shape[:-1]) + [1], x.dtype)
 
 
+def _hard_ce_forward(logits, lab, smooth_eps, ignore_index):
+    """One pass over the logits: (Softmax, Loss) in the logits' dtype, and
+    what the backward reads: e = exp(x - max) in the logits' dtype, its
+    fp32 row sum s.  The statistics run fp32 (amp.stats_dtype); the picked
+    value comes from the logits themselves (x[label] - lse) and the
+    smoothing's mean(logp) is mean(x) - lse, so no [rows, V] array of
+    log-probabilities is written for a gather to read one value a row."""
+    x = logits.astype(amp.stats_dtype(logits))
+    m = jnp.max(x, axis=-1, keepdims=True)
+    e = jnp.exp(x - m)
+    s = jnp.sum(e, axis=-1, keepdims=True)
+    lse = m + jnp.log(s)
+    valid = (lab != ignore_index)[..., None]
+    idx = jnp.where(valid, lab[..., None], 0).astype(jnp.int32)
+    picked = jnp.take_along_axis(logits, idx, axis=-1).astype(x.dtype)
+    if smooth_eps:
+        # folded uniform label smoothing (layers.py smooth_eps): the target
+        # is (1-eps)*onehot + eps/V, so -sum(target*logp) =
+        # lse - (1-eps)*x[label] - eps*mean_V(x): no [*, V] label tensor
+        picked = (1.0 - smooth_eps) * picked + smooth_eps * jnp.mean(
+            x, axis=-1, keepdims=True)
+    loss = jnp.where(valid, lse - picked, 0.0)
+    return ((e / s).astype(logits.dtype), loss.astype(logits.dtype),
+            e.astype(logits.dtype), s)
+
+
+def _softmax_grad_asked(ctx) -> bool:
+    """Whether the program asks a gradient of this site's Softmax output:
+    its grad op then names a `Softmax@GRAD`.  True where no grad op says
+    (a site differentiated as part of a larger unit)."""
+    uid = getattr(ctx.cur_op, "attrs", {}).get("__op_uid__")
+    if uid is None:
+        return True
+    for op in ctx.block.desc.ops:
+        if op.attrs.get("__fwd_op_uid__") == uid:
+            return any(op.inputs.get("Softmax" + GRAD_SUFFIX, []))
+    return True
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _hard_ce(logits, lab, smooth_eps, ignore_index, softmax_grad):
+    """Hard-label softmax + cross entropy -> (Softmax, Loss) whose backward
+    reads the saved exponentials instead of evaluating exp over the logits
+    again.  `softmax_grad` False leaves the Softmax output's cotangent out
+    of the backward: a site whose program asks no gradient of it hands the
+    vjp an array of zeros, which XLA does not fold (0 * e stays a pass over
+    [rows, V])."""
+    return _hard_ce_forward(logits, lab, smooth_eps, ignore_index)[:2]
+
+
+def _hard_ce_fwd(logits, lab, smooth_eps, ignore_index, softmax_grad):
+    softmax, loss, e, s = _hard_ce_forward(logits, lab, smooth_eps,
+                                           ignore_index)
+    # pinned: without the barrier XLA declines the saved array and fuses
+    # exp(x - max) again into each of the two gradient matmuls that read it
+    return (softmax, loss), (jax.lax.optimization_barrier(e), s, lab)
+
+
+def _hard_ce_bwd(smooth_eps, ignore_index, softmax_grad, res, cts):
+    """dLogits = (g / s) * e - g * target: a multiply and a select a value,
+    no transcendental, formed in the statistics' dtype, cast to the logits'
+    dtype, left to XLA to fuse into the matmuls that consume it."""
+    e, s, lab = res
+    g_softmax, g_loss = cts
+    e32 = e.astype(s.dtype)
+    g = jnp.where((lab != ignore_index)[..., None],
+                  g_loss.astype(s.dtype), 0.0)
+    classes = e.shape[-1]
+    hit = lab[..., None] == jnp.arange(classes, dtype=lab.dtype)
+    target = jnp.where(hit, 1.0 - smooth_eps, 0.0) + smooth_eps / classes
+    scale = g
+    if softmax_grad:  # softmax's own vjp: p * (gp - sum(gp * p)), p = e / s
+        gp = g_softmax.astype(s.dtype)
+        scale = g + gp - jnp.sum(gp * e32, axis=-1, keepdims=True) / s
+    dlogits = (scale / s) * e32 - g * target
+    return dlogits.astype(e.dtype), None
+
+
+_hard_ce.defvjp(_hard_ce_fwd, _hard_ce_bwd)
+
+
 @register_op("softmax_with_cross_entropy", infer_shape=_swce_infer, diff_inputs=["Logits"])
 def _softmax_with_cross_entropy(ctx, ins, attrs):
     """Fused, numerically-stable softmax+CE (reference:
-    operators/softmax_with_cross_entropy_op.cc)."""
+    operators/softmax_with_cross_entropy_op.cc).  Hard labels (with
+    smooth_eps and ignore_index) take `_hard_ce`: one exponential pass,
+    saved in the logits' dtype and pinned for the backward; soft labels
+    keep jax's own gradient.  `ce.lower` (a span, at lowering) says which
+    path a site took and what it pinned."""
     logits = data(ins["Logits"][0])
     label = data(ins["Label"][0])
-    # bf16 logits (amp keep_output) reduce in fp32
-    logp = jax.nn.log_softmax(logits.astype(amp.stats_dtype(logits)), axis=-1)
-    softmax = jnp.exp(logp)
-    if attrs.get("soft_label", False):
-        loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
-    else:
-        lab = label
-        if lab.ndim == logits.ndim:
-            lab = jnp.squeeze(lab, axis=-1)
-        picked = jnp.take_along_axis(logp, lab[..., None].astype(jnp.int32), axis=-1)
-        loss = -picked
-        eps = attrs.get("smooth_eps", 0.0)
-        if eps:
-            # folded uniform label smoothing (layers.py smooth_eps): the
-            # smoothed target is (1-eps)*onehot + eps/V, so
-            # -sum(target*logp) = (1-eps)*picked_CE + eps*mean_V(-logp) —
-            # no [*, V] label tensor ever exists
-            loss = (1.0 - eps) * loss - eps * jnp.mean(
-                logp, axis=-1, keepdims=True)
-        ignore = attrs.get("ignore_index", -100)
-        loss = jnp.where((lab != ignore)[..., None], loss, 0.0)
-    # outputs keep the logits' dtype (the fp32 math above is internal)
-    return {"Softmax": [softmax.astype(logits.dtype)],
-            "Loss": [loss.astype(logits.dtype)]}
+    soft = bool(attrs.get("soft_label", False))
+    eps = float(attrs.get("smooth_eps", 0.0))
+    classes = int(logits.shape[-1])
+    rows = int(logits.size // max(classes, 1))
+    with span("ce.lower", rows=rows, classes=classes,
+              dtype=str(logits.dtype),
+              path="autodiff" if soft else "saved_exp",
+              pinned_bytes=0 if soft else logits.size * logits.dtype.itemsize,
+              smooth_eps=eps, soft_label=soft):
+        if soft:
+            # bf16 logits (amp keep_output) reduce in fp32
+            logp = jax.nn.log_softmax(
+                logits.astype(amp.stats_dtype(logits)), axis=-1)
+            softmax = jnp.exp(logp).astype(logits.dtype)
+            loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
+            loss = loss.astype(logits.dtype)
+        else:
+            lab = label
+            if lab.ndim == logits.ndim:
+                lab = jnp.squeeze(lab, axis=-1)
+            softmax, loss = _hard_ce(
+                logits, lab, eps, attrs.get("ignore_index", -100),
+                _softmax_grad_asked(ctx))
+    # outputs keep the logits' dtype (the fp32 math is internal)
+    return {"Softmax": [softmax], "Loss": [loss]}
 
 
 @register_op("sigmoid_cross_entropy_with_logits", infer_shape=same_shape(), diff_inputs=["X"])

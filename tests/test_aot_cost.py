@@ -277,3 +277,58 @@ def test_flash_step_compiles_for_four_chips_under_data_parallelism(v5e):
             text = step.fn.lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 3  # enc, dec self, dec cross
     assert " all-reduce(" in text or " all-reduce-start(" in text
+
+
+def test_wide_head_step_evaluates_exp_once_and_writes_no_fp32_logits(v5e):
+    """PR 32, the `softmax_with_cross_entropy` site of a language model's
+    head as a training step compiles it for the v5e (a reduced shape: 2048
+    rows x 512 -> 8192 classes, Adam, AMP as the TPU resolves it): the
+    compiler counts under 1.3 transcendentals a logit (3.1 before: exp
+    recomputed inside both gradient matmuls), no fp32 [rows, classes] array
+    is among the step's temporaries, and one elementwise pass touches a
+    [rows, classes] array: the forward's, which writes the pinned
+    exponentials (a Softmax gradient nobody asked adds no pass of zeros)."""
+    import re
+
+    import paddle_tpu as fluid
+    from paddle_tpu import flags, layers
+
+    rows, width, classes = 2048, 512, 8192
+    fluid.reset_default_env()
+    h = layers.data("h", [width], dtype="float32")
+    lab = layers.data("lab", [1], dtype="int64")
+    logits = layers.fc(h, size=classes, bias_attr=False)
+    loss = layers.mean(layers.softmax_with_cross_entropy(logits, lab))
+    fluid.optimizer.AdamOptimizer(1e-3).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    with flags.tpu_trace_scope(True):
+        compiled, feed_vals, state_vals, rng = exe.capture_program(
+            fluid.default_main_program(),
+            feed={"h": np.zeros((rows, width), np.float32),
+                  "lab": np.zeros((rows, 1), np.int64)},
+            fetch_list=[loss])
+        exe_tpu = compile_tpu(compiled.raw_fn, feed_vals, state_vals, rng)
+    per_logit = exe_tpu.cost_analysis()["transcendentals"] / (rows * classes)
+    assert per_logit < 1.3, per_logit
+    text = exe_tpu.as_text()
+    entry = text[text.index("\nENTRY "):]
+    wide = re.compile(r"(\w+)\[%d,%d\]" % (rows, classes))
+    assert "f32" not in {m.group(1) for m in wide.finditer(entry)}
+    # an operation's line names its operands without their shapes: the
+    # shapes are on the lines that define them
+    op_line = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = (.*?) [a-z][\w\-]*\((?=%|\))")
+    shape_of, loops = {}, []
+    for line in entry.splitlines():
+        m = op_line.match(line)
+        if not m:
+            continue
+        shape_of[m.group(1)] = m.group(2)
+        if "kind=kLoop" in line:
+            loops.append((m.group(1), re.findall(
+                r"%([\w.\-]+)", line[m.end():].split(")", 1)[0])))
+    passes = [name for name, operands in loops
+              if any(wide.search(shape_of.get(n, ""))
+                     for n in [name] + operands)]
+    assert len(passes) == 1, passes

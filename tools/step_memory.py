@@ -4,14 +4,15 @@ reference, at the cell's real sizes, on the TPU compiler this sandbox has
 configuration before a single chip-minute is spent.
 
     JAX_PLATFORMS=cpu python tools/step_memory.py --workload ouro-train-loop4 \
-        [--set num_hidden_layers=6] [--no-reference]
+        [--set num_hidden_layers=6] [--no-reference] [--hlo FILE]
 
 Prints one JSON object: argument, output, temporary and alias bytes of the
 step (the state is donated, so `alias` is the state it writes in place), the
 same of the reference as benchmark/harness/reference.py::FirstStep calls it,
 and `beside_first_step`: the step's peak plus the fp32 copy of every
-parameter that FirstStep holds through the first step.  A compile is not a
-run: nothing here is a time."""
+parameter that FirstStep holds through the first step.  `--hlo FILE` also
+writes the step's optimised HLO there (its fusions, each with the compiler's
+`estimated_cycles`).  A compile is not a run: nothing here is a time."""
 
 import argparse
 import functools
@@ -37,6 +38,8 @@ def main() -> int:
     ap.add_argument("--set", action="append", default=[],
                     metavar="KEY=VALUE", help="override a configuration key")
     ap.add_argument("--no-reference", action="store_true")
+    ap.add_argument("--hlo", metavar="FILE",
+                    help="write the step's optimised HLO here")
     args = ap.parse_args()
 
     import jax
@@ -65,6 +68,9 @@ def main() -> int:
         step = aot_tpu.trace_tpu(
             compiled.raw_fn, feed_vals, state_vals, rng,
             donate_argnums=(1,)).lower().compile()
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(step.as_text())
     out = {"workload": args.workload, "set": args.set,
            "parameters": n_params, "step": _bytes(step),
            "first_step_copy": 4 * n_params}
