@@ -26,6 +26,7 @@ __all__ = [
     "fused_attention",
     "rotary_embedding",
     "latent_attention",
+    "sparse_attention",
     "moe_router",
     "moe_experts",
     "moe_bias_update",
@@ -1237,18 +1238,48 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
     return out
 
 
-def rotary_embedding(x, base=10000.0, offset=0, name=None):
+def rotary_embedding(x, base=10000.0, offset=0, positions=None,
+                     sections=None, name=None):
     """Rotary position embedding of x [..., S, D] (heads first, then
     positions, then the head's features), half-split pairs, position
-    offset + index along axis -2, angles in fp32 (TPU-native; see
-    ops/attention_ops.py rotary_embedding)."""
+    offset + index along axis -2, angles in fp32.  With `positions`
+    [B, n, S] and `sections` (n counts that add up to D / 2), x [B, ..., S,
+    D]: the pairs of section j turn by position stream j (multi-axis
+    rotary; TPU-native; see ops/attention_ops.py rotary_embedding)."""
     helper = LayerHelper("rotary_embedding", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op(
-        type="rotary_embedding", inputs={"X": [x]}, outputs={"Out": [out]},
-        attrs={"base": float(base), "offset": int(offset)},
-    )
+    inputs = {"X": [x]}
+    attrs = {"base": float(base), "offset": int(offset)}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+        attrs["sections"] = [int(n) for n in sections]
+    helper.append_op(type="rotary_embedding", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
+
+
+def sparse_attention(q, k, v, index_q, index_k, index_w, topk, q_chunk=512,
+                     kv_chunk=512, name=None):
+    """Causal grouped-query attention over the `topk` keys a learned index
+    chooses for each query: q [B, H, S, D] over k, v [B, G, S, D]; the
+    index's queries [B, Hi, S, Di], its one key a token [B, S, Di] and its
+    head weights [B, S, Hi], all rotated already.  Returns (the heads'
+    contexts [B, H, S, D], the index's loss, a scalar: KL of the heads'
+    mean probabilities from the index's softmax over the chosen keys).
+    q, k, v take their gradient from the first, the index from the second
+    (TPU-native; ops/attention_ops.py sparse_attention)."""
+    helper = LayerHelper("sparse_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    loss = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="sparse_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "IndexQ": [index_q],
+                "IndexK": [index_k], "IndexW": [index_w]},
+        outputs={"Out": [out], "IndexLoss": [loss]},
+        attrs={"topk": int(topk), "q_chunk": int(q_chunk),
+               "kv_chunk": int(kv_chunk)},
+    )
+    return out, loss
 
 
 def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
@@ -1275,12 +1306,15 @@ def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
 
 
 def moe_router(x, weight, bias, top_k, scaling=1.0, norm_topk_prob=True,
-               name=None):
-    """Sigmoid router over all the experts weight [d, E] has: (the top_k
-    experts of score + bias a token [..., k] int32, their weights [..., k]
+               scoring="sigmoid", name=None):
+    """Router over all the experts weight [d, E] has, its scores the
+    sigmoid of x.weight or, under `scoring` "softmax", the softmax over
+    the E experts: (the top_k experts of score + bias a token [..., k]
+    int32, their weights [..., k]
     fp32: the scores without the bias, normalised over the chosen and
     times `scaling`, the tokens that chose each expert [E]).  `bias` is
-    state without a gradient (TPU-native; ops/moe_ops.py)."""
+    state without a gradient, or None for a router that has none
+    (TPU-native; ops/moe_ops.py)."""
     helper = LayerHelper("moe_router", input=x, name=name)
     idx = helper.create_variable_for_type_inference("int32",
                                                     stop_gradient=True)
@@ -1289,20 +1323,23 @@ def moe_router(x, weight, bias, top_k, scaling=1.0, norm_topk_prob=True,
                                                      stop_gradient=True)
     helper.append_op(
         type="moe_router",
-        inputs={"X": [x], "Weight": [weight], "Bias": [bias]},
+        inputs={"X": [x], "Weight": [weight],
+                **({} if bias is None else {"Bias": [bias]})},
         outputs={"TopIdx": [idx], "TopWeight": [top_w], "Load": [load]},
         attrs={"top_k": int(top_k), "scaling": float(scaling),
-               "norm_topk_prob": bool(norm_topk_prob)},
+               "norm_topk_prob": bool(norm_topk_prob),
+               "scoring": str(scoring)},
     )
     return idx, top_w, load
 
 
 def moe_experts(x, top_idx, top_weight, gate_w, up_w, down_w, experts_total,
-                expert_offset=0, name=None):
+                expert_offset=0, scoring="sigmoid", name=None):
     """The held experts' part of sum_i g_i E_i(x): gate_w / up_w [held, d,
     f] and down_w [held, f, d] are experts expert_offset .. expert_offset +
     held of `experts_total`; every token routed to one of them is computed,
-    none is dropped (TPU-native; ops/moe_ops.py)."""
+    none is dropped; `scoring` is the router's rule, for the `moe.lower`
+    span alone (TPU-native; ops/moe_ops.py)."""
     helper = LayerHelper("moe_experts", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(
@@ -1311,7 +1348,8 @@ def moe_experts(x, top_idx, top_weight, gate_w, up_w, down_w, experts_total,
                 "GateW": [gate_w], "UpW": [up_w], "DownW": [down_w]},
         outputs={"Out": [out]},
         attrs={"experts_total": int(experts_total),
-               "expert_offset": int(expert_offset)},
+               "expert_offset": int(expert_offset),
+               "scoring": str(scoring)},
     )
     return out
 

@@ -19,6 +19,7 @@ __all__ = [
     "concat",
     "sums",
     "assign",
+    "detach",
     "fill_constant",
     "fill_constant_batch_size_like",
     "ones",
@@ -136,6 +137,18 @@ def assign(input, output=None):
             attrs["fp32_values"] = arr.astype(np.float64).reshape(-1).tolist()
         helper.append_op(type="assign_value", outputs={"Out": [output]}, attrs=attrs)
     return output
+
+
+def detach(x):
+    """x with no gradient through it: a copy that later operations
+    differentiate as a constant, also inside a Recurrence body, which is
+    differentiated as a whole (TPU-native; ops/tensor_ops.py detach)."""
+    helper = LayerHelper("detach")
+    out = helper.create_variable_for_type_inference(x.dtype,
+                                                    stop_gradient=True)
+    helper.append_op(type="detach", inputs={"X": [x]},
+                     outputs={"Out": [out]})
+    return out
 
 
 def fill_constant(shape, dtype, value, force_cpu=False, out=None):

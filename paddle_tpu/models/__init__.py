@@ -17,6 +17,7 @@ from .vgg import vgg16, vgg19  # noqa: F401
 from .transformer import transformer, TransformerConfig  # noqa: F401
 from .looped_decoder import looped_decoder, LoopedDecoderConfig  # noqa: F401
 from .expert_decoder import expert_decoder, ExpertDecoderConfig  # noqa: F401
+from .sparse_decoder import sparse_decoder, SparseDecoderConfig  # noqa: F401
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

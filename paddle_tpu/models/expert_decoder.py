@@ -80,7 +80,7 @@ class _ExpertBuilder(_Builder):
     def param(self, shape, name, **attr):
         return layers.create_parameter(
             shape, "float32",
-            attr=ParamAttr(name=name, initializer=self.init, **attr))
+            attr=ParamAttr(name=name, **{"initializer": self.init, **attr}))
 
     def latent_attention(self, x, name):
         cfg = self.cfg

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import amp
+from ..core.proto import DataType
 from ..core.registry import register_op
 from ..observability import span
 from .common import data, in_desc, same_shape, set_output
@@ -77,17 +78,33 @@ def _fused_attention(ctx, ins, attrs):
                             attrs.get("scale") or None)]}
 
 
-def _rotate(x, base: float, offset: int = 0):
+def _rotate(x, base: float, offset: int = 0, positions=None, sections=()):
     """Rotary position embedding (Su et al. 2021) of x [..., S, D] along
-    its last two axes, position p = offset + index on axis -2, in the
-    half-split layout: pair i is (x[i], x[i + D/2]), turned by the angle
-    p * base^(-2i/D).  The angles are fp32 whatever x is (at base 1e6 and
-    p in the thousands bf16 has no digit left of them); x's dtype out."""
+    its last two axes, in the half-split layout: pair i is (x[i],
+    x[i + D/2]), turned by the angle p * base^(-2i/D).  The position p is
+    offset + the index on axis -2; or, given `positions` [B, n, S] (x then
+    [B, ..., S, D]) and `sections` (n counts that add up to D/2), the b-th
+    row's stream j at that index for the pairs of section j (multi-axis
+    rotary: a token's temporal, height and width positions each turn their
+    own share of the pairs).  The angles are fp32 whatever x is (at base
+    1e6 and p in the thousands bf16 has no digit left of them); x's dtype
+    out."""
     seq, dim = x.shape[-2], x.shape[-1]
     half = dim // 2
     inv_freq = float(base) ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
-    pos = np.arange(seq, dtype=np.float64) + int(offset)
-    angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
+    if positions is None:
+        pos = np.arange(seq, dtype=np.float64) + int(offset)
+        angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
+    else:
+        if sum(sections) != half or len(sections) != positions.shape[1]:
+            raise ValueError(f"sections {tuple(sections)} do not cut the "
+                             f"{half} pairs over {positions.shape[1]} "
+                             "position streams")
+        stream = np.repeat(np.arange(len(sections)), sections)
+        angle = (jnp.swapaxes(positions.astype(jnp.float32), 1, 2)[..., stream]
+                 * jnp.asarray(inv_freq, jnp.float32))       # [B, S, half]
+        angle = angle.reshape((angle.shape[0],) + (1,) * (x.ndim - 3)
+                              + angle.shape[1:])
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     xs = x.astype(amp.stats_dtype(x))
     x1, x2 = xs[..., :half], xs[..., half:]
@@ -98,8 +115,12 @@ def _rotate(x, base: float, offset: int = 0):
 @register_op("rotary_embedding", infer_shape=same_shape("X", "Out"),
              diff_inputs=["X"])
 def _rotary_embedding(ctx, ins, attrs):
-    return {"Out": [_rotate(data(ins["X"][0]), attrs.get("base", 10000.0),
-                            attrs.get("offset", 0))]}
+    pos_in = ins.get("Positions", [None])[0]
+    return {"Out": [_rotate(
+        data(ins["X"][0]), attrs.get("base", 10000.0),
+        attrs.get("offset", 0),
+        None if pos_in is None else data(pos_in),
+        tuple(int(n) for n in attrs.get("sections", ())))]}
 
 
 def _latent_attn_infer(op, block):
@@ -154,3 +175,55 @@ def _latent_attention(ctx, ins, attrs):
         out = _attend(ctx, q, k, kv[..., dn:].astype(k.dtype), None, True,
                       (dn + dr) ** -0.5)
     return {"Out": [jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)]}
+
+
+def _sparse_attn_infer(op, block):
+    q = in_desc(op, block, "Q")
+    if q is None:
+        return
+    set_output(block, op, "Out", list(q.shape), q.dtype)
+    set_output(block, op, "IndexLoss", [], DataType.FP32)
+
+
+@register_op("sparse_attention", infer_shape=_sparse_attn_infer,
+             diff_inputs=["Q", "K", "V", "IndexQ", "IndexK", "IndexW"])
+def _sparse_attention(ctx, ins, attrs):
+    """Causal grouped-query attention in which every query attends to the
+    `topk` keys a learned index ranks highest (DeepSeek-V3.2-Exp's sparse
+    attention), with the index's own loss.  Q [B, H, S, D] over K, V
+    [B, G, S, D] (query head j reads key/value head j // (H / G)); the
+    index IndexQ [B, Hi, S, Di], IndexK [B, S, Di] (one key a token),
+    IndexW [B, S, Hi]: I[t, s] = sum_j w[t, j] relu(q_i[j, t].k_i[s]);
+    S_t the topk positions s <= t of largest I (all while t < topk), exact;
+    Out the softmax of q.k / sqrt(D) over S_t times v; IndexLoss the mean
+    over tokens of KL(the heads' mean probabilities over S_t, detached ||
+    softmax of I over S_t).  Q, K and V take their gradient from Out alone
+    and the index from IndexLoss alone.  Everything is rotated before it
+    comes here.  kernels/sparse_attention.py computes it a chunk of
+    queries at a time, backward included; `dsa.lower` (a span, at
+    lowering) says what a site was given."""
+    from ..kernels import sparse_attention as dsa
+    from ..kernels.flash_attention import _use_pallas
+
+    q, k, v = (data(ins[s][0]) for s in ("Q", "K", "V"))
+    qi, ki, w = (data(ins[s][0]) for s in ("IndexQ", "IndexK", "IndexW"))
+    topk = int(attrs["topk"])
+    _, H, S, D = q.shape
+    q_chunk, kv_chunk = (int(attrs.get(a, 512))
+                         for a in ("q_chunk", "kv_chunk"))
+    tiles = dsa.plan(S, q_chunk, kv_chunk)
+    seen = min(S, topk)
+    with span("dsa.lower", heads=int(H), kv_heads=int(k.shape[1]),
+              index_heads=int(qi.shape[1]), index_dim=int(qi.shape[-1]),
+              topk=topk, sq=int(S), q_chunk=tiles["q_chunk"],
+              kv_chunk=tiles["kv_block"], keys_causal=S * (S + 1) // 2,
+              keys_selected=seen * (seen + 1) // 2 + (S - seen) * topk,
+              engine="masked-block" if _use_pallas("auto") else "xla",
+              indices="recomputed"):
+        q, k, v = amp.mxu_operands(q, k, v)
+        qi, ki = amp.mxu_operands(qi, ki)
+        out, loss = dsa.sparse_attention(
+            q, k.astype(q.dtype), v.astype(q.dtype), qi, ki.astype(qi.dtype),
+            w, topk=topk, scale=float(D) ** -0.5, q_chunk=q_chunk,
+            kv_chunk=kv_chunk)
+    return {"Out": [out], "IndexLoss": [loss]}

@@ -6,9 +6,11 @@ Three ops, one chip's share of an expert-parallel layer:
 - `moe_router`: sigmoid scores over ALL the experts, the `top_k` largest of
   score + bias chosen, their weights the scores themselves (without the
   bias) divided by their sum and times a scaling factor (DeepSeek-V3's
-  `noaux_tc`).  The bias is state with no gradient.  Scores in fp32, as the
-  family's implementations compute them: a top-k over bf16 scores picks
-  another expert wherever two scores lie within bf16's rounding.
+  `noaux_tc`); or, under `scoring` "softmax", the softmax over all the
+  experts in place of the sigmoid (Qwen3-MoE's rule: no bias, scaling 1).
+  The bias is state with no gradient.  Scores in fp32, as the families'
+  implementations compute them: a top-k over bf16 scores picks another
+  expert wherever two scores lie within bf16's rounding.
 - `moe_experts`: the part of sum_i g_i E_i(x) that the HELD experts
   [expert_offset, expert_offset + held) give, E_i(u) = W_down(silu(W_gate
   u) * W_up u).  Static shapes, one compilation, no capacity factor and no
@@ -54,17 +56,23 @@ from .common import data, in_desc, same_shape, set_output
 __all__ = ["route", "held_experts_part", "row_buffers"]
 
 
-def route(x, w, bias, top_k: int, scaling: float, normalize: bool):
+SCORING = {"sigmoid": jax.nn.sigmoid,
+           "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
+def route(x, w, bias, top_k: int, scaling: float, normalize: bool,
+          scoring: str = "sigmoid"):
     """(idx [T, k] int32, weight [T, k] fp32, load [E] fp32) of tokens x
-    [T, d] under the router w [d, E]: s = sigmoid(x w) in fp32; chosen the
-    top_k of s + bias; weights s over the chosen (never s + bias), divided
-    by their sum under `normalize`, times `scaling`; load_i the tokens that
-    chose expert i."""
+    [T, d] under the router w [d, E]: s = sigmoid(x w), or under `scoring`
+    "softmax" the softmax of x w over all E, in fp32; chosen the top_k of
+    s + bias; weights s over the chosen (never s + bias), divided by their
+    sum under `normalize`, times `scaling`; load_i the tokens that chose
+    expert i.  `bias` None is no bias."""
     with jax.default_matmul_precision("highest"):
-        scores = jax.nn.sigmoid(jnp.matmul(
+        scores = SCORING[scoring](jnp.matmul(
             x.astype(jnp.float32), w.astype(jnp.float32)))
-    _, idx = jax.lax.top_k(
-        jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
         weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
@@ -316,12 +324,14 @@ def _router_infer(op, block):
 def _moe_router(ctx, ins, attrs):
     x = data(ins["X"][0])
     lead = x.shape[:-1]
+    bias_in = ins.get("Bias", [None])[0]
     with jax.named_scope("moe.router"):
         idx, weight, load = route(
             x.reshape(-1, x.shape[-1]), data(ins["Weight"][0]),
-            data(ins["Bias"][0]), int(attrs["top_k"]),
+            None if bias_in is None else data(bias_in), int(attrs["top_k"]),
             float(attrs.get("scaling", 1.0)),
-            bool(attrs.get("norm_topk_prob", True)))
+            bool(attrs.get("norm_topk_prob", True)),
+            attrs.get("scoring", "sigmoid"))
     k = idx.shape[-1]
     return {"TopIdx": [idx.reshape(lead + (k,))],
             "TopWeight": [weight.reshape(lead + (k,))], "Load": [load]}
@@ -342,7 +352,8 @@ def _moe_experts(ctx, ins, attrs):
     with span("moe.lower", experts_total=total,
               experts_held=int(gate_w.shape[0]), top_k=int(k),
               row_buffer=buffers[-1], row_buffer_usual=buffers[0],
-              row_buffers=len(buffers), engine=_engine(None), dropped=0):
+              row_buffers=len(buffers), engine=_engine(None), dropped=0,
+              scoring=attrs.get("scoring", "sigmoid")):
         y = held_experts_part(
             xc.reshape(tokens, x.shape[-1]), idx.reshape(tokens, k),
             weight.reshape(tokens, k).astype(jnp.float32),

@@ -76,6 +76,15 @@ def _assign(ctx, ins, attrs):
     return {"Out": [x]}
 
 
+@register_op("detach", infer_shape=_fill_like_infer, no_grad=True)
+def _detach(ctx, ins, attrs):
+    """X as a constant of the gradient (jax.lax.stop_gradient).  A
+    variable's `stop_gradient` mark stops append_backward's ops; this stops
+    the gradient where a whole block is differentiated at once, inside a
+    layers.Recurrence body."""
+    return {"Out": [jax.lax.stop_gradient(data(ins["X"][0]))]}
+
+
 def _assign_value_infer(op, block):
     set_output(
         block, op, "Out", list(op.attr("shape", [1])),

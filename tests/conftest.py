@@ -104,17 +104,32 @@ def _fresh_programs():
     amp.reset_amp()
 
 
-# Two cases of tests under tests/benchmark/ (the benchmark's files, which a
+# Some cases of tests under tests/benchmark/ (the benchmark's files, which a
 # model_config PR adds to and does not edit) cannot pass for a reason that
 # is not a configuration's: marked as expected failures, strictly, so that
 # the `benchmark` PR that repairs either test has to take the mark out.
-# tests/benchmark/test_moonlight_benchmark.py holds the new configuration
-# and the readers' lists to everything else those tests ask (PERF.md 7).
+# tests/benchmark/test_moonlight_benchmark.py and test_keye_benchmark.py hold
+# the configurations and the readers' lists to everything else those tests
+# ask (PERF.md 7).
 _BENCHMARK_TESTS_A_LATER_ENTRY_TRIPS = {
     "test_benchmark_manifest.py::test_configuration_entry_and_files"
     "[moonlight-16b-a3b]":
         "the width expression matches 'hidden' in num_hidden_layers, which "
         "is the depth (as for ouro-2.6b, tests/benchmark/conftest.py)",
+    "test_benchmark_manifest.py::test_configuration_entry_and_files"
+    "[keye-vl-2.0-30b-a3b]":
+        "the same width expression on the same key, num_hidden_layers "
+        "(tests/benchmark/test_keye_benchmark.py holds the file to the "
+        "rest)",
+    "test_moonlight_benchmark.py::"
+    "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
+        "PR 31's test pins moe_experts_ms.train and moe_dispatch_ms.train "
+        "to its cell alone; keye-train-dsa16k (PR 33) has the same expert "
+        "block and reports them too",
+    "test_moonlight_benchmark.py::"
+    "test_what_pr_27s_manifest_test_held_for_its_cell_still_holds":
+        "PR 31's test counts every later cell's own .train readers (PR "
+        "33's five dsa_* ones) against ouro-train-loop4",
     "test_ouro_benchmark.py::"
     "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
         "PR 27's test pins hbm_peak_gb.train to ouro-train-loop4 alone and "

@@ -212,7 +212,31 @@ def _held_experts():
             (x, weight, wide, wide, down, idx))
 
 
+def _sparse_attention():
+    """keye-vl-2.0-30b-a3b's attention at the cell's heads and tiles (32
+    query heads on 4 key/value heads of 128, an index of 16 x 64, top 2048,
+    chunks of 512 queries, score blocks of 1024 keys), a quarter of its
+    sequence: the index's two kernels, the forward, the probability pass
+    and the one backward kernel, under the chunks' scan."""
+    from paddle_tpu.kernels import sparse_attention
+
+    S = 4096
+    args = (_sds((1, 32, S, 128), jnp.bfloat16),
+            _sds((1, 4, S, 128), jnp.bfloat16),
+            _sds((1, 4, S, 128), jnp.bfloat16),
+            _sds((1, 16, S, 64), jnp.bfloat16),
+            _sds((1, S, 64), jnp.bfloat16), _sds((1, S, 16), jnp.float32))
+
+    def loss(*a):
+        out, kl = sparse_attention.sparse_attention(
+            *a, topk=2048, scale=128 ** -0.5, engine="pallas")
+        return jnp.sum(out.astype(jnp.float32)) + kl
+
+    return jax.value_and_grad(loss, argnums=tuple(range(6))), args
+
+
 _MAIN_PATH_KERNELS = {
+    "sparse_attention_bwd_pallas_keye": _sparse_attention,
     "flash_bwd_pallas_moonlight_192_128": _flash_mla,
     "held_experts_moonlight": _held_experts,
     "flash_fwd_transformer_base": lambda: _flash_fwd((32, 8, 256, 64)),

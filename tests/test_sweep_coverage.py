@@ -217,7 +217,21 @@ def _via_expert_decoder():
     return spec.loss, spec.synthetic_batch(2, 0)
 
 
+def _via_sparse_decoder():
+    # numerics and gradients: tests/test_sparse_decoder.py
+    from paddle_tpu import models
+
+    spec = models.sparse_decoder(models.SparseDecoderConfig(
+        vocab_size=32, max_length=16, n_layer=1, d_model=16, n_head=4,
+        n_kv_head=2, head_dim=8, mrope_section=(1, 1, 2), index_heads=2,
+        index_dim=8, index_topk=4, q_chunk=8, kv_chunk=8,
+        n_routed_experts=8, experts_held=2, top_k=2, d_expert=12))
+    return spec.loss, spec.synthetic_batch(2, 0)
+
+
 EXERCISED_VIA = {
+    "sparse_attention": _via_sparse_decoder,
+    "detach": _via_sparse_decoder,
     "latent_attention": _via_expert_decoder,
     "moe_router": _via_expert_decoder,
     "moe_experts": _via_expert_decoder,
