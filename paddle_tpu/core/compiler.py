@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .enforce import op_error_context
 from .framework import Block, Program
@@ -34,6 +35,31 @@ __all__ = ["LoweringContext", "compile_block", "CompiledBlock"]
 
 # ops handled by the executor itself, not lowered
 _SKIP_OPS = {"feed", "fetch"}
+
+# The one name under which a value survives the recomputation of the unit
+# around it (framework.recompute_scope): see keep / rematerialised.
+KEEP = "recompute.keep"
+# one object for every unit: jax keys its lowering caches on a checkpoint's
+# policy, and a policy made anew for each unit has every layer's functions
+# lowered again (the StableHLO then differs from the bare checkpoint's)
+_KEEP_POLICY = jax.checkpoint_policies.save_only_these_names(KEEP)
+
+
+def keep(*values):
+    """`values`, each tagged to survive the recomputation of the unit around
+    it.  A kernel tags what its backward reads and its forward had in hand
+    where making it again costs far more than holding it (an O(S^2) pass
+    for an O(S) array); `rematerialised` saves exactly what was tagged.
+    Outside a rematerialised unit a tag does nothing.  The op that lowers
+    to such a kernel adds their number to `LoweringContext.kept`."""
+    return tuple(checkpoint_name(v, KEEP) for v in values)
+
+
+def rematerialised(fn, **checkpoint_kwargs):
+    """`fn` as a unit of rematerialization, the one way a unit is made:
+    the backward computes its activations again from its inputs, all but
+    the values an op inside tagged with `keep`."""
+    return jax.checkpoint(fn, policy=_KEEP_POLICY, **checkpoint_kwargs)
 
 
 class LoweringContext:
@@ -55,6 +81,8 @@ class LoweringContext:
         self.mesh = mesh
         self.is_test = is_test
         self.cur_op = None  # the OpDesc being lowered (set by the driver)
+        # values this block's ops tagged with `keep` (`recurrence.lower`)
+        self.kept = 0
         # uid -> (vjp_fn, primal_outs, in_slots, out_slots)
         self.vjps: Dict[int, Any] = {}
         self._fixed_key = None
@@ -314,10 +342,11 @@ def _lower_forward_op(ctx: LoweringContext, op: OpDesc, need_vjp: bool) -> None:
     if attrs.get("@recompute@") and not info.meta.get("own_recompute"):
         # rematerialization (framework.recompute_scope): backward re-runs
         # this op's lowering from its inputs instead of keeping internal
-        # activations resident — jax.checkpoint drops the residuals.  An
-        # op registered own_recompute (recurrence) places the checkpoint
-        # itself, around a unit smaller than the whole op
-        fwd = jax.checkpoint(fwd)
+        # activations resident — jax.checkpoint drops the residuals, all
+        # but what an op's kernel tagged with `keep`.  An op registered
+        # own_recompute (recurrence) places the checkpoint itself, around
+        # a unit smaller than the whole op
+        fwd = rematerialised(fwd)
     primal_outs, vjp_fn = jax.vjp(fwd, *leaves)
     out_spec = out_spec_holder[0]
     outs = {

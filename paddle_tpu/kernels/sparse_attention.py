@@ -25,10 +25,21 @@ the head weights and the sum over index heads in fp32), finds each row's
 topk-th largest score EXACTLY by a bitwise search over the scores'
 order-preserving integer image (32 compare-and-count passes; a sort-sized
 lax.top_k on a TPU costs an order more), and hands the attention a
-[q_chunk, S] int8 mask.  The chosen sets are recomputed by the backward,
-not saved: the forward keeps each row's threshold (S integers a layer) and
+[q_chunk, S] int8 mask.  The chosen SETS are recomputed by the backward,
+never saved: the forward keeps each row's threshold (S integers a layer) and
 the backward compares the recomputed scores with it (the same kernel on
 the same operands gives the same bits).
+
+What survives a layer's recomputation: the forward's out, the rows'
+logsumexp and the thresholds (`KEPT`; out's bytes and a little, `kept_bytes`)
+are tagged with core.compiler.keep.  Where the layer around the op is a
+rematerialised unit (a recurrence's trip under recompute_scope), jax saves
+the three at the first forward and the backward's copy of the chunk scan
+is dead code: the layer's cheap ops are computed again to hand the
+backward q, k, v, q_i, k_i and w, but the index, the search and the attend
+kernel run once.  All three or nothing: with one left out the scan still
+has to run for it.  An O(S^2) pass for O(S) values, so always, not by
+shape.  Outside such a unit the tags do nothing.
 
 The attention is the MASKED BLOCK engine: every [q_chunk, kv_block] score
 block with a chosen key is computed on the MXU and masked; a block in which
@@ -63,6 +74,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ..core.compiler import keep
 
 __all__ = ["sparse_attention", "index_scores", "select_topk", "plan"]
 
@@ -617,8 +630,20 @@ def _sparse_attention(q, k, v, qi, ki, w, cfg):
     return out, kl
 
 
+KEPT = ("out", "lse", "thr")   # of _forward, what _sparse_attention_fwd keeps
+
+
+def kept_bytes(q) -> int:
+    """What a site keeps through its layer's recomputation, of q
+    [B, H, S, D]: out in q's dtype, the rows' logsumexp fp32 and a
+    threshold a token."""
+    B, H, S, D = q.shape
+    return B * S * (H * D * q.dtype.itemsize + H * 4 + 4)
+
+
 def _sparse_attention_fwd(q, k, v, qi, ki, w, cfg):
     out, lse, kl, thr = _forward(q, k, v, qi, ki, w, cfg)
+    out, lse, thr = keep(out, lse, thr)
     return (out, kl), (q, k, v, qi, ki, w, out, lse, thr)
 
 

@@ -200,8 +200,12 @@ def _sparse_attention(ctx, ins, attrs):
     softmax of I over S_t).  Q, K and V take their gradient from Out alone
     and the index from IndexLoss alone.  Everything is rotated before it
     comes here.  kernels/sparse_attention.py computes it a chunk of
-    queries at a time, backward included; `dsa.lower` (a span, at
-    lowering) says what a site was given."""
+    queries at a time, backward included, and tags the forward's output,
+    logsumexp and thresholds to survive the recomputation of the unit
+    around the op (core.compiler.keep): the backward of a recomputed layer
+    runs no second forward of this op.  `dsa.lower` (a span, at lowering)
+    says what a site was given, `kept` and `kept_bytes` what it holds
+    through that recomputation; the context's `kept` counts the values."""
     from ..kernels import sparse_attention as dsa
     from ..kernels.flash_attention import _use_pallas
 
@@ -219,9 +223,11 @@ def _sparse_attention(ctx, ins, attrs):
               kv_chunk=tiles["kv_block"], keys_causal=S * (S + 1) // 2,
               keys_selected=seen * (seen + 1) // 2 + (S - seen) * topk,
               engine="masked-block" if _use_pallas("auto") else "xla",
-              indices="recomputed"):
+              indices="recomputed", kept=",".join(dsa.KEPT)) as sp:
         q, k, v = amp.mxu_operands(q, k, v)
         qi, ki = amp.mxu_operands(qi, ki)
+        sp.set(kept_bytes=dsa.kept_bytes(q))
+        ctx.kept += len(dsa.KEPT)
         out, loss = dsa.sparse_attention(
             q, k.astype(q.dtype), v.astype(q.dtype), qi, ki.astype(qi.dtype),
             w, topk=topk, scale=float(D) ** -0.5, q_chunk=q_chunk,

@@ -614,10 +614,14 @@ def _recurrence(ctx, ins, attrs):
     whole by the compiler's jax.vjp, so a value read from outside has ONE
     gradient, summed over the trips inside the scan's transpose.  Under
     framework.recompute_scope (the op's @recompute@ attr) the TRIP is the
-    unit of rematerialization: jax.checkpoint goes around the scan body,
-    each trip's incoming carry is kept and its activations are computed
-    again in the backward pass."""
-    from ..core.compiler import LoweringContext, lower_op
+    unit of rematerialization: compiler.rematerialised goes around the
+    scan body, each trip's incoming carry is kept, with it whatever an op
+    of the body tagged with compiler.keep (sparse attention: its output,
+    logsumexp and thresholds), and every other activation is computed
+    again in the backward pass.  `recurrence.lower` (a span) counts the
+    tagged values as `kept`: 0 where the body's ops name nothing, and
+    then the lowering is the bare jax.checkpoint's."""
+    from ..core.compiler import LoweringContext, lower_op, rematerialised
 
     sub_block = ctx.program.block(attrs["sub_block"])
     ops = list(sub_block.desc.ops)
@@ -630,6 +634,7 @@ def _recurrence(ctx, ins, attrs):
     init = tuple(jnp.asarray(data(v)) for v in ins["Init"])
 
     lowered = [0]  # times the body went through lower_op: 1 under scan
+    kept = [0]     # values the body's ops tagged with compiler.keep
 
     def body(carry, key):
         lowered[0] += 1
@@ -639,6 +644,7 @@ def _recurrence(ctx, ins, attrs):
                                 mesh=ctx.mesh, is_test=ctx.is_test)
         for op in ops:
             lower_op(inner, op, frozenset())
+        kept[0] = inner.kept
         # a carry keeps the dtype it came in with (under amp's keep tier
         # the body hands a bf16 state on where the first came in fp32)
         new = tuple(jnp.asarray(data(env[n])).astype(c.dtype)
@@ -647,10 +653,12 @@ def _recurrence(ctx, ins, attrs):
 
     with span("recurrence.lower", trips=trips,
               recompute=int(recompute)) as sp:
-        step = jax.checkpoint(body, prevent_cse=False) if recompute else body
+        step = (rematerialised(body, prevent_cse=False) if recompute
+                else body)
         final, stacked = jax.lax.scan(
             step, init, jax.random.split(ctx.rng(), trips))
-        sp.set(bodies_lowered=lowered[0])
+        sp.set(bodies_lowered=lowered[0], kept=kept[0])
+    ctx.kept += kept[0]
     return {"Out": list(stacked), "Final": list(final)}
 
 

@@ -276,7 +276,8 @@ def test_recurrence_lower_span_counts_one_body(monkeypatch):
     spans = [s for s in observability.default_tracer().spans()
              if s.name == "recurrence.lower"]
     assert len(spans) == 1
-    assert spans[0].args == {"trips": 4, "bodies_lowered": 1, "recompute": 1}
+    assert spans[0].args == {"trips": 4, "bodies_lowered": 1, "recompute": 1,
+                             "kept": 0}
     assert observability.default_registry().counter(
         "recurrence_unrolled").value(op="while") == 0
     observability.reset()

@@ -433,8 +433,10 @@ def test_dsa_lower_and_moe_lower_say_what_a_site_was_given():
         heads=32, kv_heads=4, index_heads=16, index_dim=64, topk=2048,
         sq=S, q_chunk=512, kv_chunk=1024, keys_causal=S * (S + 1) // 2,
         keys_selected=2048 * 2049 // 2 + (S - 2048) * 2048,
-        engine="masked-block", indices="recomputed")]
+        engine="masked-block", indices="recomputed", kept="out,lse,thr",
+        kept_bytes=S * (32 * 128 * 2 + 32 * 4 + 4))]
     assert spans["dsa.lower"][0]["keys_selected"] == 31_458_304
+    assert spans["dsa.lower"][0]["kept_bytes"] == 136_380_416
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=128, experts_held=16, top_k=8, row_buffer=8 * S,
         row_buffer_usual=2 * S, row_buffers=3, engine="megablox", dropped=0,

@@ -13,6 +13,13 @@ Rows, ms a call over the whole sequence (one layer):
   forward+backward jax.value_and_grad of (sum out + L_I): what a training
                    step runs once (forward) and once again (recomputed
                    forward + backward) a layer
+  recomputed       forward+backward with the op inside a recomputed unit, as
+                   a layer of the cell holds it, twice: `bare` under
+                   jax.checkpoint (nothing but the inputs survives: the
+                   backward runs the whole forward again) and `kept` under
+                   core.compiler.rematerialised (the op's out, lse and thr
+                   survive: PERF.md PR 34); bare - kept is what the second
+                   forward costs a layer
   gather-chunk     the engine NOT kept, one chunk of 512 queries at the end
                    of the sequence, forward and backward by jax's own
                    gradient: each query gathers its 2048 chosen K and V rows
@@ -54,7 +61,7 @@ def main() -> int:
     ap.add_argument("--calls", type=int, default=3)
     ap.add_argument("--kv-chunk", type=int, default=None)
     ap.add_argument("--what", default="index,select,forward,backward,"
-                    "gather,masked")
+                    "recomputed,gather,masked")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default="chiprun_out/keye_engine_probe.json")
     a = ap.parse_args()
@@ -63,6 +70,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from paddle_tpu.core.compiler import rematerialised
     from paddle_tpu.kernels import sparse_attention as sa
 
     if a.rehearse:
@@ -136,6 +144,14 @@ def main() -> int:
         fn = jax.jit(jax.value_and_grad(op, argnums=tuple(range(6))))
         row("forward+backward", _time_ms(fn, (q, k, v, qi, ki, w), a.calls),
             3.5 * attend)
+
+    if "recomputed" in what:
+        for name, unit in (("bare", jax.checkpoint(op)),
+                           ("kept", rematerialised(op))):
+            fn = jax.jit(jax.value_and_grad(unit, argnums=tuple(range(6))))
+            row("recomputed-" + name,
+                _time_ms(fn, (q, k, v, qi, ki, w), a.calls), 3.5 * attend,
+                kept_bytes=sa.kept_bytes(q) if name == "kept" else 0)
 
     # one chunk, the last of the sequence, under both engines: the same
     # chosen keys (scattered uniformly over the causal ones)
