@@ -257,14 +257,24 @@ def staged_args(feed_vals, state_vals, rng, wants):
     return vals[:n], vals[n:-1], vals[-1], moved
 
 
+# `seq` of `executor.step`: the process's steps by number, so that a reader
+# of the spans knows step k from step k + 1 by identity and not by order
+_STEP_SEQ = itertools.count()
+
+
 def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
              return_numpy, donated=False, steps=None, sentinel=None,
              cost=None):
     """The ONE copy of a step's sequence, for Executor and ParallelExecutor,
     run and run_steps: plan, stage, dispatch, commit, fetch, each a span
     under `executor.step` (observability/tracing.py: on the profiler's
-    clock always, in the ring under FLAGS_observability).  The callers give
-    what differs between them:
+    clock always, in the ring under FLAGS_observability).  Where the fetch
+    converts to the host it is `executor.wait` (until every fetched value
+    is ready: its end is the host's "device done" mark) and then
+    `executor.copy`.  The callers open `executor.run` around all of it, so
+    what follows `executor.step` inside that span is this frame letting go
+    of the staged and donated arguments.  The callers give what differs
+    between them:
 
     lookup() -> ((fp, call, plan), hit); a miss nests the `compile` span
     feeds(plan, block0) -> the feed values as the plan phase leaves them
@@ -280,7 +290,7 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     from ..resilience import faultinject
 
     skipped = False
-    with _obs.span("executor.step", kind=kind) as step:
+    with _obs.span("executor.step", kind=kind, seq=next(_STEP_SEQ)) as step:
         with _obs.span("executor.plan") as sp:
             entry, hit = lookup()
             _, call, plan = entry
@@ -311,7 +321,20 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
                 plan.write_back(scope, new_states, new_rng)
                 _check_nan_inf(plan, fetches, new_states)
         with _obs.span("executor.fetch") as sp:
-            out = plan.convert_fetches(fetches, block0, return_numpy)
+            if return_numpy:
+                with _obs.span("executor.wait"):
+                    # the copy is asked for first, where np.asarray asked
+                    # for it when it was the one to wait: it then follows
+                    # the step on the device, and does not start only once
+                    # the host has heard of the step's end
+                    for v in jax.tree_util.tree_leaves(fetches):
+                        if isinstance(v, jax.Array):
+                            v.copy_to_host_async()
+                    jax.block_until_ready(fetches)
+                with _obs.span("executor.copy"):
+                    out = plan.convert_fetches(fetches, block0, True)
+            else:
+                out = plan.convert_fetches(fetches, block0, False)
             sp.set(n=len(out))
     if step.seconds is not None:  # FLAGS_observability
         if steps is None:
@@ -463,11 +486,15 @@ class Executor:
         return_numpy: bool = True,
         use_program_cache: bool = True,
     ) -> List[Any]:
-        # trace-time defaults scope: auto conv layout / auto AMP resolve
+        # `executor.run`: the whole call, one a step (a CompiledProgram goes
+        # on to ParallelExecutor._run_in_run, which opens none).  Inside it,
+        # the trace-time defaults scope: auto conv layout / auto AMP resolve
         # for the ACTUAL device this executor targets; entered around key
         # computation, compilation, and execution so cache keys and traced
         # programs always agree
-        with flags.tpu_trace_scope(device_is_tpu(self.place.jax_device())):
+        with _obs.span("executor.run"), \
+                flags.tpu_trace_scope(
+                    device_is_tpu(self.place.jax_device())):
             return self._run_scoped(
                 program, feed, fetch_list, feed_var_name, fetch_var_name,
                 scope, return_numpy, use_program_cache)
@@ -491,7 +518,7 @@ class Executor:
                 for r in src._py_readers:
                     feed.update(r._next_batch())
             pe = program._executor_for_scope(scope or global_scope())
-            return pe.run(fetch_list=fetch_list, feed=feed, return_numpy=return_numpy)
+            return pe._run_in_run(fetch_list, feed, None, return_numpy)
 
         program = program or default_main_program()
         if feed is None and getattr(program, "_py_readers", None):
@@ -731,7 +758,9 @@ class Executor:
         return_numpy: bool = True,
         mode: str = "scan",
     ) -> List[Any]:
-        with flags.tpu_trace_scope(device_is_tpu(self.place.jax_device())):
+        with _obs.span("executor.run"), \
+                flags.tpu_trace_scope(
+                    device_is_tpu(self.place.jax_device())):
             return self._run_steps_scoped(
                 program, feed_list, fetch_list, steps, scope, return_numpy,
                 mode)

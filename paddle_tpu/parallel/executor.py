@@ -23,6 +23,7 @@ import numpy as np
 import dataclasses
 
 from .. import flags
+from .. import observability as _obs
 from ..core.compiler import CompiledBlock
 from ..core.executor import (
     _RunPlan,
@@ -150,6 +151,12 @@ class ParallelExecutor:
         feed_dict: Optional[Dict[str, Any]] = None,
         return_numpy: bool = True,
     ) -> List[Any]:
+        with _obs.span("executor.run"):
+            return self._run_in_run(fetch_list, feed, feed_dict, return_numpy)
+
+    def _run_in_run(self, fetch_list, feed, feed_dict, return_numpy):
+        """run() under the caller's `executor.run`: Executor.run enters
+        here with a CompiledProgram, its own span open, so a step has one."""
         # trace-time defaults scope keyed off the mesh's actual devices
         # (see core/executor.py Executor.run)
         with flags.tpu_trace_scope(self._mesh_is_tpu()):
@@ -251,7 +258,8 @@ class ParallelExecutor:
         return_numpy: bool = True,
         mode: str = "scan",
     ) -> List[Any]:
-        with flags.tpu_trace_scope(self._mesh_is_tpu()):
+        with _obs.span("executor.run"), \
+                flags.tpu_trace_scope(self._mesh_is_tpu()):
             return self._run_steps_scoped(
                 feed_list, fetch_list, steps, return_numpy, mode)
 

@@ -134,6 +134,12 @@ _BENCHMARK_TESTS_A_LATER_ENTRY_TRIPS = {
     "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
         "PR 27's test pins hbm_peak_gb.train to ouro-train-loop4 alone and "
         "counts every later cell's own .train readers against it",
+    "test_keye_benchmark.py::"
+    "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
+        "PR 33's test pins the manifest's last five per-layer entries and "
+        "the cell's whole reader set; PR 35 appends seven turnaround "
+        "readers that every training cell reports "
+        "(tests/benchmark/test_turnaround.py holds what still stands)",
 }
 
 
