@@ -257,6 +257,27 @@ def staged_args(feed_vals, state_vals, rng, wants):
     return vals[:n], vals[n:-1], vals[-1], moved
 
 
+def cost_pending(entry) -> bool:
+    """Whether FLAGS_observability_cost still wants this entry's
+    once-a-program costing."""
+    return (flags.flag("observability_cost") != "off"
+            and not getattr(entry[1], "_obs_cost_done", False))
+
+
+def abstract_args(*args):
+    """A call's arguments as the once-a-program costing reads them: every
+    jax.Array as its shape, dtype and sharding (what a lowering keys on),
+    so that costing after the step holds no buffer the step consumed."""
+    def abstract(v):
+        if isinstance(v, jax.Array):
+            return jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                        sharding=v.sharding,
+                                        weak_type=v.weak_type)
+        return v
+
+    return jax.tree_util.tree_map(abstract, args)
+
+
 # `seq` of `executor.step`: the process's steps by number, so that a reader
 # of the spans knows step k from step k + 1 by identity and not by order
 _STEP_SEQ = itertools.count()
@@ -271,10 +292,15 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     clock always, in the ring under FLAGS_observability).  Where the fetch
     converts to the host it is `executor.wait` (until every fetched value
     is ready: its end is the host's "device done" mark) and then
-    `executor.copy`.  The callers open `executor.run` around all of it, so
-    what follows `executor.step` inside that span is this frame letting go
-    of the staged and donated arguments.  The callers give what differs
-    between them:
+    `executor.copy`.  The frame lets go of what the step consumed as soon
+    as the scope holds what the step produced: at the end of
+    `executor.commit` it drops the staged state and key, the last
+    references to the arrays the call took by donation, so that their
+    release (a call into the runtime a shard) runs on the host while the
+    device computes the step just enqueued, and not after the wait, when
+    the device has nothing queued.  The callers open `executor.run` around
+    all of it; what follows `executor.step` inside that span is the frames
+    returning, no more.  The callers give what differs between them:
 
     lookup() -> ((fp, call, plan), hit); a miss nests the `compile` span
     feeds(plan, block0) -> the feed values as the plan phase leaves them
@@ -285,7 +311,9 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     placed: the context the call is made in (a default device, a mesh)
     sentinel(plan, fetches, new_states) -> whether to skip the write-back
     cost(entry, feed_vals, state_vals, rng): once-a-program attribution,
-        made after the step so that it is in no step's time
+        made after the step so that it is in no step's time, from the
+        arguments' abstract shapes (abstract_args): whether the entry
+        still wants it (cost_pending) is settled before the arrays go
     """
     from ..resilience import faultinject
 
@@ -320,6 +348,14 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             else:
                 plan.write_back(scope, new_states, new_rng)
                 _check_nan_inf(plan, fetches, new_states)
+            cost_args = None
+            if (cost is not None and not skipped and _obs.enabled()
+                    and cost_pending(entry)):
+                cost_args = abstract_args(feed_vals, state_vals, rng)
+            # the scope holds the step's state (the new one, or on a
+            # skipped step still the old): the frame's references go here,
+            # under the running step, and not after the wait
+            del state_vals, rng
         with _obs.span("executor.fetch") as sp:
             if return_numpy:
                 with _obs.span("executor.wait"):
@@ -346,8 +382,8 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
                 "run_steps wall time per K-step dispatch",
             ).observe(step.seconds, steps=str(steps))
         _obs.record_device_memory(device)
-        if cost is not None and not skipped:
-            cost(entry, feed_vals, state_vals, rng)
+        if cost_args is not None:
+            cost(entry, *cost_args)
     return out
 
 
@@ -578,14 +614,12 @@ class Executor:
 
     @staticmethod
     def _maybe_record_cost(entry, feed_vals, state_vals, rng) -> None:
-        """FLAGS_observability_cost: once per fresh compiled entry,
-        record the XLA cost model's bytes/flops per step labeled by
-        program fingerprint, so a flag flip that recompiles lands on a
-        separate series with no chip."""
+        """FLAGS_observability_cost: once per fresh compiled entry (run_step
+        asks cost_pending first), record the XLA cost model's bytes/flops
+        per step labeled by program fingerprint, so a flag flip that
+        recompiles lands on a separate series with no chip."""
         fp, compiled, _ = entry
         mode = flags.flag("observability_cost")
-        if mode == "off" or getattr(compiled, "_obs_cost_done", False):
-            return
         compiled._obs_cost_done = True  # one attempt, even on failure
         try:
             ca = compiled.cost_analysis(
