@@ -59,14 +59,38 @@ accumulators of dQ and dK), P and dS cast to the operand dtype only for
 the MXU.  `flash.bwd_plan` (a span, at lowering) says what a site was
 given; the backward's operations sit under the name scope `flash.bwd`.
 
+Three things a site may ask of the same two kernels (PR 38), each read
+from the call's shape and `window`, none from a flag:
+- `window`: a query sees the `window` keys that end at its diagonal.  A
+  k-block wholly older than the window of a q-block's first row is treated
+  as one above the diagonal is: in the forward the k-axis of the grid counts
+  from the first block a q-block reads (_first_k_block), so the older ones
+  are no step at all; in the backward the q-blocks past a k-block's reach
+  are under pl.when and the index maps wait (_q_block_index).  A block that
+  straddles either edge takes the mask.  The plans count the steps that RUN
+  and, under a window, weigh them by the scores a block computes
+  (_fewest_steps): at window 1024 the widest blocks would be half waste.
+- grouped K/V: k and v come as [B, G, Sk, .], G a divisor of H, and query
+  head j reads head j // (H / G) through the index maps; they are never
+  repeated in HBM.  The backward writes a query head's dK and dV each and a
+  group's are added up after the kernel, in fp32 (_bwd_rows).
+- long rows: where the row's dQ does not fit VMEM (past S ~4k at head 128)
+  the backward runs as an outer loop over chunks of queries around the one
+  kernel (_bwd_trips): a chunk's dQ is the kernel's whole "row", the chunk is
+  handed only the keys it can see (from its first row's oldest key under a
+  window), and the chunks' dK and dV are added into the sequence's in fp32.
+  `flash.bwd_plan` says `chunks`; 1 is the kernel as it always ran.
+
 Backward selection is read from the shape in one place (_bwd_plan): the
 Pallas kernel where the plan's score block has at least 384 x 384 scores
-to spread a grid step's fixed cost over and the row's dQ fits VMEM
-(S >= 384 up to ~4k at head 128), else jax.vjp of the reference
-formulation, a recompute backward that XLA fuses, whose forward emits no
-lse.  At S 256 a head is one grid step: the kernel alone ties with XLA,
-and the lse its forward must then emit makes the pair 9% slower
-(the probe's table), so XLA keeps it.  No flag, no model name:
+to spread a grid step's fixed cost over and the row's dQ, or a chunk's,
+fits VMEM (S >= 384), else jax.vjp of the reference formulation, a
+recompute backward that XLA fuses, whose forward emits no lse; on a TPU a
+site that falls there with more than _XLA_BWD_MAX_SCORE_BYTES of fp32
+scores is refused at lowering.  At S 256 a head is one grid step: the
+kernel alone ties with XLA, and the lse its forward must then emit makes
+the pair 9% slower (the probe's table), so XLA keeps it.  No flag, no
+model name:
 force="interpret" keeps the Pallas backward at every shape (the CPU
 tests' door), force="jax" keeps none.  pallas_call instances are memoized
 by static config, blocks included, so every attention site of one shape
@@ -98,6 +122,12 @@ NEG_INF = -1e30
 # 128 x 128); the count is cautious, Mosaic still compiles 20.5 MB of it and
 # refuses 22.
 _PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
+
+# What a grid step costs before it computes anything (~0.35 us, PR 28), in
+# the scores the MXU computes in that time: how _fewest_steps weighs a
+# windowed site's steps against the scores its blocks compute outside the
+# window.
+_STEP_COST_SCORES = 256 * 256
 
 
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
@@ -151,17 +181,22 @@ def _block_lengths(seq: int):
     return [128 * n for n in range(1, tiles + 1) if tiles % n == 0]
 
 
-def _fewest_steps(sq, sk, causal, working_set):
+def _fewest_steps(sq, sk, causal, working_set, window=None):
     """The (block_q, block_k) whose grid takes the fewest steps that run,
     of the pairs whose `working_set(block_q, block_k)` bytes fit
     _PLAN_VMEM_BUDGET; the wider key block where two tie.  Under `causal`
-    the steps counted are those that run: _skipped_k_steps are free."""
+    the steps counted are those that run: _skipped_steps are free.  Under
+    `window` the steps that run are weighed by the scores they compute
+    beside _STEP_COST_SCORES: a block that straddles an edge of the window
+    is half waste, and at the widest blocks that is half of all of them."""
     def steps_then_wide(plan):
         bq, bk = plan
         nqb, nkb = -(-sq // bq), -(-sk // bk)
-        skipped = (_skipped_k_steps(nqb, nkb, bq, bk, sk - sq)
-                   if causal else 0)
-        return nqb * nkb - skipped, -bk
+        run = nqb * nkb - sum(_skipped_steps(nqb, nkb, bq, bk, sk - sq,
+                                             causal, window))
+        if window is not None:
+            run *= bq * bk + _STEP_COST_SCORES
+        return run, -bk
 
     plans = [(bq, bk) for bq in _block_lengths(sq)
              for bk in _block_lengths(sk)]
@@ -171,8 +206,10 @@ def _fewest_steps(sq, sk, causal, working_set):
     return min(fits or plans[:1], key=steps_then_wide)
 
 
-def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None):
-    """(block_q, block_k) of the forward's grid, from the shape alone.
+def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None,
+                 window=None):
+    """(block_q, block_k) of the forward's grid, from the shape (and the
+    window, which is part of it) alone.
 
     A grid step costs about the same whatever it computes (the pipeline's
     bookkeeping, two DMAs, a read-modify-write of the fp32 accumulator and
@@ -183,7 +220,7 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None):
     at 0.59 ms, 1024 x 256 at 1.52)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: fwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse, v_dim))
+            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse, v_dim), window)
 
 
 def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
@@ -214,14 +251,15 @@ def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
             + 4 * tile((block_k, block_q), "float32"))
 
 
-def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None):
+def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None,
+                     window=None):
     """(block_q, block_k) of the backward's grid, on the forward's
     principle: the fewest grid steps that run (_fewest_steps) whose working
     set (bwd_working_set_bytes) fits.  It need not be the forward's pair:
     the packed lse plane is re-cut for free (_repack)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: bwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype, v_dim))
+            bq, bk, head_dim, -(-sq // bq), dtype, v_dim), window)
 
 
 def _block_runs(qi, ki, block_q, block_k, causal_offset):
@@ -240,16 +278,52 @@ def _kv_block_index(qi, ki, block_q, block_k, causal_offset):
     return jnp.minimum(ki, last)
 
 
-def _skipped_k_steps(nqb, nkb, block_q, block_k, causal_offset):
-    """How many of one (batch, head)'s nqb x nkb grid steps lie wholly
-    above the causal diagonal, and cost neither a fetch nor a matmul: for
-    each q-block, the k-blocks past the last one that _block_runs (a row
-    at a time, so a 128k sequence plans in no time)."""
-    def running(i):
-        last = ((i + 1) * block_q - 1 + causal_offset) // block_k
-        return min(max(last + 1, 0), nkb)
+def _oldest_key(row, causal_offset, window):
+    """The oldest key query `row` sees under `window` (row t sees the
+    `window` keys that end at t + causal_offset); negative where the window
+    reaches before the first key.  Python ints or traced scalars."""
+    return row + causal_offset - (window - 1)
 
-    return sum(nkb - running(i) for i in range(nqb))
+
+def _first_k_block(qi, block_q, block_k, causal_offset, window):
+    """The oldest K/V block a windowed q-block qi reads: the one that holds
+    the oldest key its FIRST row sees.  The forward's k-steps of a windowed
+    site count from here (_flash_kernel), so the blocks before it are no
+    grid step at all."""
+    return jnp.maximum(
+        _oldest_key(qi * block_q, causal_offset, window), 0) // block_k
+
+
+def _skipped_steps(nqb, nkb, block_q, block_k, causal_offset, causal=True,
+                   window=None):
+    """(above the diagonal, older than the window): how many of one (batch,
+    head)'s nqb x nkb score blocks cost neither a fetch nor a matmul.  For
+    each q-block, the k-blocks past the last one that _block_runs, and
+    under `window` those before _first_k_block (a row of blocks at a time,
+    so a 128k sequence plans in no time)."""
+    above = older = 0
+    for i in range(nqb):
+        under = nkb
+        if causal:
+            last = ((i + 1) * block_q - 1 + causal_offset) // block_k
+            under = min(max(last + 1, 0), nkb)
+        above += nkb - under
+        if window is not None:
+            first = max(_oldest_key(i * block_q, causal_offset, window),
+                        0) // block_k
+            older += min(first, under)
+    return above, older
+
+
+def _visible_pairs(sq, sk, causal, window=None):
+    """The query-key pairs the mask lets through (bottom-right aligned
+    diagonal, `window` keys a row), static."""
+    if not causal:
+        return sq * sk
+    seen = np.clip(np.arange(sq, dtype=np.int64) + (sk - sq) + 1, 0, sk)
+    if window is not None:
+        seen = np.minimum(seen, window)
+    return int(seen.sum())
 
 
 # The per-row logsumexp/D residuals are PACKED: [B*H, num_q_blocks,
@@ -268,31 +342,48 @@ def _skipped_k_steps(nqb, nkb, block_q, block_k, causal_offset):
 # the row as it lies.
 
 
-def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None):
+def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None,
+                         window=None):
     """Pure-jax attention (fallback + backward recompute).
-    q: [B, H, Sq, D], k/v: [B, H, Sk, D], k_lengths: [B] valid key counts."""
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    q: [B, H, Sq, D], k/v: [B, G, Sk, D] (query head j reads key/value head
+    j // (H / G); K and V are not repeated: the group is an axis of q),
+    k_lengths: [B] valid key counts, `window`: a row sees the `window` keys
+    that end at its diagonal."""
+    H, G = q.shape[1], k.shape[1]
+    if G != H:
+        q = q.reshape(q.shape[0], G, H // G, *q.shape[2:])
+        scores = jnp.einsum("bghqd,bgkd->bghqk", q, k) * scale
+    else:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if bias is not None:
         scores = scores + bias
     if k_lengths is not None:
         kmask = jnp.arange(scores.shape[-1])[None, :] < k_lengths[:, None]
-        scores = jnp.where(kmask[:, None, None, :], scores, NEG_INF)
+        kmask = (kmask[:, None, None, :] if G == H
+                 else kmask[:, None, None, None, :])
+        scores = jnp.where(kmask, scores, NEG_INF)
     if causal:
         sq, sk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                              k=sk - sq - window)
         scores = jnp.where(mask, scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     # fully-masked rows (padded queries) produce zeros, not uniform weights
     all_masked = jnp.max(scores, axis=-1, keepdims=True) <= NEG_INF / 2
     weights = jnp.where(all_masked, 0.0, weights)
+    if G != H:
+        out = jnp.einsum("bghqk,bgkd->bghqd", weights, v)
+        return out.reshape(out.shape[0], H, *out.shape[3:])
     return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
 
 
 def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
-                causal, causal_offset, transposed=False):
-    """Key-padding (+ causal) mask for score block (qi, ki) of batch row
-    bi — identical in forward and backward.  `transposed`: the block is
-    [block_k, block_q] (the backward kernel's scores)."""
+                causal, causal_offset, transposed=False, window=None):
+    """Key-padding (+ causal, + window) mask for score block (qi, ki) of
+    batch row bi — identical in forward and backward.  `transposed`: the
+    block is [block_k, block_q] (the backward kernel's scores)."""
     q_axis, k_axis = (1, 0) if transposed else (0, 1)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
@@ -301,16 +392,22 @@ def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
         # bottom-right alignment (matches jnp.tril(k=Sk-Sq)): with cached
         # keys (Sk > Sq) a query at row i sees keys up to i + Sk - Sq
         mask &= k_pos <= q_pos + causal_offset
+    if window is not None:
+        # the `window` keys that end at the row's diagonal
+        mask &= k_pos > q_pos + causal_offset - window
     return mask
 
 
 def _step_cases(klen_ref, bi, qi, ki, *, causal, block_q, block_k, seq_k,
-                causal_offset):
+                causal_offset, window=None):
     """(runs, cut) of score block (qi, ki), forward and backward: whether
-    the block has a key at or under the causal diagonal, and whether the
-    diagonal (its last key is past what its first row sees), the padded end
-    of the keys or klen[b] (data: a scalar read from SMEM) cuts it.  Only a
-    block that is cut pays for the iota/compare/select mask."""
+    the block has a key at or under the causal diagonal (and, under
+    `window`, one that its first row's window still reaches), and whether
+    the diagonal (its last key is past what its first row sees), the
+    window's far edge (its first key is older than what its last row sees),
+    the padded end of the keys or klen[b] (data: a scalar read from SMEM)
+    cuts it.  Only a block that is cut pays for the iota/compare/select
+    mask."""
     k_end = jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
     cut = (ki + 1) * block_k > k_end
     runs = True
@@ -318,6 +415,10 @@ def _step_cases(klen_ref, bi, qi, ki, *, causal, block_q, block_k, seq_k,
         runs = _block_runs(qi, ki, block_q, block_k, causal_offset)
         cut = jnp.logical_or(
             cut, (ki + 1) * block_k - 1 > qi * block_q + causal_offset)
+    if window is not None:
+        oldest = _oldest_key(qi * block_q, causal_offset, window)
+        runs = jnp.logical_and(runs, (ki + 1) * block_k - 1 >= oldest)
+        cut = jnp.logical_or(cut, ki * block_k < oldest + block_q - 1)
     return runs, cut
 
 
@@ -332,7 +433,8 @@ def _when_runs(runs, cut, update):
 
 def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr,
-                  *, causal, scale, block_q, block_k, seq_k, causal_offset):
+                  *, causal, scale, block_q, block_k, seq_k, causal_offset,
+                  window=None):
     """Grid: (batch*heads, num_q_blocks, num_k_blocks); K innermost so the
     online-softmax state lives in VMEM scratch across K steps.  klen_ref
     (SMEM) holds every batch row's valid key count (key-padding mask),
@@ -346,15 +448,25 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     Of the blocks that run, only one that can be cut takes the
     iota/compare/select mask: it crosses the diagonal, or it reaches past
     the keys this batch row has (the padded end of the sequence, or
-    klen[b], which is data: a scalar read from SMEM)."""
+    klen[b], which is data: a scalar read from SMEM).
+
+    Under `window` the last grid axis counts the k-blocks from
+    _first_k_block of the q-block on (as _fwd_call's index maps do): a block
+    wholly older than the window of the q-block's first row is no step at
+    all, and the axis is as long as the widest span of blocks a q-block
+    reads.  A block that reaches behind the window of the q-block's last
+    row takes the mask."""
     import jax.experimental.pallas as pl
 
     bi = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
     num_kb = pl.num_programs(2)
+    if window is not None:
+        ki = step + _first_k_block(qi, block_q, block_k, causal_offset,
+                                   window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         # the running-max floor is NEG_INF/2, NOT NEG_INF: a fully-masked
         # row keeps m at the floor, so p = exp(NEG_INF - NEG_INF/2)
@@ -376,7 +488,8 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         if cut:
             mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q,
-                               block_k, seq_k, causal, causal_offset)
+                               block_k, seq_k, causal, causal_offset,
+                               window=window)
             s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:]  # [block_q, 1]
@@ -393,9 +506,9 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     _when_runs(*_step_cases(
         klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=seq_k, causal_offset=causal_offset), _update)
+        seq_k=seq_k, causal_offset=causal_offset, window=window), _update)
 
-    @pl.when(ki == num_kb - 1)
+    @pl.when(step == num_kb - 1)
     def _finalize():
         l_fin = l_scr[:]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
@@ -416,7 +529,7 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       dvec_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
                       dv_scr, *, causal, scale, block_q, block_k, seq_k,
-                      causal_offset):
+                      causal_offset, window=None):
     """dQ, dK and dV in one kernel: grid (BH, num_k_blocks, num_q_blocks),
     Q innermost.  The dK/dV accumulators of one k-block stay in VMEM across
     its q-blocks; dQ accumulates across the k-blocks into an fp32
@@ -459,7 +572,8 @@ def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if cut:
             pt = jnp.where(
                 _block_mask(klen_ref, bi, qi, ki, st.shape, block_q, block_k,
-                            seq_k, causal, causal_offset, transposed=True),
+                            seq_k, causal, causal_offset, transposed=True,
+                            window=window),
                 pt, 0.0)
         dpt = jax.lax.dot_general(v_ref[0], do, nt,
                                   preferred_element_type=jnp.float32)
@@ -474,7 +588,7 @@ def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     _when_runs(*_step_cases(
         klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=seq_k, causal_offset=causal_offset), _update)
+        seq_k=seq_k, causal_offset=causal_offset, window=window), _update)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
@@ -502,25 +616,51 @@ def _flash_kernel_fwd_only(klen_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, **kw)
 
 
+def _window_k_steps(nqb, nkb, block_q, block_k, causal_offset, window):
+    """The length of a windowed forward's k-axis: the most K/V blocks one
+    q-block reads, from _first_k_block to the block of its last row's
+    diagonal."""
+    def blocks(i):
+        first = max(_oldest_key(i * block_q, causal_offset, window),
+                    0) // block_k
+        last = min(((i + 1) * block_q - 1 + causal_offset) // block_k,
+                   nkb - 1)
+        return last - first + 1
+
+    return max(1, max(blocks(i) for i in range(nqb)))
+
+
 @functools.lru_cache(maxsize=128)
 def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
-              causal_offset, dtype, interpret, emit_lse=True, dv=None):
+              causal_offset, dtype, interpret, emit_lse=True, dv=None,
+              window=None, group=1):
     """Memoized pallas_call: every attention site with the same static
     config reuses ONE traced callable, so XLA sees identical kernel
     payloads (compile-cache friendly) instead of per-site clones.
     emit_lse=False drops the lse output entirely (see
     _flash_kernel_fwd_only).  `dv` is the width of V and O where it is
-    not Q's and K's `d`."""
+    not Q's and K's `d`.  `group` query heads (consecutive rows of q) read
+    one K/V head through the index maps: K and V come as [bh / group, skp,
+    .] and are never repeated.  Under `window` the k-axis counts from
+    _first_k_block (see _flash_kernel)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     kernel = _flash_kernel if emit_lse else _flash_kernel_fwd_only
-    nqb = sqp // bq
+    nqb, nkb = sqp // bq, skp // bk
     dv = d if dv is None else dv
+    k_steps = nkb if window is None else _window_k_steps(
+        nqb, nkb, bq, bk, causal_offset, window)
 
     def kv_block(b, i, j):
+        if window is not None:
+            j = j + _first_k_block(i, bq, bk, causal_offset, window)
         if causal:
             j = _kv_block_index(i, j, bq, bk, causal_offset)
+        if window is not None:
+            j = jnp.minimum(j, nkb - 1)
+        if group > 1:
+            b = b // group
         return (b, j, 0)
 
     out_specs = [pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))]
@@ -532,12 +672,13 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             pl.BlockSpec((1, nqb, bq), lambda b, i, j: (b, 0, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, nqb, bq), jnp.float32))
+    static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
+                  seq_k=seq_k, causal_offset=causal_offset)
+    if window is not None:
+        static["window"] = window
     return pl.pallas_call(
-        functools.partial(
-            kernel, causal=causal, scale=scale, block_q=bq,
-            block_k=bk, seq_k=seq_k, causal_offset=causal_offset,
-        ),
-        grid=(bh, sqp // bq, skp // bk),
+        functools.partial(kernel, **static),
+        grid=(bh, nqb, k_steps),
         in_specs=[
             # whole [B*H] vector in SMEM, indexed by program_id(0) in-kernel
             # (TPU rejects rank-1 blocks smaller than the 128 tile)
@@ -559,17 +700,20 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
 
 def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
-                  interpret=False, need_lse=True):
+                  interpret=False, need_lse=True, window=None):
     """Returns (out [B,H,Sq,Dv], lse [B*H, num_q_blocks, block_q] fp32
     per-row logsumexp in the PACKED residual layout — see the module
     comment; _pallas_flash_bwd re-cuts it to its own q-block).
     need_lse=False (inference / the recompute-jax backward) skips the lse
     output entirely — its HBM write is pure waste when nothing consumes
-    it — and returns (out, None).  The blocks come from _plan_blocks;
-    block_q / block_k pin them for a test or the probe, never a model."""
+    it — and returns (out, None).  k and v may have fewer heads than q
+    ([B, G, Sk, .]: query head j reads head j // (H / G)).  The blocks come
+    from _plan_blocks; block_q / block_k pin them for a test or the probe,
+    never a model."""
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[3]
-    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv)
+    G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv,
+                                  window)
     bq = plan_q if block_q is None else min(block_q, Sq)
     bk = plan_k if block_k is None else min(block_k, Sk)
     # pad sequence dims to block multiples (masked in-kernel)
@@ -577,19 +721,21 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     k = _pad_seq(k, bk)
     v = _pad_seq(v, bk)
     qf = q.reshape(B * H, q.shape[2], D)
-    kf = k.reshape(B * H, k.shape[2], D)
-    vf = v.reshape(B * H, v.shape[2], Dv)
+    kf = k.reshape(B * G, k.shape[2], D)
+    vf = v.reshape(B * G, v.shape[2], Dv)
     klen_bh = jnp.repeat(klen, H)  # [B*H] valid key counts
 
     nqb, nkb = qf.shape[1] // bq, kf.shape[1] // bk
-    skipped = _skipped_k_steps(nqb, nkb, bq, bk, Sk - Sq) if causal else 0
+    above, older = _skipped_steps(nqb, nkb, bq, bk, Sk - Sq, causal, window)
     # at lowering, as recurrence.lower: static counts over one (b, h)
     with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=bq,
-              block_k=bk, k_steps=nqb * nkb, k_steps_skipped=skipped,
-              causal=int(causal)):
+              block_k=bk, k_steps=nqb * nkb, k_steps_skipped=above + older,
+              causal=int(causal), window=int(window or 0), kv_heads=G,
+              chunks=1, skipped_causal=above, skipped_window=older):
         call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
                          scale, Sk, Sk - Sq, str(q.dtype), interpret,
-                         emit_lse=need_lse, dv=Dv)
+                         emit_lse=need_lse, dv=Dv, window=window,
+                         group=H // G)
         res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
     out = res[0].reshape(B, H, res[0].shape[1], Dv)
     if out.shape[2] != Sq:
@@ -599,19 +745,32 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     return out, res[1]  # packed [B*H, nqb, bq]; the bwd re-cuts it (_repack)
 
 
-def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb):
+def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb,
+                   window=None):
     """The q/dO block a causal dK/dV grid step (ki, qi) holds: its own from
     the first q-block whose last row sees k-block ki's first key; before
     that one (the kernel skips those steps) the index waits there, so the
-    first block that runs is the only one fetched."""
+    first block that runs is the only one fetched.  Under `window` it
+    stays, likewise, at the last q-block whose first row still reaches the
+    k-block's last key."""
     first = jnp.maximum(ki * block_k - causal_offset, 0) // block_q
-    return jnp.maximum(qi, jnp.minimum(first, nqb - 1))
+    first = jnp.minimum(first, nqb - 1)
+    qi = jnp.maximum(qi, first)
+    if window is not None:
+        last = jnp.maximum((ki + 1) * block_k - 1 - causal_offset
+                           + window - 1, 0) // block_q
+        qi = jnp.minimum(qi, jnp.maximum(jnp.minimum(last, nqb - 1), first))
+    return qi
 
 
 @functools.lru_cache(maxsize=128)
 def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
-              causal_offset, q_dtype, k_dtype, v_dtype, interpret, dv=None):
-    """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call."""
+              causal_offset, q_dtype, k_dtype, v_dtype, interpret, dv=None,
+              window=None, group=1):
+    """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call.  With
+    `group` > 1 K and V come as [bh / group, skp, .] and are read through
+    the index maps; dK and dV leave a query head each, [bh, skp, .], and
+    _bwd_rows adds a group's up."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -622,23 +781,29 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
     packed = pl.BlockSpec((1, nqb, bq), lambda b, j, i: (b, 0, 0))
     k_block = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
     v_block = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
+    k_in, v_in = k_block, v_block
+    if group > 1:
+        k_in = pl.BlockSpec((1, bk, d), lambda b, j, i: (b // group, j, 0))
+        v_in = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b // group, j, 0))
 
     def q_of_kv(b, j, i):
         if causal:
-            i = _q_block_index(i, j, bq, bk, causal_offset, nqb)
+            i = _q_block_index(i, j, bq, bk, causal_offset, nqb, window)
         return (b, i, 0)
 
+    static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
+                  seq_k=seq_k, causal_offset=causal_offset)
+    if window is not None:
+        static["window"] = window
     return pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, seq_k=seq_k,
-                          causal_offset=causal_offset),
+        functools.partial(_flash_bwd_kernel, **static),
         grid=(bh, skp // bk, nqb),
         in_specs=[
             pl.BlockSpec((bh,), lambda b, j, i: (0,),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), q_of_kv),
-            k_block,
-            v_block,
+            k_in,
+            v_in,
             pl.BlockSpec((1, bq, dv), q_of_kv),
             packed,
             packed,
@@ -676,16 +841,18 @@ def _repack(plane, sq, block_q, fill):
     return flat.reshape(plane.shape[0], sqp // block_q, block_q)
 
 
-def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
-                      block_q=None, block_k=None, interpret=False):
-    """(dq, dk, dv) by _flash_bwd_kernel at the backward's own plan
-    (_plan_bwd_blocks; block_q / block_k pin it for a test or the probe).
-    `lse` is the forward's packed plane, whatever q-block laid it out."""
+def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
+              bq, bk, window, interpret):
+    """(dq, dk, dv) of the queries q [B, H, Sq, D] (with their out, dO `g`
+    and packed lse) over the keys k, v [B, G, Sk, .] by ONE call of
+    _flash_bwd_kernel: a whole row, or one trip of _pallas_flash_bwd's loop
+    over chunks of queries, which hands in the keys the chunk sees and the
+    diagonal's place among them (`causal_offset`: row i sees keys up to
+    i + causal_offset).  dk and dv come per K/V head: where a group of
+    query heads shares one, the kernel writes a query head's each and they
+    are added up here, in fp32."""
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[3]
-    plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv),
-                engine="pallas")
-    bq, bk = plan["block_q"], plan["block_k"]
+    G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     qp = _pad_seq(q, bq)
     op = _pad_seq(out, bq)
     gp = _pad_seq(g, bq)  # zero-padded dO rows contribute nothing to dK/dV
@@ -695,8 +862,8 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     qf = qp.reshape(B * H, Sqp, D)
     of = op.reshape(B * H, Sqp, Dv)
     gf = gp.reshape(B * H, Sqp, Dv).astype(qf.dtype)
-    kf = kp.reshape(B * H, Skp, D)
-    vf = vp.reshape(B * H, Skp, Dv)
+    kf = kp.reshape(B * G, Skp, D)
+    vf = vp.reshape(B * G, Skp, Dv)
     klen_bh = jnp.repeat(klen, H)
     # a padded row's lse is the fully-masked row's: exp(s - lse) is 0
     lse = _repack(lse, Sq, bq, -NEG_INF)
@@ -709,16 +876,62 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     dvec = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     dvec = dvec.reshape(B * H, Sqp // bq, bq)
 
-    call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk, Sk - Sq,
-                     str(q.dtype), str(k.dtype), str(v.dtype), interpret,
-                     dv=Dv)
-    with span("flash.bwd_plan", **plan):  # at lowering, as flash.plan
-        dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
+    call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk,
+                     causal_offset, str(q.dtype), str(k.dtype), str(v.dtype),
+                     interpret, dv=Dv, window=window, group=H // G)
+    dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
 
     dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
-    dk = dk.reshape(B, H, Skp, D)[:, :, :Sk]
-    dv = dv.reshape(B, H, Skp, Dv)[:, :, :Sk]
+    if G != H:
+        dk = dk.reshape(B, G, H // G, Skp, D).astype(jnp.float32).sum(2)
+        dv = dv.reshape(B, G, H // G, Skp, Dv).astype(jnp.float32).sum(2)
+    dk = dk.reshape(B, G, Skp, D)[:, :, :Sk]
+    dv = dv.reshape(B, G, Skp, Dv)[:, :, :Sk]
     return dq, dk, dv
+
+
+def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
+                      block_q=None, block_k=None, interpret=False,
+                      window=None, chunk=None):
+    """(dq, dk, dv) by _flash_bwd_kernel at the backward's own plan
+    (_bwd_plan; block_q / block_k / chunk pin it for a test or the probe).
+    `lse` is the forward's packed plane, whatever q-block laid it out.
+
+    Where the row's dQ fits VMEM the row is one call, as it always was.
+    Past that (S ~4k at head 128) the kernel runs in an outer loop over
+    chunks of queries: a chunk's dQ is the whole "row" of its call, and it
+    is handed only the keys it can see (up to its last row's diagonal;
+    under `window` from its first row's oldest key on), whose dK and dV
+    are added, in fp32, into the sequence's."""
+    B, H, Sq, D = q.shape
+    G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    trips = _bwd_trips(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
+                       window, chunk)
+    plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
+                          window, chunk), engine="pallas", kv_heads=G)
+    with span("flash.bwd_plan", **plan):  # at lowering, as flash.plan
+        if len(trips) == 1:
+            _, _, _, _, bq, bk = trips[0]
+            dq, dk, dv = _bwd_rows(q, k, v, klen, out, lse, g, causal, scale,
+                                   Sk - Sq, bq, bk, window, interpret)
+            if G != H:
+                dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
+            return dq, dk, dv
+        lse = lse.reshape(B * H, -1)[:, :Sq]
+        dqs = []
+        dk = jnp.zeros((B, G, Sk, D), jnp.float32)
+        dv = jnp.zeros((B, G, Sk, Dv), jnp.float32)
+        for q0, q1, k0, k1, bq, bk in trips:
+            dq_c, dk_c, dv_c = _bwd_rows(
+                q[:, :, q0:q1], k[:, :, k0:k1], v[:, :, k0:k1],
+                jnp.clip(klen - k0, 0, k1 - k0), out[:, :, q0:q1],
+                lse[:, None, q0:q1], g[:, :, q0:q1], causal, scale,
+                q0 + (Sk - Sq) - k0, bq, bk, window, interpret)
+            dqs.append(dq_c)
+            dk = dk.at[:, :, k0:k1].add(dk_c.astype(jnp.float32))
+            dv = dv.at[:, :, k0:k1].add(dv_c.astype(jnp.float32))
+        return (jnp.concatenate(dqs, axis=2), dk.astype(k.dtype),
+                dv.astype(v.dtype))
 
 
 def _on_tpu() -> bool:
@@ -739,32 +952,112 @@ def _use_pallas(force: str) -> bool:
 # The backward's engine is read from the shape: the Pallas kernel where
 # the plan's score block [block_k, block_q] has at least this many scores
 # over which to spread what a grid step costs before it computes anything
-# (and the row's dQ fits VMEM at all), the XLA recompute backward elsewhere.
-# Settled on the chip by tools/flash_bwd_probe.py (PERF.md, PR 30).
+# (and the row's dQ, or a chunk's, fits VMEM at all), the XLA recompute
+# backward elsewhere.  Settled on the chip by tools/flash_bwd_probe.py
+# (PERF.md, PR 30).
 _BWD_PALLAS_MIN_BLOCK_SCORES = 384 * 384
+
+# The XLA recompute backward materialises a site's fp32 scores, [B, H, Sq,
+# Sk].  Past this many bytes of them a site that no Pallas plan takes is
+# refused at lowering (_flash_bwd) rather than compiled: 32 x 16384 x 16384
+# would be 34 GB on a 16 GB chip.
+_XLA_BWD_MAX_SCORE_BYTES = 2 << 30
+
+
+def _chunk_keys(q0, q1, causal_offset, sk, causal, window):
+    """[k0, k1) of the keys that the queries [q0, q1) see: up to the last
+    row's diagonal under `causal`, from the first row's oldest key under
+    `window` (taken back to a multiple of 128, the lane tile)."""
+    k1 = min(q1 + causal_offset, sk) if causal else sk
+    k0 = 0
+    if window is not None:
+        k0 = max(_oldest_key(q0, causal_offset, window), 0) // 128 * 128
+    return min(k0, max(k1 - 1, 0)), max(k1, 1)
+
+
+@functools.lru_cache(maxsize=128)
+def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window):
+    """(rows of queries a call of the backward kernel takes, engine).  The
+    whole row where its dQ fits VMEM beside blocks worth a grid step, as it
+    always was.  Else the longest cut of the row
+    (_block_lengths) whose plan fits with a score block worth a grid step;
+    under `window` the shortest such cut that is no shorter than the window,
+    since a longer chunk only adds k-blocks its q-blocks skip and a shorter
+    one blocks that straddle the window's edge.  Where no cut does, the row
+    stays whole and the engine is XLA's."""
+    def plan(rows, keys):
+        bq, bk = _plan_bwd_blocks(rows, keys, head_dim, dtype, causal, v_dim,
+                                  window)
+        fits = bwd_working_set_bytes(
+            bq, bk, head_dim, -(-rows // bq), dtype, v_dim
+        ) <= _PLAN_VMEM_BUDGET
+        return fits, bq * bk >= _BWD_PALLAS_MIN_BLOCK_SCORES
+
+    fits, worth = plan(sq, sk)
+    if fits and worth:
+        return sq, "pallas"
+    cuts = []
+    for rows in _block_lengths(sq)[:-1]:
+        q0 = (sq - 1) // rows * rows            # the last chunk sees most
+        k0, k1 = _chunk_keys(q0, sq, sk - sq, sk, causal, window)
+        if all(plan(min(rows, sq - q0), k1 - k0)):
+            cuts.append(rows)
+    if not cuts:
+        return sq, "xla"
+    if window is not None:
+        reach = [rows for rows in cuts if rows >= window]
+        return (min(reach) if reach else max(cuts)), "pallas"
+    return max(cuts), "pallas"
+
+
+def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
+               v_dim=None, window=None, chunk=None):
+    """[(q0, q1, k0, k1, block_q, block_k)]: the calls of the backward
+    kernel that one site makes, one where the row is whole.  `chunk` pins
+    the rows a trip, `block_q` / `block_k` the blocks (a test or the
+    probe), else _bwd_chunk_rows and each trip's own _plan_bwd_blocks."""
+    rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
+                            window)[0] if chunk is None else min(chunk, sq))
+    trips = []
+    for q0 in range(0, sq, rows):
+        q1 = min(q0 + rows, sq)
+        k0, k1 = (0, sk) if rows >= sq else _chunk_keys(
+            q0, q1, sk - sq, sk, causal, window)
+        bq, bk = _plan_bwd_blocks(q1 - q0, k1 - k0, head_dim, dtype, causal,
+                                  v_dim, window)
+        bq = bq if block_q is None else min(block_q, q1 - q0)
+        bk = bk if block_k is None else min(block_k, k1 - k0)
+        trips.append((q0, q1, k0, k1, bq, bk))
+    return trips
 
 
 def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-              v_dim=None):
+              v_dim=None, window=None, chunk=None):
     """What the backward of one attention call of this shape is given, the
     `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
-    the probe, else _plan_bwd_blocks'), steps and steps_skipped (static,
-    over one batch-head row) and engine, "pallas" or "xla": the one place
-    that says which."""
-    bq, bk = _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim)
-    fits = bwd_working_set_bytes(
-        bq, bk, head_dim, -(-sq // bq), dtype, v_dim) <= _PLAN_VMEM_BUDGET
-    engine = ("pallas" if fits and bq * bk >= _BWD_PALLAS_MIN_BLOCK_SCORES
-              else "xla")
-    bq = bq if block_q is None else min(block_q, sq)
-    bk = bk if block_k is None else min(block_k, sk)
-    nqb, nkb = -(-sq // bq), -(-sk // bk)
-    skipped = _skipped_k_steps(nqb, nkb, bq, bk, sk - sq) if causal else 0
-    return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=bq, block_k=bk,
-                steps=nqb * nkb, steps_skipped=skipped, engine=engine)
+    the probe, else _plan_bwd_blocks'; the last trip's where there are
+    several), chunks (the outer loop's trips, 1 where the row is whole),
+    steps, steps_skipped and its two parts skipped_causal and
+    skipped_window (static, over one batch-head row, all trips) and engine,
+    "pallas" or "xla": the one place that says which."""
+    engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
+                             window)[1]
+    trips = _bwd_trips(sq, sk, head_dim, dtype, causal, block_q, block_k,
+                       v_dim, window, chunk)
+    steps = above = older = 0
+    for q0, q1, k0, k1, bq, bk in trips:
+        nqb, nkb = -(-(q1 - q0) // bq), -(-(k1 - k0) // bk)
+        a, o = _skipped_steps(nqb, nkb, bq, bk, q0 + (sk - sq) - k0, causal,
+                              window)
+        steps, above, older = steps + nqb * nkb, above + a, older + o
+    return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=trips[-1][4],
+                block_k=trips[-1][5], steps=steps,
+                steps_skipped=above + older, engine=engine,
+                window=int(window or 0), chunks=len(trips),
+                skipped_causal=above, skipped_window=older)
 
 
-def _pallas_backward(q, k, v, causal, force) -> bool:
+def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
     """Whether this call's backward runs the Pallas kernel (and so its
     forward emits lse): by the shape under "auto"/"pallas", always under
     "interpret" (the CPU tests' door), never under "jax"."""
@@ -772,53 +1065,62 @@ def _pallas_backward(q, k, v, causal, force) -> bool:
         return True
     return _use_pallas(force) and _bwd_plan(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
-        v_dim=v.shape[3])["engine"] == "pallas"
+        v_dim=v.shape[3], window=window)["engine"] == "pallas"
 
 
-def _forward(q, k, v, klen, causal, scale, force, need_lse):
+def _forward(q, k, v, klen, causal, scale, force, need_lse, window=None):
     """(out, packed lse or None) by the engine `force` names."""
     if _use_pallas(force) or force == "interpret":
         return _pallas_flash(q, k, v, klen, causal, scale,
                              interpret=(force == "interpret"),
-                             need_lse=need_lse)
+                             need_lse=need_lse, window=window)
     return _reference_attention(
-        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)), None
+        q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32),
+        window=window), None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, klen, causal, scale, force):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, klen, causal, scale, force, window):
     # klen rides as float32 so custom_vjp treats it uniformly (zero grad)
-    return _forward(q, k, v, klen, causal, scale, force, need_lse=False)[0]
+    return _forward(q, k, v, klen, causal, scale, force, False, window)[0]
 
 
-def _flash_fwd(q, k, v, klen, causal, scale, force):
+def _flash_fwd(q, k, v, klen, causal, scale, force, window):
     # the XLA recompute backward holds neither O nor L as residuals, and
     # its forward skips the lse HBM write entirely
-    out, lse = _forward(q, k, v, klen, causal, scale, force,
-                        need_lse=_pallas_backward(q, k, v, causal, force))
+    out, lse = _forward(
+        q, k, v, klen, causal, scale, force,
+        _pallas_backward(q, k, v, causal, force, window), window)
     return out, (q, k, v, klen, None if lse is None else out, lse)
 
 
-def _flash_bwd(causal, scale, force, res, g):
+def _flash_bwd(causal, scale, force, window, res, g):
     q, k, v, klen, out, lse = res
     with jax.named_scope("flash.bwd"):
         if lse is not None:
             dq, dk, dv = _pallas_flash_bwd(
                 q, k, v, klen, out, lse, g, causal, scale,
-                interpret=(force == "interpret"),
+                interpret=(force == "interpret"), window=window,
             )
             return dq, dk, dv, jnp.zeros_like(klen)
         if _use_pallas(force):
+            scores = 4 * q.shape[0] * q.shape[1] * q.shape[2] * k.shape[2]
+            if scores > _XLA_BWD_MAX_SCORE_BYTES:
+                raise ValueError(
+                    f"flash_attention: no Pallas backward plan takes q "
+                    f"{q.shape} over k {k.shape} (_bwd_plan), and the XLA "
+                    f"recompute backward would materialise {scores / 1e9:.1f}"
+                    " GB of fp32 scores")
             # at lowering, beside flash.plan: the site keeps the XLA engine
             with span("flash.bwd_plan", **_bwd_plan(
                     q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
-                    v_dim=v.shape[3])):
+                    v_dim=v.shape[3], window=window), kv_heads=k.shape[1]):
                 pass
         # recompute-backward: differentiate the reference formulation
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _reference_attention(
-                q_, k_, v_, causal, scale, k_lengths=klen.astype(jnp.int32)
-            ),
+                q_, k_, v_, causal, scale, k_lengths=klen.astype(jnp.int32),
+                window=window),
             q, k, v,
         )
         dq, dk, dv = vjp(g)
@@ -829,17 +1131,31 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
-                    force="auto"):
-    """q/k/v: [B, H, S, D].  k_lengths: optional [B] valid key counts
-    (key-padding mask).
+                    force="auto", window=None):
+    """q: [B, H, Sq, D]; k/v: [B, G, Sk, .] with G = H or a divisor of it
+    (grouped-query attention: query head j reads key/value head
+    j // (H / G); K and V are never repeated).  k_lengths: optional [B]
+    valid key counts (key-padding mask).  window: under `causal`, a query
+    sees the `window` keys that end at its diagonal (itself and the
+    window - 1 before it); None: all of them.
 
     force: "auto" (pallas on TPU, jax elsewhere), "pallas", "interpret"
     (pallas interpreter — CPU testing), or "jax".  The backward's engine
     is read from the shape (_bwd_plan): never from a flag."""
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"flash_attention: {q.shape[1]} query heads over "
+                         f"{k.shape[1]} key and {v.shape[1]} value heads")
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError("flash_attention: `window` needs causal=True "
+                             f"and at least 1 key, got {window}")
+        if window >= k.shape[2]:
+            window = None       # every causal key is inside it
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if k_lengths is None:
         klen = jnp.full((q.shape[0],), k.shape[2], dtype=jnp.float32)
     else:
         klen = jnp.asarray(k_lengths, dtype=jnp.float32).reshape(-1)
-    return _flash(q, k, v, klen, causal, float(scale), force)
+    return _flash(q, k, v, klen, causal, float(scale), force, window)

@@ -1222,34 +1222,49 @@ def beam_search_decode(ids, scores, beam_size, end_id, name=None,
 
 
 def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
-                    name=None):
-    """Flash-attention in one op: q/k/v [B, H, S, D], optional [B] valid key
-    counts instead of an additive bias (TPU-native; see
+                    name=None, window=None, rope=None):
+    """Flash-attention in one op: q [B, H, S, D] over k/v [B, G, S, D], G =
+    H or a divisor of it (grouped-query attention: query head j reads
+    key/value head j // (H / G); K and V are never repeated), optional [B]
+    valid key counts instead of an additive bias.  `window` (with causal):
+    a query sees itself and the window - 1 keys before it.  `rope` labels
+    the site's `attn.lower` span with the rotary rule that turned q and k
+    ("plain", "yarn"); it changes no number (TPU-native; see
     paddle_tpu/kernels/flash_attention.py)."""
     helper = LayerHelper("fused_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if k_lengths is not None:
         inputs["KLengths"] = [k_lengths]
-    helper.append_op(
-        type="fused_attention", inputs=inputs, outputs={"Out": [out]},
-        attrs={"causal": causal, "scale": float(scale) if scale else 0.0},
-    )
+    attrs = {"causal": causal, "scale": float(scale) if scale else 0.0}
+    if window:
+        attrs["window"] = int(window)
+    if rope:
+        attrs["rope"] = str(rope)
+    helper.append_op(type="fused_attention", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
 def rotary_embedding(x, base=10000.0, offset=0, positions=None,
-                     sections=None, name=None):
+                     sections=None, name=None, yarn=None):
     """Rotary position embedding of x [..., S, D] (heads first, then
     positions, then the head's features), half-split pairs, position
     offset + index along axis -2, angles in fp32.  With `positions`
     [B, n, S] and `sections` (n counts that add up to D / 2), x [B, ..., S,
     D]: the pairs of section j turn by position stream j (multi-axis
-    rotary; TPU-native; see ops/attention_ops.py rotary_embedding)."""
+    rotary).  With `yarn` (a dict of factor, original_length, beta_fast,
+    beta_slow, attention_factor): YaRN's scaled frequencies, cos and sin
+    times attention_factor (TPU-native; see ops/attention_ops.py
+    rotary_embedding)."""
     helper = LayerHelper("rotary_embedding", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}
     attrs = {"base": float(base), "offset": int(offset)}
+    if yarn:
+        from ..ops.attention_ops import YARN_KEYS
+
+        attrs.update({"yarn_" + key: float(yarn[key]) for key in YARN_KEYS})
     if positions is not None:
         inputs["Positions"] = [positions]
         attrs["sections"] = [int(n) for n in sections]

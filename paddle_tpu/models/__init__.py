@@ -18,6 +18,8 @@ from .transformer import transformer, TransformerConfig  # noqa: F401
 from .looped_decoder import looped_decoder, LoopedDecoderConfig  # noqa: F401
 from .expert_decoder import expert_decoder, ExpertDecoderConfig  # noqa: F401
 from .sparse_decoder import sparse_decoder, SparseDecoderConfig  # noqa: F401
+from .windowed_decoder import (  # noqa: F401
+    windowed_decoder, WindowedDecoderConfig)
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

@@ -1,7 +1,12 @@
-"""ModelSpec: what a model builder hands back to benches/tests."""
+"""ModelSpec: what a model builder hands back to benches/tests, and what
+two or more of the decoder builders share: the layer as a one-trip
+recurrence (the unit of recomputation), a chip's share of a softmax-routed
+expert block with the scaled initialisation of the residual stream's
+writers, and the packed batch."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
@@ -32,3 +37,82 @@ def class_batch(
         img_name: rng.rand(batch_size, *img_shape).astype(np.float32),
         label_name: rng.randint(0, num_classes, size=(batch_size, 1)).astype(np.int64),
     }
+
+
+def one_trip_layer(h, body, use_recompute: bool = True):
+    """(h', the recurrence) of one layer built as a one-trip
+    layers.Recurrence, under `use_recompute` inside a recompute scope, so
+    that the layer is the unit of recomputation.  `body(carried)` builds
+    the layer and returns (its output, the values the recurrence hands out
+    besides: read them with the recurrence's call)."""
+    from .. import layers
+    from ..core.framework import recompute_scope
+
+    scope = recompute_scope if use_recompute else contextlib.nullcontext
+    with scope():
+        rec = layers.Recurrence(trips=1)
+        with rec.block():
+            carried = rec.carry(h)
+            out, handed_out = body(carried)
+            rec.update(carried, out)
+            for value in handed_out:
+                rec.output(value)
+        h = rec.final(carried)
+    return h, rec
+
+
+def packed_batch(vocab_size: int, length: int, batch_size: int, seed: int,
+                 tokens: str, labels: str) -> Dict[str, np.ndarray]:
+    """Packed sequences: ids uniform over the vocabulary held here, the
+    labels the ids shifted by one, no padding."""
+    rng = np.random.RandomState(seed)
+    ids = rng.randint(0, vocab_size, size=(batch_size, length + 1))
+    return {tokens: ids[:, :-1].astype(np.int64),
+            labels: ids[:, 1:].astype(np.int64)}
+
+
+class SoftmaxExpertShare:
+    """For a builder with `cfg` and `param(shape, name, **attr)`: a chip's
+    share of a softmax-routed expert block (Qwen-MoE's rule: the softmax
+    over all `n_routed_experts`, the `top_k` chosen, their weights over
+    their sum under `norm_topk_prob`; `experts_held` experts from
+    `expert_offset` on held here, whose terms alone are added), and the
+    matrices that write into the residual stream (attention's o, the
+    experts' down), which start at init_std / sqrt(2 x
+    `residual_init_layers`): the published depth, whatever depth is held
+    (0: init_std like the rest).  Where `cfg.train_router` is there and
+    False the router takes no gradient: its weight is not trainable and the
+    gates are constants to the backward pass (a share can compute 8 of the
+    64 terms of the router's gradient, and Adam would step the full rate
+    along them, towards the held experts)."""
+
+    def residual_param(self, shape, name):
+        """A matrix that writes into the residual stream."""
+        from ..initializer import NormalInitializer
+
+        cfg = self.cfg
+        scale = (2.0 * cfg.residual_init_layers) ** -0.5 \
+            if cfg.residual_init_layers else 1.0
+        return self.param(shape, name, initializer=NormalInitializer(
+            0.0, cfg.init_std * scale))
+
+    def expert_block(self, x, name):
+        from .. import layers
+
+        cfg = self.cfg
+        held, d, f = cfg.experts_held, cfg.d_model, cfg.d_expert
+        trained = getattr(cfg, "train_router", True)
+        idx, weight, _ = layers.moe_router(
+            x, self.param([d, cfg.n_routed_experts], f"{name}_router_w",
+                          trainable=trained),
+            None, top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+            scoring="softmax")
+        if not trained:
+            weight = layers.detach(weight)
+        return layers.moe_experts(
+            x, idx, weight,
+            self.param([held, d, f], f"{name}_experts_gate_w"),
+            self.param([held, d, f], f"{name}_experts_up_w"),
+            self.residual_param([held, f, d], f"{name}_experts_down_w"),
+            experts_total=cfg.n_routed_experts,
+            expert_offset=cfg.expert_offset, scoring="softmax")

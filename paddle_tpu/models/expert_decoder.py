@@ -29,17 +29,16 @@ under `use_recompute` the layer is the unit of recomputation.  Name scopes
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Dict, Optional
 
 import numpy as np
 
 from .. import layers
-from ..core.framework import name_scope, recompute_scope
+from ..core.framework import name_scope
 from ..initializer import ConstantInitializer
 from ..param_attr import ParamAttr
-from .common import ModelSpec
+from .common import ModelSpec, one_trip_layer, packed_batch
 from .looped_decoder import _Builder, _heads_and_loss
 
 __all__ = ["ExpertDecoderConfig", "expert_decoder"]
@@ -151,19 +150,17 @@ def expert_decoder(cfg: Optional[ExpertDecoderConfig] = None, tokens=None,
     h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.d_model],
                          param_attr=ParamAttr(name="embed",
                                               initializer=b.init))
-    layer_scope = (recompute_scope if cfg.use_recompute
-                   else contextlib.nullcontext)
     loads = []
     for i in range(cfg.n_layer):
-        with layer_scope():
-            rec = layers.Recurrence(trips=1)
-            with rec.block():
-                carried = rec.carry(h)
-                out, load, bias = b.layer(carried, i)
-                rec.update(carried, out)
-                if load is not None:
-                    rec.output(load)
-            h = rec.final(carried)
+        routed = []
+
+        def body(carried, i=i, routed=routed):
+            out, load, bias = b.layer(carried, i)
+            routed.append((load, bias))
+            return out, [] if load is None else [load]
+
+        h, rec = one_trip_layer(h, body, cfg.use_recompute)
+        load, bias = routed[0]
         if load is not None:
             # after the step's routing has read it: the bias follows the
             # load, outside the gradient
@@ -175,10 +172,8 @@ def expert_decoder(cfg: Optional[ExpertDecoderConfig] = None, tokens=None,
     def synthetic_batch(batch_size: int, seed: int = 0) -> Dict[str, np.ndarray]:
         """Packed sequences: ids uniform over the vocabulary held here, the
         labels the ids shifted by one, no padding."""
-        rng = np.random.RandomState(seed)
-        ids = rng.randint(0, cfg.vocab_size, size=(batch_size, S + 1))
-        return {tokens.name: ids[:, :-1].astype(np.int64),
-                labels.name: ids[:, 1:].astype(np.int64)}
+        return packed_batch(cfg.vocab_size, S, batch_size, seed,
+                            tokens.name, labels.name)
 
     return ModelSpec(
         name="expert_decoder",

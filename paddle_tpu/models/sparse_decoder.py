@@ -38,17 +38,16 @@ and ops/moe_ops.py's `moe.*` group the device's time in a trace.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from .. import layers
-from ..core.framework import name_scope, recompute_scope
-from ..initializer import NormalInitializer
+from ..core.framework import name_scope
 from ..param_attr import ParamAttr
-from .common import ModelSpec
+from .common import (ModelSpec, SoftmaxExpertShare, one_trip_layer,
+                     packed_batch)
 from .expert_decoder import _ExpertBuilder
 from .looped_decoder import _heads_and_loss
 
@@ -98,17 +97,7 @@ def _sections(sections, pairs: int) -> list:
     return [n * pairs // total for n in sections]
 
 
-class _SparseBuilder(_ExpertBuilder):
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        scale = (2.0 * cfg.residual_init_layers) ** -0.5 \
-            if cfg.residual_init_layers else 1.0
-        self.residual_init = NormalInitializer(0.0, cfg.init_std * scale)
-
-    def residual_param(self, shape, name):
-        """A matrix that writes into the residual stream."""
-        return self.param(shape, name, initializer=self.residual_init)
-
+class _SparseBuilder(SoftmaxExpertShare, _ExpertBuilder):
     def heads(self, t, n, width, positions, norm=None):
         """[B, S, n * width] -> [B, n, S, width], each head normalised
         (`norm` names the scale) and rotated."""
@@ -163,21 +152,6 @@ class _SparseBuilder(_ExpertBuilder):
             [H * D, cfg.d_model], f"{name}_o_w"))
         return out, index_loss
 
-    def expert_block(self, x, name):
-        cfg = self.cfg
-        held, d, f = cfg.experts_held, cfg.d_model, cfg.d_expert
-        idx, weight, _ = layers.moe_router(
-            x, self.param([d, cfg.n_routed_experts], f"{name}_router_w"),
-            None, top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
-            scoring="softmax")
-        return layers.moe_experts(
-            x, idx, weight,
-            self.param([held, d, f], f"{name}_experts_gate_w"),
-            self.param([held, d, f], f"{name}_experts_up_w"),
-            self.residual_param([held, f, d], f"{name}_experts_down_w"),
-            experts_total=cfg.n_routed_experts,
-            expert_offset=cfg.expert_offset, scoring="softmax")
-
     def layer(self, h, i, positions):
         """(h', the layer's index loss)."""
         name = f"l{i}"
@@ -205,18 +179,13 @@ def sparse_decoder(cfg: Optional[SparseDecoderConfig] = None, tokens=None,
     h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.d_model],
                          param_attr=ParamAttr(name="embed",
                                               initializer=b.init))
-    layer_scope = (recompute_scope if cfg.use_recompute
-                   else contextlib.nullcontext)
     index_losses = []
     for i in range(cfg.n_layer):
-        with layer_scope():
-            rec = layers.Recurrence(trips=1)
-            with rec.block():
-                carried = rec.carry(h)
-                out, index_loss = b.layer(carried, i, positions)
-                rec.update(carried, out)
-                rec.output(index_loss)
-            h = rec.final(carried)
+        def body(carried, i=i):
+            out, index_loss = b.layer(carried, i, positions)
+            return out, [index_loss]
+
+        h, rec = one_trip_layer(h, body, cfg.use_recompute)
         index_losses.append(layers.reduce_sum(rec()))
     states = layers.unsqueeze(b.norm(h, "final"), axes=[0])   # one "trip"
     cross_entropy, logits, _ = _heads_and_loss(b, states, labels)
@@ -227,12 +196,10 @@ def sparse_decoder(cfg: Optional[SparseDecoderConfig] = None, tokens=None,
                         seed: int = 0) -> Dict[str, np.ndarray]:
         """Packed text: ids uniform over the vocabulary held here, the
         labels the ids shifted by one, the three position streams equal."""
-        rng = np.random.RandomState(seed)
-        ids = rng.randint(0, cfg.vocab_size, size=(batch_size, S + 1))
         pos = np.broadcast_to(np.arange(S, dtype=np.int32),
                               (batch_size, len(cfg.mrope_section), S))
-        return {tokens.name: ids[:, :-1].astype(np.int64),
-                labels.name: ids[:, 1:].astype(np.int64),
+        return {**packed_batch(cfg.vocab_size, S, batch_size, seed,
+                               tokens.name, labels.name),
                 positions.name: np.ascontiguousarray(pos)}
 
     return ModelSpec(

@@ -18,6 +18,11 @@ from ..observability import span
 from .common import data, in_desc, same_shape, set_output
 
 
+# what layers.rotary_embedding's `yarn` holds, each an attr `yarn_<key>`
+YARN_KEYS = ("factor", "original_length", "beta_fast", "beta_slow",
+             "attention_factor")
+
+
 def _fused_attn_infer(op, block):
     q = in_desc(op, block, "Q")
     if q is None:
@@ -39,6 +44,7 @@ def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
     from ..parallel.mesh import AXIS_DP, AXIS_TP
 
     dp = AXIS_DP if mesh.has_axis(AXIS_DP) else None
+    # `n_head`: the key/value heads where they are fewer than the query's
     tp = AXIS_TP if (mesh.has_axis(AXIS_TP)
                      and n_head % mesh.axis_size(AXIS_TP) == 0) else None
     qkv = P(dp, tp, None, None)
@@ -48,19 +54,20 @@ def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
                          out_specs=qkv, check_vma=False)
 
 
-def _attend(ctx, q, k, v, klen, causal, scale):
-    """flash_attention of q/k [B, H, S, D] and v [B, H, S, Dv], under a
+def _attend(ctx, q, k, v, klen, causal, scale, window=None):
+    """flash_attention of q [B, H, S, D] over k [B, G, S, D] and v [B, G,
+    S, Dv] (G = H, or a divisor of it: grouped-query attention), under a
     shard_map where the program runs on a mesh of several devices."""
     from ..kernels import flash_attention
     from ..kernels.flash_attention import _use_pallas
 
     def attend(q, k, v, klen=None):
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               k_lengths=klen)
+                               k_lengths=klen, window=window)
 
     if (ctx.mesh is not None and ctx.mesh.num_devices > 1
             and _use_pallas("auto")):
-        attend = _shard_over_mesh(attend, ctx.mesh, q.shape[1],
+        attend = _shard_over_mesh(attend, ctx.mesh, k.shape[1],
                                   klen is not None)
     return attend(*((q, k, v) + ((klen,) if klen is not None else ())))
 
@@ -73,15 +80,47 @@ def _fused_attention(ctx, ins, attrs):
     v = data(ins["V"][0])
     klen_in = ins.get("KLengths", [None])[0]
     klen = data(klen_in).reshape(-1) if klen_in is not None else None
-    return {"Out": [_attend(ctx, q, k, v, klen,
-                            bool(attrs.get("causal", False)),
-                            attrs.get("scale") or None)]}
+    causal = bool(attrs.get("causal", False))
+    window = int(attrs.get("window", 0)) or None
+    from ..kernels.flash_attention import _visible_pairs
+
+    seen = None if window is None or window >= k.shape[2] else window
+    with span("attn.lower", kind="full" if seen is None else "sliding",
+              window=int(seen or 0), heads=int(q.shape[1]),
+              kv_heads=int(k.shape[1]), sq=int(q.shape[2]),
+              pairs=_visible_pairs(q.shape[2], k.shape[2], causal, seen),
+              rope=str(attrs.get("rope") or "none")):
+        out = _attend(ctx, q, k, v, klen, causal, attrs.get("scale") or None,
+                      window)
+    return {"Out": [out]}
 
 
-def _rotate(x, base: float, offset: int = 0, positions=None, sections=()):
+def _yarn_inv_freq(inv_freq, dim, base, factor, original_length, beta_fast,
+                   beta_slow):
+    """YaRN's frequencies (Peng et al. 2023), as the transformers library
+    initialises them: pair i keeps f_i where it turns more than beta_fast
+    times over `original_length` positions, takes f_i / factor where it
+    turns fewer than beta_slow times, and a linear ramp of the two between
+    the pairs `low` and `high` that turn just that often."""
+    def pair_that_turns(times):
+        return dim * np.log(original_length / (times * 2.0 * np.pi)) \
+            / (2.0 * np.log(base))
+
+    low = max(np.floor(pair_that_turns(beta_fast)), 0)
+    high = min(np.ceil(pair_that_turns(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return inv_freq * (1.0 - ramp) + inv_freq / factor * ramp
+
+
+def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
+            yarn=None):
     """Rotary position embedding (Su et al. 2021) of x [..., S, D] along
     its last two axes, in the half-split layout: pair i is (x[i],
-    x[i + D/2]), turned by the angle p * base^(-2i/D).  The position p is
+    x[i + D/2]), turned by the angle p * base^(-2i/D) (under `yarn`, a dict
+    of factor, original_length, beta_fast, beta_slow and attention_factor:
+    p * _yarn_inv_freq's f'_i, cos and sin times attention_factor, so that a
+    score of two rotated vectors carries its square).  The position p is
     offset + the index on axis -2; or, given `positions` [B, n, S] (x then
     [B, ..., S, D]) and `sections` (n counts that add up to D/2), the b-th
     row's stream j at that index for the pairs of section j (multi-axis
@@ -92,6 +131,11 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=()):
     seq, dim = x.shape[-2], x.shape[-1]
     half = dim // 2
     inv_freq = float(base) ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
+    if yarn is not None:
+        inv_freq = _yarn_inv_freq(
+            inv_freq, dim, float(base), float(yarn["factor"]),
+            float(yarn["original_length"]), float(yarn["beta_fast"]),
+            float(yarn["beta_slow"]))
     if positions is None:
         pos = np.arange(seq, dtype=np.float64) + int(offset)
         angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
@@ -106,6 +150,9 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=()):
         angle = angle.reshape((angle.shape[0],) + (1,) * (x.ndim - 3)
                               + angle.shape[1:])
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if yarn is not None:
+        cos, sin = (t * jnp.float32(yarn["attention_factor"])
+                    for t in (cos, sin))
     xs = x.astype(amp.stats_dtype(x))
     x1, x2 = xs[..., :half], xs[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -116,11 +163,14 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=()):
              diff_inputs=["X"])
 def _rotary_embedding(ctx, ins, attrs):
     pos_in = ins.get("Positions", [None])[0]
+    yarn = None
+    if float(attrs.get("yarn_factor", 0.0)) > 0.0:
+        yarn = {key: float(attrs["yarn_" + key]) for key in YARN_KEYS}
     return {"Out": [_rotate(
         data(ins["X"][0]), attrs.get("base", 10000.0),
         attrs.get("offset", 0),
         None if pos_in is None else data(pos_in),
-        tuple(int(n) for n in attrs.get("sections", ())))]}
+        tuple(int(n) for n in attrs.get("sections", ())), yarn)]}
 
 
 def _latent_attn_infer(op, block):

@@ -140,6 +140,30 @@ _BENCHMARK_TESTS_A_LATER_ENTRY_TRIPS = {
         "the cell's whole reader set; PR 35 appends seven turnaround "
         "readers that every training cell reports "
         "(tests/benchmark/test_turnaround.py holds what still stands)",
+    "test_benchmark_manifest.py::test_configuration_entry_and_files"
+    "[mellum2-12b-a2.5b]":
+        "the same width expression on the same key, num_hidden_layers "
+        "(tests/benchmark/test_mellum_benchmark.py holds the file to the "
+        "rest)",
+    "test_keye_benchmark.py::test_configuration_entry_and_files":
+        "PR 33's test pins its configuration and its cell to the ends of "
+        "their lists; PR 38 appends mellum2-12b-a2.5b and "
+        "mellum-train-swa16k behind them "
+        "(tests/benchmark/test_mellum_benchmark.py holds what still stands)",
+    "test_keye_benchmark.py::"
+    "test_what_pr_31s_manifest_tests_held_for_their_cells_still_holds":
+        "PR 33's test pins moe_experts_ms.train and moe_dispatch_ms.train "
+        "to two cells; mellum-train-swa16k (PR 38) has the same expert "
+        "block and reports them too",
+    "test_turnaround.py::"
+    "test_the_seven_readers_are_the_manifests_last_entries":
+        "PR 35's test pins its seven readers to the end of per_layer and "
+        "to six cells; PR 38 appends five readers and a seventh cell "
+        "(tests/benchmark/test_mellum_benchmark.py holds what still stands)",
+    "test_turnaround.py::"
+    "test_what_pr_33s_manifest_test_held_for_its_cell_still_holds":
+        "PR 35's test pins PR 33's five readers to per_layer[-12:-7]; "
+        "PR 38's five entries stand behind them",
 }
 
 

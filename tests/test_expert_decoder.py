@@ -361,23 +361,26 @@ def test_flash_with_a_value_width_of_its_own_equals_padded_v(S, d, dv, dtype):
 
 def test_flash_plans_at_the_cells_attention_shape():
     """(2048, q and k 192, v 128), bf16, causal: the forward plan, the
-    backward's engine and blocks; 4096 and 8192 keep the XLA engine (the
-    row's dQ does not fit), which is what holds the cell to S 2048."""
+    backward's engine and blocks; at 4096 and 8192 the row's dQ does not
+    fit and the backward runs in chunks of queries (PR 38)."""
     args = (2048, 2048, 192, jnp.bfloat16, True)
     assert fa._plan_blocks(*args, True, 128) == (512, 1024)
     plan = fa._bwd_plan(*args, v_dim=128)
     assert plan == dict(sq=2048, sk=2048, head_dim=192, block_q=512,
                         block_k=512, steps=16, steps_skipped=6,
-                        engine="pallas")
+                        engine="pallas", window=0, chunks=1,
+                        skipped_causal=6, skipped_window=0)
     # v padded to 192 would plan 1024 x 256, as ISSUE 31 read chip-less
     padded = fa._bwd_plan(*args)
     assert (padded["block_q"], padded["block_k"], padded["engine"]) == \
         (1024, 256, "pallas")
     assert fa.bwd_working_set_bytes(512, 512, 192, 4, "bfloat16", 128) < \
         fa.bwd_working_set_bytes(512, 512, 192, 4, "bfloat16")
+    # past S 2048 the row's dQ does not fit: since PR 38 the kernel runs
+    # in an outer loop over chunks of 2048 queries (it fell to XLA before)
     for S in (4096, 8192):
-        assert fa._bwd_plan(S, S, 192, jnp.bfloat16, True,
-                            v_dim=128)["engine"] == "xla"
+        longer = fa._bwd_plan(S, S, 192, jnp.bfloat16, True, v_dim=128)
+        assert (longer["engine"], longer["chunks"]) == ("pallas", S // 2048)
     # a value width equal to the head's changes no plan an older site had
     for sq, d in ((2048, 128), (256, 64)):
         old = (sq, sq, d, jnp.bfloat16, True)
@@ -432,8 +435,8 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
     assert spans["flash.plan"] and len(spans["flash.plan"]) % 3 == 0
     assert all((s["head_dim"], s["block_q"], s["block_k"]) ==
                (192, 512, 1024) for s in spans["flash.plan"])
-    assert spans["flash.bwd_plan"] == 3 * [
-        fa._bwd_plan(2048, 2048, 192, jnp.bfloat16, True, v_dim=128)]
+    assert spans["flash.bwd_plan"] == 3 * [dict(fa._bwd_plan(
+        2048, 2048, 192, jnp.bfloat16, True, v_dim=128), kv_heads=2)]
 
 
 def test_new_ops_keep_bf16_in_bf16_out_with_fp32_inside():

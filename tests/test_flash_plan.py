@@ -143,7 +143,7 @@ def test_block_runs_iff_dense_mask_has_a_visible_element():
                           for j in range(nkb)] for i in range(nqb)])
         np.testing.assert_array_equal(runs, visible, err_msg=str(
             (sq, sk, bq, bk)))
-        assert fa._skipped_k_steps(nqb, nkb, bq, bk, sk - sq) == \
+        assert fa._skipped_steps(nqb, nkb, bq, bk, sk - sq)[0] == \
             int((~visible).sum())
         # past the last block that runs the K/V index stays put (no DMA);
         # up to it, it is the step's own block
@@ -192,7 +192,9 @@ def test_skipped_count_in_the_span_equals_the_dense_count():
         assert spans == [dict(sq=sq, sk=sk, head_dim=8, block_q=bq,
                               block_k=bk, k_steps=visible.size,
                               k_steps_skipped=int((~visible).sum()),
-                              causal=1)]
+                              causal=1, window=0, kv_heads=1, chunks=1,
+                              skipped_causal=int((~visible).sum()),
+                              skipped_window=0)]
 
 
 @pytest.mark.parametrize("causal,pin,k_steps,skipped", [
@@ -428,7 +430,8 @@ def test_bwd_plan_span_counts_equal_the_dense_count(sq, sk, bq, bk):
     assert _bwd_spans(bwd, x, kv, kv, klen) == [dict(
         sq=sq, sk=sk, head_dim=8, block_q=bq, block_k=bk,
         steps=visible.size, steps_skipped=int((~visible).sum()),
-        engine="pallas")]
+        engine="pallas", window=0, chunks=1, kv_heads=1,
+        skipped_causal=int((~visible).sum()), skipped_window=0)]
 
 
 def test_repack_is_a_view_where_the_padded_lengths_agree():
@@ -542,7 +545,8 @@ def test_ouro_body_has_four_pallas_backward_sites():
     tokens, labels = spec.feed_names
     spans = _step_bwd_spans(spec, {tokens: ids[:, :-1], labels: ids[:, 1:]})
     assert len(spans) == 4
-    want = fa._bwd_plan(2048, 2048, 128, jnp.bfloat16, True)
+    want = dict(fa._bwd_plan(2048, 2048, 128, jnp.bfloat16, True),
+                kv_heads=2)
     assert want["engine"] == "pallas" and want["steps_skipped"] > 0
     assert all(s == want for s in spans)
 
