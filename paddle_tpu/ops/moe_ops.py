@@ -32,12 +32,17 @@ Three ops, one chip's share of an expert-parallel layer:
   load_i) over the step's tokens (the auxiliary-loss-free balancing of
   that family); the bias never enters the weights.
 
-Dispatch and combine are adjoint gathers: rows are gathered by their token,
-tokens gather their rows back by position, and each one's backward is the
-other's forward, so no scatter-add runs in either direction.  Name scopes
-`moe.dispatch` (sort, gather, combine) and `moe.experts` (the grouped
-matmuls) group the device's time in a profiler trace; `moe.lower` (a span,
-at lowering) says what a layer was given.
+Dispatch and combine are adjoint: rows are gathered by their token
+(rows <- tokens, a gather of the buffer's rows), tokens get their rows back
+by a reduction over the buffer (tokens <- rows, `tokens_from_rows`: the rows
+brought into token order by one gather, then a grouped product over blocks
+of tokens), and each one's backward is the other's forward, so no
+scatter-add runs in either direction on a TPU and nothing of width d is
+indexed by the T x k assignments, 7/8 of them held on other chips in an
+8-way share: only integers and scalars are (the sort's keys, `order`, `pos`,
+the weights).  Name scopes `moe.dispatch` (sort, gather, combine) and
+`moe.experts` (the grouped matmuls) group the device's time in a profiler
+trace; `moe.lower` (a span, at lowering) says what a layer was given.
 """
 
 from __future__ import annotations
@@ -53,7 +58,7 @@ from ..core.registry import register_op
 from ..observability import span
 from .common import data, in_desc, same_shape, set_output
 
-__all__ = ["route", "held_experts_part", "row_buffers"]
+__all__ = ["route", "held_experts_part", "row_buffers", "tokens_from_rows"]
 
 
 SCORING = {"sigmoid": jax.nn.sigmoid,
@@ -104,60 +109,161 @@ def row_buffers(tokens: int, top_k: int, held: int, total: int) -> tuple:
     return tuple(ladder) + (worst,)
 
 
+# One token block of the reduction: a token's column in its block's one-hot
+# matrix, the width of the v5e's MXU.
+TOKEN_BLOCK = 128
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def tokens_from_rows(values, token_of_row, filled, tokens, engine=None,
+                     scale=None):
+    """y [tokens, d]: y[t] = the sum of scale[r] * values[r] (`scale` [rows]
+    fp32; None: of values[r]) over the rows r < `filled` with
+    token_of_row[r] == t.  Product and sum in fp32, the result in the
+    operands' common dtype as a matmul's is (fp32 under a scale).  A token
+    with no such row reads zero; rows from `filled` on are never read into
+    a sum, whatever they hold.
+
+    Its cost goes by the rows and by `tokens`, never by the assignments:
+    one gather of `rows` rows brings the rows into token order, so that the
+    rows of a block of TOKEN_BLOCK tokens are contiguous, and the segmented
+    sum is the grouped product megablox ships, onehot^T [TOKEN_BLOCK, rows]
+    x values [rows, d] by the blocks' row counts: its grid covers the
+    filled row tiles only and it zeroes the blocks no row wrote.  A one-hot
+    operand is exact in the values' dtype and the MXU accumulates in fp32.
+    A scale rides in the one-hot's place, in fp32: the kernel widens the
+    values' tile in VMEM and the product is the MXU's fp32 one, so no
+    [rows, d] fp32 value is written for it.  Off the TPU (`engine`
+    "ragged_dot") the same sum is XLA's segment_sum, as ragged_dot stands
+    in for gmm.  The adjoint is the gather of `rows` rows that _dispatch
+    is."""
+    rows, d = values.shape
+    blocks = -(-tokens // TOKEN_BLOCK)
+    dtype = values.dtype if scale is None else jnp.result_type(values, scale)
+    # a row past the filled ones lies in no block: its key sorts last
+    key = jnp.where(jnp.arange(rows) < filled, token_of_row,
+                    blocks * TOKEN_BLOCK)
+    if _engine(engine) == "ragged_dot":
+        values = values.astype(jnp.float32)
+        return jax.ops.segment_sum(
+            values if scale is None else scale[:, None] * values, key,
+            num_segments=tokens).astype(dtype)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    # the product [TOKEN_BLOCK, rows] x [rows, d]: rows in tiles of 128 (a
+    # token block's ~128 rows straddle two at most; 256 ties, 512 loses:
+    # PERF.md 6, PR 41), d whole
+    tiling = (min(128, -(-rows // 8) * 8), TOKEN_BLOCK, d)
+    key, by_token = jax.lax.sort_key_val(key, jnp.arange(rows))
+    # the rows rounded up to whole tiles repeat row 0, outside every block
+    by_token = jnp.pad(by_token, (0, -rows % tiling[0]))
+    key = jnp.pad(key, (0, -rows % tiling[0]),
+                  constant_values=blocks * TOKEN_BLOCK)
+    onehot = key[None, :] % TOKEN_BLOCK == jnp.arange(TOKEN_BLOCK)[:, None]
+    sizes = jnp.sum(key[None, :] // TOKEN_BLOCK == jnp.arange(blocks)[:, None],
+                    axis=1, dtype=jnp.int32)
+    onehot = (onehot.astype(values.dtype) if scale is None else
+              jnp.where(onehot, jnp.take(scale, by_token)[None, :], 0.0))
+    # by_token is a permutation: clipped, so that no pass over the gathered
+    # rows fills in for an index out of range, as take's default mode runs
+    # one before a kernel's operand.  Where an operand is fp32 the kernel's
+    # product is fp32's own whatever the kernel's default precision is: a
+    # scale is never rounded to bf16.
+    with jax.default_matmul_precision(
+            "highest" if onehot.dtype == jnp.float32 else "default"):
+        y = tgmm(onehot, jnp.take(values, by_token, axis=0, mode="clip"),
+                 sizes, dtype, tiling, interpret=(engine == "interpret"))
+    return y.reshape(blocks * TOKEN_BLOCK, d)[:tokens]
+
+
+def _tokens_from_rows_fwd(values, token_of_row, filled, tokens, engine,
+                          scale):
+    return (tokens_from_rows(values, token_of_row, filled, tokens, engine,
+                             scale), (values, token_of_row, filled, scale))
+
+
+def _tokens_from_rows_bwd(tokens, engine, res, g):
+    values, token_of_row, filled, scale = res
+    live = (jnp.arange(token_of_row.shape[0]) < filled)[:, None]
+    g_row = jnp.take(g, token_of_row, axis=0)
+    if scale is None:
+        return jnp.where(live, g_row, 0).astype(values.dtype), None, None, None
+    return (jnp.where(live, scale[:, None] * g_row, 0).astype(values.dtype),
+            None, None, jnp.sum(jnp.where(live, g_row * values, 0), axis=-1))
+
+
+tokens_from_rows.defvjp(_tokens_from_rows_fwd, _tokens_from_rows_bwd)
+
+
+def feature_rows(rows: int) -> int:
+    """The rows of width d that one layer's gathers and reductions index in
+    a buffer of `rows` rows, forward + backward: _dispatch's gather, forward
+    and recomputed; _combine's gradient by row; and the two
+    tokens_from_rows (the forward's _combine, the backward's _dispatch), a
+    gather into token order and a reduction each.  No term in T x k."""
+    return (2 + 1 + 2 * 2) * rows
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, token, pos, rows):
-    """xr [rows, d]: row r is token[r]'s features.  `pos` [T, k] is where
-    assignment (t, j) lies in the buffer (>= rows: not held here)."""
+def _dispatch(x, token, filled, engine):
+    """xr [rows, d]: row r is token[r]'s features."""
     return jnp.take(x, token, axis=0)
 
 
-def _dispatch_fwd(x, token, pos, rows):
-    return jnp.take(x, token, axis=0), pos
+def _dispatch_fwd(x, token, filled, engine):
+    # (the empty slice carries the token count to the backward)
+    return jnp.take(x, token, axis=0), (token, filled, x[:, :0])
 
 
-def _dispatch_bwd(rows, pos, g):
+def _dispatch_bwd(engine, res, g):
     # a token's gradient is the sum over its held assignments' rows
-    picked = jnp.take(g, jnp.minimum(pos, rows - 1), axis=0)     # [T, k, d]
-    dx = jnp.sum(jnp.where((pos < rows)[..., None], picked, 0), axis=1,
-                 dtype=jnp.float32).astype(g.dtype)
-    return dx, None, None
+    token, filled, like = res
+    return (tokens_from_rows(g, token, filled, like.shape[0], engine), None,
+            None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(out, weight, order, pos):
+def _weight_of_row(weight, order, pos):
+    """[rows] fp32: the weight of the assignment in row r, 0 where that
+    assignment is not held here (the rows past the filled ones).  order
+    [rows] is the assignment (t * k + j) that lies in row r, `pos` [T, k]
+    where an assignment lies in the buffer (>= rows: not held here); the
+    backward is the gather by `pos`, so no scatter-add runs here either.
+
+    The forward asks `pos` too, not the filled count, which would do: then
+    a layer's first forward computes `pos` (the second argsort) as its
+    recomputed forward does for the backward, the two are one program op
+    for op, and the compiler goes on merging them where a one-trip
+    recurrence leaves no loop between them.  With `pos` dead in the first
+    forward moonlight-train-ep8share ran its four expert layers'
+    recomputation in full, 14.7 ms of a 186 ms step (PERF.md 6, PR 41)."""
+    rows = order.shape[0]
+    return jnp.where(jnp.take(pos.reshape(-1), order) < rows,
+                     jnp.take(weight.reshape(-1), order), 0.0)
+
+
+def _weight_of_row_fwd(weight, order, pos):
+    return _weight_of_row(weight, order, pos), pos
+
+
+def _weight_of_row_bwd(pos, g):
+    rows = g.shape[0]
+    return (jnp.where(pos < rows, jnp.take(g, jnp.minimum(pos, rows - 1)),
+                      0.0).astype(g.dtype), None, None)
+
+
+_weight_of_row.defvjp(_weight_of_row_fwd, _weight_of_row_bwd)
+
+
+def _combine(out, weight, order, pos, filled, engine):
     """y [T, d] fp32: sum over a token's held assignments of weight x the
-    row the experts gave it.  order [rows] is the assignment (t * k + j)
-    that lies in row r."""
-    rows = out.shape[0]
-    picked = jnp.take(out, jnp.minimum(pos, rows - 1), axis=0)   # [T, k, d]
-    return jnp.einsum("tk,tkd->td", jnp.where(pos < rows, weight, 0.0),
-                      picked.astype(jnp.float32))
-
-
-def _combine_fwd(out, weight, order, pos):
-    return _combine(out, weight, order, pos), (out, weight, order, pos)
-
-
-def _combine_bwd(res, g):
-    out, weight, order, pos = res
-    rows, k = out.shape[0], pos.shape[1]
-    # by row, a gather by token: d out[r] = its assignment's weight x
-    # dy[its token], d weight of its assignment = dy[its token] . out[r];
-    # a row past the held total lies in no assignment's pos and gets 0
-    g_row = jnp.take(g, order // k, axis=0)
-    w_row = jnp.where(jnp.take(pos.reshape(-1), order) < rows,
-                      jnp.take(weight.reshape(-1), order), 0.0)
-    dw_row = jnp.sum(g_row * out.astype(jnp.float32), axis=-1)
-    d_weight = jnp.where(pos < rows,
-                         jnp.take(dw_row, jnp.minimum(pos, rows - 1)), 0.0)
-    return ((g_row * w_row[:, None]).astype(out.dtype),
-            d_weight.astype(weight.dtype), None, None)
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
+    row the experts gave it, product and sum in fp32."""
+    T, k = pos.shape
+    return tokens_from_rows(out, order // k, filled, T, engine,
+                            _weight_of_row(weight, order, pos))
 
 
 def _gmm_tiling(m: int, k: int, n: int) -> tuple:
@@ -193,6 +299,11 @@ def _grouped_matmul(a, w, sizes, engine):
         interpret=(engine == "interpret"))
 
 
+# tokens_from_rows' engine beside the grouped matmul's
+COMBINE = {"megablox": "tgmm", "interpret": "tgmm",
+           "ragged_dot": "segment_sum"}
+
+
 def _engine(engine):
     from ..kernels.flash_attention import _use_pallas
 
@@ -207,13 +318,14 @@ def _experts_in_buffer(x, weight, gate_w, up_w, down_w, order, pos, sizes,
     `rows` rows (static) of which sum(sizes) are filled."""
     k = pos.shape[1]
     order = order[:rows]
+    filled = jnp.sum(sizes)
     with jax.named_scope("moe.dispatch"):
-        xr = _dispatch(x, order // k, pos, rows)
+        xr = _dispatch(x, order // k, filled, engine)
     with jax.named_scope("moe.experts"):
         # rows past the groups belong to no expert: whatever the kernel
         # leaves there is cut off before silu, the product or a gradient
         # can turn it into a NaN
-        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        live = (jnp.arange(rows) < filled)[:, None]
 
         def grouped(a, w):
             # the MXU accumulates in fp32; the result leaves in the compute
@@ -224,7 +336,7 @@ def _experts_in_buffer(x, weight, gate_w, up_w, down_w, order, pos, sizes,
         hidden = jax.nn.silu(grouped(xr, gate_w)) * grouped(xr, up_w)
         out = grouped(hidden, down_w)
     with jax.named_scope("moe.dispatch"):
-        return _combine(out, weight, order, pos)
+        return _combine(out, weight, order, pos, filled, engine)
 
 
 def _picked_by_the_count(sizes, buffers, make):
@@ -246,9 +358,17 @@ def _experts_by_count(x, weight, gate_w, up_w, down_w, order, pos, sizes,
     so the backward's conditional computes its branch's forward again
     instead; under a layer's recomputation that costs nothing more, the
     recomputed forward's own output being dead."""
-    return _picked_by_the_count(sizes, buffers, lambda rows: (
+    y = _picked_by_the_count(sizes, buffers, lambda rows: (
         lambda *ops: _experts_in_buffer(*ops, rows=rows, engine=engine)))(
             x, weight, gate_w, up_w, down_w, order, pos, sizes)
+    # the branches end here.  Without the barrier XLA's conditional code
+    # motion sinks the op's own reshape to [B, S, d] into them, the layer
+    # behind then runs its first forward on a value the compiler cannot see
+    # through while its recomputed one runs on the kept [T, d] carry, and
+    # the two no longer simplify to one form: a layer's recomputed forward,
+    # which the compiler merges with the first (PERF.md 6, PR 38), runs
+    # (PR 41: 7 flash forward calls for 4 in PR 38's tiny step)
+    return jax.lax.optimization_barrier(y)
 
 
 def _experts_by_count_fwd(x, weight, gate_w, up_w, down_w, order, pos, sizes,
@@ -352,7 +472,9 @@ def _moe_experts(ctx, ins, attrs):
     with span("moe.lower", experts_total=total,
               experts_held=int(gate_w.shape[0]), top_k=int(k),
               row_buffer=buffers[-1], row_buffer_usual=buffers[0],
-              row_buffers=len(buffers), engine=_engine(None), dropped=0,
+              row_buffers=len(buffers), engine=_engine(None),
+              combine=COMBINE[_engine(None)],
+              feature_rows=feature_rows(buffers[0]), dropped=0,
               scoring=attrs.get("scoring", "sigmoid")):
         y = held_experts_part(
             xc.reshape(tokens, x.shape[-1]), idx.reshape(tokens, k),

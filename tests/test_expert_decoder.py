@@ -430,7 +430,8 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=64, experts_held=8, top_k=6, row_buffer=6 * T,
         row_buffer_usual=6 * T // 4, row_buffers=3, engine="megablox",
-        dropped=0, scoring="sigmoid")]
+        combine="tgmm", feature_rows=7 * 6 * T // 4, dropped=0,
+        scoring="sigmoid")]
     # (the forward is traced once for the step and once more by jax.vjp)
     assert spans["flash.plan"] and len(spans["flash.plan"]) % 3 == 0
     assert all((s["head_dim"], s["block_q"], s["block_k"]) ==

@@ -439,7 +439,8 @@ def test_dsa_lower_and_moe_lower_say_what_a_site_was_given():
     assert spans["dsa.lower"][0]["kept_bytes"] == 136_380_416
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=128, experts_held=16, top_k=8, row_buffer=8 * S,
-        row_buffer_usual=2 * S, row_buffers=3, engine="megablox", dropped=0,
+        row_buffer_usual=2 * S, row_buffers=3, engine="megablox",
+        combine="tgmm", feature_rows=7 * 2 * S, dropped=0,
         scoring="softmax")]
 
 

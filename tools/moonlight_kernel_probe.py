@@ -25,6 +25,15 @@ and, under `experts-others`,
                 grouped kernel) in place of the Pallas one
   dense8        8 dense passes over all T tokens, masked (what a layer that
                 ignores the routing pays)
+and, under `tokens-from-rows` (PR 41), at the three expert cells' shapes
+(T, d, width, held / total, k from benchmark/configs and benchmark/cells)
+and 1 x and 2 x the expected rows: the layer forward + backward, and each
+tokens <- rows site alone (_combine's forward: bf16 rows, fp32 weights;
+_dispatch's backward: bf16 rows) with its worst difference from XLA's fp32
+scatter-add.  The forms that lost to ops/moe_ops.py::tokens_from_rows (the
+gather over all T x k assignments it replaced, other row tiles, the product
+written out first, XLA's scatter-add) are in PERF.md 6, PR 41, with their
+numbers; the tool no longer carries them.
 One JSON line a row, all rows to --out (chiprun_out/moonlight_kernel_probe.json).
 
     chiprun --chips 1 -- python3 tools/moonlight_kernel_probe.py --seed 7
@@ -86,11 +95,22 @@ def attention_rows(rng, B, H, S, dn, dr, dv, calls):
     return rows
 
 
-def expert_rows(rng, T, d, f, held, total, k, calls, shares, others):
-    import jax
+def cell_shape(name, rehearse=False):
+    """(T, d, f, held, total, k) of an expert cell, from its files."""
+    from benchmark.harness import manifest
+
+    cell = manifest.Cell(manifest.load_manifest(), name, rehearse=rehearse)
+    cfg = cell.config
+    held = cfg.get("n_routed_experts", cfg.get("num_experts"))
+    return (int(cell.sizing["per_chip_batch"]) * cfg["max_length"],
+            cfg["hidden_size"], cfg["moe_intermediate_size"], held,
+            cfg["router_experts"], cfg["num_experts_per_tok"])
+
+
+def _layer_operands(rng, T, d, f, held, k):
+    """(x, weight, gate_w, up_w, down_w) of one expert layer, bf16 with
+    fp32 gates."""
     import jax.numpy as jnp
-    import numpy as np
-    from paddle_tpu.ops import moe_ops
 
     x = jnp.asarray(rng.standard_normal((T, d)), jnp.bfloat16)
     gate_w, up_w = (jnp.asarray(rng.standard_normal((held, d, f)) * 0.02,
@@ -98,17 +118,97 @@ def expert_rows(rng, T, d, f, held, total, k, calls, shares, others):
     down_w = jnp.asarray(rng.standard_normal((held, f, d)) * 0.02,
                          jnp.bfloat16)
     weight = jnp.asarray(rng.uniform(0.2, 0.6, (T, k)), jnp.float32)
+    return x, weight, gate_w, up_w, down_w
+
+
+def _routing(rng, T, k, held, total, mean_held):
+    """idx [T, k]: a token's first n experts held ones, the rest not, n =
+    floor(mean) or one more so that the mean is `mean_held`."""
+    import numpy as np
+
+    here = np.argsort(rng.random((T, held)), axis=1)[:, :k]
+    away = held + np.argsort(rng.random((T, total - held)), axis=1)[:, :k]
+    n = int(mean_held) + (rng.random(T) < mean_held - int(mean_held))
+    return np.where(np.arange(k)[None, :] < n[:, None], here, away).astype(
+        np.int32)
+
+
+def tokens_from_rows_rows(rng, cell, shape, calls, shares):
+    """The layer forward + backward and each tokens <- rows site alone, a
+    row a share; a site's worst difference from the fp32 segment_sum beside
+    its time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import moe_ops
+
+    T, d, f, held, total, k = shape
+    operands = _layer_operands(rng, T, d, f, held, k)
+    weight = operands[1]
+    buffers = moe_ops.row_buffers(T, k, held, total)
+
+    def layer(x, weight, gate_w, up_w, down_w, idx):
+        return jnp.sum(jnp.sin(moe_ops.held_experts_part(
+            x, idx, weight, gate_w, up_w, down_w, 0, total)))
+
+    def combine(out, weight, order, pos, filled):
+        return moe_ops._combine(out, weight, order, pos, filled, None)
+
+    def dispatch_bwd(g, token, filled):
+        return moe_ops.tokens_from_rows(g, token, filled, T)
+
+    out = []
+    for share in shares:
+        idx = _routing(rng, T, k, held, total, share * k * held / total)
+        routed = int(np.sum(idx < held))
+        rows = int(next((b for b in buffers if routed <= b), buffers[-1]))
+        # the sites' operands as held_experts_part makes them
+        key = np.where(idx < held, idx, held).reshape(-1)
+        order = np.argsort(key, kind="stable").astype(np.int32)
+        pos = np.argsort(order).astype(np.int32)
+        pos = jnp.asarray(
+            np.where(idx.reshape(-1) < held, pos, T * k).reshape(T, k))
+        order = jnp.asarray(order[:rows])
+        token, filled = order // k, jnp.int32(routed)
+        rows_bf16 = jnp.asarray(rng.standard_normal((rows, d)), jnp.bfloat16)
+        held_rows = rows_bf16[:routed].astype(jnp.float32)
+        w_row = jnp.take(weight.reshape(-1), order)
+        base = {"cell": cell, "rows_over_expected": share,
+                "routed_rows": routed, "row_buffer": rows,
+                "shape": [T, d, f, held, total, k]}
+        step = jax.jit(jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4)))
+        ms = _time_ms(step, operands + (jnp.asarray(idx),), calls)
+        out.append(dict(base, what="layer", ms=round(ms, 4)))
+        print(json.dumps(out[-1]), flush=True)
+        # XLA's fp32 scatter-add of the fp32 products: what a site should give
+        for site, fn, args, want in (
+                ("combine", combine, (rows_bf16, weight, order, pos, filled),
+                 w_row[:routed, None] * held_rows),
+                ("dispatch_bwd", dispatch_bwd, (rows_bf16, token, filled),
+                 held_rows)):
+            want = np.asarray(jax.ops.segment_sum(want, token[:routed],
+                                                  num_segments=T))
+            fn = jax.jit(fn)
+            ms = _time_ms(fn, args, calls)
+            got = np.asarray(fn(*args).astype(jnp.float32))
+            out.append(dict(base, what=site, ms=round(ms, 4),
+                            max_abs_diff=float(np.max(np.abs(got - want))),
+                            max_abs=float(np.max(np.abs(want)))))
+            print(json.dumps(out[-1]), flush=True)
+    return out
+
+
+def expert_rows(rng, T, d, f, held, total, k, calls, shares, others):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import moe_ops
+
+    x, weight, gate_w, up_w, down_w = _layer_operands(rng, T, d, f, held, k)
     expected = k * held / total
 
     def routing(mean_held):
-        """idx [T, k]: a token's first n experts held ones, the rest not,
-        n = floor(mean) or one more so that the mean is `mean_held`."""
-        here = np.argsort(rng.random((T, held)), axis=1)[:, :k]
-        away = held + np.argsort(rng.random((T, total - held)),
-                                 axis=1)[:, :k]
-        n = int(mean_held) + (rng.random(T) < mean_held - int(mean_held))
-        return jnp.asarray(np.where(np.arange(k)[None, :] < n[:, None],
-                                    here, away), jnp.int32)
+        return jnp.asarray(_routing(rng, T, k, held, total, mean_held))
 
     buffers = moe_ops.row_buffers(T, k, held, total)
 
@@ -183,7 +283,13 @@ def main() -> int:
         rows += attention_rows(rng, *((1, 2, 64, 16, 8, 16) if args.rehearse
                                       else (4, 16, 2048, 128, 64, 128)),
                                calls=args.calls)
-    if "experts" in args.what:
+    if "tokens-from-rows" in args.what:
+        for cell in ("mellum-train-swa16k", "moonlight-train-ep8share",
+                     "keye-train-dsa16k"):
+            rows += tokens_from_rows_rows(
+                rng, cell, cell_shape(cell, args.rehearse), args.calls,
+                [float(s) for s in args.shares.split(",")])
+    if "experts" in args.what.replace("tokens-from-rows", ""):
         rows += expert_rows(rng, *((64, 32, 24, 4, 32, 3) if args.rehearse
                                    else (8192, 2048, 1408, 8, 64, 6)),
                             calls=args.calls,
