@@ -130,8 +130,10 @@ def flops_per_sample(cfg: dict) -> float:
 
 def grouped_matmul_flops_per_step(cfg: dict, tokens: int) -> float:
     """FLOPs of the expert layers' grouped matmuls a training step at the
-    expected rows: forward, recomputed forward and backward."""
-    passes = 3 + int(bool(cfg["use_recompute"]))
+    expected rows: forward 1 and backward 2 (input and weight gradient)
+    passes of 2 x rows x parameters a row.  Recomputed work is never
+    counted, whatever `use_recompute` says."""
+    passes = 3
     rows = tokens * expected_rows_per_token(cfg)
     return passes * 2.0 * rows * expert_matmul_params(cfg) \
         * cfg["num_hidden_layers"]
@@ -139,15 +141,19 @@ def grouped_matmul_flops_per_step(cfg: dict, tokens: int) -> float:
 
 def attend_flops_per_step(cfg: dict, sequences: int) -> float:
     """FLOPs of the main attention's core over the CHOSEN keys a training
-    step: forward (2 products), the forward computed again under
-    use_recompute, and the backward (5 products: the scores again, dP, dV,
-    dK, dQ).  What dsa_attend_roofline.train divides by the device time
-    under the scope `dsa.attend`, which holds all three passes, and the
-    MXU's peak: the masked block engine is bound by its matmuls, of which
-    it runs 4.3 times these at S 16384 (every causal block, masked), so
-    the share cannot pass 100% and reads low by design.  The heads' summed
+    step: the algorithm's passes, forward 2 products (q.k, p.v) and
+    backward 5 (the scores again, dP, dV, dK, dQ).  Recomputed work is
+    never counted, whatever `use_recompute` says and whatever the program
+    recomputes: a PR that stops or starts recomputing a forward moves the
+    share through the time alone, and a count tied to what the program
+    recomputes goes stale with every such PR.  What
+    dsa_attend_roofline.train divides by the device time under the scope
+    `dsa.attend`, which holds every pass that runs, and the MXU's peak: the
+    masked block engine is bound by its matmuls, of which it runs
+    4.3 times these at S 16384 (every causal block, masked), so the share
+    cannot pass 100% and reads low by design.  The heads' summed
     probabilities (one more q.k product a pass, for the index's loss) are
     not counted."""
-    passes = 2 + 2 * int(bool(cfg["use_recompute"])) + 5
+    passes = 2 + 5
     return (passes / 2.0) * attend_flops_per_pair(cfg) \
         * keys_selected(cfg) * cfg["num_hidden_layers"] * sequences

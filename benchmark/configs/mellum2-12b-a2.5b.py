@@ -131,8 +131,10 @@ def flops_per_sample(cfg: dict) -> float:
 
 def grouped_matmul_flops_per_step(cfg: dict, tokens: int) -> float:
     """FLOPs of the expert layers' grouped matmuls a training step at the
-    expected rows: forward, recomputed forward and backward."""
-    passes = 3 + int(bool(cfg["use_recompute"]))
+    expected rows: forward 1 and backward 2 (input and weight gradient)
+    passes of 2 x rows x parameters a row.  Recomputed work is never
+    counted, whatever `use_recompute` says."""
+    passes = 3
     rows = tokens * expected_rows_per_token(cfg)
     return passes * 2.0 * rows * expert_matmul_params(cfg) \
         * cfg["num_hidden_layers"]

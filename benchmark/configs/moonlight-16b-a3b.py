@@ -105,12 +105,16 @@ def flops_per_sample(cfg: dict) -> float:
 
 def grouped_matmul_flops_per_step(cfg: dict, tokens: int) -> float:
     """FLOPs of the expert layers' grouped matmuls a training step at the
-    expected rows: forward, the forward computed again under use_recompute,
-    and the backward (input and weight gradient), 2 FLOPs a multiply-add:
-    (1 + recompute + 2) x 2 x rows x parameters a row, over the expert
-    layers.  What moe_experts_roofline.train divides by the device time
-    under the scope `moe.experts`, which holds all three passes."""
-    passes = 3 + int(bool(cfg["use_recompute"]))
+    expected rows: the algorithm's passes, forward 1 and backward 2 (input
+    and weight gradient), 2 FLOPs a multiply-add: 3 x 2 x rows x parameters
+    a row, over the expert layers.  Recomputed work is never counted,
+    whatever `use_recompute` says and whatever the program recomputes: a PR
+    that stops or starts recomputing a forward moves the share through the
+    time alone, and a count tied to what the program recomputes goes stale
+    with every such PR.  What
+    moe_experts_roofline.train divides by the device time under the scope
+    `moe.experts`, which holds every pass that runs."""
+    passes = 3
     layers = cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
     rows = tokens * expected_rows_per_token(cfg)
     return passes * 2.0 * rows * expert_matmul_params(cfg) * layers

@@ -59,23 +59,94 @@ def allocator_peak_bytes(devices) -> int:
     return peak
 
 
-def largest_program_temp_bytes(devices) -> int:
-    """The largest temp_size_in_bytes (per device) among the executables the
-    client holds: on the v5e the allocator's peak does not count a running
-    program's temporaries (PERF.md 6, PR 22 and PR 24), and a training step
-    is mostly temporaries.  0 where the client cannot say."""
+def allocator_bytes_in_use(devices) -> int:
+    """memory_stats()["bytes_in_use"] on the fullest of the devices: what
+    the allocator holds at this moment (0 where the backend keeps no
+    statistics, as the CPU's)."""
+    return max((int((d.memory_stats() or {}).get("bytes_in_use", 0))
+                for d in devices), default=0)
+
+
+def memory_limit_bytes(devices) -> int | None:
+    """memory_stats()["bytes_limit"] of the smallest of the devices: what a
+    chip can hold (16.909e9 on a v5e: the 15.75 GiB the compiler fits a
+    program into), or None where the backend does not say."""
+    limits = [int((d.memory_stats() or {}).get("bytes_limit", 0))
+              for d in devices]
+    return min(limits) if limits and all(limits) else None
+
+
+def fits(peak_bytes: int, limit_bytes) -> bool:
+    """Whether a reading of the peak can be one: a run that did not fail
+    held no more than the chip has.  True where the limit is not known."""
+    return not limit_bytes or peak_bytes <= limit_bytes
+
+
+def live_executables(devices) -> list:
+    """The executables the client holds, [] where it cannot say."""
     try:
-        execs = devices[0].client.live_executables()
+        return list(devices[0].client.live_executables())
     except Exception:
-        return 0
+        return []
+
+
+def largest_temp_bytes(executables) -> int:
+    """The largest temp_size_in_bytes (per device) among `executables`; 0
+    where none can say."""
     worst = 0
-    for e in execs:
+    for e in executables:
         try:
             worst = max(worst, int(
                 e.get_compiled_memory_stats().temp_size_in_bytes))
         except Exception:
             continue
     return worst
+
+
+def largest_program_temp_bytes(devices) -> int:
+    """The largest temp_size_in_bytes (per device) among the executables the
+    client holds: on the v5e the allocator's peak does not count a running
+    program's temporaries (PERF.md 6, PR 22 and PR 24), and a training step
+    is mostly temporaries.  0 where the client cannot say."""
+    return largest_temp_bytes(live_executables(devices))
+
+
+class StepMemory:
+    """The fullest device's memory at the two moments at which a training
+    cell can peak, each a sum of two numbers of ONE moment: the bytes the
+    allocator holds when a step is launched, and the temporaries of the
+    program then launched, which the allocator does not count.
+
+    `first`: the first step, with whatever the harness keeps beside the
+    state (reference.FirstStep's copy of the parameters).  `window`: a step
+    of the window, read between two steps, when the allocator holds what it
+    holds at the next one's launch.  The step's program is known by
+    appearing among the client's executables during the first step; the
+    largest that does is taken.  The allocator's peak over the whole run and
+    the largest temporaries of any program (the plain reference's among
+    them) are two peaks of different moments, and their sum can pass what a
+    chip holds on a run that did not fail: `peak` cannot."""
+
+    def __init__(self, devices):
+        self.devices = devices
+        self.first_in_use = self.window_in_use = self.step_temp = 0
+        self._held = []
+
+    def before_first_step(self) -> None:
+        self._held = live_executables(self.devices)
+        self.first_in_use = allocator_bytes_in_use(self.devices)
+
+    def after_first_step(self) -> None:
+        held = set(map(id, self._held))   # alive, so no id is used twice
+        self.step_temp = largest_temp_bytes(
+            e for e in live_executables(self.devices) if id(e) not in held)
+        self._held = []
+
+    def between_window_steps(self) -> None:
+        self.window_in_use = allocator_bytes_in_use(self.devices)
+
+    def peak(self) -> int:
+        return max(self.first_in_use, self.window_in_use) + self.step_temp
 
 
 def compile_cache() -> str:

@@ -63,8 +63,12 @@ def run(cell, args, devices, t_start: float) -> dict:
 
     # the first step, which compiles, is held to the plain reference
     first = reference.FirstStep(cell, spec, place_on=replicated)
+    memory = _device.StepMemory(devices)
+    memory.before_first_step()
     warm_losses = [one_step("bench.warm_step")]
+    memory.after_first_step()
     found, problems = first.compare(warm_losses[0], batch, per_chip)
+    numbers = reference.compared(found, first.tol)
     del first
     print(f"[bench] first step against the reference: {found}", flush=True)
     warm_losses += [one_step("bench.warm_step")
@@ -81,6 +85,7 @@ def run(cell, args, devices, t_start: float) -> dict:
             break
     window_s = time.perf_counter() - t_window
     compiles = counter.count - compiles_before
+    memory.between_window_steps()
 
     # correct: the first step against the reference (above), the losses,
     # no compilation, and where the state lives
@@ -89,6 +94,8 @@ def run(cell, args, devices, t_start: float) -> dict:
         problems.append(f"loss did not fall or is not finite: before any "
                         f"step {warm_losses[0]}, the window's first quarter "
                         f"{quarters[0]}, its last quarter {quarters[1]}")
+    numbers["last_quarter_loss_under"] = [quarters[1], warm_losses[0]]
+    numbers["compiles_in_window"] = [compiles, 0]
     if compiles:
         problems.append(f"{compiles} compilations inside the window")
     scope = fluid.global_scope()
@@ -112,7 +119,8 @@ def run(cell, args, devices, t_start: float) -> dict:
         if not whole:
             problems.append(f"parameter {params[-1].name} is not a whole "
                             "copy on each device")
-        elif not apart <= COPIES_ATOL:
+        numbers["copies_apart"] = [apart, COPIES_ATOL]
+        if whole and not apart <= COPIES_ATOL:
             problems.append(f"parameter {params[-1].name} differs between "
                             f"the chips after the window by {apart}, over "
                             f"{COPIES_ATOL}")
@@ -154,11 +162,22 @@ def run(cell, args, devices, t_start: float) -> dict:
         obs["trace_steps"] = traced_steps
     obs["allocator_peak_bytes"] = _device.allocator_peak_bytes(devices)
     obs["program_temp_bytes"] = _device.largest_program_temp_bytes(devices)
-    # the allocator does not see a running program's temporaries
-    obs["memory_peak_bytes"] = (obs["allocator_peak_bytes"]
-                                + obs["program_temp_bytes"])
+    # the allocator does not see a running program's temporaries: what it
+    # held when a step was launched plus that step's, at the fuller of the
+    # two moments (the first step, a step of the window)
+    obs["first_step_in_use_bytes"] = memory.first_in_use
+    obs["window_step_in_use_bytes"] = memory.window_in_use
+    obs["step_temp_bytes"] = memory.step_temp
+    obs["memory_limit_bytes"] = _device.memory_limit_bytes(devices)
+    obs["memory_peak_bytes"] = memory.peak()
+    if not _device.fits(obs["memory_peak_bytes"], obs["memory_limit_bytes"]):
+        print(f"[bench] WARNING: memory_peak_bytes "
+              f"{obs['memory_peak_bytes']} is over what a chip holds, "
+              f"{obs['memory_limit_bytes']}: hbm_peak_gb.train is left out",
+              flush=True)
     return {"correct": not problems, "problems": problems,
-            "attempted": len(losses), "failed": 0, "obs": obs}
+            "compared": numbers, "attempted": len(losses), "failed": 0,
+            "obs": obs}
 
 
 # After the window every chip has to hold the same whole copy of a parameter:

@@ -104,6 +104,19 @@ def judge(loss: float, ref_loss: float, prods: dict) -> dict:
     }
 
 
+def compared(found: dict, tol: dict) -> dict:
+    """{short name: [the number compared, its limit]} of `problems`' four
+    comparisons, for the run's last lines and its result line."""
+    return {
+        "loss_rel": [found["loss_rel"], tol["loss_rtol"]],
+        "grad_cos_min": [found["grad_cos"], tol["grad_cos_min"]],
+        "grad_norm_off_1": [abs(found["grad_norm_ratio"] - 1.0),
+                            tol["grad_norm_rtol"]],
+        "param_norm_far": [found["param_norm_far"],
+                           tol["param_norm_factor"]],
+    }
+
+
 def problems(found: dict, tol: dict) -> list:
     out = []
     if not found["loss_rel"] <= tol["loss_rtol"]:

@@ -25,9 +25,9 @@ if __package__ in (None, ""):  # run as a file: find the sibling modules
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    from benchmark.harness import step_spans, trace
+    from benchmark.harness import trace
 else:
-    from . import step_spans, trace
+    from . import trace
 
 # the stat of an operation's metadata that holds its scope path
 SCOPE_STATS = ("tf_op", "hlo_op_name", "op_name")
@@ -121,36 +121,24 @@ def op_scopes(xspace: bytes) -> dict:
 def scope_ms(profile, scopes: dict, scope: str):
     """Device ms inside `bench.window` in the first device's operations
     whose scope path contains `scope`, or None where no operation has it."""
-    win = step_spans.window(profile)
+    win = trace.window(profile)
     if win is None or not scopes:
         return None
     t0, t1 = win
     paths = scopes[min(scopes)]
-    ops = [(s, e) for n, s, e in step_spans.first_device_ops(profile, t0, t1)
+    ops = [(s, e) for n, s, e in trace.first_device_ops(profile, t0, t1)
            if scope in paths.get(n, "")]
     if not ops:
         return None
     return sum(e - s for s, e in trace.union(ops)) / 1e6
 
 
-_parsed = {}  # {(path, mtime): (profile, scopes)}: one parse a process
-
-
 def newest(root: str | None = None):
     """(profile, scopes) of the newest trace under bench_out/trace."""
-    path = step_spans.newest_trace(root)
-    if path is None:
+    found = trace.newest_parsed(root)
+    if found is None:
         return None
-    key = (path, os.path.getmtime(path))
-    if key not in _parsed:
-        from jax.profiler import ProfileData
-
-        with open(path, "rb") as f:
-            raw = f.read()
-        _parsed.clear()
-        _parsed[key] = (ProfileData.from_serialized_xspace(raw),
-                        op_scopes(raw))
-    return _parsed[key]
+    return found.profile, found.once("op_scopes", lambda p: op_scopes(p.raw))
 
 
 def per_step_ms(obs, scope: str):

@@ -1,11 +1,15 @@
 """From a profiler trace (jax.profiler.ProfileData) to numbers: the union of
 the intervals in which an operation ran on each device, the idle gaps and
-which of the benchmark's own host spans covered them, the operations that
-took most time, and the time in all-reduce operations.
+which host span covered them (the benchmark's own `bench.*` and, inside
+those, the executors' `executor.*` phases), the operations that took most
+time, and the time in all-reduce operations.
 
 Works on anything shaped like ProfileData: `.planes`, each with `.name` and
 `.lines`, each with `.name` and `.events`, each with `.name`, `.start_ns`
-and `.duration_ns`."""
+and `.duration_ns`.
+
+A traced run's `.xplane.pb` is found and parsed here, once a process
+(`newest_parsed`); step_spans, turnaround and scope_time read from that."""
 
 from __future__ import annotations
 
@@ -18,17 +22,56 @@ OPS_LINE = "XLA Ops"
 # lines of a device plane that repeat the operations at a coarser grain
 COARSE_LINES = ("XLA Modules", "Steps", "XLA TraceMe", "Framework Ops",
                 "Framework Name Scope", "Source code")
-SPAN_PREFIX = "bench."
+# the host annotations kept: the benchmark's own, and the program's phases of
+# a step (paddle_tpu/core/executor.py::run_step), which name an idle gap
+SPAN_PREFIXES = ("bench.", "executor.")
+WINDOW = "bench.window"
+TRACE_ROOT = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "bench_out", "trace")
 COLLECTIVE = re.compile(r"all-reduce|all_reduce|AllReduce", re.I)
 
 
-def newest_xplane(logdir: str) -> str:
-    found = sorted(glob.glob(os.path.join(
-        logdir, "plugins", "profile", "*", "*.xplane.pb")),
-        key=os.path.getmtime)
-    if not found:
-        raise FileNotFoundError(f"no .xplane.pb under {logdir}")
-    return found[-1]
+def newest_trace(root: str | None = None) -> str | None:
+    """The newest .xplane.pb anywhere under `root` (default bench_out/trace,
+    where the harness keeps one a cell and has just written this run's)."""
+    found = glob.glob(os.path.join(root or TRACE_ROOT, "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(found, key=os.path.getmtime) if found else None
+
+
+class Parsed:
+    """One .xplane.pb: its bytes (scope_time reads the operations' metadata
+    from them), the ProfileData built from them, and what each module has
+    reduced from either, each made once (`once`)."""
+
+    def __init__(self, raw: bytes, profile):
+        self.raw, self.profile = raw, profile
+        self._made = {}
+
+    def once(self, what: str, make):
+        if what not in self._made:
+            self._made[what] = make(self)
+        return self._made[what]
+
+
+_parsed = {}  # {(path, mtime): Parsed}: one read and one parse a process
+
+
+def newest_parsed(root: str | None = None) -> Parsed | None:
+    """The newest trace under `root`, read and parsed once a process and
+    file (a file written anew has another mtime); None where none is."""
+    path = newest_trace(root)
+    if path is None:
+        return None
+    key = (path, os.path.getmtime(path))
+    if key not in _parsed:
+        from jax.profiler import ProfileData
+
+        with open(path, "rb") as f:
+            raw = f.read()
+        _parsed.clear()
+        _parsed[key] = Parsed(raw, ProfileData.from_serialized_xspace(raw))
+    return _parsed[key]
 
 
 def start(logdir: str) -> None:
@@ -53,10 +96,17 @@ def op_name(raw: str) -> str:
     return raw.split(" = ", 1)[0].lstrip("%")[:80]
 
 
-def load(logdir: str):
-    from jax.profiler import ProfileData
+def span_name(raw: str) -> str:
+    """What precedes any `#...#` the profiler appends for a span's counts."""
+    return raw.split("#", 1)[0]
 
-    return ProfileData.from_file(newest_xplane(logdir))
+
+def load(logdir: str):
+    """The ProfileData of the newest trace under `logdir`."""
+    found = newest_parsed(logdir)
+    if found is None:
+        raise FileNotFoundError(f"no .xplane.pb under {logdir}")
+    return found.profile
 
 
 def device_ops(profile) -> dict:
@@ -80,18 +130,34 @@ def device_ops(profile) -> dict:
 
 
 def host_spans(profile) -> list:
-    """The benchmark's own TraceAnnotations on the host planes:
-    [(name, start_ns, end_ns)]."""
+    """The TraceAnnotations on the host planes that the benchmark
+    (`bench.*`) and the executors (`executor.*`) made, without the counts
+    the profiler appends to a name: [(name, start_ns, end_ns)]."""
     spans = []
     for plane in profile.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(SPAN_PREFIX):
-                    spans.append((e.name, float(e.start_ns),
+                if e.name.startswith(SPAN_PREFIXES):
+                    spans.append((span_name(e.name), float(e.start_ns),
                                   float(e.start_ns) + float(e.duration_ns)))
     return spans
+
+
+def window(profile, spans=None):
+    """(start_ns, end_ns) of the benchmark's `bench.window`, or None."""
+    outer = [(s, e) for n, s, e in
+             (host_spans(profile) if spans is None else spans) if n == WINDOW]
+    if not outer:
+        return None
+    return min(s for s, _ in outer), max(e for _, e in outer)
+
+
+def first_device_ops(profile, t0: float, t1: float) -> list:
+    """The first device's operations inside [t0, t1], clipped to it."""
+    ops = {d: evs for d, evs in device_ops(profile).items() if evs}
+    return _clip(ops[min(ops)], t0, t1) if ops else []
 
 
 def _union_named(evs) -> list:
@@ -118,7 +184,9 @@ def _clip(evs, t0, t1):
 
 
 def _covering(spans, t: float) -> str:
-    """The innermost (shortest) of the benchmark's spans that covers t."""
+    """The innermost (shortest) of the kept spans that covers t: inside a
+    step the executor's phase (`executor.wait` under `executor.fetch` under
+    `executor.step` under `bench.step`)."""
     best = None
     for name, s, e in spans:
         if s <= t <= e and (best is None or e - s < best[1]):
@@ -128,23 +196,21 @@ def _covering(spans, t: float) -> str:
 
 def reduce(profile, top: int = 10) -> dict:
     """busy_s and window_s (averaged over the devices that ran anything),
-    the `top` operations by device time, the `top` idle-gap groups by what
-    covered them, and all-reduce seconds on the first device.  The window is
-    the span of the benchmark's outermost annotation `bench.window`, else
-    the first to the last device operation."""
+    the `top` operations by device time, the `top` idle-gap groups of the
+    first device by the innermost host span over the gap's middle and the
+    operation before it (`<span>|after:<op>`), and all-reduce seconds on the
+    first device.  The window is the span of the benchmark's outermost
+    annotation `bench.window`, else the first to the last device
+    operation."""
     ops = {d: evs for d, evs in device_ops(profile).items() if evs}
-    spans = host_spans(profile)
-    outer = [(s, e) for n, s, e in spans if n == "bench.window"]
-    if outer:
-        window = (min(s for s, _ in outer), max(e for _, e in outer))
-    elif ops:
-        window = (min(evs[0][1] for evs in ops.values()),
-                  max(e for evs in ops.values() for _, _, e in evs))
     if not ops:
         return {"busy_s": 0.0, "window_s": 0.0, "devices": 0,
                 "device_ops": [], "idle_gaps": [], "collective_s": 0.0,
                 "n_ops": 0}
-    t0, t1 = window
+    spans = host_spans(profile)
+    t0, t1 = window(profile, spans) or (
+        min(evs[0][1] for evs in ops.values()),
+        max(e for evs in ops.values() for _, _, e in evs))
     busy, by_name, n_ops = [], {}, 0
     for d, evs in sorted(ops.items()):
         evs = _clip(evs, t0, t1)

@@ -19,11 +19,12 @@ ends of `executor.step` and `executor.run`:
     run start -> dispatch start entry     prelude, plan, stage
 
 No host timestamp is ever subtracted from a device timestamp, so no skew
-between the two clocks can move any of it (`step_spans`' split intersects
-them, and is good only to the skew).  The one number here that does compare
-the clocks measures just that: the shift the device's timestamps would need
-for every run of the module to begin after its dispatch began and end before
-its wait returned.
+between the two clocks can move any of it (a split that intersects host
+spans with device intervals, as the five `gap_*_ms.train` did until PR 42,
+is good only to the skew).  The one number here that does compare the clocks
+measures just that: the shift the device's timestamps would need for every
+run of the module to begin after its dispatch began and end before its wait
+returned.
 
 Steps are known by `executor.step`'s `seq`, a boundary is two steps with
 consecutive numbers whose `executor.run` spans lie wholly inside
@@ -59,6 +60,7 @@ DISPATCH, WAIT = "executor.dispatch", "executor.wait"
 PARTS = ("copy", "release", "caller", "entry")
 # the phases whose own host durations the detail prints, a step
 PHASES = ("executor.plan", "executor.stage", "executor.dispatch")
+MODULES_LINE = "XLA Modules"
 
 
 def host_steps(spans, t0: float, t1: float) -> list:
@@ -99,7 +101,7 @@ def module_runs(profile) -> list:
             devices.append((int(m.group(1)), [
                 (trace.op_name(e.name), float(e.start_ns),
                  float(e.start_ns) + float(e.duration_ns))
-                for l in plane.lines if l.name == step_spans.MODULES_LINE
+                for l in plane.lines if l.name == MODULES_LINE
                 for e in l.events]))
     evs = min(devices)[1] if devices else []
     total = {}
@@ -182,22 +184,13 @@ def reduce(profile) -> dict | None:
     return out
 
 
-_parsed = {}  # {(path, mtime): the split}: one parse a process
-
-
 def newest(root: str | None = None) -> dict | None:
     """The split of the newest trace under bench_out/trace (the harness
     keeps one a cell and has just written this run's)."""
-    path = step_spans.newest_trace(root)
-    if path is None:
+    found = trace.newest_parsed(root)
+    if found is None:
         return None
-    key = (path, os.path.getmtime(path))
-    if key not in _parsed:
-        from jax.profiler import ProfileData
-
-        _parsed.clear()
-        _parsed[key] = reduce(ProfileData.from_file(path))
-    return _parsed[key]
+    return found.once("turnaround", lambda p: reduce(p.profile))
 
 
 def _traced(obs) -> dict | None:
@@ -235,5 +228,5 @@ if __name__ == "__main__":
     import sys
 
     root = sys.argv[1] if len(sys.argv) > 1 else None
-    print(json.dumps({"trace": step_spans.newest_trace(root),
+    print(json.dumps({"trace": trace.newest_trace(root),
                       "split": newest(root)}))

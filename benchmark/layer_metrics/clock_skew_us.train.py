@@ -1,8 +1,9 @@
 """The shift the device's timestamps need to obey causality in the traced
 window, us: with lo = max over the steps of (dispatch start - module start)
 and hi = min of (wait end - module end), 0 where lo <= 0 <= hi, else the size
-of the nearer of the two.  What `gap_*_ms.train`, which intersect the two
-clocks, are good to in this run (kind train).
+of the nearer of the two.  What anything that sets a host timestamp beside
+a device's (the span that names an idle gap in `breakdown.idle_gaps`) is
+good to in this run (kind train).
 
 A floor and a validity flag, nothing to lower: 0 says the trace is consistent
 as it stands, not that the clocks are aligned.  Where the bounds leave more

@@ -1,5 +1,7 @@
 """The sparse attention's core against the MXU's peak: the FLOPs of the
-CHOSEN keys a step (forward, recomputed forward and backward;
+CHOSEN keys a step (the algorithm's passes: forward 2 products and backward
+5; recomputed work is never counted, so a PR that stops or starts
+recomputing a forward moves the share through the time alone;
 benchmark/configs/keye-vl-2.0-30b-a3b.py::attend_flops_per_step) over the
 device time under the name scope `dsa.attend` and the chip's published bf16
 peak, in % (kind train).  The kept engine computes every causal score block

@@ -1,5 +1,7 @@
 """The expert layers' grouped matmuls against the MXU's peak: their FLOPs a
-step at the expected rows (forward, recomputed forward and backward;
+step at the expected rows (the algorithm's passes: forward 1 and backward 2;
+recomputed work is never counted, so a PR that stops or starts recomputing
+a forward moves the share through the time alone;
 benchmark/configs/moonlight-16b-a3b.py::grouped_matmul_flops_per_step) over
 the device time under the name scope `moe.experts` and the chip's published
 bf16 peak, in % (kind train).  Compute bounds it: a row's 17.3 M multiply-adds

@@ -78,6 +78,11 @@ def main() -> int:
             if obs["end_to_end"].get(name) is not None}
     if args.rehearse:
         line["rehearsal"] = True
+    # each number `correct` compared beside its limit: the run's last lines
+    # on stderr, and the line's last key
+    line["compared"] = res.get("compared", {})
+    for name, (value, limit) in line["compared"].items():
+        sys.stderr.write(f"[bench] compared {name}: {value} limit {limit}\n")
     print(json.dumps(line), flush=True)
     return 3 if args.rehearse else 0
 

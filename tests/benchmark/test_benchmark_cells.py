@@ -50,6 +50,18 @@ def test_cell_rehearses_end_to_end_on_the_cpu(cell, trace):
     assert out.returncode == 3, out.stdout[-2000:] + out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line.pop("rehearsal") is True
+    # each number `correct` compared beside its limit: the line's last key
+    # and the last lines of stderr
+    assert list(line)[-1] == "compared"
+    compared = line.pop("compared")
+    if cell not in PARKED:
+        assert {"loss_rel", "grad_cos_min", "grad_norm_off_1",
+                "param_norm_far", "compiles_in_window"} <= set(compared)
+        assert ("copies_apart" in compared) == (chips > 1)
+    said = out.stderr.strip().splitlines()[-len(compared):] if compared \
+        else []
+    for (name, (value, limit)), text in zip(compared.items(), said):
+        assert text == f"[bench] compared {name}: {value} limit {limit}"
     if trace:
         breakdown = line.pop("breakdown")
         assert set(breakdown) == {"device_ops", "idle_gaps"}

@@ -247,6 +247,177 @@ def test_union_merges_overlaps():
         [(0, 4), (5, 6)]
 
 
+# what the parent of PR 42 read on three fixture traces (busy_s, window_s,
+# collective_s, devices, device_ops; the first device's idle time): keeping
+# the executors' spans beside the benchmark's own names the idle gaps (the
+# keys, largest first) and moves nothing else
+HELD = {
+    "trace_turnaround.textproto": (
+        0.3908, 0.42, 0.0, 1, [["fusion.1", 0.3908]],
+        ["executor.run|after:fusion.1", "executor.step|after:fusion.1",
+         "executor.run|after:window-start"], 0.42 - 0.3908),
+    "trace_moe_scopes.textproto": (
+        9.7e-05, 0.0001, 0.0, 1,
+        [["conditional.2", 3.2e-05], ["conditional.1", 3e-05],
+         ["gmm.1", 1.2e-05], ["tgmm.1", 1.2e-05], ["fusion.5", 1e-05],
+         ["gmm.2", 1e-05], ["fusion.1", 8e-06], ["fusion.4", 8e-06],
+         ["fusion.7", 8e-06], ["fusion.6", 6e-06]],
+        ["executor.step|after:window-start", "bench.window|after:fusion.8"],
+        3e-06),
+    "trace_step_spans.textproto": (
+        2.775e-05, 4e-05, 0.0, 2,
+        [["fusion.1", 2.5e-05], ["fusion.2", 1.75e-06],
+         ["copy-done.6", 1e-06]],
+        ["executor.plan|after:fusion.2", "executor.fetch|after:fusion.1",
+         "executor.stage|after:copy-done.6", "executor.step|after:fusion.1"],
+        2.45e-05),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HELD))
+def test_the_executors_spans_name_the_gaps_and_move_no_other_reading(name):
+    from jax.profiler import ProfileData
+
+    busy, window, coll, devices, ops, gap_keys, idle = HELD[name]
+    red = trace.reduce(ProfileData.from_text_proto(
+        open(os.path.join(DATA, name)).read()))
+    assert red["busy_s"] == pytest.approx(busy, rel=1e-12)
+    assert red["window_s"] == pytest.approx(window, rel=1e-12)
+    assert red["collective_s"] == coll and red["devices"] == devices
+    assert [k for k, _ in red["device_ops"]] == [k for k, _ in ops]
+    for (_, got), (_, want) in zip(red["device_ops"], ops):
+        assert got == pytest.approx(want, rel=1e-12)
+    assert [k for k, _ in red["idle_gaps"]] == gap_keys
+    # the gaps are the first device's, and all of its idle time
+    assert sum(v for _, v in red["idle_gaps"]) == pytest.approx(idle)
+
+
+def test_one_traced_run_is_read_and_parsed_once_for_all_four_modules(
+        trace_root, monkeypatch):
+    """trace.load (the harness's own reduction), step_spans, turnaround and
+    scope_time read the newest .xplane.pb through trace.newest_parsed: the
+    bytes are read once and ProfileData is built from them once, and a file
+    written anew is read anew."""
+    from jax.profiler import ProfileData
+
+    from benchmark.harness import scope_time, step_spans, turnaround
+
+    built = []
+    real = ProfileData.from_serialized_xspace
+    monkeypatch.setattr(ProfileData, "from_serialized_xspace",
+                        lambda raw: built.append(len(raw)) or real(raw))
+    monkeypatch.setattr(ProfileData, "from_file", lambda path: 1 / 0)
+    trace_root("trace_turnaround.textproto", cell="resnet50-train")
+    logdir = os.path.join(trace.TRACE_ROOT, "resnet50-train")
+    profile = trace.load(logdir)
+    assert trace.reduce(profile)["n_ops"] == 4
+    assert step_spans.newest()["steps"] == 4
+    assert turnaround.newest()["boundaries"] == 2
+    prof, scopes = scope_time.newest()
+    assert prof is profile and scopes == {0: {}, 1: {}}
+    assert scope_time.newest()[1] is scopes
+    assert turnaround.newest() is turnaround.newest()
+    assert len(built) == 1
+    # the next traced run of the process writes its file anew
+    path = trace.newest_trace()
+    os.utime(path, (os.path.getmtime(path) + 5,) * 2)
+    assert step_spans.newest()["steps"] == 4
+    assert len(built) == 2 and len(trace._parsed) == 1
+    with pytest.raises(FileNotFoundError):
+        trace.load(os.path.join(trace.TRACE_ROOT, "no-such-cell"))
+
+
+class _FakeExecutable:
+    def __init__(self, temp):
+        self.temp = temp
+
+    def get_compiled_memory_stats(self):
+        if self.temp is None:
+            raise RuntimeError("no statistics for this executable")
+        return types.SimpleNamespace(temp_size_in_bytes=self.temp)
+
+
+class _FakeChip:
+    """A device whose allocator and client say what the test sets."""
+
+    def __init__(self, limit=16_909_000_000):   # a v5e's: 15.75 GiB
+        self.in_use = self.peak = 0
+        self.limit = limit
+        self.executables = []
+        self.client = types.SimpleNamespace(
+            live_executables=lambda: list(self.executables))
+
+    def memory_stats(self):
+        return {"bytes_in_use": self.in_use, "peak_bytes_in_use": self.peak,
+                "bytes_limit": self.limit}
+
+
+GB = 10 ** 9
+
+
+@pytest.mark.parametrize("first,window,want,which", [
+    # state 6.8 + FirstStep's copy 2.3 at the first step; the window holds
+    # the state alone: the first step's sum wins
+    (9.1, 6.8, 9.1 + 5.0, "first"),
+    # something the window keeps beside the state (a staged batch) that the
+    # first step had not yet: the window's sum wins
+    (6.9, 7.4, 7.4 + 5.0, "window"),
+])
+def test_memory_peak_is_a_sum_of_two_numbers_of_one_moment(first, window,
+                                                            want, which):
+    chip = _FakeChip()
+    startup, copies = _FakeExecutable(1 * GB), _FakeExecutable(None)
+    chip.executables = [startup, copies]
+    mem = device.StepMemory([chip])
+    chip.in_use = int(first * GB)
+    mem.before_first_step()
+    # the first step compiles the step's program (5.0 GB of temporaries)
+    # and something small; the plain reference (9.0 GB) comes after it
+    step = _FakeExecutable(5 * GB)
+    chip.executables += [step, _FakeExecutable(2 * 10 ** 6)]
+    mem.after_first_step()
+    chip.executables.append(_FakeExecutable(9 * GB))
+    # the reference's moment: the allocator's peak of the whole run
+    chip.peak = int(13.7 * GB)
+    chip.in_use = int(window * GB)
+    mem.between_window_steps()
+    assert mem.step_temp == 5 * GB
+    assert mem.peak() == pytest.approx(want * GB)
+    assert (mem.first_in_use > mem.window_in_use) == (which == "first")
+    # never the parent's sum of two peaks of different moments, which is
+    # over what the chip holds though the run did not fail
+    old = device.allocator_peak_bytes([chip]) \
+        + device.largest_program_temp_bytes([chip])
+    assert old == pytest.approx(22.7 * GB) and not device.fits(old, chip.limit)
+    assert mem.peak() < old and device.fits(mem.peak(), chip.limit)
+    assert device.memory_limit_bytes([chip]) == chip.limit
+
+
+def test_memory_peak_takes_the_fullest_chip_and_a_backend_without_stats():
+    a, b = _FakeChip(), _FakeChip()
+    mem = device.StepMemory([a, b])
+    a.in_use, b.in_use = 3 * GB, 4 * GB
+    mem.before_first_step()
+    a.executables.append(_FakeExecutable(2 * GB))
+    mem.after_first_step()
+    a.in_use, b.in_use = 3 * GB, 2 * GB
+    mem.between_window_steps()
+    assert (mem.first_in_use, mem.window_in_use) == (4 * GB, 3 * GB)
+    assert mem.peak() == 6 * GB
+    # the CPU of a rehearsal keeps no statistics: the temporaries alone,
+    # no limit, and any reading fits
+    held = [_FakeExecutable(7)]
+    cpu = types.SimpleNamespace(
+        memory_stats=lambda: None, client=types.SimpleNamespace(
+            live_executables=lambda: list(held)))
+    mem = device.StepMemory([cpu])
+    mem.before_first_step()
+    mem.after_first_step()          # nothing new: no step program known
+    mem.between_window_steps()
+    assert mem.peak() == 0 and device.memory_limit_bytes([cpu]) is None
+    assert device.fits(10 ** 12, None)
+
+
 READERS = {
     "compiles_in_window.train": ({"kind": "train", "compiles_in_window": 0},
                                  0.0),

@@ -38,7 +38,7 @@ def test_manifest_has_exactly_the_contracts_keys():
 
 
 @pytest.mark.parametrize("cfg", MANIFEST["configs"], ids=lambda c: c["name"])
-def test_configuration_entry_and_files(cfg):
+def test_configurations_entry_and_its_files(cfg):
     assert set(cfg) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(cfg["name"])
     assert cfg["file"].startswith("benchmark/configs/")
@@ -49,8 +49,10 @@ def test_configuration_entry_and_files(cfg):
     assert data["reduced"] == cfg["reduced"]
     for key in cfg["reduced"]:
         assert NAME.match(key)
-        assert not re.search(r"(_dim|_rank|hidden|d_model|d_inner|head)",
-                             key), f"{key} is a width"
+        # `num_hidden_layers` is the source's own name for the depth
+        assert not re.search(
+            r"(_dim|_rank|hidden_size|d_model|d_inner|head)", key), \
+            f"{key} is a width"
     assert os.path.isfile(os.path.splitext(_path(cfg["file"]))[0] + ".py")
     # its plain reference beside it, and the tolerances it is held to
     if data["kind"] == "train":
@@ -92,6 +94,30 @@ def test_cell_entry_and_files(cell):
     assert "setup_s" in e2e and len(e2e) >= 2
     assert any(cell["name"] in m.get("workloads", [cell["name"]])
                for m in MANIFEST["per_layer"])
+
+
+@pytest.mark.parametrize(
+    "cell,man", [(w["name"], m) for m in [MANIFEST] + PARKED
+                 for w in m["workloads"]], ids=lambda v: v if isinstance(
+                     v, str) else "")
+def test_every_reader_named_for_a_cell_exists_and_reads_nothing_from_nothing(
+        cell, man):
+    """What a configuration's own test file need not repeat: every per-layer
+    metric that names the cell (or names no cell, and so every one) has its
+    reader, which returns nothing where it finds nothing to read."""
+    # conftest.py put the repository on the path
+    from benchmark.harness import manifest
+
+    mine = [m for m in man["per_layer"]
+            if cell in m.get("workloads", [cell])]
+    assert mine, f"{cell} reports no per-layer metric"
+    e2e = {m["name"] for m in man["end_to_end"]
+           if cell in m.get("workloads", [cell])}
+    for m in mine:
+        path = _path("benchmark/layer_metrics", m["name"] + ".py")
+        assert os.path.isfile(path), m["name"]
+        assert manifest.load_py(path).read({}) is None, m["name"]
+        assert m["moves"] in e2e, (m["name"], m["moves"])
 
 
 def test_cells_are_distinct_and_few_take_four_chips():

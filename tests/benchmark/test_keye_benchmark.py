@@ -17,7 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark.harness import manifest, reference, scope_time, step_spans
+from benchmark.harness import manifest, reference
 from benchmark.harness.device import peaks
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -49,9 +49,7 @@ NEW_READERS = {"dsa_index_ms.train": "dsa.index",
                "dsa_kl_ms.train": "dsa.kl"}
 ROOFLINE = "dsa_attend_roofline.train"
 APPENDED = {"compiles_in_window.train", "mfu.train", "device_idle.train",
-            "gap_plan_ms.train", "gap_stage_ms.train",
-            "gap_dispatch_ms.train", "gap_fetch_ms.train",
-            "gap_unattributed_ms.train", "values_moved_per_step.train",
+            "values_moved_per_step.train",
             "loop_bodies_lowered.train", "hbm_peak_gb.train",
             "moe_experts_ms.train", "moe_dispatch_ms.train"}
 
@@ -100,11 +98,13 @@ def test_file_holds_the_published_config_and_cuts_three_counts_alone():
         "Keye-VL-2.0-30B-A3B/blob/main/config.json")
 
 
-def test_configuration_entry_and_files():
-    """Everything test_benchmark_manifest.py::test_configuration_entry_and_
-    files asks, with the width expression held to widths: `hidden_size`,
-    not the `hidden` of num_hidden_layers (tests/conftest.py)."""
-    entry = [c for c in MANIFEST["configs"] if c["name"] == CONFIG][0]
+def test_the_configurations_entry_and_files_are_there(manifest_holds):
+    """The configuration's entry and its cell's, each there once (whatever a
+    later PR appends behind them), the file with what a `train`
+    configuration states, and no width among the cuts."""
+    entry, = manifest_holds("configs", [CONFIG])
+    manifest_holds("workloads", [CELL], config=CONFIG, chips=1,
+                   traffic="train-steady")
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
     data = _config()
@@ -126,8 +126,6 @@ def test_configuration_entry_and_files():
     cells = [w for w in MANIFEST["workloads"] if w["config"] == CONFIG]
     assert [w["name"] for w in cells] == [CELL]
     assert cells[0]["chips"] == 1 and cells[0]["traffic"] == "train-steady"
-    assert MANIFEST["workloads"][-1] is cells[0]       # added at the end
-    assert MANIFEST["configs"][-1] is entry
     for text in (entry["why"], entry["source"], cells[0]["why"]):
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
     # what ISSUE 33 asked of the cell: one sequence of 16384, the depth the
@@ -160,16 +158,29 @@ def test_flops_are_counted_from_the_shapes():
     assert mod.keys_selected(cfg) / mod.keys_causal(cfg) == pytest.approx(
         0.234, abs=1e-3)
     assert 4 * attend / mod.flops_per_sample(cfg) > 0.25
-    # the attention's core a step: forward, recomputed forward (2 products
-    # each) and backward (5) over the chosen keys, 4 layers
-    pair = 2 * 32 * 128
-    assert mod.attend_flops_per_step(cfg, 1) == pytest.approx(
-        9 * pair * 31_458_304 * 4)
-    assert mod.attend_flops_per_step(
-        {**cfg, "use_recompute": False}, 2) == pytest.approx(
-        7 * pair * 31_458_304 * 4 * 2)
-    assert mod.grouped_matmul_flops_per_step(cfg, S) == pytest.approx(
-        4 * 2 * 1.0 * S * 3 * 2048 * 768 * 4)
+
+
+@pytest.mark.parametrize("use_recompute", [True, False])
+def test_the_attentions_core_counts_the_algorithms_seven_products(
+        use_recompute):
+    """The cell's real shape, by hand: 7 block products (forward q.k and
+    p.v, backward the scores again, dP, dV, dK, dQ) = 7/2 x a pair's forward
+    FLOPs (2 products x 2 FLOPs x 32 heads x 128 = 16384) x the 31 458 304
+    chosen keys of a 16384 sequence x 4 layers = 7.2158e12 a step, whatever
+    the program recomputes."""
+    mod = _module()
+    cfg = {**_config(), "use_recompute": use_recompute}
+    assert mod.attend_flops_per_pair(cfg) == 16384.0
+    assert mod.keys_selected(cfg) == 2048 * 2049 // 2 + 14336 * 2048 \
+        == 31_458_304
+    got = mod.attend_flops_per_step(cfg, 1)
+    assert got == 3.5 * 16384.0 * 31_458_304 * 4 == 7215779938304.0
+    assert mod.attend_flops_per_step(cfg, 2) == 2 * got
+    # the old rule (9 products, one forward recomputed) read 9/7 of it
+    assert got * 9 / 7 == pytest.approx(9.2774e12, rel=1e-4)
+    # and the expert block's grouped matmuls: 3 passes at 1.0 rows a token
+    assert mod.grouped_matmul_flops_per_step(cfg, 16384) == \
+        3 * 2 * 1.0 * 16384 * 3 * 2048 * 768 * 4
 
 
 def test_batch_is_packed_text_over_the_slice_and_the_seeds():
@@ -217,26 +228,6 @@ def test_the_rehearsals_first_step_is_the_references():
 # ---------------------------------------------------------------------------
 # the readers
 # ---------------------------------------------------------------------------
-def _xspace(name):
-    from jax.profiler import ProfileData
-
-    return ProfileData.text_proto_to_serialized_xspace(
-        open(os.path.join(DATA, name)).read())
-
-
-@pytest.fixture
-def trace_root(tmp_path, monkeypatch):
-    def write(name, cell=CELL):
-        d = tmp_path / cell / "plugins" / "profile" / "2026_01_01"
-        d.mkdir(parents=True, exist_ok=True)
-        (d / "vm.xplane.pb").write_bytes(_xspace(name))
-
-    monkeypatch.setattr(step_spans, "TRACE_ROOT", str(tmp_path))
-    scope_time._parsed.clear()
-    yield write
-    scope_time._parsed.clear()
-
-
 OBS = {"kind": "train", "trace_steps": 2, "trace": {"n_ops": 16},
        "platform": "tpu", "device_kind": "TPU v5 lite",
        "samples_per_step": 1}
@@ -285,34 +276,27 @@ def test_a_program_without_the_scopes_reports_nothing(name, trace,
     assert _reader(name).read(OBS) is None
 
 
-def test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone():
-    new = set(NEW_READERS) | {ROOFLINE}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert new <= set(entries)
-    assert [m["name"] for m in MANIFEST["per_layer"][-5:]] == [
-        "dsa_index_ms.train", "dsa_select_ms.train", "dsa_attend_ms.train",
-        "dsa_kl_ms.train", ROOFLINE]
-    for name in new:
-        assert set(entries[name]) == {"name", "unit", "better", "source",
-                                      "layer", "moves", "workloads"}
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "train_samples_per_s"
-        assert entries[name]["layer"] == "training kernels"
-        assert entries[name]["source"] == "device_trace"
-    assert entries[ROOFLINE]["unit"] == "%"
-    assert entries[ROOFLINE]["better"] == "higher"
-    # the cell is appended to the generic .train readers and to the expert
-    # layer's two scope readers, and to nothing else the benchmark had
-    for name, m in entries.items():
-        if name in new:
-            continue
-        assert (CELL in m.get("workloads", [])) == (name in APPENDED), name
-        if name in APPENDED:
-            assert m["workloads"][-1] == CELL
+def test_the_cells_readers_are_in_the_manifest(manifest_holds):
+    """This file's entries are there, in their own order, with at least this
+    cell; what stands behind them, and what other cells report, is theirs to
+    say (conftest.py)."""
+    entries = manifest_holds(
+        "per_layer", ["dsa_index_ms.train", "dsa_select_ms.train",
+                      "dsa_attend_ms.train", "dsa_kl_ms.train", ROOFLINE],
+        cells=[CELL], moves="train_samples_per_s", layer="training kernels",
+        source="device_trace")
+    for m in entries:
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert entries[-1]["unit"] == "%" and entries[-1]["better"] == "higher"
+    # the generic .train readers and the expert block's two name the cell
+    for name in sorted(APPENDED):
+        manifest_holds("per_layer", [name], cells=[CELL])
     cell = manifest.Cell(MANIFEST, CELL)
-    assert {m["name"] for m in cell.metrics("per_layer")} == new | APPENDED
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_samples_per_s", "setup_s"]
+    mine = {m["name"] for m in cell.metrics("per_layer")}
+    assert set(NEW_READERS) | {ROOFLINE} | APPENDED <= mine
+    assert {"train_samples_per_s", "setup_s"} <= {
+        m["name"] for m in cell.metrics("end_to_end")}
     assert cell.chips == 1 and cell.sizing["per_chip_batch"] == 1
 
 
@@ -336,43 +320,3 @@ def test_bodies_lowered_reads_one_lowering_of_every_layers_body():
     assert _reader("loop_bodies_lowered.train").read(
         {"kind": "train", "samples_per_step": 1, "chips": 1,
          "platform": "cpu"}) == 1
-
-
-def test_what_pr_31s_manifest_tests_held_for_their_cells_still_holds():
-    """test_moonlight_benchmark.py's two manifest tests are expected
-    failures since this PR (they pin the expert block's two scope readers
-    to moonlight-train-ep8share alone and count every later cell's readers
-    against the older cells: tests/conftest.py): every assertion of them
-    that a later cell does not touch."""
-    mine = set(NEW_READERS) | {ROOFLINE}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    moonlight = "moonlight-train-ep8share"
-    for name in ("mla_ms.train", "moe_shared_ms.train",
-                 "moe_experts_roofline.train"):
-        assert entries[name]["workloads"] == [moonlight]
-    for name in ("moe_experts_ms.train", "moe_dispatch_ms.train"):
-        assert entries[name]["workloads"] == [moonlight, CELL]
-    for name in ("mla_ms.train", "moe_shared_ms.train",
-                 "moe_experts_roofline.train", "moe_experts_ms.train",
-                 "moe_dispatch_ms.train"):
-        assert entries[name]["moves"] == "train_samples_per_s"
-        assert entries[name]["layer"] == "training kernels"
-        assert entries[name]["source"] == "device_trace"
-    for name in ("hbm_peak_gb.train", "loop_bodies_lowered.train"):
-        assert entries[name]["workloads"] == ["ouro-train-loop4", moonlight,
-                                              CELL]
-    train = {m["name"] for m in MANIFEST["per_layer"]
-             if m["name"].endswith(".train")}
-    for cell_name, without in (
-            (moonlight, {"collective_ms.train", "loop_body_ms.train",
-                         "loop_heads_ms.train"}),
-            ("ouro-train-loop4", {
-                "collective_ms.train", "mla_ms.train", "moe_shared_ms.train",
-                "moe_experts_roofline.train", "moe_experts_ms.train",
-                "moe_dispatch_ms.train"})):
-        cell = manifest.Cell(MANIFEST, cell_name)
-        reported = {m["name"] for m in cell.metrics("per_layer")}
-        assert train - reported == without | mine, cell_name
-        assert [m["name"] for m in cell.metrics("end_to_end")] == [
-            "train_samples_per_s", "setup_s"]
-        assert cell.chips == 1

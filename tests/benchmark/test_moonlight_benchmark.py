@@ -17,7 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark.harness import manifest, reference, scope_time, step_spans
+from benchmark.harness import manifest, reference
 from benchmark.harness.device import peaks
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -139,13 +139,22 @@ def test_flops_are_counted_from_the_shapes():
         S * (6.0 * matmul + attn))
     # ISSUE 31's count: 1.97 GFLOP a token trained
     assert mod.flops_per_sample(cfg) / S == pytest.approx(1.97e9, rel=2e-3)
-    # the grouped matmuls: forward, recomputed forward and two backward
-    # products over 4 expert layers at 0.75 rows a token
-    assert mod.grouped_matmul_flops_per_step(cfg, 8192) == pytest.approx(
-        4 * 2 * 0.75 * 8192 * 3 * 2048 * 1408 * 4)
-    assert mod.grouped_matmul_flops_per_step(
-        {**cfg, "use_recompute": False}, 8192) == pytest.approx(
-        3 * 2 * 0.75 * 8192 * 3 * 2048 * 1408 * 4)
+
+
+@pytest.mark.parametrize("use_recompute", [True, False])
+def test_the_grouped_matmuls_count_the_algorithms_three_passes(
+        use_recompute):
+    """The cell's real shape, by hand: 3 passes (forward 1, backward 2:
+    input and weight gradient) x 2 FLOPs a multiply-add x the expected rows
+    (0.75 a token of 4 x 2048) x a row's 3 x 2048 x 1408 parameters x 4
+    expert layers = 1.2756e12 a step, whatever the program recomputes."""
+    cfg = {**_config(), "use_recompute": use_recompute}
+    assert cfg["num_hidden_layers"] - cfg["first_k_dense_replace"] == 4
+    assert _module().expert_matmul_params(cfg) == 3 * 2048 * 1408 == 8650752
+    got = _module().grouped_matmul_flops_per_step(cfg, 4 * 2048)
+    assert got == 3 * 2 * (0.75 * 8192) * 8650752 * 4 == 1275605286912.0
+    # the old rule (a fourth, recomputed pass) read 4/3 of it
+    assert got * 4 / 3 == pytest.approx(1.7008e12, rel=1e-4)
 
 
 def test_batch_is_packed_shifted_over_the_slice_and_the_seeds():
@@ -233,27 +242,6 @@ def test_the_mutants_are_the_probes_and_an_unknown_one_is_an_error():
 
 # ---------------------------------------------------------------------------
 # the readers
-# ---------------------------------------------------------------------------
-def _xspace(name):
-    from jax.profiler import ProfileData
-
-    return ProfileData.text_proto_to_serialized_xspace(
-        open(os.path.join(DATA, name)).read())
-
-
-@pytest.fixture
-def trace_root(tmp_path, monkeypatch):
-    def write(name, cell=CELL):
-        d = tmp_path / cell / "plugins" / "profile" / "2026_01_01"
-        d.mkdir(parents=True, exist_ok=True)
-        (d / "vm.xplane.pb").write_bytes(_xspace(name))
-
-    monkeypatch.setattr(step_spans, "TRACE_ROOT", str(tmp_path))
-    scope_time._parsed.clear()
-    yield write
-    scope_time._parsed.clear()
-
-
 OBS = {"kind": "train", "trace_steps": 2, "trace": {"n_ops": 15},
        "platform": "tpu", "device_kind": "TPU v5 lite",
        "samples_per_step": 4}
@@ -296,61 +284,30 @@ def test_a_program_without_the_scopes_reports_nothing(name, trace,
     assert _reader(name).read(OBS) is None
 
 
-def test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone():
-    new = set(NEW_READERS) | {ROOFLINE}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert new <= set(entries)
-    for name in new:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "train_samples_per_s"
-        assert entries[name]["layer"] == "training kernels"
-        assert entries[name]["source"] == "device_trace"
-    assert entries[ROOFLINE]["unit"] == "%"
-    # the cell reports every .train metric the benchmark had but the
-    # collectives' (one chip) and the two that price looped_decoder.py's
-    # scopes; hbm_peak_gb.train and loop_bodies_lowered.train (every layer
-    # is a `recurrence`, the unit of recomputation) are ouro's and this
-    # cell's
+def test_the_cells_readers_are_in_the_manifest(manifest_holds):
+    """This file's entries are there, in their own order, with at least this
+    cell; what other cells report, and what stands behind these, is theirs
+    to say (conftest.py)."""
+    entries = manifest_holds(
+        "per_layer", ["mla_ms.train", "moe_experts_ms.train",
+                      "moe_dispatch_ms.train", "moe_shared_ms.train",
+                      ROOFLINE],
+        cells=[CELL], moves="train_samples_per_s", layer="training kernels",
+        source="device_trace")
+    assert entries[-1]["unit"] == "%" and entries[-1]["better"] == "higher"
+    # the readers the cell shares with older cells name it too
+    manifest_holds("per_layer", ["hbm_peak_gb.train"], cells=[CELL])
+    manifest_holds("per_layer", ["loop_bodies_lowered.train"], cells=[CELL])
     cell = manifest.Cell(MANIFEST, CELL)
     mine = {m["name"] for m in cell.metrics("per_layer")}
-    had = {m["name"] for m in MANIFEST["per_layer"]
-           if m["name"].endswith(".train")} - new
-    assert had - mine == {"collective_ms.train", "loop_body_ms.train",
-                          "loop_heads_ms.train"}
-    for name in ("hbm_peak_gb.train", "loop_bodies_lowered.train"):
-        assert entries[name]["workloads"] == ["ouro-train-loop4", CELL]
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_samples_per_s", "setup_s"]
+    assert set(NEW_READERS) | {ROOFLINE, "mfu.train", "device_idle.train",
+                               "compiles_in_window.train"} <= mine
+    # one chip, and none of looped_decoder.py's scopes
+    assert not {"collective_ms.train", "loop_body_ms.train",
+                "loop_heads_ms.train"} & mine
+    assert {"train_samples_per_s", "setup_s"} <= {
+        m["name"] for m in cell.metrics("end_to_end")}
     assert cell.chips == 1 and cell.sizing["per_chip_batch"] == 4
-
-
-def test_what_pr_27s_manifest_test_held_for_its_cell_still_holds():
-    """test_ouro_benchmark.py::test_every_new_reader_is_in_the_manifest_for_
-    the_new_cell_alone is an expected failure since this PR (it pins
-    hbm_peak_gb.train to its cell alone: tests/conftest.py): every
-    assertion of it that a later cell does not touch, for ouro-train-loop4."""
-    ouro_cell = "ouro-train-loop4"
-    its = {"loop_body_ms.train", "loop_heads_ms.train",
-           "loop_bodies_lowered.train", "hbm_peak_gb.train"}
-    later = set(NEW_READERS) | {ROOFLINE}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert its <= set(entries)
-    for name in its:
-        assert entries[name]["workloads"][0] == ouro_cell
-        assert entries[name]["moves"] == "train_samples_per_s"
-    for name in ("loop_body_ms.train", "loop_heads_ms.train"):
-        assert entries[name]["workloads"] == [ouro_cell]
-    # and the cell reports every .train metric the benchmark had, but the
-    # collectives' (one chip), and none of a later cell's own
-    cell = manifest.Cell(MANIFEST, ouro_cell)
-    mine = {m["name"] for m in cell.metrics("per_layer")}
-    had = {m["name"] for m in MANIFEST["per_layer"]
-           if m["name"].endswith(".train")} - its - later
-    assert had - mine == {"collective_ms.train"}
-    assert its <= mine and not later & mine
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_samples_per_s", "setup_s"]
-    assert cell.chips == 1 and cell.sizing["per_chip_batch"] == 2
 
 
 def test_bodies_lowered_reads_one_lowering_of_every_layers_body():

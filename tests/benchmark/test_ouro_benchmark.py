@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark.harness import manifest, reference, scope_time, step_spans
+from benchmark.harness import manifest, reference, scope_time
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 MANIFEST = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
@@ -193,19 +193,6 @@ def _xspace(name="trace_loop_scopes.textproto"):
         open(os.path.join(DATA, name)).read())
 
 
-@pytest.fixture
-def trace_root(tmp_path, monkeypatch):
-    def write(name, cell=CELL):
-        d = tmp_path / cell / "plugins" / "profile" / "2026_01_01"
-        d.mkdir(parents=True, exist_ok=True)
-        (d / "vm.xplane.pb").write_bytes(_xspace(name))
-
-    monkeypatch.setattr(step_spans, "TRACE_ROOT", str(tmp_path))
-    scope_time._parsed.clear()
-    yield write
-    scope_time._parsed.clear()
-
-
 def test_scope_paths_come_from_the_metadata_of_each_device_plane():
     scopes = scope_time.op_scopes(_xspace())
     assert sorted(scopes) == [0, 1]
@@ -258,6 +245,12 @@ def test_a_program_without_the_scopes_reports_nothing(name, trace,
 def test_hbm_peak_reader():
     reader = _reader("hbm_peak_gb.train")
     assert reader.read({"kind": "train", "memory_peak_bytes": 12.5e9}) == 12.5
+    # a reading over what the chip holds is no reading; at it, it is one
+    at = {"kind": "train", "memory_limit_bytes": 16.909e9}   # 15.75 GiB
+    assert reader.read({**at, "memory_peak_bytes": 16.909e9}) == 16.909
+    assert reader.read({**at, "memory_peak_bytes": 18.7e9}) is None
+    assert reader.read({"kind": "train", "memory_limit_bytes": None,
+                        "memory_peak_bytes": 18.7e9}) == 18.7
     assert reader.read({}) is None
     assert reader.read({"kind": "train", "memory_peak_bytes": 0}) is None
     assert reader.read({"kind": "serve", "memory_peak_bytes": 1e9}) is None
@@ -289,21 +282,18 @@ def test_bodies_lowered_reader_lowers_the_program_again_and_reads_the_span():
     assert reader.read(obs) is None
 
 
-def test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone():
-    new = {"loop_body_ms.train", "loop_heads_ms.train",
-           "loop_bodies_lowered.train", "hbm_peak_gb.train"}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert new <= set(entries)
-    for name in new:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "train_samples_per_s"
-    # and the cell reports every .train metric the benchmark had, but the
-    # collectives' (one chip)
+def test_the_cells_readers_are_in_the_manifest(manifest_holds):
+    """This file's entries are there, in their own order, with at least this
+    cell; what later cells report is theirs to say (conftest.py)."""
+    manifest_holds("per_layer", ["loop_body_ms.train", "loop_heads_ms.train",
+                                 "loop_bodies_lowered.train",
+                                 "hbm_peak_gb.train"],
+                   cells=[CELL], moves="train_samples_per_s")
     cell = manifest.Cell(MANIFEST, CELL)
     mine = {m["name"] for m in cell.metrics("per_layer")}
-    had = {m["name"] for m in MANIFEST["per_layer"]
-           if m["name"].endswith(".train")} - new
-    assert had - mine == {"collective_ms.train"}
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_samples_per_s", "setup_s"]
+    assert {"compiles_in_window.train", "mfu.train", "device_idle.train",
+            "values_moved_per_step.train"} <= mine
+    assert "collective_ms.train" not in mine          # one chip
+    assert {"train_samples_per_s", "setup_s"} <= {
+        m["name"] for m in cell.metrics("end_to_end")}
     assert cell.chips == 1 and cell.sizing["per_chip_batch"] == 2

@@ -1,7 +1,7 @@
 """The step's turnaround on clocks that cannot disagree
 (benchmark/harness/turnaround.py) and the seven readers on top of it, held
-exactly on a hand-made trace; and that a shift of the device's clock, which
-moves step_spans' split of the same trace, moves none of it."""
+exactly on a hand-made trace; and that a shift of the device's clock moves
+none of it but the skew."""
 
 import json
 import os
@@ -68,25 +68,6 @@ def _reader(name):
         REPO, "benchmark", "layer_metrics", name + ".py"))
 
 
-@pytest.fixture
-def trace_root(tmp_path, monkeypatch):
-    """bench_out/trace as the harness leaves it: one .xplane.pb a cell."""
-    from jax.profiler import ProfileData
-
-    def write(text, cell="resnet50-train"):
-        d = tmp_path / cell / "plugins" / "profile" / "2026_01_01"
-        d.mkdir(parents=True, exist_ok=True)
-        (d / "vm.xplane.pb").write_bytes(
-            ProfileData.text_proto_to_serialized_xspace(text))
-
-    monkeypatch.setattr(step_spans, "TRACE_ROOT", str(tmp_path))
-    turnaround._parsed.clear()
-    step_spans._parsed.clear()
-    yield write
-    turnaround._parsed.clear()
-    step_spans._parsed.clear()
-
-
 def test_every_part_is_held_exactly():
     red = turnaround.reduce(_profile())
     assert red["steps"] == 3 and red["boundaries"] == 2
@@ -143,35 +124,6 @@ def test_a_shift_of_the_devices_clock_moves_the_skew_and_nothing_else(
     assert _reader(SKEW).read(OBS) == skew_us
     for reader, part in READERS.items():
         assert _reader(reader).read(OBS) == base[part + "_ns"] / 1e6
-
-
-@pytest.mark.parametrize("shift_us", [2000, -2000])
-def test_the_same_shift_moves_the_split_that_intersects_the_clocks(
-        shift_us, trace_root):
-    """gap_dispatch_ms.train and the idle time under the fetch (the wait and
-    the copy are its children, so step_spans puts a piece under them) on
-    the same three traces: both walk with the shift."""
-    def gaps(shift):
-        trace_root(_text(device_shift_us=shift))
-        turnaround._parsed.clear()
-        step_spans._parsed.clear()
-        by = step_spans.newest()["by_span"]
-        return (_reader("gap_dispatch_ms.train").read(OBS),
-                _reader("gap_fetch_ms.train").read(OBS),
-                sum(by.get("executor." + n, 0.0)
-                    for n in ("fetch", "wait", "copy")) / 1e6 / 4)
-
-    dispatch0, fetch0, under_fetch0 = gaps(0)
-    dispatch, fetch, under_fetch = gaps(shift_us)
-    assert dispatch0 == pytest.approx(2.0 / 4)   # 600 + 500 + 500 + 400 us
-    assert under_fetch0 == pytest.approx((1200 + 1600 + 1600 + 1100) / 4e3)
-    if shift_us > 0:   # the device late: it idles while the host dispatches
-        assert dispatch == pytest.approx((800 + 700 + 500 + 400) / 4e3)
-        assert under_fetch == 0.0 and fetch == 0.0 < fetch0
-    else:              # early: no idle under a dispatch at all
-        assert dispatch == 0.0
-        assert under_fetch == pytest.approx(
-            (1300 + 1900 + 1400 + 1500) / 4e3)
 
 
 def test_the_edge_gaps_are_left_out():
@@ -252,72 +204,37 @@ def test_the_newest_trace_is_parsed_once(trace_root, monkeypatch):
     assert first["boundaries"] == 2
     from jax.profiler import ProfileData
 
-    monkeypatch.setattr(ProfileData, "from_file", lambda path: 1 / 0)
+    monkeypatch.setattr(ProfileData, "from_serialized_xspace",
+                        lambda raw: 1 / 0)
     assert turnaround.newest() is first
 
 
-def test_the_seven_readers_are_the_manifests_last_entries():
-    new = list(READERS) + [SKEW]
-    entries = MANIFEST["per_layer"][-7:]
-    assert sorted(m["name"] for m in entries) == sorted(new)
-    assert entries[0]["name"] == "turnaround_host_ms.train"
-    assert entries[-1]["name"] == SKEW
+def test_the_seven_readers_are_in_the_manifest_for_every_training_cell(
+        manifest_holds):
+    entries = manifest_holds(
+        "per_layer", list(READERS) + [SKEW], cells=TRAIN_CELLS,
+        better="lower", source="program_span", layer="program to step",
+        moves="train_samples_per_s")
     for m in entries:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["unit"] == ("us" if m["name"] == SKEW else "ms")
-        assert m["better"] == "lower" and m["source"] == "program_span"
-        assert m["layer"] == "program to step"
-        assert m["moves"] == "train_samples_per_s"
-        assert m["workloads"] == TRAIN_CELLS
         assert os.path.isfile(os.path.join(
             REPO, "benchmark", "layer_metrics", m["name"] + ".py"))
-    # nothing else moved: the five gap readers are where they were
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[4:9] == ["gap_plan_ms.train", "gap_stage_ms.train",
-                          "gap_dispatch_ms.train", "gap_fetch_ms.train",
-                          "gap_unattributed_ms.train"]
     for cell in TRAIN_CELLS:
         reported = {m["name"] for m in
                     manifest.Cell(MANIFEST, cell).metrics("per_layer")}
-        assert set(new) <= reported, cell
+        assert set(READERS) | {SKEW} <= reported, cell
 
 
-def test_what_pr_33s_manifest_test_held_for_its_cell_still_holds():
-    """test_keye_benchmark.py's
-    test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone is an
-    expected failure since this PR (it pins the manifest's last five
-    entries and the cell's whole reader set: tests/conftest.py): every
-    assertion of it, with the seven turnaround readers behind its five and
-    among what the cell reports."""
-    keye = manifest.load_py(os.path.join(
-        REPO, "tests", "benchmark", "test_keye_benchmark.py"))
-    mine = set(READERS) | {SKEW}
-    new = set(keye.NEW_READERS) | {keye.ROOFLINE}
-    entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert new <= set(entries)
-    assert [m["name"] for m in MANIFEST["per_layer"][-12:-7]] == [
-        "dsa_index_ms.train", "dsa_select_ms.train", "dsa_attend_ms.train",
-        "dsa_kl_ms.train", keye.ROOFLINE]
-    for name in new:
-        assert set(entries[name]) == {"name", "unit", "better", "source",
-                                      "layer", "moves", "workloads"}
-        assert entries[name]["workloads"] == [keye.CELL]
-        assert entries[name]["moves"] == "train_samples_per_s"
-        assert entries[name]["layer"] == "training kernels"
-        assert entries[name]["source"] == "device_trace"
-    assert entries[keye.ROOFLINE]["unit"] == "%"
-    assert entries[keye.ROOFLINE]["better"] == "higher"
-    for name, m in entries.items():
-        if name in new or name in mine:
-            continue
-        assert (keye.CELL in m.get("workloads", [])) == (
-            name in keye.APPENDED), name
-        if name in keye.APPENDED:
-            assert m["workloads"][-1] == keye.CELL
-    cell = manifest.Cell(MANIFEST, keye.CELL)
-    assert {m["name"] for m in cell.metrics("per_layer")} == (
-        new | keye.APPENDED | mine)
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_samples_per_s", "setup_s"]
-    assert cell.chips == 1 and cell.sizing["per_chip_batch"] == 1
+def test_the_split_that_intersected_the_clocks_is_gone():
+    """The five `gap_*_ms.train` set host spans beside device intervals and
+    walked with the skew (PERF.md 6, PR 35); PR 42 retired them, entries,
+    readers and the code under them."""
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert not [n for n in names if n.startswith("gap_")]
+    readers = os.listdir(os.path.join(REPO, "benchmark", "layer_metrics"))
+    assert not [f for f in readers if f.startswith("gap_")]
+    for gone in ("gap_ms", "split_idle", "idle_intervals", "clock_check",
+                 "innermost_segments", "first_device_ops"):
+        assert not hasattr(step_spans, gone), gone
