@@ -26,6 +26,7 @@ __all__ = [
     "fused_attention",
     "rotary_embedding",
     "latent_attention",
+    "compressed_conv_qkv",
     "sparse_attention",
     "moe_router",
     "moe_experts",
@@ -1247,7 +1248,7 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
 
 
 def rotary_embedding(x, base=10000.0, offset=0, positions=None,
-                     sections=None, name=None, yarn=None):
+                     sections=None, name=None, yarn=None, rotary_dim=None):
     """Rotary position embedding of x [..., S, D] (heads first, then
     positions, then the head's features), half-split pairs, position
     offset + index along axis -2, angles in fp32.  With `positions`
@@ -1255,12 +1256,16 @@ def rotary_embedding(x, base=10000.0, offset=0, positions=None,
     D]: the pairs of section j turn by position stream j (multi-axis
     rotary).  With `yarn` (a dict of factor, original_length, beta_fast,
     beta_slow, attention_factor): YaRN's scaled frequencies, cos and sin
-    times attention_factor (TPU-native; see ops/attention_ops.py
+    times attention_factor.  With `rotary_dim` < D the first `rotary_dim`
+    features of a head turn, as a head of that width would, and the others
+    pass (a partial rotary factor) (TPU-native; see ops/attention_ops.py
     rotary_embedding)."""
     helper = LayerHelper("rotary_embedding", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}
     attrs = {"base": float(base), "offset": int(offset)}
+    if rotary_dim:
+        attrs["rotary_dim"] = int(rotary_dim)
     if yarn:
         from ..ops.attention_ops import YARN_KEYS
 
@@ -1320,16 +1325,50 @@ def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
     return out
 
 
+def compressed_conv_qkv(q, k, v, conv_a_w, conv_a_b, conv_b_w, conv_b_b,
+                        tau, heads, kv_heads, rotary_dim=None,
+                        rope_base=10000.0, name=None):
+    """Compressed convolutional attention's sequence mixing, between the
+    down-projections q [B, S, H D], k, v [B, S, G D] of a layer's input and
+    the scores: two causal convolutions along the sequence over [q ; k]
+    (conv_a_w [k0, C] and conv_a_b [C], one filter a channel; conv_b_w [k1,
+    H + G, D, D] and conv_b_b [C], across the channels of one head; padded
+    once on the left), the q-k mean of the unconvolved values added after,
+    each head normalised to length sqrt(D), the keys times tau [G], rotary
+    on the first `rotary_dim` features of a head, and the second half of
+    v's channels taken from the token before.  Returns (q [B, H, S, D], k
+    and v [B, G, S, D]) for fused_attention (TPU-native;
+    ops/attention_ops.py compressed_conv_qkv)."""
+    helper = LayerHelper("compressed_conv_qkv", input=q, name=name)
+    outs = [helper.create_variable_for_type_inference(q.dtype)
+            for _ in range(3)]
+    attrs = {"heads": int(heads), "kv_heads": int(kv_heads),
+             "rope_base": float(rope_base)}
+    if rotary_dim:
+        attrs["rotary_dim"] = int(rotary_dim)
+    helper.append_op(
+        type="compressed_conv_qkv",
+        inputs={"Q": [q], "K": [k], "V": [v], "ConvAW": [conv_a_w],
+                "ConvAB": [conv_a_b], "ConvBW": [conv_b_w],
+                "ConvBB": [conv_b_b], "Tau": [tau]},
+        outputs={"QOut": [outs[0]], "KOut": [outs[1]], "VOut": [outs[2]]},
+        attrs=attrs,
+    )
+    return tuple(outs)
+
+
 def moe_router(x, weight, bias, top_k, scaling=1.0, norm_topk_prob=True,
-               scoring="sigmoid", name=None):
+               scoring="sigmoid", name=None, carried=0):
     """Router over all the experts weight [d, E] has, its scores the
     sigmoid of x.weight or, under `scoring` "softmax", the softmax over
     the E experts: (the top_k experts of score + bias a token [..., k]
     int32, their weights [..., k]
     fp32: the scores without the bias, normalised over the chosen and
     times `scaling`, the tokens that chose each expert [E]).  `bias` is
-    state without a gradient, or None for a router that has none
-    (TPU-native; ops/moe_ops.py)."""
+    state without a gradient, or None for a router that has none.
+    `carried` labels the site's `router.lower` span with the width of the
+    state a router's network took from the layer before (0: none); it
+    changes no number (TPU-native; ops/moe_ops.py)."""
     helper = LayerHelper("moe_router", input=x, name=name)
     idx = helper.create_variable_for_type_inference("int32",
                                                     stop_gradient=True)
@@ -1343,7 +1382,8 @@ def moe_router(x, weight, bias, top_k, scaling=1.0, norm_topk_prob=True,
         outputs={"TopIdx": [idx], "TopWeight": [top_w], "Load": [load]},
         attrs={"top_k": int(top_k), "scaling": float(scaling),
                "norm_topk_prob": bool(norm_topk_prob),
-               "scoring": str(scoring)},
+               "scoring": str(scoring), "carried": int(carried),
+               "trained": bool(getattr(weight, "trainable", True))},
     )
     return idx, top_w, load
 

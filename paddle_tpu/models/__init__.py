@@ -20,6 +20,8 @@ from .expert_decoder import expert_decoder, ExpertDecoderConfig  # noqa: F401
 from .sparse_decoder import sparse_decoder, SparseDecoderConfig  # noqa: F401
 from .windowed_decoder import (  # noqa: F401
     windowed_decoder, WindowedDecoderConfig)
+from .compressed_decoder import (  # noqa: F401
+    compressed_decoder, CompressedDecoderConfig)
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

@@ -42,23 +42,29 @@ def class_batch(
 def one_trip_layer(h, body, use_recompute: bool = True):
     """(h', the recurrence) of one layer built as a one-trip
     layers.Recurrence, under `use_recompute` inside a recompute scope, so
-    that the layer is the unit of recomputation.  `body(carried)` builds
-    the layer and returns (its output, the values the recurrence hands out
-    besides: read them with the recurrence's call)."""
+    that the layer is the unit of recomputation.  `h` is the value a layer
+    hands the next, or a tuple of them (the residual stream and a router's
+    state): each is a carry of the recurrence, so it survives the unit's
+    recomputation and its gradient comes back through it.  `body(carried)`
+    builds the layer and returns (its output, one value or a tuple as
+    `h` is, the values the recurrence hands out besides: read them with
+    the recurrence's call)."""
     from .. import layers
     from ..core.framework import recompute_scope
 
+    many = isinstance(h, tuple)
     scope = recompute_scope if use_recompute else contextlib.nullcontext
     with scope():
         rec = layers.Recurrence(trips=1)
         with rec.block():
-            carried = rec.carry(h)
-            out, handed_out = body(carried)
-            rec.update(carried, out)
+            carried = tuple(rec.carry(v) for v in (h if many else (h,)))
+            out, handed_out = body(carried if many else carried[0])
+            for mem, value in zip(carried, out if many else (out,)):
+                rec.update(mem, value)
             for value in handed_out:
                 rec.output(value)
-        h = rec.final(carried)
-    return h, rec
+        finals = tuple(rec.final(mem) for mem in carried)
+    return (finals if many else finals[0]), rec
 
 
 def packed_batch(vocab_size: int, length: int, batch_size: int, seed: int,
@@ -100,15 +106,23 @@ class SoftmaxExpertShare:
         from .. import layers
 
         cfg = self.cfg
-        held, d, f = cfg.experts_held, cfg.d_model, cfg.d_expert
         trained = getattr(cfg, "train_router", True)
         idx, weight, _ = layers.moe_router(
-            x, self.param([d, cfg.n_routed_experts], f"{name}_router_w",
-                          trainable=trained),
+            x, self.param([cfg.d_model, cfg.n_routed_experts],
+                          f"{name}_router_w", trainable=trained),
             None, top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
             scoring="softmax")
         if not trained:
             weight = layers.detach(weight)
+        return self.held_experts(x, idx, weight, name)
+
+    def held_experts(self, x, idx, weight, name):
+        """The held experts' terms of x under a router's choice `idx` and
+        gates `weight`."""
+        from .. import layers
+
+        cfg = self.cfg
+        held, d, f = cfg.experts_held, cfg.d_model, cfg.d_expert
         return layers.moe_experts(
             x, idx, weight,
             self.param([held, d, f], f"{name}_experts_gate_w"),

@@ -134,18 +134,22 @@ def _exit_distribution(lam):
     return layers.stack(ps + [left], axis=0)
 
 
-def _heads_and_loss(b, states, labels):
+def _heads_and_loss(b, states, labels, table=None):
     """(loss, logits, exit distribution or None) from the trips' states
     [R, B, S, D]: all of them through the one head matmul and the one
     softmax_with_cross_entropy; gate, exit distribution, entropy and the
-    weighted sum in fp32."""
+    weighted sum in fp32.  With `table` [vocabulary, D], the embedding's
+    parameter, the head is that table transposed and no parameter of its
+    own: one parameter, its two gradients summed."""
     cfg = b.cfg
     R = cfg.loop_steps
     gated = cfg.exit_gate and R > 1
     if not gated and R > 1:  # the last trip decodes; the others have no head
         states = layers.slice(states, axes=[0], starts=[R - 1], ends=[R])
     n = R if gated else 1
-    logits = b.linear(states, cfg.d_model, cfg.vocab_size, "head")
+    logits = (b.linear(states, cfg.d_model, cfg.vocab_size, "head")
+              if table is None
+              else layers.matmul(states, table, transpose_y=True))
     tiled = layers.expand(layers.unsqueeze(labels, axes=[0]),
                           expand_times=[n, 1, 1])              # [n, B, S]
     ce = layers.softmax_with_cross_entropy(logits=logits, label=tiled)

@@ -8,6 +8,7 @@ additive [Sq, Sk] bias tensor, so nothing score-shaped ever hits HBM.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -114,9 +115,13 @@ def _yarn_inv_freq(inv_freq, dim, base, factor, original_length, beta_fast,
 
 
 def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
-            yarn=None):
+            yarn=None, rotary_dim=None):
     """Rotary position embedding (Su et al. 2021) of x [..., S, D] along
-    its last two axes, in the half-split layout: pair i is (x[i],
+    its last two axes (under `rotary_dim` < D of the first `rotary_dim`
+    features alone, as a vector of that width would turn; the others pass:
+    computed at the head's full width, x times [cos, cos, 1] plus x with
+    the two halves swapped times [-sin, sin, 0], so that no value narrower
+    than a head is made), in the half-split layout: pair i is (x[i],
     x[i + D/2]), turned by the angle p * base^(-2i/D) (under `yarn`, a dict
     of factor, original_length, beta_fast, beta_slow and attention_factor:
     p * _yarn_inv_freq's f'_i, cos and sin times attention_factor, so that a
@@ -128,7 +133,7 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
     own share of the pairs).  The angles are fp32 whatever x is (at base
     1e6 and p in the thousands bf16 has no digit left of them); x's dtype
     out."""
-    seq, dim = x.shape[-2], x.shape[-1]
+    seq, dim = x.shape[-2], int(rotary_dim or x.shape[-1])
     half = dim // 2
     inv_freq = float(base) ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
     if yarn is not None:
@@ -154,6 +159,14 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
         cos, sin = (t * jnp.float32(yarn["attention_factor"])
                     for t in (cos, sin))
     xs = x.astype(amp.stats_dtype(x))
+    if dim < x.shape[-1]:
+        one = jnp.ones(cos.shape[:-1] + (x.shape[-1] - dim,), cos.dtype)
+        swapped = jnp.concatenate(
+            [xs[..., half:dim], xs[..., :half], xs[..., dim:]], axis=-1)
+        out = (xs * jnp.concatenate([cos, cos, one], axis=-1)
+               + swapped * jnp.concatenate(
+                   [-sin, sin, jnp.zeros_like(one)], axis=-1))
+        return out.astype(x.dtype)
     x1, x2 = xs[..., :half], xs[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -170,7 +183,8 @@ def _rotary_embedding(ctx, ins, attrs):
         data(ins["X"][0]), attrs.get("base", 10000.0),
         attrs.get("offset", 0),
         None if pos_in is None else data(pos_in),
-        tuple(int(n) for n in attrs.get("sections", ())), yarn)]}
+        tuple(int(n) for n in attrs.get("sections", ())), yarn,
+        int(attrs.get("rotary_dim", 0)))]}
 
 
 def _latent_attn_infer(op, block):
@@ -225,6 +239,157 @@ def _latent_attention(ctx, ins, attrs):
         out = _attend(ctx, q, k, kv[..., dn:].astype(k.dtype), None, True,
                       (dn + dr) ** -0.5)
     return {"Out": [jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)]}
+
+
+def causal_shift(x, steps: int = 1, before=None, axis: int = 1):
+    """y[t] = x[t - steps] along `axis`; where t < steps the values
+    `before` (it broadcasts against x with `axis` at length 1; None:
+    zeros), which stand for every position before the sequence's first."""
+    if steps == 0:
+        return x
+    lead = list(x.shape)
+    lead[axis] = steps
+    first = (jnp.zeros(lead, x.dtype) if before is None
+             else jnp.broadcast_to(before.astype(x.dtype), lead))
+    kept = [slice(None)] * x.ndim
+    kept[axis] = slice(0, x.shape[axis] - steps)
+    return jnp.concatenate([first, x[tuple(kept)]], axis=axis)
+
+
+def causal_conv1d(x, w, bias=None, before=None):
+    """x [B, g, S, c], g groups of c channels with the groups first,
+    convolved along S alone, causally, S rows out: y[t] = sum over the k
+    taps j of w[j] applied to x[t - (k - 1) + j] (+ bias [g, c or o]), so
+    the last tap reads the position itself and no tap a later one; a
+    position before the first reads as `before` [g, c] (None: zeros).  w
+    [k, g, c] is one filter a channel (depthwise); w [k, g, c, o] mixes the
+    channels of each group among themselves.  The k taps are k shifted
+    products summed in fp32, no [S, k c] tensor: a depthwise tap is a
+    multiply of the shifted input; a group's k taps are ONE product on the
+    AMP tier's operands, batched over the groups, x_g [S, c] x [c, k o],
+    whose k column blocks are shifted and summed (the rows shifted in are
+    `before` through that tap).  fp32 out for a half-width x.  With the
+    groups first nothing is transposed between the taps, the statistics of
+    a head and the [B, heads, S, D] that attention takes (at the cell's
+    shape a third of the time of the same sums over [B, S, C]: PERF.md 6,
+    PR 43)."""
+    k = w.shape[0]
+    acc = amp.stats_dtype(x)
+
+    def rows(t):                     # [g, c] against [B, g, S, c]
+        return None if t is None else t.astype(acc)[:, None, :]
+
+    if w.ndim == 3:
+        xs = x.astype(acc)
+        y = sum(causal_shift(xs, k - 1 - j, rows(before), axis=2) * rows(w[j])
+                for j in range(k))
+    else:
+        c_out = w.shape[-1]
+        xc, wc = amp.mxu_operands(x, w)
+        taps = jnp.concatenate(list(wc.astype(xc.dtype)), axis=-1)
+        prod = jnp.einsum("bgsi,gio->bgso", xc, taps,
+                          preferred_element_type=acc)
+        fill = None if before is None else jnp.einsum(
+            "gi,gio->go", before.astype(acc), taps.astype(acc),
+            precision="highest")
+        y = sum(causal_shift(
+            prod[..., j * c_out:(j + 1) * c_out], k - 1 - j,
+            None if fill is None else rows(fill[:, j * c_out:(j + 1) * c_out]),
+            axis=2) for j in range(k))
+    return y if bias is None else y + rows(bias)
+
+
+def _cca_infer(op, block):
+    q = in_desc(op, block, "Q")
+    k = in_desc(op, block, "K")
+    if q is None or k is None:
+        return
+    H, G = int(op.attr("heads", 1)), int(op.attr("kv_heads", 1))
+    B, S, lq = q.shape
+    set_output(block, op, "QOut", [B, H, S, lq // H], q.dtype)
+    for slot in ("KOut", "VOut"):
+        set_output(block, op, slot, [B, G, S, k.shape[-1] // G], q.dtype)
+
+
+@register_op("compressed_conv_qkv", infer_shape=_cca_infer,
+             diff_inputs=["Q", "K", "V", "ConvAW", "ConvAB", "ConvBW",
+                          "ConvBB", "Tau"])
+def _compressed_conv_qkv(ctx, ins, attrs):
+    """What compressed convolutional attention (CCA; Zyphra, arXiv:
+    2510.04476) does between its down-projections and its scores, for
+    `heads` H query heads on `kv_heads` G key/value heads of D.  Q [B, S,
+    H D], K and V [B, S, G D] are the projections q~, k~, v~ of the
+    layer's input.
+
+    z = [q~ ; k~], padded ONCE with (k0 - 1) + (k1 - 1) zero rows on the
+    left; convolution A over it (ConvAW [k0, C], ConvAB [C]: one filter a
+    channel), convolution B over A's output (ConvBW [k1, H + G, D, D],
+    ConvBB [C]: across the channels of one head), both unpadded, so what B
+    sees before position 0 is A's output on zeros, its bias (computed so,
+    on [S, D] tensors a head: causal_conv1d's `before`).  The q-k mean of the
+    values BEFORE the convolutions: m_q[j] = (q~[j] + k~[j // (H / G)]) /
+    2, m_k[g] the mean of m_q over g's query heads; q = z''[:, :H D] +
+    m_q, k = z''[:, H D:] + m_k.  Each head L2-normalised to length
+    sqrt(D), the keys times Tau [G].  Rotary on the first `rotary_dim`
+    features of each head.  The value's second half of channels comes from
+    the token before (zeros before the first).
+
+    QOut [B, H, S, D], KOut and VOut [B, G, S, D]: what fused_attention
+    takes.  Statistics, sums and angles in fp32, the grouped convolution
+    on the AMP tier's operands; one jnp path on every backend, grouped
+    under the name scope `cca.mix`; `cca.lower` (a span, at lowering) says
+    what a site was given."""
+    from ..kernels.flash_attention import _visible_pairs
+
+    q, k, v = (data(ins[s][0]) for s in ("Q", "K", "V"))
+    a_w, a_b, b_w, b_b, tau = (data(ins[s][0]) for s in (
+        "ConvAW", "ConvAB", "ConvBW", "ConvBB", "Tau"))
+    H, G = int(attrs["heads"]), int(attrs["kv_heads"])
+    S, D = q.shape[1], q.shape[2] // H
+    rotary_dim = int(attrs.get("rotary_dim", 0)) or D
+    with span("cca.lower", heads=H, kv_heads=G, latent_q=int(q.shape[-1]),
+              latent_k=int(k.shape[-1]), conv_time0=int(a_w.shape[0]),
+              conv_time1=int(b_w.shape[0]), conv_groups=int(b_w.shape[1]),
+              rotary_dim=rotary_dim, sq=int(S),
+              pairs=_visible_pairs(S, S, True, None)), \
+            jax.named_scope("cca.mix"):
+        outs = compressed_conv_mix(
+            q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim,
+            float(attrs.get("rope_base", 10000.0)))
+    return dict(zip(("QOut", "KOut", "VOut"), ([o] for o in outs)))
+
+
+def compressed_conv_mix(q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim,
+                        base):
+    """The op compressed_conv_qkv's arithmetic (its docstring), in
+    jax.numpy, heads first from the projections on: (q [B, H, S, D], k and
+    v [B, G, S, D]) in q's dtype."""
+    B, S, lq = q.shape
+    D, share, n = lq // H, H // G, H + G
+    acc = amp.stats_dtype(q)
+
+    def heads(t, m):                 # [B, S, m D] -> [B, m, S, D]
+        return jnp.swapaxes(t.reshape(B, S, m, D), 1, 2)
+
+    def unit(x):                     # a head at length sqrt(D)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True))
+
+    z = jnp.concatenate([heads(q, H), heads(k, G)], axis=1).astype(acc)
+    a_b = a_b.reshape(n, D)
+    conv = causal_conv1d(
+        causal_conv1d(z, a_w.reshape(-1, n, D), a_b), b_w,
+        b_b.reshape(n, D), before=a_b)
+    m_q = (z[:, :H].reshape(B, G, share, S, D)
+           + z[:, H:].reshape(B, G, 1, S, D)) / 2
+    qn = unit(conv[:, :H].reshape(B, G, share, S, D) + m_q)
+    kn = unit(conv[:, H:] + jnp.mean(m_q, axis=2))
+    kn = kn * tau.astype(acc)[:, None, None]
+    half = v.shape[-1] // 2
+    vs = jnp.concatenate([v[..., :half], causal_shift(v[..., half:])],
+                         axis=-1)
+    return tuple(t.astype(q.dtype) for t in (
+        _rotate(qn.reshape(B, H, S, D), base, rotary_dim=rotary_dim),
+        _rotate(kn, base, rotary_dim=rotary_dim), heads(vs, G)))
 
 
 def _sparse_attn_infer(op, block):

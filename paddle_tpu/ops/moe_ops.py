@@ -42,7 +42,11 @@ indexed by the T x k assignments, 7/8 of them held on other chips in an
 8-way share: only integers and scalars are (the sort's keys, `order`, `pos`,
 the weights).  Name scopes `moe.dispatch` (sort, gather, combine) and
 `moe.experts` (the grouped matmuls) group the device's time in a profiler
-trace; `moe.lower` (a span, at lowering) says what a layer was given.
+trace; `moe.lower` (a span, at lowering) says what a layer was given,
+`router.lower` what a `moe_router` site was: the width of its input (the
+stream's, or a router network's), its outputs, the width of the state that
+network carried from the layer before (a label the model passes) and
+whether its weight is trained.
 """
 
 from __future__ import annotations
@@ -445,7 +449,11 @@ def _moe_router(ctx, ins, attrs):
     x = data(ins["X"][0])
     lead = x.shape[:-1]
     bias_in = ins.get("Bias", [None])[0]
-    with jax.named_scope("moe.router"):
+    with span("router.lower", width=int(x.shape[-1]),
+              experts=int(data(ins["Weight"][0]).shape[-1]),
+              carried=int(attrs.get("carried", 0)),
+              trained=int(bool(attrs.get("trained", True)))), \
+            jax.named_scope("moe.router"):
         idx, weight, load = route(
             x.reshape(-1, x.shape[-1]), data(ins["Weight"][0]),
             None if bias_in is None else data(bias_in), int(attrs["top_k"]),

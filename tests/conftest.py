@@ -102,73 +102,3 @@ def _fresh_programs():
     from paddle_tpu.core import amp
 
     amp.reset_amp()
-
-
-# Some cases of tests under tests/benchmark/ (the benchmark's files, which a
-# model_config PR adds to and does not edit) cannot pass for a reason that
-# is not a configuration's: marked as expected failures, strictly, so that
-# the `benchmark` PR that repairs either test has to take the mark out.
-# tests/benchmark/test_moonlight_benchmark.py and test_keye_benchmark.py hold
-# the configurations and the readers' lists to everything else those tests
-# ask (PERF.md 7).
-_BENCHMARK_TESTS_A_LATER_ENTRY_TRIPS = {
-    "test_benchmark_manifest.py::test_configuration_entry_and_files"
-    "[moonlight-16b-a3b]":
-        "the width expression matches 'hidden' in num_hidden_layers, which "
-        "is the depth (as for ouro-2.6b, tests/benchmark/conftest.py)",
-    "test_benchmark_manifest.py::test_configuration_entry_and_files"
-    "[keye-vl-2.0-30b-a3b]":
-        "the same width expression on the same key, num_hidden_layers "
-        "(tests/benchmark/test_keye_benchmark.py holds the file to the "
-        "rest)",
-    "test_moonlight_benchmark.py::"
-    "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
-        "PR 31's test pins moe_experts_ms.train and moe_dispatch_ms.train "
-        "to its cell alone; keye-train-dsa16k (PR 33) has the same expert "
-        "block and reports them too",
-    "test_moonlight_benchmark.py::"
-    "test_what_pr_27s_manifest_test_held_for_its_cell_still_holds":
-        "PR 31's test counts every later cell's own .train readers (PR "
-        "33's five dsa_* ones) against ouro-train-loop4",
-    "test_ouro_benchmark.py::"
-    "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
-        "PR 27's test pins hbm_peak_gb.train to ouro-train-loop4 alone and "
-        "counts every later cell's own .train readers against it",
-    "test_keye_benchmark.py::"
-    "test_every_new_reader_is_in_the_manifest_for_the_new_cell_alone":
-        "PR 33's test pins the manifest's last five per-layer entries and "
-        "the cell's whole reader set; PR 35 appends seven turnaround "
-        "readers that every training cell reports "
-        "(tests/benchmark/test_turnaround.py holds what still stands)",
-    "test_benchmark_manifest.py::test_configuration_entry_and_files"
-    "[mellum2-12b-a2.5b]":
-        "the same width expression on the same key, num_hidden_layers "
-        "(tests/benchmark/test_mellum_benchmark.py holds the file to the "
-        "rest)",
-    "test_keye_benchmark.py::test_configuration_entry_and_files":
-        "PR 33's test pins its configuration and its cell to the ends of "
-        "their lists; PR 38 appends mellum2-12b-a2.5b and "
-        "mellum-train-swa16k behind them "
-        "(tests/benchmark/test_mellum_benchmark.py holds what still stands)",
-    "test_keye_benchmark.py::"
-    "test_what_pr_31s_manifest_tests_held_for_their_cells_still_holds":
-        "PR 33's test pins moe_experts_ms.train and moe_dispatch_ms.train "
-        "to two cells; mellum-train-swa16k (PR 38) has the same expert "
-        "block and reports them too",
-    "test_turnaround.py::"
-    "test_the_seven_readers_are_the_manifests_last_entries":
-        "PR 35's test pins its seven readers to the end of per_layer and "
-        "to six cells; PR 38 appends five readers and a seventh cell "
-        "(tests/benchmark/test_mellum_benchmark.py holds what still stands)",
-    "test_turnaround.py::"
-    "test_what_pr_33s_manifest_test_held_for_its_cell_still_holds":
-        "PR 35's test pins PR 33's five readers to per_layer[-12:-7]; "
-        "PR 38's five entries stand behind them",
-}
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        for tail, reason in _BENCHMARK_TESTS_A_LATER_ENTRY_TRIPS.items():
-            if item.nodeid.endswith(tail):
-                item.add_marker(pytest.mark.xfail(strict=True, reason=reason))

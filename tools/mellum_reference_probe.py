@@ -106,8 +106,13 @@ def move_off_starts(scope, rng, put):
             scope.set_var(p.name, put((v * 5).astype(np.float32)))
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+def main(cell_name=CELL, mutants=MUTANTS, make=mutant, move=move_off_starts,
+         doc=__doc__, out_name="mellum_reference_probe") -> int:
+    """This file's probe; with arguments, another cell's
+    (tools/zaya_reference_probe.py): its mutants' names, `make(name)` the
+    reference's `loss_and_grad` with that one thing wrong, `move(scope,
+    rng, put)` its parameters off their starts."""
+    ap = argparse.ArgumentParser(description=doc.splitlines()[0])
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--only", default=None,
                     help="comma-separated mutants; default all")
@@ -121,7 +126,7 @@ def main() -> int:
     import paddle_tpu as fluid
     from benchmark.harness import device, manifest, reference
 
-    cell = manifest.Cell(manifest.load_manifest(), CELL,
+    cell = manifest.Cell(manifest.load_manifest(), cell_name,
                          rehearse=args.rehearse)
     devices = device.claim(cell.chips, args.rehearse)
     if devices is None:
@@ -133,8 +138,8 @@ def main() -> int:
     exe = fluid.Executor(fluid.TPUPlace() if tpu else fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     if not args.as_the_cell_starts:
-        move_off_starts(fluid.global_scope(), np.random.default_rng(args.seed),
-                        lambda v: jax.device_put(v, devices[0]))
+        move(fluid.global_scope(), np.random.default_rng(args.seed),
+             lambda v: jax.device_put(v, devices[0]))
     batch = jax.device_put(mod.make_batch(cfg, spec, rows, args.seed),
                            devices[0])
     first = reference.FirstStep(cell, spec)
@@ -146,12 +151,12 @@ def main() -> int:
            "readings": {}}
     loss = float(np.ravel(np.asarray(
         exe.run(feed=batch, fetch_list=[spec.loss])[0]))[0])
-    names = (None,) + MUTANTS
+    names = (None,) + tuple(mutants)
     if args.only:
         names = (None,) + tuple(args.only.split(","))
     for name in names:
         first.params = params
-        first.module = types.SimpleNamespace(loss_and_grad=mutant(name))
+        first.module = types.SimpleNamespace(loss_and_grad=make(name))
         found, problems = first.compare(loss, batch, rows)
         out["readings"][name or "reference"] = {
             **found, "refused_by": [p.split(":")[0][:60] for p in problems]}
@@ -160,9 +165,9 @@ def main() -> int:
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     tail = "_as_the_cell_starts" if args.as_the_cell_starts else ""
     with open(os.path.join(ROOT, "chiprun_out",
-                           f"mellum_reference_probe{tail}.json"), "w") as f:
+                           f"{out_name}{tail}.json"), "w") as f:
         json.dump(out, f, indent=1)
-    wrong = [m for m in MUTANTS if m in out["readings"]]
+    wrong = [m for m in mutants if m in out["readings"]]
     ok = not out["readings"]["reference"]["refused_by"] and all(
         out["readings"][m]["refused_by"] for m in wrong)
     print(json.dumps({"ok": ok, "passed_though_wrong": [
