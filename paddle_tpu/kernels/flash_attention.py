@@ -95,6 +95,16 @@ force="interpret" keeps the Pallas backward at every shape (the CPU
 tests' door), force="jax" keeps none.  pallas_call instances are memoized
 by static config, blocks included, so every attention site of one shape
 (the 18 of a Transformer-base step are 3 shapes) shares one kernel payload.
+
+What survives the recomputation of the unit around a site (PR 44): where
+the backward is the Pallas kernel the forward's output and logsumexp
+(`KEPT`; out's bytes and 4 a row, `kept_bytes`) are tagged with
+core.compiler.keep, the output as its bits (_flash_fwd says why).  Where
+the layer around the call is a rematerialised unit, its backward reads the
+first forward's two and traces no second forward: an O(S^2) pass for two
+O(S) arrays.  A site on the XLA recompute
+backward has no logsumexp and tags nothing; outside a rematerialised unit
+a tag does nothing.
 """
 
 from __future__ import annotations
@@ -107,10 +117,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.pallas import V5E_VMEM_BYTES, tile_padded_bytes
+from ..core.compiler import keep
 from ..observability import span
 
 __all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes",
-           "bwd_working_set_bytes"]
+           "bwd_working_set_bytes", "KEPT", "kept", "kept_bytes"]
 
 NEG_INF = -1e30
 
@@ -1085,21 +1096,56 @@ def _flash(q, k, v, klen, causal, scale, force, window):
     return _forward(q, k, v, klen, causal, scale, force, False, window)[0]
 
 
+KEPT = ("out", "lse")   # of _forward, what _flash_fwd keeps
+_BITS = {2: jnp.uint16, 4: jnp.uint32}     # an output's bits, by its width
+
+
+def kept(q, k, v, causal, window=None, force="auto") -> tuple:
+    """What a call of this shape keeps through the recomputation of the
+    unit around it: KEPT where its backward is the Pallas kernel (the
+    forward then has the logsumexp in hand), nothing where it is the XLA
+    recompute, which reads neither."""
+    if window is not None and window >= k.shape[2]:
+        window = None           # as flash_attention reads it
+    return KEPT if _pallas_backward(q, k, v, causal, force, window) else ()
+
+
+def kept_bytes(q, v) -> int:
+    """What a site that keeps holds, of q [B, H, S, D] and v [B, G, S, Dv]:
+    out [B, H, S, Dv] in q's dtype and the rows' logsumexp fp32 (its packed
+    plane, which is padded where S is no multiple of the forward's
+    q-block)."""
+    B, H, S, _ = q.shape
+    return B * H * S * (v.shape[3] * q.dtype.itemsize + 4)
+
+
 def _flash_fwd(q, k, v, klen, causal, scale, force, window):
     # the XLA recompute backward holds neither O nor L as residuals, and
     # its forward skips the lse HBM write entirely
     out, lse = _forward(
         q, k, v, klen, causal, scale, force,
         _pallas_backward(q, k, v, causal, force, window), window)
-    return out, (q, k, v, klen, None if lse is None else out, lse)
+    if lse is None:
+        return out, (q, k, v, klen, None, None)
+    # an O(S^2) pass for two O(S) arrays: under a recomputed unit the
+    # backward reads the first forward's, and the forward runs once.  `out`
+    # is kept as its bits: on a floating-point value that a unit saves jax
+    # puts a reduce_precision to the value's own precision, which XLA runs
+    # as a pass over it (0.7 ms a layer at 32 heads x 16384, PERF.md PR 44)
+    # and a kernel's output, written at its own width, has no use for
+    bits, lse = keep(jax.lax.bitcast_convert_type(
+        out, _BITS[out.dtype.itemsize]), lse)
+    return (jax.lax.bitcast_convert_type(bits, out.dtype),
+            (q, k, v, klen, bits, lse))
 
 
 def _flash_bwd(causal, scale, force, window, res, g):
-    q, k, v, klen, out, lse = res
+    q, k, v, klen, bits, lse = res
     with jax.named_scope("flash.bwd"):
         if lse is not None:
             dq, dk, dv = _pallas_flash_bwd(
-                q, k, v, klen, out, lse, g, causal, scale,
+                q, k, v, klen, jax.lax.bitcast_convert_type(bits, q.dtype),
+                lse, g, causal, scale,
                 interpret=(force == "interpret"), window=window,
             )
             return dq, dk, dv, jnp.zeros_like(klen)

@@ -55,12 +55,21 @@ def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
                          out_specs=qkv, check_vma=False)
 
 
-def _attend(ctx, q, k, v, klen, causal, scale, window=None):
+def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None):
     """flash_attention of q [B, H, S, D] over k [B, G, S, D] and v [B, G,
     S, Dv] (G = H, or a divisor of it: grouped-query attention), under a
-    shard_map where the program runs on a mesh of several devices."""
+    shard_map where the program runs on a mesh of several devices.  The one
+    door of `fused_attention` and `latent_attention` to the kernel: it says
+    on the op's span `sp` what the site holds through the recomputation of
+    the unit around it (`kept`, `kept_bytes`: the kernel's output and
+    logsumexp where its backward is the Pallas kernel, nothing where it is
+    the XLA recompute) and adds the values to the context's `kept`."""
     from ..kernels import flash_attention
-    from ..kernels.flash_attention import _use_pallas
+    from ..kernels.flash_attention import _use_pallas, kept, kept_bytes
+
+    names = kept(q, k, v, causal, window)
+    sp.set(kept=",".join(names), kept_bytes=kept_bytes(q, v) if names else 0)
+    ctx.kept += len(names)
 
     def attend(q, k, v, klen=None):
         return flash_attention(q, k, v, causal=causal, scale=scale,
@@ -76,6 +85,16 @@ def _attend(ctx, q, k, v, klen, causal, scale, window=None):
 @register_op("fused_attention", infer_shape=_fused_attn_infer,
              diff_inputs=["Q", "K", "V"])
 def _fused_attention(ctx, ins, attrs):
+    """Attention of Q [B, H, Sq, D] over K, V [B, G, Sk, .] (G = H or a
+    divisor of it), `causal`, under a `window`, keys cut at KLengths: one
+    flash kernel (kernels/flash_attention.py).  Where the site's backward
+    is the Pallas kernel (by the shape) the kernel tags its output and
+    logsumexp to survive the recomputation of the unit around the op
+    (core.compiler.keep): the backward of a recomputed layer runs no second
+    forward of this op.  `attn.lower` (a span, at lowering) says what a
+    site was given, `kept` and `kept_bytes` what it holds through that
+    recomputation ("" and 0 on the XLA recompute backward); the context's
+    `kept` counts the values."""
     q = data(ins["Q"][0])  # [B, H, Sq, D]
     k = data(ins["K"][0])
     v = data(ins["V"][0])
@@ -90,9 +109,9 @@ def _fused_attention(ctx, ins, attrs):
               window=int(seen or 0), heads=int(q.shape[1]),
               kv_heads=int(k.shape[1]), sq=int(q.shape[2]),
               pairs=_visible_pairs(q.shape[2], k.shape[2], causal, seen),
-              rope=str(attrs.get("rope") or "none")):
-        out = _attend(ctx, q, k, v, klen, causal, attrs.get("scale") or None,
-                      window)
+              rope=str(attrs.get("rope") or "none")) as sp:
+        out = _attend(ctx, sp, q, k, v, klen, causal,
+                      attrs.get("scale") or None, window)
     return {"Out": [out]}
 
 
@@ -211,8 +230,12 @@ def _latent_attention(ctx, ins, attrs):
     The flash kernels take q and k at dn + dr and v at dv as they are: the
     kernels carry a value width of their own (kernels/flash_attention.py),
     which tools/moonlight_kernel_probe.py held against v zero-padded to the
-    key width on the chip (PERF.md, PR 31).  `mla.lower` (a span, at
-    lowering) says what a site was given."""
+    key width on the chip (PERF.md, PR 31).  What survives the
+    recomputation of the unit around the op is `fused_attention`'s: the
+    kernel's output and logsumexp where the backward is the Pallas kernel,
+    so a recomputed layer runs the op's projections again and not its
+    kernel.  `mla.lower` (a span, at lowering) says what a site was given,
+    `kept` and `kept_bytes` what it holds through that recomputation."""
     q = data(ins["Q"][0])
     latent = data(ins["Latent"][0])
     k_rope = data(ins["KRope"][0])
@@ -227,7 +250,7 @@ def _latent_attention(ctx, ins, attrs):
         return jnp.swapaxes(t.reshape(B, S, H, -1), 1, 2)
 
     with span("mla.lower", heads=H, qk_dim=dn + dr, v_dim=dv,
-              kv_rank=int(latent.shape[-1]), padded_v=0):
+              kv_rank=int(latent.shape[-1]), padded_v=0) as sp:
         lc, wc = amp.mxu_operands(latent, kv_w)
         kv = heads(amp.mxu_output(jnp.matmul(lc, wc), latent, kv_w))
         q = heads(q)
@@ -236,8 +259,8 @@ def _latent_attention(ctx, ins, attrs):
             kv.dtype), (B, H, S, dr))
         k = jnp.concatenate([kv[..., :dn], shared], -1)
         q, k = amp.match_kept(q, k)
-        out = _attend(ctx, q, k, kv[..., dn:].astype(k.dtype), None, True,
-                      (dn + dr) ** -0.5)
+        out = _attend(ctx, sp, q, k, kv[..., dn:].astype(k.dtype), None,
+                      True, (dn + dr) ** -0.5)
     return {"Out": [jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)]}
 
 

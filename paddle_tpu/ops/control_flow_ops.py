@@ -617,10 +617,11 @@ def _recurrence(ctx, ins, attrs):
     unit of rematerialization: compiler.rematerialised goes around the
     scan body, each trip's incoming carry is kept, with it whatever an op
     of the body tagged with compiler.keep (sparse attention: its output,
-    logsumexp and thresholds), and every other activation is computed
-    again in the backward pass.  `recurrence.lower` (a span) counts the
-    tagged values as `kept`: 0 where the body's ops name nothing, and
-    then the lowering is the bare jax.checkpoint's."""
+    logsumexp and thresholds; a flash site whose backward is the Pallas
+    kernel: its output and logsumexp), and every other activation is
+    computed again in the backward pass.  `recurrence.lower` (a span)
+    counts the tagged values as `kept`: 0 where the body's ops name
+    nothing, and then the lowering is the bare jax.checkpoint's."""
     from ..core.compiler import LoweringContext, lower_op, rematerialised
 
     sub_block = ctx.program.block(attrs["sub_block"])

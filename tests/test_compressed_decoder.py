@@ -603,7 +603,8 @@ def test_the_spans_say_what_each_site_was_given():
         width=16, experts=8, carried=16, trained=0)
         for s in spans["router.lower"])
     assert all(s == dict(kind="full", window=0, heads=4, kv_heads=2, sq=S,
-                         pairs=pairs, rope="partial")
+                         pairs=pairs, rope="partial", kept="out,lse",
+                         kept_bytes=4 * S * (16 * 2 + 4))
                for s in spans["attn.lower"])
     assert all(p["kv_heads"] == 2 and p["causal"] for p in spans["flash.plan"])
     assert len(spans["flash.bwd_plan"]) == 3
@@ -616,6 +617,26 @@ def test_the_spans_say_what_each_site_was_given():
     assert all(r["bodies_lowered"] == 1 and r["trips"] == 1
                for r in spans["recurrence.lower"])
     assert [c["classes"] for c in spans["ce.lower"]] == [64]
+
+
+@pytest.mark.parametrize("S, kept", [(512, "out,lse"), (256, "")])
+def test_the_flash_site_keeps_out_and_lse_through_the_layers_recomputation(
+        S, kept):
+    """The site inside `cca.attend`: where its backward is the Pallas
+    kernel its output and logsumexp survive the recomputation of the layer
+    (`attn.lower`'s `kept`, `kept_bytes`), and the layer's
+    `recurrence.lower`, which carries two values, counts two kept; at S 256
+    the XLA recompute backward keeps nothing."""
+    spans = _spans_of_a_step(
+        ("attn.lower", "flash.bwd_plan", "recurrence.lower"), max_length=S)
+    assert spans["attn.lower"] and all(
+        (s["kept"], s["kept_bytes"]) == (
+            kept, 4 * S * (16 * 2 + 4) if kept else 0)
+        for s in spans["attn.lower"])
+    assert {b["engine"] for b in spans["flash.bwd_plan"]} == {
+        "pallas" if kept else "xla"}
+    assert [(r["recompute"], r["kept"]) for r in spans["recurrence.lower"]] \
+        == 3 * [(1, 2 if kept else 0)]
 
 
 def test_where_the_parameters_start():

@@ -425,7 +425,8 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
         qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
         n_routed_experts=64, experts_held=8, expert_offset=0, top_k=6)
     assert spans["mla.lower"] == 3 * [dict(
-        heads=2, qk_dim=192, v_dim=128, kv_rank=512, padded_v=0)]
+        heads=2, qk_dim=192, v_dim=128, kv_rank=512, padded_v=0,
+        kept="out,lse", kept_bytes=2 * 2 * 2048 * (128 * 2 + 4))]
     T = 2 * 2048
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=64, experts_held=8, top_k=6, row_buffer=6 * T,
@@ -438,6 +439,23 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
                (192, 512, 1024) for s in spans["flash.plan"])
     assert spans["flash.bwd_plan"] == 3 * [dict(fa._bwd_plan(
         2048, 2048, 192, jnp.bfloat16, True, v_dim=128), kv_heads=2)]
+
+
+@pytest.mark.parametrize("S, kept", [(512, "out,lse"), (256, "")])
+def test_mla_lower_says_what_a_site_keeps_through_its_layers_recomputation(
+        S, kept):
+    """The value's own width in `kept_bytes` (out is [B, H, S, v_dim]); a
+    site the shape leaves on the XLA recompute backward keeps nothing;
+    `recurrence.lower` counts the values, a layer a one-site body."""
+    spans = _spans_of_a_step(
+        ("mla.lower", "flash.bwd_plan", "recurrence.lower"), max_length=S,
+        n_layer=2, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=24)
+    assert [(s["kept"], s["kept_bytes"]) for s in spans["mla.lower"]] == \
+        2 * [(kept, 2 * 2 * S * (24 * 2 + 4) if kept else 0)]
+    assert {b["engine"] for b in spans["flash.bwd_plan"]} == {
+        "pallas" if kept else "xla"}
+    assert [r["kept"] for r in spans["recurrence.lower"]] == 2 * [
+        2 if kept else 0]
 
 
 def test_new_ops_keep_bf16_in_bf16_out_with_fp32_inside():

@@ -515,9 +515,9 @@ def test_lowered_tpu_text_carries_the_backward_kernels_by_shape(case):
     assert ("_flash_bwd_kernel" in text) == (engine == "pallas")
 
 
-def _step_bwd_spans(spec, feed):
-    """flash.bwd_plan spans of one training step lowered abstractly for the
-    TPU (nothing compiles or runs)."""
+def _step_bwd_spans(spec, feed, name="flash.bwd_plan"):
+    """flash.bwd_plan spans (or those called `name`) of one training step
+    lowered abstractly for the TPU (nothing compiles or runs)."""
     def lower():
         with fluid.flags.tpu_trace_scope(True):
             compiled, feed_vals, state_vals, rng = fluid.Executor(
@@ -525,13 +525,12 @@ def _step_bwd_spans(spec, feed):
                     fluid.default_main_program(), feed=feed)
             return compiled.raw_fn(feed_vals, state_vals, rng)
 
-    return _bwd_spans(lower)
+    return _spans(name, lower)
 
 
-def test_ouro_body_has_four_pallas_backward_sites():
-    """ouro-2.6b's attention shape (S 2048, head 128, causal) at a width cut
-    to two heads: the body's four layers are four sites, lowered once for
-    any trip count, and every one takes the Pallas backward."""
+def _ouro_step():
+    """(spec, feed) of ouro-2.6b's attention shape (S 2048, head 128,
+    causal) at a width cut to two heads."""
     from paddle_tpu import models
 
     fluid.reset_default_env()
@@ -543,18 +542,12 @@ def test_ouro_body_has_four_pallas_backward_sites():
     fluid.Executor(fluid.CPUPlace()).run(fluid.default_startup_program())
     ids = np.zeros((2, 2049), np.int64)
     tokens, labels = spec.feed_names
-    spans = _step_bwd_spans(spec, {tokens: ids[:, :-1], labels: ids[:, 1:]})
-    assert len(spans) == 4
-    want = dict(fa._bwd_plan(2048, 2048, 128, jnp.bfloat16, True),
-                kv_heads=2)
-    assert want["engine"] == "pallas" and want["steps_skipped"] > 0
-    assert all(s == want for s in spans)
+    return spec, {tokens: ids[:, :-1], labels: ids[:, 1:]}
 
 
-def test_transformer_base_has_eighteen_xla_backward_sites():
-    """transformer-base's three attention shapes (S 256, head 64) at a width
-    cut to two heads: 6 encoder self, 6 decoder self, 6 cross, every one
-    left to the XLA recompute backward."""
+def _transformer_base_step():
+    """(spec, feed) of transformer-base's three attention shapes (S 256,
+    head 64) at a width cut to two heads."""
     from paddle_tpu import models
 
     fluid.reset_default_env()
@@ -565,8 +558,38 @@ def test_transformer_base_has_eighteen_xla_backward_sites():
     fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(spec.loss)
     fluid.Executor(fluid.CPUPlace()).run(fluid.default_startup_program())
     words = np.ones((2, 256), np.int64)
-    spans = _step_bwd_spans(spec, {n: words for n in spec.feed_names})
+    return spec, {n: words for n in spec.feed_names}
+
+
+def test_ouro_body_has_four_pallas_backward_sites():
+    """The body's four layers are four sites, lowered once for any trip
+    count, and every one takes the Pallas backward."""
+    spans = _step_bwd_spans(*_ouro_step())
+    assert len(spans) == 4
+    want = dict(fa._bwd_plan(2048, 2048, 128, jnp.bfloat16, True),
+                kv_heads=2)
+    assert want["engine"] == "pallas" and want["steps_skipped"] > 0
+    assert all(s == want for s in spans)
+
+
+def test_transformer_base_has_eighteen_xla_backward_sites():
+    """6 encoder self, 6 decoder self, 6 cross, every one left to the XLA
+    recompute backward."""
+    spans = _step_bwd_spans(*_transformer_base_step())
     assert len(spans) == 18
     assert {s["engine"] for s in spans} == {"xla"}
     assert {(s["sq"], s["sk"], s["head_dim"]) for s in spans} == \
         {(256, 256, 64)}
+
+
+@pytest.mark.parametrize("step, sites, kept", [
+    (_ouro_step, 4, ("out,lse", 2 * 2 * 2048 * (128 * 2 + 4))),
+    (_transformer_base_step, 18, ("", 0))], ids=["ouro", "transformer_base"])
+def test_the_sites_that_keep_are_the_sites_on_the_pallas_backward(
+        step, sites, kept):
+    """`attn.lower`'s `kept` / `kept_bytes`, a site: the forward's output
+    and logsumexp where the backward is the Pallas kernel (4 of 4 in
+    ouro-2.6b's body), nothing at any of transformer-base's 18."""
+    spans = _step_bwd_spans(*step(), name="attn.lower")
+    assert [(s["kept"], s["kept_bytes"]) for s in spans] == sites * [kept]
+

@@ -283,6 +283,42 @@ def test_recurrence_lower_span_counts_one_body(monkeypatch):
     observability.reset()
 
 
+@pytest.mark.parametrize("length, kept", [(512, 2), (256, 0)])
+def test_recurrence_lower_counts_what_the_flash_sites_of_the_body_keep(
+        monkeypatch, length, kept):
+    """Lowered for the TPU (abstractly: nothing compiles): a site whose
+    backward is the Pallas kernel (S 512) keeps its output and logsumexp
+    through the trip's recomputation, `kept` 2 a site of the body; at S 256
+    the shape keeps the XLA recompute backward and the body names nothing,
+    as on the CPU (the test above)."""
+    import jax
+
+    observability.reset()
+    monkeypatch.setitem(fluid.flags._VALUES, "FLAGS_observability", True)
+    fluid.reset_default_env()
+    spec = models.looped_decoder(models.LoopedDecoderConfig(
+        **{**TINY, "max_length": length, "loop_steps": 4}))
+    fluid.append_backward(spec.loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    observability.reset()
+    with fluid.flags.tpu_trace_scope(True):
+        compiled, *args = exe.capture_program(
+            fluid.default_main_program(), feed=spec.synthetic_batch(1, 0),
+            fetch_list=[spec.loss])
+        jax.eval_shape(compiled.raw_fn, *args)
+    spans = observability.default_tracer().spans()
+    assert [s.args for s in spans if s.name == "recurrence.lower"] == [
+        {"trips": 4, "bodies_lowered": 1, "recompute": 1,
+         "kept": kept * TINY["n_layer"]}]
+    sites = [s.args for s in spans if s.name == "attn.lower"]
+    assert sites and all(
+        (s["kept"], s["kept_bytes"]) == (
+            ("out,lse", TINY["n_head"] * length * (TINY["head_dim"] * 2 + 4))
+            if kept else ("", 0)) for s in sites)
+    observability.reset()
+
+
 def test_a_step_trains_through_the_executor_and_the_loss_falls():
     fluid.reset_default_env()
     spec = models.looped_decoder(models.LoopedDecoderConfig(**TINY))

@@ -311,9 +311,11 @@ def test_feature_rows_is_the_count_the_lowered_op_shows(cell):
             "keye-train-dsa16k": 229376}[cell] == moe_ops.feature_rows(rows)
 
 
-@pytest.mark.parametrize("cell", ["mellum-train-swa16k",
-                                  "moonlight-train-ep8share"])
-def test_a_layers_forward_runs_once_in_the_step_the_chip_compiles(cell):
+@pytest.mark.parametrize("cell, scope", [
+    ("mellum-train-swa16k", "attn."), ("moonlight-train-ep8share", "mla"),
+    ("zaya-train-cca16k", "cca.attend")])
+def test_a_layers_forward_runs_once_in_the_step_the_chip_compiles(cell,
+                                                                  scope):
     """The cell's step as the v5e's compiler leaves it, chip-less at the
     cell's REAL shape and compiled as the chip's own jit compiles it (one
     device, no mesh: the program's temporaries then come out to the byte
@@ -328,7 +330,12 @@ def test_a_layers_forward_runs_once_in_the_step_the_chip_compiles(cell):
     it: what the expert block's branches hand the layer behind them
     (_experts_by_count's barrier), and that a layer's first forward is op
     for op its recomputed one (_weight_of_row asks `pos`); PERF.md 6,
-    PR 41."""
+    PR 41.  In zaya-train-cca16k the compiler merges nothing (the
+    recomputation RUNS there), and the forward runs once all the same: the
+    site keeps its output and logsumexp through the layer's recomputation
+    (kernels/flash_attention.py::_flash_fwd, PR 44), so no second forward
+    is traced; the two older cells' merge has to survive that (the
+    recomputation reads the kept `out` where it called the kernel)."""
     import paddle_tpu as fluid
     from benchmark.harness import manifest
     from jax.sharding import SingleDeviceSharding
@@ -359,5 +366,6 @@ def test_a_layers_forward_runs_once_in_the_step_the_chip_compiles(cell):
         r'([^"]*/(?:fused|latent)_attention/[^"]*pallas_call)"', text)
     forward = [op for op in calls if "/flash.bwd/" not in op]
     assert len(forward) == cfg["num_hidden_layers"], forward
+    assert all(f"/{scope}" in op for op in forward), forward
     assert not any("rematted_computation" in op for op in forward)
     assert len(calls) > len(forward)                  # the backward's kernels

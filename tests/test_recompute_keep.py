@@ -4,7 +4,11 @@ from the first forward, so under the recurrence's checkpoint the backward
 runs no second chunk scan (no second index, search and attend kernel); the
 mathematics is the bare jax.checkpoint's and no checkpoint's; fewer than all
 three kept saves nothing; a unit whose ops name nothing lowers to what the
-bare jax.checkpoint gives; `recurrence.lower` counts the kept values."""
+bare jax.checkpoint gives; `recurrence.lower` counts the kept values.  The
+flash sites (PR 44) keep `out` and the logsumexp where their backward is the
+Pallas kernel: one forward kernel a site where the bare checkpoint has two,
+the bare checkpoint's numbers bit for bit; a site on the XLA recompute
+backward names nothing."""
 
 import hashlib
 import os
@@ -24,6 +28,9 @@ import paddle_tpu as fluid
 from paddle_tpu import models, observability
 from paddle_tpu.core import compiler
 from paddle_tpu.kernels import sparse_attention as dsa
+
+# (the package's `flash_attention` is the function)
+fa = sys.modules["paddle_tpu.kernels.flash_attention"]
 
 H, G, S, D, HI, DI, TOPK, TQ, TK = 4, 2, 64, 16, 3, 8, 8, 16, 8
 CHUNKS = S // TQ
@@ -194,6 +201,11 @@ FLASH = {
         n_head=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         kv_lora_rank=64, n_routed_experts=16, experts_held=4, expert_offset=4,
         top_k=3, d_expert=64)),
+    "compressed_decoder": (
+        models.compressed_decoder, models.CompressedDecoderConfig, dict(
+            vocab_size=64, max_length=512, n_layer=2, d_model=64, n_head=2,
+            n_kv_head=1, head_dim=128, rotary_dim=64, router_dim=16,
+            n_routed_experts=8, experts_held=4, d_expert=64)),
 }
 
 
@@ -221,7 +233,8 @@ def _step_for_the_tpu(build, config, rows=1):
                         lowering_platforms=("tpu",)).as_text()
         spans = {n: [dict(s.args) for s in
                      observability.default_tracer().spans() if s.name == n]
-                 for n in ("recurrence.lower", "dsa.lower")}
+                 for n in ("recurrence.lower", "dsa.lower", "attn.lower",
+                           "mla.lower")}
         return text, spans
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = False
@@ -229,7 +242,8 @@ def _step_for_the_tpu(build, config, rows=1):
 
 
 def _kernels(text) -> dict:
-    """Calls of each of kernels/sparse_attention.py's Pallas kernels."""
+    """Calls of each Pallas kernel in a step's StableHLO (a scan's body
+    stands there once, whatever its trips)."""
     names = re.findall(r'kernel_name = "(_\w+_kernel)"', text)
     return {n: names.count(n) for n in sorted(set(names))}
 
@@ -281,22 +295,183 @@ def test_without_recompute_the_tags_do_nothing():
     assert _kernels(text)["_fwd_kernel"] == SPARSE["n_layer"]
 
 
+def _flash_sites(model, config) -> int:
+    """Flash sites in one body of the model's recurrence."""
+    return config.n_layer if model == "looped_decoder" else 1
+
+
 @pytest.mark.parametrize("model", sorted(FLASH))
-def test_a_body_that_names_nothing_lowers_to_the_bare_checkpoints_step(
+def test_a_flash_site_keeps_out_and_lse_and_its_forward_is_traced_once(
         monkeypatch, model):
-    """Flash sites under a recomputed trip name nothing: `kept` 0, and the
-    step's StableHLO for the TPU is, character for character, the one the
-    bare jax.checkpoint(body, prevent_cse=False) gives."""
+    """Flash sites under a recomputed trip whose backward is the Pallas
+    kernel (S 512): `recurrence.lower` counts 2 kept values a site of its
+    body, the op's own span names them, and the step for the TPU holds one
+    `_flash_kernel` a site where the bare jax.checkpoint(body,
+    prevent_cse=False) holds two (a scan's body stands in the text once);
+    the backward kernels are the same."""
     build, config, sizes = FLASH[model]
-    text, spans = _step_for_the_tpu(build, config(**sizes), rows=2)
+    cfg = config(**sizes)
+    text, spans = _step_for_the_tpu(build, cfg, rows=2)
+    sites = _flash_sites(model, cfg)
+    assert spans["recurrence.lower"]
+    assert all(s["recompute"] == 1 and s["kept"] == len(fa.KEPT) * sites
+               for s in spans["recurrence.lower"])
+    ops = spans["attn.lower"] + spans["mla.lower"]
+    assert ops and all(s["kept"] == "out,lse" and s["kept_bytes"] > 0
+                       for s in ops)
+    assert "tpu_custom_call" in text
+    monkeypatch.setattr(compiler, "rematerialised", _bare)
+    bare, bare_spans = _step_for_the_tpu(build, cfg, rows=2)
+    # the tags are there under the bare checkpoint too, and save nothing
+    assert [s["kept"] for s in bare_spans["recurrence.lower"]] == \
+        [s["kept"] for s in spans["recurrence.lower"]]
+    kept, bare = _kernels(text), _kernels(bare)
+    assert kept["_flash_kernel"] == cfg.n_layer
+    assert bare["_flash_kernel"] == 2 * cfg.n_layer
+    assert kept["_flash_bwd_kernel"] == bare["_flash_bwd_kernel"] \
+        == cfg.n_layer
+
+
+@pytest.mark.parametrize("model", sorted(FLASH))
+def test_a_flash_site_on_the_xla_backward_names_nothing(monkeypatch, model):
+    """S 256: the shape keeps the XLA recompute backward (_bwd_plan), whose
+    forward emits no logsumexp: nothing is tagged, `kept` is 0 and "", and
+    the step's StableHLO for the TPU is, character for character, the one
+    the bare jax.checkpoint(body, prevent_cse=False) gives."""
+    build, config, sizes = FLASH[model]
+    cfg = config(**{**sizes, "max_length": 256})
+    text, spans = _step_for_the_tpu(build, cfg, rows=2)
     assert spans["recurrence.lower"]
     assert all(s["recompute"] == 1 and s["kept"] == 0
                for s in spans["recurrence.lower"])
+    ops = spans["attn.lower"] + spans["mla.lower"]
+    assert ops and all((s["kept"], s["kept_bytes"]) == ("", 0) for s in ops)
+    assert _kernels(text).get("_flash_bwd_kernel", 0) == 0
     assert "tpu_custom_call" in text
     monkeypatch.setattr(compiler, "rematerialised", _bare)
-    bare, _ = _step_for_the_tpu(build, config(**sizes), rows=2)
+    bare, _ = _step_for_the_tpu(build, cfg, rows=2)
     assert hashlib.sha256(text.encode()).hexdigest() == \
         hashlib.sha256(bare.encode()).hexdigest()
+
+
+def test_without_recompute_the_flash_tags_do_nothing():
+    """use_recompute false: the tags are there (`kept` 2 a site) and no
+    checkpoint is: one forward kernel a site either way."""
+    build, config, sizes = FLASH["compressed_decoder"]
+    cfg = config(**{**sizes, "use_recompute": False})
+    text, spans = _step_for_the_tpu(build, cfg, rows=2)
+    assert [(s["recompute"], s["kept"]) for s in spans["recurrence.lower"]] \
+        == cfg.n_layer * [(0, len(fa.KEPT))]
+    assert _kernels(text)["_flash_kernel"] == cfg.n_layer
+
+
+FS, FH, FG, FD, FDV = 256, 4, 2, 32, 16   # a flash site: 4 heads on 2
+
+
+def _flash_layer(force, window=None, hand_out=False):
+    """A fresh function a call: cheap ops a layer recomputes, the flash
+    call, a loss over its output (`hand_out`: and the call's output and
+    operands beside the loss)."""
+
+    def heads(x, n):
+        return jnp.swapaxes(x.reshape(2, FS, n, -1), 1, 2)
+
+    def layer(q, k, v):
+        q, k, v = (heads(jnp.tanh(x) * 1.5, n)
+                   for x, n in ((q, FH), (k, FG), (v, FG)))
+        out = fa.flash_attention(q, k, v, causal=True, force=force,
+                                 window=window)
+        loss = jnp.sum(out * jnp.cos(out))
+        return (loss, (out, q, k, v)) if hand_out else loss
+
+    return layer
+
+
+def _flash_inputs(seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(2, FS, n), jnp.float32)
+                 for n in (FH * FD, FG * FD, FG * FDV))
+
+
+def _pallas_calls(fn, args) -> dict:
+    """pallas_calls by kernel in the differentiated `fn` as traced."""
+    import collections
+
+    calls = collections.Counter()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 1, 2)))(*args)
+    _count(jaxpr.jaxpr, lambda e: e.primitive.name == "pallas_call"
+           and calls.update([e.params["jaxpr"].debug_info.func_name]))
+    return dict(calls)
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_the_flash_forward_is_traced_once_under_a_rematerialised_layer(
+        window):
+    args = _flash_inputs()
+    calls = {name: _pallas_calls(wrap(_flash_layer("interpret", window)),
+                                 args) for name, wrap in WRAPS.items()}
+    once = {"_flash_kernel": 1, "_flash_bwd_kernel": 1}
+    assert calls == {"none": once, "kept": once,
+                     "bare": {**once, "_flash_kernel": 2}}
+    # the XLA recompute backward: no kernel, nothing to keep
+    assert _pallas_calls(WRAPS["kept"](_flash_layer("jax", window)),
+                         args) == {}
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_loss_and_gradients_are_the_bare_checkpoints_bit_for_bit(
+        window):
+    """The backward reads the first forward's `out` and `lse` where the bare
+    checkpoint read a recomputed copy of both: the same kernel on the same
+    operands, so the same bits, loss and every gradient; and no
+    checkpoint's."""
+    args = _flash_inputs(2)
+    lowered = {name: jax.jit(jax.value_and_grad(
+        wrap(_flash_layer("interpret", window)), argnums=(0, 1, 2))).lower(
+            *args) for name, wrap in WRAPS.items()}
+    # no pass over the kept `out`: what jax puts on a saved float
+    assert "reduce_precision" not in lowered["kept"].as_text()
+    got = {name: low.compile()(*args) for name, low in lowered.items()}
+    loss, grads = got["kept"]
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads)
+    for name in ("none", "bare"):
+        want_loss, want = got[name]
+        assert np.array_equal(loss, want_loss), name
+        for g, r in zip(grads, want):
+            assert np.array_equal(g, r), name
+
+
+def test_the_kept_flash_values_are_the_first_forwards_bit_for_bit():
+    """The residuals jax holds across the rematerialised layer include the
+    `out` the same run's forward returned, as its bits (an integer array:
+    jax puts no reduce_precision pass on one), and the packed lse, bit for
+    bit against the kernel run apart on the same operands; the bare
+    checkpoint holds neither; `kept_bytes` is their size."""
+    args = _flash_inputs(1)
+    _, vjp, (out, q, k, v) = jax.vjp(
+        compiler.rematerialised(_flash_layer("interpret", hand_out=True),
+                                prevent_cse=False), *args, has_aux=True)
+    kept = jax.tree_util.tree_leaves(vjp)
+    bare = jax.tree_util.tree_leaves(
+        jax.vjp(WRAPS["bare"](_flash_layer("interpret")), *args)[1])
+    klen = jnp.full((2,), FS, jnp.float32)
+    _, lse = fa._pallas_flash(q, k, v, klen, True, FD ** -0.5,
+                              interpret=True)
+
+    def like(leaves, x):
+        return [np.asarray(l) for l in leaves
+                if l.shape == x.shape and l.dtype == x.dtype]
+
+    bits = np.asarray(out).view(np.uint32)
+    assert any(np.array_equal(l, bits) for l in like(kept, bits))
+    assert any(np.array_equal(l, lse) for l in like(kept, lse))
+    assert not like(kept, out)
+    assert not like(bare, bits) and not like(bare, out) \
+        and not like(bare, lse)
+    assert sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in (out, lse)) == fa.kept_bytes(q, v)
+    assert fa.kept(q, k, v, True, force="interpret") == fa.KEPT
+    assert fa.kept(q, k, v, True, force="jax") == ()
 
 
 def _op_step_chunk_scans(recompute: bool) -> tuple:

@@ -499,10 +499,12 @@ def test_attn_lower_and_the_flash_plans_say_what_a_site_was_given():
     spans = _spans_of_a_step(
         ("attn.lower", "flash.plan", "flash.bwd_plan", "moe.lower"),
         max_length=S, sliding_window=W)
+    keeps = dict(kept="out,lse", kept_bytes=4 * S * (16 * 2 + 4))
     sliding = dict(kind="sliding", window=W, heads=4, kv_heads=2, sq=S,
-                   pairs=W * (W + 1) // 2 + (S - W) * W, rope="plain")
+                   pairs=W * (W + 1) // 2 + (S - W) * W, rope="plain",
+                   **keeps)
     full = dict(kind="full", window=0, heads=4, kv_heads=2, sq=S,
-                pairs=S * (S + 1) // 2, rope="yarn")
+                pairs=S * (S + 1) // 2, rope="yarn", **keeps)
     sites = [s for s in spans["attn.lower"]]
     assert sites and all(s in (sliding, full) for s in sites)
     assert sum(s == sliding for s in sites) == 3 * sum(
@@ -523,6 +525,30 @@ def test_attn_lower_and_the_flash_plans_say_what_a_site_was_given():
     assert len(spans["moe.lower"]) >= 4
     assert all(m["scoring"] == "softmax" and m["experts_held"] == 4
                and m["experts_total"] == 16 for m in spans["moe.lower"])
+
+
+@pytest.mark.parametrize("S, W, kept", [(512, 128, "out,lse"),
+                                        (512, 1024, "out,lse"),
+                                        (256, 128, "")])
+def test_attn_lower_says_what_a_site_keeps_through_its_layers_recomputation(
+        S, W, kept):
+    """A site whose backward is the Pallas kernel (`flash.bwd_plan`'s
+    engine, from the shape) keeps its output and logsumexp, sliding or full,
+    a window longer than the keys read as none; one on the XLA recompute
+    backward (S 256) keeps nothing; `recurrence.lower` counts the values of
+    its one-site body."""
+    spans = _spans_of_a_step(
+        ("attn.lower", "flash.bwd_plan", "recurrence.lower"),
+        max_length=S, sliding_window=W)
+    sites = spans["attn.lower"]
+    assert {s["kind"] for s in sites} == (
+        {"sliding", "full"} if W < S else {"full"})
+    assert all(s["kept"] == kept and s["kept_bytes"] == (
+        4 * S * (16 * 2 + 4) if kept else 0) for s in sites)
+    assert {b["engine"] for b in spans["flash.bwd_plan"]} == {
+        "pallas" if kept else "xla"}
+    assert [r["kept"] for r in spans["recurrence.lower"]] == 4 * [
+        2 if kept else 0]
 
 
 def test_the_residual_writers_start_scaled_by_the_published_depth():
