@@ -1338,7 +1338,9 @@ def compressed_conv_qkv(q, k, v, conv_a_w, conv_a_b, conv_b_w, conv_b_b,
     on the first `rotary_dim` features of a head, and the second half of
     v's channels taken from the token before.  Returns (q [B, H, S, D], k
     and v [B, G, S, D]) for fused_attention (TPU-native;
-    ops/attention_ops.py compressed_conv_qkv)."""
+    ops/attention_ops.py compressed_conv_qkv: for a TPU one Pallas kernel
+    pair over tiles of rows where D is a multiple of 128 and S of a tile,
+    kernels/cca_mix.py, the same arithmetic in jax.numpy elsewhere)."""
     helper = LayerHelper("compressed_conv_qkv", input=q, name=name)
     outs = [helper.create_variable_for_type_inference(q.dtype)
             for _ in range(3)]

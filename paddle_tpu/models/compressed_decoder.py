@@ -13,7 +13,13 @@ CCA(u):               q~ = W_q u -> H x D, k~ = W_k u -> G x D, v~ = W_v u
                       convolutions along the sequence over [q~ ; k~], the
                       q-k mean added, heads normalised to sqrt(D), the keys
                       times tau, rotary on the first `rotary_dim` features,
-                      the second half of v~ from the token before.  Query
+                      the second half of v~ from the token before (for a
+                      TPU, at a head of 128 and an S of whole tiles of
+                      rows, ONE Pallas kernel forward and one backward,
+                      kernels/cca_mix.py, which keep nothing but the three
+                      projections through a layer's recomputation; the
+                      same arithmetic in jax.numpy at any other shape or
+                      backend).  Query
                       head j reads key/value head j // (H/G); causal softmax
                       of q.k / sqrt(D); o = W_o concat(P v), W_o [H D, d]
 Moe(x, r_prev):       s = W_dn x + b_dn -> R;  r = s + gamma * r_prev (gamma

@@ -133,6 +133,26 @@ def _yarn_inv_freq(inv_freq, dim, base, factor, original_length, beta_fast,
     return inv_freq * (1.0 - ramp) + inv_freq / factor * ramp
 
 
+def _inv_freq(dim, base, yarn=None):
+    """The dim / 2 pairs' frequencies base^(-2i/dim), under `yarn`
+    _yarn_inv_freq's; float64."""
+    inv_freq = float(base) ** (
+        -np.arange(dim // 2, dtype=np.float64) * 2.0 / dim)
+    if yarn is not None:
+        inv_freq = _yarn_inv_freq(
+            inv_freq, dim, float(base), float(yarn["factor"]),
+            float(yarn["original_length"]), float(yarn["beta_fast"]),
+            float(yarn["beta_slow"]))
+    return inv_freq
+
+
+def _rotary_angles(seq, dim, base, offset: int = 0, yarn=None):
+    """[seq, dim / 2] fp32: position offset + t times pair i's frequency."""
+    pos = np.arange(seq, dtype=np.float64) + int(offset)
+    return jnp.asarray(pos[:, None] * _inv_freq(dim, base, yarn)[None, :],
+                       jnp.float32)
+
+
 def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
             yarn=None, rotary_dim=None):
     """Rotary position embedding (Su et al. 2021) of x [..., S, D] along
@@ -154,15 +174,9 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
     out."""
     seq, dim = x.shape[-2], int(rotary_dim or x.shape[-1])
     half = dim // 2
-    inv_freq = float(base) ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
-    if yarn is not None:
-        inv_freq = _yarn_inv_freq(
-            inv_freq, dim, float(base), float(yarn["factor"]),
-            float(yarn["original_length"]), float(yarn["beta_fast"]),
-            float(yarn["beta_slow"]))
+    inv_freq = _inv_freq(dim, base, yarn)
     if positions is None:
-        pos = np.arange(seq, dtype=np.float64) + int(offset)
-        angle = jnp.asarray(pos[:, None] * inv_freq[None, :], jnp.float32)
+        angle = _rotary_angles(seq, dim, base, offset, yarn)
     else:
         if sum(sections) != half or len(sections) != positions.shape[1]:
             raise ValueError(f"sections {tuple(sections)} do not cut the "
@@ -359,9 +373,19 @@ def _compressed_conv_qkv(ctx, ins, attrs):
 
     QOut [B, H, S, D], KOut and VOut [B, G, S, D]: what fused_attention
     takes.  Statistics, sums and angles in fp32, the grouped convolution
-    on the AMP tier's operands; one jnp path on every backend, grouped
-    under the name scope `cca.mix`; `cca.lower` (a span, at lowering) says
-    what a site was given."""
+    on the AMP tier's operands, under the name scope `cca.mix`.  One
+    algorithm, its engine read from the site: for ONE TPU, where the shape
+    tiles (kernels/cca_mix.py::plan: D a multiple of 128, S of a tile of
+    rows, one dtype), the Pallas kernel pair of kernels/cca_mix.py, whose
+    backward keeps the op's inputs and nothing else; anywhere else (and on
+    a mesh of several devices, where XLA would have to partition the
+    kernel) compressed_conv_mix, the same arithmetic in jax.numpy.  `cca.lower` (a
+    span, at lowering) says what a site was given: `engine` (pallas |
+    xla), `tile` (the forward's rows a grid step, 0 under xla) and
+    `moved_bytes`, what the site's passes have to move through HBM (its
+    inputs and outputs: the forward, the forward again where the unit
+    around the site is rematerialised, the backward)."""
+    from ..kernels import cca_mix
     from ..kernels.flash_attention import _visible_pairs
 
     q, k, v = (data(ins[s][0]) for s in ("Q", "K", "V"))
@@ -374,11 +398,19 @@ def _compressed_conv_qkv(ctx, ins, attrs):
               latent_k=int(k.shape[-1]), conv_time0=int(a_w.shape[0]),
               conv_time1=int(b_w.shape[0]), conv_groups=int(b_w.shape[1]),
               rotary_dim=rotary_dim, sq=int(S),
-              pairs=_visible_pairs(S, S, True, None)), \
+              pairs=_visible_pairs(S, S, True, None),
+              moved_bytes=cca_mix.moved_bytes(
+                  q, k, v, bool(attrs.get("@recompute@")))) as sp, \
             jax.named_scope("cca.mix"):
-        outs = compressed_conv_mix(
+        # XLA cannot partition a Mosaic kernel (_shard_over_mesh): on a
+        # mesh of several devices the jax.numpy form, which it can
+        several = ctx.mesh is not None and ctx.mesh.num_devices > 1
+        outs, geo = cca_mix.mix(
             q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim,
-            float(attrs.get("rope_base", 10000.0)))
+            float(attrs.get("rope_base", 10000.0)),
+            force="jax" if several else "auto")
+        sp.set(engine="xla" if geo is None else "pallas",
+               tile=0 if geo is None else geo.fwd_tile)
     return dict(zip(("QOut", "KOut", "VOut"), ([o] for o in outs)))
 
 

@@ -235,7 +235,41 @@ def _sparse_attention():
     return jax.value_and_grad(loss, argnums=tuple(range(6))), args
 
 
+def _cca_mix(backward):
+    """zaya1-8b's sequence mixing at the cell's shape (8 query heads on 2
+    key/value heads of 128, S 16384, two taps a convolution, rotary 64,
+    bf16 on the keep tier's operands): kernels/cca_mix.py's forward at the
+    planned tile, and with it the backward (the forward's outputs weighted
+    by themselves, so that it stays in the program)."""
+    from paddle_tpu.core import amp
+    from paddle_tpu.kernels import cca_mix
+
+    S, H, G, D, n = 16384, 8, 2, 128, 10
+    args = (_sds((1, S, H * D), jnp.bfloat16),
+            _sds((1, S, G * D), jnp.bfloat16),
+            _sds((1, S, G * D), jnp.bfloat16), _sds((2, n * D), jnp.float32),
+            _sds((n * D,), jnp.float32), _sds((2, n, D, D), jnp.float32),
+            _sds((n * D,), jnp.float32), _sds((G,), jnp.float32))
+
+    def fwd(*a):
+        amp.enable_amp("bfloat16", keep_output=True)
+        try:
+            outs, geo = cca_mix.mix(*a, H, G, 64, 5e6, force="pallas")
+        finally:
+            amp.reset_amp()
+        assert geo is not None
+        return outs
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+        argnums=tuple(range(8))), args
+
+
 _MAIN_PATH_KERNELS = {
+    "cca_mix_fwd_zaya": lambda: _cca_mix(False),
+    "cca_mix_bwd_pallas_zaya": lambda: _cca_mix(True),
     "sparse_attention_bwd_pallas_keye": _sparse_attention,
     "flash_bwd_pallas_moonlight_192_128": _flash_mla,
     "held_experts_moonlight": _held_experts,

@@ -595,9 +595,13 @@ def test_the_spans_say_what_each_site_was_given():
          "flash.bwd_plan", "moe.lower", "recurrence.lower", "ce.lower"),
         max_length=S, train_router=False)
     pairs = S * (S + 1) // 2
+    # a head of 16 does not tile: the jax.numpy engine; the three passes
+    # (forward, the recomputed forward, backward) move the bf16 q~, k~, v~
+    # and as many bytes out, 2 + 2 + 3 times
     assert spans["cca.lower"] and all(s == dict(
         heads=4, kv_heads=2, latent_q=64, latent_k=32, conv_time0=2,
-        conv_time1=2, conv_groups=6, rotary_dim=8, sq=S, pairs=pairs)
+        conv_time1=2, conv_groups=6, rotary_dim=8, sq=S, pairs=pairs,
+        engine="xla", tile=0, moved_bytes=7 * S * (64 + 32 + 32) * 2)
         for s in spans["cca.lower"])
     assert spans["router.lower"] and all(s == dict(
         width=16, experts=8, carried=16, trained=0)
@@ -617,6 +621,62 @@ def test_the_spans_say_what_each_site_was_given():
     assert all(r["bodies_lowered"] == 1 and r["trips"] == 1
                for r in spans["recurrence.lower"])
     assert [c["classes"] for c in spans["ce.lower"]] == [64]
+
+
+@pytest.mark.parametrize("over, engine, tile, passes", [
+    (dict(head_dim=128, rotary_dim=64), "pallas", 512, 7),
+    (dict(head_dim=128, rotary_dim=64, use_recompute=False), "pallas", 512,
+     5),
+    (dict(head_dim=128, rotary_dim=64, max_length=320), "xla", 0, 7),
+    (dict(head_dim=64, rotary_dim=64), "xla", 0, 7)])
+def test_cca_lower_says_which_engine_a_site_was_given(over, engine, tile,
+                                                      passes):
+    """A head of 128 at an S of whole tiles lowers for the TPU to the
+    kernel pair of kernels/cca_mix.py (`engine` pallas, the forward's
+    `tile`); an S no tile divides or a head of 64 to the jax.numpy form;
+    `moved_bytes` counts the recomputed forward where the layer is
+    rematerialised."""
+    sizes = {"max_length": 512, **over}
+    spans = _spans_of_a_step(("cca.lower",), **sizes)["cca.lower"]
+    S, width = sizes["max_length"], (4 + 2 + 2) * sizes["head_dim"]
+    assert len(spans) == TINY["n_layer"]
+    assert all((s["engine"], s["tile"], s["moved_bytes"]) == (
+        engine, tile, passes * S * width * 2) for s in spans), spans
+
+
+def test_on_a_mesh_of_several_devices_the_site_takes_the_jnp_form():
+    """XLA cannot partition a Mosaic kernel: the data-parallel step over
+    four (virtual) devices lowers the shape that tiles to `engine` xla,
+    where one device's step lowers it to the kernel pair."""
+    from paddle_tpu.core.executor import _RunPlan
+    from paddle_tpu.parallel import ParallelExecutor, make_mesh
+
+    observability.reset()
+    fluid.flags._VALUES["FLAGS_observability"] = True
+    try:
+        fluid.reset_default_env()
+        spec = models.compressed_decoder(models.CompressedDecoderConfig(
+            **{**TINY, "max_length": 512, "head_dim": 128, "rotary_dim": 64}))
+        fluid.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(spec.loss)
+        fluid.Executor(fluid.CPUPlace()).run(fluid.default_startup_program())
+        mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+        pe = ParallelExecutor(loss_name=spec.loss.name, mesh=mesh)
+        batch, prog = spec.synthetic_batch(4, 0), fluid.default_main_program()
+        plan = _RunPlan(prog, sorted(batch), [spec.loss.name])
+        block0 = prog.desc.block(0)
+        observability.reset()
+        with fluid.flags.tpu_trace_scope(True), mesh.mesh:
+            jax.eval_shape(pe._compile(plan).fn, *(
+                tuple(plan.feed_values(batch, block0)),
+                tuple(plan.state_values(fluid.global_scope(), block0)),
+                plan.rng_value(fluid.global_scope(), prog)))
+        spans = [dict(s.args) for s in observability.default_tracer().spans()
+                 if s.name == "cca.lower"]
+    finally:
+        fluid.flags._VALUES["FLAGS_observability"] = False
+        observability.reset()
+    assert len(spans) == TINY["n_layer"]
+    assert all((s["engine"], s["tile"]) == ("xla", 0) for s in spans), spans
 
 
 @pytest.mark.parametrize("S, kept", [(512, "out,lse"), (256, "")])

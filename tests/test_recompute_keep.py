@@ -340,6 +340,11 @@ def test_a_flash_site_on_the_xla_backward_names_nothing(monkeypatch, model):
     the bare jax.checkpoint(body, prevent_cse=False) gives."""
     build, config, sizes = FLASH[model]
     cfg = config(**{**sizes, "max_length": 256})
+    # both lowerings from empty tracing caches: which of a step's jitted
+    # jax.numpy helpers share one private function goes by what the
+    # process traced before (found when a new test file ran ahead of this
+    # one: a second `_where` of the experts' counts, PR 45)
+    jax.clear_caches()
     text, spans = _step_for_the_tpu(build, cfg, rows=2)
     assert spans["recurrence.lower"]
     assert all(s["recompute"] == 1 and s["kept"] == 0
@@ -349,9 +354,29 @@ def test_a_flash_site_on_the_xla_backward_names_nothing(monkeypatch, model):
     assert _kernels(text).get("_flash_bwd_kernel", 0) == 0
     assert "tpu_custom_call" in text
     monkeypatch.setattr(compiler, "rematerialised", _bare)
+    jax.clear_caches()
     bare, _ = _step_for_the_tpu(build, cfg, rows=2)
     assert hashlib.sha256(text.encode()).hexdigest() == \
         hashlib.sha256(bare.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("recompute, forwards", [(True, 2), (False, 1)])
+def test_a_cca_mix_site_is_a_forward_its_recomputation_and_a_backward_kernel(
+        recompute, forwards):
+    """kernels/cca_mix.py's pair keeps the op's inputs and nothing else:
+    under a recomputed layer the step for the TPU holds the forward kernel
+    twice a layer (the layer's forward, and again for what the flash
+    backward reads: q^, k^, v) and the backward kernel once; without
+    recomputation once each.  (tests/test_cca_mix_kernel.py holds the
+    compiled step: at most one of the two forwards is left under
+    `rematted_computation`.)"""
+    build, config, sizes = FLASH["compressed_decoder"]
+    cfg = config(**{**sizes, "use_recompute": recompute})
+    text, _ = _step_for_the_tpu(build, cfg, rows=2)
+    kernels = _kernels(text)
+    assert kernels["_cca_mix_kernel"] == forwards * cfg.n_layer
+    assert kernels["_cca_mix_bwd_kernel"] == cfg.n_layer
+    assert kernels["_flash_kernel"] == cfg.n_layer
 
 
 def test_without_recompute_the_flash_tags_do_nothing():
