@@ -23,11 +23,7 @@ BENCH_LAYOUT ("NCHW"/"NHWC" conv internal layout; unset: "auto", NHWC for a
 TPU program; the chip's peak for MFU comes from DEVICE_PEAKS by
 device_kind),
 BENCH_DATA=pyreader (feed through the py_reader worker-thread pipeline
-instead of pre-staged device arrays — proves the data stack keeps up),
-BENCH_UNROLL (default 0; K>=2 = run K training steps per device dispatch
-via Executor.run_steps' lax.scan driver, amortizing per-call host
-latency — the AsyncExecutor whole-pass-per-call analogue; training
-models with dense feeds only).
+instead of pre-staged device arrays — proves the data stack keeps up).
 
 BENCH_LOWER_ONLY=1: per-model chip-less TPU lowering gate (no timed
 run).  BENCH_COST_ONLY=1: per-model bytes/step table from the TPU
@@ -495,66 +491,22 @@ def run_model(model: str, steps: int, peak_flops: float,
     def step_feed(i):
         return None if use_pyreader else batches[i % len(batches)]
 
-    unroll = int(os.environ.get("BENCH_UNROLL", "0"))
-    use_unroll = (
-        unroll >= 2 and run_program is None and not use_pyreader
-        and not any(isinstance(v, LoDValue) for v in batches_np[0].values())
-    )
-    if unroll >= 2 and not use_unroll:
-        sys.stderr.write(
-            f"# {model}: BENCH_UNROLL unsupported here (inference/pyreader/"
-            "LoD) — falling back to per-step dispatch\n")
-    if use_unroll and unroll % len(batches):
-        # the scan index restarts at 0 every dispatch; a non-multiple of
-        # the staged-batch count would starve the tail batches entirely
-        unroll += len(batches) - unroll % len(batches)
-        sys.stderr.write(
-            f"# {model}: BENCH_UNROLL rounded up to {unroll} "
-            f"(multiple of {len(batches)} staged batches)\n")
-    if use_unroll:
-        # K steps per dispatch: lax.scan over the staged batches (the
-        # already-device arrays — feeding batches_np would re-upload them
-        # inside the timed region).  Warmup compiles the scanned program;
-        # the timed region is whole run_steps calls, so per-dispatch
-        # latency is paid steps/K times
-        steps = max(unroll, (steps // unroll) * unroll)
-        feed_list = batches
-        # BENCH_UNROLL_MODE=flat: straight-line K-step jit (no lax.scan),
-        # for a backend that serializes while-loop iterations
-        umode = os.environ.get("BENCH_UNROLL_MODE", "scan")
-        (warm,) = exe.run_steps(feed_list=feed_list, fetch_list=[fetch_var],
-                                steps=unroll, return_numpy=False, mode=umode)
-        jax.block_until_ready(warm)
-        t0 = time.perf_counter()
-        loss_v = None
-        for k in range(steps // unroll):
-            (loss_v,) = exe.run_steps(
-                feed_list=feed_list, fetch_list=[fetch_var],
-                steps=unroll, return_numpy=False, mode=umode)
-            # cadence at dispatch granularity: every ~ckpt_every steps
-            if ckpt_mgr and ckpt_every and (
-                    (k + 1) % max(1, ckpt_every // unroll) == 0):
-                _ckpt_save(ckpt_base + (k + 1) * unroll,
-                           asynchronous=True)
-        jax.block_until_ready(loss_v)
-        dt = time.perf_counter() - t0
-    else:
-        warm = None
-        for i in range(len(batches) + 1):
-            (warm,) = exe.run(program=run_program, feed=step_feed(i),
-                              fetch_list=[fetch_var], return_numpy=False)
-        jax.block_until_ready(warm)
+    warm = None
+    for i in range(len(batches) + 1):
+        (warm,) = exe.run(program=run_program, feed=step_feed(i),
+                          fetch_list=[fetch_var], return_numpy=False)
+    jax.block_until_ready(warm)
 
-        t0 = time.perf_counter()
-        loss_v = None
-        for i in range(steps):
-            (loss_v,) = exe.run(program=run_program, feed=step_feed(i),
-                                fetch_list=[fetch_var], return_numpy=False)
-            if ckpt_mgr and ckpt_every and (i + 1) % ckpt_every == 0:
-                # async: snapshot now, write in the background
-                _ckpt_save(ckpt_base + i + 1, asynchronous=True)
-        jax.block_until_ready(loss_v)
-        dt = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    loss_v = None
+    for i in range(steps):
+        (loss_v,) = exe.run(program=run_program, feed=step_feed(i),
+                            fetch_list=[fetch_var], return_numpy=False)
+        if ckpt_mgr and ckpt_every and (i + 1) % ckpt_every == 0:
+            # async: snapshot now, write in the background
+            _ckpt_save(ckpt_base + i + 1, asynchronous=True)
+    jax.block_until_ready(loss_v)
+    dt = time.perf_counter() - t0
     if ckpt_mgr:
         # final synchronous checkpoint outside the timed region: the run
         # is resumable from its end state (joins the in-flight async
@@ -586,14 +538,12 @@ def run_model(model: str, steps: int, peak_flops: float,
         # which input path actually ran (pyreader silently falls back for
         # inference programs / LoD batches)
         "data": "pyreader" if use_pyreader else "staged",
-        "unroll": unroll if use_unroll else 1,
     }
     if ckpt_mgr:
         # attribution: async checkpoint writers shared the host with the
         # timed region, so resumable numbers are labeled as such
         result["ckpt_every"] = ckpt_every
-    if (os.environ.get("BENCH_COST", "0") == "1" and not use_unroll
-            and not use_pyreader):
+    if os.environ.get("BENCH_COST", "0") == "1" and not use_pyreader:
         # XLA cost accounting of the exact compiled step: bytes/step is
         # the number that validates (or corrects) paper HBM-traffic
         # floors like the 65 GB ResNet-50 estimate.  Opt-in: the
@@ -612,8 +562,6 @@ def run_model(model: str, steps: int, peak_flops: float,
     if model in ("transformer", "transformer_longctx"):
         feats["fuse_smooth_ce"] = cfg.fuse_smooth_ce
         feats["recompute"] = cfg.use_recompute
-    if use_unroll:
-        feats["unroll_mode"] = os.environ.get("BENCH_UNROLL_MODE", "scan")
     if feats:
         result["features"] = feats
     return result

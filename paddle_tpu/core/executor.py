@@ -191,12 +191,11 @@ def _check_nan_inf(plan, fetches, new_states) -> None:
 
 def cached_entry(cache, key, fp, build, use_cache: bool = True):
     """The ONE copy of the fingerprint-validated lookup both executors'
-    run and run_steps make: (entry, hit), the entry being
-    (fp,) + build() on a miss, built under the `compile` span.  An
-    in-place desc mutation (another fp under the same key) rebuilds and
-    replaces the stale entry.  (The reference keys on the Program object,
-    executor.py _get_program_cache — unsound here because descs mutate in
-    place.)"""
+    run makes: (entry, hit), the entry being (fp,) + build() on a miss,
+    built under the `compile` span.  An in-place desc mutation (another fp
+    under the same key) rebuilds and replaces the stale entry.  (The
+    reference keys on the Program object, executor.py _get_program_cache —
+    unsound here because descs mutate in place.)"""
     entry = cache.get(key) if use_cache else None
     hit = entry is not None and entry[0] == fp
     if not hit:
@@ -224,9 +223,9 @@ def in_place(v, want) -> bool:
 
 
 def stage_values(vals, wants):
-    """The ONE staging rule of Executor and ParallelExecutor, run and
-    run_steps: (values, wanted placement) -> (staged values, how many
-    were placed).  `wants` is one Sharding for every value or one for each.
+    """The ONE staging rule of Executor and ParallelExecutor:
+    (values, wanted placement) -> (staged values, how many were placed).
+    `wants` is one Sharding for every value or one for each.
     A value in place goes on as the very object it came as; only the rest
     go to jax.device_put, in one call.  That call is what keeps a step to
     ONE executable: committed-ness is part of jax's lowering key, so a
@@ -284,10 +283,9 @@ _STEP_SEQ = itertools.count()
 
 
 def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
-             return_numpy, donated=False, steps=None, sentinel=None,
-             cost=None):
-    """The ONE copy of a step's sequence, for Executor and ParallelExecutor,
-    run and run_steps: plan, stage, dispatch, commit, fetch, each a span
+             return_numpy, donated=False, sentinel=None, cost=None):
+    """The ONE copy of a step's sequence, for Executor and
+    ParallelExecutor: plan, stage, dispatch, commit, fetch, each a span
     under `executor.step` (observability/tracing.py: on the profiler's
     clock always, in the ring under FLAGS_observability).  Where the fetch
     converts to the host it is `executor.wait` (until every fetched value
@@ -329,8 +327,6 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             sp.set(cache="hit" if hit else "miss")
         n_given = len(plan.feed_names) + len(state_vals) + 1
         step.set(n_state=len(state_vals), n_feed=len(plan.feed_names))
-        if steps is not None:
-            step.set(steps=steps)
         with _obs.span("executor.stage") as sp:
             feed_vals, state_vals, rng, moved = stage(
                 plan, block0, feed_vals, state_vals, rng)
@@ -373,121 +369,12 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
                 out = plan.convert_fetches(fetches, block0, False)
             sp.set(n=len(out))
     if step.seconds is not None:  # FLAGS_observability
-        if steps is None:
-            _obs.record_executor_step(step.seconds, donated=donated,
-                                      skipped=skipped)
-        else:
-            _obs.default_registry().histogram(
-                "paddle_tpu_executor_run_steps_seconds",
-                "run_steps wall time per K-step dispatch",
-            ).observe(step.seconds, steps=str(steps))
+        _obs.record_executor_step(step.seconds, donated=donated,
+                                  skipped=skipped)
         _obs.record_device_memory(device)
         if cost_args is not None:
             cost(entry, *cost_args)
     return out
-
-
-def scan_multi_fn(body, n_batches, steps, flat: bool = False):
-    """Multi-step scan closure shared by Executor.run_steps and
-    ParallelExecutor.run_steps: step i feeds batch i % n_batches; the
-    LAST step's fetches ride in the carry (not scan ys — stacking
-    steps x fetch would hold every step's outputs in HBM); fetch shapes
-    come from eval_shape, no extra compilation.
-
-    flat=True replaces lax.scan with a Python-unrolled chain of `steps`
-    body calls in ONE jit: a straight-line program with no while loop.
-    Compile time grows with `steps`, but backends whose dispatch layer
-    serializes loop iterations execute the flat form as a single
-    program — the amortization run_steps exists for.  Keep
-    `steps` modest (<= ~16) to bound compile time."""
-
-    def flat_multi(feeds_stack, state_vals, rng):
-        states, k = state_vals, rng
-        fetches = None
-        for i in range(steps):
-            batch = tuple(
-                jax.lax.index_in_dim(f, i % n_batches, keepdims=False)
-                for f in feeds_stack
-            )
-            fetches, states, k = body(batch, states, k)
-        return fetches, states, k
-
-    if flat:
-        return flat_multi
-
-    def multi(feeds_stack, state_vals, rng):
-        def take(i):
-            return tuple(
-                jax.lax.dynamic_index_in_dim(f, i % n_batches, keepdims=False)
-                for f in feeds_stack
-            )
-
-        def step(carry, i):
-            states, k, _ = carry
-            fetches, states, k = body(take(i), states, k)
-            return (states, k, fetches), None
-
-        fetch_shapes = jax.eval_shape(
-            body, take(jax.numpy.int32(0)), state_vals, rng
-        )[0]
-        init_fetch = tuple(
-            jax.numpy.zeros(s.shape, s.dtype) for s in fetch_shapes
-        )
-        (states, k, last), _ = jax.lax.scan(
-            step, (state_vals, rng, init_fetch),
-            np.arange(steps, dtype=np.int32),
-        )
-        return last, states, k
-
-    return multi
-
-
-def stacked_feeds(cache, stack_key, fp, plan, feed_list, block0, wants):
-    """Stack per-step feeds into [K, ...] device arrays placed as `wants`
-    (stage_values) -> (the stack, how many arrays were placed), with an
-    identity-keyed cache: repeated calls with the SAME feed objects (a
-    training loop cycling one staged list) reuse the stacked copy instead
-    of paying conversion + stack + transfer per call.  Only immutable
-    feeds (jax.Array) are cacheable — a host-numpy buffer can be refilled
-    in place between calls, which would silently replay stale data.  The
-    cache pins the array OBJECTS themselves and revalidates by identity
-    (not raw id() values, which CPython can recycle)."""
-    cacheable = all(
-        isinstance(feed[n], jax.Array)
-        for feed in feed_list for n in plan.feed_names
-    )
-    feed_arrays = tuple(
-        tuple(feed[n] for n in plan.feed_names) for feed in feed_list
-    )
-    cached = cache.get(stack_key) if cacheable else None
-    if (
-        cached is not None
-        and cached[0] == fp
-        and len(cached[2]) == len(feed_arrays)
-        and all(
-            a is b
-            for row_a, row_b in zip(cached[2], feed_arrays)
-            for a, b in zip(row_a, row_b)
-        )
-    ):
-        return cached[1], 0
-    batches = []
-    for feed in feed_list:
-        vals = plan.feed_values(feed, block0)
-        for n, v in zip(plan.feed_names, vals):
-            if isinstance(v, LoDValue):
-                raise TypeError(
-                    f"run_steps cannot scan LoD feed '{n}'; run per step "
-                    "for ragged batches"
-                )
-        batches.append(vals)
-    feeds_stack, moved = stage_values(tuple(
-        jax.numpy.stack([b[i] for b in batches])
-        for i in range(len(plan.feed_names))
-    ), wants)
-    if cacheable:
-        cache[stack_key] = (fp, feeds_stack, feed_arrays)
-    return feeds_stack, moved
 
 
 class Executor:
@@ -781,126 +668,6 @@ class Executor:
             state_vals = plan.state_values(scope, block0)
             rng = plan.rng_value(scope, program)
             return compiled.tpu_lowering_check(feed_vals, state_vals, rng)
-
-    def run_steps(
-        self,
-        program: Optional[Program] = None,
-        feed_list: Optional[Sequence[Dict[str, Any]]] = None,
-        fetch_list: Optional[Sequence] = None,
-        steps: Optional[int] = None,
-        scope: Optional[Scope] = None,
-        return_numpy: bool = True,
-        mode: str = "scan",
-    ) -> List[Any]:
-        with _obs.span("executor.run"), \
-                flags.tpu_trace_scope(
-                    device_is_tpu(self.place.jax_device())):
-            return self._run_steps_scoped(
-                program, feed_list, fetch_list, steps, scope, return_numpy,
-                mode)
-
-    def _run_steps_scoped(
-        self,
-        program,
-        feed_list,
-        fetch_list,
-        steps,
-        scope,
-        return_numpy,
-        mode="scan",
-    ) -> List[Any]:
-        """Run `steps` iterations in ONE device dispatch.
-
-        The compiled block body is wrapped in a `lax.scan` whose carry is
-        (persistable state, rng); step i feeds `feed_list[i % len(feed_list)]`
-        (batches are stacked on device once).  Returns the LAST step's
-        fetches.  Per-call host/dispatch latency is paid once per `steps`
-        instead of once per step — the reference gets the same amortization
-        from whole-pass calls (AsyncExecutor::RunFromFile,
-        framework/async_executor.h:59) and in-graph reader pipelines
-        (operators/reader/create_double_buffer_reader_op.cc).
-
-        Feeds must be dense arrays of one shape per name (no LoD values —
-        scan requires shape-stable carries/slices).
-
-        FLAGS_check_nan_inf runs once per CALL here (last step's fetches +
-        final state), not once per step as Executor.run does: a transient
-        mid-scan nan in a fetched value whose state recovers will not
-        raise.  The FLAGS_check_numerics skip-step sentinel likewise only
-        guards per-step run() — a K-step dispatch cannot un-apply one bad
-        inner step.  Debug non-finite trajectories with per-step run().
-        """
-        if program is not None and hasattr(program, "with_data_parallel"):
-            raise TypeError(
-                "run_steps takes a plain Program; wrap multi-device runs "
-                "with ParallelExecutor and per-step run() instead of a "
-                "CompiledProgram"
-            )
-        program = program or default_main_program()
-        if not feed_list:
-            raise ValueError("run_steps requires a non-empty feed_list")
-        steps = int(steps if steps is not None else len(feed_list))
-        if steps < 1:
-            raise ValueError("run_steps requires steps >= 1")
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
-
-        feed_names = sorted(feed_list[0])
-        for i, feed in enumerate(feed_list):
-            if sorted(feed) != feed_names:
-                raise ValueError(
-                    f"run_steps feed_list[{i}] keys {sorted(feed)} differ "
-                    f"from feed_list[0] keys {feed_names}; every step must "
-                    "feed the same variables"
-                )
-        fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
-        block0 = program.desc.block(0)
-
-        if mode not in ("scan", "flat"):
-            raise ValueError(f"run_steps mode must be 'scan' or 'flat', "
-                             f"got {mode!r}")
-        key = ("run_steps", id(program), steps, len(feed_list),
-               tuple(feed_names), tuple(fetch_names), amp.state_key(),
-               flags.trace_key(), mode)
-        fp = None  # the plan phase takes the fingerprint, stage reads it
-
-        def lookup():
-            nonlocal fp
-            fp = program.desc.fingerprint()
-            return cached_entry(self._cache, key, fp, build)
-
-        def build():
-            plan = _RunPlan(program, feed_names, fetch_names)
-            compiled = CompiledBlock(
-                program, 0, plan.feed_names, plan.fetch_names,
-                plan.state_names, donate_states=False,
-            )
-            return jax.jit(
-                scan_multi_fn(compiled.raw_fn, len(feed_list), steps,
-                              flat=(mode == "flat")),
-                # plain self.donate_states: the skip-step sentinel never
-                # guards the scan path (see docstring), and its carry
-                # always writes back — keeping pre-step buffers alive
-                # here would double state HBM for zero benefit
-                donate_argnums=(1,) if self.donate_states else (),
-            ), plan
-
-        device = self.place.jax_device()
-        want = jax.sharding.SingleDeviceSharding(device)
-
-        def stage(plan, block0, feed_list, state_vals, rng):
-            feeds_stack, stacked = stacked_feeds(
-                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-                want)
-            _, state_vals, rng, moved = staged_args(
-                (), state_vals, rng, want)
-            return feeds_stack, state_vals, rng, stacked + moved
-
-        return run_step(
-            "serial", program, scope, lookup,
-            lambda plan, block0: feed_list,
-            stage, jax.default_device(device), device, return_numpy,
-            steps=steps)
 
     @staticmethod
     def _restore_declared_dtype(arr: np.ndarray, var_desc) -> np.ndarray:

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
 
 import dataclasses
@@ -30,8 +29,6 @@ from ..core.executor import (
     cached_entry,
     in_place,
     run_step,
-    scan_multi_fn,
-    stacked_feeds,
     staged_args,
 )
 from ..core.framework import Program, Variable, default_main_program
@@ -249,132 +246,6 @@ class ParallelExecutor:
                                  self.program.desc.fingerprint(), build),
             feeds, stage, self.mesh.mesh, self._first_device(),
             return_numpy, donated=True)
-
-    def run_steps(
-        self,
-        feed_list: Optional[Sequence[Dict[str, Any]]] = None,
-        fetch_list: Optional[Sequence] = None,
-        steps: Optional[int] = None,
-        return_numpy: bool = True,
-        mode: str = "scan",
-    ) -> List[Any]:
-        with _obs.span("executor.run"), \
-                flags.tpu_trace_scope(self._mesh_is_tpu()):
-            return self._run_steps_scoped(
-                feed_list, fetch_list, steps, return_numpy, mode)
-
-    def _run_steps_scoped(
-        self,
-        feed_list=None,
-        fetch_list=None,
-        steps=None,
-        return_numpy=True,
-        mode="scan",
-    ) -> List[Any]:
-        """Run `steps` SPMD iterations in ONE device dispatch: the compiled
-        block body runs under `lax.scan` inside a single pjit over the mesh,
-        so per-step host dispatch (the dominant overhead on fast chips)
-        is paid once per call.  Mirrors Executor.run_steps (see its
-        docstring for the feed-cycling, fetch and check_nan_inf contract);
-        feeds keep their usual shardings with a replicated leading steps
-        dim, persistable state round-trips in its sharding.  Dense feeds
-        only (scan needs shape-stable slices)."""
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        from ..core import amp
-        from .multihost import is_multiprocess
-
-        if is_multiprocess(self.mesh):
-            # per-process shard assembly (run()'s global_feed_value path)
-            # has no scan equivalent yet; fail clearly instead of letting
-            # jax reject non-addressable arrays mid-call
-            raise NotImplementedError(
-                "ParallelExecutor.run_steps is single-process only; on a "
-                "multi-host mesh call run() per step"
-            )
-        if not feed_list:
-            raise ValueError("run_steps requires a non-empty feed_list")
-        steps = int(steps if steps is not None else len(feed_list))
-        if steps < 1:
-            raise ValueError("run_steps requires steps >= 1")
-        feed_names = sorted(feed_list[0])
-        for i, feed in enumerate(feed_list):
-            if sorted(feed) != feed_names:
-                raise ValueError(
-                    f"run_steps feed_list[{i}] keys {sorted(feed)} differ "
-                    f"from feed_list[0] keys {feed_names}"
-                )
-        fetch_names = [
-            v.name if isinstance(v, Variable) else str(v)
-            for v in (fetch_list or [])
-        ]
-        block0 = self.program.desc.block(0)
-
-        if mode not in ("scan", "flat"):
-            raise ValueError(f"run_steps mode must be 'scan' or 'flat', "
-                             f"got {mode!r}")
-        key = ("pe_run_steps", steps, len(feed_list), tuple(feed_names),
-               tuple(fetch_names), amp.state_key(), flags.trace_key(), mode)
-
-        fp = None  # the plan phase takes the fingerprint, stage reads it
-
-        def lookup():
-            nonlocal fp
-            fp = self.program.desc.fingerprint()
-            return cached_entry(self._cache, key, fp, build)
-
-        def build():
-            plan = _RunPlan(self.program, feed_names, fetch_names)
-            compiled = CompiledBlock(
-                self.program, 0, plan.feed_names, plan.fetch_names,
-                plan.state_names, donate_states=False, mesh=self.mesh,
-            )
-            multi = scan_multi_fn(compiled.raw_fn, len(feed_list), steps,
-                                  flat=(mode == "flat"))
-            state_sh = tuple(
-                self._state_sharding(n, block0) for n in plan.state_names
-            )
-            stack_sh = tuple(
-                NamedSharding(
-                    self.mesh.mesh,
-                    PartitionSpec(
-                        None, *self._feed_sharding(n, block0).spec
-                    ),
-                )
-                for n in plan.feed_names
-            )
-            plan.shardings = (
-                stack_sh, state_sh + (self.mesh.replicated(),))
-            return jax.jit(
-                multi,
-                in_shardings=(stack_sh, state_sh, self.mesh.replicated()),
-                out_shardings=(
-                    tuple(self.mesh.replicated() for _ in plan.fetch_names),
-                    state_sh,
-                    self.mesh.replicated(),
-                ),
-                donate_argnums=(1,),
-            ), plan
-
-        def stage(plan, block0, feed_list, state_vals, rng):
-            stack_sh, state_sh = plan.shardings
-            # before the stack is placed: jax's own error for a batch that
-            # does not divide is not the framework's
-            self._check_batch_divisible(
-                plan.feed_names, plan.feed_values(feed_list[0], block0),
-                block0)
-            feeds_stack, stacked = stacked_feeds(
-                self._cache, key + ("feeds",), fp, plan, feed_list, block0,
-                stack_sh)
-            _, state_vals, rng, moved = staged_args(
-                (), state_vals, rng, state_sh)
-            return feeds_stack, state_vals, rng, stacked + moved
-
-        return run_step(
-            "spmd", self.program, self.scope, lookup,
-            lambda plan, block0: feed_list,
-            stage, self.mesh.mesh, self._first_device(), return_numpy,
-            donated=True, steps=steps)
 
     def _first_device(self):
         return np.asarray(self.mesh.mesh.devices).ravel()[0]

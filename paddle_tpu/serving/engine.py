@@ -345,7 +345,8 @@ class Engine:
         # cycles (and parks in bounded waits), so an Engine that is
         # dropped without close() is garbage-collected and its thread
         # exits within ~_IDLE_PARK_S instead of leaking both forever.
-        self._spawn_dispatcher()
+        with self._lock:
+            self._spawn_dispatcher_locked()
 
     def _flight_record(self, kind: str, **fields) -> None:
         """One engine lifecycle event into the flight recorder, labeled
@@ -354,11 +355,27 @@ class Engine:
             fields.setdefault("replica", self.replica)
         _flight.default_flight().record(kind, engine=self.name, **fields)
 
-    def _spawn_dispatcher(self) -> None:
+    def _spawn_dispatcher_locked(self) -> None:
+        # under the lock, as everyone who asks `self._thread.is_alive()`
+        # is: between the assignment and start() the new thread reads as
+        # dead, and the thread itself may die (and look) at once
         self._thread = threading.Thread(
             target=_dispatch_entry, args=(weakref.ref(self),),
             name=f"serving-{self.name}", daemon=True)
         self._thread.start()
+
+    def _restart_dead_dispatcher_locked(self) -> bool:
+        """The ONE place a dead dispatcher is found dead, counted and
+        replaced, under the lock: by the supervisor (which runs on the
+        dying thread itself) or by a submit() that finds the thread gone.
+        Whoever comes second finds the new thread alive and does
+        nothing.  -> whether this caller restarted it."""
+        thread = self._thread
+        if thread.is_alive() and thread is not threading.current_thread():
+            return False
+        self._dispatcher_restarts += 1
+        self._spawn_dispatcher_locked()
+        return True
 
     # -- submission ----------------------------------------------------
 
@@ -504,9 +521,8 @@ class Engine:
                         "deadline_shed", obs_on)
             # a dispatcher that died without its supervisor running
             # (never under normal faults) must not strand the queue
-            if not self._stopped and not self._thread.is_alive():
-                self._dispatcher_restarts += 1
-                self._spawn_dispatcher()
+            if not self._stopped:
+                self._restart_dead_dispatcher_locked()
             self._queue.append(req)
             depth = len(self._queue)
             if obs_on:
@@ -1056,7 +1072,8 @@ class Engine:
                 return
             else:
                 leftovers = None
-                self._dispatcher_restarts += 1
+                queued = len(self._queue)
+                restarted = self._restart_dead_dispatcher_locked()
         if leftovers is not None:
             _log.warning(
                 "engine '%s': replica killed by chaos; failing %d "
@@ -1069,17 +1086,17 @@ class Engine:
             for r in leftovers:  # outside the lock: done-callbacks
                 self._fail(r, EngineInternalError(exc))
             return
+        if not restarted:  # another dispatcher is alive already
+            return
         _log.warning(
-            "engine '%s': dispatcher thread died (%s: %s); restarting "
+            "engine '%s': dispatcher thread died (%s: %s); restarted "
             "with %d queued requests preserved", self.name,
-            type(exc).__name__, exc, self.queue_depth())
+            type(exc).__name__, exc, queued)
         if _flags._VALUES["FLAGS_observability"]:
             _smetrics.record_dispatcher_restart()
             self._flight_record(
                 "dispatcher_restart",
-                error=f"{type(exc).__name__}: {exc}",
-                queued=self.queue_depth())
-        self._spawn_dispatcher()
+                error=f"{type(exc).__name__}: {exc}", queued=queued)
 
     # -- health ---------------------------------------------------------
 

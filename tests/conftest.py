@@ -21,16 +21,18 @@ import pytest
 
 
 def pytest_configure(config):
-    # tier-1 runs `-m 'not slow'` (ROADMAP.md): heavy multiprocess chaos /
-    # long-soak tests opt out with `slow`; `chaos` tags the
+    # tier-1 runs `-m 'not slow'` (ROADMAP.md): multi-process soaks and
+    # real-size chip-less compiles opt out with `slow`; `chaos` tags the
     # fault-injection resilience suite so it can be run alone
     # (`-m chaos`).  `timeout` is pytest-timeout's marker when that
     # plugin is present; registering it here keeps the suite
     # warning-clean when it isn't.
     config.addinivalue_line(
         "markers",
-        "slow: heavy multiprocess/long tests, excluded from tier-1 "
-        "(-m 'not slow')")
+        "slow: excluded from tier-1 (-m 'not slow'): multi-process soaks, "
+        "and real-size chip-less compiles of what a benchmark cell's "
+        "`correct` and rate already hold on the chip.  Not a way to make "
+        "room: a tier-1 test over 60 s is resized (ROADMAP D16)")
     config.addinivalue_line(
         "markers", "chaos: fault-injection resilience tests")
     config.addinivalue_line(

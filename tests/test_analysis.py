@@ -512,6 +512,26 @@ def test_lint_cli_list_and_bank_refusal(tmp_path, capsys):
     capsys.readouterr()
 
 
+@pytest.mark.parametrize("argv", [
+    ["--programs", "paged_decode"], ["--detectors", "host-sync"],
+    ["--inject", "weak_type"]], ids=["programs", "detectors", "inject"])
+def test_lint_cli_refuses_a_narrowed_bank_before_it_compiles_the_zoo(
+        argv, tmp_path, monkeypatch, capsys):
+    """--bank of a filtered or injected run is refused (exit 2) before the
+    zoo is lowered and compiled, not after: with --detectors alone the
+    whole zoo used to run first (~1 min) only to be refused."""
+    _skip_if_no_topology()
+
+    def no_zoo(*a, **k):
+        raise AssertionError("the zoo ran before the refusal")
+
+    monkeypatch.setattr(analysis, "run_zoo", no_zoo)
+    assert _lint_main(argv + ["--bank", "--baseline",
+                              str(tmp_path / "b.json")]) == 2
+    assert "refusing to --bank" in capsys.readouterr().err
+    assert not (tmp_path / "b.json").exists()
+
+
 def test_lint_cli_gate_round_trip_and_regression(tmp_path, capsys):
     """bank -> re-gate passes; injected corpus program exits 3; a banked
     baseline with smaller bytes/step (i.e. the tree regressed) exits 3."""

@@ -217,3 +217,18 @@ def test_bench_main_passes_unset_amp_and_layout_through(monkeypatch, capsys):
     bench.main()
     capsys.readouterr()
     assert seen == [(None, None), ("keep", "NHWC")]
+
+
+@pytest.mark.parametrize("name", ["BENCH_UNROLL", "BENCH_UNROLL_MODE"])
+def test_bench_reads_no_steps_a_dispatch_variable(name):
+    """bench.py steps through Executor.run alone: the two variables that
+    chose K steps a dispatch (and how they were unrolled) are read nowhere
+    in it, so setting one changes nothing."""
+    import re
+
+    import bench
+
+    with open(bench.__file__) as f:
+        read = set(re.findall(r"BENCH_[A-Z0-9_]+", f.read()))
+    assert name not in read
+    assert "BENCH_STEPS" in read  # the scan finds what the file does read

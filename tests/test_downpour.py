@@ -90,12 +90,19 @@ def test_downpour_trains_end_to_end(tmp_path):
     ~log(2) cold start, rows materialize lazily, checkpoints round-trip
     (reference flow: async_executor.py init_server/init_worker/run)."""
     fluid.reset_default_env()
+    fluid.default_startup_program().random_seed = 1  # the dense init
     loss = _build_ctr_model()
     ps_param, _ = DownpourSGD(learning_rate=0.2, window=1).minimize(loss)
-    # dense adam's desc default LR is pserver-scale tiny; crank it for test
+    # the dense table's rule has no bias correction (beta1 0.99, beta2
+    # 0.9999): its first steps are lr whole, whatever the gradient's size.
+    # From rows of +-1e-4 that is what decides the run: at 0.05 the hidden
+    # biases outrun the embeddings, every relu can die in the first
+    # batches, and whether it does hangs on how the two Hogwild threads
+    # interleave (PR 46: 5 of 6 lone runs stayed at log 2).  At 0.005 the
+    # rows grow first and every interleaving converges (0.69 -> under 0.05)
     ps_param["server_param"]["downpour_server_param"][
         "downpour_table_param"][1]["accessor"]["dense_sgd_param"]["adam"][
-        "learning_rate"] = 0.05
+        "learning_rate"] = 0.005
 
     exe = fluid.AsyncExecutor(fluid.CPUPlace())
     exe.init_server(ps_param)
@@ -144,6 +151,30 @@ def test_downpour_trains_end_to_end(tmp_path):
         ps2.sparse(0).pull(ids), exe._ps.sparse(0).pull(ids), rtol=1e-6
     )
     np.testing.assert_allclose(ps2.dense(1).pull(), exe._ps.dense(1).pull())
+
+
+def test_dense_rule_steps_by_its_rate_whatever_the_gradients_size():
+    """Why test_downpour_trains_end_to_end's dense rate is 0.005 and not
+    0.05: the dense table's rule (the reference's, beta1 0.99, beta2
+    0.9999, no bias correction) moves a coordinate by the rate itself on
+    its first push and by rate x sqrt(n) after n pushes of one sign,
+    whether the gradient is 1e-4 or 1: from embedding rows of +-1e-4 the
+    hidden biases move ~0.05 a step while the rows' signal is ~1e-5."""
+    from paddle_tpu.distributed.ps_core import DenseTable
+
+    for g in (1e-4, 1.0):
+        t = DenseTable(dim=1, learning_rate=0.05)
+        t.init(np.zeros(1, np.float32))
+        t.push(np.full(1, g, np.float32))
+        # sqrt(ada) is 1e-2 x g after one push: ada_epsilon (1e-8) is 1%
+        # of it at g = 1e-4
+        np.testing.assert_allclose(t.pull(), -0.05, rtol=2e-2)
+        for _ in range(15):
+            t.push(np.full(1, g, np.float32))
+        step = t.pull().copy()
+        t.push(np.full(1, g, np.float32))
+        np.testing.assert_allclose(step - t.pull(), 0.05 * np.sqrt(17),
+                                   rtol=0.1)
 
 
 def test_sparse_table_uint64_ids_checkpoint(tmp_path):

@@ -251,97 +251,22 @@ def test_amp_keep_output_layer_norm_parity():
     assert got[-1] < got[0]
 
 
-def test_run_steps_matches_stepwise_run():
-    """run_steps (K iterations in one lax.scan dispatch) must reproduce the
-    step-by-step Executor.run trajectory exactly: same params, same loss,
-    same RNG advancement."""
-    x = fluid.layers.data("x", [4], dtype="float32")
-    label = fluid.layers.data("label", [1], dtype="float32")
-    pred = fluid.layers.fc(x, size=1, param_attr=fluid.ParamAttr(name="rs_w"))
-    loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, label))
-    fluid.optimizer.SGD(learning_rate=0.05).minimize(loss)
-
-    rng = np.random.RandomState(7)
-    feeds = [
-        {"x": rng.rand(8, 4).astype(np.float32),
-         "label": rng.rand(8, 1).astype(np.float32)}
-        for _ in range(3)
-    ]
-
-    exe = fluid.Executor(fluid.CPUPlace())
-
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    snapshot = {
-        n: np.asarray(scope.find_var(n)).copy()
-        for n in scope.local_var_names()
-        if scope.find_var(n) is not None
-    }
-    serial_losses = []
-    for i in range(7):  # 7 % 3 != 0: exercises batch cycling
-        (lv,) = exe.run(feed=feeds[i % 3], fetch_list=[loss])
-        serial_losses.append(float(np.ravel(lv)[0]))
-    w_serial = np.asarray(scope.find_var("rs_w")).copy()
-
-    # reset ALL post-startup state (params incl. the fc bias) and the rng
-    # stream, rerun as one scanned dispatch
-    for n in list(scope.local_var_names()):
-        if n in snapshot:
-            scope.set_var(n, snapshot[n])
-        else:
-            scope.erase(n)
-    (lv,) = exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=7)
-    np.testing.assert_allclose(
-        float(np.ravel(lv)[0]), serial_losses[-1], rtol=1e-6)
-    np.testing.assert_allclose(
-        np.asarray(fluid.global_scope().find_var("rs_w")), w_serial, rtol=1e-6)
-
-
-def test_run_steps_advances_rng():
-    out = fluid.layers.ops.uniform_random([4], min=0.0, max=1.0)
-    exe = fluid.Executor(fluid.CPUPlace())
-    (a,) = exe.run_steps(feed_list=[{}], fetch_list=[out], steps=2)
-    (b,) = exe.run_steps(feed_list=[{}], fetch_list=[out], steps=2)
-    assert not np.allclose(a, b)
-
-
-def test_run_steps_rejects_lod():
-    from paddle_tpu.core.lod import LoDValue
-
-    x = fluid.layers.data("x", [4], dtype="float32", lod_level=1)
-    y = fluid.layers.sequence_pool(x, "sum")
-    exe = fluid.Executor(fluid.CPUPlace())
-    lv = LoDValue(np.zeros((3, 4), np.float32), np.array([2, 1]))
-    with pytest.raises(TypeError, match="LoD"):
-        exe.run_steps(feed_list=[{"x": lv}], fetch_list=[y], steps=1)
-
-
-def test_run_steps_mutable_feed_not_stale():
-    """In-place mutation of a reused numpy feed buffer must reach the device
-    on the next run_steps call (the feeds-stack cache only applies to
-    immutable jax.Array feeds)."""
-    x = fluid.layers.data("x", [2], dtype="float32")
-    out = fluid.layers.reduce_mean(x)
-    exe = fluid.Executor(fluid.CPUPlace())
-    feed = {"x": np.ones((2, 2), np.float32)}
-    (a,) = exe.run_steps(feed_list=[feed], fetch_list=[out], steps=1)
-    feed["x"][:] = 5.0  # standard refill-the-buffer loading pattern
-    (b,) = exe.run_steps(feed_list=[feed], fetch_list=[out], steps=1)
-    np.testing.assert_allclose(np.ravel(a)[0], 1.0)
-    np.testing.assert_allclose(np.ravel(b)[0], 5.0)
-
-
-def test_run_steps_with_scheduler_and_dropout():
-    """run_steps must advance in-graph LR-decay state and the dropout RNG
-    stream exactly like per-step run(): the scan carries every persistable
-    (incl. the scheduler's global step) plus the PRNG key."""
+def test_run_advances_scheduler_and_dropout_key_over_six_steps():
+    """Executor.run carries every persistable (the scheduler's step count
+    among them) and the PRNG key from one step to the next: six steps
+    against SGD stepped by hand in numpy, with the learning rate the decay
+    schedule names for each step and the dropout mask that step drew."""
     import paddle_tpu.layers as layers
 
     x = fluid.layers.data("x", [8], dtype="float32")
     y = fluid.layers.data("y", [1], dtype="float32")
-    h = layers.fc(x, size=16, act="relu")
-    h = layers.dropout(h, dropout_prob=0.3)
-    pred = layers.fc(h, size=1)
+    h = layers.fc(x, size=16, act="relu",
+                  param_attr=fluid.ParamAttr(name="sd_w1"),
+                  bias_attr=fluid.ParamAttr(name="sd_b1"))
+    d = layers.dropout(h, dropout_prob=0.3)
+    pred = layers.fc(d, size=1,
+                     param_attr=fluid.ParamAttr(name="sd_w2"),
+                     bias_attr=fluid.ParamAttr(name="sd_b2"))
     loss = layers.mean(layers.square_error_cost(pred, y))
     lr = fluid.layers.exponential_decay(
         learning_rate=0.1, decay_steps=2, decay_rate=0.5, staircase=True)
@@ -351,28 +276,52 @@ def test_run_steps_with_scheduler_and_dropout():
     feeds = [{"x": rng.rand(4, 8).astype(np.float32),
               "y": rng.rand(4, 1).astype(np.float32)} for _ in range(2)]
     exe = fluid.Executor(fluid.CPUPlace())
-
     exe.run(fluid.default_startup_program())
     scope = fluid.global_scope()
-    snap = {n: np.asarray(scope.find_var(n)).copy()
-            for n in scope.local_var_names()
-            if scope.find_var(n) is not None}
-    for i in range(6):
-        exe.run(feed=feeds[i % 2], fetch_list=[loss])
-    params_serial = {
-        n: np.asarray(scope.find_var(n)).copy() for n in snap
-    }
+    names = ("sd_w1", "sd_b1", "sd_w2", "sd_b2")
+    w1, b1, w2, b2 = (np.asarray(scope.find_var(n)).astype(np.float64)
+                      for n in names)
 
-    for n in list(scope.local_var_names()):
-        if n in snap:
-            scope.set_var(n, snap[n])
-        else:
-            scope.erase(n)
-    exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=6)
-    for n, want in params_serial.items():
-        got = np.asarray(scope.find_var(n))
-        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7,
-                                   err_msg=f"state {n} diverged")
+    masks = []
+    for i in range(6):
+        feed = feeds[i % 2]
+        hv, dv, lrv = exe.run(feed=feed, fetch_list=[h, d, lr])
+        np.testing.assert_allclose(np.ravel(lrv)[0], 0.1 * 0.5 ** (i // 2),
+                                   rtol=1e-6, err_msg=f"step {i}")
+        # what the step multiplied each live unit by (a dead one passes no
+        # gradient whatever its mask)
+        mult = np.where(hv > 0, dv / np.where(hv > 0, hv, 1.0), 0.0)
+        masks.append(dv != 0)
+        xv, yv = feed["x"].astype(np.float64), feed["y"].astype(np.float64)
+        z = xv @ w1 + b1
+        dn = np.maximum(z, 0.0) * mult
+        dpred = 2.0 * (dn @ w2 + b2 - yv) / len(xv)
+        dz = (dpred @ w2.T) * mult * (z > 0)
+        step = float(np.ravel(lrv)[0])
+        w2, b2 = w2 - step * dn.T @ dpred, b2 - step * dpred.sum(0)
+        w1, b1 = w1 - step * xv.T @ dz, b1 - step * dz.sum(0)
+        for n, want in zip(names, (w1, b1, w2, b2)):
+            np.testing.assert_allclose(
+                np.asarray(scope.find_var(n)), want, rtol=1e-4, atol=1e-6,
+                err_msg=f"state {n} diverged at step {i}")
+    # the key advanced: the same batch (steps 0, 2, 4) drew three masks
+    assert not np.array_equal(masks[0], masks[2])
+    assert not np.array_equal(masks[2], masks[4])
+
+
+def test_run_reads_a_refilled_feed_buffer_anew():
+    """The refill-the-buffer loading pattern: a numpy feed mutated in place
+    between two runs reaches the device on the second (a feed is staged
+    per call, never remembered by identity)."""
+    x = fluid.layers.data("x", [2], dtype="float32")
+    out = fluid.layers.reduce_mean(x)
+    exe = fluid.Executor(fluid.CPUPlace())
+    feed = {"x": np.ones((2, 2), np.float32)}
+    (a,) = exe.run(feed=feed, fetch_list=[out])
+    feed["x"][:] = 5.0
+    (b,) = exe.run(feed=feed, fetch_list=[out])
+    np.testing.assert_allclose(np.ravel(a)[0], 1.0)
+    np.testing.assert_allclose(np.ravel(b)[0], 5.0)
 
 
 def test_fetch_var_reads_persistable():
@@ -427,55 +376,6 @@ def test_seeded_training_is_deterministic():
                 for _ in range(4)]
 
     assert run_once() == run_once()
-
-
-def test_run_steps_flat_matches_scan():
-    """mode='flat' (straight-line K-step jit, no lax.scan — for dispatch
-    layers that serialize loop iterations) must give the identical
-    trajectory to the scan form: same final loss, params, and rng."""
-    fluid.reset_default_env()
-    x = fluid.layers.data("x", [4], dtype="float32")
-    label = fluid.layers.data("label", [1], dtype="float32")
-    pred = fluid.layers.fc(x, size=1, param_attr=fluid.ParamAttr(name="rf_w"))
-    loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, label))
-    fluid.optimizer.SGD(learning_rate=0.05).minimize(loss)
-
-    rng = np.random.RandomState(9)
-    feeds = [
-        {"x": rng.rand(8, 4).astype(np.float32),
-         "label": rng.rand(8, 1).astype(np.float32)}
-        for _ in range(3)
-    ]
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    snapshot = {
-        n: np.asarray(scope.find_var(n)).copy()
-        for n in scope.local_var_names()
-        if scope.find_var(n) is not None
-    }
-    (lv_scan,) = exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=7)
-    w_scan = np.asarray(scope.find_var("rf_w")).copy()
-    rng_scan = np.asarray(scope.find_var("@rng_key@")).copy()
-
-    for n in list(scope.local_var_names()):
-        if n in snapshot:
-            scope.set_var(n, snapshot[n])
-        else:
-            scope.erase(n)
-    (lv_flat,) = exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=7,
-                               mode="flat")
-    np.testing.assert_allclose(np.ravel(lv_flat), np.ravel(lv_scan),
-                               rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(scope.find_var("rf_w")), w_scan,
-                               rtol=1e-6)
-    np.testing.assert_array_equal(
-        np.asarray(scope.find_var("@rng_key@")), rng_scan)
-
-    import pytest as _pytest
-    with _pytest.raises(ValueError, match="mode"):
-        exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=2,
-                      mode="bogus")
 
 
 def test_cost_analysis_reports_bytes_and_flops():
@@ -541,12 +441,6 @@ base = compiles["n"]
 for _ in range(3):
     exe.run(feed=feed, fetch_list=[loss])
 print("MAIN_REPEAT_COMPILES", compiles["n"] - base)
-feeds = [dict(feed) for _ in range(2)]
-exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=4, mode="flat")
-base2 = compiles["n"]
-for _ in range(3):
-    exe.run_steps(feed_list=feeds, fetch_list=[loss], steps=4, mode="flat")
-print("STEPS_REPEAT_COMPILES", compiles["n"] - base2)
 """
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", src],
@@ -559,9 +453,6 @@ print("STEPS_REPEAT_COMPILES", compiles["n"] - base2)
         "dead and the zero-recompile assertions below would be vacuous")
     n = int(out.stdout.split("MAIN_REPEAT_COMPILES")[1].split()[0])
     assert n == 0, f"repeated identical runs must not recompile, got {n}"
-    ns = int(out.stdout.split("STEPS_REPEAT_COMPILES")[1].split()[0])
-    assert ns == 0, (
-        f"repeated identical run_steps must not recompile, got {ns}")
 
 
 _ONE_COMPILE_PRELUDE = r"""
@@ -669,3 +560,36 @@ def test_one_compile_whatever_the_state_arrives_as(case):
         assert [s[1] for s in stage] == [n - 1, 0, 0, 1, 0]
     else:
         assert [s[1] for s in stage] == [n, 0, 0, 0]
+
+
+@pytest.mark.parametrize("what", [
+    "Executor", "ParallelExecutor", "run_step", "histogram"])
+def test_a_step_has_one_way_to_run(what):
+    """`run` is the one entry point of a step: no executor has a
+    K-steps-a-dispatch twin (every cell's idle share is under the 3% such
+    a twin could recover, ROADMAP D17), run_step takes no `steps`, and a
+    step leaves no second histogram behind."""
+    import inspect
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core import executor as executor_mod
+
+    if what in ("Executor", "ParallelExecutor"):
+        assert not hasattr(getattr(fluid, what), "run_steps")
+        assert "mode" not in inspect.signature(
+            getattr(fluid, what).run).parameters
+    elif what == "run_step":
+        assert "steps" not in inspect.signature(
+            executor_mod.run_step).parameters
+    else:
+        fluid.set_flags({"FLAGS_observability": True})
+        obs.reset()
+        try:
+            out = fluid.layers.fill_constant([2], "float32", 1.0)
+            fluid.Executor(fluid.CPUPlace()).run(fetch_list=[out])
+            names = [m.name for m in obs.default_registry().metrics()]
+        finally:
+            obs.reset()
+            fluid.set_flags({"FLAGS_observability": False})
+        assert "paddle_tpu_executor_step_seconds" in names
+        assert "paddle_tpu_executor_run_steps_seconds" not in names

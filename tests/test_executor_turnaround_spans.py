@@ -17,8 +17,7 @@ from paddle_tpu import layers, observability as obs
 PHASES = ["executor.plan", "executor.stage", "executor.dispatch",
           "executor.commit", "executor.fetch"]
 UNDER_FETCH = ["executor.wait", "executor.copy"]
-ENTRIES = [("serial", "run"), ("serial", "run_steps"), ("spmd", "run"),
-           ("spmd", "run_steps"), ("spmd", "compiled")]
+ENTRIES = [("serial", "run"), ("spmd", "run"), ("spmd", "compiled")]
 
 
 @pytest.fixture
@@ -46,10 +45,7 @@ def _entry(kind, how, **kw):
     host = {"x": np.ones((4, 4), "float32")}
     if kind == "serial":
         feed = jax.device_put(host, exe.place.jax_device())
-        if how == "run":
-            return lambda: exe.run(feed=feed, fetch_list=[loss], **kw)
-        return lambda: exe.run_steps(feed_list=[feed, feed],
-                                     fetch_list=[loss], **kw)
+        return lambda: exe.run(feed=feed, fetch_list=[loss], **kw)
     from paddle_tpu.parallel import make_mesh
 
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
@@ -60,10 +56,7 @@ def _entry(kind, how, **kw):
                 loss_name=loss.name, mesh=mesh)
         return lambda: exe.run(prog, feed=feed, fetch_list=[loss], **kw)
     pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
-    if how == "run":
-        return lambda: pe.run(feed=feed, fetch_list=[loss], **kw)
-    return lambda: pe.run_steps(feed_list=[feed, feed], fetch_list=[loss],
-                                **kw)
+    return lambda: pe.run(feed=feed, fetch_list=[loss], **kw)
 
 
 def _ring():

@@ -524,28 +524,50 @@ def test_controller_scale_up_down_e2e_flight_events():
 
 
 def test_rolling_upgrade_zero_lost_and_new_params_serve():
+    """The prefill steps run op by op, and on the CPU every new (rows,
+    tokens) shape of a co-admitted group costs ~1.5 s of small compiles
+    (measured by PR 46: draining the only prefill replica took 12 s with
+    four prompt lengths and groups of up to four, past the fleet's 10 s
+    placement budget, so the traffic that waited out the drain failed).
+    The fleet is right to make it wait; the test keeps the shapes few
+    (one prompt length, groups of at most two), warms them, bounds what
+    its client has outstanding, and gives the placement wait the 120 s
+    its other waits have."""
     cfg = _cfg()
     p_old = serving.init_decode_params(cfg, seed=1)
     p_new = serving.init_decode_params(cfg, seed=2)
     rng = np.random.RandomState(1)
-    prompts = [rng.randint(1, cfg.vocab_size, size=n).tolist()
-               for n in (4, 6, 3, 5)]
-    fleet = _mk_fleet(p_old, cfg, n_decode=2)
+    prompts = [rng.randint(1, cfg.vocab_size, size=4).tolist()
+               for _ in range(4)]
+    fleet = _mk_fleet(p_old, cfg, n_decode=2, max_batch=2,
+                      place_timeout_s=120.0)
     ctl = FleetController(fleet, min_replicas={"decode": 2})
     try:
-        # warm every step shape so drains are fast
-        [f.result(120) for f in
-         [fleet.submit(DecodeRequest(prompt=list(p), max_new_tokens=4))
-          for p in prompts]]
+        # warm the step shapes so drains are fast: uncached, then from
+        # the prefix cache, alone and in pairs
+        for _ in range(2):
+            [f.result(120) for f in
+             [fleet.submit(DecodeRequest(prompt=list(p), max_new_tokens=4))
+              for p in prompts]]
+            for p in prompts[:2]:
+                fleet.infer(DecodeRequest(prompt=list(p), max_new_tokens=4),
+                            timeout=120)
         stop = threading.Event()
         futs, lock = [], threading.Lock()
+        # a client with four requests outstanding: an open loop at 50 a
+        # second outruns the CPU's op-by-op steps, and every drain (and
+        # the test) then lasts as long as the backlog
+        window = threading.Semaphore(4)
 
         def traffic():
             i = 0
             while not stop.is_set():
+                if not window.acquire(timeout=0.05):
+                    continue
                 f = fleet.submit(DecodeRequest(
                     prompt=list(prompts[i % len(prompts)]),
                     max_new_tokens=4))
+                f.add_done_callback(lambda _: window.release())
                 with lock:
                     futs.append(f)
                 i += 1
@@ -572,6 +594,33 @@ def test_rolling_upgrade_zero_lost_and_new_params_serve():
         assert got.tokens == want
         audit = fleet.audit()
         assert audit["pages_leaked"] == 0 and audit["invariants_ok"]
+    finally:
+        fleet.close()
+
+
+def test_a_request_waits_out_the_drain_of_the_only_prefill_replica():
+    """The order a rolling upgrade leans on: while the only prefill
+    replica is drained nothing is placeable, and a request that arrives
+    then is neither failed nor failed over: it waits inside the placement
+    budget and is served, with the right tokens, once the replica is
+    resumed."""
+    cfg = _cfg()
+    params = serving.init_decode_params(cfg, seed=1)
+    prompt = np.random.RandomState(1).randint(
+        1, cfg.vocab_size, size=4).tolist()
+    fleet = _mk_fleet(params, cfg, place_timeout_s=120.0)
+    try:
+        assert fleet.drain_replica("prefill0", timeout=60.0)
+        fut = fleet.submit(DecodeRequest(prompt=list(prompt),
+                                         max_new_tokens=4))
+        assert not fut.done() and fleet.stats()["failed"] == 0
+        fleet.resume_replica("prefill0")
+        got = fut.result(120)
+        want, _ = serving.full_decode(params, cfg, prompt, 4)
+        assert got.error is None and got.tokens == want
+        st = fleet.stats()
+        assert st["failovers"] == 0 and st["re_prefills"] == 0
+        assert st["lost_requests"] == 0 and st["failed"] == 0
     finally:
         fleet.close()
 

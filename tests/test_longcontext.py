@@ -46,7 +46,7 @@ def test_flash_grads_flow():
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True, force="jax") ** 2)
 
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     ref = jax.grad(
         lambda q, k, v: jnp.sum(
             _reference_attention(q, k, v, True, 1 / math.sqrt(8)) ** 2
@@ -111,7 +111,7 @@ def test_ring_attention_grads():
             )(q, k, v)
         return jnp.sum(out ** 2)
 
-    g = jax.grad(loss)(q, k, v)
+    g = jax.jit(jax.grad(loss))(q, k, v)
     ref = jax.grad(
         lambda q: jnp.sum(
             _reference_attention(q, k, v, True, 1 / math.sqrt(4)) ** 2
@@ -295,7 +295,7 @@ def test_zigzag_ring_grads():
         return jnp.sum(
             _reference_attention(q, k, v, True, 1 / math.sqrt(4)) * w)
 
-    gz = jax.grad(loss_z, (0, 1, 2))(q, k, v)
+    gz = jax.jit(jax.grad(loss_z, (0, 1, 2)))(q, k, v)
     gr = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
     for a, b, name in zip(gz, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
@@ -358,7 +358,7 @@ def test_ulysses_attention_grads():
             )
         return jnp.sum(out ** 2)
 
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     ref = jax.grad(
         lambda q, k, v: jnp.sum(
             _reference_attention(q, k, v, True, 1 / math.sqrt(4)) ** 2
@@ -423,7 +423,7 @@ def test_ulysses_blockwise_grads():
             )
         return jnp.sum(out ** 2)
 
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     ref = jax.grad(
         lambda q, k, v: jnp.sum(
             _reference_attention(q, k, v, True, 1 / math.sqrt(4)) ** 2
@@ -434,16 +434,14 @@ def test_ulysses_blockwise_grads():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_longcontext_s512_sp8_all_variants():
+@pytest.mark.parametrize("variant", ["ring", "ulysses", "zigzag",
+                                     "zigzag-gradient"])
+def test_longcontext_s512_sp8(variant):
     """Beyond-toy shape on the full 8-way sp mesh: S=512 (64 tokens per
-    device), causal, all three sequence-parallel variants against the
-    dense reference — plus gradient parity for the zigzag form (the
+    device), causal, each of the three sequence-parallel variants against
+    the dense reference, and gradient parity for the zigzag form (the
     load-balanced one the long-context bench uses)."""
-    import jax
-    import jax.numpy as jnp
-
     from paddle_tpu.longcontext import (
-        sequence_parallel_attention,
         ulysses_sequence_parallel_attention,
         zigzag_sequence_parallel_attention,
     )
@@ -455,42 +453,35 @@ def test_longcontext_s512_sp8_all_variants():
     q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32) * 0.3
     k = jnp.asarray(rng.randn(B, H, S, D), jnp.float32) * 0.3
     v = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
-
     scale = 1.0 / np.sqrt(D)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     mask = np.tril(np.ones((S, S), bool))
-    s = jnp.where(mask[None, None], s, -1e30)
-    ref = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
 
-    ring = sequence_parallel_attention(mesh, q, k, v, causal=True,
-                                       batch_axis=None)
-    np.testing.assert_allclose(np.asarray(ring), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
-    uly = ulysses_sequence_parallel_attention(mesh, q, k, v, causal=True,
-                                              batch_axis=None)
-    np.testing.assert_allclose(np.asarray(uly), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
-    # the zigzag wrapper permutes internally: global-view in, global-view out
-    zig = zigzag_sequence_parallel_attention(mesh, q, k, v, batch_axis=None)
-    np.testing.assert_allclose(np.asarray(zig), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
-    # gradient parity at the same scale for the zigzag form
-    def loss_zig(q_, k_, v_):
-        o = zigzag_sequence_parallel_attention(mesh, q_, k_, v_,
-                                               batch_axis=None)
-        return jnp.sum(o * o)
-
-    def loss_ref(q_, k_, v_):
+    def dense(q_, k_, v_):
         s_ = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) * scale
         s_ = jnp.where(mask[None, None], s_, -1e30)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s_, axis=-1), v_)
-        return jnp.sum(o * o)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s_, axis=-1), v_)
 
-    gz = jax.grad(loss_zig, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gz, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=3e-3, atol=3e-4)
+    if variant == "zigzag-gradient":
+        def loss(attend):
+            return lambda q_, k_, v_: jnp.sum(attend(q_, k_, v_) ** 2)
+
+        # the zigzag wrapper permutes internally: global view in and out
+        gz = jax.jit(jax.grad(loss(
+            lambda q_, k_, v_: zigzag_sequence_parallel_attention(
+                mesh, q_, k_, v_, batch_axis=None)), argnums=(0, 1, 2)))(
+                    q, k, v)
+        gr = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gz, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=3e-3, atol=3e-4)
+        return
+    got = {
+        "ring": lambda: sequence_parallel_attention(
+            mesh, q, k, v, causal=True, batch_axis=None),
+        "ulysses": lambda: ulysses_sequence_parallel_attention(
+            mesh, q, k, v, causal=True, batch_axis=None),
+        "zigzag": lambda: zigzag_sequence_parallel_attention(
+            mesh, q, k, v, batch_axis=None),
+    }[variant]()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(q, k, v)),
+                               rtol=2e-4, atol=2e-5)

@@ -51,13 +51,17 @@ from paddle_tpu.serving.prefill_sched import plan_chunks
 
 PS = 4
 WIN, SNK = 8, 4
-MAX_NEW = 16
+MAX_NEW = 8
 CFG = DecodeConfig(vocab_size=64, d_model=32, n_head=4, n_kv_head=2,
                    n_layer=2, max_length=96, eos_id=None)
 PARAMS = init_decode_params(CFG, seed=0)
 _rng = np.random.default_rng(1)
+# lengths next to each other and 8 new tokens (12, 7, 20 and 16 before PR
+# 46): the oracle compiles ~50 small executables for every sequence length
+# it meets, 10 lengths here and 29 then, and the windowed arms still evict,
+# draft and hit the prefix cache
 PROMPTS = tuple(tuple(int(t) for t in _rng.integers(0, 64, n))
-                for n in (12, 7, 20))
+                for n in (18, 19, 20))
 
 
 @functools.lru_cache(maxsize=None)
@@ -320,33 +324,38 @@ def test_plan_chunks_flop_budget_arithmetic():
         plan_chunks([[1]], [0], 0, flop_budget=0)
 
 
-def test_prefill_flops_loop_parity_with_and_without_window():
+@pytest.mark.parametrize("windowed", [False, True],
+                         ids=["unwindowed", "windowed"])
+def test_prefill_flops_loop_parity(windowed):
+    """Prompts of three and of two compute-budgeted chunks (28 tokens:
+    16, 8 under the budget at position 16, 4; 24 tokens: 16, 8) and four
+    tokens decoded: the loop and the oracle compile every chunk and every
+    sequence length they see op by op (~2 s each on the CPU), so the
+    sizes are the smallest at which the budget, not the token cap, cuts a
+    chunk and the window's first decode step drops the prompt's pages
+    between the sinks and the window."""
     cfg = DecodeConfig(vocab_size=64, d_model=32, n_head=4, n_kv_head=2,
                        n_layer=2, max_length=128, eos_id=None)
     params = init_decode_params(cfg, seed=0)
     rng = np.random.default_rng(2)
-    prompts = [list(rng.integers(0, 64, 40)), list(rng.integers(0, 64, 25))]
-
-    def run(**req_kw):
-        pool = KVCachePool(num_pages=256, page_size=PS,
-                           num_layers=cfg.n_layer, num_heads=cfg.n_head,
-                           head_dim=cfg.head_dim,
-                           num_kv_heads=cfg.n_kv_head)
-        loop = ContinuousBatchingLoop(params, cfg, pool, max_batch=2,
-                                      prefill_chunk=16, prefill_flops=200.0,
-                                      check_every=1)
-        return loop, loop.run([DecodeRequest(p, 12, **req_kw)
-                               for p in prompts])
-
-    loop, res = run()
+    prompts = [list(rng.integers(0, 64, 28)), list(rng.integers(0, 64, 24))]
+    new = 4
+    req_kw = {"window": WIN, "sinks": SNK} if windowed else {}
+    pool = KVCachePool(num_pages=256, page_size=PS,
+                       num_layers=cfg.n_layer, num_heads=cfg.n_head,
+                       head_dim=cfg.head_dim, num_kv_heads=cfg.n_kv_head)
+    loop = ContinuousBatchingLoop(params, cfg, pool, max_batch=2,
+                                  prefill_chunk=16, prefill_flops=200.0,
+                                  check_every=1)
+    res = loop.run([DecodeRequest(p, new, **req_kw) for p in prompts])
+    oracle_kw = dict(req_kw, page_size=PS) if windowed else {}
     for p, r in zip(prompts, res):
-        assert r.tokens == full_decode(params, cfg, p, 12)[0]
-    assert loop.decode_step_p99_during_prefill_s() >= 0.0
-    loop, res = run(window=WIN, sinks=SNK)
-    for p, r in zip(prompts, res):
-        assert r.tokens == full_decode(params, cfg, p, 12, window=WIN,
-                                       sinks=SNK, page_size=PS)[0]
-    assert loop.pages_evicted > 0
+        assert r.tokens == full_decode(params, cfg, p, new, **oracle_kw)[0]
+    assert loop.prefill_steps > 2  # the prompts went in by chunks
+    if windowed:
+        assert loop.pages_evicted > 0
+    else:
+        assert loop.decode_step_p99_during_prefill_s() >= 0.0
 
 
 def test_longctx_validation_errors():

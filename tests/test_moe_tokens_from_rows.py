@@ -7,7 +7,18 @@ the gather over all assignments, differentiated by jax) on the three expert
 configurations' rehearsal shapes; and, chip-less at the configurations'
 REAL shapes, that the op as it lowers for the TPU holds no value of T x k
 rows by d and that `moe.lower`'s feature_rows is the count the lowered
-program shows."""
+program shows.
+
+The last test (three real-size compiles of whole steps, ~7 min together) is
+marked `slow` since PR 46 and is no part of tier-1: what it proves, one
+forward a layer in the step the chip compiles, a cell's `correct` and rate
+hold on the chip on every PR (a second forward costs
+moonlight-train-ep8share 6.4%, twice its bound), and the compiled-count
+bounds of tests/benchmark/test_mellum_benchmark.py and
+test_zaya_benchmark.py hold it at the smallest widths the kernels lower
+at.  Run it by hand, `python -m pytest tests/test_moe_tokens_from_rows.py
+-m slow`, in any PR that touches ops/moe_ops.py or
+models/common.py::one_trip_layer."""
 
 import os
 import re
@@ -311,6 +322,7 @@ def test_feature_rows_is_the_count_the_lowered_op_shows(cell):
             "keye-train-dsa16k": 229376}[cell] == moe_ops.feature_rows(rows)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("cell, scope", [
     ("mellum-train-swa16k", "attn."), ("moonlight-train-ep8share", "mla"),
     ("zaya-train-cca16k", "cca.attend")])

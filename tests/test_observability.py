@@ -509,33 +509,27 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
 # -----------------------------------------------------------------------
 PHASES = ["executor.plan", "executor.stage", "executor.dispatch",
           "executor.commit", "executor.fetch"]
-STEPPERS = [("serial", "run"), ("serial", "run_steps"),
-            ("spmd", "run"), ("spmd", "run_steps")]
+KINDS = ["serial", "spmd"]
 
 
-def _stepper(kind, how):
-    """A callable that makes one call into the executor of `kind` through
-    `how`; the feed is staged on the device(s) once, as a training loop
-    stages it."""
+def _stepper(kind):
+    """A callable that makes one call of `run` into the executor of `kind`;
+    the feed is staged on the device(s) once, as a training loop stages
+    it."""
     import jax
 
-    _, loss = _build_step(name=f"obs_{kind}_{how}_w")
+    _, loss = _build_step(name=f"obs_{kind}_w")
     host = {"x": np.ones((4, 4), "float32")}
     if kind == "serial":
         exe = fluid.Executor(fluid.CPUPlace())
         feed = jax.device_put(host, exe.place.jax_device())
-        if how == "run":
-            return lambda: exe.run(feed=feed, fetch_list=[loss])
-        return lambda: exe.run_steps(feed_list=[feed, feed],
-                                     fetch_list=[loss])
+        return lambda: exe.run(feed=feed, fetch_list=[loss])
     from paddle_tpu.parallel import make_mesh
 
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
     feed = jax.device_put(host, mesh.batch_sharding())
-    if how == "run":
-        return lambda: pe.run(feed=feed, fetch_list=[loss])
-    return lambda: pe.run_steps(feed_list=[feed, feed], fetch_list=[loss])
+    return lambda: pe.run(feed=feed, fetch_list=[loss])
 
 
 def _host_events(logdir):
@@ -559,15 +553,15 @@ def _host_events(logdir):
     return sorted(evs, key=lambda e: (e[1], -e[2]))
 
 
-@pytest.mark.parametrize("kind,how", STEPPERS)
-def test_step_phases_land_in_a_plain_profiler_session(kind, how, tmp_path):
+@pytest.mark.parametrize("kind", KINDS)
+def test_step_phases_land_in_a_plain_profiler_session(kind, tmp_path):
     """Sink A: a session started with jax.profiler.start_trace itself, the
     flag off, holds executor.step over its five phases in order, with
-    their counts, for both executors and both entry points."""
+    their counts, for both executors."""
     import jax
 
     assert not obs.enabled()
-    step = _stepper(kind, how)
+    step = _stepper(kind)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0  # as benchmark/harness/trace.py starts it
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
@@ -591,7 +585,7 @@ def test_step_phases_land_in_a_plain_profiler_session(kind, how, tmp_path):
         assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
         assert counts["kind"] == kind and counts["n_feed"] == 1
         assert counts["n_state"] >= 2  # the weight, the bias, ...
-        assert ("steps" in counts) == (how == "run_steps")
+        assert "steps" not in counts
     first, second = ([e for e in evs if e[0] == name]
                      for name in ("executor.plan", "executor.stage"))
     assert [e[3]["cache"] for e in first] == ["miss", "hit"]
@@ -605,12 +599,12 @@ def test_step_phases_land_in_a_plain_profiler_session(kind, how, tmp_path):
     assert fetch[3]["n"] == 1
 
 
-@pytest.mark.parametrize("kind,how", STEPPERS)
-def test_step_phases_land_in_the_ring_with_parents(kind, how, obs_on):
+@pytest.mark.parametrize("kind", KINDS)
+def test_step_phases_land_in_the_ring_with_parents(kind, obs_on):
     """Sink B: the flag on and no profiler session, the ring holds the same
     tree, each phase with executor.step as its parent, and the step is
     counted as an Executor's is, whichever executor made it."""
-    step = _stepper(kind, how)
+    step = _stepper(kind)
     obs.reset()
     step()
     step()
@@ -632,18 +626,14 @@ def test_step_phases_land_in_the_ring_with_parents(kind, how, obs_on):
     reg = obs.default_registry()
     cc = reg.counter("paddle_tpu_compile_cache", "")
     assert (cc.value(result="miss"), cc.value(result="hit")) == (1, 1)
-    if how == "run":
-        assert obs.step_stats().count == 2
-        assert reg.counter("paddle_tpu_executor_steps", "").value(
-            donated="1") == 2
-        assert reg.histogram("paddle_tpu_executor_step_seconds",
-                             "").series_summary()["count"] == 2
-        # the step's time is the span's: to the fetched value on the host
-        assert obs.step_stats().summary()["max_s"] == pytest.approx(
-            max(s.duration for s in steps))
-    else:
-        assert reg.histogram("paddle_tpu_executor_run_steps_seconds",
-                             "").series_summary(steps="2")["count"] == 2
+    assert obs.step_stats().count == 2
+    assert reg.counter("paddle_tpu_executor_steps", "").value(
+        donated="1") == 2
+    assert reg.histogram("paddle_tpu_executor_step_seconds",
+                         "").series_summary()["count"] == 2
+    # the step's time is the span's: to the fetched value on the host
+    assert obs.step_stats().summary()["max_s"] == pytest.approx(
+        max(s.duration for s in steps))
 
 
 def test_values_already_placed_are_not_counted_as_moved(tmp_path):

@@ -128,58 +128,13 @@ def test_indivisible_batch_raises_clear_error():
                fetch_list=[loss.name])
 
 
-def test_pe_run_steps_matches_stepwise():
-    """ParallelExecutor.run_steps (K sharded steps under one pjit'd scan)
-    must reproduce the exact trajectory of per-step pe.run on the same
-    mesh, including the final fetches and updated parameters."""
-    rng = np.random.RandomState(3)
-    feeds = [{"x": rng.randn(8, 16).astype("float32"),
-              "y": rng.randn(8, 1).astype("float32")} for _ in range(4)]
-
-    def build():
-        fluid.reset_default_env()
-        fluid.default_main_program().random_seed = 7
-        fluid.default_startup_program().random_seed = 7
-        from paddle_tpu import layers
-        x = layers.data("x", [16], dtype="float32")
-        y = layers.data("y", [1], dtype="float32")
-        h = layers.fc(x, size=8, act="relu")
-        pred = layers.fc(h, size=1)
-        loss = layers.mean(layers.square_error_cost(pred, y))
-        fluid.optimizer.AdamOptimizer(learning_rate=0.05).minimize(loss)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        import jax
-        mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
-        pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
-        return pe, loss
-
-    pe, loss = build()
-    for f in feeds:
-        step_out = pe.run(feed=f, fetch_list=[loss.name])
-    w_step = {
-        n: np.asarray(fluid.global_scope().find_var(n))
-        for n in ("fc_0.w_0", "fc_1.w_0")
-    }
-
-    pe2, loss2 = build()
-    scan_out = pe2.run_steps(feed_list=feeds, fetch_list=[loss2.name])
-    w_scan = {
-        n: np.asarray(fluid.global_scope().find_var(n))
-        for n in ("fc_0.w_0", "fc_1.w_0")
-    }
-
-    np.testing.assert_allclose(np.asarray(scan_out[0]),
-                               np.asarray(step_out[0]), rtol=1e-5, atol=1e-6)
-    for n in w_step:
-        np.testing.assert_allclose(w_scan[n], w_step[n], rtol=1e-5,
-                                   atol=1e-6, err_msg=n)
-
-
-def test_pe_run_steps_with_tp_sharded_weight():
-    """run_steps under a dp x tp mesh with a tensor-parallel weight keeps
-    the sharded-state round-trip exact across the scan."""
+def test_pe_run_keeps_a_tp_sharded_weight_sharded_across_steps():
+    """Under a dp x tp mesh a tensor-parallel weight comes back from every
+    step of ParallelExecutor.run as it went in, committed to its sharding
+    (so the next step stages nothing), and the trajectory is the serial
+    executor's on the same program, batches and seed."""
     import jax
+    from jax.sharding import PartitionSpec
 
     rng = np.random.RandomState(4)
     feeds = [{"x": rng.randn(4, 16).astype("float32"),
@@ -200,20 +155,28 @@ def test_pe_run_steps_with_tp_sharded_weight():
         prog.global_block().var("fc_0.w_0").sharding = [None, "tp"]
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(fluid.default_startup_program())
-        mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
-        return fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh), loss
+        return exe, loss
 
-    pe, loss = build()
-    for f in feeds:
-        (want,) = pe.run(feed=f, fetch_list=[loss.name])
-    w_want = np.asarray(fluid.global_scope().find_var("fc_0.w_0"))
+    exe, loss = build()
+    want = []
+    for i in range(6):
+        (lv,) = exe.run(feed=feeds[i % 3], fetch_list=[loss])
+        want.append((np.asarray(lv), np.asarray(
+            fluid.global_scope().find_var("fc_0.w_0"))))
 
-    pe2, loss2 = build()
-    (got,) = pe2.run_steps(feed_list=feeds, fetch_list=[loss2.name])
-    w_got = np.asarray(fluid.global_scope().find_var("fc_0.w_0"))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(w_got, w_want, rtol=1e-5, atol=1e-6)
+    _, loss = build()
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    pe = fluid.ParallelExecutor(loss_name=loss.name, mesh=mesh)
+    for i in range(6):
+        (lv,) = pe.run(feed=feeds[i % 3], fetch_list=[loss.name])
+        w = fluid.global_scope().find_var("fc_0.w_0")
+        assert w.committed and w.sharding.mesh == mesh.mesh
+        assert w.sharding.spec == PartitionSpec(None, "tp")
+        assert w.addressable_shards[0].data.shape == (16, 4)
+        np.testing.assert_allclose(np.asarray(lv), want[i][0],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(w), want[i][1],
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_parallel_conv_fused_bn_matches_serial():
@@ -275,32 +238,3 @@ def test_parallel_conv_fused_bn_matches_serial():
     np.testing.assert_allclose(
         np.asarray(fluid.global_scope().find_var("pc_mean")), mean_serial,
         rtol=2e-4, atol=1e-6)
-
-
-def test_parallel_run_steps_flat_matches_scan():
-    """ParallelExecutor.run_steps(mode='flat') gives the scan trajectory
-    exactly, SPMD over the 8-device mesh."""
-    x, y = _data(32)
-    feeds = [{"x": x[i * 8:(i + 1) * 8], "label": y[i * 8:(i + 1) * 8]}
-             for i in range(4)]
-
-    results = {}
-    for mode in ("scan", "flat"):
-        from paddle_tpu.core import framework, scope as scope_mod
-
-        framework.switch_main_program(fluid.Program())
-        framework.switch_startup_program(fluid.Program())
-        scope_mod._current_scope = scope_mod.Scope()
-        loss = _build_model(seed=4)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        pe = fluid.ParallelExecutor(loss_name=loss.name,
-                                    mesh=make_mesh({"dp": 8}))
-        (lv,) = pe.run_steps(feed_list=feeds, fetch_list=[loss], steps=6,
-                             mode=mode)
-        results[mode] = (np.ravel(lv)[0],
-                         np.asarray(fluid.global_scope().find_var("w1")))
-    np.testing.assert_allclose(results["scan"][0], results["flat"][0],
-                               rtol=1e-6)
-    np.testing.assert_allclose(results["scan"][1], results["flat"][1],
-                               rtol=1e-6)

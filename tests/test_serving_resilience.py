@@ -174,6 +174,70 @@ def test_dispatcher_death_restarts_with_queue_preserved():
     eng.close()
 
 
+def test_a_submit_inside_the_supervisors_restart_restarts_nothing(monkeypatch):
+    """One dead dispatcher is one restart, whoever else looks while the
+    supervisor replaces it: a submit() is driven from inside the new
+    thread's own start(), the moment at which the engine once published a
+    thread that had yet to start (is_alive() false) outside its lock, so
+    that submit() spawned a second dispatcher and counted a second
+    restart.  No sleep: where the restart holds the engine's lock nothing
+    can look, and the late submit is made after it."""
+    eng = Engine(_EchoBackend(),
+                 config=EngineConfig(buckets=(1,), max_wait_s=0.0))
+    real_start = threading.Thread.start
+    seen, late = [], []
+
+    def start(thread):
+        supervisor = threading.current_thread().name == f"serving-{eng.name}"
+        if supervisor and not seen:
+            seen.append(eng._lock.locked())
+            if not seen[0]:  # nothing keeps a submit() out: make one
+                late.append(eng.submit(_feed(9)))
+        real_start(thread)
+
+    monkeypatch.setattr(threading.Thread, "start", start)
+    os.environ["FAULT_SERVE_DISPATCH_RAISE"] = "thread"
+    futs = [eng.submit(_feed(i)) for i in range(3)]
+    for i, f in enumerate(futs):
+        np.testing.assert_array_equal(
+            f.result(timeout=30)[0], np.full((1, 2), 2.0 * i, np.float32))
+    late.append(eng.submit(_feed(9)))
+    for f in late:
+        np.testing.assert_array_equal(
+            f.result(timeout=30)[0], np.full((1, 2), 18.0, np.float32))
+    assert seen == [True]  # the supervisor ran, and restarted under the lock
+    assert eng.stats()["dispatcher_restarts"] == 1
+    assert sum(t.name == f"serving-{eng.name}" and t.is_alive()
+               for t in threading.enumerate()) == 1
+    eng.close()
+
+
+def test_a_dispatcher_gone_without_its_supervisor_is_restarted_by_submit(
+        monkeypatch):
+    """The other finder of a dead dispatcher: the thread is gone and no
+    supervisor ran (here: the hook is stubbed out), so the next submit()
+    restarts it, once, counts it, and the request is served; a second
+    submit() finds the new thread alive and restarts nothing."""
+    eng = Engine(_EchoBackend(),
+                 config=EngineConfig(buckets=(1,), max_wait_s=0.0))
+    monkeypatch.setattr(Engine, "_on_dispatcher_death",
+                        lambda self, exc: None)
+    os.environ["FAULT_SERVE_DISPATCH_RAISE"] = "thread"
+    dead = eng._thread
+    with eng._cond:
+        eng._cond.notify_all()  # the parked dispatcher cycles, and dies
+    dead.join(timeout=30)
+    assert not dead.is_alive()
+    assert eng.stats()["dispatcher_restarts"] == 0
+    for i in (3, 4):
+        np.testing.assert_array_equal(
+            eng.submit(_feed(i)).result(timeout=30)[0],
+            np.full((1, 2), 2.0 * i, np.float32))
+        assert eng.stats()["dispatcher_restarts"] == 1
+    assert eng._thread is not dead and eng._thread.is_alive()
+    eng.close()
+
+
 # -- (c) circuit breaker ------------------------------------------------
 
 def test_circuit_breaker_trips_fast_fails_and_recovers():

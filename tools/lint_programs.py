@@ -98,6 +98,14 @@ def main(argv=None) -> int:
             "lint_programs: --gate with --detectors would silently skip "
             "the other detectors' baselines — run the full set\n")
         return 2
+    if args.bank and (programs is not None or inject
+                      or detectors is not None):
+        # before the zoo is compiled: a refusal costs no compile
+        sys.stderr.write(
+            "lint_programs: refusing to --bank a filtered/injected "
+            "run — baselines must cover the whole zoo with every "
+            "detector\n")
+        return 2
     try:
         results = analysis.run_zoo(
             programs, inject=inject, detectors=detectors,
@@ -146,12 +154,6 @@ def main(argv=None) -> int:
 
     baseline = args.baseline or analysis.default_baseline_path()
     if args.bank:
-        if programs is not None or inject or detectors is not None:
-            sys.stderr.write(
-                "lint_programs: refusing to --bank a filtered/injected "
-                "run — baselines must cover the whole zoo with every "
-                "detector\n")
-            return 2
         try:
             doc = (analysis.bank(results, baseline, tolerance=args.tol)
                    if args.tol is not None
