@@ -26,6 +26,8 @@ __all__ = [
     "fused_attention",
     "rotary_embedding",
     "latent_attention",
+    "short_conv1d",
+    "gated_delta_attention",
     "compressed_conv_qkv",
     "sparse_attention",
     "moe_router",
@@ -1304,23 +1306,63 @@ def sparse_attention(q, k, v, index_q, index_k, index_w, topk, q_chunk=512,
 
 def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
                      qk_rope_head_dim, v_head_dim, rope_base=10000.0,
-                     name=None):
+                     name=None, rope="rotary"):
     """Causal multi-head latent attention (MLA) from its projections: q
     [B, S, H * (nope + rope)], the normalised latent [B, S, rank], the one
     rotary key part a token k_rope [B, S, rope], and the parameter
     kv_up_w [rank, H * (nope + v)]; returns the heads' contexts
-    [B, S, H * v] (TPU-native; ops/attention_ops.py latent_attention)."""
+    [B, S, H * v].  `rope` "none" leaves both rope-wide parts unturned
+    (no positions: the scores are q.k over all nope + rope features as
+    projected, under the causal mask alone); "rotary" turns them at
+    `rope_base` (TPU-native; ops/attention_ops.py latent_attention)."""
     helper = LayerHelper("latent_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"n_head": int(n_head),
+             "qk_nope_head_dim": int(qk_nope_head_dim),
+             "qk_rope_head_dim": int(qk_rope_head_dim),
+             "v_head_dim": int(v_head_dim), "rope_base": float(rope_base)}
+    if rope != "rotary":
+        attrs["rope"] = str(rope)
     helper.append_op(
         type="latent_attention",
         inputs={"Q": [q], "Latent": [latent], "KRope": [k_rope],
                 "KvUpW": [kv_up_w]},
+        outputs={"Out": [out]}, attrs=attrs,
+    )
+    return out
+
+
+def short_conv1d(x, weight, activation="silu", name=None):
+    """A causal depthwise convolution along the sequence and an
+    activation: x [B, S, C], weight [k, C] (one filter of k taps a
+    channel, the last tap on the position itself, zeros before the first
+    position), `activation` identity | silu | sigmoid | tanh | relu; [B, S,
+    C] out (TPU-native; ops/linear_attention_ops.py short_conv1d)."""
+    helper = LayerHelper("short_conv1d", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="short_conv1d", inputs={"X": [x], "W": [weight]},
+        outputs={"Out": [out]}, attrs={"activation": str(activation)},
+    )
+    return out
+
+
+def gated_delta_attention(q, k, v, g, beta, heads, chunk=64, name=None):
+    """Kimi Delta Attention's recurrence (a gated delta rule with a decay
+    for every key channel) over q, k, v [B, S, H D], the log-decay g [B, S,
+    H D] (<= 0, fp32) and beta [B, S, H]: each head's q and k to unit
+    length; a head's state M [D, D] from 0; a token does M~ = diag(exp(g))
+    M, M = M~ + beta k (v - M~^T k)^T, o = D^-1/2 M^T q.  [B, S, H D] out;
+    `chunk` tokens at a time (a power of two that divides S), backward
+    included (TPU-native; ops/linear_attention_ops.py
+    gated_delta_attention, kernels/gated_delta.py)."""
+    helper = LayerHelper("gated_delta_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        type="gated_delta_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]},
-        attrs={"n_head": int(n_head),
-               "qk_nope_head_dim": int(qk_nope_head_dim),
-               "qk_rope_head_dim": int(qk_rope_head_dim),
-               "v_head_dim": int(v_head_dim), "rope_base": float(rope_base)},
+        attrs={"heads": int(heads), "chunk": int(chunk)},
     )
     return out
 

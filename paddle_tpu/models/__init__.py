@@ -22,6 +22,8 @@ from .windowed_decoder import (  # noqa: F401
     windowed_decoder, WindowedDecoderConfig)
 from .compressed_decoder import (  # noqa: F401
     compressed_decoder, CompressedDecoderConfig)
+from .hybrid_linear_decoder import (  # noqa: F401
+    hybrid_linear_decoder, HybridLinearDecoderConfig)
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

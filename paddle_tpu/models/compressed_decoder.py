@@ -54,7 +54,6 @@ import numpy as np
 
 from .. import layers
 from ..core.framework import name_scope
-from ..initializer import ConstantInitializer, UniformInitializer
 from ..param_attr import ParamAttr
 from .common import (ModelSpec, SoftmaxExpertShare, one_trip_layer,
                      packed_batch)
@@ -97,16 +96,6 @@ class CompressedDecoderConfig:
 
 
 class _CompressedBuilder(SoftmaxExpertShare, _ExpertBuilder):
-    def conv_param(self, shape, name, fan_in):
-        """A convolution's weight or bias: U(+-1 / sqrt(fan_in))."""
-        bound = fan_in ** -0.5
-        return self.param(shape, name,
-                          initializer=UniformInitializer(-bound, bound))
-
-    def constant(self, shape, name, value, **attr):
-        return self.param(shape, name,
-                          initializer=ConstantInitializer(value), **attr)
-
     def attention(self, u, name):
         cfg = self.cfg
         H, G, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim

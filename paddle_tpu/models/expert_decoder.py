@@ -36,7 +36,7 @@ import numpy as np
 
 from .. import layers
 from ..core.framework import name_scope
-from ..initializer import ConstantInitializer
+from ..initializer import ConstantInitializer, UniformInitializer
 from ..param_attr import ParamAttr
 from .common import ModelSpec, one_trip_layer, packed_batch
 from .looped_decoder import _Builder, _heads_and_loss
@@ -58,6 +58,7 @@ class ExpertDecoderConfig:
     v_head_dim: int = 128
     kv_lora_rank: int = 512
     rope_theta: float = 50000.0
+    mla_rope: str = "rotary"        # rotary | none (no positions)
     rms_norm_eps: float = 1e-5
     n_routed_experts: int = 64      # the router's width
     experts_held: int = 8           # this chip's experts ...
@@ -81,6 +82,16 @@ class _ExpertBuilder(_Builder):
             shape, "float32",
             attr=ParamAttr(name=name, **{"initializer": self.init, **attr}))
 
+    def conv_param(self, shape, name, fan_in):
+        """A convolution's weight or bias: U(+-1 / sqrt(fan_in))."""
+        bound = fan_in ** -0.5
+        return self.param(shape, name,
+                          initializer=UniformInitializer(-bound, bound))
+
+    def constant(self, shape, name, value, **attr):
+        return self.param(shape, name,
+                          initializer=ConstantInitializer(value), **attr)
+
     def latent_attention(self, x, name):
         cfg = self.cfg
         H, dn, dr, dv = (cfg.n_head, cfg.qk_nope_head_dim,
@@ -94,11 +105,13 @@ class _ExpertBuilder(_Builder):
             q, self.norm(latent, f"{name}_kvn"), k_rope,
             self.param([cfg.kv_lora_rank, H * (dn + dv)], f"{name}_kvb_w"),
             n_head=H, qk_nope_head_dim=dn, qk_rope_head_dim=dr,
-            v_head_dim=dv, rope_base=cfg.rope_theta)
+            v_head_dim=dv, rope_base=cfg.rope_theta,
+            rope=cfg.mla_rope)
         return self.linear(ctx, H * dv, cfg.d_model, f"{name}_o")
 
     def expert_block(self, x, name):
-        """(the block's output, the tokens each expert was chosen by)."""
+        """(the block's output, the tokens each expert was chosen by, the
+        router's selection bias)."""
         cfg = self.cfg
         held, d, f = cfg.experts_held, cfg.d_model, cfg.d_expert
         bias = layers.create_parameter(
@@ -122,13 +135,16 @@ class _ExpertBuilder(_Builder):
                               cfg.n_shared_experts * f)
         return layers.elementwise_add(routed, shared), load, bias
 
+    def mixer(self, h, i):
+        """Mix_i(N1(h)): what layer i adds to the stream first."""
+        with name_scope("mla"):
+            return self.latent_attention(self.norm(h, f"l{i}_n1"),
+                                         f"l{i}_attn")
+
     def layer(self, h, i):
         """(h', load or None, the router's bias or None) of layer i."""
         name = f"l{i}"
-        with name_scope("mla"):
-            attn = self.latent_attention(self.norm(h, f"{name}_n1"),
-                                         f"{name}_attn")
-        a = layers.elementwise_add(h, attn)
+        a = layers.elementwise_add(h, self.mixer(h, i))
         x = self.norm(a, f"{name}_n2")
         if i < self.cfg.first_k_dense:
             out, load, bias = self.mlp(x, f"{name}_mlp"), None, None
@@ -139,13 +155,21 @@ class _ExpertBuilder(_Builder):
 
 def expert_decoder(cfg: Optional[ExpertDecoderConfig] = None, tokens=None,
                    labels=None) -> ModelSpec:
-    cfg = cfg or ExpertDecoderConfig()
+    return _decoder(_ExpertBuilder(cfg or ExpertDecoderConfig()),
+                    "expert_decoder", tokens, labels)
+
+
+def _decoder(b: _ExpertBuilder, name: str, tokens=None,
+             labels=None) -> ModelSpec:
+    """The model of the builder `b`: its `layer` a one-trip recurrence a
+    layer, each router's bias stepped after the step's routing read it,
+    looped_decoder's head."""
+    cfg = b.cfg
     S = cfg.max_length
     if tokens is None:
         tokens = layers.data("tokens", [S], dtype="int64")
     if labels is None:
         labels = layers.data("labels", [S], dtype="int64")
-    b = _ExpertBuilder(cfg)
 
     h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.d_model],
                          param_attr=ParamAttr(name="embed",
@@ -176,7 +200,7 @@ def expert_decoder(cfg: Optional[ExpertDecoderConfig] = None, tokens=None,
                             tokens.name, labels.name)
 
     return ModelSpec(
-        name="expert_decoder",
+        name=name,
         feed_names=[tokens.name, labels.name],
         loss=loss,
         synthetic_batch=synthetic_batch,

@@ -239,7 +239,11 @@ def _latent_attention(ctx, ins, attrs):
     [r, H * (dn + dv)] takes it to a head's key `nope` dn | value dv.
     KRope [B, S, dr]: ONE rotary key part a token, shared by all heads.
     Rotary on the two rope parts; k = [k_nope | k_rope], scores q.k /
-    sqrt(dn + dr); Out [B, S, H * dv].
+    sqrt(dn + dr); Out [B, S, H * dv].  Under `rope` "none" (default
+    "rotary") neither dr-wide part is turned: they enter the scores as
+    they are, dr more features of a query and of the shared key, and the
+    op knows no position but the causal order (a model that takes its
+    positions from other layers).
 
     The flash kernels take q and k at dn + dr and v at dv as they are: the
     kernels carry a value width of their own (kernels/flash_attention.py),
@@ -258,18 +262,25 @@ def _latent_attention(ctx, ins, attrs):
     dn, dr, dv = (int(attrs[a]) for a in
                   ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
     base = float(attrs.get("rope_base", 10000.0))
+    rope = str(attrs.get("rope") or "rotary")
+    if rope not in ("rotary", "none"):
+        raise ValueError(f"latent_attention: rope {rope!r} is neither "
+                         "'rotary' nor 'none'")
     B, S = q.shape[0], q.shape[1]
 
     def heads(t):                                    # [B, H, S, width]
         return jnp.swapaxes(t.reshape(B, S, H, -1), 1, 2)
 
+    def turned(t):
+        return _rotate(t, base) if rope == "rotary" else t
+
     with span("mla.lower", heads=H, qk_dim=dn + dr, v_dim=dv,
-              kv_rank=int(latent.shape[-1]), padded_v=0) as sp:
+              kv_rank=int(latent.shape[-1]), padded_v=0, rope=rope) as sp:
         lc, wc = amp.mxu_operands(latent, kv_w)
         kv = heads(amp.mxu_output(jnp.matmul(lc, wc), latent, kv_w))
         q = heads(q)
-        q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], base)], -1)
-        shared = jnp.broadcast_to(_rotate(k_rope[:, None], base).astype(
+        q = jnp.concatenate([q[..., :dn], turned(q[..., dn:])], -1)
+        shared = jnp.broadcast_to(turned(k_rope[:, None]).astype(
             kv.dtype), (B, H, S, dr))
         k = jnp.concatenate([kv[..., :dn], shared], -1)
         q, k = amp.match_kept(q, k)

@@ -426,7 +426,7 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
         n_routed_experts=64, experts_held=8, expert_offset=0, top_k=6)
     assert spans["mla.lower"] == 3 * [dict(
         heads=2, qk_dim=192, v_dim=128, kv_rank=512, padded_v=0,
-        kept="out,lse", kept_bytes=2 * 2 * 2048 * (128 * 2 + 4))]
+        rope="rotary", kept="out,lse", kept_bytes=2 * 2 * 2048 * (128 * 2 + 4))]
     T = 2 * 2048
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=64, experts_held=8, top_k=6, row_buffer=6 * T,
