@@ -41,29 +41,61 @@ q's dtype (bf16 on the AMP tier) and adds in fp32; decays, running sums and
 the state are fp32.
 
 The work that does not read the state (everything up to W, U, P) is
-parallel over chunks, the rest a lax.scan over them.  Both go a GROUP of
-chunks at a time (an outer scan), so that what the parallel part holds at
-once is a group's and not the sequence's (`plan`).  jax.custom_vjp: the
-backward walks the groups last to first, computes a group's parallel part again under
-jax.vjp, runs the group's chunks last to first carrying dM (written out:
-_chunk_bwd), and pulls the group's cotangents back to q, k, v, g, beta.
-Autodiff never sees the scan, so no state a token is ever stored.  Of the
-states the forward keeps the one every GROUP starts from (16 of 2 MB a
-layer at the cell's shape, where the 128 chunk states are 268 MB); the
-backward makes a group's chunk states again from it (_chunk_state: the
-state's update alone, a third of the scan's matmuls) before it walks the
-group's chunks back.  The forward tags its output and those states with
-core.compiler.keep: the backward of a recomputed layer runs no second
-forward of this op.
+parallel over chunks, the rest walks them in turn.  Two engines run it, and
+`engine` reads which from the shape and from what the program is traced
+for, no flag and no model's name:
+
+- The Pallas kernel pair (`kda.lower` says `engine` pallas): heads of whole
+  128-lane vectors, a sequence of whole tiles of 128 rows, for a TPU (or
+  force="interpret", the CPU tests' door).  The inputs stay as the layer
+  hands them, [B, S, H D]: a block spec picks a head by its lane block and
+  a GROUP of rows (512: `kernel_tiles`) by its row block, nothing is
+  regrouped in HBM.  A grid step (batch x heads parallel, the groups in
+  turn) walks its group a TILE of 128 rows at a time, two chunks of 64
+  whose A, X and P are the diagonal blocks of one [128, 128] square, so
+  that every product of the parallel part fills the MXU; the running sum
+  (log2 C rolls), the halvings (one plane exp(-|Gc - Gc_mid|) a level
+  serves both sides), the inverse, W, U, P, the correction and the output
+  live on VMEM values, the state [D, D] fp32 in VMEM scratch across the
+  groups.  The forward writes `out` and the state every group starts from.
+  The backward (jax.custom_vjp; residuals the op's inputs in their own
+  layout and those states) runs the groups last to first with dM in
+  scratch: a step makes its tiles' parallel part and chunk states again
+  (W, U', Q exp(Gc), P, K exp(Gc_C - Gc), X, the k-k product and a state a
+  chunk, all in scratch), then walks the tiles back: _chunk_bwd's
+  products stacked, and the cotangents pulled back to q, k, v, g, beta
+  written out (_tile_pull: the inverse's is -X^T dX X^T, the running
+  sum's a reversed running sum, and the decayed products' dGc needs no
+  derivative of the point a halving measures from).
+- The jax.numpy engine (`engine` xla) everywhere else: tiny heads, a chunk
+  that is the whole sequence, a working set over the budget, a CPU.  Both
+  parts go a GROUP of chunks at a time (an outer lax.scan), so that what
+  the parallel part holds at once is a group's and not the sequence's
+  (`plan`), on q, k, v, g regrouped to [groups, group, B, H, C, D].
+  jax.custom_vjp: the backward walks the groups last to first, computes a
+  group's parallel part again under jax.vjp, runs the group's chunks last
+  to first carrying dM (written out: _chunk_bwd), and pulls the group's
+  cotangents back.  It is the kernels' reference in tests/.
+
+Either way autodiff never sees the scan, so no state a token is ever
+stored.  Of the states the forward keeps the one every GROUP starts from
+(16 of 2 MB a layer at the cell's shape, where the 128 chunk states are 268
+MB); the backward makes a group's chunk states again from it before it
+walks the group's chunks back.  The forward tags its output and those
+states with core.compiler.keep: the backward of a recomputed layer runs no
+second forward of this op.  tools/kda_scan_probe.py times and checks the
+two engines alone on the chip.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from ..analysis.pallas import V5E_VMEM_BYTES
 from ..core.compiler import keep
 
 CHUNK = 64
@@ -72,6 +104,7 @@ KEPT = ("out", "states")
 # a few dozen such values at once
 _GROUP_BYTES = 8 << 20
 _HIGHEST = jax.lax.Precision.HIGHEST
+_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
 _F32 = jnp.float32
 
 
@@ -171,32 +204,54 @@ def _decayed_products(qn, kn, gc, mm):
     return out
 
 
-def _halves(c, b):
-    """[C, C] bool: the pairs (r, j) with r in the second half and j in
-    the first half of one block of 2b rows."""
-    row = jnp.arange(c)
-    return (row[:, None] // b == row[None, :] // b + 1) \
-        & (row[:, None] // (2 * b) == row[None, :] // (2 * b))
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _unit_lower_inverse(a):
+def _halves(c, b, rows=1):
+    """[rows c, c] bool: the pairs (r, j) with r in the second half and j
+    in the first half of one block of 2b rows (`rows` such squares on top
+    of each other).  Two-dimensional iotas: the kernels build it too."""
+    row, col = _iota((rows * c, c), 0) % c, _iota((rows * c, c), 1)
+    return (row // b == col // b + 1) & (row // (2 * b) == col // (2 * b))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, block=None):
     """(I + a)^-1 of a strictly lower triangular a [..., C, C], fp32, by
     doubling the blocks: X holds the inverses of the diagonal blocks of b
-    rows (I at b = 1); a block of 2b rows [[L1, 0], [A21, L2]] has the
-    inverse [[X1, 0], [-X2 A21 X1, X2]], and X a X, two whole [C, C]
-    matmuls, holds every X2 A21 X1 at once.  Every value made is an entry
-    of the inverse: the finite series I - a + a^2 - ... in powers of a is
-    shorter and loses every digit where a's entries are near 1 (a^32 has
-    entries of 1e10 that cancel)."""
+    rows (I at b = 1, so the first level is I - a's pairs itself); a block
+    of 2b rows [[L1, 0], [A21, L2]] has the inverse [[X1, 0], [-X2 A21 X1,
+    X2]], and X a X, two whole [C, C] matmuls, holds every X2 A21 X1 at
+    once.  Every value made is an entry of the inverse: the finite series
+    I - a + a^2 - ... in powers of a is shorter and loses every digit
+    where a's entries are near 1 (a^32 has entries of 1e10 that cancel).
+    `block` (C where None): the doubling stops at diagonal blocks of that
+    many rows, for an `a` that is itself block diagonal (the kernels' tile
+    of two chunks).  Its cotangent is -X^T dX X^T, two products, not the
+    levels differentiated."""
     c = a.shape[-1]
-    x = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
-    b = 1
-    while b < c:
+    x = jnp.eye(c, dtype=a.dtype) - jnp.where(_halves(c, 1), a, 0.0)
+    b = 2
+    while b < (block or c):
         xax = jnp.matmul(jnp.matmul(x, a, precision=_HIGHEST), x,
                          precision=_HIGHEST)
         x = x - jnp.where(_halves(c, b), xax, 0.0)
         b *= 2
     return x
+
+
+def _inverse_cotangent(x, dx):
+    return -jnp.einsum(
+        "...ik,...lk->...il",
+        jnp.einsum("...ji,...jk->...ik", x, dx, precision=_HIGHEST,
+                   preferred_element_type=_F32),
+        x, precision=_HIGHEST, preferred_element_type=_F32)
+
+
+_unit_lower_inverse.defvjp(
+    lambda a, block: (lambda x: (x, x))(_unit_lower_inverse(a, block)),
+    lambda block, x, dx: (_inverse_cotangent(x, dx),))
 
 
 def _unit(x, eps):
@@ -312,13 +367,568 @@ def _scan_bwd(eps, res, do):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the Pallas engine: a head's group of chunks a grid step, everything on
+# VMEM values
+# ---------------------------------------------------------------------------
+# Rows the chunk-parallel part takes at once: one MXU tile.  Two chunks of
+# 64 side by side: their A, X and P are the diagonal blocks of [128, 128]
+# matrices (the masks keep the chunks apart), so every product of that part
+# fills the array where a chunk alone fills a quarter of it.
+_TILE = 128
+# The widest group of rows `kernel_tiles` gives a grid step (today's group,
+# 8 chunks of 64) and the tiles of it a loop body holds (all four: the
+# compiler then fills one tile's waits, the inverse's ten fp32 products one
+# behind the other, with another tile's work).  On the chip at the cell's
+# shape (tools/kda_scan_probe.py --sweep, ms a layer, PERF.md PR 48) the
+# forward reads 3.86 / 3.72 / 3.61 at 128 / 256 / 512 rows a tile a body
+# and 3.37, 3.28 at two, 3.15 at four of 512; the backward 5.80 / 5.89 /
+# 5.79, then 5.31, 5.35 and 5.11.
+_MAX_ROWS = 512
+_UNROLL = 4
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+class Tiles(NamedTuple):
+    """What a site's kernels are built from, all read from the shape."""
+    rows: int          # rows a grid step: a group of chunks of one head
+    chunk: int
+    unroll: int        # tiles a loop body of the kernels holds
+    fwd_vmem: int      # bytes of the forward's working set
+    bwd_vmem: int
+
+
+def working_set_bytes(rows, chunk, dim, itemsize, backward) -> int:
+    """What a grid step holds in VMEM: the declared blocks twice (the
+    pipeline's two buffers), the scratch (the backward's: the state every
+    chunk starts from and seven values a row of what the walk back reads),
+    and the fp32 values of the tile in flight (about four dozen [tile, D]
+    planes, a dozen [tile, tile] squares, a few states)."""
+    wide, gate, state = rows * dim * itemsize, rows * dim * 4, dim * dim * 4
+    blocks = 4 * wide + gate + 4 * rows + state      # q k v out, g, beta, M
+    scratch = state
+    if backward:
+        blocks += 3 * wide + gate + 4 * rows         # dout; dq dk dv dg dbeta
+        scratch += (rows // chunk + 1) * state + 4 * wide \
+            + rows * _TILE * (itemsize + 8)
+    live = 48 * _TILE * dim * 4 + 12 * _TILE * _TILE * 4 + 6 * state
+    return 2 * blocks + scratch + live
+
+
+def kernel_tiles(batch, seq, heads, dim, chunk, dtype, rows=None,
+                 unroll=None):
+    """(the tiles of a site the kernel pair takes, "") or (None, why not).
+    It takes heads of whole 128-lane vectors (a block spec then picks a
+    head of [B, S, H D] by its lane block), chunks that tile _TILE rows at
+    the operand dtype's sublane tile, more than one chunk, and a sequence
+    of whole groups of rows whose working set fits; `rows` and `unroll`
+    pin the group and the tiles a loop body holds for a test or the
+    probe, never a model."""
+    size = jnp.dtype(dtype).itemsize
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
+        return None, f"operands of {jnp.dtype(dtype).name}"
+    if dim % 128:
+        return None, f"heads of {dim}: not whole 128-lane vectors"
+    if chunk >= seq:
+        return None, "the sequence is one chunk"
+    if _TILE % chunk or chunk % (32 // size):
+        return None, f"chunks of {chunk} do not tile {_TILE} rows"
+    if seq % _TILE:
+        return None, f"a sequence of {seq} is not whole tiles of {_TILE} rows"
+
+    def fits(r):
+        return working_set_bytes(r, chunk, dim, size, True) \
+            <= _PLAN_VMEM_BUDGET
+
+    if rows is None:
+        rows = next((r for r in (_MAX_ROWS, _MAX_ROWS // 2, _TILE)
+                     if seq % r == 0 and fits(r)), None)
+        if rows is None:
+            return None, f"heads of {dim} do not fit the VMEM budget"
+    elif seq % rows or rows % _TILE or not fits(rows):
+        return None, f"groups of {rows} rows do not tile or do not fit"
+    return Tiles(rows, chunk, unroll or _UNROLL,
+                 working_set_bytes(rows, chunk, dim, size, False),
+                 working_set_bytes(rows, chunk, dim, size, True)), ""
+
+
+def engine(batch, seq, heads, dim, chunk, dtype, force="auto", rows=None,
+           unroll=None):
+    """The tiles where the site runs the kernel pair, None where it runs
+    the jax.numpy engine: read from the shape and from what the program is
+    traced for (force="interpret": the CPU tests' door; "jax": never)."""
+    from .flash_attention import _use_pallas
+
+    if force != "interpret" and not _use_pallas(force):
+        return None
+    tiles, why = kernel_tiles(batch, seq, heads, dim, chunk, dtype, rows,
+                              unroll)
+    if tiles is None and rows is not None:
+        raise ValueError(f"gated_delta_attention: no kernels at {rows} rows "
+                         f"a grid step: {why}")
+    return tiles
+
+
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=_F32)
+
+
+def _roll(x, shift, axis=0):
+    """y[r] = x[r - shift] (jnp.roll's)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    shift %= x.shape[axis]
+    return x if shift == 0 else pltpu.roll(x, shift, axis)
+
+
+def _across(vector, eye, axis):
+    """A [1, n] row as the [n, 1] column (axis 1) or the column as the row
+    (axis 0): the diagonal of its broadcast, summed."""
+    return jnp.sum(jnp.where(eye, vector, 0.0), axis=axis, keepdims=True)
+
+
+def _running_sum(x, pos, chunk, reverse=False):
+    """The running sum down the rows of every chunk (up them under
+    `reverse`: its transpose), in log2 `chunk` steps of a roll."""
+    s = 1
+    while s < chunk:
+        if reverse:
+            x = x + jnp.where(pos < chunk - s, _roll(x, -s), 0.0)
+        else:
+            x = x + jnp.where(pos >= s, _roll(x, s), 0.0)
+        s *= 2
+    return x
+
+
+def _middles(gc, pos, b):
+    """[T, D]: Gc at the middle row of the block of 2b rows a row lies in,
+    the point both decays of a halving are measured from."""
+    T, D = gc.shape
+    if 2 * b >= 8:                       # whole sublane tiles: a broadcast
+        mid = gc.reshape(T // (2 * b), 2 * b, D)[:, b:b + 1, :]
+        return jnp.broadcast_to(mid, (T // (2 * b), 2 * b, D)).reshape(T, D)
+    at = pos % (2 * b)
+    out = gc
+    for p in range(2 * b):
+        if p != b:
+            out = jnp.where(at == p, _roll(gc, p - b), out)
+    return out
+
+
+class _Masks:
+    """The iotas of a tile, made once a grid step."""
+
+    def __init__(self, T, C, D):
+        row, col = _iota((T, T), 0), _iota((T, T), 1)
+        self.T, self.C, self.D = T, C, D
+        self.pos = _iota((T, D), 0) % C           # a row's place in its chunk
+        self.eye = row == col
+        self.eye2 = _iota((2 * T, T), 0) % T == _iota((2 * T, T), 1)
+        self.eye_d = _iota((D, D), 0) == _iota((D, D), 1)
+        self.below = (col < row) & (row // C == col // C)
+        self.halves = {}
+        b = C // 2
+        while b:
+            self.halves[b] = _halves(T, b, rows=2)
+            b //= 2
+
+
+def _tile_values(q, k, v, g, beta_row, masks, eps):
+    """The planes of a tile that cost no matmul: unit q and k with their
+    inverse lengths, Gc, beta down the rows, and the decays."""
+    q, k = q.astype(_F32), k.astype(_F32)
+    rq = jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + eps)
+    rk = jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + eps)
+    T, C, D = masks.T, masks.C, masks.D
+    gc = _running_sum(g, masks.pos, C)
+    last = [gc[c * C + C - 1:(c + 1) * C, :] for c in range(T // C)]
+    last_rows = jnp.concatenate(
+        [jnp.broadcast_to(t, (C, D)) for t in last], axis=0)
+    beta = _across(beta_row, masks.eye, 1)
+    qn, kn, eg = q * rq, k * rk, jnp.exp(gc)
+    return dict(
+        qn=qn, kn=kn, rq=rq, rk=rk, gc=gc, eg=eg, beta=beta, v=v,
+        ed=jnp.exp(last_rows - gc), gamma=[jnp.exp(t) for t in last],
+        kb=beta * (kn * eg), vb=beta * v.astype(_F32),
+        qg=qn * eg * D ** -0.5)
+
+
+def _halving(x, masks, mm):
+    """Of every level b: (b, exp(-|Gc - Gc_mid|) [T, D], k and q times it
+    in the operand dtype stacked [2T, D])."""
+    b = masks.C // 2
+    while b:
+        e = jnp.exp(-jnp.abs(x["gc"] - _middles(x["gc"], masks.pos, b)))
+        yield b, e, jnp.concatenate(
+            [(x["kn"] * e).astype(mm), (x["qn"] * e).astype(mm)], axis=0)
+        b //= 2
+
+
+def _tile_local(x, masks, mm):
+    """_local of a tile's chunks: (W, U, Q exp(Gc) D^-1/2, P, K exp(Gc_C -
+    Gc)) in the operand dtype, and X and the decayed k-k product fp32 for
+    the backward.  A row below the middle of its block has exp(Gc - Gc_mid)
+    and a row above it exp(Gc_mid - Gc): ONE plane exp(-|Gc - Gc_mid|) a
+    level serves both sides of the masked product."""
+    T, D = masks.T, masks.D
+    kn, qn = x["kn"], x["qn"]
+    both = jnp.concatenate([kn, qn], axis=0)
+    diag = jnp.sum(both * jnp.concatenate([kn, kn], axis=0), axis=-1,
+                   keepdims=True)
+    prod = jnp.where(masks.eye2, diag, 0.0)
+    for b, _, sides in _halving(x, masks, mm):
+        prod = prod + jnp.where(masks.halves[b],
+                                _dot(sides, sides[:T], _NT), 0.0)
+    kk = jnp.where(masks.below, prod[:T], 0.0)
+    inv = _unit_lower_inverse(kk * x["beta"], masks.C)
+    wu = _dot(inv.astype(mm), jnp.concatenate(
+        [x["kb"].astype(mm), x["vb"].astype(mm)], axis=1), _NN)
+    return dict(w=wu[:, :D].astype(mm), u=wu[:, D:].astype(mm),
+                qg=x["qg"].astype(mm), p=(prod[T:] * D ** -0.5).astype(mm),
+                kd=(kn * x["ed"]).astype(mm), gamma=x["gamma"], inv=inv,
+                kk=kk)
+
+
+def _tile_scan(m, loc, masks, mm):
+    """The tile's chunks in turn from the state m: (the state after them,
+    the output [T, D] fp32, the state every chunk starts from, U' [T, D])."""
+    C = masks.C
+    starts, u2s, read = [], [], []
+    for c, gamma in enumerate(loc["gamma"]):
+        rows = slice(c * C, (c + 1) * C)
+        mc = m.astype(mm)
+        wq = _dot(jnp.concatenate([loc["w"][rows], loc["qg"][rows]], axis=0),
+                  mc, _NN)
+        u2 = (loc["u"][rows].astype(_F32) - wq[:C]).astype(mm)
+        starts.append(m)
+        read.append(wq[C:])
+        u2s.append(u2)
+        m = _across(gamma, masks.eye_d, 1) * m \
+            + _dot(loc["kd"][rows], u2, _TN)
+    u2 = jnp.concatenate(u2s, axis=0)
+    return (m, jnp.concatenate(read, axis=0) + _dot(loc["p"], u2, _NN),
+            starts, u2)
+
+
+def _walk(trips, body, unroll):
+    """body(t) for t in 0 .. trips - 1, `unroll` trips a loop body."""
+    unroll = max(1, min(unroll, trips))
+    if unroll == trips:
+        for t in range(trips):
+            body(t)
+        return
+
+    def some(j, carry):
+        for u in range(unroll):
+            body(j * unroll + u)
+        return carry
+
+    jax.lax.fori_loop(0, trips // unroll, some, 0)
+
+
+def _rows_of(t, T):
+    import jax.experimental.pallas as pl
+
+    return slice(t * T, (t + 1) * T) if isinstance(t, int) \
+        else pl.ds(pl.multiple_of(t * T, T), T)
+
+
+def _row_of(t):
+    import jax.experimental.pallas as pl
+
+    return slice(t, t + 1) if isinstance(t, int) else pl.ds(t, 1)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, start_ref,
+                m_scr, *, tiles, eps):
+    import jax.experimental.pallas as pl
+
+    T, D, mm = _TILE, q_ref.shape[-1], q_ref.dtype
+    masks = _Masks(T, tiles.chunk, D)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _the_state_starts_at_zero():
+        m_scr[...] = jnp.zeros_like(m_scr)
+
+    start_ref[0, 0, 0] = m_scr[...]
+
+    def tile(t):
+        rows = _rows_of(t, T)
+        x = _tile_values(q_ref[0, rows, :], k_ref[0, rows, :],
+                         v_ref[0, rows, :], g_ref[0, rows, :],
+                         beta_ref[0, 0, 0, _row_of(t), :], masks, eps)
+        m, out, _, _ = _tile_scan(m_scr[...], _tile_local(x, masks, mm),
+                                  masks, mm)
+        m_scr[...] = m
+        out_ref[0, rows, :] = out.astype(out_ref.dtype)
+
+    _walk(tiles.rows // T, tile, tiles.unroll)
+
+
+def _tile_pull(x, inv, kk, d, masks, mm):
+    """The cotangents d = (dW, dU, dQG, dP, dKD [T, .] and d exp(Gc_C) a
+    chunk) of _tile_local's values pulled back to (dq, dk, dv, dg [T, D]
+    fp32, dbeta [T, 1]), written out.  The decayed products read Gc
+    through differences alone, so the point a halving measures from takes
+    no cotangent: dGc is x dx as a row less k dk as a column, a channel
+    at a time."""
+    T, C, D = masks.T, masks.C, masks.D
+    dw, du, dqg, dp, dkd, dgamma = d
+    qn, kn, eg, ed, beta = x["qn"], x["kn"], x["eg"], x["ed"], x["beta"]
+    # W, U = X [beta K exp(Gc) | beta V]
+    right = jnp.concatenate([x["kb"].astype(mm), x["vb"].astype(mm)], axis=1)
+    dwu = jnp.concatenate([dw.astype(mm), du.astype(mm)], axis=1)
+    dright = _dot(inv.astype(mm), dwu, _TN)
+    dkb, dvb = dright[:, :D], dright[:, D:]
+    da = jnp.where(masks.below, _inverse_cotangent(
+        inv, _dot(dwu, right, _NT)), 0.0)
+    dbeta = jnp.sum(da * kk, axis=-1, keepdims=True) \
+        + jnp.sum(dkb * (kn * eg) + dvb * x["v"].astype(_F32), axis=-1,
+                  keepdims=True)
+    # the two decayed products, level by level
+    dprod = jnp.concatenate([da * beta, dp.astype(_F32) * D ** -0.5], axis=0)
+    on_diagonal = jnp.sum(jnp.where(masks.eye, dprod[T:], 0.0), axis=-1,
+                          keepdims=True)
+    as_row, dk_col = jnp.zeros((2 * T, D), _F32), jnp.zeros((T, D), _F32)
+    for b, e, sides in _halving(x, masks, mm):
+        kept = jnp.where(masks.halves[b], dprod, 0.0).astype(mm)
+        as_row = as_row + _dot(kept, sides[:T], _NN) \
+            * jnp.concatenate([e, e], axis=0)
+        dk_col = dk_col + _dot(kept, sides, _TN) * e
+    dk_row, dq_row = as_row[:T], as_row[T:]
+    dgc = kn * dk_row + qn * dq_row - kn * dk_col
+    dkn = dk_row + dk_col + on_diagonal * qn
+    dqn = dq_row + on_diagonal * kn
+    # K exp(Gc) beta, Q exp(Gc) D^-1/2, K exp(Gc_C - Gc), exp(Gc_C)
+    dkn = dkn + dkb * (beta * eg) + dkd * ed
+    dqn = dqn + dqg * (eg * D ** -0.5)
+    through_last = dkd * (kn * ed)
+    dgc = dgc + dkb * x["kb"] + dqg * x["qg"] - through_last
+    dlast = jnp.concatenate([jnp.broadcast_to(
+        jnp.sum(through_last[c * C:(c + 1) * C], axis=0, keepdims=True)
+        + dgamma[c] * x["gamma"][c], (C, D)) for c in range(T // C)], axis=0)
+    dgc = dgc + jnp.where(masks.pos == C - 1, dlast, 0.0)
+
+    def unit_back(n, dn, r):
+        return r * (dn - n * jnp.sum(dn * n, axis=-1, keepdims=True))
+
+    return (unit_back(qn, dqn, x["rq"]), unit_back(kn, dkn, x["rk"]),
+            dvb * beta, _running_sum(dgc, masks.pos, C, reverse=True), dbeta)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dm_scr, m_scr,
+                starts_scr, w_scr, u2_scr, qg_scr, kd_scr, p_scr, inv_scr,
+                kk_scr, *, tiles, eps):
+    import jax.experimental.pallas as pl
+
+    T, C, D, mm = _TILE, tiles.chunk, q_ref.shape[-1], q_ref.dtype
+    trips, per = tiles.rows // T, T // C
+    masks = _Masks(T, C, D)
+    lower = masks.below | masks.eye
+
+    @pl.when(pl.program_id(2) == 0)
+    def _nothing_after_the_last_group():
+        dm_scr[...] = jnp.zeros_like(dm_scr)
+
+    def values(t):
+        rows = _rows_of(t, T)
+        return _tile_values(q_ref[0, rows, :], k_ref[0, rows, :],
+                            v_ref[0, rows, :], g_ref[0, rows, :],
+                            beta_ref[0, 0, 0, _row_of(t), :], masks, eps)
+
+    # the group's chunk states again from its start state, and what the
+    # walk back reads of every tile
+    m_scr[...] = start_ref[0, 0, 0]
+
+    def ahead(t):
+        rows = _rows_of(t, T)
+        loc = _tile_local(values(t), masks, mm)
+        m, _, starts, u2 = _tile_scan(m_scr[...], loc, masks, mm)
+        m_scr[...] = m
+        for c, start in enumerate(starts):
+            starts_scr[t * per + c] = start
+        for ref, value in ((w_scr, loc["w"]), (u2_scr, u2),
+                           (qg_scr, loc["qg"]), (kd_scr, loc["kd"]),
+                           (p_scr, loc["p"]), (inv_scr, loc["inv"]),
+                           (kk_scr, loc["kk"])):
+            ref[rows, :] = value
+
+    _walk(trips, ahead, tiles.unroll)
+
+    def back(j):
+        t = trips - 1 - j
+        rows = _rows_of(t, T)
+        x = values(t)
+        do = do_ref[0, rows, :]
+        w, u2, qg, kd, p = (ref[rows, :] for ref in (
+            w_scr, u2_scr, qg_scr, kd_scr, p_scr))
+        du_own = _dot(p, do, _TN)
+        dp = jnp.where(lower, _dot(do, u2, _NT), 0.0)
+        dm = dm_scr[...]
+        dw, du, dqg, dkd, dgamma = ([None] * per for _ in range(5))
+        for c in reversed(range(per)):          # _chunk_bwd, stacked
+            cut = slice(c * C, (c + 1) * C)
+            m = starts_scr[t * per + c]
+            mc, dmc = m.astype(mm), dm.astype(mm)
+            du[c] = (du_own[cut] + _dot(kd[cut], dmc, _NN)).astype(mm)
+            back_m = _dot(jnp.concatenate([du[c], do[cut]], axis=0), mc, _NT)
+            dw[c], dqg[c] = -back_m[:C], back_m[C:]
+            dkd[c] = _dot(u2[cut], dmc, _NT)
+            dgamma[c] = jnp.sum(m * dm, axis=-1, keepdims=True)
+            dm = _across(x["gamma"][c], masks.eye_d, 1) * dm + _dot(
+                jnp.concatenate([qg[cut], w[cut]], axis=0),
+                jnp.concatenate([do[cut], -du[c]], axis=0), _TN)
+        dm_scr[...] = dm
+        dgamma = [_across(t_, masks.eye_d, 0) for t_ in dgamma]
+        stacked = [jnp.concatenate(parts, axis=0)
+                   for parts in (dw, du, dqg, dkd)]
+        dq, dk, dv, dg, dbeta = _tile_pull(
+            x, inv_scr[rows, :], kk_scr[rows, :],
+            (stacked[0], stacked[1], stacked[2], dp, stacked[3], dgamma),
+            masks, mm)
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, rows, :] = dg
+        dbeta_ref[0, 0, 0, _row_of(t), :] = _across(dbeta, masks.eye, 0)
+
+    _walk(trips, back, tiles.unroll)
+
+
+def _compiler_params(need):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(max(V5E_VMEM_BYTES, 2 * need)))
+
+
+def _specs(tiles, D, last=None):
+    """The block specs of a head's group of rows: of [B, S, H D] values,
+    of beta and dbeta [B, H, groups, tiles, T], of the states [B, H,
+    groups, D, D].  `last`: the grid runs the groups last to first."""
+    import jax.experimental.pallas as pl
+
+    def group(s):
+        return s if last is None else last - s
+
+    R, T = tiles.rows, _TILE
+    return (pl.BlockSpec((1, R, D), lambda b, h, s: (b, group(s), h)),
+            pl.BlockSpec((1, 1, 1, R // T, T),
+                         lambda b, h, s: (b, h, group(s), 0, 0)),
+            pl.BlockSpec((1, 1, 1, D, D),
+                         lambda b, h, s: (b, h, group(s), 0, 0)))
+
+
+@functools.lru_cache(maxsize=64)
+def _fwd_call(B, S, H, D, tiles, dtype, eps, interpret):
+    """Memoized, as kernels/flash_attention.py::_fwd_call: every site of
+    one shape shares one kernel payload."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R = tiles.rows
+    wide, gate, state = _specs(tiles, D)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, tiles=tiles, eps=eps),
+        grid=(B, H, S // R),
+        in_specs=[wide, wide, wide, wide, gate],
+        out_specs=[wide, state],
+        out_shape=[jax.ShapeDtypeStruct((B, S, H * D), jnp.dtype(dtype)),
+                   jax.ShapeDtypeStruct((B, H, S // R, D, D), _F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), _F32)],
+        compiler_params=_compiler_params(tiles.fwd_vmem),
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _bwd_call(B, S, H, D, tiles, dtype, eps, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, T = tiles.rows, _TILE
+    wide, gate, state = _specs(tiles, D, last=S // R - 1)
+    dtype = jnp.dtype(dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, tiles=tiles, eps=eps),
+        grid=(B, H, S // R),
+        in_specs=[wide, wide, wide, wide, gate, state, wide],
+        out_specs=[wide, wide, wide, wide, gate],
+        out_shape=[jax.ShapeDtypeStruct((B, S, H * D), dtype)] * 3
+        + [jax.ShapeDtypeStruct((B, S, H * D), _F32),
+           jax.ShapeDtypeStruct((B, H, S // R, R // T, T), _F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), _F32), pltpu.VMEM((D, D), _F32),
+                        pltpu.VMEM((R // tiles.chunk, D, D), _F32)]
+        + [pltpu.VMEM((R, D), dtype)] * 4
+        + [pltpu.VMEM((R, T), dtype), pltpu.VMEM((R, T), _F32),
+           pltpu.VMEM((R, T), _F32)],
+        compiler_params=_compiler_params(tiles.bwd_vmem),
+        interpret=interpret,
+    )
+
+
+def _beta_by_tiles(beta, tiles):
+    """beta [B, S, H] as [B, H, groups, tiles a group, T] fp32: a tile's
+    betas one row of 128 lanes."""
+    B, S, H = beta.shape
+    return jnp.moveaxis(beta.astype(_F32), 2, 1).reshape(
+        B, H, S // tiles.rows, tiles.rows // _TILE, _TILE)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _kernels(q, k, v, g, beta, heads, tiles, eps, interpret):
+    return _kernels_fwd(q, k, v, g, beta, heads, tiles, eps, interpret)[0]
+
+
+def _kernels_fwd(q, k, v, g, beta, heads, tiles, eps, interpret):
+    B, S, width = q.shape
+    call = _fwd_call(B, S, heads, width // heads, tiles, str(q.dtype), eps,
+                     interpret)
+    out, starts = keep(*call(q, k, v, g, _beta_by_tiles(beta, tiles)))
+    return out, (q, k, v, g, beta, starts)
+
+
+def _kernels_bwd(heads, tiles, eps, interpret, res, do):
+    q, k, v, g, beta, starts = res
+    B, S, width = q.shape
+    call = _bwd_call(B, S, heads, width // heads, tiles, str(q.dtype), eps,
+                     interpret)
+    dq, dk, dv, dg, dbeta = call(q, k, v, g, _beta_by_tiles(beta, tiles),
+                                 starts, do.astype(q.dtype))
+    return dq, dk, dv, dg, jnp.moveaxis(
+        dbeta.reshape(B, heads, S), 1, 2).astype(beta.dtype)
+
+
+_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
 def gated_delta_attention(q, k, v, g, beta, heads: int, chunk: int = CHUNK,
-                          eps: float = 1e-6):
+                          eps: float = 1e-6, force: str = "auto",
+                          rows=None, unroll=None):
     """Out [B, S, H D] in q's dtype of q, k, v [B, S, H D] (one dtype: the
     matmuls' operands), the log-decay g [B, S, H D] (<= 0; taken to fp32)
     and beta [B, S, H]: each head's q and k to unit length (fp32, `eps`
     under the root), then the module's recurrence, `chunk` tokens at a
-    time."""
+    time.  The engine is read from the shape (`engine`): the kernel pair
+    on the values as they come, or the jax.numpy scans on regrouped
+    copies; `force`, `rows` and `unroll` are the tests' and the probe's."""
+    B, S, width = q.shape
+    chunk = plan(B, S, heads, width // heads, chunk)["chunk"]
+    tiles = engine(B, S, heads, width // heads, chunk, q.dtype, force, rows,
+                   unroll)
+    if tiles is None:
+        return _scan_by_groups(q, k, v, g, beta, heads, chunk, float(eps))
+    return _kernels(q, k, v, g.astype(_F32), beta, heads, tiles, float(eps),
+                    force == "interpret")
+
+
+def _scan_by_groups(q, k, v, g, beta, heads: int, chunk: int, eps: float):
+    """The jax.numpy engine: q, k, v, g regrouped to [groups, group, B,
+    H, C, D] (copies in HBM, and the custom backward's residuals) for the
+    two scans of _scan."""
     B, S, width = q.shape
     D = width // heads
     tiles = plan(B, S, heads, D, chunk)

@@ -57,12 +57,16 @@ def _gated_delta_attention(ctx, ins, attrs):
     (core.compiler.keep): the backward of a recomputed layer runs no
     second forward of the op.  Under the name scope `kda.scan`.  `kda.lower` (a span, at
     lowering) says what a site was given: `heads`, `head_dim`, `sq`,
-    `chunk`, `chunks`, `group` (chunks the parallel part takes at once),
-    `engine` (xla: jax.numpy matmuls and lax.scan; no Pallas engine yet),
-    `state_bytes` (one chunk boundary's states), `kept` and `kept_bytes`
-    (what it holds through that recomputation) and the static `flops` and
-    `moved_bytes` of the site's forward and backward; the context's `kept`
-    counts the values."""
+    `chunk`, `chunks`, `group` (chunks the jax.numpy engine's parallel part
+    takes at once), `engine` (pallas: the kernel pair on the inputs as
+    they come, where the program is for a TPU, heads are whole 128-lane
+    vectors and the sequence whole tiles of 128 rows, with `rows` a grid
+    step and the `fwd_vmem_bytes` and `bwd_vmem_bytes` of its working
+    sets; xla: jax.numpy matmuls and lax.scan on regrouped copies,
+    everywhere else), `state_bytes` (one chunk boundary's states), `kept`
+    and `kept_bytes` (what it holds through that recomputation) and the
+    static `flops` and `moved_bytes` of the site's forward and backward;
+    the context's `kept` counts the values."""
     from ..kernels import gated_delta as kda
 
     q, k, v = amp.mxu_operands(*(data(ins[s][0]) for s in ("Q", "K", "V")))
@@ -72,12 +76,18 @@ def _gated_delta_attention(ctx, ins, attrs):
     D = width // H
     tiles = kda.plan(B, S, H, D, int(attrs.get("chunk") or kda.CHUNK))
     size = jnp.dtype(q.dtype).itemsize
-    with span("kda.lower", heads=H, head_dim=D, sq=int(S), engine="xla",
+    taken = kda.engine(B, S, H, D, tiles["chunk"], q.dtype)
+    chosen = {} if taken is None else dict(
+        rows=taken.rows, fwd_vmem_bytes=taken.fwd_vmem,
+        bwd_vmem_bytes=taken.bwd_vmem)
+    with span("kda.lower", heads=H, head_dim=D, sq=int(S),
+              engine="xla" if taken is None else "pallas",
               state_bytes=kda.state_bytes(B, H, D), kept=",".join(kda.KEPT),
               kept_bytes=kda.kept_bytes(
                   B, S, H, D, tiles["chunks"] // tiles["group"], size),
               flops=kda.flops(B, S, H, D, tiles["chunk"]),
-              moved_bytes=kda.moved_bytes(B, S, H, D, size), **tiles), \
+              moved_bytes=kda.moved_bytes(B, S, H, D, size), **tiles,
+              **chosen), \
             jax.named_scope("kda.scan"):
         ctx.kept += len(kda.KEPT)
         out = kda.gated_delta_attention(

@@ -267,7 +267,28 @@ def _cca_mix(backward):
         argnums=tuple(range(8))), args
 
 
+def _kda_scan(backward):
+    """kimi-linear-48b-a3b's chunk scan at the cell's shape (32 heads of
+    128, S 4096, chunks of 64, bf16 operands): kernels/gated_delta.py's
+    forward kernel at the planned rows, and with it the backward."""
+    from paddle_tpu.kernels import gated_delta
+
+    S, H, D = 4096, 32, 128
+    args = (_sds((1, S, H * D), jnp.bfloat16),) * 3 + (
+        _sds((1, S, H * D), jnp.float32), _sds((1, S, H), jnp.float32))
+
+    def fwd(*a):
+        return gated_delta.gated_delta_attention(*a, heads=H, force="pallas")
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
+                    argnums=tuple(range(5))), args
+
+
 _MAIN_PATH_KERNELS = {
+    "kda_scan_fwd_kimi": lambda: _kda_scan(False),
+    "kda_scan_bwd_pallas_kimi": lambda: _kda_scan(True),
     "cca_mix_fwd_zaya": lambda: _cca_mix(False),
     "cca_mix_bwd_pallas_zaya": lambda: _cca_mix(True),
     "sparse_attention_bwd_pallas_keye": _sparse_attention,
