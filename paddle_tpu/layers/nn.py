@@ -29,6 +29,8 @@ __all__ = [
     "short_conv1d",
     "gated_delta_attention",
     "compressed_conv_qkv",
+    "kda_conv_decay",
+    "kda_gated_norm",
     "sparse_attention",
     "moe_router",
     "moe_experts",
@@ -1363,6 +1365,54 @@ def gated_delta_attention(q, k, v, g, beta, heads, chunk=64, name=None):
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]},
         attrs={"heads": int(heads), "chunk": int(chunk)},
+    )
+    return out
+
+
+def kda_conv_decay(q, k, v, f, conv_q_w, conv_k_w, conv_v_w, dt_bias, a_log,
+                   heads, name=None):
+    """What Kimi Delta Attention does before its recurrence, as one op: q,
+    k, v [B, S, H D] (the projections) each through short_conv1d's causal
+    depthwise convolution (conv_*_w [taps, H D]) and SiLU, and the
+    log-decay g = -exp(a_log [H]) softplus(f + dt_bias [H D]) of f [B, S,
+    H D], fp32, one for every key channel.  Returns (q', k', v, g) for
+    gated_delta_attention (TPU-native; ops/linear_attention_ops.py
+    kda_conv_decay: for a TPU a Pallas kernel pair over tiles of rows x
+    blocks of channels where H D is whole 128-lane vectors and S whole
+    tiles, kernels/kda_mix.py, the same arithmetic in jax.numpy
+    elsewhere)."""
+    helper = LayerHelper("kda_conv_decay", input=q, name=name)
+    outs = [helper.create_variable_for_type_inference(q.dtype)
+            for _ in range(3)]
+    outs.append(helper.create_variable_for_type_inference("float32"))
+    helper.append_op(
+        type="kda_conv_decay",
+        inputs={"Q": [q], "K": [k], "V": [v], "F": [f],
+                "ConvQW": [conv_q_w], "ConvKW": [conv_k_w],
+                "ConvVW": [conv_v_w], "DtBias": [dt_bias], "ALog": [a_log]},
+        outputs={"QOut": [outs[0]], "KOut": [outs[1]], "VOut": [outs[2]],
+                 "G": [outs[3]]},
+        attrs={"heads": int(heads)},
+    )
+    return tuple(outs)
+
+
+def kda_gated_norm(x, gate, gate_bias, scale, heads, epsilon=1e-6,
+                   name=None):
+    """What Kimi Delta Attention does after its recurrence, as one op: x
+    [B, S, H D] normalised a head (rms_norm's formula over D, one learned
+    scale [D]) times sigmoid(gate [B, S, H D] + gate_bias [H D]); [B, S,
+    H D] out (TPU-native; ops/linear_attention_ops.py kda_gated_norm: for
+    a TPU a Pallas kernel pair where D is whole 128-lane vectors and S
+    whole tiles, kernels/kda_mix.py, jax.numpy elsewhere)."""
+    helper = LayerHelper("kda_gated_norm", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="kda_gated_norm",
+        inputs={"X": [x], "Gate": [gate], "GateBias": [gate_bias],
+                "Scale": [scale]},
+        outputs={"Out": [out]},
+        attrs={"heads": int(heads), "epsilon": float(epsilon)},
     )
     return out
 

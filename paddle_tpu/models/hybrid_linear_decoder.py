@@ -13,8 +13,9 @@ program reads the entries up to its depth), pre-norm:
 Mix_l:                KDA for l in kda_layers, MLA for l in full_attn_layers
 KDA(u), H heads of D: q~ = W_q u, k~ = W_k u, v~ = W_v u [S, H D];
                       q' = silu(conv_q(q~)), k', v likewise (layers.
-                      short_conv1d: causal, depthwise, `short_conv_kernel_
-                      size` taps, zeros before the first position);
+                      short_conv1d's convolution: causal, depthwise,
+                      `short_conv_kernel_size` taps, zeros before the first
+                      position);
                       g = -exp(A_log_h) softplus(W_f2 W_f1 u + dt_bias),
                       fp32, one for every key channel; beta = sigmoid(W_b
                       u) [S, H];  o = layers.gated_delta_attention(q', k',
@@ -33,13 +34,17 @@ F_l:                  the gated MLP in the first `first_k_dense` layers,
 Output:               logits = W_head N_f(h_L); mean cross entropy
 
 Everything but the KDA mixer is expert_decoder.py's builder and model
-function.  Name scopes: `kda.mix` (the three convolutions, the decay, beta,
-and the output's norm and gate: what streams [S, H D] values through the
-vector unit), `kda.scan` (the op gated_delta_attention, forward and
-backward; the scope is the op's own), `mla`, `moe.shared` and
-ops/moe_ops.py's `moe.router`, `moe.dispatch`, `moe.experts`.  The four
-[d, H D] projections lie outside both `kda.*` scopes, as every other
-attention's do.
+function.  Name scopes: `kda.mix` (what streams [S, H D] values through the
+vector unit, as two ops: layers.kda_conv_decay before the scan, the three
+convolutions with SiLU and the decay g, and layers.kda_gated_norm after it,
+the norm a head times the gate; each a Pallas kernel pair over tiles of
+rows where the program is for a TPU and the shape tiles,
+kernels/kda_mix.py, and jax.numpy elsewhere; the span `kda.mix.lower` says
+which; beside them, in the same scope, the rank-D maps W_f, W_g and beta),
+`kda.scan` (the op gated_delta_attention, forward and backward; the scope
+is the op's own), `mla`, `moe.shared` and ops/moe_ops.py's `moe.router`,
+`moe.dispatch`, `moe.experts`.  The four [d, H D] projections lie outside
+both `kda.*` scopes, as every other attention's do.
 """
 
 from __future__ import annotations
@@ -51,7 +56,6 @@ from typing import Optional, Tuple
 from .. import layers
 from ..core.framework import name_scope
 from ..initializer import UniformInitializer
-from ..param_attr import ParamAttr
 from .common import ModelSpec
 from .expert_decoder import _ExpertBuilder, _decoder
 
@@ -118,33 +122,21 @@ class _HybridBuilder(_ExpertBuilder):
         taps = cfg.short_conv_kernel_size
         projected = [self.linear(u, d, H * D, f"{name}_{p}") for p in "qkv"]
         with name_scope("kda.mix"):
-            q, k, v = (layers.short_conv1d(
-                t, self.conv_param([taps, H * D], f"{name}_conv_{p}_w",
-                                   taps), activation="silu")
-                for t, p in zip(projected, "qkv"))
-            # fp32 from here to the op, whatever amp made of the matmuls
-            f = layers.elementwise_add(
-                layers.cast(self.low_rank(u, D, H * D, f"{name}_f"),
-                            "float32"),
-                self.uniform([H * D], f"{name}_dt_bias", *_DT_RANGE))
-            rate = layers.scale(layers.exp(
-                self.uniform([H], f"{name}_a_log", *_A_RANGE)), scale=-1.0)
-            g = layers.reshape(layers.elementwise_mul(
-                layers.reshape(layers.softplus(f), shape=[0, 0, H, D]),
-                rate, axis=2), shape=[0, 0, H * D])
+            filters = [self.conv_param([taps, H * D], f"{name}_conv_{p}_w",
+                                       taps) for p in "qkv"]
+            q, k, v, g = layers.kda_conv_decay(
+                *projected, self.low_rank(u, D, H * D, f"{name}_f"), *filters,
+                self.uniform([H * D], f"{name}_dt_bias", *_DT_RANGE),
+                self.uniform([H], f"{name}_a_log", *_A_RANGE), heads=H)
             beta = layers.sigmoid(layers.cast(
                 self.linear(u, d, H, f"{name}_beta"), "float32"))
         o = layers.gated_delta_attention(q, k, v, g, beta, heads=H)
         with name_scope("kda.mix"):
-            gate = layers.sigmoid(layers.elementwise_add(
-                self.low_rank(u, D, H * D, f"{name}_gate"),
-                self.constant([H * D], f"{name}_gate_bias", 0.0)))
-            o = layers.rms_norm(
-                layers.reshape(o, shape=[0, 0, H, D]), begin_norm_axis=-1,
-                epsilon=cfg.rms_norm_eps,
-                param_attr=ParamAttr(name=f"{name}_on_scale"))
-            o = layers.elementwise_mul(
-                layers.reshape(o, shape=[0, 0, H * D]), gate)
+            o = layers.kda_gated_norm(
+                o, self.low_rank(u, D, H * D, f"{name}_gate"),
+                self.constant([H * D], f"{name}_gate_bias", 0.0),
+                self.constant([D], f"{name}_on_scale", 1.0), heads=H,
+                epsilon=cfg.rms_norm_eps)
         return self.linear(o, H * D, d, f"{name}_o")
 
     def mixer(self, h, i):

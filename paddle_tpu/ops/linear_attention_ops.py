@@ -2,7 +2,9 @@
 token to token where attention carries the keys.  TPU-native additions (the
 2018 reference has no such op): the gated delta rule with a decay for every
 key channel (Kimi Delta Attention) and the short causal convolution that
-precedes it.
+precedes it; and, as one op each, what streams [S, H D] values through the
+vector unit before that recurrence (the three convolutions and the decay:
+kda_conv_decay) and after it (the gated norm a head: kda_gated_norm).
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ import jax.numpy as jnp
 
 from ..core import amp
 from ..core.registry import register_op
+from ..core.proto import DataType
 from ..observability import span
 from .attention_ops import causal_conv1d
 from .common import ACTS, data, in_desc, same_shape, set_output
@@ -93,4 +96,114 @@ def _gated_delta_attention(ctx, ins, attrs):
         out = kda.gated_delta_attention(
             q, k.astype(q.dtype), v.astype(q.dtype), g, beta, heads=H,
             chunk=tiles["chunk"])
+    return {"Out": [out]}
+
+
+def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads):
+    """The op kda_conv_decay's arithmetic (its docstring), in jax.numpy:
+    (q', k', v in their inputs' dtypes, g fp32)."""
+    def conv(x, w):
+        y = causal_conv1d(x[:, None], w[:, None])[:, 0]
+        return jax.nn.silu(y).astype(x.dtype)
+
+    B, S, C = f.shape
+    z = jax.nn.softplus(f.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+    rate = -jnp.exp(a_log.astype(jnp.float32))
+    g = (z.reshape(B, S, heads, C // heads) * rate[:, None]).reshape(B, S, C)
+    return conv(q, wq), conv(k, wk), conv(v, wv), g
+
+
+def gated_norm(o, gate, gate_bias, scale, heads, eps):
+    """The op kda_gated_norm's arithmetic (its docstring), in jax.numpy,
+    in o's dtype."""
+    B, S, C = o.shape
+    acc = amp.stats_dtype(o)
+    x = o.astype(acc).reshape(B, S, heads, C // heads)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                          + eps) * scale.astype(acc)
+    s = jax.nn.sigmoid(gate.astype(acc) + gate_bias.astype(acc))
+    return (y.reshape(B, S, C) * s).astype(o.dtype)
+
+
+def _lowered(ctx, what, moved_bytes, run):
+    """One site of either op under the span `kda.mix.lower`: `run(force)`
+    gives (outputs, the kernels' tiles or None).  XLA cannot partition a
+    Mosaic kernel (_shard_over_mesh), so on a mesh of several devices the
+    jax.numpy form, which it can."""
+    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
+    with span("kda.mix.lower", what=what, moved_bytes=moved_bytes) as sp:
+        outs, tiles = run("jax" if several else "auto")
+        taken = tiles or (0,) * 5
+        sp.set(engine="xla" if tiles is None else "pallas", rows=taken[0],
+               channels=taken[1], halo=taken[2], fwd_vmem_bytes=taken[3],
+               bwd_vmem_bytes=taken[4])
+    return outs
+
+
+def _conv_decay_infer(op, block):
+    q = in_desc(op, block, "Q")
+    if q is None:
+        return
+    for slot in ("QOut", "KOut", "VOut"):
+        set_output(block, op, slot, list(q.shape), q.dtype)
+    set_output(block, op, "G", list(q.shape), DataType.FP32)
+
+
+@register_op("kda_conv_decay", infer_shape=_conv_decay_infer,
+             diff_inputs=["Q", "K", "V", "F", "ConvQW", "ConvKW", "ConvVW",
+                          "DtBias", "ALog"])
+def _kda_conv_decay(ctx, ins, attrs):
+    """What Kimi Delta Attention does to its projections before the
+    recurrence, `heads` H heads of D: Q, K, V [B, S, H D] are q~, k~, v~
+    and F [B, S, H D] the decay's low-rank map of the layer's input.
+    QOut = silu(conv(Q, ConvQW)), KOut and VOut likewise (short_conv1d's
+    convolution: causal, depthwise, ConvQW [k, H D] one filter of k taps a
+    channel, zeros before the first position), in Q's dtype; G = -exp(ALog
+    [H]) softplus(F + DtBias [H D]), fp32 whatever F comes in, one decay
+    for every key channel.  All arithmetic in fp32.
+
+    One algorithm, its engine read from the site: for ONE TPU, where the
+    shape tiles (kernels/kda_mix.py::conv_tiles: H D whole 128-lane
+    vectors, S whole tiles of rows, one dtype), a Pallas kernel pair over
+    tiles of rows x blocks of channels whose backward keeps the op's
+    inputs and nothing else; anywhere else `conv_decay`, the same
+    arithmetic in jax.numpy.  `kda.mix.lower` (a span, at lowering; `what`
+    conv_decay) says what a site was given: `engine` (pallas | xla),
+    `rows`, `channels` and `halo` of a grid step, the `fwd_vmem_bytes` and
+    `bwd_vmem_bytes` of its working sets (0 under xla) and `moved_bytes`,
+    what the site's passes have to move through HBM (the forward, the
+    forward again where the unit around the site is rematerialised, the
+    backward)."""
+    from ..kernels import kda_mix
+
+    args = [data(ins[s][0]) for s in (
+        "Q", "K", "V", "F", "ConvQW", "ConvKW", "ConvVW", "DtBias", "ALog")]
+    outs = _lowered(
+        ctx, "conv_decay", kda_mix.conv_moved_bytes(
+            args[0], args[3], bool(attrs.get("@recompute@"))),
+        lambda force: kda_mix.conv_decay(*args, int(attrs["heads"]),
+                                         force=force))
+    return dict(zip(("QOut", "KOut", "VOut", "G"), ([o] for o in outs)))
+
+
+@register_op("kda_gated_norm", infer_shape=same_shape("X", "Out"),
+             diff_inputs=["X", "Gate", "GateBias", "Scale"])
+def _kda_gated_norm(ctx, ins, attrs):
+    """What Kimi Delta Attention does to the recurrence's output X [B, S,
+    H D], `heads` H heads of D: Out = X / sqrt(mean_D(X^2) + epsilon) *
+    Scale [D] * sigmoid(Gate + GateBias), rms_norm's formula a head with
+    one learned scale, Gate [B, S, H D] and GateBias [H D].  Statistics
+    and the gate in fp32, Out in X's dtype.  The engine as kda_conv_decay
+    reads it (kernels/kda_mix.py::norm_tiles: D whole 128-lane vectors): a
+    head's statistic stays in the tile, the backward reads X, Gate and the
+    cotangent alone; `kda.mix.lower` with `what` gated_norm."""
+    from ..kernels import kda_mix
+
+    args = [data(ins[s][0]) for s in ("X", "Gate", "GateBias", "Scale")]
+    out = _lowered(
+        ctx, "gated_norm", kda_mix.norm_moved_bytes(
+            args[0], args[1], bool(attrs.get("@recompute@"))),
+        lambda force: kda_mix.gated_norm(
+            *args, int(attrs["heads"]), float(attrs.get("epsilon", 1e-6)),
+            force=force))
     return {"Out": [out]}

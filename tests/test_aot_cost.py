@@ -286,7 +286,39 @@ def _kda_scan(backward):
                     argnums=tuple(range(5))), args
 
 
+def _kda_mix(backward):
+    """kimi-linear-48b-a3b's passes around the chunk scan at the cell's
+    shape (32 heads of 128, S 4096, four taps, bf16 streams):
+    kernels/kda_mix.py's two forward kernels at the planned tiles, and with
+    them the two backward (the outputs weighted by themselves, so that the
+    forwards stay in the program)."""
+    from paddle_tpu.kernels import kda_mix
+
+    S, H, D, taps = 4096, 32, 128, 4
+    wide = _sds((1, S, H * D), jnp.bfloat16)
+    args = (wide,) * 6 + (_sds((taps, H * D), jnp.float32),) * 3 + (
+        _sds((H * D,), jnp.float32), _sds((H,), jnp.float32),
+        _sds((H * D,), jnp.float32), _sds((D,), jnp.float32))
+
+    def fwd(q, k, v, f, o, gate, wq, wk, wv, dt_bias, a_log, gate_bias,
+            scale):
+        outs, before = kda_mix.conv_decay(q, k, v, f, wq, wk, wv, dt_bias,
+                                          a_log, H, force="pallas")
+        out, after = kda_mix.gated_norm(o, gate, gate_bias, scale, H, 1e-5,
+                                        force="pallas")
+        assert before is not None and after is not None
+        return outs + (out,)
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+        argnums=tuple(range(13))), args
+
+
 _MAIN_PATH_KERNELS = {
+    "kda_mix_fwd_kimi": lambda: _kda_mix(False),
+    "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
     "kda_scan_fwd_kimi": lambda: _kda_scan(False),
     "kda_scan_bwd_pallas_kimi": lambda: _kda_scan(True),
     "cca_mix_fwd_zaya": lambda: _cca_mix(False),

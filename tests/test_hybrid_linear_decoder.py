@@ -5,7 +5,8 @@ reference, benchmark/configs/kimi-linear-48b-a3b.reference.py, at tiny sizes
 on the CPU; the layer kinds read from the two 1-indexed lists; latent
 attention without rotary and with it as it was; every control of
 tools/kimi_reference_probe.py refused; the 32 shares against the uncut
-expert block."""
+expert block; the ops kda_conv_decay and kda_gated_norm against the
+composition of layers they replaced, and what their spans say."""
 
 import os
 import sys
@@ -25,11 +26,17 @@ from benchmark.harness import manifest
 from benchmark.harness import reference as harness_reference
 from paddle_tpu import layers, models, observability
 from paddle_tpu.core import amp
+from paddle_tpu.core.framework import name_scope
+from paddle_tpu.kernels import kda_mix
 from paddle_tpu.observability import span
+from paddle_tpu.param_attr import ParamAttr
 from paddle_tpu.ops import attention_ops, moe_ops
 
 sys.path.insert(0, os.path.join(REPO, "tools"))
 import kimi_reference_probe as probe  # noqa: E402
+
+# the module (models exports the function of the same name)
+hybrid = sys.modules["paddle_tpu.models.hybrid_linear_decoder"]
 
 # the rehearsal's sizes: the dense layer and one whole period, two chunks
 TINY = dict(vocab_size=64, max_length=128, n_layer=5, d_model=32, d_inner=64,
@@ -420,6 +427,123 @@ def test_the_32_shares_add_up_to_the_uncut_expert_block():
 
 
 # ---------------------------------------------------------------------------
+# the two ops around the scan
+# ---------------------------------------------------------------------------
+def _delta_attention_as_it_was(self, u, name):
+    """models/hybrid_linear_decoder.py::delta_attention before the ops
+    kda_conv_decay and kda_gated_norm: three short_conv1d, the decay from
+    elementwise layers, rms_norm a head and the gate."""
+    cfg = self.cfg
+    H, D, d = cfg.kda_heads, cfg.kda_head_dim, cfg.d_model
+    taps = cfg.short_conv_kernel_size
+    projected = [self.linear(u, d, H * D, f"{name}_{p}") for p in "qkv"]
+    with name_scope("kda.mix"):
+        q, k, v = (layers.short_conv1d(
+            t, self.conv_param([taps, H * D], f"{name}_conv_{p}_w", taps),
+            activation="silu") for t, p in zip(projected, "qkv"))
+        f = layers.elementwise_add(
+            layers.cast(self.low_rank(u, D, H * D, f"{name}_f"), "float32"),
+            self.uniform([H * D], f"{name}_dt_bias", *hybrid._DT_RANGE))
+        rate = layers.scale(layers.exp(
+            self.uniform([H], f"{name}_a_log", *hybrid._A_RANGE)), scale=-1.0)
+        g = layers.reshape(layers.elementwise_mul(
+            layers.reshape(layers.softplus(f), shape=[0, 0, H, D]),
+            rate, axis=2), shape=[0, 0, H * D])
+        beta = layers.sigmoid(layers.cast(
+            self.linear(u, d, H, f"{name}_beta"), "float32"))
+    o = layers.gated_delta_attention(q, k, v, g, beta, heads=H)
+    with name_scope("kda.mix"):
+        gate = layers.sigmoid(layers.elementwise_add(
+            self.low_rank(u, D, H * D, f"{name}_gate"),
+            self.constant([H * D], f"{name}_gate_bias", 0.0)))
+        o = layers.rms_norm(
+            layers.reshape(o, shape=[0, 0, H, D]), begin_norm_axis=-1,
+            epsilon=cfg.rms_norm_eps,
+            param_attr=ParamAttr(name=f"{name}_on_scale"))
+        o = layers.elementwise_mul(
+            layers.reshape(o, shape=[0, 0, H * D]), gate)
+    return self.linear(o, H * D, d, f"{name}_o")
+
+
+def test_the_two_ops_are_the_composition_they_replaced(one_step, monkeypatch):
+    """The same parameters (names, shapes, the values they start at), the
+    same loss and every gradient to fp32 rounding."""
+    _, params, _, grads, loss = one_step
+    monkeypatch.setattr(hybrid._HybridBuilder, "delta_attention",
+                        _delta_attention_as_it_was)
+    _, was_params, _, was_grads, was_loss = _build(
+        **SHORT, expert_offset=0, experts_held=16)
+    kinds = [op.type for b in fluid.default_main_program().blocks
+             for op in b.desc.ops]
+    assert "short_conv1d" in kinds and "kda_conv_decay" not in kinds
+    assert set(params) == set(was_params) and set(grads) == set(was_grads)
+    for name in params:
+        np.testing.assert_array_equal(params[name], was_params[name], name)
+    assert loss == pytest.approx(was_loss, rel=1e-6)
+    for name in sorted(grads):
+        scale = max(np.abs(was_grads[name]).max(), 1e-3)
+        np.testing.assert_allclose(grads[name], was_grads[name], rtol=1e-4,
+                                   atol=2e-6 * scale, err_msg=name)
+
+
+def test_kda_mix_lower_says_pallas_at_the_cells_shape():
+    """The two ops lowered (abstractly: nothing compiles) at [1, 4096, 32 x
+    128] for the TPU: `engine` pallas, the tile, the halo and the working
+    sets `conv_tiles` / `norm_tiles` give the shape, and the bytes the
+    passes move; the same program on the CPU says xla."""
+    S, H, D, taps = 4096, 32, 128, 4
+    C = H * D
+    shapes = dict(q=[1, S, C], k=[1, S, C], v=[1, S, C], f=[1, S, C],
+                  wq=[taps, C], wk=[taps, C], wv=[taps, C], dt_bias=[C],
+                  a_log=[H], gate=[1, S, C], gate_bias=[C], scale=[D])
+    fluid.reset_default_env()
+    ins = {n: layers.data(n, s, append_batch_size=False, dtype="float32")
+           for n, s in shapes.items()}
+    q, k, v, g = layers.kda_conv_decay(*(ins[n] for n in (
+        "q", "k", "v", "f", "wq", "wk", "wv", "dt_bias", "a_log")), heads=H)
+    out = layers.kda_gated_norm(q, ins["gate"], ins["gate_bias"],
+                                ins["scale"], heads=H, epsilon=1e-5)
+    assert list(g.shape) == [1, S, C] and g.dtype.name == "FP32"
+    assert list(out.shape) == [1, S, C]
+    feed = {n: np.zeros(s, np.float32) for n, s in shapes.items()}
+
+    def lowered(for_the_tpu):
+        observability.reset()
+        with fluid.flags.tpu_trace_scope(for_the_tpu):
+            compiled, *rest = fluid.Executor(
+                fluid.CPUPlace()).capture_program(
+                    feed=feed, fetch_list=[k, v, g, out])
+            jax.eval_shape(compiled.raw_fn, *rest)
+        return [dict(s.args) for s in observability.default_tracer().spans()
+                if s.name == "kda.mix.lower"]
+
+    fluid.flags._VALUES["FLAGS_observability"] = True
+    try:
+        on_tpu, on_cpu = lowered(True), lowered(False)
+    finally:
+        fluid.flags._VALUES["FLAGS_observability"] = False
+        observability.reset()
+        fluid.reset_default_env()
+    MB = 2 ** 20
+    moved = {"conv_decay": (6 * 64 + 64 + 64 + 9 * 64 + 2 * 64 + 64) * MB,
+             "gated_norm": (3 * 64 + 5 * 64) * MB}            # fp32 streams
+    tiles = {"conv_decay": kda_mix.conv_tiles(S, C, taps, jnp.float32),
+             "gated_norm": kda_mix.norm_tiles(S, C, D, jnp.float32)}
+    assert [s["what"] for s in on_tpu] == ["conv_decay", "gated_norm"]
+    for site in on_tpu:
+        t = tiles[site["what"]]
+        assert site == dict(
+            what=site["what"], moved_bytes=moved[site["what"]],
+            engine="pallas", rows=t.rows, channels=t.channels, halo=t.halo,
+            fwd_vmem_bytes=t.fwd_vmem, bwd_vmem_bytes=t.bwd_vmem)
+        assert t.bwd_vmem <= kda_mix._PLAN_VMEM_BUDGET
+    assert on_cpu == [dict(
+        what=site["what"], moved_bytes=site["moved_bytes"], engine="xla",
+        rows=0, channels=0, halo=0, fwd_vmem_bytes=0, bwd_vmem_bytes=0)
+        for site in on_tpu]
+
+
+# ---------------------------------------------------------------------------
 # the spans
 # ---------------------------------------------------------------------------
 def test_the_spans_say_what_each_site_was_given():
@@ -427,7 +551,8 @@ def test_the_spans_say_what_each_site_was_given():
     layer, one moe.lower and router.lower an expert layer (three layers:
     KDA + dense, MLA + experts, KDA + experts), at the router's published
     counts."""
-    names = ("kda.lower", "mla.lower", "moe.lower", "router.lower")
+    names = ("kda.lower", "mla.lower", "moe.lower", "router.lower",
+             "kda.mix.lower")
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
         fluid.reset_default_env()
@@ -453,6 +578,10 @@ def test_the_spans_say_what_each_site_was_given():
                 site["chunks"], site["engine"], site["kept"]) == (
             2, 16, 128, 64, 2, "xla", "out,states")
         assert site["state_bytes"] == 4 * 2 * 2 * 16 * 16
+    # heads of 16 are no 128-lane vectors, and this is the CPU
+    assert [(s["what"], s["engine"], s["rows"])
+            for s in spans["kda.mix.lower"]] == 2 * [
+        ("conv_decay", "xla", 0), ("gated_norm", "xla", 0)]
     assert [s["rope"] for s in spans["mla.lower"]] == ["none"]
     assert len(spans["moe.lower"]) == len(spans["router.lower"]) == 2
     for site in spans["moe.lower"]:

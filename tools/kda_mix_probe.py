@@ -1,0 +1,234 @@
+"""`kda.mix` alone, timed and checked on the chip at `kimi-train-kda8k`'s
+shape.
+
+  kimi   q~, k~, v~, f, o, gate [B, 4096, 32 x 128] bf16, four taps: one
+         KDA layer of the cell; B 4 sequences a call where the cell has 1,
+         so that a call is milliseconds of device time and not the host's
+         dispatch; the times are A SEQUENCE (a layer of the cell)
+
+The ops kda_conv_decay's and kda_gated_norm's arithmetic in their two
+engines: `xla` (ops/linear_attention_ops.py::conv_decay / ::gated_norm,
+jax.numpy) and `pallas` (kernels/kda_mix.py, the two kernel pairs) at the
+tiles `conv_tiles` / `norm_tiles` give the shape; `--sweep` also pins every
+tile of --rows x --channels.  For each pair: the forward and the backward
+ALONE (the pullback of jax.vjp, jitted over its residuals: the backward
+kernel, or the jax.numpy backward's passes), ms a sequence, the share of
+the HBM rate that the pass's part of `conv_moved_bytes` /
+`norm_moved_bytes` is of it, and how far the outputs and the gradients lie
+from the jax.numpy engine's (the largest difference over the largest
+value).
+
+`--check` runs both pairs at [2, 1024, 4 x 128] against the jax.numpy
+engine ON FP32 COPIES of the inputs, at fp32 and at bf16 streams: the
+kernel pairs and, at the same streams, the jax.numpy engine.  What the CPU
+interpreter cannot show is there: Mosaic's exp, log1p and rsqrt.  Rows go
+to chiprun_out/kda_mix_probe.json.
+
+A tool, run by no benchmark cell:
+    chiprun --chips 1 -- python3 tools/kda_mix_probe.py --seed 7 \
+        [--sweep] [--check]
+    JAX_PLATFORMS=cpu python3 tools/kda_mix_probe.py --rehearse --check
+`--rehearse` runs a tiny shape through the Pallas interpreter in fp32 and
+exits 3: its times are not the chip's.  One process holds the chip; it
+starts no child.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from flash_fwd_probe import _time_ms  # noqa: E402
+
+# name: (B, S, H, D, taps)
+SHAPES = {"kimi": (4, 4096, 32, 128, 4)}
+REHEARSAL_SHAPES = {"kimi": (2, 256, 2, 128, 4)}
+CHECK_SHAPE, REHEARSAL_CHECK_SHAPE = (2, 1024, 4, 128, 4), (1, 256, 2, 128, 4)
+EPS = 1e-5
+HBM_GB_S = 819.0  # one v5e (Google Cloud documentation, "TPU v5e")
+NAMES = {"conv_decay": ("q", "k", "v", "g", "dq~", "dk~", "dv~", "df", "dwq",
+                        "dwk", "dwv", "ddt_bias", "da_log"),
+         "gated_norm": ("out", "do", "dgate", "dgate_bias", "dscale")}
+
+
+def inputs(shape, seed, dtype):
+    """{pair: (arguments, cotangents)}: the streams in `dtype`, the
+    parameters fp32 and off the values they start at."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    B, S, H, D, taps = shape
+    C, rng = H * D, np.random.RandomState(seed % (2 ** 32))
+
+    def normal(*s, scale=1.0, dtype=jnp.float32):
+        return jnp.asarray(rng.randn(*s) * scale, dtype)
+
+    def wide(n):
+        return tuple(normal(B, S, C, dtype=dtype) for _ in range(n))
+
+    return {
+        "conv_decay": (
+            wide(4) + tuple(normal(taps, C, scale=0.5) for _ in range(3))
+            + (normal(C) - 2.0, 1.0 + 0.5 * normal(H)),
+            wide(3) + (normal(B, S, C),)),
+        "gated_norm": (wide(2) + (0.3 * normal(C), 1.0 + 0.3 * normal(D)),
+                       wide(1))}
+
+
+def engines(shape, force, rows=None, channels=None):
+    """{pair: (function of its arguments, the tiles it ran under)}"""
+    from paddle_tpu.kernels import kda_mix
+
+    H, taken = shape[2], {}
+
+    def conv_decay(*xs):
+        outs, taken["conv_decay"] = kda_mix.conv_decay(
+            *xs, H, force=force, rows=rows, channels=channels)
+        return outs
+
+    def gated_norm(*xs):
+        out, taken["gated_norm"] = kda_mix.gated_norm(
+            *xs, H, EPS, force=force, rows=rows, channels=channels)
+        return (out,)
+
+    return {"conv_decay": conv_decay, "gated_norm": gated_norm}, taken
+
+
+def _both_passes(fn, args, cots):
+    """[outputs, gradients] as fp32 numpy, and (forward, pullback over its
+    residuals) with their arguments for the clock."""
+    import jax
+    import numpy as np
+
+    fwd = jax.jit(fn)
+    outs, pull = jax.vjp(fwd, *args)
+    cots = tuple(c.astype(o.dtype) for c, o in zip(cots, outs))
+    back = jax.jit(lambda p, d: p(d))
+    grads = back(pull, cots)
+    return ([np.asarray(t, np.float32) for t in tuple(outs) + tuple(grads)],
+            (fwd, args), (back, (pull, cots)))
+
+
+def _rel(pair, got, want):
+    import numpy as np
+
+    return {n: float(np.max(np.abs(g - w)) / max(np.max(np.abs(w)), 1e-30))
+            for n, g, w in zip(NAMES[pair], got, want)}
+
+
+def _moved(pair, args):
+    """(forward, backward) bytes of one call."""
+    from paddle_tpu.kernels import kda_mix
+
+    if pair == "conv_decay":
+        count, streams = kda_mix.conv_moved_bytes, (args[0], args[3])
+    else:
+        count, streams = kda_mix.norm_moved_bytes, args[:2]
+    forward = count(*streams, True) - count(*streams, False)
+    return forward, count(*streams, False) - forward
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--rows", default="128,256,512")
+    ap.add_argument("--channels", default="128,256,512,1024,2048")
+    ap.add_argument("--check", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    a = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    dev = jax.devices()[0]
+    if not a.rehearse and dev.platform != "tpu":
+        print("kda_mix_probe: no TPU here (use --rehearse on the CPU)",
+              file=sys.stderr)
+        return 2
+    kernel = "interpret" if a.rehearse else "pallas"
+    half = jnp.float32 if a.rehearse else jnp.bfloat16
+    rows = []
+
+    for name, shape in (REHEARSAL_SHAPES if a.rehearse else SHAPES).items():
+        B, S = shape[:2]
+        both = inputs(shape, a.seed, half)
+        variants = [("xla", "jax", None, None),
+                    ("pallas-plan", kernel, None, None)]
+        if a.sweep:
+            variants += [(f"pallas-{r}x{c}", kernel, r, c)
+                         for r in map(int, a.rows.split(","))
+                         for c in map(int, a.channels.split(","))
+                         if S % r == 0]
+        want = {}
+        for label, force, r, c in variants:
+            fns, taken = engines(shape, force, r, c)
+            for pair, (args, cots) in both.items():
+                row = {"shape": name, "pair": pair, "variant": label,
+                       "seed": a.seed}
+                try:
+                    got, fwd, back = _both_passes(fns[pair], args, cots)
+                    tiles = taken[pair]
+                    if (tiles is None) != (force == "jax"):
+                        raise ValueError("the shape does not tile so, or "
+                                         "the working set does not fit")
+                    if tiles is not None:
+                        row.update(tiles._asdict())
+                    want.setdefault(pair, got)
+                    row["rel_err"] = _rel(pair, got, want[pair])
+                    if not a.rehearse:  # an interpreter's time is no one's
+                        f_ms = _time_ms(*fwd, a.calls) / B
+                        b_ms = _time_ms(*back, a.calls) / B
+                        moved = [m / B for m in _moved(pair, args)]
+                        row.update(
+                            fwd_ms=round(f_ms, 4), bwd_ms=round(b_ms, 4),
+                            fwd_hbm_share=round(
+                                moved[0] / f_ms / 1e6 / HBM_GB_S, 4),
+                            bwd_hbm_share=round(
+                                moved[1] / b_ms / 1e6 / HBM_GB_S, 4))
+                except Exception as e:  # a tile Mosaic refuses is a row
+                    row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+
+    if a.check:
+        shape = REHEARSAL_CHECK_SHAPE if a.rehearse else CHECK_SHAPE
+        dtypes = (jnp.float32,) if a.rehearse else (jnp.float32, jnp.bfloat16)
+        for dtype in dtypes:
+            both = inputs(shape, a.seed, dtype)
+            for pair, (args, cots) in both.items():
+                exact = tuple(t.astype(jnp.float32) for t in args)
+                want, _, _ = _both_passes(
+                    engines(shape, "jax")[0][pair], exact,
+                    tuple(c.astype(dtype) for c in cots))
+                row = {"check": pair, "streams": jnp.dtype(dtype).name,
+                       "shape": list(shape), "seed": a.seed}
+                for label, force in (("xla", "jax"), ("pallas", kernel)):
+                    got, _, _ = _both_passes(engines(shape, force)[0][pair],
+                                             args, cots)
+                    row[label] = _rel(pair, got, want)
+                row["pallas_no_further"] = all(
+                    row["pallas"][n] <= max(2 * row["xla"][n], 3e-6)
+                    for n in NAMES[pair])
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+           "rehearsal": bool(a.rehearse), "date": time.strftime(
+               "%Y-%m-%d %H:%M UTC", time.gmtime()), "rows": rows}
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kda_mix_probe.json", "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps({k: out[k] for k in ("device", "rehearsal", "date")}))
+    return 3 if a.rehearse else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
