@@ -31,6 +31,10 @@ __all__ = [
     "compressed_conv_qkv",
     "kda_conv_decay",
     "kda_gated_norm",
+    "mhc_streams",
+    "mhc_maps",
+    "mhc_read",
+    "mhc_write",
     "sparse_attention",
     "moe_router",
     "moe_experts",
@@ -1251,6 +1255,16 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
     return out
 
 
+def _yarn_attrs(yarn):
+    """The `yarn_<key>` attrs of rotary_embedding's and latent_attention's
+    `yarn` dict; none where it is None."""
+    from ..ops.attention_ops import YARN_KEYS
+
+    if not yarn:
+        return {}
+    return {"yarn_" + key: float(yarn[key]) for key in YARN_KEYS}
+
+
 def rotary_embedding(x, base=10000.0, offset=0, positions=None,
                      sections=None, name=None, yarn=None, rotary_dim=None):
     """Rotary position embedding of x [..., S, D] (heads first, then
@@ -1270,10 +1284,7 @@ def rotary_embedding(x, base=10000.0, offset=0, positions=None,
     attrs = {"base": float(base), "offset": int(offset)}
     if rotary_dim:
         attrs["rotary_dim"] = int(rotary_dim)
-    if yarn:
-        from ..ops.attention_ops import YARN_KEYS
-
-        attrs.update({"yarn_" + key: float(yarn[key]) for key in YARN_KEYS})
+    attrs.update(_yarn_attrs(yarn))
     if positions is not None:
         inputs["Positions"] = [positions]
         attrs["sections"] = [int(n) for n in sections]
@@ -1308,7 +1319,7 @@ def sparse_attention(q, k, v, index_q, index_k, index_w, topk, q_chunk=512,
 
 def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
                      qk_rope_head_dim, v_head_dim, rope_base=10000.0,
-                     name=None, rope="rotary"):
+                     name=None, rope="rotary", yarn=None, scale=None):
     """Causal multi-head latent attention (MLA) from its projections: q
     [B, S, H * (nope + rope)], the normalised latent [B, S, rank], the one
     rotary key part a token k_rope [B, S, rope], and the parameter
@@ -1316,7 +1327,11 @@ def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
     [B, S, H * v].  `rope` "none" leaves both rope-wide parts unturned
     (no positions: the scores are q.k over all nope + rope features as
     projected, under the causal mask alone); "rotary" turns them at
-    `rope_base` (TPU-native; ops/attention_ops.py latent_attention)."""
+    `rope_base`, under `yarn` (rotary_embedding's dict: factor,
+    original_length, beta_fast, beta_slow, attention_factor) at YaRN's
+    frequencies with cos and sin times attention_factor.  `scale`: the
+    softmax scale, (nope + rope)^-1/2 where none is given (TPU-native;
+    ops/attention_ops.py latent_attention)."""
     helper = LayerHelper("latent_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {"n_head": int(n_head),
@@ -1325,6 +1340,9 @@ def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
              "v_head_dim": int(v_head_dim), "rope_base": float(rope_base)}
     if rope != "rotary":
         attrs["rope"] = str(rope)
+    attrs.update(_yarn_attrs(yarn))
+    if scale is not None:
+        attrs["scale"] = float(scale)
     helper.append_op(
         type="latent_attention",
         inputs={"Q": [q], "Latent": [latent], "KRope": [k_rope],
@@ -1414,6 +1432,69 @@ def kda_gated_norm(x, gate, gate_bias, scale, heads, epsilon=1e-6,
         outputs={"Out": [out]},
         attrs={"heads": int(heads), "epsilon": float(epsilon)},
     )
+    return out
+
+
+def mhc_streams(x, streams, name=None):
+    """x [B, S, C] copied to each of `streams` residual streams: [B, S, n,
+    C], what a hyper-connected model's layers carry; half-width from here
+    on under AMP's keep tier (TPU-native; ops/hyper_connection_ops.py
+    mhc_streams)."""
+    helper = LayerHelper("mhc_streams", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_streams", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"streams": int(streams)})
+    return out
+
+
+def mhc_maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
+             sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6, clamp_min=-30.0,
+             clamp_max=30.0, name=None):
+    """The three maps of one manifold-constrained hyper-connection from
+    the streams x [B, S, n, C]: with m = RMS-normalised vec(x) times phi
+    [nC, 2n + n^2], H_pre = sigmoid(a_pre m + b_pre) [n], H_post = 2
+    sigmoid(a_post m + b_post) [n] and H_res = exp(clip(a_res m + b_res))
+    [n, n] after `sinkhorn_iters` Sinkhorn-Knopp iterations (columns, then
+    rows, `hc_eps` beside each sum): doubly stochastic.  Returns H [B, 2n +
+    n^2, S] fp32, the tokens on the minor axis: rows 0:n H_pre, n:2n
+    H_post, then H_res row by row, for mhc_read and mhc_write (TPU-native;
+    ops/hyper_connection_ops.py mhc_maps)."""
+    helper = LayerHelper("mhc_maps", input=x, name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="mhc_maps",
+        inputs={"X": [x], "Phi": [phi], "APre": [a_pre], "APost": [a_post],
+                "ARes": [a_res], "BPre": [b_pre], "BPost": [b_post],
+                "BRes": [b_res]},
+        outputs={"H": [out]},
+        attrs={"sinkhorn_iters": int(sinkhorn_iters),
+               "epsilon": float(epsilon), "hc_eps": float(hc_eps),
+               "clamp_min": float(clamp_min),
+               "clamp_max": float(clamp_max)},
+    )
+    return out
+
+
+def mhc_read(x, h, name=None):
+    """What a sublayer reads of the streams x [B, S, n, C] under mhc_maps'
+    h: sum_j H_pre[j] x[j], [B, S, C] (TPU-native;
+    ops/hyper_connection_ops.py mhc_read)."""
+    helper = LayerHelper("mhc_read", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_read", inputs={"X": [x], "H": [h]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def mhc_write(x, h, y, name=None):
+    """The streams after a sublayer wrote y [B, S, C] back under mhc_maps'
+    h: x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y, [B, S, n, C]
+    (TPU-native; ops/hyper_connection_ops.py mhc_write)."""
+    helper = LayerHelper("mhc_write", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_write", inputs={"X": [x], "H": [h], "Y": [y]},
+                     outputs={"Out": [out]})
     return out
 
 

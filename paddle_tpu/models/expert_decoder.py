@@ -92,11 +92,22 @@ class _ExpertBuilder(_Builder):
         return self.param(shape, name,
                           initializer=ConstantInitializer(value), **attr)
 
+    def query(self, x, name):
+        """The heads' queries [B, S, H * (nope + rope)] of x: one map."""
+        cfg = self.cfg
+        return self.linear(
+            x, cfg.d_model, cfg.n_head
+            * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), f"{name}_q")
+
+    def rope_scaling(self):
+        """layers.latent_attention's `yarn` and `scale`: none here."""
+        return {}
+
     def latent_attention(self, x, name):
         cfg = self.cfg
         H, dn, dr, dv = (cfg.n_head, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
-        q = self.linear(x, cfg.d_model, H * (dn + dr), f"{name}_q")
+        q = self.query(x, name)
         latent, k_rope = layers.split(
             self.linear(x, cfg.d_model, cfg.kv_lora_rank + dr,
                         f"{name}_kva"),
@@ -106,7 +117,7 @@ class _ExpertBuilder(_Builder):
             self.param([cfg.kv_lora_rank, H * (dn + dv)], f"{name}_kvb_w"),
             n_head=H, qk_nope_head_dim=dn, qk_rope_head_dim=dr,
             v_head_dim=dv, rope_base=cfg.rope_theta,
-            rope=cfg.mla_rope)
+            rope=cfg.mla_rope, **self.rope_scaling())
         return self.linear(ctx, H * dv, cfg.d_model, f"{name}_o")
 
     def expert_block(self, x, name):
@@ -141,16 +152,28 @@ class _ExpertBuilder(_Builder):
             return self.latent_attention(self.norm(h, f"l{i}_n1"),
                                          f"l{i}_attn")
 
-    def layer(self, h, i):
-        """(h', load or None, the router's bias or None) of layer i."""
+    def feed_forward(self, a, i):
+        """(F_i(N2(a)), load or None, the router's bias or None): what
+        layer i adds to the stream second."""
         name = f"l{i}"
-        a = layers.elementwise_add(h, self.mixer(h, i))
         x = self.norm(a, f"{name}_n2")
         if i < self.cfg.first_k_dense:
-            out, load, bias = self.mlp(x, f"{name}_mlp"), None, None
-        else:
-            out, load, bias = self.expert_block(x, name)
+            return self.mlp(x, f"{name}_mlp"), None, None
+        return self.expert_block(x, name)
+
+    def layer(self, h, i):
+        """(h', load or None, the router's bias or None) of layer i."""
+        a = layers.elementwise_add(h, self.mixer(h, i))
+        out, load, bias = self.feed_forward(a, i)
         return layers.elementwise_add(a, out), load, bias
+
+    def enter(self, h):
+        """What the layers carry, of the embedded tokens: the one stream."""
+        return h
+
+    def leave(self, h):
+        """What the final norm reads, of what the layers carried."""
+        return h
 
 
 def expert_decoder(cfg: Optional[ExpertDecoderConfig] = None, tokens=None,
@@ -171,9 +194,9 @@ def _decoder(b: _ExpertBuilder, name: str, tokens=None,
     if labels is None:
         labels = layers.data("labels", [S], dtype="int64")
 
-    h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="embed",
-                                              initializer=b.init))
+    h = b.enter(layers.embedding(
+        tokens, size=[cfg.vocab_size, cfg.d_model],
+        param_attr=ParamAttr(name="embed", initializer=b.init)))
     loads = []
     for i in range(cfg.n_layer):
         routed = []
@@ -190,7 +213,8 @@ def _decoder(b: _ExpertBuilder, name: str, tokens=None,
             # load, outside the gradient
             loads.append(rec())
             layers.moe_bias_update(bias, loads[-1], cfg.bias_update_gamma)
-    states = layers.unsqueeze(b.norm(h, "final"), axes=[0])   # one "trip"
+    states = layers.unsqueeze(b.norm(b.leave(h), "final"),
+                              axes=[0])                       # one "trip"
     loss, logits, _ = _heads_and_loss(b, states, labels)
 
     def synthetic_batch(batch_size: int, seed: int = 0) -> Dict[str, np.ndarray]:

@@ -15,6 +15,7 @@ from . import (  # noqa: F401
     detection_ops,
     elementwise_ops,
     framework_ops,
+    hyper_connection_ops,
     linear_attention_ops,
     loss_ops,
     math_ops,

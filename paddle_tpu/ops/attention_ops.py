@@ -205,13 +205,19 @@ def _rotate(x, base: float, offset: int = 0, positions=None, sections=(),
     return out.astype(x.dtype)
 
 
+def _yarn_attrs(attrs):
+    """The dict `_rotate` takes from an op's `yarn_<key>` attrs, None where
+    the op has none."""
+    if float(attrs.get("yarn_factor", 0.0)) <= 0.0:
+        return None
+    return {key: float(attrs["yarn_" + key]) for key in YARN_KEYS}
+
+
 @register_op("rotary_embedding", infer_shape=same_shape("X", "Out"),
              diff_inputs=["X"])
 def _rotary_embedding(ctx, ins, attrs):
     pos_in = ins.get("Positions", [None])[0]
-    yarn = None
-    if float(attrs.get("yarn_factor", 0.0)) > 0.0:
-        yarn = {key: float(attrs["yarn_" + key]) for key in YARN_KEYS}
+    yarn = _yarn_attrs(attrs)
     return {"Out": [_rotate(
         data(ins["X"][0]), attrs.get("base", 10000.0),
         attrs.get("offset", 0),
@@ -243,7 +249,11 @@ def _latent_attention(ctx, ins, attrs):
     "rotary") neither dr-wide part is turned: they enter the scores as
     they are, dr more features of a query and of the shared key, and the
     op knows no position but the causal order (a model that takes its
-    positions from other layers).
+    positions from other layers).  With the attrs `yarn_<key>` (YARN_KEYS,
+    rotary_embedding's) both parts turn at YaRN's frequencies, cos and sin
+    times `yarn_attention_factor`; `scale` replaces the softmax scale
+    (DeepSeek-V3's YaRN leaves cos and sin alone and multiplies the scale
+    by (0.1 mscale_all_dim ln factor + 1)^2).
 
     The flash kernels take q and k at dn + dr and v at dv as they are: the
     kernels carry a value width of their own (kernels/flash_attention.py),
@@ -266,13 +276,14 @@ def _latent_attention(ctx, ins, attrs):
     if rope not in ("rotary", "none"):
         raise ValueError(f"latent_attention: rope {rope!r} is neither "
                          "'rotary' nor 'none'")
+    yarn = _yarn_attrs(attrs)
     B, S = q.shape[0], q.shape[1]
 
     def heads(t):                                    # [B, H, S, width]
         return jnp.swapaxes(t.reshape(B, S, H, -1), 1, 2)
 
     def turned(t):
-        return _rotate(t, base) if rope == "rotary" else t
+        return _rotate(t, base, yarn=yarn) if rope == "rotary" else t
 
     with span("mla.lower", heads=H, qk_dim=dn + dr, v_dim=dv,
               kv_rank=int(latent.shape[-1]), padded_v=0, rope=rope) as sp:
@@ -285,7 +296,7 @@ def _latent_attention(ctx, ins, attrs):
         k = jnp.concatenate([kv[..., :dn], shared], -1)
         q, k = amp.match_kept(q, k)
         out = _attend(ctx, sp, q, k, kv[..., dn:].astype(k.dtype), None,
-                      True, (dn + dr) ** -0.5)
+                      True, float(attrs.get("scale") or (dn + dr) ** -0.5))
     return {"Out": [jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)]}
 
 

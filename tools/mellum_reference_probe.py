@@ -29,10 +29,10 @@ chiprun_out/mellum_reference_probe.json and prints them.  The mutants:
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
-import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -106,16 +106,53 @@ def move_off_starts(scope, rng, put):
             scope.set_var(p.name, put((v * 5).astype(np.float32)))
 
 
+def held_to(first, loss_and_grad, loss, batch, rows):
+    """(findings, problems, {parameter: (g.r, g.g, r.r)}) of the step that
+    `first` (the harness's FirstStep) saw against `loss_and_grad` on the
+    parameters the step started from: FirstStep.compare's numbers, by the
+    harness's own `judge` and `problems`, for a reference that may be a
+    mutant, with the products a name that the judge adds up kept beside
+    them (a by-name `listing` reads those)."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.harness import reference
+
+    micro = max(1, rows // int(first.tol.get("rows_per_part", rows)))
+    ref_loss, ref_grad = jax.jit(functools.partial(
+        loss_and_grad, cfg=first.cell.config,
+        feed_names=tuple(first.spec.feed_names), trainable=first.trainable,
+        micro=micro))(first.params, batch)
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    prods = jax.jit(lambda a, b: {
+        k: (jnp.vdot(f32(a[k]), f32(b[k])), jnp.vdot(f32(a[k]), f32(a[k])),
+            jnp.vdot(f32(b[k]), f32(b[k]))) for k in b})(
+        first.program_gradient(), ref_grad)
+    prods = {k: tuple(float(x) for x in v) for k, v in prods.items()}
+    found = reference.judge(float(loss), float(ref_loss), prods)
+    return found, reference.problems(found, first.tol), prods
+
+
 def main(cell_name=CELL, mutants=MUTANTS, make=mutant, move=move_off_starts,
-         doc=__doc__, out_name="mellum_reference_probe") -> int:
+         doc=__doc__, out_name="mellum_reference_probe", controls=None,
+         fetch=(), listing=None) -> int:
     """This file's probe; with arguments, another cell's
     (tools/zaya_reference_probe.py): its mutants' names, `make(name)` the
     reference's `loss_and_grad` with that one thing wrong, `move(scope,
-    rng, put)` its parameters off their starts."""
+    rng, put)` its parameters off their starts.  `controls` {name: a
+    context manager}: controls on THE PROGRAM's side, each the cell's
+    program built and run once more inside its context and held to the
+    reference as it is; a tolerance has to refuse it like a mutant.
+    `listing(name, prods, first, batch, fetched)` -> {key: value} goes
+    into each reading beside the harness's numbers: `prods` held_to's
+    products a name, `fetched` the step's values of `spec.extras[k]` for k
+    in `fetch`."""
     ap = argparse.ArgumentParser(description=doc.splitlines()[0])
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--only", default=None,
-                    help="comma-separated mutants; default all")
+                    help="comma-separated mutants and controls; default all")
     ap.add_argument("--as-the-cell-starts", action="store_true",
                     help="leave every parameter at the cell's own start")
     ap.add_argument("--rehearse", action="store_true")
@@ -133,45 +170,66 @@ def main(cell_name=CELL, mutants=MUTANTS, make=mutant, move=move_off_starts,
         return 2
     cfg, mod = cell.config, cell.config_module
     rows = int(cell.sizing["per_chip_batch"])
-    spec = mod.build(cfg, args.seed)
-    tpu = devices[0].platform == "tpu"
-    exe = fluid.Executor(fluid.TPUPlace() if tpu else fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    if not args.as_the_cell_starts:
-        move(fluid.global_scope(), np.random.default_rng(args.seed),
-             lambda v: jax.device_put(v, devices[0]))
-    batch = jax.device_put(mod.make_batch(cfg, spec, rows, args.seed),
-                           devices[0])
-    first = reference.FirstStep(cell, spec)
-    params = first.params
+    controls = dict(controls or {})
+    names = tuple(mutants) + tuple(controls)
+    if args.only:
+        names = tuple(args.only.split(","))
     out = {"seed": args.seed, "device": device.describe(devices),
            "as_the_cell_starts": args.as_the_cell_starts,
-           "tolerances": {k: v for k, v in first.tol.items()
+           "tolerances": {k: v for k, v in cfg["reference"].items()
                           if isinstance(v, (int, float))},
            "readings": {}}
-    loss = float(np.ravel(np.asarray(
-        exe.run(feed=batch, fetch_list=[spec.loss])[0]))[0])
-    names = (None,) + tuple(mutants)
-    if args.only:
-        names = (None,) + tuple(args.only.split(","))
-    for name in names:
-        first.params = params
-        first.module = types.SimpleNamespace(loss_and_grad=make(name))
-        found, problems = first.compare(loss, batch, rows)
-        out["readings"][name or "reference"] = {
-            **found, "refused_by": [p.split(":")[0][:60] for p in problems]}
-        print(f"[probe] {name or 'reference'}: {found}\n"
+
+    def first_step():
+        """The cell's program built anew and its first step run:
+        (FirstStep, batch, loss, the fetched extras)."""
+        spec = mod.build(cfg, args.seed)
+        tpu = devices[0].platform == "tpu"
+        exe = fluid.Executor(fluid.TPUPlace() if tpu else fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        if not args.as_the_cell_starts:
+            move(fluid.global_scope(), np.random.default_rng(args.seed),
+                 lambda v: jax.device_put(v, devices[0]))
+        batch = jax.device_put(mod.make_batch(cfg, spec, rows, args.seed),
+                               devices[0])
+        first = reference.FirstStep(cell, spec)
+        loss, *more = exe.run(feed=batch, fetch_list=[spec.loss] + [
+            spec.extras[k] for k in fetch])
+        return (first, batch, float(np.ravel(np.asarray(loss))[0]),
+                dict(zip(fetch, more)))
+
+    def read(name, step, wrong=None):
+        """One reading: `step` held to the reference with `wrong` wrong."""
+        first, batch, loss, fetched = step
+        found, problems, prods = held_to(first, make(wrong), loss, batch,
+                                         rows)
+        more = listing(wrong, prods, first, batch, fetched) \
+            if listing else {}
+        out["readings"][name] = {
+            **found, "refused_by": [p.split(":")[0][:60] for p in problems],
+            **more}
+        print(f"[probe] {name}: {found}\n"
               f"[probe]   refused by {len(problems)}: {problems}", flush=True)
+
+    step = first_step()
+    read("reference", step)
+    for name in names:
+        if name not in controls:
+            read(name, step, wrong=name)
+    # the chip has no room for two programs' states: the first goes first
+    del step
+    for name in names:
+        if name in controls:
+            with controls[name]:
+                read(name, first_step())
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     tail = "_as_the_cell_starts" if args.as_the_cell_starts else ""
     with open(os.path.join(ROOT, "chiprun_out",
                            f"{out_name}{tail}.json"), "w") as f:
         json.dump(out, f, indent=1)
-    wrong = [m for m in mutants if m in out["readings"]]
-    ok = not out["readings"]["reference"]["refused_by"] and all(
-        out["readings"][m]["refused_by"] for m in wrong)
-    print(json.dumps({"ok": ok, "passed_though_wrong": [
-        m for m in wrong if not out["readings"][m]["refused_by"]]}))
+    passed = [n for n in names if not out["readings"][n]["refused_by"]]
+    ok = not out["readings"]["reference"]["refused_by"] and not passed
+    print(json.dumps({"ok": ok, "passed_though_wrong": passed}))
     return 0 if ok or args.rehearse else 1
 
 
