@@ -1,0 +1,874 @@
+"""Manifold-constrained hyper-connections (the ops mhc_maps, mhc_read and
+mhc_write, name scopes `mhc.maps` and `mhc.mix`) as three Pallas TPU kernel
+pairs over tiles of rows.
+
+ops/hyper_connection_ops.py::maps, ::read and ::write are the arithmetic,
+in jax.numpy.  There every stage is a pass over fp32 [T, n C] values in
+HBM, in the forward, the recomputed forward and the backward, and the
+backward of the 2 x `iters` unrolled Sinkhorn normalisations is hundreds of
+small fusions: at [1, 4096, 4, 3584] the two scopes took 105.8 ms a step
+for 8.6 ms of traffic (PERF.md, PR 50).
+
+Here the kernels read the streams stream-major, [B, n, S, C]: a stream's
+[S, C] plane under the last two axes, so that a block of it is whole
+(sublane, lane) tiles.  The ops hand [B, S, n, C] over through a
+transposition that is one in name (`_by_stream`): the streams live between
+these ops alone and the compiler lays the value out stream-major, where as
+rows of n C every op paid a copy of the streams each way (a reshape of [S,
+n, C] to [S, n C] is no bitcast under the TPU's tiled layouts; PERF.md, PR
+51).  A grid step holds a tile of rows x a block of channels of a stream:
+- `maps`: the grid walks the streams' blocks of a tile of rows last (a
+  token's n C values are its n rows of C, stream after stream, as Phi's
+  rows are).  Each block
+  adds its squares (fp32, on the VPU) and its products with Phi (on the
+  MXU: Phi's three bf16 parts side by side are 72 columns of ONE pass, a
+  bf16 stream is exact in the other operand, products and sums are fp32,
+  and the three groups of columns add up to the fp32 product with no
+  rounding of Phi dropped; fp32 streams go as three parts too) to two
+  [rows, 128] accumulators in VMEM.  After the last block the tile's
+  [128, rows] transpose puts the tokens on the lanes for the activations,
+  the clamp and the Sinkhorn iterations on [n^2, rows] values (sums over a
+  map's rows and columns are sublane rolls; exact divides), and H [B, 2n +
+  n^2, S] leaves as the jax.numpy form gives it.
+- the backward of `maps` is two kernels.  The first makes the tile's
+  forward again, keeps the 2 x `iters` + 1 iterates [n^2, rows] in VMEM,
+  walks them back and leaves the cotangent of u Phi (as its three bf16
+  parts) and of the sum of squares a token, [B, 128, S] fp32, and the six
+  small parameters' gradients in blocks resident over the grid.  The
+  second streams the tile again, channel blocks outermost: dX = dm Phi^T +
+  2 u dss on the MXU and the VPU, and dPhi^T [2n + n^2, n C] accumulates in
+  fp32 in blocks resident over the rows.
+- `read` and `write`: given H they are independent per channel.  H comes
+  transposed ([B, S, 2n + n^2], 0.4 MB, by XLA), a map value a token is a
+  lane-broadcast column, sums are fp32 with one rounding.  A grid step
+  writes ONE stream's block (the innermost grid axis walks the streams
+  while the blocks they share stay in VMEM), so channels block freely
+  under one output array.  The backward (jax.custom_vjp; the residuals are
+  the ops' INPUTS) reads X, the cotangents and y, writes dX, dy and dH's
+  columns, which accumulate over the channel blocks in a resident block.
+
+`maps_tiles` and `mix_tiles` read the tile from the shape and the VMEM it
+needs (the budget and the search are kernels/kda_mix.py's), or say that the
+shape does not tile (the op then runs the jax.numpy form).
+force="interpret" is the CPU tests' door, as in kernels/flash_attention.py.
+tools/mhc_probe.py times the pairs alone on the chip.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from .cca_mix import _halo_rows, _roll, _sum
+from .kda_mix import (_F32, _LANES, _columns, _compiler_params, _kernels,
+                      _one_dtype, _sigmoid, _widest)
+
+__all__ = ["Tiles", "maps_tiles", "mix_tiles", "maps", "read", "write"]
+
+_BF16 = jnp.bfloat16
+# Both planners take the widest block of a stream's channels first, then
+# the most rows that fit beside it (kernels/kda_mix.py::_CHANNELS's lesson:
+# a wider block is a longer contiguous run a row for the DMA); the maps may
+# take more rows than kda_mix's 256, since a tile of rows streams Phi's
+# parts (3.67 MB at the cell's shape) once and its tail works on [n^2,
+# rows] values.  On the chip at the cell's shape (tools/mhc_probe.py
+# --sweep, ms a sublayer forward / backward, PERF.md PR 51) the maps read
+# 0.36 / 0.84 at 128 rows x 896 channels, 0.28 / 0.67 x 1792, 0.23 / 0.62
+# x 3584; 0.27 / 0.66, 0.23 / 0.61, 0.20 / 0.59 at 256 rows; 0.22 / 0.61
+# and 0.20 / 0.59 at 512 x 896 and x 1792; 0.22 / 0.60 at 1024 x 512;
+# `read` 0.23 / 0.52, 0.23 / 0.45, 0.22 / 0.42 at 128 rows and 0.23 / 0.46,
+# 0.23 / 0.42 at 256 x 896 and x 1792; `write` 0.65 / 0.95 and 0.54 / 0.81
+# at 128 x 896 and x 1792, 0.59 / 0.85 at 256 x 896.
+_MAPS_ROWS = (1024, 512, 256, 128)
+# rows of the sum of squares a pass of the inner loop keeps in registers
+_SQUARE_ROWS = 64
+
+
+class Tiles(NamedTuple):
+    """What a site's kernel pair is built from, all read from the shape."""
+    rows: int
+    channels: int
+    fwd_vmem: int
+    bwd_vmem: int
+
+
+class Maps(NamedTuple):
+    """What `mhc_maps` computes under, beside its operands."""
+    streams: int
+    epsilon: float
+    hc_eps: float
+    iters: int
+    clamp_min: float
+    clamp_max: float
+
+
+def _values(n):
+    """2n + n^2: the map values a token."""
+    return 2 * n + n * n
+
+
+def _parts(dtype):
+    """bf16 values that add up to a value of `dtype`."""
+    return 1 if jnp.dtype(dtype) == jnp.dtype(_BF16) else 3
+
+
+def _blocks(width):
+    """Blocks of whole 128-lane vectors that divide `width`, widest
+    first."""
+    units = width // _LANES
+    return [d * _LANES for d in range(units, 0, -1) if units % d == 0]
+
+
+def maps_working_set(rows, block, n, iters, size, backward) -> int:
+    """What a grid step of the maps' kernels holds in VMEM: the declared
+    blocks twice (the pipeline's two buffers), the scratch and the fp32
+    temporaries of the tail."""
+    N, parts = _values(n), 3 if size == 4 else 1
+    tile, wide = rows * block * size, rows * _LANES * 4
+    phi = block * _LANES * 2
+    forward = 2 * (tile + phi + N * rows * 4) + 2 * wide + 14 * N * rows * 4
+    if not backward:
+        return forward
+    first = (forward + 2 * (N * rows * 4 + wide)
+             + (2 * iters + 1) * n * n * rows * 4)
+    second = (2 * (2 * tile + 2 * wide + (parts + 1) // 2 * rows * _LANES * 2
+                   + phi + N * block * 4) + 8 * wide)
+    return max(first, second)
+
+
+def mix_working_set(rows, block, n, size, what, backward) -> int:
+    """The same for `read`'s and `write`'s kernels."""
+    tile, wide = rows * block * size, rows * _LANES * 4
+    blocks = {("read", False): n + 1, ("read", True): 3,
+              ("write", False): n + 2, ("write", True): n + 4}[what, backward]
+    return 2 * (blocks * tile + (2 if backward else 1) * wide) + 16 * wide
+
+
+def maps_tiles(seq, n, width, iters, dtype, rows=None, channels=None
+               ) -> Optional[Tiles]:
+    """The tiles of a `mhc_maps` site, None where the shape does not tile:
+    C whole 128-lane vectors, the map's rows and the five groups of 2n +
+    n^2 columns whole sublane tiles of one 128-lane vector (n = 4), S whole
+    tiles of rows with the tokens on the lanes, the working set inside the
+    budget."""
+    N, size = _values(n), jnp.dtype(dtype).itemsize
+    if width % _LANES or n % 4 or 5 * N > _LANES:
+        return None
+
+    def need(r, c, backward=True):
+        return maps_working_set(r, c, n, iters, size, backward)
+
+    for c in (_blocks(width) if channels is None else (channels,)):
+        for r in (_MAPS_ROWS if rows is None else (rows,)):
+            if r % _LANES == 0 and _widest(seq, width, _LANES, need, r, c):
+                return Tiles(r, c, need(r, c, False), need(r, c))
+    return None
+
+
+def mix_tiles(seq, n, width, dtype, what, rows=None, channels=None
+              ) -> Optional[Tiles]:
+    """The tiles of a `mhc_read` / `mhc_write` site (`what`), None where
+    the shape does not tile: C whole 128-lane vectors, S whole tiles of
+    rows, the working set inside the budget."""
+    size = jnp.dtype(dtype).itemsize
+    if width % _LANES:
+        return None
+
+    def need(r, c, backward=True):
+        return mix_working_set(r, c, n, size, what, backward)
+
+    def larger(r, c):   # `read`'s forward holds more than its backward
+        return max(need(r, c, False), need(r, c))
+
+    for c in (_blocks(width) if channels is None else (channels,)):
+        found = _widest(seq, width, _LANES, larger, rows, c)
+        if found is not None and found[0] % _halo_rows(dtype) == 0:
+            return Tiles(*found, need(*found, False), need(*found))
+    return None
+
+
+# ---------------------------------------------------------------------------
+# what the kernels compute
+# ---------------------------------------------------------------------------
+def _top(v):
+    """fp32 v with all but the 8 leading bits of its significand cleared:
+    a bf16 value, exactly, still in fp32.  A mask and not a pair of casts:
+    under jit XLA folds f32 -> bf16 -> f32 away (xla_allow_excess_precision)
+    and the parts after the first came out 0 (PERF.md, PR 51)."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000), _F32)
+
+
+def _split(v, parts):
+    """`parts` bf16 values that add up to v: all of a bf16 v in one, all 24
+    bits of an fp32 v in three."""
+    if parts == 1:
+        return [v.astype(_BF16)]
+    out, rest = [], v.astype(_F32)
+    for _ in range(parts):
+        top = _top(rest)
+        out.append(top.astype(_BF16))
+        rest = rest - top
+    return out
+
+
+def _phi_columns(phi, groups):
+    """[n C, 128] bf16: the parts of Phi [n C, N] named by `groups` side by
+    side, zeros after them."""
+    p = _split(phi, 3)
+    cols = jnp.concatenate([p[g] for g in groups], axis=1)
+    return jnp.pad(cols, ((0, 0), (0, _LANES - cols.shape[1])))
+
+
+# the second kernel of the backward multiplies the parts a1, a2, a3 of dm
+# by the parts p1, p2, p3 of Phi: a row of groups is one pass of the MXU
+# over 5 x N <= 128 contracted columns.  The first row's products are all
+# down to 2^-16 of the whole but a3 p1, far under a bf16 dX's rounding; an
+# fp32 dX takes the second row too (all but a3 p3, 2^-32).
+_PHI_GROUPS = (0, 1, 2, 0, 1)
+_DM_GROUPS = ((0, 0, 0, 1, 1), (2, 2, 1))
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=_F32)
+
+
+def _lane(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _over_maps_rows(m, n):
+    """sum_i M[i, j] at every row i n + j of m [n^2, rows]."""
+    return _sum([m] + [_roll(m, d * n, 0) for d in range(1, n)])
+
+
+def _over_maps_columns(m, n, j):
+    """sum_j M[i, j] at every row i n + j of m; j [n^2, rows] is a row's
+    place in its group of n."""
+    return _sum([m] + [jnp.where(j < n - d, _roll(m, -d, 0),
+                                 _roll(m, n - d, 0)) for d in range(1, n)])
+
+
+def _place(n, rows):
+    r = jax.lax.broadcasted_iota(jnp.int32, (n * n, rows), 0)
+    return jax.lax.rem(r, n)
+
+
+def _accumulate(x_ref, phis_ref, acc_ref, ssq_ref, parts):
+    """A block's share of u Phi's parts (acc [rows, 128]) and of the
+    squares, a lane a column of the block (ssq [rows, 128])."""
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _nothing_yet():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        ssq_ref[...] = jnp.zeros_like(ssq_ref)
+
+    acc_ref[...] += _sum(_dot(p, phis_ref[...])
+                         for p in _split(x_ref[...], parts))
+    rows, step = x_ref.shape[0], min(_SQUARE_ROWS, x_ref.shape[0])
+
+    def squares(i, carry):
+        at = pl.ds(pl.multiple_of(i * step, step), step)
+        s = ssq_ref[at, :]
+        for cols in _columns(x_ref.shape[1], _LANES):
+            v = x_ref[at, cols].astype(_F32)
+            s = s + v * v
+        ssq_ref[at, :] = s
+        return carry
+
+    jax.lax.fori_loop(0, rows // step, squares, 0)
+
+
+def _tail(acc_ref, ssq_ref, a_ref, b_ref, geo: Maps, width, keep=None):
+    """The maps of a tile from its accumulators, the tokens on the lanes:
+    (H_pre and H_post [2n, rows], H_res [n^2, rows], what the backward
+    needs besides).  `keep(i, M)` is handed the iterate before
+    normalisation i and the last."""
+    n, N = geo.streams, _values(geo.streams)
+    rows = acc_ref.shape[0]
+    m = jnp.where(_lane(acc_ref.shape) == 3 * N,
+                  jnp.sum(ssq_ref[...], axis=-1, keepdims=True),
+                  acc_ref[...]).T
+    raw = m[0:N] + m[N:2 * N] + m[2 * N:3 * N]
+    rms = jax.lax.rsqrt(m[3 * N:3 * N + 1] / width + geo.epsilon)
+    mm = raw * rms
+    z = a_ref[...] * mm + b_ref[...]
+    twice = jnp.where(jax.lax.broadcasted_iota(
+        jnp.int32, (2 * n, rows), 0) < n, 1.0, 2.0)
+    gates = twice * _sigmoid(z[0:2 * n])
+    res = jnp.exp(jnp.clip(z[2 * n:], geo.clamp_min, geo.clamp_max))
+    j = _place(n, rows)
+
+    def normalise(i, res):
+        """Columns, then rows; a loop and not 20 copies of its body: the
+        step's executable holds every kernel of every sublayer."""
+        if keep is not None:
+            keep(2 * i, res)
+        res = res / (_over_maps_rows(res, n) + geo.hc_eps)
+        if keep is not None:
+            keep(2 * i + 1, res)
+        return res / (_over_maps_columns(res, n, j) + geo.hc_eps)
+
+    res = jax.lax.fori_loop(0, geo.iters, normalise, res)
+    if keep is not None:
+        keep(2 * geo.iters, res)
+    return gates, res, (raw, rms, mm, z, twice, j)
+
+
+def _maps_kernel(x_ref, phis_ref, a_ref, b_ref, h_ref, acc_ref, ssq_ref, *,
+                 geo, parts, width):
+    import jax.experimental.pallas as pl
+
+    _accumulate(x_ref, phis_ref, acc_ref, ssq_ref, parts)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _the_maps():
+        n = geo.streams
+        gates, res, _ = _tail(acc_ref, ssq_ref, a_ref, b_ref, geo, width)
+        h_ref[0:2 * n] = gates
+        h_ref[2 * n:] = res
+
+
+def _fold(v):
+    """[N, rows] -> [N, 128]: the lane tiles added up."""
+    return _sum(v[:, c] for c in _columns(v.shape[1], _LANES))
+
+
+def _maps_bwd_tail_kernel(x_ref, phis_ref, a_ref, b_ref, dh_ref,
+                          g_ref, da_ref, db_ref, acc_ref, ssq_ref, its_ref,
+                          *, geo, parts, width):
+    """The first kernel of the backward: the tile's forward again, then
+    back through the iterations, the clamp and the activations."""
+    import jax.experimental.pallas as pl
+
+    first = ((pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+             & (pl.program_id(2) == 0))
+
+    @pl.when(first)
+    def _no_gradient_yet():
+        da_ref[...] = jnp.zeros_like(da_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    _accumulate(x_ref, phis_ref, acc_ref, ssq_ref, parts)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _back_through_the_maps():
+        n, N = geo.streams, _values(geo.streams)
+
+        def keep(i, m):
+            its_ref[i] = m
+
+        gates, _, (raw, rms, mm, z, twice, j) = _tail(
+            acc_ref, ssq_ref, a_ref, b_ref, geo, width, keep)
+
+        def back(t, g):
+            """Through normalisation 2 i + 1 (the rows), then 2 i."""
+            i = geo.iters - 1 - t
+            for step, over in (
+                    (2 * i + 1, functools.partial(_over_maps_columns, n=n,
+                                                  j=j)),
+                    (2 * i, functools.partial(_over_maps_rows, n=n))):
+                before, after = its_ref[step], its_ref[step + 1]
+                g = (g - over(g * after)) / (over(before) + geo.hc_eps)
+            return g
+
+        g = jax.lax.fori_loop(0, geo.iters, back, dh_ref[2 * n:])
+        z_res = z[2 * n:]
+        inside = (z_res >= geo.clamp_min) & (z_res <= geo.clamp_max)
+        dz_res = jnp.where(inside, g * its_ref[0], 0.0)
+        # d(t s)/dz = t s (1 - s) = gate (1 - gate / t)
+        dz_gates = dh_ref[0:2 * n] * gates * (1.0 - gates / twice)
+        dz = jnp.concatenate([dz_gates, dz_res], axis=0)
+        da_ref[...] += _fold(dz * mm)
+        db_ref[...] += _fold(dz)
+        dmm = dz * a_ref[...]
+        drms = jnp.sum(dmm * raw, axis=0, keepdims=True)
+        dss2 = drms * (-1.0 / width) * (rms * rms * rms)   # 2 x d(ss)
+        rest = dmm * rms
+        for p in range(3):
+            piece = _top(rest)
+            g_ref[p * N:(p + 1) * N] = piece
+            rest = rest - piece
+        g_ref[3 * N:3 * N + 8] = jnp.broadcast_to(
+            dss2, (8,) + dss2.shape[1:])
+        g_ref[3 * N + 8:] = jnp.zeros(
+            (g_ref.shape[0] - 3 * N - 8, g_ref.shape[1]), _F32)
+
+
+def _maps_bwd_stream_kernel(x_ref, g_ref, dss_ref, phit_ref, *refs, parts):
+    """The second: dX = dm Phi^T + 2 u dss and dPhi^T += dm^T u, a column
+    of 128 lanes at a time."""
+    import jax.experimental.pallas as pl
+
+    lhs_refs, (dx_ref, dphit_ref) = refs[:-2], refs[-2:]
+    N = dphit_ref.shape[0]
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _no_gradient_yet():
+        dphit_ref[...] = jnp.zeros_like(dphit_ref)
+
+    # the three parts of dm^T (and the 8 rows of dss after them, which no
+    # one reads back): bf16 values in fp32, so the cast drops nothing
+    dmt = g_ref[0:3 * N + 8].astype(_BF16)
+    lhs = [ref[...] for ref in lhs_refs]
+    dss2 = dss_ref[...]
+    for cols in _columns(x_ref.shape[1], _LANES):
+        x = x_ref[:, cols]
+        phit = phit_ref[:, cols]
+        du = _sum(_dot(l, phit) for l in lhs) + dss2 * x.astype(_F32)
+        dx_ref[:, cols] = du.astype(dx_ref.dtype)
+        dp = _sum(_dot(dmt, p) for p in _split(x, parts))
+        dphit_ref[:, cols] += dp[0:N] + dp[N:2 * N] + dp[2 * N:3 * N]
+
+
+def _wide(column):
+    """A map value a token [rows, 1] spread over the lanes, once a tile:
+    on the chip a loop over blocks of 16 rows that kept the spread values
+    in registers lost to this (`write` 0.54 / 0.81 -> 0.73 / 0.94 ms a
+    sublayer, tools/mhc_probe.py, PERF.md PR 51)."""
+    return jnp.broadcast_to(column, (column.shape[0], _LANES))
+
+
+def _chosen(h_ref, ks, which):
+    """The column of H^T that `which` (a grid index) names among `ks`,
+    spread over the lanes."""
+    return _wide(_sum((which == i).astype(_F32) * h_ref[:, k:k + 1]
+                      for i, k in enumerate(ks)))
+
+
+def _rowsum(v):
+    return jnp.sum(v, axis=-1, keepdims=True)
+
+
+def _read_kernel(h_ref, *refs, n):
+    xs, o_ref = refs[:n], refs[n]
+    h = [_wide(h_ref[:, j:j + 1]) for j in range(n)]
+    for cols in _columns(o_ref.shape[1], _LANES):
+        o_ref[:, cols] = _sum(h[j] * xs[j][:, cols].astype(_F32)
+                              for j in range(n)).astype(o_ref.dtype)
+
+
+def _no_map_gradient_yet(dh_ref):
+    import jax.experimental.pallas as pl
+
+    @pl.when((pl.program_id(2) == 0) & (pl.program_id(3) == 0))
+    def _zero():
+        dh_ref[...] = jnp.zeros_like(dh_ref)
+
+
+def _into_columns(dh_ref, columns, parts):
+    """dH's `columns` += the sums of `parts` over their lanes."""
+    lane = _lane(parts[0].shape)
+    dh_ref[...] += _sum(jnp.where(lane == k, _rowsum(p), 0.0)
+                        for k, p in zip(columns, parts))
+
+
+def _read_bwd_kernel(h_ref, x_ref, g_ref, dx_ref, dh_ref, *, n):
+    """Stream s of the grid's last axis: dX[s] = H_pre[s] g and dH_pre[s]
+    += sum_c g X[s]."""
+    import jax.experimental.pallas as pl
+
+    s = pl.program_id(3)
+    _no_map_gradient_yet(dh_ref)
+    h = _chosen(h_ref, range(n), s)
+    part = jnp.zeros(h.shape, _F32)
+    for cols in _columns(g_ref.shape[1], _LANES):
+        g = g_ref[:, cols].astype(_F32)
+        dx_ref[:, cols] = (h * g).astype(dx_ref.dtype)
+        part = part + g * x_ref[:, cols].astype(_F32)
+    _into_columns(dh_ref, [s], [part])
+
+
+def _write_kernel(h_ref, *refs, n):
+    """Stream s: X'[s] = sum_j H_res[s, j] X[j] + H_post[s] y."""
+    import jax.experimental.pallas as pl
+
+    xs, y_ref, o_ref = refs[:n], refs[n], refs[n + 1]
+    s = pl.program_id(3)
+    res = [_chosen(h_ref, [2 * n + i * n + j for i in range(n)], s)
+           for j in range(n)]
+    post = _chosen(h_ref, range(n, 2 * n), s)
+    for cols in _columns(o_ref.shape[1], _LANES):
+        o_ref[:, cols] = (
+            _sum(res[j] * xs[j][:, cols].astype(_F32) for j in range(n))
+            + post * y_ref[:, cols].astype(_F32)).astype(o_ref.dtype)
+
+
+def _write_bwd_kernel(h_ref, x_ref, *refs, n):
+    """Stream s: dX[s] = sum_i H_res[i, s] g[i] and dH_res[i, s] += sum_c
+    g[i] X[s]; with the first stream dy = sum_i H_post[i] g[i] and
+    dH_post[i] += sum_c g[i] y."""
+    import jax.experimental.pallas as pl
+
+    gs, y_ref = refs[:n], refs[n]
+    dx_ref, dy_ref, dh_ref = refs[n + 1:]
+    s = pl.program_id(3)
+    _no_map_gradient_yet(dh_ref)
+
+    def both(coefficient, other_ref, out_ref, columns):
+        """out = sum_i coefficient[i] g[i]; dH's `columns` += sum_c g[i]
+        other."""
+        parts = [jnp.zeros(coefficient[0].shape, _F32)] * n
+        for cols in _columns(x_ref.shape[1], _LANES):
+            other = other_ref[:, cols].astype(_F32)
+            g = [ref[:, cols].astype(_F32) for ref in gs]
+            out_ref[:, cols] = _sum(
+                coefficient[i] * g[i] for i in range(n)).astype(out_ref.dtype)
+            parts = [parts[i] + g[i] * other for i in range(n)]
+        _into_columns(dh_ref, columns, parts)
+
+    both([_chosen(h_ref, [2 * n + i * n + j for j in range(n)], s)
+          for i in range(n)],
+         x_ref, dx_ref, [2 * n + i * n + s for i in range(n)])
+
+    @pl.when(s == 0)
+    def _the_sublayers_output():
+        both([_wide(h_ref[:, n + i:n + i + 1]) for i in range(n)],
+             y_ref, dy_ref, [n + i for i in range(n)])
+
+
+# ---------------------------------------------------------------------------
+# the calls
+# ---------------------------------------------------------------------------
+def _jitted(kernel, **kw):
+    """Memoized by the callers and jitted, as kernels/kda_mix.py's calls:
+    the sites after the first find the kernel's body traced and lowered."""
+    import jax.experimental.pallas as pl
+
+    return jax.jit(pl.pallas_call(kernel, **kw))
+
+
+def _scratch(*shapes):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM(s, _F32) for s in shapes]
+
+
+def _stream_block(T, Cb, where):
+    """A [T, Cb] block of the streams [B, n, S, C]; `where` gives (sequence,
+    stream, tile of rows, block of channels) from the grid's indices."""
+    import jax.experimental.pallas as pl
+
+    return pl.BlockSpec((None, None, T, Cb), where)
+
+
+def _maps_specs(B, S, n, C, N, tiles):
+    """(grid, x, phis, small, h): the block specs on the grid (sequence,
+    tile of rows, stream x block of channels)."""
+    import jax.experimental.pallas as pl
+
+    T, Kb = tiles.rows, tiles.channels
+    per = C // Kb
+    return ((B, S // T, n * per),
+            _stream_block(T, Kb, lambda b, i, k: (b, k // per, i, k % per)),
+            pl.BlockSpec((Kb, _LANES), lambda b, i, k: (k, 0)),
+            pl.BlockSpec((N, 1), lambda b, i, k: (0, 0)),
+            pl.BlockSpec((None, N, T), lambda b, i, k: (b, 0, i)))
+
+
+@functools.lru_cache(maxsize=64)
+def _maps_fwd_call(B, S, C, geo, tiles, dtype, interpret):
+    n, N, T = geo.streams, _values(geo.streams), tiles.rows
+    grid, x, phis, small, h = _maps_specs(B, S, n, C, N, tiles)
+    return _jitted(
+        functools.partial(_maps_kernel, geo=geo, parts=_parts(dtype),
+                          width=n * C),
+        grid=grid, in_specs=[x, phis, small, small], out_specs=h,
+        out_shape=jax.ShapeDtypeStruct((B, N, S), _F32),
+        scratch_shapes=_scratch((T, _LANES), (T, _LANES)),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), tiles.fwd_vmem),
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=64)
+def _maps_bwd_tail_call(B, S, C, geo, tiles, dtype, interpret):
+    import jax.experimental.pallas as pl
+
+    n, N, T = geo.streams, _values(geo.streams), tiles.rows
+    grid, x, phis, small, h = _maps_specs(B, S, n, C, N, tiles)
+    sums = pl.BlockSpec((N, _LANES), lambda b, i, k: (0, 0))
+    return _jitted(
+        functools.partial(_maps_bwd_tail_kernel, geo=geo,
+                          parts=_parts(dtype), width=n * C),
+        grid=grid, in_specs=[x, phis, small, small, h],
+        out_specs=[pl.BlockSpec((None, _LANES, T), lambda b, i, k: (b, 0, i)),
+                   sums, sums],
+        out_shape=[jax.ShapeDtypeStruct((B, _LANES, S), _F32)]
+        + [jax.ShapeDtypeStruct((N, _LANES), _F32)] * 2,
+        scratch_shapes=_scratch((T, _LANES), (T, _LANES),
+                                (2 * geo.iters + 1, n * n, T)),
+        compiler_params=_compiler_params(("arbitrary",) * 3, tiles.bwd_vmem),
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=64)
+def _maps_bwd_stream_call(B, S, n, C, tiles, dtype, interpret):
+    import jax.experimental.pallas as pl
+
+    N, T, Kb = _values(n), tiles.rows, tiles.channels
+    per = C // Kb
+    tile = _stream_block(T, Kb, lambda k, b, i: (b, k // per, i, k % per))
+    wide = pl.BlockSpec((None, T, _LANES), lambda k, b, i: (b, i, 0))
+    return _jitted(
+        functools.partial(_maps_bwd_stream_kernel, parts=_parts(dtype)),
+        grid=(n * per, B, S // T),
+        in_specs=[tile,
+                  pl.BlockSpec((None, _LANES, T), lambda k, b, i: (b, 0, i)),
+                  pl.BlockSpec((None, T, 1), lambda k, b, i: (b, i, 0)),
+                  pl.BlockSpec((_LANES, Kb), lambda k, b, i: (0, k))]
+        + [wide] * ((_parts(dtype) + 1) // 2),
+        out_specs=[tile, pl.BlockSpec((N, Kb), lambda k, b, i: (0, k))],
+        out_shape=[jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
+                   jax.ShapeDtypeStruct((N, n * C), _F32)],
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem),
+        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _maps(x, phi, a, b, geo: Maps, tiles: Tiles, interpret: bool = False):
+    """H [B, N, S] of a site `maps_tiles` tiled: x [B, n, S, C], Phi [n C,
+    N], a and b [N, 1] (a row's scalar and bias), all but x fp32."""
+    B, _, S, C = x.shape
+    return _maps_fwd_call(B, S, C, geo, tiles, str(x.dtype), interpret)(
+        x, _phi_columns(phi, (0, 1, 2)), a, b)
+
+
+def _maps_fwd(x, phi, a, b, geo, tiles, interpret):
+    return _maps(x, phi, a, b, geo, tiles, interpret), (x, phi, a, b)
+
+
+def _maps_bwd(geo, tiles, interpret, inputs, dh):
+    x, phi, a, b = inputs
+    (B, n, S, C), N = x.shape, _values(geo.streams)
+    g, da, db = _maps_bwd_tail_call(B, S, C, geo, tiles, str(x.dtype),
+                                    interpret)(
+        x, _phi_columns(phi, (0, 1, 2)), a, b, dh.astype(_F32))
+    by_token = jnp.swapaxes(g, 1, 2)
+    pieces = [by_token[..., p * N:(p + 1) * N] for p in range(3)]
+    lhs = [jnp.pad(jnp.concatenate([pieces[p] for p in row], axis=-1),
+                   ((0, 0), (0, 0), (0, _LANES - N * len(row)))).astype(_BF16)
+           for row in _DM_GROUPS[:(_parts(x.dtype) + 1) // 2]]
+    dx, dphit = _maps_bwd_stream_call(B, S, n, C, tiles, str(x.dtype),
+                                      interpret)(
+        x, g, by_token[..., 3 * N:3 * N + 1],
+        _phi_columns(phi, _PHI_GROUPS).T, *lhs)
+    return (dx, dphit.T.astype(phi.dtype), _rowsum(da).astype(a.dtype),
+            _rowsum(db).astype(b.dtype))
+
+
+_maps.defvjp(_maps_fwd, _maps_bwd)
+
+
+def _mix_specs(B, S, C, n, N, tiles, streams_axis):
+    """(grid, h, one, wide, at(j)): the block specs of H^T, of a [B, S, C]
+    value's block, of a token's 128 map gradients and of stream j's block
+    (j None: the stream the grid's last axis names) of the streams [B, n,
+    S, C], on the grid (sequence, tile of rows, block of channels[,
+    stream])."""
+    import jax.experimental.pallas as pl
+
+    T, Cb = tiles.rows, tiles.channels
+
+    def at(j):
+        if j is None:
+            return _stream_block(T, Cb, lambda b, i, c, s: (b, s, i, c))
+        return _stream_block(T, Cb, lambda b, i, c, *s: (b, j, i, c))
+
+    grid = (B, S // T, C // Cb) + ((n,) if streams_axis else ())
+    return (grid, pl.BlockSpec((None, T, N), lambda b, i, c, *s: (b, i, 0)),
+            pl.BlockSpec((None, T, Cb), lambda b, i, c, *s: (b, i, c)),
+            pl.BlockSpec((None, T, _LANES), lambda b, i, c, *s: (b, i, 0)),
+            at)
+
+
+@functools.lru_cache(maxsize=64)
+def _read_fwd_call(B, S, C, n, tiles, dtype, interpret):
+    grid, h, one, _, at = _mix_specs(B, S, C, n, _values(n), tiles, False)
+    return _jitted(
+        functools.partial(_read_kernel, n=n), grid=grid,
+        in_specs=[h] + [at(j) for j in range(n)], out_specs=one,
+        out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
+        compiler_params=_compiler_params(("parallel",) * 3, tiles.fwd_vmem),
+        interpret=interpret)
+
+
+_ONE_STREAM_A_STEP = ("parallel", "parallel", "arbitrary", "arbitrary")
+
+
+@functools.lru_cache(maxsize=64)
+def _read_bwd_call(B, S, C, n, tiles, dtype, interpret):
+    grid, h, one, wide, at = _mix_specs(B, S, C, n, _values(n), tiles, True)
+    return _jitted(
+        functools.partial(_read_bwd_kernel, n=n), grid=grid,
+        in_specs=[h, at(None), one], out_specs=[at(None), wide],
+        out_shape=[jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
+                   jax.ShapeDtypeStruct((B, S, _LANES), _F32)],
+        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.bwd_vmem),
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=64)
+def _write_fwd_call(B, S, C, n, tiles, dtype, interpret):
+    grid, h, one, _, at = _mix_specs(B, S, C, n, _values(n), tiles, True)
+    return _jitted(
+        functools.partial(_write_kernel, n=n), grid=grid,
+        in_specs=[h] + [at(j) for j in range(n)] + [one],
+        out_specs=at(None),
+        out_shape=jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
+        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.fwd_vmem),
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=64)
+def _write_bwd_call(B, S, C, n, tiles, dtype, interpret):
+    grid, h, one, wide, at = _mix_specs(B, S, C, n, _values(n), tiles, True)
+    like = jnp.dtype(dtype)
+    return _jitted(
+        functools.partial(_write_bwd_kernel, n=n), grid=grid,
+        in_specs=[h, at(None)] + [at(i) for i in range(n)] + [one],
+        out_specs=[at(None), one, wide],
+        out_shape=[jax.ShapeDtypeStruct((B, n, S, C), like),
+                   jax.ShapeDtypeStruct((B, S, C), like),
+                   jax.ShapeDtypeStruct((B, S, _LANES), _F32)],
+        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.bwd_vmem),
+        interpret=interpret)
+
+
+def _by_token(h):
+    return jnp.swapaxes(h, 1, 2)
+
+
+def _by_value(dht, h):
+    """dH [B, N, S] of the kernels' [B, S, 128]."""
+    return jnp.swapaxes(dht[..., :h.shape[1]], 1, 2).astype(h.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _read(x, h, n: int, tiles: Tiles, interpret: bool = False):
+    """x_in [B, S, C] of a site `mix_tiles` tiled: x [B, n, S, C], H [B, N,
+    S] fp32."""
+    B, _, S, C = x.shape
+    return _read_fwd_call(B, S, C, n, tiles, str(x.dtype), interpret)(
+        _by_token(h), *[x] * n)
+
+
+def _read_fwd(x, h, n, tiles, interpret):
+    return _read(x, h, n, tiles, interpret), (x, h)
+
+
+def _read_bwd(n, tiles, interpret, inputs, g):
+    x, h = inputs
+    B, _, S, C = x.shape
+    dx, dht = _read_bwd_call(B, S, C, n, tiles, str(x.dtype),
+                             interpret)(_by_token(h), x, g.astype(x.dtype))
+    return dx, _by_value(dht, h)
+
+
+_read.defvjp(_read_fwd, _read_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _write(x, h, y, n: int, tiles: Tiles, interpret: bool = False):
+    """X' [B, n, S, C] of a site `mix_tiles` tiled; y [B, S, C] in x's
+    dtype."""
+    B, _, S, C = x.shape
+    return _write_fwd_call(B, S, C, n, tiles, str(x.dtype), interpret)(
+        _by_token(h), *[x] * n, y)
+
+
+def _write_fwd(x, h, y, n, tiles, interpret):
+    return _write(x, h, y, n, tiles, interpret), (x, h, y)
+
+
+def _write_bwd(n, tiles, interpret, inputs, g):
+    x, h, y = inputs
+    B, _, S, C = x.shape
+    g = g.astype(x.dtype)
+    dx, dy, dht = _write_bwd_call(B, S, C, n, tiles, str(x.dtype),
+                                  interpret)(_by_token(h), x, *[g] * n, y)
+    return dx, _by_value(dht, h), dy
+
+
+_write.defvjp(_write_fwd, _write_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the three ops' engines
+# ---------------------------------------------------------------------------
+def _by_stream(x):
+    """[B, S, n, C] <-> [B, n, S, C]: the kernels' view of the streams, a
+    stream's [S, C] plane under the last two axes.  A transposition in
+    name: the streams live between these ops alone, so the compiler lays
+    the [B, S, n, C] value out stream-major and this is a bitcast; as rows
+    of n C it cannot be one under the TPU's tiled layouts, and every op
+    paid a copy of the streams each way (PERF.md, PR 51)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res, epsilon, hc_eps,
+         iters, clamp, force: str = "auto", rows=None, channels=None):
+    """(ops/hyper_connection_ops.py::maps' H, the tiles it was computed
+    under: None for the jax.numpy form).  The engine is read from the
+    site: the kernel pair where the program is for a TPU and `maps_tiles`
+    tiles the shape; force="interpret" runs the pair in the Pallas
+    interpreter (the CPU tests' door), force="jax" never; `rows` and
+    `channels` pin the tile."""
+    from ..ops import hyper_connection_ops as ops
+
+    B, S, n, C = x.shape
+    tiles = None
+    if _kernels(force) and _one_dtype(x) is not None:
+        tiles = maps_tiles(S, n, C, iters, x.dtype, rows, channels)
+    if tiles is None:
+        return jax.checkpoint(functools.partial(
+            ops.maps, epsilon=epsilon, hc_eps=hc_eps, iters=iters,
+            clamp=clamp))(x, phi, a_pre, a_post, a_res, b_pre, b_post,
+                          b_res), None
+
+    def column(values):
+        return jnp.concatenate(values).astype(_F32).reshape(-1, 1)
+
+    biases = [b.reshape(-1) for b in (b_pre, b_post, b_res)]
+    scalars = [jnp.broadcast_to(a.reshape(1), b.shape)
+               for a, b in zip((a_pre, a_post, a_res), biases)]
+    geo = Maps(n, float(epsilon), float(hc_eps), int(iters),
+               float(clamp[0]), float(clamp[1]))
+    return _maps(_by_stream(x), phi.astype(_F32), column(scalars),
+                 column(biases), geo, tiles, force == "interpret"), tiles
+
+
+def read(x, h, force: str = "auto", rows=None, channels=None):
+    """(ops/hyper_connection_ops.py::read's x_in, the tiles: None for the
+    jax.numpy form); the engine as `maps` reads it."""
+    from ..ops import hyper_connection_ops as ops
+
+    B, S, n, C = x.shape
+    tiles = None
+    if _kernels(force) and _one_dtype(x) is not None:
+        tiles = mix_tiles(S, n, C, x.dtype, "read", rows, channels)
+    if tiles is None:
+        return jax.checkpoint(ops.read)(x, h), None
+    return _read(_by_stream(x), h.astype(_F32), n, tiles,
+                 force == "interpret"), tiles
+
+
+def write(x, h, y, force: str = "auto", rows=None, channels=None):
+    """(ops/hyper_connection_ops.py::write's X', the tiles: None for the
+    jax.numpy form); the engine as `maps` reads it, and x and y in one
+    dtype."""
+    from ..ops import hyper_connection_ops as ops
+
+    B, S, n, C = x.shape
+    tiles = None
+    if _kernels(force) and _one_dtype(x, y) is not None:
+        tiles = mix_tiles(S, n, C, x.dtype, "write", rows, channels)
+    if tiles is None:
+        return jax.checkpoint(ops.write)(x, h, y), None
+    return _by_stream(_write(_by_stream(x), h.astype(_F32), y, n, tiles,
+                             force == "interpret")), tiles

@@ -1459,7 +1459,10 @@ def mhc_maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
     rows, `hc_eps` beside each sum): doubly stochastic.  Returns H [B, 2n +
     n^2, S] fp32, the tokens on the minor axis: rows 0:n H_pre, n:2n
     H_post, then H_res row by row, for mhc_read and mhc_write (TPU-native;
-    ops/hyper_connection_ops.py mhc_maps)."""
+    ops/hyper_connection_ops.py mhc_maps: for ONE TPU a Pallas kernel pair
+    over tiles of rows where the shape tiles, four streams of C a multiple
+    of 128 and S of a tile, kernels/mhc.py; the same arithmetic in
+    jax.numpy elsewhere: the CPU, a mesh of several devices)."""
     helper = LayerHelper("mhc_maps", input=x, name=name)
     out = helper.create_variable_for_type_inference("float32")
     helper.append_op(
@@ -1479,7 +1482,9 @@ def mhc_maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
 def mhc_read(x, h, name=None):
     """What a sublayer reads of the streams x [B, S, n, C] under mhc_maps'
     h: sum_j H_pre[j] x[j], [B, S, C] (TPU-native;
-    ops/hyper_connection_ops.py mhc_read)."""
+    ops/hyper_connection_ops.py mhc_read; the engine as mhc_maps': a
+    Pallas kernel pair of kernels/mhc.py for one TPU where the shape
+    tiles, jax.numpy elsewhere)."""
     helper = LayerHelper("mhc_read", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(type="mhc_read", inputs={"X": [x], "H": [h]},
@@ -1490,7 +1495,9 @@ def mhc_read(x, h, name=None):
 def mhc_write(x, h, y, name=None):
     """The streams after a sublayer wrote y [B, S, C] back under mhc_maps'
     h: x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y, [B, S, n, C]
-    (TPU-native; ops/hyper_connection_ops.py mhc_write)."""
+    (TPU-native; ops/hyper_connection_ops.py mhc_write; the engine as
+    mhc_maps': a Pallas kernel pair of kernels/mhc.py for one TPU where
+    the shape tiles and x and y share a dtype, jax.numpy elsewhere)."""
     helper = LayerHelper("mhc_write", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(type="mhc_write", inputs={"X": [x], "H": [h], "Y": [y]},

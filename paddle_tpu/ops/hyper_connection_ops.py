@@ -21,21 +21,31 @@ Four ops: `mhc_streams` (a value copied to the n streams), `mhc_maps` (the
 n^2, S], rows 0:n H_pre, n:2n H_post, then H_res row by row; a minor axis
 of n would fill n of 128 lanes through 2 x `sinkhorn_iters`
 normalisations and their backward), `mhc_read` (x_in) and `mhc_write`
-(X').  jax.numpy, gradients by the compiler's jax.vjp; each op's
-arithmetic is under jax.checkpoint, so what its backward keeps is the op's
-inputs (the streams at their own element size) and never an fp32 copy of
-the streams.  Name scopes:
+(X').  `maps`, `read` and `write` below are the arithmetic, in jax.numpy.
+One algorithm, its engine read from the site (`_lowered`; PR 51): for ONE
+TPU, where the shape tiles (kernels/mhc.py::maps_tiles / ::mix_tiles: four
+streams, C whole 128-lane vectors, S whole tiles of rows, streams of one
+dtype, the working set inside the VMEM budget), each of the three ops is a
+Pallas kernel pair over tiles of rows whose backward makes the tile's
+forward again from the op's inputs; anywhere else (the CPU, a mesh of
+several devices, a shape that does not tile) the jax.numpy form with
+gradients by the compiler's jax.vjp, the op's arithmetic under
+jax.checkpoint.  Either way what a backward keeps is the op's inputs (the
+streams at their own element size) and never an fp32 copy of the streams.
+Name scopes:
 `mhc.maps` (the RMS, the [T, nC] x [nC, 2n + n^2] product, the
 activations, Sinkhorn) and `mhc.mix` (the read and the write).
 `mhc.lower` (a span, at lowering, one a `mhc_maps` op, which is one a
 sublayer) says `streams`, `sinkhorn_iters`, `sublayers` (1: a reader adds
 them up) and `moved_bytes`, what a sublayer's maps and mixing have to move
-through HBM whatever implements them (`moved_bytes`).
+through HBM whatever implements them (`moved_bytes`).  `mhc.kernel.lower`
+(a span, at lowering, one an op site, three a sublayer) says what the site
+was given: `what` (maps | read | write), `engine` (pallas | xla), `rows`
+and `channels` of a grid step and the `fwd_vmem_bytes` / `bwd_vmem_bytes`
+of its working sets (0 under xla).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +122,21 @@ def write(x, h, y):
         for i in range(n)], axis=2).astype(out)
 
 
+def _lowered(ctx, what, run):
+    """One site of `what` under the span `mhc.kernel.lower`: `run(force)`
+    gives (output, the kernels' tiles or None).  XLA cannot partition a
+    Mosaic kernel, so on a mesh of several devices the jax.numpy form,
+    which it can (linear_attention_ops.py::_lowered)."""
+    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
+    with span("mhc.kernel.lower", what=what) as sp:
+        out, tiles = run("jax" if several else "auto")
+        taken = tiles or (0,) * 4
+        sp.set(engine="xla" if tiles is None else "pallas", rows=taken[0],
+               channels=taken[1], fwd_vmem_bytes=taken[2],
+               bwd_vmem_bytes=taken[3])
+    return out
+
+
 def _streams_infer(op, block):
     x = in_desc(op, block, "X")
     if x is not None:
@@ -150,21 +175,25 @@ def _mhc_maps(ctx, ins, attrs):
     minor axis (module docstring).  Attributes `epsilon` (the RMS over
     vec(X), which has no learned weight), `hc_eps`, `sinkhorn_iters`,
     `clamp_min`, `clamp_max`.  Under the name scope `mhc.maps`; the span
-    `mhc.lower` is this op's."""
+    `mhc.lower` is this op's.  The engine is read from the site (module
+    docstring; kernels/mhc.py::maps): `mhc.kernel.lower` with `what`
+    maps."""
+    from ..kernels import mhc
+
     x, phi = data(ins["X"][0]), data(ins["Phi"][0])
     B, S, n, C = x.shape
     iters = int(attrs["sinkhorn_iters"])
+    small = [data(ins[s][0]) for s in (
+        "APre", "APost", "ARes", "BPre", "BPost", "BRes")]
     with span("mhc.lower", streams=int(n), sinkhorn_iters=iters, sublayers=1,
               moved_bytes=moved_bytes(B * S, n, C, x.dtype.itemsize,
                                       phi.size * phi.dtype.itemsize)), \
             jax.named_scope("mhc.maps"):
-        h = jax.checkpoint(functools.partial(
-            maps, epsilon=float(attrs.get("epsilon", 1e-6)),
-            hc_eps=float(attrs.get("hc_eps", 1e-6)), iters=iters,
-            clamp=(float(attrs.get("clamp_min", -30.0)),
-                   float(attrs.get("clamp_max", 30.0)))))(
-            x, phi, *(data(ins[s][0]) for s in (
-                "APre", "APost", "ARes", "BPre", "BPost", "BRes")))
+        h = _lowered(ctx, "maps", lambda force: mhc.maps(
+            x, phi, *small, float(attrs.get("epsilon", 1e-6)),
+            float(attrs.get("hc_eps", 1e-6)), iters,
+            (float(attrs.get("clamp_min", -30.0)),
+             float(attrs.get("clamp_max", 30.0))), force=force))
     return {"H": [h]}
 
 
@@ -179,10 +208,14 @@ def _read_infer(op, block):
 def _mhc_read(ctx, ins, attrs):
     """What a sublayer reads of the streams X [B, S, n, C] under the maps
     H of `mhc_maps`: Out [B, S, C] = sum_j H_pre[j] X[j].  Under the name
-    scope `mhc.mix`."""
+    scope `mhc.mix`; the engine as mhc_maps reads it (kernels/mhc.py::read),
+    `mhc.kernel.lower` with `what` read."""
+    from ..kernels import mhc
+
+    x, h = data(ins["X"][0]), data(ins["H"][0])
     with jax.named_scope("mhc.mix"):
-        return {"Out": [jax.checkpoint(read)(data(ins["X"][0]),
-                                             data(ins["H"][0]))]}
+        return {"Out": [_lowered(ctx, "read", lambda force: mhc.read(
+            x, h, force=force))]}
 
 
 @register_op("mhc_write", infer_shape=same_shape("X", "Out"),
@@ -190,7 +223,12 @@ def _mhc_read(ctx, ins, attrs):
 def _mhc_write(ctx, ins, attrs):
     """The streams after a sublayer wrote its output Y [B, S, C] back:
     Out[i] = sum_j H_res[i, j] X[j] + H_post[i] Y, [B, S, n, C].  Under the
-    name scope `mhc.mix`."""
+    name scope `mhc.mix`; the engine as mhc_maps reads it
+    (kernels/mhc.py::write: X and Y of one dtype besides),
+    `mhc.kernel.lower` with `what` write."""
+    from ..kernels import mhc
+
+    x, h, y = (data(ins[s][0]) for s in ("X", "H", "Y"))
     with jax.named_scope("mhc.mix"):
-        return {"Out": [jax.checkpoint(write)(
-            data(ins["X"][0]), data(ins["H"][0]), data(ins["Y"][0]))]}
+        return {"Out": [_lowered(ctx, "write", lambda force: mhc.write(
+            x, h, y, force=force))]}

@@ -316,7 +316,39 @@ def _kda_mix(backward):
         argnums=tuple(range(13))), args
 
 
+def _mhc(backward):
+    """xing4.0-29b-a4b's hyper-connection at the cell's shape (four streams
+    of 3584, S 4096, 20 Sinkhorn iterations, bf16 streams): kernels/mhc.py's
+    three forward kernels at the planned tiles, and with them the four
+    backward (the outputs weighted by themselves, so that the forwards
+    stay in the program)."""
+    from paddle_tpu.kernels import mhc
+
+    S, n, C, N = 4096, 4, 3584, 24
+    f32 = jnp.float32
+    args = (_sds((1, S, n, C), jnp.bfloat16), _sds((1, S, C), jnp.bfloat16),
+            _sds((n * C, N), f32), _sds((1,), f32), _sds((1,), f32),
+            _sds((1,), f32), _sds((n,), f32), _sds((n,), f32),
+            _sds((n, n), f32))
+
+    def fwd(x, y, *small):
+        h, maps = mhc.maps(x, *small, 1e-6, 1e-6, 20, (-30.0, 30.0),
+                           force="pallas")
+        x_in, read = mhc.read(x, h, force="pallas")
+        out, write = mhc.write(x, h, y, force="pallas")
+        assert None not in (maps, read, write)
+        return h, x_in, out
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+        argnums=tuple(range(9))), args
+
+
 _MAIN_PATH_KERNELS = {
+    "mhc_fwd_xing": lambda: _mhc(False),
+    "mhc_bwd_pallas_xing": lambda: _mhc(True),
     "kda_mix_fwd_kimi": lambda: _kda_mix(False),
     "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
     "kda_scan_fwd_kimi": lambda: _kda_scan(False),
@@ -347,6 +379,8 @@ def test_main_path_kernel_compiles_for_v5e(v5e, case):
     n = text.count("tpu_custom_call")
     # Pallas backward: the forward kernel (for its residuals) + the backward
     assert n >= (2 if "bwd_pallas" in case else 1), (case, n)
+    if case.startswith("mhc"):   # three forwards; + the backwards' four
+        assert n == (7 if "bwd" in case else 3), (case, n)
     if "bwd_xla" in case:
         assert n == 1, (case, n)
 
