@@ -1,0 +1,12 @@
+"""The bytes this run's writes pushed out of the persistent cache before the
+window: 0 unless the cache thrashes (kind train); None where the cache is
+off.
+
+One key of benchmark/harness/setup_log.py::summary, which cuts the program's
+set-up log at the window's start."""
+
+from benchmark.harness import setup_log
+
+
+def read(obs):
+    return setup_log.reading(obs, "cache_evicted_mb")

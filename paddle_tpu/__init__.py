@@ -96,3 +96,7 @@ from .param_attr import ParamAttr, WeightNormParamAttr  # noqa: F401
 
 # fluid-style direct names
 from .initializer import Constant, MSRA, Normal, TruncatedNormal, Uniform, Xavier  # noqa: F401
+
+# the set-up log's first mark: Python, jax, the backend's client and this
+# package are imported (observability/compiles.py)
+observability.default_compile_log().note_imported()

@@ -29,7 +29,6 @@ from .framework import Block, Program
 from .lod import LoDValue
 from .proto import OpDesc, VarType, dtype_to_numpy
 from .registry import GRAD_OP_SUFFIX, GRAD_SUFFIX, OpRegistry
-from ..observability import span as _obs_span
 
 __all__ = ["LoweringContext", "compile_block", "CompiledBlock"]
 
@@ -565,13 +564,11 @@ class CompiledBlock:
         if platform == "tpu":
             from .aot_tpu import tpu_cost_analysis
 
-            with _obs_span("compile.cost_analysis", platform="tpu"):
-                return tpu_cost_analysis(
-                    self.raw_fn, tuple(feed_vals), tuple(state_vals), key)
-        with _obs_span("compile.cost_analysis", platform="native"):
-            compiled = self.fn.trace(
-                tuple(feed_vals), tuple(state_vals), key).lower().compile()
-            ca = compiled.cost_analysis()
+            return tpu_cost_analysis(
+                self.raw_fn, tuple(feed_vals), tuple(state_vals), key)
+        compiled = self.fn.trace(
+            tuple(feed_vals), tuple(state_vals), key).lower().compile()
+        ca = compiled.cost_analysis()
         return ca if isinstance(ca, dict) else (ca[0] if ca else {})
 
     def tpu_lowering_check(self, feed_vals, state_vals, key) -> int:
@@ -584,9 +581,8 @@ class CompiledBlock:
         constraints (lse block tiling, strided slices) — failures that
         burn chip minutes but are fully reproducible on a CPU host via
         cross-platform export."""
-        with _obs_span("compile.tpu_lowering_check"):
-            exp = jax.export.export(self.fn, platforms=["tpu"])(
-                tuple(feed_vals), tuple(state_vals), key)
+        exp = jax.export.export(self.fn, platforms=["tpu"])(
+            tuple(feed_vals), tuple(state_vals), key)
         return len(exp.mlir_module_serialized)
 
 

@@ -22,6 +22,7 @@ import numpy as np
 from . import amp
 from .. import flags
 from .. import observability as _obs
+from ..observability.compiles import default_compile_log as _compile_log
 from .compiler import CompiledBlock
 from .framework import Program, Variable, default_main_program
 from .lod import LoDValue
@@ -192,21 +193,29 @@ def _check_nan_inf(plan, fetches, new_states) -> None:
 def cached_entry(cache, key, fp, build, use_cache: bool = True):
     """The ONE copy of the fingerprint-validated lookup both executors'
     run makes: (entry, hit), the entry being (fp,) + build() on a miss,
-    built under the `compile` span.  An in-place desc mutation (another fp
+    built under the `compile` span, which covers the block's construction
+    (a CompiledBlock, a closure, a jax.jit object: milliseconds) and no
+    compilation: jax traces, lowers and builds or loads under the first
+    `executor.dispatch`.  A miss of a table that is kept, and only that,
+    opens a first run in the set-up log (observability/compiles.py), which
+    run_step closes once that run's fetch is on the host; with
+    `use_cache=False` every step is a miss and none is a first run (its
+    executables are in the log all the same).  An in-place desc mutation (another fp
     under the same key) rebuilds and replaces the stale entry.  (The
     reference keys on the Program object, executor.py _get_program_cache —
     unsound here because descs mutate in place.)"""
     entry = cache.get(key) if use_cache else None
     hit = entry is not None and entry[0] == fp
     if not hit:
-        with _obs.span("compile", program=fp.hex()[:12]) as sp:
+        program = fp.hex()[:12]
+        if use_cache:
+            _compile_log().open_run(program)
+        with _obs.span("compile", program=program):
             entry = (fp,) + tuple(build())
         if use_cache:
             cache[key] = entry
     if flags.flag("FLAGS_observability"):
         _obs.record_compile_cache(hit=hit)
-        if not hit and sp.seconds is not None:  # on since before the build
-            _obs.record_compile(sp.seconds)
     return entry, hit
 
 
@@ -301,6 +310,10 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     returning, no more.  The callers give what differs between them:
 
     lookup() -> ((fp, call, plan), hit); a miss nests the `compile` span
+        and makes this step a first run of the set-up log, closed after
+        `executor.fetch`; `executor.dispatch` says which step made
+        executables (`executables`, `cache_misses`, `compile_s`, only where
+        the log grew under it: two integer reads a steady step)
     feeds(plan, block0) -> the feed values as the plan phase leaves them
     stage(plan, block0, feed_vals, state_vals, rng) ->
         (feed_vals, state_vals, rng, moved): the placement the caller
@@ -331,8 +344,14 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             feed_vals, state_vals, rng, moved = stage(
                 plan, block0, feed_vals, state_vals, rng)
             sp.set(n=n_given, moved=moved)
-        with _obs.span("executor.dispatch"), placed:
+        log = _compile_log()
+        compiled = log.count
+        with _obs.span("executor.dispatch") as sp, placed:
             fetches, new_states, new_rng = call(feed_vals, state_vals, rng)
+            if log.count != compiled:
+                # this step made executables: `executables`,
+                # `cache_misses`, `compile_s`, on the step that paid
+                sp.set(**log.since(compiled))
         with _obs.span("executor.commit") as sp:
             fetches = faultinject.nan_fetches(plan.fetch_names, fetches)
             if sentinel is not None and sentinel(plan, fetches, new_states):
@@ -368,6 +387,9 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             else:
                 out = plan.convert_fetches(fetches, block0, False)
             sp.set(n=len(out))
+        if not hit:
+            log.close_run(kind, len(plan.feed_names), len(out),
+                          len(plan.state_names))
     if step.seconds is not None:  # FLAGS_observability
         _obs.record_executor_step(step.seconds, donated=donated,
                                   skipped=skipped)

@@ -9,13 +9,17 @@ was a chrome-trace stub with no hot-path consumers.  Now:
 - **Metrics** (`metrics.py`): Counter / Gauge / Histogram with labels in
   a process-wide registry; JSON snapshots, Prometheus text exposition,
   atomic per-process dumps with cross-process merge (`aggregate_dir`).
-- **Spans** (`tracing.py`): `span("compile")` / `span("step", step=n)` /
-  `span("ckpt.save")` nest per-thread, land in any running jax.profiler
-  session (flag or no flag), and export one merged Chrome/Perfetto trace
-  per run with named threads and stable tids (timeline.py is rebased onto
-  this writer).  Both executors wrap a step's phases in them
-  (`executor.step` over `.plan`, `.stage`, `.dispatch`, `.commit`,
-  `.fetch`; core/executor.py::run_step).
+- **Spans** (`tracing.py`): `span("step", step=n)` / `span("ckpt.save")`
+  nest per-thread, land in any running jax.profiler session (flag or no
+  flag), and export one merged Chrome/Perfetto trace per run with named
+  threads and stable tids (timeline.py is rebased onto this writer).  Both
+  executors wrap a step's phases in them (`executor.step` over `.plan`,
+  `.stage`, `.dispatch`, `.commit`, `.fetch`; core/executor.py::run_step).
+- **The set-up log** (`compiles.py`): one record an executable from jax's
+  own compile events (trace, lowering, build or load from the persistent
+  cache, the cache entry's bytes and what its write evicted) and one first
+  run a program the executor had not met.  The ONE instrument that is
+  always on: set-up is over before a reader runs.
 - **Step stats** (`stepstats.py`): ring buffer of the `executor.step`
   spans' durations (to the fetched values on the host) with rolling
   p50/p99, plus the BENCH_BASELINE regression gate
@@ -32,8 +36,8 @@ was a chrome-trace stub with no hot-path consumers.  Now:
   circuit breaker trips or engine health enters BROKEN — the black box
   every chaos failure leaves behind.
 
-Everything but a span's place in a profiler session is gated on
-**FLAGS_observability** (env `FLAGS_observability=1` or
+Everything but a span's place in a profiler session and the set-up log is
+gated on **FLAGS_observability** (env `FLAGS_observability=1` or
 `fluid.set_flags({"FLAGS_observability": True})`).  Disabled, every
 instrument returns after one dict lookup and a span is an inert
 jax.profiler.TraceAnnotation — no locks, no clock reads, no registry call,
@@ -57,6 +61,7 @@ import time
 from typing import List, Optional
 
 from .. import flags as _flags
+from .compiles import CompileLog, default_compile_log  # noqa: F401
 from .flight import (  # noqa: F401
     FlightRecorder,
     default_flight,
@@ -90,6 +95,7 @@ from .tracing import (  # noqa: F401
 )
 
 __all__ = [
+    "CompileLog",
     "Counter",
     "FlightRecorder",
     "Gauge",
@@ -97,6 +103,7 @@ __all__ = [
     "MetricsRegistry",
     "RequestTrace",
     "RequestTracer",
+    "default_compile_log",
     "default_flight",
     "default_registry",
     "default_request_tracer",
@@ -113,7 +120,6 @@ __all__ = [
     "disable",
     "step_stats",
     "record_executor_step",
-    "record_compile",
     "record_cost",
     "record_device_memory",
     "export_run",
@@ -139,6 +145,7 @@ def disable() -> None:
 
 
 _step_stats = StepStats()
+default_compile_log().listen()
 
 
 def step_stats() -> StepStats:
@@ -148,11 +155,13 @@ def step_stats() -> StepStats:
 
 def reset() -> None:
     """Clear the default registry, tracer, request tracer, flight
-    recorder, and step stats (fresh run in the same process; tests)."""
+    recorder, set-up log and step stats (fresh run in the same process;
+    tests)."""
     default_registry().reset()
     default_tracer().clear()
     default_request_tracer().reset()
     default_flight().reset()
+    default_compile_log().clear()
     _step_stats.reset()
 
 
@@ -191,16 +200,6 @@ def record_compile_cache(hit: bool) -> None:
         "paddle_tpu_compile_cache",
         "Executor compiled-program cache lookups",
     ).inc(result="hit" if hit else "miss")
-
-
-def record_compile(seconds: float) -> None:
-    """One CompiledBlock build (trace-time lowering setup; the XLA
-    compile itself lands in the first step's wall time)."""
-    reg = default_registry()
-    reg.histogram(
-        "paddle_tpu_compile_seconds",
-        "CompiledBlock construction (lowering setup) wall time",
-    ).observe(seconds)
 
 
 def record_cost(cost: dict, program: str,
@@ -292,8 +291,9 @@ def export_run(dirname: str, results: Optional[List[dict]] = None,
     - metrics.json  — the same registry as a merge-able JSON snapshot
     - trace.json    — merged Chrome/Perfetto trace (spans + profiler
       events, named threads, stable tids)
-    - report.json   — step-time summary (p50/p99), optional bench
-      results, and regression verdicts vs `baseline_path`
+    - report.json   — step-time summary (p50/p99), the set-up log
+      (`setup`: compiles.py's snapshot), optional bench results, and
+      regression verdicts vs `baseline_path`
 
     On multi-process runs EVERY artifact is namespaced `*_<pid>.*` for
     process index > 0 (a shared run dir must never have two processes
@@ -325,6 +325,7 @@ def export_run(dirname: str, results: Optional[List[dict]] = None,
         "span_count": n_spans,
         "request_traces": default_request_tracer().stats(),
         "flight_dumps": list(default_flight().dump_paths),
+        "setup": default_compile_log().snapshot(),
     }
     if results:
         report["results"] = results

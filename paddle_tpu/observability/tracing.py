@@ -1,7 +1,7 @@
 """Structured trace spans + the one Chrome/Perfetto trace writer.
 
-`span("compile")` / `span("step", step=n)` / `span("ckpt.save")` record
-(name, start, end, thread, parent, attrs) into a process-wide Tracer.
+`span("step", step=n)` / `span("ckpt.save")` record (name, start, end,
+thread, parent, attrs) into a process-wide Tracer.
 Spans nest correctly across threads — each thread carries its own span
 stack (thread-local), so a checkpoint writer thread's spans never adopt
 the training thread's open "step" as parent.

@@ -449,16 +449,25 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
     """The contract with the flag off and no profiler session: a span is
     its inert TraceAnnotation and nothing else.  No instrument of the
     registry is reached, nothing is appended to the ring, the package reads
-    no clock, and nothing it allocated outlives the step."""
+    no clock, and nothing it allocated outlives the step.  The set-up log,
+    the one instrument that is always on, has the first runs and their
+    executables, and a steady step adds nothing to it and reads no clock
+    in its module either."""
     import gc
     import tracemalloc
 
-    from paddle_tpu.observability import tracing
+    from paddle_tpu.observability import compiles, tracing
 
     assert not obs.enabled()
+    obs.reset()
     exe, loss = _build_step(name="obs_cold_w")
     for i in range(2):  # warm the compile + caches
         exe.run(feed=_feed(i), fetch_list=[loss])
+    log = obs.default_compile_log()
+    before = log.snapshot()
+    assert len(before["runs"]) == 2  # the start-up program, the step
+    assert sum(r["fun"] == "jit(fn)" and r["run"] is not None
+               for r in before["records"]) == 2
 
     calls = []
     for name in ("record_executor_step", "record_compile_cache",
@@ -473,6 +482,7 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
             raise AssertionError(f"time.{name} read with the flag off")
 
     monkeypatch.setattr(tracing, "time", NoClock())
+    monkeypatch.setattr(compiles, "time", NoClock())
     obs_pkg_dir = os.path.dirname(os.path.abspath(obs.__file__))
     tracemalloc.start()
     try:
@@ -490,7 +500,9 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
         [tracemalloc.Filter(True, os.path.join(obs_pkg_dir, "*"))]
     ).statistics("filename")
     assert hits == [], f"observability allocated while disabled: {hits}"
+    assert log.count == len(before["records"])
     monkeypatch.undo()
+    assert log.snapshot() == before
     # control: the SAME steps with the flag on do reach the instruments
     calls = []
     monkeypatch.setattr(obs, "record_executor_step",

@@ -14,8 +14,10 @@ it uses a directory of its own — a temporary one unless --cache-dir — and
 keeps JAX_COMPILATION_CACHE_DIR out of the stages' environment:
 
   warm  — compile + run a small conv+BN+fc training program with
-          FLAGS_compile_cache_dir set; record losses, wall time, and the
-          persistent-cache hit/miss counts from jax's monitoring events.
+          FLAGS_compile_cache_dir set; record losses, wall time, and from
+          the program's set-up log (observability/compiles.py) the
+          persistent cache's hit/miss counts with the stage's trace_s,
+          lower_s, backend_s and retrieval_s.
   cold  — a FRESH process, same program, same cache dir; done =
           cache_hits > 0, bit-identical losses, and a compile wall that
           dropped.
@@ -46,18 +48,6 @@ STAGE_SRC = r"""
 import json, os, sys, time
 sys.path.insert(0, os.environ["COLDSTART_REPO"])
 import jax
-
-counts = {"hits": 0, "misses": 0}
-from jax._src import monitoring
-
-def _listen(event, **kw):
-    if event.endswith("/cache_hits"):
-        counts["hits"] += 1
-    elif event.endswith("/cache_misses"):
-        counts["misses"] += 1
-
-monitoring.register_event_listener(_listen)
-
 import numpy as np
 import paddle_tpu as fluid
 from paddle_tpu import layers
@@ -83,12 +73,19 @@ yv = rng.randint(0, 3, size=(8, 1)).astype("int64")
 losses = [float(np.ravel(np.asarray(
     exe.run(feed={"x": xv, "y": yv}, fetch_list=[loss])[0]))[0])
     for _ in range(3)]
+# the program's own set-up log (observability/compiles.py): one record an
+# executable, from jax's monitoring events
+records = fluid.observability.default_compile_log().snapshot()["records"]
 print(json.dumps({
     "stage": os.environ["COLDSTART_STAGE"],
     "wall_s": round(time.perf_counter() - t0, 3),
     "losses": losses,
-    "cache_hits": counts["hits"],
-    "cache_misses": counts["misses"],
+    "cache_hits": sum(r["cache"] == "hit" for r in records),
+    "cache_misses": sum(r["cache"] == "miss" for r in records),
+    "trace_s": round(sum(r["trace_s"] for r in records), 4),
+    "lower_s": round(sum(r["lower_s"] for r in records), 4),
+    "backend_s": round(sum(r["backend_s"] for r in records), 4),
+    "retrieval_s": round(sum(r["retrieval_s"] or 0.0 for r in records), 4),
     "backend": jax.default_backend(),
 }), flush=True)
 """

@@ -10,8 +10,10 @@ FLAGS_observability=1 bench.py run with BENCH_OBS_DIR, or a serve_bench
     metrics.json     registry snapshot (metrics_<pid>.json per process on
                      multi-host runs; this CLI aggregates them all)
     trace.json       merged Chrome/Perfetto trace (load in ui.perfetto.dev)
-    report.json      step-time summary + regression verdicts + request
-                     trace sampling stats
+    report.json      step-time summary + the set-up log (every first run
+                     of a program with the executables it made: trace,
+                     lowering, build or load from the persistent cache)
+                     + regression verdicts + request trace sampling stats
     flight_*.jsonl   flight-recorder dumps (breaker trips / BROKEN health)
 
 Besides metrics and step times this renders a PER-REQUEST timeline for
@@ -88,6 +90,48 @@ def _print_step_time(report: dict, out) -> None:
                      ("mean_s", "mean"), ("min_s", "min"),
                      ("max_s", "max")):
         out.write(f"  {label:<5}: {_fmt_s(st.get(k))}\n")
+
+
+def _print_setup(report: dict, out) -> None:
+    """The set-up log (observability/compiles.py): a line a first run, a
+    line an executable under the run that paid for it."""
+    setup = report.get("setup") or {}
+    records = setup.get("records") or []
+    out.write("== set-up ==\n")
+    if not records and not setup.get("runs"):
+        out.write("  (nothing compiled or loaded)\n")
+        return
+    out.write(f"  persistent cache: {setup.get('cache_dir') or 'off'}; "
+              f"{sum(r['cache'] == 'hit' for r in records)} hit, "
+              f"{sum(r['cache'] == 'miss' for r in records)} miss, "
+              f"{sum(r['cache'] == 'off' for r in records)} not asked; "
+              f"{setup.get('dropped', 0)} records dropped\n")
+
+    def line(r):
+        size = r.get("entry_bytes")
+        evicted = r.get("evicted_bytes")
+        return (f"    {r['fun']:<36} trace {_fmt_s(r['trace_s'])} lower "
+                f"{_fmt_s(r['lower_s'])} backend {_fmt_s(r['backend_s'])} "
+                f"{r['cache']}"
+                + (f" load {_fmt_s(r['retrieval_s'])}"
+                   if r.get("retrieval_s") is not None else "")
+                + (f" entry {size / 1e6:.2f}MB" if size else "")
+                + (f" evicted {evicted / 1e6:.2f}MB" if evicted else "")
+                + "\n")
+
+    for run in setup.get("runs") or []:
+        out.write(f"  first run {run['index']} [{run['kind']}] program "
+                  f"{run['program']}: {_fmt_s(run['t1'] - run['t0'])} "
+                  f"(feed {run['n_feed']}, fetch {run['n_fetch']}, state "
+                  f"{run['n_state']})\n")
+        for r in records:
+            if r["run"] == run["index"]:
+                out.write(line(r))
+    rest = [r for r in records if r["run"] is None]
+    if rest:
+        out.write("  outside any first run:\n")
+        for r in rest:
+            out.write(line(r))
 
 
 def _print_metrics(reg, out) -> None:
@@ -263,6 +307,7 @@ def main(argv=None) -> int:
     report = _load_report(args.run_dir)
     out.write(f"observability run: {os.path.abspath(args.run_dir)}\n")
     _print_step_time(report, out)
+    _print_setup(report, out)
 
     reg = _aggregate_metrics(args.run_dir)
     if reg is not None:
