@@ -10,12 +10,32 @@ block matmuls.
 The forward's grid is planned from the shape (PR 28).  A grid step costs
 about 0.35 us before it computes anything, so at 128 x 128 blocks (all this
 kernel had while its only shape was S 256, head 64) a 32 x 2048 x 128 call
-was 8192 steps of overhead.  Two faces of one mechanism:
+was 8192 steps of overhead.  (What that cost is was read in PR 53: at one
+256 x 256 block a head, 768 heads a call, putting several heads into one
+grid step with the body as it stood moved 1.23 ms to 1.22; it is the state
+a step keeps between blocks, the fp32 m / l / acc scratch initialised,
+rescaled, written, read back and flushed under pl.when, not the pipeline's
+step.)  Two faces of one mechanism:
 - _plan_blocks: the blocks that take the fewest grid steps whose working
   set (fwd_working_set_bytes: the declared buffers plus the fp32 score and
   probability blocks) fits 3/4 of the v5e's scoped VMEM.  S 2048 causal
   runs 1024 x 1024, S 256 one 256 x 256 block a head.  It reads the shape
   and `causal`, nothing else: no flag, no argument a model sets.
+- _rows_per_step (PR 53), the plan's third quantity: where a head's whole
+  score matrix is ONE block in a direction's plan (no window, a K/V head a
+  query head) a grid step takes the most consecutive rows of the flattened
+  [B * H] axis, a divisor of it, whose working set fits the same share
+  (768 rows of 256 x 256 x 64 bf16: 16 a step forward, 12 backward), and
+  _flash_rows_kernel / _flash_bwd_rows_kernel compute each row whole: the
+  one block of a row is its first and its last, so the online-softmax
+  update from the floor is the plain softmax, bit for bit, and there is no
+  state to keep; the rows are laid out one after the other in the kernel,
+  and the scheduler runs one row's matmuls under another's softmax.  Same
+  operands, same masks (klen per row: a step's rows may cross a batch
+  row), same rounding.  On the chip 1.23 -> 0.50 ms a forward (0.56 with
+  the lse) and 1.51 -> 1.17 a backward at 768 x 256 x 64
+  (tools/flash_fwd_probe.py, tools/flash_bwd_probe.py --rows-per-step;
+  PERF.md PR 53).  Everywhere else it is 1 and the call is what it was.
 - under `causal`, a k-block wholly above the diagonal (bottom-right
   aligned) is neither fetched nor computed: its body is under pl.when, and
   the K/V index maps repeat the block already held, which the pipeline
@@ -82,19 +102,24 @@ from the call's shape and `window`, none from a flag:
   `flash.bwd_plan` says `chunks`; 1 is the kernel as it always ran.
 
 Backward selection is read from the shape in one place (_bwd_plan): the
-Pallas kernel where the plan's score block has at least 384 x 384 scores
-to spread a grid step's fixed cost over and the row's dQ, or a chunk's,
-fits VMEM (S >= 384), else jax.vjp of the reference formulation, a
+Pallas kernel where a grid step (the plan's score block times the
+batch-head rows the step takes, _rows_per_step) has at least 384 x 384
+scores to spread the step's fixed cost over and the row's dQ, or a
+chunk's, fits VMEM, else jax.vjp of the reference formulation, a
 recompute backward that XLA fuses, whose forward emits no lse; on a TPU a
 site that falls there with more than _XLA_BWD_MAX_SCORE_BYTES of fp32
-scores is refused at lowering.  At S 256 a head is one grid step: the
-kernel alone ties with XLA, and the lse its forward must then emit makes
-the pair 9% slower (the probe's table), so XLA keeps it.  No flag, no
+scores is refused at lowering.  The rows a call has to give are part of
+the shape the rule reads (_packable_rows): at S 256 a call of three or
+more batch-head rows is the Pallas pair's (transformer-base's 18 sites:
+forward + backward 1.57 ms a site where the Pallas forward with XLA's
+backward takes 1.70 and the pair of PR 30, one row a step, took 2.58),
+one of one or two rows, or with grouped K/V, stays XLA's.  No flag, no
 model name:
 force="interpret" keeps the Pallas backward at every shape (the CPU
 tests' door), force="jax" keeps none.  pallas_call instances are memoized
-by static config, blocks included, so every attention site of one shape
-(the 18 of a Transformer-base step are 3 shapes) shares one kernel payload.
+by static config, blocks and rows a step included, so every attention site
+of one shape (the 18 of a Transformer-base step are 3 shapes) shares one
+kernel payload.
 
 What survives the recomputation of the unit around a site (PR 44): where
 the backward is the Pallas kernel the forward's output and logsumexp
@@ -144,7 +169,7 @@ _STEP_COST_SCORES = 256 * 256
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
                    head_dim: int = 128, num_q_blocks: int = 1,
                    dtype="float32", emit_lse: bool = True,
-                   v_dim: int | None = None) -> int:
+                   v_dim: int | None = None, rows_per_step: int = 1) -> int:
     """Analytic VMEM footprint of the buffers ONE forward pallas invocation
     declares — the kernel's own statement of the linter's pricing model
     (paddle_tpu.analysis.pallas.kernel_vmem_bytes; tests hold the two
@@ -153,31 +178,38 @@ def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
     online-softmax scratch.  The SMEM klen vector is outside VMEM, and so
     are the score blocks the body computes: fwd_working_set_bytes adds
     those, and it is what _plan_blocks holds under its budget.  `v_dim`
-    is the width of V and O where it is not the head_dim of Q and K."""
+    is the width of V and O where it is not the head_dim of Q and K.  The
+    blocks are `rows_per_step` batch-head rows deep (_rows_per_step), and
+    a step of several declares no scratch (_flash_rows_kernel)."""
     v_dim = head_dim if v_dim is None else v_dim
     blocks = [
-        ((1, block_q, head_dim), dtype),   # q
-        ((1, block_k, head_dim), dtype),   # k
-        ((1, block_k, v_dim), dtype),      # v
-        ((1, block_q, v_dim), dtype),      # o
+        ((rows_per_step, block_q, head_dim), dtype),   # q
+        ((rows_per_step, block_k, head_dim), dtype),   # k
+        ((rows_per_step, block_k, v_dim), dtype),      # v
+        ((rows_per_step, block_q, v_dim), dtype),      # o
     ]
     if emit_lse:
-        blocks.append(((1, num_q_blocks, block_q), "float32"))
+        blocks.append(((rows_per_step, num_q_blocks, block_q), "float32"))
     scratch = [((block_q, 1), "float32"), ((block_q, 1), "float32"),
-               ((block_q, v_dim), "float32")]
+               ((block_q, v_dim), "float32")] if rows_per_step == 1 else []
     return (2 * sum(tile_padded_bytes(s, d) for s, d in blocks)
             + sum(tile_padded_bytes(s, d) for s, d in scratch))
 
 
 def fwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
-                          dtype="float32", emit_lse=True, v_dim=None) -> int:
+                          dtype="float32", emit_lse=True, v_dim=None,
+                          rows_per_step=1) -> int:
     """fwd_vmem_bytes plus what a grid step computes between its two
     matmuls: the fp32 score block and the fp32 probability block, each
     [block_q, block_k].  At 128 x 128 they are 128 KB and were never
     counted; at 512 x 512 they are 2 MB, more than every declared buffer
-    together, and they are what bounds the block plan."""
+    together, and they are what bounds the block plan.  A step of several
+    rows computes them one after the other, so the count holds one row's
+    pair and only the declared blocks grow with `rows_per_step` (Mosaic
+    compiles 32 rows of 256 x 256 x 64, 17 MB of blocks, where 32 pairs of
+    planes would be 16 MB more)."""
     return (fwd_vmem_bytes(block_q, block_k, head_dim, num_q_blocks, dtype,
-                           emit_lse, v_dim)
+                           emit_lse, v_dim, rows_per_step)
             + 2 * tile_padded_bytes((block_q, block_k), "float32"))
 
 
@@ -235,7 +267,8 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None,
 
 
 def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
-                          dtype="float32", v_dim=None) -> int:
+                          dtype="float32", v_dim=None,
+                          rows_per_step=1) -> int:
     """What one grid step of the backward kernel holds: the double-buffered
     q, dO, k, v blocks, the packed lse and D planes and the dK, dV and
     (whole-row) dQ blocks it writes, the fp32 accumulators of dK and dV and
@@ -243,21 +276,24 @@ def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
     its matmuls (scores and probabilities, dP, dS and the operand cast for
     the MXU) where the forward holds two.  The dQ row is what bounds the
     sequence: 2 MB of it at 2048 x 128 bf16, 8 MB of the 12 at 8192.
-    `v_dim` is the width of V, dO and dV where it is not Q's and K's."""
+    `v_dim` is the width of V, dO and dV where it is not Q's and K's.  As
+    in the forward, only the declared blocks grow with `rows_per_step`, and
+    a step of several rows has no accumulators (_flash_bwd_rows_kernel)."""
     def tile(shape, dt=dtype):
         return tile_padded_bytes(shape, dt)
 
     v_dim = head_dim if v_dim is None else v_dim
     rows = num_q_blocks * block_q
-    blocks = (tile((1, block_q, head_dim))              # q
-              + tile((1, block_q, v_dim))               # dO
-              + 2 * tile((1, block_k, head_dim))        # k, dK
-              + 2 * tile((1, block_k, v_dim))           # v, dV
-              + tile((1, rows, head_dim))               # dQ
-              + 2 * tile((1, num_q_blocks, block_q), "float32"))
+    n = rows_per_step
+    blocks = (tile((n, block_q, head_dim))              # q
+              + tile((n, block_q, v_dim))               # dO
+              + 2 * tile((n, block_k, head_dim))        # k, dK
+              + 2 * tile((n, block_k, v_dim))           # v, dV
+              + tile((n, rows, head_dim))               # dQ
+              + 2 * tile((n, num_q_blocks, block_q), "float32"))
     scratch = (tile((rows, head_dim), "float32")
                + tile((block_k, head_dim), "float32")
-               + tile((block_k, v_dim), "float32"))
+               + tile((block_k, v_dim), "float32")) if n == 1 else 0
     return (2 * blocks + scratch
             + 4 * tile((block_k, block_q), "float32"))
 
@@ -271,6 +307,25 @@ def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None,
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: bwd_working_set_bytes(
             bq, bk, head_dim, -(-sq // bq), dtype, v_dim), window)
+
+
+def _rows_per_step(bh, one_block, working_set):
+    """The batch-head rows ONE grid step takes, of a call's `bh` (B * H
+    where a step may take several; 1 where K and V are grouped).
+
+    Where a head's whole score matrix is one block (`one_block`: one
+    q-block, one k-block, no window), a step of one row is mostly what a
+    step costs before it computes anything (768 steps of 256 x 256 x 64:
+    PERF.md PR 53), so a step takes the most consecutive rows of the
+    flattened [B * H] axis, a divisor of it, whose `working_set(rows)`
+    bytes fit _PLAN_VMEM_BUDGET, and computes each whole
+    (_flash_rows_kernel, _flash_bwd_rows_kernel).  Everywhere else it is 1
+    and the call is built as it always was.  Like the blocks it reads the
+    shape, nothing else."""
+    if not one_block:
+        return 1
+    return next(n for n in range(bh, 0, -1) if bh % n == 0
+                and (n == 1 or working_set(n) <= _PLAN_VMEM_BUDGET))
 
 
 def _block_runs(qi, ki, block_q, block_k, causal_offset):
@@ -611,6 +666,99 @@ def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0, rows, :] = (dq_scr[rows, :] * scale).astype(dq_ref.dtype)
 
 
+def _rows_of_step(rows_per_step, row):
+    """Run row(r, batch-head row) for the `rows_per_step` rows of this grid
+    step: row r of the step's blocks is batch-head row program_id(0) *
+    rows_per_step + r, which is where its klen lies (a step's rows may cross
+    a batch row).  One traced body, laid out `rows_per_step` times in the
+    kernel (unroll=True): the rows share nothing, and in straight-line code
+    the scheduler runs one row's matmuls under another's softmax (as a
+    rolled loop the same body took 0.69 ms where this takes 0.50 at 768 x
+    256 x 64, PERF.md PR 53)."""
+    import jax.experimental.pallas as pl
+
+    first = pl.program_id(0) * rows_per_step
+
+    def body(r, carry):
+        row(r, first + r)
+        return carry
+
+    jax.lax.fori_loop(0, rows_per_step, body, 0, unroll=True)
+
+
+def _flash_rows_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref=None,
+                       *, causal, scale, seq_k, causal_offset, rows_per_step):
+    """_flash_kernel where a head's whole score matrix is ONE block
+    (_rows_per_step): grid (batch*heads / rows_per_step,), a step takes
+    `rows_per_step` batch-head rows and computes each whole.  A block that
+    is the first and the last of its row needs no state: the online-softmax
+    update from the floor (m = NEG_INF/2, l = 0, acc = 0) is the plain
+    softmax, bit for bit, so nothing is initialised, rescaled or read back
+    (at 768 steps of 256 x 256 x 64 that, and not the pipeline, was what a
+    step cost before it computed anything: 1.23 ms a call against 0.50).
+    Every row takes the mask (a select that changes nothing where nothing
+    cuts the block: a branch on klen, which is data, would keep the rows of
+    a step apart).  Rounding and masks are _flash_kernel's.  Without
+    `lse_ref` (no scratch follows the outputs, so it is simply absent) the
+    lse is not written, as in _flash_kernel_fwd_only."""
+    shape = q_ref.shape[1], k_ref.shape[1]
+
+    def _row(r, row):
+        q, k, v = q_ref[r], k_ref[r], v_ref[r]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(_block_mask(klen_ref, row, 0, 0, s.shape, *shape, seq_k,
+                                  causal, causal_offset), s, NEG_INF)
+        # the floor keeps a fully-masked row at p = 0, l = 0 (_flash_kernel)
+        m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), NEG_INF / 2)
+        p = jnp.exp(s - m)
+        l_fin = jnp.sum(p, axis=-1, keepdims=True)
+        acc = jnp.dot(p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+        o_ref[r] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        if lse_ref is not None:  # static
+            lse = jnp.where(l_fin > 0.0,
+                            m + jnp.log(jnp.maximum(l_fin, 1e-30)), -NEG_INF)
+            # the packed plane [B*H, 1, block_q]: a lane-dense row a head
+            lse_ref[r, 0, :] = jnp.transpose(lse, (1, 0))[0]
+
+    _rows_of_step(rows_per_step, _row)
+
+
+def _flash_bwd_rows_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                           dvec_ref, dq_ref, dk_ref, dv_ref, *, causal, scale,
+                           seq_k, causal_offset, rows_per_step):
+    """_flash_bwd_kernel where a head is one block, as _flash_rows_kernel
+    is the forward's: `rows_per_step` batch-head rows a step, each row's
+    five matmuls and one exp written straight to dQ, dK and dV (the sum of
+    one block needs no accumulator: 0 + x is x)."""
+    shape = q_ref.shape[1], k_ref.shape[1]
+
+    def _row(r, row):
+        q, k, do = q_ref[r], k_ref[r], do_ref[r]
+        nt = (((1,), (1,)), ((), ()))  # a @ b.T on the contracting dims
+        st = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[r, 0, :].reshape(1, -1))
+        pt = jnp.where(
+            _block_mask(klen_ref, row, 0, 0, st.shape, *shape, seq_k, causal,
+                        causal_offset, transposed=True), pt, 0.0)
+        dpt = jax.lax.dot_general(v_ref[r], do, nt,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - dvec_ref[r, 0, :].reshape(1, -1))).astype(q.dtype)
+        dv_ref[r] = jnp.dot(
+            pt.astype(do.dtype), do,
+            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+        dk_ref[r] = (jnp.dot(dst, q, preferred_element_type=jnp.float32)
+                     * scale).astype(dk_ref.dtype)
+        dq_ref[r] = (jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale).astype(dq_ref.dtype)
+
+    _rows_of_step(rows_per_step, _row)
+
+
 def _pad_seq(x, to):
     pad = (to - x.shape[2] % to) % to
     if pad:
@@ -644,7 +792,7 @@ def _window_k_steps(nqb, nkb, block_q, block_k, causal_offset, window):
 @functools.lru_cache(maxsize=128)
 def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
               causal_offset, dtype, interpret, emit_lse=True, dv=None,
-              window=None, group=1):
+              window=None, group=1, rows_per_step=1):
     """Memoized pallas_call: every attention site with the same static
     config reuses ONE traced callable, so XLA sees identical kernel
     payloads (compile-cache friendly) instead of per-site clones.
@@ -653,7 +801,10 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
     not Q's and K's `d`.  `group` query heads (consecutive rows of q) read
     one K/V head through the index maps: K and V come as [bh / group, skp,
     .] and are never repeated.  Under `window` the k-axis counts from
-    _first_k_block (see _flash_kernel)."""
+    _first_k_block (see _flash_kernel).  `rows_per_step` batch-head rows
+    make one block and one step of the first grid axis (_rows_per_step);
+    more than one are a head of one block each, which is
+    _flash_rows_kernel's to run."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -674,44 +825,58 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             b = b // group
         return (b, j, 0)
 
-    out_specs = [pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))]
+    n = rows_per_step
+    out_specs = [pl.BlockSpec((n, bq, dv), lambda b, i, j: (b, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, sqp, dv), jnp.dtype(dtype))]
     if emit_lse:
         # packed lse: one [nqb, bq] plane per batch-head row, revisited
         # across q/k steps and flushed when b advances
         out_specs.append(
-            pl.BlockSpec((1, nqb, bq), lambda b, i, j: (b, 0, 0)))
+            pl.BlockSpec((n, nqb, bq), lambda b, i, j: (b, 0, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, nqb, bq), jnp.float32))
     static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
                   seq_k=seq_k, causal_offset=causal_offset)
     if window is not None:
         static["window"] = window
+    scratch = [pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, dv), jnp.float32)]
+    if n > 1:   # a head is one block, computed whole: no state to keep
+        kernel = _flash_rows_kernel
+        static = dict(causal=causal, scale=scale, seq_k=seq_k,
+                      causal_offset=causal_offset, rows_per_step=n)
+        scratch = []
     return pl.pallas_call(
         functools.partial(kernel, **static),
-        grid=(bh, nqb, k_steps),
+        grid=(bh // n, nqb, k_steps),
         in_specs=[
             # whole [B*H] vector in SMEM, indexed by program_id(0) in-kernel
             # (TPU rejects rank-1 blocks smaller than the 128 tile)
             pl.BlockSpec((bh,), lambda b, i, j: (0,),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_block),
-            pl.BlockSpec((1, bk, dv), kv_block),
+            pl.BlockSpec((n, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((n, bk, d), kv_block),
+            pl.BlockSpec((n, bk, dv), kv_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, dv), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )
 
 
+def _packable_rows(q, k):
+    """The batch-head rows of a call that grid steps may take several of
+    (_rows_per_step): B * H, the flattened axis, where every query head has
+    a K/V head of its own; 1 where they are grouped, whose index maps send
+    consecutive rows to one K/V head."""
+    return q.shape[0] * q.shape[1] if k.shape[1] == q.shape[1] else 1
+
+
 def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
-                  interpret=False, need_lse=True, window=None):
+                  interpret=False, need_lse=True, window=None,
+                  rows_per_step=None):
     """Returns (out [B,H,Sq,Dv], lse [B*H, num_q_blocks, block_q] fp32
     per-row logsumexp in the PACKED residual layout — see the module
     comment; _pallas_flash_bwd re-cuts it to its own q-block).
@@ -719,8 +884,9 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     output entirely — its HBM write is pure waste when nothing consumes
     it — and returns (out, None).  k and v may have fewer heads than q
     ([B, G, Sk, .]: query head j reads head j // (H / G)).  The blocks come
-    from _plan_blocks; block_q / block_k pin them for a test or the probe,
-    never a model."""
+    from _plan_blocks and the batch-head rows a grid step takes from
+    _rows_per_step; block_q / block_k / rows_per_step pin them for a test or
+    the probe, never a model."""
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv,
@@ -738,15 +904,21 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
 
     nqb, nkb = qf.shape[1] // bq, kf.shape[1] // bk
     above, older = _skipped_steps(nqb, nkb, bq, bk, Sk - Sq, causal, window)
+    if rows_per_step is None:
+        rows_per_step = _rows_per_step(
+            _packable_rows(q, k), nqb == nkb == 1 and window is None,
+            lambda n: fwd_working_set_bytes(bq, bk, D, 1, q.dtype, need_lse,
+                                            Dv, n))
     # at lowering, as recurrence.lower: static counts over one (b, h)
     with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=bq,
               block_k=bk, k_steps=nqb * nkb, k_steps_skipped=above + older,
               causal=int(causal), window=int(window or 0), kv_heads=G,
-              chunks=1, skipped_causal=above, skipped_window=older):
+              chunks=1, skipped_causal=above, skipped_window=older,
+              rows_per_step=rows_per_step):
         call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
                          scale, Sk, Sk - Sq, str(q.dtype), interpret,
                          emit_lse=need_lse, dv=Dv, window=window,
-                         group=H // G)
+                         group=H // G, rows_per_step=rows_per_step)
         res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
     out = res[0].reshape(B, H, res[0].shape[1], Dv)
     if out.shape[2] != Sq:
@@ -777,7 +949,7 @@ def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb,
 @functools.lru_cache(maxsize=128)
 def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
               causal_offset, q_dtype, k_dtype, v_dtype, interpret, dv=None,
-              window=None, group=1):
+              window=None, group=1, rows_per_step=1):
     """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call.  With
     `group` > 1 K and V come as [bh / group, skp, .] and are read through
     the index maps; dK and dV leave a query head each, [bh, skp, .], and
@@ -787,11 +959,12 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
     nqb = sqp // bq
     dv = d if dv is None else dv
+    n = rows_per_step
     # packed lse/dvec residuals: the whole (tiny) [nqb, bq] plane for
     # batch-head row b rides in VMEM; the kernel reads its q-block's row
-    packed = pl.BlockSpec((1, nqb, bq), lambda b, j, i: (b, 0, 0))
-    k_block = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
-    v_block = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
+    packed = pl.BlockSpec((n, nqb, bq), lambda b, j, i: (b, 0, 0))
+    k_block = pl.BlockSpec((n, bk, d), lambda b, j, i: (b, j, 0))
+    v_block = pl.BlockSpec((n, bk, dv), lambda b, j, i: (b, j, 0))
     k_in, v_in = k_block, v_block
     if group > 1:
         k_in = pl.BlockSpec((1, bk, d), lambda b, j, i: (b // group, j, 0))
@@ -802,26 +975,35 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             i = _q_block_index(i, j, bq, bk, causal_offset, nqb, window)
         return (b, i, 0)
 
+    kernel = _flash_bwd_kernel
     static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
                   seq_k=seq_k, causal_offset=causal_offset)
     if window is not None:
         static["window"] = window
+    scratch = [pltpu.VMEM((sqp, d), jnp.float32),
+               pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, dv), jnp.float32)]
+    if n > 1:   # as in _fwd_call
+        kernel = _flash_bwd_rows_kernel
+        static = dict(causal=causal, scale=scale, seq_k=seq_k,
+                      causal_offset=causal_offset, rows_per_step=n)
+        scratch = []
     return pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, **static),
-        grid=(bh, skp // bk, nqb),
+        functools.partial(kernel, **static),
+        grid=(bh // n, skp // bk, nqb),
         in_specs=[
             pl.BlockSpec((bh,), lambda b, j, i: (0,),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), q_of_kv),
+            pl.BlockSpec((n, bq, d), q_of_kv),
             k_in,
             v_in,
-            pl.BlockSpec((1, bq, dv), q_of_kv),
+            pl.BlockSpec((n, bq, dv), q_of_kv),
             packed,
             packed,
         ],
         out_specs=[
             # dQ: one block a batch-head row, written back when b advances
-            pl.BlockSpec((1, sqp, d), lambda b, j, i: (b, 0, 0)),
+            pl.BlockSpec((n, sqp, d), lambda b, j, i: (b, 0, 0)),
             k_block,
             v_block,
         ],
@@ -830,11 +1012,7 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             jax.ShapeDtypeStruct((bh, skp, d), jnp.dtype(k_dtype)),
             jax.ShapeDtypeStruct((bh, skp, dv), jnp.dtype(v_dtype)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((sqp, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )
 
@@ -853,7 +1031,7 @@ def _repack(plane, sq, block_q, fill):
 
 
 def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
-              bq, bk, window, interpret):
+              bq, bk, window, interpret, rows_per_step=1):
     """(dq, dk, dv) of the queries q [B, H, Sq, D] (with their out, dO `g`
     and packed lse) over the keys k, v [B, G, Sk, .] by ONE call of
     _flash_bwd_kernel: a whole row, or one trip of _pallas_flash_bwd's loop
@@ -889,7 +1067,8 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
 
     call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk,
                      causal_offset, str(q.dtype), str(k.dtype), str(v.dtype),
-                     interpret, dv=Dv, window=window, group=H // G)
+                     interpret, dv=Dv, window=window, group=H // G,
+                     rows_per_step=rows_per_step)
     dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
 
     dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
@@ -903,9 +1082,10 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
 
 def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
                       block_q=None, block_k=None, interpret=False,
-                      window=None, chunk=None):
+                      window=None, chunk=None, rows_per_step=None):
     """(dq, dk, dv) by _flash_bwd_kernel at the backward's own plan
-    (_bwd_plan; block_q / block_k / chunk pin it for a test or the probe).
+    (_bwd_plan; block_q / block_k / chunk / rows_per_step pin it for a test
+    or the probe).
     `lse` is the forward's packed plane, whatever q-block laid it out.
 
     Where the row's dQ fits VMEM the row is one call, as it always was.
@@ -916,15 +1096,20 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     are added, in fp32, into the sequence's."""
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    bh = _packable_rows(q, k)
     trips = _bwd_trips(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                       window, chunk)
+                       window, chunk, bh)
     plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                          window, chunk), engine="pallas", kv_heads=G)
+                          window, chunk, bh),
+                engine="pallas", kv_heads=G)
+    if rows_per_step is not None:
+        plan["rows_per_step"] = rows_per_step
     with span("flash.bwd_plan", **plan):  # at lowering, as flash.plan
         if len(trips) == 1:
             _, _, _, _, bq, bk = trips[0]
             dq, dk, dv = _bwd_rows(q, k, v, klen, out, lse, g, causal, scale,
-                                   Sk - Sq, bq, bk, window, interpret)
+                                   Sk - Sq, bq, bk, window, interpret,
+                                   plan["rows_per_step"])
             if G != H:
                 dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
             return dq, dk, dv
@@ -961,11 +1146,12 @@ def _use_pallas(force: str) -> bool:
 
 
 # The backward's engine is read from the shape: the Pallas kernel where
-# the plan's score block [block_k, block_q] has at least this many scores
-# over which to spread what a grid step costs before it computes anything
-# (and the row's dQ, or a chunk's, fits VMEM at all), the XLA recompute
-# backward elsewhere.  Settled on the chip by tools/flash_bwd_probe.py
-# (PERF.md, PR 30).
+# a grid step (its score block [block_k, block_q], times the batch-head
+# rows the step takes) has at least this many scores over which to spread
+# what a step costs before it computes anything (and the row's dQ, or a
+# chunk's, fits VMEM at all), the XLA recompute backward elsewhere.
+# Settled on the chip by tools/flash_bwd_probe.py (PERF.md, PR 30; the
+# step's rows PR 53).
 _BWD_PALLAS_MIN_BLOCK_SCORES = 384 * 384
 
 # The XLA recompute backward materialises a site's fp32 scores, [B, H, Sq,
@@ -986,25 +1172,38 @@ def _chunk_keys(q0, q1, causal_offset, sk, causal, window):
     return min(k0, max(k1 - 1, 0)), max(k1, 1)
 
 
+def _bwd_rows_per_step(bh, sq, sk, block_q, block_k, head_dim, dtype, v_dim,
+                       window):
+    """_rows_per_step of a backward call of one trip at these blocks."""
+    return _rows_per_step(
+        bh, sq <= block_q and sk <= block_k and window is None,
+        lambda n: bwd_working_set_bytes(block_q, block_k, head_dim, 1, dtype,
+                                        v_dim, n))
+
+
 @functools.lru_cache(maxsize=128)
-def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window):
+def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window, bh=1):
     """(rows of queries a call of the backward kernel takes, engine).  The
-    whole row where its dQ fits VMEM beside blocks worth a grid step, as it
+    whole row where its dQ fits VMEM beside a grid step worth taking (of the
+    call's `bh` packable batch-head rows a step may take several,
+    _rows_per_step, and it is the step's scores that count), as it
     always was.  Else the longest cut of the row
     (_block_lengths) whose plan fits with a score block worth a grid step;
     under `window` the shortest such cut that is no shorter than the window,
     since a longer chunk only adds k-blocks its q-blocks skip and a shorter
     one blocks that straddle the window's edge.  Where no cut does, the row
     stays whole and the engine is XLA's."""
-    def plan(rows, keys):
+    def plan(rows, keys, bh=1):
         bq, bk = _plan_bwd_blocks(rows, keys, head_dim, dtype, causal, v_dim,
                                   window)
         fits = bwd_working_set_bytes(
             bq, bk, head_dim, -(-rows // bq), dtype, v_dim
         ) <= _PLAN_VMEM_BUDGET
-        return fits, bq * bk >= _BWD_PALLAS_MIN_BLOCK_SCORES
+        step = bq * bk * _bwd_rows_per_step(bh, rows, keys, bq, bk, head_dim,
+                                            dtype, v_dim, window)
+        return fits, step >= _BWD_PALLAS_MIN_BLOCK_SCORES
 
-    fits, worth = plan(sq, sk)
+    fits, worth = plan(sq, sk, bh)
     if fits and worth:
         return sq, "pallas"
     cuts = []
@@ -1022,13 +1221,14 @@ def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window):
 
 
 def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-               v_dim=None, window=None, chunk=None):
+               v_dim=None, window=None, chunk=None, bh=1):
     """[(q0, q1, k0, k1, block_q, block_k)]: the calls of the backward
     kernel that one site makes, one where the row is whole.  `chunk` pins
     the rows a trip, `block_q` / `block_k` the blocks (a test or the
     probe), else _bwd_chunk_rows and each trip's own _plan_bwd_blocks."""
     rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
-                            window)[0] if chunk is None else min(chunk, sq))
+                            window, bh)[0] if chunk is None
+            else min(chunk, sq))
     trips = []
     for q0 in range(0, sq, rows):
         q1 = min(q0 + rows, sq)
@@ -1043,29 +1243,34 @@ def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
 
 
 def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-              v_dim=None, window=None, chunk=None):
+              v_dim=None, window=None, chunk=None, bh=1):
     """What the backward of one attention call of this shape is given, the
     `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
     the probe, else _plan_bwd_blocks'; the last trip's where there are
     several), chunks (the outer loop's trips, 1 where the row is whole),
     steps, steps_skipped and its two parts skipped_causal and
-    skipped_window (static, over one batch-head row, all trips) and engine,
-    "pallas" or "xla": the one place that says which."""
+    skipped_window (static, over one batch-head row, all trips),
+    rows_per_step (the batch-head rows a grid step takes, of the call's `bh`
+    packable ones, _packable_rows: part of the shape) and engine, "pallas"
+    or "xla": the one place that says which."""
     engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
-                             window)[1]
+                             window, bh)[1]
     trips = _bwd_trips(sq, sk, head_dim, dtype, causal, block_q, block_k,
-                       v_dim, window, chunk)
+                       v_dim, window, chunk, bh)
     steps = above = older = 0
     for q0, q1, k0, k1, bq, bk in trips:
         nqb, nkb = -(-(q1 - q0) // bq), -(-(k1 - k0) // bk)
         a, o = _skipped_steps(nqb, nkb, bq, bk, q0 + (sk - sq) - k0, causal,
                               window)
         steps, above, older = steps + nqb * nkb, above + a, older + o
+    rows_per_step = 1 if len(trips) > 1 else _bwd_rows_per_step(
+        bh, sq, sk, trips[0][4], trips[0][5], head_dim, dtype, v_dim, window)
     return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=trips[-1][4],
                 block_k=trips[-1][5], steps=steps,
                 steps_skipped=above + older, engine=engine,
                 window=int(window or 0), chunks=len(trips),
-                skipped_causal=above, skipped_window=older)
+                skipped_causal=above, skipped_window=older,
+                rows_per_step=rows_per_step)
 
 
 def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
@@ -1076,7 +1281,8 @@ def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
         return True
     return _use_pallas(force) and _bwd_plan(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
-        v_dim=v.shape[3], window=window)["engine"] == "pallas"
+        v_dim=v.shape[3], window=window,
+        bh=_packable_rows(q, k))["engine"] == "pallas"
 
 
 def _forward(q, k, v, klen, causal, scale, force, need_lse, window=None):
@@ -1160,7 +1366,8 @@ def _flash_bwd(causal, scale, force, window, res, g):
             # at lowering, beside flash.plan: the site keeps the XLA engine
             with span("flash.bwd_plan", **_bwd_plan(
                     q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
-                    v_dim=v.shape[3], window=window), kv_heads=k.shape[1]):
+                    v_dim=v.shape[3], window=window,
+                    bh=_packable_rows(q, k)), kv_heads=k.shape[1]):
                 pass
         # recompute-backward: differentiate the reference formulation
         _, vjp = jax.vjp(

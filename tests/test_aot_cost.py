@@ -358,13 +358,16 @@ _MAIN_PATH_KERNELS = {
     "sparse_attention_bwd_pallas_keye": _sparse_attention,
     "flash_bwd_pallas_moonlight_192_128": _flash_mla,
     "held_experts_moonlight": _held_experts,
-    "flash_fwd_transformer_base": lambda: _flash_fwd((32, 8, 256, 64)),
+    "flash_fwd_transformer_base": lambda: _flash_fwd((96, 8, 256, 64)),
     "flash_fwd_long_context": lambda: _flash_fwd((2, 8, 2048, 64)),
     "flash_fwd_ouro": lambda: _flash_fwd((2, 16, 2048, 128)),
     # S 2048, head 128: the Pallas backward at its planned blocks (a VMEM
-    # refusal shows here, before the chip); S 256: the XLA backward
+    # refusal shows here, before the chip); S 256 at the cell's 96 x 8
+    # rows: the pair that takes 16 and 12 rows a grid step (PR 53); one
+    # row of S 256: the XLA backward
     "flash_bwd_pallas_ouro": lambda: _flash_bwd((2, 16, 2048, 128)),
-    "flash_bwd_xla_transformer_base": lambda: _flash_bwd((32, 8, 256, 64)),
+    "flash_bwd_pallas_transformer_base": lambda: _flash_bwd((96, 8, 256, 64)),
+    "flash_bwd_xla_one_row_s256": lambda: _flash_bwd((1, 1, 256, 64)),
     "paged_decode_bf16_ps16": lambda: _paged(jnp.bfloat16, 16),
     "paged_decode_int8_ps32": lambda: _paged(jnp.int8, 32),
     "paged_decode_two_level_tables": lambda: _paged(jnp.bfloat16, 16,

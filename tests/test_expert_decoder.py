@@ -369,7 +369,7 @@ def test_flash_plans_at_the_cells_attention_shape():
     assert plan == dict(sq=2048, sk=2048, head_dim=192, block_q=512,
                         block_k=512, steps=16, steps_skipped=6,
                         engine="pallas", window=0, chunks=1,
-                        skipped_causal=6, skipped_window=0)
+                        skipped_causal=6, skipped_window=0, rows_per_step=1)
     # v padded to 192 would plan 1024 x 256, as ISSUE 31 read chip-less
     padded = fa._bwd_plan(*args)
     assert (padded["block_q"], padded["block_k"], padded["engine"]) == \
@@ -441,17 +441,21 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
         2048, 2048, 192, jnp.bfloat16, True, v_dim=128), kv_heads=2)]
 
 
-@pytest.mark.parametrize("S, kept", [(512, "out,lse"), (256, "")])
+@pytest.mark.parametrize("S, heads, kept", [
+    (512, 2, "out,lse"), (256, 2, "out,lse"), (256, 1, "")])
 def test_mla_lower_says_what_a_site_keeps_through_its_layers_recomputation(
-        S, kept):
+        S, heads, kept):
     """The value's own width in `kept_bytes` (out is [B, H, S, v_dim]); a
-    site the shape leaves on the XLA recompute backward keeps nothing;
-    `recurrence.lower` counts the values, a layer a one-site body."""
+    site the shape leaves on the XLA recompute backward keeps nothing (S 256
+    at two batch-head rows in all; at four a grid step takes them all and
+    the backward is the Pallas kernel's, PR 53); `recurrence.lower` counts
+    the values, a layer a one-site body."""
     spans = _spans_of_a_step(
         ("mla.lower", "flash.bwd_plan", "recurrence.lower"), max_length=S,
-        n_layer=2, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=24)
+        n_layer=2, n_head=heads, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=24)
     assert [(s["kept"], s["kept_bytes"]) for s in spans["mla.lower"]] == \
-        2 * [(kept, 2 * 2 * S * (24 * 2 + 4) if kept else 0)]
+        2 * [(kept, 2 * heads * S * (24 * 2 + 4) if kept else 0)]
     assert {b["engine"] for b in spans["flash.bwd_plan"]} == {
         "pallas" if kept else "xla"}
     assert [r["kept"] for r in spans["recurrence.lower"]] == 2 * [

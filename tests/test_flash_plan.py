@@ -2,8 +2,12 @@
 _plan_blocks, causal blocks above the diagonal neither fetched nor computed,
 the mask only where a block can be cut.  Everything here runs the kernel
 through the Pallas interpreter on the CPU: values and counts, never times.
+(d) below: several batch-head rows a grid step where a head is one block
+(PR 53), in both kernels, and the engine rule that now counts the step's
+scores.
 """
 
+import functools
 import importlib
 import itertools
 
@@ -29,14 +33,17 @@ def _qkv(seed, B, H, Sq, Sk, D, dtype):
 
 def _spans(name, fn, *args):
     """The args of the spans called `name` that lowering `fn` leaves
-    (abstractly: nothing compiles or runs)."""
+    (abstractly: nothing compiles or runs); of each of them, by name, where
+    `name` is a tuple."""
     observability.reset()
     was = fluid.flags._VALUES["FLAGS_observability"]
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
         jax.eval_shape(fn, *args)
-        return [s.args for s in observability.default_tracer().spans()
-                if s.name == name]
+        spans = observability.default_tracer().spans()
+        if isinstance(name, tuple):
+            return {n: [s.args for s in spans if s.name == n] for n in name}
+        return [s.args for s in spans if s.name == name]
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = was
         observability.reset()
@@ -194,7 +201,7 @@ def test_skipped_count_in_the_span_equals_the_dense_count():
                               k_steps_skipped=int((~visible).sum()),
                               causal=1, window=0, kv_heads=1, chunks=1,
                               skipped_causal=int((~visible).sum()),
-                              skipped_window=0)]
+                              skipped_window=0, rows_per_step=1)]
 
 
 @pytest.mark.parametrize("causal,pin,k_steps,skipped", [
@@ -342,9 +349,10 @@ def test_backward_kernels_match_the_reference_vjp(case):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gradient_through_the_custom_vjp_matches_reference(dtype):
     """force="interpret" keeps the Pallas backward at every shape, here one
-    the rule would give to XLA; force="jax" keeps none."""
+    the rule would give to XLA (two rows a step are not worth one);
+    force="jax" keeps none."""
     B, H, S, D = 1, 2, 256, 64
-    assert fa._bwd_plan(S, S, D, dtype, True)["engine"] == "xla"
+    assert fa._bwd_plan(S, S, D, dtype, True, bh=B * H)["engine"] == "xla"
     q, k, v = _qkv(11, B, H, S, S, D, dtype)
     klen = jnp.asarray([200.0])
     w = jnp.asarray(np.random.RandomState(12).randn(B, H, S, D), jnp.float32)
@@ -431,7 +439,8 @@ def test_bwd_plan_span_counts_equal_the_dense_count(sq, sk, bq, bk):
         sq=sq, sk=sk, head_dim=8, block_q=bq, block_k=bk,
         steps=visible.size, steps_skipped=int((~visible).sum()),
         engine="pallas", window=0, chunks=1, kv_heads=1,
-        skipped_causal=int((~visible).sum()), skipped_window=0)]
+        skipped_causal=int((~visible).sum()), skipped_window=0,
+        rows_per_step=1)]
 
 
 def test_repack_is_a_view_where_the_padded_lengths_agree():
@@ -471,36 +480,54 @@ def test_bwd_plan_is_tiled_inside_its_share(sq, sk, d, dtype, causal):
     assert bq * bk <= fwd[0] * fwd[1]
 
 
-# (B*H is not the rule's to read) name: (Sq, Sk, D, dtype, causal, engine)
+# (since PR 53 the batch-head rows a step can take are part of the shape
+# the rule reads) name: (B, H, Sq, Sk, D, dtype, causal, engine,
+# rows_per_step): the cells' B and H, which the lowering test below cuts
 ENGINE_BY_SHAPE = {
-    "ouro_2.6b_self": (2048, 2048, 128, "bfloat16", True, "pallas"),
-    "transformer_base_decoder_self": (256, 256, 64, "bfloat16", True, "xla"),
-    "transformer_base_encoder_self": (256, 256, 64, "bfloat16", False,
-                                      "xla"),
-    "transformer_base_cross": (256, 256, 64, "bfloat16", False, "xla"),
+    "ouro_2.6b_self": (2, 16, 2048, 2048, 128, "bfloat16", True, "pallas",
+                       1),
+    "transformer_base_decoder_self": (96, 8, 256, 256, 64, "bfloat16", True,
+                                      "pallas", 12),
+    "transformer_base_encoder_self": (96, 8, 256, 256, 64, "bfloat16", False,
+                                      "pallas", 12),
+    "transformer_base_cross": (96, 8, 256, 256, 64, "bfloat16", False,
+                               "pallas", 12),
+    # one batch-head row at the same S: a step is one 256 x 256 block, and
+    # two rows of it are not yet 384 x 384 scores
+    "s256_one_row": (1, 1, 256, 256, 64, "bfloat16", True, "xla", 1),
+    "s256_two_rows": (1, 2, 256, 256, 64, "bfloat16", True, "xla", 2),
+    "s256_three_rows": (3, 1, 256, 256, 64, "bfloat16", True, "pallas", 3),
 }
 
 
 @pytest.mark.parametrize("case", sorted(ENGINE_BY_SHAPE))
 def test_engine_is_read_from_the_shape(case):
-    sq, sk, d, dtype, causal, engine = ENGINE_BY_SHAPE[case]
-    plan = fa._bwd_plan(sq, sk, d, dtype, causal)
-    assert plan["engine"] == engine
-    assert fa._bwd_plan(sq, sk, d, dtype, causal) == plan   # a pure function
+    B, H, sq, sk, d, dtype, causal, engine, rows = ENGINE_BY_SHAPE[case]
+    plan = fa._bwd_plan(sq, sk, d, dtype, causal, bh=B * H)
+    assert (plan["engine"], plan["rows_per_step"]) == (engine, rows)
+    assert fa._bwd_plan(sq, sk, d, dtype, causal, bh=B * H) == plan  # pure
     assert (plan["block_q"], plan["block_k"]) == fa._plan_bwd_blocks(
         sq, sk, d, dtype, causal)
+    # the step's scores are what the rule weighs
+    assert (rows * plan["block_q"] * plan["block_k"]
+            >= fa._BWD_PALLAS_MIN_BLOCK_SCORES) == (engine == "pallas")
 
 
 @pytest.mark.parametrize("case", sorted(ENGINE_BY_SHAPE))
 def test_lowered_tpu_text_carries_the_backward_kernels_by_shape(case):
     """What a TPU program gets (flags.tpu_trace_scope, force="auto"): the
     forward kernel at every shape; the backward kernel's custom call at the
-    ouro-2.6b shape and at none of transformer-base's three."""
-    sq, sk, d, dtype, causal, engine = ENGINE_BY_SHAPE[case]
+    ouro-2.6b shape and, since a step takes several rows, at
+    transformer-base's three (cut to B 2, H 2: four rows a step), but not
+    where the call has one or two batch-head rows in all."""
+    B, H, sq, sk, d, dtype, causal, engine, _ = ENGINE_BY_SHAPE[case]
+    B, H = min(B, 2), min(H, 2)
+    if case == "s256_three_rows":
+        B = 3
     ragged = "encoder" in case or "cross" in case
-    q = jax.ShapeDtypeStruct((2, 2, sq, d), jnp.dtype(dtype))
-    kv = jax.ShapeDtypeStruct((2, 2, sk, d), jnp.dtype(dtype))
-    klen = jax.ShapeDtypeStruct((2,), jnp.float32)
+    q = jax.ShapeDtypeStruct((B, H, sq, d), jnp.dtype(dtype))
+    kv = jax.ShapeDtypeStruct((B, H, sk, d), jnp.dtype(dtype))
+    klen = jax.ShapeDtypeStruct((B,), jnp.float32)
 
     def loss(q, k, v, klen):
         o = fa.flash_attention(q, k, v, causal=causal,
@@ -512,7 +539,196 @@ def test_lowered_tpu_text_carries_the_backward_kernels_by_shape(case):
         text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).trace(
             q, kv, kv, klen).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == (2 if engine == "pallas" else 1)
-    assert ("_flash_bwd_kernel" in text) == (engine == "pallas")
+    # several rows a step are _flash_bwd_rows_kernel's, one _flash_bwd_kernel's
+    rows = fa._bwd_plan(sq, sk, d, dtype, causal, bh=B * H)["rows_per_step"]
+    assert ("_flash_bwd_rows_kernel" in text) == (
+        engine == "pallas" and rows > 1)
+    assert ("_flash_bwd_kernel" in text) == (engine == "pallas" and rows == 1)
+    assert ("_flash_rows_kernel" in text) == (B * H > 1 and sq == 256)
+
+
+# (d) several batch-head rows a grid step (PR 53) ---------------------------
+
+# name: (B, H, dtype, causal, k_lengths, the forward's rows a step, the
+# backward's), all at [B, H, 256, 64]: a head is one 256 x 256 block
+PACKED_CASES = {
+    "causal": (2, 4, jnp.bfloat16, True, None, 8, 8),
+    # a step's six rows cross three batch rows, each with its own klen: at
+    # the end of the keys, inside the block, and a single key
+    "ragged_rows_cross_batch_rows": (3, 2, jnp.bfloat16, False,
+                                     [256, 100, 1], 6, 6),
+    # a batch row with no key at all, beside rows that have them
+    "fully_masked_row": (3, 2, jnp.float32, True, [256, 0, 77], 6, 6),
+    # 26 rows: 26 do not fit a step; the forward falls to 13, the backward
+    # (four score planes, seven blocks a row) past 13 to 2
+    "no_divisor_at_the_most_that_fits": (13, 2, jnp.bfloat16, False,
+                                         [256] * 6 + [131] * 7, 13, 2),
+    # 23 rows, a prime past what fits: down to 1, the kernel as it always ran
+    "prime_rows_fall_to_one": (23, 1, jnp.bfloat16, True, None, 1, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_rows_of_a_step_match_the_reference_forward_and_backward(case):
+    """The kernels at the rows a step the plan gives against
+    _reference_attention and jax.vjp of it, and against the same kernels
+    held to one row a step: the loop changes no value."""
+    B, H, dtype, causal, lengths, fwd_rows, bwd_rows = PACKED_CASES[case]
+    S, D = 256, 64
+    q, k, v = _qkv(53, B, H, S, S, D, dtype)
+    g = jnp.asarray(np.random.RandomState(54).randn(B, H, S, D), dtype)
+    klen = jnp.asarray(lengths if lengths is not None else [S] * B,
+                       jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+
+    def fwd(q, k, v, klen, **pins):
+        return fa._pallas_flash(q, k, v, klen, causal, scale, interpret=True,
+                                **pins)
+
+    def bwd(q, k, v, klen, out, lse, **pins):
+        return fa._pallas_flash_bwd(q, k, v, klen, out, lse, g, causal,
+                                    scale, interpret=True, **pins)
+
+    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v, klen)]
+    assert [p["rows_per_step"] for p in _plan_spans(fwd, *shapes)] == \
+        [fwd_rows]
+    out, lse = fwd(q, k, v, klen)
+    assert [p["rows_per_step"] for p in _bwd_spans(
+        bwd, *shapes, out, lse)] == [bwd_rows]
+    assert fa._bwd_plan(S, S, D, dtype, causal, bh=B * H)[
+        "rows_per_step"] == bwd_rows
+    grads = bwd(q, k, v, klen, out, lse)
+
+    out1, lse1 = fwd(q, k, v, klen, rows_per_step=1)
+    np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                  np.asarray(out1.astype(jnp.float32)))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse1))
+    for x, x1 in zip(grads, bwd(q, k, v, klen, out, lse, rows_per_step=1)):
+        np.testing.assert_array_equal(np.asarray(x.astype(jnp.float32)),
+                                      np.asarray(x1.astype(jnp.float32)))
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = fa._reference_attention(*f32, causal, scale,
+                                   k_lengths=klen.astype(jnp.int32))
+    tol = _tol(dtype, dict(rtol=2e-4, atol=2e-5))
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(want), **tol)
+    for name, x, w in zip("qkv", grads, _reference_grads(
+            q, k, v, g, klen, causal, scale)):
+        assert x.dtype == dtype and x.shape == w.shape
+        np.testing.assert_allclose(np.asarray(x.astype(jnp.float32)),
+                                   np.asarray(w), err_msg="d" + name, **tol)
+    if lengths is not None and 0 in lengths:
+        row = lengths.index(0)
+        assert not np.any(np.asarray(out)[row])
+        assert not any(np.any(np.asarray(x)[row]) for x in grads)
+
+
+@pytest.mark.parametrize("bh,fwd_rows,bwd_rows", [
+    (768, 16, 12),      # transformer-base: 96 x 8
+    (96, 16, 12), (24, 12, 12), (22, 22, 11), (26, 13, 2), (23, 1, 1),
+    (7, 7, 7), (1, 1, 1)])
+def test_rows_of_a_step_are_the_most_that_fit_and_divide(bh, fwd_rows,
+                                                         bwd_rows):
+    """At 256 x 256 x 64 bf16: the largest divisor of B * H whose working
+    set is inside the plan's share, down to 1; the declared blocks grow with
+    the rows, the fp32 score planes do not, and a step of several rows has
+    no scratch."""
+    def fwd(n):
+        return fa.fwd_working_set_bytes(256, 256, 64, 1, "bfloat16", True,
+                                        None, n)
+
+    def bwd(n):
+        return fa.bwd_working_set_bytes(256, 256, 64, 1, "bfloat16", None, n)
+
+    assert fa._rows_per_step(bh, True, fwd) == fwd_rows
+    assert fa._rows_per_step(bh, True, bwd) == bwd_rows
+    assert fa._rows_per_step(bh, False, fwd) == 1    # several blocks a head
+    for ws, rows in ((fwd, fwd_rows), (bwd, bwd_rows)):
+        assert bh % rows == 0
+        assert rows == 1 or ws(rows) <= fa._PLAN_VMEM_BUDGET
+        assert all(ws(n) > fa._PLAN_VMEM_BUDGET
+                   for n in range(rows + 1, bh + 1) if bh % n == 0)
+        assert ws(3) - ws(2) == ws(4) - ws(3) > 0
+    assert fwd(1) == fa.fwd_working_set_bytes(256, 256, 64, 1, "bfloat16",
+                                              True)
+    assert bwd(1) == fa.bwd_working_set_bytes(256, 256, 64, 1, "bfloat16")
+    # a row more is a row of the declared blocks more, and nothing else
+    assert fwd(3) - fwd(2) == fa.fwd_vmem_bytes(
+        256, 256, 64, 1, "bfloat16", True, None, 3) - fa.fwd_vmem_bytes(
+            256, 256, 64, 1, "bfloat16", True, None, 2) == fa.fwd_vmem_bytes(
+                256, 256, 64, 1, "bfloat16", True, None, 2) // 2
+
+
+@pytest.mark.parametrize("what", ["window", "grouped", "two_k_blocks",
+                                  "two_q_blocks"])
+def test_a_step_takes_one_row_where_a_head_is_not_one_plain_block(what):
+    """A window, grouped K/V or a second block in either direction: one row
+    a step, the call as it always was, in both kernels."""
+    B, H, S, D = 2, 4, 256, 64
+    G = 2 if what == "grouped" else H
+    window = 100 if what == "window" else None
+    pins = {"two_k_blocks": dict(block_k=128),
+            "two_q_blocks": dict(block_q=128)}.get(what, {})
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.float32)
+    kv = jax.ShapeDtypeStruct((B, G, S, D), jnp.float32)
+    klen = jax.ShapeDtypeStruct((B,), jnp.float32)
+
+    def both(q, k, v, klen):
+        out, lse = fa._pallas_flash(q, k, v, klen, True, 0.125,
+                                    interpret=True, window=window, **pins)
+        return fa._pallas_flash_bwd(q, k, v, klen, out, lse, q, True, 0.125,
+                                    interpret=True, window=window, **pins)
+
+    # (a lambda each: jax keeps the trace of a function it has seen)
+    assert [p["rows_per_step"] for p in _plan_spans(
+        lambda *a: both(*a), q, kv, kv, klen)] == [1]
+    assert [p["rows_per_step"] for p in _bwd_spans(
+        lambda *a: both(*a), q, kv, kv, klen)] == [1]
+
+
+# name: (B, H, G, S, D, Dv, window) of a decoder cell's attention, then what
+# the parent commit (PR 52) planned there: the forward's blocks, the
+# backward's, its chunks and its engine
+DECODER_CELL_PLANS = {
+    "ouro_s2048_head128": (
+        (2, 16, 16, 2048, 128, 128, None), (1024, 1024), (512, 512), 1),
+    "moonlight_s2048_head192_128": (
+        (4, 16, 16, 2048, 192, 128, None), (512, 1024), (512, 512), 1),
+    "keye_and_mellum_s16384_full_32_on_4": (
+        (1, 32, 4, 16384, 128, 128, None), (1024, 1024), (512, 512), 4),
+    "mellum_s16384_window1024_32_on_4": (
+        (1, 32, 4, 16384, 128, 128, 1024), (512, 512), (512, 512), 16),
+    "zaya_s16384_8_on_2": (
+        (1, 8, 2, 16384, 128, 128, None), (1024, 1024), (512, 512), 4),
+    "kimi_s4096_head192_128": (
+        (1, 32, 32, 4096, 192, 128, None), (512, 1024), (512, 512), 2),
+    "xing_s4096_head192_128_four_held_heads": (
+        (1, 4, 4, 4096, 192, 128, None), (512, 1024), (512, 512), 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODER_CELL_PLANS))
+def test_the_decoder_cells_plans_are_the_parents(case):
+    """Several blocks a head, a window, grouped K/V or chunks: one row a
+    step and the blocks, chunks and engine these shapes were given before a
+    step could take several rows."""
+    (B, H, G, S, D, Dv, window), fwd_blocks, bwd_blocks, chunks = \
+        DECODER_CELL_PLANS[case]
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B, G, S, D), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((B, G, S, Dv), jnp.bfloat16)
+    klen = jax.ShapeDtypeStruct((B,), jnp.float32)
+    spans = _plan_spans(lambda q, k, v, klen: fa._pallas_flash(
+        q, k, v, klen, True, 0.088, interpret=True, window=window)[0],
+        q, k, v, klen)
+    assert [(p["block_q"], p["block_k"], p["rows_per_step"], p["kv_heads"])
+            for p in spans] == [(*fwd_blocks, 1, G)]
+    plan = fa._bwd_plan(S, S, D, jnp.bfloat16, True, v_dim=Dv, window=window,
+                        bh=fa._packable_rows(q, k))
+    assert (plan["block_q"], plan["block_k"], plan["chunks"], plan["engine"],
+            plan["rows_per_step"]) == (*bwd_blocks, chunks, "pallas", 1)
+    assert fa.kept(q, k, v, True, window, force="pallas") == fa.KEPT
 
 
 def _step_bwd_spans(spec, feed, name="flash.bwd_plan"):
@@ -561,6 +777,14 @@ def _transformer_base_step():
     return spec, {n: words for n in spec.feed_names}
 
 
+@functools.lru_cache(maxsize=None)
+def _transformer_base_spans():
+    """flash.plan, flash.bwd_plan and attn.lower of ONE lowering of the
+    transformer-base step (three tests read it; 9 s a lowering)."""
+    return _step_bwd_spans(*_transformer_base_step(), name=(
+        "flash.plan", "flash.bwd_plan", "attn.lower"))
+
+
 def test_ouro_body_has_four_pallas_backward_sites():
     """The body's four layers are four sites, lowered once for any trip
     count, and every one takes the Pallas backward."""
@@ -572,24 +796,37 @@ def test_ouro_body_has_four_pallas_backward_sites():
     assert all(s == want for s in spans)
 
 
-def test_transformer_base_has_eighteen_xla_backward_sites():
-    """6 encoder self, 6 decoder self, 6 cross, every one left to the XLA
-    recompute backward."""
-    spans = _step_bwd_spans(*_transformer_base_step())
+@pytest.mark.parametrize("name", ["flash.plan", "flash.bwd_plan"])
+def test_transformer_base_has_eighteen_sites_of_several_rows_a_step(name):
+    """6 encoder self, 6 decoder self, 6 cross: a head is one 256 x 256
+    block, so every site's forward and backward take several batch-head rows
+    a grid step (here all 4: B 2, H 2; the cell's 768 go 16 and 12 a step),
+    and with them the backward is the Pallas kernel's (PR 53: the XLA
+    recompute kept all 18 before)."""
+    spans = _transformer_base_spans()[name]
     assert len(spans) == 18
-    assert {s["engine"] for s in spans} == {"xla"}
-    assert {(s["sq"], s["sk"], s["head_dim"]) for s in spans} == \
-        {(256, 256, 64)}
+    assert {s["rows_per_step"] for s in spans} == {4}
+    assert {(s["sq"], s["sk"], s["head_dim"], s["block_q"], s["block_k"])
+            for s in spans} == {(256, 256, 64, 256, 256)}
+    if name == "flash.bwd_plan":
+        assert {s["engine"] for s in spans} == {"pallas"}
+        assert {(s["steps"], s["chunks"]) for s in spans} == {(1, 1)}
+    else:       # static counts over one batch-head row, as they always were
+        assert {(s["k_steps"], s["causal"]) for s in spans} == \
+            {(1, 0), (1, 1)}
 
 
-@pytest.mark.parametrize("step, sites, kept", [
-    (_ouro_step, 4, ("out,lse", 2 * 2 * 2048 * (128 * 2 + 4))),
-    (_transformer_base_step, 18, ("", 0))], ids=["ouro", "transformer_base"])
+@pytest.mark.parametrize("spans, sites, kept", [
+    (lambda: _step_bwd_spans(*_ouro_step(), name="attn.lower"), 4,
+     ("out,lse", 2 * 2 * 2048 * (128 * 2 + 4))),
+    (lambda: _transformer_base_spans()["attn.lower"], 18,
+     ("out,lse", 2 * 2 * 256 * (64 * 2 + 4)))],
+    ids=["ouro", "transformer_base"])
 def test_the_sites_that_keep_are_the_sites_on_the_pallas_backward(
-        step, sites, kept):
+        spans, sites, kept):
     """`attn.lower`'s `kept` / `kept_bytes`, a site: the forward's output
-    and logsumexp where the backward is the Pallas kernel (4 of 4 in
-    ouro-2.6b's body), nothing at any of transformer-base's 18."""
-    spans = _step_bwd_spans(*step(), name="attn.lower")
-    assert [(s["kept"], s["kept_bytes"]) for s in spans] == sites * [kept]
+    and logsumexp where the backward is the Pallas kernel: 4 of 4 in
+    ouro-2.6b's body and, since a step takes several rows, transformer-base's
+    18 (which no recomputed unit surrounds: the tag does nothing there)."""
+    assert [(s["kept"], s["kept_bytes"]) for s in spans()] == sites * [kept]
 

@@ -377,17 +377,23 @@ def test_the_cells_plans_at_the_real_shape():
 
 def test_no_site_compiles_scores_that_do_not_fit():
     """A site that no Pallas plan takes and whose fp32 scores pass 2 GiB is
-    refused at lowering on a TPU, not handed to XLA."""
+    refused at lowering on a TPU, not handed to XLA: S 256 with grouped K/V,
+    whose grid steps take one 256 x 256 block each (with a K/V head a query
+    head a step takes several rows, and the Pallas backward the site)."""
     q = jax.ShapeDtypeStruct((64, 256, 256, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((64, 128, 256, 64), jnp.bfloat16)
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True).sum()
 
     with fluid.flags.tpu_trace_scope(True):
-        assert fa._bwd_plan(256, 256, 64, jnp.bfloat16, True)["engine"] == \
-            "xla"
+        assert fa._bwd_plan(256, 256, 64, jnp.bfloat16, True,
+                            bh=fa._packable_rows(q, kv))["engine"] == "xla"
         with pytest.raises(ValueError, match="scores"):
-            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        assert fa._bwd_plan(256, 256, 64, jnp.bfloat16, True,
+                            bh=fa._packable_rows(q, q))["engine"] == "pallas"
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
 # ---------------------------------------------------------------------------

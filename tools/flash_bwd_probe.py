@@ -20,6 +20,10 @@ For each shape, one JSON line a row:
                forward emits lse only for the Pallas backward) with the
                engine held to one side, and
   step-rule    the same with the engine the rule reads off the shape
+Where a head is one block `pallas` and the `step-*` rows run at the batch-head
+rows a grid step that _rows_per_step gives, and `--rows-per-step 1,2,4,...`
+pins each count in turn: `pallas-rows-N` the backward kernel alone,
+`step-pallas-rows-N` forward and backward with both held to N (PR 53).
 `--sweep` also pins every block pair the shape admits whose working set is
 under 1.5 x the plan's share; `--parent FILE` times another commit's
 `_pallas_flash_bwd` as it stands (`git show <commit>:paddle_tpu/kernels/
@@ -39,6 +43,7 @@ child.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib
 import importlib.util
 import json
@@ -80,6 +85,9 @@ def main() -> int:
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--shapes", default=None)
+    ap.add_argument("--rows-per-step", default="", metavar="N,N,...",
+                    help="batch-head rows a grid step, each pinned in turn "
+                    "where a head is one block")
     ap.add_argument("--out", default="chiprun_out/flash_bwd_probe.json")
     ap.add_argument("--parent", metavar="FILE", help="kernels/"
                     "flash_attention.py of another commit (git show), its "
@@ -116,7 +124,7 @@ def main() -> int:
         scale = 1.0 / math.sqrt(D)
         visible = S * (S + 1) / 2 if causal else float(S * np.mean(lengths))
         counted = 2.5 * 4.0 * B * H * visible * D
-        plan = fa._bwd_plan(S, S, D, q.dtype, causal)
+        plan = fa._bwd_plan(S, S, D, q.dtype, causal, bh=B * H)
         force = "interpret" if a.rehearse else "pallas"
 
         def reference(q, k, v):
@@ -131,37 +139,56 @@ def main() -> int:
                 q, k, v, klen, out, lse, g, causal, scale,
                 interpret=a.rehearse, **pins))
 
-        def step(threshold):
-            """fwd + bwd with the rule's threshold held at `threshold` while
-            the call is traced; the loss is returned too, or the forward
-            of the XLA engine (nothing of it is a residual) is dead code."""
+        def step(threshold, rows_per_step=None):
+            """fwd + bwd with the rule's threshold held at `threshold` (and
+            both kernels' rows a grid step at `rows_per_step`, where given)
+            while the call is traced and compiled; the loss is returned too,
+            or the forward of the XLA engine (nothing of it is a residual)
+            is dead code."""
             def loss(q, k, v, g):
                 o = fa.flash_attention(q, k, v, causal=causal,
                                        k_lengths=klen, force=force)
                 return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
 
-            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-
-            def run(*args):
-                fa._BWD_PALLAS_MIN_BLOCK_SCORES = threshold
-                try:
-                    return fn(*args)[1]
-                finally:
-                    fa._BWD_PALLAS_MIN_BLOCK_SCORES = rule_threshold
-            return run
+            planned = fa._rows_per_step
+            fa._BWD_PALLAS_MIN_BLOCK_SCORES = threshold
+            if rows_per_step is not None:
+                fa._rows_per_step = lambda bh, one_block, working_set: (
+                    rows_per_step if one_block and bh % rows_per_step == 0
+                    else 1)
+            fa._bwd_chunk_rows.cache_clear()    # the rule's answer is cached
+            try:
+                fn = jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1, 2))).lower(q, k, v, g).compile()
+            finally:
+                fa._BWD_PALLAS_MIN_BLOCK_SCORES = rule_threshold
+                fa._rows_per_step = planned
+                fa._bwd_chunk_rows.cache_clear()
+            return lambda *args: fn(*args)[1]
 
         xla = jax.jit(lambda q, k, v, g: jax.vjp(reference, q, k, v)[1](g))
         pair = (plan["block_q"], plan["block_k"])
+        counts = [n for n in map(int, filter(None, a.rows_per_step.split(",")))
+                  if pair == (S, S) and (B * H) % n == 0]
+        planned = plan["rows_per_step"]
+        # (label, call, blocks[, batch-head rows a grid step: 1 if absent]);
+        # a `step-*` call is built where it is timed, so that a compile
+        # Mosaic refuses is a row like any other
         variants = [("xla", xla, (None, None)),
-                    ("pallas", kernels(fa), pair)]
+                    ("pallas", kernels(fa), pair, planned)]
+        variants += [(f"pallas-rows-{n}", kernels(fa, rows_per_step=n), pair,
+                      n) for n in counts]
         if parent is not None:
             variants.append(("parent-pallas", kernels(parent),
                              (lse.shape[2], 128)))
         if not a.rehearse:   # force="interpret" keeps the Pallas backward
-            variants.append(("step-xla", step(2 ** 62), (None, None)))
-        variants += [("step-pallas", step(0), pair),
-                     ("step-rule:" + plan["engine"], step(rule_threshold),
-                      pair)]
+            variants.append(("step-xla", lambda: step(2 ** 62),
+                             (None, None)))
+        variants += [("step-pallas", lambda: step(0), pair, planned),
+                     ("step-rule:" + plan["engine"],
+                      lambda: step(rule_threshold), pair, planned)]
+        variants += [(f"step-pallas-rows-{n}",
+                      functools.partial(step, 0, n), pair, n) for n in counts]
         if a.sweep:
             lens = fa._block_lengths(S)
             variants += [
@@ -172,14 +199,18 @@ def main() -> int:
                 <= 1.5 * fa._PLAN_VMEM_BUDGET]
         f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
         want = [np.asarray(x) for x in xla(*f32)]
-        for label, fn, (bq, bk) in variants:
+        for label, fn, (bq, bk), *rows_per_step in variants:
             row = {"shape": name, "bh": B * H, "s": S, "d": D,
                    "causal": causal, "variant": label, "block_q": bq,
                    "block_k": bk, "seed": a.seed}
             if bq is not None:
+                n = rows_per_step[0] if rows_per_step else 1
+                row["rows_per_step"] = n
                 row["working_set_mb"] = round(fa.bwd_working_set_bytes(
-                    bq, bk, D, -(-S // bq), "bfloat16") / 2 ** 20, 3)
+                    bq, bk, D, -(-S // bq), "bfloat16", None, n) / 2 ** 20, 3)
             try:
+                if label.startswith("step-"):
+                    fn = fn()
                 got = [np.asarray(x.astype(jnp.float32))
                        for x in fn(q, k, v, g)]
                 row["max_abs_err"] = max(float(np.max(np.abs(x - w)))

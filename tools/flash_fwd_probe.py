@@ -7,7 +7,10 @@
 For each shape: this repo's kernel with its blocks pinned to 128 x 128 (what
 every shape ran before the plan), the kernel at the blocks _plan_blocks gives
 it, and jax.experimental.pallas.ops.tpu.flash_attention as a yardstick, once
-at its shipped default blocks (all 128) and once at the plan's.  `--parent
+at its shipped default blocks (all 128) and once at the plan's.  Where a
+head is one block, `plan` is also the plan's batch-head rows a grid step
+(_rows_per_step) and `--rows-per-step 1,2,4,...` pins each count in turn,
+with and without the lse (how PR 53 settled it).  `--parent
 FILE` times another commit's kernel beside them (`git show
 <commit>:paddle_tpu/kernels/flash_attention.py > chip_scratch/...`).  `--sweep`
 also pins every block pair a shape admits, which is how the plan's VMEM share
@@ -74,6 +77,9 @@ def main() -> int:
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--rows-per-step", default="", metavar="N,N,...",
+                    help="batch-head rows a grid step, each pinned in turn "
+                    "where a head is one block")
     ap.add_argument("--parent", metavar="FILE", help="kernels/"
                     "flash_attention.py of another commit (git show), timed "
                     "as it stands beside this tree's")
@@ -111,10 +117,10 @@ def main() -> int:
         counted = 4.0 * B * H * visible * D
         plan = fa._plan_blocks(S, S, D, q.dtype, causal, False)
 
-        def ours(bq, bk, need_lse=False):
+        def ours(bq, bk, need_lse=False, **pins):
             return jax.jit(lambda q, k, v, klen: fa._pallas_flash(
                 q, k, v, klen, causal, scale, block_q=bq, block_k=bk,
-                interpret=a.rehearse, need_lse=need_lse)[0])
+                interpret=a.rehearse, need_lse=need_lse, **pins)[0])
 
         def shipped(bq, bk):
             from jax.experimental.pallas.ops.tpu import flash_attention as jx
@@ -134,9 +140,22 @@ def main() -> int:
                 lambda q, k, v, klen: parent._pallas_flash(
                     q, k, v, klen, causal, scale, interpret=a.rehearse,
                     need_lse=False)[0]), (128, 128)))
+        def planned_rows(lse):
+            return fa._rows_per_step(
+                B * H, plan == (S, S), lambda n: fa.fwd_working_set_bytes(
+                    *plan, D, 1, "bfloat16", lse, None, n))
+
+        # (label, call, blocks[, batch-head rows a grid step: 1 if absent])
         variants += [("pinned-128", ours(128, 128), (128, 128)),
-                    ("plan", ours(*plan), plan),
-                    ("plan+lse", ours(*plan, need_lse=True), plan)]
+                    ("plan", ours(*plan), plan, planned_rows(False)),
+                    ("plan+lse", ours(*plan, need_lse=True), plan,
+                     planned_rows(True))]
+        if plan == (S, S):      # a head is one block: rows a grid step
+            variants += [
+                (f"rows-{n}" + ("+lse" if lse else ""),
+                 ours(*plan, need_lse=lse, rows_per_step=n), plan, n)
+                for n in map(int, filter(None, a.rows_per_step.split(",")))
+                if (B * H) % n == 0 for lse in (False, True)]
         if a.sweep:
             lens = fa._block_lengths(S)
             variants += [(f"pinned-{bq}x{bk}", ours(bq, bk), (bq, bk))
@@ -148,13 +167,15 @@ def main() -> int:
         want = np.asarray(fa._reference_attention(
             q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32)
         ).astype(jnp.float32))
-        for label, fn, (bq, bk) in variants:
+        for label, fn, (bq, bk), *rows_per_step in variants:
+            lse = label.endswith("lse")
+            n = rows_per_step[0] if rows_per_step else 1
             row = {"shape": name, "bh": B * H, "s": S, "d": D,
                    "causal": causal, "variant": label, "block_q": bq,
-                   "block_k": bk, "seed": a.seed,
+                   "block_k": bk, "rows_per_step": n, "seed": a.seed,
                    "working_set_mb": round(fa.fwd_working_set_bytes(
-                       bq, bk, D, -(-S // bq), "bfloat16",
-                       label.endswith("lse")) / 2 ** 20, 3)}
+                       bq, bk, D, -(-S // bq), "bfloat16", lse, None, n)
+                       / 2 ** 20, 3)}
             try:
                 got = np.asarray(fn(q, k, v, klen).astype(jnp.float32))
                 row["max_abs_err"] = float(np.max(np.abs(got - want)))
