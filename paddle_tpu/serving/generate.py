@@ -100,6 +100,7 @@ ISSUE 16 makes speculation distribution-exact and UNIVERSAL:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -109,7 +110,6 @@ import numpy as np
 from .. import flags as _flags
 from ..kernels.flash_attention import (
     NEG_INF,
-    _reference_attention,
     flash_attention,
 )
 from ..kernels.paged_attention import (
@@ -250,45 +250,61 @@ def _layernorm(x, g, b, eps: float = 1e-5):
     return (x - mean) / jnp.sqrt(var + eps) * g + b
 
 
+@functools.lru_cache(maxsize=32)
+def _oracle_jit(H: int, Hkv: int, Dh: int):
+    """The oracle's arithmetic as ONE traced function of (params, tokens
+    [L], mask [L, L]): a compile a model, where op by op it cost some
+    fifty small executables a new length, and ``full_decode`` meets a
+    new length every token."""
+    import jax
+    import jax.numpy as jnp
+
+    def body(params, tokens, mask):
+        L, d = tokens.shape[0], H * Dh
+        h = params["embed"][tokens] * np.sqrt(d) + params["pos"][:L]
+        for lp in params["layers"]:
+            q = (h @ lp["wq"]).reshape(L, H, Dh).transpose(1, 0, 2)[None]
+            k = (h @ lp["wk"]).reshape(L, Hkv, Dh).transpose(1, 0, 2)[None]
+            v = (h @ lp["wv"]).reshape(L, Hkv, Dh).transpose(1, 0, 2)[None]
+            k, v = repeat_kv(k, v, H // Hkv)  # query head h reads h // G
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (Dh ** -0.5)
+            scores = jnp.where(mask, scores, NEG_INF)
+            attn = jnp.einsum("bhqk,bhkd->bhqd",
+                              jax.nn.softmax(scores, axis=-1), v)
+            attn = attn[0].transpose(1, 0, 2).reshape(L, d)
+            h = _layernorm(h + attn @ lp["wo"], lp["ln1_g"], lp["ln1_b"])
+            ff = jnp.maximum(h @ lp["w1"] + lp["b1"], 0.0) @ lp["w2"] \
+                + lp["b2"]
+            h = _layernorm(h + ff, lp["ln2_g"], lp["ln2_b"])
+        return h @ params["embed"].T
+
+    return jax.jit(body)
+
+
 def full_forward(params: Dict, cfg: DecodeConfig, tokens,
                  mask=None) -> np.ndarray:
     """Oracle forward: full-sequence causal attention, no cache.
     tokens [S] int -> logits [S, V].  ``mask`` (optional [S, S] bool,
     query x key) REPLACES the causal mask — the windowed-decode oracle
     passes ``window_mask`` so sliding-window + attention-sink parity
-    checks against dense arithmetic, not against another paged path."""
-    import jax.numpy as jnp
+    checks against dense arithmetic, not against another paged path.
 
+    Every length runs the one program of ``cfg.max_length`` rows: the
+    rows past S are padding that no row before them sees (their keys are
+    masked, so they add exact zeros), each sees itself, and they are cut
+    from the result."""
     tokens = np.asarray(tokens, np.int32)
-    S = tokens.shape[0]
-    if S > cfg.max_length:
-        raise ValueError(f"sequence length {S} > max_length {cfg.max_length}")
-    d, H, Dh = cfg.d_model, cfg.n_head, cfg.head_dim
-    Hkv, G = cfg.num_kv_heads, cfg.group_size
+    S, L = tokens.shape[0], cfg.max_length
+    if S > L:
+        raise ValueError(f"sequence length {S} > max_length {L}")
+    seen = np.tril(np.ones((L, L), bool))
     if mask is not None:
-        mask = jnp.asarray(np.asarray(mask, bool))[None, None]  # [1,1,S,S]
-    h = jnp.asarray(params["embed"])[tokens] * np.sqrt(d) \
-        + jnp.asarray(params["pos"])[:S]
-    for lp in params["layers"]:
-        q = (h @ lp["wq"]).reshape(S, H, Dh).transpose(1, 0, 2)[None]
-        k = (h @ lp["wk"]).reshape(S, Hkv, Dh).transpose(1, 0, 2)[None]
-        v = (h @ lp["wv"]).reshape(S, Hkv, Dh).transpose(1, 0, 2)[None]
-        k, v = repeat_kv(k, v, G)  # GQA: query head h reads KV head h//G
-        if mask is None:
-            attn = _reference_attention(q, k, v, causal=True,
-                                        scale=Dh ** -0.5)
-        else:
-            import jax
-
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (Dh ** -0.5)
-            scores = jnp.where(mask, scores, NEG_INF)
-            attn = jnp.einsum("bhqk,bhkd->bhqd",
-                              jax.nn.softmax(scores, axis=-1), v)
-        attn = attn[0].transpose(1, 0, 2).reshape(S, d)
-        h = _layernorm(h + attn @ lp["wo"], lp["ln1_g"], lp["ln1_b"])
-        ff = jnp.maximum(h @ lp["w1"] + lp["b1"], 0.0) @ lp["w2"] + lp["b2"]
-        h = _layernorm(h + ff, lp["ln2_g"], lp["ln2_b"])
-    return np.asarray(h @ jnp.asarray(params["embed"]).T)
+        seen[:S, :S] = np.asarray(mask, bool)
+    padded = np.zeros(L, np.int32)
+    padded[:S] = tokens
+    logits = _oracle_jit(cfg.n_head, cfg.num_kv_heads, cfg.head_dim)(
+        params, padded, seen)
+    return np.asarray(logits[:S])
 
 
 def window_mask(S: int, prompt_len: int, window: int, sinks: int,

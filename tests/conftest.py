@@ -32,7 +32,9 @@ def pytest_configure(config):
         "slow: excluded from tier-1 (-m 'not slow'): multi-process soaks, "
         "and real-size chip-less compiles of what a benchmark cell's "
         "`correct` and rate already hold on the chip.  Not a way to make "
-        "room: a tier-1 test over 60 s is resized (ROADMAP D16)")
+        "room: a tier-1 test over 20 s on the driver's run is resized by "
+        "the PR that adds it, and a file over 300 s of junit time is split "
+        "by subject (ROADMAP D16)")
     config.addinivalue_line(
         "markers", "chaos: fault-injection resilience tests")
     config.addinivalue_line(

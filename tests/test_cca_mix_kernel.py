@@ -34,17 +34,18 @@ import test_compressed_decoder as tiny_decoder
 
 BASE = 5e6
 NAMES = ("q", "k", "v", "a_w", "a_b", "b_w", "b_b", "tau")
-# S of three tiles of 128 rows, ISSUE 45's shape first
+# S of three tiles of 32 rows (the interpreter's seconds are the rows';
+# 128-row tiles cost 7-13 s a case, PR 54), ISSUE 45's heads first
 CASES = {
     "h8_on_g2": dict(),
-    "three_taps": dict(k0=3, k1=3),
+    "three_taps": dict(H=4, k0=3, k1=3),
     "h_equals_g": dict(H=2, G=2, B=2),
     "one_kv_head_whole_rotary": dict(H=4, G=1, rotary_dim=128),
-    "one_tap_and_four": dict(H=3, G=3, k0=1, k1=4, S=256),
+    "one_tap_and_four": dict(H=3, G=3, k0=1, k1=4, S=64),
 }
 
 
-def _inputs(seed=0, B=1, S=384, H=8, G=2, D=128, k0=2, k1=2,
+def _inputs(seed=0, B=1, S=96, H=8, G=2, D=128, k0=2, k1=2,
             dtype=jnp.float32, **_):
     rng, n = np.random.RandomState(seed), H + G
 
@@ -60,7 +61,7 @@ def _inputs(seed=0, B=1, S=384, H=8, G=2, D=128, k0=2, k1=2,
     return args, cots
 
 
-def _both(args, cots, H=8, G=2, rotary_dim=64, tile=128, force="interpret",
+def _both(args, cots, H=8, G=2, rotary_dim=64, tile=32, force="interpret",
           kernel=True, plain=True, **_):
     """(outputs, gradients, geometry) of the kernel pair and (outputs,
     gradients) of the jax.numpy form, under one loss (or one of the
@@ -106,7 +107,7 @@ def test_outputs_and_all_eight_gradients_are_the_jnp_forms(case):
     v~, both convolutions' weights and biases and tau."""
     args, cots = _inputs(**CASES[case])
     (outs, grads, geo), (want, want_grads) = _both(args, cots, **CASES[case])
-    assert geo is not None and geo.fwd_tile == geo.bwd_tile == 128
+    assert geo is not None and geo.fwd_tile == geo.bwd_tile == 32
     assert args[0].shape[1] // geo.fwd_tile >= 2
     for name, g, w in zip(("q^", "k^", "v"), outs, want):
         _close(g, w, 1e-5, f"{case}: {name}")
@@ -138,15 +139,15 @@ def test_bf16_inputs_on_the_amp_tier_to_the_tiers_tolerance():
 
 
 def test_a_tile_boundary_row_tile_is_the_untiled_result():
-    """Three tiles of 128 rows against ONE tile of 384: the rows a tile
+    """Three tiles of 32 rows against ONE tile of 96: the rows a tile
     takes from the one before it (and, backward, hands it) are the rows a
     whole sequence has there."""
     args, cots = _inputs(seed=1)
-    (outs, grads, geo), _ = _both(args, cots, tile=128)
-    (whole, whole_grads, one), _ = _both(args, cots, tile=384)
-    assert (geo.fwd_tile, one.fwd_tile) == (128, 384)
+    outs, grads, geo = _both(args, cots, tile=32, plain=False)
+    whole, whole_grads, one = _both(args, cots, tile=96, plain=False)
+    assert (geo.fwd_tile, one.fwd_tile) == (32, 96)
     for name, g, w in zip(("q^", "k^", "v"), outs, whole):
-        np.testing.assert_array_equal(g[:, :, 127:130], w[:, :, 127:130],
+        np.testing.assert_array_equal(g[:, :, 31:34], w[:, :, 31:34],
                                       err_msg=name)
         _close(g, w, 1e-6, name)
     for name, g, w in zip(NAMES, grads, whole_grads):
@@ -161,11 +162,11 @@ def test_the_first_tiles_rule_a_bias_not_zero_before_position_0():
     the kernel itself on the sequence behind a tile of zero rows, where
     the rows before are A on real zeros (the features past `rotary_dim`,
     which no position turns)."""
-    H, G, D, S, rotary_dim = 4, 2, 128, 256, 64
+    H, G, D, S, rotary_dim = 4, 2, 128, 64, 64
     args, _ = _inputs(seed=2, H=H, G=G, S=S)
     q, k, v, a_w, a_b, b_w, b_b, tau = args
     outs, geo = cca_mix.mix(*args, H, G, rotary_dim, 100.0,
-                            force="interpret", tile=128)
+                            force="interpret", tile=32)
     assert geo is not None
     lq, lk = H * D, G * D
     eye = jnp.eye(lq + 2 * lk)
@@ -188,13 +189,13 @@ def test_the_first_tiles_rule_a_bias_not_zero_before_position_0():
     assert np.abs(np.asarray(outs[0][0, :, 0] - zero_before[0][:, 0])).max() \
         > 1e-2
     # behind a tile of zeros: A's output on zeros is what B reads there
-    pad = jnp.zeros((1, 128, 1), jnp.float32)
+    pad = jnp.zeros((1, 32, 1), jnp.float32)
     behind, _ = cca_mix.mix(
         *(jnp.concatenate([pad * t[:, :1], t], 1) for t in (q, k, v)),
         a_w, a_b, b_w, b_b, tau, H, G, rotary_dim, 100.0, force="interpret",
-        tile=128)
+        tile=32)
     for got, late in zip(outs[:2], behind[:2]):
-        _close(got[..., rotary_dim:], late[:, :, 128:, rotary_dim:], 1e-5,
+        _close(got[..., rotary_dim:], late[:, :, 32:, rotary_dim:], 1e-5,
                "behind a tile of zeros")
 
 

@@ -21,6 +21,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import as_one_compile, once_a_program
 from benchmark.harness import manifest
 from benchmark.harness import reference as harness_reference
 from paddle_tpu import layers, models, observability
@@ -97,10 +98,12 @@ def _build(rows=2, **over):
     return spec, params, batch, grads, float(np.ravel(got[0])[0])
 
 
+_built = once_a_program(_build)
+
+
 def _reference_loss_and_grad(spec, params, batch, trainable, ref=None):
-    loss, grad = (ref or _reference()).loss_and_grad(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()},
+    loss, grad = as_one_compile(
+        (ref or _reference()).loss_and_grad, params, batch,
         _ref_cfg(spec.extras["config"]), tuple(spec.feed_names),
         frozenset(trainable), 1)
     return float(loss), {k: np.asarray(v) for k, v in grad.items()}
@@ -114,7 +117,7 @@ def test_program_against_the_plain_reference(over):
     """Loss and every parameter's gradient, named parameter by named
     parameter: the convolutions' taps, tau, gamma, the router's maps and
     the ONE table among them."""
-    spec, params, batch, grads, loss = _build(**over)
+    spec, params, batch, grads, loss = _built(**over)
     ref_loss, ref_grads = _reference_loss_and_grad(spec, params, batch, grads)
     assert loss == pytest.approx(ref_loss, rel=RTOL)
     assert set(grads) == set(ref_grads)
@@ -146,15 +149,13 @@ MUTANT_TOL = {"loss_rtol": 1e-4, "grad_cos_min": 0.9999,
 
 @pytest.fixture(scope="module")
 def one_step():
-    return _build(expert_offset=0, experts_held=8)
+    return _built(expert_offset=0, experts_held=8)
 
 
 def _refused(step, name):
     spec, params, batch, grads, loss = step
-    ref_loss, ref_grads = probe.mutant(name)(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()},
-        _ref_cfg(spec.extras["config"]),
+    ref_loss, ref_grads = as_one_compile(
+        probe.mutant(name), params, batch, _ref_cfg(spec.extras["config"]),
         feed_names=tuple(spec.feed_names), trainable=frozenset(grads),
         micro=1)
     prods = {k: (float(np.vdot(grads[k], ref_grads[k])),

@@ -20,6 +20,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import as_one_compile, once_a_program
 from benchmark.harness import manifest
 from paddle_tpu import models, observability
 from paddle_tpu.kernels import flash_attention as flash_attention_fn
@@ -86,10 +87,12 @@ def _build(values=None, rows=3, **over):
     return spec, params, batch, grads, float(np.ravel(got[0])[0])
 
 
+_built = once_a_program(_build)
+
+
 def _reference_loss_and_grad(spec, params, batch, trainable, micro=1):
-    loss, grad = _reference().loss_and_grad(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()},
+    loss, grad = as_one_compile(
+        _reference().loss_and_grad, params, batch,
         _ref_cfg(spec.extras["config"]), tuple(spec.feed_names),
         frozenset(trainable), micro)
     return float(loss), {k: np.asarray(v) for k, v in grad.items()}
@@ -102,7 +105,7 @@ def _reference_loss_and_grad(spec, params, batch, trainable, micro=1):
 def test_program_against_the_plain_reference(over, micro):
     """Loss and every gradient, the selection bias off zero; `micro` parts
     of the batch give the reference the same answer as the whole."""
-    spec, params, batch, grads, loss = _build(**over)
+    spec, params, batch, grads, loss = _built(**over)
     assert not any(n.endswith("_router_bias") for n in grads)
     ref_loss, ref_grads = _reference_loss_and_grad(spec, params, batch,
                                                    grads, micro)

@@ -109,9 +109,11 @@ def test_fused_test_mode_uses_moving_stats():
 def test_fused_bn_fuzz_parity_vs_composed_ops():
     """Seeded fuzz: random shapes / eps / momentum / residual presence /
     act, fwd + one SGD step, fused op vs the composed batch_norm +
-    elementwise_add + relu chain.  20 cases."""
+    elementwise_add + relu chain.  12 cases (20 until PR 54: 30 s of
+    the driver's run for 80 small compiles; every option's every value is
+    still drawn)."""
     rng = np.random.RandomState(123)
-    for case in range(20):
+    for case in range(12):
         c = int(rng.choice([1, 3, 8]))
         h = int(rng.choice([4, 7, 8]))
         bs = int(rng.choice([2, 5, 8]))

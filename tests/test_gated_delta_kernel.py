@@ -97,14 +97,16 @@ def test_the_kernel_pair_is_the_jnp_engine(dtype, rtol):
 
 
 def test_groups_of_rows_change_no_number():
-    args = _inputs(1, 512, H, D, seed=6)
+    """One group of two tiles against two groups of one (S 256: at S 512
+    and three groupings the interpreter took 31 s of the driver's run, PR
+    54; two groups of two tiles are the recurrence's cases above)."""
+    args = _inputs(1, 256, H, D, seed=6)
     weight = jnp.ones(args[0].shape, jnp.float32)
-    whole = _both_passes(_kernels(512), args, weight)
-    for rows in (128, 256):
-        for name, a, b in zip(NAMES, _both_passes(_kernels(rows), args,
-                                                  weight), whole):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7,
-                                       err_msg=f"{name} at {rows} rows")
+    whole = _both_passes(_kernels(256), args, weight)
+    for name, a, b in zip(NAMES, _both_passes(_kernels(128), args, weight),
+                          whole):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7,
+                                   err_msg=f"{name} at 128 rows")
 
 
 # ---------------------------------------------------------------------------

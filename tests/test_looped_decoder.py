@@ -14,6 +14,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import as_one_compile, once_a_program
 from paddle_tpu import layers, models, observability
 
 # the module: models/__init__.py exports the function under the same name
@@ -80,13 +81,13 @@ def _build(build=models.looped_decoder, values=None, **over):
     return spec, params, batch, grads, float(np.ravel(got[0])[0])
 
 
-def _reference_loss_and_grad(spec, params, batch, micro=1, **cfg_over):
-    import jax.numpy as jnp
+_built = once_a_program(_build)
 
+
+def _reference_loss_and_grad(spec, params, batch, micro=1, **cfg_over):
     cfg = {**_ref_cfg(spec.extras["config"]), **cfg_over}
-    loss, grad = _reference().loss_and_grad(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()}, cfg,
+    loss, grad = as_one_compile(
+        _reference().loss_and_grad, params, batch, cfg,
         tuple(spec.feed_names), frozenset(params), micro)
     return float(loss), {k: np.asarray(v) for k, v in grad.items()}
 
@@ -104,7 +105,7 @@ def _assert_same_gradients(got, want):
 def test_program_against_the_plain_reference(trips, gate, micro):
     """Loss and every gradient; `micro` parts of the batch give the
     reference the same answer as the whole."""
-    spec, params, batch, grads, loss = _build(loop_steps=trips,
+    spec, params, batch, grads, loss = _built(loop_steps=trips,
                                               exit_gate=gate)
     ref_loss, ref_grads = _reference_loss_and_grad(spec, params, batch, micro)
     assert loss == pytest.approx(ref_loss, rel=RTOL)
@@ -179,7 +180,7 @@ def _unrolled(cfg):
 
 
 def test_scan_lowering_against_an_unrolled_build():
-    spec, params, batch, grads, loss = _build(loop_steps=4)
+    spec, params, batch, grads, loss = _built(loop_steps=4, exit_gate=True)
     # the unrolled start-up program draws each tied weight four times
     _, params_u, _, grads_u, loss_u = _build(_unrolled, values=params,
                                              loop_steps=4)

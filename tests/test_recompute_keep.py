@@ -25,6 +25,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import once_a_program
 from paddle_tpu import models, observability
 from paddle_tpu.core import compiler
 from paddle_tpu.kernels import sparse_attention as dsa
@@ -241,6 +242,15 @@ def _step_for_the_tpu(build, config, rows=1):
         observability.reset()
 
 
+@once_a_program
+def _flash_step(model, rows, recompute=True):
+    """`_step_for_the_tpu` of FLASH[model] as the tree lowers it, once for
+    the cases that read the same step."""
+    build, config, sizes = FLASH[model]
+    return _step_for_the_tpu(
+        build, config(**{**sizes, "use_recompute": recompute}), rows=rows)
+
+
 def _kernels(text) -> dict:
     """Calls of each Pallas kernel in a step's StableHLO (a scan's body
     stands there once, whatever its trips).  The flash pair that takes
@@ -315,7 +325,7 @@ def test_a_flash_site_keeps_out_and_lse_and_its_forward_is_traced_once(
     the backward kernels are the same."""
     build, config, sizes = FLASH[model]
     cfg = config(**sizes)
-    text, spans = _step_for_the_tpu(build, cfg, rows=2)
+    text, spans = _flash_step(model, 2, True)
     sites = _flash_sites(model, cfg)
     assert spans["recurrence.lower"]
     assert all(s["recompute"] == 1 and s["kept"] == len(fa.KEPT) * sites
@@ -375,9 +385,9 @@ def test_a_cca_mix_site_is_a_forward_its_recomputation_and_a_backward_kernel(
     recomputation once each.  (tests/test_cca_mix_kernel.py holds the
     compiled step: at most one of the two forwards is left under
     `rematted_computation`.)"""
-    build, config, sizes = FLASH["compressed_decoder"]
-    cfg = config(**{**sizes, "use_recompute": recompute})
-    text, _ = _step_for_the_tpu(build, cfg, rows=2)
+    _, config, sizes = FLASH["compressed_decoder"]
+    cfg = config(**sizes)
+    text, _ = _flash_step("compressed_decoder", 2, recompute)
     kernels = _kernels(text)
     assert kernels["_cca_mix_kernel"] == forwards * cfg.n_layer
     assert kernels["_cca_mix_bwd_kernel"] == cfg.n_layer
@@ -387,9 +397,9 @@ def test_a_cca_mix_site_is_a_forward_its_recomputation_and_a_backward_kernel(
 def test_without_recompute_the_flash_tags_do_nothing():
     """use_recompute false: the tags are there (`kept` 2 a site) and no
     checkpoint is: one forward kernel a site either way."""
-    build, config, sizes = FLASH["compressed_decoder"]
-    cfg = config(**{**sizes, "use_recompute": False})
-    text, spans = _step_for_the_tpu(build, cfg, rows=2)
+    _, config, sizes = FLASH["compressed_decoder"]
+    cfg = config(**sizes)
+    text, spans = _flash_step("compressed_decoder", 2, False)
     assert [(s["recompute"], s["kept"]) for s in spans["recurrence.lower"]] \
         == cfg.n_layer * [(0, len(fa.KEPT))]
     assert _kernels(text)["_flash_kernel"] == cfg.n_layer

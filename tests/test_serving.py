@@ -72,6 +72,7 @@ from paddle_tpu.serving import (
     init_decode_params,
     prefill_step,
 )
+from paddle_tpu.serving.generate import window_mask
 
 
 def _export_small_cnn(dirname: str):
@@ -633,6 +634,63 @@ def test_attention_bytes_per_step_model():
     assert attention_bytes_per_step("pallas", **kw) == 2 * s_kv * 2
     assert attention_bytes_per_step("interpret", **kw) == 2 * s_kv * 2
     assert attention_bytes_per_step("reference", **kw) == 6 * s_kv * 2
+
+
+# -- the oracle itself ---------------------------------------------------
+
+def _oracle_written_out(params, cfg, tokens, mask=None):
+    """``full_forward``'s arithmetic as it stood before it was one traced
+    function (PR 54), in float32 numpy, op by op: what ten test files
+    trust the oracle to be."""
+    tokens = np.asarray(tokens, np.int32)
+    S, d, H, Dh = len(tokens), cfg.d_model, cfg.n_head, cfg.head_dim
+    Hkv, G = cfg.num_kv_heads, cfg.group_size
+    f32 = np.float32
+
+    def layernorm(x, g, b):
+        mean = x.mean(-1, keepdims=True, dtype=f32)
+        var = np.square(x - mean).mean(-1, keepdims=True, dtype=f32)
+        return (x - mean) / np.sqrt(var + f32(1e-5)) * g + b
+
+    vis = (np.tril(np.ones((S, S), bool)) if mask is None
+           else np.asarray(mask, bool))
+    h = params["embed"][tokens] * f32(np.sqrt(d)) + params["pos"][:S]
+    for lp in params["layers"]:
+        q = (h @ lp["wq"]).reshape(S, H, Dh).transpose(1, 0, 2)
+        k = (h @ lp["wk"]).reshape(S, Hkv, Dh).transpose(1, 0, 2)
+        v = (h @ lp["wv"]).reshape(S, Hkv, Dh).transpose(1, 0, 2)
+        k, v = np.repeat(k, G, axis=0), np.repeat(v, G, axis=0)
+        scores = np.einsum("hqd,hkd->hqk", q, k) * f32(Dh ** -0.5)
+        scores = np.where(vis[None], scores, f32(-1e30))
+        w = np.exp(scores - scores.max(-1, keepdims=True))
+        w = w / w.sum(-1, keepdims=True, dtype=f32)
+        attn = np.einsum("hqk,hkd->hqd", w, v)
+        attn = attn.transpose(1, 0, 2).reshape(S, d)
+        h = layernorm(h + attn @ lp["wo"], lp["ln1_g"], lp["ln1_b"])
+        ff = np.maximum(h @ lp["w1"] + lp["b1"], 0) @ lp["w2"] + lp["b2"]
+        h = layernorm(h + ff, lp["ln2_g"], lp["ln2_b"])
+    return h @ params["embed"].T
+
+
+@pytest.mark.parametrize("S", [3, 8, 13])
+@pytest.mark.parametrize("windowed", [False, True],
+                         ids=["causal", "window_mask"])
+@pytest.mark.parametrize("n_kv_head", [None, 2], ids=["mha", "kv2"])
+def test_full_forward_is_the_arithmetic_written_out(n_kv_head, windowed, S):
+    """The jitted oracle against the same arithmetic in numpy: logits to
+    1e-5 and every argmax equal, with and without grouped heads and the
+    windowed-decode mask, at three lengths."""
+    cfg = DecodeConfig(vocab_size=37, d_model=32, n_head=4, n_layer=2,
+                       d_inner=64, max_length=16, n_kv_head=n_kv_head)
+    params = init_decode_params(cfg, seed=5)
+    tokens = np.random.RandomState(S).randint(1, cfg.vocab_size, size=S)
+    mask = (window_mask(S, prompt_len=2, window=3, sinks=2, page_size=2)
+            if windowed else None)
+    got = full_forward(params, cfg, tokens, mask=mask)
+    want = _oracle_written_out(params, cfg, tokens, mask)
+    assert got.shape == (S, cfg.vocab_size) and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
 
 
 # -- (f) batched whole-prompt prefill ----------------------------------

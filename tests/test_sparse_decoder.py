@@ -21,6 +21,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import as_one_compile, once_a_program
 from benchmark.harness import manifest
 from paddle_tpu import models, observability
 from paddle_tpu.kernels import sparse_attention as dsa
@@ -98,12 +99,14 @@ def _build(rows=2, loss="loss", **over):
     return spec, params, batch, grads, float(np.ravel(got[0])[0])
 
 
+_built = once_a_program(_build)
+
+
 def _reference_loss_and_grad(spec, params, batch, trainable, ref=None,
                              **cfg_over):
     cfg = {**_ref_cfg(spec.extras["config"]), **cfg_over}
-    loss, grad = (ref or _reference()).loss_and_grad(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()}, cfg,
+    loss, grad = as_one_compile(
+        (ref or _reference()).loss_and_grad, params, batch, cfg,
         tuple(spec.feed_names), frozenset(trainable), 1)
     return float(loss), {k: np.asarray(v) for k, v in grad.items()}
 
@@ -122,7 +125,7 @@ def _assert_close(grads, ref_grads):
 def test_program_against_the_plain_reference(over):
     """Loss (cross entropy + index loss) and every parameter's gradient,
     the index's included, on unequal position streams."""
-    spec, params, batch, grads, loss = _build(**over)
+    spec, params, batch, grads, loss = _built(**over)
     ref_loss, ref_grads = _reference_loss_and_grad(spec, params, batch, grads)
     assert loss == pytest.approx(ref_loss, rel=RTOL)
     _assert_close(grads, ref_grads)
@@ -351,9 +354,7 @@ MUTANT_TOL = {"loss_rtol": 1e-4, "grad_cos_min": 0.9999,
 def one_step():
     """One forward-backward pass at the tiny size, the router's weights
     large enough for its rule to matter."""
-    spec, params, batch, grads, loss = _build(
-        expert_offset=0, experts_held=16)
-    return spec, params, batch, grads, loss
+    return _built(expert_offset=0, experts_held=16)
 
 
 @pytest.mark.parametrize("name", (None, "index_from_bf16") + probe.MUTANTS)
@@ -367,9 +368,8 @@ def test_the_reference_refuses_each_mutant(one_step, name):
     spec, params, batch, grads, loss = one_step
     cfg = _ref_cfg(spec.extras["config"])
     cfg = {**cfg, "sa_config": {**cfg["sa_config"], "topk": 8}}
-    ref_loss, ref_grads = probe.mutant(name)(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()}, cfg,
+    ref_loss, ref_grads = as_one_compile(
+        probe.mutant(name), params, batch, cfg,
         feed_names=tuple(spec.feed_names), trainable=frozenset(grads),
         micro=1)
     prods = {k: (float(np.vdot(grads[k], ref_grads[k])),

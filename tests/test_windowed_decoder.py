@@ -21,6 +21,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
+from decoder_steps import as_one_compile, once_a_program
 from benchmark.harness import manifest
 from benchmark.harness import reference as harness_reference
 from paddle_tpu import models, observability
@@ -106,12 +107,14 @@ def _build(rows=2, **over):
     return spec, params, batch, grads, float(np.ravel(got[0])[0])
 
 
+_built = once_a_program(_build)
+
+
 def _reference_loss_and_grad(spec, params, batch, trainable, ref=None,
                              **cfg_over):
     cfg = {**_ref_cfg(spec.extras["config"]), **cfg_over}
-    loss, grad = (ref or _reference()).loss_and_grad(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()}, cfg,
+    loss, grad = as_one_compile(
+        (ref or _reference()).loss_and_grad, params, batch, cfg,
         tuple(spec.feed_names), frozenset(trainable), 1)
     return float(loss), {k: np.asarray(v) for k, v in grad.items()}
 
@@ -132,7 +135,7 @@ def _assert_close(grads, ref_grads):
 def test_program_against_the_plain_reference(over):
     """Loss and every parameter's gradient, with a window shorter than the
     sequence and positions past the YaRN original length."""
-    spec, params, batch, grads, loss = _build(**over)
+    spec, params, batch, grads, loss = _built(**over)
     ref_loss, ref_grads = _reference_loss_and_grad(spec, params, batch, grads)
     assert loss == pytest.approx(ref_loss, rel=RTOL)
     _assert_close(grads, ref_grads)
@@ -157,15 +160,13 @@ MUTANT_TOL = {"loss_rtol": 1e-4, "grad_cos_min": 0.9999,
 
 @pytest.fixture(scope="module")
 def one_step():
-    return _build(expert_offset=0, experts_held=16)
+    return _built(expert_offset=0, experts_held=16)
 
 
 def _refused(step, name):
     spec, params, batch, grads, loss = step
-    ref_loss, ref_grads = probe.mutant(name)(
-        {k: jnp.asarray(v) for k, v in params.items()},
-        {k: jnp.asarray(v) for k, v in batch.items()},
-        _ref_cfg(spec.extras["config"]),
+    ref_loss, ref_grads = as_one_compile(
+        probe.mutant(name), params, batch, _ref_cfg(spec.extras["config"]),
         feed_names=tuple(spec.feed_names), trainable=frozenset(grads),
         micro=1)
     prods = {k: (float(np.vdot(grads[k], ref_grads[k])),
