@@ -16,6 +16,7 @@ import numpy as np
 from ..core import amp
 from ..core.proto import DataType
 from ..core.registry import register_op
+from ..observability import span
 from .common import data, in_desc, same_shape, set_output, wrap_lod
 
 
@@ -639,7 +640,15 @@ def _dropout_infer(op, block):
 def _dropout(ctx, ins, attrs):
     """Reference: operators/dropout_op.cc.  Implementations:
     downgrade_in_infer (default; train keeps scale, infer multiplies by 1-p)
-    and upscale_in_train (train scales by 1/(1-p), infer is identity)."""
+    and upscale_in_train (train scales by 1/(1-p), infer is identity).
+    The mask is drawn ONCE a site from the site's key and stored, a byte
+    an element (kernels/dropout_mask.py): the forward's select, the input
+    gradient and every matmul XLA fuses them into read it, none derives
+    it again.  `dropout.lower` (a span, at lowering, one a site that
+    draws) says `elements`, `prob`, `draw` (the generator), `engine`
+    (pallas | xla), `block_rows` and `mask_bytes`, what the site stores."""
+    from ..kernels import dropout_mask
+
     x = ins["X"][0]
     xv = data(x)
     p = attrs.get("dropout_prob", 0.5)
@@ -648,12 +657,15 @@ def _dropout(ctx, ins, attrs):
     if is_test:
         out = xv if impl == "upscale_in_train" else xv * (1.0 - p)
         return {"Out": [wrap_lod(x, out)], "Mask": [jnp.ones_like(xv, dtype=jnp.uint8)]}
-    keep = jax.random.bernoulli(ctx.rng(), 1.0 - p, np.shape(xv))
-    if impl == "upscale_in_train":
-        out = jnp.where(keep, xv / max(1.0 - p, 1e-8), 0.0)
-    else:
-        out = jnp.where(keep, xv, 0.0)
-    return {"Out": [wrap_lod(x, out)], "Mask": [keep.astype(jnp.uint8)]}
+    with span("dropout.lower", elements=int(np.prod(np.shape(xv))),
+              prob=float(p)) as sp:
+        mask, drawn = dropout_mask.draw(ctx.rng(), np.shape(xv), p,
+                                        mesh=ctx.mesh)
+        sp.set(draw=drawn.generator, engine=drawn.engine,
+               block_rows=drawn.block_rows, mask_bytes=int(mask.size))
+    kept = xv / max(1.0 - p, 1e-8) if impl == "upscale_in_train" else xv
+    out = jnp.where(mask != 0, kept, jnp.zeros((), xv.dtype))
+    return {"Out": [wrap_lod(x, out)], "Mask": [mask]}
 
 
 # -- interpolation -----------------------------------------------------------

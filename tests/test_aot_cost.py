@@ -480,3 +480,60 @@ def test_wide_head_step_evaluates_exp_once_and_writes_no_fp32_logits(v5e):
               if any(wide.search(shape_of.get(n, ""))
                      for n in [name] + operands)]
     assert len(passes) == 1, passes
+
+
+def _fusions_with_a_generator(text):
+    """(computations that hold a generator, those of them that also hold
+    a matmul), nested computations counted with their callers: threefry
+    is `shift-right-logical`s, XLA's own generator `rng-bit-generator`;
+    a matmul is a `convolution` or a `dot`."""
+    import re
+
+    body = dict(re.findall(
+        r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, flags=re.S | re.M))
+
+    def holds(name, words, seen=()):
+        b = body.get(name, "")
+        return any(w in b for w in words) or any(
+            holds(c, words, seen + (name,))
+            for c in re.findall(r"calls=%?([\w.\-]+)", b) if c not in seen)
+
+    drawing = [n for n in body if holds(
+        n, ("shift-right-logical(", "rng-bit-generator("))]
+    return drawing, [n for n in drawing
+                     if holds(n, (" convolution(", " dot("))]
+
+
+def test_no_matmul_fusion_of_a_transformer_step_holds_a_generator(v5e):
+    """PR 55: a one-layer Transformer step with dropout, at widths that
+    tile, compiled for the v5e: each of the 12 dropout sites is one
+    `dropout_mask` kernel, and no fusion that holds a matmul also holds a
+    generator (before, XLA cloned threefry into the weight gradients', the
+    input gradients' and the forward matmuls' fusions: the MXU waited for
+    the vector units, PERF.md §6)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import flags, models
+
+    fluid.reset_default_env()
+    spec = models.transformer(models.TransformerConfig(
+        src_vocab_size=256, trg_vocab_size=256, max_length=128, n_layer=1,
+        d_model=128, d_inner=512, n_head=2, dropout=0.1,
+        use_flash_attention=True, fuse_qkv=True))
+    fluid.optimizer.AdamOptimizer(1e-3).minimize(spec.loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    with flags.tpu_trace_scope(True):
+        compiled, feed_vals, state_vals, rng = exe.capture_program(
+            fluid.default_main_program(), feed=spec.synthetic_batch(32),
+            fetch_list=[spec.loss])
+        text = compile_tpu(compiled.raw_fn, feed_vals, state_vals,
+                           rng).as_text()
+    sites = sum(op.type == "dropout" for op in
+                fluid.default_main_program().desc.block(0).ops)
+    assert sites == 12
+    entry = text[text.index("\nENTRY "):]
+    assert entry.count('custom-call(') >= sites
+    assert len([ln for ln in entry.splitlines()
+                if "custom-call(" in ln and "dropout_mask" in ln]) == sites
+    drawing, with_a_matmul = _fusions_with_a_generator(text)
+    assert with_a_matmul == []
