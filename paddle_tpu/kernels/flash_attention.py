@@ -130,6 +130,22 @@ first forward's two and traces no second forward: an O(S^2) pass for two
 O(S) arrays.  A site on the XLA recompute
 backward has no logsumexp and tags nothing; outside a rematerialised unit
 a tag does nothing.
+
+The logsumexp as an output (PR 56): `_flash` hands out `out` alone and
+holds the logsumexp as a residual; `flash_attention(..., return_lse=True)`
+goes through `_flash_lse`, the same two kernels under a second custom_vjp
+that hands out (out, the rows' logsumexp [B, H, Sq] fp32) and takes a
+cotangent for BOTH.  A row's logsumexp moves with its scores by their
+probabilities, dL/dS = P, so dS = P (dP - D + dlse): the cotangent is taken
+off D = rowsum(dO * O) before the backward kernel runs (_bwd_rows), which is
+the kernel as it stands.  A row that sees no key (k_lengths 0) hands out
+NEG_INF and an output of zeros, and takes nothing back.  Calls over disjoint
+key sets then merge into ONE softmax exactly, forward and backward
+(merge_attention: each output weighted by exp(lse_i - logsumexp_i lse_i)):
+what EVA's window-and-summaries attention is built from
+(kernels/eva_attention.py).  Both outputs are made of the kept two, so a
+recomputed unit traces no second forward of such a site either.  A call
+without `return_lse` traces what it always traced.
 """
 
 from __future__ import annotations
@@ -145,8 +161,9 @@ from ..analysis.pallas import V5E_VMEM_BYTES, tile_padded_bytes
 from ..core.compiler import keep
 from ..observability import span
 
-__all__ = ["flash_attention", "fwd_vmem_bytes", "fwd_working_set_bytes",
-           "bwd_working_set_bytes", "KEPT", "kept", "kept_bytes"]
+__all__ = ["flash_attention", "merge_attention", "fwd_vmem_bytes",
+           "fwd_working_set_bytes", "bwd_working_set_bytes", "KEPT", "kept",
+           "kept_bytes"]
 
 NEG_INF = -1e30
 
@@ -409,12 +426,13 @@ def _visible_pairs(sq, sk, causal, window=None):
 
 
 def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None,
-                         window=None):
+                         window=None, with_lse=False):
     """Pure-jax attention (fallback + backward recompute).
     q: [B, H, Sq, D], k/v: [B, G, Sk, D] (query head j reads key/value head
     j // (H / G); K and V are not repeated: the group is an axis of q),
     k_lengths: [B] valid key counts, `window`: a row sees the `window` keys
-    that end at its diagonal."""
+    that end at its diagonal.  `with_lse`: (out, the rows' logsumexp
+    [B, H, Sq] fp32, NEG_INF where a row sees no key)."""
     H, G = q.shape[1], k.shape[1]
     if G != H:
         q = q.reshape(q.shape[0], G, H // G, *q.shape[2:])
@@ -441,8 +459,14 @@ def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None,
     weights = jnp.where(all_masked, 0.0, weights)
     if G != H:
         out = jnp.einsum("bghqk,bgkd->bghqd", weights, v)
-        return out.reshape(out.shape[0], H, *out.shape[3:])
-    return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+        out = out.reshape(out.shape[0], H, *out.shape[3:])
+    else:
+        out = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+    if not with_lse:
+        return out
+    lse = jax.nn.logsumexp(scores.astype(jnp.float32), axis=-1)
+    lse = jnp.where(all_masked[..., 0], NEG_INF, lse)
+    return out, lse.reshape(out.shape[:3])
 
 
 def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
@@ -1031,7 +1055,7 @@ def _repack(plane, sq, block_q, fill):
 
 
 def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
-              bq, bk, window, interpret, rows_per_step=1):
+              bq, bk, window, interpret, rows_per_step=1, dlse=None):
     """(dq, dk, dv) of the queries q [B, H, Sq, D] (with their out, dO `g`
     and packed lse) over the keys k, v [B, G, Sk, .] by ONE call of
     _flash_bwd_kernel: a whole row, or one trip of _pallas_flash_bwd's loop
@@ -1039,7 +1063,9 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
     diagonal's place among them (`causal_offset`: row i sees keys up to
     i + causal_offset).  dk and dv come per K/V head: where a group of
     query heads shares one, the kernel writes a query head's each and they
-    are added up here, in fp32."""
+    are added up here, in fp32.  `dlse` [B, H, Sq] is the cotangent of the
+    rows' logsumexp where the call handed it out (_flash_lse): dS = P (dP -
+    D + dlse), so it is taken off D and the kernel is the same."""
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     qp = _pad_seq(q, bq)
@@ -1063,6 +1089,9 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
     # 128x the payload and did NOT fuse away: custom-call operands are
     # materialized in HBM)
     dvec = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        dvec = dvec - jnp.pad(dlse.reshape(B * H, Sq).astype(jnp.float32),
+                              ((0, 0), (0, Sqp - Sq)))
     dvec = dvec.reshape(B * H, Sqp // bq, bq)
 
     call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk,
@@ -1082,7 +1111,8 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
 
 def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
                       block_q=None, block_k=None, interpret=False,
-                      window=None, chunk=None, rows_per_step=None):
+                      window=None, chunk=None, rows_per_step=None,
+                      dlse=None):
     """(dq, dk, dv) by _flash_bwd_kernel at the backward's own plan
     (_bwd_plan; block_q / block_k / chunk / rows_per_step pin it for a test
     or the probe).
@@ -1109,7 +1139,7 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
             _, _, _, _, bq, bk = trips[0]
             dq, dk, dv = _bwd_rows(q, k, v, klen, out, lse, g, causal, scale,
                                    Sk - Sq, bq, bk, window, interpret,
-                                   plan["rows_per_step"])
+                                   plan["rows_per_step"], dlse)
             if G != H:
                 dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
             return dq, dk, dv
@@ -1122,7 +1152,8 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
                 q[:, :, q0:q1], k[:, :, k0:k1], v[:, :, k0:k1],
                 jnp.clip(klen - k0, 0, k1 - k0), out[:, :, q0:q1],
                 lse[:, None, q0:q1], g[:, :, q0:q1], causal, scale,
-                q0 + (Sk - Sq) - k0, bq, bk, window, interpret)
+                q0 + (Sk - Sq) - k0, bq, bk, window, interpret,
+                dlse=None if dlse is None else dlse[:, :, q0:q1])
             dqs.append(dq_c)
             dk = dk.at[:, :, k0:k1].add(dk_c.astype(jnp.float32))
             dv = dv.at[:, :, k0:k1].add(dv_c.astype(jnp.float32))
@@ -1383,8 +1414,78 @@ def _flash_bwd(causal, scale, force, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _lse_rows(packed, q):
+    """The packed logsumexp plane of a forward over q [B, H, Sq, D] as
+    [B, H, Sq]: NEG_INF where a row saw no key (the kernel writes -NEG_INF
+    there, which keeps its backward's exp(S - L) at 0)."""
+    B, H, Sq, _ = q.shape
+    rows = packed.reshape(B, H, -1)[:, :, :Sq]
+    return jnp.where(rows >= -NEG_INF / 2, NEG_INF, rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_lse(q, k, v, klen, causal, scale, force, window):
+    """_flash that also hands out the rows' logsumexp [B, H, Sq] fp32, and
+    takes a cotangent for it: the Pallas engines only (flash_attention
+    sends a call that no kernel takes to _reference_attention, which jax
+    differentiates as it is)."""
+    out, lse = _forward(q, k, v, klen, causal, scale, force, True, window)
+    return out, _lse_rows(lse, q)
+
+
+def _flash_lse_fwd(q, k, v, klen, causal, scale, force, window):
+    out, lse = _forward(q, k, v, klen, causal, scale, force, True, window)
+    if not _pallas_backward(q, k, v, causal, force, window):
+        return (out, _lse_rows(lse, q)), (q, k, v, klen, None, None)
+    # kept as _flash_fwd keeps them: both outputs are made of the two
+    bits, lse = keep(jax.lax.bitcast_convert_type(
+        out, _BITS[out.dtype.itemsize]), lse)
+    return ((jax.lax.bitcast_convert_type(bits, out.dtype),
+             _lse_rows(lse, q)), (q, k, v, klen, bits, lse))
+
+
+def _flash_lse_bwd(causal, scale, force, window, res, cts):
+    """dS = P (dP - D + dlse): a row's logsumexp moves with its scores by
+    their probabilities, so its cotangent enters where D = rowsum(dO O)
+    leaves, and the kernel runs as it is on D - dlse (_bwd_rows).  A row
+    that saw no key has P = 0 and takes nothing."""
+    q, k, v, klen, bits, lse = res
+    g, dlse = cts
+    with jax.named_scope("flash.bwd"):
+        if lse is not None:
+            dq, dk, dv = _pallas_flash_bwd(
+                q, k, v, klen, jax.lax.bitcast_convert_type(bits, q.dtype),
+                lse, g, causal, scale, interpret=(force == "interpret"),
+                window=window, dlse=dlse)
+        else:
+            _, vjp = jax.vjp(
+                lambda q_, k_, v_: _reference_attention(
+                    q_, k_, v_, causal, scale,
+                    k_lengths=klen.astype(jnp.int32), window=window,
+                    with_lse=True), q, k, v)
+            dq, dk, dv = vjp((g, dlse))
+    return dq, dk, dv, jnp.zeros_like(klen)
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def merge_attention(parts):
+    """One softmax over several key sets from each set's own: `parts` is
+    [(out [B, H, Sq, Dv], lse [B, H, Sq])], a set's attention output and
+    logsumexp (flash_attention's `return_lse`); the result is the output
+    of one softmax over the union of the sets, out_i weighted by exp(lse_i
+    - logsumexp_i lse_i), in fp32, in the first output's dtype.  An empty
+    set (lse NEG_INF) weighs 0; one set at least has to see a key."""
+    lses = jnp.stack([lse for _, lse in parts])
+    weights = jax.nn.softmax(lses, axis=0)[..., None]
+    merged = sum(w * out.astype(jnp.float32)
+                 for w, (out, _) in zip(weights, parts))
+    return merged.astype(parts[0][0].dtype)
+
+
 def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
-                    force="auto", window=None):
+                    force="auto", window=None, return_lse=False):
     """q: [B, H, Sq, D]; k/v: [B, G, Sk, .] with G = H or a divisor of it
     (grouped-query attention: query head j reads key/value head
     j // (H / G); K and V are never repeated).  k_lengths: optional [B]
@@ -1394,7 +1495,12 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
 
     force: "auto" (pallas on TPU, jax elsewhere), "pallas", "interpret"
     (pallas interpreter — CPU testing), or "jax".  The backward's engine
-    is read from the shape (_bwd_plan): never from a flag."""
+    is read from the shape (_bwd_plan): never from a flag.
+
+    return_lse: (out, the rows' logsumexp [B, H, Sq] fp32, NEG_INF where a
+    row sees no key, k_lengths 0) and both take a cotangent, so that
+    several calls over disjoint key sets merge into one softmax exactly
+    (merge_attention), forward and backward."""
     if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
         raise ValueError(f"flash_attention: {q.shape[1]} query heads over "
                          f"{k.shape[1]} key and {v.shape[1]} value heads")
@@ -1411,4 +1517,10 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
         klen = jnp.full((q.shape[0],), k.shape[2], dtype=jnp.float32)
     else:
         klen = jnp.asarray(k_lengths, dtype=jnp.float32).reshape(-1)
-    return _flash(q, k, v, klen, causal, float(scale), force, window)
+    if not return_lse:
+        return _flash(q, k, v, klen, causal, float(scale), force, window)
+    if _use_pallas(force) or force == "interpret":
+        return _flash_lse(q, k, v, klen, causal, float(scale), force, window)
+    return _reference_attention(
+        q, k, v, causal, float(scale), k_lengths=klen.astype(jnp.int32),
+        window=window, with_lse=True)

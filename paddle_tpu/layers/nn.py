@@ -24,6 +24,7 @@ __all__ = [
     "beam_search",
     "beam_search_decode",
     "fused_attention",
+    "eva_attention",
     "rotary_embedding",
     "latent_attention",
     "short_conv1d",
@@ -517,9 +518,10 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-5,
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-6, param_attr=None,
-             name=None):
+             name=None, unit_offset=False):
     """input / sqrt(mean(input^2) + epsilon) over dims >= begin_norm_axis,
-    times a learned scale that starts at 1 (param_attr=False: no scale).
+    times a learned scale that starts at 1 (param_attr=False: no scale);
+    under `unit_offset` times (1 + scale), the scale started at 0.
     TPU-native addition (ops/nn_ops.py rms_norm)."""
     helper = LayerHelper("rms_norm", input=input, param_attr=param_attr,
                          name=name)
@@ -530,12 +532,14 @@ def rms_norm(input, begin_norm_axis=-1, epsilon=1e-6, param_attr=None,
         inputs["Scale"] = [helper.create_parameter(
             helper.param_attr,
             shape=[int(np.prod([abs(d) for d in input.shape[begin:]]))],
-            dtype=dtype, default_initializer=ConstantInitializer(1.0))]
+            dtype=dtype, default_initializer=ConstantInitializer(
+                0.0 if unit_offset else 1.0))]
     out = helper.create_variable_for_type_inference(dtype)
-    helper.append_op(
-        type="rms_norm", inputs=inputs, outputs={"Y": [out]},
-        attrs={"begin_norm_axis": begin, "epsilon": epsilon},
-    )
+    attrs = {"begin_norm_axis": begin, "epsilon": epsilon}
+    if unit_offset:
+        attrs["unit_offset"] = True
+    helper.append_op(type="rms_norm", inputs=inputs, outputs={"Y": [out]},
+                     attrs=attrs)
     return out
 
 
@@ -772,15 +776,19 @@ def auc(input, label, curve="ROC", num_thresholds=4095, topk=1, slide_steps=1):
     return auc_out, auc_out, [stat_pos, stat_neg]
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           out_dtype=None):
+    """`out_dtype` (TPU-native addition, e.g. "float32"): the product comes
+    out in that dtype whatever the AMP tier makes of the operands (bf16
+    operands, the fp32 accumulator handed out as it is)."""
     helper = LayerHelper("matmul", input=x, name=name)
-    out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op(
-        type="matmul",
-        inputs={"X": [x], "Y": [y]},
-        outputs={"Out": [out]},
-        attrs={"transpose_X": transpose_x, "transpose_Y": transpose_y, "alpha": float(alpha)},
-    )
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": float(alpha)}
+    if out_dtype:
+        attrs["out_dtype"] = str(out_dtype)
+    helper.append_op(type="matmul", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -1252,6 +1260,24 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
         attrs["rope"] = str(rope)
     helper.append_op(type="fused_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def eva_attention(q, k, v, mu, phi, window, chunk, name=None):
+    """EVA attention in its chunked form (Zheng et al., ICLR 2023, as
+    EvaByte runs it): q, k, v [B, H, S, D], rotated; mu, phi [H, D] pool
+    every `chunk` keys and values into one summary by two softmaxes over
+    the chunk; a query runs one softmax over the exact causal keys of its
+    own `window` and the summaries of every chunk of every window before
+    it, scores times D^-1/2.  [B, H, S, D] (TPU-native; ops/attention_ops.py eva_attention,
+    kernels/eva_attention.py)."""
+    helper = LayerHelper("eva_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        type="eva_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "Mu": [mu], "Phi": [phi]},
+        outputs={"Out": [out]},
+        attrs={"window": int(window), "chunk": int(chunk)})
     return out
 
 

@@ -39,6 +39,9 @@ from .common import ModelSpec
 
 __all__ = ["LoopedDecoderConfig", "looped_decoder"]
 
+# the label of a position that a prediction head has no target for
+IGNORED_LABEL = -100
+
 
 @dataclasses.dataclass
 class LoopedDecoderConfig:
@@ -174,6 +177,32 @@ def _heads_and_loss(b, states, labels, table=None):
     per_token = layers.elementwise_add(
         expected, layers.scale(neg_entropy, scale=cfg.entropy_beta))
     return layers.mean(per_token), logits, p
+
+
+def _several_heads_loss(b, h, labels):
+    """(loss, logits [B, S, P, vocabulary]) of the final states h [B, S, D]
+    under `cfg.pred_heads` = P heads on the one state (several byte- or
+    token-prediction heads): ONE [D, P x vocabulary] product whose fp32
+    accumulator is the logits, whatever the AMP tier makes of its operands
+    (layers.matmul's `out_dtype`); `labels` [B, S, P], head i's target at a
+    position, IGNORED_LABEL where it has none (past the sequence's end: no
+    loss, no gradient); one softmax_with_cross_entropy over [B, S, P,
+    vocabulary], the sum over the targets there are divided by their count,
+    all heads weighing alike."""
+    cfg = b.cfg
+    P = cfg.pred_heads
+    w = layers.create_parameter(
+        [cfg.d_model, P * cfg.vocab_size], "float32",
+        attr=ParamAttr(name="head_w", initializer=b.init))
+    logits = layers.reshape(layers.matmul(h, w, out_dtype="float32"),
+                            shape=[0, 0, P, cfg.vocab_size])
+    ce = layers.softmax_with_cross_entropy(logits=logits, label=labels,
+                                           ignore_index=IGNORED_LABEL)
+    there = layers.cast(layers.not_equal(
+        labels, layers.fill_constant([1], "int64", IGNORED_LABEL)), "float32")
+    loss = layers.elementwise_div(layers.reduce_sum(ce),
+                                  layers.reduce_sum(there))
+    return loss, logits
 
 
 def looped_decoder(cfg: Optional[LoopedDecoderConfig] = None, tokens=None,

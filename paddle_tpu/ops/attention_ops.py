@@ -115,6 +115,55 @@ def _fused_attention(ctx, ins, attrs):
     return {"Out": [out]}
 
 
+@register_op("eva_attention", infer_shape=_fused_attn_infer,
+             diff_inputs=["Q", "K", "V", "Mu", "Phi"])
+def _eva_attention(ctx, ins, attrs):
+    """EVA attention in its chunked form (kernels/eva_attention.py has the
+    equations): Q, K, V [B, H, S, D], rotated, H the heads held here; Mu,
+    Phi [H, D] the learned vectors that pool every `chunk` keys (and their
+    values) into one summary; a query runs ONE softmax over the exact keys
+    of its own `window` and the summaries of every chunk of every window
+    before it.  One engine: two flash_attention calls that hand out their
+    logsumexp, merged exactly; for ONE TPU they are the Pallas kernels
+    (`engine` flash), anywhere else, and on a mesh of several devices (XLA
+    cannot partition a Mosaic kernel), flash_attention's own jax.numpy
+    fallback (`engine` xla).  The pooling is jax.numpy in both, under the
+    name scope `eva.pool`; the attention under `eva.attend`.  Where the
+    flash calls' backward is the Pallas kernel each keeps its output and
+    logsumexp through the recomputation of the unit around the op
+    (core.compiler.keep), so a recomputed layer runs the pooling again and
+    no kernel's forward.  `eva.lower` (a span, at lowering) says what a
+    site was given: `windows`, `chunks` (those pooled: every window's but
+    the last), `heads_held`, `window_pairs` and `summary_pairs` (a head's
+    and a sequence's visible pairs), `pooled_bytes` (what the pooling's
+    forward has to move: K and V of the pooled positions in, 1 / chunk of
+    them out), `engine`, `kept`, `kept_bytes`."""
+    from ..kernels import eva_attention as eva
+    from ..kernels.flash_attention import _use_pallas
+
+    q, k, v = amp.mxu_operands(*(data(ins[s][0]) for s in ("Q", "K", "V")))
+    mu, phi = data(ins["Mu"][0]), data(ins["Phi"][0])
+    B, H, S, D = q.shape
+    window, chunk = int(attrs["window"]), int(attrs["chunk"])
+    geo = eva.geometry(S, window, chunk)
+    own, far = eva.pairs(S, window, chunk)
+    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
+    flash = _use_pallas("auto") and not several
+    names, held = eva.kept_by_flash(q, geo) if flash else ((), 0)
+    moved = 2 * B * H * geo["pooled"] * D * q.dtype.itemsize
+    with span("eva.lower", windows=geo["windows"], chunks=geo["chunks"],
+              heads_held=int(H), window=geo["window"], chunk=chunk,
+              sq=int(S), window_pairs=own, summary_pairs=far,
+              pooled_bytes=moved + moved // chunk,
+              engine="flash" if flash else "xla",
+              kept=",".join(names), kept_bytes=held):
+        ctx.kept += len(names)
+        out = eva.eva_attention(q, k.astype(q.dtype), v.astype(q.dtype), mu,
+                                phi, window, chunk,
+                                force="auto" if flash else "jax")
+    return {"Out": [out]}
+
+
 def _yarn_inv_freq(inv_freq, dim, base, factor, original_length, beta_fast,
                    beta_slow):
     """YaRN's frequencies (Peng et al. 2023), as the transformers library

@@ -14,7 +14,7 @@ import numpy as np
 
 from ..core import amp
 from ..core.lod import LoDValue
-from ..core.proto import DataType, dtype_to_runtime
+from ..core.proto import DataType, convert_dtype, dtype_to_runtime
 from ..core.registry import register_op
 from .common import data, in_desc, same_shape, set_output, wrap_lod
 
@@ -82,20 +82,27 @@ def _matmul_infer(op, block):
     else:
         batch = xs[:-2] if len(xs) >= len(ys) else ys[:-2]
         out = batch + [xs[-2], ys[-1]]
-    set_output(block, op, "Out", out, x.dtype)
+    wide = op.attr("out_dtype", None)
+    set_output(block, op, "Out", out, convert_dtype(wide) if wide else x.dtype)
 
 
 @register_op("matmul", infer_shape=_matmul_infer)
 def _matmul(ctx, ins, attrs):
     """Batched matmul with optional transposes and scale
-    (reference: operators/matmul_op.cc)."""
+    (reference: operators/matmul_op.cc).  Under `out_dtype` (TPU-native
+    addition) the product's accumulator is handed out as that dtype
+    whatever the AMP tier makes of the operands: fp32 logits of bf16
+    operands, not rounded on the way."""
     x, y = data(ins["X"][0]), data(ins["Y"][0])
     if attrs.get("transpose_X", False) and x.ndim >= 2:
         x = jnp.swapaxes(x, -1, -2)
     if attrs.get("transpose_Y", False) and y.ndim >= 2:
         y = jnp.swapaxes(y, -1, -2)
     xc, yc = amp.mxu_operands(x, y)
-    out = amp.mxu_output(jnp.matmul(xc, yc), x, y)
+    if attrs.get("out_dtype"):
+        out = jnp.matmul(xc, yc, preferred_element_type=attrs["out_dtype"])
+    else:
+        out = amp.mxu_output(jnp.matmul(xc, yc), x, y)
     alpha = attrs.get("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

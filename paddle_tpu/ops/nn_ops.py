@@ -525,7 +525,8 @@ def _rms_norm_infer(op, block):
 def _rms_norm(ctx, ins, attrs):
     """x / sqrt(mean(x^2) + eps) [* scale] over dims >= begin_norm_axis
     (Zhang & Sennrich 2019): layer_norm without the mean and the shift.
-    TPU-native addition; the 2018 reference has no such op."""
+    Under `unit_offset` the factor is (1 + scale), a scale that starts at
+    0.  TPU-native addition; the 2018 reference has no such op."""
     x = data(ins["X"][0])
     begin = attrs.get("begin_norm_axis", x.ndim - 1)
     eps = attrs.get("epsilon", 1e-6)
@@ -537,7 +538,8 @@ def _rms_norm(ctx, ins, attrs):
         jnp.mean(jnp.square(xs), axis=axes, keepdims=True) + eps)
     scale = ins.get("Scale", [None])[0]
     if scale is not None:
-        y = y * jnp.reshape(data(scale), (1,) * begin + x.shape[begin:])
+        scale = jnp.reshape(data(scale), (1,) * begin + x.shape[begin:])
+        y = y * (1.0 + scale if attrs.get("unit_offset", False) else scale)
     return {"Y": [y.astype(x.dtype)]}
 
 

@@ -210,10 +210,12 @@ FLASH = {
 }
 
 
-def _step_for_the_tpu(build, config, rows=1):
+def _step_for_the_tpu(build, config, rows=1,
+                      span_names=("recurrence.lower", "dsa.lower",
+                                  "attn.lower", "mla.lower")):
     """(the training step's StableHLO as it lowers for a TPU, no chip and
     no compiler, the Mosaic kernels serialised client-side, no source
-    locations in the text; its `recurrence.lower` and `dsa.lower` spans)."""
+    locations in the text; its spans called `span_names`)."""
     observability.reset()
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
@@ -234,8 +236,7 @@ def _step_for_the_tpu(build, config, rows=1):
                         lowering_platforms=("tpu",)).as_text()
         spans = {n: [dict(s.args) for s in
                      observability.default_tracer().spans() if s.name == n]
-                 for n in ("recurrence.lower", "dsa.lower", "attn.lower",
-                           "mla.lower")}
+                 for n in span_names}
         return text, spans
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = False
