@@ -9,8 +9,13 @@ host):
                      — the conv/BN pillar (the conv -> batch_norm -> relu
                      chain at bench scale)
   transformer_train  2-layer flash-attention transformer train step
-                     (Adam, fused qkv), bs=4, S=32 — the attention
-                     pillar, pallas custom calls included
+                     (Adam, fused qkv), bs=4, S=256, 2 heads of 64 — the
+                     attention pillar, pallas custom calls included: the
+                     benchmark cells' attention shape, which the
+                     heads-last flash kernels take as the projections
+                     write it (PR 57: at S=32 the op transposes to the
+                     heads-first kernels and the linter sees their
+                     relayout copies, not the cells' program)
   paged_decode       the serving decode attention step at the banked
                      AOT_COST_PAGED shape (B=4 H=8 D=128, 512 cached
                      tokens), pallas page-streaming impl — bytes/step
@@ -155,8 +160,8 @@ def _build_resnet50() -> Tuple[ProgramArtifacts, float, Dict]:
 def _build_transformer() -> Tuple[ProgramArtifacts, float, Dict]:
     from paddle_tpu import models
 
-    cfg = {"n_layer": 2, "n_head": 4, "d_model": 128, "d_inner": 256,
-           "max_length": 32, "vocab": 512, "batch": 4, "flash": True,
+    cfg = {"n_layer": 2, "n_head": 2, "d_model": 128, "d_inner": 256,
+           "max_length": 256, "vocab": 512, "batch": 4, "flash": True,
            "fuse_qkv": True, "optimizer": "adam"}
     mcfg = models.TransformerConfig(
         src_vocab_size=cfg["vocab"], trg_vocab_size=cfg["vocab"],

@@ -146,12 +146,31 @@ what EVA's window-and-summaries attention is built from
 (kernels/eva_attention.py).  Both outputs are made of the kept two, so a
 recomputed unit traces no second forward of such a site either.  A call
 without `return_lse` traces what it always traced.
+
+Heads-last (PR 57): `flash_attention(..., heads=H)` takes q, k, v as a
+model's projections write them, [B, S, H * D], and hands out the output so.
+At head 64 a [B, H, S, 64] array fills half a 128-lane tile: the compiler
+kept it in another layout and copied at every custom-call boundary, around
+the model's own transposes (26 of transformer-train's 113 busy ms moved
+attention's operands about; 14 were attention).  Where a head is one block
+(_heads_last_rows: S 256 or 384 at head 64) _flash_bshd_kernel /
+_flash_bwd_bshd_kernel take the operands as they lie: a grid step is a few
+BATCH rows, a lane tile is a pair of heads taken apart by a select
+(_tiles_of_heads), the head's mathematics is the rows kernels'
+(_head_forward, _head_backward), the logsumexp is [B, H, S] (the packed
+plane's memory), and D = rowsum(dO * O) is made INSIDE the backward kernel,
+as the row it is read as, by a product with a constant on the MXU (O is an
+operand; no reduction, no change of layout outside).  At every other shape
+(several blocks a head, a window, grouped K/V, heads that do not tile the
+lanes, the XLA engines) the call transposes to the kernels above and back:
+the same numbers.  `flash.plan` / `flash.bwd_plan` say `layout`: bshd | bhsd.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -163,7 +182,7 @@ from ..observability import span
 
 __all__ = ["flash_attention", "merge_attention", "fwd_vmem_bytes",
            "fwd_working_set_bytes", "bwd_working_set_bytes", "KEPT", "kept",
-           "kept_bytes"]
+           "kept_bytes", "takes_heads_last", "heads_first_shapes"]
 
 NEG_INF = -1e30
 
@@ -186,7 +205,8 @@ _STEP_COST_SCORES = 256 * 256
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
                    head_dim: int = 128, num_q_blocks: int = 1,
                    dtype="float32", emit_lse: bool = True,
-                   v_dim: int | None = None, rows_per_step: int = 1) -> int:
+                   v_dim: int | None = None, rows_per_step: int = 1,
+                   heads: int = 1) -> int:
     """Analytic VMEM footprint of the buffers ONE forward pallas invocation
     declares — the kernel's own statement of the linter's pricing model
     (paddle_tpu.analysis.pallas.kernel_vmem_bytes; tests hold the two
@@ -197,25 +217,30 @@ def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
     those, and it is what _plan_blocks holds under its budget.  `v_dim`
     is the width of V and O where it is not the head_dim of Q and K.  The
     blocks are `rows_per_step` batch-head rows deep (_rows_per_step), and
-    a step of several declares no scratch (_flash_rows_kernel)."""
+    a step of several declares no scratch (_flash_rows_kernel).  `heads` >
+    1: the heads-last call (_flash_bshd_kernel), whose rows are BATCH rows,
+    `heads` heads side by side on the lanes, one block a head; it declares
+    no scratch at any count."""
     v_dim = head_dim if v_dim is None else v_dim
     blocks = [
-        ((rows_per_step, block_q, head_dim), dtype),   # q
-        ((rows_per_step, block_k, head_dim), dtype),   # k
-        ((rows_per_step, block_k, v_dim), dtype),      # v
-        ((rows_per_step, block_q, v_dim), dtype),      # o
+        ((rows_per_step, block_q, heads * head_dim), dtype),   # q
+        ((rows_per_step, block_k, heads * head_dim), dtype),   # k
+        ((rows_per_step, block_k, heads * v_dim), dtype),      # v
+        ((rows_per_step, block_q, heads * v_dim), dtype),      # o
     ]
     if emit_lse:
-        blocks.append(((rows_per_step, num_q_blocks, block_q), "float32"))
+        blocks.append(
+            ((rows_per_step, heads * num_q_blocks, block_q), "float32"))
     scratch = [((block_q, 1), "float32"), ((block_q, 1), "float32"),
-               ((block_q, v_dim), "float32")] if rows_per_step == 1 else []
+               ((block_q, v_dim), "float32")] if (
+                   rows_per_step == 1 and heads == 1) else []
     return (2 * sum(tile_padded_bytes(s, d) for s, d in blocks)
             + sum(tile_padded_bytes(s, d) for s, d in scratch))
 
 
 def fwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
                           dtype="float32", emit_lse=True, v_dim=None,
-                          rows_per_step=1) -> int:
+                          rows_per_step=1, heads=1) -> int:
     """fwd_vmem_bytes plus what a grid step computes between its two
     matmuls: the fp32 score block and the fp32 probability block, each
     [block_q, block_k].  At 128 x 128 they are 128 KB and were never
@@ -224,10 +249,15 @@ def fwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
     rows computes them one after the other, so the count holds one row's
     pair and only the declared blocks grow with `rows_per_step` (Mosaic
     compiles 32 rows of 256 x 256 x 64, 17 MB of blocks, where 32 pairs of
-    planes would be 16 MB more)."""
+    planes would be 16 MB more).  Not so the heads-last step (`heads` > 1,
+    _flash_bshd_kernel), which holds a plane more for every head it lays
+    out: chip-less, Mosaic allots 3.41 / 7.85 / 17.13 MB at 1 / 2 / 4 batch
+    rows of 8 heads of 256 x 256 x 64 where the declared blocks are 2.02 /
+    4.03 / 8.06, and refuses the last (PERF.md PR 57)."""
+    planes = 2 if heads == 1 else 2 + rows_per_step * heads
     return (fwd_vmem_bytes(block_q, block_k, head_dim, num_q_blocks, dtype,
-                           emit_lse, v_dim, rows_per_step)
-            + 2 * tile_padded_bytes((block_q, block_k), "float32"))
+                           emit_lse, v_dim, rows_per_step, heads)
+            + planes * tile_padded_bytes((block_q, block_k), "float32"))
 
 
 def _block_lengths(seq: int):
@@ -285,7 +315,7 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None,
 
 def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
                           dtype="float32", v_dim=None,
-                          rows_per_step=1) -> int:
+                          rows_per_step=1, heads=1) -> int:
     """What one grid step of the backward kernel holds: the double-buffered
     q, dO, k, v blocks, the packed lse and D planes and the dK, dV and
     (whole-row) dQ blocks it writes, the fp32 accumulators of dK and dV and
@@ -295,22 +325,29 @@ def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
     sequence: 2 MB of it at 2048 x 128 bf16, 8 MB of the 12 at 8192.
     `v_dim` is the width of V, dO and dV where it is not Q's and K's.  As
     in the forward, only the declared blocks grow with `rows_per_step`, and
-    a step of several rows has no accumulators (_flash_bwd_rows_kernel)."""
+    a step of several rows has no accumulators (_flash_bwd_rows_kernel).
+    `heads` > 1: the heads-last call (_flash_bwd_bshd_kernel), `heads` heads
+    side by side on the lanes of a batch row: O is an operand where the D
+    plane was, and there is no accumulator at any count."""
     def tile(shape, dt=dtype):
         return tile_padded_bytes(shape, dt)
 
     v_dim = head_dim if v_dim is None else v_dim
     rows = num_q_blocks * block_q
     n = rows_per_step
-    blocks = (tile((n, block_q, head_dim))              # q
-              + tile((n, block_q, v_dim))               # dO
-              + 2 * tile((n, block_k, head_dim))        # k, dK
-              + 2 * tile((n, block_k, v_dim))           # v, dV
-              + tile((n, rows, head_dim))               # dQ
-              + 2 * tile((n, num_q_blocks, block_q), "float32"))
+    width, v_width = heads * head_dim, heads * v_dim
+    blocks = (tile((n, block_q, width))                 # q
+              + tile((n, block_q, v_width))             # dO
+              + 2 * tile((n, block_k, width))           # k, dK
+              + 2 * tile((n, block_k, v_width))         # v, dV
+              + tile((n, rows, width))                  # dQ
+              + tile((n, heads * num_q_blocks, block_q), "float32"))  # lse
+    blocks += (tile((n, num_q_blocks, block_q), "float32") if heads == 1
+               else tile((n, block_q, v_width)))        # D | O
     scratch = (tile((rows, head_dim), "float32")
                + tile((block_k, head_dim), "float32")
-               + tile((block_k, v_dim), "float32")) if n == 1 else 0
+               + tile((block_k, v_dim), "float32")) if (
+                   n == 1 and heads == 1) else 0
     return (2 * blocks + scratch
             + 4 * tile((block_k, block_q), "float32"))
 
@@ -710,40 +747,69 @@ def _rows_of_step(rows_per_step, row):
     jax.lax.fori_loop(0, rows_per_step, body, 0, unroll=True)
 
 
+def _head_forward(q, k, v, mask, scale, want_lse):
+    """One head whose whole score matrix is ONE block: (its output fp32
+    [Sq, width of v], its logsumexp as a column [Sq, 1] or None).  A block
+    that is the first and the last of its row needs no state: the
+    online-softmax update from the floor (m = NEG_INF/2, l = 0, acc = 0) is
+    the plain softmax, bit for bit.  Rounding and masks are _flash_kernel's:
+    every head takes the mask (a select that changes nothing where nothing
+    cuts the block: a branch on klen, which is data, would keep the heads of
+    a step apart)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask, s, NEG_INF)
+    # the floor keeps a fully-masked row at p = 0, l = 0 (_flash_kernel)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), NEG_INF / 2)
+    p = jnp.exp(s - m)
+    l_fin = jnp.sum(p, axis=-1, keepdims=True)
+    acc = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    out = acc / jnp.maximum(l_fin, 1e-30)
+    if not want_lse:
+        return out, None
+    return out, jnp.where(
+        l_fin > 0.0, m + jnp.log(jnp.maximum(l_fin, 1e-30)), -NEG_INF)
+
+
+def _head_backward(q, k, v, do, lse_row, dvec_row, mask_t, scale):
+    """(dQ, dK, dV), fp32, of one head of one block: _flash_bwd_kernel's five
+    matmuls and one exp with nothing to accumulate (the sum of one block
+    needs no accumulator: 0 + x is x).  `lse_row`, `dvec_row`: [1, Sq];
+    `mask_t`: the transposed block's, [Sk, Sq]."""
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T on the contracting dims
+    st = jax.lax.dot_general(
+        k, q, nt, preferred_element_type=jnp.float32) * scale
+    pt = jnp.where(mask_t, jnp.exp(st - lse_row), 0.0)
+    dpt = jax.lax.dot_general(v, do, nt, preferred_element_type=jnp.float32)
+    dst = (pt * (dpt - dvec_row)).astype(q.dtype)
+    dv = jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+    dk = jnp.dot(dst, q, preferred_element_type=jnp.float32) * scale
+    dq = jax.lax.dot_general(
+        dst, k, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    return dq, dk, dv
+
+
 def _flash_rows_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref=None,
                        *, causal, scale, seq_k, causal_offset, rows_per_step):
     """_flash_kernel where a head's whole score matrix is ONE block
     (_rows_per_step): grid (batch*heads / rows_per_step,), a step takes
-    `rows_per_step` batch-head rows and computes each whole.  A block that
-    is the first and the last of its row needs no state: the online-softmax
-    update from the floor (m = NEG_INF/2, l = 0, acc = 0) is the plain
-    softmax, bit for bit, so nothing is initialised, rescaled or read back
-    (at 768 steps of 256 x 256 x 64 that, and not the pipeline, was what a
-    step cost before it computed anything: 1.23 ms a call against 0.50).
-    Every row takes the mask (a select that changes nothing where nothing
-    cuts the block: a branch on klen, which is data, would keep the rows of
-    a step apart).  Rounding and masks are _flash_kernel's.  Without
-    `lse_ref` (no scratch follows the outputs, so it is simply absent) the
-    lse is not written, as in _flash_kernel_fwd_only."""
+    `rows_per_step` batch-head rows and computes each whole
+    (_head_forward: nothing is initialised, rescaled or read back; at 768
+    steps of 256 x 256 x 64 that, and not the pipeline, was what a step
+    cost before it computed anything: 1.23 ms a call against 0.50).
+    Without `lse_ref` (no scratch follows the outputs, so it is simply
+    absent) the lse is not written, as in _flash_kernel_fwd_only."""
     shape = q_ref.shape[1], k_ref.shape[1]
 
     def _row(r, row):
-        q, k, v = q_ref[r], k_ref[r], v_ref[r]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(_block_mask(klen_ref, row, 0, 0, s.shape, *shape, seq_k,
-                                  causal, causal_offset), s, NEG_INF)
-        # the floor keeps a fully-masked row at p = 0, l = 0 (_flash_kernel)
-        m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), NEG_INF / 2)
-        p = jnp.exp(s - m)
-        l_fin = jnp.sum(p, axis=-1, keepdims=True)
-        acc = jnp.dot(p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32)
-        o_ref[r] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
-        if lse_ref is not None:  # static
-            lse = jnp.where(l_fin > 0.0,
-                            m + jnp.log(jnp.maximum(l_fin, 1e-30)), -NEG_INF)
+        mask = _block_mask(klen_ref, row, 0, 0, shape, *shape, seq_k, causal,
+                           causal_offset)
+        out, lse = _head_forward(q_ref[r], k_ref[r], v_ref[r], mask, scale,
+                                 lse_ref is not None)
+        o_ref[r] = out.astype(o_ref.dtype)
+        if lse is not None:
             # the packed plane [B*H, 1, block_q]: a lane-dense row a head
             lse_ref[r, 0, :] = jnp.transpose(lse, (1, 0))[0]
 
@@ -755,30 +821,144 @@ def _flash_bwd_rows_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                            seq_k, causal_offset, rows_per_step):
     """_flash_bwd_kernel where a head is one block, as _flash_rows_kernel
     is the forward's: `rows_per_step` batch-head rows a step, each row's
-    five matmuls and one exp written straight to dQ, dK and dV (the sum of
-    one block needs no accumulator: 0 + x is x)."""
+    _head_backward written straight to dQ, dK and dV."""
     shape = q_ref.shape[1], k_ref.shape[1]
 
     def _row(r, row):
-        q, k, do = q_ref[r], k_ref[r], do_ref[r]
-        nt = (((1,), (1,)), ((), ()))  # a @ b.T on the contracting dims
-        st = jax.lax.dot_general(
-            k, q, nt, preferred_element_type=jnp.float32) * scale
-        pt = jnp.exp(st - lse_ref[r, 0, :].reshape(1, -1))
-        pt = jnp.where(
-            _block_mask(klen_ref, row, 0, 0, st.shape, *shape, seq_k, causal,
-                        causal_offset, transposed=True), pt, 0.0)
-        dpt = jax.lax.dot_general(v_ref[r], do, nt,
-                                  preferred_element_type=jnp.float32)
-        dst = (pt * (dpt - dvec_ref[r, 0, :].reshape(1, -1))).astype(q.dtype)
-        dv_ref[r] = jnp.dot(
-            pt.astype(do.dtype), do,
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dk_ref[r] = (jnp.dot(dst, q, preferred_element_type=jnp.float32)
-                     * scale).astype(dk_ref.dtype)
-        dq_ref[r] = (jax.lax.dot_general(
-            dst, k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale).astype(dq_ref.dtype)
+        mask_t = _block_mask(klen_ref, row, 0, 0, shape[::-1], *shape, seq_k,
+                             causal, causal_offset, transposed=True)
+        dq, dk, dv = _head_backward(
+            q_ref[r], k_ref[r], v_ref[r], do_ref[r],
+            lse_ref[r, 0, :].reshape(1, -1), dvec_ref[r, 0, :].reshape(1, -1),
+            mask_t, scale)
+        dq_ref[r] = dq.astype(dq_ref.dtype)
+        dk_ref[r] = dk.astype(dk_ref.dtype)
+        dv_ref[r] = dv.astype(dv_ref.dtype)
+
+    _rows_of_step(rows_per_step, _row)
+
+
+_LANES = 128    # a vector register's lanes: the tile of the last dimension
+
+
+def _tiles_of_heads(head_dim):
+    """(width, per_tile, take, join) of the lane tiles of a batch row [S,
+    heads * head_dim] whose heads lie side by side on the lanes
+    (heads-last): a tile is `width` lanes, 128 or a head where it is wider,
+    and holds `per_tile` heads (head 64: a PAIR).  take(x, a): the tile x
+    [S, width] as head a's queries (or dO); join(xs): the tile of the
+    heads' results xs.
+
+    A tile stays whole.  Head a's queries are the tile with the other
+    heads' lanes zeroed, so q_a @ k2.T contracts all 128 lanes, half of
+    them against zeros: on a 128 x 128 MXU the pass a 64-deep contraction
+    takes anyway.  K and V are the tile as it lies: what p_a @ v2 puts on
+    the other heads' lanes is dropped by join's select.  Every load, store
+    and DMA is lane-dense.  (The other way, static 64-lane slices of the
+    tile's value and the results concatenated, shifts lanes at a half-tile
+    offset: forward with the logsumexp 0.57 ms against 0.26 at 96 x 256 x
+    8 x 64, forward and backward 1.04 against 0.73; PERF.md PR 57.)"""
+    width = max(_LANES, head_dim)
+    per_tile = width // head_dim
+    if per_tile == 1:
+        return width, 1, (lambda x, a: x), (lambda xs: xs[0])
+
+    def head_of_lane():
+        return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // head_dim
+
+    def take(x, a):
+        return jnp.where(head_of_lane() == a, x, jnp.zeros_like(x))
+
+    def join(xs):
+        out, head = xs[-1], head_of_lane()
+        for a in range(per_tile - 2, -1, -1):
+            out = jnp.where(head == a, xs[a], out)
+        return out
+
+    return width, per_tile, take, join
+
+
+def _flash_bshd_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref=None,
+                       *, causal, scale, seq_k, causal_offset, rows_per_step,
+                       heads):
+    """_flash_rows_kernel on the layout the projections write: q, k, v and
+    o are [B, S, heads * D] (heads-last), a grid step takes `rows_per_step`
+    BATCH rows and computes every head of each whole (_head_forward), a
+    lane tile at a time (_tiles_of_heads).  The mask is a batch row's, made
+    once for its heads; the logsumexp is written as [B, heads, Sq], row h of
+    a batch row's plane a lane-dense row (the packed plane of the
+    heads-first kernels, [B * heads, 1, Sq], is the same memory)."""
+    shape = q_ref.shape[1], k_ref.shape[1]
+    width, per_tile, take, join = _tiles_of_heads(q_ref.shape[2] // heads)
+
+    def _row(r, row):
+        mask = _block_mask(klen_ref, row, 0, 0, shape, *shape, seq_k, causal,
+                           causal_offset)
+        for t in range(heads // per_tile):
+            tile = slice(t * width, (t + 1) * width)
+            q2, k2, v2 = q_ref[r, :, tile], k_ref[r, :, tile], v_ref[r, :, tile]
+            outs = []
+            for a in range(per_tile):
+                out, lse = _head_forward(take(q2, a), k2, v2, mask, scale,
+                                         lse_ref is not None)
+                outs.append(out)
+                if lse is not None:
+                    lse_ref[r, t * per_tile + a, :] = jnp.transpose(
+                        lse, (1, 0))[0]
+            o_ref[r, :, tile] = join(outs).astype(o_ref.dtype)
+
+    _rows_of_step(rows_per_step, _row)
+
+
+def _flash_bwd_bshd_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
+                           lse_ref, *rest, causal, scale, seq_k,
+                           causal_offset, rows_per_step, heads, with_dlse):
+    """_flash_bwd_rows_kernel on the heads-last layout, as
+    _flash_bshd_kernel is the forward's: q, k, v, o, dO in and dQ, dK, dV
+    out are [B, S, heads * D], `rows_per_step` batch rows a step.
+
+    D = rowsum(dO * O) is made here, and as the ROW [1, Sq] the transposed
+    scores want: a tile's fp32 products dO * O [Sq, width], contracted over
+    their lanes with a constant [8, width] whose row a is 1 on head a's
+    lanes, are the heads' D as rows, on the MXU (fp32 in every pass: the
+    sum XLA's reduction made, which came out a column and carried a change
+    of layout to here).  With `with_dlse` a plane like the logsumexp's
+    follows it, the cotangent of the logsumexp handed out (_flash_lse says
+    why it leaves D)."""
+    dlse_ref = rest[0] if with_dlse else None
+    dq_ref, dk_ref, dv_ref = rest[-3:]
+    shape = q_ref.shape[1], k_ref.shape[1]
+    head_dim = q_ref.shape[2] // heads
+    width, per_tile, take, join = _tiles_of_heads(head_dim)
+    # row a: the lanes of the tile's head a (whole fp32 tiles of 8 rows)
+    ones_shape = (-(-per_tile // 8) * 8, width)
+    ones = (jax.lax.broadcasted_iota(jnp.int32, ones_shape, 1) // head_dim
+            == jax.lax.broadcasted_iota(jnp.int32, ones_shape, 0)
+            ).astype(jnp.float32)
+
+    def _row(r, row):
+        mask_t = _block_mask(klen_ref, row, 0, 0, shape[::-1], *shape, seq_k,
+                             causal, causal_offset, transposed=True)
+        for t in range(heads // per_tile):
+            tile = slice(t * width, (t + 1) * width)
+            q2, k2, v2 = q_ref[r, :, tile], k_ref[r, :, tile], v_ref[r, :, tile]
+            do2 = do_ref[r, :, tile]
+            dvecs = jax.lax.dot_general(
+                ones, do2.astype(jnp.float32)
+                * o_ref[r, :, tile].astype(jnp.float32),
+                (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)     # [8 or more, Sq]
+            grads = []
+            for a in range(per_tile):
+                h = t * per_tile + a
+                dvec = dvecs[a:a + 1, :]
+                if with_dlse:
+                    dvec = dvec - dlse_ref[r, h, :].reshape(1, -1)
+                grads.append(_head_backward(
+                    take(q2, a), k2, v2, take(do2, a),
+                    lse_ref[r, h, :].reshape(1, -1), dvec, mask_t, scale))
+            for ref, xs in zip((dq_ref, dk_ref, dv_ref), zip(*grads)):
+                ref[r, :, tile] = join(xs).astype(ref.dtype)
 
     _rows_of_step(rows_per_step, _row)
 
@@ -938,7 +1118,7 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
               block_k=bk, k_steps=nqb * nkb, k_steps_skipped=above + older,
               causal=int(causal), window=int(window or 0), kv_heads=G,
               chunks=1, skipped_causal=above, skipped_window=older,
-              rows_per_step=rows_per_step):
+              rows_per_step=rows_per_step, layout="bhsd"):
         call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
                          scale, Sk, Sk - Sq, str(q.dtype), interpret,
                          emit_lse=need_lse, dv=Dv, window=window,
@@ -1282,8 +1462,10 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
     steps, steps_skipped and its two parts skipped_causal and
     skipped_window (static, over one batch-head row, all trips),
     rows_per_step (the batch-head rows a grid step takes, of the call's `bh`
-    packable ones, _packable_rows: part of the shape) and engine, "pallas"
-    or "xla": the one place that says which."""
+    packable ones, _packable_rows: part of the shape), layout ("bhsd": these
+    are the heads-first kernels; _pallas_flash_bwd_bshd says "bshd" and
+    counts batch rows) and engine, "pallas" or "xla": the one place that
+    says which."""
     engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
                              window, bh)[1]
     trips = _bwd_trips(sq, sk, head_dim, dtype, causal, block_q, block_k,
@@ -1301,7 +1483,7 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
                 steps_skipped=above + older, engine=engine,
                 window=int(window or 0), chunks=len(trips),
                 skipped_causal=above, skipped_window=older,
-                rows_per_step=rows_per_step)
+                rows_per_step=rows_per_step, layout="bhsd")
 
 
 def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
@@ -1337,14 +1519,31 @@ KEPT = ("out", "lse")   # of _forward, what _flash_fwd keeps
 _BITS = {2: jnp.uint16, 4: jnp.uint32}     # an output's bits, by its width
 
 
-def kept(q, k, v, causal, window=None, force="auto") -> tuple:
+def kept(q, k, v, causal, window=None, force="auto", heads=None) -> tuple:
     """What a call of this shape keeps through the recomputation of the
     unit around it: KEPT where its backward is the Pallas kernel (the
     forward then has the logsumexp in hand), nothing where it is the XLA
-    recompute, which reads neither."""
+    recompute, which reads neither.  `heads`: the operands are heads-last,
+    as flash_attention takes them."""
+    if heads is not None:   # heads-last: as given, or transposed to here
+        if takes_heads_last(q, k, v, heads, window, force):
+            return KEPT
+        q, k, v = heads_first_shapes(q, k, v, heads)
     if window is not None and window >= k.shape[2]:
         window = None           # as flash_attention reads it
     return KEPT if _pallas_backward(q, k, v, causal, force, window) else ()
+
+
+def heads_first_shapes(q, k, v, heads):
+    """The shapes [B, H, Sq, D], [B, G, Sk, D], [B, G, Sk, Dv] of heads-last
+    operands q [B, Sq, heads * D], k [B, Sk, G * D], v [B, Sk, G * Dv]:
+    what flash_attention hands its heads-first kernels where the heads-last
+    ones do not take a call, for `kept` and `kept_bytes`."""
+    head_dim = q.shape[2] // heads
+    kv_heads = k.shape[2] // head_dim
+    return tuple(jax.ShapeDtypeStruct(
+        (x.shape[0], n, x.shape[1], x.shape[2] // n), x.dtype)
+        for x, n in ((q, heads), (k, kv_heads), (v, kv_heads)))
 
 
 def kept_bytes(q, v) -> int:
@@ -1414,11 +1613,12 @@ def _flash_bwd(causal, scale, force, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _lse_rows(packed, q):
-    """The packed logsumexp plane of a forward over q [B, H, Sq, D] as
-    [B, H, Sq]: NEG_INF where a row saw no key (the kernel writes -NEG_INF
-    there, which keeps its backward's exp(S - L) at 0)."""
-    B, H, Sq, _ = q.shape
+def _lse_rows(packed, shape):
+    """The packed logsumexp plane of a forward over q [B, H, Sq, .]
+    (`shape`'s first three) as [B, H, Sq]: NEG_INF where a row saw no key
+    (the kernel writes -NEG_INF there, which keeps its backward's
+    exp(S - L) at 0)."""
+    B, H, Sq = shape[:3]
     rows = packed.reshape(B, H, -1)[:, :, :Sq]
     return jnp.where(rows >= -NEG_INF / 2, NEG_INF, rows)
 
@@ -1430,18 +1630,18 @@ def _flash_lse(q, k, v, klen, causal, scale, force, window):
     sends a call that no kernel takes to _reference_attention, which jax
     differentiates as it is)."""
     out, lse = _forward(q, k, v, klen, causal, scale, force, True, window)
-    return out, _lse_rows(lse, q)
+    return out, _lse_rows(lse, q.shape)
 
 
 def _flash_lse_fwd(q, k, v, klen, causal, scale, force, window):
     out, lse = _forward(q, k, v, klen, causal, scale, force, True, window)
     if not _pallas_backward(q, k, v, causal, force, window):
-        return (out, _lse_rows(lse, q)), (q, k, v, klen, None, None)
+        return (out, _lse_rows(lse, q.shape)), (q, k, v, klen, None, None)
     # kept as _flash_fwd keeps them: both outputs are made of the two
     bits, lse = keep(jax.lax.bitcast_convert_type(
         out, _BITS[out.dtype.itemsize]), lse)
     return ((jax.lax.bitcast_convert_type(bits, out.dtype),
-             _lse_rows(lse, q)), (q, k, v, klen, bits, lse))
+             _lse_rows(lse, q.shape)), (q, k, v, klen, bits, lse))
 
 
 def _flash_lse_bwd(causal, scale, force, window, res, cts):
@@ -1470,6 +1670,222 @@ def _flash_lse_bwd(causal, scale, force, window, res, cts):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+# Heads-last (PR 57; the module docstring says why): q, k, v as the
+# projections write them, [B, S, H * D], taken as they lie where a head is
+# one block and transposed to the kernels above everywhere else.
+
+class _HeadsLastRows(NamedTuple):
+    """The BATCH rows a grid step of the heads-last kernels takes."""
+    forward: int            # with the logsumexp: a training step's
+    forward_only: int       # without it
+    backward: int
+
+
+def _heads_last_rows(batch, sq, sk, heads, head_dim, dtype, interpret=False):
+    """The batch rows a grid step takes (_HeadsLastRows) where the
+    heads-last kernels take a call of this shape, None where they do not
+    and the call is transposed to heads-first.  They take: heads that tile the lanes (a head 128 / n
+    lanes, a batch row whole 128-lane tiles), whole 128-row tiles of
+    queries and keys, ONE block a head in both directions with a batch row
+    of them inside the plan's share of VMEM, and a backward step that the
+    engine rule (_BWD_PALLAS_MIN_BLOCK_SCORES) gives the Pallas kernel.  It
+    reads the shape, nothing else."""
+    if (_LANES % head_dim or (heads * head_dim) % _LANES or sq % _LANES
+            or sk % _LANES):
+        return None
+
+    def fwd(lse):
+        return lambda n: fwd_working_set_bytes(
+            sq, sk, head_dim, 1, dtype, lse, None, n, heads)
+
+    def bwd(n):
+        return bwd_working_set_bytes(sq, sk, head_dim, 1, dtype, None, n,
+                                     heads)
+
+    if max(fwd(True)(1), bwd(1)) > _PLAN_VMEM_BUDGET:
+        return None
+    rows = _HeadsLastRows(*(_rows_per_step(batch, True, ws)
+                            for ws in (fwd(True), fwd(False), bwd)))
+    if (not interpret and sq * sk * heads * rows.backward
+            < _BWD_PALLAS_MIN_BLOCK_SCORES):
+        return None
+    return rows
+
+
+def takes_heads_last(q, k, v, heads, window=None, force="auto"):
+    """Whether flash_attention(q [B, Sq, heads * D], k, v, heads=heads) runs
+    the heads-last kernels (`layout` bshd on `flash.plan`) or transposes to
+    the heads-first ones: by the shape (_heads_last_rows), no window, a K/V
+    head a query head of one width, and an engine that is Pallas."""
+    if not (_use_pallas(force) or force == "interpret"):
+        return False
+    if window is not None and window < k.shape[1]:
+        return False
+    if not (q.shape[2] == k.shape[2] == v.shape[2]) or q.shape[2] % heads:
+        return False
+    return _heads_last_rows(
+        q.shape[0], q.shape[1], k.shape[1], heads, q.shape[2] // heads,
+        str(q.dtype), force == "interpret") is not None
+
+
+def _bshd_specs(batch, rows_per_step):
+    """(the [B] valid key counts whole in SMEM, rows(length, width): the
+    block of `rows_per_step` batch rows of a [B, length, width] operand)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def rows(length, width):
+        return pl.BlockSpec((rows_per_step, length, width),
+                            lambda b: (b, 0, 0))
+
+    return pl.BlockSpec((batch,), lambda b: (0,),
+                        memory_space=pltpu.SMEM), rows
+
+
+@functools.lru_cache(maxsize=128)
+def _fwd_bshd_call(batch, sq, sk, heads, d, causal, scale, dtype, interpret,
+                   emit_lse, rows_per_step):
+    """Memoized pallas_call of _flash_bshd_kernel (see _fwd_call), jitted:
+    a body that lays out sixteen heads in Python is traced once a shape and
+    not once a site (kernels/kda_mix.py: PERF.md PR 49)."""
+    import jax.experimental.pallas as pl
+
+    n = rows_per_step
+    klen, rows = _bshd_specs(batch, n)
+    out_specs = [rows(sq, heads * d)]
+    out_shape = [jax.ShapeDtypeStruct((batch, sq, heads * d),
+                                      jnp.dtype(dtype))]
+    if emit_lse:
+        out_specs.append(rows(heads, sq))
+        out_shape.append(
+            jax.ShapeDtypeStruct((batch, heads, sq), jnp.float32))
+    return jax.jit(pl.pallas_call(
+        functools.partial(
+            _flash_bshd_kernel, causal=causal, scale=scale, seq_k=sk,
+            causal_offset=sk - sq, rows_per_step=n, heads=heads),
+        grid=(batch // n,),
+        in_specs=[klen, rows(sq, heads * d), rows(sk, heads * d),
+                  rows(sk, heads * d)],
+        out_specs=out_specs, out_shape=out_shape, interpret=interpret))
+
+
+@functools.lru_cache(maxsize=128)
+def _bwd_bshd_call(batch, sq, sk, heads, d, causal, scale, q_dtype, k_dtype,
+                   v_dtype, interpret, with_dlse, rows_per_step):
+    """Memoized pallas_call of _flash_bwd_bshd_kernel (see _bwd_call),
+    jitted as _fwd_bshd_call is."""
+    import jax.experimental.pallas as pl
+
+    n = rows_per_step
+    klen, rows = _bshd_specs(batch, n)
+    q_rows, k_rows, plane = (rows(sq, heads * d), rows(sk, heads * d),
+                             rows(heads, sq))
+    return jax.jit(pl.pallas_call(
+        functools.partial(
+            _flash_bwd_bshd_kernel, causal=causal, scale=scale, seq_k=sk,
+            causal_offset=sk - sq, rows_per_step=n, heads=heads,
+            with_dlse=with_dlse),
+        grid=(batch // n,),
+        in_specs=[klen, q_rows, k_rows, k_rows, q_rows, q_rows, plane]
+        + [plane] * with_dlse,
+        out_specs=[q_rows, k_rows, k_rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, sq, heads * d), jnp.dtype(q_dtype)),
+            jax.ShapeDtypeStruct((batch, sk, heads * d), jnp.dtype(k_dtype)),
+            jax.ShapeDtypeStruct((batch, sk, heads * d), jnp.dtype(v_dtype))],
+        interpret=interpret))
+
+
+def _pallas_flash_bshd(q, k, v, klen, heads, causal, scale, interpret=False,
+                       need_lse=True, rows_per_step=None):
+    """_pallas_flash of heads-last operands the kernels take as given
+    (takes_heads_last): q [B, Sq, heads * D], k, v [B, Sk, heads * D];
+    (out [B, Sq, heads * D], the logsumexp [B, heads, Sq] fp32 or None).
+    No transposition, no padding, no reshape.  `rows_per_step` (batch rows)
+    pins the plan for a test or the probe."""
+    B, Sq, width = q.shape
+    Sk, D = k.shape[1], width // heads
+    if rows_per_step is None:
+        rows = _heads_last_rows(B, Sq, Sk, heads, D, str(q.dtype), interpret)
+        rows_per_step = rows.forward if need_lse else rows.forward_only
+    with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=Sq, block_k=Sk,
+              k_steps=1, k_steps_skipped=0, causal=int(causal), window=0,
+              kv_heads=heads, chunks=1, skipped_causal=0, skipped_window=0,
+              rows_per_step=rows_per_step, layout="bshd"):
+        res = _fwd_bshd_call(B, Sq, Sk, heads, D, causal, scale, str(q.dtype),
+                             interpret, need_lse, rows_per_step)(klen, q, k, v)
+    return res[0], (res[1] if need_lse else None)
+
+
+def _pallas_flash_bwd_bshd(q, k, v, klen, out, lse, g, heads, causal, scale,
+                           interpret=False, rows_per_step=None, dlse=None):
+    """(dq, dk, dv) of _pallas_flash_bshd's call, each in its operand's
+    layout and dtype.  D = rowsum(dO * O) is the kernel's own work (`out`
+    is its operand); `dlse` [B, heads, Sq] is the cotangent of the
+    logsumexp where the call handed it out."""
+    B, Sq, width = q.shape
+    Sk, D = k.shape[1], width // heads
+    if rows_per_step is None:
+        rows_per_step = _heads_last_rows(B, Sq, Sk, heads, D, str(q.dtype),
+                                         interpret).backward
+    with span("flash.bwd_plan", sq=Sq, sk=Sk, head_dim=D, block_q=Sq,
+              block_k=Sk, steps=1, steps_skipped=0, engine="pallas", window=0,
+              chunks=1, skipped_causal=0, skipped_window=0,
+              rows_per_step=rows_per_step, kv_heads=heads, layout="bshd"):
+        call = _bwd_bshd_call(B, Sq, Sk, heads, D, causal, scale,
+                              str(q.dtype), str(k.dtype), str(v.dtype),
+                              interpret, dlse is not None, rows_per_step)
+        planes = (lse,) if dlse is None else (lse, dlse.astype(jnp.float32))
+        return call(klen, q, k, v, out, g.astype(q.dtype), *planes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_bshd(q, k, v, klen, heads, causal, scale, interpret, return_lse):
+    """_flash / _flash_lse of heads-last operands on the heads-last
+    kernels: out [B, Sq, heads * D], with `return_lse` (out, the rows'
+    logsumexp [B, heads, Sq])."""
+    out, lse = _pallas_flash_bshd(q, k, v, klen, heads, causal, scale,
+                                  interpret, need_lse=return_lse)
+    return (out, _lse_rows(lse, lse.shape)) if return_lse else out
+
+
+def _flash_bshd_fwd(q, k, v, klen, heads, causal, scale, interpret,
+                    return_lse):
+    out, lse = _pallas_flash_bshd(q, k, v, klen, heads, causal, scale,
+                                  interpret)
+    # kept as _flash_fwd keeps them
+    bits, lse = keep(jax.lax.bitcast_convert_type(
+        out, _BITS[out.dtype.itemsize]), lse)
+    out = jax.lax.bitcast_convert_type(bits, out.dtype)
+    return ((out, _lse_rows(lse, lse.shape)) if return_lse else out,
+            (q, k, v, klen, bits, lse))
+
+
+def _flash_bshd_bwd(heads, causal, scale, interpret, return_lse, res, cts):
+    q, k, v, klen, bits, lse = res
+    g, dlse = cts if return_lse else (cts, None)
+    with jax.named_scope("flash.bwd"):
+        dq, dk, dv = _pallas_flash_bwd_bshd(
+            q, k, v, klen, jax.lax.bitcast_convert_type(bits, q.dtype), lse,
+            g, heads, causal, scale, interpret, dlse=dlse)
+    return dq, dk, dv, jnp.zeros_like(klen)
+
+
+_flash_bshd.defvjp(_flash_bshd_fwd, _flash_bshd_bwd)
+
+
+def _heads_first(x, heads):
+    """x [B, S, heads * D] as [B, heads, S, D]."""
+    B, S, width = x.shape
+    return x.reshape(B, S, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _heads_last(x):
+    """x [B, H, S, D] as [B, S, H * D]."""
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
 def merge_attention(parts):
     """One softmax over several key sets from each set's own: `parts` is
     [(out [B, H, Sq, Dv], lse [B, H, Sq])], a set's attention output and
@@ -1485,13 +1901,20 @@ def merge_attention(parts):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
-                    force="auto", window=None, return_lse=False):
+                    force="auto", window=None, return_lse=False, heads=None):
     """q: [B, H, Sq, D]; k/v: [B, G, Sk, .] with G = H or a divisor of it
     (grouped-query attention: query head j reads key/value head
     j // (H / G); K and V are never repeated).  k_lengths: optional [B]
     valid key counts (key-padding mask).  window: under `causal`, a query
     sees the `window` keys that end at its diagonal (itself and the
     window - 1 before it); None: all of them.
+
+    heads: the operands are heads-LAST, q [B, Sq, heads * D], k/v [B, Sk,
+    G * .], the arrays a model's projections write, and so is the output,
+    [B, Sq, heads * Dv].  Where a head is one block the kernels take them as
+    they lie (takes_heads_last; `flash.plan` says `layout` bshd);
+    everywhere else they are transposed here, to the call above and back:
+    the same numbers at every shape.
 
     force: "auto" (pallas on TPU, jax elsewhere), "pallas", "interpret"
     (pallas interpreter — CPU testing), or "jax".  The backward's engine
@@ -1501,7 +1924,19 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
     row sees no key, k_lengths 0) and both take a cotangent, so that
     several calls over disjoint key sets merge into one softmax exactly
     (merge_attention), forward and backward."""
-    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+    if heads is not None:
+        if q.shape[2] % heads or k.shape[2] % (q.shape[2] // heads):
+            raise ValueError(f"flash_attention: {heads} heads-last heads in "
+                             f"q {q.shape} over k {k.shape}")
+        kv_heads = k.shape[2] // (q.shape[2] // heads)
+        if not takes_heads_last(q, k, v, heads, window, force):
+            res = flash_attention(
+                _heads_first(q, heads), _heads_first(k, kv_heads),
+                _heads_first(v, kv_heads), causal, scale, k_lengths, force,
+                window, return_lse)
+            return ((_heads_last(res[0]), res[1]) if return_lse
+                    else _heads_last(res))
+    elif q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
         raise ValueError(f"flash_attention: {q.shape[1]} query heads over "
                          f"{k.shape[1]} key and {v.shape[1]} value heads")
     if window is not None:
@@ -1509,14 +1944,17 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
         if not causal or window < 1:
             raise ValueError("flash_attention: `window` needs causal=True "
                              f"and at least 1 key, got {window}")
-        if window >= k.shape[2]:
+        if window >= k.shape[-2]:
             window = None       # every causal key is inside it
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(q.shape[-1] // (heads or 1))
     if k_lengths is None:
-        klen = jnp.full((q.shape[0],), k.shape[2], dtype=jnp.float32)
+        klen = jnp.full((q.shape[0],), k.shape[-2], dtype=jnp.float32)
     else:
         klen = jnp.asarray(k_lengths, dtype=jnp.float32).reshape(-1)
+    if heads is not None:
+        return _flash_bshd(q, k, v, klen, heads, causal, float(scale),
+                           force == "interpret", return_lse)
     if not return_lse:
         return _flash(q, k, v, klen, causal, float(scale), force, window)
     if _use_pallas(force) or force == "interpret":

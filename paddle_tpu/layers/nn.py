@@ -1239,14 +1239,21 @@ def beam_search_decode(ids, scores, beam_size, end_id, name=None,
 
 
 def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
-                    name=None, window=None, rope=None):
+                    name=None, window=None, rope=None, n_head=None):
     """Flash-attention in one op: q [B, H, S, D] over k/v [B, G, S, D], G =
     H or a divisor of it (grouped-query attention: query head j reads
     key/value head j // (H / G); K and V are never repeated), optional [B]
     valid key counts instead of an additive bias.  `window` (with causal):
     a query sees itself and the window - 1 keys before it.  `rope` labels
     the site's `attn.lower` span with the rotary rule that turned q and k
-    ("plain", "yarn"); it changes no number (TPU-native; see
+    ("plain", "yarn"); it changes no number.
+
+    With `n_head` the operands are heads-LAST, the arrays the projections
+    write: q [B, S, n_head * D] over k/v [B, S, G * D], and so is the
+    output, [B, S, n_head * D]: no transposition before or after the op.
+    Where a head is one block (S 256 at head 64) the kernels take them as
+    they lie; at any other shape the op transposes inside itself and gives
+    the heads-first numbers (TPU-native; see
     paddle_tpu/kernels/flash_attention.py)."""
     helper = LayerHelper("fused_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
@@ -1258,6 +1265,8 @@ def fused_attention(q, k, v, causal=False, scale=None, k_lengths=None,
         attrs["window"] = int(window)
     if rope:
         attrs["rope"] = str(rope)
+    if n_head:
+        attrs["n_head"] = int(n_head)
     helper.append_op(type="fused_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

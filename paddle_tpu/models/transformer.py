@@ -142,14 +142,13 @@ class _Builder:
             k = self.linear(kv_in, d, d, f"{name}_k", shard=[None, tp])
             v = self.linear(kv_in, d, d, f"{name}_v", shard=[None, tp])
 
-        def split_heads(x):
-            x = layers.reshape(x, shape=[0, 0, h, dh])
-            return layers.transpose(x, perm=[0, 2, 1, 3])  # [B, H, S, dh]
-
-        q, k, v = split_heads(q), split_heads(k), split_heads(v)
         if cfg.use_flash_attention and k_lengths is not None:
+            # heads-last: q, k, v go to the op as the projections wrote
+            # them, [B, S, H * dh], and ctx comes back so for the output
+            # projection; no transposition on either side (kernels/
+            # flash_attention.py, PR 57)
             ctx = layers.fused_attention(
-                q, k, v, causal=causal, k_lengths=k_lengths
+                q, k, v, causal=causal, k_lengths=k_lengths, n_head=h
             )
             if cfg.dropout:
                 # the flash kernel does not expose attention weights, so
@@ -157,6 +156,11 @@ class _Builder:
                 # flash-attention approximation of weight dropout)
                 ctx = layers.dropout(ctx, dropout_prob=cfg.dropout)
         else:
+            def split_heads(x):
+                x = layers.reshape(x, shape=[0, 0, h, dh])
+                return layers.transpose(x, perm=[0, 2, 1, 3])  # [B, H, S, dh]
+
+            q, k, v = split_heads(q), split_heads(k), split_heads(v)
             q = layers.scale(q, scale=dh ** -0.5)
             scores = layers.matmul(q, k, transpose_y=True)  # [B, H, Sq, Sk]
             scores = layers.elementwise_add(scores, bias)
@@ -164,8 +168,8 @@ class _Builder:
             if cfg.dropout:
                 weights = layers.dropout(weights, dropout_prob=cfg.dropout)
             ctx = layers.matmul(weights, v)  # [B, H, Sq, dh]
-        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-        ctx = layers.reshape(ctx, shape=[0, 0, d])
+            ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+            ctx = layers.reshape(ctx, shape=[0, 0, d])
         return self.linear(ctx, d, d, f"{name}_o", shard=[tp, None])
 
     def ffn(self, x, name):

@@ -31,9 +31,12 @@ def _fused_attn_infer(op, block):
     set_output(block, op, "Out", list(q.shape), q.dtype)
 
 
-def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
+def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool,
+                     heads_last: bool = False):
     """Wrap `attend(q, k, v[, k_lengths])` in a shard_map over `mesh`:
-    batch over dp, heads over tp where tp divides them, sequence whole.
+    batch over dp, heads over tp where tp divides them, sequence whole
+    (heads-first operands are [B, H, S, D]; `heads_last` ones [B, S, H * D],
+    whose lanes a cut over tp splits into whole heads).
     XLA cannot partition a Mosaic kernel by itself ("Mosaic kernels cannot
     be automatically partitioned") — without this the SPMD step of a
     flash-attention model does not compile for more than one chip.  Each
@@ -48,37 +51,50 @@ def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool):
     # `n_head`: the key/value heads where they are fewer than the query's
     tp = AXIS_TP if (mesh.has_axis(AXIS_TP)
                      and n_head % mesh.axis_size(AXIS_TP) == 0) else None
-    qkv = P(dp, tp, None, None)
+    qkv = P(dp, None, tp) if heads_last else P(dp, tp, None, None)
     in_specs = (qkv, qkv, qkv) + ((P(dp),) if has_lengths else ())
     # check_vma off: pallas_call has no replication rule
     return jax.shard_map(attend, mesh=mesh.mesh, in_specs=in_specs,
                          out_specs=qkv, check_vma=False)
 
 
-def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None):
+def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None, heads=None):
     """flash_attention of q [B, H, S, D] over k [B, G, S, D] and v [B, G,
-    S, Dv] (G = H, or a divisor of it: grouped-query attention), under a
-    shard_map where the program runs on a mesh of several devices.  The one
-    door of `fused_attention` and `latent_attention` to the kernel: it says
-    on the op's span `sp` what the site holds through the recomputation of
-    the unit around it (`kept`, `kept_bytes`: the kernel's output and
-    logsumexp where its backward is the Pallas kernel, nothing where it is
-    the XLA recompute) and adds the values to the context's `kept`."""
+    S, Dv] (G = H, or a divisor of it: grouped-query attention), or with
+    `heads` of heads-last q [B, S, heads * D] over k [B, S, G * D] and v
+    [B, S, G * Dv], under a shard_map where the program runs on a mesh of
+    several devices.  The one door of `fused_attention` and
+    `latent_attention` to the kernel: it says on the op's span `sp` what
+    the site holds through the recomputation of the unit around it (`kept`,
+    `kept_bytes`: the kernel's output and logsumexp where its backward is
+    the Pallas kernel, nothing where it is the XLA recompute) and adds the
+    values to the context's `kept`, and which layout the kernel takes
+    (`layout`: bshd where heads-last operands go to it as they lie, bhsd
+    where they are transposed first, or came heads-first)."""
     from ..kernels import flash_attention
-    from ..kernels.flash_attention import _use_pallas, kept, kept_bytes
+    from ..kernels.flash_attention import (
+        _use_pallas, heads_first_shapes, kept, kept_bytes, takes_heads_last)
 
-    names = kept(q, k, v, causal, window)
-    sp.set(kept=",".join(names), kept_bytes=kept_bytes(q, v) if names else 0)
+    names = kept(q, k, v, causal, window, heads=heads)
+    first = (q, k, v) if heads is None else heads_first_shapes(q, k, v, heads)
+    sp.set(kept=",".join(names),
+           kept_bytes=kept_bytes(first[0], first[2]) if names else 0)
     ctx.kept += len(names)
+    head_dim = heads and q.shape[2] // heads
 
     def attend(q, k, v, klen=None):
+        # a device's own heads, where a mesh cuts them
+        local = heads and q.shape[2] // head_dim
+        sp.set(layout="bshd" if heads and takes_heads_last(
+            q, k, v, local, window) else "bhsd")
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               k_lengths=klen, window=window)
+                               k_lengths=klen, window=window, heads=local)
 
     if (ctx.mesh is not None and ctx.mesh.num_devices > 1
             and _use_pallas("auto")):
-        attend = _shard_over_mesh(attend, ctx.mesh, k.shape[1],
-                                  klen is not None)
+        kv_heads = first[1].shape[1]
+        attend = _shard_over_mesh(attend, ctx.mesh, kv_heads,
+                                  klen is not None, heads is not None)
     return attend(*((q, k, v) + ((klen,) if klen is not None else ())))
 
 
@@ -87,7 +103,13 @@ def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None):
 def _fused_attention(ctx, ins, attrs):
     """Attention of Q [B, H, Sq, D] over K, V [B, G, Sk, .] (G = H or a
     divisor of it), `causal`, under a `window`, keys cut at KLengths: one
-    flash kernel (kernels/flash_attention.py).  Where the site's backward
+    flash kernel (kernels/flash_attention.py).  With the attr `n_head`
+    (which a MODEL sets: no flag) the operands and the output are
+    heads-last, Q [B, Sq, n_head * D] over K, V [B, Sk, G * .], the arrays
+    the projections write: where a head is one block the kernels take them
+    as they lie, at every other shape the call transposes inside itself and
+    runs the heads-first path, the same numbers (`attn.lower` says `layout`:
+    bshd | bhsd).  Where the site's backward
     is the Pallas kernel (by the shape) the kernel tags its output and
     logsumexp to survive the recomputation of the unit around the op
     (core.compiler.keep): the backward of a recomputed layer runs no second
@@ -95,23 +117,26 @@ def _fused_attention(ctx, ins, attrs):
     site was given, `kept` and `kept_bytes` what it holds through that
     recomputation ("" and 0 on the XLA recompute backward); the context's
     `kept` counts the values."""
-    q = data(ins["Q"][0])  # [B, H, Sq, D]
+    q = data(ins["Q"][0])  # [B, H, Sq, D], or [B, Sq, n_head * D]
     k = data(ins["K"][0])
     v = data(ins["V"][0])
     klen_in = ins.get("KLengths", [None])[0]
     klen = data(klen_in).reshape(-1) if klen_in is not None else None
     causal = bool(attrs.get("causal", False))
     window = int(attrs.get("window", 0)) or None
-    from ..kernels.flash_attention import _visible_pairs
+    heads = int(attrs.get("n_head", 0)) or None
+    from ..kernels.flash_attention import _visible_pairs, heads_first_shapes
 
-    seen = None if window is None or window >= k.shape[2] else window
+    first = (q, k, v) if heads is None else heads_first_shapes(q, k, v, heads)
+    (_, n_head, sq, _), (_, kv_heads, sk, _) = first[0].shape, first[1].shape
+    seen = None if window is None or window >= sk else window
     with span("attn.lower", kind="full" if seen is None else "sliding",
-              window=int(seen or 0), heads=int(q.shape[1]),
-              kv_heads=int(k.shape[1]), sq=int(q.shape[2]),
-              pairs=_visible_pairs(q.shape[2], k.shape[2], causal, seen),
+              window=int(seen or 0), heads=int(n_head),
+              kv_heads=int(kv_heads), sq=int(sq),
+              pairs=_visible_pairs(sq, sk, causal, seen),
               rope=str(attrs.get("rope") or "none")) as sp:
         out = _attend(ctx, sp, q, k, v, klen, causal,
-                      attrs.get("scale") or None, window)
+                      attrs.get("scale") or None, window, heads)
     return {"Out": [out]}
 
 

@@ -141,11 +141,22 @@ def _flash_fwd(shape):
                                             force="pallas")), (x, x, x)
 
 
-def _flash_bwd(shape):
+def _flash_fwd_heads_last(shape):
+    """The heads-last call on [B, S, H * D] operands, as a model's
+    projections write them (PR 57); the text says which kernel took it."""
+    from paddle_tpu.kernels import flash_attention
+
+    batch, seq, heads, head_dim = shape
+    x = _sds((batch, seq, heads * head_dim), jnp.bfloat16)
+    return (lambda q, k, v: flash_attention(
+        q, k, v, causal=True, force="pallas", heads=heads)), (x, x, x)
+
+
+def _flash_bwd(shape, forward=_flash_fwd):
     """Forward and backward through the real custom-vjp path, the loss
     returned as a step returns it; the backward's engine is read from the
     shape (kernels/flash_attention.py::_bwd_plan)."""
-    fwd, args = _flash_fwd(shape)
+    fwd, args = forward(shape)
     return jax.value_and_grad(
         lambda q, k, v: jnp.sum(fwd(q, k, v).astype(jnp.float32)),
         argnums=(0, 1, 2)), args
@@ -368,6 +379,12 @@ _MAIN_PATH_KERNELS = {
     "flash_bwd_pallas_ouro": lambda: _flash_bwd((2, 16, 2048, 128)),
     "flash_bwd_pallas_transformer_base": lambda: _flash_bwd((96, 8, 256, 64)),
     "flash_bwd_xla_one_row_s256": lambda: _flash_bwd((1, 1, 256, 64)),
+    # the same 96 x 8 heads as the model hands them over since PR 57, [96,
+    # 256, 8 x 64]: two batch rows a grid step, D = rowsum(dO * O) inside
+    "flash_fwd_transformer_base_heads_last":
+        lambda: _flash_fwd_heads_last((96, 256, 8, 64)),
+    "flash_bwd_pallas_transformer_base_heads_last":
+        lambda: _flash_bwd((96, 256, 8, 64), _flash_fwd_heads_last),
     "paged_decode_bf16_ps16": lambda: _paged(jnp.bfloat16, 16),
     "paged_decode_int8_ps32": lambda: _paged(jnp.int8, 32),
     "paged_decode_two_level_tables": lambda: _paged(jnp.bfloat16, 16,
@@ -386,6 +403,9 @@ def test_main_path_kernel_compiles_for_v5e(v5e, case):
         assert n == (7 if "bwd" in case else 3), (case, n)
     if "bwd_xla" in case:
         assert n == 1, (case, n)
+    if "heads_last" in case:    # taken as given: no copy, no transposition
+        assert n == (2 if "bwd" in case else 1), (case, n)
+        assert " transpose(" not in text and " copy(" not in text, case
 
 
 def test_flash_step_compiles_for_four_chips_under_data_parallelism(v5e):

@@ -609,7 +609,7 @@ def test_the_spans_say_what_each_site_was_given():
         for s in spans["router.lower"])
     assert all(s == dict(kind="full", window=0, heads=4, kv_heads=2, sq=S,
                          pairs=pairs, rope="partial", kept="out,lse",
-                         kept_bytes=4 * S * (16 * 2 + 4))
+                         kept_bytes=4 * S * (16 * 2 + 4), layout="bhsd")
                for s in spans["attn.lower"])
     assert all(p["kv_heads"] == 2 and p["causal"] for p in spans["flash.plan"])
     assert len(spans["flash.bwd_plan"]) == 3

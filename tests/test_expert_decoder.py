@@ -372,7 +372,8 @@ def test_flash_plans_at_the_cells_attention_shape():
     assert plan == dict(sq=2048, sk=2048, head_dim=192, block_q=512,
                         block_k=512, steps=16, steps_skipped=6,
                         engine="pallas", window=0, chunks=1,
-                        skipped_causal=6, skipped_window=0, rows_per_step=1)
+                        skipped_causal=6, skipped_window=0, rows_per_step=1,
+                        layout="bhsd")
     # v padded to 192 would plan 1024 x 256, as ISSUE 31 read chip-less
     padded = fa._bwd_plan(*args)
     assert (padded["block_q"], padded["block_k"], padded["engine"]) == \
@@ -429,7 +430,8 @@ def test_moe_lower_and_mla_lower_say_what_a_site_was_given():
         n_routed_experts=64, experts_held=8, expert_offset=0, top_k=6)
     assert spans["mla.lower"] == 3 * [dict(
         heads=2, qk_dim=192, v_dim=128, kv_rank=512, padded_v=0,
-        rope="rotary", kept="out,lse", kept_bytes=2 * 2 * 2048 * (128 * 2 + 4))]
+        rope="rotary", kept="out,lse", kept_bytes=2 * 2 * 2048 * (128 * 2 + 4),
+        layout="bhsd")]
     T = 2 * 2048
     assert spans["moe.lower"] == 2 * [dict(
         experts_total=64, experts_held=8, top_k=6, row_buffer=6 * T,

@@ -201,7 +201,8 @@ def test_skipped_count_in_the_span_equals_the_dense_count():
                               k_steps_skipped=int((~visible).sum()),
                               causal=1, window=0, kv_heads=1, chunks=1,
                               skipped_causal=int((~visible).sum()),
-                              skipped_window=0, rows_per_step=1)]
+                              skipped_window=0, rows_per_step=1,
+                                  layout="bhsd")]
 
 
 @pytest.mark.parametrize("causal,pin,k_steps,skipped", [
@@ -440,7 +441,7 @@ def test_bwd_plan_span_counts_equal_the_dense_count(sq, sk, bq, bk):
         steps=visible.size, steps_skipped=int((~visible).sum()),
         engine="pallas", window=0, chunks=1, kv_heads=1,
         skipped_causal=int((~visible).sum()), skipped_window=0,
-        rows_per_step=1)]
+        rows_per_step=1, layout="bhsd")]
 
 
 def test_repack_is_a_view_where_the_padded_lengths_agree():
@@ -687,6 +688,187 @@ def test_a_step_takes_one_row_where_a_head_is_not_one_plain_block(what):
         lambda *a: both(*a), q, kv, kv, klen)] == [1]
 
 
+# (e) heads-last: [B, S, H * D] operands as the projections write them
+# (PR 57) -------------------------------------------------------------------
+
+# name: (B, H, D, dtype, causal, k_lengths, whether the logsumexp is handed
+# out and takes a cotangent), all at [B, 256, H * D]: a head is one block
+HEADS_LAST_CASES = {
+    "causal_bf16": (2, 8, 64, jnp.bfloat16, True, None, False),
+    "causal_fp32": (2, 2, 64, jnp.float32, True, None, False),
+    # a batch row with no key at all beside rows that have them, one cut
+    # inside the block
+    "ragged_fully_masked_row_bf16": (3, 4, 64, jnp.bfloat16, False,
+                                     [256, 0, 77], False),
+    "ragged_fully_masked_row_fp32": (3, 2, 64, jnp.float32, False,
+                                     [256, 0, 77], False),
+    "causal_dlse_given_fp32": (2, 2, 64, jnp.float32, True, None, True),
+    "ragged_dlse_given_bf16": (3, 4, 64, jnp.bfloat16, False, [256, 0, 77],
+                               True),
+    # a lane tile of sixteen heads (D's constant takes two fp32 tiles of
+    # rows) and a head that IS a lane tile (nothing to take apart)
+    "sixteen_heads_a_tile_fp32": (2, 16, 8, jnp.float32, True, None, False),
+    "a_head_a_tile_fp32": (2, 2, 128, jnp.float32, False, [200, 256], False),
+}
+
+
+def _heads_last_inputs(seed, B, H, S, D, dtype):
+    rng = np.random.RandomState(seed)
+    q, k, v, g = (jnp.asarray(rng.randn(B, S, H * D), dtype)
+                  for _ in range(4))
+    return q, k, v, g, jnp.asarray(rng.randn(B, H, S), jnp.float32)
+
+
+@pytest.mark.parametrize("case", sorted(HEADS_LAST_CASES))
+def test_heads_last_matches_the_reference_forward_and_backward(case):
+    """flash_attention(heads=H) on [B, 256, H * 64] operands through the
+    interpreter against _reference_attention and jax.vjp of it on the
+    transposed operands: the output, the logsumexp where it is handed out,
+    and dQ, dK, dV in the operands' own layout; `flash.plan` and
+    `flash.bwd_plan` say `layout` bshd and batch rows a step."""
+    B, H, D, dtype, causal, lengths, with_lse = HEADS_LAST_CASES[case]
+    S = 256
+    q, k, v, g, dlse = _heads_last_inputs(57, B, H, S, D, dtype)
+    klen = None if lengths is None else jnp.asarray(lengths, jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+
+    def loss(attend):
+        def fn(q, k, v):
+            out, lse = attend(q, k, v)
+            total = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32))
+            if with_lse:    # a row without a key hands out NEG_INF
+                total += jnp.sum(jnp.where(lse > fa.NEG_INF / 2, lse, 0.0)
+                                 * dlse)
+            return total, (out, lse)
+        return jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True)
+
+    def ours(q, k, v):
+        res = fa.flash_attention(q, k, v, causal=causal, k_lengths=klen,
+                                 force="interpret", heads=H,
+                                 return_lse=with_lse)
+        return res if with_lse else (res, jnp.zeros((B, H, S)))
+
+    def reference(q, k, v):
+        out, lse = fa._reference_attention(
+            *(fa._heads_first(x, H) for x in (q, k, v)), causal, scale,
+            k_lengths=None if klen is None else klen.astype(jnp.int32),
+            with_lse=True)
+        return fa._heads_last(out), lse if with_lse else jnp.zeros((B, H, S))
+
+    assert fa.takes_heads_last(q, k, v, H, force="interpret")
+    spans = _spans(("flash.plan", "flash.bwd_plan"), loss(ours), q, k, v)
+    rows = fa._heads_last_rows(B, S, S, H, D, str(jnp.dtype(dtype)), True)
+    assert [(p["layout"], p["rows_per_step"], p["kv_heads"])
+            for p in spans["flash.plan"]] == [("bshd", rows.forward, H)]
+    assert [(p["layout"], p["rows_per_step"], p["engine"], p["steps"])
+            for p in spans["flash.bwd_plan"]] == [("bshd", rows.backward,
+                                                   "pallas", 1)]
+
+    (_, (out, lse)), grads = loss(ours)(q, k, v)
+    (_, (want, want_lse)), want_grads = loss(reference)(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    tol = _tol(dtype, dict(rtol=2e-4, atol=2e-5))
+    assert out.shape == q.shape and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(want), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=1e-5, atol=1e-5)
+    for name, x, w in zip("qkv", grads, want_grads):
+        assert x.dtype == dtype and x.shape == w.shape
+        np.testing.assert_allclose(np.asarray(x.astype(jnp.float32)),
+                                   np.asarray(w), err_msg="d" + name, **tol)
+    if lengths is not None and 0 in lengths:
+        row = lengths.index(0)
+        assert not np.any(np.asarray(out)[row])
+        assert not any(np.any(np.asarray(x)[row]) for x in grads)
+        if with_lse:
+            assert np.all(np.asarray(lse)[row] == fa.NEG_INF)
+
+
+@pytest.mark.parametrize("batch,heads,fwd_rows,fwd_only_rows,bwd_rows", [
+    (96, 8, 2, 2, 2),       # transformer-base on one chip
+    (24, 8, 2, 2, 2),       # a chip's share of it under dp = 4
+    (96, 2, 8, 8, 8), (30, 2, 10, 10, 10), (7, 8, 1, 1, 1), (9, 4, 3, 3, 3), (1, 8, 1, 1, 1)])
+def test_heads_last_rows_of_a_step_are_the_most_that_fit_and_divide(
+        batch, heads, fwd_rows, fwd_only_rows, bwd_rows):
+    """At [B, 256, heads x 64] bf16 a step's rows are BATCH rows of `heads`
+    heads: the largest divisor of B whose working set is inside the plan's
+    share.  The blocks are whole lane tiles (no 64 padded to 128: a batch
+    row of 8 heads is half the 8 heads-first rows' bytes), the forward
+    counts a plane more a head (Mosaic lays them out side by side), and the
+    backward holds O where the D plane was."""
+    def fwd(n, lse=True):
+        return fa.fwd_working_set_bytes(256, 256, 64, 1, "bfloat16", lse,
+                                        None, n, heads)
+
+    def bwd(n):
+        return fa.bwd_working_set_bytes(256, 256, 64, 1, "bfloat16", None, n,
+                                        heads)
+
+    assert fa._heads_last_rows(batch, 256, 256, heads, 64, "bfloat16") == \
+        (fwd_rows, fwd_only_rows, bwd_rows)
+    for ws, rows in ((fwd, fwd_rows), (bwd, bwd_rows)):
+        assert batch % rows == 0 and ws(rows) <= fa._PLAN_VMEM_BUDGET
+        assert all(ws(n) > fa._PLAN_VMEM_BUDGET
+                   for n in range(rows + 1, batch + 1) if batch % n == 0)
+    plane = 256 * 256 * 4
+    blocks = 256 * heads * 64 * 2
+    lse = -(-heads // 8) * 8 * 256 * 4      # whole fp32 tiles of 8 rows
+    assert fwd(2) - fwd(1) == 2 * (4 * blocks + lse) + heads * plane
+    assert bwd(2) - bwd(1) == 2 * (8 * blocks + lse)
+    assert 2 * fa.fwd_vmem_bytes(
+        256, 256, 64, 1, "bfloat16", False, None, 1, 8) == fa.fwd_vmem_bytes(
+            256, 256, 64, 1, "bfloat16", False, None, 8)
+
+
+# what: (H, G, Sq, Sk, window) of a heads-last site that is NOT one plain
+# block a head, or whose heads do not tile the lanes
+NOT_AS_GIVEN = {
+    "two_k_blocks": (2, 2, 256, 2304, None),
+    "window": (2, 2, 256, 256, 100),
+    "grouped": (4, 2, 256, 256, None),
+    "the_rehearsals_heads": (4, 4, 16, 16, None),
+}
+
+
+@pytest.mark.parametrize("what", sorted(NOT_AS_GIVEN))
+def test_heads_last_site_of_another_shape_gives_the_heads_first_numbers(what):
+    """Two k blocks, a window, grouped K/V or 4 heads of 8: the call
+    transposes inside itself and runs the heads-first path: the same bits,
+    forward and backward, and `flash.plan` says `layout` bhsd."""
+    H, G, Sq, Sk, window = NOT_AS_GIVEN[what]
+    B, D = 2, 8 if what == "the_rehearsals_heads" else 64
+    rng = np.random.RandomState(58)
+    q, g = (jnp.asarray(rng.randn(B, Sq, H * D), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(B, Sk, G * D), jnp.float32)
+            for _ in range(2))
+    klen = jnp.asarray([Sk, Sk // 3], jnp.float32)
+    assert not fa.takes_heads_last(q, k, v, H, window, force="interpret")
+
+    def last(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, k_lengths=klen,
+                                 force="interpret", window=window, heads=H)
+        return jnp.sum(out * g), out
+
+    def first(q, k, v):
+        out = fa.flash_attention(
+            fa._heads_first(q, H), fa._heads_first(k, G),
+            fa._heads_first(v, G), causal=True, k_lengths=klen,
+            force="interpret", window=window)
+        return jnp.sum(fa._heads_last(out) * g), fa._heads_last(out)
+
+    spans = _plan_spans(lambda *a: last(*a), q, k, v)
+    assert [p["layout"] for p in spans] == ["bhsd"]
+    (_, out), grads = jax.value_and_grad(last, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    (_, want), want_grads = jax.value_and_grad(first, argnums=(0, 1, 2),
+                                               has_aux=True)(q, k, v)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    for x, w in zip(grads, want_grads):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(w))
+
+
 # name: (B, H, G, S, D, Dv, window) of a decoder cell's attention, then what
 # the parent commit (PR 52) planned there: the forward's blocks, the
 # backward's, its chunks and its engine
@@ -799,21 +981,45 @@ def test_ouro_body_has_four_pallas_backward_sites():
 @pytest.mark.parametrize("name", ["flash.plan", "flash.bwd_plan"])
 def test_transformer_base_has_eighteen_sites_of_several_rows_a_step(name):
     """6 encoder self, 6 decoder self, 6 cross: a head is one 256 x 256
-    block, so every site's forward and backward take several batch-head rows
-    a grid step (here all 4: B 2, H 2; the cell's 768 go 16 and 12 a step),
-    and with them the backward is the Pallas kernel's (PR 53: the XLA
-    recompute kept all 18 before)."""
+    block and the model hands the op its projections' own arrays, so every
+    site's forward and backward are the heads-last kernels' (`layout`
+    bshd), several BATCH rows a grid step (here both: B 2 of H 2; the
+    cell's 96 of 8 heads go 2 a step, 16 heads, forward and backward), and
+    the backward is the Pallas kernel's (PR 53: the XLA recompute kept all
+    18 before)."""
     spans = _transformer_base_spans()[name]
     assert len(spans) == 18
-    assert {s["rows_per_step"] for s in spans} == {4}
+    assert {(s["layout"], s["rows_per_step"]) for s in spans} == {("bshd", 2)}
     assert {(s["sq"], s["sk"], s["head_dim"], s["block_q"], s["block_k"])
             for s in spans} == {(256, 256, 64, 256, 256)}
     if name == "flash.bwd_plan":
         assert {s["engine"] for s in spans} == {"pallas"}
         assert {(s["steps"], s["chunks"]) for s in spans} == {(1, 1)}
-    else:       # static counts over one batch-head row, as they always were
+    else:       # static counts over one head, as they always were
         assert {(s["k_steps"], s["causal"]) for s in spans} == \
             {(1, 0), (1, 1)}
+    assert {s["layout"] for s in _transformer_base_spans()["attn.lower"]} \
+        == {"bshd"}
+
+
+def test_transformer_base_hands_attention_its_projections_own_arrays():
+    """Between a projection and its fused_attention, and between the op
+    and the output projection, the main program holds no transpose2 and no
+    reshape2: q, k and v are the fused projection's three slices, [B, S,
+    H x 64], and the op says `n_head`."""
+    _transformer_base_step()       # builds the program into the default env
+    block = fluid.default_main_program().global_block()
+    ops = [op for op in block.ops if not op.type.endswith("_grad")]
+    sites = [op for op in ops if op.type == "fused_attention"]
+    assert len(sites) == 18 and {op.attr("n_head") for op in sites} == {2}
+    assert not [op.type for op in block.ops
+                if op.type.startswith(("transpose2", "reshape2"))]
+    made_by = {name: op.type for op in ops
+               for name in op.output_arg_names}
+    for op in sites:
+        assert {made_by[n] for n in op.input("Q") + op.input("K")
+                + op.input("V")} <= {"split", "elementwise_add"}
+        assert [len(block.var(n).shape) for n in op.output("Out")] == [3]
 
 
 @pytest.mark.parametrize("spans, sites, kept", [

@@ -506,7 +506,8 @@ def test_attn_lower_and_the_flash_plans_say_what_a_site_was_given():
     spans = _spans_of_a_step(
         ("attn.lower", "flash.plan", "flash.bwd_plan", "moe.lower"),
         max_length=S, sliding_window=W)
-    keeps = dict(kept="out,lse", kept_bytes=4 * S * (16 * 2 + 4))
+    keeps = dict(kept="out,lse", kept_bytes=4 * S * (16 * 2 + 4),
+                 layout="bhsd")
     sliding = dict(kind="sliding", window=W, heads=4, kv_heads=2, sq=S,
                    pairs=W * (W + 1) // 2 + (S - W) * W, rope="plain",
                    **keeps)

@@ -24,6 +24,11 @@ Where a head is one block `pallas` and the `step-*` rows run at the batch-head
 rows a grid step that _rows_per_step gives, and `--rows-per-step 1,2,4,...`
 pins each count in turn: `pallas-rows-N` the backward kernel alone,
 `step-pallas-rows-N` forward and backward with both held to N (PR 53).
+`bshd` / `step-bshd` are the heads-last kernels on [B, S, H * D] operands
+(PR 57: D = rowsum(dO * O) inside the kernel, O its operand; the heads-first
+rows take [B, H, S, D] ARGUMENTS, which a step never has, and their times
+hold the copy to the kernel's layout); `--rows-per-step` counts BATCH rows
+there.
 `--sweep` also pins every block pair the shape admits whose working set is
 under 1.5 x the plan's share; `--parent FILE` times another commit's
 `_pallas_flash_bwd` as it stands (`git show <commit>:paddle_tpu/kernels/
@@ -189,6 +194,36 @@ def main() -> int:
                       lambda: step(rule_threshold), pair, planned)]
         variants += [(f"step-pallas-rows-{n}",
                       functools.partial(step, 0, n), pair, n) for n in counts]
+        # heads-last (PR 57): [B, S, H * D] operands as the projections
+        # write them; D = rowsum(dO * O) is the kernel's own work
+        last = [fa._heads_last(x) for x in (q, k, v, g, out)]
+        lse_rows = lse.reshape(B, H, -1)
+        if fa.takes_heads_last(*last[:3], H, force=force):
+            def heads_last(**pins):
+                return jax.jit(
+                    lambda q, k, v, g, out: fa._pallas_flash_bwd_bshd(
+                        q, k, v, klen, out, lse_rows, g, H, causal, scale,
+                        interpret=a.rehearse, **pins))
+
+            def step_heads_last():
+                def loss(q, k, v, g):
+                    o = fa.flash_attention(q, k, v, causal=causal,
+                                           k_lengths=klen, force=force,
+                                           heads=H)
+                    return jnp.sum(o.astype(jnp.float32)
+                                   * g.astype(jnp.float32))
+
+                fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+                return lambda *args: fn(*args)[1]
+
+            rows_bshd = fa._heads_last_rows(B, S, S, H, D, "bfloat16",
+                                            a.rehearse).backward
+            variants += [("bshd", heads_last(), pair, rows_bshd),
+                         ("step-bshd", step_heads_last, pair, rows_bshd)]
+            variants += [
+                (f"bshd-rows-{n}", heads_last(rows_per_step=n), pair, n)
+                for n in map(int, filter(None, a.rows_per_step.split(",")))
+                if B % n == 0]
         if a.sweep:
             lens = fa._block_lengths(S)
             variants += [
@@ -203,20 +238,26 @@ def main() -> int:
             row = {"shape": name, "bh": B * H, "s": S, "d": D,
                    "causal": causal, "variant": label, "block_q": bq,
                    "block_k": bk, "seed": a.seed}
+            bshd = "bshd" in label
             if bq is not None:
                 n = rows_per_step[0] if rows_per_step else 1
                 row["rows_per_step"] = n
                 row["working_set_mb"] = round(fa.bwd_working_set_bytes(
-                    bq, bk, D, -(-S // bq), "bfloat16", None, n) / 2 ** 20, 3)
+                    bq, bk, D, -(-S // bq), "bfloat16", None, n,
+                    H if bshd else 1) / 2 ** 20, 3)
+            args = (q, k, v, g)
+            if bshd:    # the kernel alone also takes O; the step makes it
+                args = last[:4] if label.startswith("step-") else last
             try:
                 if label.startswith("step-"):
                     fn = fn()
-                got = [np.asarray(x.astype(jnp.float32))
-                       for x in fn(q, k, v, g)]
+                got = [np.asarray((fa._heads_first(x, H) if bshd
+                                   else x).astype(jnp.float32))
+                       for x in fn(*args)]
                 row["max_abs_err"] = max(float(np.max(np.abs(x - w)))
                                          for x, w in zip(got, want))
                 if not a.rehearse:  # an interpreter's time is no one's
-                    ms = _time_ms(fn, (q, k, v, g), a.calls)
+                    ms = _time_ms(fn, args, a.calls)
                     row.update(
                         ms_a_call=round(ms, 4),
                         tflops_causal_count=round(counted / ms / 1e9, 2),

@@ -10,7 +10,9 @@ it, and jax.experimental.pallas.ops.tpu.flash_attention as a yardstick, once
 at its shipped default blocks (all 128) and once at the plan's.  Where a
 head is one block, `plan` is also the plan's batch-head rows a grid step
 (_rows_per_step) and `--rows-per-step 1,2,4,...` pins each count in turn,
-with and without the lse (how PR 53 settled it).  `--parent
+with and without the lse (how PR 53 settled it); the `bshd` rows are the
+heads-last kernel on [B, S, H * D] operands (PR 57), where `--rows-per-step`
+counts BATCH rows.  `--parent
 FILE` times another commit's kernel beside them (`git show
 <commit>:paddle_tpu/kernels/flash_attention.py > chip_scratch/...`).  `--sweep`
 also pins every block pair a shape admits, which is how the plan's VMEM share
@@ -156,6 +158,29 @@ def main() -> int:
                  ours(*plan, need_lse=lse, rows_per_step=n), plan, n)
                 for n in map(int, filter(None, a.rows_per_step.split(",")))
                 if (B * H) % n == 0 for lse in (False, True)]
+        # heads-last: the operands as a model's projections write them, [B,
+        # S, H * D], handed to the kernel as they lie (the heads-first rows
+        # above take [B, H, S, D] ARGUMENTS, which a step never has: their
+        # times hold the copy to the kernel's layout, PERF.md PR 53)
+        last = [fa._heads_last(x) for x in (q, k, v)]
+        if fa.takes_heads_last(*last, H, force=(
+                "interpret" if a.rehearse else "pallas")):
+            def heads_last(lse, **pins):
+                return jax.jit(lambda q, k, v, klen: fa._pallas_flash_bshd(
+                    q, k, v, klen, H, causal, scale, interpret=a.rehearse,
+                    need_lse=lse, **pins)[0])
+
+            rows_bshd = fa._heads_last_rows(B, S, S, H, D, "bfloat16",
+                                            a.rehearse)
+            variants += [("bshd" + ("+lse" if lse else ""), heads_last(lse),
+                          plan, rows_bshd.forward if lse
+                          else rows_bshd.forward_only)
+                         for lse in (False, True)]
+            variants += [
+                (f"bshd-rows-{n}" + ("+lse" if lse else ""),
+                 heads_last(lse, rows_per_step=n), plan, n)
+                for n in map(int, filter(None, a.rows_per_step.split(",")))
+                if B % n == 0 for lse in (False, True)]
         if a.sweep:
             lens = fa._block_lengths(S)
             variants += [(f"pinned-{bq}x{bk}", ours(bq, bk), (bq, bk))
@@ -170,17 +195,21 @@ def main() -> int:
         for label, fn, (bq, bk), *rows_per_step in variants:
             lse = label.endswith("lse")
             n = rows_per_step[0] if rows_per_step else 1
+            bshd = label.startswith("bshd")
             row = {"shape": name, "bh": B * H, "s": S, "d": D,
                    "causal": causal, "variant": label, "block_q": bq,
                    "block_k": bk, "rows_per_step": n, "seed": a.seed,
                    "working_set_mb": round(fa.fwd_working_set_bytes(
-                       bq, bk, D, -(-S // bq), "bfloat16", lse, None, n)
-                       / 2 ** 20, 3)}
+                       bq, bk, D, -(-S // bq), "bfloat16", lse, None, n,
+                       H if bshd else 1) / 2 ** 20, 3)}
+            args = (*last, klen) if bshd else (q, k, v, klen)
             try:
-                got = np.asarray(fn(q, k, v, klen).astype(jnp.float32))
+                got = fn(*args)
+                got = np.asarray((fa._heads_first(got, H) if bshd
+                                  else got).astype(jnp.float32))
                 row["max_abs_err"] = float(np.max(np.abs(got - want)))
                 if not a.rehearse:  # an interpreter's time is no one's
-                    ms = _time_ms(fn, (q, k, v, klen), a.calls)
+                    ms = _time_ms(fn, args, a.calls)
                     row.update(
                         ms_a_call=round(ms, 4),
                         tflops_causal_count=round(counted / ms / 1e9, 2),
