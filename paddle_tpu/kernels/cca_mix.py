@@ -43,24 +43,26 @@ recomputed layer runs the forward kernel again only for what the flash
 backward reads.
 
 `plan` reads the tile from the shape and the VMEM it needs, or says that
-the shape does not tile (the op then runs compressed_conv_mix).
-force="interpret" is the CPU tests' door, as in kernels/flash_attention.py.
-tools/cca_mix_probe.py times the pair alone on the chip.
+the shape does not tile; the op asks kernels/engine.py whether the site
+runs this pair at all (ops/attention_ops.py::_compressed_conv_qkv) and
+runs compressed_conv_mix where it does not.  tools/cca_mix_probe.py times the
+pair alone on the chip.
 """
 
 from __future__ import annotations
 
 import functools
-import operator
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..analysis.pallas import V5E_VMEM_BYTES
 from ..core import amp
+from . import engine
+from .engine import add_up, back, roll
 
-__all__ = ["Geometry", "plan", "mix", "cca_mix", "moved_bytes"]
+__all__ = ["Geometry", "plan", "cca_mix", "moved_bytes"]
 
 # The widest tile `plan` takes and the narrowest it falls to.  On the chip
 # at the cell's shape (tools/cca_mix_probe.py --sweep, ms a sequence,
@@ -68,12 +70,9 @@ __all__ = ["Geometry", "plan", "mix", "cca_mix", "moved_bytes"]
 # 256 / 512 / 1024 rows and the backward 0.754 / 0.499 / 0.582 / 0.515: a
 # grid step's fixed cost and the halo's rows computed twice fall with the
 # tile, the values a head holds outgrow the registers with it.  The budget
-# below decides: 256 rows both ways at the cell's ten heads.
-_MAX_TILE = 512
-_MIN_TILE = 128
-
-# what the declared blocks and the kernel's live fp32 temporaries may take
-_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
+# (engine.PLAN_VMEM_BUDGET) decides: 256 rows both ways at the cell's ten
+# heads.
+_TILES = (512, 256, 128)
 
 
 class Geometry(NamedTuple):
@@ -93,11 +92,10 @@ class Geometry(NamedTuple):
     def groups(self):
         return self.heads + self.kv_heads
 
-
-def _halo_rows(dtype) -> int:
-    """A block of the narrowest aligned height: 8 rows of 32 bits, 16 of
-    16."""
-    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    @property
+    def tile(self):
+        """What `cca.lower` says of a site: the forward's."""
+        return self.fwd_tile
 
 
 def working_set_bytes(tile, halo, H, G, D, k1, dtype, backward) -> int:
@@ -127,20 +125,17 @@ def plan(S, H, G, D, k0, k1, rotary_dim, dtype, tile=None
     most D, the convolutions' reach within a halo block, and S a multiple
     of a tile whose working set fits.  `tile` pins both tiles for a test
     or the probe, never a model."""
-    halo = _halo_rows(dtype)
-    if (D % 128 or H % G or rotary_dim % 2 or not 0 < rotary_dim <= D
+    halo = engine.halo_rows(dtype)
+    if (D % engine.LANES or H % G or rotary_dim % 2 or not 0 < rotary_dim <= D
             or (k0 - 1) + (k1 - 1) > min(halo, 8) or k0 < 1 or k1 < 1):
         return None
 
     def widest(backward):
-        t = _MAX_TILE
-        while t >= _MIN_TILE:
-            if S % t == 0 and working_set_bytes(
-                    t, halo, H, G, D, k1, dtype, backward) \
-                    <= _PLAN_VMEM_BUDGET:
-                return t
-            t //= 2
-        return None
+        # heads do not block: the one candidate of channels is a head
+        found = engine.widest(
+            S, D, engine.LANES, lambda t, _: working_set_bytes(
+                t, halo, H, G, D, k1, dtype, backward), _TILES, (D,))
+        return found and found[0]
 
     if tile is not None:
         fwd = bwd = tile if S % tile == 0 and tile % halo == 0 else None
@@ -167,33 +162,16 @@ def moved_bytes(q, k, v, recomputed: bool) -> int:
 # ---------------------------------------------------------------------------
 # what both kernels compute, on [rows, D] fp32 values of one head
 # ---------------------------------------------------------------------------
-def _sum(terms):
-    return functools.reduce(operator.add, terms)
-
-
-def _roll(x, shift, axis):
-    from jax.experimental.pallas import tpu as pltpu
-
-    shift %= x.shape[axis]
-    return x if shift == 0 else pltpu.roll(x, shift, axis)
-
-
-def _back(x, steps):
-    """y[e] = x[e - steps]; the first `steps` rows wrap and are never
-    read."""
-    return _roll(x, steps, 0)
-
-
 def _ahead(x, steps, rows):
     """y[e] = x[e + steps] for the first `rows` rows of x (x is longer by
     the carried rows, so nothing read has wrapped)."""
-    return _roll(x, -steps, 0)[:rows]
+    return roll(x, -steps, 0)[:rows]
 
 
 def _partner(x, half, low):
     """x with the two halves of its first 2 * half lanes swapped (what lies
     past them is multiplied by a zero of the sin plane)."""
-    return jnp.where(low, _roll(x, -half, 1), _roll(x, half, 1))
+    return jnp.where(low, roll(x, -half, 1), roll(x, half, 1))
 
 
 def _unit(u):
@@ -204,9 +182,9 @@ def _unit(u):
 def _convolve(z, aw, ab, taps, bb, k0, k1, operand):
     """(a, c): convolution A over z [rows, D] and B over A, one head."""
     D = z.shape[1]
-    a = _sum(_back(z, k0 - 1 - j) * aw[j] for j in range(k0)) + ab
+    a = add_up(back(z, k0 - 1 - j) * aw[j] for j in range(k0)) + ab
     p = jnp.dot(a.astype(operand), taps, preferred_element_type=z.dtype)
-    c = _sum(_back(p[:, j * D:(j + 1) * D], k1 - 1 - j)
+    c = add_up(back(p[:, j * D:(j + 1) * D], k1 - 1 - j)
              for j in range(k1)) + bb
     return a, c
 
@@ -253,7 +231,7 @@ class _Site:
         """m_k of key head g over its rows: the mean of its query heads'
         m_q = (q~ + k~) / 2."""
         share = self.geo.heads // self.geo.kv_heads
-        return _sum((self.z(g * share + s) + zk) / 2
+        return add_up((self.z(g * share + s) + zk) / 2
                     for s in range(share)) / share
 
 
@@ -295,7 +273,7 @@ def _cca_mix_kernel(q_ref, k_ref, v_ref, qh_ref, kh_ref, vh_ref, cos_ref,
             continue
         top = vh_ref[0, :, cols].astype(jnp.float32) * site.seen
         v = jnp.concatenate([top, v_ref[0, :, cols].astype(jnp.float32)], 0)
-        shifted = _back(v, 1)
+        shifted = back(v, 1)
         if before:
             shifted = jnp.where(lane < before, v[halo:], shifted[halo:])
         else:
@@ -374,11 +352,11 @@ def _cca_mix_bwd_kernel(q_ref, k_ref, qh_ref, kh_ref, gq_ref, gk_ref, gv_ref,
             (((0,), (0,)), ((), ())), preferred_element_type=f32)
         dab_ref[:, cols] += total(da)
         for j in range(k0):
-            daw_ref[j:j + 1, cols] += total(da * _back(z, k0 - 1 - j))
+            daw_ref[j:j + 1, cols] += total(da * back(z, k0 - 1 - j))
         # and A's transpose k0 - 1 rows ahead, into da_scr
         ahead = jnp.concatenate([da, da_scr[n]], 0)
         da_scr[n] = da[halo:2 * halo]
-        dz = _sum(_ahead(ahead, k0 - 1 - j, rows) * aw_ref[j:j + 1, cols]
+        dz = add_up(_ahead(ahead, k0 - 1 - j, rows) * aw_ref[j:j + 1, cols]
                   for j in range(k0))
         return du, dz[halo:]
 
@@ -396,7 +374,7 @@ def _cca_mix_bwd_kernel(q_ref, k_ref, qh_ref, kh_ref, gq_ref, gk_ref, gv_ref,
                 dz + du / 2 + du_k / (2 * share)).astype(dq_ref.dtype)
             du_heads.append(du)
         dk_ref[0, :, site.cols(g)] = (
-            dz_k + du_k / 2 + _sum(du_heads) / 2).astype(dk_ref.dtype)
+            dz_k + du_k / 2 + add_up(du_heads) / 2).astype(dk_ref.dtype)
 
         cols, before = site.cols(g), _value_lanes(g, G, D)
         if before is None:
@@ -414,14 +392,10 @@ def _cca_mix_bwd_kernel(q_ref, k_ref, qh_ref, kh_ref, gq_ref, gk_ref, gv_ref,
 # the two calls
 # ---------------------------------------------------------------------------
 def _compiler_params(semantics, geo, dtype, backward):
-    from jax.experimental.pallas import tpu as pltpu
-
     tile = geo.bwd_tile if backward else geo.fwd_tile
-    need = working_set_bytes(tile, geo.halo, geo.heads, geo.kv_heads,
-                             geo.head_dim, geo.taps1, dtype, backward)
-    return pltpu.CompilerParams(
-        dimension_semantics=semantics,
-        vmem_limit_bytes=int(max(V5E_VMEM_BYTES, 2 * need)))
+    return engine.compiler_params(semantics, working_set_bytes(
+        tile, geo.halo, geo.heads, geo.kv_heads, geo.head_dim, geo.taps1,
+        dtype, backward))
 
 
 @functools.lru_cache(maxsize=64)
@@ -512,15 +486,15 @@ def _bwd_call(B, S, geo, dtype, interpret):
     )
 
 
-def _planes(S, D, rotary_dim, base):
-    """The rotary turn's two planes [S, D] fp32 (ops/attention_ops.py
-    ::_rotate's): cos, 1 past `rotary_dim`; sin with the first half's
-    sign turned, 0 past it."""
-    from ..ops.attention_ops import _rotary_angles
-
-    angle = _rotary_angles(S, rotary_dim, base)
+def _planes(S, D, freq):
+    """The rotary turn's two planes [S, D] fp32 at positions 0 .. S - 1
+    (ops/attention_ops.py::_rotate's; `freq`, its rotary_dim / 2 pairs'
+    frequencies): cos, 1 past `rotary_dim`; sin with the first half's sign
+    turned, 0 past it."""
+    angle = jnp.asarray(np.arange(S, dtype=np.float64)[:, None]
+                        * np.asarray(freq)[None, :], jnp.float32)
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    rest = D - rotary_dim
+    rest = D - 2 * len(freq)
     return (jnp.concatenate([cos, cos, jnp.ones((S, rest), cos.dtype)], 1),
             jnp.concatenate([-sin, sin, jnp.zeros((S, rest), sin.dtype)], 1))
 
@@ -537,28 +511,29 @@ def _kernel_parameters(a_w, a_b, b_w, b_b, tau, geo):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def cca_mix(q, k, v, a_w, a_b, b_w, b_b, tau, geo: Geometry, base: float,
+def cca_mix(q, k, v, a_w, a_b, b_w, b_b, tau, geo: Geometry, freq: tuple,
             interpret: bool = False):
     """compressed_conv_mix's (q^ [B, H, S, D], k^ and v [B, G, S, D]) of a
-    site whose shape `plan` tiled."""
+    site whose shape `plan` tiled; `freq`, the op's rotary frequencies
+    (ops/attention_ops.py::_inv_freq's geo.rotary_dim / 2, as a tuple)."""
     B, S, _ = q.shape
-    cos, sin = _planes(S, geo.head_dim, geo.rotary_dim, base)
+    cos, sin = _planes(S, geo.head_dim, freq)
     call = _fwd_call(B, S, geo, str(q.dtype), interpret)
     return tuple(call(
         q, k, v, q, k, v, cos, sin,
         *_kernel_parameters(a_w, a_b, b_w, b_b, tau, geo)))
 
 
-def _cca_mix_fwd(q, k, v, a_w, a_b, b_w, b_b, tau, geo, base, interpret):
-    return (cca_mix(q, k, v, a_w, a_b, b_w, b_b, tau, geo, base, interpret),
+def _cca_mix_fwd(q, k, v, a_w, a_b, b_w, b_b, tau, geo, freq, interpret):
+    return (cca_mix(q, k, v, a_w, a_b, b_w, b_b, tau, geo, freq, interpret),
             (q, k, a_w, a_b, b_w, b_b, tau))
 
 
-def _cca_mix_bwd(geo, base, interpret, inputs, cotangents):
+def _cca_mix_bwd(geo, freq, interpret, inputs, cotangents):
     q, k, a_w, a_b, b_w, b_b, tau = inputs
     B, S, _ = q.shape
     D, k1 = geo.head_dim, geo.taps1
-    cos, sin = _planes(S, D, geo.rotary_dim, base)
+    cos, sin = _planes(S, D, freq)
     call = _bwd_call(B, S, geo, str(q.dtype), interpret)
     dq, dk, dv, daw, dab, dtaps, dbb, dtau = call(
         q, k, q, k, *(g.astype(q.dtype) for g in cotangents), cos, sin,
@@ -571,25 +546,3 @@ def _cca_mix_bwd(geo, base, interpret, inputs, cotangents):
 
 
 cca_mix.defvjp(_cca_mix_fwd, _cca_mix_bwd)
-
-
-def mix(q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim, base,
-        force: str = "auto", tile=None):
-    """(compressed_conv_mix's three outputs, the geometry they were computed
-    under: None for the jax.numpy form).  The engine is read from the
-    shape: the kernel pair where the program is for a TPU and `plan` tiles
-    the site; force="interpret" runs the pair in the Pallas interpreter
-    (the CPU tests' door), force="jax" never; `tile` pins the tiles."""
-    from ..ops.attention_ops import compressed_conv_mix
-    from .flash_attention import _use_pallas
-
-    geo = None
-    if ((force == "interpret" or _use_pallas(force))
-            and q.dtype == k.dtype == v.dtype):
-        geo = plan(q.shape[1], H, G, q.shape[2] // H, a_w.shape[0],
-                   b_w.shape[0], rotary_dim, q.dtype, tile)
-    if geo is None:
-        return compressed_conv_mix(q, k, v, a_w, a_b, b_w, b_b, tau, H, G,
-                                   rotary_dim, base), None
-    return cca_mix(q, k, v, a_w, a_b, b_w, b_b, tau, geo, float(base),
-                   force == "interpret"), geo

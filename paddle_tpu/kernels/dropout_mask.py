@@ -37,9 +37,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import engine
+from .engine import LANES
+
 __all__ = ["Drawn", "threshold", "tiles", "draw"]
 
-_LANES = 128
 # an int8 tile is 32 sublanes of 128 lanes
 _SUBLANES = 32
 # elements a grid step draws: 2 MB of bits beside 0.5 MB of bytes, twice
@@ -69,7 +71,7 @@ def tiles(shape) -> Optional[Tuple[int, int, int]]:
     is a copy of the bytes, XLA's to place)."""
     n = int(np.prod(shape)) if len(shape) else 0
     last = int(shape[-1]) if len(shape) else 0
-    own = last % _LANES == 0 and 0 < last <= _BLOCK_ELEMENTS // _SUBLANES
+    own = last % LANES == 0 and 0 < last <= _BLOCK_ELEMENTS // _SUBLANES
     for cols in ((last,) if own else ()) + _WIDTHS:
         if n == 0 or n % (cols * _SUBLANES):
             continue
@@ -128,12 +130,13 @@ def _kernels(key, shape, below: int, mesh):
     """(mask, Drawn) by the kernel, or None where the site does not tile.
     On a mesh of several devices under a shard_map over its data-parallel
     axis, a shard of the leading axis a device (XLA cannot partition a
-    Mosaic kernel), where that axis divides."""
+    Mosaic kernel: kernels/engine.py's mesh rule), where that axis
+    divides."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AXIS_DP
 
-    several = mesh is not None and mesh.num_devices > 1
+    several = engine.several_devices(mesh)
     local = shape
     if several:
         if not (shape and mesh.has_axis(AXIS_DP)
@@ -159,14 +162,13 @@ def _kernels(key, shape, below: int, mesh):
 def draw(key, shape, p: float, *, mesh=None, force: str = "auto"):
     """(mask, Drawn): uint8 [shape], 1 where the element is kept, each
     independently with probability 1 - p, from `key`; a value XLA stores.
-    `force`: auto (module docstring) | pallas | xla."""
-    from .flash_attention import _use_pallas
-
+    `force`: kernels/engine.py's door (the core's generator has no
+    interpreter, jax's reads zeros: "interpret" draws as "jax" does)."""
     shape = tuple(int(d) for d in shape)
     below = threshold(p)
     if below in (0, 2 ** 32):   # nothing to draw
         return jnp.full(shape, below == 0, jnp.uint8), Drawn("xla", "none", 0)
-    if force != "xla" and _use_pallas(force):
+    if engine.use_pallas(force):
         drawn = _kernels(key, shape, below, mesh)
         if drawn is not None:
             return drawn

@@ -1,169 +1,77 @@
 """Flash attention for TPU (Pallas), forward AND backward.
 
-The reference computes attention as separate matmul/softmax/matmul ops
-(python/paddle/fluid/nets.py scaled_dot_product_attention), materializing
-the [Sq, Sk] score matrix in HBM.  The forward kernel streams K/V blocks
-through VMEM with the online-softmax recurrence (Dao et al.,
-FlashAttention), so HBM traffic stays O(S*D) and the MXU sees back-to-back
-block matmuls.
+K/V blocks stream through VMEM under the online-softmax recurrence (Dao et
+al., FlashAttention): HBM traffic is O(S D) and nothing score-shaped leaves
+VMEM (the reference, fluid/nets.py scaled_dot_product_attention, puts [Sq,
+Sk] scores in HBM).  Operands stay in the input dtype; scores, statistics
+and accumulators are fp32, the scale is on the fp32 scores, P and dS are
+cast to the operand dtype for the MXU alone.  Everything is read from a
+call's shape, `causal` and `window`: no flag, no argument a model sets
+(`force` is kernels/engine.py's door, the tests' and the probes').
 
-The forward's grid is planned from the shape (PR 28).  A grid step costs
-about 0.35 us before it computes anything, so at 128 x 128 blocks (all this
-kernel had while its only shape was S 256, head 64) a 32 x 2048 x 128 call
-was 8192 steps of overhead.  (What that cost is was read in PR 53: at one
-256 x 256 block a head, 768 heads a call, putting several heads into one
-grid step with the body as it stood moved 1.23 ms to 1.22; it is the state
-a step keeps between blocks, the fp32 m / l / acc scratch initialised,
-rescaled, written, read back and flushed under pl.when, not the pipeline's
-step.)  Two faces of one mechanism:
-- _plan_blocks: the blocks that take the fewest grid steps whose working
-  set (fwd_working_set_bytes: the declared buffers plus the fp32 score and
-  probability blocks) fits 3/4 of the v5e's scoped VMEM.  S 2048 causal
-  runs 1024 x 1024, S 256 one 256 x 256 block a head.  It reads the shape
-  and `causal`, nothing else: no flag, no argument a model sets.
-- _rows_per_step (PR 53), the plan's third quantity: where a head's whole
-  score matrix is ONE block in a direction's plan (no window, a K/V head a
-  query head) a grid step takes the most consecutive rows of the flattened
-  [B * H] axis, a divisor of it, whose working set fits the same share
-  (768 rows of 256 x 256 x 64 bf16: 16 a step forward, 12 backward), and
-  _flash_rows_kernel / _flash_bwd_rows_kernel compute each row whole: the
-  one block of a row is its first and its last, so the online-softmax
-  update from the floor is the plain softmax, bit for bit, and there is no
-  state to keep; the rows are laid out one after the other in the kernel,
-  and the scheduler runs one row's matmuls under another's softmax.  Same
-  operands, same masks (klen per row: a step's rows may cross a batch
-  row), same rounding.  On the chip 1.23 -> 0.50 ms a forward (0.56 with
-  the lse) and 1.51 -> 1.17 a backward at 768 x 256 x 64
-  (tools/flash_fwd_probe.py, tools/flash_bwd_probe.py --rows-per-step;
-  PERF.md PR 53).  Everywhere else it is 1 and the call is what it was.
-- under `causal`, a k-block wholly above the diagonal (bottom-right
-  aligned) is neither fetched nor computed: its body is under pl.when, and
-  the K/V index maps repeat the block already held, which the pipeline
-  answers with no DMA.  The blocks that run take the iota/compare/select
-  mask only where the diagonal, the end of the keys or klen[b] cuts them.
-Rounding is where it was: operands in the input dtype, scores, softmax
-statistics and the accumulator fp32, the scale on the fp32 scores,
-probabilities cast to V's dtype for the second matmul; only the order of
-the online-softmax sums moves with the block size.  `flash.plan` (an
-observability span, at lowering) says what a call was given.
-tools/flash_fwd_probe.py times the forward alone on the chip.
+Three kernel families, picked by the shape:
+- blocks with state (_flash_kernel, _flash_bwd_kernel), several blocks a
+  head.  A grid step costs ~0.35 us before it computes anything, so the
+  plans (_plan_blocks, _plan_bwd_blocks) take the blocks with the fewest
+  grid steps whose working set fits engine.PLAN_VMEM_BUDGET: S 2048 causal
+  runs 1024 x 1024 forward (0.43 ms; 4.58 at 128 x 128) and 512 x 512
+  backward (four fp32 score planes and the row's dQ count there); the lse
+  plane is re-cut between the two for free (_repack).  The backward is
+  FlashAttention-2 in ONE kernel, grid (BH, k-blocks, q-blocks): P^T and
+  dS^T = P^T (dP^T - D) are the left operands of plain matmuls, dV and dK
+  accumulate across the q-blocks, dQ in an fp32 scratch that stays a whole
+  row (a TPU grid runs in order): five matmuls and one exp a step, 0.89 ms
+  where a dq and a dkv kernel took 1.29.  D = rowsum(dO * O) is made outside.
+- heads-first rows (_flash_rows_kernel, _flash_bwd_rows_kernel): where a
+  head's scores are ONE block (no window, a K/V head a query head) a step
+  takes the most rows of the flattened [B * H] axis, a divisor of it, that
+  fit the budget (_rows_per_step) and computes each whole, with no m / l /
+  acc state to initialise, rescale and flush: that state is what a
+  one-block step cost (768 x 256 x 64: 1.23 -> 0.50 ms forward, 1.51 -> 1.17
+  backward), and the result is the plain softmax bit for bit.
+- heads-last rows (_flash_bshd_kernel, _flash_bwd_bshd_kernel):
+  `flash_attention(..., heads=H)` takes [B, S, H * D] as a model's
+  projections write it (a [B, H, S, 64] array fills half a lane tile and was
+  copied at every custom-call boundary, 26 of transformer-train's 113 busy
+  ms).  Where a head is one block (_heads_last_rows: S 256 or 384 at head
+  64) a step is a few BATCH rows, a lane tile a pair of heads taken apart by
+  a select (_tiles_of_heads), the mathematics the rows kernels', and D is
+  made inside the backward kernel on the MXU.  Every other shape transposes
+  to heads-first and back: the same numbers.
+`flash.plan` / `flash.bwd_plan` (spans, at lowering) say what a site was
+given: blocks, steps, `rows_per_step`, `layout` (bshd | bhsd), `chunks`.
 
-The backward is the FlashAttention-2 recipe in ONE Pallas kernel (PR 30),
-on the forward's two principles:
-- forward additionally emits the per-row logsumexp L (packed, below);
-- D = rowsum(dO * O) is a cheap fused elementwise pass outside the kernel;
-- _flash_bwd_kernel: grid (BH, k-blocks, q-blocks), q innermost.  A step
-  rebuilds P^T = exp(S^T - L) for its block, transposed so that P^T and
-  dS^T = P^T * (dP^T - D) are the left operands of plain matmuls:
-  dV += P^T dO and dK += dS^T Q in VMEM scratch across the q-blocks, and
-  dQ += dS K (contracting the key dimension of dS^T and K) into an fp32
-  [Sqp, D] scratch that stays a whole batch-head row, because a TPU grid
-  runs in order.  Five block matmuls and one exp a step; a dq kernel and a
-  dkv kernel, which this replaced, take seven and two (on the chip 1.29 ms
-  a call against 0.89 at 32 x 2048 x 128 causal; the XLA recompute
-  backward 3.70: tools/flash_bwd_probe.py, PERF.md PR 30).
-- its grid is planned from the shape (_plan_bwd_blocks: the fewest steps
-  that run whose working set, bwd_working_set_bytes with FOUR fp32 score
-  planes and the row's dQ, fits the same share of VMEM); it need not be
-  the forward's: the packed lse plane is a view of [B*H, Sqp] and is
-  re-cut for free (_repack).  S 2048 causal runs 512 x 512.
-- under `causal` the q-blocks that end before a k-block's first key are
-  neither fetched nor computed (pl.when; the q/dO index maps wait at the
-  first q-block that runs, _q_block_index), and a block that runs takes
-  the mask only where the diagonal, the padded end or klen[b] cuts it.
-Zero-padded dO rows make padded q rows contribute exactly zero to dK/dV,
-and the same key-padding/causal masks as forward zero padded k columns.
-Rounding is the forward's: operands in the input dtype, scores, exp, D and
-every accumulator fp32, the scale on the fp32 scores (dS's on the fp32
-accumulators of dQ and dK), P and dS cast to the operand dtype only for
-the MXU.  `flash.bwd_plan` (a span, at lowering) says what a site was
-given; the backward's operations sit under the name scope `flash.bwd`.
+Skipped blocks: under `causal` a k-block above the diagonal, and under
+`window` (a query sees the `window` keys that end at its diagonal) one older
+than a q-block's window, is neither fetched nor computed (pl.when; the index
+maps repeat the block held or wait at the first that runs, _first_k_block,
+_q_block_index); a block is masked only where an edge, the padded end or
+klen[b] cuts it.  The plans count the steps that RUN, under a window weighed
+by the scores a block computes (_fewest_steps).
 
-Three things a site may ask of the same two kernels (PR 38), each read
-from the call's shape and `window`, none from a flag:
-- `window`: a query sees the `window` keys that end at its diagonal.  A
-  k-block wholly older than the window of a q-block's first row is treated
-  as one above the diagonal is: in the forward the k-axis of the grid counts
-  from the first block a q-block reads (_first_k_block), so the older ones
-  are no step at all; in the backward the q-blocks past a k-block's reach
-  are under pl.when and the index maps wait (_q_block_index).  A block that
-  straddles either edge takes the mask.  The plans count the steps that RUN
-  and, under a window, weigh them by the scores a block computes
-  (_fewest_steps): at window 1024 the widest blocks would be half waste.
-- grouped K/V: k and v come as [B, G, Sk, .], G a divisor of H, and query
-  head j reads head j // (H / G) through the index maps; they are never
-  repeated in HBM.  The backward writes a query head's dK and dV each and a
-  group's are added up after the kernel, in fp32 (_bwd_rows).
-- long rows: where the row's dQ does not fit VMEM (past S ~4k at head 128)
-  the backward runs as an outer loop over chunks of queries around the one
-  kernel (_bwd_trips): a chunk's dQ is the kernel's whole "row", the chunk is
-  handed only the keys it can see (from its first row's oldest key under a
-  window), and the chunks' dK and dV are added into the sequence's in fp32.
-  `flash.bwd_plan` says `chunks`; 1 is the kernel as it always ran.
+Grouped K/V: k, v [B, G, Sk, .], G a divisor of H; query head j reads head
+j // (H / G) through the index maps, never repeated in HBM; a group's dK, dV
+are added up after the kernel in fp32 (_bwd_rows).  Long rows: where the
+row's dQ does not fit VMEM (past S ~4k at head 128) the backward loops over
+chunks of queries around the one kernel (_bwd_trips; `chunks` on the span).
 
-Backward selection is read from the shape in one place (_bwd_plan): the
-Pallas kernel where a grid step (the plan's score block times the
-batch-head rows the step takes, _rows_per_step) has at least 384 x 384
-scores to spread the step's fixed cost over and the row's dQ, or a
-chunk's, fits VMEM, else jax.vjp of the reference formulation, a
-recompute backward that XLA fuses, whose forward emits no lse; on a TPU a
-site that falls there with more than _XLA_BWD_MAX_SCORE_BYTES of fp32
-scores is refused at lowering.  The rows a call has to give are part of
-the shape the rule reads (_packable_rows): at S 256 a call of three or
-more batch-head rows is the Pallas pair's (transformer-base's 18 sites:
-forward + backward 1.57 ms a site where the Pallas forward with XLA's
-backward takes 1.70 and the pair of PR 30, one row a step, took 2.58),
-one of one or two rows, or with grouped K/V, stays XLA's.  No flag, no
-model name:
-force="interpret" keeps the Pallas backward at every shape (the CPU
-tests' door), force="jax" keeps none.  pallas_call instances are memoized
-by static config, blocks and rows a step included, so every attention site
-of one shape (the 18 of a Transformer-base step are 3 shapes) shares one
-kernel payload.
+Two backward engines, one rule (_bwd_plan): the Pallas kernel where a grid
+step (score block x the rows it takes, _packable_rows) has at least
+_BWD_PALLAS_MIN_BLOCK_SCORES scores to spread its fixed cost over and the
+dQ fits; else jax.vjp of the reference, a recompute XLA fuses, refused at
+lowering on a TPU past _XLA_BWD_MAX_SCORE_BYTES of scores.  At S 256 three or
+more rows are the Pallas pair's (1.57 ms a site; 1.70 with XLA's backward).
+force="interpret" keeps the Pallas backward at every shape, "jax" none.
+Calls are memoized by static config: a shape's sites share one payload.
 
-What survives the recomputation of the unit around a site (PR 44): where
-the backward is the Pallas kernel the forward's output and logsumexp
-(`KEPT`; out's bytes and 4 a row, `kept_bytes`) are tagged with
-core.compiler.keep, the output as its bits (_flash_fwd says why).  Where
-the layer around the call is a rematerialised unit, its backward reads the
-first forward's two and traces no second forward: an O(S^2) pass for two
-O(S) arrays.  A site on the XLA recompute
-backward has no logsumexp and tags nothing; outside a rematerialised unit
-a tag does nothing.
-
-The logsumexp as an output (PR 56): `_flash` hands out `out` alone and
-holds the logsumexp as a residual; `flash_attention(..., return_lse=True)`
-goes through `_flash_lse`, the same two kernels under a second custom_vjp
-that hands out (out, the rows' logsumexp [B, H, Sq] fp32) and takes a
-cotangent for BOTH.  A row's logsumexp moves with its scores by their
-probabilities, dL/dS = P, so dS = P (dP - D + dlse): the cotangent is taken
-off D = rowsum(dO * O) before the backward kernel runs (_bwd_rows), which is
-the kernel as it stands.  A row that sees no key (k_lengths 0) hands out
-NEG_INF and an output of zeros, and takes nothing back.  Calls over disjoint
-key sets then merge into ONE softmax exactly, forward and backward
-(merge_attention: each output weighted by exp(lse_i - logsumexp_i lse_i)):
-what EVA's window-and-summaries attention is built from
-(kernels/eva_attention.py).  Both outputs are made of the kept two, so a
-recomputed unit traces no second forward of such a site either.  A call
-without `return_lse` traces what it always traced.
-
-Heads-last (PR 57): `flash_attention(..., heads=H)` takes q, k, v as a
-model's projections write them, [B, S, H * D], and hands out the output so.
-At head 64 a [B, H, S, 64] array fills half a 128-lane tile: the compiler
-kept it in another layout and copied at every custom-call boundary, around
-the model's own transposes (26 of transformer-train's 113 busy ms moved
-attention's operands about; 14 were attention).  Where a head is one block
-(_heads_last_rows: S 256 or 384 at head 64) _flash_bshd_kernel /
-_flash_bwd_bshd_kernel take the operands as they lie: a grid step is a few
-BATCH rows, a lane tile is a pair of heads taken apart by a select
-(_tiles_of_heads), the head's mathematics is the rows kernels'
-(_head_forward, _head_backward), the logsumexp is [B, H, S] (the packed
-plane's memory), and D = rowsum(dO * O) is made INSIDE the backward kernel,
-as the row it is read as, by a product with a constant on the MXU (O is an
-operand; no reduction, no change of layout outside).  At every other shape
-(several blocks a head, a window, grouped K/V, heads that do not tile the
-lanes, the XLA engines) the call transposes to the kernels above and back:
-the same numbers.  `flash.plan` / `flash.bwd_plan` say `layout`: bshd | bhsd.
+Kept through recomputation: where the backward is Pallas, the forward's
+output (as bits, _flash_fwd) and logsumexp (`KEPT`, `kept_bytes`) are tagged
+core.compiler.keep, and a rematerialised layer traces no second forward.
+The logsumexp as an output: `return_lse=True` goes through `_flash_lse`, a
+second custom_vjp over the same kernels that hands out (out, lse [B, H, Sq]
+fp32; NEG_INF for a row with no key) and takes a cotangent for both (dS = P
+(dP - D + dlse), taken off D before the kernel), so calls over disjoint key
+sets merge into ONE softmax exactly (merge_attention; eva_attention.py).
 """
 
 from __future__ import annotations
@@ -176,24 +84,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..analysis.pallas import V5E_VMEM_BYTES, tile_padded_bytes
+from ..analysis.pallas import tile_padded_bytes
 from ..core.compiler import keep
 from ..observability import span
+from .engine import LANES, PLAN_VMEM_BUDGET, use_pallas, wants_kernels
 
 __all__ = ["flash_attention", "merge_attention", "fwd_vmem_bytes",
            "fwd_working_set_bytes", "bwd_working_set_bytes", "KEPT", "kept",
            "kept_bytes", "takes_heads_last", "heads_first_shapes"]
 
 NEG_INF = -1e30
-
-# What the forward's planned working set (fwd_working_set_bytes) may take:
-# 3/4 of the v5e's 16 MiB of scoped VMEM, which leaves the compiler its
-# headroom.  Settled on the chip (tools/flash_fwd_probe.py --sweep,
-# PERF.md PR 28): at 32 x 2048 x 128 causal the time falls with the steps
-# all the way to 1024 x 1024 (11.5 MB by this count, 0.43 ms against 4.58 at
-# 128 x 128); the count is cautious, Mosaic still compiles 20.5 MB of it and
-# refuses 22.
-_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
 
 # What a grid step costs before it computes anything (~0.35 us, PR 28), in
 # the scores the MXU computes in that time: how _fewest_steps weighs a
@@ -273,8 +173,9 @@ def _block_lengths(seq: int):
 
 def _fewest_steps(sq, sk, causal, working_set, window=None):
     """The (block_q, block_k) whose grid takes the fewest steps that run,
-    of the pairs whose `working_set(block_q, block_k)` bytes fit
-    _PLAN_VMEM_BUDGET; the wider key block where two tie.  Under `causal`
+    of the pairs whose `working_set(block_q, block_k)` bytes fit the
+    plan's budget (kernels/engine.py); the wider key block where two tie.
+    Under `causal`
     the steps counted are those that run: _skipped_steps are free.  Under
     `window` the steps that run are weighed by the scores they compute
     beside _STEP_COST_SCORES: a block that straddles an edge of the window
@@ -290,7 +191,8 @@ def _fewest_steps(sq, sk, causal, working_set, window=None):
 
     plans = [(bq, bk) for bq in _block_lengths(sq)
              for bk in _block_lengths(sk)]
-    fits = [plan for plan in plans if working_set(*plan) <= _PLAN_VMEM_BUDGET]
+    fits = [plan for plan in plans
+            if working_set(*plan) <= PLAN_VMEM_BUDGET]
     # a head so wide that not even the smallest blocks fit the share still
     # gets them: the share is headroom, not the compiler's limit
     return min(fits or plans[:1], key=steps_then_wide)
@@ -372,14 +274,14 @@ def _rows_per_step(bh, one_block, working_set):
     step costs before it computes anything (768 steps of 256 x 256 x 64:
     PERF.md PR 53), so a step takes the most consecutive rows of the
     flattened [B * H] axis, a divisor of it, whose `working_set(rows)`
-    bytes fit _PLAN_VMEM_BUDGET, and computes each whole
+    bytes fit the plan's budget, and computes each whole
     (_flash_rows_kernel, _flash_bwd_rows_kernel).  Everywhere else it is 1
     and the call is built as it always was.  Like the blocks it reads the
     shape, nothing else."""
     if not one_block:
         return 1
     return next(n for n in range(bh, 0, -1) if bh % n == 0
-                and (n == 1 or working_set(n) <= _PLAN_VMEM_BUDGET))
+                and (n == 1 or working_set(n) <= PLAN_VMEM_BUDGET))
 
 
 def _block_runs(qi, ki, block_q, block_k, causal_offset):
@@ -838,9 +740,6 @@ def _flash_bwd_rows_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     _rows_of_step(rows_per_step, _row)
 
 
-_LANES = 128    # a vector register's lanes: the tile of the last dimension
-
-
 def _tiles_of_heads(head_dim):
     """(width, per_tile, take, join) of the lane tiles of a batch row [S,
     heads * head_dim] whose heads lie side by side on the lanes
@@ -858,7 +757,7 @@ def _tiles_of_heads(head_dim):
     tile's value and the results concatenated, shifts lanes at a half-tile
     offset: forward with the logsumexp 0.57 ms against 0.26 at 96 x 256 x
     8 x 64, forward and backward 1.04 against 0.73; PERF.md PR 57.)"""
-    width = max(_LANES, head_dim)
+    width = max(LANES, head_dim)
     per_tile = width // head_dim
     if per_tile == 1:
         return width, 1, (lambda x, a: x), (lambda xs: xs[0])
@@ -1341,21 +1240,6 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
                 dv.astype(v.dtype))
 
 
-def _on_tpu() -> bool:
-    """True when the program being traced is for a TPU: the attached
-    device is one, or an Executor opened the TPU trace scope for a
-    chip-less compile (cost_analysis(platform="tpu"), the lowering gate,
-    analysis capture) — so that what those compile is the chip's program,
-    kernels included."""
-    from .. import flags
-
-    return flags.tpu_trace_active() or jax.devices()[0].platform == "tpu"
-
-
-def _use_pallas(force: str) -> bool:
-    return force == "pallas" or (force == "auto" and _on_tpu())
-
-
 # The backward's engine is read from the shape: the Pallas kernel where
 # a grid step (its score block [block_k, block_q], times the batch-head
 # rows the step takes) has at least this many scores over which to spread
@@ -1409,7 +1293,7 @@ def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window, bh=1):
                                   window)
         fits = bwd_working_set_bytes(
             bq, bk, head_dim, -(-rows // bq), dtype, v_dim
-        ) <= _PLAN_VMEM_BUDGET
+        ) <= PLAN_VMEM_BUDGET
         step = bq * bk * _bwd_rows_per_step(bh, rows, keys, bq, bk, head_dim,
                                             dtype, v_dim, window)
         return fits, step >= _BWD_PALLAS_MIN_BLOCK_SCORES
@@ -1492,7 +1376,7 @@ def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
     "interpret" (the CPU tests' door), never under "jax"."""
     if force == "interpret":
         return True
-    return _use_pallas(force) and _bwd_plan(
+    return use_pallas(force) and _bwd_plan(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
         v_dim=v.shape[3], window=window,
         bh=_packable_rows(q, k))["engine"] == "pallas"
@@ -1500,7 +1384,7 @@ def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
 
 def _forward(q, k, v, klen, causal, scale, force, need_lse, window=None):
     """(out, packed lse or None) by the engine `force` names."""
-    if _use_pallas(force) or force == "interpret":
+    if wants_kernels(force):
         return _pallas_flash(q, k, v, klen, causal, scale,
                              interpret=(force == "interpret"),
                              need_lse=need_lse, window=window)
@@ -1585,7 +1469,7 @@ def _flash_bwd(causal, scale, force, window, res, g):
                 interpret=(force == "interpret"), window=window,
             )
             return dq, dk, dv, jnp.zeros_like(klen)
-        if _use_pallas(force):
+        if use_pallas(force):
             scores = 4 * q.shape[0] * q.shape[1] * q.shape[2] * k.shape[2]
             if scores > _XLA_BWD_MAX_SCORE_BYTES:
                 raise ValueError(
@@ -1690,8 +1574,8 @@ def _heads_last_rows(batch, sq, sk, heads, head_dim, dtype, interpret=False):
     of them inside the plan's share of VMEM, and a backward step that the
     engine rule (_BWD_PALLAS_MIN_BLOCK_SCORES) gives the Pallas kernel.  It
     reads the shape, nothing else."""
-    if (_LANES % head_dim or (heads * head_dim) % _LANES or sq % _LANES
-            or sk % _LANES):
+    if (LANES % head_dim or (heads * head_dim) % LANES or sq % LANES
+            or sk % LANES):
         return None
 
     def fwd(lse):
@@ -1702,7 +1586,7 @@ def _heads_last_rows(batch, sq, sk, heads, head_dim, dtype, interpret=False):
         return bwd_working_set_bytes(sq, sk, head_dim, 1, dtype, None, n,
                                      heads)
 
-    if max(fwd(True)(1), bwd(1)) > _PLAN_VMEM_BUDGET:
+    if max(fwd(True)(1), bwd(1)) > PLAN_VMEM_BUDGET:
         return None
     rows = _HeadsLastRows(*(_rows_per_step(batch, True, ws)
                             for ws in (fwd(True), fwd(False), bwd)))
@@ -1717,7 +1601,7 @@ def takes_heads_last(q, k, v, heads, window=None, force="auto"):
     the heads-last kernels (`layout` bshd on `flash.plan`) or transposes to
     the heads-first ones: by the shape (_heads_last_rows), no window, a K/V
     head a query head of one width, and an engine that is Pallas."""
-    if not (_use_pallas(force) or force == "interpret"):
+    if not wants_kernels(force):
         return False
     if window is not None and window < k.shape[1]:
         return False
@@ -1957,7 +1841,7 @@ def flash_attention(q, k, v, causal=False, scale=None, k_lengths=None,
                            force == "interpret", return_lse)
     if not return_lse:
         return _flash(q, k, v, klen, causal, float(scale), force, window)
-    if _use_pallas(force) or force == "interpret":
+    if wants_kernels(force):
         return _flash_lse(q, k, v, klen, causal, float(scale), force, window)
     return _reference_attention(
         q, k, v, causal, float(scale), k_lengths=klen.astype(jnp.int32),

@@ -95,8 +95,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..analysis.pallas import V5E_VMEM_BYTES
 from ..core.compiler import keep
+from .engine import (F32, PLAN_VMEM_BUDGET, compiler_params, roll,
+                     wants_kernels)
 
 CHUNK = 64
 KEPT = ("out", "states")
@@ -104,8 +105,6 @@ KEPT = ("out", "states")
 # a few dozen such values at once
 _GROUP_BYTES = 8 << 20
 _HIGHEST = jax.lax.Precision.HIGHEST
-_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
-_F32 = jnp.float32
 
 
 def plan(batch: int, seq: int, heads: int, dim: int, chunk: int = CHUNK):
@@ -167,7 +166,7 @@ def moved_bytes(batch: int, seq: int, heads: int, dim: int,
 # the part of a chunk that does not read the state
 # ---------------------------------------------------------------------------
 def _mm(eq, a, b):
-    return jnp.einsum(eq, a, b, preferred_element_type=_F32)
+    return jnp.einsum(eq, a, b, preferred_element_type=F32)
 
 
 def _block_starts(gc, b):
@@ -192,7 +191,7 @@ def _decayed_products(qn, kn, gc, mm):
     c = gc.shape[-2]
     x = jnp.stack([kn, qn], axis=-3)
     out = jnp.sum(x * kn[..., None, :, :], axis=-1)[..., None] \
-        * jnp.eye(c, dtype=_F32)
+        * jnp.eye(c, dtype=F32)
     b = c // 2
     while b:
         own, nxt = _block_starts(gc, b)
@@ -245,8 +244,8 @@ def _inverse_cotangent(x, dx):
     return -jnp.einsum(
         "...ik,...lk->...il",
         jnp.einsum("...ji,...jk->...ik", x, dx, precision=_HIGHEST,
-                   preferred_element_type=_F32),
-        x, precision=_HIGHEST, preferred_element_type=_F32)
+                   preferred_element_type=F32),
+        x, precision=_HIGHEST, preferred_element_type=F32)
 
 
 _unit_lower_inverse.defvjp(
@@ -262,7 +261,7 @@ def _local(q, k, v, g, beta, eps):
     """Of chunks [..., C, D] (beta [..., C]): (W, U, Q exp(Gc) D^-1/2, P,
     K exp(Gc_C - Gc)) in q's dtype and exp(Gc_C) [..., D] fp32."""
     mm, (c, d) = q.dtype, q.shape[-2:]
-    qn, kn = _unit(q.astype(_F32), eps), _unit(k.astype(_F32), eps)
+    qn, kn = _unit(q.astype(F32), eps), _unit(k.astype(F32), eps)
     gc = jnp.cumsum(g, axis=-2)
     kk, qk = jnp.moveaxis(_decayed_products(qn, kn, gc, mm), -3, 0)
     below = jnp.tril(jnp.ones((c, c), bool), -1)
@@ -281,7 +280,7 @@ def _local(q, k, v, g, beta, eps):
 # the part that does: one chunk, forward and backward
 # ---------------------------------------------------------------------------
 def _corrected(w, u, mc):
-    return u.astype(_F32) - _mm("...cd,...dv->...cv", w, mc)
+    return u.astype(F32) - _mm("...cd,...dv->...cv", w, mc)
 
 
 def _after(m, u2, kd, gamma):
@@ -334,7 +333,7 @@ def _forward(q, k, v, g, beta, eps):
         after, out = jax.lax.scan(_chunk_fwd, m, _local(*xs, eps))
         return after, (out, m)
 
-    zero = jnp.zeros(q.shape[2:4] + (q.shape[-1],) * 2, _F32)
+    zero = jnp.zeros(q.shape[2:4] + (q.shape[-1],) * 2, F32)
     return jax.lax.scan(group, zero, (q, k, v, g, beta))[1]
 
 
@@ -394,8 +393,8 @@ class Tiles(NamedTuple):
     rows: int          # rows a grid step: a group of chunks of one head
     chunk: int
     unroll: int        # tiles a loop body of the kernels holds
-    fwd_vmem: int      # bytes of the forward's working set
-    bwd_vmem: int
+    fwd_vmem_bytes: int      # bytes of the forward's working set
+    bwd_vmem_bytes: int
 
 
 def working_set_bytes(rows, chunk, dim, itemsize, backward) -> int:
@@ -425,7 +424,7 @@ def kernel_tiles(batch, seq, heads, dim, chunk, dtype, rows=None,
     pin the group and the tiles a loop body holds for a test or the
     probe, never a model."""
     size = jnp.dtype(dtype).itemsize
-    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
         return None, f"operands of {jnp.dtype(dtype).name}"
     if dim % 128:
         return None, f"heads of {dim}: not whole 128-lane vectors"
@@ -438,7 +437,7 @@ def kernel_tiles(batch, seq, heads, dim, chunk, dtype, rows=None,
 
     def fits(r):
         return working_set_bytes(r, chunk, dim, size, True) \
-            <= _PLAN_VMEM_BUDGET
+            <= PLAN_VMEM_BUDGET
 
     if rows is None:
         rows = next((r for r in (_MAX_ROWS, _MAX_ROWS // 2, _TILE)
@@ -456,10 +455,9 @@ def engine(batch, seq, heads, dim, chunk, dtype, force="auto", rows=None,
            unroll=None):
     """The tiles where the site runs the kernel pair, None where it runs
     the jax.numpy engine: read from the shape and from what the program is
-    traced for (force="interpret": the CPU tests' door; "jax": never)."""
-    from .flash_attention import _use_pallas
-
-    if force != "interpret" and not _use_pallas(force):
+    traced for (`force`: kernels/engine.py's door).  The op reads no mesh
+    (ROADMAP D25): on several devices the pair is XLA's to partition."""
+    if not wants_kernels(force):
         return None
     tiles, why = kernel_tiles(batch, seq, heads, dim, chunk, dtype, rows,
                               unroll)
@@ -471,15 +469,7 @@ def engine(batch, seq, heads, dim, chunk, dtype, force="auto", rows=None,
 
 def _dot(a, b, dims, precision=None):
     return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
-                               preferred_element_type=_F32)
-
-
-def _roll(x, shift, axis=0):
-    """y[r] = x[r - shift] (jnp.roll's)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    shift %= x.shape[axis]
-    return x if shift == 0 else pltpu.roll(x, shift, axis)
+                               preferred_element_type=F32)
 
 
 def _across(vector, eye, axis):
@@ -494,9 +484,9 @@ def _running_sum(x, pos, chunk, reverse=False):
     s = 1
     while s < chunk:
         if reverse:
-            x = x + jnp.where(pos < chunk - s, _roll(x, -s), 0.0)
+            x = x + jnp.where(pos < chunk - s, roll(x, -s), 0.0)
         else:
-            x = x + jnp.where(pos >= s, _roll(x, s), 0.0)
+            x = x + jnp.where(pos >= s, roll(x, s), 0.0)
         s *= 2
     return x
 
@@ -512,7 +502,7 @@ def _middles(gc, pos, b):
     out = gc
     for p in range(2 * b):
         if p != b:
-            out = jnp.where(at == p, _roll(gc, p - b), out)
+            out = jnp.where(at == p, roll(gc, p - b), out)
     return out
 
 
@@ -537,7 +527,7 @@ class _Masks:
 def _tile_values(q, k, v, g, beta_row, masks, eps):
     """The planes of a tile that cost no matmul: unit q and k with their
     inverse lengths, Gc, beta down the rows, and the decays."""
-    q, k = q.astype(_F32), k.astype(_F32)
+    q, k = q.astype(F32), k.astype(F32)
     rq = jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + eps)
     rk = jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + eps)
     T, C, D = masks.T, masks.C, masks.D
@@ -550,7 +540,7 @@ def _tile_values(q, k, v, g, beta_row, masks, eps):
     return dict(
         qn=qn, kn=kn, rq=rq, rk=rk, gc=gc, eg=eg, beta=beta, v=v,
         ed=jnp.exp(last_rows - gc), gamma=[jnp.exp(t) for t in last],
-        kb=beta * (kn * eg), vb=beta * v.astype(_F32),
+        kb=beta * (kn * eg), vb=beta * v.astype(F32),
         qg=qn * eg * D ** -0.5)
 
 
@@ -600,7 +590,7 @@ def _tile_scan(m, loc, masks, mm):
         mc = m.astype(mm)
         wq = _dot(jnp.concatenate([loc["w"][rows], loc["qg"][rows]], axis=0),
                   mc, _NN)
-        u2 = (loc["u"][rows].astype(_F32) - wq[:C]).astype(mm)
+        u2 = (loc["u"][rows].astype(F32) - wq[:C]).astype(mm)
         starts.append(m)
         read.append(wq[C:])
         u2s.append(u2)
@@ -684,13 +674,13 @@ def _tile_pull(x, inv, kk, d, masks, mm):
     da = jnp.where(masks.below, _inverse_cotangent(
         inv, _dot(dwu, right, _NT)), 0.0)
     dbeta = jnp.sum(da * kk, axis=-1, keepdims=True) \
-        + jnp.sum(dkb * (kn * eg) + dvb * x["v"].astype(_F32), axis=-1,
+        + jnp.sum(dkb * (kn * eg) + dvb * x["v"].astype(F32), axis=-1,
                   keepdims=True)
     # the two decayed products, level by level
-    dprod = jnp.concatenate([da * beta, dp.astype(_F32) * D ** -0.5], axis=0)
+    dprod = jnp.concatenate([da * beta, dp.astype(F32) * D ** -0.5], axis=0)
     on_diagonal = jnp.sum(jnp.where(masks.eye, dprod[T:], 0.0), axis=-1,
                           keepdims=True)
-    as_row, dk_col = jnp.zeros((2 * T, D), _F32), jnp.zeros((T, D), _F32)
+    as_row, dk_col = jnp.zeros((2 * T, D), F32), jnp.zeros((T, D), F32)
     for b, e, sides in _halving(x, masks, mm):
         kept = jnp.where(masks.halves[b], dprod, 0.0).astype(mm)
         as_row = as_row + _dot(kept, sides[:T], _NN) \
@@ -798,11 +788,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
 
 
 def _compiler_params(need):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=int(max(V5E_VMEM_BYTES, 2 * need)))
+    return compiler_params(("parallel", "parallel", "arbitrary"),
+                                   need)
 
 
 def _specs(tiles, D, last=None):
@@ -837,9 +824,9 @@ def _fwd_call(B, S, H, D, tiles, dtype, eps, interpret):
         in_specs=[wide, wide, wide, wide, gate],
         out_specs=[wide, state],
         out_shape=[jax.ShapeDtypeStruct((B, S, H * D), jnp.dtype(dtype)),
-                   jax.ShapeDtypeStruct((B, H, S // R, D, D), _F32)],
-        scratch_shapes=[pltpu.VMEM((D, D), _F32)],
-        compiler_params=_compiler_params(tiles.fwd_vmem),
+                   jax.ShapeDtypeStruct((B, H, S // R, D, D), F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), F32)],
+        compiler_params=_compiler_params(tiles.fwd_vmem_bytes),
         interpret=interpret,
     )
 
@@ -858,14 +845,14 @@ def _bwd_call(B, S, H, D, tiles, dtype, eps, interpret):
         in_specs=[wide, wide, wide, wide, gate, state, wide],
         out_specs=[wide, wide, wide, wide, gate],
         out_shape=[jax.ShapeDtypeStruct((B, S, H * D), dtype)] * 3
-        + [jax.ShapeDtypeStruct((B, S, H * D), _F32),
-           jax.ShapeDtypeStruct((B, H, S // R, R // T, T), _F32)],
-        scratch_shapes=[pltpu.VMEM((D, D), _F32), pltpu.VMEM((D, D), _F32),
-                        pltpu.VMEM((R // tiles.chunk, D, D), _F32)]
+        + [jax.ShapeDtypeStruct((B, S, H * D), F32),
+           jax.ShapeDtypeStruct((B, H, S // R, R // T, T), F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), F32), pltpu.VMEM((D, D), F32),
+                        pltpu.VMEM((R // tiles.chunk, D, D), F32)]
         + [pltpu.VMEM((R, D), dtype)] * 4
-        + [pltpu.VMEM((R, T), dtype), pltpu.VMEM((R, T), _F32),
-           pltpu.VMEM((R, T), _F32)],
-        compiler_params=_compiler_params(tiles.bwd_vmem),
+        + [pltpu.VMEM((R, T), dtype), pltpu.VMEM((R, T), F32),
+           pltpu.VMEM((R, T), F32)],
+        compiler_params=_compiler_params(tiles.bwd_vmem_bytes),
         interpret=interpret,
     )
 
@@ -874,7 +861,7 @@ def _beta_by_tiles(beta, tiles):
     """beta [B, S, H] as [B, H, groups, tiles a group, T] fp32: a tile's
     betas one row of 128 lanes."""
     B, S, H = beta.shape
-    return jnp.moveaxis(beta.astype(_F32), 2, 1).reshape(
+    return jnp.moveaxis(beta.astype(F32), 2, 1).reshape(
         B, H, S // tiles.rows, tiles.rows // _TILE, _TILE)
 
 
@@ -921,7 +908,7 @@ def gated_delta_attention(q, k, v, g, beta, heads: int, chunk: int = CHUNK,
                    unroll)
     if tiles is None:
         return _scan_by_groups(q, k, v, g, beta, heads, chunk, float(eps))
-    return _kernels(q, k, v, g.astype(_F32), beta, heads, tiles, float(eps),
+    return _kernels(q, k, v, g.astype(F32), beta, heads, tiles, float(eps),
                     force == "interpret")
 
 
@@ -939,6 +926,6 @@ def _scan_by_groups(q, k, v, g, beta, heads: int, chunk: int, eps: float):
         return jnp.moveaxis(t, (1, 2, 4), (0, 1, 3))
 
     out = _scan(*(grouped(t, (D,)) for t in (q, k, v)),
-                grouped(g.astype(_F32), (D,)),
-                grouped(beta.astype(_F32), ()), float(eps))
+                grouped(g.astype(F32), (D,)),
+                grouped(beta.astype(F32), ()), float(eps))
     return jnp.moveaxis(out, (0, 1, 3), (1, 2, 4)).reshape(B, S, width)

@@ -32,10 +32,10 @@ and the norm's scale are handed in as such rows, and jax differentiates
 the few operations that make them outside.
 
 `conv_tiles` and `norm_tiles` read the tile from the shape and the VMEM it
-needs, or say that the shape does not tile (the op then runs the jax.numpy
-form).  force="interpret" is the CPU tests' door, as in
-kernels/flash_attention.py.  tools/kda_mix_probe.py times the pairs alone
-on the chip.
+needs, or say that the shape does not tile; the ops ask kernels/engine.py
+whether a site runs these pairs at all (ops/linear_attention_ops.py) and
+run their jax.numpy form where it does not.  tools/kda_mix_probe.py times the
+pairs alone on the chip.
 """
 
 from __future__ import annotations
@@ -46,8 +46,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ..analysis.pallas import V5E_VMEM_BYTES
-from .cca_mix import _back, _halo_rows, _roll, _sum
+from . import engine
+from .engine import (F32, LANES, add_up, back, columns, compiler_params, roll,
+                     sigmoid)
 
 __all__ = ["Tiles", "conv_tiles", "norm_tiles", "conv_decay", "gated_norm",
            "conv_moved_bytes", "norm_moved_bytes"]
@@ -66,11 +67,6 @@ __all__ = ["Tiles", "conv_tiles", "norm_tiles", "conv_decay", "gated_norm",
 # (kernels/cca_mix.py's sweep, PERF.md PR 45): no tile above 256 rows.
 _ROWS = (256, 128)
 _CHANNELS = (2048, 1024, 512, 256, 128)
-_LANES = 128
-
-# what the declared blocks and the kernel's live fp32 temporaries may take
-_PLAN_VMEM_BUDGET = (3 * V5E_VMEM_BYTES) // 4
-_F32 = jnp.float32
 
 
 class Tiles(NamedTuple):
@@ -78,8 +74,8 @@ class Tiles(NamedTuple):
     rows: int
     channels: int
     halo: int          # rows of the block before (and after) a tile; 0: none
-    fwd_vmem: int
-    bwd_vmem: int
+    fwd_vmem_bytes: int
+    bwd_vmem_bytes: int
 
 
 def conv_working_set(rows, channels, halo, taps, size, backward) -> int:
@@ -90,10 +86,10 @@ def conv_working_set(rows, channels, halo, taps, size, backward) -> int:
     params = (3 * taps + 2) * channels * 4
     if backward:
         blocks = (4 + 3 + 4) * tile * size + tile * 4 + 9 * edge * size
-        live = 20 * (rows + 2 * halo) * _LANES * 4
+        live = 20 * (rows + 2 * halo) * LANES * 4
         return 2 * (blocks + 2 * params) + live
     blocks = (4 + 3) * tile * size + tile * 4 + 3 * edge * size
-    return 2 * (blocks + params) + 12 * (rows + halo) * _LANES * 4
+    return 2 * (blocks + params) + 12 * (rows + halo) * LANES * 4
 
 
 def norm_working_set(rows, channels, head_dim, size, backward) -> int:
@@ -105,24 +101,11 @@ def norm_working_set(rows, channels, head_dim, size, backward) -> int:
 
 
 def _widest(seq, width, unit, need, rows, channels):
-    """(rows, channels) of the first tile of `_CHANNELS` x `_ROWS` that
-    divides the shape, is whole `unit`s of channels and fits the budget;
-    `rows` / `channels` pin either for a test or the probe, never a
-    model."""
-    for c in (_CHANNELS if channels is None else (channels,)):
-        for r in (_ROWS if rows is None else (rows,)):
-            if (seq % r == 0 and width % c == 0 and c % unit == 0
-                    and r % 8 == 0 and need(r, c) <= _PLAN_VMEM_BUDGET):
-                return r, c
-    return None
-
-
-def _one_dtype(*tensors):
-    dtype = jnp.dtype(tensors[0].dtype)
-    if any(jnp.dtype(t.dtype) != dtype for t in tensors) or dtype not in (
-            jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
-        return None
-    return dtype
+    """engine.widest over `_CHANNELS` x `_ROWS`; `rows` / `channels` pin
+    either for a test or the probe, never a model."""
+    return engine.widest(seq, width, unit, need,
+                         _ROWS if rows is None else (rows,),
+                         _CHANNELS if channels is None else (channels,))
 
 
 def conv_tiles(seq, width, taps, dtype, rows=None, channels=None
@@ -130,14 +113,14 @@ def conv_tiles(seq, width, taps, dtype, rows=None, channels=None
     """The tiles of a site before the scan, None where the shape does not
     tile: channels whole 128-lane vectors, the taps' reach within a halo
     block, S whole tiles of rows whose working set fits."""
-    halo, size = _halo_rows(dtype), jnp.dtype(dtype).itemsize
-    if width % _LANES or not 0 <= taps - 1 <= 8:
+    halo, size = engine.halo_rows(dtype), jnp.dtype(dtype).itemsize
+    if width % LANES or not 0 <= taps - 1 <= 8:
         return None
 
     def need(r, c, backward=True):
         return conv_working_set(r, c, halo, taps, size, backward)
 
-    found = _widest(seq, width, _LANES, need, rows, channels)
+    found = _widest(seq, width, LANES, need, rows, channels)
     if found is None or found[0] % halo:
         return None
     return Tiles(*found, halo, need(*found, False), need(*found))
@@ -149,14 +132,14 @@ def norm_tiles(seq, width, head_dim, dtype, rows=None, channels=None
     tile: heads whole 128-lane vectors, a block whole heads, S whole tiles
     of rows whose working set fits."""
     size = jnp.dtype(dtype).itemsize
-    if head_dim % _LANES or width % head_dim:
+    if head_dim % LANES or width % head_dim:
         return None
 
     def need(r, c, backward=True):
         return norm_working_set(r, c, head_dim, size, backward)
 
     found = _widest(seq, width, head_dim, need, rows, channels)
-    if found is None or found[0] % _halo_rows(dtype):
+    if found is None or found[0] % engine.halo_rows(dtype):
         return None
     return Tiles(*found, 0, need(*found, False), need(*found))
 
@@ -189,24 +172,16 @@ def norm_moved_bytes(o, gate, recomputed: bool) -> int:
 def _ahead(x, steps):
     """y[e] = x[e + steps]; the last `steps` rows wrap and are never
     read."""
-    return _roll(x, -steps, 0)
+    return roll(x, -steps, 0)
 
 
 def _total(x):
     return jnp.sum(x, axis=0, keepdims=True)
 
 
-def _sigmoid(x):
-    return 1.0 / (1.0 + jnp.exp(-x))
-
-
 def _softplus(x):
     """jax.nn.softplus's own form: logaddexp(x, 0)."""
     return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
-
-
-def _columns(width, unit):
-    return [slice(c, c + unit) for c in range(0, width, unit)]
 
 
 def _taps(w_ref, cols, taps):
@@ -216,7 +191,7 @@ def _taps(w_ref, cols, taps):
 def _convolve(x, w):
     """y[e] = sum_j w[j] x[e - (k - 1 - j)] over the rows of x."""
     k = len(w)
-    return _sum(_back(x, k - 1 - j) * w[j] for j in range(k))
+    return add_up(back(x, k - 1 - j) * w[j] for j in range(k))
 
 
 def _conv_decay_kernel(q_ref, k_ref, v_ref, f_ref, qb_ref, kb_ref, vb_ref,
@@ -225,17 +200,17 @@ def _conv_decay_kernel(q_ref, k_ref, v_ref, f_ref, qb_ref, kb_ref, vb_ref,
     import jax.experimental.pallas as pl
 
     # 0 at the first tile: nothing lies before position 0
-    seen = 1.0 - (pl.program_id(2) == 0).astype(_F32)
+    seen = 1.0 - (pl.program_id(2) == 0).astype(F32)
     streams = ((q_ref, qb_ref, wq_ref, qo_ref), (k_ref, kb_ref, wk_ref, ko_ref),
                (v_ref, vb_ref, wv_ref, vo_ref))
-    for cols in _columns(q_ref.shape[-1], _LANES):
+    for cols in columns(q_ref.shape[-1], LANES):
         for x_ref, before_ref, w_ref, o_ref in streams:
             x = jnp.concatenate(
-                [before_ref[0, :, cols].astype(_F32) * seen,
-                 x_ref[0, :, cols].astype(_F32)], 0)
+                [before_ref[0, :, cols].astype(F32) * seen,
+                 x_ref[0, :, cols].astype(F32)], 0)
             y = _convolve(x, _taps(w_ref, cols, taps))[halo:]
-            o_ref[0, :, cols] = (y * _sigmoid(y)).astype(o_ref.dtype)
-        z = f_ref[0, :, cols].astype(_F32) + dt_ref[:, cols]
+            o_ref[0, :, cols] = (y * sigmoid(y)).astype(o_ref.dtype)
+        z = f_ref[0, :, cols].astype(F32) + dt_ref[:, cols]
         g_ref[0, :, cols] = rate_ref[:, cols] * _softplus(z)
 
 
@@ -249,9 +224,9 @@ def _conv_decay_bwd_kernel(q_ref, k_ref, v_ref, f_ref, qb_ref, kb_ref, vb_ref,
     import jax.experimental.pallas as pl
 
     step = pl.program_id(2)
-    seen = 1.0 - (step == 0).astype(_F32)
+    seen = 1.0 - (step == 0).astype(F32)
     # 0 at the last tile: no row after it hands a cotangent back
-    more = 1.0 - (step == pl.num_programs(2) - 1).astype(_F32)
+    more = 1.0 - (step == pl.num_programs(2) - 1).astype(F32)
     tile = q_ref.shape[1]
     own = slice(halo, halo + tile)
 
@@ -266,31 +241,31 @@ def _conv_decay_bwd_kernel(q_ref, k_ref, v_ref, f_ref, qb_ref, kb_ref, vb_ref,
                 dwk_ref),
                (v_ref, vb_ref, va_ref, gv_ref, gva_ref, wv_ref, dv_ref,
                 dwv_ref))
-    for cols in _columns(q_ref.shape[-1], _LANES):
-        nothing = jnp.zeros((halo, _LANES), _F32)
+    for cols in columns(q_ref.shape[-1], LANES):
+        nothing = jnp.zeros((halo, LANES), F32)
         for (x_ref, before_ref, after_ref, g_ref, ga_ref, w_ref, dx_ref,
              dw_ref) in streams:
             w = _taps(w_ref, cols, taps)
             x = jnp.concatenate(
-                [before_ref[0, :, cols].astype(_F32) * seen,
-                 x_ref[0, :, cols].astype(_F32),
-                 after_ref[0, :, cols].astype(_F32)], 0)
+                [before_ref[0, :, cols].astype(F32) * seen,
+                 x_ref[0, :, cols].astype(F32),
+                 after_ref[0, :, cols].astype(F32)], 0)
             g = jnp.concatenate(
-                [nothing, g_ref[0, :, cols].astype(_F32),
-                 ga_ref[0, :, cols].astype(_F32) * more], 0)
+                [nothing, g_ref[0, :, cols].astype(F32),
+                 ga_ref[0, :, cols].astype(F32) * more], 0)
             y = _convolve(x, w)
-            s = _sigmoid(y)
+            s = sigmoid(y)
             dy = g * (s * (1.0 + y * (1.0 - s)))
             # the transpose reaches k - 1 rows ahead, into the block after
-            dx = _sum(_ahead(dy, taps - 1 - j) * w[j] for j in range(taps))
+            dx = add_up(_ahead(dy, taps - 1 - j) * w[j] for j in range(taps))
             dx_ref[0, :, cols] = dx[own].astype(dx_ref.dtype)
             # a row of y is counted by the tile that owns it
             for j in range(taps):
                 dw_ref[j:j + 1, cols] += _total(
-                    (dy * _back(x, taps - 1 - j))[own])
-        z = f_ref[0, :, cols].astype(_F32) + dt_ref[:, cols]
-        gg = gg_ref[0, :, cols].astype(_F32)
-        dz = gg * rate_ref[:, cols] * _sigmoid(z)
+                    (dy * back(x, taps - 1 - j))[own])
+        z = f_ref[0, :, cols].astype(F32) + dt_ref[:, cols]
+        gg = gg_ref[0, :, cols].astype(F32)
+        dz = gg * rate_ref[:, cols] * sigmoid(z)
         df_ref[0, :, cols] = dz.astype(df_ref.dtype)
         ddt_ref[:, cols] += _total(dz)
         drate_ref[:, cols] += _total(gg * _softplus(z))
@@ -303,9 +278,9 @@ def _unit(o, eps):
 
 def _gated_norm_kernel(o_ref, gate_ref, bias_ref, scale_ref, out_ref, *,
                        head_dim, eps):
-    for cols in _columns(o_ref.shape[-1], head_dim):
-        n, _ = _unit(o_ref[0, :, cols].astype(_F32), eps)
-        s = _sigmoid(gate_ref[0, :, cols].astype(_F32) + bias_ref[:, cols])
+    for cols in columns(o_ref.shape[-1], head_dim):
+        n, _ = _unit(o_ref[0, :, cols].astype(F32), eps)
+        s = sigmoid(gate_ref[0, :, cols].astype(F32) + bias_ref[:, cols])
         out_ref[0, :, cols] = (n * scale_ref[:, cols] * s).astype(
             out_ref.dtype)
 
@@ -320,10 +295,10 @@ def _gated_norm_bwd_kernel(o_ref, gate_ref, g_ref, bias_ref, scale_ref,
         dbias_ref[...] = jnp.zeros_like(dbias_ref)
         dscale_ref[...] = jnp.zeros_like(dscale_ref)
 
-    for cols in _columns(o_ref.shape[-1], head_dim):
-        n, r = _unit(o_ref[0, :, cols].astype(_F32), eps)
-        s = _sigmoid(gate_ref[0, :, cols].astype(_F32) + bias_ref[:, cols])
-        g = g_ref[0, :, cols].astype(_F32)
+    for cols in columns(o_ref.shape[-1], head_dim):
+        n, r = _unit(o_ref[0, :, cols].astype(F32), eps)
+        s = sigmoid(gate_ref[0, :, cols].astype(F32) + bias_ref[:, cols])
+        g = g_ref[0, :, cols].astype(F32)
         gn = g * n
         dscale_ref[:, cols] += _total(gn * s)
         dgate = gn * scale_ref[:, cols] * (s * (1.0 - s))
@@ -337,14 +312,6 @@ def _gated_norm_bwd_kernel(o_ref, gate_ref, g_ref, bias_ref, scale_ref,
 # ---------------------------------------------------------------------------
 # the four calls
 # ---------------------------------------------------------------------------
-def _compiler_params(semantics, need):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=semantics,
-        vmem_limit_bytes=int(max(V5E_VMEM_BYTES, 2 * need)))
-
-
 def _specs(B, S, C, tiles):
     """(grid, rows, before, after, whole): the block specs of a [B, S, C]
     stream's tile, of the halo blocks on either side of it and of a
@@ -386,8 +353,9 @@ def _conv_fwd_call(B, S, C, taps, tiles, dtype, interpret):
         in_specs=[rows] * 4 + [before] * 3 + [whole(taps)] * 3
         + [whole(1)] * 2,
         out_specs=[rows] * 4,
-        out_shape=[like] * 3 + [jax.ShapeDtypeStruct((B, S, C), _F32)],
-        compiler_params=_compiler_params(("parallel",) * 3, tiles.fwd_vmem),
+        out_shape=[like] * 3 + [jax.ShapeDtypeStruct((B, S, C), F32)],
+        compiler_params=compiler_params(
+            ("parallel",) * 3, tiles.fwd_vmem_bytes),
         interpret=interpret,
     ))
 
@@ -406,9 +374,9 @@ def _conv_bwd_call(B, S, C, taps, tiles, dtype, interpret):
         + [after] * 3 + [whole(h) for h in small],
         out_specs=[rows] * 4 + [whole(h) for h in small],
         out_shape=[like] * 4
-        + [jax.ShapeDtypeStruct((h, C), _F32) for h in small],
-        compiler_params=_compiler_params(
-            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem),
+        + [jax.ShapeDtypeStruct((h, C), F32) for h in small],
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem_bytes),
         interpret=interpret,
     ))
 
@@ -424,7 +392,8 @@ def _norm_fwd_call(B, S, C, head_dim, eps, tiles, dtype, interpret):
         in_specs=[rows] * 2 + [whole(1)] * 2,
         out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
-        compiler_params=_compiler_params(("parallel",) * 3, tiles.fwd_vmem),
+        compiler_params=compiler_params(
+            ("parallel",) * 3, tiles.fwd_vmem_bytes),
         interpret=interpret,
     ))
 
@@ -440,9 +409,9 @@ def _norm_bwd_call(B, S, C, head_dim, eps, tiles, dtype, interpret):
         grid=grid,
         in_specs=[rows] * 3 + [whole(1)] * 2,
         out_specs=[rows] * 2 + [whole(1)] * 2,
-        out_shape=[like] * 2 + [jax.ShapeDtypeStruct((1, C), _F32)] * 2,
-        compiler_params=_compiler_params(
-            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem),
+        out_shape=[like] * 2 + [jax.ShapeDtypeStruct((1, C), F32)] * 2,
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem_bytes),
         interpret=interpret,
     ))
 
@@ -467,7 +436,7 @@ def _conv_decay_bwd(tiles, interpret, inputs, cotangents):
     q, k, v, f, wq, wk, wv, dt, rate = inputs
     B, S, C = q.shape
     gq, gk, gv = (g.astype(q.dtype) for g in cotangents[:3])
-    gg = cotangents[3].astype(_F32)
+    gg = cotangents[3].astype(F32)
     call = _conv_bwd_call(B, S, C, wq.shape[0], tiles, str(q.dtype),
                           interpret)
     return tuple(call(q, k, v, f, q, k, v, q, k, v, gq, gk, gv, gg,
@@ -503,55 +472,25 @@ def _gated_norm_bwd(head_dim, eps, tiles, interpret, inputs, cotangent):
 _gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
 
 
-def _kernels(force):
-    from .flash_attention import _use_pallas
-
-    return force == "interpret" or _use_pallas(force)
-
-
 def _row(t):
-    return t.astype(_F32).reshape(1, -1)
+    return t.astype(F32).reshape(1, -1)
 
 
-def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads,
-               force: str = "auto", rows=None, channels=None):
-    """(ops/linear_attention_ops.py::conv_decay's four outputs, the tiles
-    they were computed under: None for the jax.numpy form).  The engine is
-    read from the shape: the kernel pair where the program is for a TPU
-    and `conv_tiles` tiles the site; force="interpret" runs the pair in
-    the Pallas interpreter (the CPU tests' door), force="jax" never;
-    `rows` and `channels` pin the tile."""
-    from ..ops import linear_attention_ops as ops
-
-    tiles = None
-    dtype = _one_dtype(q, k, v, f)
-    if _kernels(force) and dtype is not None:
-        tiles = conv_tiles(q.shape[1], q.shape[2], wq.shape[0], dtype, rows,
-                           channels)
-    if tiles is None:
-        return ops.conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log,
-                              heads), None
-    rate = jnp.repeat(-jnp.exp(a_log.astype(_F32)), q.shape[2] // heads)
+def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads, tiles: Tiles,
+               interpret: bool = False):
+    """ops/linear_attention_ops.py::conv_decay's four outputs by the kernel
+    pair, of a site `conv_tiles` tiled (`tiles`); `interpret` runs the pair
+    in the Pallas interpreter."""
+    rate = jnp.repeat(-jnp.exp(a_log.astype(F32)), q.shape[2] // heads)
     return _conv_decay(
-        q, k, v, f, *(w.astype(_F32) for w in (wq, wk, wv)), _row(dt_bias),
-        _row(rate), tiles, force == "interpret"), tiles
+        q, k, v, f, *(w.astype(F32) for w in (wq, wk, wv)), _row(dt_bias),
+        _row(rate), tiles, interpret)
 
 
-def gated_norm(o, gate, gate_bias, scale, heads, eps, force: str = "auto",
-               rows=None, channels=None):
-    """(ops/linear_attention_ops.py::gated_norm's output, the tiles it was
-    computed under: None for the jax.numpy form); the engine as
-    `conv_decay` reads it."""
-    from ..ops import linear_attention_ops as ops
-
-    tiles = None
-    dtype = _one_dtype(o, gate)
-    head_dim = o.shape[2] // heads
-    if _kernels(force) and dtype is not None:
-        tiles = norm_tiles(o.shape[1], o.shape[2], head_dim, dtype, rows,
-                           channels)
-    if tiles is None:
-        return ops.gated_norm(o, gate, gate_bias, scale, heads, eps), None
+def gated_norm(o, gate, gate_bias, scale, heads, eps, tiles: Tiles,
+               interpret: bool = False):
+    """ops/linear_attention_ops.py::gated_norm's output by the kernel pair,
+    of a site `norm_tiles` tiled."""
     return _gated_norm(
-        o, gate, _row(gate_bias), _row(jnp.tile(scale.astype(_F32), heads)),
-        head_dim, float(eps), tiles, force == "interpret"), tiles
+        o, gate, _row(gate_bias), _row(jnp.tile(scale.astype(F32), heads)),
+        o.shape[2] // heads, float(eps), tiles, interpret)

@@ -48,9 +48,10 @@ n, C] to [S, n C] is no bitcast under the TPU's tiled layouts; PERF.md, PR
   columns, which accumulate over the channel blocks in a resident block.
 
 `maps_tiles` and `mix_tiles` read the tile from the shape and the VMEM it
-needs (the budget and the search are kernels/kda_mix.py's), or say that the
-shape does not tile (the op then runs the jax.numpy form).
-force="interpret" is the CPU tests' door, as in kernels/flash_attention.py.
+needs (the budget and the search are kernels/engine.py's), or say that the
+shape does not tile; the ops ask kernels/engine.py whether a site runs
+these pairs at all (ops/hyper_connection_ops.py::_site) and run their
+jax.numpy form where it does not.
 tools/mhc_probe.py times the pairs alone on the chip.
 """
 
@@ -62,9 +63,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .cca_mix import _halo_rows, _roll, _sum
-from .kda_mix import (_F32, _LANES, _columns, _compiler_params, _kernels,
-                      _one_dtype, _sigmoid, _widest)
+from . import engine
+from .engine import F32, LANES, add_up, compiler_params, roll, sigmoid
 
 __all__ = ["Tiles", "maps_tiles", "mix_tiles", "maps", "read", "write"]
 
@@ -83,6 +83,9 @@ _BF16 = jnp.bfloat16
 # 0.23 / 0.42 at 256 x 896 and x 1792; `write` 0.65 / 0.95 and 0.54 / 0.81
 # at 128 x 896 and x 1792, 0.59 / 0.85 at 256 x 896.
 _MAPS_ROWS = (1024, 512, 256, 128)
+# `read` and `write` hold a column's fp32 value in registers as
+# kernels/kda_mix.py's pairs do: no tile above 256 rows
+_MIX_ROWS = (256, 128)
 # rows of the sum of squares a pass of the inner loop keeps in registers
 _SQUARE_ROWS = 64
 
@@ -91,8 +94,8 @@ class Tiles(NamedTuple):
     """What a site's kernel pair is built from, all read from the shape."""
     rows: int
     channels: int
-    fwd_vmem: int
-    bwd_vmem: int
+    fwd_vmem_bytes: int
+    bwd_vmem_bytes: int
 
 
 class Maps(NamedTuple):
@@ -118,8 +121,8 @@ def _parts(dtype):
 def _blocks(width):
     """Blocks of whole 128-lane vectors that divide `width`, widest
     first."""
-    units = width // _LANES
-    return [d * _LANES for d in range(units, 0, -1) if units % d == 0]
+    units = width // LANES
+    return [d * LANES for d in range(units, 0, -1) if units % d == 0]
 
 
 def maps_working_set(rows, block, n, iters, size, backward) -> int:
@@ -127,21 +130,21 @@ def maps_working_set(rows, block, n, iters, size, backward) -> int:
     blocks twice (the pipeline's two buffers), the scratch and the fp32
     temporaries of the tail."""
     N, parts = _values(n), 3 if size == 4 else 1
-    tile, wide = rows * block * size, rows * _LANES * 4
-    phi = block * _LANES * 2
+    tile, wide = rows * block * size, rows * LANES * 4
+    phi = block * LANES * 2
     forward = 2 * (tile + phi + N * rows * 4) + 2 * wide + 14 * N * rows * 4
     if not backward:
         return forward
     first = (forward + 2 * (N * rows * 4 + wide)
              + (2 * iters + 1) * n * n * rows * 4)
-    second = (2 * (2 * tile + 2 * wide + (parts + 1) // 2 * rows * _LANES * 2
+    second = (2 * (2 * tile + 2 * wide + (parts + 1) // 2 * rows * LANES * 2
                    + phi + N * block * 4) + 8 * wide)
     return max(first, second)
 
 
 def mix_working_set(rows, block, n, size, what, backward) -> int:
     """The same for `read`'s and `write`'s kernels."""
-    tile, wide = rows * block * size, rows * _LANES * 4
+    tile, wide = rows * block * size, rows * LANES * 4
     blocks = {("read", False): n + 1, ("read", True): 3,
               ("write", False): n + 2, ("write", True): n + 4}[what, backward]
     return 2 * (blocks * tile + (2 if backward else 1) * wide) + 16 * wide
@@ -155,17 +158,18 @@ def maps_tiles(seq, n, width, iters, dtype, rows=None, channels=None
     tiles of rows with the tokens on the lanes, the working set inside the
     budget."""
     N, size = _values(n), jnp.dtype(dtype).itemsize
-    if width % _LANES or n % 4 or 5 * N > _LANES:
+    if width % LANES or n % 4 or 5 * N > LANES:
         return None
 
     def need(r, c, backward=True):
         return maps_working_set(r, c, n, iters, size, backward)
 
-    for c in (_blocks(width) if channels is None else (channels,)):
-        for r in (_MAPS_ROWS if rows is None else (rows,)):
-            if r % _LANES == 0 and _widest(seq, width, _LANES, need, r, c):
-                return Tiles(r, c, need(r, c, False), need(r, c))
-    return None
+    found = engine.widest(
+        seq, width, LANES, need,
+        [r for r in (_MAPS_ROWS if rows is None else (rows,))
+         if r % LANES == 0],
+        _blocks(width) if channels is None else (channels,))
+    return found and Tiles(*found, need(*found, False), need(*found))
 
 
 def mix_tiles(seq, n, width, dtype, what, rows=None, channels=None
@@ -174,7 +178,7 @@ def mix_tiles(seq, n, width, dtype, what, rows=None, channels=None
     the shape does not tile: C whole 128-lane vectors, S whole tiles of
     rows, the working set inside the budget."""
     size = jnp.dtype(dtype).itemsize
-    if width % _LANES:
+    if width % LANES:
         return None
 
     def need(r, c, backward=True):
@@ -183,11 +187,12 @@ def mix_tiles(seq, n, width, dtype, what, rows=None, channels=None
     def larger(r, c):   # `read`'s forward holds more than its backward
         return max(need(r, c, False), need(r, c))
 
-    for c in (_blocks(width) if channels is None else (channels,)):
-        found = _widest(seq, width, _LANES, larger, rows, c)
-        if found is not None and found[0] % _halo_rows(dtype) == 0:
-            return Tiles(*found, need(*found, False), need(*found))
-    return None
+    found = engine.widest(
+        seq, width, LANES, larger,
+        [r for r in (_MIX_ROWS if rows is None else (rows,))
+         if r % engine.halo_rows(dtype) == 0],
+        _blocks(width) if channels is None else (channels,))
+    return found and Tiles(*found, need(*found, False), need(*found))
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +204,7 @@ def _top(v):
     under jit XLA folds f32 -> bf16 -> f32 away (xla_allow_excess_precision)
     and the parts after the first came out 0 (PERF.md, PR 51)."""
     bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
-    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000), _F32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000), F32)
 
 
 def _split(v, parts):
@@ -207,7 +212,7 @@ def _split(v, parts):
     bits of an fp32 v in three."""
     if parts == 1:
         return [v.astype(_BF16)]
-    out, rest = [], v.astype(_F32)
+    out, rest = [], v.astype(F32)
     for _ in range(parts):
         top = _top(rest)
         out.append(top.astype(_BF16))
@@ -220,7 +225,7 @@ def _phi_columns(phi, groups):
     side, zeros after them."""
     p = _split(phi, 3)
     cols = jnp.concatenate([p[g] for g in groups], axis=1)
-    return jnp.pad(cols, ((0, 0), (0, _LANES - cols.shape[1])))
+    return jnp.pad(cols, ((0, 0), (0, LANES - cols.shape[1])))
 
 
 # the second kernel of the backward multiplies the parts a1, a2, a3 of dm
@@ -233,7 +238,7 @@ _DM_GROUPS = ((0, 0, 0, 1, 1), (2, 2, 1))
 
 
 def _dot(a, b):
-    return jnp.dot(a, b, preferred_element_type=_F32)
+    return jnp.dot(a, b, preferred_element_type=F32)
 
 
 def _lane(shape):
@@ -242,14 +247,14 @@ def _lane(shape):
 
 def _over_maps_rows(m, n):
     """sum_i M[i, j] at every row i n + j of m [n^2, rows]."""
-    return _sum([m] + [_roll(m, d * n, 0) for d in range(1, n)])
+    return add_up([m] + [roll(m, d * n, 0) for d in range(1, n)])
 
 
 def _over_maps_columns(m, n, j):
     """sum_j M[i, j] at every row i n + j of m; j [n^2, rows] is a row's
     place in its group of n."""
-    return _sum([m] + [jnp.where(j < n - d, _roll(m, -d, 0),
-                                 _roll(m, n - d, 0)) for d in range(1, n)])
+    return add_up([m] + [jnp.where(j < n - d, roll(m, -d, 0),
+                                 roll(m, n - d, 0)) for d in range(1, n)])
 
 
 def _place(n, rows):
@@ -267,15 +272,15 @@ def _accumulate(x_ref, phis_ref, acc_ref, ssq_ref, parts):
         acc_ref[...] = jnp.zeros_like(acc_ref)
         ssq_ref[...] = jnp.zeros_like(ssq_ref)
 
-    acc_ref[...] += _sum(_dot(p, phis_ref[...])
+    acc_ref[...] += add_up(_dot(p, phis_ref[...])
                          for p in _split(x_ref[...], parts))
     rows, step = x_ref.shape[0], min(_SQUARE_ROWS, x_ref.shape[0])
 
     def squares(i, carry):
         at = pl.ds(pl.multiple_of(i * step, step), step)
         s = ssq_ref[at, :]
-        for cols in _columns(x_ref.shape[1], _LANES):
-            v = x_ref[at, cols].astype(_F32)
+        for cols in engine.columns(x_ref.shape[1], LANES):
+            v = x_ref[at, cols].astype(F32)
             s = s + v * v
         ssq_ref[at, :] = s
         return carry
@@ -299,7 +304,7 @@ def _tail(acc_ref, ssq_ref, a_ref, b_ref, geo: Maps, width, keep=None):
     z = a_ref[...] * mm + b_ref[...]
     twice = jnp.where(jax.lax.broadcasted_iota(
         jnp.int32, (2 * n, rows), 0) < n, 1.0, 2.0)
-    gates = twice * _sigmoid(z[0:2 * n])
+    gates = twice * sigmoid(z[0:2 * n])
     res = jnp.exp(jnp.clip(z[2 * n:], geo.clamp_min, geo.clamp_max))
     j = _place(n, rows)
 
@@ -335,7 +340,7 @@ def _maps_kernel(x_ref, phis_ref, a_ref, b_ref, h_ref, acc_ref, ssq_ref, *,
 
 def _fold(v):
     """[N, rows] -> [N, 128]: the lane tiles added up."""
-    return _sum(v[:, c] for c in _columns(v.shape[1], _LANES))
+    return add_up(v[:, c] for c in engine.columns(v.shape[1], LANES))
 
 
 def _maps_bwd_tail_kernel(x_ref, phis_ref, a_ref, b_ref, dh_ref,
@@ -396,7 +401,7 @@ def _maps_bwd_tail_kernel(x_ref, phis_ref, a_ref, b_ref, dh_ref,
         g_ref[3 * N:3 * N + 8] = jnp.broadcast_to(
             dss2, (8,) + dss2.shape[1:])
         g_ref[3 * N + 8:] = jnp.zeros(
-            (g_ref.shape[0] - 3 * N - 8, g_ref.shape[1]), _F32)
+            (g_ref.shape[0] - 3 * N - 8, g_ref.shape[1]), F32)
 
 
 def _maps_bwd_stream_kernel(x_ref, g_ref, dss_ref, phit_ref, *refs, parts):
@@ -416,12 +421,12 @@ def _maps_bwd_stream_kernel(x_ref, g_ref, dss_ref, phit_ref, *refs, parts):
     dmt = g_ref[0:3 * N + 8].astype(_BF16)
     lhs = [ref[...] for ref in lhs_refs]
     dss2 = dss_ref[...]
-    for cols in _columns(x_ref.shape[1], _LANES):
+    for cols in engine.columns(x_ref.shape[1], LANES):
         x = x_ref[:, cols]
         phit = phit_ref[:, cols]
-        du = _sum(_dot(l, phit) for l in lhs) + dss2 * x.astype(_F32)
+        du = add_up(_dot(l, phit) for l in lhs) + dss2 * x.astype(F32)
         dx_ref[:, cols] = du.astype(dx_ref.dtype)
-        dp = _sum(_dot(dmt, p) for p in _split(x, parts))
+        dp = add_up(_dot(dmt, p) for p in _split(x, parts))
         dphit_ref[:, cols] += dp[0:N] + dp[N:2 * N] + dp[2 * N:3 * N]
 
 
@@ -430,13 +435,13 @@ def _wide(column):
     on the chip a loop over blocks of 16 rows that kept the spread values
     in registers lost to this (`write` 0.54 / 0.81 -> 0.73 / 0.94 ms a
     sublayer, tools/mhc_probe.py, PERF.md PR 51)."""
-    return jnp.broadcast_to(column, (column.shape[0], _LANES))
+    return jnp.broadcast_to(column, (column.shape[0], LANES))
 
 
 def _chosen(h_ref, ks, which):
     """The column of H^T that `which` (a grid index) names among `ks`,
     spread over the lanes."""
-    return _wide(_sum((which == i).astype(_F32) * h_ref[:, k:k + 1]
+    return _wide(add_up((which == i).astype(F32) * h_ref[:, k:k + 1]
                       for i, k in enumerate(ks)))
 
 
@@ -447,8 +452,8 @@ def _rowsum(v):
 def _read_kernel(h_ref, *refs, n):
     xs, o_ref = refs[:n], refs[n]
     h = [_wide(h_ref[:, j:j + 1]) for j in range(n)]
-    for cols in _columns(o_ref.shape[1], _LANES):
-        o_ref[:, cols] = _sum(h[j] * xs[j][:, cols].astype(_F32)
+    for cols in engine.columns(o_ref.shape[1], LANES):
+        o_ref[:, cols] = add_up(h[j] * xs[j][:, cols].astype(F32)
                               for j in range(n)).astype(o_ref.dtype)
 
 
@@ -463,7 +468,7 @@ def _no_map_gradient_yet(dh_ref):
 def _into_columns(dh_ref, columns, parts):
     """dH's `columns` += the sums of `parts` over their lanes."""
     lane = _lane(parts[0].shape)
-    dh_ref[...] += _sum(jnp.where(lane == k, _rowsum(p), 0.0)
+    dh_ref[...] += add_up(jnp.where(lane == k, _rowsum(p), 0.0)
                         for k, p in zip(columns, parts))
 
 
@@ -475,11 +480,11 @@ def _read_bwd_kernel(h_ref, x_ref, g_ref, dx_ref, dh_ref, *, n):
     s = pl.program_id(3)
     _no_map_gradient_yet(dh_ref)
     h = _chosen(h_ref, range(n), s)
-    part = jnp.zeros(h.shape, _F32)
-    for cols in _columns(g_ref.shape[1], _LANES):
-        g = g_ref[:, cols].astype(_F32)
+    part = jnp.zeros(h.shape, F32)
+    for cols in engine.columns(g_ref.shape[1], LANES):
+        g = g_ref[:, cols].astype(F32)
         dx_ref[:, cols] = (h * g).astype(dx_ref.dtype)
-        part = part + g * x_ref[:, cols].astype(_F32)
+        part = part + g * x_ref[:, cols].astype(F32)
     _into_columns(dh_ref, [s], [part])
 
 
@@ -492,10 +497,10 @@ def _write_kernel(h_ref, *refs, n):
     res = [_chosen(h_ref, [2 * n + i * n + j for i in range(n)], s)
            for j in range(n)]
     post = _chosen(h_ref, range(n, 2 * n), s)
-    for cols in _columns(o_ref.shape[1], _LANES):
+    for cols in engine.columns(o_ref.shape[1], LANES):
         o_ref[:, cols] = (
-            _sum(res[j] * xs[j][:, cols].astype(_F32) for j in range(n))
-            + post * y_ref[:, cols].astype(_F32)).astype(o_ref.dtype)
+            add_up(res[j] * xs[j][:, cols].astype(F32) for j in range(n))
+            + post * y_ref[:, cols].astype(F32)).astype(o_ref.dtype)
 
 
 def _write_bwd_kernel(h_ref, x_ref, *refs, n):
@@ -512,11 +517,11 @@ def _write_bwd_kernel(h_ref, x_ref, *refs, n):
     def both(coefficient, other_ref, out_ref, columns):
         """out = sum_i coefficient[i] g[i]; dH's `columns` += sum_c g[i]
         other."""
-        parts = [jnp.zeros(coefficient[0].shape, _F32)] * n
-        for cols in _columns(x_ref.shape[1], _LANES):
-            other = other_ref[:, cols].astype(_F32)
-            g = [ref[:, cols].astype(_F32) for ref in gs]
-            out_ref[:, cols] = _sum(
+        parts = [jnp.zeros(coefficient[0].shape, F32)] * n
+        for cols in engine.columns(x_ref.shape[1], LANES):
+            other = other_ref[:, cols].astype(F32)
+            g = [ref[:, cols].astype(F32) for ref in gs]
+            out_ref[:, cols] = add_up(
                 coefficient[i] * g[i] for i in range(n)).astype(out_ref.dtype)
             parts = [parts[i] + g[i] * other for i in range(n)]
         _into_columns(dh_ref, columns, parts)
@@ -545,7 +550,7 @@ def _jitted(kernel, **kw):
 def _scratch(*shapes):
     from jax.experimental.pallas import tpu as pltpu
 
-    return [pltpu.VMEM(s, _F32) for s in shapes]
+    return [pltpu.VMEM(s, F32) for s in shapes]
 
 
 def _stream_block(T, Cb, where):
@@ -565,7 +570,7 @@ def _maps_specs(B, S, n, C, N, tiles):
     per = C // Kb
     return ((B, S // T, n * per),
             _stream_block(T, Kb, lambda b, i, k: (b, k // per, i, k % per)),
-            pl.BlockSpec((Kb, _LANES), lambda b, i, k: (k, 0)),
+            pl.BlockSpec((Kb, LANES), lambda b, i, k: (k, 0)),
             pl.BlockSpec((N, 1), lambda b, i, k: (0, 0)),
             pl.BlockSpec((None, N, T), lambda b, i, k: (b, 0, i)))
 
@@ -578,10 +583,10 @@ def _maps_fwd_call(B, S, C, geo, tiles, dtype, interpret):
         functools.partial(_maps_kernel, geo=geo, parts=_parts(dtype),
                           width=n * C),
         grid=grid, in_specs=[x, phis, small, small], out_specs=h,
-        out_shape=jax.ShapeDtypeStruct((B, N, S), _F32),
-        scratch_shapes=_scratch((T, _LANES), (T, _LANES)),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary"), tiles.fwd_vmem),
+        out_shape=jax.ShapeDtypeStruct((B, N, S), F32),
+        scratch_shapes=_scratch((T, LANES), (T, LANES)),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), tiles.fwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -591,18 +596,19 @@ def _maps_bwd_tail_call(B, S, C, geo, tiles, dtype, interpret):
 
     n, N, T = geo.streams, _values(geo.streams), tiles.rows
     grid, x, phis, small, h = _maps_specs(B, S, n, C, N, tiles)
-    sums = pl.BlockSpec((N, _LANES), lambda b, i, k: (0, 0))
+    sums = pl.BlockSpec((N, LANES), lambda b, i, k: (0, 0))
     return _jitted(
         functools.partial(_maps_bwd_tail_kernel, geo=geo,
                           parts=_parts(dtype), width=n * C),
         grid=grid, in_specs=[x, phis, small, small, h],
-        out_specs=[pl.BlockSpec((None, _LANES, T), lambda b, i, k: (b, 0, i)),
+        out_specs=[pl.BlockSpec((None, LANES, T), lambda b, i, k: (b, 0, i)),
                    sums, sums],
-        out_shape=[jax.ShapeDtypeStruct((B, _LANES, S), _F32)]
-        + [jax.ShapeDtypeStruct((N, _LANES), _F32)] * 2,
-        scratch_shapes=_scratch((T, _LANES), (T, _LANES),
+        out_shape=[jax.ShapeDtypeStruct((B, LANES, S), F32)]
+        + [jax.ShapeDtypeStruct((N, LANES), F32)] * 2,
+        scratch_shapes=_scratch((T, LANES), (T, LANES),
                                 (2 * geo.iters + 1, n * n, T)),
-        compiler_params=_compiler_params(("arbitrary",) * 3, tiles.bwd_vmem),
+        compiler_params=compiler_params(
+            ("arbitrary",) * 3, tiles.bwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -613,20 +619,20 @@ def _maps_bwd_stream_call(B, S, n, C, tiles, dtype, interpret):
     N, T, Kb = _values(n), tiles.rows, tiles.channels
     per = C // Kb
     tile = _stream_block(T, Kb, lambda k, b, i: (b, k // per, i, k % per))
-    wide = pl.BlockSpec((None, T, _LANES), lambda k, b, i: (b, i, 0))
+    wide = pl.BlockSpec((None, T, LANES), lambda k, b, i: (b, i, 0))
     return _jitted(
         functools.partial(_maps_bwd_stream_kernel, parts=_parts(dtype)),
         grid=(n * per, B, S // T),
         in_specs=[tile,
-                  pl.BlockSpec((None, _LANES, T), lambda k, b, i: (b, 0, i)),
+                  pl.BlockSpec((None, LANES, T), lambda k, b, i: (b, 0, i)),
                   pl.BlockSpec((None, T, 1), lambda k, b, i: (b, i, 0)),
-                  pl.BlockSpec((_LANES, Kb), lambda k, b, i: (0, k))]
+                  pl.BlockSpec((LANES, Kb), lambda k, b, i: (0, k))]
         + [wide] * ((_parts(dtype) + 1) // 2),
         out_specs=[tile, pl.BlockSpec((N, Kb), lambda k, b, i: (0, k))],
         out_shape=[jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
-                   jax.ShapeDtypeStruct((N, n * C), _F32)],
-        compiler_params=_compiler_params(
-            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem),
+                   jax.ShapeDtypeStruct((N, n * C), F32)],
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -648,11 +654,11 @@ def _maps_bwd(geo, tiles, interpret, inputs, dh):
     (B, n, S, C), N = x.shape, _values(geo.streams)
     g, da, db = _maps_bwd_tail_call(B, S, C, geo, tiles, str(x.dtype),
                                     interpret)(
-        x, _phi_columns(phi, (0, 1, 2)), a, b, dh.astype(_F32))
+        x, _phi_columns(phi, (0, 1, 2)), a, b, dh.astype(F32))
     by_token = jnp.swapaxes(g, 1, 2)
     pieces = [by_token[..., p * N:(p + 1) * N] for p in range(3)]
     lhs = [jnp.pad(jnp.concatenate([pieces[p] for p in row], axis=-1),
-                   ((0, 0), (0, 0), (0, _LANES - N * len(row)))).astype(_BF16)
+                   ((0, 0), (0, 0), (0, LANES - N * len(row)))).astype(_BF16)
            for row in _DM_GROUPS[:(_parts(x.dtype) + 1) // 2]]
     dx, dphit = _maps_bwd_stream_call(B, S, n, C, tiles, str(x.dtype),
                                       interpret)(
@@ -683,7 +689,7 @@ def _mix_specs(B, S, C, n, N, tiles, streams_axis):
     grid = (B, S // T, C // Cb) + ((n,) if streams_axis else ())
     return (grid, pl.BlockSpec((None, T, N), lambda b, i, c, *s: (b, i, 0)),
             pl.BlockSpec((None, T, Cb), lambda b, i, c, *s: (b, i, c)),
-            pl.BlockSpec((None, T, _LANES), lambda b, i, c, *s: (b, i, 0)),
+            pl.BlockSpec((None, T, LANES), lambda b, i, c, *s: (b, i, 0)),
             at)
 
 
@@ -694,7 +700,8 @@ def _read_fwd_call(B, S, C, n, tiles, dtype, interpret):
         functools.partial(_read_kernel, n=n), grid=grid,
         in_specs=[h] + [at(j) for j in range(n)], out_specs=one,
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
-        compiler_params=_compiler_params(("parallel",) * 3, tiles.fwd_vmem),
+        compiler_params=compiler_params(
+            ("parallel",) * 3, tiles.fwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -708,8 +715,9 @@ def _read_bwd_call(B, S, C, n, tiles, dtype, interpret):
         functools.partial(_read_bwd_kernel, n=n), grid=grid,
         in_specs=[h, at(None), one], out_specs=[at(None), wide],
         out_shape=[jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
-                   jax.ShapeDtypeStruct((B, S, _LANES), _F32)],
-        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.bwd_vmem),
+                   jax.ShapeDtypeStruct((B, S, LANES), F32)],
+        compiler_params=compiler_params(
+            _ONE_STREAM_A_STEP, tiles.bwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -721,7 +729,8 @@ def _write_fwd_call(B, S, C, n, tiles, dtype, interpret):
         in_specs=[h] + [at(j) for j in range(n)] + [one],
         out_specs=at(None),
         out_shape=jax.ShapeDtypeStruct((B, n, S, C), jnp.dtype(dtype)),
-        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.fwd_vmem),
+        compiler_params=compiler_params(
+            _ONE_STREAM_A_STEP, tiles.fwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -735,8 +744,9 @@ def _write_bwd_call(B, S, C, n, tiles, dtype, interpret):
         out_specs=[at(None), one, wide],
         out_shape=[jax.ShapeDtypeStruct((B, n, S, C), like),
                    jax.ShapeDtypeStruct((B, S, C), like),
-                   jax.ShapeDtypeStruct((B, S, _LANES), _F32)],
-        compiler_params=_compiler_params(_ONE_STREAM_A_STEP, tiles.bwd_vmem),
+                   jax.ShapeDtypeStruct((B, S, LANES), F32)],
+        compiler_params=compiler_params(
+            _ONE_STREAM_A_STEP, tiles.bwd_vmem_bytes),
         interpret=interpret)
 
 
@@ -799,7 +809,7 @@ _write.defvjp(_write_fwd, _write_bwd)
 
 
 # ---------------------------------------------------------------------------
-# the three ops' engines
+# the three ops' kernel pairs, on the ops' own arguments
 # ---------------------------------------------------------------------------
 def _by_stream(x):
     """[B, S, n, C] <-> [B, n, S, C]: the kernels' view of the streams, a
@@ -811,64 +821,33 @@ def _by_stream(x):
     return jnp.swapaxes(x, 1, 2)
 
 
-def maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res, epsilon, hc_eps,
-         iters, clamp, force: str = "auto", rows=None, channels=None):
-    """(ops/hyper_connection_ops.py::maps' H, the tiles it was computed
-    under: None for the jax.numpy form).  The engine is read from the
-    site: the kernel pair where the program is for a TPU and `maps_tiles`
-    tiles the shape; force="interpret" runs the pair in the Pallas
-    interpreter (the CPU tests' door), force="jax" never; `rows` and
-    `channels` pin the tile."""
-    from ..ops import hyper_connection_ops as ops
-
-    B, S, n, C = x.shape
-    tiles = None
-    if _kernels(force) and _one_dtype(x) is not None:
-        tiles = maps_tiles(S, n, C, iters, x.dtype, rows, channels)
-    if tiles is None:
-        return jax.checkpoint(functools.partial(
-            ops.maps, epsilon=epsilon, hc_eps=hc_eps, iters=iters,
-            clamp=clamp))(x, phi, a_pre, a_post, a_res, b_pre, b_post,
-                          b_res), None
+def maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res, tiles: Tiles,
+         interpret: bool = False, *, epsilon, hc_eps, iters, clamp):
+    """ops/hyper_connection_ops.py::maps' H by the kernel pair, of a site
+    `maps_tiles` tiled (`tiles`); `interpret` runs the pair in the Pallas
+    interpreter."""
+    n = x.shape[2]
 
     def column(values):
-        return jnp.concatenate(values).astype(_F32).reshape(-1, 1)
+        return jnp.concatenate(values).astype(F32).reshape(-1, 1)
 
     biases = [b.reshape(-1) for b in (b_pre, b_post, b_res)]
     scalars = [jnp.broadcast_to(a.reshape(1), b.shape)
                for a, b in zip((a_pre, a_post, a_res), biases)]
     geo = Maps(n, float(epsilon), float(hc_eps), int(iters),
                float(clamp[0]), float(clamp[1]))
-    return _maps(_by_stream(x), phi.astype(_F32), column(scalars),
-                 column(biases), geo, tiles, force == "interpret"), tiles
+    return _maps(_by_stream(x), phi.astype(F32), column(scalars),
+                 column(biases), geo, tiles, interpret)
 
 
-def read(x, h, force: str = "auto", rows=None, channels=None):
-    """(ops/hyper_connection_ops.py::read's x_in, the tiles: None for the
-    jax.numpy form); the engine as `maps` reads it."""
-    from ..ops import hyper_connection_ops as ops
-
-    B, S, n, C = x.shape
-    tiles = None
-    if _kernels(force) and _one_dtype(x) is not None:
-        tiles = mix_tiles(S, n, C, x.dtype, "read", rows, channels)
-    if tiles is None:
-        return jax.checkpoint(ops.read)(x, h), None
-    return _read(_by_stream(x), h.astype(_F32), n, tiles,
-                 force == "interpret"), tiles
+def read(x, h, tiles: Tiles, interpret: bool = False):
+    """ops/hyper_connection_ops.py::read's x_in by the kernel pair, of a
+    site `mix_tiles` tiled."""
+    return _read(_by_stream(x), h.astype(F32), x.shape[2], tiles, interpret)
 
 
-def write(x, h, y, force: str = "auto", rows=None, channels=None):
-    """(ops/hyper_connection_ops.py::write's X', the tiles: None for the
-    jax.numpy form); the engine as `maps` reads it, and x and y in one
-    dtype."""
-    from ..ops import hyper_connection_ops as ops
-
-    B, S, n, C = x.shape
-    tiles = None
-    if _kernels(force) and _one_dtype(x, y) is not None:
-        tiles = mix_tiles(S, n, C, x.dtype, "write", rows, channels)
-    if tiles is None:
-        return jax.checkpoint(ops.write)(x, h, y), None
-    return _by_stream(_write(_by_stream(x), h.astype(_F32), y, n, tiles,
-                             force == "interpret")), tiles
+def write(x, h, y, tiles: Tiles, interpret: bool = False):
+    """ops/hyper_connection_ops.py::write's X' by the kernel pair, of a
+    site `mix_tiles` tiled; x and y in one dtype."""
+    return _by_stream(_write(_by_stream(x), h.astype(F32), y, x.shape[2],
+                             tiles, interpret))

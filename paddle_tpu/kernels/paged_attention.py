@@ -148,7 +148,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import NEG_INF, _on_tpu, flash_attention
+from .engine import on_tpu
+from .flash_attention import NEG_INF, flash_attention
 
 __all__ = [
     "GroupedHeadsError",
@@ -338,7 +339,7 @@ def resolve_paged_impl(impl, page_size: int, head_dim: int,
         raise ValueError(
             f"paged-attention impl must be one of {_IMPLS}, got {impl!r}")
     if impl == "auto":
-        if _on_tpu() and not pallas_paged_viable(page_size, head_dim,
+        if on_tpu() and not pallas_paged_viable(page_size, head_dim,
                                                  dtype):
             # auto on a TPU host WANTED pallas; an out-of-envelope pool
             # geometry silently degrading to the reference gather is the
@@ -346,7 +347,7 @@ def resolve_paged_impl(impl, page_size: int, head_dim: int,
             # auto->reference is expected and stays uncounted)
             _record_fallback()
             return "reference"
-        return ("pallas" if _on_tpu() else "reference")
+        return ("pallas" if on_tpu() else "reference")
     if impl == "pallas" and not pallas_paged_viable(
             page_size, head_dim, dtype):
         if not _fallback_noted:

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.compiler import keep
+from .engine import use_pallas
 
 __all__ = ["sparse_attention", "index_scores", "select_topk", "plan"]
 
@@ -704,16 +705,16 @@ _sparse_attention.defvjp(_sparse_attention_fwd, _sparse_attention_bwd)
 
 def sparse_attention(q, k, v, qi, ki, w, topk: int, scale: float,
                      q_chunk: int = 512, kv_chunk: int = 512,
-                     engine: str | None = None):
+                     force: str = "auto"):
     """(out [B, H, S, D], L_I fp32 scalar) of q [B, H, S, D], k and v
     [B, G, S, D], the index's qi [B, Hi, S, Di], ki [B, S, Di] and w
     [B, S, Hi] (module docstring; L_I the mean over all B x S tokens).
-    `engine`: "pallas" (a TPU's default), "interpret" (the kernels in the
-    interpreter, for tests) or "xla" (the default elsewhere)."""
-    from .flash_attention import _use_pallas
-
-    if engine is None:
-        engine = "pallas" if _use_pallas("auto") else "xla"
+    `force`: kernels/engine.py's door (the masked block kernels, compiled
+    for a TPU or interpreted; jax.numpy elsewhere).  The op reads no mesh
+    (ROADMAP D25): on several devices the kernels are XLA's to
+    partition."""
+    engine = ("interpret" if force == "interpret"
+              else "pallas" if use_pallas(force) else "xla")
     B, _, S, _ = q.shape
     p = plan(S, q_chunk, kv_chunk)
     cfg = (int(topk), float(scale), p["q_chunk"], p["kv_block"], engine)

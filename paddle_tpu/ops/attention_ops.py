@@ -31,33 +31,6 @@ def _fused_attn_infer(op, block):
     set_output(block, op, "Out", list(q.shape), q.dtype)
 
 
-def _shard_over_mesh(attend, mesh, n_head: int, has_lengths: bool,
-                     heads_last: bool = False):
-    """Wrap `attend(q, k, v[, k_lengths])` in a shard_map over `mesh`:
-    batch over dp, heads over tp where tp divides them, sequence whole
-    (heads-first operands are [B, H, S, D]; `heads_last` ones [B, S, H * D],
-    whose lanes a cut over tp splits into whole heads).
-    XLA cannot partition a Mosaic kernel by itself ("Mosaic kernels cannot
-    be automatically partitioned") — without this the SPMD step of a
-    flash-attention model does not compile for more than one chip.  Each
-    device runs the kernel on its own [B/dp, H/tp, S, D] block; attention
-    never mixes batch rows or heads, so no collective is needed."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import AXIS_DP, AXIS_TP
-
-    dp = AXIS_DP if mesh.has_axis(AXIS_DP) else None
-    # `n_head`: the key/value heads where they are fewer than the query's
-    tp = AXIS_TP if (mesh.has_axis(AXIS_TP)
-                     and n_head % mesh.axis_size(AXIS_TP) == 0) else None
-    qkv = P(dp, None, tp) if heads_last else P(dp, tp, None, None)
-    in_specs = (qkv, qkv, qkv) + ((P(dp),) if has_lengths else ())
-    # check_vma off: pallas_call has no replication rule
-    return jax.shard_map(attend, mesh=mesh.mesh, in_specs=in_specs,
-                         out_specs=qkv, check_vma=False)
-
-
 def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None, heads=None):
     """flash_attention of q [B, H, S, D] over k [B, G, S, D] and v [B, G,
     S, Dv] (G = H, or a divisor of it: grouped-query attention), or with
@@ -71,9 +44,9 @@ def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None, heads=None):
     values to the context's `kept`, and which layout the kernel takes
     (`layout`: bshd where heads-last operands go to it as they lie, bhsd
     where they are transposed first, or came heads-first)."""
-    from ..kernels import flash_attention
+    from ..kernels import engine, flash_attention
     from ..kernels.flash_attention import (
-        _use_pallas, heads_first_shapes, kept, kept_bytes, takes_heads_last)
+        heads_first_shapes, kept, kept_bytes, takes_heads_last)
 
     names = kept(q, k, v, causal, window, heads=heads)
     first = (q, k, v) if heads is None else heads_first_shapes(q, k, v, heads)
@@ -90,11 +63,9 @@ def _attend(ctx, sp, q, k, v, klen, causal, scale, window=None, heads=None):
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                k_lengths=klen, window=window, heads=local)
 
-    if (ctx.mesh is not None and ctx.mesh.num_devices > 1
-            and _use_pallas("auto")):
-        kv_heads = first[1].shape[1]
-        attend = _shard_over_mesh(attend, ctx.mesh, kv_heads,
-                                  klen is not None, heads is not None)
+    # the mesh rule (kernels/engine.py): on several devices a shard_map
+    attend = engine.shard_over_mesh(attend, ctx.mesh, first[1].shape[1],
+                                    klen is not None, heads is not None)
     return attend(*((q, k, v) + ((klen,) if klen is not None else ())))
 
 
@@ -163,8 +134,7 @@ def _eva_attention(ctx, ins, attrs):
     and a sequence's visible pairs), `pooled_bytes` (what the pooling's
     forward has to move: K and V of the pooled positions in, 1 / chunk of
     them out), `engine`, `kept`, `kept_bytes`."""
-    from ..kernels import eva_attention as eva
-    from ..kernels.flash_attention import _use_pallas
+    from ..kernels import engine, eva_attention as eva
 
     q, k, v = amp.mxu_operands(*(data(ins[s][0]) for s in ("Q", "K", "V")))
     mu, phi = data(ins["Mu"][0]), data(ins["Phi"][0])
@@ -172,8 +142,7 @@ def _eva_attention(ctx, ins, attrs):
     window, chunk = int(attrs["window"]), int(attrs["chunk"])
     geo = eva.geometry(S, window, chunk)
     own, far = eva.pairs(S, window, chunk)
-    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
-    flash = _use_pallas("auto") and not several
+    flash = engine.wants_kernels("auto", ctx.mesh)
     names, held = eva.kept_by_flash(q, geo) if flash else ((), 0)
     moved = 2 * B * H * geo["pooled"] * D * q.dtype.itemsize
     with span("eva.lower", windows=geo["windows"], chunks=geo["chunks"],
@@ -470,18 +439,18 @@ def _compressed_conv_qkv(ctx, ins, attrs):
     QOut [B, H, S, D], KOut and VOut [B, G, S, D]: what fused_attention
     takes.  Statistics, sums and angles in fp32, the grouped convolution
     on the AMP tier's operands, under the name scope `cca.mix`.  One
-    algorithm, its engine read from the site: for ONE TPU, where the shape
-    tiles (kernels/cca_mix.py::plan: D a multiple of 128, S of a tile of
-    rows, one dtype), the Pallas kernel pair of kernels/cca_mix.py, whose
-    backward keeps the op's inputs and nothing else; anywhere else (and on
-    a mesh of several devices, where XLA would have to partition the
-    kernel) compressed_conv_mix, the same arithmetic in jax.numpy.  `cca.lower` (a
+    algorithm, its engine read from the site (kernels/engine.py::site):
+    for ONE TPU, where the shape tiles (kernels/cca_mix.py::plan: D a
+    multiple of 128, S of a tile of rows, one dtype), the Pallas kernel
+    pair of kernels/cca_mix.py, whose backward keeps the op's inputs and
+    nothing else; anywhere else (and on a mesh of several devices)
+    compressed_conv_mix, the same arithmetic in jax.numpy.  `cca.lower` (a
     span, at lowering) says what a site was given: `engine` (pallas |
     xla), `tile` (the forward's rows a grid step, 0 under xla) and
     `moved_bytes`, what the site's passes have to move through HBM (its
     inputs and outputs: the forward, the forward again where the unit
     around the site is rematerialised, the backward)."""
-    from ..kernels import cca_mix
+    from ..kernels import cca_mix, engine
     from ..kernels.flash_attention import _visible_pairs
 
     q, k, v = (data(ins[s][0]) for s in ("Q", "K", "V"))
@@ -490,23 +459,24 @@ def _compressed_conv_qkv(ctx, ins, attrs):
     H, G = int(attrs["heads"]), int(attrs["kv_heads"])
     S, D = q.shape[1], q.shape[2] // H
     rotary_dim = int(attrs.get("rotary_dim", 0)) or D
-    with span("cca.lower", heads=H, kv_heads=G, latent_q=int(q.shape[-1]),
-              latent_k=int(k.shape[-1]), conv_time0=int(a_w.shape[0]),
-              conv_time1=int(b_w.shape[0]), conv_groups=int(b_w.shape[1]),
-              rotary_dim=rotary_dim, sq=int(S),
-              pairs=_visible_pairs(S, S, True, None),
-              moved_bytes=cca_mix.moved_bytes(
-                  q, k, v, bool(attrs.get("@recompute@")))) as sp, \
-            jax.named_scope("cca.mix"):
-        # XLA cannot partition a Mosaic kernel (_shard_over_mesh): on a
-        # mesh of several devices the jax.numpy form, which it can
-        several = ctx.mesh is not None and ctx.mesh.num_devices > 1
-        outs, geo = cca_mix.mix(
-            q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim,
-            float(attrs.get("rope_base", 10000.0)),
-            force="jax" if several else "auto")
-        sp.set(engine="xla" if geo is None else "pallas",
-               tile=0 if geo is None else geo.fwd_tile)
+    args = (q, k, v, a_w, a_b, b_w, b_b, tau)
+    base = float(attrs.get("rope_base", 10000.0))
+    with jax.named_scope("cca.mix"):
+        outs = engine.site(
+            "cca.lower", ("tile",), ctx.mesh,
+            lambda: cca_mix.plan(S, H, G, D, a_w.shape[0], b_w.shape[0],
+                                 rotary_dim, q.dtype)
+            if q.dtype == k.dtype == v.dtype else None,
+            lambda geo, interpret: cca_mix.cca_mix(
+                *args, geo, tuple(_inv_freq(rotary_dim, base)), interpret),
+            lambda: compressed_conv_mix(*args, H, G, rotary_dim, base),
+            heads=H, kv_heads=G, latent_q=int(q.shape[-1]),
+            latent_k=int(k.shape[-1]), conv_time0=int(a_w.shape[0]),
+            conv_time1=int(b_w.shape[0]), conv_groups=int(b_w.shape[1]),
+            rotary_dim=rotary_dim, sq=int(S),
+            pairs=_visible_pairs(S, S, True, None),
+            moved_bytes=cca_mix.moved_bytes(
+                q, k, v, bool(attrs.get("@recompute@"))))
     return dict(zip(("QOut", "KOut", "VOut"), ([o] for o in outs)))
 
 
@@ -572,8 +542,7 @@ def _sparse_attention(ctx, ins, attrs):
     runs no second forward of this op.  `dsa.lower` (a span, at lowering)
     says what a site was given, `kept` and `kept_bytes` what it holds
     through that recomputation; the context's `kept` counts the values."""
-    from ..kernels import sparse_attention as dsa
-    from ..kernels.flash_attention import _use_pallas
+    from ..kernels import engine, sparse_attention as dsa
 
     q, k, v = (data(ins[s][0]) for s in ("Q", "K", "V"))
     qi, ki, w = (data(ins[s][0]) for s in ("IndexQ", "IndexK", "IndexW"))
@@ -588,7 +557,7 @@ def _sparse_attention(ctx, ins, attrs):
               topk=topk, sq=int(S), q_chunk=tiles["q_chunk"],
               kv_chunk=tiles["kv_block"], keys_causal=S * (S + 1) // 2,
               keys_selected=seen * (seen + 1) // 2 + (S - seen) * topk,
-              engine="masked-block" if _use_pallas("auto") else "xla",
+              engine="masked-block" if engine.use_pallas("auto") else "xla",
               indices="recomputed", kept=",".join(dsa.KEPT)) as sp:
         q, k, v = amp.mxu_operands(q, k, v)
         qi, ki = amp.mxu_operands(qi, ki)

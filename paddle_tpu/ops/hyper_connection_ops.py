@@ -22,10 +22,10 @@ n^2, S], rows 0:n H_pre, n:2n H_post, then H_res row by row; a minor axis
 of n would fill n of 128 lanes through 2 x `sinkhorn_iters`
 normalisations and their backward), `mhc_read` (x_in) and `mhc_write`
 (X').  `maps`, `read` and `write` below are the arithmetic, in jax.numpy.
-One algorithm, its engine read from the site (`_lowered`; PR 51): for ONE
-TPU, where the shape tiles (kernels/mhc.py::maps_tiles / ::mix_tiles: four
-streams, C whole 128-lane vectors, S whole tiles of rows, streams of one
-dtype, the working set inside the VMEM budget), each of the three ops is a
+One algorithm, its engine read from the site (`_site`): for ONE TPU, where
+the shape tiles (kernels/mhc.py::maps_tiles / ::mix_tiles: four streams, C
+whole 128-lane vectors, S whole tiles of rows, streams of one dtype, the
+working set inside the VMEM budget), each of the three ops is a
 Pallas kernel pair over tiles of rows whose backward makes the tile's
 forward again from the op's inputs; anywhere else (the CPU, a mesh of
 several devices, a shape that does not tile) the jax.numpy form with
@@ -46,6 +46,8 @@ of its working sets (0 under xla).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -122,19 +124,17 @@ def write(x, h, y):
         for i in range(n)], axis=2).astype(out)
 
 
-def _lowered(ctx, what, run):
-    """One site of `what` under the span `mhc.kernel.lower`: `run(force)`
-    gives (output, the kernels' tiles or None).  XLA cannot partition a
-    Mosaic kernel, so on a mesh of several devices the jax.numpy form,
-    which it can (linear_attention_ops.py::_lowered)."""
-    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
-    with span("mhc.kernel.lower", what=what) as sp:
-        out, tiles = run("jax" if several else "auto")
-        taken = tiles or (0,) * 4
-        sp.set(engine="xla" if tiles is None else "pallas", rows=taken[0],
-               channels=taken[1], fwd_vmem_bytes=taken[2],
-               bwd_vmem_bytes=taken[3])
-    return out
+def _site(ctx, what, plan, pair, form, *args):
+    """One site of `what` under the span `mhc.kernel.lower`
+    (kernels/engine.py::site): kernels/mhc.py's `pair` on `args` where
+    `plan()` tiles the streams, `form`, the op's arithmetic, under
+    jax.checkpoint where it does not (module docstring)."""
+    from ..kernels import engine, mhc
+
+    return engine.site(
+        "mhc.kernel.lower", mhc.Tiles._fields, ctx.mesh, plan,
+        lambda tiles, interpret: pair(*args, tiles, interpret),
+        lambda: jax.checkpoint(form)(*args), what=what)
 
 
 def _streams_infer(op, block):
@@ -178,7 +178,7 @@ def _mhc_maps(ctx, ins, attrs):
     `mhc.lower` is this op's.  The engine is read from the site (module
     docstring; kernels/mhc.py::maps): `mhc.kernel.lower` with `what`
     maps."""
-    from ..kernels import mhc
+    from ..kernels import engine, mhc
 
     x, phi = data(ins["X"][0]), data(ins["Phi"][0])
     B, S, n, C = x.shape
@@ -189,11 +189,15 @@ def _mhc_maps(ctx, ins, attrs):
               moved_bytes=moved_bytes(B * S, n, C, x.dtype.itemsize,
                                       phi.size * phi.dtype.itemsize)), \
             jax.named_scope("mhc.maps"):
-        h = _lowered(ctx, "maps", lambda force: mhc.maps(
-            x, phi, *small, float(attrs.get("epsilon", 1e-6)),
-            float(attrs.get("hc_eps", 1e-6)), iters,
-            (float(attrs.get("clamp_min", -30.0)),
-             float(attrs.get("clamp_max", 30.0))), force=force))
+        cfg = dict(epsilon=float(attrs.get("epsilon", 1e-6)),
+                   hc_eps=float(attrs.get("hc_eps", 1e-6)), iters=iters,
+                   clamp=(float(attrs.get("clamp_min", -30.0)),
+                          float(attrs.get("clamp_max", 30.0))))
+        h = _site(
+            ctx, "maps", lambda: mhc.maps_tiles(S, n, C, iters, x.dtype)
+            if engine.one_dtype(x) else None,
+            functools.partial(mhc.maps, **cfg), functools.partial(maps, **cfg),
+            x, phi, *small)
     return {"H": [h]}
 
 
@@ -210,12 +214,14 @@ def _mhc_read(ctx, ins, attrs):
     H of `mhc_maps`: Out [B, S, C] = sum_j H_pre[j] X[j].  Under the name
     scope `mhc.mix`; the engine as mhc_maps reads it (kernels/mhc.py::read),
     `mhc.kernel.lower` with `what` read."""
-    from ..kernels import mhc
+    from ..kernels import engine, mhc
 
     x, h = data(ins["X"][0]), data(ins["H"][0])
+    B, S, n, C = x.shape
     with jax.named_scope("mhc.mix"):
-        return {"Out": [_lowered(ctx, "read", lambda force: mhc.read(
-            x, h, force=force))]}
+        return {"Out": [_site(
+            ctx, "read", lambda: mhc.mix_tiles(S, n, C, x.dtype, "read")
+            if engine.one_dtype(x) else None, mhc.read, read, x, h)]}
 
 
 @register_op("mhc_write", infer_shape=same_shape("X", "Out"),
@@ -226,9 +232,11 @@ def _mhc_write(ctx, ins, attrs):
     name scope `mhc.mix`; the engine as mhc_maps reads it
     (kernels/mhc.py::write: X and Y of one dtype besides),
     `mhc.kernel.lower` with `what` write."""
-    from ..kernels import mhc
+    from ..kernels import engine, mhc
 
     x, h, y = (data(ins[s][0]) for s in ("X", "H", "Y"))
+    B, S, n, C = x.shape
     with jax.named_scope("mhc.mix"):
-        return {"Out": [_lowered(ctx, "write", lambda force: mhc.write(
-            x, h, y, force=force))]}
+        return {"Out": [_site(
+            ctx, "write", lambda: mhc.mix_tiles(S, n, C, x.dtype, "write")
+            if engine.one_dtype(x, y) else None, mhc.write, write, x, h, y)]}

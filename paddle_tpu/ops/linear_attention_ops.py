@@ -81,8 +81,8 @@ def _gated_delta_attention(ctx, ins, attrs):
     size = jnp.dtype(q.dtype).itemsize
     taken = kda.engine(B, S, H, D, tiles["chunk"], q.dtype)
     chosen = {} if taken is None else dict(
-        rows=taken.rows, fwd_vmem_bytes=taken.fwd_vmem,
-        bwd_vmem_bytes=taken.bwd_vmem)
+        rows=taken.rows, fwd_vmem_bytes=taken.fwd_vmem_bytes,
+        bwd_vmem_bytes=taken.bwd_vmem_bytes)
     with span("kda.lower", heads=H, head_dim=D, sq=int(S),
               engine="xla" if taken is None else "pallas",
               state_bytes=kda.state_bytes(B, H, D), kept=",".join(kda.KEPT),
@@ -125,21 +125,6 @@ def gated_norm(o, gate, gate_bias, scale, heads, eps):
     return (y.reshape(B, S, C) * s).astype(o.dtype)
 
 
-def _lowered(ctx, what, moved_bytes, run):
-    """One site of either op under the span `kda.mix.lower`: `run(force)`
-    gives (outputs, the kernels' tiles or None).  XLA cannot partition a
-    Mosaic kernel (_shard_over_mesh), so on a mesh of several devices the
-    jax.numpy form, which it can."""
-    several = ctx.mesh is not None and ctx.mesh.num_devices > 1
-    with span("kda.mix.lower", what=what, moved_bytes=moved_bytes) as sp:
-        outs, tiles = run("jax" if several else "auto")
-        taken = tiles or (0,) * 5
-        sp.set(engine="xla" if tiles is None else "pallas", rows=taken[0],
-               channels=taken[1], halo=taken[2], fwd_vmem_bytes=taken[3],
-               bwd_vmem_bytes=taken[4])
-    return outs
-
-
 def _conv_decay_infer(op, block):
     q = in_desc(op, block, "Q")
     if q is None:
@@ -162,27 +147,33 @@ def _kda_conv_decay(ctx, ins, attrs):
     [H]) softplus(F + DtBias [H D]), fp32 whatever F comes in, one decay
     for every key channel.  All arithmetic in fp32.
 
-    One algorithm, its engine read from the site: for ONE TPU, where the
-    shape tiles (kernels/kda_mix.py::conv_tiles: H D whole 128-lane
-    vectors, S whole tiles of rows, one dtype), a Pallas kernel pair over
-    tiles of rows x blocks of channels whose backward keeps the op's
-    inputs and nothing else; anywhere else `conv_decay`, the same
-    arithmetic in jax.numpy.  `kda.mix.lower` (a span, at lowering; `what`
-    conv_decay) says what a site was given: `engine` (pallas | xla),
+    One algorithm, its engine read from the site (kernels/engine.py::site):
+    for ONE TPU, where the shape tiles (kernels/kda_mix.py::conv_tiles: H D
+    whole 128-lane vectors, S whole tiles of rows, one dtype), a Pallas
+    kernel pair over tiles of rows x blocks of channels whose backward
+    keeps the op's inputs and nothing else; anywhere else `conv_decay`, the
+    same arithmetic in jax.numpy.  `kda.mix.lower` (a span, at lowering;
+    `what` conv_decay) says what a site was given: `engine` (pallas | xla),
     `rows`, `channels` and `halo` of a grid step, the `fwd_vmem_bytes` and
     `bwd_vmem_bytes` of its working sets (0 under xla) and `moved_bytes`,
     what the site's passes have to move through HBM (the forward, the
     forward again where the unit around the site is rematerialised, the
     backward)."""
-    from ..kernels import kda_mix
+    from ..kernels import engine, kda_mix
 
     args = [data(ins[s][0]) for s in (
         "Q", "K", "V", "F", "ConvQW", "ConvKW", "ConvVW", "DtBias", "ALog")]
-    outs = _lowered(
-        ctx, "conv_decay", kda_mix.conv_moved_bytes(
-            args[0], args[3], bool(attrs.get("@recompute@"))),
-        lambda force: kda_mix.conv_decay(*args, int(attrs["heads"]),
-                                         force=force))
+    args.append(int(attrs["heads"]))
+    q, k, v, f, wq = args[:5]
+    outs = engine.site(
+        "kda.mix.lower", kda_mix.Tiles._fields, ctx.mesh,
+        lambda: kda_mix.conv_tiles(q.shape[1], q.shape[2], wq.shape[0],
+                                   q.dtype)
+        if engine.one_dtype(q, k, v, f) else None,
+        lambda tiles, interpret: kda_mix.conv_decay(*args, tiles, interpret),
+        lambda: conv_decay(*args), what="conv_decay",
+        moved_bytes=kda_mix.conv_moved_bytes(
+            q, f, bool(attrs.get("@recompute@"))))
     return dict(zip(("QOut", "KOut", "VOut", "G"), ([o] for o in outs)))
 
 
@@ -197,13 +188,18 @@ def _kda_gated_norm(ctx, ins, attrs):
     reads it (kernels/kda_mix.py::norm_tiles: D whole 128-lane vectors): a
     head's statistic stays in the tile, the backward reads X, Gate and the
     cotangent alone; `kda.mix.lower` with `what` gated_norm."""
-    from ..kernels import kda_mix
+    from ..kernels import engine, kda_mix
 
     args = [data(ins[s][0]) for s in ("X", "Gate", "GateBias", "Scale")]
-    out = _lowered(
-        ctx, "gated_norm", kda_mix.norm_moved_bytes(
-            args[0], args[1], bool(attrs.get("@recompute@"))),
-        lambda force: kda_mix.gated_norm(
-            *args, int(attrs["heads"]), float(attrs.get("epsilon", 1e-6)),
-            force=force))
+    args += [int(attrs["heads"]), float(attrs.get("epsilon", 1e-6))]
+    o, gate = args[:2]
+    out = engine.site(
+        "kda.mix.lower", kda_mix.Tiles._fields, ctx.mesh,
+        lambda: kda_mix.norm_tiles(o.shape[1], o.shape[2],
+                                   o.shape[2] // args[4], o.dtype)
+        if engine.one_dtype(o, gate) else None,
+        lambda tiles, interpret: kda_mix.gated_norm(*args, tiles, interpret),
+        lambda: gated_norm(*args), what="gated_norm",
+        moved_bytes=kda_mix.norm_moved_bytes(
+            o, gate, bool(attrs.get("@recompute@"))))
     return {"Out": [out]}

@@ -309,11 +309,11 @@ COMBINE = {"megablox": "tgmm", "interpret": "tgmm",
 
 
 def _engine(engine):
-    from ..kernels.flash_attention import _use_pallas
+    from ..kernels.engine import use_pallas
 
     if engine is not None:
         return engine
-    return "megablox" if _use_pallas("auto") else "ragged_dot"
+    return "megablox" if use_pallas("auto") else "ragged_dot"
 
 
 def _experts_in_buffer(x, weight, gate_w, up_w, down_w, order, pos, sizes,

@@ -663,13 +663,13 @@ def test_paged_fallback_counted_and_metered(monkeypatch):
     assert pa.fallback_count() == before
     # auto on a TPU host wanted pallas: out-of-envelope degradation to
     # the reference gather must count (in-envelope must not)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
     assert pa.resolve_paged_impl("auto", 16, 96, jnp.float32) \
         == "reference"
     assert pa.fallback_count() == before + 1
     assert pa.resolve_paged_impl("auto", 16, 128, jnp.float32) == "pallas"
     assert pa.fallback_count() == before + 1
-    monkeypatch.setattr(pa, "_on_tpu", lambda: False)
+    monkeypatch.setattr(pa, "on_tpu", lambda: False)
     before = pa.fallback_count()
     # out-of-envelope explicit pallas falls back AND counts
     assert pa.resolve_paged_impl("pallas", 16, 96, jnp.float32) \

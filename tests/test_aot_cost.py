@@ -240,7 +240,7 @@ def _sparse_attention():
 
     def loss(*a):
         out, kl = sparse_attention.sparse_attention(
-            *a, topk=2048, scale=128 ** -0.5, engine="pallas")
+            *a, topk=2048, scale=128 ** -0.5, force="pallas")
         return jnp.sum(out.astype(jnp.float32)) + kl
 
     return jax.value_and_grad(loss, argnums=tuple(range(6))), args
@@ -254,6 +254,7 @@ def _cca_mix(backward):
     by themselves, so that it stays in the program)."""
     from paddle_tpu.core import amp
     from paddle_tpu.kernels import cca_mix
+    from paddle_tpu.ops.attention_ops import _inv_freq
 
     S, H, G, D, n = 16384, 8, 2, 128, 10
     args = (_sds((1, S, H * D), jnp.bfloat16),
@@ -265,11 +266,11 @@ def _cca_mix(backward):
     def fwd(*a):
         amp.enable_amp("bfloat16", keep_output=True)
         try:
-            outs, geo = cca_mix.mix(*a, H, G, 64, 5e6, force="pallas")
+            geo = cca_mix.plan(S, H, G, D, 2, 2, 64, jnp.bfloat16)
+            assert geo is not None
+            return cca_mix.cca_mix(*a, geo, tuple(_inv_freq(64, 5e6)))
         finally:
             amp.reset_amp()
-        assert geo is not None
-        return outs
 
     if not backward:
         return fwd, args
@@ -313,12 +314,13 @@ def _kda_mix(backward):
 
     def fwd(q, k, v, f, o, gate, wq, wk, wv, dt_bias, a_log, gate_bias,
             scale):
-        outs, before = kda_mix.conv_decay(q, k, v, f, wq, wk, wv, dt_bias,
-                                          a_log, H, force="pallas")
-        out, after = kda_mix.gated_norm(o, gate, gate_bias, scale, H, 1e-5,
-                                        force="pallas")
+        before = kda_mix.conv_tiles(S, H * D, taps, jnp.bfloat16)
+        after = kda_mix.norm_tiles(S, H * D, D, jnp.bfloat16)
         assert before is not None and after is not None
-        return outs + (out,)
+        outs = kda_mix.conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, H,
+                                  before)
+        return outs + (kda_mix.gated_norm(o, gate, gate_bias, scale, H, 1e-5,
+                                          after),)
 
     if not backward:
         return fwd, args
@@ -343,12 +345,13 @@ def _mhc(backward):
             _sds((n, n), f32))
 
     def fwd(x, y, *small):
-        h, maps = mhc.maps(x, *small, 1e-6, 1e-6, 20, (-30.0, 30.0),
-                           force="pallas")
-        x_in, read = mhc.read(x, h, force="pallas")
-        out, write = mhc.write(x, h, y, force="pallas")
+        maps = mhc.maps_tiles(S, n, C, 20, x.dtype)
+        read, write = (mhc.mix_tiles(S, n, C, x.dtype, what)
+                       for what in ("read", "write"))
         assert None not in (maps, read, write)
-        return h, x_in, out
+        h = mhc.maps(x, *small, maps, epsilon=1e-6, hc_eps=1e-6, iters=20,
+                     clamp=(-30.0, 30.0))
+        return h, mhc.read(x, h, read), mhc.write(x, h, y, write)
 
     if not backward:
         return fwd, args

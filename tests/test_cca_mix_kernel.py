@@ -27,7 +27,8 @@ if os.path.join(REPO, "tests") not in sys.path:
 import paddle_tpu as fluid
 from paddle_tpu import models
 from paddle_tpu.core import amp
-from paddle_tpu.kernels import cca_mix
+from paddle_tpu.kernels import cca_mix, engine
+from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.attention_ops import compressed_conv_mix
 
 import test_compressed_decoder as tiny_decoder
@@ -61,6 +62,22 @@ def _inputs(seed=0, B=1, S=96, H=8, G=2, D=128, k0=2, k1=2,
     return args, cots
 
 
+def _site(q, k, v, a_w, a_b, b_w, b_b, tau, H, G, rotary_dim, base,
+          force="interpret", tile=None):
+    """(the three outputs, the geometry) of one site by the engine `force`
+    names: as the op chooses it (kernels/engine.py::site), without the
+    span."""
+    args = (q, k, v, a_w, a_b, b_w, b_b, tau)
+    S = q.shape[1]
+    geo = engine.tiles_or_none(force, None, lambda: cca_mix.plan(
+        S, H, G, q.shape[2] // H, a_w.shape[0], b_w.shape[0], rotary_dim,
+        q.dtype, tile))
+    if geo is None:
+        return compressed_conv_mix(*args, H, G, rotary_dim, base), None
+    freq = tuple(attention_ops._inv_freq(rotary_dim, base))
+    return cca_mix.cca_mix(*args, geo, freq, force == "interpret"), geo
+
+
 def _both(args, cots, H=8, G=2, rotary_dim=64, tile=32, force="interpret",
           kernel=True, plain=True, **_):
     """(outputs, gradients, geometry) of the kernel pair and (outputs,
@@ -69,8 +86,8 @@ def _both(args, cots, H=8, G=2, rotary_dim=64, tile=32, force="interpret",
     geos = []
 
     def kernels(*xs):
-        outs, geo = cca_mix.mix(*xs, H, G, rotary_dim, BASE, force=force,
-                                tile=tile)
+        outs, geo = _site(*xs, H, G, rotary_dim, BASE, force=force,
+                          tile=tile)
         geos.append(geo)
         return outs
 
@@ -165,8 +182,7 @@ def test_the_first_tiles_rule_a_bias_not_zero_before_position_0():
     H, G, D, S, rotary_dim = 4, 2, 128, 64, 64
     args, _ = _inputs(seed=2, H=H, G=G, S=S)
     q, k, v, a_w, a_b, b_w, b_b, tau = args
-    outs, geo = cca_mix.mix(*args, H, G, rotary_dim, 100.0,
-                            force="interpret", tile=32)
+    outs, geo = _site(*args, H, G, rotary_dim, 100.0, tile=32)
     assert geo is not None
     lq, lk = H * D, G * D
     eye = jnp.eye(lq + 2 * lk)
@@ -190,10 +206,9 @@ def test_the_first_tiles_rule_a_bias_not_zero_before_position_0():
         > 1e-2
     # behind a tile of zeros: A's output on zeros is what B reads there
     pad = jnp.zeros((1, 32, 1), jnp.float32)
-    behind, _ = cca_mix.mix(
+    behind, _ = _site(
         *(jnp.concatenate([pad * t[:, :1], t], 1) for t in (q, k, v)),
-        a_w, a_b, b_w, b_b, tau, H, G, rotary_dim, 100.0, force="interpret",
-        tile=32)
+        a_w, a_b, b_w, b_b, tau, H, G, rotary_dim, 100.0, tile=32)
     for got, late in zip(outs[:2], behind[:2]):
         _close(got[..., rotary_dim:], late[:, :, 32:, rotary_dim:], 1e-5,
                "behind a tile of zeros")
@@ -228,7 +243,7 @@ def test_plan_reads_the_tile_from_the_shape_and_the_vmem_it_needs():
     for tile, backward in ((cell.fwd_tile, False), (cell.bwd_tile, True)):
         assert cca_mix.working_set_bytes(
             tile, 16, 8, 2, 128, 2, jnp.bfloat16, backward) \
-            <= cca_mix._PLAN_VMEM_BUDGET
+            <= engine.PLAN_VMEM_BUDGET
     wide = cca_mix.plan(16384, 32, 8, 128, 2, 2, 64, jnp.bfloat16)
     assert wide is None or wide.bwd_tile <= wide.fwd_tile < cell.fwd_tile, wide
     assert cca_mix.plan(16384, 8, 2, 128, 2, 2, 64, jnp.bfloat16,
@@ -257,14 +272,16 @@ def kernel_step():
     has no option for it)."""
     geos = []
 
-    def interpreted(*args, **kwargs):
-        outs, geo = mix(*args, **{**kwargs, "force": "interpret"})
-        geos.append(geo)
-        return outs, geo
+    def interpreted(name, fields, mesh, plan, kernels, fallback, **fixed):
+        def noting(geo, interpret):
+            geos.append(geo)
+            return kernels(geo, interpret)
+        return site(name, fields, mesh, plan, noting, fallback,
+                    force="interpret", **fixed)
 
-    mix = cca_mix.mix
+    site = engine.site
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(cca_mix, "mix", interpreted)
+        mp.setattr(engine, "site", interpreted)
         step = tiny_decoder._build(**KERNEL_SHAPED)
     return step, geos
 

@@ -18,6 +18,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import observability
+from paddle_tpu.kernels import engine
 
 # the kernels package re-exports the flash_attention FUNCTION under the
 # same name as its module; go through importlib for the module itself
@@ -251,7 +252,8 @@ def test_plan_is_tiled_inside_its_share_and_never_pads_further(
                 assert b % 128 == 0
                 assert -(-s // b) * b == -(-s // 128) * 128
         assert fa.fwd_working_set_bytes(
-            bq, bk, d, -(-sq // bq), dtype, emit_lse) <= fa._PLAN_VMEM_BUDGET
+            bq, bk, d, -(-sq // bq), dtype, emit_lse) \
+            <= engine.PLAN_VMEM_BUDGET
         if max(sq, sk) > 128:
             assert bq * bk > 128 * 128        # what the plan is for
 
@@ -474,7 +476,7 @@ def test_bwd_plan_is_tiled_inside_its_share(sq, sk, d, dtype, causal):
             assert b % 128 == 0
             assert -(-s // b) * b == -(-s // 128) * 128
     ws = fa.bwd_working_set_bytes(bq, bk, d, -(-sq // bq), dtype)
-    assert ws <= fa._PLAN_VMEM_BUDGET or (bq, bk) == (128, 128)
+    assert ws <= engine.PLAN_VMEM_BUDGET or (bq, bk) == (128, 128)
     # four fp32 score planes where the forward counts two
     assert ws - 4 * bq * bk * 4 > 0
     fwd = fa._plan_blocks(sq, sk, d, dtype, causal, True)
@@ -647,8 +649,8 @@ def test_rows_of_a_step_are_the_most_that_fit_and_divide(bh, fwd_rows,
     assert fa._rows_per_step(bh, False, fwd) == 1    # several blocks a head
     for ws, rows in ((fwd, fwd_rows), (bwd, bwd_rows)):
         assert bh % rows == 0
-        assert rows == 1 or ws(rows) <= fa._PLAN_VMEM_BUDGET
-        assert all(ws(n) > fa._PLAN_VMEM_BUDGET
+        assert rows == 1 or ws(rows) <= engine.PLAN_VMEM_BUDGET
+        assert all(ws(n) > engine.PLAN_VMEM_BUDGET
                    for n in range(rows + 1, bh + 1) if bh % n == 0)
         assert ws(3) - ws(2) == ws(4) - ws(3) > 0
     assert fwd(1) == fa.fwd_working_set_bytes(256, 256, 64, 1, "bfloat16",
@@ -808,8 +810,8 @@ def test_heads_last_rows_of_a_step_are_the_most_that_fit_and_divide(
     assert fa._heads_last_rows(batch, 256, 256, heads, 64, "bfloat16") == \
         (fwd_rows, fwd_only_rows, bwd_rows)
     for ws, rows in ((fwd, fwd_rows), (bwd, bwd_rows)):
-        assert batch % rows == 0 and ws(rows) <= fa._PLAN_VMEM_BUDGET
-        assert all(ws(n) > fa._PLAN_VMEM_BUDGET
+        assert batch % rows == 0 and ws(rows) <= engine.PLAN_VMEM_BUDGET
+        assert all(ws(n) > engine.PLAN_VMEM_BUDGET
                    for n in range(rows + 1, batch + 1) if batch % n == 0)
     plane = 256 * 256 * 4
     blocks = 256 * heads * 64 * 2

@@ -18,7 +18,7 @@ if REPO not in sys.path:
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observability
-from paddle_tpu.kernels import gated_delta as kda
+from paddle_tpu.kernels import engine, gated_delta as kda
 
 from test_gated_delta_attention import _inputs, token_recurrence
 
@@ -116,7 +116,8 @@ def test_the_cells_site_takes_the_kernels():
     tiles, why = kda.kernel_tiles(1, 4096, 32, 128, 64, jnp.bfloat16)
     assert why == "" and (tiles.rows, tiles.chunk, tiles.unroll) == (512, 64,
                                                                      4)
-    assert tiles.fwd_vmem < tiles.bwd_vmem <= kda._PLAN_VMEM_BUDGET
+    assert tiles.fwd_vmem_bytes < tiles.bwd_vmem_bytes \
+        <= engine.PLAN_VMEM_BUDGET
     # the engine's choice is a function of its own: `plan` says what it said
     assert kda.plan(1, 4096, 32, 128) == {"chunk": 64, "chunks": 64,
                                           "group": 8}
@@ -137,12 +138,12 @@ def test_what_the_shape_rule_refuses_and_why(site, why):
 
 def test_a_working_set_over_the_budget_takes_fewer_rows(monkeypatch):
     site = (1, 4096, 32, 128, 64, jnp.bfloat16)
-    at_512 = kda.kernel_tiles(*site)[0].bwd_vmem
-    monkeypatch.setattr(kda, "_PLAN_VMEM_BUDGET", at_512 - 1)
+    at_512 = kda.kernel_tiles(*site)[0].bwd_vmem_bytes
+    monkeypatch.setattr(kda, "PLAN_VMEM_BUDGET", at_512 - 1)
     assert kda.kernel_tiles(*site)[0].rows == 256
     with pytest.raises(ValueError, match="no kernels at 512 rows"):
         kda.engine(*site, force="pallas", rows=512)
-    monkeypatch.setattr(kda, "_PLAN_VMEM_BUDGET", 1 << 20)
+    monkeypatch.setattr(kda, "PLAN_VMEM_BUDGET", 1 << 20)
     assert kda.kernel_tiles(*site) == (
         None, "heads of 128 do not fit the VMEM budget")
 
@@ -214,8 +215,8 @@ def test_kda_lower_says_pallas_at_the_cells_shape():
     tiles = kda.kernel_tiles(1, S, heads, D, 64, jnp.bfloat16)[0]
     assert spans == [dict(
         heads=heads, head_dim=D, sq=S, chunk=64, chunks=64, group=8,
-        engine="pallas", rows=tiles.rows, fwd_vmem_bytes=tiles.fwd_vmem,
-        bwd_vmem_bytes=tiles.bwd_vmem, state_bytes=4 * heads * D * D,
+        engine="pallas", rows=tiles.rows, fwd_vmem_bytes=tiles.fwd_vmem_bytes,
+        bwd_vmem_bytes=tiles.bwd_vmem_bytes, state_bytes=4 * heads * D * D,
         kept="out,states",
         kept_bytes=2 * S * heads * D + 8 * 4 * heads * D * D,
         flops=kda.flops(1, S, heads, D, 64),

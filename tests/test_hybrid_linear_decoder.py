@@ -28,7 +28,7 @@ from benchmark.harness import reference as harness_reference
 from paddle_tpu import layers, models, observability
 from paddle_tpu.core import amp
 from paddle_tpu.core.framework import name_scope
-from paddle_tpu.kernels import kda_mix
+from paddle_tpu.kernels import engine, kda_mix
 from paddle_tpu.observability import span
 from paddle_tpu.param_attr import ParamAttr
 from paddle_tpu.ops import attention_ops, moe_ops
@@ -543,8 +543,8 @@ def test_kda_mix_lower_says_pallas_at_the_cells_shape():
         assert site == dict(
             what=site["what"], moved_bytes=moved[site["what"]],
             engine="pallas", rows=t.rows, channels=t.channels, halo=t.halo,
-            fwd_vmem_bytes=t.fwd_vmem, bwd_vmem_bytes=t.bwd_vmem)
-        assert t.bwd_vmem <= kda_mix._PLAN_VMEM_BUDGET
+            fwd_vmem_bytes=t.fwd_vmem_bytes, bwd_vmem_bytes=t.bwd_vmem_bytes)
+        assert t.bwd_vmem_bytes <= engine.PLAN_VMEM_BUDGET
     assert on_cpu == [dict(
         what=site["what"], moved_bytes=site["moved_bytes"], engine="xla",
         rows=0, channels=0, halo=0, fwd_vmem_bytes=0, bwd_vmem_bytes=0)

@@ -19,7 +19,8 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as fluid
-from paddle_tpu.kernels import kda_mix
+from paddle_tpu.kernels import engine, kda_mix
+from paddle_tpu.ops import linear_attention_ops as ops
 
 D, EPS = 128, 1e-5
 CONV = ("q", "k", "v", "g", "dq~", "dk~", "dv~", "df", "dwq", "dwk", "dwv",
@@ -69,14 +70,31 @@ def _passes(fn, args, cots):
             seen[0])
 
 
+def _site(force, plan, pair, form, *args):
+    """(outputs, tiles) of one site by the engine `force` names: as the op
+    chooses it (kernels/engine.py::site), without the span."""
+    tiles = engine.tiles_or_none(force, None, plan)
+    if tiles is None:
+        return form(*args), None
+    return pair(*args, tiles, force == "interpret"), tiles
+
+
 def _conv(force, H=2, rows=128, channels=128, **_):
-    return lambda *xs: kda_mix.conv_decay(*xs, H, force=force, rows=rows,
-                                          channels=channels)
+    def site(q, k, v, f, wq, *rest):
+        return _site(force, lambda: kda_mix.conv_tiles(
+            q.shape[1], q.shape[2], wq.shape[0], q.dtype, rows, channels)
+            if engine.one_dtype(q, k, v, f) else None,
+            kda_mix.conv_decay, ops.conv_decay, q, k, v, f, wq, *rest, H)
+    return site
 
 
 def _norm(force, H=2, rows=128, channels=128, **_):
-    return lambda *xs: kda_mix.gated_norm(*xs, H, EPS, force=force,
-                                          rows=rows, channels=channels)
+    def site(o, gate, *rest):
+        return _site(force, lambda: kda_mix.norm_tiles(
+            o.shape[1], o.shape[2], o.shape[2] // H, o.dtype, rows, channels)
+            if engine.one_dtype(o, gate) else None,
+            kda_mix.gated_norm, ops.gated_norm, o, gate, *rest, H, EPS)
+    return site
 
 
 @pytest.fixture(scope="module")
@@ -162,8 +180,8 @@ def test_the_cells_shape_tiles_within_the_budget():
     for tiles in (conv, norm):
         assert S % tiles.rows == 0 and C % tiles.channels == 0
         assert tiles.channels % D == 0 and tiles.rows >= 128
-        assert 0 < tiles.fwd_vmem <= tiles.bwd_vmem <= \
-            kda_mix._PLAN_VMEM_BUDGET
+        assert 0 < tiles.fwd_vmem_bytes <= tiles.bwd_vmem_bytes <= \
+            engine.PLAN_VMEM_BUDGET
     assert (conv.halo, norm.halo) == (16, 0)
     wide = jax.ShapeDtypeStruct((1, S, C), jnp.bfloat16)
     MB = 2 ** 20
@@ -206,8 +224,12 @@ def test_the_engine_is_read_from_the_shape_and_the_platform():
         jax.eval_shape(lambda *xs: seen.append(fn(*xs, **kw)[1]), *args)
         return seen[0]
 
-    conv = lambda *xs, **kw: kda_mix.conv_decay(*xs, 2, **kw)      # noqa: E731
-    norm = lambda *xs, **kw: kda_mix.gated_norm(*xs, 2, EPS, **kw)  # noqa: E731
+    def conv(*xs, force="auto"):
+        return _conv(force, rows=None, channels=None)(*xs)
+
+    def norm(*xs, force="auto"):
+        return _norm(force, rows=None, channels=None)(*xs)
+
     assert tiles(conv, conv_args) is None and tiles(norm, norm_args) is None
     with fluid.flags.tpu_trace_scope(True):
         assert tiles(conv, conv_args).rows == 128

@@ -7,6 +7,7 @@ H_res through the kernel is doubly stochastic; what `maps_tiles` /
 `mix_tiles` say of the cell's shape and of shapes that do not tile; the
 engine each site is given and the span `mhc.kernel.lower` that says so."""
 
+import functools
 import os
 import sys
 import types
@@ -22,7 +23,7 @@ if REPO not in sys.path:
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observability
-from paddle_tpu.kernels import kda_mix, mhc
+from paddle_tpu.kernels import engine, mhc
 from paddle_tpu.ops import hyper_connection_ops as hc
 
 N_STREAMS, N = 4, 24
@@ -71,10 +72,27 @@ def _inputs(pair, B, S, C, dtype=jnp.float32, seed=0, a=1.5, **_):
 
 
 def _engine(pair, force, tile=(None, None)):
-    pin = dict(force=force, rows=tile[0], channels=tile[1])
-    if pair == "maps":
-        return lambda *xs: mhc.maps(*xs, **CFG, **pin)
-    return lambda *xs: getattr(mhc, pair)(*xs, **pin)
+    """(output, tiles) of one site by the engine `force` names: as the op
+    chooses it (ops/hyper_connection_ops.py::_site), without the span."""
+    cfg = CFG if pair == "maps" else {}
+
+    def site(x, *rest):
+        B, S, n, C = x.shape
+
+        def plan():
+            if not engine.one_dtype(x, *rest[1:] if pair == "write" else ()):
+                return None
+            if pair == "maps":
+                return mhc.maps_tiles(S, n, C, CFG["iters"], x.dtype, *tile)
+            return mhc.mix_tiles(S, n, C, x.dtype, pair, *tile)
+
+        tiles = engine.tiles_or_none(force, None, plan)
+        if tiles is None:
+            return jax.checkpoint(functools.partial(
+                getattr(hc, pair), **cfg))(x, *rest), None
+        return getattr(mhc, pair)(x, *rest, tiles, force == "interpret",
+                                  **cfg), tiles
+    return site
 
 
 def _passes(fn, args, cots):
@@ -189,8 +207,8 @@ def test_the_cells_shape_tiles_within_the_budget():
     for tiles in found:
         assert S % tiles.rows == 0 and C % tiles.channels == 0
         assert tiles.channels % 128 == 0 and tiles.rows >= 128
-        assert 0 < max(tiles.fwd_vmem, tiles.bwd_vmem) <= \
-            kda_mix._PLAN_VMEM_BUDGET
+        assert 0 < max(tiles.fwd_vmem_bytes, tiles.bwd_vmem_bytes) <= \
+            engine.PLAN_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("why, maps, mix", [
@@ -295,8 +313,9 @@ def test_mhc_kernel_lower_says_pallas_at_the_cells_shape():
     for site in on_tpu["mhc.kernel.lower"]:
         t = tiles[site["what"]]
         assert site == dict(what=site["what"], engine="pallas", rows=t.rows,
-                            channels=t.channels, fwd_vmem_bytes=t.fwd_vmem,
-                            bwd_vmem_bytes=t.bwd_vmem)
+                            channels=t.channels,
+                            fwd_vmem_bytes=t.fwd_vmem_bytes,
+                            bwd_vmem_bytes=t.bwd_vmem_bytes)
     assert on_cpu["mhc.kernel.lower"] == [dict(
         what=what, engine="xla", rows=0, channels=0, fwd_vmem_bytes=0,
         bwd_vmem_bytes=0) for what in ("maps", "read", "write")]

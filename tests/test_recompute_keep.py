@@ -43,6 +43,8 @@ def _bare(fn, **kwargs):
     return jax.checkpoint(fn, **kwargs)
 
 
+# the door's word (kernels/engine.py) for each engine of the op
+FORCE = {"xla": "jax", "interpret": "interpret"}
 WRAPS = {"none": lambda f: f,
          "bare": lambda f: _bare(f, prevent_cse=False),
          "kept": lambda f: compiler.rematerialised(f, prevent_cse=False)}
@@ -71,7 +73,7 @@ def _layer(engine):
                    for x, n in ((q, H), (k, G), (v, G)))
         out, kl = dsa.sparse_attention(
             q, k, v, heads(qi, HI), ki * 0.5, w, topk=TOPK, scale=D ** -0.5,
-            q_chunk=TQ, kv_chunk=TK, engine=engine)
+            q_chunk=TQ, kv_chunk=TK, force=FORCE[engine])
         return jnp.sum(out * jnp.cos(out)) + 3.0 * kl
 
     return layer
@@ -133,7 +135,7 @@ def test_the_kept_values_are_the_first_forwards_bit_for_bit(engine):
                       for x, n in ((q, H), (k, G), (v, G)))
         out, kl = dsa.sparse_attention(
             qh, kh, vh, heads(qi, HI), ki * 0.5, w, topk=TOPK,
-            scale=D ** -0.5, q_chunk=TQ, kv_chunk=TK, engine=engine)
+            scale=D ** -0.5, q_chunk=TQ, kv_chunk=TK, force=FORCE[engine])
         return jnp.sum(out * jnp.cos(out)) + 3.0 * kl, (out[0], qh[0], kh[0],
                                                         vh[0])
 

@@ -156,7 +156,8 @@ def _op_inputs(rng, B, H, G, S, D, Hi, Di):
             normal(B, Hi, S, Di), normal(B, S, Di), normal(B, S, Hi))
 
 
-@pytest.mark.parametrize("engine", ["xla", "interpret"])
+@pytest.mark.parametrize("engine", ["jax", "interpret"],
+                         ids=["xla", "interpret"])
 def test_below_topk_positions_the_layer_is_dense_causal_gqa(engine):
     """While t < topk every causal key is chosen, whatever the index says:
     the output is plain causal grouped-query attention, and both engines
@@ -167,7 +168,7 @@ def test_below_topk_positions_the_layer_is_dense_causal_gqa(engine):
     with jax.default_matmul_precision("highest"):
         out, _ = dsa.sparse_attention(q, k, v, qi, ki, w, topk=S,
                                       scale=D ** -0.5, q_chunk=8, kv_chunk=8,
-                                      engine=engine)
+                                      force=engine)
         kr, vr = (jnp.repeat(x, H // G, axis=1) for x in (k, v))
         s = jnp.einsum("bhtd,bhsd->bhts", q, kr) * D ** -0.5
         causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
@@ -187,13 +188,13 @@ def test_the_kernels_in_the_interpreter_against_plain_jax():
         def f(*a):
             out, kl = dsa.sparse_attention(
                 *a, topk=8, scale=0.25, q_chunk=16, kv_chunk=8,
-                engine=engine)
+                force=engine)
             return jnp.sum(out * jnp.cos(out)) + 3.0 * kl, (out, kl)
         return jax.value_and_grad(f, argnums=tuple(range(6)), has_aux=True)
 
     with jax.default_matmul_precision("highest"):
         (_, (out, kl)), grads = loss("interpret")(*args)
-        (_, (want, want_kl)), want_grads = loss("xla")(*args)
+        (_, (want, want_kl)), want_grads = loss("jax")(*args)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
     assert float(kl) == pytest.approx(float(want_kl), rel=1e-5)
     for g, r in zip(grads, want_grads):

@@ -61,6 +61,7 @@ def main() -> int:
 
     from paddle_tpu.core import amp
     from paddle_tpu.kernels import cca_mix
+    from paddle_tpu.ops.attention_ops import _inv_freq, compressed_conv_mix
 
     dev = jax.devices()[0]
     if not a.rehearse and dev.platform != "tpu":
@@ -90,8 +91,12 @@ def main() -> int:
 
         def engine(force, tile=None):
             def fwd(*xs):
-                return cca_mix.mix(*xs, H, G, rotary_dim, base, force=force,
-                                   tile=tile)[0]
+                if force == "jax":
+                    return compressed_conv_mix(*xs, H, G, rotary_dim, base)
+                geo = cca_mix.plan(S, H, G, D, k0, k1, rotary_dim, half, tile)
+                return cca_mix.cca_mix(
+                    *xs, geo, tuple(_inv_freq(rotary_dim, base)),
+                    force == "interpret")
 
             def loss(*xs):
                 outs = jax.checkpoint(fwd)(*xs)

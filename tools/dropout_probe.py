@@ -146,7 +146,7 @@ def forms(rehearse: bool, block_rows=None):
     table = {
         "tree": tree,
         "threefry-kept": select(lambda key, shape: dropout_mask.draw(
-            key, shape, P, force="xla")[0]),
+            key, shape, P, force="jax")[0]),
         "rbg-kept": select(rbg),
     }
     if not rehearse:
@@ -390,7 +390,9 @@ def one_chip(a, shapes, half, key, rng, emit, timed):
         for p in (0.1, 0.3, 0.5):
             for engine in ("xla",) if a.rehearse else ("pallas", "xla"):
                 def mask_of(k, s, p=p, engine=engine):
-                    return dropout_mask.draw(k, s, p, force=engine)[0]
+                    return dropout_mask.draw(
+                        k, s, p,
+                        force="jax" if engine == "xla" else engine)[0]
                 emit({"what": "check", "engine": engine, "p": p,
                       "elements": shape[0] * shape[1], **check(
                           mask_of, key, shape, p,

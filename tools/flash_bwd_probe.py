@@ -104,6 +104,7 @@ def main() -> int:
     import numpy as np
 
     fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    from paddle_tpu.kernels import engine
     parent = None
     if a.parent:
         spec = importlib.util.spec_from_file_location(
@@ -231,7 +232,7 @@ def main() -> int:
                  (bq, bk)) for bq in lens for bk in lens
                 if (bq, bk) != pair and fa.bwd_working_set_bytes(
                     bq, bk, D, -(-S // bq), "bfloat16")
-                <= 1.5 * fa._PLAN_VMEM_BUDGET]
+                <= 1.5 * engine.PLAN_VMEM_BUDGET]
         f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
         want = [np.asarray(x) for x in xla(*f32)]
         for label, fn, (bq, bk), *rows_per_step in variants:

@@ -82,20 +82,29 @@ def inputs(shape, seed, dtype):
 
 
 def engines(shape, force, rows=None, channels=None):
-    """{pair: (function of its arguments, the tiles it ran under)}"""
-    from paddle_tpu.kernels import kda_mix
+    """({pair: function of its arguments}, {pair: the tiles it ran under,
+    None for the op's jax.numpy form}), chosen as the ops choose
+    (kernels/engine.py) with the door and the tile in the caller's hand"""
+    from paddle_tpu.kernels import engine, kda_mix
+    from paddle_tpu.ops import linear_attention_ops as ops
 
     H, taken = shape[2], {}
 
-    def conv_decay(*xs):
-        outs, taken["conv_decay"] = kda_mix.conv_decay(
-            *xs, H, force=force, rows=rows, channels=channels)
-        return outs
+    def site(pair, plan, *xs):
+        tiles = taken[pair] = engine.tiles_or_none(force, None, plan)
+        if tiles is None:
+            return getattr(ops, pair)(*xs)
+        return getattr(kda_mix, pair)(*xs, tiles, force == "interpret")
 
-    def gated_norm(*xs):
-        out, taken["gated_norm"] = kda_mix.gated_norm(
-            *xs, H, EPS, force=force, rows=rows, channels=channels)
-        return (out,)
+    def conv_decay(q, k, v, f, wq, *rest):
+        return site("conv_decay", lambda: kda_mix.conv_tiles(
+            q.shape[1], q.shape[2], wq.shape[0], q.dtype, rows, channels),
+            q, k, v, f, wq, *rest, H)
+
+    def gated_norm(o, *rest):
+        return (site("gated_norm", lambda: kda_mix.norm_tiles(
+            o.shape[1], o.shape[2], o.shape[2] // H, o.dtype, rows, channels),
+            o, *rest, H, EPS),)
 
     return {"conv_decay": conv_decay, "gated_norm": gated_norm}, taken
 

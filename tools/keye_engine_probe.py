@@ -132,7 +132,8 @@ def main() -> int:
 
     def op(*args):
         out, kl = sa.sparse_attention(*args, topk=topk, scale=scale,
-                                      q_chunk=tq, kv_chunk=tk, engine=engine)
+                                      q_chunk=tq, kv_chunk=tk,
+                                      force="jax" if engine else "auto")
         return jnp.sum(out.astype(jnp.float32)) + kl
 
     pairs = chosen_pairs(S, topk)

@@ -103,25 +103,34 @@ def inputs(shape, seed, dtype):
 
 
 def engines(force, rows=None, channels=None):
-    """({pair: function of its arguments}, {pair: the tiles it ran
-    under})"""
-    from paddle_tpu.kernels import mhc
+    """({pair: function of its arguments}, {pair: the tiles it ran under,
+    None for the op's jax.numpy form}), chosen as the ops choose
+    (kernels/engine.py) with the door and the tile in the caller's hand"""
+    import functools
 
-    taken, pin = {}, dict(force=force, rows=rows, channels=channels)
+    import jax
 
-    def maps(x, *small):
-        out, taken["maps"] = mhc.maps(_turned(x), *small, **MAPS, **pin)
-        return (out,)
+    from paddle_tpu.kernels import engine, mhc
+    from paddle_tpu.ops import hyper_connection_ops as hc
 
-    def read(x, h):
-        out, taken["read"] = mhc.read(_turned(x), h, **pin)
-        return (out,)
+    taken = {}
 
-    def write(x, h, y):
-        out, taken["write"] = mhc.write(_turned(x), h, y, **pin)
-        return (_turned(out),)
+    def site(pair, x, *rest, **cfg):
+        x = _turned(x)
+        B, S, n, C = x.shape
+        tiles = taken[pair] = engine.tiles_or_none(force, None, lambda: (
+            mhc.maps_tiles(S, n, C, MAPS["iters"], x.dtype, rows, channels)
+            if pair == "maps" else
+            mhc.mix_tiles(S, n, C, x.dtype, pair, rows, channels)))
+        if tiles is None:
+            return jax.checkpoint(functools.partial(
+                getattr(hc, pair), **cfg))(x, *rest)
+        return getattr(mhc, pair)(x, *rest, tiles, force == "interpret",
+                                  **cfg)
 
-    return {"maps": maps, "read": read, "write": write}, taken
+    return {"maps": lambda *xs: (site("maps", *xs, **MAPS),),
+            "read": lambda *xs: (site("read", *xs),),
+            "write": lambda *xs: (_turned(site("write", *xs)),)}, taken
 
 
 def _turned(x):

@@ -1,8 +1,11 @@
 """sha256 of a benchmark cell's step as it lowers for the TPU (StableHLO,
 chip-less, at the cell's real sizes), whole and with what embeds source
-paths cut (the kernels' payloads, the locations): how a PR shows that it
-left the program alone.  Two checkouts lower to the same step where their
-`sha256_cut` agree; `sha256` agrees besides only from one path.
+paths cut (the kernels' payloads, the locations), and of the kernels
+themselves (`kernels` of them: every tpu_custom_call's Mosaic module
+printed without its locations, beside the rest of its config): how a PR
+shows that it left the program alone.  Two checkouts lower to the same
+step where their `sha256_cut` and `sha256_kernels` agree; `sha256` agrees
+besides only from one path.
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         python3 tools/step_sha.py <cell> [<checkout, default this one>]
@@ -12,6 +15,7 @@ loads the TPU compiler: one such process at a time), a four-chip cell
 through ParallelExecutor's own compile and jax.export on four virtual CPU
 devices.  Seconds for the two oldest configurations, minutes for the three
 newest (the startup program runs on the CPU).  Nothing runs on a chip."""
+import base64
 import hashlib
 import json
 import os
@@ -71,10 +75,40 @@ else:
             feeds, states, sds(key, state_sh[-1]))
         text = exp.mlir_module()
 
+
+
+def kernels(text):
+    """Every kernel of the step as text that holds no source location: the
+    custom call's config with its `body`, Mosaic's serialised module,
+    parsed and printed without debug information."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True   # the serialised form's own
+    found = []
+    for quoted in re.findall(r'backend_config = "([^"\n]*)"', text):
+        config = json.loads(re.sub(
+            r'\\([0-9A-Fa-f]{2})', lambda m: chr(int(m.group(1), 16)),
+            quoted)).get("custom_call_config")
+        if config and "body" in config:
+            with ctx:
+                body = ir.Module.parse(base64.b64decode(config.pop("body")))
+            found.append(json.dumps(config, sort_keys=True) + "\n"
+                         + body.operation.get_asm(enable_debug_info=False))
+    return found
+
+
+found = kernels(text)
 cut = re.sub(r'"[^"\n]{200,}"', '"<cut>"', text)
 cut = re.sub(r'loc\([^\n]*', '', cut)
 print(json.dumps({
     "cell": name, "checkout": checkout, "bytes": len(text),
     "sha256": hashlib.sha256(text.encode()).hexdigest(),
     "bytes_cut": len(cut),
-    "sha256_cut": hashlib.sha256(cut.encode()).hexdigest()}))
+    "sha256_cut": hashlib.sha256(cut.encode()).hexdigest(),
+    "kernels": len(found),
+    "sha256_kernels": hashlib.sha256(
+        "\n====\n".join(found).encode()).hexdigest()}))
