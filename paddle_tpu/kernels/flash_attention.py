@@ -9,7 +9,7 @@ cast to the operand dtype for the MXU alone.  Everything is read from a
 call's shape, `causal` and `window`: no flag, no argument a model sets
 (`force` is kernels/engine.py's door, the tests' and the probes').
 
-Three kernel families, picked by the shape:
+Four kernel families, picked by the shape:
 - blocks with state (_flash_kernel, _flash_bwd_kernel), several blocks a
   head.  A grid step costs ~0.35 us before it computes anything, so the
   plans (_plan_blocks, _plan_bwd_blocks) take the blocks with the fewest
@@ -38,22 +38,45 @@ Three kernel families, picked by the shape:
   a select (_tiles_of_heads), the mathematics the rows kernels', and D is
   made inside the backward kernel on the MXU.  Every other shape transposes
   to heads-first and back: the same numbers.
+- the band (_band_kernel, _band_bwd_kernel): a causal call whose `window`
+  (a query sees the `window` keys that end at its diagonal) is shorter than
+  its keys walks the band, not the square.  Queries and keys are cut into
+  ONE block length b (_plan_band; _Band): q-block i reads the strip of
+  window / b + 1 K/V blocks that ends at its diagonal, the same two arrays
+  handed in once a sub-block.  Forward: a step computes its q-block WHOLE,
+  the strip's scores, the plain softmax over them (no state, as the rows
+  kernels), a P V product a sub-block; the group's query heads are the
+  innermost grid axis, so a strip is fetched once for the heads that read
+  it.  Backward: ONE call whatever the row, grid (K/V head, k-block, head
+  of the group, the q-blocks that see the k-block); dK and dV accumulate in
+  VMEM over the group and leave once, already summed; dQ lives in a ring of
+  window / b blocks a head (a block is complete when its diagonal k-block
+  has run, leaves then and frees the slot of the block that enters): no
+  loop over chunks, no per-chunk dK / dV in HBM, no sum after the kernel.
 `flash.plan` / `flash.bwd_plan` (spans, at lowering) say what a site was
-given: blocks, steps, `rows_per_step`, `layout` (bshd | bhsd), `chunks`.
+given: `form` (band | blocks), blocks, steps, `rows_per_step`, `layout`
+(bshd | bhsd), `chunks`.
 
-Skipped blocks: under `causal` a k-block above the diagonal, and under
-`window` (a query sees the `window` keys that end at its diagonal) one older
-than a q-block's window, is neither fetched nor computed (pl.when; the index
-maps repeat the block held or wait at the first that runs, _first_k_block,
-_q_block_index); a block is masked only where an edge, the padded end or
-klen[b] cuts it.  The plans count the steps that RUN, under a window weighed
-by the scores a block computes (_fewest_steps).
+Skipped blocks: under `causal` a k-block above the diagonal is neither
+fetched nor computed (pl.when; the index maps repeat the block held or wait
+at the first that runs, _kv_block_index, _q_block_index); a block is masked
+only where the diagonal, the padded end or klen[b] cuts it, and the plans
+count the steps that RUN (_fewest_steps).  Under a window the blocks of the
+square outside the band are no grid step at all: a sub-block's place in its
+strip is static, so only the oldest takes the window's compare and only the
+diagonal one the causal compare, each against a constant (the sub-blocks
+between them take none), and the keys' ends are compared only in the steps
+whose strip they cut (a scalar test).  The spans count in score blocks of
+the square either way (`k_steps` / `steps`, `skipped_causal`,
+`skipped_window`), and the band's plan weighs a step by the scores it
+computes beside _STEP_COST_SCORES.
 
 Grouped K/V: k, v [B, G, Sk, .], G a divisor of H; query head j reads head
 j // (H / G) through the index maps, never repeated in HBM; a group's dK, dV
-are added up after the kernel in fp32 (_bwd_rows).  Long rows: where the
-row's dQ does not fit VMEM (past S ~4k at head 128) the backward loops over
-chunks of queries around the one kernel (_bwd_trips; `chunks` on the span).
+are added up after the block kernel in fp32 (_bwd_rows), inside the band's.
+Long rows: where the row's dQ does not fit VMEM (past S ~4k at head 128)
+the block backward loops over chunks of queries around the one kernel
+(_bwd_trips; `chunks` on the span); the band's never does.
 
 Two backward engines, one rule (_bwd_plan): the Pallas kernel where a grid
 step (score block x the rows it takes, _packable_rows) has at least
@@ -87,19 +110,25 @@ import numpy as np
 from ..analysis.pallas import tile_padded_bytes
 from ..core.compiler import keep
 from ..observability import span
-from .engine import LANES, PLAN_VMEM_BUDGET, use_pallas, wants_kernels
+from .engine import (LANES, PLAN_VMEM_BUDGET, compiler_params, use_pallas,
+                     wants_kernels)
 
 __all__ = ["flash_attention", "merge_attention", "fwd_vmem_bytes",
-           "fwd_working_set_bytes", "bwd_working_set_bytes", "KEPT", "kept",
+           "fwd_working_set_bytes", "bwd_working_set_bytes",
+           "band_fwd_vmem_bytes", "band_fwd_working_set_bytes",
+           "band_bwd_vmem_bytes", "band_bwd_working_set_bytes", "KEPT", "kept",
            "kept_bytes", "takes_heads_last", "heads_first_shapes"]
 
 NEG_INF = -1e30
 
-# What a grid step costs before it computes anything (~0.35 us, PR 28), in
-# the scores the MXU computes in that time: how _fewest_steps weighs a
-# windowed site's steps against the scores its blocks compute outside the
-# window.
-_STEP_COST_SCORES = 256 * 256
+# What a grid step of the band costs before it computes anything, in the
+# scores the kernel computes in that time: how _plan_band weighs a windowed
+# site's steps against the scores its blocks compute outside the window.
+# Settled on the chip (tools/flash_fwd_probe.py, PERF.md PR 59): the forward
+# at 32 heads x 16384 x 128, window 1024, reads 4.99 / 3.01 / 2.65 / 3.22 ms
+# at blocks of 128 / 256 / 512 / 1024, which is 3.8 ps a score computed and
+# 0.85 us a step: ~225 k scores.
+_STEP_COST_SCORES = 480 * 480
 
 
 def fwd_vmem_bytes(block_q: int = 128, block_k: int = 128,
@@ -171,22 +200,17 @@ def _block_lengths(seq: int):
     return [128 * n for n in range(1, tiles + 1) if tiles % n == 0]
 
 
-def _fewest_steps(sq, sk, causal, working_set, window=None):
+def _fewest_steps(sq, sk, causal, working_set):
     """The (block_q, block_k) whose grid takes the fewest steps that run,
     of the pairs whose `working_set(block_q, block_k)` bytes fit the
     plan's budget (kernels/engine.py); the wider key block where two tie.
     Under `causal`
-    the steps counted are those that run: _skipped_steps are free.  Under
-    `window` the steps that run are weighed by the scores they compute
-    beside _STEP_COST_SCORES: a block that straddles an edge of the window
-    is half waste, and at the widest blocks that is half of all of them."""
+    the steps counted are those that run: _skipped_steps are free."""
     def steps_then_wide(plan):
         bq, bk = plan
         nqb, nkb = -(-sq // bq), -(-sk // bk)
         run = nqb * nkb - sum(_skipped_steps(nqb, nkb, bq, bk, sk - sq,
-                                             causal, window))
-        if window is not None:
-            run *= bq * bk + _STEP_COST_SCORES
+                                             causal))
         return run, -bk
 
     plans = [(bq, bk) for bq in _block_lengths(sq)
@@ -198,10 +222,9 @@ def _fewest_steps(sq, sk, causal, working_set, window=None):
     return min(fits or plans[:1], key=steps_then_wide)
 
 
-def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None,
-                 window=None):
-    """(block_q, block_k) of the forward's grid, from the shape (and the
-    window, which is part of it) alone.
+def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None):
+    """(block_q, block_k) of the forward's grid, from the shape alone (a
+    windowed site's plan is _plan_band's).
 
     A grid step costs about the same whatever it computes (the pipeline's
     bookkeeping, two DMAs, a read-modify-write of the fp32 accumulator and
@@ -212,7 +235,7 @@ def _plan_blocks(sq, sk, head_dim, dtype, causal, emit_lse, v_dim=None,
     at 0.59 ms, 1024 x 256 at 1.52)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: fwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse, v_dim), window)
+            bq, bk, head_dim, -(-sq // bq), dtype, emit_lse, v_dim))
 
 
 def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
@@ -254,15 +277,14 @@ def bwd_working_set_bytes(block_q, block_k, head_dim, num_q_blocks=1,
             + 4 * tile((block_k, block_q), "float32"))
 
 
-def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None,
-                     window=None):
+def _plan_bwd_blocks(sq, sk, head_dim, dtype, causal, v_dim=None):
     """(block_q, block_k) of the backward's grid, on the forward's
     principle: the fewest grid steps that run (_fewest_steps) whose working
     set (bwd_working_set_bytes) fits.  It need not be the forward's pair:
     the packed lse plane is re-cut for free (_repack)."""
     return _fewest_steps(
         sq, sk, causal, lambda bq, bk: bwd_working_set_bytes(
-            bq, bk, head_dim, -(-sq // bq), dtype, v_dim), window)
+            bq, bk, head_dim, -(-sq // bq), dtype, v_dim))
 
 
 def _rows_per_step(bh, one_block, working_set):
@@ -307,22 +329,14 @@ def _oldest_key(row, causal_offset, window):
     return row + causal_offset - (window - 1)
 
 
-def _first_k_block(qi, block_q, block_k, causal_offset, window):
-    """The oldest K/V block a windowed q-block qi reads: the one that holds
-    the oldest key its FIRST row sees.  The forward's k-steps of a windowed
-    site count from here (_flash_kernel), so the blocks before it are no
-    grid step at all."""
-    return jnp.maximum(
-        _oldest_key(qi * block_q, causal_offset, window), 0) // block_k
-
-
 def _skipped_steps(nqb, nkb, block_q, block_k, causal_offset, causal=True,
                    window=None):
     """(above the diagonal, older than the window): how many of one (batch,
     head)'s nqb x nkb score blocks cost neither a fetch nor a matmul.  For
     each q-block, the k-blocks past the last one that _block_runs, and
-    under `window` those before _first_k_block (a row of blocks at a time,
-    so a 128k sequence plans in no time)."""
+    under `window` those before the block of the oldest key its first row
+    sees (a row of blocks at a time, so a 128k sequence plans in no
+    time)."""
     above = older = 0
     for i in range(nqb):
         under = nkb
@@ -409,10 +423,10 @@ def _reference_attention(q, k, v, causal, scale, bias=None, k_lengths=None,
 
 
 def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
-                causal, causal_offset, transposed=False, window=None):
-    """Key-padding (+ causal, + window) mask for score block (qi, ki) of
-    batch row bi — identical in forward and backward.  `transposed`: the
-    block is [block_k, block_q] (the backward kernel's scores)."""
+                causal, causal_offset, transposed=False):
+    """Key-padding (+ causal) mask for score block (qi, ki) of batch row
+    bi — identical in forward and backward.  `transposed`: the block is
+    [block_k, block_q] (the backward kernel's scores)."""
     q_axis, k_axis = (1, 0) if transposed else (0, 1)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
@@ -421,22 +435,16 @@ def _block_mask(klen_ref, bi, qi, ki, shape, block_q, block_k, seq_k,
         # bottom-right alignment (matches jnp.tril(k=Sk-Sq)): with cached
         # keys (Sk > Sq) a query at row i sees keys up to i + Sk - Sq
         mask &= k_pos <= q_pos + causal_offset
-    if window is not None:
-        # the `window` keys that end at the row's diagonal
-        mask &= k_pos > q_pos + causal_offset - window
     return mask
 
 
 def _step_cases(klen_ref, bi, qi, ki, *, causal, block_q, block_k, seq_k,
-                causal_offset, window=None):
+                causal_offset):
     """(runs, cut) of score block (qi, ki), forward and backward: whether
-    the block has a key at or under the causal diagonal (and, under
-    `window`, one that its first row's window still reaches), and whether
-    the diagonal (its last key is past what its first row sees), the
-    window's far edge (its first key is older than what its last row sees),
-    the padded end of the keys or klen[b] (data: a scalar read from SMEM)
-    cuts it.  Only a block that is cut pays for the iota/compare/select
-    mask."""
+    the block has a key at or under the causal diagonal, and whether the
+    diagonal (its last key is past what its first row sees), the padded
+    end of the keys or klen[b] (data: a scalar read from SMEM) cuts it.
+    Only a block that is cut pays for the iota/compare/select mask."""
     k_end = jnp.minimum(seq_k, klen_ref[bi].astype(jnp.int32))
     cut = (ki + 1) * block_k > k_end
     runs = True
@@ -444,10 +452,6 @@ def _step_cases(klen_ref, bi, qi, ki, *, causal, block_q, block_k, seq_k,
         runs = _block_runs(qi, ki, block_q, block_k, causal_offset)
         cut = jnp.logical_or(
             cut, (ki + 1) * block_k - 1 > qi * block_q + causal_offset)
-    if window is not None:
-        oldest = _oldest_key(qi * block_q, causal_offset, window)
-        runs = jnp.logical_and(runs, (ki + 1) * block_k - 1 >= oldest)
-        cut = jnp.logical_or(cut, ki * block_k < oldest + block_q - 1)
     return runs, cut
 
 
@@ -462,8 +466,7 @@ def _when_runs(runs, cut, update):
 
 def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr,
-                  *, causal, scale, block_q, block_k, seq_k, causal_offset,
-                  window=None):
+                  *, causal, scale, block_q, block_k, seq_k, causal_offset):
     """Grid: (batch*heads, num_q_blocks, num_k_blocks); K innermost so the
     online-softmax state lives in VMEM scratch across K steps.  klen_ref
     (SMEM) holds every batch row's valid key count (key-padding mask),
@@ -477,25 +480,15 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     Of the blocks that run, only one that can be cut takes the
     iota/compare/select mask: it crosses the diagonal, or it reaches past
     the keys this batch row has (the padded end of the sequence, or
-    klen[b], which is data: a scalar read from SMEM).
-
-    Under `window` the last grid axis counts the k-blocks from
-    _first_k_block of the q-block on (as _fwd_call's index maps do): a block
-    wholly older than the window of the q-block's first row is no step at
-    all, and the axis is as long as the widest span of blocks a q-block
-    reads.  A block that reaches behind the window of the q-block's last
-    row takes the mask."""
+    klen[b], which is data: a scalar read from SMEM)."""
     import jax.experimental.pallas as pl
 
     bi = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = step = pl.program_id(2)
+    ki = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    if window is not None:
-        ki = step + _first_k_block(qi, block_q, block_k, causal_offset,
-                                   window)
 
-    @pl.when(step == 0)
+    @pl.when(ki == 0)
     def _init():
         # the running-max floor is NEG_INF/2, NOT NEG_INF: a fully-masked
         # row keeps m at the floor, so p = exp(NEG_INF - NEG_INF/2)
@@ -517,8 +510,7 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         if cut:
             mask = _block_mask(klen_ref, bi, qi, ki, s.shape, block_q,
-                               block_k, seq_k, causal, causal_offset,
-                               window=window)
+                               block_k, seq_k, causal, causal_offset)
             s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:]  # [block_q, 1]
@@ -535,9 +527,9 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     _when_runs(*_step_cases(
         klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=seq_k, causal_offset=causal_offset, window=window), _update)
+        seq_k=seq_k, causal_offset=causal_offset), _update)
 
-    @pl.when(step == num_kb - 1)
+    @pl.when(ki == num_kb - 1)
     def _finalize():
         l_fin = l_scr[:]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
@@ -558,7 +550,7 @@ def _flash_kernel(klen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       dvec_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
                       dv_scr, *, causal, scale, block_q, block_k, seq_k,
-                      causal_offset, window=None):
+                      causal_offset):
     """dQ, dK and dV in one kernel: grid (BH, num_k_blocks, num_q_blocks),
     Q innermost.  The dK/dV accumulators of one k-block stay in VMEM across
     its q-blocks; dQ accumulates across the k-blocks into an fp32
@@ -601,8 +593,7 @@ def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if cut:
             pt = jnp.where(
                 _block_mask(klen_ref, bi, qi, ki, st.shape, block_q, block_k,
-                            seq_k, causal, causal_offset, transposed=True,
-                            window=window),
+                            seq_k, causal, causal_offset, transposed=True),
                 pt, 0.0)
         dpt = jax.lax.dot_general(v_ref[0], do, nt,
                                   preferred_element_type=jnp.float32)
@@ -617,7 +608,7 @@ def _flash_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     _when_runs(*_step_cases(
         klen_ref, bi, qi, ki, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=seq_k, causal_offset=causal_offset, window=window), _update)
+        seq_k=seq_k, causal_offset=causal_offset), _update)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
@@ -878,24 +869,10 @@ def _flash_kernel_fwd_only(klen_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, **kw)
 
 
-def _window_k_steps(nqb, nkb, block_q, block_k, causal_offset, window):
-    """The length of a windowed forward's k-axis: the most K/V blocks one
-    q-block reads, from _first_k_block to the block of its last row's
-    diagonal."""
-    def blocks(i):
-        first = max(_oldest_key(i * block_q, causal_offset, window),
-                    0) // block_k
-        last = min(((i + 1) * block_q - 1 + causal_offset) // block_k,
-                   nkb - 1)
-        return last - first + 1
-
-    return max(1, max(blocks(i) for i in range(nqb)))
-
-
 @functools.lru_cache(maxsize=128)
 def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
               causal_offset, dtype, interpret, emit_lse=True, dv=None,
-              window=None, group=1, rows_per_step=1):
+              group=1, rows_per_step=1):
     """Memoized pallas_call: every attention site with the same static
     config reuses ONE traced callable, so XLA sees identical kernel
     payloads (compile-cache friendly) instead of per-site clones.
@@ -903,8 +880,7 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
     _flash_kernel_fwd_only).  `dv` is the width of V and O where it is
     not Q's and K's `d`.  `group` query heads (consecutive rows of q) read
     one K/V head through the index maps: K and V come as [bh / group, skp,
-    .] and are never repeated.  Under `window` the k-axis counts from
-    _first_k_block (see _flash_kernel).  `rows_per_step` batch-head rows
+    .] and are never repeated.  `rows_per_step` batch-head rows
     make one block and one step of the first grid axis (_rows_per_step);
     more than one are a head of one block each, which is
     _flash_rows_kernel's to run."""
@@ -914,16 +890,10 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
     kernel = _flash_kernel if emit_lse else _flash_kernel_fwd_only
     nqb, nkb = sqp // bq, skp // bk
     dv = d if dv is None else dv
-    k_steps = nkb if window is None else _window_k_steps(
-        nqb, nkb, bq, bk, causal_offset, window)
 
     def kv_block(b, i, j):
-        if window is not None:
-            j = j + _first_k_block(i, bq, bk, causal_offset, window)
         if causal:
             j = _kv_block_index(i, j, bq, bk, causal_offset)
-        if window is not None:
-            j = jnp.minimum(j, nkb - 1)
         if group > 1:
             b = b // group
         return (b, j, 0)
@@ -940,8 +910,6 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
             jax.ShapeDtypeStruct((bh, nqb, bq), jnp.float32))
     static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
                   seq_k=seq_k, causal_offset=causal_offset)
-    if window is not None:
-        static["window"] = window
     scratch = [pltpu.VMEM((bq, 1), jnp.float32),
                pltpu.VMEM((bq, 1), jnp.float32),
                pltpu.VMEM((bq, dv), jnp.float32)]
@@ -952,7 +920,7 @@ def _fwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
         scratch = []
     return pl.pallas_call(
         functools.partial(kernel, **static),
-        grid=(bh // n, nqb, k_steps),
+        grid=(bh // n, nqb, nkb),
         in_specs=[
             # whole [B*H] vector in SMEM, indexed by program_id(0) in-kernel
             # (TPU rejects rank-1 blocks smaller than the 128 tile)
@@ -989,11 +957,13 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     ([B, G, Sk, .]: query head j reads head j // (H / G)).  The blocks come
     from _plan_blocks and the batch-head rows a grid step takes from
     _rows_per_step; block_q / block_k / rows_per_step pin them for a test or
-    the probe, never a model."""
+    the probe, never a model.  A `window` is the band's (_pallas_band)."""
+    if window is not None:
+        return _pallas_band(q, k, v, klen, scale, window, block_q, interpret,
+                            need_lse)
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
-    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv,
-                                  window)
+    plan_q, plan_k = _plan_blocks(Sq, Sk, D, q.dtype, causal, need_lse, Dv)
     bq = plan_q if block_q is None else min(block_q, Sq)
     bk = plan_k if block_k is None else min(block_k, Sk)
     # pad sequence dims to block multiples (masked in-kernel)
@@ -1006,21 +976,21 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     klen_bh = jnp.repeat(klen, H)  # [B*H] valid key counts
 
     nqb, nkb = qf.shape[1] // bq, kf.shape[1] // bk
-    above, older = _skipped_steps(nqb, nkb, bq, bk, Sk - Sq, causal, window)
+    above, older = _skipped_steps(nqb, nkb, bq, bk, Sk - Sq, causal)
     if rows_per_step is None:
         rows_per_step = _rows_per_step(
-            _packable_rows(q, k), nqb == nkb == 1 and window is None,
+            _packable_rows(q, k), nqb == nkb == 1,
             lambda n: fwd_working_set_bytes(bq, bk, D, 1, q.dtype, need_lse,
                                             Dv, n))
     # at lowering, as recurrence.lower: static counts over one (b, h)
     with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=bq,
               block_k=bk, k_steps=nqb * nkb, k_steps_skipped=above + older,
-              causal=int(causal), window=int(window or 0), kv_heads=G,
+              causal=int(causal), window=0, kv_heads=G,
               chunks=1, skipped_causal=above, skipped_window=older,
-              rows_per_step=rows_per_step, layout="bhsd"):
+              rows_per_step=rows_per_step, layout="bhsd", form="blocks"):
         call = _fwd_call(B * H, qf.shape[1], kf.shape[1], D, bq, bk, causal,
                          scale, Sk, Sk - Sq, str(q.dtype), interpret,
-                         emit_lse=need_lse, dv=Dv, window=window,
+                         emit_lse=need_lse, dv=Dv,
                          group=H // G, rows_per_step=rows_per_step)
         res = call(klen_bh, qf, kf, vf)  # list: [out] or [out, lse]
     out = res[0].reshape(B, H, res[0].shape[1], Dv)
@@ -1031,28 +1001,19 @@ def _pallas_flash(q, k, v, klen, causal, scale, block_q=None, block_k=None,
     return out, res[1]  # packed [B*H, nqb, bq]; the bwd re-cuts it (_repack)
 
 
-def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb,
-                   window=None):
+def _q_block_index(qi, ki, block_q, block_k, causal_offset, nqb):
     """The q/dO block a causal dK/dV grid step (ki, qi) holds: its own from
     the first q-block whose last row sees k-block ki's first key; before
     that one (the kernel skips those steps) the index waits there, so the
-    first block that runs is the only one fetched.  Under `window` it
-    stays, likewise, at the last q-block whose first row still reaches the
-    k-block's last key."""
+    first block that runs is the only one fetched."""
     first = jnp.maximum(ki * block_k - causal_offset, 0) // block_q
-    first = jnp.minimum(first, nqb - 1)
-    qi = jnp.maximum(qi, first)
-    if window is not None:
-        last = jnp.maximum((ki + 1) * block_k - 1 - causal_offset
-                           + window - 1, 0) // block_q
-        qi = jnp.minimum(qi, jnp.maximum(jnp.minimum(last, nqb - 1), first))
-    return qi
+    return jnp.maximum(qi, jnp.minimum(first, nqb - 1))
 
 
 @functools.lru_cache(maxsize=128)
 def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
               causal_offset, q_dtype, k_dtype, v_dtype, interpret, dv=None,
-              window=None, group=1, rows_per_step=1):
+              group=1, rows_per_step=1):
     """Memoized pallas_call of _flash_bwd_kernel — see _fwd_call.  With
     `group` > 1 K and V come as [bh / group, skp, .] and are read through
     the index maps; dK and dV leave a query head each, [bh, skp, .], and
@@ -1075,14 +1036,12 @@ def _bwd_call(bh, sqp, skp, d, bq, bk, causal, scale, seq_k,
 
     def q_of_kv(b, j, i):
         if causal:
-            i = _q_block_index(i, j, bq, bk, causal_offset, nqb, window)
+            i = _q_block_index(i, j, bq, bk, causal_offset, nqb)
         return (b, i, 0)
 
     kernel = _flash_bwd_kernel
     static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
                   seq_k=seq_k, causal_offset=causal_offset)
-    if window is not None:
-        static["window"] = window
     scratch = [pltpu.VMEM((sqp, d), jnp.float32),
                pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, dv), jnp.float32)]
@@ -1133,8 +1092,25 @@ def _repack(plane, sq, block_q, fill):
     return flat.reshape(plane.shape[0], sqp // block_q, block_q)
 
 
+def _packed_d(gf, of, dlse, sq, block_q):
+    """D_i = rowsum(dO * O) of gf, of [B*H, Sqp, Dv] in the packed layout
+    the backward kernels index, [B*H, Sqp / block_q, block_q] fp32: one
+    fused elementwise+reduce pass, reshaped (a free, layout-preserving
+    view), so no lane broadcast ever materializes (the old [B*H, Sqp, 128]
+    operands were 128x the payload and did NOT fuse away: custom-call
+    operands are materialized in HBM).  `dlse` [B, H, sq] is the cotangent
+    of the rows' logsumexp where the call handed it out (_flash_lse): dS =
+    P (dP - D + dlse), so it is taken off D and the kernels are the same."""
+    rows, sqp = gf.shape[:2]
+    dvec = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        dvec = dvec - jnp.pad(dlse.reshape(rows, sq).astype(jnp.float32),
+                              ((0, 0), (0, sqp - sq)))
+    return dvec.reshape(rows, sqp // block_q, block_q)
+
+
 def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
-              bq, bk, window, interpret, rows_per_step=1, dlse=None):
+              bq, bk, interpret, rows_per_step=1, dlse=None):
     """(dq, dk, dv) of the queries q [B, H, Sq, D] (with their out, dO `g`
     and packed lse) over the keys k, v [B, G, Sk, .] by ONE call of
     _flash_bwd_kernel: a whole row, or one trip of _pallas_flash_bwd's loop
@@ -1142,9 +1118,7 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
     diagonal's place among them (`causal_offset`: row i sees keys up to
     i + causal_offset).  dk and dv come per K/V head: where a group of
     query heads shares one, the kernel writes a query head's each and they
-    are added up here, in fp32.  `dlse` [B, H, Sq] is the cotangent of the
-    rows' logsumexp where the call handed it out (_flash_lse): dS = P (dP -
-    D + dlse), so it is taken off D and the kernel is the same."""
+    are added up here, in fp32.  `dlse`: _packed_d."""
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     qp = _pad_seq(q, bq)
@@ -1161,21 +1135,11 @@ def _bwd_rows(q, k, v, klen, out, lse, g, causal, scale, causal_offset,
     klen_bh = jnp.repeat(klen, H)
     # a padded row's lse is the fully-masked row's: exp(s - lse) is 0
     lse = _repack(lse, Sq, bq, -NEG_INF)
-    # D_i = rowsum(dO * O): one fused elementwise+reduce pass, fp32,
-    # reshaped (a free, layout-preserving view) straight into the packed
-    # [B*H, nqb, bq] residual layout the kernel indexes — no lane
-    # broadcast ever materializes (the old [B*H, Sqp, 128] operands were
-    # 128x the payload and did NOT fuse away: custom-call operands are
-    # materialized in HBM)
-    dvec = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
-    if dlse is not None:
-        dvec = dvec - jnp.pad(dlse.reshape(B * H, Sq).astype(jnp.float32),
-                              ((0, 0), (0, Sqp - Sq)))
-    dvec = dvec.reshape(B * H, Sqp // bq, bq)
+    dvec = _packed_d(gf, of, dlse, Sq, bq)
 
     call = _bwd_call(B * H, Sqp, Skp, D, bq, bk, causal, scale, Sk,
                      causal_offset, str(q.dtype), str(k.dtype), str(v.dtype),
-                     interpret, dv=Dv, window=window, group=H // G,
+                     interpret, dv=Dv, group=H // G,
                      rows_per_step=rows_per_step)
     dq, dk, dv = call(klen_bh, qf, kf, vf, gf, lse, dvec)
 
@@ -1200,16 +1164,19 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     Where the row's dQ fits VMEM the row is one call, as it always was.
     Past that (S ~4k at head 128) the kernel runs in an outer loop over
     chunks of queries: a chunk's dQ is the whole "row" of its call, and it
-    is handed only the keys it can see (up to its last row's diagonal;
-    under `window` from its first row's oldest key on), whose dK and dV
-    are added, in fp32, into the sequence's."""
+    is handed only the keys it can see (up to its last row's diagonal),
+    whose dK and dV are added, in fp32, into the sequence's.  A `window` is
+    the band's, one call whatever the row (_pallas_band_bwd)."""
+    if window is not None:
+        return _pallas_band_bwd(q, k, v, klen, out, lse, g, scale, window,
+                                block_q, interpret, dlse)
     B, H, Sq, D = q.shape
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     bh = _packable_rows(q, k)
     trips = _bwd_trips(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                       window, chunk, bh)
+                       chunk, bh)
     plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                          window, chunk, bh),
+                          None, chunk, bh),
                 engine="pallas", kv_heads=G)
     if rows_per_step is not None:
         plan["rows_per_step"] = rows_per_step
@@ -1217,7 +1184,7 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
         if len(trips) == 1:
             _, _, _, _, bq, bk = trips[0]
             dq, dk, dv = _bwd_rows(q, k, v, klen, out, lse, g, causal, scale,
-                                   Sk - Sq, bq, bk, window, interpret,
+                                   Sk - Sq, bq, bk, interpret,
                                    plan["rows_per_step"], dlse)
             if G != H:
                 dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
@@ -1231,7 +1198,7 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
                 q[:, :, q0:q1], k[:, :, k0:k1], v[:, :, k0:k1],
                 jnp.clip(klen - k0, 0, k1 - k0), out[:, :, q0:q1],
                 lse[:, None, q0:q1], g[:, :, q0:q1], causal, scale,
-                q0 + (Sk - Sq) - k0, bq, bk, window, interpret,
+                q0 + (Sk - Sq) - k0, bq, bk, interpret,
                 dlse=None if dlse is None else dlse[:, :, q0:q1])
             dqs.append(dq_c)
             dk = dk.at[:, :, k0:k1].add(dk_c.astype(jnp.float32))
@@ -1256,46 +1223,37 @@ _BWD_PALLAS_MIN_BLOCK_SCORES = 384 * 384
 _XLA_BWD_MAX_SCORE_BYTES = 2 << 30
 
 
-def _chunk_keys(q0, q1, causal_offset, sk, causal, window):
-    """[k0, k1) of the keys that the queries [q0, q1) see: up to the last
-    row's diagonal under `causal`, from the first row's oldest key under
-    `window` (taken back to a multiple of 128, the lane tile)."""
+def _chunk_keys(q1, causal_offset, sk, causal):
+    """[k0, k1) of the keys that the queries up to row q1 see: from the
+    first, up to the last row's diagonal under `causal`."""
     k1 = min(q1 + causal_offset, sk) if causal else sk
-    k0 = 0
-    if window is not None:
-        k0 = max(_oldest_key(q0, causal_offset, window), 0) // 128 * 128
-    return min(k0, max(k1 - 1, 0)), max(k1, 1)
+    return 0, max(k1, 1)
 
 
-def _bwd_rows_per_step(bh, sq, sk, block_q, block_k, head_dim, dtype, v_dim,
-                       window):
+def _bwd_rows_per_step(bh, sq, sk, block_q, block_k, head_dim, dtype, v_dim):
     """_rows_per_step of a backward call of one trip at these blocks."""
     return _rows_per_step(
-        bh, sq <= block_q and sk <= block_k and window is None,
+        bh, sq <= block_q and sk <= block_k,
         lambda n: bwd_working_set_bytes(block_q, block_k, head_dim, 1, dtype,
                                         v_dim, n))
 
 
 @functools.lru_cache(maxsize=128)
-def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window, bh=1):
+def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh=1):
     """(rows of queries a call of the backward kernel takes, engine).  The
     whole row where its dQ fits VMEM beside a grid step worth taking (of the
     call's `bh` packable batch-head rows a step may take several,
     _rows_per_step, and it is the step's scores that count), as it
     always was.  Else the longest cut of the row
-    (_block_lengths) whose plan fits with a score block worth a grid step;
-    under `window` the shortest such cut that is no shorter than the window,
-    since a longer chunk only adds k-blocks its q-blocks skip and a shorter
-    one blocks that straddle the window's edge.  Where no cut does, the row
-    stays whole and the engine is XLA's."""
+    (_block_lengths) whose plan fits with a score block worth a grid step.
+    Where no cut does, the row stays whole and the engine is XLA's."""
     def plan(rows, keys, bh=1):
-        bq, bk = _plan_bwd_blocks(rows, keys, head_dim, dtype, causal, v_dim,
-                                  window)
+        bq, bk = _plan_bwd_blocks(rows, keys, head_dim, dtype, causal, v_dim)
         fits = bwd_working_set_bytes(
             bq, bk, head_dim, -(-rows // bq), dtype, v_dim
         ) <= PLAN_VMEM_BUDGET
         step = bq * bk * _bwd_rows_per_step(bh, rows, keys, bq, bk, head_dim,
-                                            dtype, v_dim, window)
+                                            dtype, v_dim)
         return fits, step >= _BWD_PALLAS_MIN_BLOCK_SCORES
 
     fits, worth = plan(sq, sk, bh)
@@ -1304,33 +1262,28 @@ def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, window, bh=1):
     cuts = []
     for rows in _block_lengths(sq)[:-1]:
         q0 = (sq - 1) // rows * rows            # the last chunk sees most
-        k0, k1 = _chunk_keys(q0, sq, sk - sq, sk, causal, window)
+        k0, k1 = _chunk_keys(sq, sk - sq, sk, causal)
         if all(plan(min(rows, sq - q0), k1 - k0)):
             cuts.append(rows)
-    if not cuts:
-        return sq, "xla"
-    if window is not None:
-        reach = [rows for rows in cuts if rows >= window]
-        return (min(reach) if reach else max(cuts)), "pallas"
-    return max(cuts), "pallas"
+    return (max(cuts), "pallas") if cuts else (sq, "xla")
 
 
 def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-               v_dim=None, window=None, chunk=None, bh=1):
+               v_dim=None, chunk=None, bh=1):
     """[(q0, q1, k0, k1, block_q, block_k)]: the calls of the backward
-    kernel that one site makes, one where the row is whole.  `chunk` pins
-    the rows a trip, `block_q` / `block_k` the blocks (a test or the
-    probe), else _bwd_chunk_rows and each trip's own _plan_bwd_blocks."""
-    rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
-                            window, bh)[0] if chunk is None
-            else min(chunk, sq))
+    kernel that one site with no window makes, one where the row is whole.
+    `chunk` pins the rows a trip, `block_q` / `block_k` the blocks (a test
+    or the probe), else _bwd_chunk_rows and each trip's own
+    _plan_bwd_blocks."""
+    rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh)[0]
+            if chunk is None else min(chunk, sq))
     trips = []
     for q0 in range(0, sq, rows):
         q1 = min(q0 + rows, sq)
         k0, k1 = (0, sk) if rows >= sq else _chunk_keys(
-            q0, q1, sk - sq, sk, causal, window)
+            q1, sk - sq, sk, causal)
         bq, bk = _plan_bwd_blocks(q1 - q0, k1 - k0, head_dim, dtype, causal,
-                                  v_dim, window)
+                                  v_dim)
         bq = bq if block_q is None else min(block_q, q1 - q0)
         bk = bk if block_k is None else min(block_k, k1 - k0)
         trips.append((q0, q1, k0, k1, bq, bk))
@@ -1338,7 +1291,7 @@ def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
 
 
 def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-              v_dim=None, window=None, chunk=None, bh=1):
+              v_dim=None, window=None, chunk=None, bh=1, group=1):
     """What the backward of one attention call of this shape is given, the
     `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
     the probe, else _plan_bwd_blocks'; the last trip's where there are
@@ -1348,26 +1301,589 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
     rows_per_step (the batch-head rows a grid step takes, of the call's `bh`
     packable ones, _packable_rows: part of the shape), layout ("bhsd": these
     are the heads-first kernels; _pallas_flash_bwd_bshd says "bshd" and
-    counts batch rows) and engine, "pallas" or "xla": the one place that
-    says which."""
-    engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim,
-                             window, bh)[1]
+    counts batch rows), form ("blocks"; under a `window` shorter than the
+    keys "band", _band_bwd_plan's counts: one call, `group` query heads a
+    K/V head) and engine, "pallas" or "xla": the one place that says
+    which."""
+    if window is not None:
+        return _band_bwd_plan(sq, sk, head_dim, dtype, block_q, v_dim,
+                              window, group)[0]
+    engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh)[1]
     trips = _bwd_trips(sq, sk, head_dim, dtype, causal, block_q, block_k,
-                       v_dim, window, chunk, bh)
-    steps = above = older = 0
+                       v_dim, chunk, bh)
+    steps = above = 0
     for q0, q1, k0, k1, bq, bk in trips:
         nqb, nkb = -(-(q1 - q0) // bq), -(-(k1 - k0) // bk)
-        a, o = _skipped_steps(nqb, nkb, bq, bk, q0 + (sk - sq) - k0, causal,
-                              window)
-        steps, above, older = steps + nqb * nkb, above + a, older + o
+        steps += nqb * nkb
+        above += _skipped_steps(nqb, nkb, bq, bk, q0 + (sk - sq) - k0,
+                                causal)[0]
     rows_per_step = 1 if len(trips) > 1 else _bwd_rows_per_step(
-        bh, sq, sk, trips[0][4], trips[0][5], head_dim, dtype, v_dim, window)
+        bh, sq, sk, trips[0][4], trips[0][5], head_dim, dtype, v_dim)
     return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=trips[-1][4],
-                block_k=trips[-1][5], steps=steps,
-                steps_skipped=above + older, engine=engine,
-                window=int(window or 0), chunks=len(trips),
-                skipped_causal=above, skipped_window=older,
-                rows_per_step=rows_per_step, layout="bhsd")
+                block_k=trips[-1][5], steps=steps, steps_skipped=above,
+                engine=engine, window=0, chunks=len(trips),
+                skipped_causal=above, skipped_window=0,
+                rows_per_step=rows_per_step, layout="bhsd", form="blocks")
+
+
+# ---------------------------------------------------------------------------
+# The band: a site whose `window` is shorter than its keys (PR 59)
+# ---------------------------------------------------------------------------
+
+class _Band(NamedTuple):
+    """The strip of K/V blocks a q-block reads under a window, both cut into
+    blocks of `block` rows: q-block i reads the `n` sub-blocks i + lo .. i
+    + lo + n - 1 (its rows' oldest key lies in the first, its last row's
+    diagonal in the last), and which of them an edge can cut, a flag a
+    sub-block: `window_cut` (a row's oldest key is past the sub-block's
+    first) and `causal_cut` (a row's diagonal is before its last).  The
+    sub-blocks between the two take no mask."""
+    block: int
+    lo: int
+    n: int
+    window_cut: tuple
+    causal_cut: tuple
+
+    @property
+    def hi(self) -> int:
+        return self.lo + self.n - 1
+
+
+@functools.lru_cache(maxsize=256)
+def _band(block, nqb, nkb, causal_offset, window) -> _Band:
+    """The _Band of `nqb` q-blocks over `nkb` K/V blocks (row t sees the
+    `window` keys that end at t + causal_offset, which is not negative).
+    Everything is static: sub-block s of q-block i is K/V block i + lo + s
+    whatever i, so its keys lie at one offset from its queries and an edge
+    is a compare against a constant.  Sub-blocks that no q-block has a K/V
+    block for (a strip longer than the keys) are left out."""
+    b = block
+    oldest = causal_offset - window + 1     # of row 0, among the keys
+    lo = oldest // b
+    m = oldest - lo * b
+    n = (m + b + window - 2) // b + 1
+    first = max(0, -lo - (nqb - 1))
+    last = min(n - 1, nkb - 1 - lo)
+    assert 0 <= first <= last, (block, nqb, nkb, causal_offset, window)
+    return _Band(b, lo + first, last - first + 1,
+                 tuple(m + b - 1 > s * b for s in range(first, last + 1)),
+                 tuple(m + window - 1 < (s + 1) * b - 1
+                       for s in range(first, last + 1)))
+
+
+def _band_mask(shape, transposed, newest=None, oldest=None, first=None,
+               end=None):
+    """Which scores of one sub-block stay, or None where nothing is
+    compared.  A key's offset from a query inside the sub-block (iota -
+    iota) is at most `newest` (the diagonal) and at least `oldest` (the
+    window's far edge), and the key itself lies in [first, end) (the keys
+    the batch row has): each a Python int, a traced scalar, or None for no
+    compare.  `transposed`: [keys, queries]."""
+    if newest is None and oldest is None and end is None:
+        return None
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    key = jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
+    rel = key - jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    stay = [] if newest is None else [rel <= newest]
+    stay += [] if oldest is None else [rel >= oldest]
+    stay += [] if end is None else [key >= first, key < end]
+    return functools.reduce(jnp.logical_and, stay)
+
+
+def _band_kernel(klen_ref, q_ref, *refs, band, scale, seq_k, causal_offset,
+                 window, group, emit_lse):
+    """Grid (batch * K/V heads, q-blocks, the group's query heads), the
+    heads innermost so that a strip of K/V is fetched once for the heads
+    that read it.  refs: the strip's `n` K blocks, its `n` V blocks (the
+    same two arrays, a sub-block an operand: _band_fwd_call), out and the
+    group's packed logsumexp plane.
+
+    A step computes its q-block whole: the strip's scores, the plain
+    softmax over them (the mathematics of _head_forward: no m / l / acc
+    state to initialise, rescale and flush), one P V product a sub-block.
+    A sub-block takes only the compares its place in the strip can need
+    (_Band: constants), and the keys' ends (klen[b], the padded end, a
+    strip that starts before the first key) only in the steps whose strip
+    they cut, a scalar test."""
+    import jax.experimental.pallas as pl
+
+    n, b = band.n, band.block
+    k_refs, v_refs, o_ref = refs[:n], refs[n:2 * n], refs[2 * n]
+    lse_ref = refs[2 * n + 1] if emit_lse else None
+    qi, h = pl.program_id(1), pl.program_id(2)
+    first = qi + band.lo        # the strip's first K/V block, unclamped
+    k_end = jnp.minimum(
+        seq_k, klen_ref[pl.program_id(0) * group + h].astype(jnp.int32))
+
+    def strip(bounded):
+        q = q_ref[0]
+        scores = []
+        for s in range(n):
+            x = jax.lax.dot_general(
+                q, k_refs[s][0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            newest = causal_offset - (band.lo + s) * b
+            mask = _band_mask(
+                x.shape, False, newest if band.causal_cut[s] else None,
+                newest - window + 1 if band.window_cut[s] else None,
+                *((-(first + s) * b, k_end - (first + s) * b) if bounded
+                  else ()))
+            scores.append(x if mask is None else jnp.where(mask, x, NEG_INF))
+        # the floor keeps a fully-masked row at p = 0, l = 0 (_flash_kernel)
+        m = functools.reduce(jnp.maximum, [
+            jnp.max(x, axis=-1, keepdims=True) for x in scores])
+        m = jnp.maximum(m, NEG_INF / 2)
+        l_fin = acc = None
+        for s, x in enumerate(scores):
+            p = jnp.exp(x - m)
+            v = v_refs[s][0]
+            l_s = jnp.sum(p, axis=-1, keepdims=True)
+            acc_s = jnp.dot(p.astype(v.dtype), v,
+                            preferred_element_type=jnp.float32)
+            l_fin = l_s if l_fin is None else l_fin + l_s
+            acc = acc_s if acc is None else acc + acc_s
+        o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse = jnp.where(
+                l_fin > 0.0, m + jnp.log(jnp.maximum(l_fin, 1e-30)), -NEG_INF)
+            # the packed plane, a lane-dense row a (head, q-block)
+            lse_ref[h, qi, :] = jnp.transpose(lse, (1, 0))[0]
+
+    bounded = jnp.logical_or(first < 0, (first + n) * b > k_end)
+    pl.when(bounded)(lambda: strip(True))
+    pl.when(jnp.logical_not(bounded))(lambda: strip(False))
+
+
+def _band_bwd_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                     dq_ref, dk_ref, dv_ref, ring, dk_scr, dv_scr, *, band,
+                     scale, seq_k, causal_offset, window, group, nqb):
+    """dQ, dK and dV of a windowed site in ONE call.  Grid (rows of
+    `group` query heads that read one K/V head, k-blocks j, the row's heads
+    h, the band's `n` q-blocks that see k-block j): step t takes q-block i =
+    j - hi + t, whose strip holds block j as sub-block n - 1 - t.  The five
+    matmuls and the one exp a score block are _flash_bwd_kernel's, the
+    scores transposed as there.
+
+    dK and dV of k-block j accumulate in VMEM over the row's heads and the
+    q-blocks and leave ONCE, summed over them (a row is a K/V head's whole
+    group wherever the ring fits, _band_bwd_heads).  dQ lives in a ring: a
+    q-block takes its first contribution at t = n - 1 (k-block i + lo, the
+    slot is assigned) and its last at t = 0 (k-block i + hi: it leaves, the
+    ring's sum and the step's), so n - 1 blocks a head are live between two
+    k-blocks and the block that leaves frees the slot of the one that
+    enters.  The q-blocks whose strip starts before the first key enter at
+    j = 0 by addition, into slots zeroed there.  A dQ block before the
+    first q-block (keys the queries' first rows never see, Sk > Sq) is
+    written to block 0 and written over when block 0 leaves; a k-block past
+    the last one (the padded queries' diagonal) is masked whole."""
+    import jax.experimental.pallas as pl
+
+    n, b = band.n, band.block
+    g, j, h, t = (pl.program_id(a) for a in range(4))
+    qi = j - band.hi + t
+    slots = max(n - 1, 1)
+    slot = h * slots + jax.lax.rem(jnp.maximum(qi, 0), slots)
+    k_end = jnp.minimum(seq_k, klen_ref[g * group + h].astype(jnp.int32))
+
+    @pl.when(jnp.logical_and(h == 0, t == 0))
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    if n > 1:
+        @pl.when(jnp.logical_and(j == 0, t == 0))
+        def _init_ring():
+            for r in range(slots):
+                ring[h * slots + r] = jnp.zeros(ring.shape[1:], jnp.float32)
+
+    def _update(sub):
+        """`sub`: the sub-block this step's k-block is in its q-block's
+        strip where its masks are the constants', None where the keys' ends
+        cut it and every compare is made (scalars)."""
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        nt = (((1,), (1,)), ((), ()))  # a @ b.T on the contracting dims
+        st = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0, qi, :].reshape(1, -1))
+        if sub is None:
+            newest = causal_offset - (band.lo + n - 1 - t) * b
+            mask = _band_mask(st.shape, True, newest, newest - window + 1,
+                              -j * b, k_end - j * b)
+        else:
+            newest = causal_offset - (band.lo + sub) * b
+            mask = _band_mask(
+                st.shape, True, newest if band.causal_cut[sub] else None,
+                newest - window + 1 if band.window_cut[sub] else None)
+        if mask is not None:
+            pt = jnp.where(mask, pt, 0.0)
+        dpt = jax.lax.dot_general(v_ref[0], do, nt,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - dvec_ref[0, qi, :].reshape(1, -1))).astype(q.dtype)
+        dv_scr[:] = dv_scr[:] + jnp.dot(
+            pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dk_scr[:] = dk_scr[:] + jnp.dot(
+            dst, q, preferred_element_type=jnp.float32)
+        dq = jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if n == 1:
+            dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+            return
+
+        @pl.when(t == 0)
+        def _leaves():
+            dq_ref[0] = ((ring[slot] + dq) * scale).astype(dq_ref.dtype)
+
+        @pl.when(t == n - 1)
+        def _enters():
+            ring[slot] = dq
+
+        if n > 2:
+            @pl.when(jnp.logical_and(t > 0, t < n - 1))
+            def _adds():
+                ring[slot] = ring[slot] + dq
+
+    runs = jnp.logical_and(qi >= 0, qi < nqb)
+    bounded = (j + 1) * b > k_end
+    pl.when(jnp.logical_and(runs, bounded))(lambda: _update(None))
+    free = jnp.logical_and(runs, jnp.logical_not(bounded))
+    plain = [s for s in range(n)
+             if not (band.window_cut[s] or band.causal_cut[s])]
+    for s in sorted(set(range(n)) - set(plain)):
+        pl.when(jnp.logical_and(free, t == n - 1 - s))(
+            functools.partial(_update, s))
+    if plain:       # consecutive: the cuts are at the strip's two ends
+        pl.when(jnp.logical_and(free, jnp.logical_and(
+            t >= n - 1 - plain[-1], t <= n - 1 - plain[0])))(
+                functools.partial(_update, plain[0]))
+
+    @pl.when(jnp.logical_and(h == group - 1, t == n - 1))
+    def _finalize():
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def band_fwd_vmem_bytes(block, strip, head_dim, num_q_blocks=1,
+                        dtype="float32", emit_lse=True, v_dim=None,
+                        group=1) -> int:
+    """fwd_vmem_bytes of the band's forward call (_band_fwd_call): the
+    double-buffered q and o blocks, `strip` K and `strip` V blocks and the
+    group's packed logsumexp plane; it declares no scratch."""
+    v_dim = head_dim if v_dim is None else v_dim
+    blocks = ([((1, block, head_dim), dtype), ((1, block, v_dim), dtype)]
+              + strip * [((1, block, head_dim), dtype),
+                         ((1, block, v_dim), dtype)])
+    if emit_lse:
+        blocks.append(((group, num_q_blocks, block), "float32"))
+    return 2 * sum(tile_padded_bytes(s, d) for s, d in blocks)
+
+
+def band_fwd_working_set_bytes(block, strip, head_dim, num_q_blocks=1,
+                               dtype="float32", emit_lse=True, v_dim=None,
+                               group=1) -> int:
+    """band_fwd_vmem_bytes plus the two fp32 planes of the strip a step
+    holds between its matmuls, scores and probabilities [block, strip *
+    block]: what _plan_band holds under the budget."""
+    return (band_fwd_vmem_bytes(block, strip, head_dim, num_q_blocks, dtype,
+                                emit_lse, v_dim, group)
+            + 2 * strip * tile_padded_bytes((block, block), "float32"))
+
+
+def band_bwd_vmem_bytes(block, strip, head_dim, num_q_blocks=1,
+                        dtype="float32", v_dim=None, group=1) -> int:
+    """What the band's backward call declares (_band_bwd_call): the
+    double-buffered q, dO, k, v, dQ, dK, dV blocks and a head's packed lse
+    and D planes, the fp32 accumulators of dK and dV and the ring of dQ,
+    `strip` - 1 blocks a head of the group."""
+    def tile(shape, dt=dtype):
+        return tile_padded_bytes(shape, dt)
+
+    v_dim = head_dim if v_dim is None else v_dim
+    blocks = (3 * tile((1, block, head_dim))            # q, k, dK
+              + 3 * tile((1, block, v_dim))             # dO, v, dV
+              + tile((1, block, head_dim))              # dQ
+              + 2 * tile((1, num_q_blocks, block), "float32"))
+    scratch = (tile((group * max(strip - 1, 1), block, head_dim), "float32")
+               + tile((block, head_dim), "float32")
+               + tile((block, v_dim), "float32"))
+    return 2 * blocks + scratch
+
+
+def band_bwd_working_set_bytes(block, strip, head_dim, num_q_blocks=1,
+                               dtype="float32", v_dim=None, group=1) -> int:
+    """band_bwd_vmem_bytes plus the four fp32 [block, block] planes a step
+    holds between its matmuls (bwd_working_set_bytes)."""
+    return (band_bwd_vmem_bytes(block, strip, head_dim, num_q_blocks, dtype,
+                                v_dim, group)
+            + 4 * tile_padded_bytes((block, block), "float32"))
+
+
+def _plan_band(sq, sk, window, working_set, a_strip_a_step):
+    """The block length of a windowed site's band, from the shape: of the
+    lengths both sequences are cut into (_block_lengths) whose
+    `working_set(block, strip)` fits the plan's budget, the one whose grid
+    computes the least, a step counted as the scores it computes beside
+    _STEP_COST_SCORES; the longer where two tie.  `a_strip_a_step`: a step
+    computes a q-block's whole strip (the forward), else one score block
+    (the backward, whose steps before the first key do not run)."""
+    def band(b):
+        return _band(b, -(-sq // b), -(-sk // b), sk - sq, window)
+
+    def cost(b):
+        nqb, nkb = -(-sq // b), -(-sk // b)
+        if a_strip_a_step:
+            return nqb * (band(b).n * b * b + _STEP_COST_SCORES), -b
+        run = nqb * nkb - sum(_skipped_steps(nqb, nkb, b, b, sk - sq, True,
+                                             window))
+        return run * (b * b + _STEP_COST_SCORES), -b
+
+    lens = [b for b in _block_lengths(sq) if b in _block_lengths(sk)] or [128]
+    fits = [b for b in lens if working_set(b, band(b).n) <= PLAN_VMEM_BUDGET]
+    return min(fits or lens[:1], key=cost)
+
+
+@functools.lru_cache(maxsize=128)
+def _band_fwd_call(bgs, group, sqp, skp, d, dv, band, scale, seq_k,
+                   causal_offset, window, dtype, interpret, emit_lse):
+    """Memoized pallas_call of _band_kernel (see _fwd_call).  q and out
+    are [bgs * group, sqp, .], a block a (head, q-block); K and V [bgs, skp,
+    .] are handed in once a sub-block of the strip, each with the index map
+    of its place (block i + lo + s of q-block i, held inside the keys: a
+    sub-block outside them is masked whole, by its unclamped place); the
+    logsumexp leaves as the group's packed plane [group, nqb, block],
+    written a row a step and flushed when the K/V head advances."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n = band.block, band.n
+    nqb, nkb = sqp // b, skp // b
+
+    def head_block(g, i, h):
+        return (g * group + h, i, 0)
+
+    def strip_block(s):
+        return lambda g, i, h: (g, jnp.clip(i + band.lo + s, 0, nkb - 1), 0)
+
+    out_specs = [pl.BlockSpec((1, b, dv), head_block)]
+    out_shape = [jax.ShapeDtypeStruct((bgs * group, sqp, dv),
+                                      jnp.dtype(dtype))]
+    if emit_lse:
+        out_specs.append(
+            pl.BlockSpec((group, nqb, b), lambda g, i, h: (g, 0, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((bgs * group, nqb, b), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(
+            _band_kernel, band=band, scale=scale, seq_k=seq_k,
+            causal_offset=causal_offset, window=window, group=group,
+            emit_lse=emit_lse),
+        grid=(bgs, nqb, group),
+        in_specs=[pl.BlockSpec((bgs * group,), lambda g, i, h: (0,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, b, d), head_block)]
+        + [pl.BlockSpec((1, b, d), strip_block(s)) for s in range(n)]
+        + [pl.BlockSpec((1, b, dv), strip_block(s)) for s in range(n)],
+        out_specs=out_specs, out_shape=out_shape,
+        compiler_params=compiler_params(
+            3 * ("arbitrary",), band_fwd_working_set_bytes(
+                b, n, d, nqb, dtype, emit_lse, dv, group)),
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=128)
+def _band_bwd_call(rows, group, shares, sqp, skp, steps, d, dv, band, scale,
+                   seq_k, causal_offset, window, q_dtype, k_dtype, v_dtype,
+                   interpret):
+    """Memoized pallas_call of _band_bwd_kernel (see _bwd_call): `rows`
+    rows of `group` query heads each, `shares` consecutive rows reading one
+    K/V head (1 where a row is the K/V head's whole group).  The k-axis has
+    `steps` blocks: the keys' and, where the padded queries' diagonal runs
+    past them, as many more as let the last q-block leave; dK and dV are
+    [rows, steps * block, .], summed over a row's heads, and the caller
+    adds the shares up and cuts them to the keys."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n = band.block, band.n
+    nqb, nkb = sqp // b, skp // b
+
+    def q_block(g, j, h, t):
+        return (g * group + h, jnp.clip(j - band.hi + t, 0, nqb - 1), 0)
+
+    def k_in(g, j, h, t):
+        return (g // shares, jnp.minimum(j, nkb - 1), 0)
+
+    packed = pl.BlockSpec((1, nqb, b), lambda g, j, h, t: (g * group + h, 0,
+                                                           0))
+    return pl.pallas_call(
+        functools.partial(
+            _band_bwd_kernel, band=band, scale=scale, seq_k=seq_k,
+            causal_offset=causal_offset, window=window, group=group,
+            nqb=nqb),
+        grid=(rows, steps, group, n),
+        in_specs=[pl.BlockSpec((rows * group,), lambda g, j, h, t: (0,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, b, d), q_block),
+                  pl.BlockSpec((1, b, d), k_in),
+                  pl.BlockSpec((1, b, dv), k_in),
+                  pl.BlockSpec((1, b, dv), q_block),
+                  packed, packed],
+        out_specs=[
+            # the q-block that leaves at k-block j
+            pl.BlockSpec((1, b, d), lambda g, j, h, t: (
+                g * group + h, jnp.clip(j - band.hi, 0, nqb - 1), 0)),
+            pl.BlockSpec((1, b, d), lambda g, j, h, t: (g, j, 0)),
+            pl.BlockSpec((1, b, dv), lambda g, j, h, t: (g, j, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct((rows * group, sqp, d), jnp.dtype(q_dtype)),
+            jax.ShapeDtypeStruct((rows, steps * b, d), jnp.dtype(k_dtype)),
+            jax.ShapeDtypeStruct((rows, steps * b, dv), jnp.dtype(v_dtype))],
+        scratch_shapes=[
+            pltpu.VMEM((group * max(n - 1, 1), b, d), jnp.float32),
+            pltpu.VMEM((b, d), jnp.float32),
+            pltpu.VMEM((b, dv), jnp.float32)],
+        compiler_params=compiler_params(
+            4 * ("arbitrary",), band_bwd_working_set_bytes(
+                b, n, d, nqb, q_dtype, dv, group)),
+        interpret=interpret)
+
+
+def _band_counts(sq, sk, block, window):
+    """(blocks of the square, above the diagonal, older than the window):
+    what a band of `block` x `block` score blocks leaves out of the square
+    the spans count in, over one (batch, head)."""
+    nqb, nkb = -(-sq // block), -(-sk // block)
+    return (nqb * nkb, *_skipped_steps(nqb, nkb, block, block, sk - sq, True,
+                                       window))
+
+
+def _pallas_band(q, k, v, klen, scale, window, block=None, interpret=False,
+                 need_lse=True):
+    """_pallas_flash of a causal call under a `window` shorter than its
+    keys: (out, the packed logsumexp [B*H, num_q_blocks, block] or None) by
+    _band_kernel at _plan_band's block (`block` pins it for a test or the
+    probe).  Queries before the first key (Sq > Sk) see none: they are cut
+    off and come back as zeros."""
+    B, H, Sq, D = q.shape
+    G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    if Sq > Sk:
+        out, lse = _pallas_band(q[:, :, Sq - Sk:], k, v, klen, scale, window,
+                                block, interpret, need_lse)
+        out = jnp.pad(out, ((0, 0), (0, 0), (Sq - Sk, 0), (0, 0)))
+        if lse is not None:
+            lse = jnp.pad(lse.reshape(B * H, -1)[:, :Sk],
+                          ((0, 0), (Sq - Sk, 0)),
+                          constant_values=-NEG_INF)[:, None]
+        return out, lse
+    group = H // G
+    b = block or _plan_band(
+        Sq, Sk, window, lambda b, n: band_fwd_working_set_bytes(
+            b, n, D, -(-Sq // b), q.dtype, need_lse, Dv, group), True)
+    q, k, v = _pad_seq(q, b), _pad_seq(k, b), _pad_seq(v, b)
+    sqp, skp = q.shape[2], k.shape[2]
+    band = _band(b, sqp // b, skp // b, Sk - Sq, window)
+    blocks, above, older = _band_counts(Sq, Sk, b, window)
+    # counted in score blocks of the square, as the block kernels' plans
+    with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=b, block_k=b,
+              k_steps=blocks, k_steps_skipped=above + older, causal=1,
+              window=window, kv_heads=G, chunks=1, skipped_causal=above,
+              skipped_window=older, rows_per_step=1, layout="bhsd",
+              form="band"):
+        call = _band_fwd_call(B * G, group, sqp, skp, D, Dv, band, scale, Sk,
+                              Sk - Sq, window, str(q.dtype), interpret,
+                              need_lse)
+        kf, vf = k.reshape(B * G, skp, D), v.reshape(B * G, skp, Dv)
+        res = call(jnp.repeat(klen, H), q.reshape(B * H, sqp, D),
+                   *(band.n * [kf]), *(band.n * [vf]))
+    out = res[0].reshape(B, H, sqp, Dv)[:, :, :Sq]
+    return out, (res[1] if need_lse else None)
+
+
+def _band_bwd_heads(block, strip, head_dim, num_q_blocks, dtype, v_dim,
+                    group):
+    """The query heads of a K/V head's `group` that ONE row of the band's
+    backward grid takes (their dK, dV summed in VMEM, a ring of dQ each):
+    the most, a divisor of the group, whose working set fits the plan's
+    budget; None where not even one head's does.  The whole group at the
+    cell's shape (8 heads of 128 in bf16 under window 1024, blocks of 512:
+    10.5 of 12 MiB); half of it where the operands are fp32 (12.25)."""
+    return next((n for n in range(group, 0, -1) if group % n == 0
+                 and band_bwd_working_set_bytes(
+                     block, strip, head_dim, num_q_blocks, dtype, v_dim, n)
+                 <= PLAN_VMEM_BUDGET), None)
+
+
+def _band_bwd_plan(sq, sk, head_dim, dtype, block=None, v_dim=None,
+                   window=None, group=1):
+    """(_bwd_plan's counts for a windowed site, the heads a row of its grid
+    takes): one call (`chunks` 1) of _band_bwd_kernel at _plan_band's block
+    (or `block`, pinned) over rows of _band_bwd_heads' heads, the score
+    blocks counted in the square as the forward's; the engine is Pallas
+    where a block has _BWD_PALLAS_MIN_BLOCK_SCORES scores, XLA's recompute
+    elsewhere."""
+    rows = min(sq, sk)      # queries before the first key are cut off
+    # a block length fits where one head's ring does
+    b = block or _plan_band(
+        rows, sk, window, lambda b, n: band_bwd_working_set_bytes(
+            b, n, head_dim, -(-rows // b), dtype, v_dim, 1), False)
+    blocks, above, older = _band_counts(rows, sk, b, window)
+    heads = _band_bwd_heads(
+        b, _band(b, -(-rows // b), -(-sk // b), sk - rows, window).n,
+        head_dim, -(-rows // b), dtype, v_dim, group)
+    return dict(sq=sq, sk=sk, head_dim=head_dim, block_q=b, block_k=b,
+                steps=blocks, steps_skipped=above + older,
+                engine=("pallas" if b * b >= _BWD_PALLAS_MIN_BLOCK_SCORES
+                        else "xla"),
+                window=window, chunks=1, skipped_causal=above,
+                skipped_window=older, rows_per_step=1, layout="bhsd",
+                form="band"), heads or 1
+
+
+def _pallas_band_bwd(q, k, v, klen, out, lse, g, scale, window, block=None,
+                     interpret=False, dlse=None):
+    """(dq, dk, dv) of _pallas_band's call by ONE call of _band_bwd_kernel:
+    D = rowsum(dO * O) and the lse's re-cut are made here as _bwd_rows makes
+    them; dK and dV come back a K/V head's, summed over its group in the
+    kernel (where the group's rings do not fit VMEM together, over shares
+    of it, added up here in fp32: _band_bwd_heads)."""
+    B, H, Sq, D = q.shape
+    G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    if Sq > Sk:         # as _pallas_band: the first rows see no key
+        cut = Sq - Sk
+        dq, dk, dv = _pallas_band_bwd(
+            q[:, :, cut:], k, v, klen, out[:, :, cut:],
+            lse.reshape(B * H, -1)[:, None, cut:Sq], g[:, :, cut:], scale,
+            window, block, interpret,
+            None if dlse is None else dlse[:, :, cut:])
+        return jnp.pad(dq, ((0, 0), (0, 0), (cut, 0), (0, 0))), dk, dv
+    plan, heads = _band_bwd_plan(Sq, Sk, D, q.dtype, block, Dv, window,
+                                 H // G)
+    b, shares = plan["block_q"], H // G // heads
+    with span("flash.bwd_plan", **dict(plan, engine="pallas", kv_heads=G)):
+        # at lowering, as flash.plan
+        qp, op, gp = _pad_seq(q, b), _pad_seq(out, b), _pad_seq(g, b)
+        kp, vp = _pad_seq(k, b), _pad_seq(v, b)
+        sqp, skp = qp.shape[2], kp.shape[2]
+        band = _band(b, sqp // b, skp // b, Sk - Sq, window)
+        steps = max(skp // b, sqp // b + band.hi)
+        qf = qp.reshape(B * H, sqp, D)
+        gf = gp.reshape(B * H, sqp, Dv).astype(qf.dtype)
+        of = op.reshape(B * H, sqp, Dv)
+        # a padded row's lse is the fully-masked row's: exp(s - lse) is 0
+        lse = _repack(lse, Sq, b, -NEG_INF)
+        call = _band_bwd_call(B * G * shares, heads, shares, sqp, skp, steps,
+                              D, Dv, band, scale, Sk, Sk - Sq, window,
+                              str(q.dtype), str(k.dtype), str(v.dtype),
+                              interpret)
+        dq, dk, dv = call(
+            jnp.repeat(klen, H), qf, kp.reshape(B * G, skp, D),
+            vp.reshape(B * G, skp, Dv), gf, lse,
+            _packed_d(gf, of, dlse, Sq, b))
+    if shares > 1:
+        dk, dv = (x.reshape(B * G, shares, *x.shape[1:]).astype(
+            jnp.float32).sum(1).astype(x.dtype) for x in (dk, dv))
+    return (dq.reshape(B, H, sqp, D)[:, :, :Sq],
+            dk.reshape(B, G, steps * b, D)[:, :, :Sk],
+            dv.reshape(B, G, steps * b, Dv)[:, :, :Sk])
 
 
 def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
@@ -1378,8 +1894,8 @@ def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
         return True
     return use_pallas(force) and _bwd_plan(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
-        v_dim=v.shape[3], window=window,
-        bh=_packable_rows(q, k))["engine"] == "pallas"
+        v_dim=v.shape[3], window=window, bh=_packable_rows(q, k),
+        group=q.shape[1] // k.shape[1])["engine"] == "pallas"
 
 
 def _forward(q, k, v, klen, causal, scale, force, need_lse, window=None):
@@ -1481,7 +1997,8 @@ def _flash_bwd(causal, scale, force, window, res, g):
             with span("flash.bwd_plan", **_bwd_plan(
                     q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
                     v_dim=v.shape[3], window=window,
-                    bh=_packable_rows(q, k)), kv_heads=k.shape[1]):
+                    bh=_packable_rows(q, k), group=q.shape[1] // k.shape[1]),
+                    kv_heads=k.shape[1]):
                 pass
         # recompute-backward: differentiate the reference formulation
         _, vjp = jax.vjp(
@@ -1695,7 +2212,7 @@ def _pallas_flash_bshd(q, k, v, klen, heads, causal, scale, interpret=False,
     with span("flash.plan", sq=Sq, sk=Sk, head_dim=D, block_q=Sq, block_k=Sk,
               k_steps=1, k_steps_skipped=0, causal=int(causal), window=0,
               kv_heads=heads, chunks=1, skipped_causal=0, skipped_window=0,
-              rows_per_step=rows_per_step, layout="bshd"):
+              rows_per_step=rows_per_step, layout="bshd", form="blocks"):
         res = _fwd_bshd_call(B, Sq, Sk, heads, D, causal, scale, str(q.dtype),
                              interpret, need_lse, rows_per_step)(klen, q, k, v)
     return res[0], (res[1] if need_lse else None)
@@ -1715,7 +2232,8 @@ def _pallas_flash_bwd_bshd(q, k, v, klen, out, lse, g, heads, causal, scale,
     with span("flash.bwd_plan", sq=Sq, sk=Sk, head_dim=D, block_q=Sq,
               block_k=Sk, steps=1, steps_skipped=0, engine="pallas", window=0,
               chunks=1, skipped_causal=0, skipped_window=0,
-              rows_per_step=rows_per_step, kv_heads=heads, layout="bshd"):
+              rows_per_step=rows_per_step, kv_heads=heads, layout="bshd",
+              form="blocks"):
         call = _bwd_bshd_call(B, Sq, Sk, heads, D, causal, scale,
                               str(q.dtype), str(k.dtype), str(v.dtype),
                               interpret, dlse is not None, rows_per_step)

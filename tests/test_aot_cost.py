@@ -203,6 +203,21 @@ def _flash_mla():
         argnums=(0, 1, 2)), (q, q, v)
 
 
+def _flash_band():
+    """mellum2-12b-a2.5b's sliding site at the cell's size (PR 59): 32
+    query heads on 4 K/V heads of 128 over 16384 rows, window 1024, the
+    band's forward and its ONE backward call."""
+    from paddle_tpu.kernels import flash_attention
+
+    q = _sds((1, 32, 16384, 128), jnp.bfloat16)
+    kv = _sds((1, 4, 16384, 128), jnp.bfloat16)
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=1024,
+            force="pallas").astype(jnp.float32)),
+        argnums=(0, 1, 2)), (q, kv, kv)
+
+
 def _held_experts():
     """moonlight-16b-a3b's expert layer at the cell's size: 8192 tokens, 8
     held of 64, top 6, forward and backward, the three row buffers under
@@ -371,6 +386,7 @@ _MAIN_PATH_KERNELS = {
     "cca_mix_bwd_pallas_zaya": lambda: _cca_mix(True),
     "sparse_attention_bwd_pallas_keye": _sparse_attention,
     "flash_bwd_pallas_moonlight_192_128": _flash_mla,
+    "flash_band_bwd_pallas_mellum_window1024": _flash_band,
     "held_experts_moonlight": _held_experts,
     "flash_fwd_transformer_base": lambda: _flash_fwd((96, 8, 256, 64)),
     "flash_fwd_long_context": lambda: _flash_fwd((2, 8, 2048, 64)),
@@ -404,6 +420,8 @@ def test_main_path_kernel_compiles_for_v5e(v5e, case):
     assert n >= (2 if "bwd_pallas" in case else 1), (case, n)
     if case.startswith("mhc"):   # three forwards; + the backwards' four
         assert n == (7 if "bwd" in case else 3), (case, n)
+    if "flash_band" in case:    # ONE backward call, no chunks
+        assert n == 2, (case, n)
     if "bwd_xla" in case:
         assert n == 1, (case, n)
     if "heads_last" in case:    # taken as given: no copy, no transposition

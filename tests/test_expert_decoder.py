@@ -373,7 +373,7 @@ def test_flash_plans_at_the_cells_attention_shape():
                         block_k=512, steps=16, steps_skipped=6,
                         engine="pallas", window=0, chunks=1,
                         skipped_causal=6, skipped_window=0, rows_per_step=1,
-                        layout="bhsd")
+                        layout="bhsd", form="blocks")
     # v padded to 192 would plan 1024 x 256, as ISSUE 31 read chip-less
     padded = fa._bwd_plan(*args)
     assert (padded["block_q"], padded["block_k"], padded["engine"]) == \

@@ -203,7 +203,7 @@ def test_skipped_count_in_the_span_equals_the_dense_count():
                               causal=1, window=0, kv_heads=1, chunks=1,
                               skipped_causal=int((~visible).sum()),
                               skipped_window=0, rows_per_step=1,
-                                  layout="bhsd")]
+                              layout="bhsd", form="blocks")]
 
 
 @pytest.mark.parametrize("causal,pin,k_steps,skipped", [
@@ -443,7 +443,7 @@ def test_bwd_plan_span_counts_equal_the_dense_count(sq, sk, bq, bk):
         steps=visible.size, steps_skipped=int((~visible).sum()),
         engine="pallas", window=0, chunks=1, kv_heads=1,
         skipped_causal=int((~visible).sum()), skipped_window=0,
-        rows_per_step=1, layout="bhsd")]
+        rows_per_step=1, layout="bhsd", form="blocks")]
 
 
 def test_repack_is_a_view_where_the_padded_lengths_agree():
@@ -690,6 +690,65 @@ def test_a_step_takes_one_row_where_a_head_is_not_one_plain_block(what):
         lambda *a: both(*a), q, kv, kv, klen)] == [1]
 
 
+@pytest.mark.parametrize("window,form", [(100, "band"), (255, "band"),
+                                         (256, "blocks"), (1000, "blocks")])
+def test_a_window_shorter_than_the_keys_is_the_bands(window, form):
+    """flash_attention reads the band off the call (PR 59): a window
+    shorter than the keys lowers the band's pair, forward and backward (one
+    call, no chunks); a window that holds every key is no window and takes
+    the block kernels."""
+    q = jax.ShapeDtypeStruct((2, 4, 256, 64), jnp.float32)
+    kv = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.float32)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: fa.flash_attention(
+            *a, causal=True, window=window, force="interpret").sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    spans = _spans(("flash.plan", "flash.bwd_plan"), grads, q, kv, kv)
+    assert [p["form"] for p in spans["flash.plan"]] == [form]
+    assert [(p["form"], p["chunks"], p["engine"])
+            for p in spans["flash.bwd_plan"]] == [(form, 1, "pallas")]
+    assert {p["window"] for name in spans for p in spans[name]} == {
+        window if form == "band" else 0}
+
+
+def test_band_vmem_estimates_match_linter_price():
+    """A windowed site's two calls (kernels/flash_attention.py, PR 59): the
+    band's analytic counts (the strip's K and V blocks and the group's lse
+    plane forward; the ring of dQ and the accumulators backward) equal what
+    the linter prices off the traced calls, and what the plans hold under
+    their budget adds the score planes no declared buffer shows."""
+    from paddle_tpu.analysis import pallas as AP
+
+    B, H, G, S, D, W = 1, 8, 2, 4096, 128, 1024
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, G, S, D), jnp.bfloat16)
+    eqns = list(AP.iter_pallas_calls(jax.make_jaxpr(
+        jax.grad(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=W, force="interpret").sum().astype(
+                jnp.float32), argnums=(0, 1, 2)))(q, kv, kv)))
+    assert [AP.kernel_cost(e).name for e in eqns] == [
+        "_band_kernel", "_band_bwd_kernel"]
+    group = H // G
+    bwd = fa._bwd_plan(S, S, D, jnp.bfloat16, True, window=W, group=group)
+    for eqn, b, declared, planned, planes in (
+            (eqns[0], fa._plan_band(
+                S, S, W, lambda b, n: fa.band_fwd_working_set_bytes(
+                    b, n, D, S // b, "bfloat16", True, None, group), True),
+             fa.band_fwd_vmem_bytes, fa.band_fwd_working_set_bytes, 2),
+            (eqns[1], bwd["block_q"], fa.band_bwd_vmem_bytes,
+             fa.band_bwd_working_set_bytes, 4)):
+        n = fa._band(b, S // b, S // b, 0, W).n
+        assert n == W // b + 1
+        lse = (True,) if planes == 2 else ()
+        args = (b, n, D, S // b, "bfloat16", *lse, None, group)
+        assert AP.kernel_vmem_bytes(eqn) == declared(*args)
+        strip = n if planes == 2 else 1
+        assert planned(*args) == declared(*args) + planes * strip * b * b * 4
+        assert planned(*args) < AP.default_vmem_budget()
+
+
 # (e) heads-last: [B, S, H * D] operands as the projections write them
 # (PR 57) -------------------------------------------------------------------
 
@@ -873,7 +932,8 @@ def test_heads_last_site_of_another_shape_gives_the_heads_first_numbers(what):
 
 # name: (B, H, G, S, D, Dv, window) of a decoder cell's attention, then what
 # the parent commit (PR 52) planned there: the forward's blocks, the
-# backward's, its chunks and its engine
+# backward's, its chunks and its engine.  The windowed site's row is PR
+# 59's: the band (`form`), one block length a direction, ONE backward call.
 DECODER_CELL_PLANS = {
     "ouro_s2048_head128": (
         (2, 16, 16, 2048, 128, 128, None), (1024, 1024), (512, 512), 1),
@@ -882,7 +942,7 @@ DECODER_CELL_PLANS = {
     "keye_and_mellum_s16384_full_32_on_4": (
         (1, 32, 4, 16384, 128, 128, None), (1024, 1024), (512, 512), 4),
     "mellum_s16384_window1024_32_on_4": (
-        (1, 32, 4, 16384, 128, 128, 1024), (512, 512), (512, 512), 16),
+        (1, 32, 4, 16384, 128, 128, 1024), (512, 512), (512, 512), 1),
     "zaya_s16384_8_on_2": (
         (1, 8, 2, 16384, 128, 128, None), (1024, 1024), (512, 512), 4),
     "kimi_s4096_head192_128": (
@@ -906,12 +966,14 @@ def test_the_decoder_cells_plans_are_the_parents(case):
     spans = _plan_spans(lambda q, k, v, klen: fa._pallas_flash(
         q, k, v, klen, True, 0.088, interpret=True, window=window)[0],
         q, k, v, klen)
-    assert [(p["block_q"], p["block_k"], p["rows_per_step"], p["kv_heads"])
-            for p in spans] == [(*fwd_blocks, 1, G)]
+    form = "blocks" if window is None else "band"
+    assert [(p["block_q"], p["block_k"], p["rows_per_step"], p["kv_heads"],
+             p["form"]) for p in spans] == [(*fwd_blocks, 1, G, form)]
     plan = fa._bwd_plan(S, S, D, jnp.bfloat16, True, v_dim=Dv, window=window,
-                        bh=fa._packable_rows(q, k))
+                        bh=fa._packable_rows(q, k), group=H // G)
     assert (plan["block_q"], plan["block_k"], plan["chunks"], plan["engine"],
-            plan["rows_per_step"]) == (*bwd_blocks, chunks, "pallas", 1)
+            plan["rows_per_step"], plan["form"]) == (
+                *bwd_blocks, chunks, "pallas", 1, form)
     assert fa.kept(q, k, v, True, window, force="pallas") == fa.KEPT
 
 

@@ -453,9 +453,12 @@ def test_the_flash_forward_is_traced_once_under_a_rematerialised_layer(
     args = _flash_inputs()
     calls = {name: _pallas_calls(wrap(_flash_layer("interpret", window)),
                                  args) for name, wrap in WRAPS.items()}
-    once = {"_flash_kernel": 1, "_flash_bwd_kernel": 1}
+    # under a window the band's pair (PR 59), else the block kernels
+    fwd, bwd = (("_flash_kernel", "_flash_bwd_kernel") if window is None
+                else ("_band_kernel", "_band_bwd_kernel"))
+    once = {fwd: 1, bwd: 1}
     assert calls == {"none": once, "kept": once,
-                     "bare": {**once, "_flash_kernel": 2}}
+                     "bare": {**once, fwd: 2}}
     # the XLA recompute backward: no kernel, nothing to keep
     assert _pallas_calls(WRAPS["kept"](_flash_layer("jax", window)),
                          args) == {}

@@ -2,9 +2,9 @@
 and full grouped-query attention in a pattern, YaRN on the full layers, a
 softmax top-k expert block as a chip's share) against its plain reference,
 benchmark/configs/mellum2-12b-a2.5b.reference.py, at tiny sizes on the CPU;
-the flash kernels with `window` and grouped K/V through the Pallas
-interpreter, the long row's backward in chunks; the plans' static counts
-against brute force; the shares against the uncut layer; every mutant
+the flash kernels with `window` (the band, PR 59) and grouped K/V through
+the Pallas interpreter, the long row's backward in chunks; the plans' static
+counts against brute force; the shares against the uncut layer; every mutant
 tools/mellum_reference_probe.py holds the chip's first step to, refused."""
 
 import importlib
@@ -208,9 +208,10 @@ def test_the_mutants_are_issue_38s_and_an_unknown_one_is_an_error():
 # ---------------------------------------------------------------------------
 # the kernels in the interpreter
 # ---------------------------------------------------------------------------
-def _plain_attention(q, k, v, window):
+def _plain_attention(q, k, v, window, klen=None, with_lse=False):
     """K and V repeated, an explicit mask, a softmax: nothing of the
-    kernel's."""
+    kernel's.  `klen` [B]: the keys a batch row has; a row that sees none
+    gives zeros.  `with_lse`: (out, the rows' logsumexp)."""
     H, G, S, Sk = q.shape[1], k.shape[1], q.shape[2], k.shape[2]
     k, v = (jnp.repeat(x, H // G, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
@@ -219,38 +220,109 @@ def _plain_attention(q, k, v, window):
     mask = u <= t
     if window is not None:
         mask &= t - u < window
-    return jnp.einsum("bhqk,bhkd->bhqd",
-                      jax.nn.softmax(jnp.where(mask, s, -1e30), -1), v)
+    mask = mask[None, None]
+    if klen is not None:
+        mask = mask & (u[None, None] < klen[:, None, None, None])
+    s = jnp.where(mask, s, -1e30)
+    p = jnp.where(mask.any(-1, keepdims=True), jax.nn.softmax(s, -1), 0.0)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return (out, jax.nn.logsumexp(s, -1)) if with_lse else out
 
 
-def _qkvg(seed, B, H, G, S, D):
+def _qkvg(seed, B, H, G, S, D, Sk=None):
     rng = np.random.RandomState(seed)
-    return tuple(jnp.asarray(rng.randn(B, n, S, D), jnp.float32)
-                 for n in (H, G, G, H))
+    return tuple(jnp.asarray(rng.randn(B, n, length, D), jnp.float32)
+                 for n, length in ((H, S), (G, Sk or S), (G, Sk or S),
+                                   (H, S)))
 
 
-@pytest.mark.parametrize("window", [None, 200])
-def test_windowed_grouped_kernels_against_plain_jax_in_chunks(window):
-    """Forward, dQ, dK and dV of 4 query heads on 2 key/value heads over a
-    row of 640, the backward pinned to chunks of 256 queries (three trips,
-    the last ragged) and 128 x 128 blocks through the plan's test door."""
-    q, k, v, g = _qkvg(0, 1, 4, 2, 640, 16)
-    klen = jnp.full((1,), 640.0)
+# name: (H, G, Sq, Sk, window, block or None for the plan's, k_lengths or
+# None) of a windowed call the band takes (PR 59), beside the two cases the
+# test had: no window (the block kernels, in chunks) and 200 of 640
+BAND_CASES = {
+    "no_window_in_chunks": (4, 2, 640, 640, None, 128, None),
+    "200": (4, 2, 640, 640, 200, 128, None),
+    "100_is_less_than_a_block": (4, 2, 640, 640, 100, 128, None),
+    "one_block": (4, 4, 384, 384, 128, 128, None),
+    "8_on_2_klen_cuts_the_oldest_and_the_diagonal": (
+        8, 2, 512, 512, 200, 128, (300, 512)),
+    "8_on_8_a_row_with_no_key": (8, 8, 256, 256, 100, 128, (0, 129)),
+    "more_keys_than_queries": (2, 1, 256, 512, 100, 128, None),
+    "more_keys_no_multiple_of_the_block": (2, 1, 100, 256, 60, 128, (200,)),
+    "more_queries_than_keys": (2, 2, 384, 256, 100, 128, None),
+    "the_plans_own_block": (2, 1, 768, 768, 200, None, (700,)),
+    "a_window_of_one_key": (2, 2, 256, 256, 1, 128, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAND_CASES))
+def test_windowed_grouped_kernels_against_plain_jax_in_chunks(case):
+    """Forward, logsumexp, dQ, dK and dV through the plan's test door, the
+    blocks pinned to 128 x 128.  No window: 4 query heads on 2 key/value
+    heads over a row of 640, the backward pinned to chunks of 256 queries
+    (three trips, the last ragged).  A window: the band, ONE backward call
+    whatever the row (windows that are no multiple of the block, one of one
+    block, k_lengths that cut a strip, Sk != Sq, grouped and not; a row with
+    no key has the logsumexp -NEG_INF and no gradient)."""
+    H, G, Sq, Sk, window, block, lengths = BAND_CASES[case]
+    B = len(lengths or (0,))
+    q, k, v, g = _qkvg(0, B, H, G, Sq, 16, Sk)
+    klen = jnp.asarray(lengths or (Sk,), jnp.float32)
     scale = 16 ** -0.5
-    want, vjp = jax.vjp(lambda *x: _plain_attention(*x, window), q, k, v)
-    out, lse = fa._pallas_flash(q, k, v, klen, True, scale, block_q=128,
-                                block_k=128, interpret=True, window=window)
+    (want, want_lse), vjp = jax.vjp(
+        lambda *x: _plain_attention(*x, window, klen, True), q, k, v)
+    out, lse = fa._pallas_flash(q, k, v, klen, True, scale, block_q=block,
+                                block_k=block, interpret=True, window=window)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    rows = np.asarray(lse).reshape(B, H, -1)[:, :, :Sq]
+    empty = np.asarray(want_lse) < -1e29
+    np.testing.assert_array_equal(rows[empty], np.float32(-fa.NEG_INF))
+    np.testing.assert_allclose(rows[~empty], np.asarray(want_lse)[~empty],
+                               rtol=1e-5, atol=1e-5)
+    chunk = 256 if window is None else None
     got = fa._pallas_flash_bwd(q, k, v, klen, out, lse, g, True, scale,
-                               block_q=128, block_k=128, interpret=True,
-                               window=window, chunk=256)
+                               block_q=block, block_k=block, interpret=True,
+                               window=window, chunk=chunk)
+    for a, b, name in zip(got, vjp((g, jnp.zeros_like(want_lse))), "qkv"):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                   err_msg="d" + name)
+    if empty.any():
+        assert not np.asarray(got[0])[empty].any()
+    plan = fa._bwd_plan(Sq, Sk, 16, jnp.float32, True, block, block,
+                        window=window, chunk=chunk, group=H // G)
+    assert plan["window"] == (window or 0)
+    assert (plan["chunks"], plan["form"]) == (
+        (3, "blocks") if window is None else (1, "band"))
+    older = _brute(Sq, Sk, plan["block_q"], plan["block_k"], window)[2]
+    assert plan["skipped_window"] == older and (window or not older)
+
+
+def test_the_bands_backward_takes_a_share_of_a_group_where_it_does_not_fit(
+        monkeypatch):
+    """The band's backward keeps a ring of dQ a head: where a K/V head's
+    whole group does not fit the plan's budget a grid row takes the most
+    heads that do (fp32 operands at the cell's shape: 4 of 8) and the
+    shares' dK, dV are added up after the kernel; the same numbers."""
+    assert fa._band_bwd_plan(16384, 16384, 128, jnp.bfloat16, None, 128,
+                             1024, 8)[1] == 8
+    plan, heads = fa._band_bwd_plan(16384, 16384, 128, jnp.float32, None,
+                                    128, 1024, 8)
+    assert (heads, plan["block_q"], plan["engine"]) == (4, 512, "pallas")
+    q, k, v, g = _qkvg(2, 1, 8, 2, 512, 16)
+    klen = jnp.full((1,), 512.0)
+    want, vjp = jax.vjp(lambda *x: _plain_attention(*x, 200), q, k, v)
+    out, lse = fa._pallas_flash(q, k, v, klen, True, 0.25, block_q=128,
+                                interpret=True, window=200)
+    # room for two heads' rings of the group's four
+    monkeypatch.setattr(fa, "PLAN_VMEM_BUDGET", fa.band_bwd_working_set_bytes(
+        128, 3, 16, 4, jnp.float32, 16, 2))
+    assert fa._band_bwd_plan(512, 512, 16, jnp.float32, 128, 16, 200,
+                             4)[1] == 2
+    got = fa._pallas_flash_bwd(q, k, v, klen, out, lse, g, True, 0.25,
+                               block_q=128, interpret=True, window=200)
     for a, b, name in zip(got, vjp(g), "qkv"):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
                                    err_msg="d" + name)
-    plan = fa._bwd_plan(640, 640, 16, jnp.float32, True, 128, 128,
-                        window=window, chunk=256)
-    assert plan["chunks"] == 3 and plan["window"] == (window or 0)
-    assert (plan["skipped_window"] > 0) == (window is not None)
 
 
 def test_the_public_door_takes_window_and_groups_on_every_engine():
@@ -310,16 +382,20 @@ def test_static_counts_equal_the_brute_force_count(sq, sk, bq, bk, window):
     assert fa._skipped_steps(nqb, nkb, bq, bk, sk - sq, True, window) == \
         (above, older)
     assert fa._visible_pairs(sq, sk, True, window) == pairs
-    # the forward's k-axis under a window: the widest span a q-block reads
+    if window is not None and bq != bk:
+        return      # the band cuts queries and keys into one block length
+    # the band's strip (PR 59): q-block i's sub-blocks i + lo .. i + hi hold
+    # every block of its row with a visible pair, and some row fills it
     if window is not None:
-        spans = [sum(1 for j in range(nkb) if _brute(
-            sq, sk, bq, bk, window) and _runs(i, j, sq, sk, bq, bk, window))
-            for i in range(nqb)]
-        assert fa._window_k_steps(nqb, nkb, bq, bk, sk - sq, window) == \
-            max(spans)
-    # the whole row as one trip of the backward: the same blocks
+        band = fa._band(bq, nqb, nkb, sk - sq, window)
+        rows = [[j for j in range(nkb) if _runs(i, j, sq, sk, bq, bk, window)]
+                for i in range(nqb)]
+        assert all(i + band.lo <= min(r) and max(r) <= i + band.hi
+                   for i, r in enumerate(rows) if r)
+        assert band.n == max(len(r) for r in rows) or sq % bq or sk % bk
+    # the whole row as one call of the backward: the same blocks
     plan = fa._bwd_plan(sq, sk, 8, jnp.float32, True, bq, bk, window=window,
-                        chunk=sq)
+                        chunk=None if window else sq)
     assert (plan["steps"], plan["skipped_causal"], plan["skipped_window"],
             plan["chunks"]) == (blocks, above, older, 1)
 
@@ -332,22 +408,25 @@ def _runs(i, j, sq, sk, bq, bk, window):
 
 @pytest.mark.parametrize("window", [None, 24])
 def test_chunked_trips_cover_every_visible_pair_once(window):
-    """The outer loop's trips: every query in one trip, and every key a
-    trip's queries see among the keys it is handed; the counts add up."""
+    """The outer loop's trips (no window): every query in one trip, and
+    every key a trip's queries see among the keys it is handed; the counts
+    add up.  Under a window there is no loop: the band's one call counts the
+    blocks with a visible pair."""
     sq = sk = 96
-    trips = fa._bwd_trips(sq, sk, 8, jnp.float32, True, 16, 16, None, window,
-                          chunk=32)
-    assert [t[:2] for t in trips] == [(0, 32), (32, 64), (64, 96)]
     t = np.arange(sq)[:, None]
     u = np.arange(sk)[None, :]
     inside = (u <= t) if window is None else (u <= t) & (t - u < window)
-    for q0, q1, k0, k1, bq, bk in trips:
-        assert (bq, bk) == (16, 16) and k0 % 8 == 0
-        seen = np.flatnonzero(inside[q0:q1].any(axis=0))
-        assert k0 <= seen.min() and seen.max() < k1
+    if window is None:
+        trips = fa._bwd_trips(sq, sk, 8, jnp.float32, True, 16, 16, None,
+                              chunk=32)
+        assert [t[:2] for t in trips] == [(0, 32), (32, 64), (64, 96)]
+        for q0, q1, k0, k1, bq, bk in trips:
+            assert (bq, bk) == (16, 16) and k0 % 8 == 0
+            seen = np.flatnonzero(inside[q0:q1].any(axis=0))
+            assert k0 <= seen.min() and seen.max() < k1
     plan = fa._bwd_plan(sq, sk, 8, jnp.float32, True, 16, 16, window=window,
-                        chunk=32)
-    assert plan["chunks"] == 3
+                        chunk=None if window else 32)
+    assert plan["chunks"] == (1 if window else 3)
     run = plan["steps"] - plan["steps_skipped"]
     blocks = sum(bool(inside[i:i + 16, j:j + 16].any())
                  for i in range(0, sq, 16) for j in range(0, sk, 16))
@@ -355,17 +434,25 @@ def test_chunked_trips_cover_every_visible_pair_once(window):
 
 
 def test_the_cells_plans_at_the_real_shape():
-    """S 16384, head 128, bf16: a sliding layer's forward reads 512 x 512
-    blocks over a 3-block k-axis, its backward 16 chunks of 1024 queries; a
-    full layer's backward 4 chunks of 4096; the shapes of the older cells
-    keep their one call, their blocks and their engine."""
+    """S 16384, head 128, bf16, 8 query heads a K/V head: a sliding layer's
+    forward walks the band a strip of three 512-row blocks a step, its
+    backward is ONE call of 512 x 512 blocks, three q-blocks a k-block (PR
+    59; 16 chunks of 1024 queries before); a full layer's backward 4 chunks
+    of 4096; the shapes of the older cells keep their one call, their
+    blocks and their engine."""
     args = (16384, 16384, 128, jnp.bfloat16, True)
-    assert fa._plan_blocks(*args, True, 128, 1024) == (512, 512)
-    assert fa._window_k_steps(32, 32, 512, 512, 0, 1024) == 3
+    block = fa._plan_band(
+        16384, 16384, 1024, lambda b, n: fa.band_fwd_working_set_bytes(
+            b, n, 128, 16384 // b, jnp.bfloat16, True, 128, 8), True)
+    assert block == 512
+    assert fa._band(512, 32, 32, 0, 1024) == fa._Band(
+        512, -2, 3, (True, False, False), (False, False, True))
     assert fa._plan_blocks(*args, True, 128) == (1024, 1024)
-    sliding = fa._bwd_plan(*args, window=1024)
+    sliding = fa._bwd_plan(*args, window=1024, group=8)
     assert (sliding["engine"], sliding["chunks"], sliding["block_q"],
-            sliding["block_k"]) == ("pallas", 16, 512, 512)
+            sliding["block_k"], sliding["form"]) == (
+                "pallas", 1, 512, 512, "band")
+    assert sliding["steps"] - sliding["steps_skipped"] == 32 * 3 - 3
     full = fa._bwd_plan(*args)
     assert (full["engine"], full["chunks"], full["block_q"],
             full["block_k"]) == ("pallas", 4, 512, 512)
@@ -528,8 +615,10 @@ def test_attn_lower_and_the_flash_plans_say_what_a_site_was_given():
     assert len(bwd) == 4 and {b["window"] for b in bwd} == {0, W}
     for b in bwd:
         want = dict(fa._bwd_plan(S, S, 16, jnp.bfloat16, True,
-                                 window=b["window"] or None), kv_heads=2)
+                                 window=b["window"] or None, group=2),
+                    kv_heads=2)
         assert b == want and b["engine"] == "pallas" and b["chunks"] == 1
+        assert b["form"] == ("band" if b["window"] else "blocks")
     assert len(spans["moe.lower"]) >= 4
     assert all(m["scoring"] == "softmax" and m["experts_held"] == 4
                and m["experts_total"] == 16 for m in spans["moe.lower"])

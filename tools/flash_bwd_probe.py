@@ -8,6 +8,8 @@ settled.
   nmt-encoder  768 x 256 x 64, not causal, ragged k_lengths  transformer-train
   s<S>-d<D>    the ladder: S 256 / 384 / 512 / 1024 / 2048 at head 64 and
                128, causal, B * S held at 24576 (d 64) or 4096 (d 128) tokens
+  mellum-sliding  32 heads on 4 x 16384 x 128, window 1024   mellum-train-swa16k
+  mellum-full     the same, no window                        mellum-train-swa16k
 
 For each shape, one JSON line a row:
   xla          jax.vjp of the reference formulation given (q, k, v, dO): the
@@ -29,6 +31,11 @@ pins each count in turn: `pallas-rows-N` the backward kernel alone,
 rows take [B, H, S, D] ARGUMENTS, which a step never has, and their times
 hold the copy to the kernel's layout); `--rows-per-step` counts BATCH rows
 there.
+A windowed shape (PR 59) runs the band, ONE call of _band_bwd_kernel:
+`pallas` at _plan_band's block, `band-<b>` each candidate pinned, beside the
+parent's chunked calls and their glue (`--parent`: `parent-pallas`,
+`step-parent`).  The two 16k shapes have no `xla` rows (34 GB of scores) and
+are held to the reference, a head at a time, on their first K/V head's group.
 `--sweep` also pins every block pair the shape admits whose working set is
 under 1.5 x the plan's share; `--parent FILE` times another commit's
 `_pallas_flash_bwd` as it stands (`git show <commit>:paddle_tpu/kernels/
@@ -60,7 +67,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from flash_fwd_probe import PEAK_TFLOPS, _time_ms  # noqa: E402
+from flash_fwd_probe import BAND_BLOCKS, PEAK_TFLOPS, _time_ms  # noqa: E402
 
 SHAPES = {
     # name: (B, H, S, D, causal, ragged)
@@ -75,11 +82,16 @@ SHAPES = {
     "s1024-d64": (24, 8, 1024, 64, True, False),
     "s1024-d128": (4, 16, 1024, 128, True, False),
     "s2048-d64": (12, 8, 2048, 64, True, False),
+    # (..., K/V heads, window): run on request (--shapes)
+    "mellum-sliding": (1, 32, 16384, 128, True, False, 4, 1024),
+    "mellum-full": (1, 32, 16384, 128, True, False, 4, None),
 }
 REHEARSAL_SHAPES = {
     "ouro": (1, 2, 512, 128, True, False),
     "nmt-decoder": (2, 2, 256, 64, True, False),
     "nmt-encoder": (2, 2, 256, 64, False, True),
+    "mellum-sliding": (1, 4, 512, 64, True, False, 2, 128),
+    "mellum-full": (1, 4, 512, 64, True, False, 2, None),
 }
 
 
@@ -119,41 +131,50 @@ def main() -> int:
     shapes = REHEARSAL_SHAPES if a.rehearse else SHAPES
     rule_threshold = fa._BWD_PALLAS_MIN_BLOCK_SCORES
     rows = []
-    for name in (a.shapes.split(",") if a.shapes else shapes):
-        B, H, S, D, causal, ragged = shapes[name]
+    for name in (a.shapes.split(",") if a.shapes else [
+            n for n in shapes if not n.startswith("mellum")]):
+        B, H, S, D, causal, ragged, *rest = shapes[name]
+        G, window = rest or (H, None)
         rng = np.random.RandomState(a.seed % (2 ** 32))
-        q, k, v, g = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
-                      for _ in range(4))
+        q, k, v, g = (jnp.asarray(rng.randn(B, n, S, D), jnp.bfloat16)
+                      for n in (H, G, G, H))
         lengths = (rng.randint(S // 2, S + 1, size=B) if ragged
                    else np.full(B, S))
         klen = jnp.asarray(lengths, jnp.float32)
         scale = 1.0 / math.sqrt(D)
-        visible = S * (S + 1) / 2 if causal else float(S * np.mean(lengths))
+        visible = (fa._visible_pairs(S, S, True, window) if causal
+                   else float(S * np.mean(lengths)))
         counted = 2.5 * 4.0 * B * H * visible * D
-        plan = fa._bwd_plan(S, S, D, q.dtype, causal, bh=B * H)
+        plan = fa._bwd_plan(S, S, D, q.dtype, causal, window=window,
+                            bh=fa._packable_rows(q, k), group=H // G)
         force = "interpret" if a.rehearse else "pallas"
+        windowed = {} if window is None else {"window": window}
+        big = 4 * B * H * S * S > 2 ** 32   # no [B, H, S, S] fp32 scores
 
         def reference(q, k, v):
             return fa._reference_attention(
-                q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32))
+                q, k, v, causal, scale, k_lengths=klen.astype(jnp.int32),
+                window=window)
 
         out, lse = jax.jit(lambda q, k, v: fa._pallas_flash(
-            q, k, v, klen, causal, scale, interpret=a.rehearse))(q, k, v)
+            q, k, v, klen, causal, scale, interpret=a.rehearse,
+            **windowed))(q, k, v)
 
         def kernels(module, **pins):
             return jax.jit(lambda q, k, v, g: module._pallas_flash_bwd(
                 q, k, v, klen, out, lse, g, causal, scale,
-                interpret=a.rehearse, **pins))
+                interpret=a.rehearse, **windowed, **pins))
 
-        def step(threshold, rows_per_step=None):
+        def step(threshold, rows_per_step=None, module=fa):
             """fwd + bwd with the rule's threshold held at `threshold` (and
             both kernels' rows a grid step at `rows_per_step`, where given)
             while the call is traced and compiled; the loss is returned too,
             or the forward of the XLA engine (nothing of it is a residual)
             is dead code."""
             def loss(q, k, v, g):
-                o = fa.flash_attention(q, k, v, causal=causal,
-                                       k_lengths=klen, force=force)
+                o = module.flash_attention(q, k, v, causal=causal,
+                                           k_lengths=klen, force=force,
+                                           **windowed)
                 return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
 
             planned = fa._rows_per_step
@@ -180,14 +201,20 @@ def main() -> int:
         # (label, call, blocks[, batch-head rows a grid step: 1 if absent]);
         # a `step-*` call is built where it is timed, so that a compile
         # Mosaic refuses is a row like any other
-        variants = [("xla", xla, (None, None)),
-                    ("pallas", kernels(fa), pair, planned)]
+        variants = [] if big else [("xla", xla, (None, None))]
+        variants.append(("pallas", kernels(fa), pair, planned))
         variants += [(f"pallas-rows-{n}", kernels(fa, rows_per_step=n), pair,
                       n) for n in counts]
+        if window is not None:      # the band at each candidate block
+            blocks = BAND_BLOCKS + ((128, 1024) if a.sweep else ())
+            variants += [(f"band-{b}", kernels(fa, block_q=b, block_k=b),
+                          (b, b)) for b in blocks if b <= S]
         if parent is not None:
-            variants.append(("parent-pallas", kernels(parent),
-                             (lse.shape[2], 128)))
-        if not a.rehearse:   # force="interpret" keeps the Pallas backward
+            variants += [
+                ("parent-pallas", kernels(parent), (lse.shape[2], 128)),
+                ("step-parent", lambda: step(rule_threshold, module=parent),
+                 (None, None))]
+        if not (a.rehearse or big):   # "interpret" keeps the Pallas backward
             variants.append(("step-xla", lambda: step(2 ** 62),
                              (None, None)))
         variants += [("step-pallas", lambda: step(0), pair, planned),
@@ -225,7 +252,7 @@ def main() -> int:
                 (f"bshd-rows-{n}", heads_last(rows_per_step=n), pair, n)
                 for n in map(int, filter(None, a.rows_per_step.split(",")))
                 if B % n == 0]
-        if a.sweep:
+        if a.sweep and window is None:
             lens = fa._block_lengths(S)
             variants += [
                 (f"pallas-{bq}x{bk}", kernels(fa, block_q=bq, block_k=bk),
@@ -234,27 +261,49 @@ def main() -> int:
                     bq, bk, D, -(-S // bq), "bfloat16")
                 <= 1.5 * engine.PLAN_VMEM_BUDGET]
         f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
-        want = [np.asarray(x) for x in xla(*f32)]
+        group = H // G
+
+        def first_group(grads):
+            """dq of the first K/V head's query heads, its dk and dv."""
+            dq, dk, dv = grads
+            return dq[:, :group], dk[:, :1], dv[:, :1]
+
+        if big:     # a head at a time: a head's fp32 scores are 1 GB
+            one = jax.jit(lambda q, k, v, g: jax.vjp(reference, q, k, v)[1](g))
+            heads = [one(f32[0][:, h:h + 1], f32[1][:, :1], f32[2][:, :1],
+                         f32[3][:, h:h + 1]) for h in range(group)]
+            want = [np.concatenate([np.asarray(x[0]) for x in heads], 1),
+                    sum(np.asarray(x[1]) for x in heads),
+                    sum(np.asarray(x[2]) for x in heads)]
+        else:
+            want = [np.asarray(x) for x in xla(*f32)]
         for label, fn, (bq, bk), *rows_per_step in variants:
             row = {"shape": name, "bh": B * H, "s": S, "d": D,
                    "causal": causal, "variant": label, "block_q": bq,
-                   "block_k": bk, "seed": a.seed}
+                   "block_k": bk, "seed": a.seed, "kv_heads": G,
+                   "window": window or 0}
             bshd = "bshd" in label
             if bq is not None:
                 n = rows_per_step[0] if rows_per_step else 1
                 row["rows_per_step"] = n
-                row["working_set_mb"] = round(fa.bwd_working_set_bytes(
-                    bq, bk, D, -(-S // bq), "bfloat16", None, n,
-                    H if bshd else 1) / 2 ** 20, 3)
+                band = window is not None and not label.startswith("parent")
+                row["working_set_mb"] = round((
+                    fa.band_bwd_working_set_bytes(
+                        bq, fa._band(bq, S // bq, S // bq, 0, window).n, D,
+                        S // bq, "bfloat16", None, group) if band
+                    else fa.bwd_working_set_bytes(
+                        bq, bk, D, -(-S // bq), "bfloat16", None, n,
+                        H if bshd else 1)) / 2 ** 20, 3)
             args = (q, k, v, g)
             if bshd:    # the kernel alone also takes O; the step makes it
                 args = last[:4] if label.startswith("step-") else last
             try:
                 if label.startswith("step-"):
                     fn = fn()
+                got = fn(*args)
                 got = [np.asarray((fa._heads_first(x, H) if bshd
                                    else x).astype(jnp.float32))
-                       for x in fn(*args)]
+                       for x in (first_group(got) if big else got)]
                 row["max_abs_err"] = max(float(np.max(np.abs(x - w)))
                                          for x, w in zip(got, want))
                 if not a.rehearse:  # an interpreter's time is no one's
