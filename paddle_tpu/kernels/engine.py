@@ -17,9 +17,9 @@ The mesh rule as it stands (ROADMAP D25) is three behaviours, because XLA
 cannot partition a Mosaic kernel: flash attention (`shard_over_mesh`) and
 the dropout mask (kernels/dropout_mask.py, its own specs) wrap their
 kernels in a `shard_map`; compressed_conv_qkv, kda_conv_decay,
-kda_gated_norm, the mhc_* ops and eva_attention run their jax.numpy form
-on a mesh of several devices (`wants_kernels(force, mesh)`, which
-`tiles_or_none` and `site` ask); sparse_attention and
+kda_gated_norm, the mhc_* ops, eva_attention and selective_scan run their
+jax.numpy form on a mesh of several devices (`wants_kernels(force,
+mesh)`, which `tiles_or_none` and `site` ask); sparse_attention and
 gated_delta_attention read no mesh.  No four-chip cell measures the last
 two groups: the PR that makes the three one edits `wants_kernels` and
 needs that cell first.

@@ -581,11 +581,13 @@ class Recurrence:
     over the trips; built inside fluid.recompute_scope() the trip is the
     unit of recomputation (ops/control_flow_ops.py::_recurrence)."""
 
-    def __init__(self, trips: int, name: Optional[str] = None):
+    def __init__(self, trips: int, name: Optional[str] = None,
+                 prevent_cse: bool = False):
         if int(trips) < 1:
             raise ValueError(f"Recurrence needs at least one trip, got {trips}")
         self.helper = LayerHelper("recurrence", name=name)
         self.trips = int(trips)
+        self.prevent_cse = bool(prevent_cse)
         self._parent_block = None
         self._sub_block = None
         self._carries: List[list] = []   # [init, in-block var, next, final]
@@ -665,6 +667,7 @@ class Recurrence:
                 "__carry_names__": [c[1].name for c in self._carries],
                 "__next_names__": [c[2].name for c in self._carries],
                 "__step_out_names__": [o.name for o, _ in self._outputs],
+                **({"prevent_cse": True} if self.prevent_cse else {}),
             },
         )
 

@@ -28,6 +28,9 @@ __all__ = [
     "rotary_embedding",
     "latent_attention",
     "short_conv1d",
+    "selective_scan",
+    "differential_attention",
+    "handed_on",
     "gated_delta_attention",
     "compressed_conv_qkv",
     "kda_conv_decay",
@@ -1387,18 +1390,81 @@ def latent_attention(q, latent, k_rope, kv_up_w, n_head, qk_nope_head_dim,
     return out
 
 
-def short_conv1d(x, weight, activation="silu", name=None):
+def short_conv1d(x, weight, activation="silu", name=None, bias=None):
     """A causal depthwise convolution along the sequence and an
     activation: x [B, S, C], weight [k, C] (one filter of k taps a
     channel, the last tap on the position itself, zeros before the first
-    position), `activation` identity | silu | sigmoid | tanh | relu; [B, S,
-    C] out (TPU-native; ops/linear_attention_ops.py short_conv1d)."""
+    position), `bias` [C] added before `activation` identity | silu |
+    sigmoid | tanh | relu; [B, S, C] out (TPU-native;
+    ops/linear_attention_ops.py short_conv1d)."""
     helper = LayerHelper("short_conv1d", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x], "W": [weight]}
+    if bias is not None:
+        inputs["Bias"] = [bias]
     helper.append_op(
-        type="short_conv1d", inputs={"X": [x], "W": [weight]},
+        type="short_conv1d", inputs=inputs,
         outputs={"Out": [out]}, attrs={"activation": str(activation)},
     )
+    return out
+
+
+def selective_scan(x, dt, a, b, c, d, dt_bias=None, name=None):
+    """Mamba-1's selective scan: x, dt [B, S, E], a [E, N] (negative), b,
+    c [B, S, N], d [E]; a channel's N states from 0, s_t = exp(dt_t a)
+    s_(t-1) + dt_t x_t b_t, y_t = s_t c_t + d x_t; with `dt_bias` [E] the
+    step is softplus(dt + dt_bias).  fp32 inside, [B, S, E] out in x's
+    dtype; no state a token is ever stored, backward included
+    (TPU-native; ops/state_space_ops.py selective_scan,
+    kernels/selective_scan.py)."""
+    helper = LayerHelper("selective_scan", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x], "Dt": [dt], "A": [a], "B": [b], "C": [c], "D": [d]}
+    if dt_bias is not None:
+        inputs["DtBias"] = [dt_bias]
+    helper.append_op(type="selective_scan", inputs=inputs,
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def handed_on(x, what, readers, name=None):
+    """x as it is, marked as a value that one layers.Recurrence hands out
+    and `readers` later ones read from outside their bodies (`what`:
+    memory | kv): the span `shared.lower` says its bytes and readers at
+    lowering (TPU-native; ops/control_flow_ops.py handed_on)."""
+    helper = LayerHelper("handed_on", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="handed_on", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"what": str(what), "readers": int(readers)})
+    return out
+
+
+def differential_attention(q, k, v, lambda_q1, lambda_k1, lambda_q2,
+                           lambda_k2, scale, n_head, lambda_init,
+                           causal=True, window=None, epsilon=1e-5,
+                           name=None):
+    """Differential attention (Ye et al., arXiv:2410.05258) of heads-last
+    q [B, S, n_head * D] over k, v [B, S, G * D]: adjacent heads pair up,
+    a pair's two softmax maps (head D) read the pair's two value heads
+    side by side (2 D), out = (1 - lambda_init) RMSNorm(A1 - lambda A2)
+    scale [2 D] with lambda = exp(lambda_q1 . lambda_k1) - exp(lambda_q2 .
+    lambda_k2) + lambda_init; `causal`, under `window` a query sees itself
+    and the window - 1 keys before it.  [B, S, n_head * D] out
+    (TPU-native; ops/attention_ops.py differential_attention: two flash
+    sites and jax.numpy around them)."""
+    helper = LayerHelper("differential_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"n_head": int(n_head), "lambda_init": float(lambda_init),
+             "causal": bool(causal), "epsilon": float(epsilon)}
+    if window:
+        attrs["window"] = int(window)
+    helper.append_op(
+        type="differential_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "LambdaQ1": [lambda_q1],
+                "LambdaK1": [lambda_k1], "LambdaQ2": [lambda_q2],
+                "LambdaK2": [lambda_k2], "Scale": [scale]},
+        outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -27,6 +27,8 @@ from .hybrid_linear_decoder import (  # noqa: F401
 from .hyper_expert_decoder import (  # noqa: F401
     hyper_expert_decoder, HyperExpertDecoderConfig)
 from .eva_decoder import eva_decoder, EvaDecoderConfig  # noqa: F401
+from .sambay_decoder import (  # noqa: F401
+    sambay_decoder, SambaYDecoderConfig)
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

@@ -39,7 +39,8 @@ def class_batch(
     }
 
 
-def one_trip_layer(h, body, use_recompute: bool = True):
+def one_trip_layer(h, body, use_recompute: bool = True,
+                   prevent_cse: bool = False):
     """(h', the recurrence) of one layer built as a one-trip
     layers.Recurrence, under `use_recompute` inside a recompute scope, so
     that the layer is the unit of recomputation.  `h` is the value a layer
@@ -55,7 +56,7 @@ def one_trip_layer(h, body, use_recompute: bool = True):
     many = isinstance(h, tuple)
     scope = recompute_scope if use_recompute else contextlib.nullcontext
     with scope():
-        rec = layers.Recurrence(trips=1)
+        rec = layers.Recurrence(trips=1, prevent_cse=prevent_cse)
         with rec.block():
             carried = tuple(rec.carry(v) for v in (h if many else (h,)))
             out, handed_out = body(carried if many else carried[0])

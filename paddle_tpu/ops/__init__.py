@@ -29,6 +29,7 @@ from . import (  # noqa: F401
     reduce_ops,
     rnn_ops,
     sequence_ops,
+    state_space_ops,
     tensor_ops,
     vision_ops,
 )

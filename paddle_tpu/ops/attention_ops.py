@@ -88,14 +88,19 @@ def _fused_attention(ctx, ins, attrs):
     site was given, `kept` and `kept_bytes` what it holds through that
     recomputation ("" and 0 on the XLA recompute backward); the context's
     `kept` counts the values."""
-    q = data(ins["Q"][0])  # [B, H, Sq, D], or [B, Sq, n_head * D]
-    k = data(ins["K"][0])
-    v = data(ins["V"][0])
     klen_in = ins.get("KLengths", [None])[0]
-    klen = data(klen_in).reshape(-1) if klen_in is not None else None
-    causal = bool(attrs.get("causal", False))
-    window = int(attrs.get("window", 0)) or None
-    heads = int(attrs.get("n_head", 0)) or None
+    out = _site(ctx, data(ins["Q"][0]), data(ins["K"][0]), data(ins["V"][0]),
+                data(klen_in).reshape(-1) if klen_in is not None else None,
+                bool(attrs.get("causal", False)), attrs.get("scale") or None,
+                int(attrs.get("window", 0)) or None,
+                int(attrs.get("n_head", 0)) or None,
+                str(attrs.get("rope") or "none"))
+    return {"Out": [out]}
+
+
+def _site(ctx, q, k, v, klen, causal, scale, window, heads, rope):
+    """One attention site under its `attn.lower` span: q [B, H, Sq, D], or
+    with `heads` [B, Sq, heads * D]."""
     from ..kernels.flash_attention import _visible_pairs, heads_first_shapes
 
     first = (q, k, v) if heads is None else heads_first_shapes(q, k, v, heads)
@@ -104,11 +109,71 @@ def _fused_attention(ctx, ins, attrs):
     with span("attn.lower", kind="full" if seen is None else "sliding",
               window=int(seen or 0), heads=int(n_head),
               kv_heads=int(kv_heads), sq=int(sq),
-              pairs=_visible_pairs(sq, sk, causal, seen),
-              rope=str(attrs.get("rope") or "none")) as sp:
-        out = _attend(ctx, sp, q, k, v, klen, causal,
-                      attrs.get("scale") or None, window, heads)
-    return {"Out": [out]}
+              pairs=_visible_pairs(sq, sk, causal, seen), rope=rope) as sp:
+        return _attend(ctx, sp, q, k, v, klen, causal, scale, window, heads)
+
+
+def difference_of_maps(a1, a2, lam, scale, lambda_init, eps):
+    """Differential attention's combination (Ye et al., arXiv:2410.05258)
+    of two softmax maps' outputs a1, a2 [B, P, S, W] of P head pairs: (1 -
+    lambda_init) RMSNorm_W(a1 - lam a2) scale, the statistic and the
+    difference fp32, a1's dtype out."""
+    diff = a1.astype(jnp.float32) - lam * a2.astype(jnp.float32)
+    normed = diff * jax.lax.rsqrt(
+        jnp.mean(jnp.square(diff), axis=-1, keepdims=True) + eps)
+    return (normed * scale.astype(jnp.float32)
+            * (1.0 - lambda_init)).astype(a1.dtype)
+
+
+@register_op("differential_attention", infer_shape=_fused_attn_infer,
+             diff_inputs=["Q", "K", "V", "LambdaQ1", "LambdaK1", "LambdaQ2",
+                          "LambdaK2", "Scale"])
+def _differential_attention(ctx, ins, attrs):
+    """Differential attention (Ye et al., arXiv:2410.05258) of heads-last
+    operands, Q [B, Sq, H D] over K, V [B, Sk, G D], H = `n_head` and G a
+    divisor of it, both even.  Heads go in adjacent pairs: pair j's q1, q2
+    are query heads 2j, 2j + 1; its k1, k2 the two key heads of key/value
+    pair j // (H / G) and its value that pair's two value heads side by
+    side (2 D wide).  A1 = softmax(q1 k1^T / sqrt(D) + mask) v, A2 likewise
+    of q2, k2; lambda = exp(LambdaQ1 . LambdaK1) - exp(LambdaQ2 . LambdaK2)
+    + `lambda_init` (four learned [D] vectors, fp32); the pair's output is
+    (1 - lambda_init) RMSNorm_2D(A1 - lambda A2) Scale [2 D] (`epsilon`
+    under the root).  Out [B, Sq, H D], pair j's 2 D columns at 2 D j.  The
+    mask is `causal`, under `window` also t - s < window.
+
+    A composition, no kernel of its own: the two maps are two sites of
+    flash_attention at H / 2 heads over G / 2, head D reading values 2 D
+    wide (each under its own `attn.lower` span, kept and sharded over a
+    mesh as fused_attention's are: `_attend`), lambda, the norm and the
+    scale jax.numpy around them."""
+    q, k, v = amp.mxu_operands(*(data(ins[s][0]) for s in ("Q", "K", "V")))
+    lq1, lk1, lq2, lk2 = (data(ins[s][0]).astype(jnp.float32) for s in (
+        "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"))
+    H = int(attrs["n_head"])
+    B, Sq, width = q.shape
+    D = width // H
+    G, Sk = k.shape[2] // D, k.shape[1]
+    if H % 2 or G % 2 or H % G:
+        raise ValueError(f"differential_attention: {H} query heads over "
+                         f"{G} key/value heads do not pair")
+    lambda_init = float(attrs["lambda_init"])
+
+    def halves(t, n, s):        # [B, s, n D] -> two [B, n / 2, s, D]
+        t = t.reshape(B, s, n // 2, 2, D).transpose(0, 2, 3, 1, 4)
+        return t[:, :, 0], t[:, :, 1]
+
+    (q1, q2), (k1, k2) = halves(q, H, Sq), halves(k.astype(q.dtype), G, Sk)
+    wide = v.astype(q.dtype).reshape(B, Sk, G // 2, 2 * D).transpose(
+        0, 2, 1, 3)
+    window = int(attrs.get("window", 0)) or None
+    causal = bool(attrs.get("causal", True))
+    a1, a2 = (_site(ctx, qi, ki, wide, None, causal, D ** -0.5, window, None,
+                    "none") for qi, ki in ((q1, k1), (q2, k2)))
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) \
+        + lambda_init
+    out = difference_of_maps(a1, a2, lam, data(ins["Scale"][0]), lambda_init,
+                             float(attrs.get("epsilon", 1e-5)))
+    return {"Out": [out.transpose(0, 2, 1, 3).reshape(B, Sq, width)]}
 
 
 @register_op("eva_attention", infer_shape=_fused_attn_infer,

@@ -654,13 +654,31 @@ def _recurrence(ctx, ins, attrs):
 
     with span("recurrence.lower", trips=trips,
               recompute=int(recompute)) as sp:
-        step = (rematerialised(body, prevent_cse=False) if recompute
-                else body)
+        step = (rematerialised(body, prevent_cse=bool(
+            attrs.get("prevent_cse", False))) if recompute else body)
         final, stacked = jax.lax.scan(
             step, init, jax.random.split(ctx.rng(), trips))
         sp.set(bodies_lowered=lowered[0], kept=kept[0])
     ctx.kept += kept[0]
     return {"Out": list(stacked), "Final": list(final)}
+
+
+@register_op("handed_on", infer_shape=same_shape("X", "Out"),
+             diff_inputs=["X"])
+def _handed_on(ctx, ins, attrs):
+    """X as it is: the mark of a value that one recurrence hands out
+    (`rec.output`) and `readers` later ones read from outside their bodies
+    (a Mamba layer's memory, a layer's keys and values).  It is computed
+    once a step, a reader's recomputation takes it as an input, and
+    core/backward.py sums the readers' cotangents into the one the
+    producer's unit is differentiated with.  `shared.lower` (a span, at
+    lowering) says `what`, its `bytes` and its `readers`."""
+    x = data(ins["X"][0])
+    with span("shared.lower", what=str(attrs["what"]),
+              bytes=int(x.size) * x.dtype.itemsize,
+              readers=int(attrs["readers"])):
+        pass
+    return {"Out": [x]}
 
 
 # ---------------------------------------------------------------------------

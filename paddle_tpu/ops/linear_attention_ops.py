@@ -23,15 +23,17 @@ _CONV_ACTS = {**ACTS, "silu": jax.nn.silu}
 
 
 @register_op("short_conv1d", infer_shape=same_shape("X", "Out"),
-             diff_inputs=["X", "W"])
+             diff_inputs=["X", "W", "Bias"])
 def _short_conv1d(ctx, ins, attrs):
     """A causal depthwise convolution along the sequence, then
     `activation` (identity | silu | ...): X [B, S, C], W [k, C], one filter
     of k taps a channel; y[t] = sum_j W[j] x[t - (k - 1) + j], zeros before
     the first position (attention_ops.causal_conv1d: k shifted products
-    summed in fp32).  Out in X's dtype."""
+    summed in fp32), plus Bias [C] where there is one.  Out in X's dtype."""
     x, w = data(ins["X"][0]), data(ins["W"][0])
-    y = causal_conv1d(x[:, None], w[:, None])[:, 0]
+    bias = ins.get("Bias", [None])[0]
+    y = causal_conv1d(x[:, None], w[:, None],
+                      None if bias is None else data(bias)[None])[:, 0]
     return {"Out": [_CONV_ACTS[str(attrs.get("activation") or "identity")](
         y).astype(x.dtype)]}
 

@@ -313,6 +313,29 @@ def _kda_scan(backward):
                     argnums=tuple(range(5))), args
 
 
+def _ssm_scan(backward):
+    """phi-4-mini-flash's selective scan at the cell's shape (5120 channels
+    of 16 states, S 8192, chunks of 64, fp32 streams):
+    kernels/selective_scan.py's forward kernel at the planned tiles, and
+    with it the backward."""
+    from paddle_tpu.kernels import selective_scan as ss
+
+    S, E, N = 8192, 5120, 16
+    args = (_sds((1, S, E), jnp.float32),) * 2 + (
+        _sds((E, N), jnp.float32), _sds((1, S, N), jnp.float32),
+        _sds((1, S, N), jnp.float32), _sds((E,), jnp.float32))
+    tiles = ss.tiles(S, E, N)
+    assert tiles is not None
+
+    def fwd(*a):
+        return ss.selective_scan(*a, tiles_=tiles)
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a) ** 2),
+                    argnums=tuple(range(6))), args
+
+
 def _kda_mix(backward):
     """kimi-linear-48b-a3b's passes around the chunk scan at the cell's
     shape (32 heads of 128, S 4096, four taps, bf16 streams):
@@ -380,6 +403,8 @@ _MAIN_PATH_KERNELS = {
     "mhc_bwd_pallas_xing": lambda: _mhc(True),
     "kda_mix_fwd_kimi": lambda: _kda_mix(False),
     "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
+    "ssm_scan_fwd_sambay": lambda: _ssm_scan(False),
+    "ssm_scan_bwd_pallas_sambay": lambda: _ssm_scan(True),
     "kda_scan_fwd_kimi": lambda: _kda_scan(False),
     "kda_scan_bwd_pallas_kimi": lambda: _kda_scan(True),
     "cca_mix_fwd_zaya": lambda: _cca_mix(False),

@@ -215,6 +215,7 @@ ASKS = {"attention_ops.py": {"shard_over_mesh", "wants_kernels", "site",
                              "use_pallas"},
         "linear_attention_ops.py": {"site", "one_dtype"},
         "hyper_connection_ops.py": {"site", "one_dtype"},
+        "state_space_ops.py": {"site"},
         "moe_ops.py": {"use_pallas"}}
 
 
