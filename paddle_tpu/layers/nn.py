@@ -31,6 +31,7 @@ __all__ = [
     "selective_scan",
     "differential_attention",
     "handed_on",
+    "kept",
     "gated_delta_attention",
     "compressed_conv_qkv",
     "kda_conv_decay",
@@ -1437,6 +1438,19 @@ def handed_on(x, what, readers, name=None):
     helper.append_op(type="handed_on", inputs={"X": [x]},
                      outputs={"Out": [out]},
                      attrs={"what": str(what), "readers": int(readers)})
+    return out
+
+
+def kept(x, name=None):
+    """x as it is, marked to survive the recomputation of the unit around
+    it (a layers.Recurrence under recompute_scope): the unit saves it
+    beside its inputs, x's bytes held from the forward to the backward, and
+    the backward does not make it again; anywhere else the identity
+    (TPU-native; ops/control_flow_ops.py kept)."""
+    helper = LayerHelper("kept", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="kept", inputs={"X": [x]}, outputs={"Out": [out]},
+                     attrs={})
     return out
 
 

@@ -52,10 +52,14 @@ bodies, as a body reads a parameter: each is computed once a step, a
 reader's recomputation takes it as an input and never makes it again, and
 core/backward.py sums the readers' cotangents into the ONE cotangent the
 producer's unit is differentiated with (tests/test_sambay_decoder.py holds
-all three).  Name scopes: `ssm.mix` (the convolution with its SiLU, the
-step's softplus, the gate), `ssm.scan` (the op selective_scan's own),
+all three).  What a layer's recomputation does NOT make again: what the
+kernels keep (the scan's output and chunk starts, a flash site's output and
+logsumexp) and W1's output (layers.kept, `_SambaYBuilder.mlp`).  Name
+scopes: `ssm.mix` (the convolution with its SiLU, the step's softplus, the
+gate), `ssm.scan` (the op selective_scan's own),
 `gmu` (the whole mixer), `attn.sliding`, `attn.full`, `attn.cross` (the op
-differential_attention; the projections outside), `loop.heads`.  Spans at
+differential_attention; the projections outside), `mlp` (both products and
+what lies between them), `loop.heads`.  Spans at
 lowering: `ssm.lower`, `attn.lower`, `flash.plan` / `flash.bwd_plan`, and
 `shared.lower` (`what` memory | kv, `bytes`, `readers`) where a value is
 handed on.
@@ -193,12 +197,18 @@ class _SambaYBuilder(_ExpertBuilder):
                              out_dtype="float32")
 
     def mlp(self, x, name):
+        """W1's output [B, S, 2 d_inner] survives the layer's recomputation
+        (layers.kept): the backward reads g and u themselves, and the
+        product that makes them is the dearest thing a layer would run
+        twice."""
         cfg = self.cfg
-        gate, up = layers.split(
-            self.linear(x, cfg.d_model, 2 * cfg.d_inner, f"{name}_1"),
-            2, dim=-1)
-        return self.linear(layers.elementwise_mul(layers.swish(gate), up),
-                           cfg.d_inner, cfg.d_model, f"{name}_2")
+        with name_scope("mlp"):
+            gate, up = layers.split(layers.kept(
+                self.linear(x, cfg.d_model, 2 * cfg.d_inner, f"{name}_1")),
+                2, dim=-1)
+            return self.linear(
+                layers.elementwise_mul(layers.swish(gate), up),
+                cfg.d_inner, cfg.d_model, f"{name}_2")
 
     def mamba(self, u, name):
         """(Mamba(u), the scan's output y)."""

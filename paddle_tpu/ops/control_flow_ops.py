@@ -780,3 +780,24 @@ def _print(ctx, ins, attrs):
     msg = attrs.get("message", "") or ""
     jax.debug.print(msg + " {}", d, ordered=False)
     return {"Out": [x]}
+
+
+# ---------------------------------------------------------------------------
+# kept
+# ---------------------------------------------------------------------------
+@register_op("kept", infer_shape=same_shape("X", "Out"), diff_inputs=["X"])
+def _kept(ctx, ins, attrs):
+    """X as it is, tagged to survive the recomputation of the unit around
+    it (compiler.keep): the mark of a value of plain ops that is dear to
+    make again and cheap to hold, as a kernel's own lowering marks its
+    outputs (a decoder MLP's first product, [B, S, 2 d_inner]: a matmul
+    over the whole stream against one read of its result).  A
+    rematerialised unit saves it beside its inputs, in X's dtype, so it
+    costs X's bytes from the unit's forward to its backward, and the
+    backward computes nothing that only X's making needed.  Outside a
+    rematerialised unit it is the identity.  `recurrence.lower` counts it
+    among the unit's `kept`."""
+    from ..core.compiler import keep
+
+    ctx.kept += 1
+    return {"Out": list(keep(data(ins["X"][0])))}

@@ -8,7 +8,9 @@ bare jax.checkpoint gives; `recurrence.lower` counts the kept values.  The
 flash sites (PR 44) keep `out` and the logsumexp where their backward is the
 Pallas kernel: one forward kernel a site where the bare checkpoint has two,
 the bare checkpoint's numbers bit for bit; a site on the XLA recompute
-backward names nothing."""
+backward names nothing.  The op `kept` (PR 61) tags a value of plain ops:
+the product before it stays from the first forward and leaves the
+recomputation."""
 
 import hashlib
 import os
@@ -568,3 +570,83 @@ def test_the_compilers_own_recompute_branch_keeps_what_an_op_named(
                                    atol=1e-6 * np.abs(r).max())
     monkeypatch.setattr(compiler, "rematerialised", _bare)
     assert _op_step_chunk_scans(recompute=True)[0] == 3
+
+
+# ---------------------------------------------------------------------------
+# the op `kept`: a value of plain ops that a program names
+
+def _mlp(tag, hand_out=False):
+    """A fresh function a call: a norm a layer recomputes, W1's product,
+    the op `kept`'s registered lowering (or nothing) on it, silu(g) * u,
+    W2's product, a loss (`hand_out`: and W1's output beside it)."""
+    import types
+
+    from paddle_tpu.core.registry import OpRegistry
+
+    def kept(x):
+        ctx = types.SimpleNamespace(cur_op=None, kept=0)
+        return OpRegistry.get("kept").lower(ctx, {"X": [x]}, {})["Out"][0]
+
+    def layer(x, w1, w2):
+        x = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5)
+        first = jnp.dot(x.astype(jnp.bfloat16), w1.astype(jnp.bfloat16))
+        g, u = jnp.split(kept(first) if tag else first, 2, -1)
+        out = jnp.dot(jax.nn.silu(g) * u, w2.astype(jnp.bfloat16))
+        loss = jnp.sum(jnp.square(out.astype(jnp.float32)))
+        return (loss, first) if hand_out else loss
+
+    return layer
+
+
+def _dots(fn, args, width) -> int:
+    """dot_generals of the differentiated `fn` whose result is `width`
+    wide."""
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 1, 2)))(*args)
+    return _count(jaxpr.jaxpr, lambda e: e.primitive.name == "dot_general"
+                  and e.outvars[0].aval.shape[-1] == width)
+
+
+def _mlp_inputs(seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(*shape), jnp.float32)
+                 for shape in ((2, 32, 16), (16, 48), (24, 16)))
+
+
+def test_the_op_kept_takes_its_product_out_of_the_recomputation():
+    """W1's product, [.., 2 x 24]: once with no checkpoint, twice under the
+    bare one, once where the op `kept` tags its output; untagged,
+    `rematerialised` is the bare checkpoint."""
+    args = _mlp_inputs()
+    dots = {(name, tag): _dots(wrap(_mlp(tag)), args, 48)
+            for name, wrap in WRAPS.items() for tag in (True, False)}
+    assert dots == {("none", True): 1, ("none", False): 1,
+                    ("bare", True): 2, ("bare", False): 2,
+                    ("kept", True): 1, ("kept", False): 2}
+
+
+def test_the_value_the_op_kept_holds_is_the_first_forwards_bit_for_bit():
+    """The residuals jax holds across the rematerialised layer include the
+    bf16 [.., 48] array W1's product returned in the same run, bit for bit;
+    untagged, and under the bare checkpoint, none of that shape is held;
+    loss and gradients are the untagged layer's and no checkpoint's."""
+    args = _mlp_inputs(1)
+    _, vjp, first = jax.vjp(compiler.rematerialised(
+        _mlp(True, hand_out=True), prevent_cse=False), *args, has_aux=True)
+    assert first.dtype == jnp.bfloat16
+
+    def like(leaves):
+        return [np.asarray(l) for l in jax.tree_util.tree_leaves(leaves)
+                if l.shape == first.shape and l.dtype == first.dtype]
+
+    assert any(np.array_equal(l, first) for l in like(vjp))
+    assert not like(jax.vjp(WRAPS["kept"](_mlp(False)), *args)[1])
+    assert not like(jax.vjp(WRAPS["bare"](_mlp(True)), *args)[1])
+    got = {(name, tag): jax.jit(jax.value_and_grad(
+        wrap(_mlp(tag)), argnums=(0, 1, 2)))(*args)
+        for name, wrap in WRAPS.items() for tag in (True, False)}
+    loss, grads = got["kept", True]
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads)
+    for key, (want_loss, want) in got.items():
+        assert np.array_equal(loss, want_loss), key
+        for g, r in zip(grads, want):
+            np.testing.assert_allclose(g, r, rtol=1e-6, atol=0, err_msg=key)

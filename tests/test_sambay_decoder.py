@@ -10,7 +10,11 @@ vocabulary slices' logits against the uncut model's; every wrong rule
 tools/sambay_reference_probe.py holds the chip's first step to, refused at
 the rehearsal's limits; the flash kernels at head 64 reading values 128
 wide; and the step as it lowers for a TPU (one scan forward and one
-backward a Mamba layer, no score array)."""
+backward a Mamba layer, no score array); and the MLPs' first product, which
+`layers.kept` holds through a layer's recomputation: the untagged program's
+loss and gradients bit for bit, one [B, S, 2 d_inner] product a layer in the
+TPU's step where the untagged one has two, no operation without
+recomputation, bf16 kept bf16."""
 
 import functools
 import importlib
@@ -382,6 +386,15 @@ def test_flash_attention_at_head_64_reading_values_128_wide(shape):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+def _first_products(text, S, width) -> int:
+    """dot_generals of a step's StableHLO whose result is the tokens by
+    `width`: with `width` 2 d_inner W1's forward product and every
+    recomputation of it (dW1's result is [d_model, 2 d_inner], dX's
+    [B, S, d_model])."""
+    return len(re.findall(
+        rf"stablehlo\.dot_general.*-> tensor<1x{S}x{width}x\w+>", text))
+
+
 def test_the_step_as_it_lowers_for_a_tpu():
     """At channels that tile (d 512: 1024 channels, 8 heads of 64 over 4)
     and S 384: the selective scan's kernel pair once a Mamba layer, forward
@@ -390,12 +403,15 @@ def test_the_step_as_it_lowers_for_a_tpu():
     [S, S] array and no state a token anywhere in the step."""
     S = 384
     cfg = models.SambaYDecoderConfig(
-        vocab_size=64, max_length=S, d_model=512, d_inner=256, n_head=8,
+        vocab_size=64, max_length=S, d_model=512, d_inner=320, n_head=8,
         n_kv_head=4, sliding_window=128, dt_rank=8)
     text, spans = _step_for_the_tpu(
         models.sambay_decoder, cfg,
         span_names=("ssm.lower", "attn.lower", "shared.lower",
                     "recurrence.lower"))
+    # the scan's y and starts, two flash sites' out and lse, and W1's output
+    assert [s["kept"] for s in spans["recurrence.lower"]] == [3, 5, 3, 5, 1, 5]
+    assert _first_products(text, S, 2 * cfg.d_inner) == cfg.n_layer
     calls = _kernels(text)
     assert calls["_fwd_kernel"] == 2 and calls["_bwd_kernel"] == 2
     assert calls["_band_kernel"] == 2 and calls["_band_bwd_kernel"] == 2
@@ -409,3 +425,129 @@ def test_the_step_as_it_lowers_for_a_tpu():
     assert not re.search(rf"tensor<[0-9x]*{S}x{S}x", text)
     assert not re.search(rf"tensor<[0-9x]*{S}x1024x16x", text)
     assert not re.search(rf"tensor<[0-9x]*{S}x16x1024x", text)
+
+
+# ---------------------------------------------------------------------------
+# the MLP's first product survives its layer's recomputation (layers.kept)
+# ---------------------------------------------------------------------------
+def _untagged(build, *args, **over):
+    """`build` with `layers.kept` the identity function: the program before
+    the tag."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sambay.layers, "kept", lambda x: x)
+        return build(*args, **over)
+
+
+def _loss_and_gradients(**over):
+    """The loss and every parameter's gradient of the tiny program, through
+    the Executor's own compiled block, with the CPU compiler's fusion off:
+    fused, a multiply-add contracts or not by where a fusion ends, and the
+    fusions of two steps that differ in one product a layer end in
+    different places (84 of 174 values then differ in their last bit)."""
+    fluid.reset_default_env()
+    spec = models.sambay_decoder(models.SambaYDecoderConfig(
+        **{**TINY, **over}))
+    pairs = fluid.append_backward(spec.loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    compiled, *args = exe.capture_program(
+        fluid.default_main_program(), feed=spec.synthetic_batch(2, seed=5),
+        fetch_list=[spec.loss] + [g for _, g in pairs])
+    got = jax.jit(compiled.raw_fn).lower(*args).compile(compiler_options={
+        "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"})(*args)
+    kept = sum(op.type == "kept" for b in spec.loss.block.program.blocks
+               for op in b.desc.ops)
+    return kept, [np.asarray(x) for x in jax.tree_util.tree_leaves(got)]
+
+
+@pytest.mark.parametrize("recompute", [True, False])
+def test_the_tagged_programs_loss_and_gradients_are_the_untagged_ones(
+        recompute):
+    """The kept value is the first forward's own output, which the
+    recomputation would have made again from the same operands: the loss
+    and every parameter's gradient bit for bit, with the units recomputed
+    and not."""
+    tags, got = _loss_and_gradients(use_recompute=recompute)
+    none, want = _untagged(_loss_and_gradients, use_recompute=recompute)
+    assert (tags, none) == (6, 0)
+    assert len(got) == len(want) > 80
+    assert sum(float(np.abs(x).max()) > 0 for x in got) > 80
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+
+
+@once_a_program
+def _tiny_step(tagged, recompute):
+    """The tiny model's step as it lowers for a TPU (the scan and the flash
+    sites on their jax.numpy engines at these widths), with the tag or
+    without it."""
+    cfg = models.SambaYDecoderConfig(**{**TINY, "use_recompute": recompute})
+    lower = _step_for_the_tpu if tagged else functools.partial(
+        _untagged, _step_for_the_tpu)
+    return lower(models.sambay_decoder, cfg, span_names=("recurrence.lower",))
+
+
+def test_w1s_product_is_lowered_once_a_layer_where_the_untagged_step_has_two():
+    S, width = TINY["max_length"], 2 * TINY["d_inner"]
+    (text, spans), (bare, bare_spans) = _tiny_step(True, True), \
+        _tiny_step(False, True)
+    assert _first_products(text, S, width) == 6
+    assert _first_products(bare, S, width) == 12
+    assert [s["kept"] - b["kept"] for s, b in zip(
+        spans["recurrence.lower"], bare_spans["recurrence.lower"])] == [1] * 6
+    assert all(s["recompute"] == 1 for s in spans["recurrence.lower"])
+    # what the unit saves is the bf16 the product wrote (the AMP keep tier
+    # of a step for a TPU), and nothing of the width is made in fp32
+    assert re.search(rf"-> tensor<1x{S}x{width}xbf16>", text)
+    assert not re.search(rf"tensor<1x{S}x{width}xf32>", text)
+
+
+def test_without_recompute_the_tag_adds_no_operation():
+    """use_recompute false: the tag is counted (`kept` one higher a layer)
+    and there is no checkpoint for it to speak to: the step's StableHLO is
+    the untagged step's, operation for operation."""
+    (text, spans), (bare, bare_spans) = _tiny_step(True, False), \
+        _tiny_step(False, False)
+    assert [(s["recompute"], s["kept"] - b["kept"]) for s, b in zip(
+        spans["recurrence.lower"], bare_spans["recurrence.lower"])] \
+        == [(0, 1)] * 6
+    # (a private function's number goes by what was traced before it)
+    numbers = re.compile(r"@(\w+?)_\d+\b")
+    assert numbers.sub(r"@\1", text) == numbers.sub(r"@\1", bare)
+
+
+@pytest.mark.parametrize("tier", ["keep", "mxu", "off"])
+def test_the_op_kept_hands_its_input_on_in_its_own_dtype(tier):
+    """`layers.kept` behind a product under each AMP tier: the product's
+    own output, bit for bit and in its dtype (bf16 on the keep tier, the
+    cell's), and the gradient of what it read."""
+    from paddle_tpu import layers
+    from paddle_tpu.core import amp
+
+    r = np.random.RandomState(3)
+    feed = {"x": r.randn(2, 8, 16).astype(np.float32),
+            "w": r.randn(16, 24).astype(np.float32)}
+
+    def run(tagged):
+        fluid.reset_default_env()
+        x, w = (layers.data(n, list(v.shape), dtype="float32",
+                            append_batch_size=False) for n, v in feed.items())
+        x.stop_gradient = w.stop_gradient = False
+        y = layers.matmul(x, w)
+        y = layers.kept(y) if tagged else y
+        loss = layers.reduce_sum(layers.square(y))
+        return fluid.Executor(fluid.CPUPlace()).run(
+            feed=feed, fetch_list=[y] + list(fluid.calc_gradient(
+                loss, [x, w])), return_numpy=False)
+
+    if tier != "off":
+        amp.enable_amp("bfloat16", keep_output=(tier == "keep"))
+    try:
+        got, want = run(True), run(False)
+    finally:
+        amp.reset_amp()
+    assert jnp.asarray(got[0]).dtype == (
+        jnp.bfloat16 if tier == "keep" else jnp.float32)
+    for a, b in zip(got, want):
+        a, b = jnp.asarray(a), jnp.asarray(b)
+        assert a.dtype == b.dtype and np.array_equal(a, b)
