@@ -38,6 +38,7 @@ __all__ = [
     "kda_gated_norm",
     "mhc_streams",
     "mhc_maps",
+    "mhc_maps_read",
     "mhc_read",
     "mhc_write",
     "sparse_attention",
@@ -1563,6 +1564,21 @@ def mhc_streams(x, streams, name=None):
     return out
 
 
+def _maps_op(helper, outputs, x, phi, a_pre, a_post, a_res, b_pre, b_post,
+             b_res, sinkhorn_iters, epsilon, hc_eps, clamp_min, clamp_max):
+    helper.append_op(
+        type=helper.layer_type,
+        inputs={"X": [x], "Phi": [phi], "APre": [a_pre], "APost": [a_post],
+                "ARes": [a_res], "BPre": [b_pre], "BPost": [b_post],
+                "BRes": [b_res]},
+        outputs=outputs,
+        attrs={"sinkhorn_iters": int(sinkhorn_iters),
+               "epsilon": float(epsilon), "hc_eps": float(hc_eps),
+               "clamp_min": float(clamp_min),
+               "clamp_max": float(clamp_max)},
+    )
+
+
 def mhc_maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
              sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6, clamp_min=-30.0,
              clamp_max=30.0, name=None):
@@ -1580,26 +1596,37 @@ def mhc_maps(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
     jax.numpy elsewhere: the CPU, a mesh of several devices)."""
     helper = LayerHelper("mhc_maps", input=x, name=name)
     out = helper.create_variable_for_type_inference("float32")
-    helper.append_op(
-        type="mhc_maps",
-        inputs={"X": [x], "Phi": [phi], "APre": [a_pre], "APost": [a_post],
-                "ARes": [a_res], "BPre": [b_pre], "BPost": [b_post],
-                "BRes": [b_res]},
-        outputs={"H": [out]},
-        attrs={"sinkhorn_iters": int(sinkhorn_iters),
-               "epsilon": float(epsilon), "hc_eps": float(hc_eps),
-               "clamp_min": float(clamp_min),
-               "clamp_max": float(clamp_max)},
-    )
+    _maps_op(helper, {"H": [out]}, x, phi, a_pre, a_post, a_res, b_pre,
+             b_post, b_res, sinkhorn_iters, epsilon, hc_eps, clamp_min,
+             clamp_max)
     return out
+
+
+def mhc_maps_read(x, phi, a_pre, a_post, a_res, b_pre, b_post, b_res,
+                  sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6,
+                  clamp_min=-30.0, clamp_max=30.0, name=None):
+    """mhc_maps and mhc_read of one sublayer as one op: returns (H [B, 2n +
+    n^2, S] fp32 as mhc_maps gives it, sum_j H_pre[j] x[j] [B, S, C] as
+    mhc_read does under it), the two ops' arithmetic exactly.  The streams'
+    gradient through both leaves as one value (TPU-native;
+    ops/hyper_connection_ops.py mhc_maps_read: for ONE TPU one Pallas
+    kernel pair of kernels/mhc.py where the shape tiles, whose forward
+    reads the streams once where a tile of rows x all n C channels fits
+    VMEM; the two ops' jax.numpy forms elsewhere)."""
+    helper = LayerHelper("mhc_maps_read", input=x, name=name)
+    h = helper.create_variable_for_type_inference("float32")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    _maps_op(helper, {"H": [h], "Out": [out]}, x, phi, a_pre, a_post, a_res,
+             b_pre, b_post, b_res, sinkhorn_iters, epsilon, hc_eps, clamp_min,
+             clamp_max)
+    return h, out
 
 
 def mhc_read(x, h, name=None):
     """What a sublayer reads of the streams x [B, S, n, C] under mhc_maps'
     h: sum_j H_pre[j] x[j], [B, S, C] (TPU-native;
-    ops/hyper_connection_ops.py mhc_read; the engine as mhc_maps': a
-    Pallas kernel pair of kernels/mhc.py for one TPU where the shape
-    tiles, jax.numpy elsewhere)."""
+    ops/hyper_connection_ops.py mhc_read, in jax.numpy everywhere: a model
+    that reads under maps it has just made takes mhc_maps_read)."""
     helper = LayerHelper("mhc_read", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(type="mhc_read", inputs={"X": [x], "H": [h]},

@@ -128,7 +128,7 @@ class _HyperBuilder(_ExpertBuilder):
         cfg = self.cfg
         n = cfg.hc_mult
         near_identity = cfg.hc_res_diag_init * np.eye(n)
-        h = layers.mhc_maps(
+        h, x_in = layers.mhc_maps_read(
             streams,
             self.param([n * cfg.d_model, 2 * n + n * n], f"{name}_phi"),
             *(self.constant([1], f"{name}_a_{m}", cfg.hc_alpha_init)
@@ -141,7 +141,7 @@ class _HyperBuilder(_ExpertBuilder):
             sinkhorn_iters=cfg.hc_sinkhorn_iters, epsilon=cfg.rms_norm_eps,
             hc_eps=cfg.hc_eps, clamp_min=cfg.hc_clamp_min,
             clamp_max=cfg.hc_clamp_max)
-        y, *rest = sublayer(layers.mhc_read(streams, h))
+        y, *rest = sublayer(x_in)
         return (layers.mhc_write(streams, h, y), *rest)
 
     def layer(self, streams, i):

@@ -16,33 +16,44 @@ Per token, u = vec(X) in R^{nC}, everything of the maps in fp32:
     x_in   = sum_j H_pre[j] X[j];   y = F(x_in)
     X'[i]  = sum_j H_res[i, j] X[j] + H_post[i] y
 
-Four ops: `mhc_streams` (a value copied to the n streams), `mhc_maps` (the
+Five ops: `mhc_streams` (a value copied to the n streams), `mhc_maps` (the
 2n + n^2 map values a token, WITH THE TOKENS ON THE LANE AXIS: H [B, 2n +
 n^2, S], rows 0:n H_pre, n:2n H_post, then H_res row by row; a minor axis
 of n would fill n of 128 lanes through 2 x `sinkhorn_iters`
-normalisations and their backward), `mhc_read` (x_in) and `mhc_write`
-(X').  `maps`, `read` and `write` below are the arithmetic, in jax.numpy.
+normalisations and their backward), `mhc_read` (x_in), `mhc_maps_read`
+(H and x_in under it: the two ops as ONE, with one backward, which is what
+a sublayer emits: the streams' gradient through the maps and through the
+read is then one value made in one pass, and the backward reads the
+streams twice where the two ops' read them three times) and `mhc_write`
+(X').  `maps`, `read`, `maps_read` and `write` below are the arithmetic,
+in jax.numpy.
 One algorithm, its engine read from the site (`_site`): for ONE TPU, where
-the shape tiles (kernels/mhc.py::maps_tiles / ::mix_tiles: four streams, C
-whole 128-lane vectors, S whole tiles of rows, streams of one dtype, the
-working set inside the VMEM budget), each of the three ops is a
-Pallas kernel pair over tiles of rows whose backward makes the tile's
-forward again from the op's inputs; anywhere else (the CPU, a mesh of
-several devices, a shape that does not tile) the jax.numpy form with
-gradients by the compiler's jax.vjp, the op's arithmetic under
-jax.checkpoint.  Either way what a backward keeps is the op's inputs (the
-streams at their own element size) and never an fp32 copy of the streams.
+the shape tiles (kernels/mhc.py::maps_tiles / ::maps_read_tiles /
+::mix_tiles: four streams, C whole 128-lane vectors, S whole tiles of
+rows, streams of one dtype, the working set inside the VMEM budget), each
+of `mhc_maps`, `mhc_maps_read` and `mhc_write` is a Pallas kernel pair
+over tiles of rows whose backward makes the tile's forward again from the
+op's inputs; anywhere else (the CPU, a mesh of several devices, a shape
+that does not tile) the jax.numpy form with gradients by the compiler's
+jax.vjp, the op's arithmetic under jax.checkpoint, which is what
+`mhc_read` alone runs everywhere.  Either way what a backward keeps is the
+op's inputs (the streams at their own element size) and never an fp32 copy
+of the streams.
 Name scopes:
 `mhc.maps` (the RMS, the [T, nC] x [nC, 2n + n^2] product, the
-activations, Sinkhorn) and `mhc.mix` (the read and the write).
-`mhc.lower` (a span, at lowering, one a `mhc_maps` op, which is one a
-sublayer) says `streams`, `sinkhorn_iters`, `sublayers` (1: a reader adds
-them up) and `moved_bytes`, what a sublayer's maps and mixing have to move
-through HBM whatever implements them (`moved_bytes`).  `mhc.kernel.lower`
-(a span, at lowering, one an op site, three a sublayer) says what the site
-was given: `what` (maps | read | write), `engine` (pallas | xla), `rows`
-and `channels` of a grid step and the `fwd_vmem_bytes` / `bwd_vmem_bytes`
-of its working sets (0 under xla).
+activations, Sinkhorn, and `mhc_maps_read`'s read) and `mhc.mix` (the
+write, and `mhc_read` alone).
+`mhc.lower` (a span, at lowering, one a `mhc_maps_read` or `mhc_maps` op,
+which is one a sublayer) says `streams`, `sinkhorn_iters`, `sublayers` (1:
+a reader adds them up) and `moved_bytes`, what a sublayer's maps and
+mixing have to move through HBM whatever implements them (`moved_bytes`).
+`mhc.kernel.lower` (a span, at lowering, one a site that has kernels, two
+a sublayer) says what the site was given: `what` (maps_read | maps |
+write), `engine` (pallas | xla), `rows` and `channels` of a grid step and
+the `fwd_vmem_bytes` / `bwd_vmem_bytes` of its working sets (0 under xla),
+and of a maps_read site `resident` (1 where the forward holds a tile of
+rows x all n C channels and reads the streams once, 0 where it is the
+maps' kernel and then the read's, or under xla).
 """
 
 from __future__ import annotations
@@ -124,15 +135,16 @@ def write(x, h, y):
         for i in range(n)], axis=2).astype(out)
 
 
-def _site(ctx, what, plan, pair, form, *args):
+def _site(ctx, what, tiles, plan, pair, form, *args):
     """One site of `what` under the span `mhc.kernel.lower`
-    (kernels/engine.py::site): kernels/mhc.py's `pair` on `args` where
-    `plan()` tiles the streams, `form`, the op's arithmetic, under
-    jax.checkpoint where it does not (module docstring)."""
-    from ..kernels import engine, mhc
+    (kernels/engine.py::site), which says the fields of `tiles`
+    (kernels/mhc.py's Tiles or FusedTiles): kernels/mhc.py's `pair` on
+    `args` where `plan()` tiles the streams, `form`, the op's arithmetic,
+    under jax.checkpoint where it does not (module docstring)."""
+    from ..kernels import engine
 
     return engine.site(
-        "mhc.kernel.lower", mhc.Tiles._fields, ctx.mesh, plan,
+        "mhc.kernel.lower", tiles._fields, ctx.mesh, plan,
         lambda tiles, interpret: pair(*args, tiles, interpret),
         lambda: jax.checkpoint(form)(*args), what=what)
 
@@ -165,9 +177,35 @@ def _maps_infer(op, block):
         set_output(block, op, "H", [B, 2 * n + n * n, S], DataType.FP32)
 
 
-@register_op("mhc_maps", infer_shape=_maps_infer,
-             diff_inputs=["X", "Phi", "APre", "APost", "ARes", "BPre",
-                          "BPost", "BRes"])
+_MAPS_INPUTS = ["X", "Phi", "APre", "APost", "ARes", "BPre", "BPost", "BRes"]
+
+
+def _maps_site(ctx, ins, attrs, what, tiles, plan, pair, form):
+    """A `mhc_maps` or `mhc_maps_read` site (`what`) under the name scope
+    `mhc.maps` and the span `mhc.lower`: kernels/mhc.py's `pair` where
+    `plan(S, n, C, iters, dtype)` tiles the streams (its `tiles`), `form(x,
+    phi, *small, **cfg)` where it does not."""
+    from ..kernels import engine
+
+    x, phi, *small = (data(ins[s][0]) for s in _MAPS_INPUTS)
+    B, S, n, C = x.shape
+    iters = int(attrs["sinkhorn_iters"])
+    with span("mhc.lower", streams=int(n), sinkhorn_iters=iters, sublayers=1,
+              moved_bytes=moved_bytes(B * S, n, C, x.dtype.itemsize,
+                                      phi.size * phi.dtype.itemsize)), \
+            jax.named_scope("mhc.maps"):
+        cfg = dict(epsilon=float(attrs.get("epsilon", 1e-6)),
+                   hc_eps=float(attrs.get("hc_eps", 1e-6)), iters=iters,
+                   clamp=(float(attrs.get("clamp_min", -30.0)),
+                          float(attrs.get("clamp_max", 30.0))))
+        return _site(
+            ctx, what, tiles, lambda: plan(S, n, C, iters, x.dtype)
+            if engine.one_dtype(x) else None,
+            functools.partial(pair, **cfg), functools.partial(form, **cfg),
+            x, phi, *small)
+
+
+@register_op("mhc_maps", infer_shape=_maps_infer, diff_inputs=_MAPS_INPUTS)
 def _mhc_maps(ctx, ins, attrs):
     """The three maps of one sublayer from the streams X [B, S, n, C], Phi
     [nC, 2n + n^2], the scalars APre, APost, ARes [1] and the biases BPre,
@@ -178,27 +216,16 @@ def _mhc_maps(ctx, ins, attrs):
     `mhc.lower` is this op's.  The engine is read from the site (module
     docstring; kernels/mhc.py::maps): `mhc.kernel.lower` with `what`
     maps."""
-    from ..kernels import engine, mhc
+    from ..kernels import mhc
 
-    x, phi = data(ins["X"][0]), data(ins["Phi"][0])
-    B, S, n, C = x.shape
-    iters = int(attrs["sinkhorn_iters"])
-    small = [data(ins[s][0]) for s in (
-        "APre", "APost", "ARes", "BPre", "BPost", "BRes")]
-    with span("mhc.lower", streams=int(n), sinkhorn_iters=iters, sublayers=1,
-              moved_bytes=moved_bytes(B * S, n, C, x.dtype.itemsize,
-                                      phi.size * phi.dtype.itemsize)), \
-            jax.named_scope("mhc.maps"):
-        cfg = dict(epsilon=float(attrs.get("epsilon", 1e-6)),
-                   hc_eps=float(attrs.get("hc_eps", 1e-6)), iters=iters,
-                   clamp=(float(attrs.get("clamp_min", -30.0)),
-                          float(attrs.get("clamp_max", 30.0))))
-        h = _site(
-            ctx, "maps", lambda: mhc.maps_tiles(S, n, C, iters, x.dtype)
-            if engine.one_dtype(x) else None,
-            functools.partial(mhc.maps, **cfg), functools.partial(maps, **cfg),
-            x, phi, *small)
-    return {"H": [h]}
+    return {"H": [_maps_site(ctx, ins, attrs, "maps", mhc.Tiles,
+                             mhc.maps_tiles, mhc.maps, maps)]}
+
+
+def maps_read(x, *small, **cfg):
+    """(`maps`' H, `read`'s x_in under it): the two, one after the other."""
+    h = maps(x, *small, **cfg)
+    return h, read(x, h)
 
 
 def _read_infer(op, block):
@@ -208,20 +235,37 @@ def _read_infer(op, block):
                    x.dtype)
 
 
+def _maps_read_infer(op, block):
+    _maps_infer(op, block)
+    _read_infer(op, block)
+
+
+@register_op("mhc_maps_read", infer_shape=_maps_read_infer,
+             diff_inputs=_MAPS_INPUTS)
+def _mhc_maps_read(ctx, ins, attrs):
+    """`mhc_maps` and `mhc_read` of one sublayer as ONE op with one
+    backward: mhc_maps' inputs and attributes, H [B, 2n + n^2, S] fp32 and
+    Out [B, S, C] = sum_j H_pre[j] X[j], the two ops' arithmetic exactly.
+    The streams' gradient through the maps and through the read leaves as
+    one value made in one pass.  Under the name scope `mhc.maps`, with the
+    span `mhc.lower` as mhc_maps has it; `mhc.kernel.lower` with `what`
+    maps_read and `resident` (kernels/mhc.py::maps_read)."""
+    from ..kernels import mhc
+
+    h, out = _maps_site(ctx, ins, attrs, "maps_read", mhc.FusedTiles,
+                        mhc.maps_read_tiles, mhc.maps_read, maps_read)
+    return {"H": [h], "Out": [out]}
+
+
 @register_op("mhc_read", infer_shape=_read_infer, diff_inputs=["X", "H"])
 def _mhc_read(ctx, ins, attrs):
     """What a sublayer reads of the streams X [B, S, n, C] under the maps
     H of `mhc_maps`: Out [B, S, C] = sum_j H_pre[j] X[j].  Under the name
-    scope `mhc.mix`; the engine as mhc_maps reads it (kernels/mhc.py::read),
-    `mhc.kernel.lower` with `what` read."""
-    from ..kernels import engine, mhc
-
+    scope `mhc.mix`; the jax.numpy form under jax.checkpoint on every
+    site (the kernels that read are `mhc_maps_read`'s)."""
     x, h = data(ins["X"][0]), data(ins["H"][0])
-    B, S, n, C = x.shape
     with jax.named_scope("mhc.mix"):
-        return {"Out": [_site(
-            ctx, "read", lambda: mhc.mix_tiles(S, n, C, x.dtype, "read")
-            if engine.one_dtype(x) else None, mhc.read, read, x, h)]}
+        return {"Out": [jax.checkpoint(read)(x, h)]}
 
 
 @register_op("mhc_write", infer_shape=same_shape("X", "Out"),
@@ -238,5 +282,6 @@ def _mhc_write(ctx, ins, attrs):
     B, S, n, C = x.shape
     with jax.named_scope("mhc.mix"):
         return {"Out": [_site(
-            ctx, "write", lambda: mhc.mix_tiles(S, n, C, x.dtype, "write")
+            ctx, "write", mhc.Tiles,
+            lambda: mhc.mix_tiles(S, n, C, x.dtype, "write")
             if engine.one_dtype(x, y) else None, mhc.write, write, x, h, y)]}

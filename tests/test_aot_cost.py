@@ -367,12 +367,14 @@ def _kda_mix(backward):
         argnums=tuple(range(13))), args
 
 
-def _mhc(backward):
+def _mhc(backward, resident=1):
     """xing4.0-29b-a4b's hyper-connection at the cell's shape (four streams
     of 3584, S 4096, 20 Sinkhorn iterations, bf16 streams): kernels/mhc.py's
-    three forward kernels at the planned tiles, and with them the four
-    backward (the outputs weighted by themselves, so that the forwards
-    stay in the program)."""
+    forward kernels at the planned tiles (the fused maps-and-read, whose
+    forward holds the tile as the planner has it, `resident` 1, or streams
+    through the maps' kernel and `read`'s, 0; the write), and with them the
+    three backward (the outputs weighted by themselves, so that the
+    forwards stay in the program)."""
     from paddle_tpu.kernels import mhc
 
     S, n, C, N = 4096, 4, 3584, 24
@@ -383,13 +385,13 @@ def _mhc(backward):
             _sds((n, n), f32))
 
     def fwd(x, y, *small):
-        maps = mhc.maps_tiles(S, n, C, 20, x.dtype)
-        read, write = (mhc.mix_tiles(S, n, C, x.dtype, what)
-                       for what in ("read", "write"))
-        assert None not in (maps, read, write)
-        h = mhc.maps(x, *small, maps, epsilon=1e-6, hc_eps=1e-6, iters=20,
-                     clamp=(-30.0, 30.0))
-        return h, mhc.read(x, h, read), mhc.write(x, h, y, write)
+        planned = mhc.maps_read_tiles(S, n, C, 20, x.dtype)
+        fused = mhc.maps_read_tiles(S, n, C, 20, x.dtype, resident=resident)
+        write = mhc.mix_tiles(S, n, C, x.dtype, "write")
+        assert None not in (fused, write) and planned.resident == 1
+        h, x_in = mhc.maps_read(x, *small, fused, epsilon=1e-6, hc_eps=1e-6,
+                                iters=20, clamp=(-30.0, 30.0))
+        return h, x_in, mhc.write(x, h, y, write)
 
     if not backward:
         return fwd, args
@@ -401,6 +403,7 @@ def _mhc(backward):
 _MAIN_PATH_KERNELS = {
     "mhc_fwd_xing": lambda: _mhc(False),
     "mhc_bwd_pallas_xing": lambda: _mhc(True),
+    "mhc_bwd_pallas_streamed_xing": lambda: _mhc(True, resident=0),
     "kda_mix_fwd_kimi": lambda: _kda_mix(False),
     "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
     "ssm_scan_fwd_sambay": lambda: _ssm_scan(False),
@@ -443,8 +446,10 @@ def test_main_path_kernel_compiles_for_v5e(v5e, case):
     n = text.count("tpu_custom_call")
     # Pallas backward: the forward kernel (for its residuals) + the backward
     assert n >= (2 if "bwd_pallas" in case else 1), (case, n)
-    if case.startswith("mhc"):   # three forwards; + the backwards' four
-        assert n == (7 if "bwd" in case else 3), (case, n)
+    if case.startswith("mhc"):   # two forwards (three streamed); + the
+        # backwards' three: the read has no backward kernel of its own
+        assert n == (2 + ("streamed" in case)
+                     + (3 if "bwd" in case else 0)), (case, n)
     if "flash_band" in case:    # ONE backward call, no chunks
         assert n == 2, (case, n)
     if "bwd_xla" in case:

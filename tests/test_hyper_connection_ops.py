@@ -1,6 +1,6 @@
 """The ops of manifold-constrained hyper-connections
-(ops/hyper_connection_ops.py: mhc_streams, mhc_maps, mhc_read, mhc_write)
-against the equations as the plain reference of xing4.0-29b-a4b writes them
+(ops/hyper_connection_ops.py: mhc_streams, mhc_maps_read, mhc_maps,
+mhc_read, mhc_write) against the equations as the plain reference of xing4.0-29b-a4b writes them
 (a token's maps [n] and [n, n], its own Sinkhorn loop), forward and every
 gradient by name; what Sinkhorn-Knopp leaves after 20 iterations and after
 2; the clamp; the layout of the maps; the span; and latent_attention's
@@ -65,19 +65,25 @@ def _reference_sublayer(p, x, w_f, cfg):
     return REFERENCE._write(flat, maps, y).reshape(x.shape), maps
 
 
-def _program_sublayer(p, x, w_f, iters=20):
+def _program_sublayer(p, x, w_f, iters=20, fused=True):
     """(X', H, the gradient of sum(X' * weight) by name) of the same
-    sublayer as a fluid program through the Executor."""
+    sublayer as a fluid program through the Executor: its maps and its read
+    as the one op `mhc_maps_read` (`fused`, the decoder's form) or as
+    `mhc_maps` and `mhc_read`."""
     fluid.reset_default_env()
     B, S, n, C = x.shape
     params = {k: layers.create_parameter(
         list(v.shape), "float32", attr=ParamAttr(
             name=k, initializer=NumpyArrayInitializer(v)))
         for k, v in {**p, "x": x, "w_f": w_f}.items()}
-    h = layers.mhc_maps(params["x"], *(params[k] for k in NAMES),
-                        sinkhorn_iters=iters)
-    y = layers.tanh(layers.matmul(layers.mhc_read(params["x"], h),
-                                  params["w_f"]))
+    small = [params[k] for k in NAMES]
+    if fused:
+        h, x_in = layers.mhc_maps_read(params["x"], *small,
+                                       sinkhorn_iters=iters)
+    else:
+        h = layers.mhc_maps(params["x"], *small, sinkhorn_iters=iters)
+        x_in = layers.mhc_read(params["x"], h)
+    y = layers.tanh(layers.matmul(x_in, params["w_f"]))
     out = layers.mhc_write(params["x"], h, y)
     weight = np.random.RandomState(7).randn(*x.shape).astype(np.float32)
     loss = layers.reduce_sum(layers.elementwise_mul(
@@ -90,15 +96,18 @@ def _program_sublayer(p, x, w_f, iters=20):
     return np.asarray(got[0]), np.asarray(got[1]), grads, weight
 
 
-@pytest.mark.parametrize("B,S,n,C", [(2, 6, 4, 16), (1, 5, 2, 8),
-                                     (1, 3, 3, 8)])
-def test_the_ops_are_the_equations_forward_and_every_gradient(B, S, n, C):
+@pytest.mark.parametrize("B,S,n,C,fused", [
+    (2, 6, 4, 16, True), (1, 5, 2, 8, True), (1, 3, 3, 8, True),
+    (2, 6, 4, 16, False)])
+def test_the_ops_are_the_equations_forward_and_every_gradient(B, S, n, C,
+                                                              fused):
     """X' and the gradients of Phi, the three scalars, the three biases,
-    the streams and the sublayer's weight, each by its name."""
+    the streams and the sublayer's weight, each by its name; the maps and
+    the read as one op and as two."""
     p, x = _values(B, S, n, C, seed=n + C)
     w_f = (np.random.RandomState(3).randn(C, C) / math.sqrt(C)).astype(
         np.float32)
-    out, h, grads, weight = _program_sublayer(p, x, w_f)
+    out, h, grads, weight = _program_sublayer(p, x, w_f, fused=fused)
 
     def total(p, x, w_f):
         return jnp.sum(_reference_sublayer(p, x, w_f, _ref_cfg())[0]
@@ -180,9 +189,10 @@ def test_maps_in_bf16_are_not_the_maps():
 
 
 def test_the_streams_start_as_copies_and_the_span_says_what_a_site_moves():
-    """mhc_streams copies; one mhc.lower a mhc_maps op with the streams,
-    the iterations, one sublayer and moved_bytes = 5 passes over the
-    streams + 4 over a [T, C] value + Phi once."""
+    """mhc_streams copies; one mhc.lower a mhc_maps_read or mhc_maps op
+    with the streams, the iterations, one sublayer and moved_bytes = 5
+    passes over the streams + 4 over a [T, C] value + Phi once; the fused
+    op's H is mhc_maps' and its x_in is mhc_read's under it."""
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
         fluid.reset_default_env()
@@ -190,10 +200,12 @@ def test_the_streams_start_as_copies_and_the_span_says_what_a_site_moves():
         p, x = _values(2, 6, 4, 16, seed=5)
         e = layers.assign(x[:, :, 0])
         streams = layers.mhc_streams(e, 4)
-        h = layers.mhc_maps(streams, *(layers.assign(p[k]) for k in NAMES),
-                            sinkhorn_iters=7)
+        small = [layers.assign(p[k]) for k in NAMES]
+        h = layers.mhc_maps(streams, *small, sinkhorn_iters=7)
+        both = layers.mhc_maps_read(streams, *small, sinkhorn_iters=7)
         exe = fluid.Executor(fluid.CPUPlace())
-        got, maps = exe.run(fetch_list=[streams, h])
+        got, maps, x_in, maps_too, x_in_too = exe.run(fetch_list=[
+            streams, h, layers.mhc_read(streams, h), *both])
         spans = [dict(s.args) for s in
                  observability.default_tracer().spans()
                  if s.name == "mhc.lower"]
@@ -203,10 +215,12 @@ def test_the_streams_start_as_copies_and_the_span_says_what_a_site_moves():
     assert got.shape == (2, 6, 4, 16)
     for j in range(4):
         np.testing.assert_array_equal(got[:, :, j], x[:, :, 0])
-    assert maps.shape == (2, 24, 6)
+    assert maps.shape == (2, 24, 6) and x_in.shape == (2, 6, 16)
+    np.testing.assert_array_equal(maps_too, maps)
+    np.testing.assert_array_equal(x_in_too, x_in)
     assert spans == [{"streams": 4, "sinkhorn_iters": 7, "sublayers": 1,
                       "moved_bytes": (5 * 4 + 4) * 12 * 16 * 4
-                      + 64 * 24 * 4}]
+                      + 64 * 24 * 4}] * 2
     assert hc.moved_bytes(4096, 4, 3584, 2, 14336 * 24 * 4) == 706019328
 
 

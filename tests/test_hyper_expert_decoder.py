@@ -202,11 +202,13 @@ def test_the_streams_are_one_carry_and_the_held_heads_size_the_maps():
     assert shapes["l1_experts_gate_w"] == (4, 32, 24)
     assert shapes["l1_router_w"] == (32, 16)
     ops = [op for b in program.blocks for op in b.desc.ops]
+    # a sublayer's maps and its read are ONE op: no mhc_maps, no mhc_read
     count = {t: sum(op.type == t for op in ops) for t in (
-        "mhc_streams", "mhc_maps", "mhc_read", "mhc_write", "recurrence",
-        "latent_attention")}
-    assert count == {"mhc_streams": 1, "mhc_maps": 4, "mhc_read": 4,
-                     "mhc_write": 4, "recurrence": 2, "latent_attention": 2}
+        "mhc_streams", "mhc_maps_read", "mhc_maps", "mhc_read", "mhc_write",
+        "recurrence", "latent_attention")}
+    assert count == {"mhc_streams": 1, "mhc_maps_read": 4, "mhc_maps": 0,
+                     "mhc_read": 0, "mhc_write": 4, "recurrence": 2,
+                     "latent_attention": 2}
     for op in ops:
         if op.type == "recurrence":
             assert len(op.attrs["__carry_names__"]) == 1
