@@ -29,6 +29,8 @@ __all__ = [
     "latent_attention",
     "short_conv1d",
     "selective_scan",
+    "ssd_scan",
+    "gated_rms_norm",
     "differential_attention",
     "handed_on",
     "kept",
@@ -1426,6 +1428,40 @@ def selective_scan(x, dt, a, b, c, d, dt_bias=None, name=None):
         inputs["DtBias"] = [dt_bias]
     helper.append_op(type="selective_scan", inputs=inputs,
                      outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def ssd_scan(x, dt, a, b, c, d, dt_bias=None, name=None):
+    """Mamba-2's state-space-dual scan: x [B, S, H, P], dt [B, S, H], a [H]
+    (negative), b, c [B, S, G, N] (head h reads group h // (H / G)), d
+    [H]; a head's P x N state from 0, s_t = exp(dt_t a) s_(t-1) + dt_t x_t
+    (x) b_t, y_t = s_t c_t + d x_t, ONE decay a head a token; with
+    `dt_bias` [H] the step is softplus(dt + dt_bias).  Decays and the
+    state fp32, [B, S, H, P] out in x's dtype; no state a token is ever
+    stored, backward included (TPU-native; ops/state_space_ops.py ssd_scan,
+    kernels/ssd_scan.py)."""
+    helper = LayerHelper("ssd_scan", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x], "Dt": [dt], "A": [a], "B": [b], "C": [c], "D": [d]}
+    if dt_bias is not None:
+        inputs["DtBias"] = [dt_bias]
+    helper.append_op(type="ssd_scan", inputs=inputs,
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def gated_rms_norm(x, gate, scale, groups=1, epsilon=1e-5, name=None):
+    """Mamba-2's output norm: g = x silu(gate), the gate FIRST, then g /
+    sqrt(mean(g^2) + epsilon) over each of `groups` runs of channels times
+    scale [E]; x, gate [B, S, E] (TPU-native; ops/state_space_ops.py
+    gated_rms_norm)."""
+    helper = LayerHelper("gated_rms_norm", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="gated_rms_norm",
+        inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+        outputs={"Out": [out]},
+        attrs={"groups": int(groups), "epsilon": float(epsilon)})
     return out
 
 

@@ -117,7 +117,9 @@ def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads):
 
 def gated_norm(o, gate, gate_bias, scale, heads, eps):
     """The op kda_gated_norm's arithmetic (its docstring), in jax.numpy,
-    in o's dtype."""
+    in o's dtype: the norm a HEAD first, then a sigmoid gate.  Mamba-2's
+    gated norm (the silu gate first, then one mean square over the whole
+    width) is ops/state_space_ops.py::gated_rms_norm."""
     B, S, C = o.shape
     acc = amp.stats_dtype(o)
     x = o.astype(acc).reshape(B, S, heads, C // heads)

@@ -336,6 +336,29 @@ def _ssm_scan(backward):
                     argnums=tuple(range(6))), args
 
 
+def _ssd_scan(backward):
+    """granite-4.0-h-micro's state-space-dual scan at the cell's shape (32
+    held heads of 64 over a 128-state group, S 8192, chunks of 256, bf16
+    streams): kernels/ssd_scan.py's forward kernel at the planned tiles,
+    and with it the backward."""
+    from paddle_tpu.kernels import ssd_scan as ssd
+
+    S, H, P, N = 8192, 32, 64, 128
+    args = (_sds((1, S, H, P), jnp.bfloat16), _sds((1, S, H), jnp.float32),
+            _sds((H,), jnp.float32), _sds((1, S, 1, N), jnp.bfloat16),
+            _sds((1, S, 1, N), jnp.bfloat16), _sds((H,), jnp.float32))
+    tiles = ssd.tiles(S, H, P, N, 1, itemsize=2)
+    assert tiles is not None and (tiles.chunk, tiles.block) == (256, 8)
+
+    def fwd(*a):
+        return ssd.ssd_scan(*a, tiles_=tiles)
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
+                    argnums=tuple(range(6))), args
+
+
 def _kda_mix(backward):
     """kimi-linear-48b-a3b's passes around the chunk scan at the cell's
     shape (32 heads of 128, S 4096, four taps, bf16 streams):
@@ -408,6 +431,8 @@ _MAIN_PATH_KERNELS = {
     "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
     "ssm_scan_fwd_sambay": lambda: _ssm_scan(False),
     "ssm_scan_bwd_pallas_sambay": lambda: _ssm_scan(True),
+    "ssd_scan_fwd_granite": lambda: _ssd_scan(False),
+    "ssd_scan_bwd_pallas_granite": lambda: _ssd_scan(True),
     "kda_scan_fwd_kimi": lambda: _kda_scan(False),
     "kda_scan_bwd_pallas_kimi": lambda: _kda_scan(True),
     "cca_mix_fwd_zaya": lambda: _cca_mix(False),
