@@ -1,6 +1,7 @@
 """ModelSpec: what a model builder hands back to benches/tests, and what
 two or more of the decoder builders share: the layer as a one-trip
-recurrence (the unit of recomputation), a chip's share of a softmax-routed
+recurrence (the unit of recomputation), the gated MLP whose first product
+survives that recomputation, a chip's share of a softmax-routed
 expert block with the scaled initialisation of the residual stream's
 writers, and the packed batch."""
 
@@ -66,6 +67,26 @@ def one_trip_layer(h, body, use_recompute: bool = True,
                 rec.output(value)
         finals = tuple(rec.final(mem) for mem in carried)
     return (finals if many else finals[0]), rec
+
+
+def kept_gated_mlp(builder, x, name):
+    """W2(silu(g) * u), (g, u) = split(W1 x), no bias, under the name scope
+    `mlp`; a builder's `mlp` where its layers are units that really run
+    their recomputation (`prevent_cse`: sambay_decoder, ssd_hybrid_decoder).
+    W1's output [B, S, 2 d_inner] survives it (layers.kept): the backward
+    reads g and u themselves, and the product that makes them is the
+    dearest thing a layer would run twice."""
+    from .. import layers
+    from ..core.framework import name_scope
+
+    cfg = builder.cfg
+    with name_scope("mlp"):
+        gate, up = layers.split(layers.kept(
+            builder.linear(x, cfg.d_model, 2 * cfg.d_inner, f"{name}_1")),
+            2, dim=-1)
+        return builder.linear(
+            layers.elementwise_mul(layers.swish(gate), up),
+            cfg.d_inner, cfg.d_model, f"{name}_2")
 
 
 def packed_batch(vocab_size: int, length: int, batch_size: int, seed: int,

@@ -54,7 +54,7 @@ core/backward.py sums the readers' cotangents into the ONE cotangent the
 producer's unit is differentiated with (tests/test_sambay_decoder.py holds
 all three).  What a layer's recomputation does NOT make again: what the
 kernels keep (the scan's output and chunk starts, a flash site's output and
-logsumexp) and W1's output (layers.kept, `_SambaYBuilder.mlp`).  Name
+logsumexp) and W1's output (layers.kept, common.kept_gated_mlp).  Name
 scopes: `ssm.mix` (the convolution with its SiLU, the step's softplus, the
 gate), `ssm.scan` (the op selective_scan's own),
 `gmu` (the whole mixer), `attn.sliding`, `attn.full`, `attn.cross` (the op
@@ -78,7 +78,8 @@ from ..core.framework import name_scope
 from ..initializer import (Initializer, NormalInitializer,
                            NumpyArrayInitializer, UniformInitializer)
 from ..param_attr import ParamAttr
-from .common import ModelSpec, one_trip_layer, packed_batch
+from .common import (ModelSpec, kept_gated_mlp, one_trip_layer,
+                     packed_batch)
 from .expert_decoder import _ExpertBuilder
 from .looped_decoder import _heads_and_loss
 
@@ -196,19 +197,8 @@ class _SambaYBuilder(_ExpertBuilder):
         return layers.matmul(x, self.param([d_in, d_out], f"{name}_w"),
                              out_dtype="float32")
 
-    def mlp(self, x, name):
-        """W1's output [B, S, 2 d_inner] survives the layer's recomputation
-        (layers.kept): the backward reads g and u themselves, and the
-        product that makes them is the dearest thing a layer would run
-        twice."""
-        cfg = self.cfg
-        with name_scope("mlp"):
-            gate, up = layers.split(layers.kept(
-                self.linear(x, cfg.d_model, 2 * cfg.d_inner, f"{name}_1")),
-                2, dim=-1)
-            return self.linear(
-                layers.elementwise_mul(layers.swish(gate), up),
-                cfg.d_inner, cfg.d_model, f"{name}_2")
+    # W1's output survives the layer's recomputation
+    mlp = kept_gated_mlp
 
     def mamba(self, u, name):
         """(Mamba(u), the scan's output y)."""

@@ -44,11 +44,16 @@ in for them.
 Every layer is a one-trip layers.Recurrence, the unit of recomputation
 (common.one_trip_layer, prevent_cse as sambay_decoder's).  What a layer's
 recomputation does NOT make again is what the kernels keep (the scan's
-output and chunk starts, a flash site's output and logsumexp).  Name
-scopes: `ssd.mix` (the convolution with its SiLU, the step's softplus, the
-gate and the norm), `ssd.scan` (the op ssd_scan's own), `attn.full` (the
-op fused_attention; the projections outside), `mlp`, `loop.heads`.  Spans
-at lowering: `ssd.lower`, `attn.lower`, `flash.plan` / `flash.bwd_plan`.
+output and chunk starts, a flash site's output and logsumexp) and the two
+widest products' outputs, tagged with layers.kept: W1's [B, S, 2 F] in every
+layer (common.kept_gated_mlp, the function sambay_decoder's layers call)
+and a Mamba-2 mixer's in-projection's [B, S, 2 E + 2 G N + H].  The stream
+after the mixer IS made again (the out-projection, for the norm W1 reads):
+kept too it would put the cell's first step at 16.16 of the chip's 16.91 GB.
+Name scopes: `ssd.mix` (the convolution with its SiLU, the step's softplus,
+the gate and the norm), `ssd.scan` (the op ssd_scan's own), `attn.full`
+(the op fused_attention; the projections outside), `mlp`, `loop.heads`.
+Spans at lowering: `ssd.lower`, `attn.lower`, `flash.plan` / `flash.bwd_plan`.
 """
 
 from __future__ import annotations
@@ -62,7 +67,8 @@ from .. import layers
 from ..core.framework import name_scope
 from ..initializer import NumpyArrayInitializer
 from ..param_attr import ParamAttr
-from .common import ModelSpec, one_trip_layer, packed_batch
+from .common import (ModelSpec, kept_gated_mlp, one_trip_layer,
+                     packed_batch)
 from .expert_decoder import _ExpertBuilder
 from .looped_decoder import _heads_and_loss
 from .sambay_decoder import _DT_RANGE, _InverseSoftplusOfLogUniform
@@ -104,23 +110,16 @@ class SsdHybridDecoderConfig:
 
 
 class _SsdHybridBuilder(_ExpertBuilder):
-    def mlp(self, x, name):
-        cfg = self.cfg
-        with name_scope("mlp"):
-            gate, up = layers.split(
-                self.linear(x, cfg.d_model, 2 * cfg.d_inner, f"{name}_1"),
-                2, dim=-1)
-            return self.linear(
-                layers.elementwise_mul(layers.swish(gate), up),
-                cfg.d_inner, cfg.d_model, f"{name}_2")
+    # W1's output survives the layer's recomputation
+    mlp = kept_gated_mlp
 
     def mamba(self, u, name):
         cfg = self.cfg
         H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state,
                       cfg.n_groups)
         E, taps = H * P, cfg.d_conv
-        z, xbc, dt = layers.split(
-            self.linear(u, cfg.d_model, 2 * E + 2 * G * N + H, f"{name}_in"),
+        z, xbc, dt = layers.split(layers.kept(
+            self.linear(u, cfg.d_model, 2 * E + 2 * G * N + H, f"{name}_in")),
             [E, E + 2 * G * N, H], dim=-1)
         with name_scope("ssd.mix"):
             xbc = layers.short_conv1d(

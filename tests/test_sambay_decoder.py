@@ -35,7 +35,10 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 import paddle_tpu as fluid
 from benchmark.harness import manifest
 from benchmark.harness import reference as harness_reference
-from decoder_steps import as_one_compile, once_a_program
+from decoder_steps import (
+    as_one_compile, first_products, kept_adds_no_operation_without_recompute,
+    kept_is_the_untagged_program_bit_for_bit, kept_products_are_lowered_once,
+    once_a_program)
 from paddle_tpu import models
 flash = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
@@ -386,15 +389,6 @@ def test_flash_attention_at_head_64_reading_values_128_wide(shape):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
-def _first_products(text, S, width) -> int:
-    """dot_generals of a step's StableHLO whose result is the tokens by
-    `width`: with `width` 2 d_inner W1's forward product and every
-    recomputation of it (dW1's result is [d_model, 2 d_inner], dX's
-    [B, S, d_model])."""
-    return len(re.findall(
-        rf"stablehlo\.dot_general.*-> tensor<1x{S}x{width}x\w+>", text))
-
-
 def test_the_step_as_it_lowers_for_a_tpu():
     """At channels that tile (d 512: 1024 channels, 8 heads of 64 over 4)
     and S 384: the selective scan's kernel pair once a Mamba layer, forward
@@ -411,7 +405,7 @@ def test_the_step_as_it_lowers_for_a_tpu():
                     "recurrence.lower"))
     # the scan's y and starts, two flash sites' out and lse, and W1's output
     assert [s["kept"] for s in spans["recurrence.lower"]] == [3, 5, 3, 5, 1, 5]
-    assert _first_products(text, S, 2 * cfg.d_inner) == cfg.n_layer
+    assert first_products(text, S, 2 * cfg.d_inner) == cfg.n_layer
     calls = _kernels(text)
     assert calls["_fwd_kernel"] == 2 and calls["_bwd_kernel"] == 2
     assert calls["_band_kernel"] == 2 and calls["_band_bwd_kernel"] == 2
@@ -430,90 +424,24 @@ def test_the_step_as_it_lowers_for_a_tpu():
 # ---------------------------------------------------------------------------
 # the MLP's first product survives its layer's recomputation (layers.kept)
 # ---------------------------------------------------------------------------
-def _untagged(build, *args, **over):
-    """`build` with `layers.kept` the identity function: the program before
-    the tag."""
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(sambay.layers, "kept", lambda x: x)
-        return build(*args, **over)
-
-
-def _loss_and_gradients(**over):
-    """The loss and every parameter's gradient of the tiny program, through
-    the Executor's own compiled block, with the CPU compiler's fusion off:
-    fused, a multiply-add contracts or not by where a fusion ends, and the
-    fusions of two steps that differ in one product a layer end in
-    different places (84 of 174 values then differ in their last bit)."""
-    fluid.reset_default_env()
-    spec = models.sambay_decoder(models.SambaYDecoderConfig(
-        **{**TINY, **over}))
-    pairs = fluid.append_backward(spec.loss)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    compiled, *args = exe.capture_program(
-        fluid.default_main_program(), feed=spec.synthetic_batch(2, seed=5),
-        fetch_list=[spec.loss] + [g for _, g in pairs])
-    got = jax.jit(compiled.raw_fn).lower(*args).compile(compiler_options={
-        "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"})(*args)
-    kept = sum(op.type == "kept" for b in spec.loss.block.program.blocks
-               for op in b.desc.ops)
-    return kept, [np.asarray(x) for x in jax.tree_util.tree_leaves(got)]
+KEPT = (models.sambay_decoder, models.SambaYDecoderConfig)
 
 
 @pytest.mark.parametrize("recompute", [True, False])
 def test_the_tagged_programs_loss_and_gradients_are_the_untagged_ones(
         recompute):
-    """The kept value is the first forward's own output, which the
-    recomputation would have made again from the same operands: the loss
-    and every parameter's gradient bit for bit, with the units recomputed
-    and not."""
-    tags, got = _loss_and_gradients(use_recompute=recompute)
-    none, want = _untagged(_loss_and_gradients, use_recompute=recompute)
-    assert (tags, none) == (6, 0)
-    assert len(got) == len(want) > 80
-    assert sum(float(np.abs(x).max()) > 0 for x in got) > 80
-    for a, b in zip(got, want):
-        assert np.array_equal(a, b)
-
-
-@once_a_program
-def _tiny_step(tagged, recompute):
-    """The tiny model's step as it lowers for a TPU (the scan and the flash
-    sites on their jax.numpy engines at these widths), with the tag or
-    without it."""
-    cfg = models.SambaYDecoderConfig(**{**TINY, "use_recompute": recompute})
-    lower = _step_for_the_tpu if tagged else functools.partial(
-        _untagged, _step_for_the_tpu)
-    return lower(models.sambay_decoder, cfg, span_names=("recurrence.lower",))
+    kept_is_the_untagged_program_bit_for_bit(
+        *KEPT, tags=6, least=80, **TINY, use_recompute=recompute)
 
 
 def test_w1s_product_is_lowered_once_a_layer_where_the_untagged_step_has_two():
-    S, width = TINY["max_length"], 2 * TINY["d_inner"]
-    (text, spans), (bare, bare_spans) = _tiny_step(True, True), \
-        _tiny_step(False, True)
-    assert _first_products(text, S, width) == 6
-    assert _first_products(bare, S, width) == 12
-    assert [s["kept"] - b["kept"] for s, b in zip(
-        spans["recurrence.lower"], bare_spans["recurrence.lower"])] == [1] * 6
-    assert all(s["recompute"] == 1 for s in spans["recurrence.lower"])
-    # what the unit saves is the bf16 the product wrote (the AMP keep tier
-    # of a step for a TPU), and nothing of the width is made in fp32
-    assert re.search(rf"-> tensor<1x{S}x{width}xbf16>", text)
-    assert not re.search(rf"tensor<1x{S}x{width}xf32>", text)
+    kept_products_are_lowered_once(
+        *KEPT, widths={2 * TINY["d_inner"]: 6}, kept_a_unit=[1] * 6, **TINY)
 
 
 def test_without_recompute_the_tag_adds_no_operation():
-    """use_recompute false: the tag is counted (`kept` one higher a layer)
-    and there is no checkpoint for it to speak to: the step's StableHLO is
-    the untagged step's, operation for operation."""
-    (text, spans), (bare, bare_spans) = _tiny_step(True, False), \
-        _tiny_step(False, False)
-    assert [(s["recompute"], s["kept"] - b["kept"]) for s, b in zip(
-        spans["recurrence.lower"], bare_spans["recurrence.lower"])] \
-        == [(0, 1)] * 6
-    # (a private function's number goes by what was traced before it)
-    numbers = re.compile(r"@(\w+?)_\d+\b")
-    assert numbers.sub(r"@\1", text) == numbers.sub(r"@\1", bare)
+    kept_adds_no_operation_without_recompute(
+        *KEPT, kept_a_unit=[1] * 6, **TINY)
 
 
 @pytest.mark.parametrize("tier", ["keep", "mxu", "off"])

@@ -6,8 +6,13 @@ tiny sizes on the CPU: the loss and every parameter's gradient at the
 rehearsal's depth (Mamba-2, attention, Mamba-2) and on a ragged
 row with two groups and no recomputation; each of the four multipliers shown to bite by a reference that drops it; the
 tie of the share to the model (two shares of a Mamba-2 layer and of an
-attention layer add up to the uncut layer); and the step as it lowers for a
-TPU (the scan's kernel pair once a Mamba-2 layer, no second forward)."""
+attention layer add up to the uncut layer); the step as it lowers for a
+TPU (the scan's kernel pair once a Mamba-2 layer, no second forward); and
+what `layers.kept` holds through a layer's recomputation, every MLP's first
+product and every Mamba-2 mixer's in-projection (decoder_steps.py's cases:
+the untagged program's loss and gradients bit for bit, each product once a
+layer in the TPU's step where the untagged one has two, bf16 kept bf16, no
+operation without recomputation)."""
 
 import functools
 import os
@@ -26,7 +31,10 @@ if REPO not in sys.path:
 import paddle_tpu as fluid
 from benchmark.harness import manifest
 from benchmark.harness import reference as harness_reference
-from decoder_steps import as_one_compile, once_a_program
+from decoder_steps import (
+    as_one_compile, kept_adds_no_operation_without_recompute,
+    kept_is_the_untagged_program_bit_for_bit, kept_products_are_lowered_once,
+    once_a_program)
 from paddle_tpu import models
 
 from test_recompute_keep import _kernels, _step_for_the_tpu  # noqa: E402
@@ -272,10 +280,10 @@ def test_the_step_as_it_lowers_for_a_tpu():
     text, spans = _step_for_the_tpu(
         models.ssd_hybrid_decoder, cfg,
         span_names=("ssd.lower", "attn.lower", "recurrence.lower"))
-    # the scan's y and starts; a flash site's out and lse
-    # (a flash site keeps its out and lse where its backward is the
-    # Pallas kernel: not at S 256)
-    assert [s["kept"] for s in spans["recurrence.lower"]][::2] == [2, 2]
+    # the scan's y and starts, the in-projection's output and W1's; of the
+    # attention layer W1's output alone (a flash site keeps its out and lse
+    # where its backward is the Pallas kernel: not at S 256)
+    assert [s["kept"] for s in spans["recurrence.lower"]] == [4, 1, 4]
     calls = _kernels(text)
     assert calls["_fwd_kernel"] == 2 and calls["_bwd_kernel"] == 2
     assert [(s["engine"], s["chunk"], s["block"], s["heads"], s["states"])
@@ -283,3 +291,42 @@ def test_the_step_as_it_lowers_for_a_tpu():
     assert [(s["kind"], s["heads"], s["kv_heads"])
             for s in spans["attn.lower"]] == [("full", 2, 1)]
     assert not re.search(rf"tensor<[0-9x]*{S}x2x64x128x", text)
+
+
+# ---------------------------------------------------------------------------
+# what survives a layer's recomputation besides the kernels' own values
+# (layers.kept): W1's output in every layer, the in-projection's (z | xBC |
+# dt) in a Mamba-2 layer
+# ---------------------------------------------------------------------------
+KEPT = (models.ssd_hybrid_decoder, models.SsdHybridDecoderConfig)
+KEPT_SIZES = {**TINY, **REHEARSAL}
+W1_WIDTH = 2 * TINY["d_inner"]
+IN_WIDTH = 2 * TINY["ssm_heads"] * TINY["ssm_head_dim"] \
+    + 2 * TINY["d_state"] + TINY["ssm_heads"]
+KEPT_A_UNIT = [2, 1, 2]
+
+
+@pytest.mark.parametrize("recompute", [True, False])
+def test_the_tagged_programs_loss_and_gradients_are_the_untagged_ones(
+        recompute):
+    """With the stream entering the first unit as the embedding wrote it
+    (`embedding_multiplier` 1): the CPU compiler's algebraic simplifier
+    folds that constant into the first unit's FIRST forward and cannot
+    behind the recomputation's barrier, so with it the UNTAGGED step's
+    recomputed first layer is not its own first forward to the bit (13 of
+    70 values differ in their last bit, all of layer 0; none with the
+    simplifier off, which compiles for twice as long)."""
+    kept_is_the_untagged_program_bit_for_bit(
+        *KEPT, tags=sum(KEPT_A_UNIT), least=30, **KEPT_SIZES,
+        embedding_multiplier=1.0, use_recompute=recompute)
+
+
+def test_the_kept_products_are_lowered_once_a_layer_where_the_untagged_step_has_two():
+    kept_products_are_lowered_once(
+        *KEPT, widths={W1_WIDTH: 3, IN_WIDTH: 2}, kept_a_unit=KEPT_A_UNIT,
+        **KEPT_SIZES)
+
+
+def test_without_recompute_the_tags_add_no_operation():
+    kept_adds_no_operation_without_recompute(
+        *KEPT, kept_a_unit=KEPT_A_UNIT, **KEPT_SIZES)
