@@ -81,8 +81,10 @@ the block backward loops over chunks of queries around the one kernel
 Two backward engines, one rule (_bwd_plan): the Pallas kernel where a grid
 step (score block x the rows it takes, _packable_rows) has at least
 _BWD_PALLAS_MIN_BLOCK_SCORES scores to spread its fixed cost over and the
-dQ fits; else jax.vjp of the reference, a recompute XLA fuses, refused at
-lowering on a TPU past _XLA_BWD_MAX_SCORE_BYTES of scores.  At S 256 three or
+dQ fits; else jax.vjp of the reference, a recompute XLA fuses.  Past
+_XLA_BWD_MAX_SCORE_BYTES of scores on a TPU a site takes the longest Pallas
+plan that fits whatever its blocks (_bwd_chunk_rows), and is refused at
+lowering where none does.  At S 256 three or
 more rows are the Pallas pair's (1.57 ms a site; 1.70 with XLA's backward).
 force="interpret" keeps the Pallas backward at every shape, "jax" none.
 Calls are memoized by static config: a shape's sites share one payload.
@@ -1174,9 +1176,9 @@ def _pallas_flash_bwd(q, k, v, klen, out, lse, g, causal, scale,
     G, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     bh = _packable_rows(q, k)
     trips = _bwd_trips(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                       chunk, bh)
+                       chunk, bh, B * H)
     plan = dict(_bwd_plan(Sq, Sk, D, q.dtype, causal, block_q, block_k, Dv,
-                          None, chunk, bh),
+                          None, chunk, bh, site_bh=B * H),
                 engine="pallas", kv_heads=G)
     if rows_per_step is not None:
         plan["rows_per_step"] = rows_per_step
@@ -1238,15 +1240,26 @@ def _bwd_rows_per_step(bh, sq, sk, block_q, block_k, head_dim, dtype, v_dim):
                                         v_dim, n))
 
 
+def _xla_bwd_score_bytes(site_bh, sq, sk):
+    """The fp32 scores [B, H, Sq, Sk] that the XLA recompute backward of a
+    site of `site_bh` batch x heads materialises."""
+    return 4 * site_bh * sq * sk
+
+
 @functools.lru_cache(maxsize=128)
-def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh=1):
+def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh=1,
+                    site_bh=None):
     """(rows of queries a call of the backward kernel takes, engine).  The
     whole row where its dQ fits VMEM beside a grid step worth taking (of the
     call's `bh` packable batch-head rows a step may take several,
     _rows_per_step, and it is the step's scores that count), as it
     always was.  Else the longest cut of the row
     (_block_lengths) whose plan fits with a score block worth a grid step.
-    Where no cut does, the row stays whole and the engine is XLA's."""
+    Where no cut does, the row stays whole and the engine is XLA's, unless
+    XLA's engine would be refused the site (`site_bh`, its batch x heads
+    however they are grouped; `bh` where not given): _flash_bwd's own
+    test, so one rule decides.  Such a site takes the longest plan that
+    fits, at its smaller blocks."""
     def plan(rows, keys, bh=1):
         bq, bk = _plan_bwd_blocks(rows, keys, head_dim, dtype, causal, v_dim)
         fits = bwd_working_set_bytes(
@@ -1259,23 +1272,33 @@ def _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh=1):
     fits, worth = plan(sq, sk, bh)
     if fits and worth:
         return sq, "pallas"
-    cuts = []
+    cuts, fitting = [], [sq] if fits else []
     for rows in _block_lengths(sq)[:-1]:
         q0 = (sq - 1) // rows * rows            # the last chunk sees most
         k0, k1 = _chunk_keys(sq, sk - sq, sk, causal)
-        if all(plan(min(rows, sq - q0), k1 - k0)):
-            cuts.append(rows)
-    return (max(cuts), "pallas") if cuts else (sq, "xla")
+        fits, worth = plan(min(rows, sq - q0), k1 - k0)
+        if fits:
+            (cuts if worth else fitting).append(rows)
+    if cuts:
+        return max(cuts), "pallas"
+    # e.g. head 256 in fp32, where no block worth a grid step fits (512 x
+    # 512 is 12.5 of 12 MiB), over 16 heads of 8192 rows
+    if fitting and _xla_bwd_score_bytes(
+            bh if site_bh is None else site_bh, sq, sk
+    ) > _XLA_BWD_MAX_SCORE_BYTES:
+        return max(fitting), "pallas"
+    return sq, "xla"
 
 
 def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-               v_dim=None, chunk=None, bh=1):
+               v_dim=None, chunk=None, bh=1, site_bh=None):
     """[(q0, q1, k0, k1, block_q, block_k)]: the calls of the backward
     kernel that one site with no window makes, one where the row is whole.
     `chunk` pins the rows a trip, `block_q` / `block_k` the blocks (a test
     or the probe), else _bwd_chunk_rows and each trip's own
     _plan_bwd_blocks."""
-    rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh)[0]
+    rows = (_bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh,
+                            site_bh)[0]
             if chunk is None else min(chunk, sq))
     trips = []
     for q0 in range(0, sq, rows):
@@ -1291,7 +1314,8 @@ def _bwd_trips(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
 
 
 def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
-              v_dim=None, window=None, chunk=None, bh=1, group=1):
+              v_dim=None, window=None, chunk=None, bh=1, group=1,
+              site_bh=None):
     """What the backward of one attention call of this shape is given, the
     `flash.bwd_plan` span's counts: block_q, block_k (pinned by a test or
     the probe, else _plan_bwd_blocks'; the last trip's where there are
@@ -1299,7 +1323,8 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
     steps, steps_skipped and its two parts skipped_causal and
     skipped_window (static, over one batch-head row, all trips),
     rows_per_step (the batch-head rows a grid step takes, of the call's `bh`
-    packable ones, _packable_rows: part of the shape), layout ("bhsd": these
+    packable ones, _packable_rows: part of the shape, as `site_bh`, all of
+    its batch x heads, is: _bwd_chunk_rows), layout ("bhsd": these
     are the heads-first kernels; _pallas_flash_bwd_bshd says "bshd" and
     counts batch rows), form ("blocks"; under a `window` shorter than the
     keys "band", _band_bwd_plan's counts: one call, `group` query heads a
@@ -1308,9 +1333,10 @@ def _bwd_plan(sq, sk, head_dim, dtype, causal, block_q=None, block_k=None,
     if window is not None:
         return _band_bwd_plan(sq, sk, head_dim, dtype, block_q, v_dim,
                               window, group)[0]
-    engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh)[1]
+    engine = _bwd_chunk_rows(sq, sk, head_dim, dtype, causal, v_dim, bh,
+                             site_bh)[1]
     trips = _bwd_trips(sq, sk, head_dim, dtype, causal, block_q, block_k,
-                       v_dim, chunk, bh)
+                       v_dim, chunk, bh, site_bh)
     steps = above = 0
     for q0, q1, k0, k1, bq, bk in trips:
         nqb, nkb = -(-(q1 - q0) // bq), -(-(k1 - k0) // bk)
@@ -1895,7 +1921,8 @@ def _pallas_backward(q, k, v, causal, force, window=None) -> bool:
     return use_pallas(force) and _bwd_plan(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
         v_dim=v.shape[3], window=window, bh=_packable_rows(q, k),
-        group=q.shape[1] // k.shape[1])["engine"] == "pallas"
+        group=q.shape[1] // k.shape[1],
+        site_bh=q.shape[0] * q.shape[1])["engine"] == "pallas"
 
 
 def _forward(q, k, v, klen, causal, scale, force, need_lse, window=None):
@@ -1986,7 +2013,8 @@ def _flash_bwd(causal, scale, force, window, res, g):
             )
             return dq, dk, dv, jnp.zeros_like(klen)
         if use_pallas(force):
-            scores = 4 * q.shape[0] * q.shape[1] * q.shape[2] * k.shape[2]
+            site_bh = q.shape[0] * q.shape[1]
+            scores = _xla_bwd_score_bytes(site_bh, q.shape[2], k.shape[2])
             if scores > _XLA_BWD_MAX_SCORE_BYTES:
                 raise ValueError(
                     f"flash_attention: no Pallas backward plan takes q "
@@ -1997,8 +2025,8 @@ def _flash_bwd(causal, scale, force, window, res, g):
             with span("flash.bwd_plan", **_bwd_plan(
                     q.shape[2], k.shape[2], q.shape[3], q.dtype, causal,
                     v_dim=v.shape[3], window=window,
-                    bh=_packable_rows(q, k), group=q.shape[1] // k.shape[1]),
-                    kv_heads=k.shape[1]):
+                    bh=_packable_rows(q, k), group=q.shape[1] // k.shape[1],
+                    site_bh=site_bh), kv_heads=k.shape[1]):
                 pass
         # recompute-backward: differentiate the reference formulation
         _, vjp = jax.vjp(

@@ -1,7 +1,11 @@
-"""Gated delta-rule linear attention with a decay for every key channel
-(Kimi Delta Attention, KDA: arXiv:2510.26692 section 3; the op
-gated_delta_attention, name scope `kda.scan`) as a scan over chunks of the
-sequence, with a backward of its own.
+"""Gated delta-rule linear attention as a scan over chunks of the sequence,
+with a backward of its own (the op gated_delta_attention), in two forms
+read from the operands' shapes (`form`), no flag: a decay for every key
+channel, g [B, S, H D] (Kimi Delta Attention, KDA: arXiv:2510.26692 section
+3; name scope `kda.scan`), and ONE decay a head, g [B, S, H], whose q and k
+may come at fewer heads than v, value head j reading key head j // (H / Hk)
+(Gated DeltaNet: arXiv:2412.06464; name scope `gdn.scan`).  The text below
+is the first form's; what a head's one decay changes stands at its end.
 
 A head keeps a state M [D keys, D values], M_0 = 0, and a token does
 
@@ -85,6 +89,26 @@ walks the group's chunks back.  The forward tags its output and those
 states with core.compiler.keep: the backward of a recomputed layer runs no
 second forward of this op.  tools/kda_scan_probe.py times and checks the
 two engines alone on the chip.
+
+ONE decay a head (alpha_t a scalar, Gc a column): the chunk algebra above
+with a scalar in diag's place, the same walk over chunks and groups, the
+same inverse, kept states and backward, through the same functions.  What
+is that form's alone: exp(Gc_r - Gc_j) is ONE [C, C] lower-triangular
+square a head (every exponent still a difference <= 0), applied AFTER the
+plain products k k^T and q k^T (_masked_products; in the kernels
+_raw_products times x["decay"]), so the halvings (_decayed_products,
+_halving, _middles, _block_starts) have no work to do and are the channel
+form's alone; Gc's cotangent is the masked square's row sums less its
+column sums.  Planes that are [., D] in the channel form are [., 1] here
+and broadcast (exp(Gc), exp(Gc_C - Gc), the chunk's last decay).  Fewer key
+heads: the jax.numpy engine regroups v, g and beta to (Hk, r) heads and q,
+k to (Hk, 1), which broadcast inside _local and whose cotangents come back
+summed; the kernels' block specs send value head h to the lane block h //
+r of q and k [B, S, Hk D] (nothing is repeated in HBM; a grid step is still
+one value head, so a key head's products are made once a value head), g
+and its cotangent travel by tiles as beta does, and dq, dk leave the
+backward kernel a value head each and are summed over a key head's
+(_kernels_bwd).
 """
 
 from __future__ import annotations
@@ -137,28 +161,35 @@ def kept_bytes(batch: int, seq: int, heads: int, dim: int, groups: int,
             + groups * state_bytes(batch, heads, dim))
 
 
-def flops(batch: int, seq: int, heads: int, dim: int, chunk: int) -> int:
+def flops(batch: int, seq: int, heads: int, dim: int, chunk: int,
+          key_heads: int = None) -> int:
     """The algorithm's FLOPs a site, forward + backward (the backward
     twice the forward; a recomputed pass is no work of the algorithm): a
     chunk of a head takes the two decayed products at their triangles (2
-    C^2 D), the triangular inverse (C^3 / 3), T applied to [K | V] (2 C^2
-    D), the state read twice and written once (6 C D^2) and P U' (C^2 D)."""
+    C^2 D; once a KEY head where `key_heads` < `heads`: the value heads of
+    a key head share q k^T and k k^T before their decays), the triangular
+    inverse (C^3 / 3), T applied to [K | V] (2 C^2 D), the state read twice
+    and written once (6 C D^2) and P U' (C^2 D)."""
     c, d = chunk, dim
-    a_chunk = 5 * c * c * d + c ** 3 // 3 + 6 * c * d * d
-    return 3 * batch * heads * (seq // chunk) * a_chunk
+    a_chunk = 3 * c * c * d + c ** 3 // 3 + 6 * c * d * d
+    shared = 2 * c * c * d * (key_heads or heads)
+    return 3 * batch * (seq // chunk) * (heads * a_chunk + shared)
 
 
 def moved_bytes(batch: int, seq: int, heads: int, dim: int,
-                itemsize: int) -> int:
+                itemsize: int, key_heads: int = None,
+                head_decay: bool = False) -> int:
     """What a site's two passes have to move through HBM whatever engine
-    runs them: the forward reads q, k, v (itemsize), g (fp32) and beta and
-    writes out; the backward reads those and out's cotangent and writes
-    the five gradients.  The chunk states are the engine's choice and are
-    not counted."""
+    runs them: the forward reads q, k (at `key_heads` heads where there are
+    fewer), v (itemsize), g (fp32: a channel's, or under `head_decay` a
+    head's) and beta and writes out; the backward reads those and out's
+    cotangent and writes the five gradients.  The chunk states are the
+    engine's choice and are not counted."""
     row = batch * seq * heads
-    wide, gate = row * dim, 4 * row * dim
-    fwd = 3 * itemsize * wide + gate + 4 * row + itemsize * wide
-    bwd = fwd + 3 * itemsize * wide + gate + 4 * row
+    wide, keys = row * dim, batch * seq * (key_heads or heads) * dim
+    gate = 4 * row * (1 if head_decay else dim)
+    fwd = itemsize * (2 * keys + wide) + gate + 4 * row + itemsize * wide
+    bwd = fwd + itemsize * (2 * keys + wide) + gate + 4 * row
     return fwd + bwd
 
 
@@ -201,6 +232,19 @@ def _decayed_products(qn, kn, gc, mm):
             "...xrd,...jd->...xrj", left.astype(mm), right.astype(mm)), 0.0)
         b //= 2
     return out
+
+
+def _masked_products(qn, kn, gc, mm):
+    """_decayed_products where a HEAD has one decay, gc [..., C]: exp(Gc_r
+    - Gc_j) is one [C, C] mask under the diagonal, applied after k k^T and
+    q k^T (which the value heads of a key head share: qn, kn broadcast
+    against gc), so no halving has work to do."""
+    c = gc.shape[-1]
+    x = jnp.stack([kn, qn], axis=-3).astype(mm)
+    decay = jnp.exp(jnp.minimum(gc[..., :, None] - gc[..., None, :], 0.0))
+    raw = _mm("...xrd,...jd->...xrj", x, kn.astype(mm))
+    return jnp.where(jnp.tril(jnp.ones((c, c), bool)),
+                     raw * decay[..., None, :, :], 0.0)
 
 
 def _iota(shape, axis):
@@ -259,11 +303,19 @@ def _unit(x, eps):
 
 def _local(q, k, v, g, beta, eps):
     """Of chunks [..., C, D] (beta [..., C]): (W, U, Q exp(Gc) D^-1/2, P,
-    K exp(Gc_C - Gc)) in q's dtype and exp(Gc_C) [..., D] fp32."""
+    K exp(Gc_C - Gc)) in q's dtype and exp(Gc_C) [..., D] fp32.  With one
+    decay a head, g [..., C]: Gc is a column [..., C, 1] that every plane
+    below broadcasts, exp(Gc_C) [..., 1], and q, k may come at [..., 1, C,
+    D] for v's [..., r, C, D] (r value heads a key head)."""
     mm, (c, d) = q.dtype, q.shape[-2:]
     qn, kn = _unit(q.astype(F32), eps), _unit(k.astype(F32), eps)
-    gc = jnp.cumsum(g, axis=-2)
-    kk, qk = jnp.moveaxis(_decayed_products(qn, kn, gc, mm), -3, 0)
+    if g.ndim < v.ndim:
+        gc = jnp.cumsum(g, axis=-1)[..., None]
+        products = _masked_products(qn, kn, gc[..., 0], mm)
+    else:
+        gc = jnp.cumsum(g, axis=-2)
+        products = _decayed_products(qn, kn, gc, mm)
+    kk, qk = jnp.moveaxis(products, -3, 0)
     below = jnp.tril(jnp.ones((c, c), bool), -1)
     t = _unit_lower_inverse(jnp.where(below, kk, 0.0) * beta[..., :, None]) \
         * beta[..., None, :]
@@ -315,12 +367,14 @@ def _chunk_bwd(dm, xs):
            + _mm("...cd,...dv->...cv", kd, dmc)).astype(mm)
     before = (_mm("...cd,...cv->...dv", qg, do) + gamma[..., :, None] * dm
               - _mm("...cd,...cv->...dv", w, du2))
+    dgamma = jnp.sum(m * dm, axis=-1)
+    if gamma.shape[-1] == 1:                 # one decay a head
+        dgamma = jnp.sum(dgamma, axis=-1, keepdims=True)
     return before, (
         (-_mm("...cv,...dv->...cd", du2, mc)).astype(mm), du2,
         _mm("...cv,...dv->...cd", do, mc).astype(mm),
         _mm("...rv,...jv->...rj", do, u2).astype(mm),
-        _mm("...cv,...dv->...cd", u2, dmc).astype(mm),
-        jnp.sum(m * dm, axis=-1))
+        _mm("...cv,...dv->...cd", u2, dmc).astype(mm), dgamma)
 
 
 # ---------------------------------------------------------------------------
@@ -328,12 +382,13 @@ def _chunk_bwd(dm, xs):
 # ---------------------------------------------------------------------------
 def _forward(q, k, v, g, beta, eps):
     """(out [groups, group, B, H, C, D], the state every group starts
-    from [groups, B, H, D, D])."""
+    from [groups, B, H, D, D]); H is (Hk, r) where q and k come at fewer
+    heads than v."""
     def group(m, xs):
         after, out = jax.lax.scan(_chunk_fwd, m, _local(*xs, eps))
         return after, (out, m)
 
-    zero = jnp.zeros(q.shape[2:4] + (q.shape[-1],) * 2, F32)
+    zero = jnp.zeros(v.shape[2:-2] + (v.shape[-1],) * 2, F32)
     return jax.lax.scan(group, zero, (q, k, v, g, beta))[1]
 
 
@@ -509,19 +564,24 @@ def _middles(gc, pos, b):
 class _Masks:
     """The iotas of a tile, made once a grid step."""
 
-    def __init__(self, T, C, D):
+    def __init__(self, T, C, D, head_decay=False):
         row, col = _iota((T, T), 0), _iota((T, T), 1)
-        self.T, self.C, self.D = T, C, D
+        self.T, self.C, self.D, self.head_decay = T, C, D, head_decay
         self.pos = _iota((T, D), 0) % C           # a row's place in its chunk
         self.eye = row == col
         self.eye2 = _iota((2 * T, T), 0) % T == _iota((2 * T, T), 1)
         self.eye_d = _iota((D, D), 0) == _iota((D, D), 1)
         self.below = (col < row) & (row // C == col // C)
         self.halves = {}
-        b = C // 2
+        b = 0 if head_decay else C // 2
         while b:
             self.halves[b] = _halves(T, b, rows=2)
             b //= 2
+
+    @property
+    def lower(self):
+        """The diagonal and what lies under it, a chunk at a time."""
+        return self.below | self.eye
 
 
 def _tile_values(q, k, v, g, beta_row, masks, eps):
@@ -531,14 +591,23 @@ def _tile_values(q, k, v, g, beta_row, masks, eps):
     rq = jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + eps)
     rk = jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + eps)
     T, C, D = masks.T, masks.C, masks.D
-    gc = _running_sum(g, masks.pos, C)
+    head = {}
+    if masks.head_decay:
+        # g a [1, T] row, one decay a head: Gc a [T, 1] column that every
+        # plane below broadcasts, and exp(Gc_r - Gc_j) ONE [T, T] square
+        lower = masks.lower
+        gc = jnp.sum(jnp.where(lower, g, 0.0), axis=1, keepdims=True)
+        head["decay"] = jnp.where(lower, jnp.exp(jnp.minimum(
+            gc - _across(gc, masks.eye, 0), 0.0)), 0.0)
+    else:
+        gc = _running_sum(g, masks.pos, C)
     last = [gc[c * C + C - 1:(c + 1) * C, :] for c in range(T // C)]
     last_rows = jnp.concatenate(
-        [jnp.broadcast_to(t, (C, D)) for t in last], axis=0)
+        [jnp.broadcast_to(t, (C, gc.shape[1])) for t in last], axis=0)
     beta = _across(beta_row, masks.eye, 1)
     qn, kn, eg = q * rq, k * rk, jnp.exp(gc)
     return dict(
-        qn=qn, kn=kn, rq=rq, rk=rk, gc=gc, eg=eg, beta=beta, v=v,
+        **head, qn=qn, kn=kn, rq=rq, rk=rk, gc=gc, eg=eg, beta=beta, v=v,
         ed=jnp.exp(last_rows - gc), gamma=[jnp.exp(t) for t in last],
         kb=beta * (kn * eg), vb=beta * v.astype(F32),
         qg=qn * eg * D ** -0.5)
@@ -555,6 +624,19 @@ def _halving(x, masks, mm):
         b //= 2
 
 
+def _raw_products(x, mm):
+    """[2T, T] fp32: k k^T over q k^T before any decay (one decay a head)."""
+    return _dot(jnp.concatenate([x["kn"], x["qn"]], axis=0).astype(mm),
+                x["kn"].astype(mm), _NT)
+
+
+def _times_decay(gamma, m, masks):
+    """exp(Gc_C) m: gamma a [1, D] row of the key channels' decays, or a
+    head's one [1, 1]."""
+    return (gamma if masks.head_decay
+            else _across(gamma, masks.eye_d, 1)) * m
+
+
 def _tile_local(x, masks, mm):
     """_local of a tile's chunks: (W, U, Q exp(Gc) D^-1/2, P, K exp(Gc_C -
     Gc)) in the operand dtype, and X and the decayed k-k product fp32 for
@@ -563,13 +645,17 @@ def _tile_local(x, masks, mm):
     level serves both sides of the masked product."""
     T, D = masks.T, masks.D
     kn, qn = x["kn"], x["qn"]
-    both = jnp.concatenate([kn, qn], axis=0)
-    diag = jnp.sum(both * jnp.concatenate([kn, kn], axis=0), axis=-1,
-                   keepdims=True)
-    prod = jnp.where(masks.eye2, diag, 0.0)
-    for b, _, sides in _halving(x, masks, mm):
-        prod = prod + jnp.where(masks.halves[b],
-                                _dot(sides, sides[:T], _NT), 0.0)
+    if masks.head_decay:
+        prod = _raw_products(x, mm) * jnp.concatenate([x["decay"]] * 2,
+                                                      axis=0)
+    else:
+        both = jnp.concatenate([kn, qn], axis=0)
+        diag = jnp.sum(both * jnp.concatenate([kn, kn], axis=0), axis=-1,
+                       keepdims=True)
+        prod = jnp.where(masks.eye2, diag, 0.0)
+        for b, _, sides in _halving(x, masks, mm):
+            prod = prod + jnp.where(masks.halves[b],
+                                    _dot(sides, sides[:T], _NT), 0.0)
     kk = jnp.where(masks.below, prod[:T], 0.0)
     inv = _unit_lower_inverse(kk * x["beta"], masks.C)
     wu = _dot(inv.astype(mm), jnp.concatenate(
@@ -594,8 +680,7 @@ def _tile_scan(m, loc, masks, mm):
         starts.append(m)
         read.append(wq[C:])
         u2s.append(u2)
-        m = _across(gamma, masks.eye_d, 1) * m \
-            + _dot(loc["kd"][rows], u2, _TN)
+        m = _times_decay(gamma, m, masks) + _dot(loc["kd"][rows], u2, _TN)
     u2 = jnp.concatenate(u2s, axis=0)
     return (m, jnp.concatenate(read, axis=0) + _dot(loc["p"], u2, _NN),
             starts, u2)
@@ -630,12 +715,20 @@ def _row_of(t):
     return slice(t, t + 1) if isinstance(t, int) else pl.ds(t, 1)
 
 
+def _decays_of(g_ref, t, rows, masks):
+    """Tile t's log-decays: [T, D] of a [B, S, H D] value (its `rows`), or
+    the [1, T] row of a head's (laid out as beta is)."""
+    return g_ref[0, 0, 0, _row_of(t), :] if masks.head_decay \
+        else g_ref[0, rows, :]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, start_ref,
                 m_scr, *, tiles, eps):
     import jax.experimental.pallas as pl
 
     T, D, mm = _TILE, q_ref.shape[-1], q_ref.dtype
-    masks = _Masks(T, tiles.chunk, D)
+    head_decay = g_ref.shape != q_ref.shape
+    masks = _Masks(T, tiles.chunk, D, head_decay)
 
     @pl.when(pl.program_id(2) == 0)
     def _the_state_starts_at_zero():
@@ -646,7 +739,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, start_ref,
     def tile(t):
         rows = _rows_of(t, T)
         x = _tile_values(q_ref[0, rows, :], k_ref[0, rows, :],
-                         v_ref[0, rows, :], g_ref[0, rows, :],
+                         v_ref[0, rows, :], _decays_of(g_ref, t, rows, masks),
                          beta_ref[0, 0, 0, _row_of(t), :], masks, eps)
         m, out, _, _ = _tile_scan(m_scr[...], _tile_local(x, masks, mm),
                                   masks, mm)
@@ -678,33 +771,59 @@ def _tile_pull(x, inv, kk, d, masks, mm):
                   keepdims=True)
     # the two decayed products, level by level
     dprod = jnp.concatenate([da * beta, dp.astype(F32) * D ** -0.5], axis=0)
-    on_diagonal = jnp.sum(jnp.where(masks.eye, dprod[T:], 0.0), axis=-1,
-                          keepdims=True)
-    as_row, dk_col = jnp.zeros((2 * T, D), F32), jnp.zeros((T, D), F32)
-    for b, e, sides in _halving(x, masks, mm):
-        kept = jnp.where(masks.halves[b], dprod, 0.0).astype(mm)
-        as_row = as_row + _dot(kept, sides[:T], _NN) \
-            * jnp.concatenate([e, e], axis=0)
-        dk_col = dk_col + _dot(kept, sides, _TN) * e
-    dk_row, dq_row = as_row[:T], as_row[T:]
-    dgc = kn * dk_row + qn * dq_row - kn * dk_col
-    dkn = dk_row + dk_col + on_diagonal * qn
-    dqn = dq_row + on_diagonal * kn
+    if masks.head_decay:
+        # prod = raw * decay: the square's cotangent goes to Gc as a row
+        # sum less a column sum, the raw products' to q and k as matmuls
+        decay, raw = x["decay"], _raw_products(x, mm)
+        draw = (dprod * jnp.concatenate([decay] * 2, axis=0)).astype(mm)
+        as_row = _dot(draw, kn.astype(mm), _NN)
+        dk_col = _dot(draw, jnp.concatenate([kn, qn], axis=0).astype(mm),
+                      _TN)
+        ddiff = (dprod[:T] * raw[:T] + dprod[T:] * raw[T:]) * decay
+        dgc = jnp.sum(ddiff, axis=1, keepdims=True) - _across(
+            jnp.sum(ddiff, axis=0, keepdims=True), masks.eye, 1)
+        dkn, dqn, pos = as_row[:T] + dk_col, as_row[T:], masks.pos[:, :1]
+
+        def lanes(t):
+            return jnp.sum(t, axis=-1, keepdims=True)
+    else:
+        on_diagonal = jnp.sum(jnp.where(masks.eye, dprod[T:], 0.0), axis=-1,
+                              keepdims=True)
+        as_row, dk_col = jnp.zeros((2 * T, D), F32), jnp.zeros((T, D), F32)
+        for b, e, sides in _halving(x, masks, mm):
+            kept = jnp.where(masks.halves[b], dprod, 0.0).astype(mm)
+            as_row = as_row + _dot(kept, sides[:T], _NN) \
+                * jnp.concatenate([e, e], axis=0)
+            dk_col = dk_col + _dot(kept, sides, _TN) * e
+        dk_row, dq_row = as_row[:T], as_row[T:]
+        dgc = kn * dk_row + qn * dq_row - kn * dk_col
+        dkn = dk_row + dk_col + on_diagonal * qn
+        dqn, pos = dq_row + on_diagonal * kn, masks.pos
+
+        def lanes(t):
+            return t
     # K exp(Gc) beta, Q exp(Gc) D^-1/2, K exp(Gc_C - Gc), exp(Gc_C)
     dkn = dkn + dkb * (beta * eg) + dkd * ed
     dqn = dqn + dqg * (eg * D ** -0.5)
-    through_last = dkd * (kn * ed)
-    dgc = dgc + dkb * x["kb"] + dqg * x["qg"] - through_last
+    through_last = lanes(dkd * (kn * ed))
+    dgc = dgc + lanes(dkb * x["kb"]) + lanes(dqg * x["qg"]) - through_last
     dlast = jnp.concatenate([jnp.broadcast_to(
         jnp.sum(through_last[c * C:(c + 1) * C], axis=0, keepdims=True)
-        + dgamma[c] * x["gamma"][c], (C, D)) for c in range(T // C)], axis=0)
-    dgc = dgc + jnp.where(masks.pos == C - 1, dlast, 0.0)
+        + dgamma[c] * x["gamma"][c], (C, dgc.shape[1]))
+        for c in range(T // C)], axis=0)
+    dgc = dgc + jnp.where(pos == C - 1, dlast, 0.0)
 
     def unit_back(n, dn, r):
         return r * (dn - n * jnp.sum(dn * n, axis=-1, keepdims=True))
 
-    return (unit_back(qn, dqn, x["rq"]), unit_back(kn, dkn, x["rk"]),
-            dvb * beta, _running_sum(dgc, masks.pos, C, reverse=True), dbeta)
+    dq, dk = unit_back(qn, dqn, x["rq"]), unit_back(kn, dkn, x["rk"])
+    dv = dvb * beta
+    # the running sum's transpose: up the rows of every chunk (a head's
+    # decay: summed under the mask, and out as the [1, T] row g came as)
+    dg = jnp.sum(jnp.where(masks.lower, dgc, 0.0), axis=0, keepdims=True) \
+        if masks.head_decay \
+        else _running_sum(dgc, masks.pos, C, reverse=True)
+    return dq, dk, dv, dg, dbeta
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
@@ -715,8 +834,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
 
     T, C, D, mm = _TILE, tiles.chunk, q_ref.shape[-1], q_ref.dtype
     trips, per = tiles.rows // T, T // C
-    masks = _Masks(T, C, D)
-    lower = masks.below | masks.eye
+    head_decay = g_ref.shape != q_ref.shape
+    masks = _Masks(T, C, D, head_decay)
+    lower = masks.lower
 
     @pl.when(pl.program_id(2) == 0)
     def _nothing_after_the_last_group():
@@ -725,7 +845,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
     def values(t):
         rows = _rows_of(t, T)
         return _tile_values(q_ref[0, rows, :], k_ref[0, rows, :],
-                            v_ref[0, rows, :], g_ref[0, rows, :],
+                            v_ref[0, rows, :], _decays_of(g_ref, t, rows, masks),
                             beta_ref[0, 0, 0, _row_of(t), :], masks, eps)
 
     # the group's chunk states again from its start state, and what the
@@ -767,11 +887,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
             dw[c], dqg[c] = -back_m[:C], back_m[C:]
             dkd[c] = _dot(u2[cut], dmc, _NT)
             dgamma[c] = jnp.sum(m * dm, axis=-1, keepdims=True)
-            dm = _across(x["gamma"][c], masks.eye_d, 1) * dm + _dot(
+            dm = _times_decay(x["gamma"][c], dm, masks) + _dot(
                 jnp.concatenate([qg[cut], w[cut]], axis=0),
                 jnp.concatenate([do[cut], -du[c]], axis=0), _TN)
         dm_scr[...] = dm
-        dgamma = [_across(t_, masks.eye_d, 0) for t_ in dgamma]
+        dgamma = [jnp.sum(t_, axis=0, keepdims=True) if head_decay
+                  else _across(t_, masks.eye_d, 0) for t_ in dgamma]
         stacked = [jnp.concatenate(parts, axis=0)
                    for parts in (dw, du, dqg, dkd)]
         dq, dk, dv, dg, dbeta = _tile_pull(
@@ -781,7 +902,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
         dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
         dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
         dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
-        dg_ref[0, rows, :] = dg
+        if head_decay:
+            dg_ref[0, 0, 0, _row_of(t), :] = dg
+        else:
+            dg_ref[0, rows, :] = dg
         dbeta_ref[0, 0, 0, _row_of(t), :] = _across(dbeta, masks.eye, 0)
 
     _walk(trips, back, tiles.unroll)
@@ -792,36 +916,43 @@ def _compiler_params(need):
                                    need)
 
 
-def _specs(tiles, D, last=None):
+def _specs(tiles, D, last=None, ratio=1):
     """The block specs of a head's group of rows: of [B, S, H D] values,
-    of beta and dbeta [B, H, groups, tiles, T], of the states [B, H,
-    groups, D, D].  `last`: the grid runs the groups last to first."""
+    of beta and dbeta (and a head's one decay) [B, H, groups, tiles, T], of
+    the states [B, H, groups, D, D], and of q and k [B, S, Hk D] at `ratio`
+    value heads a key head: value head h reads the lane block h // ratio,
+    nothing is repeated in HBM.  `last`: the grid runs the groups last to
+    first."""
     import jax.experimental.pallas as pl
 
     def group(s):
         return s if last is None else last - s
 
     R, T = tiles.rows, _TILE
-    return (pl.BlockSpec((1, R, D), lambda b, h, s: (b, group(s), h)),
+    wide = pl.BlockSpec((1, R, D), lambda b, h, s: (b, group(s), h))
+    return (wide,
             pl.BlockSpec((1, 1, 1, R // T, T),
                          lambda b, h, s: (b, h, group(s), 0, 0)),
             pl.BlockSpec((1, 1, 1, D, D),
-                         lambda b, h, s: (b, h, group(s), 0, 0)))
+                         lambda b, h, s: (b, h, group(s), 0, 0)),
+            wide if ratio == 1 else pl.BlockSpec(
+                (1, R, D), lambda b, h, s: (b, group(s), h // ratio)))
 
 
 @functools.lru_cache(maxsize=64)
-def _fwd_call(B, S, H, D, tiles, dtype, eps, interpret):
+def _fwd_call(B, S, H, D, tiles, dtype, eps, interpret, ratio=1,
+              head_decay=False):
     """Memoized, as kernels/flash_attention.py::_fwd_call: every site of
     one shape shares one kernel payload."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R = tiles.rows
-    wide, gate, state = _specs(tiles, D)
+    wide, gate, state, key = _specs(tiles, D, ratio=ratio)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, tiles=tiles, eps=eps),
         grid=(B, H, S // R),
-        in_specs=[wide, wide, wide, wide, gate],
+        in_specs=[key, key, wide, gate if head_decay else wide, gate],
         out_specs=[wide, state],
         out_shape=[jax.ShapeDtypeStruct((B, S, H * D), jnp.dtype(dtype)),
                    jax.ShapeDtypeStruct((B, H, S // R, D, D), F32)],
@@ -832,21 +963,26 @@ def _fwd_call(B, S, H, D, tiles, dtype, eps, interpret):
 
 
 @functools.lru_cache(maxsize=64)
-def _bwd_call(B, S, H, D, tiles, dtype, eps, interpret):
+def _bwd_call(B, S, H, D, tiles, dtype, eps, interpret, ratio=1,
+              head_decay=False):
+    """dq and dk come out a VALUE head each, [B, S, H D]: _kernels_bwd sums
+    a key head's."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R, T = tiles.rows, _TILE
-    wide, gate, state = _specs(tiles, D, last=S // R - 1)
+    wide, gate, state, key = _specs(tiles, D, last=S // R - 1, ratio=ratio)
     dtype = jnp.dtype(dtype)
+    by_tiles = jax.ShapeDtypeStruct((B, H, S // R, R // T, T), F32)
+    decay = gate if head_decay else wide
     return pl.pallas_call(
         functools.partial(_bwd_kernel, tiles=tiles, eps=eps),
         grid=(B, H, S // R),
-        in_specs=[wide, wide, wide, wide, gate, state, wide],
-        out_specs=[wide, wide, wide, wide, gate],
+        in_specs=[key, key, wide, decay, gate, state, wide],
+        out_specs=[wide, wide, wide, decay, gate],
         out_shape=[jax.ShapeDtypeStruct((B, S, H * D), dtype)] * 3
-        + [jax.ShapeDtypeStruct((B, S, H * D), F32),
-           jax.ShapeDtypeStruct((B, H, S // R, R // T, T), F32)],
+        + [by_tiles if head_decay
+           else jax.ShapeDtypeStruct((B, S, H * D), F32), by_tiles],
         scratch_shapes=[pltpu.VMEM((D, D), F32), pltpu.VMEM((D, D), F32),
                         pltpu.VMEM((R // tiles.chunk, D, D), F32)]
         + [pltpu.VMEM((R, D), dtype)] * 4
@@ -865,44 +1001,92 @@ def _beta_by_tiles(beta, tiles):
         B, H, S // tiles.rows, tiles.rows // _TILE, _TILE)
 
 
+def _from_tiles(t, like):
+    """_beta_by_tiles back: [B, S, H] in `like`'s dtype."""
+    B, S, H = like.shape
+    return jnp.moveaxis(t.reshape(B, H, S), 1, 2).astype(like.dtype)
+
+
+def _calls_form(q, v, g, heads):
+    """(value heads a key head, whether a head has one decay): the two
+    calls' last arguments."""
+    key_heads, head_decay = form(q, v, g, heads)
+    return heads // key_heads, head_decay
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _kernels(q, k, v, g, beta, heads, tiles, eps, interpret):
     return _kernels_fwd(q, k, v, g, beta, heads, tiles, eps, interpret)[0]
 
 
 def _kernels_fwd(q, k, v, g, beta, heads, tiles, eps, interpret):
-    B, S, width = q.shape
+    B, S, width = v.shape
+    ratio, head_decay = _calls_form(q, v, g, heads)
     call = _fwd_call(B, S, heads, width // heads, tiles, str(q.dtype), eps,
-                     interpret)
-    out, starts = keep(*call(q, k, v, g, _beta_by_tiles(beta, tiles)))
+                     interpret, ratio, head_decay)
+    out, starts = keep(*call(
+        q, k, v, _beta_by_tiles(g, tiles) if head_decay else g,
+        _beta_by_tiles(beta, tiles)))
     return out, (q, k, v, g, beta, starts)
 
 
 def _kernels_bwd(heads, tiles, eps, interpret, res, do):
     q, k, v, g, beta, starts = res
-    B, S, width = q.shape
-    call = _bwd_call(B, S, heads, width // heads, tiles, str(q.dtype), eps,
-                     interpret)
-    dq, dk, dv, dg, dbeta = call(q, k, v, g, _beta_by_tiles(beta, tiles),
-                                 starts, do.astype(q.dtype))
-    return dq, dk, dv, dg, jnp.moveaxis(
-        dbeta.reshape(B, heads, S), 1, 2).astype(beta.dtype)
+    B, S, width = v.shape
+    D = width // heads
+    ratio, head_decay = _calls_form(q, v, g, heads)
+    call = _bwd_call(B, S, heads, D, tiles, str(q.dtype), eps, interpret,
+                     ratio, head_decay)
+    dq, dk, dv, dg, dbeta = call(
+        q, k, v, _beta_by_tiles(g, tiles) if head_decay else g,
+        _beta_by_tiles(beta, tiles), starts, do.astype(q.dtype))
+
+    def a_key_heads(t):          # its value heads' cotangents, summed fp32
+        if ratio == 1:
+            return t
+        return jnp.sum(t.reshape(B, S, heads // ratio, ratio, D), axis=3,
+                       dtype=F32).astype(t.dtype).reshape(q.shape)
+
+    return (a_key_heads(dq), a_key_heads(dk), dv,
+            _from_tiles(dg, g) if head_decay else dg,
+            _from_tiles(dbeta, beta))
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
+def form(q, v, g, heads: int):
+    """(key heads, whether a head has ONE decay) of a site, read from its
+    operands: q (and k) [B, S, Hk D] against v [B, S, H D], and g [B, S, H]
+    (a head's) against [B, S, H D] (a key channel's).  A channel's decay
+    goes with as many key heads as value heads, and nothing else."""
+    D = v.shape[-1] // heads
+    key_heads = q.shape[-1] // D
+    head_decay = g.shape[-1] == heads != v.shape[-1]
+    if q.shape[-1] % D or heads % key_heads or (
+            not head_decay and (g.shape != v.shape or key_heads != heads)):
+        raise ValueError(
+            f"gated_delta_attention: q {q.shape}, v {v.shape}, g {g.shape} "
+            f"at {heads} heads: g is [B, S, H] with q, k at a divisor of H "
+            "heads, or [B, S, H D] with q, k at H")
+    return key_heads, head_decay
+
+
 def gated_delta_attention(q, k, v, g, beta, heads: int, chunk: int = CHUNK,
                           eps: float = 1e-6, force: str = "auto",
                           rows=None, unroll=None):
-    """Out [B, S, H D] in q's dtype of q, k, v [B, S, H D] (one dtype: the
-    matmuls' operands), the log-decay g [B, S, H D] (<= 0; taken to fp32)
-    and beta [B, S, H]: each head's q and k to unit length (fp32, `eps`
-    under the root), then the module's recurrence, `chunk` tokens at a
-    time.  The engine is read from the shape (`engine`): the kernel pair
-    on the values as they come, or the jax.numpy scans on regrouped
+    """Out [B, S, H D] in q's dtype of q, k, v (one dtype: the matmuls'
+    operands), the log-decay g (<= 0; taken to fp32) and beta [B, S, H]:
+    each head's q and k to unit length (fp32, `eps` under the root), then
+    the module's recurrence, `chunk` tokens at a time.  Two forms, read
+    from the shapes (`form`): g [B, S, H D], a decay for every key channel,
+    with q, k, v [B, S, H D]; or g [B, S, H], ONE decay a head, with q, k
+    [B, S, Hk D] at Hk <= H key heads, value head j reading key head j //
+    (H / Hk).  The engine is read from the shape (`engine`): the kernel
+    pair on the values as they come, or the jax.numpy scans on regrouped
     copies; `force`, `rows` and `unroll` are the tests' and the probe's."""
-    B, S, width = q.shape
+    B, S, width = v.shape
+    form(q, v, g, heads)
     chunk = plan(B, S, heads, width // heads, chunk)["chunk"]
     tiles = engine(B, S, heads, width // heads, chunk, q.dtype, force, rows,
                    unroll)
@@ -915,17 +1099,25 @@ def gated_delta_attention(q, k, v, g, beta, heads: int, chunk: int = CHUNK,
 def _scan_by_groups(q, k, v, g, beta, heads: int, chunk: int, eps: float):
     """The jax.numpy engine: q, k, v, g regrouped to [groups, group, B,
     H, C, D] (copies in HBM, and the custom backward's residuals) for the
-    two scans of _scan."""
-    B, S, width = q.shape
+    two scans of _scan.  With one decay a head the heads are two axes, (Hk,
+    r) of v, g and beta against (Hk, 1) of q and k: a key head's q and k
+    broadcast to its r value heads inside _local, and their cotangents come
+    back summed."""
+    B, S, width = v.shape
     D = width // heads
     tiles = plan(B, S, heads, D, chunk)
     C, n = tiles["chunk"], tiles["group"]
+    key_heads, head_decay = form(q, v, g, heads)
+    H = (key_heads, heads // key_heads) if head_decay else (heads,)
+    at = 3 + len(H)
 
-    def grouped(t, last):        # [B, S, H (D)] -> [groups, n, B, H, C (, D)]
-        t = t.reshape((B, S // (n * C), n, C, heads) + last)
-        return jnp.moveaxis(t, (1, 2, 4), (0, 1, 3))
+    def grouped(t, heads, last):   # [B, S, H (D)] -> [groups, n, B, H, C (, D)]
+        t = t.reshape((B, S // (n * C), n, C) + heads + last)
+        return jnp.moveaxis(t, (1, 2, 3), (0, 1, at))
 
-    out = _scan(*(grouped(t, (D,)) for t in (q, k, v)),
-                grouped(g.astype(F32), (D,)),
-                grouped(beta.astype(F32), ()), float(eps))
-    return jnp.moveaxis(out, (0, 1, 3), (1, 2, 4)).reshape(B, S, width)
+    keys = (key_heads, 1) if head_decay else H
+    out = _scan(grouped(q, keys, (D,)), grouped(k, keys, (D,)),
+                grouped(v, H, (D,)),
+                grouped(g.astype(F32), H, () if head_decay else (D,)),
+                grouped(beta.astype(F32), H, ()), float(eps))
+    return jnp.moveaxis(out, (0, 1, at), (1, 2, 3)).reshape(B, S, width)

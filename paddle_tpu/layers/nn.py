@@ -38,6 +38,7 @@ __all__ = [
     "compressed_conv_qkv",
     "kda_conv_decay",
     "kda_gated_norm",
+    "gated_delta_decay",
     "mhc_streams",
     "mhc_maps",
     "mhc_maps_read",
@@ -1520,16 +1521,19 @@ def differential_attention(q, k, v, lambda_q1, lambda_k1, lambda_q2,
 
 
 def gated_delta_attention(q, k, v, g, beta, heads, chunk=64, name=None):
-    """Kimi Delta Attention's recurrence (a gated delta rule with a decay
-    for every key channel) over q, k, v [B, S, H D], the log-decay g [B, S,
-    H D] (<= 0, fp32) and beta [B, S, H]: each head's q and k to unit
-    length; a head's state M [D, D] from 0; a token does M~ = diag(exp(g))
-    M, M = M~ + beta k (v - M~^T k)^T, o = D^-1/2 M^T q.  [B, S, H D] out;
-    `chunk` tokens at a time (a power of two that divides S), backward
-    included (TPU-native; ops/linear_attention_ops.py
+    """The gated delta rule's recurrence over q, k, v [B, S, H D], the
+    log-decay g (<= 0, fp32) and beta [B, S, H]: each head's q and k to
+    unit length; a head's state M [D, D] from 0; a token does M~ =
+    diag(exp(g)) M, M = M~ + beta k (v - M~^T k)^T, o = D^-1/2 M^T q.  Two
+    forms, read from the shapes: g [B, S, H D], a decay for every key
+    channel (Kimi Delta Attention); or g [B, S, H], ONE decay a head, with
+    q, k [B, S, Hk D] at Hk <= H key heads, value head j reading key head
+    j // (H / Hk) (Gated DeltaNet; layers.gated_delta_decay makes that g).
+    [B, S, H D] out; `chunk` tokens at a time (a power of two that divides
+    S), backward included (TPU-native; ops/linear_attention_ops.py
     gated_delta_attention, kernels/gated_delta.py)."""
-    helper = LayerHelper("gated_delta_attention", input=q, name=name)
-    out = helper.create_variable_for_type_inference(q.dtype)
+    helper = LayerHelper("gated_delta_attention", input=v, name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
     helper.append_op(
         type="gated_delta_attention",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
@@ -1568,22 +1572,39 @@ def kda_conv_decay(q, k, v, f, conv_q_w, conv_k_w, conv_v_w, dt_bias, a_log,
 
 
 def kda_gated_norm(x, gate, gate_bias, scale, heads, epsilon=1e-6,
-                   name=None):
+                   name=None, gate_activation="sigmoid"):
     """What Kimi Delta Attention does after its recurrence, as one op: x
     [B, S, H D] normalised a head (rms_norm's formula over D, one learned
     scale [D]) times sigmoid(gate [B, S, H D] + gate_bias [H D]); [B, S,
     H D] out (TPU-native; ops/linear_attention_ops.py kda_gated_norm: for
     a TPU a Pallas kernel pair where D is whole 128-lane vectors and S
-    whole tiles, kernels/kda_mix.py, jax.numpy elsewhere)."""
+    whole tiles, kernels/kda_mix.py, jax.numpy elsewhere).  Gated
+    DeltaNet's: `gate_bias` None and `gate_activation` "silu", the same
+    norm a head times silu(gate)."""
     helper = LayerHelper("kda_gated_norm", input=x, name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x], "Gate": [gate], "Scale": [scale]}
+    attrs = {"heads": int(heads), "epsilon": float(epsilon)}
+    if gate_bias is not None:
+        inputs["GateBias"] = [gate_bias]
+    if gate_activation != "sigmoid":
+        attrs["gate_activation"] = str(gate_activation)
+    helper.append_op(type="kda_gated_norm", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def gated_delta_decay(x, a_log, dt_bias, name=None):
+    """Gated DeltaNet's log-decay, one a head: -exp(a_log [H]) softplus(x
+    [B, S, H] + dt_bias [H]), fp32; gated_delta_attention's g in its
+    head-decay form (TPU-native; ops/linear_attention_ops.py
+    gated_delta_decay)."""
+    helper = LayerHelper("gated_delta_decay", input=x, name=name)
+    out = helper.create_variable_for_type_inference("float32")
     helper.append_op(
-        type="kda_gated_norm",
-        inputs={"X": [x], "Gate": [gate], "GateBias": [gate_bias],
-                "Scale": [scale]},
-        outputs={"Out": [out]},
-        attrs={"heads": int(heads), "epsilon": float(epsilon)},
-    )
+        type="gated_delta_decay",
+        inputs={"X": [x], "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]}, attrs={})
     return out
 
 

@@ -31,6 +31,8 @@ from .sambay_decoder import (  # noqa: F401
     sambay_decoder, SambaYDecoderConfig)
 from .ssd_hybrid_decoder import (  # noqa: F401
     ssd_hybrid_decoder, SsdHybridDecoderConfig)
+from .gated_delta_decoder import (  # noqa: F401
+    gated_delta_decoder, GatedDeltaDecoderConfig)
 from .stacked_lstm import stacked_dynamic_lstm  # noqa: F401
 from .machine_translation import machine_translation  # noqa: F401
 from .se_resnext import se_resnext  # noqa: F401

@@ -1,10 +1,13 @@
 """Linear attention: sequence mixers that carry a state of fixed size from
 token to token where attention carries the keys.  TPU-native additions (the
-2018 reference has no such op): the gated delta rule with a decay for every
-key channel (Kimi Delta Attention) and the short causal convolution that
-precedes it; and, as one op each, what streams [S, H D] values through the
-vector unit before that recurrence (the three convolutions and the decay:
-kda_conv_decay) and after it (the gated norm a head: kda_gated_norm).
+2018 reference has no such op): the gated delta rule, with a decay for
+every key channel (Kimi Delta Attention) or ONE decay a head whose keys may
+serve several value heads (Gated DeltaNet), one op whose form is read from
+its operands, and the short causal convolution that precedes it; and, as
+one op each, what streams [S, H D] values through the vector unit before
+that recurrence (the three convolutions and the channels' decay:
+kda_conv_decay; a head's decay: gated_delta_decay) and after it (the gated
+norm a head, under a sigmoid with a bias or a SiLU: kda_gated_norm).
 """
 
 from __future__ import annotations
@@ -20,6 +23,7 @@ from .attention_ops import causal_conv1d
 from .common import ACTS, data, in_desc, same_shape, set_output
 
 _CONV_ACTS = {**ACTS, "silu": jax.nn.silu}
+_GATES = {"sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu}
 
 
 @register_op("short_conv1d", infer_shape=same_shape("X", "Out"),
@@ -39,9 +43,9 @@ def _short_conv1d(ctx, ins, attrs):
 
 
 def _gated_delta_infer(op, block):
-    q = in_desc(op, block, "Q")
-    if q is not None:
-        set_output(block, op, "Out", list(q.shape), q.dtype)
+    v = in_desc(op, block, "V")
+    if v is not None:
+        set_output(block, op, "Out", list(v.shape), v.dtype)
 
 
 @register_op("gated_delta_attention", infer_shape=_gated_delta_infer,
@@ -71,29 +75,42 @@ def _gated_delta_attention(ctx, ins, attrs):
     everywhere else), `state_bytes` (one chunk boundary's states), `kept`
     and `kept_bytes` (what it holds through that recomputation) and the
     static `flops` and `moved_bytes` of the site's forward and backward;
-    the context's `kept` counts the values."""
+    the context's `kept` counts the values.
+
+    The second form (Gated DeltaNet, arXiv:2412.06464), read from the
+    operands and from no attribute: G [B, S, H], ONE decay a head, with Q,
+    K [B, S, Hk D] at Hk <= H key heads, value head j reading key head j //
+    (H / Hk); the same recurrence with exp(g_t) a scalar, the same engines,
+    kept values and backward (dG [B, S, H]; dQ, dK summed over a key
+    head's value heads), neither a [B, S, H D] decay nor a repeated q or k
+    anywhere.  It stands under the name scope `gdn.scan` and its span is
+    `gdn.lower`: `kda.lower`'s fields and `decay` head, `key_heads`."""
     from ..kernels import gated_delta as kda
 
     q, k, v = amp.mxu_operands(*(data(ins[s][0]) for s in ("Q", "K", "V")))
     g, beta = data(ins["G"][0]), data(ins["Beta"][0])
     H = int(attrs["heads"])
-    B, S, width = q.shape
+    B, S, width = v.shape
     D = width // H
+    key_heads, head_decay = kda.form(q, v, g, H)
     tiles = kda.plan(B, S, H, D, int(attrs.get("chunk") or kda.CHUNK))
     size = jnp.dtype(q.dtype).itemsize
     taken = kda.engine(B, S, H, D, tiles["chunk"], q.dtype)
     chosen = {} if taken is None else dict(
         rows=taken.rows, fwd_vmem_bytes=taken.fwd_vmem_bytes,
         bwd_vmem_bytes=taken.bwd_vmem_bytes)
-    with span("kda.lower", heads=H, head_dim=D, sq=int(S),
+    family = "gdn" if head_decay else "kda"
+    said = dict(decay="head", key_heads=key_heads) if head_decay else {}
+    with span(f"{family}.lower", heads=H, head_dim=D, sq=int(S),
               engine="xla" if taken is None else "pallas",
               state_bytes=kda.state_bytes(B, H, D), kept=",".join(kda.KEPT),
               kept_bytes=kda.kept_bytes(
                   B, S, H, D, tiles["chunks"] // tiles["group"], size),
-              flops=kda.flops(B, S, H, D, tiles["chunk"]),
-              moved_bytes=kda.moved_bytes(B, S, H, D, size), **tiles,
-              **chosen), \
-            jax.named_scope("kda.scan"):
+              flops=kda.flops(B, S, H, D, tiles["chunk"], key_heads),
+              moved_bytes=kda.moved_bytes(B, S, H, D, size, key_heads,
+                                          head_decay), **tiles,
+              **chosen, **said), \
+            jax.named_scope(f"{family}.scan"):
         ctx.kept += len(kda.KEPT)
         out = kda.gated_delta_attention(
             q, k.astype(q.dtype), v.astype(q.dtype), g, beta, heads=H,
@@ -115,18 +132,21 @@ def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads):
     return conv(q, wq), conv(k, wk), conv(v, wv), g
 
 
-def gated_norm(o, gate, gate_bias, scale, heads, eps):
+def gated_norm(o, gate, gate_bias, scale, heads, eps, activation="sigmoid"):
     """The op kda_gated_norm's arithmetic (its docstring), in jax.numpy,
-    in o's dtype: the norm a HEAD first, then a sigmoid gate.  Mamba-2's
-    gated norm (the silu gate first, then one mean square over the whole
-    width) is ops/state_space_ops.py::gated_rms_norm."""
+    in o's dtype: the norm a HEAD first, then the gate, a sigmoid (of gate
+    + gate_bias) or a SiLU (no bias).  Mamba-2's gated norm (the silu gate
+    first, then one mean square over the whole width) is
+    ops/state_space_ops.py::gated_rms_norm."""
     B, S, C = o.shape
     acc = amp.stats_dtype(o)
     x = o.astype(acc).reshape(B, S, heads, C // heads)
     y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
                           + eps) * scale.astype(acc)
-    s = jax.nn.sigmoid(gate.astype(acc) + gate_bias.astype(acc))
-    return (y.reshape(B, S, C) * s).astype(o.dtype)
+    gate = gate.astype(acc)
+    if gate_bias is not None:
+        gate = gate + gate_bias.astype(acc)
+    return (y.reshape(B, S, C) * _GATES[activation](gate)).astype(o.dtype)
 
 
 def _conv_decay_infer(op, block):
@@ -181,6 +201,18 @@ def _kda_conv_decay(ctx, ins, attrs):
     return dict(zip(("QOut", "KOut", "VOut", "G"), ([o] for o in outs)))
 
 
+@register_op("gated_delta_decay", infer_shape=same_shape("X", "Out"),
+             diff_inputs=["X", "ALog", "DtBias"])
+def _gated_delta_decay(ctx, ins, attrs):
+    """Gated DeltaNet's log-decay, ONE a head: Out = -exp(ALog [H])
+    softplus(X + DtBias [H]) of X [B, S, H], fp32 whatever X comes in
+    (gated_delta_attention's G in its head-decay form).  kda_conv_decay
+    makes Kimi Delta Attention's, one for every key channel."""
+    x, a_log, dt_bias = (data(ins[s][0]).astype(jnp.float32)
+                         for s in ("X", "ALog", "DtBias"))
+    return {"Out": [-jnp.exp(a_log) * jax.nn.softplus(x + dt_bias)]}
+
+
 @register_op("kda_gated_norm", infer_shape=same_shape("X", "Out"),
              diff_inputs=["X", "Gate", "GateBias", "Scale"])
 def _kda_gated_norm(ctx, ins, attrs):
@@ -191,19 +223,28 @@ def _kda_gated_norm(ctx, ins, attrs):
     and the gate in fp32, Out in X's dtype.  The engine as kda_conv_decay
     reads it (kernels/kda_mix.py::norm_tiles: D whole 128-lane vectors): a
     head's statistic stays in the tile, the backward reads X, Gate and the
-    cotangent alone; `kda.mix.lower` with `what` gated_norm."""
+    cotangent alone; `kda.mix.lower` with `what` gated_norm.
+
+    Under `gate_activation` silu, with no GateBias, it is Gated DeltaNet's:
+    the same norm a head times silu(Gate).  That rule has no kernel pair
+    yet: the site says `engine` xla (the jax.numpy arithmetic, which the
+    compiler fuses into two passes over the rows)."""
     from ..kernels import engine, kda_mix
 
-    args = [data(ins[s][0]) for s in ("X", "Gate", "GateBias", "Scale")]
-    args += [int(attrs["heads"]), float(attrs.get("epsilon", 1e-6))]
+    bias = ins.get("GateBias", [None])[0]
+    act = str(attrs.get("gate_activation") or "sigmoid")
+    args = [data(ins["X"][0]), data(ins["Gate"][0]),
+            None if bias is None else data(bias), data(ins["Scale"][0]),
+            int(attrs["heads"]), float(attrs.get("epsilon", 1e-6))]
     o, gate = args[:2]
+    kimis = act == "sigmoid" and bias is not None
     out = engine.site(
         "kda.mix.lower", kda_mix.Tiles._fields, ctx.mesh,
         lambda: kda_mix.norm_tiles(o.shape[1], o.shape[2],
                                    o.shape[2] // args[4], o.dtype)
-        if engine.one_dtype(o, gate) else None,
+        if kimis and engine.one_dtype(o, gate) else None,
         lambda tiles, interpret: kda_mix.gated_norm(*args, tiles, interpret),
-        lambda: gated_norm(*args), what="gated_norm",
+        lambda: gated_norm(*args, act), what="gated_norm",
         moved_bytes=kda_mix.norm_moved_bytes(
             o, gate, bool(attrs.get("@recompute@"))))
     return {"Out": [out]}

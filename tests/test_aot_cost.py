@@ -203,6 +203,24 @@ def _flash_mla():
         argnums=(0, 1, 2)), (q, q, v)
 
 
+def _flash_gated(dtype, heads=16):
+    """qwen3-next-80b-a3b's attention site at the cell's size: 16 query
+    heads on 2 K/V heads of 256 over 8192 rows, causal, forward and Pallas
+    backward; in bf16 as the step runs it and in fp32 as the benchmark's
+    readers lower it once more (PERF.md 7 (s)): a plan at both widths.  In
+    fp32 no block worth a grid step fits, and the engine is read from the
+    site's scores (_bwd_chunk_rows): 16 heads' 4.3 GB are the Pallas
+    kernel's at its smaller blocks, 8 heads' 2 GiB still XLA's."""
+    from paddle_tpu.kernels import flash_attention
+
+    q = _sds((1, heads, 8192, 256), dtype)
+    kv = _sds((1, heads // 8, 8192, 256), dtype)
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, force="pallas").astype(jnp.float32)),
+        argnums=(0, 1, 2)), (q, kv, kv)
+
+
 def _flash_band():
     """mellum2-12b-a2.5b's sliding site at the cell's size (PR 59): 32
     query heads on 4 K/V heads of 128 over 16384 rows, window 1024, the
@@ -303,6 +321,27 @@ def _kda_scan(backward):
     S, H, D = 4096, 32, 128
     args = (_sds((1, S, H * D), jnp.bfloat16),) * 3 + (
         _sds((1, S, H * D), jnp.float32), _sds((1, S, H), jnp.float32))
+
+    def fwd(*a):
+        return gated_delta.gated_delta_attention(*a, heads=H, force="pallas")
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
+                    argnums=tuple(range(5))), args
+
+
+def _gdn_scan(backward):
+    """qwen3-next-80b-a3b's chunk scan at the cell's shape (ONE decay a
+    head: g [S, 32]; 32 value heads of 128 over 16 key heads, S 8192,
+    chunks of 64, bf16 operands): kernels/gated_delta.py's pair in its
+    head-decay form, q and k read through the index maps."""
+    from paddle_tpu.kernels import gated_delta
+
+    S, H, Hk, D = 8192, 32, 16, 128
+    args = (_sds((1, S, Hk * D), jnp.bfloat16),) * 2 + (
+        _sds((1, S, H * D), jnp.bfloat16), _sds((1, S, H), jnp.float32),
+        _sds((1, S, H), jnp.float32))
 
     def fwd(*a):
         return gated_delta.gated_delta_attention(*a, heads=H, force="pallas")
@@ -435,11 +474,19 @@ _MAIN_PATH_KERNELS = {
     "ssd_scan_bwd_pallas_granite": lambda: _ssd_scan(True),
     "kda_scan_fwd_kimi": lambda: _kda_scan(False),
     "kda_scan_bwd_pallas_kimi": lambda: _kda_scan(True),
+    "gdn_scan_fwd_qwen3next": lambda: _gdn_scan(False),
+    "gdn_scan_bwd_pallas_qwen3next": lambda: _gdn_scan(True),
     "cca_mix_fwd_zaya": lambda: _cca_mix(False),
     "cca_mix_bwd_pallas_zaya": lambda: _cca_mix(True),
     "sparse_attention_bwd_pallas_keye": _sparse_attention,
     "flash_bwd_pallas_moonlight_192_128": _flash_mla,
     "flash_band_bwd_pallas_mellum_window1024": _flash_band,
+    "flash_bwd_pallas_qwen3next_head256_bf16":
+        lambda: _flash_gated(jnp.bfloat16),
+    "flash_bwd_pallas_qwen3next_head256_fp32":
+        lambda: _flash_gated(jnp.float32),
+    "flash_bwd_xla_head256_fp32_8_heads":
+        lambda: _flash_gated(jnp.float32, heads=8),
     "held_experts_moonlight": _held_experts,
     "flash_fwd_transformer_base": lambda: _flash_fwd((96, 8, 256, 64)),
     "flash_fwd_long_context": lambda: _flash_fwd((2, 8, 2048, 64)),

@@ -1,7 +1,9 @@
-"""The op gated_delta_attention (Kimi Delta Attention's recurrence as a scan
+"""The op gated_delta_attention (the gated delta rule's recurrence as a scan
 over chunks, kernels/gated_delta.py) against the recurrence one token at a
-time, forward and every gradient; the short causal convolution; what
-`kda.lower` says of a site."""
+time, forward and every gradient, in both of its forms: a decay for every
+key channel (Kimi Delta Attention) and ONE decay a head with q and k at
+fewer heads than v (Gated DeltaNet); the short causal convolution; what
+`kda.lower` / `gdn.lower` say of a site."""
 
 import os
 import sys
@@ -21,9 +23,16 @@ from paddle_tpu.kernels import gated_delta as kda
 
 
 def token_recurrence(q, k, v, g, beta, heads, eps=1e-6):
-    """The module docstring's three lines, S dependent steps."""
-    B, S, width = q.shape
+    """The module docstring's three lines, S dependent steps.  The
+    head-decay form the plain way: a head's one decay repeated to its
+    channels, a key head's q and k repeated to its value heads."""
+    B, S, width = v.shape
     D = width // heads
+    if g.shape[-1] == heads != width:
+        g = jnp.repeat(g, D, axis=-1)
+    if q.shape[-1] != width:
+        q, k = (jnp.repeat(t.reshape(B, S, -1, D), width // t.shape[-1],
+                           axis=2).reshape(B, S, width) for t in (q, k))
 
     def split(t):
         return jnp.moveaxis(t.reshape(B, S, heads, D), 1, 0)
@@ -46,19 +55,31 @@ def token_recurrence(q, k, v, g, beta, heads, eps=1e-6):
     return jnp.moveaxis(out, 0, 1).reshape(B, S, width) * D ** -0.5
 
 
-def _inputs(B, S, H, D, seed, rate=1.0, shift=-2.0, alike=0.0):
+def _inputs(B, S, H, D, seed, rate=1.0, shift=-2.0, alike=0.0,
+            key_heads=None):
     """q, k, v ~ N(0, 1) (`alike`: a share of every key that all keys
-    have in common), g = -rate * softplus(N(shift, 1)), beta in (0, 1)."""
+    have in common), g = -rate * softplus(N(shift, 1)), beta in (0, 1).
+    With `key_heads` the head-decay form: q, k [B, S, key_heads D] and g
+    [B, S, H], `rate` a number or one a head."""
     r = np.random.RandomState(seed)
-    q, k, v = (r.randn(B, S, H * D) for _ in range(3))
-    k = k + alike * np.abs(r.randn(1, 1, H * D)) * 10
-    g = -rate * np.log1p(np.exp(r.randn(B, S, H * D) + shift))
+    Hk = key_heads or H
+    q, k = (r.randn(B, S, Hk * D) for _ in range(2))
+    v = r.randn(B, S, H * D)
+    k = k + alike * np.abs(r.randn(1, 1, Hk * D)) * 10
+    g = -np.asarray(rate) * np.log1p(np.exp(
+        r.randn(B, S, H * (1 if key_heads else D)) + shift))
     beta = 1.0 / (1.0 + np.exp(-(r.randn(B, S, H) + 3 * alike)))
     return tuple(jnp.asarray(t, jnp.float32) for t in (q, k, v, g, beta))
 
 
+def from_weak_to_strong(H):
+    """One decay rate a head, from e^-0.001 to e^-21 a token (softplus(3 +
+    N(0, 1)) is ~3: with `shift` 3 the rates below are a third of these)."""
+    return np.geomspace(0.001, 21.0, H) / 3.0
+
+
 def _held_to_the_recurrence(args, H, chunk, rtol=2e-5):
-    weight = jnp.asarray(np.random.RandomState(1).randn(*args[0].shape),
+    weight = jnp.asarray(np.random.RandomState(1).randn(*args[2].shape),
                          jnp.float32)
 
     def chunked(*a):
@@ -80,29 +101,58 @@ def _held_to_the_recurrence(args, H, chunk, rtol=2e-5):
             err_msg=name)
 
 
-@pytest.mark.parametrize("B,S,H,D,chunk", [
-    (2, 128, 2, 16, 64),        # two chunks of 64
-    (1, 64, 2, 8, 16),          # a smaller chunk
-    (1, 64, 1, 8, 64),          # S of one chunk
-    (1, 32, 3, 8, 128),         # a chunk longer than S: S is the chunk
+@pytest.mark.parametrize("B,S,H,D,chunk,key_heads", [
+    (2, 128, 2, 16, 64, None),  # two chunks of 64
+    (1, 64, 2, 8, 16, None),    # a smaller chunk
+    (1, 64, 1, 8, 64, None),    # S of one chunk
+    (1, 32, 3, 8, 128, None),   # a chunk longer than S: S is the chunk
+    # ONE decay a head (g [B, S, H]), key heads 1 : 1, 1 : 2 and 1 : 4
+    (1, 64, 2, 8, 16, 2),
+    (2, 128, 4, 16, 64, 2),
+    (1, 64, 4, 8, 64, 1),
 ])
-def test_the_chunked_scan_is_the_token_recurrence(B, S, H, D, chunk):
-    """Forward and the gradients of q, k, v, g and beta."""
-    _held_to_the_recurrence(_inputs(B, S, H, D, seed=S + chunk), H, chunk)
+def test_the_chunked_scan_is_the_token_recurrence(B, S, H, D, chunk,
+                                                  key_heads):
+    """Forward and the gradients of q, k, v, g and beta (dq, dk summed over
+    a key head's value heads, dg [B, S, H] where g is)."""
+    _held_to_the_recurrence(
+        _inputs(B, S, H, D, seed=S + chunk, key_heads=key_heads), H, chunk)
 
 
-def test_a_strong_decay_that_exp_of_minus_gc_would_overflow():
+@pytest.mark.parametrize("key_heads", [None, 2])
+def test_a_strong_decay_that_exp_of_minus_gc_would_overflow(key_heads):
     """A_log large: channels that decay by e^-1500 inside a chunk, beside
     channels that hardly decay.  exp(-Gc), which the factored form K
     exp(Gc) (K exp(-Gc))^T needs, is inf there; the chunked scan forms
-    every exponent from a difference <= 0 and stays finite and right."""
-    args = _inputs(1, 256, 2, 16, seed=3, rate=16.0, shift=1.0)
+    every exponent from a difference <= 0 and stays finite and right.  A
+    head's one decay: four heads from e^-0.001 to e^-21 a token in one
+    input."""
+    if key_heads:
+        H = 4
+        args = _inputs(1, 256, H, 16, seed=3, rate=from_weak_to_strong(H),
+                       shift=3.0, key_heads=key_heads)
+        assert float(jnp.max(args[3][..., 0])) > -0.01
+    else:
+        H = 2
+        args = _inputs(1, 256, H, 16, seed=3, rate=16.0, shift=1.0)
     g = args[3]
     assert float(jnp.min(jnp.sum(g[:, :64], axis=1))) < -1000
     with np.errstate(over="ignore"):
         naive = np.exp(-np.cumsum(np.asarray(g[:, :64]), 1))
     assert not np.isfinite(naive).all()
-    _held_to_the_recurrence(args, 2, 64)
+    _held_to_the_recurrence(args, H, 64)
+
+
+def test_a_channels_decay_goes_with_as_many_key_heads_and_nothing_else():
+    q, k, v, g, beta = _inputs(1, 64, 4, 8, seed=1)
+    with pytest.raises(ValueError, match="a divisor of H"):
+        kda.gated_delta_attention(q[..., :16], k[..., :16], v, g, beta,
+                                  heads=4)
+    with pytest.raises(ValueError, match="a divisor of H"):
+        kda.gated_delta_attention(q[..., :24], k[..., :24], v, g[..., :4],
+                                  beta, heads=4)
+    assert kda.form(q[..., :16], v, g[..., :4], 4) == (2, True)
+    assert kda.form(q, v, g, 4) == (4, False)
 
 
 def test_keys_alike_and_beta_near_one():
@@ -156,6 +206,17 @@ def test_the_real_shapes_plan_and_counts():
     assert kda.moved_bytes(1, 4096, 32, 128, 2) == \
         (4 * 2 + 4) * wide + 4 * 4096 * 32 \
         + (7 * 2 + 8) * wide + 8 * 4096 * 32
+    # one decay a head over 16 key heads at S 8192 (qwen3next-train-gdn8k's
+    # site): q and k half as wide, g [S, 32], the two products once a key
+    # head: 0.54 GB where a decay a channel and repeated keys move 1.14
+    wide = 8192 * 4096
+    assert kda.moved_bytes(1, 8192, 32, 128, 2, 16, True) == \
+        3 * 2 * wide + 8 * 8192 * 32 + 5 * 2 * wide + 16 * 8192 * 32
+    assert kda.moved_bytes(1, 8192, 32, 128, 2) > 2.1 * kda.moved_bytes(
+        1, 8192, 32, 128, 2, 16, True)
+    a_value_head = 3 * 64 * 64 * 128 + 64 ** 3 // 3 + 6 * 64 * 128 * 128
+    assert kda.flops(1, 8192, 32, 128, 64, 16) == 3 * 128 * (
+        32 * a_value_head + 16 * 2 * 64 * 64 * 128)
 
 
 # ---------------------------------------------------------------------------
@@ -178,8 +239,9 @@ def _through_a_program(args, H, chunk, grads=False):
                    fetch_list=fetch)
 
 
-def test_the_op_and_its_gradients_through_the_executor():
-    args = _inputs(2, 64, 2, 8, seed=9)
+@pytest.mark.parametrize("key_heads", [None, 1])
+def test_the_op_and_its_gradients_through_the_executor(key_heads):
+    args = _inputs(2, 64, 2, 8, seed=9, key_heads=key_heads)
     got = _through_a_program(args, 2, 16, grads=True)
     want = token_recurrence(*args, heads=2)
     np.testing.assert_allclose(got[0], want, rtol=2e-5, atol=1e-6)
@@ -189,26 +251,36 @@ def test_the_op_and_its_gradients_through_the_executor():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
-def test_kda_lower_says_what_a_site_was_given():
+@pytest.mark.parametrize("key_heads", [None, 1])
+def test_kda_lower_says_what_a_site_was_given(key_heads):
     """One span an op: the plan, the engine, what survives a recomputation
-    and the static counts the roofline divides."""
-    args = _inputs(2, 128, 2, 16, seed=2)
+    and the static counts the roofline divides; of the head-decay form
+    under the name `gdn.lower`, with `decay` and `key_heads`."""
+    args = _inputs(2, 128, 2, 16, seed=2, key_heads=key_heads)
     fluid.flags._VALUES["FLAGS_observability"] = True
     try:
         observability.reset()
         _through_a_program(args, 2, 64)
-        spans = [dict(s.args) for s in
-                 observability.default_tracer().spans()
-                 if s.name == "kda.lower"]
+        spans = {name: [dict(s.args) for s in
+                        observability.default_tracer().spans()
+                        if s.name == name]
+                 for name in ("kda.lower", "gdn.lower")}
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = False
         observability.reset()
-    assert spans == [dict(
+    said = dict(
         heads=2, head_dim=16, sq=128, chunk=64, chunks=2, group=2,
         engine="xla", state_bytes=4 * 2 * 2 * 16 * 16, kept="out,states",
-        kept_bytes=4 * 2 * 128 * 32 + 1 * 4 * 2 * 2 * 16 * 16,
-        flops=kda.flops(2, 128, 2, 16, 64),
-        moved_bytes=kda.moved_bytes(2, 128, 2, 16, 4))]
+        kept_bytes=4 * 2 * 128 * 32 + 1 * 4 * 2 * 2 * 16 * 16)
+    if key_heads:
+        assert spans == {"kda.lower": [], "gdn.lower": [dict(
+            said, flops=kda.flops(2, 128, 2, 16, 64, 1),
+            moved_bytes=kda.moved_bytes(2, 128, 2, 16, 4, 1, True),
+            decay="head", key_heads=1)]}
+    else:
+        assert spans == {"gdn.lower": [], "kda.lower": [dict(
+            said, flops=kda.flops(2, 128, 2, 16, 64),
+            moved_bytes=kda.moved_bytes(2, 128, 2, 16, 4))]}
 
 
 def test_the_scan_keeps_its_output_and_states_through_a_recomputation():
@@ -268,3 +340,49 @@ def test_short_conv1d_is_the_causal_depthwise_convolution(taps, activation):
         jnp.asarray(x), jnp.asarray(w))
     np.testing.assert_allclose(got[1], ref[0], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(got[2], ref[1], rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# around the head-decay form: the decay itself and the SiLU-gated norm
+# ---------------------------------------------------------------------------
+def test_the_heads_decay_and_the_silu_gated_norm_through_the_executor():
+    """layers.gated_delta_decay is -exp(a_log) softplus(x + dt_bias), fp32;
+    layers.kda_gated_norm under gate_activation silu and no bias is the norm
+    a head times silu(gate); both with their gradients."""
+    r = np.random.RandomState(4)
+    B, S, H, D = 2, 6, 3, 4
+    feed = {"x": r.randn(B, S, H), "a_log": r.randn(H), "dt": r.randn(H),
+            "o": r.randn(B, S, H * D), "z": r.randn(B, S, H * D),
+            "w": 1 + 0.1 * r.randn(D)}
+    feed = {k: v.astype(np.float32) for k, v in feed.items()}
+    fluid.reset_default_env()
+    ins = {k: layers.data(k, list(v.shape), append_batch_size=False)
+           for k, v in feed.items()}
+    for t in ins.values():
+        t.stop_gradient = False
+    g = layers.gated_delta_decay(ins["x"], ins["a_log"], ins["dt"])
+    n = layers.kda_gated_norm(ins["o"], ins["z"], None, ins["w"], heads=H,
+                              epsilon=1e-6, gate_activation="silu")
+    loss = layers.elementwise_add(layers.reduce_sum(layers.square(g)),
+                                  layers.reduce_sum(layers.square(n)))
+    order = sorted(ins)
+    grads = fluid.calc_gradient(loss, [ins[k] for k in order])
+    got = fluid.Executor(fluid.CPUPlace()).run(
+        feed=feed, fetch_list=[g, n] + grads)
+
+    def plain(a_log, dt, o, w, x, z):
+        g = -jnp.exp(a_log) * jax.nn.softplus(x + dt)
+        heads = o.reshape(B, S, H, D)
+        normed = heads * jax.lax.rsqrt(jnp.mean(
+            heads * heads, axis=-1, keepdims=True) + 1e-6) * w
+        return g, normed.reshape(B, S, H * D) * jax.nn.silu(z)
+
+    args = [jnp.asarray(feed[k]) for k in order]
+    want = plain(*args)
+    assert got[0].dtype == np.float32 and float(np.max(got[0])) < 0
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-6)
+    ref = jax.grad(lambda *a: sum(jnp.sum(t ** 2) for t in plain(*a)),
+                   argnums=range(6))(*args)
+    for name, a, b in zip(order, got[2:], ref):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)

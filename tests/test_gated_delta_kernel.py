@@ -20,7 +20,8 @@ import paddle_tpu as fluid
 from paddle_tpu import layers, observability
 from paddle_tpu.kernels import engine, gated_delta as kda
 
-from test_gated_delta_attention import _inputs, token_recurrence
+from test_gated_delta_attention import (_inputs, from_weak_to_strong,
+                                        token_recurrence)
 
 H, D = 2, 128
 NAMES = ("out", "q", "k", "v", "g", "beta")
@@ -46,7 +47,7 @@ def _jnp_engine(*a):
 
 
 def _held(args, fn, to, rtol):
-    weight = jnp.asarray(np.random.RandomState(1).randn(*args[0].shape),
+    weight = jnp.asarray(np.random.RandomState(1).randn(*args[2].shape),
                          jnp.float32)
     got, want = _both_passes(fn, args, weight), _both_passes(to, args, weight)
     for name, a, b in zip(NAMES, got, want):
@@ -56,42 +57,60 @@ def _held(args, fn, to, rtol):
                                    atol=rtol * float(np.max(np.abs(b))))
 
 
-@pytest.mark.parametrize("S,rows,chunk,unroll", [
-    (256, 128, 64, 1),      # two groups of one tile of two chunks
-    (512, 256, 64, 1),      # two groups of two tiles, a loop over the tiles
-    (512, 256, 64, 2),      # the same, both tiles in one loop body
-    (256, 128, 32, 1),      # four chunks a tile
-    (256, 128, 128, 1),     # a chunk a tile
+@pytest.mark.parametrize("S,rows,chunk,unroll,key_heads", [
+    (256, 128, 64, 1, None),    # two groups of one tile of two chunks
+    (512, 256, 64, 1, None),    # two groups of two tiles, a loop over them
+    (512, 256, 64, 2, None),    # the same, both tiles in one loop body
+    (256, 128, 32, 1, None),    # four chunks a tile
+    (256, 128, 128, 1, None),   # a chunk a tile
+    # ONE decay a head, g [B, S, H]: key heads 1 : 1, then 1 : 2 (both value
+    # heads read the one key head through the index map)
+    (256, 128, 64, 1, 2),
+    (256, 128, 64, 1, 1),
+    (256, 256, 32, 2, 1),       # four chunks a tile, two tiles a loop body
 ])
-def test_the_kernel_pair_is_the_token_recurrence(S, rows, chunk, unroll):
+def test_the_kernel_pair_is_the_token_recurrence(S, rows, chunk, unroll,
+                                                 key_heads):
     """Forward and the gradients of q, k, v, g and beta."""
-    _held(_inputs(1, S, H, D, seed=S + chunk), _kernels(rows, chunk, unroll),
-          _plain, rtol=2e-5)
+    _held(_inputs(1, S, H, D, seed=S + chunk, key_heads=key_heads),
+          _kernels(rows, chunk, unroll), _plain, rtol=2e-5)
 
 
 def test_two_sequences_and_a_state_that_starts_at_zero_for_each():
     _held(_inputs(2, 256, H, D, seed=11), _kernels(128), _plain, rtol=2e-5)
 
 
-@pytest.mark.parametrize("case,rtol", [("decay", 1e-4), ("alike", 2e-4)])
+@pytest.mark.parametrize("case,rtol", [("decay", 1e-4), ("alike", 2e-4),
+                                       ("a-heads-decay", 1e-4),
+                                       ("a-heads-decay-alike", 2e-4)])
 def test_the_hard_inputs(case, rtol):
     """A decay of e^-1500 inside a chunk (exp(-Gc) would be inf: every
     exponent of the kernels is a difference <= 0 too), and keys alike at
-    beta ~ 0.95 (the inverse by doubling, fp32)."""
+    beta ~ 0.95 (the inverse by doubling, fp32); with one decay a head, one
+    head at e^-0.001 and one at e^-21 a token over ONE key head."""
     if case == "decay":
         args = _inputs(1, 256, H, D, seed=3, rate=16.0, shift=1.0)
         assert float(jnp.min(jnp.sum(args[3][:, :64], axis=1))) < -1000
+    elif case == "a-heads-decay":
+        args = _inputs(1, 256, H, D, seed=3, rate=from_weak_to_strong(H),
+                       shift=3.0, key_heads=1)
+        per_chunk = jnp.sum(args[3][:, :64], axis=1)
+        assert float(per_chunk.min()) < -1000 and float(per_chunk.max()) > -1
+    elif case == "a-heads-decay-alike":
+        args = _inputs(1, 256, H, D, seed=7, alike=1.0, key_heads=1)
     else:
         args = _inputs(1, 256, H, D, seed=7, alike=1.0)
     _held(args, _kernels(128), _plain, rtol=rtol)
 
 
-@pytest.mark.parametrize("dtype,rtol", [("float32", 1e-5), ("bfloat16", 4e-2)])
-def test_the_kernel_pair_is_the_jnp_engine(dtype, rtol):
+@pytest.mark.parametrize("dtype,rtol,key_heads", [
+    ("float32", 1e-5, None), ("bfloat16", 4e-2, None),
+    ("float32", 1e-5, 1), ("bfloat16", 4e-2, 1)])
+def test_the_kernel_pair_is_the_jnp_engine(dtype, rtol, key_heads):
     """At fp32 the two engines differ by rounding; at bf16 operands by
     where each rounds to bf16 (beta goes into X's right operand here, into
     X there)."""
-    args = _inputs(1, 256, H, D, seed=5)
+    args = _inputs(1, 256, H, D, seed=5, key_heads=key_heads)
     args = tuple(t.astype(dtype) for t in args[:3]) + args[3:]
     _held(args, _kernels(128), _jnp_engine, rtol=rtol)
 
@@ -185,13 +204,18 @@ def test_a_recomputed_units_backward_holds_no_forward_kernel():
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
 
 
-def test_kda_lower_says_pallas_at_the_cells_shape():
+@pytest.mark.parametrize("S,key_heads", [(4096, None), (8192, 16)])
+def test_kda_lower_says_pallas_at_the_cells_shape(S, key_heads):
     """The op lowered (abstractly: nothing compiles) at [1, 4096, 32 x 128]
     for the TPU (whose AMP tier makes the operands bf16): `engine` pallas,
     the rows a grid step and the two working sets beside what the span
-    said before."""
-    S, heads = 4096, 32
-    shapes = [[1, S, heads * D]] * 4 + [[1, S, heads]]
+    said before.  At qwen3next-train-gdn8k's shape (S 8192, one decay a
+    head, 16 key heads) the span is `gdn.lower`, and the lowered op holds
+    neither a [8192, 4096] fp32 decay nor a repeated q or k: the kernels'
+    operands are the [8192, 2048] q and k as they came and g by tiles."""
+    heads = 32
+    shapes = [[1, S, (key_heads or heads) * D]] * 2 + [[1, S, heads * D]] \
+        + [[1, S, heads * (1 if key_heads else D)]] + [[1, S, heads]]
     fluid.reset_default_env()
     names = ("q", "k", "v", "g", "beta")
     ins = [layers.data(n, s, append_batch_size=False, dtype="float32")
@@ -204,20 +228,33 @@ def test_kda_lower_says_pallas_at_the_cells_shape():
         with fluid.flags.tpu_trace_scope(True):
             compiled, *rest = fluid.Executor(fluid.CPUPlace()).capture_program(
                 feed=feed, fetch_list=[out])
-            jax.eval_shape(compiled.raw_fn, *rest)
+            text = str(jax.make_jaxpr(compiled.raw_fn)(*rest))
         spans = [dict(s.args) for s in
                  observability.default_tracer().spans()
-                 if s.name == "kda.lower"]
+                 if s.name == ("gdn.lower" if key_heads else "kda.lower")]
     finally:
         fluid.flags._VALUES["FLAGS_observability"] = False
         observability.reset()
         fluid.reset_default_env()
     tiles = kda.kernel_tiles(1, S, heads, D, 64, jnp.bfloat16)[0]
-    assert spans == [dict(
-        heads=heads, head_dim=D, sq=S, chunk=64, chunks=64, group=8,
+    said = dict(
+        heads=heads, head_dim=D, sq=S, chunk=64, chunks=S // 64, group=8,
         engine="pallas", rows=tiles.rows, fwd_vmem_bytes=tiles.fwd_vmem_bytes,
         bwd_vmem_bytes=tiles.bwd_vmem_bytes, state_bytes=4 * heads * D * D,
         kept="out,states",
-        kept_bytes=2 * S * heads * D + 8 * 4 * heads * D * D,
-        flops=kda.flops(1, S, heads, D, 64),
-        moved_bytes=kda.moved_bytes(1, S, heads, D, 2))]
+        kept_bytes=2 * S * heads * D + S // 512 * 4 * heads * D * D)
+    if not key_heads:
+        assert spans == [dict(
+            said, flops=kda.flops(1, S, heads, D, 64),
+            moved_bytes=kda.moved_bytes(1, S, heads, D, 2))]
+        return
+    assert spans == [dict(
+        said, flops=kda.flops(1, S, heads, D, 64, key_heads),
+        moved_bytes=kda.moved_bytes(1, S, heads, D, 2, key_heads, True),
+        decay="head", key_heads=key_heads)]
+    # from the kernel on: q and k at their 16 heads, v at its 32, g and
+    # beta by tiles; out and the group states: no decay a channel, no q or
+    # k at 32 heads
+    call = text[text.index("pallas_call"):]
+    assert f"f32[1,{S},{heads * D}]" not in call
+    assert call.count(f"bf16[1,{S},{heads * D}]") <= 3

@@ -464,23 +464,35 @@ def test_the_cells_plans_at_the_real_shape():
 
 
 def test_no_site_compiles_scores_that_do_not_fit():
-    """A site that no Pallas plan takes and whose fp32 scores pass 2 GiB is
-    refused at lowering on a TPU, not handed to XLA: S 256 with grouped K/V,
-    whose grid steps take one 256 x 256 block each (with a K/V head a query
-    head a step takes several rows, and the Pallas backward the site)."""
+    """A site whose fp32 scores pass 2 GiB is never handed to XLA on a TPU.
+    S 256 with grouped K/V, whose grid steps take one 256 x 256 block each,
+    is XLA's while its scores fit and the Pallas kernel's past that, at
+    those blocks (one rule, _bwd_chunk_rows'; with a K/V head a query head
+    a step takes several rows, and the Pallas backward takes the site at any
+    size); a head so wide that no plan fits is refused at lowering."""
     q = jax.ShapeDtypeStruct((64, 256, 256, 64), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((64, 128, 256, 64), jnp.bfloat16)
+    wide = jax.ShapeDtypeStruct((64, 256, 256, 2048), jnp.float32)
+    wide_kv = jax.ShapeDtypeStruct((64, 128, 256, 2048), jnp.float32)
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True).sum()
 
+    def engine(q, k, site_bh):
+        return fa._bwd_plan(256, 256, q.shape[3], q.dtype, True,
+                            bh=fa._packable_rows(q, k),
+                            site_bh=site_bh)["engine"]
+
     with fluid.flags.tpu_trace_scope(True):
-        assert fa._bwd_plan(256, 256, 64, jnp.bfloat16, True,
-                            bh=fa._packable_rows(q, kv))["engine"] == "xla"
+        assert engine(q, kv, 8192) == "xla"       # 2 GiB of scores: held
+        assert engine(q, kv, 8193) == "pallas"
+        assert engine(q, kv, 64 * 256) == "pallas"
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        assert engine(wide, wide_kv, 64 * 256) == "xla"
         with pytest.raises(ValueError, match="scores"):
-            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-        assert fa._bwd_plan(256, 256, 64, jnp.bfloat16, True,
-                            bh=fa._packable_rows(q, q))["engine"] == "pallas"
+            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)),
+                           wide, wide_kv, wide_kv)
+        assert engine(q, q, 64 * 256) == "pallas"
         jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 

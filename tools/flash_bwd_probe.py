@@ -146,7 +146,8 @@ def main() -> int:
                    else float(S * np.mean(lengths)))
         counted = 2.5 * 4.0 * B * H * visible * D
         plan = fa._bwd_plan(S, S, D, q.dtype, causal, window=window,
-                            bh=fa._packable_rows(q, k), group=H // G)
+                            bh=fa._packable_rows(q, k), group=H // G,
+                            site_bh=B * H)
         force = "interpret" if a.rehearse else "pallas"
         windowed = {} if window is None else {"window": window}
         big = 4 * B * H * S * S > 2 ** 32   # no [B, H, S, S] fp32 scores

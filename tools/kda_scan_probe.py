@@ -1,8 +1,11 @@
-"""`kda.scan` alone, timed and checked on the chip at `kimi-train-kda8k`'s
-shape.
+"""`kda.scan` / `gdn.scan` alone, timed and checked on the chip at
+`kimi-train-kda8k`'s and `qwen3next-train-gdn8k`'s shapes (--shapes).
 
-  kimi   q, k, v [1, 4096, 32 x 128] bf16, g fp32, beta [1, 4096, 32]:
-         one KDA layer of the cell, chunks of 64
+  kimi       q, k, v [1, 4096, 32 x 128] bf16, g fp32, beta [1, 4096, 32]:
+             one KDA layer of the cell, chunks of 64, a decay a key channel
+  qwen3next  q, k [1, 8192, 16 x 128], v [1, 8192, 32 x 128] bf16, g and
+             beta [1, 8192, 32] fp32: one Gated DeltaNet layer of the cell,
+             ONE decay a head, two value heads a key head
 
 The op gated_delta_attention's arithmetic in its two engines: `xla`
 (kernels/gated_delta.py::_scan_by_groups, jax.numpy scans over regrouped
@@ -17,8 +20,10 @@ engine's (the largest difference over the largest value).
 
 `--check` runs three inputs at [1, 1024, 4 x 128] (the token recurrence's
 backward keeps a state a token): a random one, a decay of e^-1500 a chunk,
-and keys alike at beta ~ 1 (tests/test_gated_delta_attention.py's), at
-fp32 and at bf16 operands: kernel pair and jax.numpy engine against the
+and keys alike at beta ~ 1 (tests/test_gated_delta_attention.py's), in the
+form of every shape named (the head-decay form: 2 key heads for the 4
+value heads, and a fourth input whose four heads decay from e^-0.001 to
+e^-21 a token), at fp32 and at bf16 operands: kernel pair and jax.numpy engine against the
 recurrence one token at a time in fp32, the largest error of the output
 and of each gradient over the largest value.  What the CPU interpreter
 cannot show is there: the precision Mosaic gives an fp32 product.  Rows go
@@ -26,7 +31,7 @@ to chiprun_out/kda_scan_probe.json.
 
 A tool, run by no benchmark cell:
     chiprun --chips 1 -- python3 tools/kda_scan_probe.py --seed 7 \
-        [--sweep] [--check]
+        [--shapes kimi,qwen3next] [--sweep] [--check]
     JAX_PLATFORMS=cpu python3 tools/kda_scan_probe.py --rehearse --check
 `--rehearse` runs a tiny shape through the Pallas interpreter in fp32 and
 exits 3: its times are not the chip's.  One process holds the chip; it
@@ -46,9 +51,11 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 
 from flash_fwd_probe import _time_ms  # noqa: E402
 
-# name: (B, S, H, D, chunk)
-SHAPES = {"kimi": (1, 4096, 32, 128, 64)}
-REHEARSAL_SHAPES = {"kimi": (1, 256, 2, 128, 64)}
+# name: (B, S, H, D, chunk[, key heads: ONE decay a head])
+SHAPES = {"kimi": (1, 4096, 32, 128, 64),
+          "qwen3next": (1, 8192, 32, 128, 64, 16)}
+REHEARSAL_SHAPES = {"kimi": (1, 256, 2, 128, 64),
+                    "qwen3next": (1, 256, 2, 128, 64, 1)}
 CHECK_SHAPE, REHEARSAL_CHECK_SHAPE = (1, 1024, 4, 128, 64), (1, 256, 2, 128, 64)
 HBM_GB_S = 819.0  # one v5e (Google Cloud documentation, "TPU v5e")
 NAMES = ("out", "dq", "dk", "dv", "dg", "dbeta")
@@ -64,9 +71,9 @@ def inputs(shape, seed, dtype, rate=1.0, shift=-2.0, alike=0.0):
     import numpy as np
     from test_gated_delta_attention import _inputs
 
-    B, S, H, D, _ = shape
+    B, S, H, D = shape[:4]
     q, k, v, g, beta = _inputs(B, S, H, D, seed % (2 ** 32), rate, shift,
-                               alike)
+                               alike, key_heads=(shape[5:] or (None,))[0])
     weight = np.random.RandomState(seed % (2 ** 32)).randn(B, S, H * D)
     return ((q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta),
             jnp.asarray(weight, dtype))
@@ -109,6 +116,7 @@ def main() -> int:
     ap.add_argument("--rows", default="128,256,512,1024")
     ap.add_argument("--unroll", default="1,2")
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--shapes", default="kimi")
     ap.add_argument("--rehearse", action="store_true")
     a = ap.parse_args()
 
@@ -132,8 +140,11 @@ def main() -> int:
             *xs, heads=H, chunk=chunk, force=force, rows=group,
             unroll=unroll)
 
-    for name, shape in (REHEARSAL_SHAPES if a.rehearse else SHAPES).items():
-        B, S, H, D, chunk = shape
+    shapes = REHEARSAL_SHAPES if a.rehearse else SHAPES
+    for name in a.shapes.split(","):
+        shape = shapes[name]
+        B, S, H, D, chunk = shape[:5]
+        form = (shape[5], True) if shape[5:] else (None, False)
         args, weight = inputs(shape, a.seed, half)
         plan, why = kda.kernel_tiles(B, S, H, D, chunk, half)
         assert plan is not None, why
@@ -146,8 +157,11 @@ def main() -> int:
                          if S % r == 0 and u <= r // 128]
         size = jnp.dtype(half).itemsize
         wide, gate, small = size * S * H * D, 4 * S * H * D, 4 * S * H
-        moved_fwd = B * (4 * wide + gate + small)
-        moved = (moved_fwd, kda.moved_bytes(B, S, H, D, size) - moved_fwd)
+        keys = size * S * (form[0] or H) * D
+        moved_fwd = B * (2 * keys + 2 * wide
+                         + (small if form[1] else gate) + small)
+        moved = (moved_fwd,
+                 kda.moved_bytes(B, S, H, D, size, *form) - moved_fwd)
         want = None
         for label, force, group, unroll in variants:
             row = {"shape": name, "variant": label, "seed": a.seed,
@@ -171,17 +185,24 @@ def main() -> int:
             rows.append(row)
             print(json.dumps(row), flush=True)
 
-    if a.check:
+    for name in a.shapes.split(",") if a.check else ():
+        from test_gated_delta_attention import from_weak_to_strong
+
         shape = REHEARSAL_CHECK_SHAPE if a.rehearse else CHECK_SHAPE
-        H = shape[2]
+        H, hard = shape[2], dict(HARD)
+        if shapes[name][5:]:                  # ONE decay a head
+            shape = shape + (H // 2,)
+            hard["heads_from_e-0.001_to_e-21_a_token"] = (
+                from_weak_to_strong(H), 3.0, 0.0)
         dtypes = (jnp.float32,) if a.rehearse else (jnp.float32, jnp.bfloat16)
-        for case, (rate, shift, alike) in HARD.items():
+        for case, (rate, shift, alike) in hard.items():
             for dtype in dtypes:
                 args, weight = inputs(shape, a.seed, dtype, rate, shift, alike)
                 want, _, _ = _both_passes(
                     lambda *xs: token_recurrence(*xs, heads=H), args,
                     weight.astype(jnp.float32))
-                row = {"check": case, "operands": jnp.dtype(dtype).name,
+                row = {"check": case, "form": name,
+                       "operands": jnp.dtype(dtype).name,
                        "shape": list(shape), "seed": a.seed}
                 for label, force in (("xla", "jax"), ("pallas", kernel)):
                     got, _, _ = _both_passes(engine(shape, force), args,
