@@ -31,11 +31,19 @@ resident over the batch and the rows, a row of C each: the head's rate
 and the norm's scale are handed in as such rows, and jax differentiates
 the few operations that make them outside.
 
-`conv_tiles` and `norm_tiles` read the tile from the shape and the VMEM it
-needs, or say that the shape does not tile; the ops ask kernels/engine.py
-whether a site runs these pairs at all (ops/linear_attention_ops.py) and
-run their jax.numpy form where it does not.  tools/kda_mix_probe.py times the
-pairs alone on the chip.
+The same design serves what streams around the other recurrent mixers:
+`short_conv`, the op short_conv1d's ONE causal depthwise convolution with a
+bias and an activation (silu | identity; Gated DeltaNet's q | k | v,
+Mamba's x and Mamba-2's x | B | C), is the pair before the scan for one
+stream with a bias and no decay, and the pair after the scan takes the
+gate's rule as a static argument: sigmoid or SiLU, of the gate with or
+without a bias row (Gated DeltaNet's: silu, none).
+
+`conv_tiles`, `short_conv_tiles` and `norm_tiles` read the tile from the
+shape and the VMEM it needs, or say that the shape does not tile; the ops
+ask kernels/engine.py whether a site runs these pairs at all
+(ops/linear_attention_ops.py) and run their jax.numpy form where it does
+not.  tools/kda_mix_probe.py times the pairs alone on the chip.
 """
 
 from __future__ import annotations
@@ -50,8 +58,9 @@ from . import engine
 from .engine import (F32, LANES, add_up, back, columns, compiler_params, roll,
                      sigmoid)
 
-__all__ = ["Tiles", "conv_tiles", "norm_tiles", "conv_decay", "gated_norm",
-           "conv_moved_bytes", "norm_moved_bytes"]
+__all__ = ["Tiles", "conv_tiles", "short_conv_tiles", "norm_tiles",
+           "conv_decay", "short_conv", "SHORT_CONV_ACTS", "gated_norm",
+           "conv_moved_bytes", "short_conv_moved_bytes", "norm_moved_bytes"]
 
 # The tiles `conv_tiles` / `norm_tiles` try: the widest block of channels
 # first, then the most rows that fit beside it.  On the chip at the cell's
@@ -67,6 +76,21 @@ __all__ = ["Tiles", "conv_tiles", "norm_tiles", "conv_decay", "gated_norm",
 # (kernels/cca_mix.py's sweep, PERF.md PR 45): no tile above 256 rows.
 _ROWS = (256, 128)
 _CHANNELS = (2048, 1024, 512, 256, 128)
+# the activations `short_conv` has a kernel for
+SHORT_CONV_ACTS = ("identity", "silu")
+# `short_conv_tiles`' widest block of channels.  At [8192, 8192] bf16, four
+# taps (tools/kda_mix_probe.py --shapes qwen3next --sweep, ms a layer
+# forward / backward, PERF.md PR 67) the one-stream pair reads 1.50 / 1.95
+# at 128 rows x 128 channels, 1.04 / 1.41 x 256, 0.78 / 1.08 x 512, 0.60 /
+# 0.92 x 1024, 0.52 / 0.80 x 2048, 0.48 / 0.78 x 4096; 1.05 / 1.43, 0.81 /
+# 1.14, 0.63 / 0.95, 0.53 / 0.92, 0.49 / 0.90 at 256 rows x 128 ... 2048;
+# 512 rows never win (0.59 / 1.13 x 512).  XLA's passes: 2.24 / 7.40.  A
+# block of 4096 fits (one stream's working set is a quarter of the four
+# streams') and is 8% faster than 2048, 0.2% of the cell's step; it is not
+# taken because a body unrolls a column for every 128 channels and a step
+# pays for its kernels' bodies in `setup_s`: at 4096 the cell's warm
+# set-up read +7% (bound 10%), `setup_trace_lower_s.train` 23.3 -> 28.1.
+_SHORT_WIDEST = 2048
 
 
 class Tiles(NamedTuple):
@@ -92,6 +116,19 @@ def conv_working_set(rows, channels, halo, taps, size, backward) -> int:
     return 2 * (blocks + params) + 12 * (rows + halo) * LANES * 4
 
 
+def short_conv_working_set(rows, channels, halo, taps, size, backward) -> int:
+    """The same for the one-stream pair: X, the cotangent and dX (forward:
+    X and Out), a halo block of X on either side and one of the cotangent,
+    the filter's rows and the bias row."""
+    tile, edge = rows * channels, halo * channels
+    params = (taps + 1) * channels * 4
+    if backward:
+        return (2 * ((3 * tile + 3 * edge) * size + 2 * params)
+                + 20 * (rows + 2 * halo) * LANES * 4)
+    return (2 * ((2 * tile + edge) * size + params)
+            + 12 * (rows + halo) * LANES * 4)
+
+
 def norm_working_set(rows, channels, head_dim, size, backward) -> int:
     """The same for the kernels after the scan."""
     tile = rows * channels
@@ -100,12 +137,29 @@ def norm_working_set(rows, channels, head_dim, size, backward) -> int:
     return 2 * (blocks + params) + 12 * rows * head_dim * 4
 
 
-def _widest(seq, width, unit, need, rows, channels):
-    """engine.widest over `_CHANNELS` x `_ROWS`; `rows` / `channels` pin
+def _widest(seq, width, unit, need, rows, channels, candidates=_CHANNELS):
+    """engine.widest over `candidates` x `_ROWS`; `rows` / `channels` pin
     either for a test or the probe, never a model."""
     return engine.widest(seq, width, unit, need,
                          _ROWS if rows is None else (rows,),
-                         _CHANNELS if channels is None else (channels,))
+                         candidates if channels is None else (channels,))
+
+
+def _halo_tiles(working_set, candidates, seq, width, taps, dtype, rows,
+                channels) -> Optional[Tiles]:
+    """The tiles of a convolution's site by its `working_set` count over
+    the `candidates` blocks of channels."""
+    halo, size = engine.halo_rows(dtype), jnp.dtype(dtype).itemsize
+    if width % LANES or not 0 <= taps - 1 <= 8:
+        return None
+
+    def need(r, c, backward=True):
+        return working_set(r, c, halo, taps, size, backward)
+
+    found = _widest(seq, width, LANES, need, rows, channels, candidates)
+    if found is None or found[0] % halo:
+        return None
+    return Tiles(*found, halo, need(*found, False), need(*found))
 
 
 def conv_tiles(seq, width, taps, dtype, rows=None, channels=None
@@ -113,17 +167,20 @@ def conv_tiles(seq, width, taps, dtype, rows=None, channels=None
     """The tiles of a site before the scan, None where the shape does not
     tile: channels whole 128-lane vectors, the taps' reach within a halo
     block, S whole tiles of rows whose working set fits."""
-    halo, size = engine.halo_rows(dtype), jnp.dtype(dtype).itemsize
-    if width % LANES or not 0 <= taps - 1 <= 8:
-        return None
+    return _halo_tiles(conv_working_set, _CHANNELS, seq, width, taps, dtype,
+                       rows, channels)
 
-    def need(r, c, backward=True):
-        return conv_working_set(r, c, halo, taps, size, backward)
 
-    found = _widest(seq, width, LANES, need, rows, channels)
-    if found is None or found[0] % halo:
-        return None
-    return Tiles(*found, halo, need(*found, False), need(*found))
+def short_conv_tiles(seq, width, taps, dtype, rows=None, channels=None
+                     ) -> Optional[Tiles]:
+    """The same rule for a short_conv1d site and its one stream's working
+    set; the blocks it tries are the width's own divisors in whole lane
+    vectors, widest first (2304 = 9 x 256 channels has no wide block among
+    the powers of two)."""
+    candidates = tuple(c for c in range(min(width, _SHORT_WIDEST), 0, -LANES)
+                       if width % c == 0)
+    return _halo_tiles(short_conv_working_set, candidates, seq, width, taps,
+                       dtype, rows, channels)
 
 
 def norm_tiles(seq, width, head_dim, dtype, rows=None, channels=None
@@ -157,6 +214,13 @@ def conv_moved_bytes(q, f, recomputed: bool) -> int:
     x, z, g = (int(q.size) * q.dtype.itemsize, int(f.size) * f.dtype.itemsize,
                int(f.size) * 4)
     return _passes(6 * x + z + g, 9 * x + 2 * z + g, recomputed)
+
+
+def short_conv_moved_bytes(x, recomputed: bool) -> int:
+    """The same for a short_conv1d site: the forward reads X and writes
+    Out; the backward reads X and the cotangent and writes dX."""
+    size = int(x.size) * x.dtype.itemsize
+    return _passes(2 * size, 3 * size, recomputed)
 
 
 def norm_moved_bytes(o, gate, recomputed: bool) -> int:
@@ -271,39 +335,124 @@ def _conv_decay_bwd_kernel(q_ref, k_ref, v_ref, f_ref, qb_ref, kb_ref, vb_ref,
         drate_ref[:, cols] += _total(gg * _softplus(z))
 
 
+def _short_conv_kernel(x_ref, before_ref, w_ref, *refs, taps, halo, silu):
+    """`_conv_decay_kernel` for ONE stream with no decay; `refs`: the bias
+    row where the site has one, then Out."""
+    import jax.experimental.pallas as pl
+
+    *bias_ref, o_ref = refs
+    seen = 1.0 - (pl.program_id(2) == 0).astype(F32)
+    for cols in columns(x_ref.shape[-1], LANES):
+        x = jnp.concatenate(
+            [before_ref[0, :, cols].astype(F32) * seen,
+             x_ref[0, :, cols].astype(F32)], 0)
+        y = _convolve(x, _taps(w_ref, cols, taps))[halo:]
+        for ref in bias_ref:
+            y = y + ref[:, cols]
+        o_ref[0, :, cols] = (y * sigmoid(y) if silu else y).astype(
+            o_ref.dtype)
+
+
+def _short_conv_bwd_kernel(x_ref, before_ref, after_ref, g_ref, ga_ref, w_ref,
+                           *refs, taps, halo, silu):
+    """`_conv_decay_bwd_kernel` likewise; `refs`: [bias,] dx, dw [, dbias]."""
+    import jax.experimental.pallas as pl
+
+    biased = len(refs) == 4
+    bias_ref, dbias_ref = (refs[:1], refs[3:]) if biased else ((), ())
+    dx_ref, dw_ref = refs[biased:biased + 2]
+    step = pl.program_id(2)
+    seen = 1.0 - (step == 0).astype(F32)
+    more = 1.0 - (step == pl.num_programs(2) - 1).astype(F32)
+    tile = x_ref.shape[1]
+    own = slice(halo, halo + tile)
+
+    @pl.when((step == 0) & (pl.program_id(1) == 0))
+    def _no_gradient_yet():
+        for ref in (dw_ref, *dbias_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    for cols in columns(x_ref.shape[-1], LANES):
+        w = _taps(w_ref, cols, taps)
+        x = jnp.concatenate(
+            [before_ref[0, :, cols].astype(F32) * seen,
+             x_ref[0, :, cols].astype(F32),
+             after_ref[0, :, cols].astype(F32)], 0)
+        dy = jnp.concatenate(
+            [jnp.zeros((halo, LANES), F32), g_ref[0, :, cols].astype(F32),
+             ga_ref[0, :, cols].astype(F32) * more], 0)
+        if silu:
+            y = _convolve(x, w)
+            for ref in bias_ref:
+                y = y + ref[:, cols]
+            s = sigmoid(y)
+            dy = dy * (s * (1.0 + y * (1.0 - s)))
+        dx = add_up(_ahead(dy, taps - 1 - j) * w[j] for j in range(taps))
+        dx_ref[0, :, cols] = dx[own].astype(dx_ref.dtype)
+        for j in range(taps):
+            dw_ref[j:j + 1, cols] += _total((dy * back(x, taps - 1 - j))[own])
+        for ref in dbias_ref:
+            ref[:, cols] += _total(dy[own])
+
+
 def _unit(o, eps):
     r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
     return o * r, r
 
 
-def _gated_norm_kernel(o_ref, gate_ref, bias_ref, scale_ref, out_ref, *,
-                       head_dim, eps):
+# The gate's rules, on fp32 values: the gate's argument z is Gate (+ the
+# bias row where the site has one), its factor s = sigmoid(z) or silu(z) =
+# z sigmoid(z), and ds/dz is made from z and sigmoid(z).  The sites differ
+# in their refs (no bias row and no dBias without a bias) and in the static
+# `rule`; under sigmoid with a bias the operations and their order are
+# `kimi-train-kda8k`'s kernels as PR 49 wrote them.
+def _gate(rule, gate_ref, bias_ref, cols):
+    """(z, sigmoid(z), s)."""
+    z = gate_ref[0, :, cols].astype(F32)
+    if bias_ref:
+        z = z + bias_ref[0][:, cols]
+    sg = sigmoid(z)
+    return z, sg, z * sg if rule == "silu" else sg
+
+
+def _gate_slope(rule, z, sg):
+    return sg * ((1.0 + z * (1.0 - sg)) if rule == "silu" else (1.0 - sg))
+
+
+def _gated_norm_kernel(o_ref, gate_ref, *refs, head_dim, eps, rule):
+    *bias_ref, scale_ref, out_ref = refs
     for cols in columns(o_ref.shape[-1], head_dim):
         n, _ = _unit(o_ref[0, :, cols].astype(F32), eps)
-        s = sigmoid(gate_ref[0, :, cols].astype(F32) + bias_ref[:, cols])
+        _, _, s = _gate(rule, gate_ref, bias_ref, cols)
         out_ref[0, :, cols] = (n * scale_ref[:, cols] * s).astype(
             out_ref.dtype)
 
 
-def _gated_norm_bwd_kernel(o_ref, gate_ref, g_ref, bias_ref, scale_ref,
-                           do_ref, dgate_ref, dbias_ref, dscale_ref, *,
-                           head_dim, eps):
+def _gated_norm_bwd_kernel(o_ref, gate_ref, g_ref, *refs, head_dim, eps,
+                           rule):
     import jax.experimental.pallas as pl
+
+    # in: [bias,] scale; out: do, dgate, [dbias,] dscale
+    biased = len(refs) == 6
+    bias_ref, dbias_ref = (refs[:1], refs[-2:-1]) if biased else ((), ())
+    scale_ref, do_ref, dgate_ref = refs[biased:biased + 3]
+    dscale_ref = refs[-1]
 
     @pl.when((pl.program_id(2) == 0) & (pl.program_id(1) == 0))
     def _no_gradient_yet():
-        dbias_ref[...] = jnp.zeros_like(dbias_ref)
-        dscale_ref[...] = jnp.zeros_like(dscale_ref)
+        for ref in (*dbias_ref, dscale_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     for cols in columns(o_ref.shape[-1], head_dim):
         n, r = _unit(o_ref[0, :, cols].astype(F32), eps)
-        s = sigmoid(gate_ref[0, :, cols].astype(F32) + bias_ref[:, cols])
+        z, sg, s = _gate(rule, gate_ref, bias_ref, cols)
         g = g_ref[0, :, cols].astype(F32)
         gn = g * n
         dscale_ref[:, cols] += _total(gn * s)
-        dgate = gn * scale_ref[:, cols] * (s * (1.0 - s))
+        dgate = gn * scale_ref[:, cols] * _gate_slope(rule, z, sg)
         dgate_ref[0, :, cols] = dgate.astype(dgate_ref.dtype)
-        dbias_ref[:, cols] += _total(dgate)
+        for ref in dbias_ref:
+            ref[:, cols] += _total(dgate)
         dn = g * scale_ref[:, cols] * s
         do = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
         do_ref[0, :, cols] = do.astype(do_ref.dtype)
@@ -382,14 +531,15 @@ def _conv_bwd_call(B, S, C, taps, tiles, dtype, interpret):
 
 
 @functools.lru_cache(maxsize=64)
-def _norm_fwd_call(B, S, C, head_dim, eps, tiles, dtype, interpret):
+def _short_fwd_call(B, S, C, taps, silu, biased, tiles, dtype, interpret):
     import jax.experimental.pallas as pl
 
-    grid, rows, _, _, whole = _specs(B, S, C, tiles)
+    grid, rows, before, _, whole = _specs(B, S, C, tiles)
     return jax.jit(pl.pallas_call(
-        functools.partial(_gated_norm_kernel, head_dim=head_dim, eps=eps),
+        functools.partial(_short_conv_kernel, taps=taps, halo=tiles.halo,
+                          silu=silu),
         grid=grid,
-        in_specs=[rows] * 2 + [whole(1)] * 2,
+        in_specs=[rows, before, whole(taps)] + [whole(1)] * biased,
         out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
         compiler_params=compiler_params(
@@ -399,17 +549,60 @@ def _norm_fwd_call(B, S, C, head_dim, eps, tiles, dtype, interpret):
 
 
 @functools.lru_cache(maxsize=64)
-def _norm_bwd_call(B, S, C, head_dim, eps, tiles, dtype, interpret):
+def _short_bwd_call(B, S, C, taps, silu, biased, tiles, dtype, interpret):
+    import jax.experimental.pallas as pl
+
+    grid, rows, before, after, whole = _specs(B, S, C, tiles)
+    return jax.jit(pl.pallas_call(
+        functools.partial(_short_conv_bwd_kernel, taps=taps, halo=tiles.halo,
+                          silu=silu),
+        grid=grid,
+        in_specs=[rows, before, after, rows, after, whole(taps)]
+        + [whole(1)] * biased,
+        out_specs=[rows, whole(taps)] + [whole(1)] * biased,
+        out_shape=[jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
+                   jax.ShapeDtypeStruct((taps, C), F32)]
+        + [jax.ShapeDtypeStruct((1, C), F32)] * biased,
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem_bytes),
+        interpret=interpret,
+    ))
+
+
+@functools.lru_cache(maxsize=64)
+def _norm_fwd_call(B, S, C, head_dim, eps, rule, biased, tiles, dtype,
+                   interpret):
+    import jax.experimental.pallas as pl
+
+    grid, rows, _, _, whole = _specs(B, S, C, tiles)
+    return jax.jit(pl.pallas_call(
+        functools.partial(_gated_norm_kernel, head_dim=head_dim, eps=eps,
+                          rule=rule),
+        grid=grid,
+        in_specs=[rows] * 2 + [whole(1)] * (1 + biased),
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype)),
+        compiler_params=compiler_params(
+            ("parallel",) * 3, tiles.fwd_vmem_bytes),
+        interpret=interpret,
+    ))
+
+
+@functools.lru_cache(maxsize=64)
+def _norm_bwd_call(B, S, C, head_dim, eps, rule, biased, tiles, dtype,
+                   interpret):
     import jax.experimental.pallas as pl
 
     grid, rows, _, _, whole = _specs(B, S, C, tiles)
     like = jax.ShapeDtypeStruct((B, S, C), jnp.dtype(dtype))
     return jax.jit(pl.pallas_call(
-        functools.partial(_gated_norm_bwd_kernel, head_dim=head_dim, eps=eps),
+        functools.partial(_gated_norm_bwd_kernel, head_dim=head_dim, eps=eps,
+                          rule=rule),
         grid=grid,
-        in_specs=[rows] * 3 + [whole(1)] * 2,
-        out_specs=[rows] * 2 + [whole(1)] * 2,
-        out_shape=[like] * 2 + [jax.ShapeDtypeStruct((1, C), F32)] * 2,
+        in_specs=[rows] * 3 + [whole(1)] * (1 + biased),
+        out_specs=[rows] * 2 + [whole(1)] * (1 + biased),
+        out_shape=[like] * 2
+        + [jax.ShapeDtypeStruct((1, C), F32)] * (1 + biased),
         compiler_params=compiler_params(
             ("parallel", "arbitrary", "arbitrary"), tiles.bwd_vmem_bytes),
         interpret=interpret,
@@ -446,27 +639,63 @@ def _conv_decay_bwd(tiles, interpret, inputs, cotangents):
 _conv_decay.defvjp(_conv_decay_fwd, _conv_decay_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _gated_norm(o, gate, bias, scale, head_dim: int, eps: float,
+def _present(*rows):
+    """The parameter rows a site has: a bias it has not is no operand."""
+    return tuple(row for row in rows if row is not None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _short_conv(x, w, bias, silu: bool, tiles: Tiles,
+                interpret: bool = False):
+    """The convolution of a site `short_conv_tiles` tiled; the filter [k,
+    C] fp32, the bias [1, C] fp32 or None."""
+    B, S, C = x.shape
+    return _short_fwd_call(B, S, C, w.shape[0], silu, bias is not None,
+                           tiles, str(x.dtype), interpret)(
+        x, x, w, *_present(bias))
+
+
+def _short_conv_fwd(x, w, bias, silu, tiles, interpret):
+    return _short_conv(x, w, bias, silu, tiles, interpret), (x, w, bias)
+
+
+def _short_conv_bwd(silu, tiles, interpret, inputs, cotangent):
+    x, w, bias = inputs
+    B, S, C = x.shape
+    g = cotangent.astype(x.dtype)
+    dx, dw, *dbias = _short_bwd_call(
+        B, S, C, w.shape[0], silu, bias is not None, tiles, str(x.dtype),
+        interpret)(x, x, x, g, g, w, *_present(bias))
+    return dx, dw, (dbias[0] if dbias else None)
+
+
+_short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _gated_norm(o, gate, bias, scale, head_dim: int, eps: float, rule: str,
                 tiles: Tiles, interpret: bool = False):
-    """The gated norm of a site `norm_tiles` tiled; bias and scale [1, C]
-    fp32."""
+    """The gated norm of a site `norm_tiles` tiled under the gate's `rule`;
+    scale [1, C] fp32, bias likewise or None."""
     B, S, C = o.shape
-    return _norm_fwd_call(B, S, C, head_dim, eps, tiles, str(o.dtype),
-                          interpret)(o, gate, bias, scale)
+    return _norm_fwd_call(B, S, C, head_dim, eps, rule, bias is not None,
+                          tiles, str(o.dtype), interpret)(
+        o, gate, *_present(bias, scale))
 
 
-def _gated_norm_fwd(o, gate, bias, scale, head_dim, eps, tiles, interpret):
-    return (_gated_norm(o, gate, bias, scale, head_dim, eps, tiles,
+def _gated_norm_fwd(o, gate, bias, scale, head_dim, eps, rule, tiles,
+                    interpret):
+    return (_gated_norm(o, gate, bias, scale, head_dim, eps, rule, tiles,
                         interpret), (o, gate, bias, scale))
 
 
-def _gated_norm_bwd(head_dim, eps, tiles, interpret, inputs, cotangent):
+def _gated_norm_bwd(head_dim, eps, rule, tiles, interpret, inputs, cotangent):
     o, gate, bias, scale = inputs
     B, S, C = o.shape
-    call = _norm_bwd_call(B, S, C, head_dim, eps, tiles, str(o.dtype),
-                          interpret)
-    return tuple(call(o, gate, cotangent.astype(o.dtype), bias, scale))
+    do, dgate, *dbias, dscale = _norm_bwd_call(
+        B, S, C, head_dim, eps, rule, bias is not None, tiles, str(o.dtype),
+        interpret)(o, gate, cotangent.astype(o.dtype), *_present(bias, scale))
+    return do, dgate, (dbias[0] if dbias else None), dscale
 
 
 _gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
@@ -487,10 +716,24 @@ def conv_decay(q, k, v, f, wq, wk, wv, dt_bias, a_log, heads, tiles: Tiles,
         _row(rate), tiles, interpret)
 
 
-def gated_norm(o, gate, gate_bias, scale, heads, eps, tiles: Tiles,
+def short_conv(x, w, bias, activation, tiles: Tiles,
                interpret: bool = False):
+    """ops/linear_attention_ops.py::short_conv's output by the kernel pair,
+    of a site `short_conv_tiles` tiled; `activation` silu | identity, `bias`
+    [C] or None."""
+    return _short_conv(x, w.astype(F32), None if bias is None else _row(bias),
+                       bool(SHORT_CONV_ACTS.index(activation)), tiles,
+                       interpret)
+
+
+def gated_norm(o, gate, gate_bias, scale, heads, eps, tiles: Tiles,
+               interpret: bool = False, activation: str = "sigmoid"):
     """ops/linear_attention_ops.py::gated_norm's output by the kernel pair,
-    of a site `norm_tiles` tiled."""
+    of a site `norm_tiles` tiled; `activation` sigmoid | silu, `gate_bias`
+    [C] or None."""
+    if activation not in ("sigmoid", "silu"):
+        raise ValueError(f"no kernel for the gate {activation!r}")
     return _gated_norm(
-        o, gate, _row(gate_bias), _row(jnp.tile(scale.astype(F32), heads)),
-        o.shape[2] // heads, float(eps), tiles, interpret)
+        o, gate, None if gate_bias is None else _row(gate_bias),
+        _row(jnp.tile(scale.astype(F32), heads)), o.shape[2] // heads,
+        float(eps), activation, tiles, interpret)

@@ -8,6 +8,11 @@ one op each, what streams [S, H D] values through the vector unit before
 that recurrence (the three convolutions and the channels' decay:
 kda_conv_decay; a head's decay: gated_delta_decay) and after it (the gated
 norm a head, under a sigmoid with a bias or a SiLU: kda_gated_norm).
+short_conv1d, kda_conv_decay and kda_gated_norm are each ONE algorithm
+whose engine is read from the site: kernels/kda_mix.py's row-tiled Pallas
+kernel pairs for ONE TPU where the shape tiles, the jax.numpy functions of
+this file (`short_conv`, `conv_decay`, `gated_norm`: the definitions the
+kernels are held to) anywhere else.
 """
 
 from __future__ import annotations
@@ -26,6 +31,13 @@ _CONV_ACTS = {**ACTS, "silu": jax.nn.silu}
 _GATES = {"sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu}
 
 
+def short_conv(x, w, bias, activation):
+    """The op short_conv1d's arithmetic (its docstring), in jax.numpy."""
+    y = causal_conv1d(x[:, None], w[:, None],
+                      None if bias is None else bias[None])[:, 0]
+    return _CONV_ACTS[activation](y).astype(x.dtype)
+
+
 @register_op("short_conv1d", infer_shape=same_shape("X", "Out"),
              diff_inputs=["X", "W", "Bias"])
 def _short_conv1d(ctx, ins, attrs):
@@ -33,13 +45,35 @@ def _short_conv1d(ctx, ins, attrs):
     `activation` (identity | silu | ...): X [B, S, C], W [k, C], one filter
     of k taps a channel; y[t] = sum_j W[j] x[t - (k - 1) + j], zeros before
     the first position (attention_ops.causal_conv1d: k shifted products
-    summed in fp32), plus Bias [C] where there is one.  Out in X's dtype."""
+    summed in fp32), plus Bias [C] where there is one.  Out in X's dtype.
+
+    One algorithm, its engine read from the site (kernels/engine.py::site)
+    as kda_conv_decay's is: for ONE TPU, under silu or identity, where the
+    shape tiles (kernels/kda_mix.py::short_conv_tiles: C whole 128-lane
+    vectors, k - 1 <= 8, S whole tiles of rows, bf16 or fp32), a Pallas
+    kernel pair over tiles of rows x blocks of channels whose backward
+    keeps X, W and Bias and nothing else; anywhere else `short_conv`, the
+    same arithmetic in jax.numpy.  `short_conv.lower` (a span, at lowering;
+    `what` short_conv) says what a site was given, in `kda.mix.lower`'s
+    fields: `engine`, `rows`, `channels`, `halo`, `fwd_vmem_bytes`,
+    `bwd_vmem_bytes`, `moved_bytes`."""
+    from ..kernels import engine, kda_mix
+
     x, w = data(ins["X"][0]), data(ins["W"][0])
     bias = ins.get("Bias", [None])[0]
-    y = causal_conv1d(x[:, None], w[:, None],
-                      None if bias is None else data(bias)[None])[:, 0]
-    return {"Out": [_CONV_ACTS[str(attrs.get("activation") or "identity")](
-        y).astype(x.dtype)]}
+    bias = None if bias is None else data(bias)
+    act = str(attrs.get("activation") or "identity")
+    out = engine.site(
+        "short_conv.lower", kda_mix.Tiles._fields, ctx.mesh,
+        lambda: kda_mix.short_conv_tiles(x.shape[1], x.shape[2], w.shape[0],
+                                         x.dtype)
+        if act in kda_mix.SHORT_CONV_ACTS and engine.one_dtype(x) else None,
+        lambda tiles, interpret: kda_mix.short_conv(x, w, bias, act, tiles,
+                                                    interpret),
+        lambda: short_conv(x, w, bias, act), what="short_conv",
+        moved_bytes=kda_mix.short_conv_moved_bytes(
+            x, bool(attrs.get("@recompute@"))))
+    return {"Out": [out]}
 
 
 def _gated_delta_infer(op, block):
@@ -226,9 +260,9 @@ def _kda_gated_norm(ctx, ins, attrs):
     cotangent alone; `kda.mix.lower` with `what` gated_norm.
 
     Under `gate_activation` silu, with no GateBias, it is Gated DeltaNet's:
-    the same norm a head times silu(Gate).  That rule has no kernel pair
-    yet: the site says `engine` xla (the jax.numpy arithmetic, which the
-    compiler fuses into two passes over the rows)."""
+    the same norm a head times silu(Gate).  The gate's rule (a key of
+    `_GATES`, with or without a GateBias) is a static argument of the one
+    kernel pair, so the site runs the kernels wherever the shape tiles."""
     from ..kernels import engine, kda_mix
 
     bias = ins.get("GateBias", [None])[0]
@@ -237,13 +271,13 @@ def _kda_gated_norm(ctx, ins, attrs):
             None if bias is None else data(bias), data(ins["Scale"][0]),
             int(attrs["heads"]), float(attrs.get("epsilon", 1e-6))]
     o, gate = args[:2]
-    kimis = act == "sigmoid" and bias is not None
     out = engine.site(
         "kda.mix.lower", kda_mix.Tiles._fields, ctx.mesh,
         lambda: kda_mix.norm_tiles(o.shape[1], o.shape[2],
                                    o.shape[2] // args[4], o.dtype)
-        if kimis and engine.one_dtype(o, gate) else None,
-        lambda tiles, interpret: kda_mix.gated_norm(*args, tiles, interpret),
+        if act in _GATES and engine.one_dtype(o, gate) else None,
+        lambda tiles, interpret: kda_mix.gated_norm(*args, tiles, interpret,
+                                                    act),
         lambda: gated_norm(*args, act), what="gated_norm",
         moved_bytes=kda_mix.norm_moved_bytes(
             o, gate, bool(attrs.get("@recompute@"))))

@@ -429,6 +429,34 @@ def _kda_mix(backward):
         argnums=tuple(range(13))), args
 
 
+def _gdn_mix(backward):
+    """qwen3-next-80b-a3b's passes around the scan at the cell's shapes
+    (q | k | v [1, 8192, 8192], four taps, silu, no bias; o and the gate
+    [1, 8192, 32 x 128] under silu with no bias; bf16 streams):
+    kernels/kda_mix.py's one-stream convolution and the norm under the
+    SiLU rule at the planned tiles, forward and with them the backward."""
+    from paddle_tpu.kernels import kda_mix
+
+    S, C, H, D, taps = 8192, 8192, 32, 128, 4
+    args = (_sds((1, S, C), jnp.bfloat16), _sds((taps, C), jnp.float32),
+            _sds((1, S, H * D), jnp.bfloat16),
+            _sds((1, S, H * D), jnp.bfloat16), _sds((D,), jnp.float32))
+
+    def fwd(x, w, o, gate, scale):
+        before = kda_mix.short_conv_tiles(S, C, taps, jnp.bfloat16)
+        after = kda_mix.norm_tiles(S, H * D, D, jnp.bfloat16)
+        assert before is not None and after is not None
+        return (kda_mix.short_conv(x, w, None, "silu", before),
+                kda_mix.gated_norm(o, gate, None, scale, H, 1e-6, after,
+                                   activation="silu"))
+
+    if not backward:
+        return fwd, args
+    return jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32) ** 2) for o in fwd(*a)),
+        argnums=tuple(range(5))), args
+
+
 def _mhc(backward, resident=1):
     """xing4.0-29b-a4b's hyper-connection at the cell's shape (four streams
     of 3584, S 4096, 20 Sinkhorn iterations, bf16 streams): kernels/mhc.py's
@@ -468,6 +496,8 @@ _MAIN_PATH_KERNELS = {
     "mhc_bwd_pallas_streamed_xing": lambda: _mhc(True, resident=0),
     "kda_mix_fwd_kimi": lambda: _kda_mix(False),
     "kda_mix_bwd_pallas_kimi": lambda: _kda_mix(True),
+    "gdn_mix_fwd_qwen3next": lambda: _gdn_mix(False),
+    "gdn_mix_bwd_pallas_qwen3next": lambda: _gdn_mix(True),
     "ssm_scan_fwd_sambay": lambda: _ssm_scan(False),
     "ssm_scan_bwd_pallas_sambay": lambda: _ssm_scan(True),
     "ssd_scan_fwd_granite": lambda: _ssd_scan(False),

@@ -346,18 +346,26 @@ def test_the_step_as_it_lowers_for_a_tpu():
         d_expert=128, d_shared_expert=128)
     text, spans = _step_for_the_tpu(
         models.gated_delta_decoder, cfg,
-        span_names=("gdn.lower", "kda.lower", "kda.mix.lower", "attn.lower",
-                    "recurrence.lower", "router.lower"))
+        span_names=("gdn.lower", "kda.lower", "kda.mix.lower",
+                    "short_conv.lower", "attn.lower", "recurrence.lower",
+                    "router.lower"))
     assert spans["kda.lower"] == []
     assert [(s["engine"], s["decay"], s["key_heads"], s["heads"], s["chunk"])
             for s in spans["gdn.lower"]] == [("pallas", "head", 1, 2, 64)]
-    assert [(s["what"], s["engine"]) for s in spans["kda.mix.lower"]] \
-        == [("gated_norm", "xla")]
+    # what streams around the scan: the convolution of q | k | v (512
+    # channels) and the SiLU-gated norm, kernels/kda_mix.py's pairs
+    assert [(s["what"], s["engine"], s["channels"])
+            for s in spans["short_conv.lower"] + spans["kda.mix.lower"]] \
+        == [("short_conv", "pallas", 512), ("gated_norm", "pallas", 256)]
     assert [(s["kind"], s["heads"], s["kv_heads"], s["rope"])
             for s in spans["attn.lower"]] == [("full", 2, 1, "partial")]
     assert [s["recompute"] for s in spans["recurrence.lower"]] == [1, 1]
     calls = _kernels(text)
     assert calls["_fwd_kernel"] == 1 and calls["_bwd_kernel"] == 1
+    # these keep nothing: a forward in the layer, one in its recomputation
+    assert (calls["_short_conv_kernel"], calls["_short_conv_bwd_kernel"],
+            calls["_gated_norm_kernel"], calls["_gated_norm_bwd_kernel"]) \
+        == (2, 1, 2, 1)
     # g reaches the kernels by tiles, [1, 2 heads, 1 group, 2 tiles, 128],
     # q and k at their one head, [1, S, 128] bf16
     assert re.search(r"tensor<1x2x1x2x128xf32>", text)
