@@ -23,6 +23,12 @@ from . import amp
 from .. import flags
 from .. import observability as _obs
 from ..observability.compiles import default_compile_log as _compile_log
+from ..observability.stepstats import (
+    DISPATCH as _T_DISPATCH,
+    DISPATCHED as _T_DISPATCHED,
+    FETCH as _T_FETCH,
+    READY as _T_READY,
+)
 from .compiler import CompiledBlock
 from .framework import Program, Variable, default_main_program
 from .lod import LoDValue
@@ -299,8 +305,10 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     clock always, in the ring under FLAGS_observability).  Where the fetch
     converts to the host it is `executor.wait` (until every fetched value
     is ready: its end is the host's "device done" mark) and then
-    `executor.copy`.  The frame lets go of what the step consumed as soon
-    as the scope holds what the step produced: at the end of
+    `executor.copy`.  The same boundaries go to the step log, flag or no
+    flag (observability/stepstats.py: one record a step under the span's
+    `seq`, eight clock reads).  The frame lets go of what the step
+    consumed as soon as the scope holds what the step produced: at the end of
     `executor.commit` it drops the staged state and key, the last
     references to the arrays the call took by donation, so that their
     release (a call into the runtime a shard) runs on the host while the
@@ -329,7 +337,10 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
     from ..resilience import faultinject
 
     skipped = False
-    with _obs.span("executor.step", kind=kind, seq=next(_STEP_SEQ)) as step:
+    steps = _obs.step_stats()
+    seq = next(_STEP_SEQ)
+    with _obs.span("executor.step", kind=kind, seq=seq) as step:
+        rec = steps.begin(seq, kind)
         with _obs.span("executor.plan") as sp:
             entry, hit = lookup()
             _, call, plan = entry
@@ -346,12 +357,14 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             sp.set(n=n_given, moved=moved)
         log = _compile_log()
         compiled = log.count
+        steps.mark(rec + _T_DISPATCH)
         with _obs.span("executor.dispatch") as sp, placed:
             fetches, new_states, new_rng = call(feed_vals, state_vals, rng)
             if log.count != compiled:
                 # this step made executables: `executables`,
                 # `cache_misses`, `compile_s`, on the step that paid
                 sp.set(**log.since(compiled))
+        steps.mark(rec + _T_DISPATCHED)
         with _obs.span("executor.commit") as sp:
             fetches = faultinject.nan_fetches(plan.fetch_names, fetches)
             if sentinel is not None and sentinel(plan, fetches, new_states):
@@ -371,6 +384,10 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
             # skipped step still the old): the frame's references go here,
             # under the running step, and not after the wait
             del state_vals, rng
+        if return_numpy:
+            steps.mark_cpu(rec + _T_FETCH)
+        else:
+            steps.mark(rec + _T_FETCH)
         with _obs.span("executor.fetch") as sp:
             if return_numpy:
                 with _obs.span("executor.wait"):
@@ -382,10 +399,14 @@ def run_step(kind, program, scope, lookup, feeds, stage, placed, device,
                         if isinstance(v, jax.Array):
                             v.copy_to_host_async()
                     jax.block_until_ready(fetches)
+                steps.mark_cpu(rec + _T_READY)
                 with _obs.span("executor.copy"):
                     out = plan.convert_fetches(fetches, block0, True)
             else:
                 out = plan.convert_fetches(fetches, block0, False)
+            # a step that missed the table, or under which the set-up log
+            # grew, is no reference for the steps around it
+            steps.end(rec, not hit or log.count != compiled)
             sp.set(n=len(out))
         if not hit:
             log.close_run(kind, len(plan.feed_names), len(out),

@@ -46,10 +46,11 @@ _DEFS: Dict[str, Any] = {
     # unified telemetry spine — per-step executor metrics (wall-time
     # histogram, compile-cache hit/miss, donation status, sentinel
     # skips), trace spans (compile/step/ckpt, exported as one merged
-    # Chrome/Perfetto trace), resilience/elastic counters, and the
-    # StepStats p50/p99 ring buffer.  Off (default): every instrument
-    # returns after a single dict lookup — no locks, allocations, or
-    # clock reads on the hot path (tier-1 asserts this).
+    # Chrome/Perfetto trace) and resilience/elastic counters.  Off
+    # (default): each of those returns after a single dict lookup — no
+    # locks, allocations, or clock reads on the hot path; the set-up log
+    # and the step log (observability/compiles.py, stepstats.py) are on
+    # whatever this says (tier-1 asserts both).
     "FLAGS_observability": False,
     # per-program bytes/step cost attribution, recorded once per fresh
     # compiled entry when observability is on: "native" prices the

@@ -18,12 +18,17 @@ was a chrome-trace stub with no hot-path consumers.  Now:
 - **The set-up log** (`compiles.py`): one record an executable from jax's
   own compile events (trace, lowering, build or load from the persistent
   cache, the cache entry's bytes and what its write evicted) and one first
-  run a program the executor had not met.  The ONE instrument that is
-  always on: set-up is over before a reader runs.
-- **Step stats** (`stepstats.py`): ring buffer of the `executor.step`
-  spans' durations (to the fetched values on the host) with rolling
-  p50/p99, plus the BENCH_BASELINE regression gate
-  bench.py uses to emit pass/fail deltas.
+  run a program the executor had not met.  Always on: set-up is over
+  before a reader runs.
+- **The step log** (`stepstats.py`): one record a step of either executor,
+  all numbers, at the boundaries the `executor.*` spans mark (step start,
+  dispatch start and end, fetch start, ready, step end) plus the process's
+  CPU time around the wait, under the span's `seq`.  Always on too, and for
+  the same reason: a stalled step is over before a reader can ask, and the
+  runs it hits are the untraced ones.  Rolling p50/p99 of the step's
+  duration come from it; a stalled step is logged as it happens.  Beside
+  it the BENCH_BASELINE regression gate bench.py uses to emit pass/fail
+  deltas.
 - **Request traces** (`requesttrace.py`): per-request trace ids minted
   at Engine.submit(), cross-thread span trees (submit thread ->
   dispatcher -> completion) folded into the same merged trace, kept by
@@ -36,26 +41,30 @@ was a chrome-trace stub with no hot-path consumers.  Now:
   circuit breaker trips or engine health enters BROKEN — the black box
   every chaos failure leaves behind.
 
-Everything but a span's place in a profiler session and the set-up log is
+Two instruments are always on, the set-up log and the step log; a span's
+place in a profiler session needs no flag either.  Everything else is
 gated on **FLAGS_observability** (env `FLAGS_observability=1` or
-`fluid.set_flags({"FLAGS_observability": True})`).  Disabled, every
+`fluid.set_flags({"FLAGS_observability": True})`).  Disabled, every other
 instrument returns after one dict lookup and a span is an inert
 jax.profiler.TraceAnnotation — no locks, no clock reads, no registry call,
-nothing appended, nothing that outlives the with-block (tier-1 asserts this
-of the executor's disabled path).  `FLAGS_observability_cost=native|tpu`
+nothing appended, nothing that outlives the with-block; a steady step adds
+one record to the step log, eight clock reads in `stepstats.py` and no
+allocation, and nothing to the set-up log (tier-1 asserts all of this of
+the executor's disabled path).  `FLAGS_observability_cost=native|tpu`
 additionally records each compiled program's bytes/step from XLA's cost
 model (the `tpu` mode prices the CHIP program via the chip-less AOT
 tier, core/aot_tpu.py — a bytes/step measurement loop with no chip).
 
 Artifacts: `export_run(dirname)` writes `metrics.prom`, `metrics.json`,
-`trace.json` (Perfetto-loadable) and `report.json` (step-time summary +
-regression verdicts); `tools/obsdump.py` renders a run directory into a
-human-readable report.
+`trace.json` (Perfetto-loadable) and `report.json` (step-time summary, the
+step log and its stalls, the set-up log, regression verdicts);
+`tools/obsdump.py` renders a run directory into a human-readable report.
 """
 
 from __future__ import annotations
 
 import json
+import logging
 import os
 import time
 from typing import List, Optional
@@ -81,6 +90,7 @@ from .requesttrace import (  # noqa: F401
     mint_trace_id,
 )
 from .stepstats import (  # noqa: F401
+    MAX_LINES,
     StepStats,
     gate_results,
     load_baseline_metrics,
@@ -149,13 +159,13 @@ default_compile_log().listen()
 
 
 def step_stats() -> StepStats:
-    """The process-wide step-time ring buffer Executor.run records into."""
+    """The process-wide step log core/executor.py::run_step writes."""
     return _step_stats
 
 
 def reset() -> None:
     """Clear the default registry, tracer, request tracer, flight
-    recorder, set-up log and step stats (fresh run in the same process;
+    recorder, set-up log and step log (fresh run in the same process;
     tests)."""
     default_registry().reset()
     default_tracer().clear()
@@ -172,11 +182,12 @@ def reset() -> None:
 
 def record_executor_step(seconds: float, donated: bool,
                          skipped: bool = False) -> None:
-    """One step of either executor: the `executor.step` span's duration
-    (plan to the fetched values on the host; with return_numpy=False the
-    fetch does not wait and the device's time shows up at the caller's
-    sync points), donation status, and whether the sentinel skipped the
-    write-back."""
+    """One step of either executor into the registry: the `executor.step`
+    span's duration (plan to the fetched values on the host; with
+    return_numpy=False the fetch does not wait and the device's time shows
+    up at the caller's sync points), donation status, and whether the
+    sentinel skipped the write-back.  (The step log has the step already,
+    flag or no flag.)"""
     reg = default_registry()
     reg.histogram(
         "paddle_tpu_executor_step_seconds",
@@ -191,7 +202,6 @@ def record_executor_step(seconds: float, donated: bool,
             "paddle_tpu_executor_skipped_steps",
             "steps skipped by the FLAGS_check_numerics sentinel",
         ).inc()
-    _step_stats.record(seconds)
 
 
 def record_compile_cache(hit: bool) -> None:
@@ -291,9 +301,13 @@ def export_run(dirname: str, results: Optional[List[dict]] = None,
     - metrics.json  — the same registry as a merge-able JSON snapshot
     - trace.json    — merged Chrome/Perfetto trace (spans + profiler
       events, named threads, stable tids)
-    - report.json   — step-time summary (p50/p99), the set-up log
-      (`setup`: compiles.py's snapshot), optional bench results, and
-      regression verdicts vs `baseline_path`
+    - report.json   — step-time summary (p50/p99), the step log (`steps`:
+      stepstats.py's snapshot, every retained record and the stalled
+      ones), the set-up log (`setup`: compiles.py's snapshot), optional
+      bench results, and regression verdicts vs `baseline_path`
+
+    Where more steps stalled than the step log's WARNING lines told of,
+    one more line says how many.
 
     On multi-process runs EVERY artifact is namespaced `*_<pid>.*` for
     process index > 0 (a shared run dir must never have two processes
@@ -322,6 +336,7 @@ def export_run(dirname: str, results: Optional[List[dict]] = None,
         "version": 1,
         "wall_time": time.time(),
         "step_time": _step_stats.summary(),
+        "steps": _step_stats.snapshot(),
         "span_count": n_spans,
         "request_traces": default_request_tracer().stats(),
         "flight_dumps": list(default_flight().dump_paths),
@@ -340,4 +355,9 @@ def export_run(dirname: str, results: Optional[List[dict]] = None,
     with open(tmp, "w") as f:
         json.dump(report, f, indent=2)
     os.replace(tmp, os.path.join(dirname, f"report{sfx}.json"))
+    unsaid = _step_stats.stalls_seen - MAX_LINES
+    if unsaid > 0:
+        logging.getLogger("paddle_tpu").warning(
+            "%d more steps stalled than were logged: report%s.json's "
+            "`steps.stalls` has those the step log still holds", unsaid, sfx)
     return report

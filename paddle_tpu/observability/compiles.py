@@ -1,8 +1,8 @@
 """The set-up log: what a process paid before its first steady step.
 
 Set-up is over before any reader runs, and the benchmark runs with
-FLAGS_observability off, so this one log is ALWAYS on.  It costs a steady
-step nothing: jax fires its compile events only when something is traced,
+FLAGS_observability off, so this log is ALWAYS on (one of two: the step
+log, stepstats.py, is the other).  It costs a steady step nothing: jax fires its compile events only when something is traced,
 lowered, built or loaded, and the executor reads a clock only on a run that
 missed its in-memory table (core/executor.py::cached_entry).
 

@@ -164,7 +164,7 @@ class RequestTracer:
             p99 = self._p99_locked()
             keep = forced or p99 is None or latency >= p99
             if not forced:
-                self._latency.record(latency)
+                self._latency.add(rt.t0, t_end)
             budget = int(_flags._VALUES["FLAGS_request_trace_budget"])
             if keep and self.kept >= budget:
                 keep = False

@@ -21,8 +21,10 @@ stable per-thread tids (main thread is always tid 0; other threads are
 ordered by their first span's start time — insertion-order ints with no
 names left Perfetto rows unlabeled).
 
-Flag off: a span is its TraceAnnotation and nothing else: no clock is
-read, nothing is appended, nothing outlives the with-block.
+Flag off: a span is its TraceAnnotation and nothing else: this module
+reads no clock, appends nothing, and nothing outlives the with-block.  (A
+step's boundaries are kept all the same, by the step log, stepstats.py,
+which core/executor.py::run_step writes beside the spans.)
 """
 
 from __future__ import annotations
@@ -119,7 +121,7 @@ class Tracer:
 
     Bounded: keeps the newest `capacity` spans (deque ring — a
     long-lived trainer with observability on must not grow host memory
-    one Span per step forever; StepStats and the profiler trace are
+    one Span per step forever; the step log and the profiler trace are
     bounded the same way).  `dropped` counts evictions so an export can
     say the trace is a tail window."""
 

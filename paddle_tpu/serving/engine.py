@@ -939,7 +939,7 @@ class Engine:
             # them a slow-failing outage would leave the shed estimator
             # trusting a stale fast-success p50
             now = time.perf_counter()
-            self._batch_lat.record(now - t0)
+            self._batch_lat.add(t0, now)
             if obs_on:
                 for r in batch:
                     if r.trace is None:
@@ -982,7 +982,7 @@ class Engine:
             self._consecutive_errors = 0
             self._breaker_open_until = 0.0
             self._last_dispatch_ok = now
-        self._batch_lat.record(now - t0)
+        self._batch_lat.add(t0, now)
         if obs_on:
             if breaker_was_open:
                 self._flight_record("breaker_close")

@@ -258,13 +258,13 @@ def test_span_disabled_records_nothing():
 def test_stepstats_ring_and_percentiles():
     st = obs.StepStats(capacity=100)
     for v in range(1, 101):
-        st.record(v / 1000.0)
+        st.add(7.0, 7.0 + v / 1000.0)
     assert st.count == 100
     assert st.p50() == pytest.approx(0.050)
     assert st.p99() == pytest.approx(0.099)
     # rollover: 50 more samples push the window past capacity
     for v in range(101, 151):
-        st.record(v / 1000.0)
+        st.add(7.0, 7.0 + v / 1000.0)
     w = st.window()
     assert len(w) == 100 and st.count == 150
     assert min(w) == pytest.approx(0.051)  # oldest 50 rotated out
@@ -272,6 +272,17 @@ def test_stepstats_ring_and_percentiles():
     assert s["count"] == 150 and s["window"] == 100
     assert s["max_s"] == pytest.approx(0.150)
     assert s["last_s"] == pytest.approx(0.150)
+    # the same store as plain values: what rotated out is counted
+    snap = st.snapshot()
+    assert snap["count"] == 150 and snap["dropped"] == 50
+    assert len(snap["records"]) == 100 and snap["stalls"] == []
+    newest = dict(zip(snap["fields"], snap["records"][-1]))
+    assert (newest["kind"], newest["t_start"]) == (-1.0, 7.0)
+    assert newest["t_end"] == pytest.approx(7.150)
+    assert newest["t_ready"] is None and newest["seq"] is None
+    st.reset()
+    assert st.count == 0 and st.window() == []
+    assert st.summary() == {"count": 0, "window": 0}
 
 
 def test_regression_verdicts():
@@ -445,29 +456,34 @@ def test_histogram_rejects_conflicting_buckets(obs_on):
         reg.histogram("h", "", buckets=[0.1, 1.0])
 
 
-def test_disabled_path_zero_observability_overhead(monkeypatch):
-    """The contract with the flag off and no profiler session: a span is
-    its inert TraceAnnotation and nothing else.  No instrument of the
-    registry is reached, nothing is appended to the ring, the package reads
-    no clock, and nothing it allocated outlives the step.  The set-up log,
-    the one instrument that is always on, has the first runs and their
-    executables, and a steady step adds nothing to it and reads no clock
-    in its module either."""
+@pytest.mark.parametrize("kind", ["serial", "spmd"])
+def test_disabled_path_zero_observability_overhead(kind, monkeypatch):
+    """The contract with the flag off and no profiler session, for either
+    executor: a span is its inert TraceAnnotation and nothing else.  No
+    instrument of the registry is reached, nothing is appended to the ring,
+    `tracing` and `compiles` read no clock, and nothing the package
+    allocated outlives the step.  Two instruments are on all the same.  The
+    set-up log has the first runs and their executables, and a steady step
+    adds nothing to it.  The step log gets exactly one record a step, from
+    exactly eight clock reads in its module: six of `perf_counter`, two of
+    `process_time`."""
     import gc
     import tracemalloc
 
-    from paddle_tpu.observability import compiles, tracing
+    from paddle_tpu.observability import compiles, stepstats, tracing
 
     assert not obs.enabled()
     obs.reset()
-    exe, loss = _build_step(name="obs_cold_w")
+    step = _stepper(kind)
     for i in range(2):  # warm the compile + caches
-        exe.run(feed=_feed(i), fetch_list=[loss])
+        step()
     log = obs.default_compile_log()
     before = log.snapshot()
     assert len(before["runs"]) == 2  # the start-up program, the step
-    assert sum(r["fun"] == "jit(fn)" and r["run"] is not None
-               for r in before["records"]) == 2
+    assert sum(r["fun"].startswith("jit(") and r["run"] is not None
+               for r in before["records"]) >= 2
+    steps = obs.step_stats()
+    assert steps.count == 3  # the start-up program's run, the two steps
 
     calls = []
     for name in ("record_executor_step", "record_compile_cache",
@@ -483,11 +499,25 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
 
     monkeypatch.setattr(tracing, "time", NoClock())
     monkeypatch.setattr(compiles, "time", NoClock())
+    # the step log's module reads its two clocks through these two names
+    # and through nothing else
+    monkeypatch.setattr(stepstats, "time", NoClock())
+    reads = {"wall": 0, "cpu": 0}
+    wall, cpu = stepstats._wall, stepstats._cpu
+
+    def counted(clock, name):
+        def read():
+            reads[name] += 1
+            return clock()
+        return read
+
+    monkeypatch.setattr(stepstats, "_wall", counted(wall, "wall"))
+    monkeypatch.setattr(stepstats, "_cpu", counted(cpu, "cpu"))
     obs_pkg_dir = os.path.dirname(os.path.abspath(obs.__file__))
     tracemalloc.start()
     try:
         for i in range(3):
-            exe.run(feed=_feed(i), fetch_list=[loss])
+            step()
         # an annotation's memory goes back at the next collection, not at
         # the with-block's end: live is what a collection leaves
         gc.collect()
@@ -501,15 +531,20 @@ def test_disabled_path_zero_observability_overhead(monkeypatch):
     ).statistics("filename")
     assert hits == [], f"observability allocated while disabled: {hits}"
     assert log.count == len(before["records"])
+    assert reads == {"wall": 18, "cpu": 6}  # eight a step
+    assert steps.count == 6  # one record a step
     monkeypatch.undo()
     assert log.snapshot() == before
+    rows = _log_rows()
+    assert [r["fresh"] for r in rows] == [1.0, 1.0, 0.0, 0.0, 0.0, 0.0]
+    assert all(r["kind"] == stepstats.KINDS.index(kind) for r in rows[1:])
     # control: the SAME steps with the flag on do reach the instruments
     calls = []
     monkeypatch.setattr(obs, "record_executor_step",
                         lambda *a, **k: calls.append(1))
     fluid.set_flags({"FLAGS_observability": True})
     try:
-        exe.run(feed=_feed(0), fetch_list=[loss])
+        step()
     finally:
         fluid.set_flags({"FLAGS_observability": False})
         obs.reset()
@@ -643,9 +678,269 @@ def test_step_phases_land_in_the_ring_with_parents(kind, obs_on):
         donated="1") == 2
     assert reg.histogram("paddle_tpu_executor_step_seconds",
                          "").series_summary()["count"] == 2
-    # the step's time is the span's: to the fetched value on the host
+    # the step's time is the span's, to the fetched value on the host: the
+    # log reads the same clock just inside the span's two ends
     assert obs.step_stats().summary()["max_s"] == pytest.approx(
-        max(s.duration for s in steps))
+        max(s.duration for s in steps), abs=200e-6)
+
+
+# -----------------------------------------------------------------------
+# the step log: always on, one record a step, both executors
+# -----------------------------------------------------------------------
+def _log_rows():
+    from paddle_tpu.observability import stepstats
+
+    return [dict(zip(stepstats.FIELDS, r))
+            for r in obs.step_stats().snapshot()["records"]]
+
+
+ORDER = ["t_start", "t_dispatch", "t_dispatched", "t_fetch", "t_ready",
+         "t_end"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_step_log_one_record_a_step(kind):
+    """The flag off, no profiler: N steps leave N records with consecutive
+    `seq`, their boundaries in order on one clock, and periods that add up
+    to the run (a step's period is the next step's start less its own)."""
+    assert not obs.enabled()
+    step = _stepper(kind)
+    step()  # compiles
+    obs.reset()
+    t0 = time.perf_counter()
+    for _ in range(5):
+        step()
+    t1 = time.perf_counter()
+    rows = _log_rows()
+    assert len(rows) == 5 and obs.step_stats().count == 5
+    seqs = [r["seq"] for r in rows]
+    assert seqs == [seqs[0] + i for i in range(5)]
+    for r in rows:
+        marks = [r[k] for k in ORDER]
+        assert marks == sorted(marks) and t0 <= marks[0] and marks[-1] <= t1
+        assert 0.0 <= r["cpu_ready"] - r["cpu_fetch"]
+        assert (r["kind"], r["fresh"]) == (KINDS.index(kind), 0.0)
+    starts = [r["t_start"] for r in rows] + [t1]
+    periods = [b - a for a, b in zip(starts, starts[1:])]
+    assert all(p > 0 for p in periods)
+    assert sum(periods) == pytest.approx(t1 - rows[0]["t_start"])
+    assert sum(periods) == pytest.approx(t1 - t0, abs=1e-3)
+    # the summary is of the records: the step's duration
+    summ = obs.step_stats().summary()
+    assert summ["count"] == summ["window"] == 5
+    assert summ["max_s"] == pytest.approx(
+        max(r["t_end"] - r["t_start"] for r in rows))
+    obs.reset()
+    assert obs.step_stats().count == 0
+
+
+def test_step_log_without_a_wait_has_no_ready():
+    """`return_numpy=False` does not wait: the record says so with NaN
+    (None as plain values), and still ends."""
+    exe, loss = _build_step(name="obs_nowait_w")
+    obs.reset()
+    exe.run(feed=_feed(0), fetch_list=[loss], return_numpy=False)
+    (row,) = _log_rows()
+    assert row["t_ready"] is None
+    assert row["cpu_fetch"] is None and row["cpu_ready"] is None
+    assert row["t_start"] <= row["t_fetch"] <= row["t_end"]
+    assert row["fresh"] == 1.0  # its first run
+    obs.reset()
+
+
+def test_step_log_seq_is_the_spans_seq(tmp_path):
+    """One clock with the device trace, by `seq`: inside a plain profiler
+    session a record's step and wait are the host plane's `executor.step`
+    and `executor.wait` of that `seq`, to 200 us."""
+    import jax
+
+    step = _stepper("serial")
+    step()
+    obs.reset()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(3):
+            step()
+    finally:
+        jax.profiler.stop_trace()
+    evs = _host_events(str(tmp_path))
+    spans = [e for e in evs if e[0] == "executor.step"]
+    rows = {r["seq"]: r for r in _log_rows()}
+    assert sorted(rows) == sorted(e[3]["seq"] for e in spans)
+    for _, s0, e0, counts in spans:
+        r = rows[counts["seq"]]
+        (wait,) = [e for e in evs if e[0] == "executor.wait"
+                   and s0 <= e[1] and e[2] <= e0]
+        assert r["t_end"] - r["t_start"] == pytest.approx(
+            (e0 - s0) / 1e9, abs=200e-6)
+        assert r["t_ready"] - r["t_fetch"] == pytest.approx(
+            (wait[2] - wait[1]) / 1e9, abs=200e-6)
+    obs.reset()
+
+
+def test_step_log_keeps_every_step_of_concurrent_threads():
+    """Hogwild threads share the one log: each step gets a record of its
+    own (a lost update of the count would hand two steps one record and
+    leave marks of two steps in it)."""
+    from paddle_tpu.observability import stepstats as ss
+
+    threads, steps = 8, 400
+    log = ss.StepStats(capacity=threads * steps)
+
+    def work(t):
+        for i in range(steps):
+            rec = log.begin(t * steps + i, "serial")
+            log.mark(rec + ss.DISPATCH)
+            log.mark(rec + ss.DISPATCHED)
+            log.mark_cpu(rec + ss.FETCH)
+            log.mark_cpu(rec + ss.READY)
+            log.end(rec, False)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pool = [threading.Thread(target=work, args=(t,))
+                for t in range(threads)]
+        for th in pool:
+            th.start()
+        for th in pool:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(th.is_alive() for th in pool)
+    assert log.count == threads * steps
+    snap = log.snapshot()
+    rows = [dict(zip(snap["fields"], r)) for r in snap["records"]]
+    assert sorted(r["seq"] for r in rows) == list(range(threads * steps))
+    for r in rows:
+        marks = [r[k] for k in ORDER]
+        assert marks == sorted(marks)
+
+
+class _Clock:
+    """The step log's two clocks by hand: a read of the wall clock takes
+    0.1 ms, and the process is on a CPU for a tenth of that."""
+
+    def __init__(self):
+        self.wall = self.cpu = 100.0
+
+    def read_wall(self):
+        self.wall += 1e-4
+        self.cpu += 1e-5
+        return self.wall
+
+    def read_cpu(self):
+        return self.cpu
+
+    def sleep(self, s):
+        self.wall += s
+
+    def spin(self, s):
+        self.wall += s
+        self.cpu += s
+
+
+@pytest.fixture
+def by_hand(monkeypatch):
+    """A toy step whose wait can be made long: (step, clock, hook); the
+    step log reads `clock`, and `hook["wait"]` runs inside `executor.wait`
+    after the values are ready."""
+    import jax
+
+    from paddle_tpu.observability import stepstats
+
+    exe, loss = _build_step(name="obs_stall_w")
+    feed = jax.device_put(_feed(0), exe.place.jax_device())
+    exe.run(feed=feed, fetch_list=[loss])
+    clock, hook = _Clock(), {"wait": None}
+    ready = jax.block_until_ready
+
+    def waited(x):
+        out = ready(x)
+        if hook["wait"] is not None:
+            hook["wait"]()
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", waited)
+    monkeypatch.setattr(stepstats, "_wall", clock.read_wall)
+    monkeypatch.setattr(stepstats, "_cpu", clock.read_cpu)
+    obs.reset()
+    yield (lambda: exe.run(feed=feed, fetch_list=[loss])), clock, hook
+    obs.reset()
+
+
+@pytest.mark.parametrize("how", ["asleep", "spinning"])
+def test_a_stalled_step_says_so_once(how, by_hand, caplog):
+    """After 64 plain steps a step whose fetch takes 50 ms longer is the one
+    stalled record, its excess in the wait; the process slept through it
+    or was on a CPU, and the record tells which.  It is logged as it ends,
+    once; a caller that pauses between two steps has stalled nothing."""
+    import logging
+
+    from paddle_tpu.observability.stepstats import BLOCK
+
+    step, clock, hook = by_hand
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu"):
+        for _ in range(BLOCK - 1):
+            step()
+        hook["wait"] = lambda: clock.sleep(0.05)
+        step()  # the 64th: no reference yet, a long step says nothing
+        hook["wait"] = None
+        for _ in range(BLOCK + 3):
+            step()
+        clock.sleep(0.5)  # the caller evaluates, checkpoints, ...
+        step()
+        assert caplog.records == []
+        hook["wait"] = lambda: (clock.sleep if how == "asleep"
+                                else clock.spin)(0.05)
+        step()
+        hook["wait"] = None
+        assert len(caplog.records) == 1  # as it ends
+        step()
+        step()
+    (line,) = [r.getMessage() for r in caplog.records]
+    snap = obs.step_stats().snapshot()
+    (stall,) = snap["stalls"]
+    rows = _log_rows()
+    assert stall["seq"] == rows[2 * BLOCK + 4]["seq"]
+    assert line.startswith(f"step {stall['seq']} stalled: 50.50 ms "
+                           "against a median of 0.50; ")
+    assert stall["kind"] == "serial"
+    assert stall["median_s"] == pytest.approx(5e-4)  # five reads apart
+    assert stall["step_s"] == pytest.approx(0.05 + 5e-4)
+    assert stall["wait_s"] == pytest.approx(0.05 + 1e-4)
+    parts = ("plan_s", "dispatch_s", "commit_s", "wait_s", "copy_s")
+    assert sum(stall[k] for k in parts) == pytest.approx(stall["step_s"])
+    assert stall["wait_cpu_s"] == pytest.approx(
+        1e-5 + (0.0 if how == "asleep" else 0.05))
+    assert obs.step_stats().stalls_seen == 1
+
+
+def test_stall_lines_are_capped_and_export_run_says_the_rest(
+        by_hand, caplog, tmp_path):
+    import logging
+
+    from paddle_tpu.observability.stepstats import BLOCK, MAX_LINES
+
+    step, clock, hook = by_hand
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu"):
+        for _ in range(BLOCK):
+            step()
+        for _ in range(MAX_LINES + 3):
+            hook["wait"] = lambda: clock.sleep(0.02)
+            step()
+            hook["wait"] = None
+            step()
+        stalled = [r.getMessage() for r in caplog.records]
+        assert len(stalled) == MAX_LINES
+        assert all(" stalled: 20.50 ms " in ln for ln in stalled)
+        assert obs.step_stats().stalls_seen == MAX_LINES + 3
+        report = obs.export_run(str(tmp_path))
+    assert len(report["steps"]["stalls"]) == MAX_LINES + 3
+    (rest,) = [r.getMessage() for r in caplog.records[MAX_LINES:]]
+    assert rest.startswith("3 more steps stalled than were logged")
 
 
 def test_values_already_placed_are_not_counted_as_moved(tmp_path):
@@ -785,11 +1080,25 @@ def test_remote_master_retry_stats_accumulate(obs_on, monkeypatch):
 # -----------------------------------------------------------------------
 # run artifacts + obsdump + bench integration
 # -----------------------------------------------------------------------
-def test_export_run_artifacts_and_obsdump(obs_on, tmp_path):
+def test_export_run_artifacts_and_obsdump(obs_on, tmp_path, monkeypatch):
+    import jax
+
     exe, loss = _build_step(name="obs_art_w")
     obs.reset()
     for i in range(4):
         exe.run(feed=_feed(i), fetch_list=[loss])
+    old_report = obs.export_run(str(tmp_path / "few"))
+    assert old_report["steps"]["stalls"] == []  # no reference, no stall
+    # a step that waits 40 ms longer, once the log has a reference
+    feed = jax.device_put(_feed(0), exe.place.jax_device())
+    for i in range(64):
+        exe.run(feed=feed, fetch_list=[loss])
+    ready = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready", lambda x: (ready(x), time.sleep(0.04))[0])
+    exe.run(feed=feed, fetch_list=[loss])
+    monkeypatch.setattr(jax, "block_until_ready", ready)
+    exe.run(feed=feed, fetch_list=[loss])
     base = str(tmp_path / "base.json")
     json.dump({"toy_metric": 100.0}, open(base, "w"))
     d = str(tmp_path / "run")
@@ -798,7 +1107,17 @@ def test_export_run_artifacts_and_obsdump(obs_on, tmp_path):
         baseline_path=base)
     assert sorted(os.listdir(d)) == [
         "metrics.json", "metrics.prom", "report.json", "trace.json"]
-    assert report["step_time"]["count"] == 4
+    assert report["step_time"]["count"] == 70
+    assert set(report["step_time"]) == {
+        "count", "window", "mean_s", "min_s", "max_s", "last_s", "p50_s",
+        "p90_s", "p99_s"}
+    steps = report["steps"]
+    assert steps["count"] == 70 and steps["dropped"] == 0
+    assert len(steps["records"]) == 70
+    assert all(len(r) == len(steps["fields"]) for r in steps["records"])
+    slow = int(steps["records"][68][steps["fields"].index("seq")])
+    (mine,) = [st for st in steps["stalls"] if st["seq"] == slow]
+    assert mine["wait_s"] > 0.04 and mine["wait_cpu_s"] < 0.03  # asleep
     assert report["regression"][0]["verdict"] == "pass"
     prom = open(os.path.join(d, "metrics.prom")).read()
     assert "paddle_tpu_executor_step_seconds_bucket" in prom
@@ -815,8 +1134,21 @@ def test_export_run_artifacts_and_obsdump(obs_on, tmp_path):
     )
     assert out.returncode == 0, out.stderr[-500:]
     assert "p50" in out.stdout
+    assert f"step {slow} stalled: " in out.stdout
     assert "paddle_tpu_executor_step_seconds" in out.stdout
     assert "[PASS]" in out.stdout
+    # a report from before the step log renders as it did
+    older = str(tmp_path / "older")
+    os.makedirs(older)
+    with open(os.path.join(older, "report.json"), "w") as f:
+        json.dump({k: v for k, v in report.items() if k != "steps"}, f)
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "obsdump.py"), older],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.returncode == 0, out.stderr[-500:]
+    assert "p50" in out.stdout and "stalled" not in out.stdout
     # --gate turns a fail verdict into a nonzero exit
     json.dump({"toy_metric": 1000.0}, open(base, "w"))
     out = subprocess.run(

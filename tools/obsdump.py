@@ -10,7 +10,9 @@ FLAGS_observability=1 bench.py run with BENCH_OBS_DIR, or a serve_bench
     metrics.json     registry snapshot (metrics_<pid>.json per process on
                      multi-host runs; this CLI aggregates them all)
     trace.json       merged Chrome/Perfetto trace (load in ui.perfetto.dev)
-    report.json      step-time summary + the set-up log (every first run
+    report.json      step-time summary + the step log (`steps`: a record
+                     a step with its phases' boundaries, and the stalled
+                     ones) + the set-up log (every first run
                      of a program with the executables it made: trace,
                      lowering, build or load from the persistent cache)
                      + regression verdicts + request trace sampling stats
@@ -90,6 +92,18 @@ def _print_step_time(report: dict, out) -> None:
                      ("mean_s", "mean"), ("min_s", "min"),
                      ("max_s", "max")):
         out.write(f"  {label:<5}: {_fmt_s(st.get(k))}\n")
+    # the step log (observability/stepstats.py): a line a stalled step;
+    # a report from before it has no `steps` and ends here
+    steps = report.get("steps") or {}
+    stalls = steps.get("stalls") or []
+    if not stalls:
+        return
+    from paddle_tpu.observability.stepstats import stall_line
+
+    out.write(f"  stalled: {len(stalls)} of the {len(steps['records'])} "
+              f"steps the log holds ({steps['dropped']} dropped)\n")
+    for stall in stalls:
+        out.write(f"    {stall_line(stall)}\n")
 
 
 def _print_setup(report: dict, out) -> None:
